@@ -7,16 +7,12 @@ Executor on CPUPlace/TPUPlace.  Execution lowers whole blocks to XLA via JAX.
 
 import jax as _jax
 
-try:
-    # Make every in-trace random draw a pure function of (key, global
-    # element offset): the legacy threefry lowering re-derives its
-    # counter per SHARD under GSPMD, so the same program draws a
-    # different dropout mask once a mesh shards its operands (the
-    # dp4xtp2 ~0.5%-rel drift — ROADMAP "TP dropout stream alignment").
-    # Global-offset counters make the draw sharding-invariant.
-    _jax.config.update("jax_threefry_partitionable", True)
-except AttributeError:  # newer jax: partitionable is the only mode
-    pass
+# Make every in-trace random draw a pure function of (key, global element
+# offset): the legacy threefry lowering re-derives its counter per SHARD
+# under GSPMD, so the same program draws a different dropout mask once a
+# mesh shards its operands (the dp4xtp2 ~0.5%-rel drift).  Global-offset
+# counters make the draw sharding-invariant.
+_jax.config.update("jax_threefry_partitionable", True)
 
 from . import framework
 from .framework import (

@@ -1,4 +1,4 @@
-"""Two-tier persistent compilation cache (FLAGS_compile_cache_dir).
+"""Two-tier persistent compilation cache.
 
 The reference framework compiles a Program once and reuses the executor
 across steps; this port re-pays trace + lower + XLA compile on every
@@ -6,13 +6,27 @@ process start and every elastic epoch.  With a cache dir set, that cost is
 paid once per (program, flags, world, shapes) key and then amortized across
 processes, restarts, and elastic re-quorums:
 
-  tier A  ``<dir>/xla``  JAX's native persistent XLA cache
-          (``jax_compilation_cache_dir``): dedupes backend compiles of
-          identical HLO, even across different framework-level keys.
+  tier A  JAX's native persistent XLA cache (``jax_compilation_cache_dir``):
+          dedupes backend compiles of identical HLO, even across different
+          framework-level keys.
   tier B  ``<dir>/aot``  framework-level serialized executables
           (``jax.experimental.serialize_executable``): a hit skips trace +
           lower + compile entirely and hands the executor a ready
           ``Compiled`` it can call.
+
+Where the cache lives is decided here and nowhere else (``cache_dir``):
+
+  ``JAX_COMPILATION_CACHE_DIR`` set   the machine placed the cache.  Tier A
+          is that directory — JAX reads the variable itself and this
+          package never writes ``jax_compilation_cache_dir`` — and tier B
+          is its ``aot`` subdirectory.  No flag overrides it.
+  else ``FLAGS_compile_cache_dir``    tier A ``<dir>/xla``, tier B
+          ``<dir>/aot``.
+  else    off.  Entry points (chip_smoke.py, bench.py, tools/serve.py,
+          ``AnalysisConfig.set_optim_cache_dir``) call ``place()``, which
+          falls back to ``DEFAULT_DIR``: one fixed path inside the checkout,
+          because the path is part of tier A's key and a cache that moves
+          never hits.
 
 Tier-B layout: one directory per key, written with the checkpoint
 machinery's crash-safe idiom (``LocalFS.atomic_write_dir`` temp-then-rename
@@ -53,6 +67,7 @@ from . import telemetry as _tm
 
 __all__ = [
     "enabled", "cache_dir", "aot_dir", "xla_dir", "enable_xla_cache",
+    "place", "DEFAULT_DIR",
     "program_fingerprint", "artifact_key", "raw_artifact_key", "load",
     "store", "invalidate",
     "entries", "stats", "clear", "evict_to_cap",
@@ -63,8 +78,14 @@ _SUCCESS = "_SUCCESS"
 _FILES = ("executable.bin", "trees.pkl")
 
 
+_ENV = "JAX_COMPILATION_CACHE_DIR"
+DEFAULT_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
 def cache_dir():
-    return _flags.flag("compile_cache_dir") or ""
+    return os.environ.get(_ENV) or _flags.flag("compile_cache_dir") or ""
 
 
 def enabled():
@@ -76,7 +97,17 @@ def aot_dir():
 
 
 def xla_dir():
-    return os.path.join(cache_dir(), "xla")
+    return os.environ.get(_ENV) or os.path.join(cache_dir(), "xla")
+
+
+def place(path=None):
+    """Turn the cache on for an entry point and return where it lives:
+    ``$JAX_COMPILATION_CACHE_DIR`` if the machine set it, else ``path``,
+    else an already-set FLAGS_compile_cache_dir, else ``DEFAULT_DIR``."""
+    if not os.environ.get(_ENV) and (path or not cache_dir()):
+        _flags.set_flags({"FLAGS_compile_cache_dir": path or DEFAULT_DIR})
+    enable_xla_cache()
+    return cache_dir()
 
 
 # -- tier A: JAX's native persistent XLA cache -------------------------------
@@ -85,30 +116,23 @@ _xla_wired = [None]
 
 
 def enable_xla_cache():
-    """Point jax_compilation_cache_dir at <dir>/xla (idempotent; re-wires
-    if the flag changes).  Called from the executor's compile-miss path so
-    a flag set after Executor construction still takes effect."""
+    """Wire tier A for the current ``cache_dir()`` (idempotent; re-wires if
+    the flag changes).  Called from the executor's compile-miss path so a
+    flag set after Executor construction still takes effect."""
     d = cache_dir()
     if not d or _xla_wired[0] == d:
         return bool(d)
     import jax
 
-    try:
+    if not os.environ.get(_ENV):
         os.makedirs(xla_dir(), exist_ok=True)
         jax.config.update("jax_compilation_cache_dir", xla_dir())
-        # cache everything: the defaults skip sub-second compiles, which is
-        # exactly the CPU-tier test population
-        for knob, val in (("jax_persistent_cache_min_entry_size_bytes", -1),
-                          ("jax_persistent_cache_min_compile_time_secs", 0)):
-            try:
-                jax.config.update(knob, val)
-            except Exception:
-                pass  # knob not present in this jax
-        _xla_wired[0] = d
-        return True
-    except Exception as e:
-        logging.warning("compile_cache: could not enable XLA cache: %s", e)
-        return False
+    # cache everything: the defaults skip sub-second compiles, which is the
+    # whole CPU-tier test population and most decode-step buckets
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    _xla_wired[0] = d
+    return True
 
 
 # -- keys --------------------------------------------------------------------
@@ -148,9 +172,9 @@ def artifact_key(program, feed_sig, fetch_names, trace_flags, mesh_sig=None,
                  extra=None):
     """Content key for one executable.  ``feed_sig`` is the sorted
     (name, shape, dtype-str) tuple the executor already builds; ``mesh_sig``
-    must describe axis names/sizes only (never device ids — an executable
-    serialized in one world must be loadable by the re-initialized backend
-    of the next, where ids are reassigned)."""
+    is the mesh's axis names/sizes; the device ids the executable was
+    compiled for ride in ``extra`` (a serialized executable loads only
+    onto the ids it names)."""
     import jax
 
     cmeta = getattr(program, "_collective_meta", None)
@@ -359,18 +383,27 @@ def entries():
     return out
 
 
+def _xla_files():
+    """Tier A's files.  Placed by the environment, tier A is the cache dir
+    itself and ``aot/`` sits inside it — that subtree is tier B's."""
+    out = []
+    for dirpath, dirs, files in os.walk(xla_dir()):
+        if dirpath == cache_dir() and "aot" in dirs:
+            dirs.remove("aot")
+        out.extend(os.path.join(dirpath, f) for f in files)
+    return out
+
+
 def stats():
     ents = entries()
     total = sum(r["bytes"] for r in ents)
     xla_files = xla_bytes = 0
-    if os.path.isdir(xla_dir()):
-        for dirpath, _dirs, files in os.walk(xla_dir()):
-            for f in files:
-                xla_files += 1
-                try:
-                    xla_bytes += os.path.getsize(os.path.join(dirpath, f))
-                except OSError:
-                    pass
+    for path in _xla_files():
+        xla_files += 1
+        try:
+            xla_bytes += os.path.getsize(path)
+        except OSError:
+            pass
     return {
         "dir": cache_dir(),
         "enabled": enabled(),
@@ -414,8 +447,8 @@ def evict_to_cap():
 
 
 def clear():
-    """Wipe both tiers (the cache dir itself survives).  -> entries
-    removed."""
+    """Wipe both tiers (the directories survive).  -> tier-B entries plus
+    tier-A files removed."""
     n = 0
     root = aot_dir()
     for name in _entry_names(root):
@@ -424,12 +457,10 @@ def clear():
             n += 1
         except OSError:
             pass
-    if os.path.isdir(xla_dir()):
+    for path in _xla_files():
         try:
-            shutil.rmtree(xla_dir())
+            os.remove(path)
             n += 1
         except OSError:
             pass
-    # a cleared dir must re-wire tier A on next use (the dir was deleted)
-    _xla_wired[0] = None
     return n

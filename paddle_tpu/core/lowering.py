@@ -8,6 +8,8 @@ is the TPU-idiomatic execution model — XLA fuses across op boundaries, plans
 HBM, and overlaps collectives; per-op dispatch only exists in dygraph mode.
 """
 
+import contextlib
+
 import jax
 import jax.numpy as jnp
 
@@ -358,6 +360,13 @@ def run_op(op, env, rng_key, mesh=None, axis_names=(), runner=None,
     ctx = LowerCtx(rng_key=rng_key, op=op, block=op.block, mesh=mesh,
                    axis_names=axis_names, runner=runner, env=env,
                    data_axis=data_axis)
+    guard = contextlib.nullcontext()
+    if mesh is not None and not axis_names and mesh.size > 1:
+        # a program XLA partitions automatically: no Pallas kernel may be
+        # chosen while its ops lower (pallas_kernels/adoption.py)
+        from ..pallas_kernels import adoption
+
+        guard = adoption.auto_partitioned()
     # Constant folding at trace time: ops whose inputs are all trace-time
     # constants evaluate eagerly.  This keeps loop counters / bounds concrete
     # so `while` can unroll and tensor arrays can grow (ops/control_flow.py).
@@ -371,10 +380,11 @@ def run_op(op, env, rng_key, mesh=None, axis_names=(), runner=None,
         # multi-process excluded: compile-time-eval arrays get committed
         # with shardings spanning non-addressable devices, which cannot be
         # closed over as constants in the per-process trace
-        with jax.ensure_compile_time_eval():
+        with guard, jax.ensure_compile_time_eval():
             out = opdef.lower(ctx, *args, **_lower_attrs(op.attrs))
     else:
-        out = opdef.lower(ctx, *args, **_lower_attrs(op.attrs))
+        with guard:
+            out = opdef.lower(ctx, *args, **_lower_attrs(op.attrs))
     if (len(opdef.output_slots) == 1
             and opdef.output_slots[0] in opdef.duplicable_outputs
             and isinstance(out, list)):
@@ -391,22 +401,6 @@ def run_op(op, env, rng_key, mesh=None, axis_names=(), runner=None,
         out = (list(out),)
     for slot, val in zip(opdef.output_slots, out):
         _scatter_slot(opdef, op, slot, val, env)
-
-
-def shard_map_compat(f, mesh, in_specs, out_specs):
-    """jax.shard_map across the API rename (new: check_vma; old
-    jax.experimental.shard_map: check_rep).  Single shim shared by the SPMD
-    executor and paddle_tpu.parallel."""
-    try:
-        from jax import shard_map as _new
-
-        return _new(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                    check_vma=False)
-    except ImportError:
-        from jax.experimental.shard_map import shard_map as _old
-
-        return _old(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-                    check_rep=False)
 
 
 def has_collective_ops(block):
@@ -485,11 +479,12 @@ def build_spmd_block_fn(plan, mesh, axis="data"):
         # each rank holds only its 1/nranks slot shard.
         out_specs = ([P(axis)] * len(fetch_names),
                      {n: _var_spec(n) for n in persist_written})
-        sm = shard_map_compat(
+        sm = jax.shard_map(
             local,
-            mesh,
-            (feed_specs, param_ro_specs, param_rw_specs, P()),
-            out_specs,
+            mesh=mesh,
+            in_specs=(feed_specs, param_ro_specs, param_rw_specs, P()),
+            out_specs=out_specs,
+            check_vma=False,
         )
         return sm(feeds, params_ro, params_rw, rng)
 

@@ -464,8 +464,11 @@ def _default_infer_shape(opdef, op, block):
     def fn(*args):
         return opdef.lower(ctx, *args, **_lower_attrs(op.attrs))
 
+    from ..pallas_kernels import adoption
+
     try:
-        out = jax.eval_shape(fn, *in_structs)
+        with adoption.shape_inference():
+            out = jax.eval_shape(fn, *in_structs)
     except Exception:
         return  # leave declared shapes in place when symbolic eval fails
     if not isinstance(out, (tuple, list)):

@@ -745,7 +745,7 @@ def check_memory(program, rep, rank=None, budget=None, batch=1,
                 % (_fmt_mb(est["peak_bytes"]), est["peak_bytes"],
                    _fmt_mb(budget), budget),
                 rank=rank,
-                suggestion="shrink the batch, enable BENCH_REMAT=auto "
+                suggestion="shrink the batch, enable BENCH_REMAT=1 "
                 "recompute, or shard optimizer state "
                 "(FLAGS_collective_mode=zero1)"
                 + (", or shrink the paged KV pool "

@@ -196,7 +196,7 @@ class _JaxWorld:
                host_service):
         import jax
         from jax._src import distributed as _dist
-        from jax._src.lib import xla_extension as _xe
+        from jax._src.lib import _jax as _xe
         from jax.extend import backend as _jexb
 
         if os.getenv("JAX_PLATFORMS", "").startswith("cpu"):

@@ -144,7 +144,10 @@ _DEFAULTS = {
     # even across different programs); <dir>/aot holds framework-level
     # serialized executables keyed by (program content hash, trace-flag
     # fingerprint, collective world, feed shapes/dtypes) (tier B — a hit
-    # skips trace + lower + compile entirely).  Empty = both tiers off.
+    # skips trace + lower + compile entirely).  Empty = both tiers off —
+    # unless JAX_COMPILATION_CACHE_DIR is set: the machine placed the
+    # cache, both tiers live under it and this flag is not consulted
+    # (compile_cache.cache_dir is the one resolver).
     "FLAGS_compile_cache_dir": "",
     # tier-B size cap in bytes; least-recently-used entries are evicted
     # after each store once the total exceeds it.  <=0 disables eviction.

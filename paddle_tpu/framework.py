@@ -129,22 +129,35 @@ class Place:
         return hash((type(self).__name__, self.device_id))
 
     def jax_device(self):
+        """The one device this place names, or an error — never a stand-in.
+
+        CPUPlace is the host backend's device.  TPUPlace(i) is the i-th
+        accelerator this process addresses; with no accelerator it raises,
+        except where the process was pinned to the CPU on purpose
+        (``JAX_PLATFORMS=cpu``: the test tier's virtual mesh stands in for
+        the chips, one CPU device each).  An id past the device count is an
+        error, not chip ``id % count``."""
         import jax
 
-        kind = "cpu" if isinstance(self, CPUPlace) else None
         # process-LOCAL devices: under multi-controller jax (nccl2-mode
         # analog) eager values and single-device programs must live on a
         # device this process addresses, never on another host's
-        devs = jax.local_devices(backend=kind) if kind else jax.local_devices()
-        if kind is None:
-            # prefer an accelerator backend if present
-            try:
-                accel = [d for d in devs if d.platform != "cpu"]
-                if accel:
-                    devs = accel
-            except Exception:
-                pass
-        return devs[self.device_id % len(devs)]
+        if isinstance(self, CPUPlace):
+            devs = jax.local_devices(backend="cpu")
+        else:
+            devs = [d for d in jax.local_devices() if d.platform != "cpu"]
+            if not devs:
+                if (jax.config.jax_platforms or "").split(",")[0] != "cpu":
+                    raise RuntimeError(
+                        "%r: JAX found no accelerator (devices: %s).  Run "
+                        "on a chip, use CPUPlace(), or pin the CPU on "
+                        "purpose with JAX_PLATFORMS=cpu."
+                        % (self, jax.local_devices()))
+                devs = jax.local_devices()
+        if not 0 <= self.device_id < len(devs):
+            raise RuntimeError("%r: this process has %d such device(s)"
+                               % (self, len(devs)))
+        return devs[self.device_id]
 
 
 class CPUPlace(Place):

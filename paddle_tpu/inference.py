@@ -23,21 +23,6 @@ __all__ = [
 ]
 
 
-def _enable_executable_cache(path):
-    """Route compiled executables through the unified two-tier cache
-    (core/compile_cache.py): tier A is XLA's persistent cache wired by
-    enable_xla_cache(), tier B holds whole-step AOT artifacts — the same
-    store Executor.warmup and the elastic standby path use, so a serving
-    replica restores the buckets a trainer or earlier replica compiled."""
-    from . import flags as _flags
-    from .core import compile_cache as _cc
-
-    path = str(path)
-    if _flags.flag("compile_cache_dir") != path:
-        _flags.set_flags({"FLAGS_compile_cache_dir": path})
-    _cc.enable_xla_cache()
-
-
 class AnalysisConfig:
     """Mirror of paddle_analysis_config.h's commonly-used surface."""
 
@@ -179,7 +164,13 @@ class AnalysisPredictor:
     def __init__(self, config, _shared=None):
         self._config = config
         if config.optim_cache_dir():
-            _enable_executable_cache(config.optim_cache_dir())
+            # the unified two-tier cache — the same store Executor.warmup
+            # and the elastic standby path use, so a serving replica
+            # restores the buckets a trainer or earlier replica compiled
+            # (JAX_COMPILATION_CACHE_DIR, when set, wins over this path)
+            from .core import compile_cache
+
+            compile_cache.place(str(config.optim_cache_dir()))
         if _shared is not None:
             # clone: share program + scope (shared params, reference
             # AnalysisPredictor::Clone) AND the Executor — its executable

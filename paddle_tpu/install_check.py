@@ -18,28 +18,18 @@ def run_check():
         fluid.optimizer.SGD(0.01).minimize(loss)
     feed = {"install_check_x": np.ones((2, 2), "float32")}
 
-    def _try(place):
-        exe = fluid.Executor(place)
-        with fluid.scope_guard(fluid.Scope()):
-            exe.run(startup)
-            exe.run(prog, feed=feed, fetch_list=[loss])
+    # TPUPlace raises when JAX finds no accelerator (framework.py): a
+    # broken chip path is a failed check, not a CPU pass
+    import jax
 
-    # the device only materializes at run time — fall back to CPU when the
-    # accelerator path fails end to end
-    try:
-        import jax
-
-        has_accel = any(d.platform != "cpu" for d in jax.local_devices())
-    except Exception:
-        has_accel = False
-    dev = "TPU" if has_accel else "CPU"
-    try:
-        _try(fluid.TPUPlace(0) if has_accel else fluid.CPUPlace())
-    except Exception:
-        if not has_accel:
-            raise
-        dev = "CPU"
-        _try(fluid.CPUPlace())
-    print("Your paddle_tpu works well on %s." % dev)
+    place = (fluid.CPUPlace() if jax.default_backend() == "cpu"
+             else fluid.TPUPlace(0))
+    exe = fluid.Executor(place)
+    with fluid.scope_guard(fluid.Scope()):
+        exe.run(startup)
+        exe.run(prog, feed=feed, fetch_list=[loss])
+    dev = place.jax_device()
+    print("Your paddle_tpu works well on %s (%s)."
+          % (dev.platform.upper(), dev.device_kind))
     print("Your paddle_tpu is installed successfully! Let's start deep "
           "learning with paddle_tpu now.")

@@ -3,11 +3,15 @@
 The reference keeps its data hand-off and dataset parsing in C++
 (paddle/fluid/operators/reader/blocking_queue.h,
 paddle/fluid/framework/data_feed.cc); so do we.  Sources live in
-``csrc/`` and are compiled on first import with g++ into a cached shared
-library (no pybind11 in this image — plain C ABI + ctypes).
+``csrc/`` and are compiled on first use with g++ into a cached shared
+library (no pybind11 in this image — plain C ABI + ctypes).  The outputs
+are never committed: a checkout builds them from what git holds, and every
+build is keyed on a hash of its inputs (``build_if_stale``), not on mtimes —
+a copied tree gives every file the same age.
 """
 
 import ctypes
+import hashlib
 import os
 import subprocess
 import threading
@@ -41,23 +45,38 @@ def _sources():
     )
 
 
-def _needs_build():
-    if not os.path.exists(_LIB_PATH):
-        return True
-    so_mtime = os.path.getmtime(_LIB_PATH)
-    return any(os.path.getmtime(s) > so_mtime for s in _sources())
-
-
-def _build():
-    cmd = [
-        "g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread",
-        *_sources(), "-o", _LIB_PATH + ".tmp",
-    ]
-    # _lib_lock exists precisely to serialize this one-time g++ build;
-    # blocking under it is the invariant (a second importer must wait for
-    # the .so, not race the compiler), and no other lock nests with it.
-    subprocess.run(cmd, check=True, capture_output=True)  # threadlint: waive CC102 _lib_lock serializes the one-shot native build; waiting is the contract
-    os.replace(_LIB_PATH + ".tmp", _LIB_PATH)  # threadlint: waive CC102 atomic publish of the .so must stay inside the build critical section
+def build_if_stale(out, inputs, cmd):
+    """Build ``out`` with ``cmd + ["-o", <tmp>]`` unless it was built from
+    exactly these input bytes by exactly this command.  The key — sha256
+    over the command line and every input file — sits beside the output in
+    ``out + ".key"``; a binary with no key, or another key, is rebuilt.
+    A missing compiler raises: nothing here has a pure-Python stand-in."""
+    h = hashlib.sha256("\0".join(cmd).encode())
+    for path in inputs:
+        with open(path, "rb") as f:
+            h.update(f.read())
+    key = h.hexdigest()
+    try:
+        with open(out + ".key") as f:
+            if f.read() == key and os.path.exists(out):
+                return out
+    except OSError:
+        pass
+    # a tmp name of its own for every builder: concurrent first users
+    # (threads or processes) must not interleave their compilers' writes;
+    # os.replace publishes atomically and the last one wins with the same
+    # bytes
+    tmp = "%s.%d.%d.tmp" % (out, os.getpid(), threading.get_ident())
+    try:
+        subprocess.run(cmd + ["-o", tmp], check=True, capture_output=True)
+    except subprocess.CalledProcessError as e:
+        raise RuntimeError("native build failed: %s\n%s"
+                           % (" ".join(cmd), e.stderr.decode())) from e
+    os.replace(tmp, out)
+    with open(tmp, "w") as f:
+        f.write(key)
+    os.replace(tmp, out + ".key")
+    return out
 
 
 def _declare(lib):
@@ -139,14 +158,17 @@ def load():
     global _lib
     if _lib is not None:
         return _lib
+    # outside the lock: the build blocks for seconds, is idempotent and
+    # publishes atomically, so racing first users need no serialization
+    build_if_stale(
+        _LIB_PATH, _sources(),
+        ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread",
+         *_sources()])
     with _lib_lock:
-        if _lib is not None:
-            return _lib
-        if _needs_build():
-            _build()
-        lib = ctypes.CDLL(_LIB_PATH)
-        _declare(lib)
-        _lib = lib
+        if _lib is None:
+            lib = ctypes.CDLL(_LIB_PATH)
+            _declare(lib)
+            _lib = lib
     return _lib
 
 

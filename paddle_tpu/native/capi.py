@@ -3,8 +3,9 @@
 train/demo/CMakeLists)."""
 
 import os
-import subprocess
 import sysconfig
+
+from . import build_if_stale
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
 _REPO = os.path.dirname(os.path.dirname(_HERE))
@@ -21,30 +22,20 @@ def _py_flags():
                           "-lpython" + ver, "-ldl", "-lm"]
 
 
-def build_capi(force=False):
+def build_capi():
     """Compile native/csrc_capi/paddle_tpu_c.cc -> _libpaddle_tpu_c.so."""
-    if not force and os.path.exists(_CAPI_LIB) and (
-            os.path.getmtime(_CAPI_LIB) >= os.path.getmtime(_CAPI_SRC)):
-        return _CAPI_LIB
     cflags, ldflags = _py_flags()
-    cmd = ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread",
-           *cflags, _CAPI_SRC, "-o", _CAPI_LIB + ".tmp", *ldflags]
-    subprocess.run(cmd, check=True, capture_output=True)
-    os.replace(_CAPI_LIB + ".tmp", _CAPI_LIB)
-    return _CAPI_LIB
+    return build_if_stale(
+        _CAPI_LIB, [_CAPI_SRC],
+        ["g++", "-O2", "-std=c++17", "-shared", "-fPIC", "-pthread",
+         *cflags, _CAPI_SRC, *ldflags])
 
 
-def build_demo_trainer(out_path=None, force=False):
+def build_demo_trainer(out_path=None):
     """Compile tools/demo_trainer.cc linking the C API library."""
-    lib = build_capi(force=force)
+    lib = build_capi()
     src = os.path.join(_REPO, "tools", "demo_trainer.cc")
-    out = out_path or os.path.join(_HERE, "_demo_trainer")
-    if not force and os.path.exists(out) and (
-            os.path.getmtime(out) >= max(os.path.getmtime(src),
-                                         os.path.getmtime(lib))):
-        return out
-    cmd = ["g++", "-O2", "-std=c++17", src, lib,
-           "-Wl,-rpath," + os.path.dirname(lib), "-o", out + ".tmp"]
-    subprocess.run(cmd, check=True, capture_output=True)
-    os.replace(out + ".tmp", out)
-    return out
+    return build_if_stale(
+        out_path or os.path.join(_HERE, "_demo_trainer"), [src, lib],
+        ["g++", "-O2", "-std=c++17", src, lib,
+         "-Wl,-rpath," + os.path.dirname(lib)])

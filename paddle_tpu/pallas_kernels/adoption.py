@@ -1,10 +1,10 @@
 """Probe-gated Pallas kernel adoption — one funnel for every kernel family.
 
-Five kernel families live under ``pallas_kernels/`` (layer_norm, fused_ln,
-conv_block, fused_opt, embedding_bag) and until this module each carried its
-own copy of the shape/dtype eligibility checks and fell back SILENTLY — a
-misconfigured flag or an off-by-128 channel count ran the jnp composition
-with no trace in the metrics.  This module centralizes:
+Every kernel family under ``pallas_kernels/`` (``KERNELS``) decides here
+whether it engages; before this module each carried its own copy of the
+shape/dtype eligibility checks and fell back SILENTLY — a misconfigured flag
+or an off-by-128 channel count ran the jnp composition with no trace in the
+metrics.  This module centralizes:
 
 * **eligibility** — ``decide()`` walks an ordered check list; the first
   failing check becomes the fallback *reason*.
@@ -17,28 +17,35 @@ with no trace in the metrics.  This module centralizes:
   (PAPERS.md arXiv 2110.10548): a kernel may be *written* optimistically
   but is *adopted* only where a measured ``tools/op_bench.py --pallas``
   probe shows >= 1.1x over its own fallback on the target device.  Probe
-  rows are JSON files archived next to BENCH_*.json (BASELINE.md round-9
-  protocol); ``PADDLE_PALLAS_PROBE_DIR`` points at the archive
-  (default: the checked-in ``tools/probes/results/``).
+  rows are JSON files (BASELINE.md round-9 protocol);
+  ``PADDLE_PALLAS_PROBE_DIR`` points at the archive (default:
+  ``tools/probes/results/``, which holds no row from the current
+  installation).
 
 Flag-off is INERT: no counters move, so a default-configured run pays one
 dict lookup per decision and nothing else.
 
 ``PADDLE_PALLAS_INTERPRET=1`` forces interpret-mode execution (kernels run
 through the Pallas interpreter on CPU) and waives the backend + probe
-checks — the CI ``--kernel-smoke`` leg and the parity tests ride this.
+checks — the CI ``--kernel-smoke`` leg and the parity tests ride this.  On
+any backend but the CPU's it raises.
+
+In a program that XLA partitions automatically over several devices no
+kernel engages (``auto_partitioned``, reason ``gspmd_mesh``).
 """
 
+import contextlib
 import json
 import os
 import threading
 
 __all__ = ["decide", "active_kernels", "probe_speedup", "register_probe",
-           "reset", "interpret_mode", "KERNELS", "MIN_SPEEDUP"]
+           "reset", "interpret_mode", "interpret", "auto_partitioned",
+           "shape_inference", "KERNELS", "MIN_SPEEDUP"]
 
 # the kernel families sharing this funnel
-KERNELS = ("layer_norm", "fused_ln", "conv_block", "fused_opt",
-           "embedding_bag", "paged_attention")
+KERNELS = ("layer_norm", "fused_ln", "flash_attention", "conv_block",
+           "fused_opt", "embedding_bag", "paged_attention")
 
 # adoption threshold: a probe row below this keeps the fallback
 MIN_SPEEDUP = 1.1
@@ -51,8 +58,63 @@ _probe_cache = None      # kernel -> speedup loaded from the archive dir
 
 def interpret_mode():
     """True when PADDLE_PALLAS_INTERPRET forces the Pallas interpreter
-    (CPU parity tests / the --kernel-smoke probe leg)."""
-    return os.environ.get("PADDLE_PALLAS_INTERPRET", "") in ("1", "true")
+    (CPU parity tests / the --kernel-smoke probe leg).  The interpreter is
+    for the CPU backend only: leaked into a chip run, the switch would
+    waive every probe gate and run each kernel interpreted while counting
+    it as engaged, so there it is an error."""
+    if os.environ.get("PADDLE_PALLAS_INTERPRET", "") not in ("1", "true"):
+        return False
+    import jax
+
+    if jax.default_backend() != "cpu":
+        raise RuntimeError(
+            "PADDLE_PALLAS_INTERPRET is set but the backend is %r: the "
+            "Pallas interpreter is for the CPU test tier, unset it"
+            % jax.default_backend())
+    return True
+
+
+def interpret():
+    """The ``interpret=`` argument of a pallas_call: compiled by Mosaic on
+    the TPU, interpreted on the CPU test mesh."""
+    import jax
+
+    return interpret_mode() or jax.default_backend() != "tpu"
+
+
+# what kind of trace is running op lowerings on this thread, when it is one
+# in which no kernel may engage
+_trace = threading.local()
+
+
+@contextlib.contextmanager
+def _tracing(kind):
+    old = getattr(_trace, "kind", None)
+    _trace.kind = kind
+    try:
+        yield
+    finally:
+        _trace.kind = old
+
+
+def shape_inference():
+    """Entered by core/registry.py around build-time shape inference
+    (``jax.eval_shape`` of the lowering with a symbolic batch).  Nothing is
+    being lowered, so no kernel is chosen and NOTHING is counted — the
+    used/fallback counters describe real lowerings only."""
+    return _tracing("shape_inference")
+
+
+def auto_partitioned():
+    """Entered by core/lowering.py while it lowers the ops of a program that
+    XLA partitions automatically over a mesh of several devices
+    (CompiledProgram.with_data_parallel: jit + NamedSharding, no shard_map).
+    Mosaic kernels cannot be partitioned automatically — JAX raises
+    "wrap the call in a shard_map" at lowering — so there every family falls
+    back, counted under reason ``gspmd_mesh``.  Inside a shard_map (the
+    transpiled collective route) kernels run per shard and this is not
+    entered."""
+    return _tracing("gspmd_mesh")
 
 
 def _probe_dir():
@@ -172,6 +234,12 @@ def decide(kernel, flag=None, checks=(), require_probe=True):
 
     if flag is not None and not _flags.flag(flag):
         return False, "flag_off"
+    kind = getattr(_trace, "kind", None)
+    if kind == "shape_inference":
+        return False, kind
+    if kind == "gspmd_mesh":
+        _inc("pallas_kernel_fallback_total", kernel=kernel, reason=kind)
+        return False, kind
     for reason, ok in checks:
         if not ok:
             _inc("pallas_kernel_fallback_total", kernel=kernel,

@@ -42,12 +42,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-try:
-    from jax.experimental import pallas as pl
-
-    _HAS_PALLAS = True
-except ImportError:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
 
 from . import adoption
 
@@ -72,7 +67,6 @@ def conv_block_checks(x_shape, w_shape, strides, paddings, dilations=(1, 1),
     pd = tuple(paddings)
     static = all(isinstance(d, int) for d in tuple(x_shape) + tuple(w_shape))
     checks = [
-        ("no_pallas", _HAS_PALLAS),
         ("backend", adoption.interpret_mode()
          or jax.default_backend() == "tpu"),
         ("layout", data_format in ("NCHW", "AnyLayout")),
@@ -166,10 +160,6 @@ def _affine_relu_kernel(c_ref, a_ref, b_ref, y_ref, *, relu):
 # ---------------------------------------------------------------------------
 
 
-def _interp():
-    return adoption.interpret_mode() or jax.default_backend() != "tpu"
-
-
 def _infer_pallas(x, w, a, b, stride, pad, relu):
     n, c, h, w_ = x.shape
     co, _, kh, kw = w.shape
@@ -183,7 +173,7 @@ def _infer_pallas(x, w, a, b, stride, pad, relu):
                   pl.BlockSpec((1, co), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((1, co, oh, ow), lambda i: (i, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n, co, oh, ow), x.dtype),
-        interpret=_interp(),
+        interpret=adoption.interpret(),
     )(x, w, a.reshape(1, co).astype(jnp.float32),
       b.reshape(1, co).astype(jnp.float32))
 
@@ -203,7 +193,7 @@ def _train_pallas(x, w, stride, pad):
         out_shape=[jax.ShapeDtypeStruct((n, co, oh, ow), jnp.float32),
                    jax.ShapeDtypeStruct((n, co), jnp.float32),
                    jax.ShapeDtypeStruct((n, co), jnp.float32)],
-        interpret=_interp(),
+        interpret=adoption.interpret(),
     )(x, w)
     return conv, s, ss
 
@@ -218,7 +208,7 @@ def _affine_pallas(conv, a, b, relu, out_dtype):
                   pl.BlockSpec((1, co), lambda i: (0, 0))],
         out_specs=pl.BlockSpec((1, co, oh, ow), lambda i: (i, 0, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((n, co, oh, ow), out_dtype),
-        interpret=_interp(),
+        interpret=adoption.interpret(),
     )(conv, a.reshape(1, co).astype(jnp.float32),
       b.reshape(1, co).astype(jnp.float32))
 

@@ -26,16 +26,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    # renamed TPUCompilerParams -> CompilerParams across jax releases
-    _CompilerParams = getattr(pltpu, "CompilerParams", None) \
-        or pltpu.TPUCompilerParams
-    _HAS_PALLAS = True
-except ImportError:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from . import adoption
 
@@ -47,7 +39,6 @@ def bag_checks(rows_shape, ids_shape, dtype):
     static = all(isinstance(d, int) and d >= 0
                  for d in tuple(rows_shape) + tuple(ids_shape))
     return [
-        ("no_pallas", _HAS_PALLAS),
         ("backend", adoption.interpret_mode()
          or jax.default_backend() == "tpu"),
         ("symbolic_shape", static),
@@ -58,10 +49,6 @@ def bag_checks(rows_shape, ids_shape, dtype):
         ("empty", static and all(d > 0 for d in tuple(rows_shape)
                                  + tuple(ids_shape))),
     ]
-
-
-def _interp():
-    return adoption.interpret_mode() or jax.default_backend() != "tpu"
 
 
 def embedding_bag_reference(rows, ids):
@@ -103,12 +90,12 @@ def _bag_pallas(rows, ids):
             out_specs=out_spec,
         ),
         out_shape=jax.ShapeDtypeStruct((bb, d), rows.dtype),
-        interpret=_interp(),
+        interpret=adoption.interpret(),
     )
-    if not _interp():
+    if not adoption.interpret():
         # k must iterate sequentially (the out block accumulates across it)
         call = functools.partial(
-            call, compiler_params=_CompilerParams(
+            call, compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary")))
     return call()(ids.astype(jnp.int32), rows)
 

@@ -209,16 +209,8 @@ def _bwd_dkv_kernel(q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref, delta_ref,
 # pallas_call plumbing
 # ---------------------------------------------------------------------------
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    # renamed TPUCompilerParams -> CompilerParams across jax releases
-    _CompilerParams = getattr(pltpu, "CompilerParams", None) \
-        or pltpu.TPUCompilerParams
-    _HAS_PALLAS = True
-except ImportError:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 
 def _causal_jmax(i, block_q, block_k):
@@ -321,7 +313,7 @@ def _fwd_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
             pltpu.VMEM((block_q, _LANES), jnp.float32),
             pltpu.VMEM((block_q, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -377,7 +369,7 @@ def _bwd_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
         out_specs=q_spec,
         out_shape=jax.ShapeDtypeStruct(q.shape, q.dtype),
         scratch_shapes=[pltpu.VMEM((block_q, D), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -429,7 +421,7 @@ def _bwd_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
             pltpu.VMEM((block_k, D), jnp.float32),
             pltpu.VMEM((block_k, D), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
@@ -443,24 +435,31 @@ def _bwd_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
 
 
 def _can_use_pallas(q, k, interpret):
-    if not _HAS_PALLAS:
-        return False, None, None
+    from . import adoption
+
     Sq, Sk = q.shape[2], k.shape[2]
-    if Sk < 1024:
-        # measured on v5e: below ~1k keys the XLA-fused composition is
-        # faster (kernel launch/grid overhead dominates); above it the
-        # blockwise kernel wins and, more importantly, never materializes
-        # the [Sq, Sk] score matrix
-        return False, None, None
     bq = _pick_block(Sq, preferred=1024 if Sq >= 4096 else 512)
     bk = _pick_block(Sk, preferred=1024)
-    if bq is None or bk is None:
+    # the shared adoption funnel, flag-less like fused_ln: the kernel
+    # engages by default on TPU and every route around it is counted
+    use, _ = adoption.decide(
+        "flash_attention",
+        checks=[
+            # measured on v5e: below ~1k keys the XLA-fused composition is
+            # faster (kernel launch/grid overhead dominates); above it the
+            # blockwise kernel wins and, more importantly, never
+            # materializes the [Sq, Sk] score matrix
+            ("short_keys", Sk >= 1024),
+            ("blocks", bq is not None and bk is not None),
+            # off the TPU only a caller that asks for the interpreter gets
+            # the kernel: the jnp reference is faster than interpret
+            ("backend", interpret is not None
+             or jax.default_backend() == "tpu"),
+        ],
+        require_probe=False)
+    if not use:
         return False, None, None
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
-        if interpret:
-            return False, None, None  # CPU: jnp reference is faster than interpret
-    return True, (bq, bk), interpret
+    return True, (bq, bk), bool(interpret)
 
 
 # bias=None routes through the same vjp (None is a valid empty pytree for a
@@ -644,7 +643,7 @@ def small_attention_fwd(q, k, v, bias, sm_scale, dropout_prob, seed):
             in_specs=in_specs, out_specs=[qspec, lspec]),
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct((B, H, S, 1), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
     )(seed, *args)
     return out, lse
@@ -690,7 +689,7 @@ def small_attention_bwd(q, k, v, bias, sm_scale, dropout_prob, seed, out,
         out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
                    jax.ShapeDtypeStruct(k.shape, k.dtype),
                    jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
     )(seed, *args)
     return dq, dk, dv

@@ -36,16 +36,8 @@ import functools
 import jax
 import jax.numpy as jnp
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    # renamed TPUCompilerParams -> CompilerParams across jax releases
-    _CompilerParams = getattr(pltpu, "CompilerParams", None) \
-        or pltpu.TPUCompilerParams
-    _HAS_PALLAS = True
-except ImportError:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from . import prng as _prng
 
@@ -178,7 +170,7 @@ def _fwd_pallas(x2, y2, gamma, beta, seed, thr, eps, rows):
             jax.ShapeDtypeStruct((n, 1), jnp.float32),
             jax.ShapeDtypeStruct((n, 1), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
     )(seed, x2, y2, gamma.reshape(1, h), beta.reshape(1, h))
 
@@ -205,7 +197,7 @@ def _bwd_pallas(r2, gamma, seed, mean, var, dz2, thr, eps, rows):
             jax.ShapeDtypeStruct((n // rows * 8, h), jnp.float32),
             jax.ShapeDtypeStruct((n // rows * 8, h), jnp.float32),
         ],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel",)),
     )(seed, r2, gamma.reshape(1, h), mean, var, dz2)
     return dx, dy, jnp.sum(dgp, axis=0), jnp.sum(dbp, axis=0)
@@ -274,7 +266,7 @@ def _use_pallas(x2, y2):
     n, h = x2.shape
     concrete = isinstance(n, int)
     rows = None
-    if _HAS_PALLAS and concrete and h % _LANES == 0:
+    if concrete and h % _LANES == 0:
         rows = _pick_rows(n, h, x2.dtype.itemsize)
     # the shared adoption funnel (counts fallbacks; flag-less: this kernel
     # engages by default on TPU).  require_probe=False: adoption predates
@@ -284,7 +276,6 @@ def _use_pallas(x2, y2):
     use, _ = adoption.decide(
         "fused_ln",
         checks=[
-            ("no_pallas", _HAS_PALLAS),
             ("backend", jax.default_backend() == "tpu"),
             ("symbolic_shape", concrete),
             ("lanes", h % _LANES == 0),

@@ -35,12 +35,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-try:
-    from jax.experimental import pallas as pl
-
-    _HAS_PALLAS = True
-except ImportError:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
 
 from . import adoption
 
@@ -54,17 +49,12 @@ def fused_opt_checks(params, grads, moments=()):
     """Ordered (reason, ok) pairs for adoption.decide()."""
     f32 = jnp.dtype(jnp.float32)
     return [
-        ("no_pallas", _HAS_PALLAS),
         ("backend", adoption.interpret_mode()
          or jax.default_backend() == "tpu"),
         ("empty_group", len(params) > 0),
         ("dtype", all(p.dtype == f32 for p in params)
          and all(m.dtype == f32 for ms in moments for m in ms)),
     ]
-
-
-def _interp():
-    return adoption.interpret_mode() or jax.default_backend() != "tpu"
 
 
 def _pad_flat(tensors):
@@ -172,7 +162,7 @@ def fused_adam_step(params, grads, m1s, m2s, lr, b1pows, b2pows,
                    jax.ShapeDtypeStruct((rows, 128), dt),
                    jax.ShapeDtypeStruct((rows, 128), dt),
                    jax.ShapeDtypeStruct((rows, 128), jnp.bfloat16)],
-        interpret=_interp(),
+        interpret=adoption.interpret(),
     )(p_flat, g_flat, m1_flat, m2_flat, lrt_blocks)
 
     return (_unpad(p_new, sizes, counts, offs, shapes),
@@ -208,7 +198,7 @@ def fused_momentum_step(params, grads, vels, lr, mu=0.0, use_nesterov=False):
         out_shape=[jax.ShapeDtypeStruct((rows, 128), dt),
                    jax.ShapeDtypeStruct((rows, 128), dt),
                    jax.ShapeDtypeStruct((rows, 128), jnp.bfloat16)],
-        interpret=_interp(),
+        interpret=adoption.interpret(),
     )(p_flat, g_flat, v_flat, lr_blocks)
 
     return (_unpad(p_new, sizes, counts, offs, shapes),

@@ -18,12 +18,7 @@ import functools
 import jax
 import jax.numpy as jnp
 
-try:
-    from jax.experimental import pallas as pl
-
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
 
 
 def _ln_fwd_kernel(x_ref, g_ref, b_ref, y_ref, m_ref, v_ref, *, eps):
@@ -55,7 +50,6 @@ def ln_checks(R, C):
     (fused_ln.py carried a near-duplicate; both now feed adoption.py so a
     fallback is a counted event, not a silent branch)."""
     return [
-        ("no_pallas", _HAS_PALLAS),
         ("backend", jax.default_backend() == "tpu"),
         ("lanes", C % 128 == 0),
         ("block_rows", _pick_block_r(R) is not None),

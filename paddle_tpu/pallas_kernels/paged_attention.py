@@ -32,16 +32,8 @@ import math
 import jax
 import jax.numpy as jnp
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-
-    # renamed TPUCompilerParams -> CompilerParams across jax releases
-    _CompilerParams = getattr(pltpu, "CompilerParams", None) \
-        or pltpu.TPUCompilerParams
-    _HAS_PALLAS = True
-except ImportError:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 from . import adoption
 
@@ -84,7 +76,6 @@ def paged_attention_checks(q_shape, kv_shape, dtype, block_size):
     dims = tuple(q_shape) + tuple(kv_shape)
     static = all(isinstance(x, int) and x >= 0 for x in dims)
     return [
-        ("no_pallas", _HAS_PALLAS),
         ("backend", adoption.interpret_mode()
          or jax.default_backend() == "tpu"),
         ("symbolic_shape", static),
@@ -96,10 +87,6 @@ def paged_attention_checks(q_shape, kv_shape, dtype, block_size):
          and block_size % 8 == 0),
         ("empty", static and all(x > 0 for x in dims)),
     ]
-
-
-def _interp():
-    return adoption.interpret_mode() or jax.default_backend() != "tpu"
 
 
 def _paged_kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref, o_ref,
@@ -159,12 +146,12 @@ def _paged_pallas(q, k_cache, v_cache, block_tables, context_lens):
                             pltpu.VMEM((h, d), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((bb, h, d), q.dtype),
-        interpret=_interp(),
+        interpret=adoption.interpret(),
     )
-    if not _interp():
+    if not adoption.interpret():
         # j accumulates the online softmax, so it must run sequentially
         call = functools.partial(
-            call, compiler_params=_CompilerParams(
+            call, compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "arbitrary")))
     return call()(block_tables.astype(jnp.int32),
                   context_lens.astype(jnp.int32), q, k_cache, v_cache)

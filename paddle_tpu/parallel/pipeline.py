@@ -193,8 +193,6 @@ def make_pipeline_step(stage_fn, loss_fn, mesh, n_micro, axis="pp",
             return loss, new_params
         return loss, grads
 
-    from ..core.lowering import shard_map_compat
-
     def step(params_stacked, x, labels):
         for path, v in jax.tree_util.tree_flatten_with_path(
                 params_stacked)[0]:
@@ -223,10 +221,10 @@ def make_pipeline_step(stage_fn, loss_fn, mesh, n_micro, axis="pp",
             lambda v: P(axis, *([None] * (v.ndim - 1))), params_stacked)
         # composed dp x pp: shard the within-microbatch dim over data_axis
         xspec = P(None, data_axis) if data_axis is not None else P()
-        body = shard_map_compat(
-            spmd_body, mesh,
+        body = jax.shard_map(
+            spmd_body, mesh=mesh,
             in_specs=(pspec, xspec, xspec),
-            out_specs=(P(), pspec))
+            out_specs=(P(), pspec), check_vma=False)
         return body(params_stacked, x_micro, labels_micro)
 
     return jax.jit(step)

@@ -132,12 +132,11 @@ def make_ring_attention_sharded(mesh, axis_name="sp", causal=False,
     the shard_map'ed region."""
     from jax.sharding import PartitionSpec as P
 
-    from ..core.lowering import shard_map_compat
-
     spec = P(None, None, axis_name, None)
     fn = ring_attention if impl == "ring" else ulysses_attention
 
     def per_shard(q, k, v):
         return fn(q, k, v, axis_name, causal=causal, sm_scale=sm_scale)
 
-    return shard_map_compat(per_shard, mesh, (spec, spec, spec), spec)
+    return jax.shard_map(per_shard, mesh=mesh, in_specs=(spec, spec, spec),
+                         out_specs=spec, check_vma=False)

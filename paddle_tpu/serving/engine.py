@@ -277,12 +277,10 @@ class ServingEngine:
         from ..inference import AnalysisConfig, AnalysisPredictor
 
         if isinstance(predictor_or_dir, str):
-            cfg = AnalysisConfig(predictor_or_dir)
-            cfg.disable_gpu()
-            cache = _flag("compile_cache_dir")
-            if cache:
-                cfg.set_optim_cache_dir(cache)
-            predictor_or_dir = AnalysisPredictor(cfg)
+            # AnalysisConfig's default place is TPUPlace(0): a server on a
+            # chip machine serves from the chip
+            predictor_or_dir = AnalysisPredictor(
+                AnalysisConfig(predictor_or_dir))
         self._models[name] = _ModelEntry(name, predictor_or_dir)
         return self._models[name].predictor
 

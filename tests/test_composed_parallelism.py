@@ -15,7 +15,6 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from paddle_tpu.core.lowering import shard_map_compat
 from paddle_tpu.parallel import (make_pipeline_step, reference_step,
                                  stack_stage_params)
 from paddle_tpu.parallel.ring_attention import ring_attention
@@ -98,9 +97,10 @@ def test_dp_x_sp_ring_attention_parity(causal):
     rng = np.random.RandomState(2)
     q, k, v = (rng.randn(B, H, S, D).astype("f") for _ in range(3))
     spec = P("data", None, "sp", None)
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         lambda a, b, c: ring_attention(a, b, c, "sp", causal=causal),
-        mesh, (spec, spec, spec), spec)
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False)
     got = np.asarray(jax.jit(fn)(q, k, v))
     want = np.asarray(_ref_attention(q, k, v, None, causal, D ** -0.5))
     np.testing.assert_allclose(got, want, rtol=2e-4, atol=2e-4)
@@ -115,9 +115,10 @@ def test_dp_x_sp_ring_attention_grads():
     rng = np.random.RandomState(3)
     q, k, v = (rng.randn(B, H, S, D).astype("f") for _ in range(3))
     spec = P("data", None, "sp", None)
-    fn = shard_map_compat(
+    fn = jax.shard_map(
         lambda a, b, c: ring_attention(a, b, c, "sp", causal=False),
-        mesh, (spec, spec, spec), spec)
+        mesh=mesh, in_specs=(spec, spec, spec), out_specs=spec,
+        check_vma=False)
 
     def loss(fn_):
         return lambda a, b, c: (fn_(a, b, c) ** 2).sum()

@@ -23,6 +23,7 @@ from paddle_tpu.pallas_kernels import adoption
 from paddle_tpu.pallas_kernels import paged_attention as pa
 from paddle_tpu.serving import (DecodeEngine, ServingClient, ServingEngine,
                                 ServingServer)
+from paddle_tpu.utils import fault_injection
 from paddle_tpu.serving.decode_model import (DecoderConfig,
                                              init_decoder_params,
                                              unpaged_generate)
@@ -164,14 +165,21 @@ def test_streaming_phases_and_on_token(eng):
 def test_join_and_leave_mid_batch(eng):
     started = threading.Event()
     order = []
-    ra = eng.submit("toy", [1, 2], max_new_tokens=40,
-                    deadline_ms=30000.0,
-                    callback=lambda r: order.append("A"),
-                    on_token=lambda *a: started.set())
-    assert started.wait(20.0), "long sequence never produced a token"
-    rb = eng.submit("toy", [3], max_new_tokens=2, deadline_ms=30000.0,
-                    callback=lambda r: order.append("B"))
-    b = rb.wait(timeout=60.0)
+    # every iteration takes 100 ms until B is done: A's 40 sub-millisecond
+    # steps would otherwise all land before this thread submits B.  (No
+    # firing count: every idle engine's loop checks the same fault point.)
+    fault_injection.arm("serving.decode_step:delay:1")
+    try:
+        ra = eng.submit("toy", [1, 2], max_new_tokens=40,
+                        deadline_ms=30000.0,
+                        callback=lambda r: order.append("A"),
+                        on_token=lambda *a: started.set())
+        assert started.wait(20.0), "long sequence never produced a token"
+        rb = eng.submit("toy", [3], max_new_tokens=2, deadline_ms=30000.0,
+                        callback=lambda r: order.append("B"))
+        b = rb.wait(timeout=60.0)
+    finally:
+        fault_injection.disarm()
     a = ra.wait(timeout=60.0)
     assert a.status == "ok" and b.status == "ok"
     # B joined the running batch and LEFT it while A kept decoding
@@ -187,13 +195,19 @@ def test_abort_queued_and_active(eng, telemetry_on):
         rq = eng.submit("toy", [1], max_new_tokens=4, deadline_ms=30000.0)
         assert eng.abort(rq.req_id)
     assert rq.wait(timeout=10.0).status == "aborted"
-    # active: abort mid-decode frees the blocks
+    # active: abort mid-decode frees the blocks.  The toy step takes well
+    # under a millisecond, so all 40 tokens can land before this thread
+    # wakes: slow the loop (100 ms per iteration) while the abort races it
     started = threading.Event()
-    ra = eng.submit("toy", [1, 2], max_new_tokens=40,
-                    deadline_ms=30000.0,
-                    on_token=lambda *a: started.set())
-    assert started.wait(20.0)
-    assert eng.abort(ra.req_id)
+    fault_injection.arm("serving.decode_step:delay:1")
+    try:
+        ra = eng.submit("toy", [1, 2], max_new_tokens=40,
+                        deadline_ms=30000.0,
+                        on_token=lambda *a: started.set())
+        assert started.wait(20.0)
+        assert eng.abort(ra.req_id)
+    finally:
+        fault_injection.disarm()
     assert ra.wait(timeout=10.0).status == "aborted"
     deadline = time.time() + 5
     while time.time() < deadline and \
@@ -516,7 +530,11 @@ def test_spec_shed_mid_decode_keeps_decoding(cache_dir, telemetry_on):
         deep = threading.Event()
 
         def on_tok(rid, i, tok, done, st):
-            if i >= 20:     # A holds >= 7 of the 9 usable blocks now
+            if i >= 20 and not deep.is_set():
+                # A holds >= 7 of the 9 usable blocks now; slow its
+                # remaining iterations (100 ms each) so B arrives while
+                # A still decodes — the toy step alone is sub-millisecond
+                fault_injection.arm("serving.decode_step:delay:1")
                 deep.set()
 
         ra = e.submit("toy", [1] * 5, max_new_tokens=30,
@@ -525,6 +543,7 @@ def test_spec_shed_mid_decode_keeps_decoding(cache_dir, telemetry_on):
         rb = e.submit("toy", [2] * 12, max_new_tokens=4,
                       deadline_ms=30000.0)
         b = rb.wait(timeout=30.0)
+        fault_injection.disarm()
         assert b.status == "shed", b.status
         assert b.retry_after_ms >= 1.0
         assert _tm.counter_total("serving_shed_total") >= 1
@@ -532,6 +551,7 @@ def test_spec_shed_mid_decode_keeps_decoding(cache_dir, telemetry_on):
         assert a.status == "ok"
         assert np.array_equal(a.outputs["tokens"], _unpaged([1] * 5, 30))
     finally:
+        fault_injection.disarm()
         e.stop()
 
 
@@ -741,6 +761,10 @@ def test_abort_mid_prefill_publishes_no_partial_block(cache_dir,
     try:
         m = e._models["toy"]
         prompt = [(i % 29) + 1 for i in range(40)]       # 10 blocks
+        # 100 ms per iteration until the abort has landed: the 40
+        # sub-millisecond prefill steps would otherwise be over before the
+        # poll below catches one
+        fault_injection.arm("serving.decode_step:delay:1")
         ra = e.submit("toy", prompt, max_new_tokens=4,
                       deadline_ms=30000.0)
         n_at_abort = None
@@ -753,6 +777,7 @@ def test_abort_mid_prefill_publishes_no_partial_block(cache_dir,
                         n_at_abort = s.n_fed
                         assert e.abort(ra.req_id)
             time.sleep(0.0005)
+        fault_injection.disarm()
         assert n_at_abort is not None, "never caught the seq mid-prefill"
         assert ra.wait(timeout=10.0).status == "aborted"
         # the index holds exactly the COMPLETELY fed blocks (mid-prefill
@@ -766,6 +791,7 @@ def test_abort_mid_prefill_publishes_no_partial_block(cache_dir,
         assert m.cache.allocator.in_use == 0
         assert m.cache.allocator.num_evictable == len(m.prefix)
     finally:
+        fault_injection.disarm()
         e.stop()
 
 

@@ -511,8 +511,6 @@ def test_sync_batch_norm_matches_bn_on_mesh():
     # batch (the exact property the reference's NCCL kernel provides)
     import jax
     from jax.sharding import Mesh, PartitionSpec as P
-    from paddle_tpu.core.lowering import shard_map_compat
-
     rng = np.random.RandomState(0)
     x = jnp.asarray(rng.rand(8, 6, 4, 4).astype("f"))
     scale = jnp.ones((6,), "float32")
@@ -536,8 +534,8 @@ def test_sync_batch_norm_matches_bn_on_mesh():
                                        use_global_stats=False)
         return y, m, v
 
-    fn = shard_map_compat(shard_fn, mesh, (P("data"),),
-                          (P("data"), P(), P()))
+    fn = jax.shard_map(shard_fn, mesh=mesh, in_specs=(P("data"),),
+                       out_specs=(P("data"), P(), P()), check_vma=False)
     y, m, v = jax.jit(fn)(x)
     np.testing.assert_allclose(np.asarray(y), np.asarray(y_ref), rtol=2e-4,
                                atol=2e-5)

@@ -80,7 +80,6 @@ def test_mesh_distributed_lookup_table_op():
     the mesh axis must equal a plain gather of the full table."""
     import jax
     import jax.numpy as jnp
-    from paddle_tpu.core.lowering import shard_map_compat
     from jax.sharding import Mesh, PartitionSpec as P
     from paddle_tpu.core.registry import get_op_def
 
@@ -100,8 +99,9 @@ def test_mesh_distributed_lookup_table_op():
     def f(w_shard, ids_in):
         return opdef.lower(Ctx(), ids_in, w_shard, ring_id=0)
 
-    sharded = shard_map_compat(
-        f, mesh, in_specs=(P("model", None), P()), out_specs=P())
+    sharded = jax.shard_map(
+        f, mesh=mesh, in_specs=(P("model", None), P()), out_specs=P(),
+        check_vma=False)
     out = np.asarray(sharded(jnp.asarray(table), jnp.asarray(ids)))
     exp = table[ids.reshape(-1)]
     np.testing.assert_allclose(out, exp, rtol=1e-6)

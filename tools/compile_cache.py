@@ -80,7 +80,8 @@ def cmd_prewarm(cc, args):
     from paddle_tpu import models
 
     if not cc.enabled():
-        print("error: no cache dir (set FLAGS_compile_cache_dir or --dir)",
+        print("error: no cache dir (set JAX_COMPILATION_CACHE_DIR, "
+              "FLAGS_compile_cache_dir or --dir)",
               file=sys.stderr)
         return 2
     builders = models.bundled_builders()
@@ -137,7 +138,6 @@ def main(argv=None):
                     help="batch substituted for -1 feed dims (default 8)")
     args = ap.parse_args(argv)
 
-    os.environ.setdefault("JAX_PLATFORMS", "cpu")
     if args.dir:
         os.environ["FLAGS_compile_cache_dir"] = args.dir
     import paddle_tpu as fluid  # noqa: F401  (flags read env at import)

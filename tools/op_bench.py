@@ -131,8 +131,7 @@ def bench_pallas(cfg, device=None, save_probe=None, repeat=None,
     family).  The kernel leg runs with the family flag ON and an in-memory
     probe override, bypassing the disk probe gate — this IS the
     measurement that creates the probe row.  `save_probe`: directory to
-    archive the row into (what adoption.py reads; BASELINE.md round-9
-    protocol says commit it next to BENCH_*.json)."""
+    archive the row into (what adoption.py reads)."""
     import paddle_tpu as fluid
     from paddle_tpu.pallas_kernels import adoption
 

@@ -19,7 +19,6 @@ Env: PROFILE_BATCH (default 192), PROFILE_TOP_OPS=1 for per-op listing.
 
 import os
 import sys
-import tempfile
 from collections import defaultdict
 
 sys.path.insert(0, os.path.dirname(os.path.abspath(__file__)))
@@ -153,7 +152,14 @@ def main():
 
         from timeline import from_xplane
 
-        tmpd = tempfile.mkdtemp(prefix="bert_prof_")
+        # a fixed (git-ignored) output directory: a chip call brings
+        # chiprun_out/ back, a temp dir dies with the machine
+        tmpd = os.path.join(os.path.dirname(os.path.dirname(
+            os.path.abspath(__file__))), "chiprun_out", "bert_prof")
+        import shutil
+
+        shutil.rmtree(tmpd, ignore_errors=True)  # from_xplane reads all
+        os.makedirs(tmpd)
         with jax.profiler.trace(tmpd):
             for _ in range(steps):
                 out = step()

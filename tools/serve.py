@@ -89,6 +89,31 @@ def save_demo_decoder(dirname, vocab=31, layers=2, heads=2, head_dim=8,
                         draft=truncate_decoder(cfg, params, layers=1))
 
 
+def _open_device(rank):
+    """Open this replica's JAX device before anything else needs it.  A
+    chip belongs to one process: a replica started beside a process that
+    already holds the chip (a standby forked by a coordinator that serves
+    from it, for one) fails here, or hangs inside the driver — so say
+    what is going on first, and turn a hang into an exit."""
+    import faulthandler
+
+    import jax
+
+    print("serve[rank %d]: opening the JAX device (replicas on one host are "
+          "one process per chip; a chip another process holds cannot be "
+          "opened)" % rank, file=sys.stderr, flush=True)
+    faulthandler.dump_traceback_later(180, exit=True)
+    try:
+        dev = jax.devices()[0]
+    except RuntimeError as e:
+        raise SystemExit(
+            "serve[rank %d]: no JAX device for this replica: %s" % (rank, e))
+    finally:
+        faulthandler.cancel_dump_traceback_later()
+    print("serve[rank %d]: serving from %s (%s)"
+          % (rank, dev, dev.device_kind), file=sys.stderr, flush=True)
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--model", action="append", default=[],
@@ -101,7 +126,8 @@ def main(argv=None):
                     help="batch buckets, e.g. 1,4,16,64 "
                     "(default FLAGS_serving_buckets)")
     ap.add_argument("--cache-dir", default=None,
-                    help="FLAGS_compile_cache_dir for AOT bucket artifacts")
+                    help="compile cache for AOT bucket artifacts "
+                    "(JAX_COMPILATION_CACHE_DIR, when set, wins)")
     ap.add_argument("--prewarm-only", action="store_true",
                     help="compile every (model, bucket), print the "
                     "manifest, exit")
@@ -168,12 +194,14 @@ def main(argv=None):
               save_demo_decoder(args.save_demo_decoder))
         return 0
 
-    import paddle_tpu as fluid
     from paddle_tpu.core import tracing
     from paddle_tpu.serving import ServingEngine, ServingFleet, ServingServer
 
+    _open_device(args.rank)
     if args.cache_dir:
-        fluid.set_flags({"FLAGS_compile_cache_dir": args.cache_dir})
+        from paddle_tpu.core import compile_cache
+
+        compile_cache.place(args.cache_dir)
     # names this replica's track in the merged trace_view.py output
     tracing.set_process_name("serving-replica-%d" % args.rank)
     if not args.model:
