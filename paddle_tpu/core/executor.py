@@ -450,8 +450,6 @@ class Executor:
         from ..compiler import CompiledProgram
 
         scope = scope if scope is not None else global_scope()
-        fetch_list = fetch_list or []
-        fetch_names = [_fetch_name(f) for f in fetch_list]
 
         # unwrap CompiledProgram FIRST so PS metadata on the inner program
         # is seen (a wrapped PS trainer must still send/recv)
@@ -470,150 +468,187 @@ class Executor:
 
             return run_pserver(self, program, scope)
 
-        # PS trainer program: ensure comms + initial param pull, and fetch
-        # this step's grads for the send/recv exchange after the run
-        ps_meta = getattr(program, "_ps_trainer", None) if program else None
-        ps_grad_names = []
-        if ps_meta is not None:
-            if getattr(scope, "_ps_comm", None) is None:
-                from ..distributed.ps import TrainerPSComm
+        # one span for the whole call; it nests under whatever span is
+        # active on this thread — the serving dispatcher's
+        # serving.execute, or a training loop's root — so cross-process
+        # traces reach down to the step
+        with _tracing.span("executor.step") as sspan:
+            t_run = time.perf_counter()
+            try:
+                return self._run_step(sspan, program, mesh, data_axis, feed,
+                                      fetch_list, scope, return_numpy,
+                                      use_program_cache)
+            finally:
+                phases = sspan.take_phases("executor.")
+                if phases:
+                    # host time of the call: all of it but the two
+                    # stretches that hand work to the device and wait for
+                    # it
+                    run_us = int((time.perf_counter() - t_run) * 1e6)
+                    sspan.annotate(
+                        host_us=run_us - phases.get("executor.dispatch", 0)
+                        - phases.get("executor.fetch", 0))
 
-                scope._ps_comm = TrainerPSComm(ps_meta)
-                scope._ps_comm.pull_initial_params(scope)
-                if not hasattr(self, "_ps_comms"):
-                    self._ps_comms = []
-                self._ps_comms.append(scope._ps_comm)
-            if not ps_meta.get("geo"):
-                # geo-SGD trains locally (no grad sends) — only the
-                # grad-shipping modes need the per-step grad fetch
-                ps_grad_names = [g for g in ps_meta["param_grad"].values()
-                                 if g not in fetch_names]
-                fetch_names = fetch_names + ps_grad_names
+    def _run_step(self, sspan, program, mesh, data_axis, feed, fetch_list,
+                  scope, return_numpy, use_program_cache):
+        """``run`` below its span.  The cache-hit path is covered end to
+        end by ``tracing.phase`` blocks (prepare, shard_feeds,
+        shard_params, dispatch, writeback, fetch), which a profile shows
+        as host events and the span carries as ``phases``."""
+        with _tracing.phase("executor.prepare"):
+            fetch_list = fetch_list or []
+            fetch_names = [_fetch_name(f) for f in fetch_list]
 
-        if program is None:
-            program = default_main_program()
+            # PS trainer program: ensure comms + initial param pull, and fetch
+            # this step's grads for the send/recv exchange after the run
+            ps_meta = getattr(program, "_ps_trainer", None) \
+                if program else None
+            ps_grad_names = []
+            if ps_meta is not None:
+                if getattr(scope, "_ps_comm", None) is None:
+                    from ..distributed.ps import TrainerPSComm
 
-        if not feed:
-            # program-driven input: a started non-iterable DataLoader
-            # attached to this program supplies the batch (the reference's
-            # py_reader `read` op path; raises core.EOFException at end)
-            for loader in program._attached_loaders:
-                if loader._started:
-                    feed = loader._next_feed()
-                    break
-        feed = feed or {}
+                    scope._ps_comm = TrainerPSComm(ps_meta)
+                    scope._ps_comm.pull_initial_params(scope)
+                    if not hasattr(self, "_ps_comms"):
+                        self._ps_comms = []
+                    self._ps_comms.append(scope._ps_comm)
+                if not ps_meta.get("geo"):
+                    # geo-SGD trains locally (no grad sends) — only the
+                    # grad-shipping modes need the per-step grad fetch
+                    ps_grad_names = [g for g in ps_meta["param_grad"].values()
+                                     if g not in fetch_names]
+                    fetch_names = fetch_names + ps_grad_names
 
-        feed_arrays = {}
-        block = program.global_block()
-        for name, value in feed.items():
-            if isinstance(value, jax.Array):
-                # device-resident feed: never pull back to host for dtype
-                # coercion (x64-disabled JAX can't hold int64 anyway)
-                feed_arrays[name] = value
-                continue
-            arr = np.asarray(value)
-            v = block._find_var_recursive(name)
-            if v is not None and v.dtype is not None and arr.dtype != dtype_to_np(v.dtype):
-                arr = np.asarray(arr, dtype=dtype_to_np(v.dtype))
-            feed_arrays[name] = arr
+            if program is None:
+                program = default_main_program()
 
-        # fuse BEFORE the cache key: the pass bumps the program version,
-        # so running it inside _compile would orphan the cache entry and
-        # force a full recompile on the next step
-        self._maybe_fuse_optimizers(program, program.global_block(),
-                                    list(feed_arrays), fetch_names)
-        # trace-affecting flags must key the cache: a cached executable
-        # baked the flag value it was traced under, and flipping the flag
-        # without a cache miss would silently keep the old lowering
-        from .. import flags as _flags
+            if not feed:
+                # program-driven input: a started non-iterable DataLoader
+                # attached to this program supplies the batch (the reference's
+                # py_reader `read` op path; raises core.EOFException at end)
+                for loader in program._attached_loaders:
+                    if loader._started:
+                        feed = loader._next_feed()
+                        break
+            feed = feed or {}
 
-        trace_flags = tuple(sorted(_flags.get_flags(
-            ["FLAGS_use_pallas_layer_norm", "FLAGS_check_nan_inf",
-             "FLAGS_bn_stat_subsample",
-             "FLAGS_fused_small_attention",
-             "FLAGS_layout_match_params",
-             "FLAGS_use_pallas_conv_block",
-             "FLAGS_use_pallas_fused_opt",
-             "FLAGS_use_pallas_embedding_bag",
-             "FLAGS_deterministic_reduction"]).items()))
-        # mesh keyed by content, not id(): a GC'd Mesh's successor can alias
-        # the address exactly like the Program case above
-        mesh_key = None
+            feed_arrays = {}
+            block = program.global_block()
+            for name, value in feed.items():
+                if isinstance(value, jax.Array):
+                    # device-resident feed: never pull back to host for dtype
+                    # coercion (x64-disabled JAX can't hold int64 anyway)
+                    feed_arrays[name] = value
+                    continue
+                arr = np.asarray(value)
+                v = block._find_var_recursive(name)
+                if v is not None and v.dtype is not None \
+                        and arr.dtype != dtype_to_np(v.dtype):
+                    arr = np.asarray(arr, dtype=dtype_to_np(v.dtype))
+                feed_arrays[name] = arr
+
+            # fuse BEFORE the cache key: the pass bumps the program version,
+            # so running it inside _compile would orphan the cache entry and
+            # force a full recompile on the next step
+            self._maybe_fuse_optimizers(program, program.global_block(),
+                                        list(feed_arrays), fetch_names)
+            # trace-affecting flags must key the cache: a cached executable
+            # baked the flag value it was traced under, and flipping the flag
+            # without a cache miss would silently keep the old lowering
+            from .. import flags as _flags
+
+            trace_flags = tuple(sorted(_flags.get_flags(
+                ["FLAGS_use_pallas_layer_norm", "FLAGS_check_nan_inf",
+                 "FLAGS_bn_stat_subsample",
+                 "FLAGS_fused_small_attention",
+                 "FLAGS_layout_match_params",
+                 "FLAGS_use_pallas_conv_block",
+                 "FLAGS_use_pallas_fused_opt",
+                 "FLAGS_use_pallas_embedding_bag",
+                 "FLAGS_deterministic_reduction"]).items()))
+            # mesh keyed by content, not id(): a GC'd Mesh's successor can
+            # alias the address exactly like the Program case above
+            mesh_key = None
+            if mesh is not None:
+                mesh_key = (tuple(mesh.shape.items()),
+                            tuple(d.id for d in mesh.devices.flat))
+            key = (
+                program._uid,
+                program.version,
+                tuple(sorted((n, a.shape, str(a.dtype))
+                             for n, a in feed_arrays.items())),
+                tuple(fetch_names),
+                mesh_key,
+                trace_flags,
+            )
+            tel = _telemetry.enabled()
+            entry = self._cache.get(key) if use_program_cache else None
+            cache_hit = entry is not None
+            build = None
+            build_s = 0.0
+            if entry is None:
+                # static verifier runs only on the compile path (cache
+                # misses), memoized per program signature inside
+                # check_before_compile — steady-state steps never pay for
+                # it, and FLAGS_static_check=off is a single flag read
+                from .analysis import check_before_compile
+                from . import compile_cache as _cc
+
+                _cc.enable_xla_cache()
+                check_before_compile(program, list(feed_arrays), fetch_names,
+                                     scope=scope,
+                                     feed_shapes={n: tuple(a.shape)
+                                                  for n, a in
+                                                  feed_arrays.items()})
+                t_build = time.perf_counter()
+                build = self._build(program, list(feed_arrays), fetch_names,
+                                    mesh, data_axis)
+                build_s = time.perf_counter() - t_build
+                plan = build.plan
+                if build.mesh is not None and mesh is None:
+                    mesh = build.mesh
+                    data_axis = build.data_axis
+            else:
+                plan = entry.plan
+                if entry.mesh is not None and mesh is None:
+                    mesh = entry.mesh
+                    data_axis = entry.data_axis
+
+            # gather params from scope
+            params_ro, params_rw = {}, {}
+            for n in plan.ro_names:
+                params_ro[n] = self._scope_value(scope, n, block)
+            for n in plan.rw_names:
+                params_rw[n] = self._scope_value(scope, n, block)
+            params_carry, carry_hits, carry_converts = self._gather_carry(
+                scope, plan, block)
+            # host->device transfer volume: numpy feeds cross the PCIe
+            # boundary; device-resident jax.Arrays are already there
+            feed_bytes = 0
+            if tel:
+                feed_bytes = sum(int(a.nbytes) for a in feed_arrays.values()
+                                 if not isinstance(a, jax.Array))
+
+            # deterministic functional PRNG: (program seed, per-scope step
+            # counter).  Locked: pipeline section workers run concurrently
+            # against one scope and must never draw the same key.
+            seed = program.random_seed or 0
+            with _RNG_COUNTER_LOCK:
+                counter = scope._rng_counter
+                scope._rng_counter = counter + 1
+            # key derivation happens inside the compiled fn (kept out of the
+            # eager path: one dispatch per key op, every step)
+            rng = np.asarray([seed & 0xFFFFFFFF, counter & 0xFFFFFFFF],
+                             dtype=np.uint32)
+
         if mesh is not None:
-            mesh_key = (tuple(mesh.shape.items()),
-                        tuple(d.id for d in mesh.devices.flat))
-        key = (
-            program._uid,
-            program.version,
-            tuple(sorted((n, a.shape, str(a.dtype)) for n, a in feed_arrays.items())),
-            tuple(fetch_names),
-            mesh_key,
-            trace_flags,
-        )
-        tel = _telemetry.enabled()
-        entry = self._cache.get(key) if use_program_cache else None
-        cache_hit = entry is not None
-        build = None
-        build_s = 0.0
-        if entry is None:
-            # static verifier runs only on the compile path (cache misses),
-            # memoized per program signature inside check_before_compile —
-            # steady-state steps never pay for it, and FLAGS_static_check=
-            # off is a single flag read
-            from .analysis import check_before_compile
-            from . import compile_cache as _cc
-
-            _cc.enable_xla_cache()
-            check_before_compile(program, list(feed_arrays), fetch_names,
-                                 scope=scope,
-                                 feed_shapes={n: tuple(a.shape)
-                                              for n, a in
-                                              feed_arrays.items()})
-            t_build = time.perf_counter()
-            build = self._build(program, list(feed_arrays), fetch_names,
-                                mesh, data_axis)
-            build_s = time.perf_counter() - t_build
-            plan = build.plan
-            if build.mesh is not None and mesh is None:
-                mesh = build.mesh
-                data_axis = build.data_axis
-        else:
-            plan = entry.plan
-            if entry.mesh is not None and mesh is None:
-                mesh = entry.mesh
-                data_axis = entry.data_axis
-
-        # gather params from scope
-        params_ro, params_rw = {}, {}
-        for n in plan.ro_names:
-            params_ro[n] = self._scope_value(scope, n, block)
-        for n in plan.rw_names:
-            params_rw[n] = self._scope_value(scope, n, block)
-        params_carry, carry_hits, carry_converts = self._gather_carry(
-            scope, plan, block)
-        # host->device transfer volume: numpy feeds cross the PCIe
-        # boundary; device-resident jax.Arrays are already there
-        feed_bytes = 0
-        if tel:
-            feed_bytes = sum(int(a.nbytes) for a in feed_arrays.values()
-                             if not isinstance(a, jax.Array))
-
-        # deterministic functional PRNG: (program seed, per-scope step
-        # counter).  Locked: pipeline section workers run concurrently
-        # against one scope and must never draw the same key.
-        seed = program.random_seed or 0
-        with _RNG_COUNTER_LOCK:
-            counter = scope._rng_counter
-            scope._rng_counter = counter + 1
-        # key derivation happens inside the compiled fn (kept out of the
-        # eager path: one dispatch per key op, every step)
-        rng = np.asarray([seed & 0xFFFFFFFF, counter & 0xFFFFFFFF],
-                         dtype=np.uint32)
-
-        if mesh is not None:
-            feed_arrays = self._shard_feeds(feed_arrays, mesh, data_axis)
-            params_ro = self._shard_params(params_ro, mesh, block)
-            params_rw = self._shard_params(params_rw, mesh, block)
+            with _tracing.phase("executor.shard_feeds"):
+                feed_arrays = self._shard_feeds(feed_arrays, mesh,
+                                                data_axis)
+            with _tracing.phase("executor.shard_params"):
+                params_ro = self._shard_params(params_ro, mesh, block)
+                params_rw = self._shard_params(params_rw, mesh, block)
 
         cstats = None
         if entry is None:
@@ -634,11 +669,10 @@ class Executor:
         # never the default device
         ctx = (jax.default_device(self.place.jax_device()) if mesh is None
                else contextlib.nullcontext())
-        from ..profiler import RecordEvent
 
-        from ..flags import flag as _trace_flag
+        from ..flags import flag as _flag
 
-        if _trace_flag("hbm_audit"):
+        if _flag("hbm_audit"):
             from .memory_audit import maybe_audit
 
             report = maybe_audit(entry, feed_arrays, params_ro, params_rw,
@@ -648,17 +682,12 @@ class Executor:
                 # metrics.json answers both "how slow" and "how big"
                 _telemetry.set_info("memory_audit", report)
 
+        sspan.annotate(step=int(counter), cache_hit=cache_hit)
         t_step = time.perf_counter() if tel else 0.0
         try:
-            # nests under whatever span is active on this thread — the
-            # serving dispatcher's serving.execute, or a training loop's
-            # root — so cross-process traces reach down to the step
-            with _tracing.span("executor.step", step=int(counter),
-                               cache_hit=cache_hit):
-                with ctx, RecordEvent("Executor::Run"):
-                    fetches, updated, updated_carry = entry.jfn(
-                        feed_arrays, params_ro, params_rw, params_carry,
-                        rng)
+            with ctx, _tracing.phase("executor.dispatch"):
+                fetches, updated, updated_carry = entry.jfn(
+                    feed_arrays, params_ro, params_rw, params_carry, rng)
         except Exception:
             if params_carry:
                 # the carry inputs were donated: a failed call may have
@@ -672,87 +701,96 @@ class Executor:
                 _telemetry.event("step_error", step=int(counter))
             raise
 
-        if tel:
-            step_ms = (time.perf_counter() - t_step) * 1e3
-            fetch_bytes = sum(int(getattr(f, "nbytes", 0)) for f in fetches)
-            no_donate = getattr(program, "_no_donate", False)
-            if cache_hit:
-                compile_ms = None
-            elif cstats is not None and cstats["source"] != "fallback":
-                # eager AOT path: plan build + trace/lower + XLA compile
-                # (or tier-B deserialize) — measured apart from the step
-                compile_ms = build_s * 1e3 + cstats["compile_ms"]
-            else:
-                # lazy fallback: jit compiles inside the first call, so the
-                # pre-PR conflation is the honest number
-                compile_ms = build_s * 1e3 + step_ms
-            _telemetry.record_step(
-                step_ms, cache_hit,
-                compile_ms=compile_ms,
-                donated=0 if no_donate else
-                len(params_rw) + len(params_carry),
-                feed_bytes=feed_bytes, fetch_bytes=fetch_bytes,
-                carry_hits=carry_hits, carry_converts=carry_converts)
-            cmeta = getattr(program, "_collective_meta", None)
-            if cmeta and cmeta.get("wire_bytes_per_step"):
-                # analytic bytes-on-ICI for the step's gradient exchange
-                # (stamped by the collective transpiler; see
-                # transpiler/collective.py _wire_bytes)
-                wire = float(cmeta["wire_bytes_per_step"])
-                _telemetry.inc("collective_wire_bytes_total", wire)
-                _telemetry.set_gauge("collective_wire_bytes_per_step", wire)
-        from ..profiler import mark_instant
-
-        mark_instant("step", args={"step": int(counter)})
-        _tracing.instant("step", step=int(counter))
-
-        for n, val in updated.items():
-            scope.var(n).set(val)
-        if updated_carry:
-            # refresh the carry cache: pair each bf16 copy with the scope
-            # object it mirrors so staleness is caught by identity (an
-            # external scope.set — checkpoint restore — forces reconvert)
-            cache = scope.__dict__.setdefault("_layout_carry_cache", {})
-            for n, bf in updated_carry.items():
-                if n in updated:
-                    cache[n] = (scope.var(n).get_tensor().get(), bf)
-                elif n in cache:
-                    cache[n] = (cache[n][0], bf)
+        with _tracing.phase("executor.writeback"):
+            if tel:
+                step_ms = (time.perf_counter() - t_step) * 1e3
+                fetch_bytes = sum(int(getattr(f, "nbytes", 0))
+                                  for f in fetches)
+                no_donate = getattr(program, "_no_donate", False)
+                if cache_hit:
+                    compile_ms = None
+                elif cstats is not None and cstats["source"] != "fallback":
+                    # eager AOT path: plan build + trace/lower + XLA
+                    # compile (or tier-B deserialize) — measured apart
+                    # from the step
+                    compile_ms = build_s * 1e3 + cstats["compile_ms"]
                 else:
-                    cache[n] = (None, bf)
+                    # lazy fallback: jit compiles inside the first call,
+                    # so the pre-PR conflation is the honest number
+                    compile_ms = build_s * 1e3 + step_ms
+                _telemetry.record_step(
+                    step_ms, cache_hit,
+                    compile_ms=compile_ms,
+                    donated=0 if no_donate else
+                    len(params_rw) + len(params_carry),
+                    feed_bytes=feed_bytes, fetch_bytes=fetch_bytes,
+                    carry_hits=carry_hits, carry_converts=carry_converts)
+                cmeta = getattr(program, "_collective_meta", None)
+                if cmeta and cmeta.get("wire_bytes_per_step"):
+                    # analytic bytes-on-ICI for the step's gradient
+                    # exchange (stamped by the collective transpiler; see
+                    # transpiler/collective.py _wire_bytes)
+                    wire = float(cmeta["wire_bytes_per_step"])
+                    _telemetry.inc("collective_wire_bytes_total", wire)
+                    _telemetry.set_gauge("collective_wire_bytes_per_step",
+                                         wire)
 
-        from ..flags import flag as _flag
+            for n, val in updated.items():
+                scope.var(n).set(val)
+            if updated_carry:
+                # refresh the carry cache: pair each bf16 copy with the
+                # scope object it mirrors so staleness is caught by
+                # identity (an external scope.set — checkpoint restore —
+                # forces reconvert)
+                cache = scope.__dict__.setdefault("_layout_carry_cache", {})
+                for n, bf in updated_carry.items():
+                    if n in updated:
+                        cache[n] = (scope.var(n).get_tensor().get(), bf)
+                    elif n in cache:
+                        cache[n] = (cache[n][0], bf)
+                    else:
+                        cache[n] = (None, bf)
+            # the step consumed (donated) these inputs and the scope now
+            # holds their successors: drop the last references here, while
+            # the device works, not at the function's return, after the
+            # wait for it
+            del params_rw, params_carry, feed_arrays
 
-        if _flag("check_nan_inf"):
-            # reference FLAGS_check_nan_inf (operator.cc:947): scan outputs;
-            # block compilation means we check fetches + updated state vars
-            for name, val in list(zip(fetch_names, fetches)) + list(
-                    updated.items()):
-                arr = np.asarray(val)
-                if np.issubdtype(arr.dtype, np.floating) and not np.isfinite(
-                        arr).all():
-                    raise RuntimeError(
-                        "Operator output contains NaN/Inf: variable %r "
-                        "(FLAGS_check_nan_inf)" % name)
+        # everything below reads device values on the host: the wait for
+        # the device is here
+        with _tracing.phase("executor.fetch"):
+            if _flag("check_nan_inf"):
+                # reference FLAGS_check_nan_inf (operator.cc:947): scan
+                # outputs; block compilation means we check fetches +
+                # updated state vars
+                for name, val in list(zip(fetch_names, fetches)) + list(
+                        updated.items()):
+                    arr = np.asarray(val)
+                    if np.issubdtype(arr.dtype, np.floating) \
+                            and not np.isfinite(arr).all():
+                        raise RuntimeError(
+                            "Operator output contains NaN/Inf: variable %r "
+                            "(FLAGS_check_nan_inf)" % name)
 
-        if ps_meta is not None:
-            # send grads -> barrier -> pull params (the transpiler-
-            # rewritten send/recv op sequence, executed by the runtime so
-            # the compiled step stays pure).  Taken from the FULL fetch
-            # list: a grad the user fetches themselves is still a grad.
-            all_grads = set(ps_meta["param_grad"].values())
-            grad_vals = {
-                name: np.asarray(v)
-                for name, v in zip(fetch_names, fetches)
-                if name in all_grads
-            }
-            scope._ps_comm.step(scope, grad_vals)
-            n_user = len(fetches) - len(ps_grad_names)
-            fetches = fetches[:n_user]
+            if ps_meta is not None:
+                # send grads -> barrier -> pull params (the transpiler-
+                # rewritten send/recv op sequence, executed by the runtime
+                # so the compiled step stays pure).  Taken from the FULL
+                # fetch list: a grad the user fetches themselves is still
+                # a grad.
+                all_grads = set(ps_meta["param_grad"].values())
+                grad_vals = {
+                    name: np.asarray(v)
+                    for name, v in zip(fetch_names, fetches)
+                    if name in all_grads
+                }
+                scope._ps_comm.step(scope, grad_vals)
+                n_user = len(fetches) - len(ps_grad_names)
+                fetches = fetches[:n_user]
 
-        if return_numpy:
-            return [as_numpy(f) for f in fetches]
-        return list(fetches)
+            if return_numpy:
+                return [as_numpy(f) for f in fetches]
+            return list(fetches)
 
     # -- internals -----------------------------------------------------------
     def _devices(self, mesh):

@@ -383,7 +383,10 @@ def run_op(op, env, rng_key, mesh=None, axis_names=(), runner=None,
         with guard, jax.ensure_compile_time_eval():
             out = opdef.lower(ctx, *args, **_lower_attrs(op.attrs))
     else:
-        with guard:
+        # the op's type as a scope: metadata only, it reaches the
+        # ``op_name`` of every HLO instruction this op lowers to, so a
+        # device trace can be grouped by Program op
+        with guard, jax.named_scope(op.type):
             out = opdef.lower(ctx, *args, **_lower_attrs(op.attrs))
     if (len(opdef.output_slots) == 1
             and opdef.output_slots[0] in opdef.duplicable_outputs

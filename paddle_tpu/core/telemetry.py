@@ -12,7 +12,9 @@ after the fact:
   when the flag is off, so instrumented call sites cost one dict lookup in
   production.  ``FLAGS_telemetry_dir`` selects where the JSONL stream and
   ``dump()`` snapshots land; with no dir, events stay in a bounded
-  in-memory ring.
+  in-memory ring.  ``event()`` is an append to that ring; the lines reach
+  ``steps.jsonl`` in ``flush()``, in ``dump()`` (so at exit), and whenever
+  ``_EVENT_FLUSH_AT`` of them wait — never one write per event.
 - export: ``dump()`` writes a Prometheus-style text file (metrics.prom)
   and a JSON snapshot (metrics.json); pservers publish the snapshot under
   the ``__metrics__`` RPC key (``publish_rpc``) so trainers and
@@ -42,8 +44,8 @@ import threading
 import time
 
 __all__ = [
-    "enabled", "inc", "set_gauge", "observe", "event", "set_info",
-    "record_step", "snapshot", "counter_total", "label_sets",
+    "enabled", "inc", "set_gauge", "observe", "observe_many", "event",
+    "flush", "set_info", "record_step", "snapshot", "counter_total", "label_sets",
     "prometheus_text", "dump", "maybe_dump", "reset", "publish_rpc",
     "start_publisher", "decode_snapshot", "scrape", "METRICS_RPC_KEY",
     "HIST_BUCKET_BOUNDS", "bucket_percentile", "merge_hist_snapshots",
@@ -57,6 +59,7 @@ METRICS_RPC_KEY = "__metrics__"
 # sample set is decimated (every other kept) so long runs stay bounded
 _HIST_SAMPLE_CAP = 8192
 _EVENT_RING_CAP = 4096
+_EVENT_FLUSH_AT = 1024     # unwritten events that trigger a flush
 
 
 def _log_bounds(lo, hi, growth):
@@ -77,12 +80,14 @@ def _log_bounds(lo, hi, growth):
 # decimated sample lists, which cannot be merged.
 HIST_BUCKET_BOUNDS = _log_bounds(0.05, 120000.0, 1.25)
 
-_lock = threading.RLock()
+_lock = threading.RLock()  # the registry; taken per mutation
+_io_lock = threading.Lock()  # steps.jsonl; taken per flush, never per event
 _counters = {}     # (name, labels) -> float
 _gauges = {}       # (name, labels) -> float
 _hists = {}        # (name, labels) -> _Hist
 _info = {}         # one-off structured payloads (e.g. memory_audit report)
 _events = []       # bounded in-memory ring of event dicts
+_unwritten = []    # events not yet in steps.jsonl
 _event_seq = {}    # kind -> next sequence number
 _event_sink = [None, None]  # (path, open file handle) for the JSONL stream
 _series = []       # bounded ring of timestamped counter/gauge samples
@@ -267,6 +272,12 @@ def set_gauge(name, value, **labels):
 
 
 def observe(name, value, **labels):
+    observe_many(name, (value,), **labels)
+
+
+def observe_many(name, values, **labels):
+    """``observe`` for a batch of samples of one histogram under a single
+    lock take (a finished request's inter-token gaps)."""
     if not enabled():
         return
     k = _key(name, labels)
@@ -274,7 +285,8 @@ def observe(name, value, **labels):
         h = _hists.get(k)
         if h is None:
             h = _hists[k] = _Hist()
-        h.add(value)
+        for v in values:
+            h.add(v)
 
 
 def set_info(key, value):
@@ -287,9 +299,9 @@ def set_info(key, value):
 
 
 def event(kind, **fields):
-    """Append one structured event to the JSONL step log.  Events stream to
-    ``<FLAGS_telemetry_dir>/steps.jsonl`` when a dir is set; a bounded
-    in-memory ring keeps the tail either way."""
+    """Append one structured event to the step log: a bounded in-memory
+    ring keeps the tail, and with ``FLAGS_telemetry_dir`` set the event is
+    queued for ``<dir>/steps.jsonl`` (written by ``flush()``)."""
     if not enabled():
         return
     with _lock:
@@ -300,12 +312,26 @@ def event(kind, **fields):
         _events.append(rec)
         if len(_events) > _EVENT_RING_CAP:
             del _events[: len(_events) - _EVENT_RING_CAP]
+        if telemetry_dir():
+            _unwritten.append(rec)
+        full = len(_unwritten) >= _EVENT_FLUSH_AT
+    if full:
+        flush()
+
+
+def flush():
+    """Write the queued events to ``<FLAGS_telemetry_dir>/steps.jsonl``."""
+    with _io_lock:
+        with _lock:
+            batch = _unwritten[:]
+            _unwritten[:] = []
         d = telemetry_dir()
-        if d:
-            fh = _event_fh(d)
-            if fh is not None:
-                fh.write(json.dumps(rec) + "\n")
-                fh.flush()
+        fh = _event_fh(d) if d and batch else None
+        if fh is None:
+            return
+        for rec in batch:
+            fh.write(json.dumps(rec) + "\n")
+        fh.flush()
 
 
 class _RotatingFile:
@@ -593,6 +619,7 @@ def dump(dirname=None):
             "telemetry.dump() needs a directory (argument or "
             "FLAGS_telemetry_dir)")
     os.makedirs(d, exist_ok=True)
+    flush()
     snap = snapshot()
     jpath = os.path.join(d, "metrics.json")
     ppath = os.path.join(d, "metrics.prom")
@@ -621,8 +648,10 @@ def reset():
         _hists.clear()
         _info.clear()
         _events.clear()
+        _unwritten[:] = []
         _event_seq.clear()
         _series.clear()
+    with _io_lock:
         if _event_sink[1] is not None:
             _event_sink[1].close()
         _event_sink[0] = _event_sink[1] = None

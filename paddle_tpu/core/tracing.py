@@ -20,8 +20,21 @@ chain and across ranks.  Design:
 - **sink**: one JSONL stream per process, ``trace-<pid>.jsonl`` under
   ``FLAGS_telemetry_dir``, size-bounded by ``FLAGS_telemetry_max_bytes``
   (same rotate-and-keep-one guard as telemetry's steps.jsonl).
+  Recording is an append to an in-memory buffer; records are serialised
+  and written by ``flush()``, at exit, and whenever ``_FLUSH_AT`` records
+  are waiting — never one write per record.  ``records(name)`` reads the
+  newest ``_RECENT_CAP`` records of this process, flushed or not
+  (``tracing_dropped_total`` counts what fell out of that window).
   tools/trace_view.py merges the per-process files into a single
   Chrome/Perfetto trace.json with cross-process flow arrows.
+- **host phases**: ``phase(name)`` brackets a stretch of a hot loop
+  (``serving.dispatch``, ``executor.shard_feeds``...).  It always enters a
+  ``jax.profiler.TraceAnnotation`` — inert without a profiler session, a
+  host event on the profiler's clock beside the device timeline with one
+  (``profiler.start_profiler(device_trace_dir=...)``) — and, when
+  ``FLAGS_tracing`` is on, adds its duration to a per-thread tally that
+  the loop's step span takes as its ``phases`` attribute
+  (``Span.take_phases``).  A phase emits no record of its own.
 - **zero-cost off**: ``FLAGS_tracing`` is off by default; every public
   call early-returns after a single flag read, handing back one shared
   inert ``_NULL_SPAN``.  No file, no thread state, no signal handlers.
@@ -31,34 +44,48 @@ chain and across ranks.  Design:
   unhandled exception, SIGTERM, and atexit — a killed fleet replica
   leaves a postmortem naming its in-flight batch.  Because SIGKILL is
   uncatchable, ``note()`` checkpoints the ring to disk immediately, so
-  even a -9'd process leaves its last breadcrumbs behind.
+  even a -9'd process leaves its last breadcrumbs behind; callers on a
+  hot loop therefore note only what changed (the decode engine: the
+  lane set), and a dump serialises only the records new since the last.
 """
 
 import atexit
+import collections
 import json
 import os
+import random
 import sys
 import threading
 import time
 
+from jax.profiler import TraceAnnotation
+
+from . import telemetry as _tm
+
 __all__ = [
     "enabled", "Span", "start_span", "span", "activate", "remote_parent",
-    "record_span", "instant", "current_span", "current_context",
+    "record_span", "instant", "phase", "current_span", "current_context",
     "traceparent", "parse_traceparent", "set_process_name", "note",
-    "flight_dump", "flush", "reset",
+    "flight_dump", "flush", "records", "reset",
 ]
 
 _FLIGHT_CAP = 512          # ring slots kept for the postmortem dump
+_RECENT_CAP = 65536        # newest records kept in memory for records()
+_FLUSH_AT = 1024           # unwritten records that trigger a flush
 _WIRE_SEP = "\x1f"         # RPC frame-name separator for the traceparent
 
-_lock = threading.RLock()
-_tls = threading.local()   # .stack = [Span, ...] per thread
+_lock = threading.RLock()  # the buffers below; taken once per record
+_io_lock = threading.Lock()  # the sink; taken per flush, never per record
+_tls = threading.local()   # .stack = [Span, ...], .phases = {name: s}
 _sink = [None, None]       # (path, _RotatingFile) — telemetry's sink idiom
 _proc_name = [None]        # explicit process track name (serve.py sets it)
 _proc_header_written = [False]
 _flight = []               # bounded ring of record dicts
+_flight_json = []          # their serialised forms (None until dumped)
+_recent = collections.deque(maxlen=_RECENT_CAP)
+_unwritten = []            # recorded, not yet in the sink
 _handlers_installed = [False]
-_rng_state = [None]        # (pid, counter) — fork-safe id generation
+_ids = random.Random(os.urandom(16))   # trace/span ids
 
 
 _flags_mod = [None]        # cached flags module (import once, read often)
@@ -84,22 +111,18 @@ def _telemetry_dir():
 
 def _new_id(nbytes):
     # os.urandom per id is measurably slow; draw from a per-process
-    # counter folded with startup entropy (fork-safe: keyed by pid)
-    pid = os.getpid()
-    with _lock:
-        st = _rng_state[0]
-        if st is None or st[0] != pid:
-            st = [pid, int.from_bytes(os.urandom(8), "little")]
-            _rng_state[0] = st
-        st[1] = (st[1] * 6364136223846793005 + 1442695040888963407) \
-            % (1 << 64)
-        v = st[1]
-        if nbytes > 8:
-            st[1] = (st[1] * 6364136223846793005 + 1442695040888963407) \
-                % (1 << 64)
-            v = (v << 64) | st[1]
-    h = "%0*x" % (2 * nbytes, v)
-    return h[-2 * nbytes:]
+    # generator seeded from it (re-seeded in a forked child, below)
+    return "%0*x" % (2 * nbytes, _ids.getrandbits(8 * nbytes))
+
+
+def _after_fork_in_child():
+    # the parent's ids must not repeat here, and its buffered records are
+    # the parent's to write
+    _ids.seed(os.urandom(16))
+    _drop_buffers()
+
+
+os.register_at_fork(after_in_child=_after_fork_in_child)
 
 
 # -- W3C-style context --------------------------------------------------------
@@ -181,6 +204,18 @@ class Span:
     def traceparent(self):
         return _format_traceparent(self.trace_id, self.span_id)
 
+    def take_phases(self, prefix=""):
+        """Move this thread's ``phase()`` tally (names starting with
+        ``prefix``) into the ``phases`` attribute, name -> microseconds,
+        and return it.  The loop's step span calls this once an
+        iteration, so phases that ran before the span opened, or in an
+        iteration that opened none, are carried to the next one."""
+        tally = getattr(_tls, "phases", None) or {}
+        taken = {n: int(tally.pop(n) * 1e6)
+                 for n in list(tally) if n.startswith(prefix)}
+        self.attrs["phases"] = taken
+        return taken
+
     def end(self):
         if self._ended:
             return self
@@ -217,6 +252,14 @@ class _NullSpan:
 
     def link(self, other):
         return self
+
+    def take_phases(self, prefix=""):
+        # a tally left from before the flag went off is stale by the
+        # time it comes back on
+        tally = getattr(_tls, "phases", None)
+        if tally:
+            tally.clear()
+        return {}
 
     def end(self):
         return self
@@ -385,6 +428,52 @@ def instant(name, **attrs):
     _emit(rec)
 
 
+class _Phase:
+    """Context manager behind ``phase()``; ``stop()`` ends it early (the
+    decode loop's lock wait ends inside the ``with`` that takes the
+    lock)."""
+
+    __slots__ = ("name", "_annotation", "_t0")
+
+    def __init__(self, name):
+        self.name = name
+        self._annotation = TraceAnnotation(name)
+        self._t0 = None
+
+    def __enter__(self):
+        self._annotation.__enter__()
+        if enabled():
+            self._t0 = time.perf_counter()
+        return self
+
+    def stop(self):
+        annotation, self._annotation = self._annotation, None
+        if annotation is None:
+            return
+        annotation.__exit__(None, None, None)
+        if self._t0 is not None:
+            tally = getattr(_tls, "phases", None)
+            if tally is None:
+                tally = _tls.phases = {}
+            tally[self.name] = tally.get(self.name, 0.0) \
+                + time.perf_counter() - self._t0
+
+    def __exit__(self, exc_type, exc, tb):
+        self.stop()
+        return False
+
+
+def phase(name):
+    """``with tracing.phase("executor.dispatch"): ...`` — a named stretch
+    of a hot loop's iteration.  Always a ``jax.profiler.TraceAnnotation``
+    (inert without a profiler session; with one, a host event on the
+    profiler's clock, which is how a profile taken with ``FLAGS_tracing``
+    off names its idle gaps).  With ``FLAGS_tracing`` on its duration is
+    also added to this thread's tally for ``Span.take_phases``.  No record
+    is emitted."""
+    return _Phase(name)
+
+
 def set_process_name(name):
     """Name this process's track in the merged trace (e.g.
     ``serving-replica-0``); defaults to ``pid-<pid>``."""
@@ -401,8 +490,6 @@ def _proc_header():
 
 
 def _sink_fh(d):
-    from .telemetry import _RotatingFile
-
     path = os.path.join(d, "trace-%d.jsonl" % os.getpid())
     if _sink[0] != path:
         if _sink[1] is not None:
@@ -410,59 +497,88 @@ def _sink_fh(d):
         try:
             os.makedirs(d, exist_ok=True)
             _sink[0] = path
-            _sink[1] = _RotatingFile(path)
+            _sink[1] = _tm._RotatingFile(path)
             _proc_header_written[0] = False
         except OSError:
             _sink[0] = _sink[1] = None
     return _sink[1]
 
 
+def _drop_buffers():
+    with _lock:
+        _flight[:] = []
+        _flight_json[:] = []
+        _recent.clear()
+        _unwritten[:] = []
+
+
 def _emit(rec):
-    _install_handlers()
+    """Record: an append to the in-memory buffers.  Serialising and
+    writing are ``flush()``'s."""
+    if not _handlers_installed[0]:
+        _install_handlers()
     with _lock:
         _flight.append(rec)
+        _flight_json.append(None)
         if len(_flight) > _FLIGHT_CAP:
             del _flight[: len(_flight) - _FLIGHT_CAP]
+            del _flight_json[: len(_flight_json) - _FLIGHT_CAP]
+        dropped = len(_recent) == _RECENT_CAP
+        _recent.append(rec)
+        if _telemetry_dir():
+            _unwritten.append(rec)
+        full = len(_unwritten) >= _FLUSH_AT
+    if _tm.enabled():
+        _tm.inc("tracing_records_total", kind=rec["t"])
+        if dropped:
+            _tm.inc("tracing_dropped_total")
+    if full:
+        flush()
+
+
+def flush(wait=True):
+    """Write every record made so far to ``trace-<pid>.jsonl`` under
+    ``FLAGS_telemetry_dir`` (whatever ``FLAGS_tracing`` says now).
+    ``wait=False`` gives up instead of waiting for a flush in progress
+    (the SIGTERM handler may have interrupted one on its own thread)."""
+    if not _io_lock.acquire(wait):
+        return
+    try:
+        with _lock:
+            batch = _unwritten[:]
+            _unwritten[:] = []
         d = _telemetry_dir()
-        if not d:
-            return
-        fh = _sink_fh(d)
+        fh = _sink_fh(d) if d and batch else None
         if fh is None:
             return
         if not _proc_header_written[0]:
             _proc_header_written[0] = True
             fh.write(json.dumps(_proc_header()) + "\n")
-        fh.write(json.dumps(rec, default=str) + "\n")
+        for rec in batch:
+            fh.write(json.dumps(rec, default=str) + "\n")
         fh.flush()
-    if _telemetry_enabled():
-        from . import telemetry as _tm
-
-        _tm.inc("tracing_records_total", kind=rec["t"])
+    finally:
+        _io_lock.release()
 
 
-def _telemetry_enabled():
-    from . import telemetry as _tm
-
-    return _tm.enabled()
-
-
-def flush():
-    """Flush the JSONL sink (tests; the stream is flushed per record
-    already, this also covers a swapped telemetry_dir)."""
+def records(name):
+    """This process's recorded spans of one name, oldest first, in the
+    sink's record format, flushed or not: the newest ``_RECENT_CAP``
+    records are kept."""
     with _lock:
-        if _sink[1] is not None:
-            _sink[1].flush()
+        return [r for r in _recent
+                if r["t"] == "span" and r["name"] == name]
 
 
 def reset():
-    """Tests: drop the sink, the flight ring, and per-thread stacks are
-    left to unwind naturally (they are context-managed)."""
-    with _lock:
+    """Tests: drop the sink and every buffer; per-thread stacks are left
+    to unwind naturally (they are context-managed)."""
+    with _io_lock:
         if _sink[1] is not None:
             _sink[1].close()
         _sink[0] = _sink[1] = None
         _proc_header_written[0] = False
-        _flight[:] = []
+    _drop_buffers()
 
 
 # -- flight recorder ----------------------------------------------------------
@@ -495,20 +611,22 @@ def flight_dump(reason="manual"):
         return None
     path = os.path.join(d, "flightrec-%d.json" % os.getpid())
     with _lock:
-        doc = {"proc": _proc_header(), "reason": reason,
-               "dumped_at": int(time.time() * 1e6),
-               "records": list(_flight)}
+        # only what is new since the last dump is serialised
+        for i, line in enumerate(_flight_json):
+            if line is None:
+                _flight_json[i] = json.dumps(_flight[i], default=str)
+        body = ", ".join(_flight_json)
+    head = json.dumps({"proc": _proc_header(), "reason": reason,
+                       "dumped_at": int(time.time() * 1e6)})
     tmp = path + ".tmp"
     try:
         os.makedirs(d, exist_ok=True)
         with open(tmp, "w") as f:
-            json.dump(doc, f, default=str)
+            f.write('%s, "records": [%s]}' % (head[:-1], body))
         os.replace(tmp, path)
     except OSError:
         return None
-    if _telemetry_enabled():
-        from . import telemetry as _tm
-
+    if _tm.enabled():
         _tm.inc("tracing_flightrec_dumps_total",
                 reason=reason.split(":", 1)[0])
     return path
@@ -524,12 +642,13 @@ def _install_handlers():
         if _handlers_installed[0]:
             return
         _handlers_installed[0] = True
-    atexit.register(lambda: flight_dump(reason="atexit"))
+    atexit.register(lambda: (flush(), flight_dump(reason="atexit")))
 
     prev_hook = sys.excepthook
 
     def hook(exc_type, exc, tb):
         try:
+            flush()
             flight_dump(reason="exception:%s" % exc_type.__name__)
         except Exception:
             pass
@@ -544,6 +663,7 @@ def _install_handlers():
 
             def on_term(signum, frame):
                 try:
+                    flush(wait=False)
                     flight_dump(reason="sigterm")
                 except Exception:
                     pass

@@ -65,9 +65,10 @@ def paged_attention_reference(q, k_cache, v_cache, block_tables,
     are unused slots, clamped to block 0 and masked by context_lens)."""
     bb, maxb = block_tables.shape
     bs, h, d = k_cache.shape[1:]
-    idx = jnp.maximum(block_tables, 0)
-    k = jnp.take(k_cache, idx, axis=0).reshape(bb, maxb * bs, h, d)
-    v = jnp.take(v_cache, idx, axis=0).reshape(bb, maxb * bs, h, d)
+    with jax.named_scope("kv_gather"):
+        idx = jnp.maximum(block_tables, 0)
+        k = jnp.take(k_cache, idx, axis=0).reshape(bb, maxb * bs, h, d)
+        v = jnp.take(v_cache, idx, axis=0).reshape(bb, maxb * bs, h, d)
     return masked_attention(q, k, v, context_lens)
 
 

@@ -160,7 +160,10 @@ def _ln(x, g, b):
 def _token_logits(params, cfg, tok, pos, attend):
     """One token per lane through every layer; ``attend(l, q, k, v)``
     owns the KV write + history attention (the only paged/unpaged
-    difference)."""
+    difference).  The ``jax.named_scope`` names (``layer<i>/attn``,
+    ``.../kv_write``, ``.../kv_gather``, ``layer<i>/mlp``, ``lm_head``)
+    are metadata: they reach each HLO instruction's ``op_name``, so a
+    device trace can be grouped by them, and change nothing computed."""
     bb = tok.shape[0]
     x = jnp.take(params["embed"], tok, axis=0) \
         + jnp.take(params["pos_embed"], pos, axis=0)
@@ -168,16 +171,21 @@ def _token_logits(params, cfg, tok, pos, attend):
         def p(n, _l=l):
             return params["l%d_%s" % (_l, n)]
 
-        h = _ln(x, p("ln1_g"), p("ln1_b"))
-        q = (h @ p("wq")).reshape(bb, cfg.heads, cfg.head_dim)
-        k = (h @ p("wk")).reshape(bb, cfg.heads, cfg.head_dim)
-        v = (h @ p("wv")).reshape(bb, cfg.heads, cfg.head_dim)
-        a = attend(l, q, k, v).reshape(bb, cfg.hidden)
-        x = x + a @ p("wo")
-        h2 = _ln(x, p("ln2_g"), p("ln2_b"))
-        x = x + jax.nn.gelu(h2 @ p("w1") + p("b1")) @ p("w2") + p("b2")
-    x = _ln(x, params["lnf_g"], params["lnf_b"])
-    return x @ params["head"]
+        with jax.named_scope("layer%d" % l):
+            with jax.named_scope("attn"):
+                h = _ln(x, p("ln1_g"), p("ln1_b"))
+                q = (h @ p("wq")).reshape(bb, cfg.heads, cfg.head_dim)
+                k = (h @ p("wk")).reshape(bb, cfg.heads, cfg.head_dim)
+                v = (h @ p("wv")).reshape(bb, cfg.heads, cfg.head_dim)
+                a = attend(l, q, k, v).reshape(bb, cfg.hidden)
+                x = x + a @ p("wo")
+            with jax.named_scope("mlp"):
+                h2 = _ln(x, p("ln2_g"), p("ln2_b"))
+                x = x + jax.nn.gelu(h2 @ p("w1") + p("b1")) @ p("w2") \
+                    + p("b2")
+    with jax.named_scope("lm_head"):
+        x = _ln(x, params["lnf_g"], params["lnf_b"])
+        return x @ params["head"]
 
 
 # -- paged step --------------------------------------------------------------
@@ -220,25 +228,28 @@ def make_paged_step(cfg, kv_config):
         def attend(l, q, k, v):
             nonlocal k_c, v_c
             if not int8:
-                k_c = k_c.at[l, blk_ids, offs].set(k)
-                v_c = v_c.at[l, blk_ids, offs].set(v)
+                with jax.named_scope("kv_write"):
+                    k_c = k_c.at[l, blk_ids, offs].set(k)
+                    v_c = v_c.at[l, blk_ids, offs].set(v)
                 return paged_attention(q, k_c[l], v_c[l], block_tables,
                                        context_lens)
             nonlocal k_s, v_s
-            qk, sk = _kv.quantize_kv(k)
-            qv, sv = _kv.quantize_kv(v)
-            k_c = k_c.at[l, blk_ids, offs].set(qk)
-            v_c = v_c.at[l, blk_ids, offs].set(qv)
-            k_s = k_s.at[l, blk_ids, offs].set(sk)
-            v_s = v_s.at[l, blk_ids, offs].set(sv)
-            idx = jnp.maximum(block_tables, 0)
-            bb, maxb = block_tables.shape
-            kk = _kv.dequantize_kv(jnp.take(k_c[l], idx, axis=0),
-                                   jnp.take(k_s[l], idx, axis=0))
-            vv = _kv.dequantize_kv(jnp.take(v_c[l], idx, axis=0),
-                                   jnp.take(v_s[l], idx, axis=0))
-            kk = kk.reshape(bb, maxb * bs, cfg.heads, cfg.head_dim)
-            vv = vv.reshape(bb, maxb * bs, cfg.heads, cfg.head_dim)
+            with jax.named_scope("kv_write"):
+                qk, sk = _kv.quantize_kv(k)
+                qv, sv = _kv.quantize_kv(v)
+                k_c = k_c.at[l, blk_ids, offs].set(qk)
+                v_c = v_c.at[l, blk_ids, offs].set(qv)
+                k_s = k_s.at[l, blk_ids, offs].set(sk)
+                v_s = v_s.at[l, blk_ids, offs].set(sv)
+            with jax.named_scope("kv_gather"):
+                idx = jnp.maximum(block_tables, 0)
+                bb, maxb = block_tables.shape
+                kk = _kv.dequantize_kv(jnp.take(k_c[l], idx, axis=0),
+                                       jnp.take(k_s[l], idx, axis=0))
+                vv = _kv.dequantize_kv(jnp.take(v_c[l], idx, axis=0),
+                                       jnp.take(v_s[l], idx, axis=0))
+                kk = kk.reshape(bb, maxb * bs, cfg.heads, cfg.head_dim)
+                vv = vv.reshape(bb, maxb * bs, cfg.heads, cfg.head_dim)
             return masked_attention(q, kk, vv, context_lens)
 
         logits = _token_logits(params, cfg, tok, pos, attend)

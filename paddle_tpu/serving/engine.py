@@ -185,7 +185,8 @@ class _Pending:
     """Handle returned by submit(): wait() blocks for the InferReply."""
 
     __slots__ = ("model", "tenant", "feeds", "rows", "deadline",
-                 "t_submit", "t_dispatch", "req_id", "callback", "_done",
+                 "t_submit", "t_locked", "t_dispatch", "req_id", "callback",
+                 "_done",
                  "reply", "traceparent", "span", "qspan", "tier", "weight")
 
     def __init__(self, model, tenant, feeds, rows, deadline_ms, req_id,
@@ -197,6 +198,7 @@ class _Pending:
         self.feeds = feeds
         self.rows = rows
         self.t_submit = time.perf_counter()
+        self.t_locked = None   # DecodeEngine.submit: the engine lock taken
         self.t_dispatch = None
         self.deadline = self.t_submit + deadline_ms / 1e3
         self.req_id = req_id
@@ -964,6 +966,13 @@ class DecodeEngine:
         self._admit_seq = 0
         self._step_no = 0
         self._rr_prefill = 0        # round-robin pointer (token budget)
+        # what the next serving.decode_step span reports: (admit wait,
+        # lock wait) in ms of each request admitted since the last one,
+        # when the device last handed a step's tokens back, and the lane
+        # set last written to the flight recorder
+        self._admit_waits = []
+        self._t_fetched = None
+        self._noted_lanes = None
         self.in_batch = False
         self.on_batch_boundary = None
         # disaggregated prefill role hooks (serving/disagg.py wires them):
@@ -1323,6 +1332,7 @@ class DecodeEngine:
             seq.replay_upto = len(prompt_ids) + len(resume_out)
             seq.resume_tail = resume_tail
         with self._cond:
+            req.t_locked = time.perf_counter()
             if resume_out is not None and (
                     req.req_id in self._migrating or any(
                         s.pending.req_id == req.req_id
@@ -1785,8 +1795,9 @@ class DecodeEngine:
             if "ttft_ms" in reply.phases:
                 _tm.observe("ttft_ms", reply.phases["ttft_ms"],
                             model=r.model)
-            for g in reply.phases.get("itl_ms_samples") or ():
-                _tm.observe("itl_ms", g, model=r.model)
+            _tm.observe_many("itl_ms",
+                             reply.phases.get("itl_ms_samples") or (),
+                             model=r.model)
             met = time.perf_counter() <= r.deadline
             _tm.inc("serving_deadline_met_total" if met
                     else "serving_deadline_missed_total", tier=r.tier)
@@ -1842,6 +1853,12 @@ class DecodeEngine:
             self._waiting.pop(0)
             self._admit_seq += 1
             s.admit_seq = self._admit_seq
+            if s.t_admit is None:
+                # first admission (not a preempted sequence's replay)
+                r = s.pending
+                self._admit_waits.append(
+                    (round((now - r.t_submit) * 1e3, 3),
+                     round(((r.t_locked or now) - r.t_submit) * 1e3, 3)))
             s.t_admit = now
             if m.prefix is not None and s.replay_upto > len(s.prompt):
                 # resumed (migrated-in) or preempted replay: match the
@@ -1885,8 +1902,6 @@ class DecodeEngine:
             cap = float(alloc.capacity) or 1.0
             _tm.set_gauge("kv_pool_occupancy", alloc.in_use / cap,
                           model=name)
-            _tm.set_gauge("kv_pool_reclaimable_ratio",
-                          alloc.reclaimable / cap, model=name)
             if m.prefix is not None:
                 _tm.set_gauge("prefix_cache_hit_rate", m.prefix.hit_rate(),
                               model=name)
@@ -2109,36 +2124,83 @@ class DecodeEngine:
         return max(self.buckets)
 
     def _decode_loop(self):
+        # every stretch of an iteration runs inside a tracing.phase, so a
+        # profile names what the host did in each device idle gap and the
+        # step span's ``phases`` attribute accounts for the whole period
         while True:
             # named fault point OUTSIDE the lock: a "delay" spec slows
             # every decode iteration (slow-replica chaos — keeps
             # sessions alive across a drain/kill window in CI) without
             # holding submitters on the cond during the sleep
-            maybe_fail("serving.decode_step")
-            with self._cond:
+            with _tr.phase("serving.between_steps"):
+                maybe_fail("serving.decode_step")
+            with _tr.phase("serving.lock_wait") as lock_wait, self._cond:
+                lock_wait.stop()
                 if not self._running:
                     return
-                self._expire_and_admit()
+                with _tr.phase("serving.admit"):
+                    self._expire_and_admit()
                 if not self._active:
-                    self._cond.wait(0.05)
+                    with _tr.phase("serving.idle"):
+                        self._cond.wait(0.05)
+                    # waiting for work is not the host keeping the
+                    # device waiting: gap_us counts from here
+                    self._t_fetched = time.perf_counter()
                     continue
                 step_ok = self._decode_step_locked()
                 preempted, self._preempted = self._preempted, []
-            if preempted and self.on_preempt is not None:
-                # pressure-trigger migration hook (CC105: fired with the
-                # lock released; the victims are already back in the
-                # waiting queue with their emitted tokens intact)
-                try:
-                    self.on_preempt(preempted)
-                except Exception:
-                    pass
-            if self.on_batch_boundary is not None:
-                try:
-                    self.on_batch_boundary()
-                except Exception:
-                    pass
-            if not step_ok:
-                time.sleep(0.001)
+            with _tr.phase("serving.between_steps"):
+                if preempted and self.on_preempt is not None:
+                    # pressure-trigger migration hook (CC105: fired with
+                    # the lock released; the victims are already back in
+                    # the waiting queue with their emitted tokens intact)
+                    try:
+                        self.on_preempt(preempted)
+                    except Exception:
+                        pass
+                if self.on_batch_boundary is not None:
+                    try:
+                        self.on_batch_boundary()
+                    except Exception:
+                        pass
+                if not step_ok:
+                    time.sleep(0.001)
+
+    def _open_step_span(self, m, bucket, lanes, **attrs):
+        """The iteration's ``serving.decode_step`` span, linked to the
+        requests it serves.  A flight-recorder breadcrumb names them when
+        the lane set differs from the last one written: a step with the
+        lanes of the step before writes nothing to disk."""
+        self._step_no += 1
+        sspan = _tr.start_span(
+            "serving.decode_step", model=m.name, bucket=bucket,
+            lanes=len(lanes), step=self._step_no, **attrs)
+        for s in lanes:
+            sspan.link(s.pending.span.context
+                       if s.pending.span is not None else None)
+        if _tr.enabled():
+            req_ids = [s.pending.req_id for s in lanes]
+            if req_ids != self._noted_lanes:
+                self._noted_lanes = req_ids
+                _tr.note("decode_step", model=m.name, step=self._step_no,
+                         req_ids=req_ids)
+        return sspan
+
+    def _dispatch_gap_us(self):
+        """Host time since the device handed the last step's tokens back
+        (``serving.fetch`` ended), read as this step's dispatch starts:
+        the device has nothing queued meanwhile."""
+        if self._t_fetched is None:
+            return 0
+        return int((time.perf_counter() - self._t_fetched) * 1e6)
+
+    def _close_step_span(self, sspan, **attrs):
+        waits, self._admit_waits = self._admit_waits, []
+        sspan.annotate(admitted=len(waits),
+                       admit_wait_ms=[w for w, _ in waits],
+                       admit_lock_wait_ms=[w for _, w in waits], **attrs)
+        sspan.take_phases("serving.")
+        sspan.end()
 
     def _decode_step_locked(self):
         """One token for every active lane (call with self._cond held).
@@ -2148,117 +2210,116 @@ class DecodeEngine:
         continuous-batching contract.  submit()/abort() block for at
         most one step (milliseconds at serving batch sizes), and in
         exchange the active set and block tables need no second lock."""
-        m = self._model_of(self._active[0])
-        # drop client-aborted + deadline-expired actives first, freeing
-        # their blocks before this step's allocations
-        now = time.perf_counter()
-        for s in list(self._active):
-            if s.aborted:
-                self._active.remove(s)
-                self._free_blocks(s)
-                self._finish(s, InferReply("aborted",
-                                           error="aborted by client"))
-            elif now > s.pending.deadline:
-                self._active.remove(s)
-                self._free_blocks(s)
-                _tm.inc("serving_timeout_total", model=s.pending.model)
-                self._finish(s, InferReply(
-                    "timeout", error="deadline expired mid-decode"))
-        # complete prefill-role sequences whose boundary was reached (by
-        # the previous step, or at admission via a warm prefix match)
-        self._sweep_handoff_locked()
-        if not self._active:
-            return True
+        with _tr.phase("serving.plan"):
+            m = self._model_of(self._active[0])
+            # drop client-aborted + deadline-expired actives first, freeing
+            # their blocks before this step's allocations
+            now = time.perf_counter()
+            for s in list(self._active):
+                if s.aborted:
+                    self._active.remove(s)
+                    self._free_blocks(s)
+                    self._finish(s, InferReply("aborted",
+                                               error="aborted by client"))
+                elif now > s.pending.deadline:
+                    self._active.remove(s)
+                    self._free_blocks(s)
+                    _tm.inc("serving_timeout_total", model=s.pending.model)
+                    self._finish(s, InferReply(
+                        "timeout", error="deadline expired mid-decode"))
+            # complete prefill-role sequences whose boundary was reached
+            # (by the previous step, or at admission via a warm prefix
+            # match)
+            self._sweep_handoff_locked()
+            if not self._active:
+                return True
         if m.spec_k > 0:
             return self._spec_step_locked(m)
-        # token-budget prefill scheduling: decode lanes always run;
-        # prefilling lanes beyond the budget sit this iteration out
-        participants, _caps = self._plan_lanes_locked(1)
-        for s in participants:
-            if s in self._active and not self._ensure_block(s):
-                pass  # defensively completed inside _ensure_block
-        lanes = [s for s in participants if s in self._active]
-        if not lanes:
-            return True
-        bucket = self._bucket_for(len(lanes))
-        tok = np.zeros(bucket, np.int32)
-        pos = np.zeros(bucket, np.int32)
-        tables = np.full((bucket, m.maxb), -1, np.int32)
-        lens = np.zeros(bucket, np.int32)
-        for i, s in enumerate(lanes):
-            tok[i] = s.next_tok
-            pos[i] = s.n_fed
-            tables[i] = s.table
-            lens[i] = s.n_fed + 1    # token valid AFTER this step's write
-        self._step_no += 1
-        sspan = _tr.start_span(
-            "serving.decode_step", model=m.name, bucket=bucket,
-            lanes=len(lanes), step=self._step_no)
-        for s in lanes:
-            sspan.link(s.pending.span.context
-                       if s.pending.span is not None else None)
-        _tr.note("decode_step", model=m.name, step=self._step_no,
-                 req_ids=[s.pending.req_id for s in lanes])
+        with _tr.phase("serving.plan"):
+            # token-budget prefill scheduling: decode lanes always run;
+            # prefilling lanes beyond the budget sit this iteration out
+            participants, _caps = self._plan_lanes_locked(1)
+            for s in participants:
+                if s in self._active and not self._ensure_block(s):
+                    pass  # defensively completed inside _ensure_block
+            lanes = [s for s in participants if s in self._active]
+            if not lanes:
+                return True
+            bucket = self._bucket_for(len(lanes))
+            tok = np.zeros(bucket, np.int32)
+            pos = np.zeros(bucket, np.int32)
+            tables = np.full((bucket, m.maxb), -1, np.int32)
+            lens = np.zeros(bucket, np.int32)
+            for i, s in enumerate(lanes):
+                tok[i] = s.next_tok
+                pos[i] = s.n_fed
+                tables[i] = s.table
+                lens[i] = s.n_fed + 1  # token valid AFTER this step's write
+            sspan = self._open_step_span(m, bucket, lanes)
+            args = self._step_args(m, bucket, tok, pos, tables, lens)
         self.in_batch = True
         t0 = time.perf_counter()
+        gap_us = self._dispatch_gap_us()
         try:
-            with _tr.activate(sspan):
+            with _tr.activate(sspan), _tr.phase("serving.dispatch"):
                 # threadlint: waive CC102 continuous-batching contract: the device step runs under _cond so lane state is frozen for the whole step (see _decode_step_locked docstring); submitters park on the cond, never spin
-                carry, nxt, _logits = m.stepfn(
-                    *self._step_args(m, bucket, tok, pos, tables, lens))
-            m.cache.replace_carry(carry)
-            nxt = np.asarray(nxt)
+                carry, nxt, _logits = m.stepfn(*args)
+            with _tr.phase("serving.fetch"):
+                m.cache.replace_carry(carry)
+                nxt = np.asarray(nxt)
         except Exception as e:
             for s in lanes:
                 self._active.remove(s)
                 self._free_blocks(s)
                 self._finish(s, InferReply("error", error=str(e)))
             _tm.inc("serving_batch_errors_total", model=m.name)
-            sspan.annotate(error=str(e)[:200]).end()
+            self._close_step_span(sspan, gap_us=gap_us,
+                                  error=str(e)[:200])
             self.in_batch = False
             return False
         self.in_batch = False
-        ms = (time.perf_counter() - t0) * 1e3
+        self._t_fetched = t_tok = time.perf_counter()
+        ms = (t_tok - t0) * 1e3
         m.step_ms = ms if m.step_ms <= 0 else 0.8 * m.step_ms + 0.2 * ms
-        t_tok = time.perf_counter()
         n_generated = 0
-        for i, s in enumerate(lanes):
-            s.n_fed += 1
-            # seal + publish any prompt block this write completed (the
-            # boundary-crossing write completes the final full block),
-            # then any completed history block (session migration)
-            self._publish_prefix_locked(m, s)
-            self._publish_history_locked(m, s)
-            if s.in_prefill:
-                s.next_tok = s.feed_tok(s.n_fed)
-                continue
-            token = int(nxt[i])
-            s.next_tok = token
-            s.out.append(token)
-            s.token_times.append(t_tok)
-            if s.t_first is None:
-                s.t_first = t_tok
-            n_generated += 1
-            done = (len(s.out) >= s.max_new or token == s.eos_id)
-            if s.on_token is not None:
-                try:
-                    s.on_token(s.pending.req_id, len(s.out) - 1, token,
-                               done, "ok")
-                except Exception:
-                    pass
-            if done:
-                self._active.remove(s)
-                self._free_blocks(s)   # same-step free: next admission
-                self._finish(s, InferReply("ok"))
-                _tm.observe("serving_latency_ms",
-                            s.pending.reply.latency_ms, model=m.name)
-        if n_generated:
-            _tm.inc("serving_tokens_generated_total", n_generated,
-                    model=m.name)
-        _tm.inc("serving_decode_steps_total", model=m.name)
-        _tm.observe("decode_batch_occupancy",
-                    len(lanes) / float(bucket), model=m.name)
-        sspan.annotate(generated=n_generated, ms=round(ms, 3)).end()
+        with _tr.phase("serving.emit"):
+            for i, s in enumerate(lanes):
+                s.n_fed += 1
+                # seal + publish any prompt block this write completed
+                # (the boundary-crossing write completes the final full
+                # block), then any completed history block (session
+                # migration)
+                self._publish_prefix_locked(m, s)
+                self._publish_history_locked(m, s)
+                if s.in_prefill:
+                    s.next_tok = s.feed_tok(s.n_fed)
+                    continue
+                token = int(nxt[i])
+                s.next_tok = token
+                s.out.append(token)
+                s.token_times.append(t_tok)
+                if s.t_first is None:
+                    s.t_first = t_tok
+                n_generated += 1
+                done = (len(s.out) >= s.max_new or token == s.eos_id)
+                if s.on_token is not None:
+                    try:
+                        s.on_token(s.pending.req_id, len(s.out) - 1, token,
+                                   done, "ok")
+                    except Exception:
+                        pass
+                if done:
+                    self._active.remove(s)
+                    self._free_blocks(s)   # same-step free: next admission
+                    self._finish(s, InferReply("ok"))
+            if n_generated:
+                _tm.inc("serving_tokens_generated_total", n_generated,
+                        model=m.name)
+            _tm.inc("serving_decode_steps_total", model=m.name)
+            _tm.observe("decode_batch_occupancy",
+                        len(lanes) / float(bucket), model=m.name)
+        self._close_step_span(sspan, generated=n_generated,
+                              ms=round(ms, 3), gap_us=gap_us)
         return True
 
     def _spec_step_locked(self, m):
@@ -2281,237 +2342,240 @@ class DecodeEngine:
         into attended history."""
         k = m.spec_k
         width = k + 1
-        # token-budget prefill scheduling: caps[id(s)] trims a prefill
-        # lane's chunk span when the budget runs low this iteration
-        participants, caps = self._plan_lanes_locked(width)
-        plans = {}
-        for s in participants:
-            if s not in self._active:
-                continue   # preempted by an earlier lane's allocation
-            p = s.n_fed
-            if s.in_prefill:
-                span = caps.get(id(s),
-                                min(width, self._prefill_limit(s) - p))
-                spec = False
-                # the prompt chunk mirrors into the draft TAIL-ONLY: with
-                # a cached prefix p starts past it, so draft positions
-                # below p stay zero — that can only lower acceptance,
-                # never correctness (verify guards every emitted token)
-                draft_upto = p + span
-            else:
-                span = min(width, s.max_new - len(s.out))
-                spec = span > 1         # last token needs no proposals
-                # rollout writes up to p+k-1 (position-clamped to the
-                # sequence end); a full accept ingests d_k at p+k
-                draft_upto = min(p + k + 1, s.total) if spec else 0
-            if not self._ensure_capacity(s, p + span, draft_upto):
-                continue   # defensively completed
-            plans[id(s)] = (span, spec)
-        lanes = [s for s in participants
-                 if s in self._active and id(s) in plans]
-        if not lanes:
-            return True
-        bucket = self._bucket_for(len(lanes))
-        tok = np.zeros((bucket, width), np.int32)
-        pos = np.zeros((bucket, width), np.int32)
-        lens = np.zeros((bucket, width), np.int32)
-        tables = np.full((bucket, m.maxb), -1, np.int32)
-        rtok = np.zeros(bucket, np.int32)
-        rpos = np.zeros(bucket, np.int32)
-        rlens = np.zeros(bucket, np.int32)
-        rmax = np.zeros(bucket, np.int32)
-        rtables = np.full((bucket, m.maxb), -1, np.int32)
-        n_spec = 0
-        for i, s in enumerate(lanes):
-            span, spec = plans[id(s)]
-            p = s.n_fed
-            pad = width - span
-            tables[i] = s.table
-            pos[i, :pad] = p
-            feed = s.feed_slice(p, span) if s.in_prefill else [s.next_tok]
-            for j in range(span):
-                pos[i, pad + j] = p + j
-                lens[i, pad + j] = p + j + 1
-            for j, t in enumerate(feed):
-                tok[i, pad + j] = t
-            if spec:
-                n_spec += 1
-                rtok[i] = s.next_tok
-                rpos[i] = p
-                rlens[i] = p + 1
-                rmax[i] = s.total - 1
-                rtables[i] = s.draft_table
-        self._step_no += 1
-        sspan = _tr.start_span(
-            "serving.decode_step", model=m.name, bucket=bucket,
-            lanes=len(lanes), step=self._step_no, speculative=True, k=k)
-        for s in lanes:
-            sspan.link(s.pending.span.context
-                       if s.pending.span is not None else None)
-        req_ids = [s.pending.req_id for s in lanes]
+        with _tr.phase("serving.plan"):
+            # token-budget prefill scheduling: caps[id(s)] trims a prefill
+            # lane's chunk span when the budget runs low this iteration
+            participants, caps = self._plan_lanes_locked(width)
+            plans = {}
+            for s in participants:
+                if s not in self._active:
+                    continue   # preempted by an earlier lane's allocation
+                p = s.n_fed
+                if s.in_prefill:
+                    span = caps.get(id(s),
+                                    min(width, self._prefill_limit(s) - p))
+                    spec = False
+                    # the prompt chunk mirrors into the draft TAIL-ONLY:
+                    # with a cached prefix p starts past it, so draft
+                    # positions below p stay zero — that can only lower
+                    # acceptance, never correctness (verify guards every
+                    # emitted token)
+                    draft_upto = p + span
+                else:
+                    span = min(width, s.max_new - len(s.out))
+                    spec = span > 1         # last token needs no proposals
+                    # rollout writes up to p+k-1 (position-clamped to the
+                    # sequence end); a full accept ingests d_k at p+k
+                    draft_upto = min(p + k + 1, s.total) if spec else 0
+                if not self._ensure_capacity(s, p + span, draft_upto):
+                    continue   # defensively completed
+                plans[id(s)] = (span, spec)
+            lanes = [s for s in participants
+                     if s in self._active and id(s) in plans]
+            if not lanes:
+                return True
+            bucket = self._bucket_for(len(lanes))
+            tok = np.zeros((bucket, width), np.int32)
+            pos = np.zeros((bucket, width), np.int32)
+            lens = np.zeros((bucket, width), np.int32)
+            tables = np.full((bucket, m.maxb), -1, np.int32)
+            rtok = np.zeros(bucket, np.int32)
+            rpos = np.zeros(bucket, np.int32)
+            rlens = np.zeros(bucket, np.int32)
+            rmax = np.zeros(bucket, np.int32)
+            rtables = np.full((bucket, m.maxb), -1, np.int32)
+            n_spec = 0
+            for i, s in enumerate(lanes):
+                span, spec = plans[id(s)]
+                p = s.n_fed
+                pad = width - span
+                tables[i] = s.table
+                pos[i, :pad] = p
+                feed = s.feed_slice(p, span) if s.in_prefill \
+                    else [s.next_tok]
+                for j in range(span):
+                    pos[i, pad + j] = p + j
+                    lens[i, pad + j] = p + j + 1
+                for j, t in enumerate(feed):
+                    tok[i, pad + j] = t
+                if spec:
+                    n_spec += 1
+                    rtok[i] = s.next_tok
+                    rpos[i] = p
+                    rlens[i] = p + 1
+                    rmax[i] = s.total - 1
+                    rtables[i] = s.draft_table
+            sspan = self._open_step_span(m, bucket, lanes,
+                                         speculative=True, k=k)
         self.in_batch = True
         t0 = time.perf_counter()
+        gap_us = self._dispatch_gap_us()
         props = None
         try:
             with _tr.activate(sspan):
                 if n_spec:
-                    _tr.note("decode_step", model=m.name,
-                             step=self._step_no, phase="draft",
-                             req_ids=req_ids)
-                    with _tr.span("serving.draft", lanes=n_spec, k=k):
+                    with _tr.span("serving.draft", lanes=n_spec, k=k), \
+                            _tr.phase("serving.dispatch"):
                         # threadlint: waive CC102 draft rollout runs under _cond by the same frozen-lane contract as stepfn in _decode_step_locked
                         dcarry, props = m.rolloutfn(
                             m.draft_cache.carry(), m.draft_params,
                             rtok, rpos, rtables, rlens, rmax)
-                    m.draft_cache.replace_carry(dcarry)
-                    props = np.asarray(props)
-                    for i, s in enumerate(lanes):
-                        span, spec = plans[id(s)]
-                        if spec:
-                            for j in range(span - 1):
-                                tok[i, width - span + 1 + j] = props[i, j]
-                _tr.note("decode_step", model=m.name, step=self._step_no,
-                         phase="verify", req_ids=req_ids)
+                    with _tr.phase("serving.fetch"):
+                        m.draft_cache.replace_carry(dcarry)
+                        props = np.asarray(props)
+                    with _tr.phase("serving.plan"):
+                        for i, s in enumerate(lanes):
+                            span, spec = plans[id(s)]
+                            if spec:
+                                for j in range(span - 1):
+                                    tok[i, width - span + 1 + j] = \
+                                        props[i, j]
                 with _tr.span("serving.verify", lanes=len(lanes),
-                              width=width):
+                              width=width), _tr.phase("serving.dispatch"):
                     # threadlint: waive CC102 target-model verify runs under _cond by the same frozen-lane contract as stepfn in _decode_step_locked
                     carry, nxt, _logits = m.verifyfn(
                         m.cache.carry(), m.params, tok, pos, tables, lens)
-                m.cache.replace_carry(carry)
-                nxt = np.asarray(nxt)
+                with _tr.phase("serving.fetch"):
+                    m.cache.replace_carry(carry)
+                    nxt = np.asarray(nxt)
         except Exception as e:
             for s in lanes:
                 self._active.remove(s)
                 self._free_blocks(s)
                 self._finish(s, InferReply("error", error=str(e)))
             _tm.inc("serving_batch_errors_total", model=m.name)
-            sspan.annotate(error=str(e)[:200]).end()
+            self._close_step_span(sspan, gap_us=gap_us,
+                                  error=str(e)[:200])
             self.in_batch = False
             return False
         self.in_batch = False
-        ms = (time.perf_counter() - t0) * 1e3
+        self._t_fetched = t_tok = time.perf_counter()
+        ms = (t_tok - t0) * 1e3
         m.step_ms = ms if m.step_ms <= 0 else 0.8 * m.step_ms + 0.2 * ms
-        t_tok = time.perf_counter()
         n_generated = 0
         k_proposed = 0
         k_accepted = 0
         ingest = []    # (seq, start_pos, tokens) draft catch-up writes
-        for i, s in enumerate(lanes):
-            span, spec = plans[id(s)]
-            p = s.n_fed
-            pad = width - span
-            accepted = 0
-            if s.in_prefill:
-                s.n_fed += span
-                self._publish_prefix_locked(m, s)
-                self._publish_history_locked(m, s)
-                ingest.append((s, p, s.feed_slice(p, span)))
+        with _tr.phase("serving.emit"):
+            for i, s in enumerate(lanes):
+                span, spec = plans[id(s)]
+                p = s.n_fed
+                pad = width - span
+                accepted = 0
                 if s.in_prefill:
-                    s.next_tok = s.feed_tok(s.n_fed)
-                    continue
-                # chunk crossed the prompt boundary: its last column's
-                # argmax is the first generated token
-                emitted = [int(nxt[i, pad + span - 1])]
-            else:
-                # accept-longest-prefix: column j's argmax continues the
-                # chain only while proposal j matched the previous argmax
-                emitted = [int(nxt[i, pad])]
-                while accepted < span - 1 and \
-                        int(props[i, accepted]) == emitted[-1]:
-                    emitted.append(int(nxt[i, pad + accepted + 1]))
-                    accepted += 1
-                if spec:
-                    k_proposed += span - 1
-                    k_accepted += accepted
-                    _tm.observe("spec_acceptance",
-                                accepted / float(span - 1), model=m.name)
-                s.n_fed += len(emitted)
-            done = False
-            for t in emitted:
-                s.out.append(t)
-                s.token_times.append(t_tok)
-                if s.t_first is None:
-                    s.t_first = t_tok
-                n_generated += 1
-                done = (len(s.out) >= s.max_new or t == s.eos_id)
-                if s.on_token is not None:
-                    try:
-                        s.on_token(s.pending.req_id, len(s.out) - 1, t,
-                                   done, "ok")
-                    except Exception:
-                        pass
+                    s.n_fed += span
+                    self._publish_prefix_locked(m, s)
+                    self._publish_history_locked(m, s)
+                    ingest.append((s, p, s.feed_slice(p, span)))
+                    if s.in_prefill:
+                        s.next_tok = s.feed_tok(s.n_fed)
+                        continue
+                    # chunk crossed the prompt boundary: its last column's
+                    # argmax is the first generated token
+                    emitted = [int(nxt[i, pad + span - 1])]
+                else:
+                    # accept-longest-prefix: column j's argmax continues
+                    # the chain only while proposal j matched the previous
+                    # argmax
+                    emitted = [int(nxt[i, pad])]
+                    while accepted < span - 1 and \
+                            int(props[i, accepted]) == emitted[-1]:
+                        emitted.append(int(nxt[i, pad + accepted + 1]))
+                        accepted += 1
+                    if spec:
+                        k_proposed += span - 1
+                        k_accepted += accepted
+                        _tm.observe("spec_acceptance",
+                                    accepted / float(span - 1),
+                                    model=m.name)
+                    s.n_fed += len(emitted)
+                done = False
+                for t in emitted:
+                    s.out.append(t)
+                    s.token_times.append(t_tok)
+                    if s.t_first is None:
+                        s.t_first = t_tok
+                    n_generated += 1
+                    done = (len(s.out) >= s.max_new or t == s.eos_id)
+                    if s.on_token is not None:
+                        try:
+                            s.on_token(s.pending.req_id, len(s.out) - 1, t,
+                                       done, "ok")
+                        except Exception:
+                            pass
+                    if done:
+                        break
+                # history publication must follow the appends: a
+                # multi-token accept advances n_fed past tokens that only
+                # exist in ``emitted`` until this point, and the chain
+                # digest replays them from prompt ++ out
+                self._publish_history_locked(m, s)
                 if done:
-                    break
-            # history publication must follow the appends: a multi-token
-            # accept advances n_fed past tokens that only exist in
-            # ``emitted`` until this point, and the chain digest replays
-            # them from prompt ++ out
-            self._publish_history_locked(m, s)
-            if done:
-                self._active.remove(s)
-                self._free_blocks(s)   # same-step free, both pools
-                self._finish(s, InferReply("ok"))
-                _tm.observe("serving_latency_ms",
-                            s.pending.reply.latency_ms, model=m.name)
-                continue
-            s.next_tok = emitted[-1]
-            if accepted == k:
-                # full accept: the rollout never wrote position p+k; its
-                # token is d_k (== the target's g_k), caught up below
-                ingest.append((s, p + k, [int(props[i, k - 1])]))
-        # free rollback: every block past the accepted frontier returns
-        # to its pool in the SAME iteration (context_lens truncation next
-        # step masks the stale writes)
-        rolled = 0
-        for s in lanes:
-            if s not in self._active:
-                continue
-            rolled += m.cache.trim_table(s.table, s.blocks, s.n_fed)
-            rolled += m.draft_cache.trim_table(
-                s.draft_table, s.draft_blocks, s.n_fed)
-        if rolled:
-            _tm.inc("spec_blocks_rolled_back_total", rolled, model=m.name)
+                    self._active.remove(s)
+                    self._free_blocks(s)   # same-step free, both pools
+                    self._finish(s, InferReply("ok"))
+                    continue
+                s.next_tok = emitted[-1]
+                if accepted == k:
+                    # full accept: the rollout never wrote position p+k;
+                    # its token is d_k (== the target's g_k), caught up
+                    # below
+                    ingest.append((s, p + k, [int(props[i, k - 1])]))
+            # free rollback: every block past the accepted frontier
+            # returns to its pool in the SAME iteration (context_lens
+            # truncation next step masks the stale writes)
+            rolled = 0
+            for s in lanes:
+                if s not in self._active:
+                    continue
+                rolled += m.cache.trim_table(s.table, s.blocks, s.n_fed)
+                rolled += m.draft_cache.trim_table(
+                    s.draft_table, s.draft_blocks, s.n_fed)
+            if rolled:
+                _tm.inc("spec_blocks_rolled_back_total", rolled,
+                        model=m.name)
         ingest = [(s, q, t) for (s, q, t) in ingest if s in self._active]
         if ingest:
-            itok = np.zeros((bucket, width), np.int32)
-            ipos = np.zeros((bucket, width), np.int32)
-            ilens = np.zeros((bucket, width), np.int32)
-            itables = np.full((bucket, m.maxb), -1, np.int32)
-            for r, (s, q, toks) in enumerate(ingest):
-                ipad = width - len(toks)
-                itables[r] = s.draft_table
-                ipos[r, :ipad] = q
-                for j, t in enumerate(toks):
-                    ipos[r, ipad + j] = q + j
-                    ilens[r, ipad + j] = q + j + 1
-                    itok[r, ipad + j] = t
+            with _tr.phase("serving.plan"):
+                itok = np.zeros((bucket, width), np.int32)
+                ipos = np.zeros((bucket, width), np.int32)
+                ilens = np.zeros((bucket, width), np.int32)
+                itables = np.full((bucket, m.maxb), -1, np.int32)
+                for r, (s, q, toks) in enumerate(ingest):
+                    ipad = width - len(toks)
+                    itables[r] = s.draft_table
+                    ipos[r, :ipad] = q
+                    for j, t in enumerate(toks):
+                        ipos[r, ipad + j] = q + j
+                        ilens[r, ipad + j] = q + j + 1
+                        itok[r, ipad + j] = t
             try:
-                with _tr.activate(sspan):
-                    _tr.note("decode_step", model=m.name,
-                             step=self._step_no, phase="draft",
-                             ingest=len(ingest))
-                    with _tr.span("serving.draft_ingest",
-                                  lanes=len(ingest)):
-                        # threadlint: waive CC102 draft-cache ingest runs under _cond by the same frozen-lane contract as stepfn in _decode_step_locked
-                        dcarry, _nx, _lg = m.ingestfn(
-                            m.draft_cache.carry(), m.draft_params,
-                            itok, ipos, itables, ilens)
+                with _tr.activate(sspan), \
+                        _tr.span("serving.draft_ingest",
+                                 lanes=len(ingest)), \
+                        _tr.phase("serving.dispatch"):
+                    # threadlint: waive CC102 draft-cache ingest runs under _cond by the same frozen-lane contract as stepfn in _decode_step_locked
+                    dcarry, _nx, _lg = m.ingestfn(
+                        m.draft_cache.carry(), m.draft_params,
+                        itok, ipos, itables, ilens)
                 m.draft_cache.replace_carry(dcarry)
             except Exception:
                 # a stale draft cache only costs acceptance, never
                 # correctness — the verify step guards every token
                 _tm.inc("spec_ingest_errors_total", model=m.name)
-        if n_spec:
-            _tm.inc("spec_tokens_proposed_total", k_proposed,
-                    model=m.name)
-            _tm.inc("spec_tokens_accepted_total", k_accepted,
-                    model=m.name)
-        if n_generated:
-            _tm.inc("serving_tokens_generated_total", n_generated,
-                    model=m.name)
-        _tm.inc("serving_decode_steps_total", model=m.name)
-        _tm.observe("decode_batch_occupancy",
-                    len(lanes) / float(bucket), model=m.name)
-        sspan.annotate(generated=n_generated, ms=round(ms, 3),
-                       k_proposed=k_proposed, k_accepted=k_accepted).end()
+        with _tr.phase("serving.emit"):
+            if n_spec:
+                _tm.inc("spec_tokens_proposed_total", k_proposed,
+                        model=m.name)
+                _tm.inc("spec_tokens_accepted_total", k_accepted,
+                        model=m.name)
+            if n_generated:
+                _tm.inc("serving_tokens_generated_total", n_generated,
+                        model=m.name)
+            _tm.inc("serving_decode_steps_total", model=m.name)
+            _tm.observe("decode_batch_occupancy",
+                        len(lanes) / float(bucket), model=m.name)
+        self._close_step_span(sspan, generated=n_generated, ms=round(ms, 3),
+                              gap_us=gap_us, k_proposed=k_proposed,
+                              k_accepted=k_accepted)
         return True

@@ -8,6 +8,7 @@ import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu import metrics, profiler
+from paddle_tpu.core import tracing
 
 
 def _tiny_program():
@@ -26,15 +27,28 @@ def test_profiler_records_and_exports(tmp_path):
     with fluid.scope_guard(fluid.Scope()):
         exe.run(startup)
         profiler.reset_profiler()
-        with profiler.profiler("All", "total", path):
-            for _ in range(3):
-                exe.run(main, feed={"x": np.ones((2, 4), "f")},
-                        fetch_list=[loss])
+        fluid.set_flags({"FLAGS_tracing": True})
+        tracing.reset()
+        try:
+            with profiler.profiler("All", "total", path):
+                for _ in range(3):
+                    with profiler.RecordEvent("train::step"):
+                        exe.run(main, feed={"x": np.ones((2, 4), "f")},
+                                fetch_list=[loss])
+        finally:
+            fluid.set_flags({"FLAGS_tracing": False})
     with open(path) as f:
         trace = json.load(f)
-    runs = [e for e in trace["traceEvents"] if e["name"] == "Executor::Run"]
+    runs = [e for e in trace["traceEvents"] if e["name"] == "train::step"]
     assert len(runs) == 3
     assert all(e["dur"] >= 0 for e in runs)
+    # the executor's own record of a run is its executor.step span (the
+    # tracing stream), no longer a RecordEvent of its own
+    assert not [e for e in trace["traceEvents"]
+                if e["name"] == "Executor::Run"]
+    steps = tracing.records("executor.step")
+    tracing.reset()
+    assert len(steps) == 3 and all(s["dur"] >= 0 for s in steps)
     # disabled afterwards: no new events
     n = len(trace["traceEvents"])
     with fluid.scope_guard(fluid.Scope()):

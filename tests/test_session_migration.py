@@ -253,10 +253,15 @@ def test_double_migration_loudly_refused(cache_dir, telemetry_on):
             == 1
         # a duplicate resume for a LIVE req_id is refused at admission
         live, _, _ = _export_live(eng, PROMPT, 24)
-        assert eng.abort_migration(live.req_id)   # back in the scheduler
-        dup = eng.generate("toy", PROMPT, max_new_tokens=24,
-                           deadline_ms=30000.0, req_id=live.req_id,
-                           resume_from=[5, 6])
+        # under the engine condition (an RLock: abort and submit re-enter)
+        # the decode loop cannot finish the re-queued session before the
+        # duplicate arrives: a submitter otherwise waits several of the
+        # toy's sub-millisecond steps for the lock
+        with eng._cond:
+            assert eng.abort_migration(live.req_id)   # back in the scheduler
+            dup = eng.submit("toy", PROMPT, max_new_tokens=24,
+                             deadline_ms=30000.0, req_id=live.req_id,
+                             resume_from=[5, 6]).wait(30.0)
         assert dup.status == "error" and "double migration" in dup.error
         assert _ctr("kv_migrate_refused_total", reason="duplicate") == 1
         assert live.wait(60.0).status == "ok"

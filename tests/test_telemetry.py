@@ -114,6 +114,8 @@ def test_executor_step_instrumentation(tmp_path):
     assert snap["histograms"]["executor_step_ms"]["count"] == 3
     assert snap["histograms"]["executor_compile_ms"]["count"] == 1
     # JSONL step-event stream: one line per step, hit flags in order
+    # (events are buffered; flush() puts them in the file)
+    telemetry.flush()
     with open(os.path.join(d, "steps.jsonl")) as f:
         events = [json.loads(line) for line in f]
     assert [e["ev"] for e in events] == ["step"] * 3

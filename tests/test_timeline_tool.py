@@ -35,22 +35,26 @@ def test_from_profiler_cli_round_trip(tmp_path):
     with fluid.scope_guard(fluid.Scope()):
         exe.run(startup)
         profiler.reset_profiler()
+        # the caller's loop marks its own events and step edges (the
+        # executor records its runs as executor.step spans in the tracing
+        # stream, tests/test_tracing_hot_path.py)
         with profiler.profiler("All", "total", prof_path):
-            for _ in range(2):
-                exe.run(main, feed={"x": np.ones((2, 4), "f")},
-                        fetch_list=[loss])
+            for step in range(2):
+                with profiler.RecordEvent("train::step"):
+                    exe.run(main, feed={"x": np.ones((2, 4), "f")},
+                            fetch_list=[loss])
+                profiler.mark_instant("step", args={"step": step})
     rc = timeline.main(["--profile_path", prof_path,
                         "--timeline_path", out_path])
     assert rc == 0
     with open(out_path) as f:
         trace = json.load(f)
     evs = trace["traceEvents"]
-    runs = [e for e in evs if e["name"] == "Executor::Run"]
+    runs = [e for e in evs if e["name"] == "train::step"]
     assert len(runs) == 2
-    # the executor marks each step as a ph:"i" instant while profiling
+    # each step edge is a ph:"i" instant
     insts = [e for e in evs if e.get("ph") == "i"]
     assert [e["name"] for e in insts] == ["step", "step"]
-    # the executor's step counter is cumulative, so only ordering is fixed
     s0, s1 = (e["args"]["step"] for e in insts)
     assert s1 == s0 + 1
     assert all(e["s"] == "g" for e in insts)

@@ -44,6 +44,7 @@ def _tracing_on(tmp_path):
 
 
 def _read_trace(d):
+    tr.flush()   # records are buffered until then
     path = os.path.join(d, "trace-%d.jsonl" % os.getpid())
     with open(path) as f:
         return [json.loads(line) for line in f if line.strip()]
@@ -310,6 +311,7 @@ def test_trace_jsonl_rotation(tmp_path):
     fluid.set_flags({"FLAGS_telemetry_max_bytes": 4096})
     for i in range(200):
         tr.instant("filler", i=i, pad="x" * 64)
+    tr.flush()
     path = os.path.join(d, "trace-%d.jsonl" % os.getpid())
     assert os.path.exists(path) and os.path.exists(path + ".1")
     assert os.path.getsize(path) <= 4096
@@ -327,6 +329,7 @@ def test_telemetry_events_rotation(tmp_path):
                      "FLAGS_telemetry_max_bytes": 2048})
     for i in range(200):
         _tm.event("soak", i=i, pad="y" * 32)
+    _tm.flush()
     path = os.path.join(d, "steps.jsonl")
     assert os.path.exists(path + ".1"), "steps.jsonl never rotated"
     assert os.path.getsize(path) <= 2048

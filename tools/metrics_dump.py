@@ -173,7 +173,8 @@ def main(argv=None):
                     "kv_pool_occupancy / prefix_cache_hit_rate gauges")
     ap.add_argument("--tracing", action="store_true", dest="tracing_only",
                     help="show only distributed-tracing health metrics: "
-                    "tracing_records_total{kind} and "
+                    "tracing_records_total{kind}, "
+                    "tracing_dropped_total and "
                     "tracing_flightrec_dumps_total{reason} "
                     "(core/tracing.py)")
     ap.add_argument("--checkpoint", action="store_true", dest="ckpt_only",
