@@ -293,6 +293,17 @@ def aot_compile_cached(jfn, args, disk_key, devices, meta=None):
     return compiled, cstats
 
 
+def _step_memory(compiled):
+    """{"temp_bytes", "alias_bytes"} of an AOT executable, None each where
+    there is no executable (the lazy-jit fallback) or it does not say."""
+    try:
+        stats = compiled.memory_analysis()
+        return {"temp_bytes": int(stats.temp_size_in_bytes),
+                "alias_bytes": int(stats.alias_size_in_bytes)}
+    except Exception:
+        return {"temp_bytes": None, "alias_bytes": None}
+
+
 class CarriedStepFn:
     """AOT-compiled step function with a persistent donated carry — the
     decode-serving analog of the Program path's bf16 param-carry: the
@@ -304,7 +315,11 @@ class CarriedStepFn:
     the lowering besides the argument signature (model fingerprint, cache
     geometry, trace flags) — it feeds ``compile_cache.raw_artifact_key``.
     ``warmup()`` compiles eagerly for one signature (the serving
-    prewarm); a ``__call__`` on a signature never warmed compiles on the
+    prewarm) and says what the executable needs beside its arguments
+    (``temp_bytes``) and how much of them it updates in their own buffers
+    (``alias_bytes``: a carry written in place is aliased whole, and its
+    temporaries stay far under it); a ``__call__`` on a signature never
+    warmed compiles on the
     spot and counts ``executor_cache_miss_total``, so "zero runtime
     compiles under decode load" stays provable from the same counter the
     Program path uses."""
@@ -319,6 +334,7 @@ class CarriedStepFn:
         # zero-runtime-compile asserts stay one prefix sum
         self._name = name
         self._compiled = {}
+        self._memory = {}
 
     @staticmethod
     def _sig(args):
@@ -351,10 +367,13 @@ class CarriedStepFn:
 
     def warmup(self, *args):
         """Eager-compile for this signature; {"source", "compile_ms",
-        "key"}.  Memory hits are free (idempotent prewarm)."""
+        "key", "temp_bytes", "alias_bytes"} (the last two from the
+        executable's ``memory_analysis()``, None where it has none).
+        Memory hits are free (idempotent prewarm)."""
         sig = self._sig(args)
         if sig in self._compiled:
-            return {"source": "memory", "compile_ms": 0.0, "key": None}
+            return dict(self._memory[sig], source="memory", compile_ms=0.0,
+                        key=None)
         devices = self._devices(args)
         disk_key = self._disk_key(sig, devices)
         compiled, cstats = aot_compile_cached(
@@ -362,11 +381,12 @@ class CarriedStepFn:
             meta={"kind": "carried_step"})
         self._compiled[sig] = compiled if compiled is not None \
             else self._jfn
+        self._memory[sig] = _step_memory(compiled)
         if _telemetry.enabled():
             labels = {"fn": self._name} if self._name else {}
             _telemetry.inc("executor_cache_miss_total", **labels)
-        return {"source": cstats["source"],
-                "compile_ms": cstats["compile_ms"], "key": disk_key}
+        return dict(self._memory[sig], source=cstats["source"],
+                    compile_ms=cstats["compile_ms"], key=disk_key)
 
     def __call__(self, *args):
         sig = self._sig(args)

@@ -23,7 +23,12 @@ CPU tier.
 
 Adoption: FLAGS_use_pallas_paged_attention + ``paged_attention_checks``
 eligibility + a >= 1.1x tools/probes row, all through adoption.decide()
-(interpret mode waives backend + probe for the CPU parity tests).
+(interpret mode waives backend + probe for the CPU parity tests).  The
+kernel reads pools with the heads split, ``[num_blocks, block_size, H,
+D]``; the serving cache keeps them folded, ``[num_blocks, block_size,
+H * D]`` (serving/kv_cache.py says why), which the ``rank`` check
+declines: the decode step takes the jnp path until the kernel's block
+specs read folded rows (splitting the heads on the pool would copy it).
 """
 
 import functools
@@ -38,7 +43,7 @@ from jax.experimental.pallas import tpu as pltpu
 from . import adoption
 
 __all__ = ["paged_attention", "paged_attention_reference",
-           "paged_attention_checks", "masked_attention"]
+           "paged_attention_checks", "masked_attention", "gather_blocks"]
 
 _MASK = -1e30  # finite: a fully-masked lane softmaxes to uniform, not NaN
 
@@ -47,28 +52,56 @@ def masked_attention(q, k, v, context_lens):
     """Single-token attention over a contiguous history: q [B, H, D],
     k/v [B, S, H, D], context_lens [B] -> [B, H, D].  Positions >= the
     context length are masked.  Shared by the paged gather path AND the
-    unpaged reference loop so the two stay bitwise-comparable."""
-    d = q.shape[-1]
-    s = jnp.einsum("bhd,bshd->bhs", q, k) * (1.0 / math.sqrt(d))
-    pos = jnp.arange(k.shape[1], dtype=jnp.int32)[None, None, :]
-    s = jnp.where(pos < context_lens[:, None, None].astype(jnp.int32),
-                  s, _MASK)
-    p = jax.nn.softmax(s, axis=-1)
-    return jnp.einsum("bhs,bshd->bhd", p, v)
+    unpaged reference loop so the two stay bitwise-comparable.
+
+    Both contractions run over the folded minor dimension H * D, against
+    a block-diagonal query: row h of ``qx`` holds head h's query in its
+    own D columns and zeros elsewhere, so ``qx @ k`` is head h's scores
+    and the diagonal blocks of ``p @ v`` are its output.  The history is
+    then read as it lies in the pool ([S, H * D] rows): splitting H * D
+    into heads on it costs a relayout of everything gathered wherever D
+    is under the 128 lanes (D = 64: a second, padded copy of the history
+    per layer), while the extra H - 1 zero blocks are matmul work on a
+    unit that is otherwise idle.  ``HIGHEST`` keeps the products float32,
+    as the elementwise form they replace had them."""
+    b, h, d = q.shape
+    s = k.shape[1]
+    dot = functools.partial(jnp.einsum, precision=jax.lax.Precision.HIGHEST)
+    eye = jnp.eye(h, dtype=q.dtype)[None, :, :, None]
+    qx = (q[:, :, None, :] * eye).reshape(b, h, h * d)
+    sc = dot("bhc,bsc->bhs", qx, k.reshape(b, s, h * d)) \
+        * (1.0 / math.sqrt(d))
+    pos = jnp.arange(s, dtype=jnp.int32)[None, None, :]
+    sc = jnp.where(pos < context_lens[:, None, None].astype(jnp.int32),
+                   sc, _MASK)
+    p = jax.nn.softmax(sc, axis=-1)
+    out = dot("bhs,bsc->bhc", p, v.reshape(b, s, h * d))
+    return (out.reshape(b, h, h, d) * eye).sum(axis=2)
+
+
+def gather_blocks(cache, block_tables):
+    """A pool ``[num_blocks, block_size, ...]`` read through the tables
+    ``[B, MAXB]`` as contiguous history ``[B, MAXB * block_size, ...]``.
+    Entries < 0 are unused slots: clamped to block 0 here and masked by
+    ``context_lens`` in the attention.  Every index is then in range,
+    which ``mode="clip"`` lets the gather rely on: the default mode wraps
+    it in a select over everything gathered."""
+    bb, maxb = block_tables.shape
+    got = jnp.take(cache, jnp.maximum(block_tables, 0), axis=0, mode="clip")
+    return got.reshape((bb, maxb * cache.shape[1]) + cache.shape[2:])
 
 
 def paged_attention_reference(q, k_cache, v_cache, block_tables,
                               context_lens):
     """jnp fallback: gather the table's blocks into contiguous K/V, then
     masked_attention.  q [B, H, D]; k_cache/v_cache
-    [num_blocks, block_size, H, D]; block_tables [B, MAXB] (entries < 0
-    are unused slots, clamped to block 0 and masked by context_lens)."""
-    bb, maxb = block_tables.shape
-    bs, h, d = k_cache.shape[1:]
+    [num_blocks, block_size, H, D], or the serving pool's
+    [num_blocks, block_size, H * D]: the heads are split after the
+    gather, never on the pool."""
+    bb, h, d = q.shape
     with jax.named_scope("kv_gather"):
-        idx = jnp.maximum(block_tables, 0)
-        k = jnp.take(k_cache, idx, axis=0).reshape(bb, maxb * bs, h, d)
-        v = jnp.take(v_cache, idx, axis=0).reshape(bb, maxb * bs, h, d)
+        k, v = (gather_blocks(c, block_tables).reshape(bb, -1, h, d)
+                for c in (k_cache, v_cache))
     return masked_attention(q, k, v, context_lens)
 
 

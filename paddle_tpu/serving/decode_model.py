@@ -16,9 +16,10 @@ attention gather runs through the probe-gated
 Two step builders share every layer of math through one ``attend``
 callback:
 
-* ``make_paged_step``   — writes this token's K/V into the paged cache
-  (block ids steered by the per-lane block table) and attends through
-  ``paged_attention`` over the block pool.
+* ``make_paged_step``   — writes this token's K/V rows into the layer's
+  own pools of the paged cache (``[num_blocks, block_size, H * D]``, block
+  ids steered by the per-lane block table) and attends through
+  ``paged_attention`` over them.
 * ``make_unpaged_step`` — the reference: contiguous per-lane K/V
   ``[L, B, S, H, D]`` updated at ``pos`` and attended via the same
   ``masked_attention`` core.
@@ -36,8 +37,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..pallas_kernels.paged_attention import masked_attention, \
-    paged_attention
+from ..pallas_kernels.paged_attention import gather_blocks, \
+    masked_attention, paged_attention
 from . import kv_cache as _kv
 
 __all__ = ["DecoderConfig", "init_decoder_params", "save_decoder",
@@ -190,14 +191,29 @@ def _token_logits(params, cfg, tok, pos, attend):
 
 # -- paged step --------------------------------------------------------------
 
+def _write_rows(pool, blk_ids, offs, rows):
+    """``pool[blk_ids[b], offs[b]] = rows[b]`` for every lane b: the one
+    KV write of the step, for payload and scales alike (pool ``[num_blocks,
+    block_size, W]``, rows ``[B, W]``).  A scatter of B rows into a whole
+    donated array, which XLA:TPU updates in its buffer.  Idle lanes all
+    name row (0, 0) of the scratch block, so the indices are not unique
+    and nothing is promised about them: which idle lane's row lands there
+    is unspecified, and nothing reads it."""
+    return pool.at[blk_ids, offs].set(rows.reshape(rows.shape[0], -1))
+
+
 def make_paged_step(cfg, kv_config):
     """-> step(kv_carry, params, tok, pos, block_tables, context_lens)
     returning (new_kv_carry, next_tokens, logits).
 
-    All shapes are static per lane bucket: tok/pos/context_lens [B],
-    block_tables [B, MAXB].  ``context_lens[b]`` counts the tokens valid
-    AFTER this step's write (pos + 1 for live lanes, 0 for idle lanes,
-    whose table points at the reserved scratch block 0).
+    ``kv_carry`` is ``PagedKVCache.carry()``: per-layer pools, K then V
+    (then their scales for int8), donated by ``CarriedStepFn`` and written
+    in place.  All shapes are static per lane bucket: tok/pos/context_lens
+    [B], block_tables [B, MAXB].  ``context_lens[b]`` counts the tokens
+    valid AFTER this step's write (pos + 1 for live lanes, 0 for idle
+    lanes, whose table points at the reserved scratch block 0: an idle
+    lane feeds pos 0, so its write lands on row 0 of that block, the only
+    row of the pool a step may change besides the live lanes' own).
 
     Feed-planning contract (what prefix caching leans on): the step
     WRITES exactly one position — ``pos``, into block
@@ -220,42 +236,35 @@ def make_paged_step(cfg, kv_config):
         blk_ids = jnp.take_along_axis(
             jnp.maximum(block_tables, 0), (pos // bs)[:, None], axis=1)[:, 0]
         offs = pos % bs
-        if int8:
-            k_c, v_c, k_s, v_s = kv_carry
-        else:
-            k_c, v_c = kv_carry
+        pools = _kv.carry_groups(kv_carry, cfg.layers)
+
+        def write(group, l, rows):
+            pools[group][l] = _write_rows(pools[group][l], blk_ids, offs,
+                                          rows)
 
         def attend(l, q, k, v):
-            nonlocal k_c, v_c
             if not int8:
                 with jax.named_scope("kv_write"):
-                    k_c = k_c.at[l, blk_ids, offs].set(k)
-                    v_c = v_c.at[l, blk_ids, offs].set(v)
-                return paged_attention(q, k_c[l], v_c[l], block_tables,
-                                       context_lens)
-            nonlocal k_s, v_s
+                    write(0, l, k)
+                    write(1, l, v)
+                return paged_attention(q, pools[0][l], pools[1][l],
+                                       block_tables, context_lens)
             with jax.named_scope("kv_write"):
-                qk, sk = _kv.quantize_kv(k)
-                qv, sv = _kv.quantize_kv(v)
-                k_c = k_c.at[l, blk_ids, offs].set(qk)
-                v_c = v_c.at[l, blk_ids, offs].set(qv)
-                k_s = k_s.at[l, blk_ids, offs].set(sk)
-                v_s = v_s.at[l, blk_ids, offs].set(sv)
+                for group, x in ((0, k), (1, v)):
+                    payload, scale = _kv.quantize_kv(x)
+                    write(group, l, payload)
+                    write(group + 2, l, scale)
             with jax.named_scope("kv_gather"):
-                idx = jnp.maximum(block_tables, 0)
-                bb, maxb = block_tables.shape
-                kk = _kv.dequantize_kv(jnp.take(k_c[l], idx, axis=0),
-                                       jnp.take(k_s[l], idx, axis=0))
-                vv = _kv.dequantize_kv(jnp.take(v_c[l], idx, axis=0),
-                                       jnp.take(v_s[l], idx, axis=0))
-                kk = kk.reshape(bb, maxb * bs, cfg.heads, cfg.head_dim)
-                vv = vv.reshape(bb, maxb * bs, cfg.heads, cfg.head_dim)
+                kk, vv = (_kv.dequantize_kv(
+                    gather_blocks(pools[g][l], block_tables).reshape(
+                        q.shape[0], -1, *q.shape[1:]),
+                    gather_blocks(pools[g + 2][l], block_tables))
+                    for g in (0, 1))
             return masked_attention(q, kk, vv, context_lens)
 
         logits = _token_logits(params, cfg, tok, pos, attend)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        carry = (k_c, v_c, k_s, v_s) if int8 else (k_c, v_c)
-        return carry, nxt, logits
+        return tuple(a for group in pools for a in group), nxt, logits
 
     return step
 
@@ -286,9 +295,17 @@ def make_paged_step_multi(cfg, kv_config, width):
         context_lens = context_lens.astype(jnp.int32)
         nxts, logits = [], []
         for j in range(width):
-            kv_carry, nxt, lg = base(kv_carry, params, tok[:, j],
-                                     pos[:, j], block_tables,
-                                     context_lens[:, j])
+            tok_j = tok[:, j]
+            if nxts:
+                # an argmax is never negative, so this is tok[:, j]; what
+                # it adds is column j's dependence on column j-1's result.
+                # Without it column j's write and column j-1's read of one
+                # pool are unordered, and a compiler may keep both by
+                # copying the pool (XLA:CPU does) instead of writing in
+                # place after the read.
+                tok_j = jnp.where(nxts[-1] < 0, nxts[-1], tok_j)
+            kv_carry, nxt, lg = base(kv_carry, params, tok_j, pos[:, j],
+                                     block_tables, context_lens[:, j])
             nxts.append(nxt)
             logits.append(lg)
         return kv_carry, jnp.stack(nxts, axis=1), jnp.stack(logits, axis=1)

@@ -1144,6 +1144,23 @@ class DecodeEngine:
         this, mixed-length continuous batching can only hit the
         in-memory executables: ``executor_cache_miss_total`` stays flat
         under load — the zero-runtime-compile proof."""
+        def note(model, bucket, fn, got, **extra):
+            # what the executable holds beside its arguments, and how
+            # much of them (the KV pool) it updates in their own buffers
+            _tm.inc("serving_prewarm_total", model=model,
+                    source=got["source"])
+            _tm.event("serving_prewarm", model=model, bucket=bucket,
+                      source=got["source"], decode=True, fn=fn,
+                      ms=round(got["compile_ms"], 3),
+                      temp_bytes=got["temp_bytes"],
+                      alias_bytes=got["alias_bytes"], **extra)
+            for key in ("temp_bytes", "alias_bytes"):
+                if got[key] is not None:
+                    _tm.set_gauge("serving_step_" + key, got[key],
+                                  model=model, bucket=bucket, fn=fn)
+            return {"source": got["source"],
+                    "compile_ms": round(got["compile_ms"], 3)}
+
         manifest = {}
         for name, m in self._models.items():
             per = {}
@@ -1171,29 +1188,14 @@ class DecodeEngine:
                             np.full((b, m.maxb), -1, np.int32),
                             np.zeros((b, w), np.int32)),
                     }
-                    per[b] = {}
-                    for kind, got in warms.items():
-                        per[b][kind] = {
-                            "source": got["source"],
-                            "compile_ms": round(got["compile_ms"], 3)}
-                        _tm.inc("serving_prewarm_total", model=name,
-                                source=got["source"])
-                        _tm.event("serving_prewarm", model=name, bucket=b,
-                                  source=got["source"], decode=True,
-                                  fn=kind, k=m.spec_k,
-                                  ms=round(got["compile_ms"], 3))
+                    per[b] = {kind: note(name, b, kind, got, k=m.spec_k)
+                              for kind, got in warms.items()}
                     continue
-                got = m.stepfn.warmup(*self._step_args(
-                    m, b, np.zeros(b, np.int32), np.zeros(b, np.int32),
-                    np.full((b, m.maxb), -1, np.int32),
-                    np.zeros(b, np.int32)))
-                per[b] = {"source": got["source"],
-                          "compile_ms": round(got["compile_ms"], 3)}
-                _tm.inc("serving_prewarm_total", model=name,
-                        source=got["source"])
-                _tm.event("serving_prewarm", model=name, bucket=b,
-                          source=got["source"], decode=True,
-                          ms=round(got["compile_ms"], 3))
+                per[b] = note(name, b, "decode", m.stepfn.warmup(
+                    *self._step_args(
+                        m, b, np.zeros(b, np.int32), np.zeros(b, np.int32),
+                        np.full((b, m.maxb), -1, np.int32),
+                        np.zeros(b, np.int32))))
             manifest[name] = per
         return manifest
 

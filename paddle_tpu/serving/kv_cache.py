@@ -1,12 +1,13 @@
 """Paged KV-cache for autoregressive decode serving.
 
-The decode engine owns a pool of fixed-size KV blocks (``[layers,
-num_blocks, block_size, heads, head_dim]`` device arrays) and hands each
-admitted sequence a *block table* — the list of physical blocks holding
-its history, grown one block per ``block_size`` generated tokens.  The
-physical layout is the point: sequences of wildly different lengths all
-present the decode step with the same static shapes (token ids, tables
-padded to ``max_seq // block_size`` slots, context lengths), so ONE
+The decode engine owns a pool of fixed-size KV blocks (one K and one V
+device array per layer, each ``[num_blocks, block_size, heads *
+head_dim]``) and hands each admitted sequence a *block table* — the list
+of physical blocks holding its history, grown one block per
+``block_size`` generated tokens.  The physical layout is the point:
+sequences of wildly different lengths all present the decode step with
+the same static shapes (token ids, tables padded to
+``max_seq // block_size`` slots, context lengths), so ONE
 AOT-compiled step per lane bucket serves every mixture of lengths with
 zero runtime XLA compiles, and a finished sequence returns its blocks to
 the free list the same step it finishes.
@@ -21,7 +22,16 @@ pressure, so cache residency costs nothing when blocks are needed.
 ``PagedKVCache`` owns the device arrays as a donated carry: every decode
 step consumes the current arrays and returns the updated ones
 (``carry()``/``replace_carry()``), so the cache is updated in place on
-device instead of being copied per token.
+device instead of being copied per token.  Two properties of the arrays
+make "in place" hold on the TPU and not only in this sentence.  They are
+per layer, so no write or read goes through a slice of a stacked array
+(which the compiler materialises, a whole layer's pool per slice).  And
+their minor dimension is ``heads * head_dim``, a multiple of the 128
+lanes for every published width, where ``head_dim`` alone (64) pads to
+128: the runtime then keeps the padded pool in another layout than the
+step computes in, and converts the whole pool at the step's entry and
+exit.  Heads are split by a reshape of what attention gathers, never of
+the pool.
 
 ``PrefixCache`` is the content-addressed index over sealed blocks: a
 per-model hash chain ``h_i = sha(h_{i-1}, block_token_ids)`` over *full*
@@ -43,17 +53,19 @@ so ``core/world_analysis.check_memory`` counts engine-owned KV blocks in
 the static per-replica peak estimate.
 """
 
+import functools
 import hashlib
 import threading
 import weakref
 from collections import OrderedDict
 
+import jax
 import jax.numpy as jnp
 
 from ..core import telemetry as _tm
 
 __all__ = ["KVCacheConfig", "BlockAllocator", "PagedKVCache",
-           "PrefixCache",
+           "PrefixCache", "carry_groups",
            "plan_num_blocks", "block_bytes", "engine_owned_kv_bytes",
            "engine_owned_resident_bytes", "register_resident_bytes",
            "quantize_kv", "dequantize_kv"]
@@ -534,25 +546,56 @@ def dequantize_kv(q, scale):
     return q.astype(jnp.float32) * scale[..., None]
 
 
+def carry_groups(carry, layers):
+    """A cache carry (or anything laid out like one) as its groups of
+    ``layers`` per-layer arrays: ``[k, v]``, and ``[k, v, k_scales,
+    v_scales]`` for int8 residency."""
+    carry = list(carry)
+    return [carry[i:i + layers] for i in range(0, len(carry), layers)]
+
+
+@functools.partial(jax.jit, static_argnums=(2,))
+def _get_block(carry, block, layers):
+    """One block of every pool, stacked over the layers per group."""
+    return [jnp.stack([jax.lax.dynamic_index_in_dim(c, block, 0, False)
+                       for c in group])
+            for group in carry_groups(carry, layers)]
+
+
+@functools.partial(jax.jit, donate_argnums=(0,))
+def _set_block(carry, block, rows):
+    """``pool[block] = rows`` for every pool of a carry and its rows, each
+    pool in its own (donated) buffer: one block is written, none copied."""
+    return tuple(jax.lax.dynamic_update_slice(
+        c, r[None], (block,) + (0,) * r.ndim) for c, r in zip(carry, rows))
+
+
 class PagedKVCache:
     """Engine-owned paged K/V device arrays, carried (donated) through
     the decode step.  Block 0 is reserved: idle lanes in a partially-full
     bucket point their table at it, so their (masked, discarded) writes
-    never touch a sequence's history."""
+    never touch a sequence's history.
+
+    The carry is a flat tuple of per-layer arrays, one *group* after
+    another, layer 0 first within a group: K then V (``2 * layers``
+    arrays of ``[num_blocks, block_size, heads * head_dim]``), and for
+    int8 residency the K then V scales after them (``4 * layers`` in
+    all; a scale array is ``[num_blocks, block_size, heads]``).
+    ``carry_groups`` splits a carry back into its groups."""
 
     def __init__(self, config):
         self.config = config
         self.allocator = BlockAllocator(config.num_blocks, reserve=1)
-        shape = (config.layers, config.num_blocks, config.block_size,
-                 config.heads, config.head_dim)
+        rows = (config.num_blocks, config.block_size)
+        payload = rows + (config.heads * config.head_dim,)
         if config.dtype == "int8":
-            self._carry = (jnp.zeros(shape, jnp.int8),
-                           jnp.zeros(shape, jnp.int8),
-                           jnp.zeros(shape[:-1], jnp.float32),
-                           jnp.zeros(shape[:-1], jnp.float32))
+            groups = [(payload, jnp.int8)] * 2 \
+                + [(rows + (config.heads,), jnp.float32)] * 2
         else:
-            self._carry = (jnp.zeros(shape, jnp.float32),
-                           jnp.zeros(shape, jnp.float32))
+            groups = [(payload, jnp.float32)] * 2
+        self._carry = tuple(jnp.zeros(shape, dtype)
+                            for shape, dtype in groups
+                            for _ in range(config.layers))
         _LIVE.add(self)
         _tm.set_gauge("kv_cache_bytes", self.nbytes)
 
@@ -577,42 +620,56 @@ class PagedKVCache:
 
     # -- sealed-block export/import (the disaggregated transfer unit) --------
 
+    def _wire_shape(self, group):
+        """One block of one carry group on the wire: every layer's rows
+        with the heads split, ``[layers, block_size, heads, head_dim]``
+        (scales ``[layers, block_size, heads]``)."""
+        c = self.config
+        tail = (c.heads,) if group >= 2 else (c.heads, c.head_dim)
+        return (c.layers, c.block_size) + tail
+
     def export_block(self, block):
-        """Host copies of one physical block's slices of every carry
-        array, in carry order: ``[k, v]`` for f32 residency, ``[k, v,
-        k_scales, v_scales]`` for int8.  The wire payload IS the
-        residency payload — prefill's compiled step is deterministic, so
-        an adopted block is bitwise-identical to the one the decode
-        replica would have computed itself."""
+        """Host copies of one physical block, one array per carry group:
+        ``[k, v]`` for f32 residency, ``[k, v, k_scales, v_scales]`` for
+        int8, each stacked over the layers in its wire shape.  The wire
+        payload IS the residency payload — prefill's compiled step is
+        deterministic, so an adopted block is bitwise-identical to the
+        one the decode replica would have computed itself."""
         import numpy as np
 
-        return [np.asarray(c[:, block]) for c in self._carry]
+        return [np.asarray(a).reshape(self._wire_shape(g))
+                for g, a in enumerate(_get_block(
+                    self._carry, block, self.config.layers))]
 
     def import_block(self, block, arrays):
-        """Install transferred payloads into physical ``block``.  The
-        caller must hold the engine step lock (the carry is swapped
-        wholesale) and own the block at refcount 1.  Shape/dtype mismatch
-        raises — adopting a frame cut for different cache geometry would
-        corrupt every sequence that later matches the digest."""
+        """Install transferred payloads into physical ``block``: one
+        block-sized update of each (donated) carry array.  The caller
+        must hold the engine step lock (the carry is swapped) and own the
+        block at refcount 1.  Shape/dtype mismatch raises before anything
+        is written — adopting a frame cut for different cache geometry
+        would corrupt every sequence that later matches the digest."""
         import numpy as np
 
-        if len(arrays) != len(self._carry):
+        layers = self.config.layers
+        groups = carry_groups(self._carry, layers)
+        if len(arrays) != len(groups):
             raise ValueError(
                 "kv import arity mismatch: %d arrays for a %s-dtype "
                 "carry of %d" % (len(arrays), self.config.dtype,
-                                 len(self._carry)))
-        new = []
-        for c, a in zip(self._carry, arrays):
-            a = np.asarray(a)
-            want_shape = tuple(c.shape[:1] + c.shape[2:])
-            if tuple(a.shape) != want_shape or a.dtype != c.dtype:
+                                 len(groups)))
+        arrays = [np.asarray(a) for a in arrays]
+        for g, (group, a) in enumerate(zip(groups, arrays)):
+            want_shape, want = self._wire_shape(g), group[0].dtype
+            if tuple(a.shape) != want_shape or a.dtype != want:
                 raise ValueError(
                     "kv import geometry mismatch: got %s%s, carry wants "
                     "%s%s (block_size/heads/head_dim/dtype must agree "
                     "across the disaggregated pair)"
-                    % (a.dtype, tuple(a.shape), c.dtype, want_shape))
-            new.append(c.at[:, block].set(jnp.asarray(a)))
-        self._carry = tuple(new)
+                    % (a.dtype, tuple(a.shape), want, want_shape))
+        self._carry = _set_block(
+            self._carry, block,
+            [a[l].reshape(c.shape[1:]) for group, a in zip(groups, arrays)
+             for l, c in enumerate(group)])
 
     # -- multi-token growth / rollback (the speculative-decode contract) -----
 

@@ -278,6 +278,49 @@ def test_preemption_recompute_is_deterministic(cache_dir, telemetry_on):
         e.stop()
 
 
+# -- the step's write ----------------------------------------------------------
+
+
+@pytest.mark.parametrize("dtype", ["f32", "int8"])
+def test_step_writes_one_row_per_lane_and_nothing_else(dtype):
+    """The feed-planning contract, on the pool itself: a step writes row
+    ``(block_tables[b, pos // bs], pos % bs)`` of every layer's pools for
+    each live lane, row (0, 0) of the scratch block for the idle lanes,
+    and leaves every other row of every pool bit-identical."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddle_tpu.serving.decode_model import make_paged_step
+    from paddle_tpu.serving.kv_cache import KVCacheConfig, PagedKVCache
+
+    kv = KVCacheConfig(CFG.layers, CFG.heads, CFG.head_dim, BS, 16, dtype)
+    cache = PagedKVCache(kv)
+    rng = np.random.RandomState(11)
+    before = [np.asarray(rng.randint(-90, 90, a.shape), str(a.dtype))
+              for a in cache.carry()]
+    maxb = CFG.max_seq // BS
+    tables = np.full((4, maxb), -1, np.int32)
+    tables[0, :3] = [5, 9, 2]          # lane 0 at pos 6: block 9, row 2
+    tables[1, :1] = [7]                # lane 1 at pos 0: block 7, row 0
+    tables[3, :2] = [3, 12]            # lane 3 at pos 7: block 12, row 3
+    pos = np.asarray([6, 0, 0, 7], np.int32)          # lane 2 is idle
+    lens = np.asarray([7, 1, 0, 8], np.int32)
+    step = jax.jit(make_paged_step(CFG, kv), donate_argnums=(0,))
+    carry, _nxt, _logits = step(
+        tuple(jnp.asarray(a) for a in before),
+        {k: jnp.asarray(v) for k, v in PARAMS.items()},
+        np.asarray([3, 4, 0, 5], np.int32), pos, tables, lens)
+    written = {(9, 2), (7, 0), (12, 3)}
+    assert len(carry) == len(before) == (4 if dtype == "int8" else 2) * 2
+    for got, was in zip(carry, before):
+        got = np.asarray(got)
+        changed = {(int(b), int(r)) for b, r in
+                   zip(*np.nonzero((got != was).any(axis=-1)))}
+        # random rows never equal what the model writes, so every written
+        # row shows; the scratch row may or may not differ
+        assert written <= changed <= written | {(0, 0)}, changed
+
+
 # -- int8 KV residency -------------------------------------------------------
 
 
@@ -285,12 +328,35 @@ def test_int8_residency_generates(cache_dir):
     e = _mkengine(cache_dir, 16, kv_cache_dtype="int8")
     try:
         assert e.spec("toy")["kv_dtype"] == "int8"
-        assert len(e._models["toy"].cache.carry()) == 4
+        m = e._models["toy"]
+        assert len(m.cache.carry()) == 4 * m.cfg.layers
         r = e.generate("toy", [1, 2, 3], max_new_tokens=4,
                        deadline_ms=30000.0)
         assert r.status == "ok"
         toks = r.outputs["tokens"]
         assert len(toks) == 4 and all(0 <= t < 31 for t in toks)
+    finally:
+        e.stop()
+
+
+def test_prewarm_reports_the_step_memory(cache_dir, telemetry_on):
+    """Prewarm says what each compiled step holds beside its arguments and
+    how much of them it updates in their own buffers: in place means the
+    whole KV pool is aliased."""
+    e = _mkengine(cache_dir, 512, buckets="2", kv_cache_dtype="f32")
+    try:
+        e.prewarm()
+        gauges = _tm.snapshot()["gauges"]
+        labels = "{bucket=2,fn=decode,model=toy}"
+        alias = gauges["serving_step_alias_bytes" + labels]
+        temp = gauges["serving_step_temp_bytes" + labels]
+        pool = e._models["toy"].cache.nbytes
+        assert gauges["kv_cache_bytes"] == pool
+        assert alias >= pool and temp < pool / 4
+        # a second prewarm is a memory hit and says the same
+        e.prewarm()
+        assert _tm.snapshot()["gauges"][
+            "serving_step_alias_bytes" + labels] == alias
     finally:
         e.stop()
 

@@ -10,6 +10,7 @@ engine-owned KV bytes into the static per-replica peak estimate."""
 import contextlib
 import gc
 
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -378,8 +379,13 @@ def test_quantize_all_zero_block_is_safe():
 def test_cache_reserves_scratch_block_and_carry_shapes():
     c = PagedKVCache(_cfg())
     assert c.allocator.reserve == 1 and c.allocator.capacity == 7
-    k, v = c.carry()
-    assert k.shape == (2, 8, 4, 2, 8) and str(k.dtype) == "float32"
+    # per-layer pools, K group then V group, heads folded into the minor dim
+    carry = c.carry()
+    assert len(carry) == 2 * 2
+    assert all(a.shape == (8, 4, 2 * 8) and str(a.dtype) == "float32"
+               for a in carry)
+    k, v = kv_cache.carry_groups(carry, 2)
+    assert len(k) == len(v) == 2
     assert c.blocks_for_tokens(1) == 1
     assert c.blocks_for_tokens(4) == 1
     assert c.blocks_for_tokens(5) == 2
@@ -388,14 +394,62 @@ def test_cache_reserves_scratch_block_and_carry_shapes():
 
 def test_cache_int8_carry_has_scales():
     c = PagedKVCache(_cfg(dtype="int8"))
-    k, v, ks, vs = c.carry()
-    assert str(k.dtype) == "int8" and ks.shape == (2, 8, 4, 2)
+    k, v, ks, vs = kv_cache.carry_groups(c.carry(), 2)
+    assert all(str(a.dtype) == "int8" and a.shape == (8, 4, 16)
+               for a in k + v)
+    assert all(str(a.dtype) == "float32" and a.shape == (8, 4, 2)
+               for a in ks + vs)
 
 
 def test_replace_carry_arity_guard():
     c = PagedKVCache(_cfg())
     with pytest.raises(ValueError):
         c.replace_carry(c.carry() + (c.carry()[0],))
+
+
+@pytest.mark.parametrize("dtype", ["f32", "int8"])
+def test_export_import_block_roundtrip_is_bitwise(dtype):
+    """A block leaves one cache and enters another bit for bit, in the
+    wire shapes the disaggregated pair agreed on ([layers, block_size,
+    heads, head_dim] per group, scales without head_dim), and the import
+    touches no other block."""
+    rng = np.random.RandomState(3)
+    src, dst = PagedKVCache(_cfg(dtype=dtype)), PagedKVCache(_cfg(dtype=dtype))
+
+    def fill(c):
+        return tuple(
+            np.asarray(rng.randint(-100, 100, a.shape), str(a.dtype))
+            if str(a.dtype) == "int8"
+            else rng.standard_normal(a.shape).astype("float32")
+            for a in c.carry())
+
+    src.replace_carry([jnp.asarray(a) for a in fill(src)])
+    before = fill(dst)
+    dst.replace_carry([jnp.asarray(a) for a in before])
+
+    wire = src.export_block(5)
+    groups = 4 if dtype == "int8" else 2
+    assert [a.shape for a in wire] == (
+        [(2, 4, 2, 8)] * 2 + [(2, 4, 2)] * (groups - 2))
+    assert [str(a.dtype) for a in wire] == (
+        [dtype.replace("f32", "float32")] * 2 + ["float32"] * (groups - 2))
+    dst.import_block(3, wire)
+
+    assert all(np.array_equal(a, b) for a, b in
+               zip(dst.export_block(3), wire))
+    for got, was, sent in zip(dst.carry(), before, src.carry()):
+        got = np.asarray(got)
+        assert np.array_equal(got[3], np.asarray(sent)[5])
+        rest = np.arange(got.shape[0]) != 3
+        assert np.array_equal(got[rest], was[rest])
+
+    # a frame cut for another geometry is refused before anything moves
+    with pytest.raises(ValueError, match="geometry mismatch"):
+        dst.import_block(3, [a[:, :2] for a in wire])
+    with pytest.raises(ValueError, match="arity mismatch"):
+        dst.import_block(3, wire[:1])
+    assert all(np.array_equal(a, b) for a, b in
+               zip(dst.export_block(3), wire))
 
 
 def test_engine_owned_bytes_tracks_live_caches():

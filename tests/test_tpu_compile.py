@@ -1,0 +1,85 @@
+"""Compiles for the chip, without the chip: the TPU's compiler is installed
+here and compiles for a *described* v5e, so what it makes of the main
+path's programs at their real widths is checked on every run of the tests,
+at no chip time.  Nothing runs, so nothing here is a time or a result.
+
+All such tests live in this one file, and the topology is described inside
+a fixture (never at import): only one process may load the TPU's library,
+and it is the worker that is given this file.
+"""
+
+import json
+import os
+import re
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from benchmark.models import gpt2_decoder
+from paddle_tpu.serving import decode_model as dm
+from paddle_tpu.serving.kv_cache import KVCacheConfig, PagedKVCache
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:
+        pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.mark.parametrize("kind", ["step", "multi"])
+def test_decode_step_keeps_the_pool_in_place_on_v5e(one_chip, kind):
+    """GPT-2-medium widths (16 heads of 64, 1024 positions), bucket 32, a
+    4.8e8-byte pool a layer pair: the compiled step aliases the whole pool,
+    needs under a quarter of it beside its arguments, and holds no copy,
+    select, transpose or slice of a pool's shape (PERF.md section 6, PR 26:
+    the parent had four whole-pool layout copies and a slice per layer)."""
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "gpt2-medium-serve.json")) as fp:
+        config = dict(json.load(fp), n_layer=4)
+    cfg = gpt2_decoder.decoder_config(config)
+    assert (cfg.heads, cfg.head_dim, cfg.max_seq) == (16, 64, 1024)
+    lanes, block_size, blocks = 32, 16, 1536
+    kv = KVCacheConfig(cfg.layers, cfg.heads, cfg.head_dim, block_size,
+                       blocks, "f32")
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip), tree)
+
+    carry = on_chip(jax.eval_shape(lambda: PagedKVCache(kv).carry()))
+    params = on_chip({
+        name: jax.ShapeDtypeStruct(shape, jnp.float32)
+        for name, (shape, _kind) in gpt2_decoder.param_shapes(config).items()})
+    per_lane = (lanes,) if kind == "step" else (lanes, 2)
+    feeds = on_chip([
+        jax.ShapeDtypeStruct(per_lane, jnp.int32),
+        jax.ShapeDtypeStruct(per_lane, jnp.int32),
+        jax.ShapeDtypeStruct((lanes, cfg.max_seq // block_size), jnp.int32),
+        jax.ShapeDtypeStruct(per_lane, jnp.int32)])
+    fn = dm.make_paged_step(cfg, kv) if kind == "step" \
+        else dm.make_paged_step_multi(cfg, kv, 2)
+    compiled = jax.jit(fn, donate_argnums=(0,)).lower(
+        carry, params, *feeds).compile()
+
+    pool_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in carry)
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= pool_bytes
+    assert memory.temp_size_in_bytes < pool_bytes / 4
+    pool_shape = r"f32\[%d,%d,%d\]" % (blocks, block_size, cfg.hidden)
+    whole_pool_pass = re.compile(
+        r" = %s\S* (copy|select|transpose|slice)\(" % pool_shape)
+    found = [line.strip()[:160] for line in compiled.as_text().splitlines()
+             if whole_pool_pass.search(line)]
+    assert not found, found
+    assert re.search(r" = %s\S* scatter\(" % pool_shape, compiled.as_text())

@@ -21,7 +21,7 @@ from paddle_tpu.serving import DecodeEngine
 from paddle_tpu.serving.decode_model import (DecoderConfig,
                                              init_decoder_params,
                                              make_paged_step)
-from paddle_tpu.serving.kv_cache import KVCacheConfig
+from paddle_tpu.serving.kv_cache import KVCacheConfig, PagedKVCache
 
 CFG = DecoderConfig(vocab=31, layers=2, heads=2, head_dim=8, max_seq=48)
 PARAMS = init_decoder_params(CFG, seed=7)
@@ -333,9 +333,9 @@ def test_lowered_text_carries_the_scopes():
     kv = KVCacheConfig(CFG.layers, CFG.heads, CFG.head_dim, block_size=4,
                        num_blocks=8)
     step = make_paged_step(CFG, kv)
-    pool = np.zeros((CFG.layers, 8, 4, CFG.heads, CFG.head_dim), "f")
     text = jax.jit(step).lower(
-        (pool, pool), PARAMS, np.zeros(2, np.int32), np.zeros(2, np.int32),
+        PagedKVCache(kv).carry(), PARAMS, np.zeros(2, np.int32),
+        np.zeros(2, np.int32),
         np.zeros((2, 12), np.int32), np.ones(2, np.int32)
     ).as_text(debug_info=True)
     for scope in ("layer0/attn", "layer1/attn/kv_write",
