@@ -63,19 +63,26 @@ def masked_attention(q, k, v, context_lens):
     is under the 128 lanes (D = 64: a second, padded copy of the history
     per layer), while the extra H - 1 zero blocks are matmul work on a
     unit that is otherwise idle.  ``HIGHEST`` keeps the products float32,
-    as the elementwise form they replace had them."""
+    as the elementwise form they replace had them.
+
+    The history's dtype is the matmuls' input dtype: against a float32
+    pool nothing is cast; against a bf16 pool (a bf16 model's) the query
+    and the probabilities are rounded to bf16, the history is read as it
+    is stored, and both contractions accumulate in float32.  Scores,
+    mask and softmax are float32 either way."""
     b, h, d = q.shape
     s = k.shape[1]
-    dot = functools.partial(jnp.einsum, precision=jax.lax.Precision.HIGHEST)
+    dot = functools.partial(jnp.einsum, precision=jax.lax.Precision.HIGHEST,
+                            preferred_element_type=jnp.float32)
     eye = jnp.eye(h, dtype=q.dtype)[None, :, :, None]
-    qx = (q[:, :, None, :] * eye).reshape(b, h, h * d)
+    qx = (q[:, :, None, :] * eye).reshape(b, h, h * d).astype(k.dtype)
     sc = dot("bhc,bsc->bhs", qx, k.reshape(b, s, h * d)) \
         * (1.0 / math.sqrt(d))
     pos = jnp.arange(s, dtype=jnp.int32)[None, None, :]
     sc = jnp.where(pos < context_lens[:, None, None].astype(jnp.int32),
                    sc, _MASK)
     p = jax.nn.softmax(sc, axis=-1)
-    out = dot("bhs,bsc->bhc", p, v.reshape(b, s, h * d))
+    out = dot("bhs,bsc->bhc", p.astype(v.dtype), v.reshape(b, s, h * d))
     return (out.reshape(b, h, h, d) * eye).sum(axis=2)
 
 

@@ -15,6 +15,14 @@ shapes):
   - EP: experts sharded over `axis_name`; token blocks exchanged with
     lax.all_to_all before and after the expert FFN (ICI all-to-all), the
     canonical EP schedule.
+
+This is the trainer's routing.  Serving routes in ``models/olmoe.py``
+(``_route`` / ``_experts``) and differs on purpose: there every token is
+computed by exactly its chosen experts with the published softmax weights,
+so a served logit can equal a reference's; capacity (and the dropped
+tokens it implies), renormalised gates and the auxiliary loss are what a
+trainer wants for static shapes and balanced load, and none of them is
+part of a published model's forward pass.
 """
 
 import math

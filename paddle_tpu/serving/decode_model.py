@@ -13,6 +13,13 @@ compiles the step per lane bucket with tier-B disk persistence, and the
 attention gather runs through the probe-gated
 ``pallas_kernels.paged_attention`` funnel.
 
+Since PR 27 the decoder is one of two blocks, picked by
+``DecoderConfig.arch``: the ``gpt2`` block of this file, in float32, and
+the routed-expert ``olmoe`` block of ``models/olmoe.py``, in bfloat16 with
+a bfloat16 cache.  Every step builder below serves both through one
+contract (``_block``), so there is one paged step, one multi-token step,
+one draft rollout and one unpaged reference, whatever the block.
+
 Two step builders share every layer of math through one ``attend``
 callback:
 
@@ -32,6 +39,7 @@ bitwise-equal to the unpaged loop on the CPU tier — the acceptance bar
 
 import json
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -48,16 +56,46 @@ __all__ = ["DecoderConfig", "init_decoder_params", "save_decoder",
 
 
 class DecoderConfig:
-    __slots__ = ("vocab", "layers", "heads", "head_dim", "ffn", "max_seq")
+    """What a decode step is built from.  ``arch`` picks the block:
+    ``gpt2`` is the pre-LN MHA + GELU block of this file (learned
+    positions), ``olmoe`` the routed-expert block of ``models/olmoe.py``
+    (RMSNorm, Q/K norm, RoPE, ``experts`` SiLU-gated experts of width
+    ``ffn``, ``experts_per_token`` of them a token).  ``dtype`` is the
+    weights' (``f32`` | ``bf16``); ``kv_dtype`` the cache's residency
+    (``f32`` | ``bf16`` | ``int8``), and None leaves it to
+    ``FLAGS_kv_cache_dtype``: a bf16 model keeps a bf16 cache."""
+
+    __slots__ = ("vocab", "layers", "heads", "head_dim", "ffn", "max_seq",
+                 "arch", "dtype", "kv_dtype", "experts",
+                 "experts_per_token", "rope_theta", "norm_eps")
 
     def __init__(self, vocab, layers, heads, head_dim, ffn=None,
-                 max_seq=64):
+                 max_seq=64, arch="gpt2", dtype="f32", kv_dtype=None,
+                 experts=0, experts_per_token=0, rope_theta=10000.0,
+                 norm_eps=1e-5):
+        if arch not in ("gpt2", "olmoe"):
+            raise ValueError("decoder arch must be gpt2|olmoe: %r" % (arch,))
+        if dtype not in ("f32", "bf16"):
+            raise ValueError("decoder dtype must be f32|bf16: %r" % (dtype,))
+        if arch == "gpt2" and dtype != "f32":
+            raise ValueError("the gpt2 block is served in f32")
+        if arch == "olmoe" and not 0 < int(experts_per_token) <= int(experts):
+            raise ValueError("olmoe wants 0 < experts_per_token <= experts, "
+                             "got %r of %r" % (experts_per_token, experts))
         self.vocab = int(vocab)
         self.layers = int(layers)
         self.heads = int(heads)
         self.head_dim = int(head_dim)
         self.ffn = int(ffn if ffn is not None else 4 * heads * head_dim)
         self.max_seq = int(max_seq)
+        self.arch = arch
+        self.dtype = dtype
+        self.kv_dtype = kv_dtype if kv_dtype is not None \
+            else ("bf16" if dtype == "bf16" else None)
+        self.experts = int(experts)
+        self.experts_per_token = int(experts_per_token)
+        self.rope_theta = float(rope_theta)
+        self.norm_eps = float(norm_eps)
 
     @property
     def hidden(self):
@@ -66,9 +104,17 @@ class DecoderConfig:
     def to_dict(self):
         return {s: getattr(self, s) for s in self.__slots__}
 
+    def replace(self, **changes):
+        return DecoderConfig(**dict(self.to_dict(), **changes))
+
 
 def init_decoder_params(cfg, seed=0):
-    """name -> np.float32 array; 0.02-normal weights, identity LN."""
+    """name -> np array in the config's weight dtype; 0.02-normal
+    weights, identity norms."""
+    if cfg.arch == "olmoe":
+        from ..models import olmoe
+
+        return olmoe.init_params(cfg, seed)
     r = np.random.RandomState(seed)
     h, f, v = cfg.hidden, cfg.ffn, cfg.vocab
 
@@ -92,6 +138,13 @@ def init_decoder_params(cfg, seed=0):
     return p
 
 
+_BF16 = np.dtype(jnp.bfloat16)
+
+
+def _as_stored(a):
+    return a.view(np.uint16) if a.dtype == _BF16 else a
+
+
 def save_decoder(dirname, cfg, params, draft=None):
     """params.npz + decoder.json under `dirname` (tools/serve.py loads
     decode models from such a dir).  ``draft`` — an optional
@@ -99,7 +152,10 @@ def save_decoder(dirname, cfg, params, draft=None):
     ``<dirname>/draft`` so the speculative-decode draft ships beside its
     target and the two can never drift apart."""
     os.makedirs(dirname, exist_ok=True)
-    np.savez(os.path.join(dirname, "params.npz"), **params)
+    # npz has no bfloat16: such arrays are stored as their 16 bits, and
+    # load_decoder reads them back by the config's weight dtype
+    np.savez(os.path.join(dirname, "params.npz"),
+             **{k: _as_stored(np.asarray(v)) for k, v in params.items()})
     with open(os.path.join(dirname, "decoder.json"), "w") as fp:
         json.dump(cfg.to_dict(), fp, indent=1, sort_keys=True)
     if draft is not None:
@@ -116,6 +172,9 @@ def load_decoder(dirname):
         cfg = DecoderConfig(**json.load(fp))
     with np.load(os.path.join(dirname, "params.npz")) as z:
         params = {k: z[k] for k in z.files}
+    if cfg.dtype == "bf16":
+        params = {k: v.view(_BF16) if v.dtype == np.uint16 else v
+                  for k, v in params.items()}
     return cfg, params
 
 
@@ -140,13 +199,12 @@ def truncate_decoder(cfg, params, layers=1):
     tracks the full model's closely — a distillation-free draft for
     demos and smokes (real deployments train one)."""
     layers = min(int(layers), cfg.layers)
-    dcfg = DecoderConfig(vocab=cfg.vocab, layers=layers, heads=cfg.heads,
-                         head_dim=cfg.head_dim, ffn=cfg.ffn,
-                         max_seq=cfg.max_seq)
-    keep = {"embed", "pos_embed", "lnf_g", "lnf_b", "head"}
-    dparams = {k: np.asarray(v) for k, v in params.items()
-               if k in keep or (k.startswith("l")
-                                and int(k[1:k.index("_")]) < layers)}
+    dcfg = cfg.replace(layers=layers)
+    dparams = {}
+    for k, v in params.items():
+        m = re.match(r"l(\d+)_", k)
+        if m is None or int(m.group(1)) < layers:
+            dparams[k] = np.asarray(v)
     return dcfg, dparams
 
 
@@ -158,10 +216,23 @@ def _ln(x, g, b):
     return (x - m) * jax.lax.rsqrt(var + 1e-5) * g + b
 
 
-def _token_logits(params, cfg, tok, pos, attend):
-    """One token per lane through every layer; ``attend(l, q, k, v)``
-    owns the KV write + history attention (the only paged/unpaged
-    difference).  The ``jax.named_scope`` names (``layer<i>/attn``,
+def _block(cfg):
+    """The architecture's block: ``block(params, cfg, tok, pos, attend,
+    live) -> (logits [B, vocab], extras)``.  ``attend(l, q, k, v)`` owns
+    the KV write + history attention (the only paged/unpaged difference);
+    ``live`` [B] bool marks the lanes that hold a sequence; ``extras`` is
+    a tuple of small arrays the step returns after its logits (the olmoe
+    block's tokens routed to each expert; nothing for gpt2)."""
+    if cfg.arch == "olmoe":
+        from ..models import olmoe
+
+        return olmoe.token_logits
+    return _token_logits
+
+
+def _token_logits(params, cfg, tok, pos, attend, live=None):
+    """The gpt2 block: one token per lane through every layer.  The
+    ``jax.named_scope`` names (``layer<i>/attn``,
     ``.../kv_write``, ``.../kv_gather``, ``layer<i>/mlp``, ``lm_head``)
     are metadata: they reach each HLO instruction's ``op_name``, so a
     device trace can be grouped by them, and change nothing computed."""
@@ -186,7 +257,7 @@ def _token_logits(params, cfg, tok, pos, attend):
                     + p("b2")
     with jax.named_scope("lm_head"):
         x = _ln(x, params["lnf_g"], params["lnf_b"])
-        return x @ params["head"]
+        return x @ params["head"], ()
 
 
 # -- paged step --------------------------------------------------------------
@@ -198,13 +269,16 @@ def _write_rows(pool, blk_ids, offs, rows):
     donated array, which XLA:TPU updates in its buffer.  Idle lanes all
     name row (0, 0) of the scratch block, so the indices are not unique
     and nothing is promised about them: which idle lane's row lands there
-    is unspecified, and nothing reads it."""
-    return pool.at[blk_ids, offs].set(rows.reshape(rows.shape[0], -1))
+    is unspecified, and nothing reads it.  Rows take the pool's dtype
+    here (a bf16 pool rounds the block's float32 K and V once)."""
+    return pool.at[blk_ids, offs].set(
+        rows.reshape(rows.shape[0], -1).astype(pool.dtype))
 
 
 def make_paged_step(cfg, kv_config):
     """-> step(kv_carry, params, tok, pos, block_tables, context_lens)
-    returning (new_kv_carry, next_tokens, logits).
+    returning (new_kv_carry, next_tokens, logits) and then the block's
+    extras, if it has any (``_block``).
 
     ``kv_carry`` is ``PagedKVCache.carry()``: per-layer pools, K then V
     (then their scales for int8), donated by ``CarriedStepFn`` and written
@@ -227,6 +301,7 @@ def make_paged_step(cfg, kv_config):
     have produced, so output parity is structural, not numerical."""
     bs = kv_config.block_size
     int8 = kv_config.dtype == "int8"
+    block = _block(cfg)
 
     def step(kv_carry, params, tok, pos, block_tables, context_lens):
         tok = tok.astype(jnp.int32)
@@ -262,9 +337,11 @@ def make_paged_step(cfg, kv_config):
                     for g in (0, 1))
             return masked_attention(q, kk, vv, context_lens)
 
-        logits = _token_logits(params, cfg, tok, pos, attend)
+        logits, extras = block(params, cfg, tok, pos, attend,
+                               context_lens > 0)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return tuple(a for group in pools for a in group), nxt, logits
+        return (tuple(a for group in pools for a in group), nxt,
+                logits) + tuple(extras)
 
     return step
 
@@ -275,7 +352,8 @@ def make_paged_step_multi(cfg, kv_config, width):
     """-> step(kv_carry, params, tok, pos, block_tables, context_lens)
     scoring ``width`` query tokens per lane in ONE call: tok/pos/
     context_lens are [B, width], block_tables stays [B, MAXB]; returns
-    (new_kv_carry, next_tokens [B, width], logits [B, width, vocab]).
+    (new_kv_carry, next_tokens [B, width], logits [B, width, vocab]) and
+    then the block's extras summed over the columns.
 
     The body is the single-token step composed ``width`` times inside
     one jit — each position runs the IDENTICAL write-then-attend op
@@ -293,7 +371,7 @@ def make_paged_step_multi(cfg, kv_config, width):
         tok = tok.astype(jnp.int32)
         pos = pos.astype(jnp.int32)
         context_lens = context_lens.astype(jnp.int32)
-        nxts, logits = [], []
+        nxts, logits, extras = [], [], None
         for j in range(width):
             tok_j = tok[:, j]
             if nxts:
@@ -304,11 +382,15 @@ def make_paged_step_multi(cfg, kv_config, width):
                 # copying the pool (XLA:CPU does) instead of writing in
                 # place after the read.
                 tok_j = jnp.where(nxts[-1] < 0, nxts[-1], tok_j)
-            kv_carry, nxt, lg = base(kv_carry, params, tok_j, pos[:, j],
-                                     block_tables, context_lens[:, j])
+            kv_carry, nxt, lg, *more = base(
+                kv_carry, params, tok_j, pos[:, j], block_tables,
+                context_lens[:, j])
             nxts.append(nxt)
             logits.append(lg)
-        return kv_carry, jnp.stack(nxts, axis=1), jnp.stack(logits, axis=1)
+            extras = more if extras is None \
+                else [a + b for a, b in zip(extras, more)]
+        return (kv_carry, jnp.stack(nxts, axis=1),
+                jnp.stack(logits, axis=1)) + tuple(extras)
 
     return step
 
@@ -339,11 +421,11 @@ def make_draft_rollout(cfg, kv_config, k):
         live = context_lens > 0
         props = []
         for j in range(k):
-            kv_carry, nxt, _lg = base(
+            kv_carry, nxt = base(
                 kv_carry, params, tok,
                 jnp.minimum(pos + j, max_pos), block_tables,
                 jnp.where(live,
-                          jnp.minimum(context_lens + j, max_pos + 1), 0))
+                          jnp.minimum(context_lens + j, max_pos + 1), 0))[:2]
             props.append(nxt)
             tok = nxt
         return kv_carry, jnp.stack(props, axis=1)
@@ -357,6 +439,7 @@ def make_unpaged_step(cfg, pad_len):
     """Reference step over contiguous per-lane K/V [L, B, pad_len, H, D].
     Same ``masked_attention`` core at the same [B, pad_len, H, D] shapes
     as the paged gather path — the bitwise comparison target."""
+    block = _block(cfg)
 
     def step(kv_carry, params, tok, pos, context_lens):
         tok = tok.astype(jnp.int32)
@@ -367,11 +450,12 @@ def make_unpaged_step(cfg, pad_len):
 
         def attend(l, q, k, v):
             nonlocal k_c, v_c
-            k_c = k_c.at[l, lanes, pos].set(k)
-            v_c = v_c.at[l, lanes, pos].set(v)
+            k_c = k_c.at[l, lanes, pos].set(k.astype(k_c.dtype))
+            v_c = v_c.at[l, lanes, pos].set(v.astype(v_c.dtype))
             return masked_attention(q, k_c[l], v_c[l], context_lens)
 
-        logits = _token_logits(params, cfg, tok, pos, attend)
+        logits, _extras = block(params, cfg, tok, pos, attend,
+                                context_lens > 0)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
         return (k_c, v_c), nxt, logits
 
@@ -388,10 +472,11 @@ def unpaged_generate(cfg, params, prompt_ids, max_new, pad_len=None,
         pad_len = cfg.max_seq
     step = jax.jit(make_unpaged_step(cfg, pad_len), donate_argnums=(0,))
     jparams = {k: jnp.asarray(v) for k, v in params.items()}
-    kv = (jnp.zeros((cfg.layers, 1, pad_len, cfg.heads, cfg.head_dim),
-                    jnp.float32),
-          jnp.zeros((cfg.layers, 1, pad_len, cfg.heads, cfg.head_dim),
-                    jnp.float32))
+    # K and V in the residency the paged pool would have (bf16 for a bf16
+    # model; the f32 default otherwise: int8 has no unpaged twin)
+    kv_dtype = jnp.bfloat16 if cfg.kv_dtype == "bf16" else jnp.float32
+    kv = tuple(jnp.zeros((cfg.layers, 1, pad_len, cfg.heads, cfg.head_dim),
+                         kv_dtype) for _ in range(2))
     prompt_ids = [int(t) for t in prompt_ids]
     out, logits_hist = [], []
     tok = prompt_ids[0]

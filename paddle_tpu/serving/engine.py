@@ -1027,7 +1027,8 @@ class DecodeEngine:
                 else _flag("speculative_k") or 0)
         if draft is None:
             k = 0   # no draft bundle -> non-speculative regardless of k
-        resident = sum(int(np.asarray(v).nbytes) for v in params.values())
+        # .nbytes of a device array is read without copying it to the host
+        resident = sum(int(v.nbytes) for v in params.values())
         draft_resident = 0
         if k > 0:
             dcfg, dparams = draft
@@ -1038,13 +1039,14 @@ class DecodeEngine:
                 raise ValueError("draft max_seq %d != target max_seq %d "
                                  "(block tables must line up)"
                                  % (dcfg.max_seq, cfg.max_seq))
-            draft_resident = sum(int(np.asarray(v).nbytes)
-                                 for v in dparams.values())
+            draft_resident = sum(int(v.nbytes) for v in dparams.values())
         kv_config = _kvc.KVCacheConfig(
             layers=cfg.layers, heads=cfg.heads, head_dim=cfg.head_dim,
             block_size=int(_flag("kv_block_size")),
             num_blocks=2,  # placeholder; plan_num_blocks decides below
-            dtype=str(_flag("kv_cache_dtype")))
+            # the model's own residency (a bf16 model keeps a bf16 cache),
+            # else the deployment's flag
+            dtype=cfg.kv_dtype or str(_flag("kv_cache_dtype")))
         n, capped = _kvc.plan_num_blocks(
             kv_config, model_resident_bytes=resident + draft_resident,
             requested=kv_blocks)
@@ -1122,7 +1124,7 @@ class DecodeEngine:
 
     def spec(self, model):
         m = self._models[model]
-        out = {"model": model, "type": "decode",
+        out = {"model": model, "type": "decode", "arch": m.cfg.arch,
                "vocab": m.cfg.vocab, "max_seq": m.cfg.max_seq,
                "buckets": list(self.buckets), "mode": self.mode,
                "block_size": m.kv_config.block_size,
@@ -1570,6 +1572,10 @@ class DecodeEngine:
             m = self._model_of(seq)
             if m.prefix is None or not bool(_flag("session_migration")):
                 self._refuse_export(req_id, "disabled")
+            if m.kv_config.dtype == "bf16":
+                # codec.py frames an array by numpy's dtype string, which
+                # bfloat16 does not have: refuse, never mis-encode
+                self._refuse_export(req_id, "dtype")
             bs = m.kv_config.block_size
             # steady decode keeps n_fed == len(prompt ++ out) - 1 (the
             # last emitted token is fed by the NEXT step); a preempted
@@ -2265,10 +2271,17 @@ class DecodeEngine:
         try:
             with _tr.activate(sspan), _tr.phase("serving.dispatch"):
                 # threadlint: waive CC102 continuous-batching contract: the device step runs under _cond so lane state is frozen for the whole step (see _decode_step_locked docstring); submitters park on the cond, never spin
-                carry, nxt, _logits = m.stepfn(*args)
+                carry, nxt, _logits, *extras = m.stepfn(*args)
             with _tr.phase("serving.fetch"):
                 m.cache.replace_carry(carry)
+                # a routed step's counts ride with the tokens, started
+                # before the wait, and only while the span is recorded
+                if extras and _tr.enabled():
+                    extras[0].copy_to_host_async()
+                else:
+                    extras = None
                 nxt = np.asarray(nxt)
+                moe = self._moe_attrs(m, extras)
         except Exception as e:
             for s in lanes:
                 self._active.remove(s)
@@ -2321,8 +2334,28 @@ class DecodeEngine:
             _tm.observe("decode_batch_occupancy",
                         len(lanes) / float(bucket), model=m.name)
         self._close_step_span(sspan, generated=n_generated,
-                              ms=round(ms, 3), gap_us=gap_us)
+                              ms=round(ms, 3), gap_us=gap_us, **moe)
         return True
+
+    @staticmethod
+    def _moe_attrs(m, extras):
+        """A routed-expert step returns the tokens it sent to each expert
+        in each layer (int32 [layers, experts], live lanes only).  The
+        caller hands them over only while the step span is being recorded,
+        so an untraced window pays for no transfer; a step with no experts
+        has none."""
+        if not extras:
+            return {}
+        routed = np.asarray(extras[0])
+        hit = float((routed > 0).sum(axis=1).mean())
+        _tm.inc("moe_tokens_routed_total", int(routed.sum()), model=m.name)
+        _tm.set_gauge("moe_experts_hit", hit, model=m.name)
+        # means over the layers: experts with a token, the fullest
+        # expert's tokens, and the tokens routed (lanes x experts a token)
+        return {"moe_experts_hit": round(hit, 3),
+                "moe_load_max": round(float(routed.max(axis=1).mean()), 3),
+                "moe_assignments": round(float(routed.sum(axis=1).mean()),
+                                         3)}
 
     def _spec_step_locked(self, m):
         """One speculative iteration (lock held): the draft decoder
@@ -2435,7 +2468,7 @@ class DecodeEngine:
                 with _tr.span("serving.verify", lanes=len(lanes),
                               width=width), _tr.phase("serving.dispatch"):
                     # threadlint: waive CC102 target-model verify runs under _cond by the same frozen-lane contract as stepfn in _decode_step_locked
-                    carry, nxt, _logits = m.verifyfn(
+                    carry, nxt, _logits, *_extras = m.verifyfn(
                         m.cache.carry(), m.params, tok, pos, tables, lens)
                 with _tr.phase("serving.fetch"):
                     m.cache.replace_carry(carry)
@@ -2557,7 +2590,7 @@ class DecodeEngine:
                                  lanes=len(ingest)), \
                         _tr.phase("serving.dispatch"):
                     # threadlint: waive CC102 draft-cache ingest runs under _cond by the same frozen-lane contract as stepfn in _decode_step_locked
-                    dcarry, _nx, _lg = m.ingestfn(
+                    dcarry, _nx, _lg, *_extras = m.ingestfn(
                         m.draft_cache.carry(), m.draft_params,
                         itok, ipos, itables, ilens)
                 m.draft_cache.replace_carry(dcarry)

@@ -40,8 +40,11 @@ cached prefix of a new prompt (capped at ``len(prompt) - 1`` tokens so
 prefill always computes at least one tail token and never writes into a
 shared block), and ``publish`` is first-publisher-wins.
 
-Residency dtype (FLAGS_kv_cache_dtype): ``f32`` keeps bitwise parity
-with the unpaged reference loop; ``int8`` stores quantized blocks plus
+Residency dtype (FLAGS_kv_cache_dtype, unless the model names its own:
+``DecoderConfig.kv_dtype``): ``f32`` keeps bitwise parity with the
+unpaged reference loop; ``bf16`` is a bf16 model's cache (K and V rounded
+once at the write, half the bytes, and the unpaged loop keeps bf16 K/V
+too, so parity stays bitwise); ``int8`` stores quantized blocks plus
 per-(block, position, head) max-abs scales — the EQuARX
 quantize-for-the-wire idiom (PAPERS.md arXiv 2506.17615) applied to
 residency, ~4x the tokens per HBM byte.
@@ -91,6 +94,11 @@ UNLOCKED_CALLBACKS = (
 )
 
 
+# residency dtype -> (payload dtype, payload bytes a value)
+_PAYLOAD = {"f32": (jnp.float32, 4), "bf16": (jnp.bfloat16, 2),
+            "int8": (jnp.int8, 1)}
+
+
 class KVCacheConfig:
     """Static cache geometry; hidden = heads * head_dim per layer."""
 
@@ -99,8 +107,9 @@ class KVCacheConfig:
 
     def __init__(self, layers, heads, head_dim, block_size, num_blocks,
                  dtype="f32"):
-        if dtype not in ("f32", "int8"):
-            raise ValueError("kv_cache dtype must be f32|int8: %r" % dtype)
+        if dtype not in _PAYLOAD:
+            raise ValueError("kv_cache dtype must be f32|bf16|int8: %r"
+                             % (dtype,))
         if block_size <= 0 or num_blocks <= 1:
             raise ValueError("need block_size > 0 and num_blocks > 1 "
                              "(block 0 is the idle-lane scratch)")
@@ -115,11 +124,9 @@ class KVCacheConfig:
 def block_bytes(config):
     """HBM bytes ONE block costs across all layers (K + V, + scales for
     int8)."""
-    per_tok = config.heads * config.head_dim
+    tok = config.heads * config.head_dim * _PAYLOAD[config.dtype][1]
     if config.dtype == "int8":
-        tok = per_tok * 1 + config.heads * 4        # int8 payload + scales
-    else:
-        tok = per_tok * 4
+        tok += config.heads * 4                     # f32 scales
     return 2 * config.layers * config.block_size * tok
 
 
@@ -588,11 +595,9 @@ class PagedKVCache:
         self.allocator = BlockAllocator(config.num_blocks, reserve=1)
         rows = (config.num_blocks, config.block_size)
         payload = rows + (config.heads * config.head_dim,)
+        groups = [(payload, _PAYLOAD[config.dtype][0])] * 2
         if config.dtype == "int8":
-            groups = [(payload, jnp.int8)] * 2 \
-                + [(rows + (config.heads,), jnp.float32)] * 2
-        else:
-            groups = [(payload, jnp.float32)] * 2
+            groups += [(rows + (config.heads,), jnp.float32)] * 2
         self._carry = tuple(jnp.zeros(shape, dtype)
                             for shape, dtype in groups
                             for _ in range(config.layers))
@@ -630,7 +635,7 @@ class PagedKVCache:
 
     def export_block(self, block):
         """Host copies of one physical block, one array per carry group:
-        ``[k, v]`` for f32 residency, ``[k, v, k_scales, v_scales]`` for
+        ``[k, v]`` for f32 and bf16 residency, ``[k, v, k_scales, v_scales]`` for
         int8, each stacked over the layers in its wire shape.  The wire
         payload IS the residency payload — prefill's compiled step is
         deterministic, so an adopted block is bitwise-identical to the

@@ -445,6 +445,10 @@ class ServingServer:
         off the carry and queue the transfer frame."""
         if self._xfer is None:
             return
+        if m.kv_config.dtype == "bf16":
+            # the frame codec has no bfloat16 (engine.export_session)
+            _tm.inc("kv_migrate_refused_total", reason="dtype")
+            return
         try:
             arrays = m.cache.export_block(s.blocks[j])
         except Exception:

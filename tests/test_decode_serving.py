@@ -281,7 +281,7 @@ def test_preemption_recompute_is_deterministic(cache_dir, telemetry_on):
 # -- the step's write ----------------------------------------------------------
 
 
-@pytest.mark.parametrize("dtype", ["f32", "int8"])
+@pytest.mark.parametrize("dtype", ["f32", "bf16", "int8"])
 def test_step_writes_one_row_per_lane_and_nothing_else(dtype):
     """The feed-planning contract, on the pool itself: a step writes row
     ``(block_tables[b, pos // bs], pos % bs)`` of every layer's pools for

@@ -83,3 +83,55 @@ def test_decode_step_keeps_the_pool_in_place_on_v5e(one_chip, kind):
              if whole_pool_pass.search(line)]
     assert not found, found
     assert re.search(r" = %s\S* scatter\(" % pool_shape, compiled.as_text())
+
+
+def test_olmoe_step_streams_its_experts_and_keeps_the_pool_in_place(one_chip):
+    """OLMoE's published widths (16 heads of 128, 64 experts of 1024, 2048
+    positions served), 2 layers, bucket 32, a bf16 pool of 2048 blocks: the
+    compiled step aliases the whole pool, holds no copy or transpose of an
+    expert tensor (0.27e9 bytes each: the matmuls read the weights as they
+    lie), and needs beside its arguments about one layer's gathered K and V
+    (PERF.md section 6, PR 27: 0.28e9 for any depth)."""
+    from benchmark.models import olmoe_decoder
+
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "olmoe-1b-7b-serve.json")) as fp:
+        config = dict(json.load(fp), num_hidden_layers=2)
+    cfg = olmoe_decoder.decoder_config(config)
+    assert (cfg.heads, cfg.head_dim, cfg.experts, cfg.experts_per_token,
+            cfg.ffn, cfg.max_seq, cfg.kv_dtype) == (16, 128, 64, 8, 1024,
+                                                    2048, "bf16")
+    lanes, block_size, blocks = 32, 16, 2048
+    kv = KVCacheConfig(cfg.layers, cfg.heads, cfg.head_dim, block_size,
+                       blocks, cfg.kv_dtype)
+
+    def on_chip(tree):
+        return jax.tree_util.tree_map(
+            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
+                                           sharding=one_chip), tree)
+
+    carry = on_chip(jax.eval_shape(lambda: PagedKVCache(kv).carry()))
+    params = on_chip({
+        name: jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+        for name, (shape, _kind)
+        in olmoe_decoder.param_shapes(config).items()})
+    feeds = on_chip([
+        jax.ShapeDtypeStruct((lanes,), jnp.int32),
+        jax.ShapeDtypeStruct((lanes,), jnp.int32),
+        jax.ShapeDtypeStruct((lanes, cfg.max_seq // block_size), jnp.int32),
+        jax.ShapeDtypeStruct((lanes,), jnp.int32)])
+    compiled = jax.jit(dm.make_paged_step(cfg, kv), donate_argnums=(0,)
+                       ).lower(carry, params, *feeds).compile()
+    out = jax.eval_shape(dm.make_paged_step(cfg, kv), carry, params, *feeds)
+    assert out[3].shape == (cfg.layers, cfg.experts)       # routed counts
+
+    pool_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in carry)
+    gathered = lanes * cfg.max_seq * cfg.hidden * 2         # one K or V
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= pool_bytes
+    assert memory.temp_size_in_bytes < 2.5 * gathered
+    expert_pass = re.compile(
+        r" = bf16\[64,(2048,1024|1024,2048)\]\S* (copy|transpose|convert)\(")
+    found = [line.strip()[:160] for line in compiled.as_text().splitlines()
+             if expert_pass.search(line)]
+    assert not found, found

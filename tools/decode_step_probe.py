@@ -1,7 +1,9 @@
 """Probe of the decode step alone, on the chip: no server, no wire, no
-scheduler.  GPT-2-medium widths (``benchmark/configs/gpt2-medium-serve.json``)
-at one lane bucket and one pool size, through the same ``CarriedStepFn`` the
-engine uses.  Prints, and writes under ``chiprun_out/``, one JSON object:
+scheduler.  One serving configuration of the benchmark (``--config``: a
+name under ``benchmark/configs/`` or a path; GPT-2-medium by default) at
+its own widths, weight dtype and KV residency, at one lane bucket and one
+pool size, through the same ``CarriedStepFn`` the engine uses.  Prints, and
+writes under ``chiprun_out/``, one JSON object:
 
 * ``memory``: the compiled step's ``temp_bytes`` / ``alias_bytes`` beside the
   pool's bytes, and the pool-sized instructions left in its HLO;
@@ -12,6 +14,14 @@ engine uses.  Prints, and writes under ``chiprun_out/``, one JSON object:
   ``op_name`` metadata; ``top_ops`` names the dearest single instructions.
 
     chiprun -- python tools/decode_step_probe.py --blocks 1024 --bucket 32
+    chiprun -- python tools/decode_step_probe.py --config olmoe-1b-7b-serve \
+        --blocks 2048
+
+For a routed-expert configuration the result also gives ``moe/experts``'
+achieved bytes/s (``benchmark/moe_cost.py`` over the scope's device time),
+and ``--experts ragged`` swaps the block's expert matmuls for this file's
+``experts_ragged`` (tokens sorted by expert, ``jax.lax.ragged_dot``): the
+comparison the block's choice was made by, not an option of the program.
 
 It needs the TPU for a time; ``--compile-only`` stops after ``memory`` (it
 then says what the local backend's compiler made, which is not the chip's).
@@ -51,8 +61,8 @@ def scope_of(op_name):
     parts = [p for p in op_name.split("/") if not p.startswith("jit(")]
     parts = [re.sub(r"^layer\d+$", "layerN", p) for p in parts]
     keep = [p for p in parts
-            if p in ("layerN", "attn", "mlp", "lm_head", "kv_write",
-                     "kv_gather")]
+            if p in ("layerN", "attn", "mlp", "moe", "router", "experts",
+                     "lm_head", "kv_write", "kv_gather")]
     return "/".join(keep) or "other"
 
 
@@ -70,12 +80,42 @@ def pool_sized(index, pool_elems):
     return found
 
 
+def experts_ragged(k):
+    """``models/olmoe.py`` ``_experts`` by sorting: each lane's ``k``
+    chosen experts become ``B * k`` rows ordered by expert, and one
+    ``ragged_dot`` per projection runs each expert over its own rows."""
+    import jax
+    import jax.numpy as jnp
+
+    def experts(h2, gates, wgate, wup, wdown):
+        weight, idx = jax.lax.top_k(gates, k)
+        order = jnp.argsort(idx.reshape(-1))
+        lane = order // k
+        sizes = jnp.bincount(idx.reshape(-1), length=gates.shape[1]
+                             ).astype(jnp.int32)
+        dot = lambda x, w: jax.lax.ragged_dot(
+            x.astype(w.dtype), w, sizes,
+            preferred_element_type=jnp.float32)
+        x = h2[lane]
+        y = dot(jax.nn.silu(dot(x, wgate)) * dot(x, wup), wdown)
+        y = y * weight.reshape(-1)[order][:, None]
+        return jnp.zeros(h2.shape, jnp.float32).at[lane].add(y)
+
+    return experts
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
+    ap.add_argument("--config", default="gpt2-medium-serve",
+                    help="a serving configuration of the benchmark: a name "
+                    "under benchmark/configs/ or a path to such a file")
+    ap.add_argument("--experts", default="block",
+                    choices=("block", "ragged"))
     ap.add_argument("--blocks", type=int, default=1024)
     ap.add_argument("--bucket", type=int, default=32)
     ap.add_argument("--block-size", type=int, default=16)
-    ap.add_argument("--dtype", default="f32")
+    ap.add_argument("--dtype", default=None,
+                    help="KV residency (default: the model's own, else f32)")
     ap.add_argument("--layers", type=int, default=None)
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--seed", type=int, default=2147483659)
@@ -87,20 +127,29 @@ def main(argv=None):
     import jax
     import numpy as np
 
-    from benchmark import trace_reduce
-    from benchmark.models import gpt2_decoder
+    from benchmark import moe_cost, trace_reduce
+    from benchmark.run import load_module
     from paddle_tpu.core.executor import CarriedStepFn
     from paddle_tpu.serving import decode_model as dm
     from paddle_tpu.serving import kv_cache as kvc
 
-    with open(os.path.join(ROOT, "benchmark", "configs",
-                           "gpt2-medium-serve.json")) as fp:
+    path = args.config if os.path.exists(args.config) else os.path.join(
+        ROOT, "benchmark", "configs", args.config + ".json")
+    with open(path) as fp:
         config = json.load(fp)
+    config.pop("tiny", None)
     if args.layers:
-        config["n_layer"] = args.layers
+        config["n_layer" if "n_layer" in config
+               else "num_hidden_layers"] = args.layers
     device = jax.devices()[0]
-    cfg = gpt2_decoder.decoder_config(config)
-    params = gpt2_decoder.make_params(config, args.seed, device)
+    model = load_module("models", config["model"])
+    cfg = model.decoder_config(config)
+    params = model.make_params(config, args.seed, device)
+    if args.experts == "ragged":
+        from paddle_tpu.models import olmoe
+
+        olmoe._experts = experts_ragged(cfg.experts_per_token)
+    args.dtype = args.dtype or cfg.kv_dtype or "f32"
     kv = kvc.KVCacheConfig(cfg.layers, cfg.heads, cfg.head_dim,
                            args.block_size, args.blocks, args.dtype)
     cache = kvc.PagedKVCache(kv)
@@ -134,7 +183,8 @@ def main(argv=None):
     index = hlo_index(text)
     pool_elems = args.blocks * args.block_size * cfg.hidden
     result = {
-        "label": args.label, "device": device.device_kind,
+        "label": args.label, "config": config["name"],
+        "experts": args.experts, "device": device.device_kind,
         "platform": device.platform, "blocks": args.blocks,
         "bucket": b, "dtype": args.dtype, "layers": cfg.layers,
         "memory": {"temp_bytes": int(memory.temp_size_in_bytes),
@@ -151,7 +201,7 @@ def main(argv=None):
 
         def run(n0, n):
             for i in range(n0, n0 + n):
-                carry, nxt, _logits = stepfn(*feed(i))
+                carry, nxt = stepfn(*feed(i))[:2]
                 cache.replace_carry(carry)
                 nxt.block_until_ready()
 
@@ -179,6 +229,14 @@ def main(argv=None):
             [n, round(s * 1e3 / args.steps, 4),
              "/".join(index.get(short(n), ("", "", ""))[::2])[:120]]
             for n, s in prof["device_ops"]]
+        moe_ms = sum(v for k, v in scopes.items() if k.endswith("experts"))
+        if cfg.arch == "olmoe" and moe_ms:
+            # every expert of every layer, read once a step (32 lanes x 8
+            # over 64 experts leave none unread), over the scope's time
+            moved = moe_cost.expert_stream_bytes_per_step(
+                config, config["num_experts"])
+            result["moe_experts_bytes_per_step"] = moved
+            result["moe_experts_bytes_per_s"] = moved / (moe_ms / 1e3)
         stats = device.memory_stats() or {}
         result["peak_bytes_in_use"] = stats.get("peak_bytes_in_use")
         result["peak_bytes_reserved"] = stats.get("peak_bytes_reserved")

@@ -72,18 +72,37 @@ def save_demo_model(dirname, in_dim=8, out_dim=4):
     return dirname
 
 
+def demo_decoder_config(path):
+    """A DecoderConfig from a file: a bundle's ``decoder.json`` (the
+    config's own keys), or a serving configuration of the benchmark
+    (``benchmark/configs/<name>.json``), read through the model module it
+    names at its ``tiny`` sizes: a demo bundle is written to disk, and the
+    published widths are gigabytes."""
+    from benchmark.run import load_module, with_tiny
+    from paddle_tpu.serving.decode_model import DecoderConfig
+
+    with open(path) as fp:
+        data = json.load(fp)
+    if "model" not in data:
+        return DecoderConfig(**data)
+    return load_module("models", data["model"]).decoder_config(
+        with_tiny(data, True))
+
+
 def save_demo_decoder(dirname, vocab=31, layers=2, heads=2, head_dim=8,
-                      max_seq=48, seed=7):
+                      max_seq=48, seed=7, config=None):
     """Tiny decode model via serving.decode_model.save_decoder, bundled
     with a first-layer-truncation draft so FLAGS_speculative_k > 0 can
-    speculate out of the box."""
+    speculate out of the box.  ``config`` (``demo_decoder_config``) names
+    the architecture and sizes instead of the six numbers."""
     from paddle_tpu.serving.decode_model import (DecoderConfig,
                                                  init_decoder_params,
                                                  save_decoder,
                                                  truncate_decoder)
 
-    cfg = DecoderConfig(vocab=vocab, layers=layers, heads=heads,
-                        head_dim=head_dim, max_seq=max_seq)
+    cfg = demo_decoder_config(config) if config else DecoderConfig(
+        vocab=vocab, layers=layers, heads=heads, head_dim=head_dim,
+        max_seq=max_seq)
     params = init_decoder_params(cfg, seed=seed)
     return save_decoder(dirname, cfg, params,
                         draft=truncate_decoder(cfg, params, layers=1))
@@ -144,6 +163,10 @@ def main(argv=None):
     ap.add_argument("--save-demo-decoder", metavar="DIR", default=None,
                     help="write a tiny autoregressive decoder to DIR "
                     "and exit")
+    ap.add_argument("--demo-decoder-config", metavar="FILE", default=None,
+                    help="with --save-demo-decoder: the decoder's "
+                    "architecture and sizes from a decoder.json or a "
+                    "benchmark configuration file (its tiny sizes)")
     ap.add_argument("--decode-buckets", default=None,
                     help="decode lane buckets, e.g. 4,8 "
                     "(default FLAGS_serving_decode_buckets)")
@@ -191,7 +214,8 @@ def main(argv=None):
         return 0
     if args.save_demo_decoder:
         print("saved demo decoder:",
-              save_demo_decoder(args.save_demo_decoder))
+              save_demo_decoder(args.save_demo_decoder,
+                                config=args.demo_decoder_config))
         return 0
 
     from paddle_tpu.core import tracing
