@@ -317,11 +317,6 @@ _DEFAULTS = {
     # (block, position, head) max-abs scales; ~4x the f32 capacity per
     # byte of HBM at a small accuracy cost)
     "FLAGS_kv_cache_dtype": "f32",
-    # opt-in Pallas paged-attention gather kernel
-    # (pallas_kernels/paged_attention.py): scalar-prefetched block tables
-    # steer the K/V block DMA so the gathered [B, S, H, D] intermediate
-    # never materializes in HBM.  Probe-gated like every PR-9 kernel.
-    "FLAGS_use_pallas_paged_attention": False,
     # draft-model speculative decoding on the paged decode path
     # (DecodeEngine): 0 = off; k > 0 runs the model's bundled draft
     # decoder (save_decoder(draft=...) / <model_dir>/draft) k tokens

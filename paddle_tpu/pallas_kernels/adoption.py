@@ -229,7 +229,9 @@ def decide(kernel, flag=None, checks=(), require_probe=True):
     `require_probe=False` is for kernels whose adoption predates the probe
     protocol and is pinned by in-step BASELINE numbers instead (fused_ln:
     the round-3 LN lesson is that a microbench win is necessary but not
-    sufficient, so an in-step capture outranks the probe row)."""
+    sufficient, so an in-step capture outranks the probe row;
+    paged_attention: no flag either, pinned by the serving cells of
+    BENCHMARK.json)."""
     from .. import flags as _flags
 
     if flag is not None and not _flags.flag(flag):

@@ -1,34 +1,43 @@
-"""Paged-attention gather kernel for the decode serving path.
+"""Paged attention for the decode serving path: the kernel that reads the
+KV blocks where they lie, and the jnp path it is checked against.
 
-The decode step (serving/decode_model.py) attends one query token per
-sequence against that sequence's KV history, which lives scattered across
-fixed-size cache blocks (serving/kv_cache.py) named by a per-sequence
-block table.  The generic lowering gathers the blocks into a contiguous
-``[B, S, H, D]`` intermediate (``jnp.take`` over the block axis) and runs
-masked attention over it — B*S*H*D of HBM writes + reads that exist only
-to be reduced.  This kernel uses the scalar-prefetched block table to
-steer the K/V block DMA directly (the embedding-bag idiom): grid step
-(b, j) fetches ONE ``(block_size, H, D)`` K block and V block chosen by
-``block_tables[b, j]`` and folds them into an online-softmax accumulator
-in VMEM, so the gathered intermediate never materializes.
+The decode step (serving/decode_model.py) attends one query token per lane
+against that lane's KV history, which lies scattered over the fixed-size
+blocks of the layer's pool (serving/kv_cache.py: ``[num_blocks,
+block_size, H * D]``, heads folded) and is named by the lane's row of the
+block table.
 
-Positions at or beyond ``context_lens[b]`` are masked with a large
-negative before the softmax (finite, so a fully-masked idle lane yields a
-uniform distribution instead of NaN — the engine discards idle-lane
-output anyway).  ``masked_attention`` is the shared jnp core: the paged
-reference gathers blocks and calls it, and the UNPAGED reference loop in
-decode_model.py calls the very same function on contiguous K/V — that
-sharing is what makes paged-vs-unpaged decode bitwise-comparable on the
-CPU tier.
+``paged_attention`` picks the path from what it can see, with no flag:
 
-Adoption: FLAGS_use_pallas_paged_attention + ``paged_attention_checks``
-eligibility + a >= 1.1x tools/probes row, all through adoption.decide()
-(interpret mode waives backend + probe for the CPU parity tests).  The
-kernel reads pools with the heads split, ``[num_blocks, block_size, H,
-D]``; the serving cache keeps them folded, ``[num_blocks, block_size,
-H * D]`` (serving/kv_cache.py says why), which the ``rank`` check
-declines: the decode step takes the jnp path until the kernel's block
-specs read folded rows (splitting the heads on the pool would copy it).
+* **the kernel** (scope ``kv_read``), on a TPU backend, for a rank-3 pool
+  in float32 or bfloat16 whose ``H * D`` is a multiple of the 128 lanes and
+  whose ``block_size`` is a multiple of the dtype's sublane tile.  One
+  invocation walks the lanes; for each it loops over chunks of
+  ``CHUNK_TOKENS`` positions up to ``context_lens[b]``, fetches the chunk's
+  blocks from the pools in HBM by async copies steered by the
+  scalar-prefetched table (double buffered, the next chunk or the next
+  lane's first in flight while this one is reduced), and folds them into
+  an online softmax.  Work follows the live context: an idle lane
+  (``context_lens`` 0) fetches nothing and returns zeros; nothing of the
+  table's size is written.
+* **the gather** (scope ``kv_gather``) everywhere else: on the CPU tier,
+  for the int8 residency (gather, dequantize), in a program XLA partitions
+  over a mesh.  ``gather_blocks`` copies every slot of the padded table
+  into contiguous history and ``masked_attention`` reduces it.
+
+Both compute ``masked_attention``'s mathematics at its precision: head h's
+scores are row h of a block-diagonal query against the folded rows, so
+every dot is a plain 2-D matmul over ``H * D``.  Against a bfloat16 pool
+the query and the probabilities are rounded to bfloat16 and the MXU
+accumulates in float32.  Against a float32 pool every product is float32:
+each operand is split into three bfloat16 pieces and the six products
+``Precision.HIGHEST`` keeps are taken, the pieces of the query (and of the
+probabilities) stacked as rows so that each piece of K (and V) passes
+through the MXU once.  Only the order of the softmax's sums differs.
+
+``masked_attention`` is also the core of the UNPAGED reference loop in
+decode_model.py: sharing it is what makes paged-vs-unpaged decode
+bitwise-comparable on the CPU tier.
 """
 
 import functools
@@ -43,9 +52,23 @@ from jax.experimental.pallas import tpu as pltpu
 from . import adoption
 
 __all__ = ["paged_attention", "paged_attention_reference",
-           "paged_attention_checks", "masked_attention", "gather_blocks"]
+           "paged_attention_checks", "attention_path", "blocks_read",
+           "masked_attention", "gather_blocks"]
 
 _MASK = -1e30  # finite: a fully-masked lane softmaxes to uniform, not NaN
+
+# positions the kernel fetches and reduces at a time (whole blocks: 8 of 16
+# tokens).  A lane's last chunk is fetched whole, so the mean waste is half
+# a chunk a lane; smaller chunks leave the MXU's 128 columns part empty.
+CHUNK_TOKENS = 128
+
+_SUBLANES = {"float32": 8, "bfloat16": 16}   # rows of a dtype's memory tile
+
+# what the kernel may hold in VMEM: its four chunk buffers, the queries and
+# the outputs.  Both serving cells hold 2.3-2.5 MB; Mosaic's scoped limit on
+# a v5e is 16 MB and the matmuls' temporaries share it (a width of 8192 is
+# refused at 18-20 MB, compiled for a described chip).
+_VMEM_BUDGET = 8 << 20
 
 
 def masked_attention(q, k, v, context_lens):
@@ -100,11 +123,10 @@ def gather_blocks(cache, block_tables):
 
 def paged_attention_reference(q, k_cache, v_cache, block_tables,
                               context_lens):
-    """jnp fallback: gather the table's blocks into contiguous K/V, then
-    masked_attention.  q [B, H, D]; k_cache/v_cache
-    [num_blocks, block_size, H, D], or the serving pool's
-    [num_blocks, block_size, H * D]: the heads are split after the
-    gather, never on the pool."""
+    """The jnp path: gather the table's blocks into contiguous K/V, then
+    masked_attention.  q [B, H, D]; k_cache/v_cache the serving pool's
+    [num_blocks, block_size, H * D] (or [num_blocks, block_size, H, D]):
+    the heads are split after the gather, never on the pool."""
     bb, h, d = q.shape
     with jax.named_scope("kv_gather"):
         k, v = (gather_blocks(c, block_tables).reshape(bb, -1, h, d)
@@ -112,102 +134,232 @@ def paged_attention_reference(q, k_cache, v_cache, block_tables,
     return masked_attention(q, k, v, context_lens)
 
 
-def paged_attention_checks(q_shape, kv_shape, dtype, block_size):
-    """Ordered (reason, ok) pairs for adoption.decide()."""
+# -- the shape rule ----------------------------------------------------------
+
+def paged_attention_checks(q_shape, kv_shape, kv_dtype):
+    """Ordered (reason, ok) pairs for adoption.decide(): what the kernel
+    needs of the query ``[B, H, D]`` and of a pool ``[num_blocks,
+    block_size, H * D]`` in ``kv_dtype``."""
     dims = tuple(q_shape) + tuple(kv_shape)
     static = all(isinstance(x, int) and x >= 0 for x in dims)
+    rank = len(q_shape) == 3 and len(kv_shape) == 3
+    tile = _SUBLANES.get(jnp.dtype(kv_dtype).name)
     return [
         ("backend", adoption.interpret_mode()
          or jax.default_backend() == "tpu"),
         ("symbolic_shape", static),
-        ("rank", len(q_shape) == 3 and len(kv_shape) == 4),
-        ("dtype", jnp.dtype(dtype) == jnp.dtype(jnp.float32)),
-        ("head_dim", static and len(q_shape) == 3
-         and q_shape[2] % 128 == 0),
-        ("block_size", isinstance(block_size, int) and block_size > 0
-         and block_size % 8 == 0),
+        ("rank", rank),
+        ("dtype", tile is not None),
+        ("lanes", static and rank and kv_shape[2] % 128 == 0
+         and kv_shape[2] == q_shape[1] * q_shape[2]),
+        ("block_size", static and rank and tile is not None
+         and kv_shape[1] > 0 and kv_shape[1] % tile == 0),
         ("empty", static and all(x > 0 for x in dims)),
+        ("vmem", static and rank and tile is not None
+         and (4 * max(CHUNK_TOKENS, kv_shape[1]) * jnp.dtype(kv_dtype).itemsize
+              + 2 * q_shape[0] * 4) * kv_shape[2] <= _VMEM_BUDGET),
     ]
 
 
-def _paged_kernel(bt_ref, cl_ref, q_ref, k_ref, v_ref, o_ref,
-                  m_ref, l_ref, acc_ref):
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _zero():
-        m_ref[...] = jnp.full_like(m_ref, _MASK)
-        l_ref[...] = jnp.zeros_like(l_ref)
-        acc_ref[...] = jnp.zeros_like(acc_ref)
-
-    b = pl.program_id(0)
-    bs = k_ref.shape[1]
-    scale = 1.0 / math.sqrt(q_ref.shape[-1])
-    q = q_ref[0].astype(jnp.float32)                    # [H, D]
-    k = k_ref[0].astype(jnp.float32)                    # [bs, H, D]
-    s = jnp.einsum("hd,shd->hs", q, k) * scale          # [H, bs]
-    pos = j * bs + jax.lax.broadcasted_iota(jnp.int32, (1, bs), 1)
-    s = jnp.where(pos < cl_ref[b], s, _MASK)
-    # online softmax across the block-table axis (j is sequential)
-    m_prev = m_ref[...]                                 # [H, 1]
-    m_new = jnp.maximum(m_prev, jnp.max(s, axis=1, keepdims=True))
-    alpha = jnp.exp(m_prev - m_new)
-    p = jnp.exp(s - m_new)                              # [H, bs]
-    l_ref[...] = alpha * l_ref[...] + jnp.sum(p, axis=1, keepdims=True)
-    acc_ref[...] = acc_ref[...] * alpha + jnp.einsum(
-        "hs,shd->hd", p, v_ref[0].astype(jnp.float32))
-    m_ref[...] = m_new
-
-    @pl.when(j == pl.num_programs(1) - 1)
-    def _flush():
-        o_ref[0] = (acc_ref[...] / l_ref[...]).astype(o_ref.dtype)
+def attention_path(q_shape, kv_shape, kv_dtype):
+    """``"pallas"`` where the kernel would serve these shapes on this
+    backend, else ``"gather"``: the same rule as ``paged_attention``,
+    counted nowhere.  The engine names the step's path by it, in the
+    executable's cache key and on the ``serving_prewarm`` event."""
+    ok = all(ok for _reason, ok in
+             paged_attention_checks(q_shape, kv_shape, kv_dtype))
+    return "pallas" if ok else "gather"
 
 
-def _paged_pallas(q, k_cache, v_cache, block_tables, context_lens):
+def blocks_read(context_lens, block_size, maxb, path):
+    """Blocks one layer's attention fetches for these lanes: every slot of
+    the table on the gather path; on the kernel's, each lane's live blocks
+    rounded up to the kernel's chunk (a host-side count for the step's
+    span: ``context_lens`` is the numpy feed)."""
+    if path != "pallas":
+        return len(context_lens) * maxb
+    per = _chunk_blocks(block_size, maxb)
+    return int((-(-context_lens // (per * block_size))).sum()) * per
+
+
+def _chunk_blocks(block_size, maxb):
+    return max(1, min(CHUNK_TOKENS // block_size, maxb))
+
+
+# -- the kernel --------------------------------------------------------------
+
+_NT = (((1,), (1,)), ((), ()))   # a [M, K] . b [N, K] -> [M, N]
+_NN = (((1,), (0,)), ((), ()))   # a [M, K] . b [K, N] -> [M, N]
+
+
+def _split3(x):
+    """float32 -> three bfloat16 pieces whose sum is x to 2**-24."""
+    hi = x.astype(jnp.bfloat16)
+    rest = x - hi.astype(jnp.float32)
+    mid = rest.astype(jnp.bfloat16)
+    lo = (rest - mid.astype(jnp.float32)).astype(jnp.bfloat16)
+    return hi, mid, lo
+
+
+def _dot(a, b, dims):
+    return jax.lax.dot_general(a, b, dims,
+                               preferred_element_type=jnp.float32)
+
+
+def _product(rows, x, dims):
+    """``rows . x`` in ``x``'s precision.  bfloat16 ``x``: ``rows``
+    rounded to bfloat16, one pass, float32 accumulation.  float32 ``x``:
+    the six products of the operands' bfloat16 pieces that
+    ``Precision.HIGHEST`` keeps (all but lo.lo, lo.mid, mid.lo), with the
+    pieces of ``rows`` stacked so that each piece of ``x`` is contracted
+    once; summed smallest first."""
+    if x.dtype == jnp.bfloat16:
+        return _dot(rows.astype(jnp.bfloat16), x, dims)
+    n = rows.shape[0]
+    r3 = jnp.concatenate(_split3(rows), axis=0)
+    hi, mid, lo = _split3(x)
+    by_hi = _dot(r3, hi, dims)
+    by_mid = _dot(r3[:2 * n], mid, dims)
+    by_lo = _dot(r3[:n], lo, dims)
+    return ((by_lo + by_hi[2 * n:] + by_mid[n:])
+            + (by_mid[:n] + by_hi[n:2 * n])) + by_hi[:n]
+
+
+def _kernel(bt_ref, cl_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, *,
+            heads, head_dim, block_size, maxb, per):
+    lanes = q_ref.shape[0]
+    hd = heads * head_dim
+    rows = -(-heads // 16) * 16          # whole sublane tiles in any dtype
+    span = per * block_size              # positions a chunk
+    scale = 1.0 / math.sqrt(head_dim)
+
+    def chunks(b):
+        return (cl_ref[b] + span - 1) // span
+
+    def copies(slot, block_of):
+        """A chunk's 2 x ``per`` block copies into buffer ``slot``."""
+        for i in range(per):
+            at = pl.ds(i * block_size, block_size)
+            for pool, buf, which in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
+                yield pltpu.make_async_copy(
+                    pool.at[block_of(i)], buf.at[slot, at],
+                    sem.at[which, slot])
+
+    def start(b, c, slot):
+        # slots past the table's end or unused (-1) fetch block 0: their
+        # positions lie beyond the context and are masked
+        def block_of(i):
+            j = jnp.minimum(c * per + i, maxb - 1)
+            return jnp.maximum(bt_ref[b * maxb + j], 0)
+
+        for dma in copies(slot, block_of):
+            dma.start()
+
+    def wait(slot):
+        # a wait needs the copy's shape and semaphore, not its source
+        for dma in copies(slot, lambda i: 0):
+            dma.wait()
+
+    # row h of the mask covers head h's own columns of the folded width
+    col = jax.lax.broadcasted_iota(jnp.int32, (rows, hd), 1)
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, hd), 0) * head_dim
+    own = ((col >= row) & (col < row + head_dim)).astype(jnp.float32)
+
+    def lane(b, g):
+        """One lane; ``g`` counts the chunks fetched so far, so ``g % 2``
+        is the buffer this lane's first chunk lies (or will lie) in."""
+        n = chunks(b)
+        ctx = cl_ref[b]
+
+        # the lane before, when it had a chunk, fetched this one's first
+        @pl.when((n > 0) & ((b == 0) | (chunks(jnp.maximum(b - 1, 0)) == 0)))
+        def _first():
+            start(b, 0, g % 2)
+
+        qx = q_ref[b] * own                              # [rows, hd]
+
+        def chunk(c, carry):
+            m, l, acc = carry
+            slot = (g + c) % 2
+            more = c + 1 < n
+            nxt = jnp.minimum(b + 1, lanes - 1)
+
+            @pl.when(more | ((b + 1 < lanes) & (chunks(nxt) > 0)))
+            def _prefetch():
+                start(jnp.where(more, b, nxt), jnp.where(more, c + 1, 0),
+                      1 - slot)
+
+            wait(slot)
+            sc = _product(qx, kbuf[slot], _NT) * scale   # [rows, span]
+            pos = c * span + jax.lax.broadcasted_iota(
+                jnp.int32, (1, span), 1)
+            sc = jnp.where(pos < ctx, sc, _MASK)
+            m_new = jnp.maximum(m, jnp.max(sc, axis=1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(sc - m_new)
+            l = alpha * l + jnp.sum(p, axis=1, keepdims=True)
+            acc = alpha * acc + _product(p, vbuf[slot], _NN)
+            return m_new, l, acc
+
+        _m, l, acc = jax.lax.fori_loop(
+            0, n, chunk, (jnp.full((rows, 1), _MASK, jnp.float32),
+                          jnp.zeros((rows, 1), jnp.float32),
+                          jnp.zeros((rows, hd), jnp.float32)))
+        # an idle lane has l == 0 and acc == 0: zeros out, not 0 / 0
+        out = acc / jnp.where(l > 0, l, 1.0)
+        o_ref[b] = jnp.sum(out * own, axis=0, keepdims=True
+                           ).astype(o_ref.dtype)
+        return g + n
+
+    jax.lax.fori_loop(0, lanes, lane, jnp.int32(0))
+
+
+def _paged_pallas(q, k_cache, v_cache, block_tables, context_lens,
+                  interpret=None):
+    """q [B, H, D] against folded pools [num_blocks, block_size, H * D]
+    -> [B, H, D].  ``interpret`` None follows the backend."""
     bb, h, d = q.shape
-    bs = k_cache.shape[1]
+    _nb, bs, hd = k_cache.shape
     maxb = block_tables.shape[1]
-    # the prefetched table steers the K/V block DMA; unused (-1) slots
-    # clamp to block 0 and are masked off by context_lens in the kernel
-    kv_spec = pl.BlockSpec(
-        (1, bs, h, d),
-        lambda b, j, bt_ref, cl_ref: (jnp.maximum(bt_ref[b, j], 0), 0, 0, 0))
-    q_spec = pl.BlockSpec((1, h, d), lambda b, j, bt_ref, cl_ref: (b, 0, 0))
-    o_spec = pl.BlockSpec((1, h, d), lambda b, j, bt_ref, cl_ref: (b, 0, 0))
-    call = functools.partial(
-        pl.pallas_call,
-        _paged_kernel,
+    per = _chunk_blocks(bs, maxb)
+    if interpret is None:
+        interpret = adoption.interpret()
+    whole = lambda i, bt, cl: (0, 0, 0)
+    out = pl.pallas_call(
+        functools.partial(_kernel, heads=h, head_dim=d, block_size=bs,
+                          maxb=maxb, per=per),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(bb, maxb),
-            in_specs=[q_spec, kv_spec, kv_spec],
-            out_specs=o_spec,
-            scratch_shapes=[pltpu.VMEM((h, 1), jnp.float32),
-                            pltpu.VMEM((h, 1), jnp.float32),
-                            pltpu.VMEM((h, d), jnp.float32)],
+            grid=(1,),
+            in_specs=[pl.BlockSpec((bb, 1, hd), whole),
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((bb, 1, hd), whole),
+            scratch_shapes=[pltpu.VMEM((2, per * bs, hd), k_cache.dtype),
+                            pltpu.VMEM((2, per * bs, hd), v_cache.dtype),
+                            pltpu.SemaphoreType.DMA((2, 2))],
         ),
-        out_shape=jax.ShapeDtypeStruct((bb, h, d), q.dtype),
-        interpret=adoption.interpret(),
-    )
-    if not adoption.interpret():
-        # j accumulates the online softmax, so it must run sequentially
-        call = functools.partial(
-            call, compiler_params=pltpu.CompilerParams(
-                dimension_semantics=("parallel", "arbitrary")))
-    return call()(block_tables.astype(jnp.int32),
-                  context_lens.astype(jnp.int32), q, k_cache, v_cache)
+        out_shape=jax.ShapeDtypeStruct((bb, 1, hd), jnp.float32),
+        interpret=interpret,
+    )(block_tables.astype(jnp.int32).reshape(-1),
+      context_lens.astype(jnp.int32),
+      q.reshape(bb, 1, hd).astype(jnp.float32), k_cache, v_cache)
+    return out.reshape(bb, h, d)
 
 
 def paged_attention(q, k_cache, v_cache, block_tables, context_lens):
-    """Funnel-gated paged attention: the Pallas gather kernel where
-    adoption.decide() allows it, the jnp gather reference otherwise."""
+    """The step's attention over a layer's pools: the kernel where the
+    shape rule admits it (``adoption.decide`` counts the lowering under
+    ``pallas_kernel_used_total`` / ``..._fallback_total{reason}``), the
+    gather otherwise.  ``require_probe=False`` as ``fused_ln`` has it: the
+    serving cells' numbers outrank a probe row."""
     use, _reason = adoption.decide(
         "paged_attention",
-        flag="FLAGS_use_pallas_paged_attention",
-        checks=paged_attention_checks(q.shape, k_cache.shape, q.dtype,
-                                      int(k_cache.shape[1])))
+        checks=paged_attention_checks(q.shape, k_cache.shape, k_cache.dtype),
+        require_probe=False)
     if use:
-        return _paged_pallas(q, k_cache, v_cache, block_tables,
-                             context_lens)
+        with jax.named_scope("kv_read"):
+            return _paged_pallas(q, k_cache, v_cache, block_tables,
+                                 context_lens)
     return paged_attention_reference(q, k_cache, v_cache, block_tables,
                                      context_lens)

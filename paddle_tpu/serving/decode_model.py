@@ -10,8 +10,10 @@ transformer: embed + learned positions, per-layer MHA + GELU MLP, tied
 vocab head kept separate for clarity) and plugs into the SAME executor
 machinery the Program path uses: ``core.executor.CarriedStepFn`` AOT-
 compiles the step per lane bucket with tier-B disk persistence, and the
-attention gather runs through the probe-gated
-``pallas_kernels.paged_attention`` funnel.
+step's attention is ``pallas_kernels.paged_attention``: on a TPU a kernel
+that reads each lane's live KV blocks from the pool where they lie, and
+elsewhere (the CPU tier, the int8 residency) a gather of the padded table
+into ``masked_attention``, chosen by what the shapes and the backend are.
 
 Since PR 27 the decoder is one of two blocks, picked by
 ``DecoderConfig.arch``: the ``gpt2`` block of this file, in float32, and
@@ -26,15 +28,18 @@ callback:
 * ``make_paged_step``   — writes this token's K/V rows into the layer's
   own pools of the paged cache (``[num_blocks, block_size, H * D]``, block
   ids steered by the per-lane block table) and attends through
-  ``paged_attention`` over them.
+  ``paged_attention`` over them (scope ``kv_read`` where the kernel
+  serves, ``kv_gather`` where the table is gathered).
 * ``make_unpaged_step`` — the reference: contiguous per-lane K/V
   ``[L, B, S, H, D]`` updated at ``pos`` and attended via the same
   ``masked_attention`` core.
 
-Because both paths feed bitwise-identical K/V values into the identical
-attention/MLP expressions at identical shapes, paged decode is
-bitwise-equal to the unpaged loop on the CPU tier — the acceptance bar
-``unpaged_generate`` exists to prove.
+Because the gather path and the unpaged loop feed bitwise-identical K/V
+values into the identical attention/MLP expressions at identical shapes,
+paged decode is bitwise-equal to the unpaged loop on the CPU tier — the
+acceptance bar ``unpaged_generate`` exists to prove.  The kernel computes
+the same mathematics at the same precision with the softmax's sums in
+another order, so there the bar is the same tokens and logits to 1e-5.
 """
 
 import json
@@ -45,13 +50,15 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..pallas_kernels import paged_attention as _pa
 from ..pallas_kernels.paged_attention import gather_blocks, \
     masked_attention, paged_attention
 from . import kv_cache as _kv
 
 __all__ = ["DecoderConfig", "init_decoder_params", "save_decoder",
            "load_decoder", "is_decoder_dir", "has_draft", "load_draft",
-           "truncate_decoder", "make_paged_step", "make_paged_step_multi",
+           "truncate_decoder", "attention_path", "make_paged_step",
+           "make_paged_step_multi",
            "make_draft_rollout", "make_unpaged_step", "unpaged_generate"]
 
 
@@ -232,8 +239,8 @@ def _block(cfg):
 
 def _token_logits(params, cfg, tok, pos, attend, live=None):
     """The gpt2 block: one token per lane through every layer.  The
-    ``jax.named_scope`` names (``layer<i>/attn``,
-    ``.../kv_write``, ``.../kv_gather``, ``layer<i>/mlp``, ``lm_head``)
+    ``jax.named_scope`` names (``layer<i>/attn``, ``.../kv_write``,
+    ``.../kv_read`` or ``.../kv_gather``, ``layer<i>/mlp``, ``lm_head``)
     are metadata: they reach each HLO instruction's ``op_name``, so a
     device trace can be grouped by them, and change nothing computed."""
     bb = tok.shape[0]
@@ -273,6 +280,18 @@ def _write_rows(pool, blk_ids, offs, rows):
     here (a bf16 pool rounds the block's float32 K and V once)."""
     return pool.at[blk_ids, offs].set(
         rows.reshape(rows.shape[0], -1).astype(pool.dtype))
+
+
+def attention_path(cfg, kv_config, lanes=1):
+    """``"pallas"`` where ``make_paged_step``'s attention is the kernel
+    that reads the live blocks in place, for this model and pool on this
+    backend at a bucket of ``lanes`` (what holds for a bucket holds for
+    every smaller one); ``"gather"`` where it gathers the padded table
+    (the int8 residency always does)."""
+    return _pa.attention_path(
+        (lanes, cfg.heads, cfg.head_dim),
+        (kv_config.num_blocks, kv_config.block_size, cfg.hidden),
+        _kv._PAYLOAD[kv_config.dtype][0])
 
 
 def make_paged_step(cfg, kv_config):

@@ -52,6 +52,7 @@ Telemetry: ``serving_queue_depth`` gauge, ``serving_batch_fill`` +
 ``serving_request_errors_total{model}``.
 """
 
+import functools
 import threading
 import time
 import uuid
@@ -873,7 +874,8 @@ class _DecodeSeq:
 
 class _DecodeModel:
     __slots__ = ("name", "cfg", "params", "kv_config", "cache", "stepfn",
-                 "maxb", "step_ms", "prefix", "__weakref__",
+                 "maxb", "attn_path", "blocks_read", "step_ms", "prefix",
+                 "__weakref__",
                  # speculative decode (spec_k == 0 means off): the draft
                  # decoder runs k tokens ahead through its own paged pool,
                  # then verifyfn scores all k+1 positions in one target call
@@ -888,6 +890,11 @@ class _DecodeModel:
         self.cache = cache
         self.stepfn = stepfn        # CarriedStepFn over make_paged_step
         self.maxb = -(-cfg.max_seq // kv_config.block_size)
+        # how the step's attention reads the pool ("pallas" | "gather"),
+        # and lens -> the blocks a layer's attention then fetches
+        # (add_model sets both)
+        self.attn_path = None
+        self.blocks_read = None
         self.step_ms = 0.0          # EWMA of one decode step
         self.prefix = None          # PrefixCache (FLAGS_prefix_cache)
         self.spec_k = 0
@@ -1016,6 +1023,7 @@ class DecodeEngine:
         from . import decode_model as _dm
         from . import kv_cache as _kvc
         from ..core.executor import CarriedStepFn
+        from ..pallas_kernels import paged_attention as _pa
 
         if isinstance(source, str):
             cfg, params = _dm.load_decoder(source)
@@ -1063,6 +1071,7 @@ class DecodeEngine:
             prefix = _kvc.PrefixCache(cache.allocator,
                                       kv_config.block_size, namespace=name)
         jparams = {key: jnp.asarray(v) for key, v in params.items()}
+        attn_path = _dm.attention_path(cfg, kv_config, max(self.buckets))
         stepfn = CarriedStepFn(
             _dm.make_paged_step(cfg, kv_config), donate_argnums=(0,),
             name="decode_step",
@@ -1071,8 +1080,14 @@ class DecodeEngine:
                        "kv": {"block_size": kv_config.block_size,
                               "num_blocks": kv_config.num_blocks,
                               "dtype": kv_config.dtype},
-                       "pallas": bool(_flag("use_pallas_paged_attention"))})
+                       # the path the step's attention takes: an executable
+                       # compiled for one is never restored for the other
+                       "attention": attn_path})
         entry = _DecodeModel(name, cfg, jparams, kv_config, cache, stepfn)
+        entry.attn_path = attn_path
+        entry.blocks_read = functools.partial(
+            _pa.blocks_read, block_size=kv_config.block_size,
+            maxb=entry.maxb, path=attn_path)
         entry.prefix = prefix
         if k > 0:
             # draft pool mirrors the target's block COUNT (draft blocks
@@ -1087,7 +1102,9 @@ class DecodeEngine:
             base_parts = {"model": name, "kv": {
                 "block_size": kv_config.block_size, "num_blocks": n,
                 "dtype": kv_config.dtype},
-                "pallas": bool(_flag("use_pallas_paged_attention"))}
+                "attention": [attn_path,
+                              _dm.attention_path(dcfg, draft_kv,
+                                                 max(self.buckets))]}
             entry.spec_k = k
             entry.draft_cfg = dcfg
             entry.draft_params = {key: jnp.asarray(v)
@@ -1155,7 +1172,8 @@ class DecodeEngine:
                       source=got["source"], decode=True, fn=fn,
                       ms=round(got["compile_ms"], 3),
                       temp_bytes=got["temp_bytes"],
-                      alias_bytes=got["alias_bytes"], **extra)
+                      alias_bytes=got["alias_bytes"],
+                      attention=self._models[model].attn_path, **extra)
             for key in ("temp_bytes", "alias_bytes"):
                 if got[key] is not None:
                     _tm.set_gauge("serving_step_" + key, got[key],
@@ -2263,7 +2281,13 @@ class DecodeEngine:
                 pos[i] = s.n_fed
                 tables[i] = s.table
                 lens[i] = s.n_fed + 1  # token valid AFTER this step's write
-            sspan = self._open_step_span(m, bucket, lanes)
+            # blocks a layer's attention fetches this step, of the slots
+            # the table has: the live context's share where the kernel
+            # reads in place, all of them where the table is gathered
+            read = {"kv_blocks_read": m.blocks_read(lens),
+                    "kv_table_slots": bucket * m.maxb} \
+                if _tr.enabled() else {}
+            sspan = self._open_step_span(m, bucket, lanes, **read)
             args = self._step_args(m, bucket, tok, pos, tables, lens)
         self.in_batch = True
         t0 = time.perf_counter()

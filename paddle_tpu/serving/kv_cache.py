@@ -30,8 +30,10 @@ their minor dimension is ``heads * head_dim``, a multiple of the 128
 lanes for every published width, where ``head_dim`` alone (64) pads to
 128: the runtime then keeps the padded pool in another layout than the
 step computes in, and converts the whole pool at the step's entry and
-exit.  Heads are split by a reshape of what attention gathers, never of
-the pool.
+exit.  Heads are never split on the pool: the step's attention
+(``pallas_kernels/paged_attention.py``) reads the folded rows where they
+lie, a lane's live blocks at a time, and its jnp fallback reshapes only
+what it has gathered.
 
 ``PrefixCache`` is the content-addressed index over sealed blocks: a
 per-model hash chain ``h_i = sha(h_{i-1}, block_token_ids)`` over *full*

@@ -431,12 +431,13 @@ def test_client_replays_on_server_timeout(cache_dir):
 
 
 # -- Pallas paged-attention funnel -------------------------------------------
+# (the kernel's own cases are in tests/test_paged_attention_kernel.py)
 
 
 def _paged_fixture(rng, bb=2, blocks=4, bs=8, h=1, d=128, maxb=2):
     q = rng.randn(bb, h, d).astype(np.float32)
-    k = rng.randn(blocks, bs, h, d).astype(np.float32)
-    v = rng.randn(blocks, bs, h, d).astype(np.float32)
+    k = rng.randn(blocks, bs, h * d).astype(np.float32)
+    v = rng.randn(blocks, bs, h * d).astype(np.float32)
     tables = np.array([[1, 3], [2, -1]], np.int32)
     lens = np.array([12, 5], np.int32)
     return q, k, v, tables, lens
@@ -446,17 +447,15 @@ def test_paged_attention_interpret_parity(monkeypatch, telemetry_on):
     monkeypatch.setenv("PADDLE_PALLAS_INTERPRET", "1")
     adoption.reset()
     try:
-        fluid.set_flags({"FLAGS_use_pallas_paged_attention": True})
         args = _paged_fixture(np.random.RandomState(0))
         out = np.asarray(pa.paged_attention(*args))
         ref = np.asarray(pa.paged_attention_reference(*args))
         # online-softmax accumulation vs one-shot softmax: allclose, and
-        # the funnel actually adopted the kernel
+        # the funnel adopted the kernel with no flag set
         assert np.allclose(out, ref, atol=1e-5), np.abs(out - ref).max()
         assert "paged_attention" in adoption.active_kernels()
         assert _tm.counter_total("pallas_kernel_used_total") >= 1
     finally:
-        fluid.set_flags({"FLAGS_use_pallas_paged_attention": False})
         adoption.reset()
 
 
@@ -465,7 +464,6 @@ def test_paged_attention_funnel_falls_back_off_tpu(monkeypatch,
     monkeypatch.delenv("PADDLE_PALLAS_INTERPRET", raising=False)
     adoption.reset()
     try:
-        fluid.set_flags({"FLAGS_use_pallas_paged_attention": True})
         args = _paged_fixture(np.random.RandomState(1))
         out = np.asarray(pa.paged_attention(*args))
         ref = np.asarray(pa.paged_attention_reference(*args))
@@ -473,22 +471,26 @@ def test_paged_attention_funnel_falls_back_off_tpu(monkeypatch,
         # and the jnp fallback is the reference itself
         assert np.array_equal(out, ref)
         assert adoption.active_kernels() == []
-        assert _tm.counter_total("pallas_kernel_fallback_total") >= 1
+        assert [labels for _key, labels in _tm.label_sets(
+            "pallas_kernel_fallback_total")] == [
+                {"kernel": "paged_attention", "reason": "backend"}]
     finally:
-        fluid.set_flags({"FLAGS_use_pallas_paged_attention": False})
         adoption.reset()
 
 
 def test_paged_attention_checks_catch_bad_geometry():
-    reasons = dict(pa.paged_attention_checks((2, 1, 64), (4, 8, 1, 64),
-                                             np.float32, 8))
-    assert reasons["head_dim"] is False      # 64 % 128 != 0
-    reasons = dict(pa.paged_attention_checks((2, 1, 128), (4, 6, 1, 128),
-                                             np.float32, 6))
+    reasons = dict(pa.paged_attention_checks((2, 3, 64), (4, 8, 192),
+                                             np.float32))
+    assert reasons["lanes"] is False         # 192 % 128 != 0
+    reasons = dict(pa.paged_attention_checks((2, 1, 128), (4, 6, 128),
+                                             np.float32))
     assert reasons["block_size"] is False    # 6 % 8 != 0
-    reasons = dict(pa.paged_attention_checks((2, 1, 128), (4, 8, 1, 128),
-                                             np.float16, 8))
+    reasons = dict(pa.paged_attention_checks((2, 1, 128), (4, 8, 128),
+                                             np.float16))
     assert reasons["dtype"] is False
+    reasons = dict(pa.paged_attention_checks((2, 1, 128), (4, 8, 1, 128),
+                                             np.float32))
+    assert reasons["rank"] is False          # a head-split pool
 
 
 # -- speculative decoding ----------------------------------------------------

@@ -1,0 +1,291 @@
+"""The paged-attention kernel (pallas_kernels/paged_attention.py) on the CPU
+tier, through the Pallas interpreter (``PADDLE_PALLAS_INTERPRET=1``): its
+output against the gather path on folded pools, the rule that picks the
+path from shapes and backend, and the decode steps built on it against the
+unpaged reference loop.  What the chip's compiler makes of it at the real
+widths is in tests/test_tpu_compile.py; times are the chip's alone."""
+
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as fluid
+from paddle_tpu.core import telemetry as _tm
+from paddle_tpu.core import tracing as tr
+from paddle_tpu.pallas_kernels import adoption
+from paddle_tpu.pallas_kernels import paged_attention as pa
+from paddle_tpu.serving import DecodeEngine
+from paddle_tpu.serving import decode_model as dm
+from paddle_tpu.serving.kv_cache import KVCacheConfig, PagedKVCache
+
+# the two pools the serving cells keep, at two heads: GPT-2's (f32, head_dim
+# 64, two heads to a lane tile) and OLMoE's (bf16, head_dim 128)
+POOLS = {"f32_d64": (jnp.float32, 64, 8, 2e-6),
+         "bf16_d128": (jnp.bfloat16, 128, 16, 8e-3)}
+HEADS, MAXB = 2, 20          # 20 blocks a lane: two chunks of 128 at 8 a block
+
+
+@pytest.fixture()
+def interpreted(monkeypatch):
+    monkeypatch.setenv("PADDLE_PALLAS_INTERPRET", "1")
+    fluid.set_flags({"FLAGS_telemetry": True})
+    adoption.reset()
+    _tm.reset()
+    yield
+    adoption.reset()
+    _tm.reset()
+    fluid.set_flags({"FLAGS_telemetry": False})
+
+
+def _contexts(block_size):
+    full = MAXB * block_size
+    return {"idle": [0, 5], "one": [1, 0],
+            "a_block": [block_size, block_size],
+            "a_block_and_one": [block_size + 1, 3],
+            "ragged": [0, 1, block_size, 3 * block_size + 5, full - 7, 77,
+                       full],
+            "full_table": [full, full - 1]}
+
+
+def _pools(kind, lens, seed=0):
+    """Random folded pools and a table: each lane's blocks its own, but the
+    first block of lanes 0 and 1 shared (a prefix-cache hit) wherever both
+    have one; every unused slot is -1."""
+    dtype, head_dim, block_size, _tol = POOLS[kind]
+    rng = np.random.RandomState(seed)
+    blocks = 1 + MAXB * len(lens)
+    width = HEADS * head_dim
+    q = rng.randn(len(lens), HEADS, head_dim).astype(np.float32)
+    k, v = (jnp.asarray(rng.randn(blocks, block_size, width)
+                        .astype(np.float32)).astype(dtype) for _ in "kv")
+    tables = np.full((len(lens), MAXB), -1, np.int32)
+    free = list(rng.permutation(np.arange(1, blocks)))
+    for i, n in enumerate(lens):
+        for j in range(-(-n // block_size)):
+            tables[i, j] = free.pop()
+    if lens[0] and lens[1]:
+        tables[1, 0] = tables[0, 0]
+    return q, k, v, tables, np.asarray(lens, np.int32)
+
+
+@pytest.mark.parametrize("context", sorted(_contexts(8)))
+@pytest.mark.parametrize("kind", sorted(POOLS))
+def test_kernel_matches_the_gather_path(interpreted, kind, context):
+    """Live lanes agree with ``paged_attention_reference`` to the pool
+    dtype's rounding; an idle lane returns zeros (the gather path returns
+    the mean of whatever block 0 holds: nothing reads either)."""
+    lens = _contexts(POOLS[kind][2])[context]
+    args = _pools(kind, lens)
+    out = np.asarray(pa.paged_attention(*args))
+    ref = np.asarray(pa.paged_attention_reference(*args))
+    live = np.asarray(lens) > 0
+    assert np.isfinite(out).all()
+    assert np.abs(out[live] - ref[live]).max() <= POOLS[kind][3]
+    assert not out[~live].any()
+    assert adoption.active_kernels() == ["paged_attention"]
+    assert _tm.counter_total("pallas_kernel_used_total") == 1
+    assert _tm.counter_total("pallas_kernel_fallback_total") == 0
+
+
+def test_f32_products_are_f32(interpreted):
+    """Against an f32 pool the kernel sits orders below a path whose
+    products are rounded to bfloat16: the three-piece split keeps what
+    ``Precision.HIGHEST`` keeps."""
+    q, k, v, tables, lens = _pools("f32_d64", [150, 160, 9])
+    ref = np.asarray(pa.paged_attention_reference(q, k, v, tables, lens))
+    out = np.asarray(pa.paged_attention(q, k, v, tables, lens))
+    coarse = np.asarray(pa.paged_attention_reference(
+        q, k.astype(jnp.bfloat16), v.astype(jnp.bfloat16), tables, lens))
+    assert np.abs(coarse - ref).max() > 100 * np.abs(out - ref).max()
+
+
+RULE = {
+    # name: (q shape, pool shape, pool dtype, where, expected)
+    "f32_folded": ((4, 16, 64), (64, 16, 1024), "float32", None, "ok"),
+    "bf16_folded": ((4, 16, 128), (64, 16, 2048), "bfloat16", None, "ok"),
+    "rank_4": ((4, 16, 64), (64, 16, 16, 64), "float32", None, "rank"),
+    "int8": ((4, 16, 64), (64, 32, 1024), "int8", None, "dtype"),
+    "width_192": ((4, 3, 64), (64, 16, 192), "float32", None, "lanes"),
+    "bf16_block_of_8": ((4, 16, 128), (64, 8, 2048), "bfloat16", None,
+                        "block_size"),
+    "width_8192": ((4, 64, 128), (64, 16, 8192), "bfloat16", None, "vmem"),
+    "symbolic": ((None, 16, 64), (64, 16, 1024), "float32", None,
+                 "symbolic_shape"),
+    "gspmd_mesh": ((4, 16, 64), (64, 16, 1024), "float32", "mesh",
+                   "gspmd_mesh"),
+    "off_tpu": ((4, 16, 64), (64, 16, 1024), "float32", "cpu", "backend"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(RULE))
+def test_shape_rule(interpreted, monkeypatch, case):
+    """The kernel engages for the folded f32 and bf16 pools and declines,
+    under the right reason, everything else; ``attention_path`` says the
+    same and counts nothing."""
+    q_shape, kv_shape, dtype, where, expected = RULE[case]
+    if where == "cpu":
+        monkeypatch.delenv("PADDLE_PALLAS_INTERPRET")
+    checks = pa.paged_attention_checks(q_shape, kv_shape, dtype)
+    path = pa.attention_path(q_shape, kv_shape, dtype)
+    assert _tm.counter_total("pallas_kernel_used_total") == 0
+    assert _tm.counter_total("pallas_kernel_fallback_total") == 0
+    if where == "mesh":
+        with adoption.auto_partitioned():
+            use, reason = adoption.decide("paged_attention", checks=checks,
+                                          require_probe=False)
+    else:
+        use, reason = adoption.decide("paged_attention", checks=checks,
+                                      require_probe=False)
+        assert path == ("pallas" if use else "gather")
+    assert (use, reason) == (expected == "ok", expected)
+    counted = dict(_tm.label_sets("pallas_kernel_fallback_total"))
+    if use:
+        assert not counted
+    else:
+        assert list(counted.values()) == [
+            {"kernel": "paged_attention", "reason": expected}]
+
+
+def test_blocks_read_counts_live_chunks():
+    lens = np.array([0, 1, 128, 129, 1000], np.int32)
+    assert pa.blocks_read(lens, 16, 64, "gather") == 5 * 64
+    # chunks of 128 positions = 8 blocks: 0 + 1 + 1 + 2 + 8 chunks
+    assert pa.blocks_read(lens, 16, 64, "pallas") == 12 * 8
+    # a table shorter than a chunk is one chunk
+    assert pa.blocks_read(np.array([5, 0], np.int32), 4, 12, "pallas") == 12
+
+
+# -- the decode steps on the kernel ------------------------------------------
+
+CFG = dm.DecoderConfig(vocab=37, layers=2, heads=2, head_dim=64, max_seq=48)
+PARAMS = dm.init_decoder_params(CFG, seed=3)
+BS = 8
+
+
+def _drive(step, kv, prompts, max_new, width=1):
+    """Feed each lane its prompt then its own argmax, ``width`` positions a
+    call (``width`` 1 is ``make_paged_step``); the last lane stays idle.
+    -> per lane (tokens, logits of each generated token)."""
+    cache = PagedKVCache(kv)
+    lanes, maxb = len(prompts) + 1, CFG.max_seq // BS
+    tables = np.full((lanes, maxb), -1, np.int32)
+    for i in range(len(prompts)):
+        tables[i] = 1 + i * maxb + np.arange(maxb)
+    seqs = [list(p) for p in prompts]
+    fed = [0] * len(prompts)
+    logits = [[] for _ in prompts]
+    params = {k: jnp.asarray(v) for k, v in PARAMS.items()}
+    fn = jax.jit(step, donate_argnums=(0,))
+    total = [len(p) + max_new for p in prompts]
+    while any(len(s) < t for s, t in zip(seqs, total)):
+        tok = np.zeros((lanes, width), np.int32)
+        pos = np.zeros((lanes, width), np.int32)
+        lens = np.zeros((lanes, width), np.int32)
+        for i, s in enumerate(seqs):
+            # this call feeds the known tokens from fed[i] on, at most one
+            # past the prompt (what follows has to be generated first)
+            n = min(width, len(s) - fed[i])
+            for j in range(width):
+                real = min(j, n - 1)
+                tok[i, j] = s[fed[i] + real]
+                pos[i, j] = fed[i] + real
+                lens[i, j] = fed[i] + real + 1
+        feeds = (tok, pos, tables, lens) if width > 1 \
+            else (tok[:, 0], pos[:, 0], tables, lens[:, 0])
+        carry, nxt, lg = fn(cache.carry(), params, *feeds)[:3]
+        cache.replace_carry(carry)
+        nxt, lg = np.asarray(nxt), np.asarray(lg)
+        for i, s in enumerate(seqs):
+            n = min(width, len(s) - fed[i])
+            fed[i] += n
+            if fed[i] == len(s) and len(s) < total[i]:
+                last = (nxt[i, n - 1], lg[i, n - 1]) if width > 1 \
+                    else (nxt[i], lg[i])
+                s.append(int(last[0]))
+                logits[i].append(last[1])
+    return [(s[len(p):], lg) for s, p, lg in zip(seqs, prompts, logits)]
+
+
+@pytest.mark.parametrize("width", [1, 2], ids=["step", "multi"])
+def test_paged_steps_on_the_kernel_match_unpaged(interpreted, width):
+    """``make_paged_step`` and ``make_paged_step_multi`` with the kernel
+    serving every layer: the tokens of ``unpaged_generate``, logits to
+    1e-5 (the softmax's sums run in another order, nothing else differs)."""
+    kv = KVCacheConfig(CFG.layers, CFG.heads, CFG.head_dim, BS, 16, "f32")
+    assert dm.attention_path(CFG, kv) == "pallas"
+    step = dm.make_paged_step(CFG, kv) if width == 1 \
+        else dm.make_paged_step_multi(CFG, kv, width)
+    prompts = [[5, 6, 7, 8, 9, 10, 11, 12, 13], [3]]
+    got = _drive(step, kv, prompts, 6, width)
+    assert _tm.counter_total("pallas_kernel_used_total") \
+        == CFG.layers * width
+    assert _tm.counter_total("pallas_kernel_fallback_total") == 0
+    for prompt, (tokens, logits) in zip(prompts, got):
+        want, want_logits = dm.unpaged_generate(
+            CFG, PARAMS, prompt, 6, pad_len=CFG.max_seq, return_logits=True)
+        assert tokens == want
+        assert np.abs(np.asarray(logits) - np.asarray(want_logits)).max() \
+            < 1e-5
+
+
+def test_engine_names_the_path_and_counts_the_blocks(interpreted, tmp_path):
+    """An engine on the kernel: the prewarm event names the attention path,
+    each step's span carries the blocks read beside the table's slots, and
+    the tokens are the unpaged loop's."""
+    d = str(tmp_path / "tel")
+    old = fluid.get_flags(["FLAGS_kv_block_size", "FLAGS_kv_cache_dtype",
+                           "FLAGS_compile_cache_dir", "FLAGS_tracing",
+                           "FLAGS_telemetry_dir"])
+    fluid.set_flags({"FLAGS_kv_block_size": BS, "FLAGS_kv_cache_dtype": "f32",
+                     "FLAGS_compile_cache_dir": str(tmp_path / "cc"),
+                     "FLAGS_tracing": True, "FLAGS_telemetry_dir": d})
+    tr.reset()
+    try:
+        e = DecodeEngine(buckets="2", deadline_ms=60000.0)
+        e.add_model("toy", (CFG, PARAMS), kv_blocks=16)
+        e.prewarm()
+        e.start()
+        try:
+            prompt = [5, 6, 7, 8, 9, 10, 11, 12, 13]
+            r = e.generate("toy", prompt, max_new_tokens=4,
+                           deadline_ms=60000.0)
+        finally:
+            e.stop()
+        assert r.status == "ok", r.error
+        assert list(r.outputs["tokens"]) == dm.unpaged_generate(
+            CFG, PARAMS, prompt, 4, pad_len=CFG.max_seq)
+        _tm.flush()
+        with open(os.path.join(d, "steps.jsonl")) as fp:
+            events = [json.loads(line) for line in fp]
+        warm = [ev for ev in events if ev["ev"] == "serving_prewarm"]
+        assert warm and all(ev["attention"] == "pallas" for ev in warm)
+        attrs = [s["attrs"] for s in tr.records("serving.decode_step")]
+        maxb = CFG.max_seq // BS
+        assert attrs and all(a["kv_table_slots"] == 2 * maxb for a in attrs)
+        # one lane of 1..12 positions and an idle one: a table of six blocks
+        # is one chunk, fetched whole
+        assert all(a["kv_blocks_read"] == maxb for a in attrs)
+    finally:
+        fluid.set_flags(old)
+        tr.reset()
+
+
+def test_kv_blocks_read_share_reader():
+    """The benchmark's reader of the two span attributes: the median share
+    over the steps that carry them; nothing from a program that records
+    none (the parent), and nothing from a training run."""
+    from benchmark.run import load_module
+
+    reader = load_module("layer_metrics", "kv_blocks_read_share.serve")
+    spans = [{"attrs": {"kv_blocks_read": n, "kv_table_slots": 2048}}
+             for n in (512, 640, 768)] + [{"attrs": {"lanes": 3}}]
+    assert reader.read({"kind": "serve", "decode_spans": spans}) \
+        == pytest.approx(31.25)
+    assert reader.read({"kind": "serve",
+                        "decode_spans": [{"attrs": {"lanes": 3}}]}) is None
+    assert reader.read({"kind": "serve", "decode_spans": None}) is None
+    assert reader.read({"kind": "train", "decode_spans": spans}) is None

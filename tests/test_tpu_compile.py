@@ -8,6 +8,7 @@ a fixture (never at import): only one process may load the TPU's library,
 and it is the worker that is given this file.
 """
 
+import functools
 import json
 import os
 import re
@@ -36,10 +37,31 @@ def one_chip():
     return SingleDeviceSharding(topo.devices[0])
 
 
+@pytest.fixture()
+def as_on_tpu(monkeypatch):
+    """The path rule asks ``jax.default_backend()``, which is the CPU's
+    here: answer as the chip would, so that what is compiled for the
+    described chip is the program the chip runs (the kernel, not the
+    gather)."""
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def _kernel_calls(text):
+    return len(re.findall(r" custom-call\(.*tpu_custom_call", text))
+
+
+def _placed(sharding, tree):
+    """The tree's shapes, placed on the described chip."""
+    return jax.tree_util.tree_map(
+        lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
+        tree)
+
+
 @pytest.mark.parametrize("kind", ["step", "multi"])
 def test_decode_step_keeps_the_pool_in_place_on_v5e(one_chip, kind):
     """GPT-2-medium widths (16 heads of 64, 1024 positions), bucket 32, a
-    4.8e8-byte pool a layer pair: the compiled step aliases the whole pool,
+    4.8e8-byte pool a layer pair, on the gather path (the fallback: what
+    the backend check picks here): the compiled step aliases the whole pool,
     needs under a quarter of it beside its arguments, and holds no copy,
     select, transpose or slice of a pool's shape (PERF.md section 6, PR 26:
     the parent had four whole-pool layout copies and a slice per layer)."""
@@ -52,10 +74,7 @@ def test_decode_step_keeps_the_pool_in_place_on_v5e(one_chip, kind):
     kv = KVCacheConfig(cfg.layers, cfg.heads, cfg.head_dim, block_size,
                        blocks, "f32")
 
-    def on_chip(tree):
-        return jax.tree_util.tree_map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
-                                           sharding=one_chip), tree)
+    on_chip = functools.partial(_placed, one_chip)
 
     carry = on_chip(jax.eval_shape(lambda: PagedKVCache(kv).carry()))
     params = on_chip({
@@ -105,10 +124,7 @@ def test_olmoe_step_streams_its_experts_and_keeps_the_pool_in_place(one_chip):
     kv = KVCacheConfig(cfg.layers, cfg.heads, cfg.head_dim, block_size,
                        blocks, cfg.kv_dtype)
 
-    def on_chip(tree):
-        return jax.tree_util.tree_map(
-            lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype,
-                                           sharding=one_chip), tree)
+    on_chip = functools.partial(_placed, one_chip)
 
     carry = on_chip(jax.eval_shape(lambda: PagedKVCache(kv).carry()))
     params = on_chip({
@@ -134,4 +150,63 @@ def test_olmoe_step_streams_its_experts_and_keeps_the_pool_in_place(one_chip):
         r" = bf16\[64,(2048,1024|1024,2048)\]\S* (copy|transpose|convert)\(")
     found = [line.strip()[:160] for line in compiled.as_text().splitlines()
              if expert_pass.search(line)]
+    assert not found, found
+
+
+@pytest.mark.parametrize("kind", ["step", "multi"])
+@pytest.mark.parametrize("model", ["gpt2-medium-serve", "olmoe-1b-7b-serve"])
+def test_decode_step_reads_the_pool_through_the_kernel_on_v5e(
+        one_chip, as_on_tpu, model, kind):
+    """Both serving configurations at their published widths, 2 layers,
+    bucket 32, the cells' pools: Mosaic accepts the paged-attention kernel
+    inside the whole step (one call a layer and column), the pool is
+    aliased whole and written in place between the kernel's reads, and
+    beside its arguments the step holds nothing of the table's size: no
+    gathered history (``[32, positions, width]``, 0.134e9 / 0.268e9 bytes)
+    and no pass over a pool (PERF.md section 6, PR 28)."""
+    from benchmark.models import olmoe_decoder
+
+    module, layers_key, blocks, weights = {
+        "gpt2-medium-serve": (gpt2_decoder, "n_layer", 1024, jnp.float32),
+        "olmoe-1b-7b-serve": (olmoe_decoder, "num_hidden_layers", 2048,
+                              jnp.bfloat16)}[model]
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           model + ".json")) as fp:
+        config = dict(json.load(fp), **{layers_key: 2})
+    cfg = module.decoder_config(config)
+    lanes, block_size, width = 32, 16, 1 if kind == "step" else 2
+    kv = KVCacheConfig(cfg.layers, cfg.heads, cfg.head_dim, block_size,
+                       blocks, cfg.kv_dtype or "f32")
+    assert dm.attention_path(cfg, kv) == "pallas"
+
+    on_chip = functools.partial(_placed, one_chip)
+
+    carry = on_chip(jax.eval_shape(lambda: PagedKVCache(kv).carry()))
+    params = on_chip({
+        name: jax.ShapeDtypeStruct(shape, weights)
+        for name, (shape, _kind) in module.param_shapes(config).items()})
+    per_lane = (lanes,) if kind == "step" else (lanes, width)
+    feeds = on_chip([
+        jax.ShapeDtypeStruct(per_lane, jnp.int32),
+        jax.ShapeDtypeStruct(per_lane, jnp.int32),
+        jax.ShapeDtypeStruct((lanes, cfg.max_seq // block_size), jnp.int32),
+        jax.ShapeDtypeStruct(per_lane, jnp.int32)])
+    fn = dm.make_paged_step(cfg, kv) if kind == "step" \
+        else dm.make_paged_step_multi(cfg, kv, width)
+    compiled = jax.jit(fn, donate_argnums=(0,)).lower(
+        carry, params, *feeds).compile()
+
+    text = compiled.as_text()
+    assert _kernel_calls(text) == cfg.layers * width
+    pool_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in carry)
+    gathered = lanes * cfg.max_seq * cfg.hidden * carry[0].dtype.itemsize
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= pool_bytes
+    assert memory.temp_size_in_bytes < gathered / 8
+    big = re.compile(
+        r" = \w+\[(%d,%d|%d,%d),%d\]\S* "
+        r"(copy|select|transpose|slice|dynamic-slice|gather|concatenate)\("
+        % (blocks, block_size, lanes, cfg.max_seq, cfg.hidden))
+    found = [line.strip()[:160] for line in text.splitlines()
+             if big.search(line)]
     assert not found, found

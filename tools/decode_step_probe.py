@@ -6,7 +6,9 @@ pool size, through the same ``CarriedStepFn`` the engine uses.  Prints, and
 writes under ``chiprun_out/``, one JSON object:
 
 * ``memory``: the compiled step's ``temp_bytes`` / ``alias_bytes`` beside the
-  pool's bytes, and the pool-sized instructions left in its HLO;
+  pool's bytes, and the pool-sized instructions left in its HLO; ``attention``
+  and ``pallas_kernel_counters``: the path the step's attention took, as the
+  rule names it and as each layer's lowering counted it;
 * ``step_ms``: host clock over steps that end in ``block_until_ready``;
 * ``scope_ms_per_step``: device time per step by ``jax.named_scope``
   (``layer<i>`` folded to ``layerN``), from a profile of ``--steps`` steps
@@ -22,6 +24,14 @@ achieved bytes/s (``benchmark/moe_cost.py`` over the scope's device time),
 and ``--experts ragged`` swaps the block's expert matmuls for this file's
 ``experts_ragged`` (tokens sorted by expert, ``jax.lax.ragged_dot``): the
 comparison the block's choice was made by, not an option of the program.
+
+``--check`` leaves the model out and compares the step's attention alone,
+at the configuration's shapes, on one layer's random pools and the same
+tables: the kernel (``pallas_kernels/paged_attention.py``) against the
+gather path, largest absolute and rms error, beside a control whose
+products are rounded to bfloat16 (an f32 pool's kernel has to sit orders
+below it) and, for a bf16 pool, beside the gather path's own distance from
+float32 mathematics on the stored values; and the time of each a call.
 
 It needs the TPU for a time; ``--compile-only`` stops after ``memory`` (it
 then says what the local backend's compiler made, which is not the chip's).
@@ -62,7 +72,7 @@ def scope_of(op_name):
     parts = [re.sub(r"^layer\d+$", "layerN", p) for p in parts]
     keep = [p for p in parts
             if p in ("layerN", "attn", "mlp", "moe", "router", "experts",
-                     "lm_head", "kv_write", "kv_gather")]
+                     "lm_head", "kv_write", "kv_read", "kv_gather")]
     return "/".join(keep) or "other"
 
 
@@ -104,6 +114,67 @@ def experts_ragged(k):
     return experts
 
 
+def check_attention(cfg, kv, tables, lens, seed, repeat=24):
+    """The kernel against the gather path on one layer's pools (see the
+    module docstring).  ``scale`` is the rms of the exact output.  A path is
+    timed as ``repeat`` calls chained inside one program, each call's query
+    the last one's output: one call alone costs less than its dispatch from
+    the host (0.2 ms)."""
+    import jax
+    import jax.numpy as jnp
+    import numpy as np
+
+    from paddle_tpu.pallas_kernels import paged_attention as pa
+    from paddle_tpu.serving import kv_cache as kvc
+
+    dtype = kvc._PAYLOAD[kv.dtype][0]
+    shape = (kv.num_blocks, kv.block_size, cfg.hidden)
+    kq, kk, kv_ = jax.random.split(jax.random.PRNGKey(seed % (2 ** 31)), 3)
+    q = jax.random.normal(kq, (len(lens), cfg.heads, cfg.head_dim),
+                          jnp.float32)
+    k_pool = jax.random.normal(kk, shape, jnp.float32).astype(dtype)
+    v_pool = jax.random.normal(kv_, shape, jnp.float32).astype(dtype)
+    tables, lens = jnp.asarray(tables), jnp.asarray(lens)
+
+    paths = {
+        "kernel": pa._paged_pallas,
+        "gather": pa.paged_attention_reference,
+        # float32 mathematics on the values as stored
+        "exact": lambda q, k, v, t, n: pa.paged_attention_reference(
+            q, k.astype(jnp.float32), v.astype(jnp.float32), t, n),
+        # every product of bfloat16 operands
+        "bf16_products": lambda q, k, v, t, n: pa.paged_attention_reference(
+            q, k.astype(jnp.bfloat16), v.astype(jnp.bfloat16), t, n),
+    }
+    rest = (k_pool, v_pool, tables, lens)
+    out, ms = {}, {}
+    for name, fn in paths.items():
+        out[name] = np.asarray(jax.jit(fn)(q, *rest), np.float64)
+        chained = jax.jit(lambda q, *rest, _fn=fn: jax.lax.fori_loop(
+            0, repeat, lambda _i, q: _fn(q, *rest), q))
+        chained(q, *rest).block_until_ready()
+        t0 = time.perf_counter()
+        chained(q, *rest).block_until_ready()
+        ms[name] = (time.perf_counter() - t0) * 1e3 / repeat
+
+    def err(a, b):
+        d = out[a] - out[b]
+        return {"max_abs": float(np.abs(d).max()),
+                "rms": float(np.sqrt((d ** 2).mean()))}
+
+    return {"scale": float(np.sqrt((out["exact"] ** 2).mean())),
+            "finite": bool(np.isfinite(out["kernel"]).all()),
+            "kernel_vs_gather": err("kernel", "gather"),
+            "kernel_vs_exact": err("kernel", "exact"),
+            "gather_vs_exact": err("gather", "exact"),
+            "bf16_products_vs_exact": err("bf16_products", "exact"),
+            "ms_per_call": ms,
+            "live_blocks": int((-(-np.asarray(lens) // kv.block_size)).sum()),
+            "blocks_read": pa.blocks_read(np.asarray(lens), kv.block_size,
+                                          tables.shape[1], "pallas"),
+            "table_slots": int(tables.size)}
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--config", default="gpt2-medium-serve",
@@ -120,6 +191,9 @@ def main(argv=None):
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--seed", type=int, default=2147483659)
     ap.add_argument("--compile-only", action="store_true")
+    ap.add_argument("--check", action="store_true",
+                    help="the attention kernel against the gather path at "
+                    "this configuration's shapes, and nothing else")
     ap.add_argument("--hlo-out", default=None)
     ap.add_argument("--label", default="probe")
     args = ap.parse_args(argv)
@@ -129,6 +203,8 @@ def main(argv=None):
 
     from benchmark import moe_cost, trace_reduce
     from benchmark.run import load_module
+    import paddle_tpu as fluid
+    from paddle_tpu.core import telemetry
     from paddle_tpu.core.executor import CarriedStepFn
     from paddle_tpu.serving import decode_model as dm
     from paddle_tpu.serving import kv_cache as kvc
@@ -144,7 +220,6 @@ def main(argv=None):
     device = jax.devices()[0]
     model = load_module("models", config["model"])
     cfg = model.decoder_config(config)
-    params = model.make_params(config, args.seed, device)
     if args.experts == "ragged":
         from paddle_tpu.models import olmoe
 
@@ -155,19 +230,42 @@ def main(argv=None):
     cache = kvc.PagedKVCache(kv)
     b, maxb = args.bucket, cfg.max_seq // args.block_size
 
-    # every lane mid-sequence, its blocks its own, as in the cell's window
+    # every lane mid-sequence, its blocks its own, as in the cell's window:
+    # contexts from a third of what a lane's share of the pool holds up to
+    # all of it, so the lanes hold 59-63% of the pool (the cells' windows
+    # end at 52-71%) and a third of the table's slots are live
     rng = np.random.default_rng(args.seed)
     grow = WARM_STEPS + 2 * args.steps
-    longest = min(700, cfg.max_seq - grow,
+    longest = min(cfg.max_seq - grow,
                   (args.blocks - 1) // b * args.block_size - grow)
-    lens = rng.integers(min(200, longest - 1), longest, b).astype(np.int32)
+    lens = rng.integers(longest // 3, longest, b).astype(np.int32)
     tables = np.full((b, maxb), -1, np.int32)
     free = iter(rng.permutation(np.arange(1, args.blocks)))
     for i in range(b):
         for j in range(-(-int(lens[i] + grow) // args.block_size)):
             tables[i, j] = next(free)
     tok = rng.integers(0, cfg.vocab, b).astype(np.int32)
+    out_dir = os.path.join(ROOT, "chiprun_out")
+    os.makedirs(out_dir, exist_ok=True)
 
+    if args.check:
+        if device.platform != "tpu":
+            print("decode_step_probe: no TPU, so no kernel to check",
+                  file=sys.stderr)
+            return 2
+        result = dict(
+            check_attention(cfg, kv, tables, lens, args.seed),
+            label=args.label, config=config["name"], dtype=args.dtype,
+            device=device.device_kind, blocks=args.blocks, bucket=b)
+        with open(os.path.join(out_dir, "decode_step_check.jsonl"),
+                  "a") as fp:
+            fp.write(json.dumps(result) + "\n")
+        print(json.dumps(result))
+        return 0
+
+    params = model.make_params(config, args.seed, device)
+    # count which attention path each layer's lowering takes
+    fluid.set_flags({"FLAGS_telemetry": True})
     stepfn = CarriedStepFn(dm.make_paged_step(cfg, kv), donate_argnums=(0,),
                            name="probe")
     feed = lambda n: (cache.carry(), params, tok, lens + n - 1, tables,
@@ -192,6 +290,10 @@ def main(argv=None):
                    "pool_bytes": cache.nbytes,
                    "compile_ms": round(warm["compile_ms"], 1),
                    "pool_sized_instructions": pool_sized(index, pool_elems)},
+        "attention": dm.attention_path(cfg, kv, b),
+        "pallas_kernel_counters": {
+            key: value for key, value in telemetry.snapshot()["counters"].items()
+            if key.startswith("pallas_kernel_")},
     }
     if not args.compile_only:
         if device.platform != "tpu":
@@ -240,8 +342,6 @@ def main(argv=None):
         stats = device.memory_stats() or {}
         result["peak_bytes_in_use"] = stats.get("peak_bytes_in_use")
         result["peak_bytes_reserved"] = stats.get("peak_bytes_reserved")
-    out_dir = os.path.join(ROOT, "chiprun_out")
-    os.makedirs(out_dir, exist_ok=True)
     with open(os.path.join(out_dir, "decode_step_probe.jsonl"), "a") as fp:
         fp.write(json.dumps(result) + "\n")
     print(json.dumps(result))
