@@ -37,6 +37,12 @@ import threading
 
 _RNG_COUNTER_LOCK = threading.Lock()
 
+# trace-affecting flags must key the cache: a cached executable baked the
+# flag value it was traced under, and flipping the flag without a cache miss
+# would silently keep the old lowering
+_TRACE_FLAGS = ("FLAGS_check_nan_inf", "FLAGS_bn_stat_subsample",
+                "FLAGS_layout_match_params", "FLAGS_deterministic_reduction")
+
 _global_scope = Scope()
 # Per-thread scope override (same design as framework's default-program TLS):
 # role threads (pserver/worker standing in for separate processes) each
@@ -401,6 +407,30 @@ class CarriedStepFn:
         return fn(*args)
 
 
+def _cache_key(program, feed_arrays, fetch_names, mesh):
+    """(in-memory cache key, trace-flag tuple) of one program signature —
+    run and warmup share it, so a warmed signature is a hit in run."""
+    from .. import flags as _flags
+
+    trace_flags = tuple(sorted(_flags.get_flags(_TRACE_FLAGS).items()))
+    # mesh keyed by content, not id(): a GC'd Mesh's successor can alias the
+    # address exactly like the Program case (program._uid)
+    mesh_key = None
+    if mesh is not None:
+        mesh_key = (tuple(mesh.shape.items()),
+                    tuple(d.id for d in mesh.devices.flat))
+    key = (
+        program._uid,
+        program.version,
+        tuple(sorted((n, a.shape, str(a.dtype))
+                     for n, a in feed_arrays.items())),
+        tuple(fetch_names),
+        mesh_key,
+        trace_flags,
+    )
+    return key, trace_flags
+
+
 class Executor:
     """Per-place executor with a program cache."""
 
@@ -573,35 +603,8 @@ class Executor:
             # force a full recompile on the next step
             self._maybe_fuse_optimizers(program, program.global_block(),
                                         list(feed_arrays), fetch_names)
-            # trace-affecting flags must key the cache: a cached executable
-            # baked the flag value it was traced under, and flipping the flag
-            # without a cache miss would silently keep the old lowering
-            from .. import flags as _flags
-
-            trace_flags = tuple(sorted(_flags.get_flags(
-                ["FLAGS_use_pallas_layer_norm", "FLAGS_check_nan_inf",
-                 "FLAGS_bn_stat_subsample",
-                 "FLAGS_fused_small_attention",
-                 "FLAGS_layout_match_params",
-                 "FLAGS_use_pallas_conv_block",
-                 "FLAGS_use_pallas_fused_opt",
-                 "FLAGS_use_pallas_embedding_bag",
-                 "FLAGS_deterministic_reduction"]).items()))
-            # mesh keyed by content, not id(): a GC'd Mesh's successor can
-            # alias the address exactly like the Program case above
-            mesh_key = None
-            if mesh is not None:
-                mesh_key = (tuple(mesh.shape.items()),
-                            tuple(d.id for d in mesh.devices.flat))
-            key = (
-                program._uid,
-                program.version,
-                tuple(sorted((n, a.shape, str(a.dtype))
-                             for n, a in feed_arrays.items())),
-                tuple(fetch_names),
-                mesh_key,
-                trace_flags,
-            )
+            key, trace_flags = _cache_key(program, feed_arrays, fetch_names,
+                                          mesh)
             tel = _telemetry.enabled()
             entry = self._cache.get(key) if use_program_cache else None
             cache_hit = entry is not None
@@ -1027,30 +1030,8 @@ class Executor:
 
         self._maybe_fuse_optimizers(program, block, list(feed_arrays),
                                     fetch_names)
-        from .. import flags as _flags
-
-        trace_flags = tuple(sorted(_flags.get_flags(
-            ["FLAGS_use_pallas_layer_norm", "FLAGS_check_nan_inf",
-             "FLAGS_bn_stat_subsample",
-             "FLAGS_fused_small_attention",
-             "FLAGS_layout_match_params",
-             "FLAGS_use_pallas_conv_block",
-             "FLAGS_use_pallas_fused_opt",
-             "FLAGS_use_pallas_embedding_bag",
-             "FLAGS_deterministic_reduction"]).items()))
-        mesh_key = None
-        if mesh is not None:
-            mesh_key = (tuple(mesh.shape.items()),
-                        tuple(d.id for d in mesh.devices.flat))
-        key = (
-            program._uid,
-            program.version,
-            tuple(sorted((n, a.shape, str(a.dtype))
-                         for n, a in feed_arrays.items())),
-            tuple(fetch_names),
-            mesh_key,
-            trace_flags,
-        )
+        key, trace_flags = _cache_key(program, feed_arrays, fetch_names,
+                                      mesh)
         if devices is None and key in self._cache:
             return {"source": "memory", "compile_ms": 0.0, "key": None}
         from .analysis import check_before_compile

@@ -534,13 +534,6 @@ def build_block_fn(plan, mesh=None, axis_names=()):
         updated = {n: env[n] for n in persist_written if n in env}
         updated_carry = {}
         for n in carry_names:
-            if (n + "@PALLAS_BF16") in env:
-                # the Pallas fused-opt kernel already cast ParamOut to bf16
-                # inside its VMEM pass (ops/optimizer_ops.py stash) —
-                # bitwise-identical to the astype below, minus one full
-                # elementwise pass over the parameter bytes
-                updated_carry[n] = env[n + "@PALLAS_BF16"]
-                continue
             v = env[n]  # f32 new master after ParamOut, else the bf16 carry
             if v.dtype != jnp.bfloat16:
                 v = v.astype(jnp.bfloat16)

@@ -406,10 +406,11 @@ def run_pserver(exe, program, scope):
         no optimizer runs server-side.  Deltas are NOT idempotent (the
         server accumulates them), so dedupe matters doubly here."""
         replay = _ReplayFilter()
+        applied = {}    # (tid, param) -> the key its last delta is read by
 
-        def publish_geo(p):
+        def publish_geo(p, key=None):
             server.set_var(
-                _vkey(p, -1),
+                key or _vkey(p, -1),
                 np.asarray(scope.find_var(p).get_tensor().numpy()))
             _tm.publish_rpc(server)
 
@@ -436,6 +437,16 @@ def run_pserver(exe, program, scope):
                 cur = np.asarray(scope.find_var(base).get_tensor().numpy())
                 scope.var(base).set(cur + arr)
                 publish_geo(base)
+                # read-your-write: the merged value also under the delta's
+                # own tag, which the sender's GET blocks on.  A SEND is
+                # acknowledged when it is queued, not when it is applied, so
+                # a pull of the -1 key could come back without the delta and
+                # the next push would then add the same progress twice.
+                publish_geo(base, key=name)
+                stale = applied.get((tid, base))
+                if stale is not None:
+                    server.del_var(stale)
+                applied[(tid, base)] = name
 
     with _LIVE_LOCK:
         _LIVE_SERVERS.add(id(server))
@@ -544,12 +555,16 @@ class TrainerPSComm:
         self._step_count += 1
         if self._step_count % self.geo_push_nums:
             return
+        tags = {}
         for p, ep in self.param_to_ep.items():
             cur = np.asarray(scope.find_var(p).get_tensor().numpy())
             delta = cur - self._snapshot[p]
-            self._clients[ep].send_var(self._tag(p), delta)
-        self._pull(scope, -1)
-        for p in self.param_to_ep:
+            tags[p] = self._tag(p)
+            self._clients[ep].send_var(tags[p], delta)
+        # the merged params as of this trainer's own deltas: the server
+        # publishes them under each delta's tag once it has applied it
+        for p, ep in self.param_to_ep.items():
+            scope.var(p).set(self._clients[ep].get_var(tags[p]))
             self._snapshot[p] = np.asarray(
                 scope.find_var(p).get_tensor().numpy()).copy()
 

@@ -226,10 +226,8 @@ class DistributedEmbedding:
         feature ids; the step computes Out[b] = sum of that sample's rows —
         the recommender read pattern.  Emits ONE `embedding_bag` op over
         the pulled [batch_ids_max, D] rows with [B, K] local ids (-1 pads
-        ragged bags), which routes to the block-sparse Pallas gather/sum
-        kernel under FLAGS_use_pallas_embedding_bag (probe-gated,
-        pallas_kernels/adoption.py) and to the masked take+sum composition
-        otherwise.  Feed with prepare_feed_bags()."""
+        ragged bags), a masked take + sum.  Feed with
+        prepare_feed_bags()."""
         import paddle_tpu as fluid
         from ..layer_helper import LayerHelper
 

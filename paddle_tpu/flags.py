@@ -102,28 +102,6 @@ _DEFAULTS = {
     # costs relayout copies exceeding the launch savings (round-3:
     # fuse-everything = 1786 img/s vs 2200 unfused).  0 = no restriction.
     "FLAGS_fuse_optimizer_max_rank": 2,
-    # opt-in fused Pallas LayerNorm (pallas_kernels/layer_norm.py): wins
-    # standalone microbenches, measured -1.5% inside full BERT on the
-    # bench chip (breaks XLA's LN-neighbor fusions) — see ops/nn.py
-    "FLAGS_use_pallas_layer_norm": False,
-    # opt-in fused conv+bn+relu trunk block (pallas_kernels/conv_block.py):
-    # one VMEM-resident pass per image over the NCHW ResNet trunk shapes
-    # (inference folds the BN affine; training emits the batch statistics).
-    # Adoption is probe-gated (pallas_kernels/adoption.py): even with the
-    # flag on, the kernel engages only where shape/dtype checks pass AND a
-    # tools/probes/ op_bench row shows >=1.1x over the XLA fallback.
-    "FLAGS_use_pallas_conv_block": False,
-    # opt-in fused optimizer-step kernel (pallas_kernels/fused_opt.py):
-    # Adam/momentum moment recurrence + param AXPY + the bf16 param-carry
-    # cast in ONE pass over the flat fused group (the PR-2 fuse_optimizer
-    # grouping), so moments/params stream HBM once instead of three times.
-    # Bitwise-identical to the unfused fused_adam expression; probe-gated.
-    "FLAGS_use_pallas_fused_opt": False,
-    # opt-in block-sparse embedding-bag gather/sum kernel
-    # (pallas_kernels/embedding_bag.py): scalar-prefetched row indices
-    # drive the DMA schedule, opening the recommender/sparse-table path
-    # (distributed/sparse_table.py) at high QPS.  Probe-gated.
-    "FLAGS_use_pallas_embedding_bag": False,
     # deterministic collective reduction order (ops/collective.py
     # c_allreduce_sum): replace lax.psum with all_gather + a fixed-order
     # pairwise tree-reduce, so the cross-rank gradient sum reassociates
@@ -131,13 +109,6 @@ _DEFAULTS = {
     # reduction-reassociation item (ROADMAP; test_dp4_tp2 step-2 drift).
     # Costs gather bandwidth over psum, so default off.
     "FLAGS_deterministic_reduction": False,
-    # small-seq fused training attention (in-kernel mask+dropout,
-    # pallas_kernels/flash_attention.py small_attention_*): measured
-    # 3.1x faster fwd in isolation but 18% SLOWER in-step at bs224
-    # (889 vs 1081 seqs/s — the recompute backward's serial per-head
-    # VPU chain loses to XLA's materialized-probs backward), so the
-    # composed emission stays the default training path (BASELINE.md r5)
-    "FLAGS_fused_small_attention": False,
     # two-tier persistent compilation cache (core/compile_cache.py).
     # Non-empty = enabled: <dir>/xla holds JAX's native persistent XLA
     # cache (jax_compilation_cache_dir, tier A — dedupes identical HLO

@@ -808,10 +808,8 @@ def conv2d_bn_relu(input, num_filters, filter_size, stride=1, padding=0,
                    is_test=False, moving_mean_name=None,
                    moving_variance_name=None, name=None, data_format="NCHW"):
     """Fused conv + batch-norm (+ relu) trunk block: ONE `conv2d_bn_relu`
-    op instead of the conv2d / batch_norm / relu triple, so the lowering
-    can route the whole block to the Pallas fused kernel
-    (FLAGS_use_pallas_conv_block, probe-gated — pallas_kernels/adoption.py)
-    and falls back to the exact composition otherwise.  The conv carries
+    op instead of the conv2d / batch_norm / relu triple; it lowers to
+    exactly that composition.  The conv carries
     no bias: the BN affine absorbs it (the reference's conv_bn_fuse_pass
     precondition).  Only act in (None, "relu") is expressible."""
     if act not in (None, "relu"):

@@ -59,28 +59,15 @@ def multi_head_attention(x, cfg, prefix, is_test=False, use_tp=False,
         return fluid.layers.transpose(t, [0, 2, 1, 3])
 
     q, k, v = split_heads(q), split_heads(k), split_heads(v)
-    import os as _os
-
     if is_test or not cfg.dropout:
         # fast path: one fused Pallas flash-attention kernel (no
         # attention-prob dropout in this mode, so semantics are identical)
         ctxv = fluid.layers.flash_attention(q, k, v, bias_qk=attn_mask,
                                             scale=d ** -0.5)
-    elif _os.environ.get("BERT_FUSED_ATTN") == "1":
-        # A/B probe path: the flash_attention op with in-op dropout — on
-        # TPU with FLAGS_fused_small_attention it lowers to the small-seq
-        # fused kernel (bias + softmax + dropout drawn in-kernel, nothing
-        # but Out/Lse ever in HBM).  MEASURED NEGATIVE in-step at the
-        # flagship shape (889 vs 1081 seqs/s at bs224, r5 — the recompute
-        # backward loses to XLA's materialized-probs backward), so the
-        # composed emission below stays the default (BASELINE.md r5)
-        ctxv = fluid.layers.flash_attention(
-            q, k, v, bias_qk=attn_mask, scale=d ** -0.5,
-            dropout_prob=cfg.dropout, is_test=is_test)
     else:
         # composed emission for the dropout training path: measured
-        # fastest on this chip across rounds 3-5 (in-op dropout, BSHD,
-        # and the round-5 Pallas small-seq kernel all landed below it)
+        # fastest on this chip across rounds 3-5 (in-op dropout and BSHD
+        # both landed below it)
         scores = fluid.layers.matmul(q, k, transpose_y=True,
                                      alpha=d ** -0.5)
         if attn_mask is not None:
@@ -118,8 +105,8 @@ def encoder_layer(x, cfg, prefix, is_test=False, use_tp=False,
                                 attn_mask)
     # dropout -> residual add -> LayerNorm as ONE op: single-HBM-pass
     # Pallas kernel on TPU, mask drawn in-kernel (measured 1.82x the
-    # composed emission fwd+bwd at bs256/seq128 in isolation —
-    # tools/bench_fused_ln_probe.py; semantics identical).
+    # composed emission fwd+bwd at bs256/seq128 in isolation; semantics
+    # identical).
     # BERT_COMPOSED_LN=1 restores the composed emission (A/B probe).
     x = _epilogue(x, attn, cfg, is_test)
     ffn = fluid.layers.fc(x, cfg.ffn, num_flatten_dims=2, act="gelu",

@@ -347,24 +347,14 @@ def lookup_table_v2(ctx, w, ids, padding_idx=-1, **_):
 def embedding_bag(ctx, w, ids, mode="sum"):
     """Bagged lookup: Out[b] = sum_k W[Ids[b, k]] over Ids >= 0 (-1 pads
     ragged bags) — the multi-hot feature read of the recommender path
-    (distributed/sparse_table.py lookup_bag).  Routes to the block-sparse
-    Pallas gather/sum kernel (FLAGS_use_pallas_embedding_bag, probe-gated)
-    which steers the row DMA with scalar-prefetched ids so the [B, K, D]
-    take-intermediate never materializes; falls back to the masked
-    take+sum composition.  W grads (scatter-add) come from the fallback's
-    VJP on both paths."""
+    (distributed/sparse_table.py lookup_bag): a masked take + sum.  W
+    grads (scatter-add) come from its VJP."""
     if mode != "sum":
         raise ValueError("embedding_bag supports mode='sum', got %r"
                          % (mode,))
-    from ..pallas_kernels import adoption
-    from ..pallas_kernels import embedding_bag as _bag
-
-    use_kernel, _r = adoption.decide(
-        "embedding_bag", flag="FLAGS_use_pallas_embedding_bag",
-        checks=_bag.bag_checks(w.shape, ids.shape, w.dtype))
-    if use_kernel:
-        return _bag.embedding_bag(w, ids)
-    return _bag.embedding_bag_reference(w, ids)
+    g = jnp.take(w, jnp.maximum(ids, 0), axis=0)              # [B, K, D]
+    mask = (ids >= 0)[..., None]
+    return jnp.sum(jnp.where(mask, g, 0.0), axis=1).astype(w.dtype)
 
 
 @register_op("one_hot", inputs=("X", "depth_tensor"), outputs=("Out",),

@@ -408,40 +408,11 @@ def conv2d_bn_relu(ctx, x, w, scale, bias, mean, variance, strides=(1, 1),
                    data_format="NCHW", momentum=0.9, epsilon=1e-5,
                    is_test=False, with_relu=True, **_):
     """Fused conv + batch-norm (+ relu) trunk block — the reference's
-    conv_bn_fuse_pass / conv2d_fusion analogue.  Routes to the Pallas
-    block kernel when FLAGS_use_pallas_conv_block + eligibility + the
-    probe gate all pass (pallas_kernels/adoption.py); otherwise lowers to
-    the exact conv2d + _bn_impl (+ relu) composition, so the op is safe to
-    emit unconditionally.  SavedVariance holds the INVERSE std, mirroring
-    batch_norm.  Gradients come from the auto grad maker (jax.vjp over
-    this lowering; the kernel path carries a custom_vjp that routes its
-    backward through the reference composition)."""
-    from ..pallas_kernels import adoption, conv_block
-
-    f32 = jnp.float32
-    checks = conv_block.conv_block_checks(
-        x.shape, w.shape, strides, paddings, dilations, groups, data_format,
-        jnp.dtype(x.dtype).itemsize)
-    use_kernel, _ = adoption.decide(
-        "conv_block", flag="FLAGS_use_pallas_conv_block", checks=checks)
-    if use_kernel:
-        stride, pad = int(strides[0]), int(paddings[0])
-        if is_test:
-            y = conv_block.conv_bn_relu_inference(
-                x, w, scale, bias, mean, variance, epsilon, stride, pad,
-                bool(with_relu))
-            m, v = mean.astype(f32), variance.astype(f32)
-            new_mean, new_var = mean, variance
-        else:
-            y, m, v = conv_block.conv_bn_relu_train(
-                x, w, scale, bias, epsilon, stride, pad, bool(with_relu))
-            new_mean = momentum * mean + (1 - momentum) * m.astype(mean.dtype)
-            new_var = momentum * variance + (1 - momentum) * v.astype(
-                variance.dtype)
-        inv = 1.0 / jnp.sqrt(v + epsilon)
-        return y, new_mean, new_var, m, inv
-    # fallback: the general composition (any stride/padding/dilation/groups,
-    # AMP handled by the conv2d lowering)
+    conv_bn_fuse_pass / conv2d_fusion analogue.  Lowers to the exact
+    conv2d + _bn_impl (+ relu) composition (any stride, padding, dilation
+    and groups; AMP handled by the conv2d lowering), which XLA fuses.
+    SavedVariance holds the INVERSE std, mirroring batch_norm.  Gradients
+    come from the auto grad maker (jax.vjp over this lowering)."""
     conv = conv2d(ctx, x, w, strides, paddings, dilations, groups,
                   data_format)
     nchw = data_format in ("NCHW", "AnyLayout")
@@ -465,35 +436,8 @@ def conv2d_bn_relu(ctx, x, w, scale, bias, mean, variance, strides=(1, 1),
     optional_inputs=("Scale", "Bias"),
 )
 def layer_norm(ctx, x, scale, bias, epsilon=1e-5, begin_norm_axis=1):
-    import numpy as _np
-
     lead = x.shape[:begin_norm_axis]
     tail = x.shape[begin_norm_axis:]
-    # symbolic dims (shape inference's eval_shape) must stay clear of the
-    # int-only np.prod below — they take the jnp composition branch
-    concrete = all(isinstance(d, int) and d > 0 for d in x.shape)
-    if concrete and scale is not None and bias is not None:
-        from ..pallas_kernels import adoption
-        from ..pallas_kernels.layer_norm import layer_norm_2d, ln_checks
-
-        R = int(_np.prod(lead)) if lead else 1
-        C = int(_np.prod(tail)) if tail else 1
-        use_kernel, _ = adoption.decide(
-            "layer_norm", flag="FLAGS_use_pallas_layer_norm",
-            checks=ln_checks(R, C))
-        if use_kernel:
-            # fused single-pass kernel: wins standalone (5.44 vs
-            # 6.27 ms at BERT shapes, f32-stat accuracy) but loses
-            # in-program on the bench chip (719.7 vs 730.6 seqs/s —
-            # it breaks XLA's LN-neighbor fusions), hence opt-in.
-            # Mean/Variance cast to x.dtype so the op's output
-            # dtypes don't depend on the flag
-            y2, m2, v2 = layer_norm_2d(
-                x.reshape(R, C), scale.reshape(C), bias.reshape(C),
-                epsilon)
-            return (y2.reshape(x.shape),
-                    m2.astype(x.dtype).reshape(lead),
-                    v2.astype(x.dtype).reshape(lead))
     axes = tuple(range(begin_norm_axis, x.ndim))
     # bf16 inputs (the AMP carry dtype) get f32 internal statistics — an
     # 8-bit-mantissa mean/var costs accuracy (same policy as the Pallas
@@ -827,39 +771,6 @@ def _flash_attention_grad_maker(op, no_grad_set):
                        dict(op.attrs))]
 
 
-def _fa_module():
-    """The flash_attention MODULE — the package __init__ re-exports the
-    function under the same name, so a plain from-import gets the
-    function; every site needing module attributes goes through here."""
-    import importlib
-
-    return importlib.import_module(
-        "paddle_tpu.pallas_kernels.flash_attention")
-
-
-def _fa_small_kernel_ok(q_shape, k_shape, bias_shape, attrs):
-    """Static routing predicate for the small-seq fused training kernel.
-    Shared by the forward and grad lowerings: both MUST route identically
-    (the grad replays the in-kernel dropout mask from Seed)."""
-    import jax as _jax
-
-    from .. import flags as _flags
-
-    # opt-in (FLAGS_fused_small_attention): measured 18% slower in-step
-    # than the composed training emission at bs224 — see flags.py note
-    if not _flags.get_flags(["FLAGS_fused_small_attention"])[
-            "FLAGS_fused_small_attention"]:
-        return False
-    _fam = _fa_module()
-    if not _fa_uses_dropout(attrs):
-        return False
-    if _jax.default_backend() != "tpu":
-        return False
-    return _fam.small_attention_shapes_ok(
-        q_shape, k_shape, bias_shape, attrs.get("causal", False),
-        attrs.get("layout", "BHSD"))
-
-
 @register_op(
     "flash_attention",
     inputs=("Q", "K", "V", "BiasQK"),
@@ -899,7 +810,6 @@ def flash_attention_op(ctx, q, k, v, bias_qk=None, causal=False, scale=0.0,
     """
     from ..pallas_kernels import flash_attention as _fa
 
-    _fam = _fa_module()
     _fa_check_layout(layout)
     head_dim = q.shape[-1]
     sm_scale = scale if scale else head_dim ** -0.5
@@ -908,17 +818,6 @@ def flash_attention_op(ctx, q, k, v, bias_qk=None, causal=False, scale=0.0,
              "causal": causal, "layout": layout}
     seed_ph = jnp.zeros((2,), jnp.int32)
     lse_ph = jnp.zeros((1, 1, 1, 1), jnp.float32)
-    if _fa_small_kernel_ok(q.shape, k.shape,
-                           None if bias_qk is None else bias_qk.shape,
-                           attrs):
-        # small-seq fused training kernel: bias + softmax + in-kernel
-        # dropout in one pass; Seed+Lse (not a materialized mask) carry
-        # the backward's replay state
-        seed_arr = jax.random.bits(ctx.rng(), (2,), jnp.uint32)
-        out, lse = _fam.small_attention_fwd(q, k, v, bias_qk, sm_scale,
-                                            dropout_prob, seed_arr)
-        return (out, jnp.zeros((1,), jnp.uint8),
-                seed_arr.astype(jnp.int32), lse)
     if _fa_uses_dropout(attrs):
         B = q.shape[0]
         H = q.shape[2] if bshd else q.shape[1]
@@ -952,25 +851,17 @@ def flash_attention_grad_op(ctx, q, k, v, bias_qk, mask, out, seed_words,
                             lse, dy, causal=False, scale=0.0,
                             layout="BHSD", dropout_prob=0.0,
                             is_test=False):
-    """Backward: the small-seq fused kernel re-draws its in-kernel mask
-    from the saved Seed and recomputes probs from Lse; the composed
-    dropout path replays with the SAVED Mask; the dropout-free path
-    differentiates the kernel's own custom vjp.  Routing must mirror the
-    forward exactly (same static predicate)."""
+    """Backward: the composed dropout path replays with the SAVED Mask; the
+    dropout-free path differentiates the kernel's own custom vjp.  Routing
+    mirrors the forward (same static predicate).  Seed and Lse are the
+    forward's placeholders on every path."""
     from ..pallas_kernels import flash_attention as _fa
 
-    _fam = _fa_module()
     _fa_check_layout(layout)
     sm_scale = scale if scale else q.shape[-1] ** -0.5
     bshd = layout == "BSHD"
     attrs = {"dropout_prob": dropout_prob, "is_test": is_test,
              "causal": causal, "layout": layout}
-    if _fa_small_kernel_ok(q.shape, k.shape,
-                           None if bias_qk is None else bias_qk.shape,
-                           attrs):
-        return _fam.small_attention_bwd(
-            q, k, v, bias_qk, sm_scale, dropout_prob,
-            seed_words.astype(jnp.uint32), out, lse, dy)
     if _fa_uses_dropout(attrs):
         fn = lambda a, b, c: _attention_composed(
             a, b, c, bias_qk, causal, sm_scale, mask, dropout_prob, bshd)
@@ -1036,7 +927,7 @@ def fused_dropout_add_ln_op(ctx, x, y, scale, bias, dropout_prob=0.0,
     (paddle/fluid/operators/fused/fused_fc_elementwise_layernorm_op.cu —
     inference-only there), extended with in-kernel dropout for training:
     measured 1.82x the composed dropout->add->layer_norm emission fwd+bwd
-    at the flagship BERT shape (tools/bench_fused_ln_probe.py).
+    at the flagship BERT shape.
 
     The dropout mask is never materialized: the forward draws it from the
     on-core PRNG seeded by the Seed output (two u32 words stored as

@@ -334,21 +334,6 @@ def fused_momentum(ctx, params, grads, vels, lr, mu=0.0,
                    regularization_coeff=0.0):
     dt = params[0].dtype
     lr_ = _lr(lr).astype(dt)
-    if regularization_method != "l2_decay":
-        # the l2 fold reads p_flat anyway, so the one-pass win is gone —
-        # keep that case on the jnp path (the kernel would need a second
-        # read of params just to rebuild g)
-        from ..pallas_kernels import adoption, fused_opt
-
-        use_kernel, _ = adoption.decide(
-            "fused_opt", flag="FLAGS_use_pallas_fused_opt",
-            checks=fused_opt.fused_opt_checks(params, grads, (vels,)))
-        if use_kernel:
-            p_news, v_news, bf16s = fused_opt.fused_momentum_step(
-                params, grads, vels, _lr(lr), mu=mu,
-                use_nesterov=use_nesterov)
-            fused_opt.stash_bf16_carry(ctx, bf16s)
-            return (p_news, v_news)
     p_flat, sizes = _flatten_group(params)
     g_flat, _ = _flatten_group([g.astype(dt) for g in grads])
     v_flat, _ = _flatten_group(vels)
@@ -381,21 +366,6 @@ def fused_adam(ctx, params, grads, m1s, m2s, lr, b1pows, b2pows,
                beta1=0.9, beta2=0.999, epsilon=1e-8):
     dt = params[0].dtype
     lr_ = _lr(lr).astype(dt)
-    from ..pallas_kernels import adoption, fused_opt
-
-    use_kernel, _ = adoption.decide(
-        "fused_opt", flag="FLAGS_use_pallas_fused_opt",
-        checks=fused_opt.fused_opt_checks(params, grads, (m1s, m2s)))
-    if use_kernel:
-        # one VMEM pass per tile: moments + AXPY + the bf16 carry cast —
-        # bitwise-equal to the jnp path below (fused_opt.py docstring),
-        # verified over 3 steps by tests/test_pallas_blocks.py
-        p_news, m1ns, m2ns, b1outs, b2outs, bf16s = \
-            fused_opt.fused_adam_step(
-                params, grads, m1s, m2s, _lr(lr), b1pows, b2pows,
-                beta1=beta1, beta2=beta2, epsilon=epsilon)
-        fused_opt.stash_bf16_carry(ctx, bf16s)
-        return (p_news, m1ns, m2ns, b1outs, b2outs)
     b1 = jnp.asarray(beta1, dt)
     b2 = jnp.asarray(beta2, dt)
     sizes = [int(np.prod(p.shape)) for p in params]

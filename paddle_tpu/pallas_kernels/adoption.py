@@ -1,67 +1,50 @@
-"""Probe-gated Pallas kernel adoption — one funnel for every kernel family.
+"""One rule for choosing a Pallas kernel — one funnel for every family.
 
 Every kernel family under ``pallas_kernels/`` (``KERNELS``) decides here
-whether it engages; before this module each carried its own copy of the
-shape/dtype eligibility checks and fell back SILENTLY — a misconfigured flag
-or an off-by-128 channel count ran the jnp composition with no trace in the
-metrics.  This module centralizes:
+whether it engages.  A family is chosen from what the lowering can observe
+(shapes, dtypes, the backend, the kind of trace), never by a flag:
 
-* **eligibility** — ``decide()`` walks an ordered check list; the first
-  failing check becomes the fallback *reason*.
+* **eligibility** — ``decide(kernel, checks)`` walks the family's ordered
+  check list; the first failing check becomes the fallback *reason*.  The
+  checks live beside the kernel that owns them (``fused_ln_checks``,
+  ``flash_attention_checks``, ``paged_attention_checks``).
 * **telemetry** — every decision increments
   ``pallas_kernel_used_total{kernel}`` or
-  ``pallas_kernel_fallback_total{kernel,reason}`` in the PR-3 registry
-  (no-ops when FLAGS_telemetry is off), so a silent fallback is now a
-  countable event.
-* **the probe gate** — the hierarchical-systems cost-model discipline
-  (PAPERS.md arXiv 2110.10548): a kernel may be *written* optimistically
-  but is *adopted* only where a measured ``tools/op_bench.py --pallas``
-  probe shows >= 1.1x over its own fallback on the target device.  Probe
-  rows are JSON files (BASELINE.md round-9 protocol);
-  ``PADDLE_PALLAS_PROBE_DIR`` points at the archive (default:
-  ``tools/probes/results/``, which holds no row from the current
-  installation).
-
-Flag-off is INERT: no counters move, so a default-configured run pays one
-dict lookup per decision and nothing else.
+  ``pallas_kernel_fallback_total{kernel,reason}`` in the telemetry
+  registry (no-ops when FLAGS_telemetry is off), so a fallback is a
+  countable event and never silent.
+* **the trace kind** — build-time shape inference chooses nothing and
+  counts nothing (``shape_inference``); in a program that XLA partitions
+  automatically over several devices no kernel engages
+  (``auto_partitioned``, counted under reason ``gspmd_mesh``).
 
 ``PADDLE_PALLAS_INTERPRET=1`` forces interpret-mode execution (kernels run
-through the Pallas interpreter on CPU) and waives the backend + probe
-checks — the CI ``--kernel-smoke`` leg and the parity tests ride this.  On
-any backend but the CPU's it raises.
+through the Pallas interpreter on the CPU test tier).  On any backend but
+the CPU's it raises.
 
-In a program that XLA partitions automatically over several devices no
-kernel engages (``auto_partitioned``, reason ``gspmd_mesh``).
+A new kernel enters through ``decide`` with its checks, a benchmark cell
+that runs it, and a case in tests/test_tpu_compile.py.
 """
 
 import contextlib
-import json
 import os
 import threading
 
-__all__ = ["decide", "active_kernels", "probe_speedup", "register_probe",
-           "reset", "interpret_mode", "interpret", "auto_partitioned",
-           "shape_inference", "KERNELS", "MIN_SPEEDUP"]
+__all__ = ["decide", "active_kernels", "reset", "interpret_mode",
+           "interpret", "auto_partitioned", "shape_inference", "KERNELS"]
 
 # the kernel families sharing this funnel
-KERNELS = ("layer_norm", "fused_ln", "flash_attention", "conv_block",
-           "fused_opt", "embedding_bag", "paged_attention")
-
-# adoption threshold: a probe row below this keeps the fallback
-MIN_SPEEDUP = 1.1
+KERNELS = ("fused_ln", "flash_attention", "paged_attention")
 
 _lock = threading.Lock()
 _active = set()          # kernels that engaged >= 1 time this process
-_probe_overrides = {}    # kernel -> speedup (register_probe: tests/op_bench)
-_probe_cache = None      # kernel -> speedup loaded from the archive dir
 
 
 def interpret_mode():
     """True when PADDLE_PALLAS_INTERPRET forces the Pallas interpreter
-    (CPU parity tests / the --kernel-smoke probe leg).  The interpreter is
-    for the CPU backend only: leaked into a chip run, the switch would
-    waive every probe gate and run each kernel interpreted while counting
-    it as engaged, so there it is an error."""
+    (the CPU parity tests).  The interpreter is for the CPU backend only:
+    leaked into a chip run, the switch would run each kernel interpreted
+    while counting it as engaged, so there it is an error."""
     if os.environ.get("PADDLE_PALLAS_INTERPRET", "") not in ("1", "true"):
         return False
     import jax
@@ -117,98 +100,10 @@ def auto_partitioned():
     return _tracing("gspmd_mesh")
 
 
-def _probe_dir():
-    d = os.environ.get("PADDLE_PALLAS_PROBE_DIR", "")
-    if d:
-        return d
-    here = os.path.dirname(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))))
-    return os.path.join(here, "tools", "probes", "results")
-
-
-def _load_probes():
-    """kernel -> best measured speedup across every archived probe row.
-
-    A row is any JSON object (one per file, or one per line) with
-    ``kernel`` and ``speedup`` keys — exactly what
-    ``op_bench.py --pallas --save-probe`` writes.  Unreadable files are
-    skipped: a corrupt archive must degrade to "no probe" (fallback),
-    never to a crash in the hot path."""
-    out = {}
-    d = _probe_dir()
-    try:
-        names = sorted(os.listdir(d))
-    except OSError:
-        return out
-    for name in names:
-        if not name.endswith(".json"):
-            continue
-        try:
-            with open(os.path.join(d, name)) as f:
-                text = f.read()
-        except OSError:
-            continue
-        rows = []
-        try:
-            obj = json.loads(text)
-            rows = obj if isinstance(obj, list) else [obj]
-        except ValueError:
-            for line in text.splitlines():
-                line = line.strip()
-                if not line:
-                    continue
-                try:
-                    rows.append(json.loads(line))
-                except ValueError:
-                    pass
-        for row in rows:
-            if not isinstance(row, dict):
-                continue
-            k = row.get("kernel")
-            try:
-                sp = float(row.get("speedup"))
-            except (TypeError, ValueError):
-                continue
-            if k in KERNELS:
-                out[k] = max(out.get(k, 0.0), sp)
-    return out
-
-
-def probe_speedup(kernel):
-    """Best archived probe speedup for `kernel`, or None if never probed.
-    In-memory registrations (register_probe) win over the disk archive."""
-    global _probe_cache
-    with _lock:
-        if kernel in _probe_overrides:
-            return _probe_overrides[kernel]
-        cache = _probe_cache
-    if cache is None:
-        # read the archive outside the lock — disk I/O must not stall
-        # register_probe()/decide() callers on other threads.  Two racing
-        # loaders both read the same files; first publish wins and the
-        # loser adopts it, so every caller sees one consistent cache.
-        loaded = _load_probes()
-        with _lock:
-            if _probe_cache is None:
-                _probe_cache = loaded
-            cache = _probe_cache
-    return cache.get(kernel)
-
-
-def register_probe(kernel, speedup):
-    """Record an in-process probe result (op_bench --pallas runs this after
-    measuring; tests use it to exercise the gate without touching disk)."""
-    with _lock:
-        _probe_overrides[kernel] = float(speedup)
-
-
 def reset():
-    """Clear the active set, probe overrides, and the disk cache (tests)."""
-    global _probe_cache
+    """Clear the active set (tests)."""
     with _lock:
         _active.clear()
-        _probe_overrides.clear()
-        _probe_cache = None
 
 
 def _inc(name, **labels):
@@ -217,25 +112,13 @@ def _inc(name, **labels):
     telemetry.inc(name, **labels)
 
 
-def decide(kernel, flag=None, checks=(), require_probe=True):
+def decide(kernel, checks):
     """Single adoption decision.  Returns (use: bool, reason: str).
 
-    `flag`: the FLAGS_use_pallas_* name gating this family; when the flag
-    is off the decision is inert — (False, "flag_off") with NO telemetry,
-    so default-configured runs cost one flag read.  `checks` is an ordered
-    iterable of (reason, ok) pairs; the first falsy `ok` is the recorded
-    fallback reason (eligibility stays next to the kernel that owns it —
-    this funnel owns the ordering, counting, and the probe gate).
-    `require_probe=False` is for kernels whose adoption predates the probe
-    protocol and is pinned by in-step BASELINE numbers instead (fused_ln:
-    the round-3 LN lesson is that a microbench win is necessary but not
-    sufficient, so an in-step capture outranks the probe row;
-    paged_attention: no flag either, pinned by the serving cells of
-    BENCHMARK.json)."""
-    from .. import flags as _flags
-
-    if flag is not None and not _flags.flag(flag):
-        return False, "flag_off"
+    `checks` is an ordered iterable of (reason, ok) pairs; the first falsy
+    `ok` is the recorded fallback reason (eligibility stays next to the
+    kernel that owns it — this funnel owns the trace kind, the ordering
+    and the counting)."""
     kind = getattr(_trace, "kind", None)
     if kind == "shape_inference":
         return False, kind
@@ -247,16 +130,6 @@ def decide(kernel, flag=None, checks=(), require_probe=True):
             _inc("pallas_kernel_fallback_total", kernel=kernel,
                  reason=reason)
             return False, reason
-    if require_probe and not interpret_mode():
-        sp = probe_speedup(kernel)
-        if sp is None:
-            _inc("pallas_kernel_fallback_total", kernel=kernel,
-                 reason="no_probe")
-            return False, "no_probe"
-        if sp < MIN_SPEEDUP:
-            _inc("pallas_kernel_fallback_total", kernel=kernel,
-                 reason="probe_below_min")
-            return False, "probe_below_min"
     _inc("pallas_kernel_used_total", kernel=kernel)
     with _lock:
         _active.add(kernel)
@@ -266,7 +139,6 @@ def decide(kernel, flag=None, checks=(), require_probe=True):
 def active_kernels():
     """Sorted kernels that engaged at least once this process — bench.py
     prints this as `pallas_kernels_active` so a capture records which
-    kernels actually ran (a kernel adopted without a probe row is an
-    invalid capture, BASELINE.md round-9)."""
+    kernels actually ran."""
     with _lock:
         return sorted(_active)

@@ -19,8 +19,6 @@ import functools
 import jax
 import jax.numpy as jnp
 
-from . import prng as _prng
-
 __all__ = ["flash_attention"]
 
 _NEG_INF = -1e30
@@ -434,32 +432,39 @@ def _bwd_pallas(q, k, v, bias, causal, sm_scale, block_q, block_k,
 # ---------------------------------------------------------------------------
 
 
+def _blocks(q_shape, k_shape):
+    Sq, Sk = q_shape[2], k_shape[2]
+    return (_pick_block(Sq, preferred=1024 if Sq >= 4096 else 512),
+            _pick_block(Sk, preferred=1024))
+
+
+def flash_attention_checks(q_shape, k_shape, interpret):
+    """Ordered (reason, ok) pairs for adoption.decide(): what the kernel
+    needs of ``[B, H, Sq, D]`` queries and ``[B, H, Sk, D]`` keys."""
+    bq, bk = _blocks(q_shape, k_shape)
+    return [
+        # measured on v5e: below ~1k keys the XLA-fused composition is
+        # faster (kernel launch/grid overhead dominates); above it the
+        # blockwise kernel wins and, more importantly, never
+        # materializes the [Sq, Sk] score matrix
+        ("short_keys", k_shape[2] >= 1024),
+        ("blocks", bq is not None and bk is not None),
+        # off the TPU only a caller that asks for the interpreter gets
+        # the kernel: the jnp reference is faster than interpret
+        ("backend", interpret is not None
+         or jax.default_backend() == "tpu"),
+    ]
+
+
 def _can_use_pallas(q, k, interpret):
     from . import adoption
 
-    Sq, Sk = q.shape[2], k.shape[2]
-    bq = _pick_block(Sq, preferred=1024 if Sq >= 4096 else 512)
-    bk = _pick_block(Sk, preferred=1024)
-    # the shared adoption funnel, flag-less like fused_ln: the kernel
-    # engages by default on TPU and every route around it is counted
     use, _ = adoption.decide(
         "flash_attention",
-        checks=[
-            # measured on v5e: below ~1k keys the XLA-fused composition is
-            # faster (kernel launch/grid overhead dominates); above it the
-            # blockwise kernel wins and, more importantly, never
-            # materializes the [Sq, Sk] score matrix
-            ("short_keys", Sk >= 1024),
-            ("blocks", bq is not None and bk is not None),
-            # off the TPU only a caller that asks for the interpreter gets
-            # the kernel: the jnp reference is faster than interpret
-            ("backend", interpret is not None
-             or jax.default_backend() == "tpu"),
-        ],
-        require_probe=False)
+        flash_attention_checks(q.shape, k.shape, interpret))
     if not use:
         return False, None, None
-    return True, (bq, bk), bool(interpret)
+    return True, _blocks(q.shape, k.shape), bool(interpret)
 
 
 # bias=None routes through the same vjp (None is a valid empty pytree for a
@@ -485,244 +490,6 @@ def _flash_b_bwd(causal, sm_scale, blocks, interpret, res, do):
 
 
 _flash_b.defvjp(_flash_b_fwd, _flash_b_bwd)
-
-
-# ---------------------------------------------------------------------------
-# small-sequence fused training attention (mask + dropout INSIDE the kernel)
-# ---------------------------------------------------------------------------
-#
-# The blockwise kernel above targets long sequences (online softmax, never
-# materializes [Sq, Sk]).  At the flagship BERT training shape
-# (bs256/seq128) the whole score tile fits in VMEM, so this kernel does
-# plain softmax per head and — the actual point — draws the
-# attention-prob dropout mask with the ON-CORE PRNG: the composed
-# emission materializes [B, H, S, S] probs (100 MB bf16) plus a u8 keep
-# mask (50 MB) per layer and re-reads them in the backward; here neither
-# ever touches HBM (the backward re-draws the mask from the 2-word seed
-# and recomputes probs from the saved per-row LSE, FlashAttention-style).
-# Reference analog: fused/multihead_matmul_op.cu (inference-only there;
-# trainable here).
-
-_SMALL_SEQ_MAX = 256
-
-
-def small_attention_shapes_ok(q_shape, k_shape, bias_shape, causal, layout):
-    """Static predicate shared by the op's forward and grad lowerings —
-    BOTH must route identically or the backward replays a wrong mask."""
-    if layout != "BHSD" or causal:
-        return False
-    if len(q_shape) != 4 or len(k_shape) != 4:
-        return False
-    B, H, S, D = q_shape
-    if not all(isinstance(d, int) for d in (B, H, S, D)):
-        return False
-    if k_shape[2] != S or k_shape[3] != D or S > _SMALL_SEQ_MAX \
-            or S % 128 != 0:
-        return False
-    if D not in (64, 128):
-        return False
-    if bias_shape is not None:
-        # the kernel tiles the bias as full [Sq, Sk] blocks: broadcast
-        # shapes like [B,1,1,S] must take the composed fallback
-        if (len(bias_shape) != 4 or bias_shape[1] not in (1, H)
-                or bias_shape[2] != S or bias_shape[3] != S):
-            return False
-    return True
-
-
-def _small_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref,
-                      lse_ref, *, sm_scale, thr, H, S, D, bias_per_head):
-    if thr is not None:
-        _prng.seed_block_prng(seed_ref)
-        bits = _prng.draw_keep_bits((H * S, S), thr)
-        inv_q = _prng.inv_realized_q(thr)
-    for h in range(H):
-        # dots take the NATIVE (bf16) operands — the MXU multiplies bf16
-        # at full rate and accumulates f32; upcasting operands first
-        # makes every dot a multi-pass f32 matmul (~6x slower)
-        s = jax.lax.dot_general(q_ref[0, h], k_ref[0, h],
-                                (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * sm_scale
-        if bias_ref is not None:
-            s = s + bias_ref[0, h if bias_per_head else 0].astype(jnp.float32)
-        m = jnp.max(s, axis=1, keepdims=True)
-        p = jnp.exp(s - m)
-        l = jnp.sum(p, axis=1, keepdims=True)
-        prob = p / l
-        if thr is not None:
-            keep = bits[h * S:(h + 1) * S, :]
-            prob = jnp.where(keep, prob * inv_q, 0.0)
-        oh = jax.lax.dot_general(
-            prob.astype(v_ref.dtype), v_ref[0, h],
-            (((1,), (0,)), ((), ())), preferred_element_type=jnp.float32)
-        o_ref[0, h] = oh.astype(o_ref.dtype)
-        lse_ref[0, h] = m + jnp.log(l)
-
-
-def _small_bwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref,
-                      lse_ref, delta_ref, dq_ref, dk_ref, dv_ref, *,
-                      sm_scale, thr, H, S, D, bias_per_head):
-    if thr is not None:
-        _prng.seed_block_prng(seed_ref)
-        bits = _prng.draw_keep_bits((H * S, S), thr)
-        inv_q = _prng.inv_realized_q(thr)
-    cdt = q_ref.dtype  # carry dtype for MXU operands (bf16 in training)
-    for h in range(H):
-        # all dots on native-dtype operands, f32 accumulation (see fwd)
-        s = jax.lax.dot_general(q_ref[0, h], k_ref[0, h],
-                                (((1,), (1,)), ((), ())),
-                                preferred_element_type=jnp.float32) * sm_scale
-        if bias_ref is not None:
-            s = s + bias_ref[0, h if bias_per_head else 0].astype(jnp.float32)
-        prob = jnp.exp(s - lse_ref[0, h])
-        if thr is not None:
-            keep = bits[h * S:(h + 1) * S, :]
-            pd = jnp.where(keep, prob * inv_q, 0.0)
-        else:
-            pd = prob
-        dv_ref[0, h] = jax.lax.dot_general(
-            pd.astype(cdt), do_ref[0, h], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32).astype(dv_ref.dtype)
-        dpd = jax.lax.dot_general(do_ref[0, h], v_ref[0, h],
-                                  (((1,), (1,)), ((), ())),
-                                  preferred_element_type=jnp.float32)
-        if thr is not None:
-            dp = jnp.where(keep, dpd * inv_q, 0.0)
-        else:
-            dp = dpd
-        ds = (prob * (dp - delta_ref[0, h]) * sm_scale).astype(cdt)
-        dq_ref[0, h] = jax.lax.dot_general(
-            ds, k_ref[0, h], (((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32).astype(dq_ref.dtype)
-        dk_ref[0, h] = jax.lax.dot_general(
-            ds, q_ref[0, h], (((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32).astype(dk_ref.dtype)
-
-
-# shared realized-keep-probability contract (pallas_kernels/prng.py)
-small_keep_threshold = _prng.keep_threshold
-
-
-def _small_specs(B, H, S, D, bias_shape):
-    qspec = pl.BlockSpec((1, H, S, D), lambda b, *_: (b, 0, 0, 0))
-    lspec = pl.BlockSpec((1, H, S, 1), lambda b, *_: (b, 0, 0, 0))
-    bias_per_head = bias_shape is not None and bias_shape[1] != 1
-    bspec = None
-    if bias_shape is not None:
-        bh = H if bias_per_head else 1
-        bspec = pl.BlockSpec((1, bh, S, S), lambda b, *_: (b, 0, 0, 0))
-    return qspec, lspec, bspec, bias_per_head
-
-
-def small_attention_fwd(q, k, v, bias, sm_scale, dropout_prob, seed):
-    """Fused small-seq attention forward: (out, lse).  seed: [2] u32."""
-    B, H, S, D = q.shape
-    thr = small_keep_threshold(dropout_prob)
-    seed = jnp.asarray(seed).reshape(2).astype(jnp.uint32)
-    qspec, lspec, bspec, bph = _small_specs(B, H, S, D,
-                                            None if bias is None
-                                            else bias.shape)
-    args = [q, k, v] + ([bias] if bias is not None else [])
-    in_specs = [qspec, qspec, qspec] + ([bspec] if bias is not None else [])
-
-    if bias is not None:
-        def kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, o_ref, lse_ref):
-            _small_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref,
-                              o_ref, lse_ref, sm_scale=sm_scale, thr=thr,
-                              H=H, S=S, D=D, bias_per_head=bph)
-    else:
-        def kernel(seed_ref, q_ref, k_ref, v_ref, o_ref, lse_ref):
-            _small_fwd_kernel(seed_ref, q_ref, k_ref, v_ref, None,
-                              o_ref, lse_ref, sm_scale=sm_scale, thr=thr,
-                              H=H, S=S, D=D, bias_per_head=bph)
-
-    out, lse = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(B,),
-            in_specs=in_specs, out_specs=[qspec, lspec]),
-        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
-                   jax.ShapeDtypeStruct((B, H, S, 1), jnp.float32)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",)),
-    )(seed, *args)
-    return out, lse
-
-
-def small_attention_bwd(q, k, v, bias, sm_scale, dropout_prob, seed, out,
-                        lse, do):
-    """Fused small-seq attention backward: (dq, dk, dv)."""
-    B, H, S, D = q.shape
-    thr = small_keep_threshold(dropout_prob)
-    seed = jnp.asarray(seed).reshape(2).astype(jnp.uint32)
-    delta = jnp.sum(do.astype(jnp.float32) * out.astype(jnp.float32),
-                    axis=-1, keepdims=True)  # rowsum(dO . O) — dropout-safe
-    qspec, lspec, bspec, bph = _small_specs(B, H, S, D,
-                                            None if bias is None
-                                            else bias.shape)
-    args = [q, k, v] + ([bias] if bias is not None else []) + [do, lse,
-                                                               delta]
-    in_specs = ([qspec, qspec, qspec]
-                + ([bspec] if bias is not None else [])
-                + [qspec, lspec, lspec])
-
-    if bias is not None:
-        def kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref, do_ref, lse_ref,
-                   delta_ref, dq_ref, dk_ref, dv_ref):
-            _small_bwd_kernel(seed_ref, q_ref, k_ref, v_ref, bias_ref,
-                              do_ref, lse_ref, delta_ref, dq_ref, dk_ref,
-                              dv_ref, sm_scale=sm_scale, thr=thr, H=H, S=S,
-                              D=D, bias_per_head=bph)
-    else:
-        def kernel(seed_ref, q_ref, k_ref, v_ref, do_ref, lse_ref,
-                   delta_ref, dq_ref, dk_ref, dv_ref):
-            _small_bwd_kernel(seed_ref, q_ref, k_ref, v_ref, None,
-                              do_ref, lse_ref, delta_ref, dq_ref, dk_ref,
-                              dv_ref, sm_scale=sm_scale, thr=thr, H=H, S=S,
-                              D=D, bias_per_head=bph)
-
-    dq, dk, dv = pl.pallas_call(
-        kernel,
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=1, grid=(B,),
-            in_specs=in_specs, out_specs=[qspec, qspec, qspec]),
-        out_shape=[jax.ShapeDtypeStruct(q.shape, q.dtype),
-                   jax.ShapeDtypeStruct(k.shape, k.dtype),
-                   jax.ShapeDtypeStruct(v.shape, v.dtype)],
-        compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel",)),
-    )(seed, *args)
-    return dq, dk, dv
-
-
-@functools.partial(jax.custom_vjp, nondiff_argnums=(4, 5))
-def small_attention(q, k, v, bias, sm_scale, dropout_prob, seed):
-    """Functional fused small-seq training attention (custom vjp).
-
-    softmax(q k^T * scale + bias) with attention-prob dropout
-    (upscale_in_train, realized keep prob round(q*2^32)/2^32) applied
-    INSIDE the kernel; the backward re-draws the identical mask from
-    seed.  TPU-only (callers gate on small_attention_shapes_ok +
-    backend)."""
-    out, _ = small_attention_fwd(q, k, v, bias, sm_scale, dropout_prob,
-                                 seed)
-    return out
-
-
-def _small_attn_vjp_fwd(q, k, v, bias, sm_scale, dropout_prob, seed):
-    out, lse = small_attention_fwd(q, k, v, bias, sm_scale, dropout_prob,
-                                   seed)
-    return out, (q, k, v, bias, seed, out, lse)
-
-
-def _small_attn_vjp_bwd(sm_scale, dropout_prob, res, do):
-    q, k, v, bias, seed, out, lse = res
-    dq, dk, dv = small_attention_bwd(q, k, v, bias, sm_scale, dropout_prob,
-                                     seed, out, lse, do)
-    return dq, dk, dv, None, None
-
-
-small_attention.defvjp(_small_attn_vjp_fwd, _small_attn_vjp_bwd)
 
 
 def flash_attention(q, k, v, bias=None, causal=False, sm_scale=None,

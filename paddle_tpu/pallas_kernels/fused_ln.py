@@ -253,6 +253,18 @@ def _bwd_fallback(r2, gamma, seed, mean, var, dz2, thr, eps):
 # ---------------------------------------------------------------------------
 
 
+def fused_ln_checks(n, h, itemsize):
+    """Ordered (reason, ok) pairs for adoption.decide(): what the kernel
+    needs of its ``[n, h]`` operands of ``itemsize`` bytes an element."""
+    return [
+        ("backend", jax.default_backend() == "tpu"),
+        ("symbolic_shape", isinstance(n, int)),
+        ("lanes", h % _LANES == 0),
+        ("block_rows", isinstance(n, int) and h % _LANES == 0
+         and _pick_rows(n, h, itemsize) is not None),
+    ]
+
+
 def _use_pallas(x2, y2):
     # The entry points cast y to x.dtype BEFORE this choice, so the fwd
     # (x2, y2) and bwd (r2, r2 — r stored in x.dtype) calls see the SAME
@@ -264,25 +276,9 @@ def _use_pallas(x2, y2):
     from . import adoption
 
     n, h = x2.shape
-    concrete = isinstance(n, int)
-    rows = None
-    if concrete and h % _LANES == 0:
-        rows = _pick_rows(n, h, x2.dtype.itemsize)
-    # the shared adoption funnel (counts fallbacks; flag-less: this kernel
-    # engages by default on TPU).  require_probe=False: adoption predates
-    # the probe protocol and is pinned by in-step BASELINE r5 captures —
-    # the round-3 LN lesson is that a microbench probe is necessary but
-    # not sufficient, so the in-step number outranks it here.
-    use, _ = adoption.decide(
-        "fused_ln",
-        checks=[
-            ("backend", jax.default_backend() == "tpu"),
-            ("symbolic_shape", concrete),
-            ("lanes", h % _LANES == 0),
-            ("block_rows", rows is not None),
-        ],
-        require_probe=False)
-    return rows if use else None
+    itemsize = x2.dtype.itemsize
+    use, _ = adoption.decide("fused_ln", fused_ln_checks(n, h, itemsize))
+    return _pick_rows(n, h, itemsize) if use else None
 
 
 def _fwd_any(x2, y2, gamma, beta, seed, thr, eps):

@@ -351,12 +351,10 @@ def paged_attention(q, k_cache, v_cache, block_tables, context_lens):
     """The step's attention over a layer's pools: the kernel where the
     shape rule admits it (``adoption.decide`` counts the lowering under
     ``pallas_kernel_used_total`` / ``..._fallback_total{reason}``), the
-    gather otherwise.  ``require_probe=False`` as ``fused_ln`` has it: the
-    serving cells' numbers outrank a probe row."""
+    gather otherwise."""
     use, _reason = adoption.decide(
         "paged_attention",
-        checks=paged_attention_checks(q.shape, k_cache.shape, k_cache.dtype),
-        require_probe=False)
+        paged_attention_checks(q.shape, k_cache.shape, k_cache.dtype))
     if use:
         with jax.named_scope("kv_read"):
             return _paged_pallas(q, k_cache, v_cache, block_tables,

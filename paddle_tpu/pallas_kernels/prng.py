@@ -2,9 +2,8 @@
 
 The realized-keep-probability contract — keep iff random_u32 <
 round(q * 2^32), upscale divided by that REALIZED probability — is
-load-bearing for forward/backward mask replay in BOTH fused kernels
-(fused_ln.py, flash_attention.py small_attention_*).  It lives here once
-so the copies cannot drift.
+load-bearing for forward/backward mask replay in the fused LN kernel
+(fused_ln.py): both passes draw through these helpers.
 """
 
 import jax.numpy as jnp
@@ -28,11 +27,6 @@ def keep_threshold(dropout_prob):
 def realized_q(thr):
     """The keep probability the threshold actually samples with."""
     return thr / _TWO32
-
-
-def inv_realized_q(thr):
-    """Upscale multiplier 1/realized_q(thr)."""
-    return 1.0 / realized_q(thr)
 
 
 def seed_block_prng(seed_ref, grid_axis=0):

@@ -199,8 +199,8 @@ def test_no_pallas_kernel_is_chosen_in_an_auto_partitioned_program():
                    tm.label_sets("pallas_kernel_fallback_total")
                    if ls["kernel"] == "fused_ln"}
         assert reasons == {"gspmd_mesh"}, reasons
-        assert adoption.decide("fused_ln", require_probe=False,
-                               checks=[("backend", False)])[1] == "backend"
+        assert adoption.decide("fused_ln",
+                               [("backend", False)])[1] == "backend"
     finally:
         tm.reset()
         fluid.set_flags(old)

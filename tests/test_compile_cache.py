@@ -20,9 +20,11 @@ import subprocess
 import sys
 
 import numpy as np
+import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu.core import compile_cache as cc
+from paddle_tpu.core import executor as executor_mod
 from paddle_tpu.core import telemetry as tm
 
 _PAYLOAD = os.path.join(os.path.dirname(os.path.abspath(__file__)),
@@ -259,3 +261,41 @@ def test_artifact_key_stable_and_flag_sensitive(tmp_path):
     finally:
         del main1._collective_meta
     assert k4 != k1
+
+
+_FLIPPED = {"FLAGS_check_nan_inf": True, "FLAGS_bn_stat_subsample": 2,
+            "FLAGS_layout_match_params": False,
+            "FLAGS_deterministic_reduction": True}
+
+
+@pytest.mark.parametrize("flag", executor_mod._TRACE_FLAGS)
+def test_trace_flag_keys_the_cache(tmp_path, flag):
+    """Each trace-affecting flag is in both keys: flipping it between two
+    runs of one program is exactly one more in-memory miss and another
+    tier-B artifact, and run and warmup agree on both keys under either
+    setting (a warmed signature is no miss; an artifact a run stored is
+    what another executor's warmup restores)."""
+    specs = {"x": ((8, 4), "float32"), "y": ((8, 1), "float32")}
+    with _flags(compile_cache_dir=str(tmp_path / "cc"), telemetry=True):
+        main, startup, loss = _build()
+        exe = fluid.Executor(fluid.CPUPlace())
+        with fluid.scope_guard(fluid.Scope()):
+            exe.run(startup)
+            warmed = exe.warmup(main, feed_specs=specs,
+                                fetch_list=[loss.name])
+            assert warmed["source"] == "compiled", warmed
+            before = _counters()
+            exe.run(main, feed=_feed(), fetch_list=[loss.name])
+            assert _delta(before, "executor_cache_miss_total") == 0
+            with _flags(**{flag: _FLIPPED[flag]}):
+                exe.run(main, feed=_feed(), fetch_list=[loss.name])
+                assert _delta(before, "executor_cache_miss_total") == 1
+                exe.run(main, feed=_feed(), fetch_list=[loss.name])
+                assert _delta(before, "executor_cache_miss_total") == 1
+                again = fluid.Executor(fluid.CPUPlace()).warmup(
+                    main, feed_specs=specs, fetch_list=[loss.name])
+            assert again["source"] == "disk", again
+            assert again["key"] and again["key"] != warmed["key"]
+            # back under the first setting the first entry still serves
+            exe.run(main, feed=_feed(), fetch_list=[loss.name])
+            assert _delta(before, "executor_cache_miss_total") == 1

@@ -135,11 +135,9 @@ def test_shape_rule(interpreted, monkeypatch, case):
     assert _tm.counter_total("pallas_kernel_fallback_total") == 0
     if where == "mesh":
         with adoption.auto_partitioned():
-            use, reason = adoption.decide("paged_attention", checks=checks,
-                                          require_probe=False)
+            use, reason = adoption.decide("paged_attention", checks)
     else:
-        use, reason = adoption.decide("paged_attention", checks=checks,
-                                      require_probe=False)
+        use, reason = adoption.decide("paged_attention", checks)
         assert path == ("pallas" if use else "gather")
     assert (use, reason) == (expected == "ok", expected)
     counted = dict(_tm.label_sets("pallas_kernel_fallback_total"))

@@ -339,10 +339,28 @@ def test_async_eviction_reclaims_replay_state():
         fluid.flags.set_flags({"FLAGS_worker_hb_timeout": old_to})
 
 
-def test_geo_sgd_converges():
+@pytest.mark.parametrize("apply_delay_s", [0.0, 0.03])
+def test_geo_sgd_converges(monkeypatch, apply_delay_s):
     """Geo-SGD: local training + periodic delta pushes; both trainers'
     params drift toward each other through the server merge and the task
-    converges (reference geo_sgd_transpiler.py semantics)."""
+    converges (reference geo_sgd_transpiler.py semantics).  With the
+    server slow to apply what it has acknowledged (a loaded machine), a
+    trainer still reads back params that hold its own delta: a pull that
+    outran the apply made the next push add the same progress twice, and
+    the task diverged."""
+    if apply_delay_s:
+        import time
+
+        from paddle_tpu.native import rpc
+
+        poll = rpc.RpcServer.poll
+
+        def slow_poll(self):
+            event = poll(self)
+            time.sleep(apply_delay_s)
+            return event
+
+        monkeypatch.setattr(rpc.RpcServer, "poll", slow_poll)
     steps, bs, K = 24, 8, 4
     eps = ["127.0.0.1:%d" % p for p in _free_ports(1)]
     xs, ys = _make_data(steps, 2 * bs, seed=21)

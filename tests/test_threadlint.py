@@ -2,9 +2,8 @@
 one seeded fixture module per CC1xx rule asserting rule id + file + line,
 a clean-run assertion over the whole package (every waiver accounted
 for), waiver syntax/count semantics, CLI exit codes, telemetry counters,
-and a regression test for the blocking-under-lock defect the lint
-surfaced in pallas_kernels/adoption.py (probe archive read moved outside
-the module lock)."""
+and pallas_kernels/adoption.py, where the lint once found blocking under a
+lock, linting clean."""
 
 import contextlib
 import json
@@ -234,64 +233,6 @@ def test_threadlint_telemetry_counters():
         "static_check_concurrency_total{rule=CC102}", 0) >= 1
     assert counters.get(
         "static_check_waivers_total{rule=CC102}", 0) >= 1
-
-
-# -- regression: adoption.py probe archive read moved off the lock ----------
-
-
-def test_probe_archive_loads_outside_lock(tmp_path, monkeypatch):
-    from paddle_tpu.pallas_kernels import adoption
-
-    adoption.reset()
-    monkeypatch.setenv("PADDLE_PALLAS_PROBE_DIR", str(tmp_path))
-    (tmp_path / "p.json").write_text(
-        json.dumps({"kernel": "layer_norm", "speedup": 1.7}))
-    seen = {}
-    orig = adoption._load_probes
-
-    def spy():
-        seen["locked_during_io"] = adoption._lock.locked()
-        return orig()
-
-    monkeypatch.setattr(adoption, "_load_probes", spy)
-    try:
-        assert adoption.probe_speedup("layer_norm") == pytest.approx(1.7)
-        # the disk read must happen with the module lock released — a
-        # blocked register_probe()/decide() on another thread was the
-        # CC102 finding this restructure fixed
-        assert seen["locked_during_io"] is False
-        # cache is published: second call never re-reads the archive
-        seen.clear()
-        assert adoption.probe_speedup("layer_norm") == pytest.approx(1.7)
-        assert "locked_during_io" not in seen
-        # overrides still win over the archive
-        adoption.register_probe("layer_norm", 2.5)
-        assert adoption.probe_speedup("layer_norm") == pytest.approx(2.5)
-    finally:
-        adoption.reset()
-
-
-def test_probe_cache_single_publish_under_race(tmp_path, monkeypatch):
-    from paddle_tpu.pallas_kernels import adoption
-
-    adoption.reset()
-    monkeypatch.setenv("PADDLE_PALLAS_PROBE_DIR", str(tmp_path))
-    (tmp_path / "p.json").write_text(
-        json.dumps({"kernel": "fused_ln", "speedup": 1.3}))
-    gate = threading.Barrier(4)
-    results = []
-
-    def reader():
-        gate.wait()
-        results.append(adoption.probe_speedup("fused_ln"))
-
-    threads = [threading.Thread(target=reader) for _ in range(4)]
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join(10.0)
-    adoption.reset()
-    assert results == [pytest.approx(1.3)] * 4
 
 
 def test_adoption_module_now_lints_clean():

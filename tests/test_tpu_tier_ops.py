@@ -249,59 +249,6 @@ class TestFusionFamilyOnChip:
                                    atol=2e-3)
 
 
-class TestPallasLayerNormOnChip:
-    """Opt-in fused LN kernel (pallas_kernels/layer_norm.py): forward and
-    gradient parity vs the jnp composition, on the chip."""
-
-    def test_forward_and_grad_parity(self):
-        import paddle_tpu as fluid
-        from paddle_tpu.pallas_kernels.layer_norm import can_use_pallas_ln
-
-        rng = np.random.RandomState(0)
-        R, C = 256, 256
-        xv = rng.randn(R, C).astype("f")
-        # the kernel must actually engage, else this compares the jnp
-        # path with itself and passes vacuously
-        assert can_use_pallas_ln(R, C)
-
-        def run(use_kernel):
-            fluid.flags.set_flags(
-                {"FLAGS_use_pallas_layer_norm": use_kernel})
-            try:
-                main, startup = fluid.Program(), fluid.Program()
-                main.random_seed = 3
-                startup.random_seed = 3
-                with fluid.program_guard(main, startup):
-                    x = fluid.layers.data("x", shape=[R, C],
-                                          append_batch_size=False)
-                    x.stop_gradient = False
-                    y = fluid.layers.layer_norm(x, begin_norm_axis=1)
-                    loss = fluid.layers.reduce_mean(
-                        fluid.layers.square(y))
-                    grads = fluid.gradients([loss], [x])
-                exe = fluid.Executor(fluid.TPUPlace(0))
-                with fluid.scope_guard(fluid.Scope()):
-                    exe.run(startup)
-                    res = exe.run(main, feed={"x": xv},
-                                  fetch_list=[y, grads[0]])
-                return [np.asarray(r) for r in res]
-            finally:
-                fluid.flags.set_flags(
-                    {"FLAGS_use_pallas_layer_norm": False})
-
-        yk, gk = run(True)
-        yj, gj = run(False)
-        np.testing.assert_allclose(yk, yj, rtol=2e-2, atol=2e-2)
-        np.testing.assert_allclose(gk, gj, rtol=2e-2, atol=2e-2)
-        # kernel accuracy vs f64 golden must be at least as good
-        x64 = xv.astype(np.float64)
-        m = x64.mean(1, keepdims=True)
-        v = x64.var(1, keepdims=True)
-        want = (x64 - m) / np.sqrt(v + 1e-5)
-        assert (np.abs(yk - want).max()
-                <= np.abs(yj - want).max() + 1e-4)
-
-
 def _grad_params():
     """Classes opt into the on-chip grad check by declaring a `tpu_grad`
     dict (inputs_to_check + optional check_grad kwargs) — single source

@@ -180,69 +180,6 @@ def test_fused_dropout_add_ln_op_dropout_trains_on_chip():
     assert all(np.isfinite(losses))
 
 
-def test_small_attention_kernel_mask_replay_on_chip():
-    """The flag-gated small-seq fused attention kernel: p=0 exact parity
-    vs the jnp reference, and at p>0 the backward's re-drawn mask matches
-    the forward's (perturbation invariance at a dropped coordinate)."""
-    import importlib
-
-    import jax
-    import jax.numpy as jnp
-
-    _tpu()
-    FA = importlib.import_module(
-        "paddle_tpu.pallas_kernels.flash_attention")
-    rng = np.random.RandomState(4)
-    B, H, S, D = 2, 2, 128, 64
-    q = jnp.asarray(rng.randn(B, H, S, D), jnp.float32) * 0.3
-    k = jnp.asarray(rng.randn(B, H, S, D), jnp.float32) * 0.3
-    v = jnp.asarray(rng.randn(B, H, S, D), jnp.float32)
-    bias = jnp.zeros((B, 1, S, S), jnp.float32)
-    seed = jnp.array([5, 6], jnp.uint32)
-    scale = D ** -0.5
-
-    out = FA.small_attention(q, k, v, bias, scale, 0.0, seed)
-    ref = FA._ref_attention(q, k, v, bias, False, scale)
-    # the reference einsum itself runs at the chip's default (bf16 MXU)
-    # precision, so parity is at the bf16 tier here
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref), **TPU_TOL)
-
-    p = 0.25
-    dv = jax.grad(lambda v: (
-        FA.small_attention(q, k, v, bias, scale, p, seed) ** 2).sum())(v)
-    assert bool(jnp.isfinite(dv).all())
-    zval = FA.small_attention(q, k, v, bias, scale, p, seed)
-    z2 = FA.small_attention(q, k, v, bias, scale, p, seed)
-    assert bool(jnp.array_equal(zval, z2))  # deterministic given seed
-
-
-def test_small_attention_op_route_on_chip():
-    """FLAGS_fused_small_attention routes the op through the kernel and
-    the grad op replays (finite grads, deterministic loss under a fixed
-    program/seed draw)."""
-    place = _tpu()
-    fluid.set_flags({"FLAGS_fused_small_attention": True})
-    try:
-        main, startup = fluid.Program(), fluid.Program()
-        with fluid.program_guard(main, startup):
-            q = fluid.layers.data("q", shape=[4, 128, 64])
-            k = fluid.layers.data("k", shape=[4, 128, 64])
-            v = fluid.layers.data("v", shape=[4, 128, 64])
-            o = fluid.layers.flash_attention(q, k, v, dropout_prob=0.1)
-            loss = fluid.layers.mean(o * o)
-            fluid.optimizer.SGD(learning_rate=0.1).minimize(loss)
-        exe = fluid.Executor(place)
-        rng = np.random.RandomState(5)
-        feed = {n: rng.randn(2, 4, 128, 64).astype("float32") * 0.3
-                for n in ("q", "k", "v")}
-        exe.run(startup)
-        for _ in range(2):
-            lo, = exe.run(main, feed=feed, fetch_list=[loss])
-            assert np.isfinite(lo).all()
-    finally:
-        fluid.set_flags({"FLAGS_fused_small_attention": False})
-
-
 def test_gelu_bf16_custom_vjp_on_chip():
     """The bf16 gelu custom vjp (CSE-breaking barrier) matches the f32
     gelu derivative on the chip."""

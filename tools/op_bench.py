@@ -114,70 +114,10 @@ def bench_op(cfg, device=None, repeat=None, warmup=None):
             "min_ms": round(float(times.min()), 4)}
 
 
-# kernel family -> the flag gating it (pallas_kernels/adoption.py KERNELS;
-# fused_ln is flag-less/default-on and has no compare mode)
-_PALLAS_FLAGS = {
-    "conv_block": "FLAGS_use_pallas_conv_block",
-    "fused_opt": "FLAGS_use_pallas_fused_opt",
-    "embedding_bag": "FLAGS_use_pallas_embedding_bag",
-    "layer_norm": "FLAGS_use_pallas_layer_norm",
-}
-
-
-def bench_pallas(cfg, device=None, save_probe=None, repeat=None,
-                 warmup=None):
-    """Back-to-back fallback vs Pallas-kernel run of one probe config
-    (a normal bench_op config plus a "pallas_kernel" key naming the
-    family).  The kernel leg runs with the family flag ON and an in-memory
-    probe override, bypassing the disk probe gate — this IS the
-    measurement that creates the probe row.  `save_probe`: directory to
-    archive the row into (what adoption.py reads)."""
-    import paddle_tpu as fluid
-    from paddle_tpu.pallas_kernels import adoption
-
-    kernel = cfg["pallas_kernel"]
-    flag = _PALLAS_FLAGS[kernel]
-    adoption.register_probe(kernel, float("inf"))
-    fluid.flags.set_flags({flag: False})
-    base = bench_op(cfg, device, repeat=repeat, warmup=warmup)
-    fluid.flags.set_flags({flag: True})
-    try:
-        kern = bench_op(cfg, device, repeat=repeat, warmup=warmup)
-    finally:
-        fluid.flags.set_flags({flag: False})
-    speedup = (base["mean_ms"] / kern["mean_ms"]) if kern["mean_ms"] else 0.0
-    row = {
-        "op_type": cfg["op_type"],
-        "kernel": kernel,
-        "device": kern["device"],
-        "repeat": kern["repeat"],
-        "fallback_mean_ms": base["mean_ms"],
-        "kernel_mean_ms": kern["mean_ms"],
-        "speedup": round(float(speedup), 4),
-        # honesty bit: False means the kernel leg silently fell back
-        # (ineligible shape / wrong backend) and the "speedup" compares
-        # the fallback with itself — such a row must not be archived
-        "kernel_engaged": kernel in adoption.active_kernels(),
-    }
-    if save_probe and row["kernel_engaged"]:
-        os.makedirs(save_probe, exist_ok=True)
-        path = os.path.join(save_probe, "%s.json" % kernel)
-        with open(path, "a") as f:
-            f.write(json.dumps(row) + "\n")
-        row["probe_file"] = path
-    return row
-
-
 def main():
     ap = argparse.ArgumentParser()
     ap.add_argument("config")
     ap.add_argument("--device", default=None, choices=[None, "cpu", "tpu"])
-    ap.add_argument("--pallas", action="store_true",
-                    help="compare mode: fallback vs Pallas kernel per row "
-                         "(rows need a 'pallas_kernel' key)")
-    ap.add_argument("--save-probe", default=None, metavar="DIR",
-                    help="with --pallas: append the probe JSON row to "
-                         "DIR/<kernel>.json (the adoption-gate archive)")
     ap.add_argument("--repeat", type=int, default=None,
                     help="override every config row's repeat count")
     ap.add_argument("--warmup", type=int, default=None,
@@ -188,15 +128,8 @@ def main():
     if isinstance(cfgs, dict):
         cfgs = [cfgs]
     for cfg in cfgs:
-        if args.pallas and cfg.get("pallas_kernel"):
-            print(json.dumps(bench_pallas(cfg, device=args.device,
-                                          save_probe=args.save_probe,
-                                          repeat=args.repeat,
-                                          warmup=args.warmup)))
-        else:
-            print(json.dumps(bench_op(cfg, device=args.device,
-                                      repeat=args.repeat,
-                                      warmup=args.warmup)))
+        print(json.dumps(bench_op(cfg, device=args.device,
+                                  repeat=args.repeat, warmup=args.warmup)))
 
 
 if __name__ == "__main__":

@@ -1,7 +1,7 @@
 #!/usr/bin/env bash
 # CI harness (reference paddle/scripts/paddle_build.sh analog): build the
 # native pieces, run the full test pyramid, smoke the bench + graft entry.
-# Usage: tools/run_ci.sh [quick|full|tpu|--layout-smoke|--obs-smoke|--lint|--elastic-smoke|--zero1-smoke|--cache-smoke|--kernel-smoke|--serve-smoke|--fleetmon-smoke|--trace-smoke|--decode-smoke|--disagg-smoke|--migrate-smoke|--ckpt-smoke]
+# Usage: tools/run_ci.sh [quick|full|tpu|--layout-smoke|--obs-smoke|--lint|--elastic-smoke|--zero1-smoke|--cache-smoke|--serve-smoke|--fleetmon-smoke|--trace-smoke|--decode-smoke|--disagg-smoke|--migrate-smoke|--ckpt-smoke]
 set -euo pipefail
 cd "$(dirname "$0")/.."
 
@@ -94,23 +94,6 @@ if [ "$MODE" = "--cache-smoke" ]; then
   python tools/compile_cache.py --dir "$CC_DIR" stats
   rm -rf "$CC_DIR"
   echo "CI --cache-smoke: PASS"
-  exit 0
-fi
-
-if [ "$MODE" = "--kernel-smoke" ]; then
-  # Pallas fused-block leg: interpret-mode parity tests for the three
-  # kernel families (conv+bn+relu, fused optimizer, embedding-bag) plus
-  # the adoption-funnel units, then one op_bench --pallas probe config
-  # driven end-to-end through the real op registry in interpret mode
-  # with the static verifier in error mode
-  echo "== kernel smoke: Pallas block-kernel parity + adoption tests =="
-  JAX_PLATFORMS=cpu PADDLE_PALLAS_INTERPRET=1 \
-    python -m pytest tests/test_pallas_blocks.py -q
-  echo "== kernel smoke: interpret-mode op_bench probe (embedding_bag) =="
-  JAX_PLATFORMS=cpu PADDLE_PALLAS_INTERPRET=1 FLAGS_static_check=error \
-    python tools/op_bench.py tools/probes/embedding_bag.json \
-    --pallas --device cpu --repeat 2 --warmup 1
-  echo "CI --kernel-smoke: PASS"
   exit 0
 fi
 
