@@ -128,6 +128,12 @@ def _declare(lib):
         c.c_void_p, c.c_char_p, c.c_ubyte, c.POINTER(c.c_longlong),
         c.c_int, c.c_void_p, c.c_longlong,
     ]
+    lib.rpcs_set_vars.argtypes = [
+        c.c_void_p, c.c_int, c.POINTER(c.c_char_p), c.POINTER(c.c_ubyte),
+        c.POINTER(c.c_int), c.POINTER(c.c_longlong), c.POINTER(c.c_void_p),
+        c.POINTER(c.c_longlong), c.c_int, c.POINTER(c.c_char_p),
+    ]
+    lib.rpcs_wait_stats.argtypes = [c.c_void_p, c.POINTER(c.c_longlong)]
     lib.rpcs_serve.argtypes = [c.c_void_p, c.c_int]
     lib.rpcs_del_var.argtypes = [c.c_void_p, c.c_char_p]
     lib.rpcs_destroy.argtypes = [c.c_void_p]

@@ -127,11 +127,41 @@ struct Server {
   std::vector<int> conn_fds;  // so destroy can unblock idle recv()s
   std::mutex mu;
   std::condition_variable events_cv;   // Python waits for inbound events
-  std::condition_variable store_cv;    // GET handlers wait for published vars
   std::deque<Event> events;
   std::map<std::string, Tensor> store;
+  // a GET handler whose variable is not there yet parks on a condition
+  // variable of its own, registered here under the name it wants, so that
+  // a store wakes the readers of the names it wrote and nobody else (one
+  // shared condition variable costs parked readers x stores wake-ups, each
+  // a round of ``mu``: 1,024 a decode step at 32 streaming lanes).
+  std::multimap<std::string, std::condition_variable*> waiters;
+  long long wakeups = 0;  // times a parked GET handler woke (tests read it)
   bool serving = false;  // GETs blocked until Python publishes + enables
   bool stop = false;
+
+  // Erase ``gone`` and store ``items`` as ONE transaction: a reader sees
+  // all of it or none of it, and each waiter of a stored name is woken
+  // once.  (Call without ``mu``; the tensors are built before it is taken.
+  // The notify happens under ``mu``: a waiter's condition variable lives on
+  // its stack and it cannot leave wait() before ``mu`` is released.)
+  void publish(std::vector<std::pair<std::string, Tensor>>* items,
+               const std::vector<std::string>& gone) {
+    std::lock_guard<std::mutex> lk(mu);
+    for (const auto& name : gone) store.erase(name);
+    for (auto& kv : *items) store[kv.first] = std::move(kv.second);
+    if (!serving) return;  // parked GETs stay parked until rpcs_serve(1)
+    for (const auto& kv : *items) {
+      auto range = waiters.equal_range(kv.first);
+      for (auto it = range.first; it != range.second; ++it)
+        it->second->notify_one();
+    }
+  }
+
+  // The ``serving`` gate opened or the server stops: every parked GET
+  // handler looks again.  (Call with ``mu`` held.)
+  void wake_all_locked() {
+    for (auto& kv : waiters) kv.second->notify_one();
+  }
 
   void forget_fd(int fd) {
     std::lock_guard<std::mutex> lk(mu);
@@ -159,9 +189,18 @@ struct Server {
         Tensor t;
         {
           std::unique_lock<std::mutex> lk(mu);
-          store_cv.wait(lk, [&] {
+          auto ready = [&] {
             return stop || (serving && store.count(f.name));
-          });
+          };
+          if (!ready()) {
+            std::condition_variable cv;
+            auto self = waiters.emplace(f.name, &cv);
+            while (!ready()) {
+              cv.wait(lk);
+              ++wakeups;
+            }
+            waiters.erase(self);
+          }
           if (stop) break;
           t = store[f.name];
         }
@@ -256,34 +295,55 @@ int rpcs_poll(void* h, char* name_buf, int name_cap, unsigned char* dtype,
   return current.type;
 }
 
+// Store ``n`` variables and erase ``n_gone`` names in one acquisition of
+// the store's mutex.  ``dims`` holds every variable's dims one after
+// another (``ndims[i]`` of them each).  A name both erased and stored ends
+// up stored.
+void rpcs_set_vars(void* h, int n, const char* const* names,
+                   const unsigned char* dtypes, const int* ndims,
+                   const long long* dims, const void* const* data,
+                   const long long* lens, int n_gone,
+                   const char* const* gone) {
+  auto* s = static_cast<Server*>(h);
+  std::vector<std::pair<std::string, Tensor>> items(n);
+  for (int i = 0; i < n; ++i) {
+    items[i].first = names[i];
+    Tensor& t = items[i].second;
+    t.dtype = dtypes[i];
+    t.dims.assign(dims, dims + ndims[i]);
+    dims += ndims[i];
+    t.data.assign(static_cast<const char*>(data[i]),
+                  static_cast<size_t>(lens[i]));
+  }
+  s->publish(&items, std::vector<std::string>(gone, gone + n_gone));
+}
+
 void rpcs_set_var(void* h, const char* name, unsigned char dtype,
                   const long long* dims, int ndim, const void* data,
                   long long len) {
-  auto* s = static_cast<Server*>(h);
-  Tensor t;
-  t.dtype = dtype;
-  t.dims.assign(dims, dims + ndim);
-  t.data.assign(static_cast<const char*>(data), static_cast<size_t>(len));
-  {
-    std::lock_guard<std::mutex> lk(s->mu);
-    s->store[name] = std::move(t);
-  }
-  s->store_cv.notify_all();
+  rpcs_set_vars(h, 1, &name, &dtype, &ndim, dims, &data, &len, 0, nullptr);
 }
 
 void rpcs_del_var(void* h, const char* name) {
-  auto* s = static_cast<Server*>(h);
-  std::lock_guard<std::mutex> lk(s->mu);
-  s->store.erase(name);
+  rpcs_set_vars(h, 0, nullptr, nullptr, nullptr, nullptr, nullptr, nullptr,
+                1, &name);
 }
 
 void rpcs_serve(void* h, int enable) {
   auto* s = static_cast<Server*>(h);
-  {
-    std::lock_guard<std::mutex> lk(s->mu);
-    s->serving = enable != 0;
-  }
-  s->store_cv.notify_all();
+  std::lock_guard<std::mutex> lk(s->mu);
+  s->serving = enable != 0;
+  s->wake_all_locked();
+}
+
+// out[0]: GET handlers parked now; out[1]: times a parked handler has woken
+// since the server started (one a reader where a store wakes only the
+// readers of what it wrote).
+void rpcs_wait_stats(void* h, long long* out) {
+  auto* s = static_cast<Server*>(h);
+  std::lock_guard<std::mutex> lk(s->mu);
+  out[0] = static_cast<long long>(s->waiters.size());
+  out[1] = s->wakeups;
 }
 
 void rpcs_destroy(void* h) {
@@ -294,8 +354,8 @@ void rpcs_destroy(void* h) {
     // unblock handler threads parked in recv() on idle connections —
     // joining without this deadlocks when a client is mid-compute
     for (int fd : s->conn_fds) ::shutdown(fd, SHUT_RDWR);
+    s->wake_all_locked();
   }
-  s->store_cv.notify_all();
   s->events_cv.notify_all();
   ::shutdown(s->listen_fd, SHUT_RDWR);
   ::close(s->listen_fd);

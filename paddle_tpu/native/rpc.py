@@ -99,9 +99,33 @@ class RpcServer:
             _tr.wire_received(bare, tp)
         return t, bare, arr
 
-    def set_var(self, name, arr):
+    def set_vars(self, items, delete=()):
+        """Store every ``(name, array)`` of ``items`` and erase the names
+        in ``delete`` as ONE transaction of the store: a reader sees all
+        of it or none, and only GETs parked on a stored name are woken."""
         # use-after-shutdown must raise, not hand the native layer a NULL
         # handle (a late publisher thread would segfault the process)
+        if self._h is None:
+            raise ConnectionError("rpc server already shut down")
+        c = ctypes
+        items = list(items)
+        names = [name.encode() for name, _ in items]
+        arrs = [np.ascontiguousarray(arr) for _, arr in items]
+        gone = [name.encode() for name in delete]
+        n = len(arrs)
+        dims = [d for a in arrs for d in a.shape]
+        self._lib.rpcs_set_vars(
+            self._h, n, (c.c_char_p * n)(*names),
+            (c.c_ubyte * n)(*[_DT_TO_CODE[a.dtype] for a in arrs]),
+            (c.c_int * n)(*[a.ndim for a in arrs]),
+            (c.c_longlong * len(dims))(*dims),
+            (c.c_void_p * n)(*[a.ctypes.data for a in arrs]),
+            (c.c_longlong * n)(*[a.nbytes for a in arrs]),
+            len(gone), (c.c_char_p * len(gone))(*gone))
+
+    def set_var(self, name, arr):
+        """The transaction of one (``rpcs_set_var`` is ``rpcs_set_vars``
+        with a single variable)."""
         if self._h is None:
             raise ConnectionError("rpc server already shut down")
         arr = np.ascontiguousarray(arr)
@@ -110,15 +134,24 @@ class RpcServer:
             self._h, name.encode(), _DT_TO_CODE[arr.dtype], dims, arr.ndim,
             arr.ctypes.data_as(ctypes.c_void_p), arr.nbytes)
 
-    def serve(self, enable=True):
-        if self._h is None:
-            raise ConnectionError("rpc server already shut down")
-        self._lib.rpcs_serve(self._h, 1 if enable else 0)
-
     def del_var(self, name):
         if self._h is None:
             raise ConnectionError("rpc server already shut down")
         self._lib.rpcs_del_var(self._h, name.encode())
+
+    def wait_stats(self):
+        """GET handlers parked on a variable now, and how often a parked
+        handler has woken since the server started."""
+        if self._h is None:
+            raise ConnectionError("rpc server already shut down")
+        out = (ctypes.c_longlong * 2)()
+        self._lib.rpcs_wait_stats(self._h, out)
+        return {"parked": out[0], "wakeups": out[1]}
+
+    def serve(self, enable=True):
+        if self._h is None:
+            raise ConnectionError("rpc server already shut down")
+        self._lib.rpcs_serve(self._h, 1 if enable else 0)
 
     def shutdown(self):
         if self._h:

@@ -982,6 +982,12 @@ class DecodeEngine:
         self._noted_lanes = None
         self.in_batch = False
         self.on_batch_boundary = None
+        # ``on_tokens_emitted()`` fires once where an iteration has made
+        # its last ``on_token`` call (the end of a step's emit, under the
+        # step lock) and after a terminal chunk emitted outside a step:
+        # the server publishes the stream chunks it gathered meanwhile as
+        # one store transaction, and returns their number
+        self.on_tokens_emitted = None
         # disaggregated prefill role hooks (serving/disagg.py wires them):
         # on_block_sealed(m, seq, j, digest) fires under the step lock for
         # every sealed full-prompt block of a handoff sequence (including
@@ -1279,12 +1285,13 @@ class DecodeEngine:
         def _early(reply):
             """Terminal before admission: also emit the done stream chunk
             so a streaming client unblocks instead of hanging on k=0."""
-            req.complete(reply)
             if on_token is not None:
                 try:
                     on_token(req.req_id, 0, None, True, reply.status)
                 except Exception:
                     pass
+            req.complete(reply)
+            self._tokens_emitted()
             return req
 
         m = self._models.get(model)
@@ -1781,6 +1788,17 @@ class DecodeEngine:
             seq.draft_blocks = []
             seq.draft_table.fill(-1)
 
+    def _tokens_emitted(self):
+        """Every ``on_token`` call of this iteration has been made: tell
+        whoever gathers them (``on_tokens_emitted``).  Returns how many
+        stream chunks that published, 0 with no hook set."""
+        if self.on_tokens_emitted is None:
+            return 0
+        try:
+            return int(self.on_tokens_emitted() or 0)
+        except Exception:
+            return 0
+
     def _finish(self, seq, reply):
         r = seq.pending
         if reply.ok or reply.status in ("timeout", "migrated"):
@@ -1810,8 +1828,20 @@ class DecodeEngine:
         out_tokens = np.asarray(seq.out, np.int32)
         if reply.ok:
             reply.outputs = {"tokens": out_tokens}
+        elif seq.on_token is not None:
+            # terminal stream chunk so a streaming client unblocks even
+            # on shed/timeout/abort/error; emitted before the reply, so it
+            # is in the store no later than the reply is
+            try:
+                seq.on_token(r.req_id, len(seq.out), None, True,
+                             reply.status)
+            except Exception:
+                pass
         r.complete(reply)
-        if reply.ok:
+        if not reply.ok:
+            # outside a step's emit nothing else says the chunk is out
+            self._tokens_emitted()
+        else:
             # fleet-mergeable per-phase histograms: per-tier server_ms
             # (end-to-end on this replica), per-model TTFT and ITL —
             # fleetmon's SLO rules (decode ITL p99) window their bucket
@@ -1837,14 +1867,6 @@ class DecodeEngine:
             r.span.annotate(status=reply.status,
                             tokens=len(seq.out)).end()
             r.span = None
-        if seq.on_token is not None and not reply.ok:
-            # terminal stream chunk so a streaming client unblocks even
-            # on shed/timeout/abort/error
-            try:
-                seq.on_token(r.req_id, len(seq.out), None, True,
-                             reply.status)
-            except Exception:
-                pass
 
     def _expire_and_admit(self):
         """Under the lock: time out stale waiters, then admit while
@@ -2357,8 +2379,10 @@ class DecodeEngine:
             _tm.inc("serving_decode_steps_total", model=m.name)
             _tm.observe("decode_batch_occupancy",
                         len(lanes) / float(bucket), model=m.name)
+            published = self._tokens_emitted()
         self._close_step_span(sspan, generated=n_generated,
-                              ms=round(ms, 3), gap_us=gap_us, **moe)
+                              ms=round(ms, 3), gap_us=gap_us,
+                              published=published, **moe)
         return True
 
     @staticmethod
@@ -2593,6 +2617,8 @@ class DecodeEngine:
             if rolled:
                 _tm.inc("spec_blocks_rolled_back_total", rolled,
                         model=m.name)
+            # before the draft's catch-up dispatch: the tokens are final
+            published = self._tokens_emitted()
         ingest = [(s, q, t) for (s, q, t) in ingest if s in self._active]
         if ingest:
             with _tr.phase("serving.plan"):
@@ -2635,6 +2661,6 @@ class DecodeEngine:
             _tm.observe("decode_batch_occupancy",
                         len(lanes) / float(bucket), model=m.name)
         self._close_step_span(sspan, generated=n_generated, ms=round(ms, 3),
-                              gap_us=gap_us, k_proposed=k_proposed,
-                              k_accepted=k_accepted)
+                              gap_us=gap_us, published=published,
+                              k_proposed=k_proposed, k_accepted=k_accepted)
         return True

@@ -57,6 +57,7 @@ still serves plain monolith traffic (the pair var's ``{"decode": None}``
 is the no-peers fallback).
 """
 
+import collections
 import threading
 import time
 
@@ -89,7 +90,10 @@ class ServingServer:
         self.rollout = None            # RolloutController (coordinator)
         self.on_retire = None          # callback after a __retire__ drain
         self._retire_thread = None
-        self._reply_keys = []
+        # per-request keys in the store, oldest first (the GC ring), and
+        # the stream chunks packed since the last store transaction
+        self._reply_keys = collections.deque()
+        self._chunks = []
         self._reply_lock = threading.Lock()
         self._thread = None
         self._pub_stop = None
@@ -121,6 +125,7 @@ class ServingServer:
             self.rpc.set_var(codec.SPEC_KEY + name,
                              codec.pack(self.engine.spec(name)))
         if self.decode_engine is not None:
+            self.decode_engine.on_tokens_emitted = self._store
             self.decode_engine.start()
             for name in self.decode_engine.models():
                 self.rpc.set_var(codec.SPEC_KEY + name,
@@ -355,12 +360,8 @@ class ServingServer:
         return m.kv_config.dtype if m is not None else "f32"
 
     def _publish_pair(self, req_id, peer):
-        key = codec.PAIR_KEY + req_id
-        self.rpc.set_var(key, codec.pack({"decode": peer}))
-        with self._reply_lock:
-            self._reply_keys.append(key)
-            while len(self._reply_keys) > _REPLY_RING:
-                self.rpc.del_var(self._reply_keys.pop(0))
+        self._store([(codec.PAIR_KEY + req_id,
+                      codec.pack({"decode": peer}))])
 
     def _try_handoff(self, req_id, meta, prompt):
         """Prefill-role admission: pick a decode peer, announce the pair,
@@ -606,12 +607,7 @@ class ServingServer:
         doc = {"status": status}
         if error:
             doc["error"] = error
-        key = codec.RESUME_ACK_KEY + req_id
-        self.rpc.set_var(key, codec.pack(doc))
-        with self._reply_lock:
-            self._reply_keys.append(key)
-            while len(self._reply_keys) > _REPLY_RING:
-                self.rpc.del_var(self._reply_keys.pop(0))
+        self._store([(codec.RESUME_ACK_KEY + req_id, codec.pack(doc))])
 
     def _on_session(self, req_id, meta, arrays):
         """Session manifest (sent LAST on the migration FIFO): consume
@@ -757,19 +753,43 @@ class ServingServer:
 
     def _stream_publisher(self, req_id):
         """Per-token chunk publisher: ``__stream__:<id>:<k>`` carries the
-        k-th generated token; the final/terminal chunk sets done.  Chunk
-        keys join the reply GC ring so crashed streamers can't leak."""
+        k-th generated token; the final/terminal chunk sets done.  The
+        chunk is packed here and waits in ``_chunks``: the decode loop
+        says once a step that its tokens are all out
+        (``DecodeEngine.on_tokens_emitted`` -> ``_store``), and the step's
+        chunks reach the store together."""
 
         def on_token(rid, index, token, done, status):
-            key = "%s%s:%d" % (codec.STREAM_KEY, rid, index)
-            self.rpc.set_var(key, codec.pack(
+            chunk = ("%s%s:%d" % (codec.STREAM_KEY, rid, index), codec.pack(
                 {"i": int(index), "done": bool(done), "status": status,
                  "token": None if token is None else int(token)}))
             with self._reply_lock:
-                self._reply_keys.append(key)
-                while len(self._reply_keys) > _REPLY_RING:
-                    self.rpc.del_var(self._reply_keys.pop(0))
+                self._chunks.append(chunk)
         return on_token
+
+    def _store(self, items=()):
+        """The one way a per-request key (stream chunk, reply, pair,
+        resume ack) enters the RPC store: the waiting stream chunks, then
+        ``items``, and the keys the GC ring retires for them, as one
+        transaction of the store.  So a step's chunks wake exactly their
+        readers under one acquisition of the store's mutex, a request's
+        last chunk is there no later than its reply, and the ring bounds
+        the store at ``_REPLY_RING`` keys whoever crashed mid-stream.
+        Returns the stream chunks handed over."""
+        with self._reply_lock:
+            chunks, self._chunks = self._chunks, []
+            batch = chunks + list(items)
+            if not batch:
+                return 0
+            ring = self._reply_keys
+            ring.extend(key for key, _ in batch)
+            gone = [ring.popleft()
+                    for _ in range(len(ring) - _REPLY_RING)]
+            self.rpc.set_vars(batch, delete=gone)
+        if chunks:
+            _tm.inc("serving_stream_publish_total")
+            _tm.inc("serving_stream_chunks_total", len(chunks))
+        return len(chunks)
 
     def _publish(self, req_id, reply, pending=None):
         from .engine import InferReply
@@ -793,12 +813,7 @@ class ServingServer:
                 meta[codec.TRACEPARENT] = tp
             names = list(reply.outputs)
             buf = codec.pack(meta, [reply.outputs[n] for n in names])
-            key = codec.REPLY_KEY + req_id
-            self.rpc.set_var(key, buf)
-        with self._reply_lock:
-            self._reply_keys.append(key)
-            while len(self._reply_keys) > _REPLY_RING:
-                self.rpc.del_var(self._reply_keys.pop(0))
+            self._store([(codec.REPLY_KEY + req_id, buf)])
 
     # -- control plane -------------------------------------------------------
 
