@@ -124,7 +124,7 @@ def _experts(h2, gates, wgate, wup, wdown):
     return jnp.sum(y * gates.T[:, :, None], axis=0)
 
 
-def token_logits(params, cfg, tok, pos, attend, live):
+def token_logits(params, cfg, tok, pos, attend, live, recur=None):
     """-> (logits [B, vocab] float32, (routed,)) with ``routed``
     int32 [layers, experts]: the tokens of live lanes sent to each expert
     this step.  Scope names as the GPT-2 block's, plus

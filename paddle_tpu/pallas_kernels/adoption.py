@@ -7,7 +7,8 @@ whether it engages.  A family is chosen from what the lowering can observe
 * **eligibility** — ``decide(kernel, checks)`` walks the family's ordered
   check list; the first failing check becomes the fallback *reason*.  The
   checks live beside the kernel that owns them (``fused_ln_checks``,
-  ``flash_attention_checks``, ``paged_attention_checks``).
+  ``flash_attention_checks``, ``paged_attention_checks``,
+  ``ssm_update_checks``).
 * **telemetry** — every decision increments
   ``pallas_kernel_used_total{kernel}`` or
   ``pallas_kernel_fallback_total{kernel,reason}`` in the telemetry
@@ -34,7 +35,7 @@ __all__ = ["decide", "active_kernels", "reset", "interpret_mode",
            "interpret", "auto_partitioned", "shape_inference", "KERNELS"]
 
 # the kernel families sharing this funnel
-KERNELS = ("fused_ln", "flash_attention", "paged_attention")
+KERNELS = ("fused_ln", "flash_attention", "paged_attention", "ssm_update")
 
 _lock = threading.Lock()
 _active = set()          # kernels that engaged >= 1 time this process

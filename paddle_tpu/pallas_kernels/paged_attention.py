@@ -4,13 +4,13 @@ KV blocks where they lie, and the jnp path it is checked against.
 The decode step (serving/decode_model.py) attends one query token per lane
 against that lane's KV history, which lies scattered over the fixed-size
 blocks of the layer's pool (serving/kv_cache.py: ``[num_blocks,
-block_size, H * D]``, heads folded) and is named by the lane's row of the
+block_size, KH * D]``, KV heads folded) and is named by the lane's row of the
 block table.
 
 ``paged_attention`` picks the path from what it can see, with no flag:
 
 * **the kernel** (scope ``kv_read``), on a TPU backend, for a rank-3 pool
-  in float32 or bfloat16 whose ``H * D`` is a multiple of the 128 lanes and
+  in float32 or bfloat16 whose ``KH * D`` is a multiple of the 128 lanes and
   whose ``block_size`` is a multiple of the dtype's sublane tile.  One
   invocation walks the lanes; for each it loops over chunks of
   ``CHUNK_TOKENS`` positions up to ``context_lens[b]``, fetches the chunk's
@@ -25,9 +25,15 @@ block table.
   over a mesh.  ``gather_blocks`` copies every slot of the padded table
   into contiguous history and ``masked_attention`` reduces it.
 
-Both compute ``masked_attention``'s mathematics at its precision: head h's
-scores are row h of a block-diagonal query against the folded rows, so
-every dot is a plain 2-D matmul over ``H * D``.  Against a bfloat16 pool
+The pool's width is its own (``KH * D``, the KV heads it stores), and the
+query may have more heads than that: with ``H`` query heads over ``KH`` KV
+heads, query head ``r`` reads KV head ``r // (H // KH)`` (grouped-query
+attention; ``H == KH`` is the multi-head case).  The scale of the scores is
+an argument, ``1 / sqrt(D)`` unless the block says otherwise.
+
+Both compute ``masked_attention``'s mathematics at its precision: head r's
+scores are row r of a block-diagonal query against the folded rows, so
+every dot is a plain 2-D matmul over ``KH * D``.  Against a bfloat16 pool
 the query and the probabilities are rounded to bfloat16 and the MXU
 accumulates in float32.  Against a float32 pool every product is float32:
 each operand is split into three bfloat16 pieces and the six products
@@ -71,22 +77,34 @@ _SUBLANES = {"float32": 8, "bfloat16": 16}   # rows of a dtype's memory tile
 _VMEM_BUDGET = 8 << 20
 
 
-def masked_attention(q, k, v, context_lens):
-    """Single-token attention over a contiguous history: q [B, H, D],
-    k/v [B, S, H, D], context_lens [B] -> [B, H, D].  Positions >= the
-    context length are masked.  Shared by the paged gather path AND the
-    unpaged reference loop so the two stay bitwise-comparable.
+def _own(heads, kv_heads, dtype):
+    """[heads, kv_heads] 0/1: query head ``r`` owns KV head ``r // group``
+    (``group = heads // kv_heads``; the identity when every query head has
+    its own K and V)."""
+    group = heads // kv_heads
+    return (jnp.arange(heads)[:, None] // group
+            == jnp.arange(kv_heads)[None, :]).astype(dtype)
 
-    Both contractions run over the folded minor dimension H * D, against
-    a block-diagonal query: row h of ``qx`` holds head h's query in its
-    own D columns and zeros elsewhere, so ``qx @ k`` is head h's scores
-    and the diagonal blocks of ``p @ v`` are its output.  The history is
-    then read as it lies in the pool ([S, H * D] rows): splitting H * D
-    into heads on it costs a relayout of everything gathered wherever D
-    is under the 128 lanes (D = 64: a second, padded copy of the history
-    per layer), while the extra H - 1 zero blocks are matmul work on a
-    unit that is otherwise idle.  ``HIGHEST`` keeps the products float32,
-    as the elementwise form they replace had them.
+
+def masked_attention(q, k, v, context_lens, scale=None):
+    """Single-token attention over a contiguous history: q [B, H, D],
+    k/v [B, S, KH, D] with ``H`` a multiple of ``KH`` (grouped queries:
+    query head ``r`` reads KV head ``r // (H // KH)``), context_lens [B]
+    -> [B, H, D].  Positions >= the context length are masked; scores are
+    multiplied by ``scale`` (None: ``1 / sqrt(D)``).  Shared by the paged
+    gather path AND the unpaged reference loop so the two stay
+    bitwise-comparable.
+
+    Both contractions run over the folded minor dimension KH * D, against
+    a block-diagonal query: row r of ``qx`` holds head r's query in the D
+    columns of its KV head and zeros elsewhere, so ``qx @ k`` is head r's
+    scores and the owned blocks of ``p @ v`` are its output.  The history
+    is then read as it lies in the pool ([S, KH * D] rows): splitting
+    KH * D into heads on it costs a relayout of everything gathered
+    wherever D is under the 128 lanes (D = 64: a second, padded copy of
+    the history per layer), while the extra zero blocks are matmul work on
+    a unit that is otherwise idle.  ``HIGHEST`` keeps the products
+    float32, as the elementwise form they replace had them.
 
     The history's dtype is the matmuls' input dtype: against a float32
     pool nothing is cast; against a bf16 pool (a bf16 model's) the query
@@ -94,19 +112,20 @@ def masked_attention(q, k, v, context_lens):
     is stored, and both contractions accumulate in float32.  Scores,
     mask and softmax are float32 either way."""
     b, h, d = q.shape
-    s = k.shape[1]
+    s, kh = k.shape[1], k.shape[2]
+    if scale is None:
+        scale = 1.0 / math.sqrt(d)
     dot = functools.partial(jnp.einsum, precision=jax.lax.Precision.HIGHEST,
                             preferred_element_type=jnp.float32)
-    eye = jnp.eye(h, dtype=q.dtype)[None, :, :, None]
-    qx = (q[:, :, None, :] * eye).reshape(b, h, h * d).astype(k.dtype)
-    sc = dot("bhc,bsc->bhs", qx, k.reshape(b, s, h * d)) \
-        * (1.0 / math.sqrt(d))
+    own = _own(h, kh, q.dtype)[None, :, :, None]
+    qx = (q[:, :, None, :] * own).reshape(b, h, kh * d).astype(k.dtype)
+    sc = dot("bhc,bsc->bhs", qx, k.reshape(b, s, kh * d)) * scale
     pos = jnp.arange(s, dtype=jnp.int32)[None, None, :]
     sc = jnp.where(pos < context_lens[:, None, None].astype(jnp.int32),
                    sc, _MASK)
     p = jax.nn.softmax(sc, axis=-1)
-    out = dot("bhs,bsc->bhc", p.astype(v.dtype), v.reshape(b, s, h * d))
-    return (out.reshape(b, h, h, d) * eye).sum(axis=2)
+    out = dot("bhs,bsc->bhc", p.astype(v.dtype), v.reshape(b, s, kh * d))
+    return (out.reshape(b, h, kh, d) * own).sum(axis=2)
 
 
 def gather_blocks(cache, block_tables):
@@ -122,16 +141,22 @@ def gather_blocks(cache, block_tables):
 
 
 def paged_attention_reference(q, k_cache, v_cache, block_tables,
-                              context_lens):
+                              context_lens, scale=None):
     """The jnp path: gather the table's blocks into contiguous K/V, then
     masked_attention.  q [B, H, D]; k_cache/v_cache the serving pool's
-    [num_blocks, block_size, H * D] (or [num_blocks, block_size, H, D]):
+    [num_blocks, block_size, KH * D] (or [num_blocks, block_size, KH, D]):
     the heads are split after the gather, never on the pool."""
-    bb, h, d = q.shape
+    bb, _h, d = q.shape
     with jax.named_scope("kv_gather"):
-        k, v = (gather_blocks(c, block_tables).reshape(bb, -1, h, d)
-                for c in (k_cache, v_cache))
-    return masked_attention(q, k, v, context_lens)
+        k, v = (gather_blocks(c, block_tables).reshape(bb, -1, _kv_heads(
+            c.shape, d), d) for c in (k_cache, v_cache))
+    return masked_attention(q, k, v, context_lens, scale)
+
+
+def _kv_heads(kv_shape, head_dim):
+    """KV heads of a pool ``[num_blocks, block_size, KH * D]`` (or ``[...,
+    KH, D]``): the pool's own width says how many heads it stores."""
+    return kv_shape[2] if len(kv_shape) == 4 else kv_shape[2] // head_dim
 
 
 # -- the shape rule ----------------------------------------------------------
@@ -139,26 +164,40 @@ def paged_attention_reference(q, k_cache, v_cache, block_tables,
 def paged_attention_checks(q_shape, kv_shape, kv_dtype):
     """Ordered (reason, ok) pairs for adoption.decide(): what the kernel
     needs of the query ``[B, H, D]`` and of a pool ``[num_blocks,
-    block_size, H * D]`` in ``kv_dtype``."""
+    block_size, KH * D]`` in ``kv_dtype``, ``H`` a multiple of ``KH``."""
     dims = tuple(q_shape) + tuple(kv_shape)
     static = all(isinstance(x, int) and x >= 0 for x in dims)
     rank = len(q_shape) == 3 and len(kv_shape) == 3
     tile = _SUBLANES.get(jnp.dtype(kv_dtype).name)
+    # the pool's width is whole KV heads, and the query heads divide over
+    # them evenly
+    grouped = static and rank and q_shape[2] > 0 \
+        and kv_shape[2] % q_shape[2] == 0 and kv_shape[2] > 0 \
+        and q_shape[1] % (kv_shape[2] // q_shape[2]) == 0
     return [
         ("backend", adoption.interpret_mode()
          or jax.default_backend() == "tpu"),
         ("symbolic_shape", static),
         ("rank", rank),
         ("dtype", tile is not None),
-        ("lanes", static and rank and kv_shape[2] % 128 == 0
-         and kv_shape[2] == q_shape[1] * q_shape[2]),
+        ("lanes", grouped and kv_shape[2] % 128 == 0),
         ("block_size", static and rank and tile is not None
          and kv_shape[1] > 0 and kv_shape[1] % tile == 0),
         ("empty", static and all(x > 0 for x in dims)),
-        ("vmem", static and rank and tile is not None
+        ("vmem", grouped and tile is not None
          and (4 * max(CHUNK_TOKENS, kv_shape[1]) * jnp.dtype(kv_dtype).itemsize
-              + 2 * q_shape[0] * 4) * kv_shape[2] <= _VMEM_BUDGET),
+              + 2 * q_shape[0] * 4 * _query_rows(
+                  q_shape[1], kv_shape[2] // q_shape[2]))
+         * kv_shape[2] <= _VMEM_BUDGET),
     ]
+
+
+def _query_rows(heads, kv_heads):
+    """Rows a lane's query (and output) takes in VMEM: one, broadcast over
+    the heads, where each head has its own columns of the pool's width; a
+    row a head, in whole sublane tiles, where ``heads // kv_heads`` query
+    heads share a KV head's columns."""
+    return 1 if heads == kv_heads else -(-heads // 16) * 16
 
 
 def attention_path(q_shape, kv_shape, kv_dtype):
@@ -226,12 +265,12 @@ def _product(rows, x, dims):
 
 
 def _kernel(bt_ref, cl_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, *,
-            heads, head_dim, block_size, maxb, per):
+            heads, kv_heads, head_dim, block_size, maxb, per, scale):
     lanes = q_ref.shape[0]
-    hd = heads * head_dim
+    hd = kv_heads * head_dim             # the pool's width
+    group = heads // kv_heads            # query heads a KV head
     rows = -(-heads // 16) * 16          # whole sublane tiles in any dtype
     span = per * block_size              # positions a chunk
-    scale = 1.0 / math.sqrt(head_dim)
 
     def chunks(b):
         return (cl_ref[b] + span - 1) // span
@@ -260,9 +299,13 @@ def _kernel(bt_ref, cl_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, *,
         for dma in copies(slot, lambda i: 0):
             dma.wait()
 
-    # row h of the mask covers head h's own columns of the folded width
+    # row r of the mask covers the columns of query head r's KV head
+    # (r // group; its own where group is 1) in the folded width
     col = jax.lax.broadcasted_iota(jnp.int32, (rows, hd), 1)
-    row = jax.lax.broadcasted_iota(jnp.int32, (rows, hd), 0) * head_dim
+    row = jax.lax.broadcasted_iota(jnp.int32, (rows, hd), 0)
+    if group > 1:
+        row = row // group
+    row = row * head_dim
     own = ((col >= row) & (col < row + head_dim)).astype(jnp.float32)
 
     def lane(b, g):
@@ -276,6 +319,8 @@ def _kernel(bt_ref, cl_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, *,
         def _first():
             start(b, 0, g % 2)
 
+        # one row broadcast over the heads, or (grouped) a row a head
+        # with its query already in every KV head's columns
         qx = q_ref[b] * own                              # [rows, hd]
 
         def chunk(c, carry):
@@ -306,58 +351,76 @@ def _kernel(bt_ref, cl_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, *,
                           jnp.zeros((rows, 1), jnp.float32),
                           jnp.zeros((rows, hd), jnp.float32)))
         # an idle lane has l == 0 and acc == 0: zeros out, not 0 / 0
-        out = acc / jnp.where(l > 0, l, 1.0)
-        o_ref[b] = jnp.sum(out * own, axis=0, keepdims=True
-                           ).astype(o_ref.dtype)
+        out = acc / jnp.where(l > 0, l, 1.0) * own
+        if group == 1:
+            # each column belongs to one row: fold the rows
+            out = jnp.sum(out, axis=0, keepdims=True)
+        o_ref[b] = out.astype(o_ref.dtype)
         return g + n
 
     jax.lax.fori_loop(0, lanes, lane, jnp.int32(0))
 
 
 def _paged_pallas(q, k_cache, v_cache, block_tables, context_lens,
-                  interpret=None):
-    """q [B, H, D] against folded pools [num_blocks, block_size, H * D]
+                  scale=None, interpret=None):
+    """q [B, H, D] against folded pools [num_blocks, block_size, KH * D]
     -> [B, H, D].  ``interpret`` None follows the backend."""
     bb, h, d = q.shape
     _nb, bs, hd = k_cache.shape
+    kh = hd // d
     maxb = block_tables.shape[1]
     per = _chunk_blocks(bs, maxb)
     if interpret is None:
         interpret = adoption.interpret()
+    if scale is None:
+        scale = 1.0 / math.sqrt(d)
+    qrows = _query_rows(h, kh)
+    q = q.astype(jnp.float32)
+    if kh == h:
+        qx = q.reshape(bb, 1, hd)
+    else:
+        # a row a head, the head's query repeated under every KV head's
+        # columns: the kernel's mask keeps the one it owns
+        qx = jnp.pad(jnp.tile(q, (1, 1, kh)), ((0, 0), (0, qrows - h), (0, 0)))
     whole = lambda i, bt, cl: (0, 0, 0)
     out = pl.pallas_call(
-        functools.partial(_kernel, heads=h, head_dim=d, block_size=bs,
-                          maxb=maxb, per=per),
+        functools.partial(_kernel, heads=h, kv_heads=kh, head_dim=d,
+                          block_size=bs, maxb=maxb, per=per,
+                          scale=float(scale)),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(1,),
-            in_specs=[pl.BlockSpec((bb, 1, hd), whole),
+            in_specs=[pl.BlockSpec((bb, qrows, hd), whole),
                       pl.BlockSpec(memory_space=pl.ANY),
                       pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec((bb, 1, hd), whole),
+            out_specs=pl.BlockSpec((bb, qrows, hd), whole),
             scratch_shapes=[pltpu.VMEM((2, per * bs, hd), k_cache.dtype),
                             pltpu.VMEM((2, per * bs, hd), v_cache.dtype),
                             pltpu.SemaphoreType.DMA((2, 2))],
         ),
-        out_shape=jax.ShapeDtypeStruct((bb, 1, hd), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((bb, qrows, hd), jnp.float32),
         interpret=interpret,
     )(block_tables.astype(jnp.int32).reshape(-1),
-      context_lens.astype(jnp.int32),
-      q.reshape(bb, 1, hd).astype(jnp.float32), k_cache, v_cache)
-    return out.reshape(bb, h, d)
+      context_lens.astype(jnp.int32), qx, k_cache, v_cache)
+    if kh == h:
+        return out.reshape(bb, h, d)
+    # row r holds head r's output in its KV head's columns, zeros elsewhere
+    return out[:, :h].reshape(bb, h, kh, d).sum(axis=2)
 
 
-def paged_attention(q, k_cache, v_cache, block_tables, context_lens):
+def paged_attention(q, k_cache, v_cache, block_tables, context_lens,
+                    scale=None):
     """The step's attention over a layer's pools: the kernel where the
     shape rule admits it (``adoption.decide`` counts the lowering under
     ``pallas_kernel_used_total`` / ``..._fallback_total{reason}``), the
-    gather otherwise."""
+    gather otherwise.  The pools' width says how many KV heads they store;
+    ``scale`` None is ``1 / sqrt(D)``."""
     use, _reason = adoption.decide(
         "paged_attention",
         paged_attention_checks(q.shape, k_cache.shape, k_cache.dtype))
     if use:
         with jax.named_scope("kv_read"):
             return _paged_pallas(q, k_cache, v_cache, block_tables,
-                                 context_lens)
+                                 context_lens, scale)
     return paged_attention_reference(q, k_cache, v_cache, block_tables,
-                                     context_lens)
+                                     context_lens, scale)

@@ -15,24 +15,35 @@ that reads each lane's live KV blocks from the pool where they lie, and
 elsewhere (the CPU tier, the int8 residency) a gather of the padded table
 into ``masked_attention``, chosen by what the shapes and the backend are.
 
-Since PR 27 the decoder is one of two blocks, picked by
-``DecoderConfig.arch``: the ``gpt2`` block of this file, in float32, and
-the routed-expert ``olmoe`` block of ``models/olmoe.py``, in bfloat16 with
-a bfloat16 cache.  Every step builder below serves both through one
-contract (``_block``), so there is one paged step, one multi-token step,
-one draft rollout and one unpaged reference, whatever the block.
+The decoder is one of three blocks, picked by ``DecoderConfig.arch``: the
+``gpt2`` block of this file, in float32; the routed-expert ``olmoe`` block
+of ``models/olmoe.py``, in bfloat16 with a bfloat16 cache; and the
+``granite_hybrid`` block of ``models/granite_hybrid.py``, whose layers are of
+two kinds: grouped-query attention (fewer KV heads than query heads, so
+pools ``kv_heads * head_dim`` wide) and Mamba-2 state-space mixers, which
+keep a recurrent state a sequence instead of K and V.  Every step builder
+below serves all three through one contract (``_block``), so there is one
+paged step, one multi-token step, one draft rollout and one unpaged
+reference, whatever the block.
 
-Two step builders share every layer of math through one ``attend``
-callback:
+Two step builders share every layer of math through two callbacks, which
+own what a layer keeps between tokens: ``attend`` the K and V of an
+attention layer, ``recur`` (``_Recurrent``) the convolution window and the
+state of a recurrent layer:
 
 * ``make_paged_step``   — writes this token's K/V rows into the layer's
-  own pools of the paged cache (``[num_blocks, block_size, H * D]``, block
+  own pools of the paged cache (``[num_blocks, block_size, KH * D]``, block
   ids steered by the per-lane block table) and attends through
   ``paged_attention`` over them (scope ``kv_read`` where the kernel
-  serves, ``kv_gather`` where the table is gathered).
+  serves, ``kv_gather`` where the table is gathered); a recurrent layer's
+  window and state are read from and written to the slot the step is told
+  for each lane (``state_slots``), the state through
+  ``pallas_kernels.ssm_update.state_update`` (on a TPU a kernel that moves
+  each slot in place, elsewhere gather, update, scatter).
 * ``make_unpaged_step`` — the reference: contiguous per-lane K/V
-  ``[L, B, S, H, D]`` updated at ``pos`` and attended via the same
-  ``masked_attention`` core.
+  ``[L, B, S, KH, D]`` updated at ``pos`` and attended via the same
+  ``masked_attention`` core, window and state a row a lane moved by the
+  same ``ssm_update.advance``.
 
 Because the gather path and the unpaged loop feed bitwise-identical K/V
 values into the identical attention/MLP expressions at identical shapes,
@@ -51,6 +62,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..pallas_kernels import paged_attention as _pa
+from ..pallas_kernels import ssm_update as _ssm
 from ..pallas_kernels.paged_attention import gather_blocks, \
     masked_attention, paged_attention
 from . import kv_cache as _kv
@@ -59,7 +71,12 @@ __all__ = ["DecoderConfig", "init_decoder_params", "save_decoder",
            "load_decoder", "is_decoder_dir", "has_draft", "load_draft",
            "truncate_decoder", "attention_path", "make_paged_step",
            "make_paged_step_multi",
-           "make_draft_rollout", "make_unpaged_step", "unpaged_generate"]
+           "make_draft_rollout", "make_unpaged_step", "unpaged_generate",
+           "cache_config"]
+
+
+ARCHS = ("gpt2", "olmoe", "granite_hybrid")
+LAYER_KINDS = ("attention", "mamba")
 
 
 class DecoderConfig:
@@ -67,21 +84,41 @@ class DecoderConfig:
     ``gpt2`` is the pre-LN MHA + GELU block of this file (learned
     positions), ``olmoe`` the routed-expert block of ``models/olmoe.py``
     (RMSNorm, Q/K norm, RoPE, ``experts`` SiLU-gated experts of width
-    ``ffn``, ``experts_per_token`` of them a token).  ``dtype`` is the
-    weights' (``f32`` | ``bf16``); ``kv_dtype`` the cache's residency
-    (``f32`` | ``bf16`` | ``int8``), and None leaves it to
-    ``FLAGS_kv_cache_dtype``: a bf16 model keeps a bf16 cache."""
+    ``ffn``, ``experts_per_token`` of them a token), ``granite_hybrid`` the
+    block of ``models/granite_hybrid.py``: layers of two kinds named one
+    by one in ``layer_types`` (``attention`` | ``mamba``), grouped-query
+    attention (``heads`` query heads over ``kv_heads`` KV heads, no
+    position encoding) beside Mamba-2 mixers of ``ssm_heads`` heads of
+    ``ssm_head_dim``, state ``ssm_state`` and a causal convolution of
+    ``ssm_conv`` taps, a gated MLP of width ``ffn``, a tied head, and the
+    family's four multipliers (``embedding_multiplier`` on the embedding,
+    ``residual_multiplier`` on every branch, ``attention_multiplier`` as
+    the scale of the scores, logits divided by ``logits_scaling``).
+
+    ``kv_heads`` None means ``heads`` (multi-head); ``layer_types`` None
+    means ``layers`` attention layers.  ``dtype`` is the weights' (``f32``
+    | ``bf16``); ``kv_dtype`` the cache's residency (``f32`` | ``bf16`` |
+    ``int8``), and None leaves it to ``FLAGS_kv_cache_dtype``: a bf16
+    model keeps a bf16 cache."""
 
     __slots__ = ("vocab", "layers", "heads", "head_dim", "ffn", "max_seq",
                  "arch", "dtype", "kv_dtype", "experts",
-                 "experts_per_token", "rope_theta", "norm_eps")
+                 "experts_per_token", "rope_theta", "norm_eps",
+                 "kv_heads", "layer_types", "ssm_heads", "ssm_head_dim",
+                 "ssm_state", "ssm_conv", "embedding_multiplier",
+                 "residual_multiplier", "attention_multiplier",
+                 "logits_scaling")
 
     def __init__(self, vocab, layers, heads, head_dim, ffn=None,
                  max_seq=64, arch="gpt2", dtype="f32", kv_dtype=None,
                  experts=0, experts_per_token=0, rope_theta=10000.0,
-                 norm_eps=1e-5):
-        if arch not in ("gpt2", "olmoe"):
-            raise ValueError("decoder arch must be gpt2|olmoe: %r" % (arch,))
+                 norm_eps=1e-5, kv_heads=None, layer_types=None,
+                 ssm_heads=0, ssm_head_dim=0, ssm_state=0, ssm_conv=0,
+                 embedding_multiplier=1.0, residual_multiplier=1.0,
+                 attention_multiplier=None, logits_scaling=1.0):
+        if arch not in ARCHS:
+            raise ValueError("decoder arch must be %s: %r"
+                             % ("|".join(ARCHS), arch))
         if dtype not in ("f32", "bf16"):
             raise ValueError("decoder dtype must be f32|bf16: %r" % (dtype,))
         if arch == "gpt2" and dtype != "f32":
@@ -103,25 +140,96 @@ class DecoderConfig:
         self.experts_per_token = int(experts_per_token)
         self.rope_theta = float(rope_theta)
         self.norm_eps = float(norm_eps)
+        self.kv_heads = int(kv_heads if kv_heads is not None else heads)
+        self.layer_types = tuple(
+            layer_types if layer_types is not None
+            else ("attention",) * self.layers)
+        self.ssm_heads = int(ssm_heads)
+        self.ssm_head_dim = int(ssm_head_dim)
+        self.ssm_state = int(ssm_state)
+        self.ssm_conv = int(ssm_conv)
+        self.embedding_multiplier = float(embedding_multiplier)
+        self.residual_multiplier = float(residual_multiplier)
+        self.attention_multiplier = None if attention_multiplier is None \
+            else float(attention_multiplier)
+        self.logits_scaling = float(logits_scaling)
+        if len(self.layer_types) != self.layers or any(
+                k not in LAYER_KINDS for k in self.layer_types):
+            raise ValueError("layer_types must name each of the %d layers "
+                             "%s: %r" % (self.layers, "|".join(LAYER_KINDS),
+                                         layer_types))
+        if self.heads % self.kv_heads:
+            raise ValueError("heads %d must be a multiple of kv_heads %d"
+                             % (self.heads, self.kv_heads))
+        if arch != "granite_hybrid" and (
+                self.ssm_layers or self.kv_heads != self.heads):
+            raise ValueError("the %s block is multi-head attention in every "
+                             "layer" % arch)
+        if self.ssm_layers and min(self.ssm_heads, self.ssm_head_dim,
+                                   self.ssm_state, self.ssm_conv - 1) < 1:
+            raise ValueError("mamba layers want ssm_heads, ssm_head_dim, "
+                             "ssm_state >= 1 and ssm_conv >= 2")
 
     @property
     def hidden(self):
         return self.heads * self.head_dim
 
+    @property
+    def attn_layers(self):
+        """Indices of the layers that hold K and V, in order."""
+        return tuple(l for l, k in enumerate(self.layer_types)
+                     if k == "attention")
+
+    @property
+    def ssm_layers(self):
+        """Indices of the layers that hold a recurrent state, in order."""
+        return tuple(l for l, k in enumerate(self.layer_types)
+                     if k == "mamba")
+
+    @property
+    def ssm_inner(self):
+        return self.ssm_heads * self.ssm_head_dim
+
     def to_dict(self):
-        return {s: getattr(self, s) for s in self.__slots__}
+        d = {s: getattr(self, s) for s in self.__slots__}
+        d["layer_types"] = list(self.layer_types)
+        return d
 
     def replace(self, **changes):
         return DecoderConfig(**dict(self.to_dict(), **changes))
 
 
+def cache_config(cfg, block_size, num_blocks, dtype=None, state_slots=0):
+    """The cache geometry a model's step is built over: K and V pools for
+    its attention layers (``kv_heads`` wide), and for its recurrent layers
+    what one sequence's slot holds: the convolution's window (``ssm_conv -
+    1`` inputs of ``ssm_inner + 2 * ssm_state`` values, flat, in the
+    weights' dtype) and the state (``[ssm_state, ssm_inner]`` float32: the
+    heads' ``[head_dim, ssm_state]`` matrices, transposed so the minor
+    dimension is the 128-lane-dense one), in ``state_slots`` slots (slot 0
+    the idle lanes' scratch)."""
+    return _kv.KVCacheConfig(
+        len(cfg.attn_layers), cfg.kv_heads, cfg.head_dim, block_size,
+        num_blocks, dtype or cfg.kv_dtype or "f32",
+        state_layers=len(cfg.ssm_layers), state_shapes=_state_shapes(cfg),
+        state_slots=state_slots)
+
+
+def _state_shapes(cfg):
+    """``(shape, dtype)`` of what a recurrent layer keeps a sequence: the
+    window, then the state; nothing for a model with no such layer."""
+    if not cfg.ssm_layers:
+        return ()
+    return ((((cfg.ssm_conv - 1) * (cfg.ssm_inner + 2 * cfg.ssm_state),),
+             cfg.dtype),
+            ((cfg.ssm_state, cfg.ssm_inner), "f32"))
+
+
 def init_decoder_params(cfg, seed=0):
     """name -> np array in the config's weight dtype; 0.02-normal
     weights, identity norms."""
-    if cfg.arch == "olmoe":
-        from ..models import olmoe
-
-        return olmoe.init_params(cfg, seed)
+    if cfg.arch != "gpt2":
+        return _model(cfg).init_params(cfg, seed)
     r = np.random.RandomState(seed)
     h, f, v = cfg.hidden, cfg.ffn, cfg.vocab
 
@@ -206,7 +314,7 @@ def truncate_decoder(cfg, params, layers=1):
     tracks the full model's closely — a distillation-free draft for
     demos and smokes (real deployments train one)."""
     layers = min(int(layers), cfg.layers)
-    dcfg = cfg.replace(layers=layers)
+    dcfg = cfg.replace(layers=layers, layer_types=cfg.layer_types[:layers])
     dparams = {}
     for k, v in params.items():
         m = re.match(r"l(\d+)_", k)
@@ -223,21 +331,34 @@ def _ln(x, g, b):
     return (x - m) * jax.lax.rsqrt(var + 1e-5) * g + b
 
 
-def _block(cfg):
-    """The architecture's block: ``block(params, cfg, tok, pos, attend,
-    live) -> (logits [B, vocab], extras)``.  ``attend(l, q, k, v)`` owns
-    the KV write + history attention (the only paged/unpaged difference);
-    ``live`` [B] bool marks the lanes that hold a sequence; ``extras`` is
-    a tuple of small arrays the step returns after its logits (the olmoe
-    block's tokens routed to each expert; nothing for gpt2)."""
+def _model(cfg):
+    """The module of an architecture that has one of its own."""
     if cfg.arch == "olmoe":
         from ..models import olmoe
 
-        return olmoe.token_logits
-    return _token_logits
+        return olmoe
+    from ..models import granite_hybrid
+
+    return granite_hybrid
 
 
-def _token_logits(params, cfg, tok, pos, attend, live=None):
+def _block(cfg):
+    """The architecture's block: ``block(params, cfg, tok, pos, attend,
+    live, recur) -> (logits [B, vocab], extras)``.  Two callbacks own what
+    a layer keeps between tokens, the only paged/unpaged difference:
+    ``attend(l, q, k, v)`` the KV write + history attention of attention
+    layer ``l``, and ``recur`` the recurrent layers' state
+    (``recur.window(l, x)`` pushes this token's convolution input and
+    returns the newest ``ssm_conv``, ``recur.advance(l, decay, dx, b, c)``
+    moves the state one token and returns its read-out; None for a model
+    with no such layer).  ``live`` [B] bool marks the lanes that hold a
+    sequence; ``extras`` is a tuple of small arrays the step returns after
+    its logits (the olmoe block's tokens routed to each expert; nothing
+    for the others)."""
+    return _token_logits if cfg.arch == "gpt2" else _model(cfg).token_logits
+
+
+def _token_logits(params, cfg, tok, pos, attend, live=None, recur=None):
     """The gpt2 block: one token per lane through every layer.  The
     ``jax.named_scope`` names (``layer<i>/attn``, ``.../kv_write``,
     ``.../kv_read`` or ``.../kv_gather``, ``layer<i>/mlp``, ``lm_head``)
@@ -290,23 +411,72 @@ def attention_path(cfg, kv_config, lanes=1):
     (the int8 residency always does)."""
     return _pa.attention_path(
         (lanes, cfg.heads, cfg.head_dim),
-        (kv_config.num_blocks, kv_config.block_size, cfg.hidden),
+        (kv_config.num_blocks, kv_config.block_size,
+         kv_config.heads * kv_config.head_dim),
         _kv._PAYLOAD[kv_config.dtype][0])
 
 
+def _pool_index(cfg):
+    """layer -> its place among the layers of its kind (the index of its
+    pools, or of its state arrays, in the cache's groups)."""
+    return {l: i for kind in (cfg.attn_layers, cfg.ssm_layers)
+            for i, l in enumerate(kind)}
+
+
+class _Recurrent:
+    """The recurrent layers' callback of a step (``_block``), built by the
+    step maker from where it keeps things: ``read(i)`` / ``write(i, value)``
+    reach recurrent layer ``i``'s window ``[B, (K - 1) * W]`` for the step's
+    lanes, and ``advance(i, fresh, decay, dx, b, c) -> y`` moves its state
+    one token (``ssm_update.advance``'s mathematics, on values or on the
+    slots of a pool).  A lane at position 0 (``fresh``) starts from zeros
+    whatever is stored."""
+
+    def __init__(self, pool_of, taps, pos, read, write, advance):
+        self._at, self._taps = pool_of, taps
+        self._fresh = pos == 0
+        self._read, self._write, self._advance = read, write, advance
+
+    def window(self, l, xbc):
+        """Push this token's convolution input ``xbc`` [B, W] -> the
+        ``ssm_conv`` newest inputs [B, K, W] float32, oldest first, this
+        one (as stored) last."""
+        i = self._at[l]
+        old = _ssm.started(self._fresh, self._read(i))
+        new = jnp.concatenate(
+            [old[:, xbc.shape[1]:], xbc.astype(old.dtype)], axis=1)
+        self._write(i, new)
+        return jnp.concatenate([old[:, :xbc.shape[1]], new], axis=1).reshape(
+            xbc.shape[0], self._taps, -1).astype(jnp.float32)
+
+    def advance(self, l, decay, dx, b, c):
+        """``S = decay * S + outer(b, dx)`` -> ``c . S`` [B, I]: the state
+        [N, I] of each lane one token on, ``decay`` and ``dx`` [B, I] (a
+        head's decay repeated over its values), ``b`` and ``c`` [B, N]."""
+        return self._advance(self._at[l], self._fresh, decay, dx, b, c)
+
+
 def make_paged_step(cfg, kv_config):
-    """-> step(kv_carry, params, tok, pos, block_tables, context_lens)
-    returning (new_kv_carry, next_tokens, logits) and then the block's
-    extras, if it has any (``_block``).
+    """-> step(kv_carry, params, tok, pos, block_tables, context_lens[,
+    state_slots]) returning (new_kv_carry, next_tokens, logits) and then
+    the block's extras, if it has any (``_block``).
 
     ``kv_carry`` is ``PagedKVCache.carry()``: per-layer pools, K then V
-    (then their scales for int8), donated by ``CarriedStepFn`` and written
-    in place.  All shapes are static per lane bucket: tok/pos/context_lens
-    [B], block_tables [B, MAXB].  ``context_lens[b]`` counts the tokens
-    valid AFTER this step's write (pos + 1 for live lanes, 0 for idle
-    lanes, whose table points at the reserved scratch block 0: an idle
-    lane feeds pos 0, so its write lands on row 0 of that block, the only
-    row of the pool a step may change besides the live lanes' own).
+    (then their scales for int8) for the attention layers and then the
+    recurrent layers' window and state slots, donated by ``CarriedStepFn``
+    and written in place.  All shapes are static per lane bucket:
+    tok/pos/context_lens [B], block_tables [B, MAXB].  ``context_lens[b]``
+    counts the tokens valid AFTER this step's write (pos + 1 for live
+    lanes, 0 for idle lanes, whose table points at the reserved scratch
+    block 0: an idle lane feeds pos 0, so its write lands on row 0 of that
+    block, the only row of the pool a step may change besides the live
+    lanes' own).
+
+    A model with recurrent layers takes ``state_slots`` [B] int32 too: the
+    slot each lane's sequence holds (idle lanes name the scratch slot 0).
+    A lane's window and state are read from its slot and written back to
+    it, and a lane whose ``pos`` is 0 starts from zeros whatever the slot
+    holds, so a slot needs no clearing between sequences.
 
     Feed-planning contract (what prefix caching leans on): the step
     WRITES exactly one position — ``pos``, into block
@@ -317,12 +487,18 @@ def make_paged_step(cfg, kv_config):
     blocks are read-only by construction because every write lands at
     ``pos >= cached_tokens``, i.e. in a private tail block.  The values a
     cache hit skips recomputing are bitwise the ones this step would
-    have produced, so output parity is structural, not numerical."""
+    have produced, so output parity is structural, not numerical.  That
+    holds for K and V.  A recurrent state at ``pos`` exists only in the
+    slot of the sequence that computed it, so a model with recurrent
+    layers starts every sequence at position 0 (the engine declines prefix
+    reuse for it)."""
     bs = kv_config.block_size
     int8 = kv_config.dtype == "int8"
     block = _block(cfg)
+    pool_of = _pool_index(cfg)
 
-    def step(kv_carry, params, tok, pos, block_tables, context_lens):
+    def step(kv_carry, params, tok, pos, block_tables, context_lens,
+             state_slots=None):
         tok = tok.astype(jnp.int32)
         pos = pos.astype(jnp.int32)
         block_tables = block_tables.astype(jnp.int32)
@@ -330,36 +506,59 @@ def make_paged_step(cfg, kv_config):
         blk_ids = jnp.take_along_axis(
             jnp.maximum(block_tables, 0), (pos // bs)[:, None], axis=1)[:, 0]
         offs = pos % bs
-        pools = _kv.carry_groups(kv_carry, cfg.layers)
+        pools, state = kv_config.groups(kv_carry)
 
-        def write(group, l, rows):
-            pools[group][l] = _write_rows(pools[group][l], blk_ids, offs,
+        def write(group, i, rows):
+            pools[group][i] = _write_rows(pools[group][i], blk_ids, offs,
                                           rows)
 
         def attend(l, q, k, v):
+            i = pool_of[l]
             if not int8:
                 with jax.named_scope("kv_write"):
-                    write(0, l, k)
-                    write(1, l, v)
-                return paged_attention(q, pools[0][l], pools[1][l],
-                                       block_tables, context_lens)
+                    write(0, i, k)
+                    write(1, i, v)
+                return paged_attention(q, pools[0][i], pools[1][i],
+                                       block_tables, context_lens,
+                                       cfg.attention_multiplier)
             with jax.named_scope("kv_write"):
                 for group, x in ((0, k), (1, v)):
                     payload, scale = _kv.quantize_kv(x)
-                    write(group, l, payload)
-                    write(group + 2, l, scale)
+                    write(group, i, payload)
+                    write(group + 2, i, scale)
             with jax.named_scope("kv_gather"):
                 kk, vv = (_kv.dequantize_kv(
-                    gather_blocks(pools[g][l], block_tables).reshape(
-                        q.shape[0], -1, *q.shape[1:]),
-                    gather_blocks(pools[g + 2][l], block_tables))
+                    gather_blocks(pools[g][i], block_tables).reshape(
+                        k.shape[0], -1, *k.shape[1:]),
+                    gather_blocks(pools[g + 2][i], block_tables))
                     for g in (0, 1))
-            return masked_attention(q, kk, vv, context_lens)
+            return masked_attention(q, kk, vv, context_lens,
+                                    cfg.attention_multiplier)
+
+        recur = None
+        if state:
+            slots = state_slots.astype(jnp.int32)
+            windows, states = state
+
+            def put(i, value):
+                # a scatter of B slots into a whole donated array, as
+                # _write_rows is of B rows; idle lanes all name slot 0
+                windows[i] = windows[i].at[slots].set(value)
+
+            def advance(i, fresh, *operands):
+                states[i], y = _ssm.state_update(states[i], slots, fresh,
+                                                 *operands)
+                return y
+
+            recur = _Recurrent(
+                pool_of, cfg.ssm_conv, pos,
+                lambda i: jnp.take(windows[i], slots, axis=0, mode="clip"),
+                put, advance)
 
         logits, extras = block(params, cfg, tok, pos, attend,
-                               context_lens > 0)
+                               context_lens > 0, recur)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return (tuple(a for group in pools for a in group), nxt,
+        return (tuple(a for group in pools + state for a in group), nxt,
                 logits) + tuple(extras)
 
     return step
@@ -383,10 +582,15 @@ def make_paged_step_multi(cfg, kv_config, width):
     ``width`` real tokens freeze their later columns' lens so the junk
     columns' (discarded) logits never read an unwritten position, and
     their writes land beyond every lens — overwritten before any later
-    step can attend to them."""
+    step can attend to them.  A recurrent state has no such "beyond": a
+    model with recurrent layers takes ``state_slots`` [B] after the lens,
+    and every column of a live lane has to be a real token (a chunk of
+    prefill; its state cannot be rolled back, which is why the engine
+    refuses it speculation)."""
     base = make_paged_step(cfg, kv_config)
 
-    def step(kv_carry, params, tok, pos, block_tables, context_lens):
+    def step(kv_carry, params, tok, pos, block_tables, context_lens,
+             *state_slots):
         tok = tok.astype(jnp.int32)
         pos = pos.astype(jnp.int32)
         context_lens = context_lens.astype(jnp.int32)
@@ -403,7 +607,7 @@ def make_paged_step_multi(cfg, kv_config, width):
                 tok_j = jnp.where(nxts[-1] < 0, nxts[-1], tok_j)
             kv_carry, nxt, lg, *more = base(
                 kv_carry, params, tok_j, pos[:, j], block_tables,
-                context_lens[:, j])
+                context_lens[:, j], *state_slots)
             nxts.append(nxt)
             logits.append(lg)
             extras = more if extras is None \
@@ -455,30 +659,62 @@ def make_draft_rollout(cfg, kv_config, k):
 # -- unpaged reference -------------------------------------------------------
 
 def make_unpaged_step(cfg, pad_len):
-    """Reference step over contiguous per-lane K/V [L, B, pad_len, H, D].
-    Same ``masked_attention`` core at the same [B, pad_len, H, D] shapes
-    as the paged gather path — the bitwise comparison target."""
+    """Reference step over contiguous per-lane K/V [L, B, pad_len, KH, D]
+    (``L`` the attention layers).  Same ``masked_attention`` core at the
+    same [B, pad_len, KH, D] shapes as the paged gather path — the bitwise
+    comparison target.  A model with recurrent layers carries their window
+    ``[Lr, B, (K - 1) * W]`` and state ``[Lr, B, N, I]`` after K and V, a
+    lane a row, through the same ``_Recurrent`` as the paged step."""
     block = _block(cfg)
+    pool_of = _pool_index(cfg)
 
     def step(kv_carry, params, tok, pos, context_lens):
         tok = tok.astype(jnp.int32)
         pos = pos.astype(jnp.int32)
         context_lens = context_lens.astype(jnp.int32)
-        k_c, v_c = kv_carry
+        k_c, v_c, *state = kv_carry
         lanes = jnp.arange(k_c.shape[1], dtype=jnp.int32)
 
         def attend(l, q, k, v):
             nonlocal k_c, v_c
-            k_c = k_c.at[l, lanes, pos].set(k.astype(k_c.dtype))
-            v_c = v_c.at[l, lanes, pos].set(v.astype(v_c.dtype))
-            return masked_attention(q, k_c[l], v_c[l], context_lens)
+            i = pool_of[l]
+            k_c = k_c.at[i, lanes, pos].set(k.astype(k_c.dtype))
+            v_c = v_c.at[i, lanes, pos].set(v.astype(v_c.dtype))
+            return masked_attention(q, k_c[i], v_c[i], context_lens,
+                                    cfg.attention_multiplier)
 
+        def put(i, value):
+            state[0] = state[0].at[i].set(value)
+
+        def advance(i, fresh, *operands):
+            new, y = _ssm.advance(_ssm.started(fresh, state[1][i]),
+                                  *operands)
+            state[1] = state[1].at[i].set(new)
+            return y
+
+        recur = _Recurrent(pool_of, cfg.ssm_conv, pos,
+                           lambda i: state[0][i], put,
+                           advance) if state else None
         logits, _extras = block(params, cfg, tok, pos, attend,
-                                context_lens > 0)
+                                context_lens > 0, recur)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return (k_c, v_c), nxt, logits
+        return (k_c, v_c, *state), nxt, logits
 
     return step
+
+
+def _unpaged_carry(cfg, lanes, pad_len):
+    """Zeros for ``make_unpaged_step``'s carry: K and V in the residency
+    the paged pool would have (bf16 for a bf16 model; the f32 default
+    otherwise: int8 has no unpaged twin), and the recurrent layers' window
+    and state as the paged cache would hold them."""
+    kv_dtype = jnp.bfloat16 if cfg.kv_dtype == "bf16" else jnp.float32
+    carry = tuple(jnp.zeros((len(cfg.attn_layers), lanes, pad_len,
+                             cfg.kv_heads, cfg.head_dim), kv_dtype)
+                  for _ in range(2))
+    return carry + tuple(
+        jnp.zeros((len(cfg.ssm_layers), lanes) + shape, _kv._PAYLOAD[dt][0])
+        for shape, dt in _state_shapes(cfg))
 
 
 def unpaged_generate(cfg, params, prompt_ids, max_new, pad_len=None,
@@ -491,11 +727,7 @@ def unpaged_generate(cfg, params, prompt_ids, max_new, pad_len=None,
         pad_len = cfg.max_seq
     step = jax.jit(make_unpaged_step(cfg, pad_len), donate_argnums=(0,))
     jparams = {k: jnp.asarray(v) for k, v in params.items()}
-    # K and V in the residency the paged pool would have (bf16 for a bf16
-    # model; the f32 default otherwise: int8 has no unpaged twin)
-    kv_dtype = jnp.bfloat16 if cfg.kv_dtype == "bf16" else jnp.float32
-    kv = tuple(jnp.zeros((cfg.layers, 1, pad_len, cfg.heads, cfg.head_dim),
-                         kv_dtype) for _ in range(2))
+    kv = _unpaged_carry(cfg, 1, pad_len)
     prompt_ids = [int(t) for t in prompt_ids]
     out, logits_hist = [], []
     tok = prompt_ids[0]

@@ -777,7 +777,7 @@ class _DecodeSeq:
 
     __slots__ = ("pending", "prompt", "max_new", "eos_id", "on_token",
                  "blocks", "table", "draft_blocks", "draft_table",
-                 "n_fed", "next_tok", "out",
+                 "state_slot", "n_fed", "next_tok", "out",
                  "t_admit", "t_first", "token_times", "admit_seq",
                  "aborted", "hashes", "published", "cached_tokens",
                  "handoff", "prefill_upto",
@@ -794,6 +794,9 @@ class _DecodeSeq:
         self.table = np.full(maxb, -1, np.int32)
         self.draft_blocks = []                # speculative draft KV lanes
         self.draft_table = np.full(maxb, -1, np.int32)
+        # the slot holding the recurrent layers' state (a model that has
+        # them): taken at admission, given back with the blocks
+        self.state_slot = None
         self.n_fed = 0
         self.next_tok = self.prompt[0]
         self.out = []
@@ -855,7 +858,10 @@ class _DecodeSeq:
         (Freed shared blocks only dropped a reference — re-admission
         re-matches the prefix index, now including any published
         history blocks, so the replay usually skips straight past the
-        cached prefix again.)"""
+        cached prefix again.  A model with recurrent layers has no
+        index to match: its replay starts at position 0, in whatever
+        slot re-admission hands it.)"""
+        self.state_slot = None
         self.blocks = []
         self.table.fill(-1)
         self.draft_blocks = []
@@ -875,7 +881,7 @@ class _DecodeSeq:
 class _DecodeModel:
     __slots__ = ("name", "cfg", "params", "kv_config", "cache", "stepfn",
                  "maxb", "attn_path", "blocks_read", "step_ms", "prefix",
-                 "__weakref__",
+                 "declines", "slot_bytes", "__weakref__",
                  # speculative decode (spec_k == 0 means off): the draft
                  # decoder runs k tokens ahead through its own paged pool,
                  # then verifyfn scores all k+1 positions in one target call
@@ -897,6 +903,12 @@ class _DecodeModel:
         self.blocks_read = None
         self.step_ms = 0.0          # EWMA of one decode step
         self.prefix = None          # PrefixCache (FLAGS_prefix_cache)
+        # why this model declines what starts or moves a sequence at
+        # pos > 0 over K/V blocks alone (prefix reuse, history
+        # publication, block adoption, session export), or None
+        self.declines = None
+        # one sequence's recurrent state over all such layers, in bytes
+        self.slot_bytes = 0
         self.spec_k = 0
         self.draft_cfg = None
         self.draft_params = None
@@ -1041,6 +1053,14 @@ class DecodeEngine:
                 else _flag("speculative_k") or 0)
         if draft is None:
             k = 0   # no draft bundle -> non-speculative regardless of k
+        recurrent = bool(cfg.ssm_layers)
+        if recurrent and k > 0:
+            # verify rolls a rejected proposal back by trimming the block
+            # table; a recurrent state that has consumed it cannot be
+            raise ValueError(
+                "model %r has recurrent layers: speculative decoding needs "
+                "state snapshots to roll back to, which the cache does not "
+                "keep (speculative_k=%d)" % (name, k))
         # .nbytes of a device array is read without copying it to the host
         resident = sum(int(v.nbytes) for v in params.values())
         draft_resident = 0
@@ -1054,20 +1074,22 @@ class DecodeEngine:
                                  "(block tables must line up)"
                                  % (dcfg.max_seq, cfg.max_seq))
             draft_resident = sum(int(v.nbytes) for v in dparams.values())
-        kv_config = _kvc.KVCacheConfig(
-            layers=cfg.layers, heads=cfg.heads, head_dim=cfg.head_dim,
-            block_size=int(_flag("kv_block_size")),
-            num_blocks=2,  # placeholder; plan_num_blocks decides below
+        kv_config = _dm.cache_config(
+            cfg, int(_flag("kv_block_size")),
+            2,  # placeholder; plan_num_blocks decides below
             # the model's own residency (a bf16 model keeps a bf16 cache),
             # else the deployment's flag
-            dtype=cfg.kv_dtype or str(_flag("kv_cache_dtype")))
+            cfg.kv_dtype or str(_flag("kv_cache_dtype")),
+            # a sequence holds a state slot exactly while it holds a lane:
+            # one a lane of the largest bucket, and the scratch
+            state_slots=max(self.buckets) + 1 if recurrent else 0)
         n, capped = _kvc.plan_num_blocks(
             kv_config, model_resident_bytes=resident + draft_resident,
             requested=kv_blocks)
         kv_config.num_blocks = n
         cache = _kvc.PagedKVCache(kv_config)
         prefix = None
-        if bool(_flag("prefix_cache")):
+        if bool(_flag("prefix_cache")) and not recurrent:
             # content-addressed prefix reuse over the SAME pool: sealed
             # full-prompt blocks park evictable at zero refs, the index
             # revives them on a hash-chain match at admission.  The draft
@@ -1076,6 +1098,9 @@ class DecodeEngine:
             # never change the verified output.
             prefix = _kvc.PrefixCache(cache.allocator,
                                       kv_config.block_size, namespace=name)
+        # a model with recurrent layers gets no index (entry.declines): a
+        # hit would start a sequence at pos > 0 over shared K/V blocks,
+        # where the recurrent layers' state at that position is nowhere
         jparams = {key: jnp.asarray(v) for key, v in params.items()}
         attn_path = _dm.attention_path(cfg, kv_config, max(self.buckets))
         stepfn = CarriedStepFn(
@@ -1095,16 +1120,19 @@ class DecodeEngine:
             _pa.blocks_read, block_size=kv_config.block_size,
             maxb=entry.maxb, path=attn_path)
         entry.prefix = prefix
+        if recurrent:
+            entry.declines = "recurrent_state"
+            entry.slot_bytes = _kvc.slot_bytes(kv_config)
+            _tm.set_gauge("ssm_state_bytes", _kvc.state_bytes(kv_config),
+                          model=name)
         if k > 0:
             # draft pool mirrors the target's block COUNT (draft blocks
             # are strictly smaller at fewer layers), so any sequence the
             # target pool can hold, the draft pool can shadow; the budget
             # plan above already counted both param sets, and MEM001
             # reports the exact combined pool bytes afterwards
-            draft_kv = _kvc.KVCacheConfig(
-                layers=dcfg.layers, heads=dcfg.heads,
-                head_dim=dcfg.head_dim, block_size=kv_config.block_size,
-                num_blocks=n, dtype=kv_config.dtype)
+            draft_kv = _dm.cache_config(dcfg, kv_config.block_size, n,
+                                        kv_config.dtype)
             base_parts = {"model": name, "kv": {
                 "block_size": kv_config.block_size, "num_blocks": n,
                 "dtype": kv_config.dtype},
@@ -1137,7 +1165,8 @@ class DecodeEngine:
         _kvc.register_resident_bytes(entry, resident + draft_resident)
         self._models[name] = entry
         _tm.event("decode_model_added", model=name, blocks=n,
-                  budget_capped=capped, kv_bytes=cache.nbytes,
+                  budget_capped=capped, kv_bytes=cache.kv_nbytes,
+                  state_bytes=_kvc.state_bytes(kv_config),
                   speculative_k=k, prefix_cache=prefix is not None,
                   draft_kv_bytes=entry.draft_cache.nbytes if k else 0)
         return self._models[name]
@@ -1154,7 +1183,8 @@ class DecodeEngine:
                "num_blocks": m.kv_config.num_blocks,
                "kv_dtype": m.kv_config.dtype,
                "speculative_k": m.spec_k,
-               "prefix_cache": m.prefix is not None}
+               "prefix_cache": m.prefix is not None,
+               "state_slots": m.kv_config.state_slots}
         if m.spec_k > 0:
             out["draft"] = {"layers": m.draft_cfg.layers,
                             "num_blocks": m.draft_kv_config.num_blocks,
@@ -1225,8 +1255,15 @@ class DecodeEngine:
             manifest[name] = per
         return manifest
 
-    def _step_args(self, m, bucket, tok, pos, tables, lens):
-        return (m.cache.carry(), m.params, tok, pos, tables, lens)
+    def _step_args(self, m, bucket, tok, pos, tables, lens, slots=None):
+        """The step's arguments.  A model with recurrent layers is told
+        the state slot of each lane's sequence too (None: every lane idle,
+        on the scratch slot, as prewarm has them)."""
+        args = (m.cache.carry(), m.params, tok, pos, tables, lens)
+        if m.cache.slots is not None:
+            args += (slots if slots is not None
+                     else np.zeros(bucket, np.int32),)
+        return args
 
     # -- admission -----------------------------------------------------------
 
@@ -1492,6 +1529,11 @@ class DecodeEngine:
         m = self._models.get(model)
         if m is None:
             return "rejected:unknown model %r" % (model,)
+        if m.declines is not None:
+            # K/V blocks alone cannot continue a sequence whose other
+            # layers hold a state
+            _tm.inc("kv_migrate_refused_total", reason=m.declines)
+            return "rejected:%s" % m.declines
         if m.prefix is None:
             return "rejected:prefix cache disabled"
         with self._cond:
@@ -1595,6 +1637,8 @@ class DecodeEngine:
             if not seq.out or (not waiting and seq.in_prefill):
                 self._refuse_export(req_id, "in_prefill")
             m = self._model_of(seq)
+            if m.declines is not None:
+                self._refuse_export(req_id, m.declines)
             if m.prefix is None or not bool(_flag("session_migration")):
                 self._refuse_export(req_id, "disabled")
             if m.kv_config.dtype == "bf16":
@@ -1787,6 +1831,12 @@ class DecodeEngine:
             m.draft_cache.allocator.free(seq.draft_blocks)
             seq.draft_blocks = []
             seq.draft_table.fill(-1)
+        if seq.state_slot is not None:
+            # with the blocks, wherever they go: finish, abort, timeout,
+            # preemption, error.  Nothing is cleared: the next holder's
+            # first step starts from zeros
+            m.cache.slots.give(seq.state_slot)
+            seq.state_slot = None
 
     def _tokens_emitted(self):
         """Every ``on_token`` call of this iteration has been made: tell
@@ -1908,6 +1958,12 @@ class DecodeEngine:
                     (round((now - r.t_submit) * 1e3, 3),
                      round(((r.t_locked or now) - r.t_submit) * 1e3, 3)))
             s.t_admit = now
+            if m.cache.slots is not None:
+                s.state_slot = m.cache.slots.take()
+            if m.declines is not None and bool(_flag("prefix_cache")):
+                # no index to match: the whole prompt (or replay) is fed
+                _tm.inc("prefix_cache_declined_total", model=m.name,
+                        reason=m.declines)
             if m.prefix is not None and s.replay_upto > len(s.prompt):
                 # resumed (migrated-in) or preempted replay: match the
                 # full-history chain instead of the prompt alone
@@ -2298,19 +2354,32 @@ class DecodeEngine:
             pos = np.zeros(bucket, np.int32)
             tables = np.full((bucket, m.maxb), -1, np.int32)
             lens = np.zeros(bucket, np.int32)
+            slots = np.zeros(bucket, np.int32) \
+                if m.cache.slots is not None else None
             for i, s in enumerate(lanes):
                 tok[i] = s.next_tok
                 pos[i] = s.n_fed
                 tables[i] = s.table
                 lens[i] = s.n_fed + 1  # token valid AFTER this step's write
+                if slots is not None:
+                    slots[i] = s.state_slot
             # blocks a layer's attention fetches this step, of the slots
             # the table has: the live context's share where the kernel
             # reads in place, all of them where the table is gathered
             read = {"kv_blocks_read": m.blocks_read(lens),
                     "kv_table_slots": bucket * m.maxb} \
                 if _tr.enabled() else {}
+            if slots is not None:
+                # lanes at position 0 start their slot from zeros
+                resets = int((pos[:len(lanes)] == 0).sum())
+                if resets:
+                    _tm.inc("ssm_state_resets_total", resets, model=m.name)
+                if _tr.enabled():
+                    # the recurrent state this step reads and writes
+                    read["ssm_state_lanes"] = len(lanes)
+                    read["ssm_state_bytes"] = len(lanes) * m.slot_bytes
             sspan = self._open_step_span(m, bucket, lanes, **read)
-            args = self._step_args(m, bucket, tok, pos, tables, lens)
+            args = self._step_args(m, bucket, tok, pos, tables, lens, slots)
         self.in_batch = True
         t0 = time.perf_counter()
         gap_us = self._dispatch_gap_us()

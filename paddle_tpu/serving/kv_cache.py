@@ -35,6 +35,21 @@ exit.  Heads are never split on the pool: the step's attention
 lie, a lane's live blocks at a time, and its jnp fallback reshapes only
 what it has gathered.
 
+Layers are of two kinds (``KVCacheConfig`` names them).  Attention layers
+page K and V as above, ``heads`` being the KV heads the pool stores (fewer
+than the query heads under grouped-query attention).  Recurrent layers
+(state-space mixers) keep, a sequence, a state that is constant in its
+length: the cache holds it in ``state_slots`` *slots* (one array per layer
+and per ``(shape, dtype)`` the config lists, ``[state_slots, *shape]``),
+``SlotAllocator`` hands a sequence one slot at admission for as long as it
+holds blocks, and the step is told each lane's slot beside its block table
+(slot 0, like block 0, is the idle lanes' scratch).  The slots ride in the
+same donated carry after the K/V pools, and ``config.groups(carry)`` is the
+one accessor that splits a carry by the description.  A slot cannot be
+shared, trimmed or framed: prefix reuse, speculative roll-back and block
+export stay with models whose every layer pages, and the engine declines
+them for the others under counters.
+
 ``PrefixCache`` is the content-addressed index over sealed blocks: a
 per-model hash chain ``h_i = sha(h_{i-1}, block_token_ids)`` over *full*
 prompt blocks keys each physical block, ``match`` revives the longest
@@ -53,9 +68,10 @@ residency, ~4x the tokens per HBM byte.
 
 Sizing is budget-gated (the MEM001/MEM003 satellite):
 ``plan_num_blocks`` fits the pool under ``FLAGS_hbm_budget_bytes`` after
-the model's resident bytes, and every live cache registers its footprint
-so ``core/world_analysis.check_memory`` counts engine-owned KV blocks in
-the static per-replica peak estimate.
+the model's resident bytes and the recurrent layers' slots, and every live
+cache registers its footprint (pools and slots) so
+``core/world_analysis.check_memory`` counts engine-owned cache bytes in the
+static per-replica peak estimate.
 """
 
 import functools
@@ -69,9 +85,10 @@ import jax.numpy as jnp
 
 from ..core import telemetry as _tm
 
-__all__ = ["KVCacheConfig", "BlockAllocator", "PagedKVCache",
-           "PrefixCache", "carry_groups",
-           "plan_num_blocks", "block_bytes", "engine_owned_kv_bytes",
+__all__ = ["KVCacheConfig", "BlockAllocator", "SlotAllocator",
+           "PagedKVCache", "PrefixCache",
+           "plan_num_blocks", "block_bytes", "slot_bytes", "state_bytes",
+           "engine_owned_kv_bytes",
            "engine_owned_resident_bytes", "register_resident_bytes",
            "quantize_kv", "dequantize_kv"]
 
@@ -102,13 +119,24 @@ _PAYLOAD = {"f32": (jnp.float32, 4), "bf16": (jnp.bfloat16, 2),
 
 
 class KVCacheConfig:
-    """Static cache geometry; hidden = heads * head_dim per layer."""
+    """Static cache geometry, layers by kind.
+
+    Attention layers: ``layers`` of them hold K and V, ``heads`` KV heads
+    of ``head_dim`` (a pool row is ``heads * head_dim`` wide), paged in
+    ``num_blocks`` blocks of ``block_size`` tokens in residency ``dtype``.
+
+    Recurrent layers: ``state_layers`` of them hold, a sequence, one array
+    of each ``(shape, dtype)`` of ``state_shapes`` (dtype ``f32`` |
+    ``bf16``), constant in the sequence's length, in one of
+    ``state_slots`` slots (slot 0 is the idle lanes' scratch, as block 0
+    is).  A model of attention layers only has none."""
 
     __slots__ = ("layers", "heads", "head_dim", "block_size", "num_blocks",
-                 "dtype")
+                 "dtype", "state_layers", "state_shapes", "state_slots")
 
     def __init__(self, layers, heads, head_dim, block_size, num_blocks,
-                 dtype="f32"):
+                 dtype="f32", state_layers=0, state_shapes=(),
+                 state_slots=0):
         if dtype not in _PAYLOAD:
             raise ValueError("kv_cache dtype must be f32|bf16|int8: %r"
                              % (dtype,))
@@ -121,15 +149,69 @@ class KVCacheConfig:
         self.block_size = int(block_size)
         self.num_blocks = int(num_blocks)
         self.dtype = dtype
+        self.state_layers = int(state_layers)
+        self.state_shapes = tuple(
+            (tuple(int(x) for x in shape), dt) for shape, dt in state_shapes)
+        self.state_slots = int(state_slots)
+        if any(dt not in ("f32", "bf16") for _shape, dt in self.state_shapes):
+            raise ValueError("a recurrent state is f32|bf16: %r"
+                             % (state_shapes,))
+        if self.state_layers and (not self.state_shapes
+                                  or self.state_slots <= 1):
+            raise ValueError("recurrent layers need state_shapes and "
+                             "state_slots > 1 (slot 0 is the idle-lane "
+                             "scratch)")
+
+    @property
+    def kv_groups(self):
+        """Groups of per-layer K/V arrays in a carry: K, V, and for int8
+        residency their scales."""
+        return 4 if self.dtype == "int8" else 2
+
+    def groups(self, carry):
+        """A cache carry (or anything laid out like one) by what it holds
+        -> ``(kv, state)``: ``kv`` the groups of ``layers`` per-layer
+        pools (``[k, v]``, and ``[k, v, k_scales, v_scales]`` for int8
+        residency), ``state`` one group of ``state_layers`` per-layer
+        arrays for each entry of ``state_shapes``."""
+        carry = list(carry)
+        cut = self.kv_groups * self.layers
+        held = len(self.state_shapes) * self.state_layers
+        if len(carry) != cut + held:
+            raise ValueError("a carry of %d arrays is not this cache's "
+                             "(%d KV + %d state)" % (len(carry), cut, held))
+        kv = [carry[i:i + self.layers] for i in range(0, cut, self.layers)]
+        state = [carry[i:i + self.state_layers]
+                 for i in range(cut, len(carry), self.state_layers or 1)]
+        return kv, state
 
 
 def block_bytes(config):
-    """HBM bytes ONE block costs across all layers (K + V, + scales for
-    int8)."""
+    """HBM bytes ONE block costs across all attention layers (K + V, +
+    scales for int8)."""
     tok = config.heads * config.head_dim * _PAYLOAD[config.dtype][1]
     if config.dtype == "int8":
         tok += config.heads * 4                     # f32 scales
     return 2 * config.layers * config.block_size * tok
+
+
+def slot_bytes(config):
+    """HBM bytes ONE sequence's recurrent state costs across all recurrent
+    layers."""
+    per = 0
+    for shape, dt in config.state_shapes:
+        n = _PAYLOAD[dt][1]
+        for x in shape:
+            n *= x
+        per += n
+    return config.state_layers * per
+
+
+def state_bytes(config):
+    """HBM bytes of the recurrent layers' state pools: every slot, the
+    scratch one included.  Fixed by the lane buckets, not by the pool's
+    blocks."""
+    return config.state_slots * slot_bytes(config)
 
 
 def plan_num_blocks(config, model_resident_bytes=0, requested=None,
@@ -150,11 +232,15 @@ def plan_num_blocks(config, model_resident_bytes=0, requested=None,
         budget = int(_flags.flag("hbm_budget_bytes") or 0)
     per = block_bytes(config)
     if budget > 0:
+        # the recurrent layers' slots are held whatever the blocks: they
+        # come off the budget first
+        model_resident_bytes = int(model_resident_bytes) \
+            + state_bytes(config)
         fit = int((budget - int(model_resident_bytes)) // per)
         if fit < 2:
             raise ValueError(
                 "FLAGS_hbm_budget_bytes=%d leaves room for %d KV block(s) "
-                "of %d bytes beside %d model-resident bytes; the decode "
+                "of %d bytes beside %d model-resident and state bytes; the decode "
                 "cache needs >= 2 (shrink the model, raise the budget, or "
                 "set FLAGS_kv_cache_dtype=int8)"
                 % (budget, max(fit, 0), per, model_resident_bytes))
@@ -352,6 +438,49 @@ class BlockAllocator:
                     "high_water": self.high_water}
 
 
+class SlotAllocator:
+    """Host-side free list over the recurrent layers' state slots.  A
+    sequence holds one slot exactly while it holds a lane's worth of
+    blocks; slot 0 never enters circulation (the idle lanes' scratch).
+    There are as many slots as the largest lane bucket has lanes, so a
+    ``take`` that finds none, like a ``give`` of a slot not held, is an
+    engine bug and raises."""
+
+    def __init__(self, num_slots):
+        self.num_slots = int(num_slots)
+        self._free = list(range(self.num_slots - 1, 0, -1))
+        self._held = set()
+        self._lock = threading.Lock()
+
+    @property
+    def capacity(self):
+        return self.num_slots - 1
+
+    @property
+    def in_use(self):
+        with self._lock:
+            return len(self._held)
+
+    def take(self):
+        with self._lock:
+            if not self._free:
+                raise RuntimeError(
+                    "no recurrent-state slot free (%d held): more "
+                    "sequences hold lanes than the largest bucket has"
+                    % len(self._held))
+            slot = self._free.pop()
+            self._held.add(slot)
+            return slot
+
+    def give(self, slot):
+        with self._lock:
+            if slot not in self._held:
+                raise ValueError("give of a state slot not held: %r"
+                                 % (slot,))
+            self._held.discard(slot)
+            self._free.append(slot)
+
+
 class PrefixCache:
     """Content-addressed index of sealed full-prompt KV blocks.
 
@@ -523,8 +652,9 @@ _LIVE_RESIDENT = weakref.WeakKeyDictionary()
 
 
 def engine_owned_kv_bytes():
-    """Total HBM bytes of every live PagedKVCache in this process —
-    world_analysis.check_memory folds this into MEM001/MEM003."""
+    """Total HBM bytes of every live PagedKVCache in this process, K/V
+    pools and recurrent-state slots alike — world_analysis.check_memory
+    folds this into MEM001/MEM003."""
     return sum(c.nbytes for c in list(_LIVE))
 
 
@@ -555,20 +685,13 @@ def dequantize_kv(q, scale):
     return q.astype(jnp.float32) * scale[..., None]
 
 
-def carry_groups(carry, layers):
-    """A cache carry (or anything laid out like one) as its groups of
-    ``layers`` per-layer arrays: ``[k, v]``, and ``[k, v, k_scales,
-    v_scales]`` for int8 residency."""
-    carry = list(carry)
-    return [carry[i:i + layers] for i in range(0, len(carry), layers)]
-
-
 @functools.partial(jax.jit, static_argnums=(2,))
 def _get_block(carry, block, layers):
-    """One block of every pool, stacked over the layers per group."""
+    """One block of every K/V pool, stacked over the layers per group."""
+    carry = list(carry)
     return [jnp.stack([jax.lax.dynamic_index_in_dim(c, block, 0, False)
-                       for c in group])
-            for group in carry_groups(carry, layers)]
+                       for c in carry[i:i + layers]])
+            for i in range(0, len(carry), layers)]
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -580,21 +703,32 @@ def _set_block(carry, block, rows):
 
 
 class PagedKVCache:
-    """Engine-owned paged K/V device arrays, carried (donated) through
-    the decode step.  Block 0 is reserved: idle lanes in a partially-full
-    bucket point their table at it, so their (masked, discarded) writes
-    never touch a sequence's history.
+    """Engine-owned device arrays of what a model's layers keep between
+    tokens, carried (donated) through the decode step: paged K/V pools for
+    the attention layers and, for a model with recurrent layers, a slot a
+    sequence for their state.  Block 0 and slot 0 are reserved: idle lanes
+    in a partially-full bucket point their table and their slot at them,
+    so their (masked, discarded) writes never touch a sequence's history.
 
     The carry is a flat tuple of per-layer arrays, one *group* after
-    another, layer 0 first within a group: K then V (``2 * layers``
-    arrays of ``[num_blocks, block_size, heads * head_dim]``), and for
-    int8 residency the K then V scales after them (``4 * layers`` in
-    all; a scale array is ``[num_blocks, block_size, heads]``).
-    ``carry_groups`` splits a carry back into its groups."""
+    another, layer 0 (of its kind) first within a group: K then V (``2 *
+    layers`` arrays of ``[num_blocks, block_size, heads * head_dim]``),
+    for int8 residency the K then V scales after them (``4 * layers`` in
+    all; a scale array is ``[num_blocks, block_size, heads]``), and then
+    one group of ``state_layers`` arrays ``[state_slots, *shape]`` for
+    each entry of the config's ``state_shapes``.  ``config.groups`` splits
+    a carry back by that description.
+
+    ``allocator`` hands out blocks and ``slots`` (None without recurrent
+    layers) state slots.  A slot is constant in the sequence's length and
+    cannot be shared, trimmed or snapshotted: prefix reuse, speculative
+    roll-back and block export are for models whose every layer pages."""
 
     def __init__(self, config):
         self.config = config
         self.allocator = BlockAllocator(config.num_blocks, reserve=1)
+        self.slots = SlotAllocator(config.state_slots) \
+            if config.state_layers else None
         rows = (config.num_blocks, config.block_size)
         payload = rows + (config.heads * config.head_dim,)
         groups = [(payload, _PAYLOAD[config.dtype][0])] * 2
@@ -603,12 +737,23 @@ class PagedKVCache:
         self._carry = tuple(jnp.zeros(shape, dtype)
                             for shape, dtype in groups
                             for _ in range(config.layers))
+        self._carry += tuple(
+            jnp.zeros((config.state_slots,) + shape, _PAYLOAD[dt][0])
+            for shape, dt in config.state_shapes
+            for _ in range(config.state_layers))
         _LIVE.add(self)
-        _tm.set_gauge("kv_cache_bytes", self.nbytes)
+        _tm.set_gauge("kv_cache_bytes", self.kv_nbytes)
+
+    @property
+    def kv_nbytes(self):
+        """The K/V pools' bytes (the ``kv_cache_bytes`` gauge)."""
+        return block_bytes(self.config) * self.config.num_blocks
 
     @property
     def nbytes(self):
-        return block_bytes(self.config) * self.config.num_blocks
+        """Everything this cache holds on the device: the K/V pools and
+        the recurrent layers' slots (``state_bytes``)."""
+        return self.kv_nbytes + state_bytes(self.config)
 
     def carry(self):
         """The current device arrays, in decode-step argument order."""
@@ -626,6 +771,12 @@ class PagedKVCache:
         return max(1, -(-int(n_tokens) // bs))
 
     # -- sealed-block export/import (the disaggregated transfer unit) --------
+
+    def _kv_carry(self):
+        """The K/V pools of the carry (a block's export and import frame
+        these only: a model with recurrent state is refused both by the
+        engine, before it gets here)."""
+        return self._carry[:self.config.kv_groups * self.config.layers]
 
     def _wire_shape(self, group):
         """One block of one carry group on the wire: every layer's rows
@@ -646,7 +797,7 @@ class PagedKVCache:
 
         return [np.asarray(a).reshape(self._wire_shape(g))
                 for g, a in enumerate(_get_block(
-                    self._carry, block, self.config.layers))]
+                    self._kv_carry(), block, self.config.layers))]
 
     def import_block(self, block, arrays):
         """Install transferred payloads into physical ``block``: one
@@ -657,8 +808,8 @@ class PagedKVCache:
         would corrupt every sequence that later matches the digest."""
         import numpy as np
 
-        layers = self.config.layers
-        groups = carry_groups(self._carry, layers)
+        kv_carry = self._kv_carry()
+        groups, _state = self.config.groups(self._carry)
         if len(arrays) != len(groups):
             raise ValueError(
                 "kv import arity mismatch: %d arrays for a %s-dtype "
@@ -674,9 +825,9 @@ class PagedKVCache:
                     "across the disaggregated pair)"
                     % (a.dtype, tuple(a.shape), want, want_shape))
         self._carry = _set_block(
-            self._carry, block,
+            kv_carry, block,
             [a[l].reshape(c.shape[1:]) for group, a in zip(groups, arrays)
-             for l, c in enumerate(group)])
+             for l, c in enumerate(group)]) + self._carry[len(kv_carry):]
 
     # -- multi-token growth / rollback (the speculative-decode contract) -----
 

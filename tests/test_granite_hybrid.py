@@ -1,0 +1,750 @@
+"""The hybrid decoder block (paddle_tpu/models/granite_hybrid.py: Mamba-2
+state-space layers beside grouped-query attention) through the same step
+makers, cache manager and engine as the GPT-2 and OLMoE blocks, against its
+plain reference (benchmark/reference/granite_hybrid_ref.py, the file the
+benchmark uses): logits at every position, paged against unpaged, the
+multi-token step, the engine (lanes that move, a reused slot, preemption
+with recompute), what declines for a model with recurrent state and under
+which counter, the manager's bytes and budget, and the two kernels under
+the interpreter.  Tiny sizes on the CPU: 8 layers in two periods of
+``mamba, mamba, attention, mamba``, hidden 64, 4 query heads over 2 (or 1)
+KV heads of 16, 8 state-space heads of 16 with state 32, vocab 97."""
+
+import contextlib
+import importlib.util
+import os
+import threading
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+import paddle_tpu as fluid
+from paddle_tpu.core import telemetry as _tm
+from paddle_tpu.core import tracing as _trc
+from paddle_tpu.models import granite_hybrid as gh
+from paddle_tpu.pallas_kernels import adoption
+from paddle_tpu.pallas_kernels import paged_attention as pa
+from paddle_tpu.pallas_kernels import ssm_update as su
+from paddle_tpu.serving import DecodeEngine
+from paddle_tpu.serving import decode_model as dm
+from paddle_tpu.serving import kv_cache as kvc
+from paddle_tpu.utils import fault_injection
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+
+
+def _load_ref():
+    spec = importlib.util.spec_from_file_location(
+        "granite_hybrid_ref", os.path.join(
+            ROOT, "benchmark", "reference", "granite_hybrid_ref.py"))
+    module = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(module)
+    return module
+
+
+ref = _load_ref()
+
+BS = 4
+PERIOD = ("mamba", "mamba", "attention", "mamba")
+CFG = dm.DecoderConfig(
+    arch="granite_hybrid", vocab=97, layers=8, heads=4, kv_heads=2,
+    head_dim=16, ffn=48, max_seq=64, layer_types=PERIOD * 2, ssm_heads=8,
+    ssm_head_dim=16, ssm_state=32, ssm_conv=4, embedding_multiplier=2.0,
+    residual_multiplier=0.22, attention_multiplier=0.25, logits_scaling=8.0)
+CFG16 = CFG.replace(dtype="bf16")
+CFG_G4 = CFG.replace(kv_heads=1)
+# normal(0, 0.3): at this hidden size the family's 0.02 leaves the layers'
+# share of the residual stream, and so a fault's mark on the logits, small,
+# and the tied head would make every token repeat its input
+PARAMS = gh.init_params(CFG, seed=3, std=0.3)
+PARAMS16 = gh.init_params(CFG16, seed=3, std=0.3)
+PARAMS_G4 = gh.init_params(CFG_G4, seed=3, std=0.3)
+
+
+def ref_config(cfg):
+    """The source's keys, as the reference reads them."""
+    return {"num_attention_heads": cfg.heads,
+            "num_key_value_heads": cfg.kv_heads,
+            "hidden_size": cfg.hidden, "rms_norm_eps": cfg.norm_eps,
+            "shared_intermediate_size": cfg.ffn,
+            "layer_types": list(cfg.layer_types),
+            "mamba_n_heads": cfg.ssm_heads, "mamba_d_head": cfg.ssm_head_dim,
+            "mamba_d_state": cfg.ssm_state, "mamba_d_conv": cfg.ssm_conv,
+            "embedding_multiplier": cfg.embedding_multiplier,
+            "residual_multiplier": cfg.residual_multiplier,
+            "attention_multiplier": cfg.attention_multiplier,
+            "logits_scaling": cfg.logits_scaling}
+
+
+# float32 rounding over eight layers (measured 2e-5 here); a fault in
+# structure is 1e-2 or more (the broken-reference controls below)
+TOL_F32 = 5e-4
+# bfloat16 as served against the float32 reference on the same (bfloat16)
+# weights: the rounding of every matmul's input, of the cached K and V and
+# of the convolution's window to 8 bits of mantissa, over eight layers.
+# Root-mean-square error over 3 sequences x 40-47 positions x 97 logits
+# (standard deviation 0.29): 0.0080-0.0092 on three seeds; the limit is half
+# as much again.  At 40 positions a bfloat16 state reads the same (0.0079-
+# 0.0107: it is the length of the chip check, 1,024 positions, that tells
+# it apart), so the control here is a state rounded to 8 bits (e4m3) at
+# every write: 0.032.
+RMS_BF16 = 0.014
+
+
+def _ref_logits(cfg, params, tokens, **changed):
+    with jax.default_matmul_precision("highest"):
+        return np.asarray(ref.forward(
+            dict(ref_config(cfg), **changed),
+            {k: jnp.asarray(v) for k, v in params.items()},
+            jnp.asarray(tokens, jnp.int32)))
+
+
+def _sequences(n, seed=0, lo=5, hi=14, n_decode=8):
+    rng = np.random.RandomState(seed)
+    return [(list(rng.randint(0, CFG.vocab, rng.randint(lo, hi))), n_decode)
+            for _ in range(n)]
+
+
+def run_paged(cfg, params, seqs, width=1, blocks=40, table_seed=5,
+              state_dtype=None):
+    """Every (prompt, n_decode) of ``seqs`` in its own lane through the
+    paged step and real pools, a shuffled block table and shuffled state
+    slots: the prompt one token a step (``width`` a step for the
+    multi-token step), then the step's own argmax.  -> per lane (tokens
+    fed, logits [n, vocab] of every position fed)."""
+    b = len(seqs)
+    kv = dm.cache_config(cfg, BS, blocks, state_slots=b + 3)
+    cache = kvc.PagedKVCache(kv)
+    maxb = cfg.max_seq // BS
+    rs = np.random.RandomState(table_seed)
+    order = iter(rs.permutation(np.arange(1, blocks)))
+    slots = rs.permutation(np.arange(1, b + 3))[:b].astype(np.int32)
+    tables = np.full((b, maxb), -1, np.int32)
+    total = [len(p) + n for p, n in seqs]
+    for i, t in enumerate(total):
+        for j in range(-(-t // BS)):
+            tables[i, j] = next(order)
+    make = dm.make_paged_step(cfg, kv) if width == 1 \
+        else dm.make_paged_step_multi(cfg, kv, width)
+    step = jax.jit(make, donate_argnums=(0,))
+    jparams = {k: jnp.asarray(v) for k, v in params.items()}
+    fed = [list(p) for p, _ in seqs]          # grows by the step's argmax
+    logits = [[] for _ in seqs]
+    while any(len(lg) < t for lg, t in zip(logits, total)):
+        tok = np.zeros((b, width), np.int32)
+        pos = np.zeros((b, width), np.int32)
+        lens = np.zeros((b, width), np.int32)
+        cols = []
+        for i in range(b):
+            at = len(logits[i])
+            n = max(min(width, len(fed[i]) - at, total[i] - at), 0)
+            # a recurrent state cannot skip a junk column: a lane feeds
+            # whole chunks of what it knows, or sits the step out
+            n = n if n == width else (n if width == 1 else 0)
+            cols.append(n)
+            for j in range(n):
+                tok[i, j] = fed[i][at + j]
+                pos[i, j] = at + j
+                lens[i, j] = at + j + 1
+        live = np.where(np.asarray(cols) > 0, slots, 0).astype(np.int32)
+        args = (tok, pos, tables, lens) if width > 1 \
+            else (tok[:, 0], pos[:, 0], tables, lens[:, 0])
+        carry, nxt, lg = step(cache.carry(), jparams, *args, live)
+        if state_dtype is not None:
+            # the control: the state rounded at every write
+            groups, (windows, states) = kv.groups(carry)
+            states = [s.astype(state_dtype).astype(s.dtype) for s in states]
+            carry = tuple(a for g in groups + [windows, states] for a in g)
+        cache.replace_carry(carry)
+        nxt = np.asarray(nxt).reshape(b, width)
+        lg = np.asarray(lg).reshape(b, width, -1)
+        for i, n in enumerate(cols):
+            for j in range(n):
+                logits[i].append(lg[i, j])
+            if n and len(logits[i]) == len(fed[i]) < total[i]:
+                fed[i].append(int(nxt[i, n - 1]))
+    return [(f, np.stack(lg)) for f, lg in zip(fed, logits)]
+
+
+def _worst(cfg, out, params, **changed):
+    return max(float(np.abs(lg - _ref_logits(cfg, params, toks,
+                                             **changed)).max())
+               for toks, lg in out)
+
+
+# -- 1. against the reference, and the reference broken ------------------------
+
+F32_OUT = {}
+
+
+def _f32_out():
+    if not F32_OUT:
+        F32_OUT["out"] = run_paged(CFG, PARAMS, _sequences(3))
+    return F32_OUT["out"]
+
+
+def test_f32_logits_equal_the_reference_at_every_position():
+    """Prefill token by token, then decode, three lanes of different
+    lengths in shuffled blocks and slots: every position's logits are the
+    reference's whole-sequence pass, and the decoded tokens are not the
+    tied head repeating its input."""
+    out = _f32_out()
+    assert _worst(CFG, out, PARAMS) < TOL_F32
+    toks, _lg = out[0]
+    assert len(set(toks[-8:])) > 2
+
+
+def test_group_of_four_equals_the_reference():
+    out = run_paged(CFG_G4, PARAMS_G4, _sequences(2, seed=4))
+    assert _worst(CFG_G4, out, PARAMS_G4) < TOL_F32
+
+
+def _zeroed(name):
+    return lambda p: dict(p, **{k: np.zeros_like(v) for k, v in p.items()
+                                if k.endswith(name)})
+
+
+BREAKS = {
+    # what the reference is told, against the block as served
+    "attention_multiplier": dict(attention_multiplier=1.0),
+    "residual_multiplier": dict(residual_multiplier=0.3),
+    "logits_scaling": dict(logits_scaling=4.0),
+    "embedding_multiplier": dict(embedding_multiplier=1.0),
+    "one_kv_head": dict(num_key_value_heads=4),
+}
+PARAM_BREAKS = {
+    # a reference that forgets: no decay (A_log -> -inf is decay 1: here
+    # A_log 0 is A = -1, another memory), no D skip, no convolution bias
+    "another_decay": _zeroed("A_log"), "no_skip": _zeroed("_D"),
+    "no_conv_bias": _zeroed("conv_b"),
+}
+
+
+@pytest.mark.parametrize("how", sorted(BREAKS))
+def test_f32_tolerance_catches_a_reference_told_otherwise(how):
+    if how == "one_kv_head":
+        # the reference reads K and V as 4 heads: only a shape it can split
+        params = dict(PARAMS)
+        with pytest.raises(Exception):
+            _worst(CFG, _f32_out()[:1], params, **BREAKS[how])
+        return
+    assert _worst(CFG, _f32_out()[:1], PARAMS, **BREAKS[how]) > 20 * TOL_F32
+
+
+@pytest.mark.parametrize("how", sorted(PARAM_BREAKS))
+def test_f32_tolerance_catches_a_forgetful_reference(how):
+    assert _worst(CFG, _f32_out()[:1], PARAM_BREAKS[how](PARAMS)) \
+        > 20 * TOL_F32
+
+
+def test_the_state_is_remembered_across_many_tokens():
+    """The check is not blind to a lost state: with Mamba-2's own start of
+    A_log and dt_bias a state reset half way moves the logits of every later
+    position (the reference on the second half alone differs from the
+    reference on the whole)."""
+    toks, _lg = _f32_out()[0]
+    whole = _ref_logits(CFG, PARAMS, toks)
+    cut = len(toks) // 2
+    # attention layers alone would also differ; so strip them from both
+    only_ssm = dict(ref_config(CFG), layer_types=["mamba"] * CFG.layers)
+    p = dict(PARAMS)
+    for l, kind in enumerate(CFG.layer_types):
+        if kind == "attention":
+            for k, v in gh.init_params(CFG.replace(
+                    layer_types=("mamba",) * CFG.layers), 7, 0.3).items():
+                if k.startswith("l%d_" % l):
+                    p[k] = v
+    with jax.default_matmul_precision("highest"):
+        fwd = lambda t: np.asarray(ref.forward(
+            only_ssm, {k: jnp.asarray(v) for k, v in p.items()},
+            jnp.asarray(t, jnp.int32)))
+        whole, tail = fwd(toks), fwd(toks[cut:])
+    assert np.abs(whole[cut + 6:] - tail[6:]).max() > 100 * TOL_F32
+    del whole
+
+
+def _rms(cfg, out, params):
+    sq = [np.square(lg - _ref_logits(cfg, params, toks)) for toks, lg in out]
+    return float(np.sqrt(sum(x.sum() for x in sq) / sum(x.size for x in sq)))
+
+
+def test_bf16_logits_within_tolerance_and_a_coarse_state_outside():
+    seqs = _sequences(3, seed=1, lo=16, hi=24, n_decode=24)
+    served = _rms(CFG16, run_paged(CFG16, PARAMS16, seqs), PARAMS16)
+    coarse = _rms(CFG16, run_paged(CFG16, PARAMS16, seqs,
+                                   state_dtype=jnp.float8_e4m3fn), PARAMS16)
+    assert served < RMS_BF16 < coarse
+
+
+# -- 2. paged against unpaged, one step against many ----------------------------
+
+@pytest.mark.parametrize("cfg,params", [(CFG, PARAMS), (CFG16, PARAMS16),
+                                        (CFG_G4, PARAMS_G4)],
+                         ids=["f32", "bf16", "group4"])
+def test_paged_is_bitwise_equal_to_unpaged(cfg, params):
+    (prompt, n), = _sequences(1, seed=2)
+    (fed, lg), = run_paged(cfg, params, [(prompt, n)])
+    toks, want = dm.unpaged_generate(cfg, params, prompt, n,
+                                     pad_len=cfg.max_seq,
+                                     return_logits=True)
+    assert fed[len(prompt):] == toks and len(set(toks)) > 2
+    assert np.array_equal(lg[len(prompt) - 1:len(prompt) - 1 + n],
+                          np.stack(want))
+
+
+def test_multi_token_step_equals_single():
+    """``width`` single steps composed in one call: the same logits, bit
+    for bit, for lanes fed in whole chunks (a recurrent state has no junk
+    columns to hide)."""
+    seqs = [(list(range(3, 12)), 0), (list(range(20, 26)), 0)]
+    single = run_paged(CFG, PARAMS, seqs)
+    multi = run_paged(CFG, PARAMS, seqs, width=3)
+    for (_f, a), (_g, b) in zip(single, multi):
+        assert np.array_equal(a, b)
+
+
+# -- 3. the engine ---------------------------------------------------------------
+
+@contextlib.contextmanager
+def _flags(**kv):
+    kv = {"FLAGS_" + k: v for k, v in kv.items()}
+    old = fluid.get_flags(list(kv))
+    fluid.set_flags(kv)
+    try:
+        yield
+    finally:
+        fluid.set_flags(old)
+
+
+@pytest.fixture(scope="module")
+def cache_dir(tmp_path_factory):
+    d = str(tmp_path_factory.mktemp("cc"))
+    old = fluid.get_flags(["FLAGS_compile_cache_dir"])
+    fluid.set_flags({"FLAGS_compile_cache_dir": d})
+    yield d
+    fluid.set_flags(old)
+
+
+@pytest.fixture()
+def telemetry_on():
+    fluid.set_flags({"FLAGS_telemetry": True})
+    _tm.reset()
+    yield
+    _tm.reset()
+    fluid.set_flags({"FLAGS_telemetry": False})
+
+
+def _engine(cfg, params, kv_blocks, buckets="4", **kw):
+    with _flags(kv_block_size=BS):
+        e = DecodeEngine(buckets=buckets, deadline_ms=60000.0)
+        e.add_model("hy", (cfg, params), kv_blocks=kv_blocks, **kw)
+    return e.start()
+
+
+def _alone(cfg, params, prompt, n):
+    return np.asarray(dm.unpaged_generate(cfg, params, prompt, n,
+                                          pad_len=cfg.max_seq), np.int32)
+
+
+def _counters(prefix):
+    return {k: v for k, v in _tm.snapshot()["counters"].items()
+            if k.startswith(prefix)}
+
+
+@pytest.mark.parametrize("cfg,params", [(CFG, PARAMS), (CFG16, PARAMS16)],
+                         ids=["f32", "bf16"])
+def test_lanes_move_up_and_slots_are_reused(cfg, params, cache_dir,
+                                            telemetry_on):
+    """Six requests over four lanes, lengths all different: sequences
+    finish mid-batch, later lanes move up a place, the waiting ones take the
+    freed slots (dirty: nothing clears them), and every request's tokens are
+    those of the sequence alone."""
+    e = _engine(cfg, params, 60)
+    try:
+        e.prewarm()
+        m = e._models["hy"]
+        assert e.spec("hy")["arch"] == "granite_hybrid"
+        assert e.spec("hy")["state_slots"] == 5 and m.prefix is None
+        miss0 = _tm.counter_total("executor_cache_miss_total")
+        prompts = [[3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5], [2, 7], [1, 8, 2, 8],
+                   [6], [9, 9, 8, 7, 6, 5], [4, 4]]
+        news = [5, 11, 3, 8, 7, 6]
+        with e._cond:
+            waits = [e.submit("hy", p, max_new_tokens=n, deadline_ms=60000.0)
+                     for p, n in zip(prompts, news)]
+        for p, n, w in zip(prompts, news, waits):
+            r = w.wait(timeout=120.0)
+            assert r is not None and r.status == "ok", r and r.error
+            assert np.array_equal(r.outputs["tokens"],
+                                  _alone(cfg, params, p, n)), p
+        assert m.cache.slots.in_use == 0
+        assert m.cache.allocator.in_use == 0
+        assert _tm.counter_total("executor_cache_miss_total") == miss0
+        # one reset a sequence: its first step starts the slot from zeros
+        assert _tm.counter_total("ssm_state_resets_total") == len(prompts)
+    finally:
+        e.stop()
+
+
+def test_preemption_recomputes_into_a_fresh_slot(cache_dir, telemetry_on):
+    """Capacity 3 blocks, A wants 3 and B 2: B is preempted, gives its
+    slot back with its blocks, and replays from position 0; both finish
+    with the tokens of the sequence alone."""
+    e = _engine(CFG, PARAMS, 4, buckets="2")
+    try:
+        with e._cond:
+            ra = e.submit("hy", [1, 2, 3, 4], max_new_tokens=8,
+                          deadline_ms=60000.0)
+            rb = e.submit("hy", [5, 6, 7, 8], max_new_tokens=4,
+                          deadline_ms=60000.0)
+        a, b = ra.wait(timeout=120.0), rb.wait(timeout=120.0)
+        assert a is not None and a.status == "ok", a and a.error
+        assert b is not None and b.status == "ok", b and b.error
+        assert np.array_equal(a.outputs["tokens"],
+                              _alone(CFG, PARAMS, [1, 2, 3, 4], 8))
+        assert np.array_equal(b.outputs["tokens"],
+                              _alone(CFG, PARAMS, [5, 6, 7, 8], 4))
+        assert _tm.counter_total("kv_block_evictions_total") >= 1
+        assert _tm.counter_total("ssm_state_resets_total") >= 3
+        assert e._models["hy"].cache.slots.in_use == 0
+    finally:
+        e.stop()
+
+
+def test_prefix_cache_declines_and_counts(cache_dir, telemetry_on):
+    """FLAGS_prefix_cache is on by default: for a model with recurrent
+    layers there is no index, each admission is counted under its reason,
+    and two requests with one prompt give the tokens of the prompt alone
+    (a hit would have started the second at pos 12 with no state)."""
+    assert fluid.get_flags(["FLAGS_prefix_cache"])["FLAGS_prefix_cache"]
+    e = _engine(CFG, PARAMS, 40)
+    try:
+        prompt = [3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5, 8, 9, 7]
+        want = _alone(CFG, PARAMS, prompt, 9)
+        for _ in range(2):
+            r = e.generate("hy", prompt, max_new_tokens=9,
+                           deadline_ms=60000.0)
+            assert r.status == "ok" and r.phases["cached_tokens"] == 0
+            assert np.array_equal(r.outputs["tokens"], want)
+        assert e.handoff_prefill_upto("hy", len(prompt)) == 0
+        assert _counters("prefix_cache_declined_total") == {
+            "prefix_cache_declined_total{model=hy,reason=recurrent_state}": 2}
+        assert not _counters("prefix_cache_hit_tokens_total")
+    finally:
+        e.stop()
+
+
+def test_speculation_is_refused(cache_dir):
+    draft = dm.truncate_decoder(CFG, PARAMS, layers=2)
+    assert draft[0].layer_types == PERIOD[:2]
+    with _flags(kv_block_size=BS):
+        e = DecodeEngine(buckets="2", deadline_ms=60000.0)
+        with pytest.raises(ValueError, match="recurrent"):
+            e.add_model("hy", (CFG, PARAMS), kv_blocks=16, draft=draft,
+                        speculative_k=2)
+        # without a draft there is nothing to speculate with: k is ignored
+        e.add_model("hy", (CFG, PARAMS), kv_blocks=16, speculative_k=2)
+        assert e.spec("hy")["speculative_k"] == 0
+
+
+def test_export_and_adoption_are_refused_with_their_reason(cache_dir,
+                                                           telemetry_on):
+    with _flags(session_migration=True):
+        e = _engine(CFG, PARAMS, 16, buckets="2")
+        try:
+            fault_injection.arm("serving.decode_step:delay:1")
+            streamed = threading.Event()
+            done = e.submit("hy", [1, 2, 3, 4, 5], max_new_tokens=40,
+                            deadline_ms=60000.0,
+                            on_token=lambda *a: streamed.set())
+            assert streamed.wait(60.0)
+            with pytest.raises(ValueError, match="recurrent_state"):
+                e.export_session(done.req_id)
+            fault_injection.disarm()
+            with e._cond:        # between steps: the carry is donated
+                block = e._models["hy"].cache.export_block(1)
+            assert e.adopt_kv_block("hy", "00" * 32, block) \
+                == "rejected:recurrent_state"
+            assert _counters("kv_migrate_refused_total") == {
+                "kv_migrate_refused_total{reason=recurrent_state}": 2}
+            r = done.wait(timeout=120.0)
+            assert r.status == "ok"
+            assert np.array_equal(r.outputs["tokens"],
+                                  _alone(CFG, PARAMS, [1, 2, 3, 4, 5], 40))
+        finally:
+            fault_injection.disarm()
+            e.stop()
+
+
+def test_step_span_and_gauge_carry_the_state(cache_dir, telemetry_on,
+                                             tmp_path):
+    """Traced, the step's span says how many lanes' state it moved and how
+    many bytes that is; the gauge holds the slots' bytes; untraced, the span
+    attributes are not computed."""
+    with _flags(tracing=True, telemetry_dir=str(tmp_path)):
+        e = _engine(CFG, PARAMS, 16, buckets="2")
+        try:
+            r = e.generate("hy", [1, 2, 3], max_new_tokens=4,
+                           deadline_ms=60000.0)
+            assert r.status == "ok"
+        finally:
+            e.stop()
+        _trc.flush()
+    import json
+    spans = [json.loads(line) for fn in os.listdir(tmp_path)
+             if fn.startswith("trace-")
+             for line in open(os.path.join(tmp_path, fn))]
+    steps = [s["attrs"] for s in spans
+             if s.get("name") == "serving.decode_step"]
+    per_slot = len(CFG.ssm_layers) * (3 * (128 + 64) * 4 + 32 * 128 * 4)
+    assert steps and all(s["ssm_state_lanes"] == 1
+                         and s["ssm_state_bytes"] == per_slot for s in steps)
+    gauges = _tm.snapshot()["gauges"]
+    assert gauges["ssm_state_bytes{model=hy}"] == 3 * per_slot
+
+
+# -- 4. the manager: layers by kind, bytes, budget -------------------------------
+
+def test_cache_describes_layers_by_kind():
+    kv = dm.cache_config(CFG, BS, 16, state_slots=5)
+    assert (kv.layers, kv.heads, kv.head_dim) == (2, 2, 16)
+    assert kv.state_layers == 6 and kv.state_slots == 5
+    assert kv.state_shapes == (((3 * (128 + 64),), "f32"),
+                               ((32, 128), "f32"))
+    cache = kvc.PagedKVCache(kv)
+    carry = cache.carry()
+    groups, (windows, states) = kv.groups(carry)
+    assert [len(g) for g in groups] == [2, 2]
+    assert all(a.shape == (16, BS, 32) for g in groups for a in g)
+    assert len(windows) == len(states) == 6
+    assert all(w.shape == (5, 576) for w in windows)
+    assert all(s.shape == (5, 32, 128) and s.dtype == jnp.float32
+               for s in states)
+    assert cache.kv_nbytes == 2 * 2 * 16 * BS * 32 * 4
+    assert kvc.state_bytes(kv) == 5 * 6 * (576 + 32 * 128) * 4
+    assert cache.nbytes == cache.kv_nbytes + kvc.state_bytes(kv)
+    assert kvc.engine_owned_kv_bytes() >= cache.nbytes
+    with pytest.raises(ValueError, match="carry"):
+        kv.groups(carry[:-1])
+    # a bf16 model keeps its window in bf16 and its state in float32
+    kv16 = dm.cache_config(CFG16, BS, 16, state_slots=5)
+    assert [dt for _s, dt in kv16.state_shapes] == ["bf16", "f32"]
+
+
+def test_published_sizes_give_the_issues_bytes():
+    """At the published widths: 8,192 B of K and V a token over 4 layers,
+    268,435,456 B for 2048 blocks, and 76,437,504 B of state a sequence."""
+    cfg = dm.DecoderConfig(
+        arch="granite_hybrid", vocab=100352, layers=40, heads=32, kv_heads=8,
+        head_dim=64, ffn=8192, max_seq=2048, dtype="bf16",
+        layer_types=[("attention" if l % 10 == 5 else "mamba")
+                     for l in range(40)],
+        ssm_heads=64, ssm_head_dim=64, ssm_state=128, ssm_conv=4)
+    kv = dm.cache_config(cfg, 16, 2048, state_slots=33)
+    assert kvc.block_bytes(kv) * 2048 == 268435456
+    assert kvc.slot_bytes(kv) == 76437504
+    assert kvc.state_bytes(kv) == 33 * 76437504
+    shapes = gh.param_shapes(cfg)
+    assert sum(int(np.prod(s)) for s, _k in shapes.values()) == 3191396096
+
+
+def test_budget_gate_counts_the_state():
+    kv = dm.cache_config(CFG, BS, 2, state_slots=5)
+    per, state = kvc.block_bytes(kv), kvc.state_bytes(kv)
+    n, capped = kvc.plan_num_blocks(kv, model_resident_bytes=1000,
+                                    requested=64,
+                                    budget=1000 + state + 10 * per)
+    assert (n, capped) == (10, True)
+    with pytest.raises(ValueError, match="state bytes"):
+        kvc.plan_num_blocks(kv, model_resident_bytes=1000, requested=64,
+                            budget=1000 + state + per)
+    # the same budget without recurrent layers fits the state's worth more
+    plain = kvc.KVCacheConfig(kv.layers, kv.heads, kv.head_dim, BS, 2)
+    n2, _ = kvc.plan_num_blocks(plain, model_resident_bytes=1000,
+                                requested=0, budget=1000 + state + 10 * per)
+    assert n2 == 10 + state // per
+
+
+def test_slot_allocator_is_loud():
+    slots = kvc.SlotAllocator(3)
+    a, b = slots.take(), slots.take()
+    assert {a, b} == {1, 2} and slots.in_use == 2 and slots.capacity == 2
+    with pytest.raises(RuntimeError, match="no recurrent-state slot"):
+        slots.take()
+    slots.give(a)
+    with pytest.raises(ValueError, match="not held"):
+        slots.give(a)
+    assert slots.take() == a
+
+
+@pytest.mark.parametrize("arch", ["gpt2", "olmoe"])
+def test_attention_only_carries_are_as_they_were(arch):
+    """The GPT-2 and OLMoE carries: K then V, a pool a layer, and nothing
+    after them; their steps take no slots."""
+    cfg = dm.DecoderConfig(vocab=50, layers=3, heads=2, head_dim=16,
+                           max_seq=32) if arch == "gpt2" else \
+        dm.DecoderConfig(arch="olmoe", vocab=50, layers=3, heads=2,
+                         head_dim=16, ffn=16, max_seq=32, experts=4,
+                         experts_per_token=2)
+    kv = dm.cache_config(cfg, BS, 8, state_slots=5)
+    assert (kv.layers, kv.heads, kv.state_layers, kv.state_shapes) \
+        == (3, 2, 0, ())
+    cache = kvc.PagedKVCache(kv)
+    assert cache.slots is None and kvc.state_bytes(kv) == 0
+    carry = cache.carry()
+    assert len(carry) == 6 and all(a.shape == (8, BS, 32) for a in carry)
+    (k, v), state = kv.groups(carry)
+    assert state == [] and k == list(carry[:3]) and v == list(carry[3:])
+    params = {n: jnp.asarray(a)
+              for n, a in dm.init_decoder_params(cfg, 0).items()}
+    tables = np.full((2, 8), -1, np.int32)
+    tables[0, 0] = 3
+    out = jax.jit(dm.make_paged_step(cfg, kv))(
+        carry, params, np.array([5, 0]), np.array([0, 0]), tables,
+        np.array([1, 0]))
+    assert len(out[0]) == 6 and out[1].shape == (2,)
+    # and the engine plans no slot for them
+    with _flags(kv_block_size=BS):
+        e = DecodeEngine(buckets="2", deadline_ms=60000.0)
+        e.add_model("m", (cfg, dm.init_decoder_params(cfg, 0)), kv_blocks=8)
+    assert e.spec("m")["state_slots"] == 0
+
+
+def test_config_refuses_what_no_block_computes():
+    with pytest.raises(ValueError, match="layer_types"):
+        CFG.replace(layer_types=PERIOD)                  # 4 names, 8 layers
+    with pytest.raises(ValueError, match="layer_types"):
+        CFG.replace(layer_types=("window",) * 8)
+    with pytest.raises(ValueError, match="multiple of kv_heads"):
+        CFG.replace(kv_heads=3)
+    with pytest.raises(ValueError, match="multi-head"):
+        dm.DecoderConfig(vocab=50, layers=2, heads=4, head_dim=16,
+                         kv_heads=2)
+    with pytest.raises(ValueError, match="ssm_heads"):
+        CFG.replace(ssm_state=0)
+
+
+def test_bundle_roundtrip(tmp_path):
+    d = dm.save_decoder(str(tmp_path / "hy"), CFG16, PARAMS16)
+    cfg, params = dm.load_decoder(d)
+    assert cfg.to_dict() == CFG16.to_dict()
+    assert cfg.layer_types == CFG.layer_types
+    assert all(np.array_equal(np.asarray(params[k]).view(np.uint16),
+                              np.asarray(v).view(np.uint16))
+               for k, v in PARAMS16.items())
+
+
+# -- 5. the kernels under the interpreter ----------------------------------------
+
+@pytest.fixture()
+def interpreted(monkeypatch):
+    monkeypatch.setenv("PADDLE_PALLAS_INTERPRET", "1")
+    adoption.reset()
+    yield
+    adoption.reset()
+
+
+def _pools(heads, kv_heads, dtype, seed=0):
+    r = np.random.default_rng(seed)
+    blocks, bs, d, lanes, maxb = 12, 16, 64, 3, 4
+    q = jnp.asarray(r.standard_normal((lanes, heads, d)), jnp.float32)
+    k, v = (jnp.asarray(r.standard_normal((blocks, bs, kv_heads * d)),
+                        jnp.float32).astype(dtype) for _ in range(2))
+    tables = np.full((lanes, maxb), -1, np.int32)
+    tables[0, :3] = [5, 2, 9]
+    tables[1, :1] = [7]
+    lens = np.array([40, 9, 0], np.int32)        # the third lane idle
+    return q, k, v, jnp.asarray(tables), jnp.asarray(lens)
+
+
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("heads,kv_heads", [(8, 2), (4, 4), (8, 4)],
+                         ids=["group4", "group1", "group2"])
+def test_attention_kernel_equals_the_gather(interpreted, heads, kv_heads,
+                                            dtype):
+    """The kernel under the interpreter against the gather path: grouped
+    queries (row r reads KV head r // group) and the multi-head case it had,
+    with the scale as an argument."""
+    q, k, v, tables, lens = _pools(heads, kv_heads, dtype)
+    assert pa.attention_path(q.shape, k.shape, k.dtype) == "pallas"
+    for scale in (None, 0.015625):
+        got = pa._paged_pallas(q, k, v, tables, lens, scale)
+        want = pa.paged_attention_reference(q, k, v, tables, lens, scale)
+        assert got.shape == want.shape == q.shape
+        # the idle lane: zeros from the kernel, a uniform softmax over
+        # masked scores from the gather; nothing reads either
+        np.testing.assert_allclose(np.asarray(got[:2]), np.asarray(want[:2]),
+                                   atol=2e-5 if dtype == jnp.float32
+                                   else 2e-2)
+        assert not np.asarray(got[2]).any()
+
+
+def test_grouped_attention_is_attention_over_repeated_heads():
+    """masked_attention with 8 query heads over 2 KV heads is multi-head
+    attention over K and V repeated four times."""
+    r = np.random.default_rng(1)
+    q = jnp.asarray(r.standard_normal((2, 8, 16)), jnp.float32)
+    k, v = (jnp.asarray(r.standard_normal((2, 12, 2, 16)), jnp.float32)
+            for _ in range(2))
+    lens = jnp.asarray([12, 5])
+    got = pa.masked_attention(q, k, v, lens, 0.3)
+    want = pa.masked_attention(q, jnp.repeat(k, 4, axis=2),
+                               jnp.repeat(v, 4, axis=2), lens, 0.3)
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want), atol=1e-6)
+    # and the default scale is the one it always had
+    assert np.array_equal(
+        np.asarray(pa.masked_attention(q, k, v, lens)),
+        np.asarray(pa.masked_attention(q, k, v, lens, 0.25)))
+
+
+@pytest.mark.parametrize("inner", [256, 128], ids=["two_chunks", "one"])
+def test_state_update_kernel_equals_the_gather(interpreted, monkeypatch,
+                                               inner):
+    """The state-update kernel under the interpreter against gather, update
+    and scatter: the lanes' slots moved one token in place, the others
+    untouched, a fresh lane started from zeros, bit for bit."""
+    monkeypatch.setattr(su, "COLUMNS", 128)
+    r = np.random.default_rng(0)
+    slots_n, n, lanes = 6, 16, 4
+    f = lambda *shape: jnp.asarray(r.standard_normal(shape), jnp.float32)
+    pool = f(slots_n, n, inner)
+    slots = jnp.asarray([3, 5, 1, 0], jnp.int32)
+    fresh = jnp.asarray([False, True, False, True])
+    decay = jnp.asarray(r.uniform(0.2, 1.0, (lanes, inner)), jnp.float32)
+    args = (slots, fresh, decay, f(lanes, inner), f(lanes, n), f(lanes, n))
+    assert all(ok for _r, ok in su.ssm_update_checks(pool.shape, pool.dtype,
+                                                     lanes))
+    want_pool, want_y = jax.jit(su.state_update_reference)(pool, *args)
+    got_pool, got_y = jax.jit(su.state_update)(pool, *args)
+    assert adoption.active_kernels() == ["ssm_update"]
+    assert np.array_equal(np.asarray(got_y), np.asarray(want_y))
+    live = [1, 3, 5, 2, 4]                               # all but the scratch
+    assert np.array_equal(np.asarray(got_pool)[live],
+                          np.asarray(want_pool)[live])
+    assert np.array_equal(np.asarray(got_pool)[[2, 4]],
+                          np.asarray(pool)[[2, 4]])
+
+
+def test_paged_step_with_both_kernels_equals_the_gather_step(interpreted):
+    """The whole hybrid step with both kernels interpreted: the tokens and
+    logits of the jnp step."""
+    cfg = CFG.replace(head_dim=64, heads=4, kv_heads=2, ssm_head_dim=16)
+    params = gh.init_params(cfg, seed=5, std=0.1)
+    seqs = [([3, 1, 4, 1, 5], 4), ([9, 2], 5)]
+    # 16-token blocks: the attention kernel's sublane tile
+    global BS
+    old, BS = BS, 16
+    try:
+        with_kernels = run_paged(cfg, params, seqs, blocks=12)
+        assert set(adoption.active_kernels()) == {"paged_attention",
+                                                  "ssm_update"}
+        os.environ.pop("PADDLE_PALLAS_INTERPRET")
+        plain = run_paged(cfg, params, seqs, blocks=12)
+    finally:
+        BS = old
+    for (fa, la), (fb, lb) in zip(with_kernels, plain):
+        assert fa == fb
+        np.testing.assert_allclose(la, lb, atol=1e-5)

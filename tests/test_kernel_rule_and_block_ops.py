@@ -37,6 +37,7 @@ from paddle_tpu.pallas_kernels import adoption
 from paddle_tpu.pallas_kernels import fused_ln
 from paddle_tpu.pallas_kernels.flash_attention import flash_attention_checks
 from paddle_tpu.pallas_kernels import paged_attention as pa
+from paddle_tpu.pallas_kernels import ssm_update
 
 _FLAGS = ("FLAGS_deterministic_reduction", "FLAGS_telemetry")
 
@@ -83,6 +84,9 @@ _ELIGIBLE = {
         (2, 4, 1024, 64), (2, 4, 1024, 64), None),
     "paged_attention": lambda: pa.paged_attention_checks(
         (4, 16, 64), (64, 16, 1024), "float32"),
+    # granite-4.0-h-micro's state slots: 33 of [128, 64 heads x 64]
+    "ssm_update": lambda: ssm_update.ssm_update_checks(
+        (33, 128, 4096), "float32", 32),
 }
 
 RULE = {
@@ -106,6 +110,25 @@ RULE = {
             (2, 4, 1024, 64), (2, 4, 1088, 64), None), True, "blocks"),
     "flash_attention-backend": (
         "flash_attention", _ELIGIBLE["flash_attention"], False, "backend"),
+    "paged_attention-grouped_lanes": (
+        # 32 query heads over a pool of 3 KV heads of 64: 192 columns
+        "paged_attention", lambda: pa.paged_attention_checks(
+            (4, 32, 64), (64, 16, 192), "bfloat16"), True, "lanes"),
+    "paged_attention-uneven_groups": (
+        # 6 query heads cannot divide over 4 KV heads
+        "paged_attention", lambda: pa.paged_attention_checks(
+            (4, 6, 64), (64, 16, 256), "bfloat16"), True, "lanes"),
+    "ssm_update-backend": (
+        "ssm_update", _ELIGIBLE["ssm_update"], False, "backend"),
+    "ssm_update-dtype": (
+        "ssm_update", lambda: ssm_update.ssm_update_checks(
+            (33, 128, 4096), "bfloat16", 32), True, "dtype"),
+    "ssm_update-lanes": (
+        "ssm_update", lambda: ssm_update.ssm_update_checks(
+            (33, 128, 4096 + 64), "float32", 32), True, "lanes"),
+    "ssm_update-sublanes": (
+        "ssm_update", lambda: ssm_update.ssm_update_checks(
+            (33, 100, 4096), "float32", 32), True, "sublanes"),
 }
 for _family in adoption.KERNELS:
     for _kind in ("gspmd_mesh", "shape_inference"):

@@ -384,7 +384,8 @@ def test_cache_reserves_scratch_block_and_carry_shapes():
     assert len(carry) == 2 * 2
     assert all(a.shape == (8, 4, 2 * 8) and str(a.dtype) == "float32"
                for a in carry)
-    k, v = kv_cache.carry_groups(carry, 2)
+    (k, v), state = c.config.groups(carry)
+    assert state == []
     assert len(k) == len(v) == 2
     assert c.blocks_for_tokens(1) == 1
     assert c.blocks_for_tokens(4) == 1
@@ -394,7 +395,7 @@ def test_cache_reserves_scratch_block_and_carry_shapes():
 
 def test_cache_int8_carry_has_scales():
     c = PagedKVCache(_cfg(dtype="int8"))
-    k, v, ks, vs = kv_cache.carry_groups(c.carry(), 2)
+    (k, v, ks, vs), _state = c.config.groups(c.carry())
     assert all(str(a.dtype) == "int8" and a.shape == (8, 4, 16)
                for a in k + v)
     assert all(str(a.dtype) == "float32" and a.shape == (8, 4, 2)

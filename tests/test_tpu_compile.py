@@ -210,3 +210,62 @@ def test_decode_step_reads_the_pool_through_the_kernel_on_v5e(
     found = [line.strip()[:160] for line in text.splitlines()
              if big.search(line)]
     assert not found, found
+
+
+def test_granite_hybrid_step_updates_both_pools_in_place(one_chip, as_on_tpu):
+    """granite-4.0-h-micro's published widths (32 query heads over 8 KV
+    heads of 64, 64 state-space heads of 64 with state 128), its first 6
+    layers (5 mamba, 1 attention), bucket 32, the cell's pools (2048 bf16
+    blocks 512 wide, 33 state slots): Mosaic accepts the grouped-query
+    paged-attention kernel and the state-update kernel inside the whole
+    step, both pools are aliased whole, and beside its arguments the step
+    holds nothing of a state pool's size (PERF.md section 6, PR 31: XLA's
+    form of the update made a whole-pool pass a layer and 0.15e9 bytes of
+    temporaries)."""
+    from benchmark.models import granite_hybrid_decoder
+
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "granite-4.0-h-micro-serve.json")) as fp:
+        config = json.load(fp)
+    config = dict(config, num_hidden_layers=6,
+                  layer_types=config["layer_types"][:6])
+    cfg = granite_hybrid_decoder.decoder_config(config)
+    assert (cfg.heads, cfg.kv_heads, cfg.head_dim, cfg.ssm_heads,
+            cfg.ssm_head_dim, cfg.ssm_state, cfg.attention_multiplier) \
+        == (32, 8, 64, 64, 64, 128, 0.015625)
+    lanes, block_size, blocks = 32, 16, 2048
+    kv = dm.cache_config(cfg, block_size, blocks, state_slots=lanes + 1)
+    assert dm.attention_path(cfg, kv, lanes) == "pallas"
+
+    on_chip = functools.partial(_placed, one_chip)
+
+    carry = on_chip(jax.eval_shape(lambda: PagedKVCache(kv).carry()))
+    params = on_chip({
+        name: jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+        for name, (shape, _kind)
+        in granite_hybrid_decoder.param_shapes(config).items()})
+    feeds = on_chip([
+        jax.ShapeDtypeStruct((lanes,), jnp.int32),
+        jax.ShapeDtypeStruct((lanes,), jnp.int32),
+        jax.ShapeDtypeStruct((lanes, cfg.max_seq // block_size), jnp.int32),
+        jax.ShapeDtypeStruct((lanes,), jnp.int32),
+        jax.ShapeDtypeStruct((lanes,), jnp.int32)])
+    compiled = jax.jit(dm.make_paged_step(cfg, kv), donate_argnums=(0,)
+                       ).lower(carry, params, *feeds).compile()
+
+    text = compiled.as_text()
+    assert _kernel_calls(text) == 6            # 1 attention, 5 state updates
+    assert len(re.findall(r"%ssm_state_update\S* = ", text)) == 5
+    pool_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in carry)
+    state_pool = (lanes + 1) * 128 * 4096 * 4
+    assert pool_bytes == 2 * 2048 * 16 * 512 * 2 + 5 * (
+        state_pool + 33 * 13056 * 2)
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= pool_bytes
+    assert memory.temp_size_in_bytes < state_pool / 2
+    big = re.compile(
+        r" = f32\[(33|32),128,(4096|2048)\]\S* "
+        r"(copy|select|transpose|slice|dynamic-slice|gather|scatter|fusion)\(")
+    found = [line.strip()[:160] for line in text.splitlines()
+             if big.search(line)]
+    assert not found, found

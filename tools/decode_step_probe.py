@@ -19,6 +19,13 @@ writes under ``chiprun_out/``, one JSON object:
     chiprun -- python tools/decode_step_probe.py --config olmoe-1b-7b-serve \
         --blocks 2048
 
+For a configuration with state-space layers (``--config
+granite-4.0-h-micro-serve --blocks 2048``) every lane holds a state slot,
+the scopes gain ``layerN/ssm/in_proj``, ``conv``, ``state_update`` and
+``out_proj``, and the result gives ``ssm/state_update``'s achieved bytes/s
+(``benchmark/ssm_cost.py``'s state traffic over the scope's device time);
+``--ssm-update xla`` swaps the kernel for the gather, update and scatter the
+step makes of it off the TPU: the comparison the kernel was adopted by.
 For a routed-expert configuration the result also gives ``moe/experts``'
 achieved bytes/s (``benchmark/moe_cost.py`` over the scope's device time),
 and ``--experts ragged`` swaps the block's expert matmuls for this file's
@@ -57,7 +64,9 @@ def hlo_index(text):
     module's entry computation and fusions."""
     out = {}
     for line in text.splitlines():
-        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (\S+) ([\w\-]+)\(", line)
+        # a result is one shape, or (a kernel's) a tuple of them
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) "
+                     r"([\w\-]+)\(", line)
         if not m:
             continue
         scope = re.search(r'op_name="([^"]*)"', line)
@@ -72,7 +81,8 @@ def scope_of(op_name):
     parts = [re.sub(r"^layer\d+$", "layerN", p) for p in parts]
     keep = [p for p in parts
             if p in ("layerN", "attn", "mlp", "moe", "router", "experts",
-                     "lm_head", "kv_write", "kv_read", "kv_gather")]
+                     "lm_head", "kv_write", "kv_read", "kv_gather",
+                     "ssm", "in_proj", "conv", "state_update", "out_proj")]
     return "/".join(keep) or "other"
 
 
@@ -128,7 +138,7 @@ def check_attention(cfg, kv, tables, lens, seed, repeat=24):
     from paddle_tpu.serving import kv_cache as kvc
 
     dtype = kvc._PAYLOAD[kv.dtype][0]
-    shape = (kv.num_blocks, kv.block_size, cfg.hidden)
+    shape = (kv.num_blocks, kv.block_size, kv.heads * kv.head_dim)
     kq, kk, kv_ = jax.random.split(jax.random.PRNGKey(seed % (2 ** 31)), 3)
     q = jax.random.normal(kq, (len(lens), cfg.heads, cfg.head_dim),
                           jnp.float32)
@@ -136,15 +146,18 @@ def check_attention(cfg, kv, tables, lens, seed, repeat=24):
     v_pool = jax.random.normal(kv_, shape, jnp.float32).astype(dtype)
     tables, lens = jnp.asarray(tables), jnp.asarray(lens)
 
+    scale = cfg.attention_multiplier
     paths = {
-        "kernel": pa._paged_pallas,
-        "gather": pa.paged_attention_reference,
+        "kernel": lambda q, k, v, t, n: pa._paged_pallas(q, k, v, t, n,
+                                                         scale),
+        "gather": lambda q, k, v, t, n: pa.paged_attention_reference(
+            q, k, v, t, n, scale),
         # float32 mathematics on the values as stored
         "exact": lambda q, k, v, t, n: pa.paged_attention_reference(
-            q, k.astype(jnp.float32), v.astype(jnp.float32), t, n),
+            q, k.astype(jnp.float32), v.astype(jnp.float32), t, n, scale),
         # every product of bfloat16 operands
         "bf16_products": lambda q, k, v, t, n: pa.paged_attention_reference(
-            q, k.astype(jnp.bfloat16), v.astype(jnp.bfloat16), t, n),
+            q, k.astype(jnp.bfloat16), v.astype(jnp.bfloat16), t, n, scale),
     }
     rest = (k_pool, v_pool, tables, lens)
     out, ms = {}, {}
@@ -182,6 +195,9 @@ def main(argv=None):
                     "under benchmark/configs/ or a path to such a file")
     ap.add_argument("--experts", default="block",
                     choices=("block", "ragged"))
+    ap.add_argument("--ssm-update", default="step", choices=("step", "xla"),
+                    help="xla: the state update as gather, update, scatter "
+                    "(what the step does off the TPU) in place of the kernel")
     ap.add_argument("--blocks", type=int, default=1024)
     ap.add_argument("--bucket", type=int, default=32)
     ap.add_argument("--block-size", type=int, default=16)
@@ -201,7 +217,7 @@ def main(argv=None):
     import jax
     import numpy as np
 
-    from benchmark import moe_cost, trace_reduce
+    from benchmark import moe_cost, ssm_cost, trace_reduce
     from benchmark.run import load_module
     import paddle_tpu as fluid
     from paddle_tpu.core import telemetry
@@ -224,9 +240,14 @@ def main(argv=None):
         from paddle_tpu.models import olmoe
 
         olmoe._experts = experts_ragged(cfg.experts_per_token)
+    if args.ssm_update == "xla":
+        from paddle_tpu.pallas_kernels import ssm_update
+
+        ssm_update.state_update = ssm_update.state_update_reference
     args.dtype = args.dtype or cfg.kv_dtype or "f32"
-    kv = kvc.KVCacheConfig(cfg.layers, cfg.heads, cfg.head_dim,
-                           args.block_size, args.blocks, args.dtype)
+    # a state slot a lane and the scratch, for a model with recurrent layers
+    kv = dm.cache_config(cfg, args.block_size, args.blocks, args.dtype,
+                         state_slots=args.bucket + 1)
     cache = kvc.PagedKVCache(kv)
     b, maxb = args.bucket, cfg.max_seq // args.block_size
 
@@ -268,8 +289,9 @@ def main(argv=None):
     fluid.set_flags({"FLAGS_telemetry": True})
     stepfn = CarriedStepFn(dm.make_paged_step(cfg, kv), donate_argnums=(0,),
                            name="probe")
+    slots = (np.arange(1, b + 1, dtype=np.int32),) if cfg.ssm_layers else ()
     feed = lambda n: (cache.carry(), params, tok, lens + n - 1, tables,
-                      lens + n)
+                      lens + n) + slots
     warm = stepfn.warmup(*feed(0))
     compiled = stepfn._compiled[stepfn._sig(feed(0))]
     memory = compiled.memory_analysis()
@@ -279,15 +301,22 @@ def main(argv=None):
         with open(args.hlo_out, "w") as fp:
             fp.write(text)
     index = hlo_index(text)
-    pool_elems = args.blocks * args.block_size * cfg.hidden
+    # one layer's K (or V) pool, or a recurrent layer's state slots,
+    # whichever is smaller: a copy of either is what the search is for
+    pool_elems = args.blocks * args.block_size * kv.heads * kv.head_dim
+    if cfg.ssm_layers:
+        pool_elems = min(pool_elems,
+                         kv.state_slots * cfg.ssm_state * cfg.ssm_inner)
     result = {
         "label": args.label, "config": config["name"],
-        "experts": args.experts, "device": device.device_kind,
+        "experts": args.experts, "ssm_update": args.ssm_update,
+        "device": device.device_kind,
         "platform": device.platform, "blocks": args.blocks,
         "bucket": b, "dtype": args.dtype, "layers": cfg.layers,
         "memory": {"temp_bytes": int(memory.temp_size_in_bytes),
                    "alias_bytes": int(memory.alias_size_in_bytes),
-                   "pool_bytes": cache.nbytes,
+                   "pool_bytes": cache.kv_nbytes,
+                   "state_bytes": kvc.state_bytes(kv),
                    "compile_ms": round(warm["compile_ms"], 1),
                    "pool_sized_instructions": pool_sized(index, pool_elems)},
         "attention": dm.attention_path(cfg, kv, b),
@@ -339,6 +368,14 @@ def main(argv=None):
                 config, config["num_experts"])
             result["moe_experts_bytes_per_step"] = moved
             result["moe_experts_bytes_per_s"] = moved / (moe_ms / 1e3)
+        ssm_ms = sum(v for k, v in scopes.items()
+                     if k.endswith("ssm/state_update"))
+        if cfg.ssm_layers and ssm_ms:
+            # every lane's state in every mamba layer, read and written
+            # once a step, over the scope's time
+            moved = ssm_cost.state_traffic_bytes_per_step(config, b)
+            result["ssm_state_bytes_per_step"] = moved
+            result["ssm_state_update_bytes_per_s"] = moved / (ssm_ms / 1e3)
         stats = device.memory_stats() or {}
         result["peak_bytes_in_use"] = stats.get("peak_bytes_in_use")
         result["peak_bytes_reserved"] = stats.get("peak_bytes_reserved")
