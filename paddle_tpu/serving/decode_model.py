@@ -70,7 +70,7 @@ from . import kv_cache as _kv
 __all__ = ["DecoderConfig", "init_decoder_params", "save_decoder",
            "load_decoder", "is_decoder_dir", "has_draft", "load_draft",
            "truncate_decoder", "attention_path", "make_paged_step",
-           "make_paged_step_multi",
+           "make_fed_step", "make_paged_step_multi",
            "make_draft_rollout", "make_unpaged_step", "unpaged_generate",
            "cache_config"]
 
@@ -562,6 +562,35 @@ def make_paged_step(cfg, kv_config):
                 logits) + tuple(extras)
 
     return step
+
+
+def make_fed_step(cfg, kv_config, feed_width):
+    """-> step(kv_carry, params, tok, prev_next, src, pos, block_tables,
+    context_lens[, state_slots]): ``make_paged_step``'s step with each
+    lane's input token chosen on the device, in the same executable.
+
+    ``prev_next`` is the ``next_tokens`` of the step before, int32
+    [feed_width], as that step returned them: a device array the host need
+    not have read.  Lane ``b`` feeds ``prev_next[src[b]]`` where ``src[b]
+    >= 0`` (the lane that produced its token a step ago) and the host's
+    ``tok[b]`` where it is -1: a prompt or replayed token, a lane's first
+    step, an idle lane, the very first step.  So the engine can dispatch
+    step n+1 before it has fetched step n's tokens.  ``next_tokens`` comes
+    back padded to ``feed_width`` (the engine's largest lane bucket), so it
+    feeds the next step whatever the two steps' buckets; lane ``b``'s token
+    is still at index ``b``.  Everything else is the inner step's."""
+    step = make_paged_step(cfg, kv_config)
+
+    def fed(kv_carry, params, tok, prev_next, src, pos, block_tables,
+            context_lens, state_slots=None):
+        src = src.astype(jnp.int32)
+        tok = jnp.where(src >= 0, prev_next[jnp.maximum(src, 0)],
+                        tok.astype(jnp.int32))
+        carry, nxt, *rest = step(kv_carry, params, tok, pos, block_tables,
+                                 context_lens, state_slots)
+        return (carry, jnp.pad(nxt, (0, feed_width - nxt.shape[0])), *rest)
+
+    return fed
 
 
 # -- multi-token paged step (speculative verify / prefill chunks) ------------

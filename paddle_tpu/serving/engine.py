@@ -772,12 +772,18 @@ class _DecodeSeq:
     Prefill is token-feed: the prompt is fed one token per step through
     the SAME bucketed step executable as generation, so mixed-phase
     batches never force a second compiled shape.  ``n_fed`` counts
-    positions already written to the KV cache; once it passes the last
-    prompt position every step's argmax is a generated token."""
+    positions whose step the host has read back (confirmed: written to
+    the KV cache, their token taken); once it passes the last prompt
+    position every step's argmax is a generated token.  ``n_disp`` counts
+    positions a step has been *dispatched* for: ``n_fed`` or, while the
+    one-ahead loop holds that step's tokens unread on the device,
+    ``n_fed + 1``.  The loop plans from ``n_disp`` (position, block, slot
+    and context length need no token's value) and everything that reads
+    the sequence's history goes by ``n_fed``."""
 
     __slots__ = ("pending", "prompt", "max_new", "eos_id", "on_token",
                  "blocks", "table", "draft_blocks", "draft_table",
-                 "state_slot", "n_fed", "next_tok", "out",
+                 "state_slot", "n_fed", "n_disp", "out",
                  "t_admit", "t_first", "token_times", "admit_seq",
                  "aborted", "hashes", "published", "cached_tokens",
                  "handoff", "prefill_upto",
@@ -798,7 +804,7 @@ class _DecodeSeq:
         # them): taken at admission, given back with the blocks
         self.state_slot = None
         self.n_fed = 0
-        self.next_tok = self.prompt[0]
+        self.n_disp = 0
         self.out = []
         self.t_admit = None
         self.t_first = None                   # first *generated* token
@@ -836,9 +842,16 @@ class _DecodeSeq:
     def in_prefill(self):
         return self.n_fed < self.replay_upto
 
+    @property
+    def known(self):
+        """Positions whose input token the host holds: ``prompt ++ out``
+        (one past ``n_fed`` in steady decode: the last token emitted is
+        fed by the next step)."""
+        return len(self.prompt) + len(self.out)
+
     def feed_tok(self, i):
-        """Token fed at position ``i`` during replay — the history
-        ``prompt ++ out`` (valid for every ``i < replay_upto``)."""
+        """Token fed at position ``i`` — the history ``prompt ++ out``
+        (valid for every ``i < known``)."""
         p = len(self.prompt)
         return self.prompt[i] if i < p else self.out[i - p]
 
@@ -848,6 +861,14 @@ class _DecodeSeq:
     @property
     def total(self):
         return len(self.prompt) + self.max_new
+
+    @property
+    def feed_limit(self):
+        """Positions this replica dispatches a step for: up to the
+        hand-off boundary for a prefill-role sequence, else all but the
+        last token's (``max_new`` is known at dispatch: the step at
+        ``total - 2`` yields the last token, which is never fed)."""
+        return self.prefill_upto if self.handoff else self.total - 1
 
     def reset_for_recompute(self):
         """Preempted (or an aborted migration hand-off): blocks were
@@ -867,7 +888,7 @@ class _DecodeSeq:
         self.draft_blocks = []
         self.draft_table.fill(-1)
         self.n_fed = 0
-        self.next_tok = self.prompt[0]
+        self.n_disp = 0
         self.replay_upto = len(self.prompt) + len(self.out)
         self.t_first = None
         self.token_times = []
@@ -881,7 +902,7 @@ class _DecodeSeq:
 class _DecodeModel:
     __slots__ = ("name", "cfg", "params", "kv_config", "cache", "stepfn",
                  "maxb", "attn_path", "blocks_read", "step_ms", "prefix",
-                 "declines", "slot_bytes", "__weakref__",
+                 "declines", "slot_bytes", "feed0", "__weakref__",
                  # speculative decode (spec_k == 0 means off): the draft
                  # decoder runs k tokens ahead through its own paged pool,
                  # then verifyfn scores all k+1 positions in one target call
@@ -909,6 +930,9 @@ class _DecodeModel:
         self.declines = None
         # one sequence's recurrent state over all such layers, in bytes
         self.slot_bytes = 0
+        # what the first step after a pause takes as "the step before's
+        # tokens": zeros on the device, never selected (every src is -1)
+        self.feed0 = None
         self.spec_k = 0
         self.draft_cfg = None
         self.draft_params = None
@@ -917,6 +941,36 @@ class _DecodeModel:
         self.rolloutfn = None       # draft: k chained proposals per lane
         self.ingestfn = None        # draft: multi-token catch-up writes
         self.verifyfn = None        # target: [B, k+1] multi-token step
+
+
+class _Flight:
+    """A decode step that was dispatched and whose tokens the host has
+    not read: the lanes it took, the position each fed, and its outputs
+    as the device will leave them."""
+
+    __slots__ = ("m", "bucket", "lanes", "pos", "lane_of", "nxt", "extras",
+                 "t0")
+
+    def __init__(self, m, bucket, lanes, nxt, extras, t0):
+        self.m = m
+        self.bucket = bucket
+        self.lanes = lanes
+        self.pos = [s.n_disp for s in lanes]
+        self.lane_of = {id(s): i for i, s in enumerate(lanes)}
+        self.nxt = nxt              # int32 [largest bucket], on the device
+        self.extras = extras
+        self.t0 = t0
+
+    def ready(self):
+        """Has the device finished this step?  (Asks, does not wait.)"""
+        return self.nxt.is_ready()
+
+    def live(self):
+        """[(lane, sequence)] for the lanes whose sequence is still what
+        the step took it for: at the position it fed, its dispatched count
+        standing (``DecodeEngine._discard_in_flight`` takes it back)."""
+        return [(i, s) for i, (s, p) in enumerate(zip(self.lanes, self.pos))
+                if s.n_fed == p < s.n_disp]
 
 
 class DecodeEngine:
@@ -929,15 +983,50 @@ class DecodeEngine:
        into free lanes while the allocator can cover their prompts (in
        ``request`` mode admission only happens when no lane is active —
        the comparison baseline for the token-level win);
-    2. picks the smallest configured lane bucket >= active count and
-       rebuilds tok/pos/block_tables/context_lens arrays for it — idle
+    2. picks the smallest configured lane bucket >= the lanes it plans and
+       rebuilds tok/src/pos/block_tables/context_lens arrays for it — idle
        lanes point at the reserved scratch block with context_len 0;
-    3. runs ONE AOT-compiled step (``CarriedStepFn``; the paged KV carry
-       is donated and swapped back into the cache), so mixed-length
-       sequences never trigger a runtime compile;
-    4. appends each live lane's sampled token, finishing sequences at
-       max_new/EOS and freeing their blocks in the SAME iteration so the
-       next step's admission sees the space.
+    3. dispatches ONE AOT-compiled step (``CarriedStepFn``; the paged KV
+       carry is donated and swapped back into the cache at dispatch), so
+       mixed-length sequences never trigger a runtime compile;
+    4. *then* fetches the tokens of the step dispatched an iteration
+       before, and appends each live lane's sampled token, finishing
+       sequences at max_new/EOS and freeing their blocks in the SAME
+       iteration so the next admission sees the space.
+
+    **One step ahead.**  Step n+1 is queued on the device before step n's
+    tokens are read, so dispatch, emit, plan and admission run under the
+    device's work and not beside it.  The token a lane feeds stays on the
+    device: the compiled step (``make_fed_step``) takes the step before's
+    ``next_tokens`` and picks lane i's input from it where ``src[i] >= 0``,
+    from the host's ``tok[i]`` where it is -1 (prompt and replayed tokens,
+    a lane's first step).  The host plans from ``n_disp`` (positions
+    dispatched), which needs no token's value; ``n_fed``, ``out`` and what
+    is published go by what has been read back.  Three rules keep that
+    exact:
+
+    - *discard*: when a step's tokens are read, lane i's is applied only
+      if its sequence is still what the step took it for.  Whatever takes
+      a sequence off its lane while a step holds it (end of sequence,
+      abort, expiry, preemption) goes through ``_discard_in_flight``, and
+      the late token is dropped and counted
+      (``serving_lane_steps_discarded_total{model,reason}``).  ``max_new``
+      is known at dispatch, so a last token's lane is simply not planned
+      again; an ``eos_id`` hit costs one wasted lane-step; a preempted
+      sequence's dropped token is recomputed (greedy: the same token).
+    - *drain*: whoever needs a sequence's exact position from outside the
+      loop calls ``_drain_locked`` first (``export_session``,
+      ``abort_migration``, the hand-off sweep, ``drain``'s choice of a
+      session to push, ``stop``); it fetches and applies the step in
+      flight.  ``abort`` only marks.
+    - *device order*: blocks and state slots the host frees while a step
+      that names them is in flight are safe, because whoever gets them
+      next writes them in a later executable on the same stream, after
+      the step in flight has run (see ``_decode_step_locked``).
+
+    A model that speculates (``spec_k > 0``) keeps the synchronous
+    iteration: ``_spec_step_locked`` needs the accepted count on the host
+    before it can plan the next step.
 
     Mid-decode allocation failure preempts the youngest active sequence
     (blocks freed, sequence re-queued for deterministic recompute) —
@@ -992,6 +1081,9 @@ class DecodeEngine:
         self._admit_waits = []
         self._t_fetched = None
         self._noted_lanes = None
+        # the step dispatched and not read back yet (one-ahead loop), and
+        # "a step is in flight or being applied"
+        self._flight = None
         self.in_batch = False
         self.on_batch_boundary = None
         # ``on_tokens_emitted()`` fires once where an iteration has made
@@ -1036,6 +1128,7 @@ class DecodeEngine:
         capacity keeps the two allocators in lockstep), and three AOT
         step fns replace the single-token one — verify ([B, k+1] target),
         rollout (k chained draft proposals), ingest (draft catch-up)."""
+        import jax
         import jax.numpy as jnp
 
         from . import decode_model as _dm
@@ -1104,8 +1197,10 @@ class DecodeEngine:
         jparams = {key: jnp.asarray(v) for key, v in params.items()}
         attn_path = _dm.attention_path(cfg, kv_config, max(self.buckets))
         stepfn = CarriedStepFn(
-            _dm.make_paged_step(cfg, kv_config), donate_argnums=(0,),
-            name="decode_step",
+            # make_paged_step's step with the token feed on the device:
+            # still one executable an engine step
+            _dm.make_fed_step(cfg, kv_config, max(self.buckets)),
+            donate_argnums=(0,), name="decode_step",
             key_parts={"kind": "decode_step", "model": name,
                        "cfg": cfg.to_dict(),
                        "kv": {"block_size": kv_config.block_size,
@@ -1120,6 +1215,10 @@ class DecodeEngine:
             _pa.blocks_read, block_size=kv_config.block_size,
             maxb=entry.maxb, path=attn_path)
         entry.prefix = prefix
+        entry.feed0 = jax.device_put(
+            np.zeros(max(self.buckets), np.int32),
+            min(next(iter(jparams.values())).sharding.device_set,
+                key=lambda d: d.id))
         if recurrent:
             entry.declines = "recurrent_state"
             entry.slot_bytes = _kvc.slot_bytes(kv_config)
@@ -1255,11 +1354,18 @@ class DecodeEngine:
             manifest[name] = per
         return manifest
 
-    def _step_args(self, m, bucket, tok, pos, tables, lens, slots=None):
-        """The step's arguments.  A model with recurrent layers is told
-        the state slot of each lane's sequence too (None: every lane idle,
-        on the scratch slot, as prewarm has them)."""
-        args = (m.cache.carry(), m.params, tok, pos, tables, lens)
+    def _step_args(self, m, bucket, tok, pos, tables, lens, slots=None,
+                   prev=None, src=None):
+        """The step's arguments (``make_fed_step``).  ``prev`` is the step
+        before's tokens on the device and ``src`` the lane of it each lane
+        feeds from (None: nothing in flight, every lane feeds the host's
+        ``tok``, as prewarm has them).  A model with recurrent layers is
+        told the state slot of each lane's sequence too (None: every lane
+        idle, on the scratch slot)."""
+        args = (m.cache.carry(), m.params, tok,
+                prev if prev is not None else m.feed0,
+                src if src is not None else np.full(bucket, -1, np.int32),
+                pos, tables, lens)
         if m.cache.slots is not None:
             args += (slots if slots is not None
                      else np.zeros(bucket, np.int32),)
@@ -1548,8 +1654,9 @@ class DecodeEngine:
                 return "rejected:kv pool exhausted"
             b = got[0]
             try:
-                # the step holds self._cond for its whole duration, so
-                # swapping the carry here is race-free
+                # a step is dispatched, and the carry swapped for its
+                # outputs, under self._cond: this update queues behind
+                # the step in flight, race-free
                 m.cache.import_block(b, arrays)
             except Exception as e:
                 m.cache.allocator.free([b])
@@ -1614,6 +1721,9 @@ class DecodeEngine:
         no stable digest), and engines without a prefix cache or with
         ``FLAGS_session_migration`` off."""
         with self._cond:
+            # the drain rule: position, ``out`` and the blocks' content
+            # are exact only once the step in flight has been read
+            self._drain_locked()
             seq, waiting = None, False
             for s in self._active:
                 if s.pending.req_id == req_id:
@@ -1734,6 +1844,9 @@ class DecodeEngine:
             seq = self._migrating.pop(req_id, None)
             if seq is None:
                 return False
+            # the drain rule (the parked sequence itself has nothing in
+            # flight: export_session read it back before parking it)
+            self._drain_locked()
             self._free_blocks(seq)
             seq.reset_for_recompute()
             self._waiting.insert(0, seq)
@@ -1760,6 +1873,9 @@ class DecodeEngine:
             self._thread.join(drain_s)
             self._thread = None
         with self._cond:
+            # the loop read its last step back as it left; this is for a
+            # loop that did not leave in time
+            self._drain_locked()
             leftovers = self._active + self._waiting + \
                 list(self._migrating.values())
             self._active, self._waiting = [], []
@@ -1795,6 +1911,8 @@ class DecodeEngine:
                         and not self._migrating:
                     return True
                 if migrate is not None:
+                    # choose by what has been read back (the drain rule)
+                    self._drain_locked()
                     for s in self._active + self._waiting:
                         rid = s.pending.req_id
                         if rid in failed or s.handoff or s.aborted \
@@ -1983,7 +2101,6 @@ class DecodeEngine:
                     s.blocks = list(shared)
                     s.table[:len(shared)] = shared
                     s.n_fed = cached
-                    s.next_tok = s.feed_tok(cached)
                 if s.handoff and self.on_block_sealed is not None:
                     # a warm prefill replica still announces prefix-hit
                     # digests: the decode peer may be cold (the sender's
@@ -1996,6 +2113,7 @@ class DecodeEngine:
             if s.pending.qspan is not None:
                 s.pending.qspan.end()
                 s.pending.qspan = None
+            s.n_disp = s.n_fed      # nothing dispatched beyond the match
             self._active.append(s)
         _tm.set_gauge("serving_queue_depth", len(self._waiting))
         # per-model pressure gauges ride the 1s __metrics__ republish:
@@ -2011,8 +2129,8 @@ class DecodeEngine:
                               model=name)
 
     def _ensure_block(self, seq):
-        """Single-token path: cover seq's next write position."""
-        return self._ensure_capacity(seq, seq.n_fed + 1)
+        """Single-token path: cover the position being dispatched."""
+        return self._ensure_capacity(seq, seq.n_disp + 1)
 
     def _ensure_capacity(self, seq, upto, draft_upto=0):
         """Grow seq's block table(s) to cover ``upto`` tokens (and the
@@ -2031,12 +2149,14 @@ class DecodeEngine:
                 return True
             victims = [s for s in self._active if s is not seq]
             if not victims:
+                self._discard_in_flight(seq, "error")
                 self._active.remove(seq)
                 self._free_blocks(seq)
                 self._finish(seq, InferReply(
                     "error", error="KV pool exhausted with no victim"))
                 return False
             v = max(victims, key=lambda s: s.admit_seq)
+            self._discard_in_flight(v, "preempted")
             self._active.remove(v)
             self._free_blocks(v)
             v.reset_for_recompute()
@@ -2158,7 +2278,6 @@ class DecodeEngine:
                         s.table[nfull] = b
                         s.n_fed = pos
         s.cached_tokens = s.n_fed
-        s.next_tok = s.feed_tok(s.n_fed)
 
     def _prefill_limit(self, s):
         """Last position this replica feeds for ``s``: the known
@@ -2172,9 +2291,14 @@ class DecodeEngine:
         ``on_handoff`` while the blocks are still owned — the hook
         snapshots nothing, the sealed blocks were already streamed — then
         free and finish with status "handoff" (the prefill replica's
-        terminal state; the decode half owns the client-visible reply)."""
-        for s in list(self._active):
-            if not s.handoff or s.n_fed < s.prefill_upto:
+        terminal state; the decode half owns the client-visible reply).
+        A sequence dispatched up to its boundary whose last step is still
+        in flight is read back first (the drain rule)."""
+        handoffs = [s for s in self._active if s.handoff]
+        if any(s.n_fed < s.prefill_upto <= s.n_disp for s in handoffs):
+            self._drain_locked()
+        for s in handoffs:
+            if s.n_fed < s.prefill_upto:
                 continue
             m = self._model_of(s)
             self._active.remove(s)
@@ -2200,13 +2324,19 @@ class DecodeEngine:
         span cap (spec mode feeds multi-token chunks; non-spec feeds one
         token, so the cap only gates participation).  Pure scheduling:
         participants still pad to a configured lane bucket, so no new
-        shape is ever compiled."""
+        shape is ever compiled.
+
+        Planning goes by what has been dispatched (``n_disp``): a
+        sequence whose every position has a step (its last token, or its
+        hand-off boundary, is in flight) sits out until that step is
+        read."""
         max_lanes = max(self.buckets)
         budget = int(_flag("decode_prefill_token_budget") or 0)
+        ready = [s for s in self._active if s.n_disp < s.feed_limit]
         if budget <= 0:
-            return self._active[:max_lanes], {}
-        decode = [s for s in self._active if not s.in_prefill]
-        prefill = [s for s in self._active if s.in_prefill]
+            return ready[:max_lanes], {}
+        decode = [s for s in ready if s.n_disp >= s.replay_upto]
+        prefill = [s for s in ready if s.n_disp < s.replay_upto]
         if prefill:
             r = self._rr_prefill % len(prefill)
             prefill = prefill[r:] + prefill[:r]
@@ -2214,7 +2344,7 @@ class DecodeEngine:
         for s in prefill:
             if left <= 0 or len(decode) + len(chosen) >= max_lanes:
                 break
-            span = min(chunk, self._prefill_limit(s) - s.n_fed, left)
+            span = min(chunk, self._prefill_limit(s) - s.n_disp, left)
             caps[id(s)] = span
             left -= span
             chosen.append(s)
@@ -2228,47 +2358,64 @@ class DecodeEngine:
         return max(self.buckets)
 
     def _decode_loop(self):
-        # every stretch of an iteration runs inside a tracing.phase, so a
-        # profile names what the host did in each device idle gap and the
-        # step span's ``phases`` attribute accounts for the whole period
-        while True:
-            # named fault point OUTSIDE the lock: a "delay" spec slows
-            # every decode iteration (slow-replica chaos — keeps
-            # sessions alive across a drain/kill window in CI) without
-            # holding submitters on the cond during the sleep
-            with _tr.phase("serving.between_steps"):
-                maybe_fail("serving.decode_step")
-            with _tr.phase("serving.lock_wait") as lock_wait, self._cond:
-                lock_wait.stop()
-                if not self._running:
-                    return
-                with _tr.phase("serving.admit"):
-                    self._expire_and_admit()
-                if not self._active:
-                    with _tr.phase("serving.idle"):
-                        self._cond.wait(0.05)
-                    # waiting for work is not the host keeping the
-                    # device waiting: gap_us counts from here
-                    self._t_fetched = time.perf_counter()
-                    continue
-                step_ok = self._decode_step_locked()
-                preempted, self._preempted = self._preempted, []
-            with _tr.phase("serving.between_steps"):
-                if preempted and self.on_preempt is not None:
-                    # pressure-trigger migration hook (CC105: fired with
-                    # the lock released; the victims are already back in
-                    # the waiting queue with their emitted tokens intact)
-                    try:
-                        self.on_preempt(preempted)
-                    except Exception:
-                        pass
-                if self.on_batch_boundary is not None:
-                    try:
-                        self.on_batch_boundary()
-                    except Exception:
-                        pass
-                if not step_ok:
-                    time.sleep(0.001)
+        while self._loop_once():
+            pass
+
+    def _loop_once(self):
+        """One iteration of the decode loop; False once the engine has
+        been stopped.  Every stretch of it runs inside a tracing.phase, so
+        a profile names what the host did in each device idle gap and the
+        step span's ``phases`` attribute accounts for the whole period."""
+        # named fault point OUTSIDE the lock: a "delay" spec slows
+        # every decode iteration (slow-replica chaos — keeps
+        # sessions alive across a drain/kill window in CI) without
+        # holding submitters on the cond during the sleep
+        with _tr.phase("serving.between_steps"):
+            maybe_fail("serving.decode_step")
+        with _tr.phase("serving.lock_wait") as lock_wait, self._cond:
+            lock_wait.stop()
+            if not self._running:
+                # stop() finds every sequence at its exact position
+                self._drain_locked()
+                return False
+            with _tr.phase("serving.admit"):
+                self._expire_and_admit()
+            if not self._active:
+                # a step whose every lane has left is read and dropped
+                self._drain_locked()
+                with _tr.phase("serving.idle"):
+                    # for an arrival, at most 50 ms: then round through
+                    # admission (and its gauges) again
+                    until = time.perf_counter() + 0.05
+                    queued = len(self._waiting)
+                    while self._running and len(self._waiting) == queued:
+                        left = until - time.perf_counter()
+                        if left <= 0:
+                            break
+                        self._cond.wait(left)
+                # waiting for work is not the host keeping the
+                # device waiting: gap_us counts from here
+                self._t_fetched = time.perf_counter()
+                return True
+            step_ok = self._decode_step_locked()
+            preempted, self._preempted = self._preempted, []
+        with _tr.phase("serving.between_steps"):
+            if preempted and self.on_preempt is not None:
+                # pressure-trigger migration hook (CC105: fired with
+                # the lock released; the victims are already back in
+                # the waiting queue with their emitted tokens intact)
+                try:
+                    self.on_preempt(preempted)
+                except Exception:
+                    pass
+            if self.on_batch_boundary is not None:
+                try:
+                    self.on_batch_boundary()
+                except Exception:
+                    pass
+            if not step_ok:
+                time.sleep(0.001)
+        return True
 
     def _open_step_span(self, m, bucket, lanes, **attrs):
         """The iteration's ``serving.decode_step`` span, linked to the
@@ -2292,8 +2439,11 @@ class DecodeEngine:
 
     def _dispatch_gap_us(self):
         """Host time since the device handed the last step's tokens back
-        (``serving.fetch`` ended), read as this step's dispatch starts:
-        the device has nothing queued meanwhile."""
+        (``serving.fetch`` ended), read as this step's dispatch starts
+        with nothing running on the device: it has had nothing queued
+        since the step read last ended, which is at the latest then.  (A
+        step dispatched while the one before still runs has no such gap:
+        its span says ``ahead`` and ``gap_us`` 0.)"""
         if self._t_fetched is None:
             return 0
         return int((time.perf_counter() - self._t_fetched) * 1e6)
@@ -2306,37 +2456,83 @@ class DecodeEngine:
         sspan.take_phases("serving.")
         sspan.end()
 
-    def _decode_step_locked(self):
-        """One token for every active lane (call with self._cond held).
+    def _discard_in_flight(self, s, reason):
+        """``s`` leaves its lane (or is reset) for ``reason``: the token a
+        step in flight still holds for it will not be applied.  The one
+        rule for every late discovery: ``_apply_flight_locked`` takes a
+        lane's token only while the sequence's dispatched count stands."""
+        if s.n_disp > s.n_fed:
+            s.n_disp = s.n_fed
+            _tm.inc("serving_lane_steps_discarded_total",
+                    model=s.pending.model, reason=reason)
 
-        NOTE: the step executes under the lock — sequences can only
-        join/leave at iteration boundaries, which is exactly the
+    def _drain_locked(self):
+        """Fetch and apply the step in flight, if there is one (call with
+        self._cond held).  Whoever needs a sequence's exact position,
+        its ``out`` or the cache's content as the host has confirmed it,
+        from outside the loop, calls this before it looks."""
+        flight, self._flight = self._flight, None
+        if flight is not None:
+            self._apply_flight_locked(flight)
+        self.in_batch = False
+
+    def _fail_lanes_locked(self, m, lanes, error):
+        for s in lanes:
+            self._discard_in_flight(s, "error")
+            self._active.remove(s)
+            self._free_blocks(s)
+            self._finish(s, InferReply("error", error=error))
+        _tm.inc("serving_batch_errors_total", model=m.name)
+
+    def _decode_step_locked(self):
+        """One loop iteration for a plain decode step (call with
+        self._cond held): plan and dispatch the next step, *then* fetch
+        and apply the step dispatched an iteration ago.  At most one step
+        is in flight beyond the one being read.
+
+        NOTE: the dispatch and the apply run under the lock — sequences
+        can only join/leave at iteration boundaries, which is exactly the
         continuous-batching contract.  submit()/abort() block for at
-        most one step (milliseconds at serving batch sizes), and in
-        exchange the active set and block tables need no second lock."""
+        most one iteration (milliseconds at serving batch sizes), and in
+        exchange the active set and block tables need no second lock.
+        The device runs the step *after* the lock is released too, so
+        between iterations the host may free blocks and state slots that
+        a step in flight still names (a finished, aborted, expired or
+        preempted sequence's, or an end-of-sequence lane's wasted write).
+        That is safe by device order: the pools chain from step to step
+        (the carry is replaced at dispatch with the outputs not yet
+        computed, and JAX queues behind them), so whoever gets a freed
+        block or slot next writes it in a later executable on the same
+        stream: a later step, or ``import_block``'s update of the same
+        carry.  Nothing on the host reads a pool without going through
+        that carry."""
+        m = self._model_of(self._active[0])
+        if self._flight is not None and self._flight.m is not m:
+            self._drain_locked()    # the last step of another model
         with _tr.phase("serving.plan"):
-            m = self._model_of(self._active[0])
             # drop client-aborted + deadline-expired actives first, freeing
             # their blocks before this step's allocations
             now = time.perf_counter()
             for s in list(self._active):
                 if s.aborted:
+                    self._discard_in_flight(s, "aborted")
                     self._active.remove(s)
                     self._free_blocks(s)
                     self._finish(s, InferReply("aborted",
                                                error="aborted by client"))
                 elif now > s.pending.deadline:
+                    self._discard_in_flight(s, "expired")
                     self._active.remove(s)
                     self._free_blocks(s)
                     _tm.inc("serving_timeout_total", model=s.pending.model)
                     self._finish(s, InferReply(
                         "timeout", error="deadline expired mid-decode"))
-            # complete prefill-role sequences whose boundary was reached
-            # (by the previous step, or at admission via a warm prefix
-            # match)
-            self._sweep_handoff_locked()
-            if not self._active:
-                return True
+        # complete prefill-role sequences whose boundary was reached (by
+        # the step read last, or at admission via a warm prefix match)
+        self._sweep_handoff_locked()
+        if not self._active:
+            self._drain_locked()
+            return True
         if m.spec_k > 0:
             return self._spec_step_locked(m)
         with _tr.phase("serving.plan"):
@@ -2347,20 +2543,31 @@ class DecodeEngine:
                 if s in self._active and not self._ensure_block(s):
                     pass  # defensively completed inside _ensure_block
             lanes = [s for s in participants if s in self._active]
-            if not lanes:
-                return True
+        if not lanes:
+            # every active sequence waits for a token in flight
+            self._drain_locked()
+            return True
+        prev = self._flight
+        with _tr.phase("serving.plan"):
             bucket = self._bucket_for(len(lanes))
             tok = np.zeros(bucket, np.int32)
+            src = np.full(bucket, -1, np.int32)
             pos = np.zeros(bucket, np.int32)
             tables = np.full((bucket, m.maxb), -1, np.int32)
             lens = np.zeros(bucket, np.int32)
             slots = np.zeros(bucket, np.int32) \
                 if m.cache.slots is not None else None
             for i, s in enumerate(lanes):
-                tok[i] = s.next_tok
-                pos[i] = s.n_fed
+                p = s.n_disp
+                if p < s.known:
+                    tok[i] = s.feed_tok(p)
+                else:
+                    # the token the step in flight is computing: lane i
+                    # takes it from that step's output, on the device
+                    src[i] = prev.lane_of[id(s)]
+                pos[i] = p
                 tables[i] = s.table
-                lens[i] = s.n_fed + 1  # token valid AFTER this step's write
+                lens[i] = p + 1  # token valid AFTER this step's write
                 if slots is not None:
                     slots[i] = s.state_slot
             # blocks a layer's attention fetches this step, of the slots
@@ -2379,41 +2586,68 @@ class DecodeEngine:
                     read["ssm_state_lanes"] = len(lanes)
                     read["ssm_state_bytes"] = len(lanes) * m.slot_bytes
             sspan = self._open_step_span(m, bucket, lanes, **read)
-            args = self._step_args(m, bucket, tok, pos, tables, lens, slots)
-        self.in_batch = True
+            args = self._step_args(
+                m, bucket, tok, pos, tables, lens, slots,
+                prev=prev.nxt if prev is not None else None, src=src)
+        # is the device still at work on the step before?  Then it never
+        # runs dry between the two, and the host's time since the last
+        # fetch kept nothing waiting
+        ahead = prev is not None and not prev.ready()
         t0 = time.perf_counter()
-        gap_us = self._dispatch_gap_us()
+        gap_us = 0 if ahead else self._dispatch_gap_us()
         try:
             with _tr.activate(sspan), _tr.phase("serving.dispatch"):
-                # threadlint: waive CC102 continuous-batching contract: the device step runs under _cond so lane state is frozen for the whole step (see _decode_step_locked docstring); submitters park on the cond, never spin
+                # threadlint: waive CC102 continuous-batching contract: the step is dispatched under _cond so lane state is frozen while it is planned and queued (see _decode_step_locked docstring); submitters park on the cond, never spin
                 carry, nxt, _logits, *extras = m.stepfn(*args)
-            with _tr.phase("serving.fetch"):
+                # at dispatch: the next step, and whoever writes a block,
+                # queues behind outputs the device has yet to compute
                 m.cache.replace_carry(carry)
                 # a routed step's counts ride with the tokens, started
-                # before the wait, and only while the span is recorded
+                # now, and only while the span is recorded
                 if extras and _tr.enabled():
                     extras[0].copy_to_host_async()
                 else:
                     extras = None
-                nxt = np.asarray(nxt)
-                moe = self._moe_attrs(m, extras)
         except Exception as e:
-            for s in lanes:
-                self._active.remove(s)
-                self._free_blocks(s)
-                self._finish(s, InferReply("error", error=str(e)))
-            _tm.inc("serving_batch_errors_total", model=m.name)
-            self._close_step_span(sspan, gap_us=gap_us,
+            self._fail_lanes_locked(m, lanes, str(e))
+            self._drain_locked()
+            self._close_step_span(sspan, gap_us=gap_us, ahead=ahead,
                                   error=str(e)[:200])
-            self.in_batch = False
             return False
-        self.in_batch = False
-        self._t_fetched = t_tok = time.perf_counter()
-        ms = (t_tok - t0) * 1e3
+        self._flight = _Flight(m, bucket, lanes, nxt, extras, t0)
+        for s in lanes:
+            s.n_disp += 1
+        self.in_batch = True
+        if ahead:
+            _tm.inc("serving_steps_ahead_total", model=m.name)
+        applied = self._apply_flight_locked(prev) if prev is not None \
+            else {"generated": 0, "published": 0}
+        self._close_step_span(sspan, gap_us=gap_us, ahead=ahead, **applied)
+        return "error" not in applied
+
+    def _apply_flight_locked(self, flight):
+        """Fetch a dispatched step's tokens (the wait, if the device is
+        still at it) and apply them: one token for every lane that is
+        still what the step took it for.  -> the attributes its iteration's
+        span reports."""
+        m = flight.m
+        try:
+            with _tr.phase("serving.fetch"):
+                nxt = np.asarray(flight.nxt)
+                moe = self._moe_attrs(m, flight.extras)
+        except Exception as e:
+            self._fail_lanes_locked(m, [s for _, s in flight.live()], str(e))
+            return {"error": str(e)[:200]}
+        t_tok = time.perf_counter()
+        # the period: since the step before was read, or since this one's
+        # dispatch where nothing was in flight before it
+        ms = (t_tok - max(flight.t0, self._t_fetched or 0.0)) * 1e3
+        self._t_fetched = t_tok
         m.step_ms = ms if m.step_ms <= 0 else 0.8 * m.step_ms + 0.2 * ms
         n_generated = 0
         with _tr.phase("serving.emit"):
-            for i, s in enumerate(lanes):
+            # a lane that is not live any more was counted where it left
+            for i, s in flight.live():
                 s.n_fed += 1
                 # seal + publish any prompt block this write completed
                 # (the boundary-crossing write completes the final full
@@ -2422,10 +2656,8 @@ class DecodeEngine:
                 self._publish_prefix_locked(m, s)
                 self._publish_history_locked(m, s)
                 if s.in_prefill:
-                    s.next_tok = s.feed_tok(s.n_fed)
                     continue
                 token = int(nxt[i])
-                s.next_tok = token
                 s.out.append(token)
                 s.token_times.append(t_tok)
                 if s.t_first is None:
@@ -2439,6 +2671,9 @@ class DecodeEngine:
                     except Exception:
                         pass
                 if done:
+                    # max_new was known at dispatch (nothing in flight);
+                    # an eos_id hit was not: its lane ran one step more
+                    self._discard_in_flight(s, "eos")
                     self._active.remove(s)
                     self._free_blocks(s)   # same-step free: next admission
                     self._finish(s, InferReply("ok"))
@@ -2447,12 +2682,11 @@ class DecodeEngine:
                         model=m.name)
             _tm.inc("serving_decode_steps_total", model=m.name)
             _tm.observe("decode_batch_occupancy",
-                        len(lanes) / float(bucket), model=m.name)
+                        len(flight.lanes) / float(flight.bucket),
+                        model=m.name)
             published = self._tokens_emitted()
-        self._close_step_span(sspan, generated=n_generated,
-                              ms=round(ms, 3), gap_us=gap_us,
-                              published=published, **moe)
-        return True
+        return dict(moe, generated=n_generated, ms=round(ms, 3),
+                    published=published)
 
     @staticmethod
     def _moe_attrs(m, extras):
@@ -2485,6 +2719,10 @@ class DecodeEngine:
         per iteration, auto-accepted, mirrored into the draft cache).
         Greedy accept keeps the emitted stream bitwise equal to the
         non-speculative engine; draft quality only moves throughput.
+        This iteration is synchronous (dispatch, wait, emit): the next
+        one cannot be planned before the accepted count is on the host,
+        so nothing is ever in flight for a speculating model and
+        ``n_disp`` follows ``n_fed``.
 
         Verify/ingest lane layout is junk-first: a lane with span < k+1
         valid tokens pads the LEADING columns with context_len-0 writes
@@ -2544,7 +2782,7 @@ class DecodeEngine:
                 tables[i] = s.table
                 pos[i, :pad] = p
                 feed = s.feed_slice(p, span) if s.in_prefill \
-                    else [s.next_tok]
+                    else [s.feed_tok(p)]
                 for j in range(span):
                     pos[i, pad + j] = p + j
                     lens[i, pad + j] = p + j + 1
@@ -2552,7 +2790,7 @@ class DecodeEngine:
                     tok[i, pad + j] = t
                 if spec:
                     n_spec += 1
-                    rtok[i] = s.next_tok
+                    rtok[i] = s.feed_tok(p)
                     rpos[i] = p
                     rlens[i] = p + 1
                     rmax[i] = s.total - 1
@@ -2616,11 +2854,11 @@ class DecodeEngine:
                 accepted = 0
                 if s.in_prefill:
                     s.n_fed += span
+                    s.n_disp = s.n_fed
                     self._publish_prefix_locked(m, s)
                     self._publish_history_locked(m, s)
                     ingest.append((s, p, s.feed_slice(p, span)))
                     if s.in_prefill:
-                        s.next_tok = s.feed_tok(s.n_fed)
                         continue
                     # chunk crossed the prompt boundary: its last column's
                     # argmax is the first generated token
@@ -2641,6 +2879,7 @@ class DecodeEngine:
                                     accepted / float(span - 1),
                                     model=m.name)
                     s.n_fed += len(emitted)
+                    s.n_disp = s.n_fed
                 done = False
                 for t in emitted:
                     s.out.append(t)
@@ -2667,7 +2906,6 @@ class DecodeEngine:
                     self._free_blocks(s)   # same-step free, both pools
                     self._finish(s, InferReply("ok"))
                     continue
-                s.next_tok = emitted[-1]
                 if accepted == k:
                     # full accept: the rollout never wrote position p+k;
                     # its token is d_k (== the target's g_k), caught up

@@ -234,9 +234,19 @@ def test_decode_step_spans_carry_admission_gap_and_phases(tmp_path):
     assert all(0 <= lock <= wait for lock, wait in zip(locks, waits))
     assert all(len(a["admit_wait_ms"]) == a["admitted"] for a in attrs)
     assert all(a["gap_us"] >= 0 for a in attrs)
+    # an iteration dispatches a step and then reads the one before it:
+    # the first has none to read (no ``ms``); a span that read one has
+    # every phase
+    read = {"serving.fetch", "serving.emit"}
     for a in attrs:
-        assert SERVING_PHASES <= set(a["phases"]), a["phases"]
-        assert {"lanes", "generated", "bucket", "step", "ms"} <= set(a)
+        assert SERVING_PHASES - read <= set(a["phases"]), a["phases"]
+        assert {"lanes", "generated", "bucket", "step", "ahead"} <= set(a)
+        if "ms" in a:
+            assert SERVING_PHASES <= set(a["phases"]), a["phases"]
+    assert attrs[0]["ahead"] is False and "ms" not in attrs[0]
+    # (so has one that follows a pause, or an iteration that found every
+    # lane waiting for its last token and only read)
+    assert sum("ms" in a for a in attrs) > len(attrs) // 2
     # a span's phases are those since the span before it took its own:
     # they sum to no more than the time between the two spans' ends
     ends = [s["ts"] + s["dur"] for s in steps]
