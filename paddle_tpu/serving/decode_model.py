@@ -15,21 +15,26 @@ that reads each lane's live KV blocks from the pool where they lie, and
 elsewhere (the CPU tier, the int8 residency) a gather of the padded table
 into ``masked_attention``, chosen by what the shapes and the backend are.
 
-The decoder is one of three blocks, picked by ``DecoderConfig.arch``: the
+The decoder is one of four blocks, picked by ``DecoderConfig.arch``: the
 ``gpt2`` block of this file, in float32; the routed-expert ``olmoe`` block
-of ``models/olmoe.py``, in bfloat16 with a bfloat16 cache; and the
-``granite_hybrid`` block of ``models/granite_hybrid.py``, whose layers are of
-two kinds: grouped-query attention (fewer KV heads than query heads, so
-pools ``kv_heads * head_dim`` wide) and Mamba-2 state-space mixers, which
-keep a recurrent state a sequence instead of K and V.  Every step builder
-below serves all three through one contract (``_block``), so there is one
-paged step, one multi-token step, one draft rollout and one unpaged
+of ``models/olmoe.py``, in bfloat16 with a bfloat16 cache; the
+``granite_hybrid`` block of ``models/granite_hybrid.py``; and the
+``lfm2_moe`` block of ``models/lfm2_moe.py``.  Layers are of three kinds
+(``LAYER_KINDS``): ``attention`` (multi-head, or grouped-query with fewer KV
+heads than query heads, so pools ``kv_heads * head_dim`` wide), which keeps
+K and V a token; ``mamba``, a Mamba-2 state-space mixer, which keeps a
+convolution window and a recurrent state a sequence; and ``conv``, a gated
+short convolution, which keeps a window and no state.  The hybrid blocks
+mix attention with one recurrent kind (``ARCH_LAYER_KINDS``).  Every step
+builder below serves all four through one contract (``_block``), so there
+is one paged step, one multi-token step, one draft rollout and one unpaged
 reference, whatever the block.
 
 Two step builders share every layer of math through two callbacks, which
 own what a layer keeps between tokens: ``attend`` the K and V of an
-attention layer, ``recur`` (``_Recurrent``) the convolution window and the
-state of a recurrent layer:
+attention layer, ``recur`` (``_Recurrent``) the convolution window and, for
+a kind that has one, the state of a recurrent layer (``_state_shapes`` says
+what a kind keeps):
 
 * ``make_paged_step``   — writes this token's K/V rows into the layer's
   own pools of the paged cache (``[num_blocks, block_size, KH * D]``, block
@@ -53,6 +58,7 @@ the same mathematics at the same precision with the softmax's sums in
 another order, so there the bar is the same tokens and logits to 1e-5.
 """
 
+import importlib
 import json
 import os
 import re
@@ -75,8 +81,20 @@ __all__ = ["DecoderConfig", "init_decoder_params", "save_decoder",
            "cache_config"]
 
 
-ARCHS = ("gpt2", "olmoe", "granite_hybrid")
-LAYER_KINDS = ("attention", "mamba")
+# architecture -> the kinds of layer its block computes (a block other than
+# gpt2's is ``models/<arch>.py``)
+ARCH_LAYER_KINDS = {"gpt2": ("attention",), "olmoe": ("attention",),
+                    "granite_hybrid": ("attention", "mamba"),
+                    "lfm2_moe": ("attention", "conv")}
+ARCHS = tuple(ARCH_LAYER_KINDS)
+LAYER_KINDS = ("attention", "mamba", "conv")
+# recurrent kind -> the name its slot goes by in spans, gauges and counters
+# (``ssm_state_lanes``, ``conv_state_bytes{model}``, ...)
+STATE_NAMES = {"mamba": "ssm_state", "conv": "conv_state"}
+# the blocks whose attention may have fewer KV heads than query heads, and
+# those whose feed-forward is routed experts
+_GROUPED_QUERY = ("granite_hybrid", "lfm2_moe")
+_ROUTED = ("olmoe", "lfm2_moe")
 
 
 class DecoderConfig:
@@ -94,6 +112,14 @@ class DecoderConfig:
     family's four multipliers (``embedding_multiplier`` on the embedding,
     ``residual_multiplier`` on every branch, ``attention_multiplier`` as
     the scale of the scores, logits divided by ``logits_scaling``).
+    ``lfm2_moe`` is the block of ``models/lfm2_moe.py``: ``layer_types`` of
+    ``attention`` | ``conv``, grouped-query attention with per-head Q/K norm
+    and RoPE beside gated short convolutions of ``conv_taps`` taps over the
+    hidden width, and a feed-forward by layer: the first ``dense_layers``
+    layers a gated MLP of width ``dense_ffn``, the rest ``experts`` experts
+    of width ``ffn`` routed by sigmoid scores (a bias selects
+    ``experts_per_token``, the gates are renormalised and scaled by
+    ``routed_scaling``), and a tied head.
 
     ``kv_heads`` None means ``heads`` (multi-head); ``layer_types`` None
     means ``layers`` attention layers.  ``dtype`` is the weights' (``f32``
@@ -107,7 +133,8 @@ class DecoderConfig:
                  "kv_heads", "layer_types", "ssm_heads", "ssm_head_dim",
                  "ssm_state", "ssm_conv", "embedding_multiplier",
                  "residual_multiplier", "attention_multiplier",
-                 "logits_scaling")
+                 "logits_scaling", "conv_taps", "dense_layers", "dense_ffn",
+                 "routed_scaling")
 
     def __init__(self, vocab, layers, heads, head_dim, ffn=None,
                  max_seq=64, arch="gpt2", dtype="f32", kv_dtype=None,
@@ -115,7 +142,9 @@ class DecoderConfig:
                  norm_eps=1e-5, kv_heads=None, layer_types=None,
                  ssm_heads=0, ssm_head_dim=0, ssm_state=0, ssm_conv=0,
                  embedding_multiplier=1.0, residual_multiplier=1.0,
-                 attention_multiplier=None, logits_scaling=1.0):
+                 attention_multiplier=None, logits_scaling=1.0,
+                 conv_taps=0, dense_layers=0, dense_ffn=0,
+                 routed_scaling=1.0):
         if arch not in ARCHS:
             raise ValueError("decoder arch must be %s: %r"
                              % ("|".join(ARCHS), arch))
@@ -123,9 +152,11 @@ class DecoderConfig:
             raise ValueError("decoder dtype must be f32|bf16: %r" % (dtype,))
         if arch == "gpt2" and dtype != "f32":
             raise ValueError("the gpt2 block is served in f32")
-        if arch == "olmoe" and not 0 < int(experts_per_token) <= int(experts):
-            raise ValueError("olmoe wants 0 < experts_per_token <= experts, "
-                             "got %r of %r" % (experts_per_token, experts))
+        if arch in _ROUTED \
+                and not 0 < int(experts_per_token) <= int(experts):
+            raise ValueError("%s wants 0 < experts_per_token <= experts, "
+                             "got %r of %r"
+                             % (arch, experts_per_token, experts))
         self.vocab = int(vocab)
         self.layers = int(layers)
         self.heads = int(heads)
@@ -153,6 +184,10 @@ class DecoderConfig:
         self.attention_multiplier = None if attention_multiplier is None \
             else float(attention_multiplier)
         self.logits_scaling = float(logits_scaling)
+        self.conv_taps = int(conv_taps)
+        self.dense_layers = int(dense_layers)
+        self.dense_ffn = int(dense_ffn)
+        self.routed_scaling = float(routed_scaling)
         if len(self.layer_types) != self.layers or any(
                 k not in LAYER_KINDS for k in self.layer_types):
             raise ValueError("layer_types must name each of the %d layers "
@@ -161,30 +196,72 @@ class DecoderConfig:
         if self.heads % self.kv_heads:
             raise ValueError("heads %d must be a multiple of kv_heads %d"
                              % (self.heads, self.kv_heads))
-        if arch != "granite_hybrid" and (
-                self.ssm_layers or self.kv_heads != self.heads):
+        if any(k not in ARCH_LAYER_KINDS[arch] for k in self.layer_types):
+            raise ValueError("the %s block's layers are %s: %r"
+                             % (arch, "|".join(ARCH_LAYER_KINDS[arch]),
+                                layer_types))
+        if arch not in _GROUPED_QUERY and self.kv_heads != self.heads:
             raise ValueError("the %s block is multi-head attention in every "
                              "layer" % arch)
         if self.ssm_layers and min(self.ssm_heads, self.ssm_head_dim,
                                    self.ssm_state, self.ssm_conv - 1) < 1:
             raise ValueError("mamba layers want ssm_heads, ssm_head_dim, "
                              "ssm_state >= 1 and ssm_conv >= 2")
+        if self.conv_layers and self.conv_taps < 2:
+            raise ValueError("conv layers want conv_taps >= 2")
+        if not 0 <= self.dense_layers <= self.layers or (
+                self.dense_layers and (arch != "lfm2_moe"
+                                       or self.dense_ffn < 1)):
+            raise ValueError(
+                "dense_layers leads the lfm2_moe block's %d layers with a "
+                "gated MLP of width dense_ffn >= 1: %r of width %r"
+                % (self.layers, dense_layers, dense_ffn))
 
     @property
     def hidden(self):
         return self.heads * self.head_dim
 
+    def _of_kind(self, kind):
+        return tuple(l for l, k in enumerate(self.layer_types) if k == kind)
+
     @property
     def attn_layers(self):
         """Indices of the layers that hold K and V, in order."""
-        return tuple(l for l, k in enumerate(self.layer_types)
-                     if k == "attention")
+        return self._of_kind("attention")
 
     @property
     def ssm_layers(self):
-        """Indices of the layers that hold a recurrent state, in order."""
+        """Indices of the Mamba-2 layers (a window and a state), in order."""
+        return self._of_kind("mamba")
+
+    @property
+    def conv_layers(self):
+        """Indices of the short-convolution layers (a window), in order."""
+        return self._of_kind("conv")
+
+    @property
+    def recurrent_layers(self):
+        """Indices of the layers that keep, a sequence, something constant
+        in its length (a slot of the cache): the model's ``mamba`` or
+        ``conv`` layers, in order."""
         return tuple(l for l, k in enumerate(self.layer_types)
-                     if k == "mamba")
+                     if k in STATE_NAMES)
+
+    @property
+    def state_name(self):
+        """What the recurrent layers' slot goes by in telemetry
+        (``ssm_state`` | ``conv_state``); None for a model with no such
+        layer."""
+        return next((STATE_NAMES[k] for k in self.layer_types
+                     if k in STATE_NAMES), None)
+
+    @property
+    def routed_layers(self):
+        """Indices of the layers whose feed-forward is routed experts, in
+        order: the rows of the step's ``routed`` counts."""
+        if self.arch not in _ROUTED:
+            return ()
+        return tuple(range(self.dense_layers, self.layers))
 
     @property
     def ssm_inner(self):
@@ -202,27 +279,39 @@ class DecoderConfig:
 def cache_config(cfg, block_size, num_blocks, dtype=None, state_slots=0):
     """The cache geometry a model's step is built over: K and V pools for
     its attention layers (``kv_heads`` wide), and for its recurrent layers
-    what one sequence's slot holds: the convolution's window (``ssm_conv -
-    1`` inputs of ``ssm_inner + 2 * ssm_state`` values, flat, in the
-    weights' dtype) and the state (``[ssm_state, ssm_inner]`` float32: the
-    heads' ``[head_dim, ssm_state]`` matrices, transposed so the minor
-    dimension is the 128-lane-dense one), in ``state_slots`` slots (slot 0
-    the idle lanes' scratch)."""
+    what one sequence's slot holds (``_state_shapes``: a window and a state
+    for ``mamba`` layers, a window alone for ``conv`` layers), in
+    ``state_slots`` slots (slot 0 the idle lanes' scratch)."""
     return _kv.KVCacheConfig(
         len(cfg.attn_layers), cfg.kv_heads, cfg.head_dim, block_size,
         num_blocks, dtype or cfg.kv_dtype or "f32",
-        state_layers=len(cfg.ssm_layers), state_shapes=_state_shapes(cfg),
-        state_slots=state_slots)
+        state_layers=len(cfg.recurrent_layers),
+        state_shapes=_state_shapes(cfg), state_slots=state_slots)
+
+
+def _conv_window(cfg):
+    """``(taps, width)`` of the causal convolution of the model's recurrent
+    layers: a slot keeps its ``taps - 1`` newest inputs."""
+    if cfg.ssm_layers:
+        return cfg.ssm_conv, cfg.ssm_inner + 2 * cfg.ssm_state
+    return cfg.conv_taps, cfg.hidden
 
 
 def _state_shapes(cfg):
-    """``(shape, dtype)`` of what a recurrent layer keeps a sequence: the
-    window, then the state; nothing for a model with no such layer."""
-    if not cfg.ssm_layers:
+    """``(shape, dtype)`` of each array a recurrent layer keeps a sequence,
+    by the kind of layer: always the convolution's window first (``taps -
+    1`` inputs, flat, in the weights' dtype); a ``mamba`` layer then its
+    state (``[ssm_state, ssm_inner]`` float32: the heads' ``[head_dim,
+    ssm_state]`` matrices, transposed so the minor dimension is the
+    128-lane-dense one), a ``conv`` layer nothing more.  Nothing for a
+    model with no such layer."""
+    if not cfg.recurrent_layers:
         return ()
-    return ((((cfg.ssm_conv - 1) * (cfg.ssm_inner + 2 * cfg.ssm_state),),
-             cfg.dtype),
-            ((cfg.ssm_state, cfg.ssm_inner), "f32"))
+    taps, width = _conv_window(cfg)
+    window = (((taps - 1) * width,), cfg.dtype)
+    if cfg.ssm_layers:
+        return (window, ((cfg.ssm_state, cfg.ssm_inner), "f32"))
+    return (window,)
 
 
 def init_decoder_params(cfg, seed=0):
@@ -314,7 +403,8 @@ def truncate_decoder(cfg, params, layers=1):
     tracks the full model's closely — a distillation-free draft for
     demos and smokes (real deployments train one)."""
     layers = min(int(layers), cfg.layers)
-    dcfg = cfg.replace(layers=layers, layer_types=cfg.layer_types[:layers])
+    dcfg = cfg.replace(layers=layers, layer_types=cfg.layer_types[:layers],
+                       dense_layers=min(cfg.dense_layers, layers))
     dparams = {}
     for k, v in params.items():
         m = re.match(r"l(\d+)_", k)
@@ -332,14 +422,9 @@ def _ln(x, g, b):
 
 
 def _model(cfg):
-    """The module of an architecture that has one of its own."""
-    if cfg.arch == "olmoe":
-        from ..models import olmoe
-
-        return olmoe
-    from ..models import granite_hybrid
-
-    return granite_hybrid
+    """The module of an architecture that has one of its own:
+    ``models/<arch>.py``."""
+    return importlib.import_module("..models." + cfg.arch, __package__)
 
 
 def _block(cfg):
@@ -347,14 +432,17 @@ def _block(cfg):
     live, recur) -> (logits [B, vocab], extras)``.  Two callbacks own what
     a layer keeps between tokens, the only paged/unpaged difference:
     ``attend(l, q, k, v)`` the KV write + history attention of attention
-    layer ``l``, and ``recur`` the recurrent layers' state
+    layer ``l``, and ``recur`` what the recurrent layers keep
     (``recur.window(l, x)`` pushes this token's convolution input and
-    returns the newest ``ssm_conv``, ``recur.advance(l, decay, dx, b, c)``
-    moves the state one token and returns its read-out; None for a model
-    with no such layer).  ``live`` [B] bool marks the lanes that hold a
-    sequence; ``extras`` is a tuple of small arrays the step returns after
-    its logits (the olmoe block's tokens routed to each expert; nothing
-    for the others)."""
+    returns the newest ``taps``; for a kind with a state,
+    ``recur.advance(l, decay, dx, b, c)`` moves it one token and returns
+    its read-out; None for a model with no such layer).  ``live`` [B] bool
+    marks the lanes that hold a sequence; ``extras`` is a tuple of small
+    arrays the step returns after its logits (a routed block's tokens sent
+    to each expert, a row a layer of ``cfg.routed_layers``; nothing for the
+    others).  The four blocks: ``_token_logits`` here (gpt2), and
+    ``token_logits`` of ``models/olmoe.py``, ``models/granite_hybrid.py``
+    and ``models/lfm2_moe.py``."""
     return _token_logits if cfg.arch == "gpt2" else _model(cfg).token_logits
 
 
@@ -419,7 +507,7 @@ def attention_path(cfg, kv_config, lanes=1):
 def _pool_index(cfg):
     """layer -> its place among the layers of its kind (the index of its
     pools, or of its state arrays, in the cache's groups)."""
-    return {l: i for kind in (cfg.attn_layers, cfg.ssm_layers)
+    return {l: i for kind in (cfg.attn_layers, cfg.recurrent_layers)
             for i, l in enumerate(kind)}
 
 
@@ -429,8 +517,9 @@ class _Recurrent:
     reach recurrent layer ``i``'s window ``[B, (K - 1) * W]`` for the step's
     lanes, and ``advance(i, fresh, decay, dx, b, c) -> y`` moves its state
     one token (``ssm_update.advance``'s mathematics, on values or on the
-    slots of a pool).  A lane at position 0 (``fresh``) starts from zeros
-    whatever is stored."""
+    slots of a pool; None for a kind of layer that keeps a window and no
+    state).  A lane at position 0 (``fresh``) starts from zeros whatever is
+    stored."""
 
     def __init__(self, pool_of, taps, pos, read, write, advance):
         self._at, self._taps = pool_of, taps
@@ -439,8 +528,8 @@ class _Recurrent:
 
     def window(self, l, xbc):
         """Push this token's convolution input ``xbc`` [B, W] -> the
-        ``ssm_conv`` newest inputs [B, K, W] float32, oldest first, this
-        one (as stored) last."""
+        ``taps`` newest inputs [B, K, W] float32, oldest first, this one
+        (as stored) last."""
         i = self._at[l]
         old = _ssm.started(self._fresh, self._read(i))
         new = jnp.concatenate(
@@ -496,6 +585,7 @@ def make_paged_step(cfg, kv_config):
     int8 = kv_config.dtype == "int8"
     block = _block(cfg)
     pool_of = _pool_index(cfg)
+    taps = _conv_window(cfg)[0]
 
     def step(kv_carry, params, tok, pos, block_tables, context_lens,
              state_slots=None):
@@ -538,7 +628,8 @@ def make_paged_step(cfg, kv_config):
         recur = None
         if state:
             slots = state_slots.astype(jnp.int32)
-            windows, states = state
+            # the window, then (for a kind that has one) the state
+            windows, states = state if len(state) == 2 else (state[0], None)
 
             def put(i, value):
                 # a scatter of B slots into a whole donated array, as
@@ -551,9 +642,9 @@ def make_paged_step(cfg, kv_config):
                 return y
 
             recur = _Recurrent(
-                pool_of, cfg.ssm_conv, pos,
+                pool_of, taps, pos,
                 lambda i: jnp.take(windows[i], slots, axis=0, mode="clip"),
-                put, advance)
+                put, advance if states is not None else None)
 
         logits, extras = block(params, cfg, tok, pos, attend,
                                context_lens > 0, recur)
@@ -693,7 +784,8 @@ def make_unpaged_step(cfg, pad_len):
     same [B, pad_len, KH, D] shapes as the paged gather path — the bitwise
     comparison target.  A model with recurrent layers carries their window
     ``[Lr, B, (K - 1) * W]`` and state ``[Lr, B, N, I]`` after K and V, a
-    lane a row, through the same ``_Recurrent`` as the paged step."""
+    lane a row (the window alone where the layers keep no state), through
+    the same ``_Recurrent`` as the paged step."""
     block = _block(cfg)
     pool_of = _pool_index(cfg)
 
@@ -721,9 +813,10 @@ def make_unpaged_step(cfg, pad_len):
             state[1] = state[1].at[i].set(new)
             return y
 
-        recur = _Recurrent(pool_of, cfg.ssm_conv, pos,
+        recur = _Recurrent(pool_of, _conv_window(cfg)[0], pos,
                            lambda i: state[0][i], put,
-                           advance) if state else None
+                           advance if len(state) > 1 else None) \
+            if state else None
         logits, _extras = block(params, cfg, tok, pos, attend,
                                 context_lens > 0, recur)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
@@ -742,7 +835,8 @@ def _unpaged_carry(cfg, lanes, pad_len):
                              cfg.kv_heads, cfg.head_dim), kv_dtype)
                   for _ in range(2))
     return carry + tuple(
-        jnp.zeros((len(cfg.ssm_layers), lanes) + shape, _kv._PAYLOAD[dt][0])
+        jnp.zeros((len(cfg.recurrent_layers), lanes) + shape,
+                  _kv._PAYLOAD[dt][0])
         for shape, dt in _state_shapes(cfg))
 
 

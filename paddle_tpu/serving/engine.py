@@ -902,7 +902,8 @@ class _DecodeSeq:
 class _DecodeModel:
     __slots__ = ("name", "cfg", "params", "kv_config", "cache", "stepfn",
                  "maxb", "attn_path", "blocks_read", "step_ms", "prefix",
-                 "declines", "slot_bytes", "feed0", "__weakref__",
+                 "declines", "slot_bytes", "state_name", "feed0",
+                 "__weakref__",
                  # speculative decode (spec_k == 0 means off): the draft
                  # decoder runs k tokens ahead through its own paged pool,
                  # then verifyfn scores all k+1 positions in one target call
@@ -928,8 +929,11 @@ class _DecodeModel:
         # pos > 0 over K/V blocks alone (prefix reuse, history
         # publication, block adoption, session export), or None
         self.declines = None
-        # one sequence's recurrent state over all such layers, in bytes
+        # one sequence's recurrent state over all such layers, in bytes,
+        # and what its spans, gauge and counter call it (add_model sets
+        # both for a model with recurrent layers)
         self.slot_bytes = 0
+        self.state_name = None
         # what the first step after a pause takes as "the step before's
         # tokens": zeros on the device, never selected (every src is -1)
         self.feed0 = None
@@ -1146,7 +1150,7 @@ class DecodeEngine:
                 else _flag("speculative_k") or 0)
         if draft is None:
             k = 0   # no draft bundle -> non-speculative regardless of k
-        recurrent = bool(cfg.ssm_layers)
+        recurrent = bool(cfg.recurrent_layers)
         if recurrent and k > 0:
             # verify rolls a rejected proposal back by trimming the block
             # table; a recurrent state that has consumed it cannot be
@@ -1222,8 +1226,10 @@ class DecodeEngine:
         if recurrent:
             entry.declines = "recurrent_state"
             entry.slot_bytes = _kvc.slot_bytes(kv_config)
-            _tm.set_gauge("ssm_state_bytes", _kvc.state_bytes(kv_config),
-                          model=name)
+            # what the slots hold names them: ssm_state_*, conv_state_*
+            entry.state_name = cfg.state_name
+            _tm.set_gauge(entry.state_name + "_bytes",
+                          _kvc.state_bytes(kv_config), model=name)
         if k > 0:
             # draft pool mirrors the target's block COUNT (draft blocks
             # are strictly smaller at fewer layers), so any sequence the
@@ -2580,11 +2586,12 @@ class DecodeEngine:
                 # lanes at position 0 start their slot from zeros
                 resets = int((pos[:len(lanes)] == 0).sum())
                 if resets:
-                    _tm.inc("ssm_state_resets_total", resets, model=m.name)
+                    _tm.inc(m.state_name + "_resets_total", resets,
+                            model=m.name)
                 if _tr.enabled():
                     # the recurrent state this step reads and writes
-                    read["ssm_state_lanes"] = len(lanes)
-                    read["ssm_state_bytes"] = len(lanes) * m.slot_bytes
+                    read[m.state_name + "_lanes"] = len(lanes)
+                    read[m.state_name + "_bytes"] = len(lanes) * m.slot_bytes
             sspan = self._open_step_span(m, bucket, lanes, **read)
             args = self._step_args(
                 m, bucket, tok, pos, tables, lens, slots,
@@ -2691,7 +2698,9 @@ class DecodeEngine:
     @staticmethod
     def _moe_attrs(m, extras):
         """A routed-expert step returns the tokens it sent to each expert
-        in each layer (int32 [layers, experts], live lanes only).  The
+        in each layer that routes (int32 [routed layers, experts], live
+        lanes only: a dense layer has no row, so the means are over the
+        layers that route).  The
         caller hands them over only while the step span is being recorded,
         so an untraced window pays for no transfer; a step with no experts
         has none."""
@@ -2701,7 +2710,7 @@ class DecodeEngine:
         hit = float((routed > 0).sum(axis=1).mean())
         _tm.inc("moe_tokens_routed_total", int(routed.sum()), model=m.name)
         _tm.set_gauge("moe_experts_hit", hit, model=m.name)
-        # means over the layers: experts with a token, the fullest
+        # means over the routed layers: experts with a token, the fullest
         # expert's tokens, and the tokens routed (lanes x experts a token)
         return {"moe_experts_hit": round(hit, 3),
                 "moe_load_max": round(float(routed.max(axis=1).mean()), 3),

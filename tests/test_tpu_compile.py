@@ -269,3 +269,61 @@ def test_granite_hybrid_step_updates_both_pools_in_place(one_chip, as_on_tpu):
     found = [line.strip()[:160] for line in text.splitlines()
              if big.search(line)]
     assert not found, found
+
+
+def test_lfm2_moe_step_updates_pools_and_windows_in_place(one_chip,
+                                                          as_on_tpu):
+    """LFM2-24B-A2B's published widths (32 query heads over 8 KV heads of
+    64, 64 experts of width 1536, the dense MLP of 11776, 3 taps), the first
+    three layers of the configuration's cut (conv + dense, attention +
+    experts, conv + experts), bucket 32, the cell's pools (2048 bf16 blocks
+    512 wide, 33 window slots): Mosaic accepts the grouped-query
+    paged-attention kernel inside the whole step at Granite's geometry, the
+    KV pools and the window slots are aliased whole, and beside its
+    arguments the step holds less than one expert's activations would take
+    in float32 for every lane and expert."""
+    from benchmark.models import lfm2_moe_decoder
+
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "lfm2-24b-a2b-serve.json")) as fp:
+        config = json.load(fp)
+    config = dict(config, num_hidden_layers=3,
+                  layer_types=config["layer_types"][:3])
+    cfg = lfm2_moe_decoder.decoder_config(config)
+    assert (cfg.heads, cfg.kv_heads, cfg.head_dim, cfg.conv_taps, cfg.experts,
+            cfg.experts_per_token, cfg.ffn, cfg.dense_ffn, cfg.layer_types,
+            cfg.routed_layers) == (
+        32, 8, 64, 3, 64, 4, 1536, 11776, ("conv", "attention", "conv"),
+        (1, 2))
+    lanes, block_size, blocks = 32, 16, 2048
+    kv = dm.cache_config(cfg, block_size, blocks, state_slots=lanes + 1)
+    assert dm.attention_path(cfg, kv, lanes) == "pallas"
+
+    on_chip = functools.partial(_placed, one_chip)
+
+    carry = on_chip(jax.eval_shape(lambda: PagedKVCache(kv).carry()))
+    params = on_chip({
+        name: jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+        for name, (shape, _kind)
+        in lfm2_moe_decoder.param_shapes(config).items()})
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    feeds = on_chip([i32(lanes), i32(lanes), i32(lanes), i32(lanes),
+                     i32(lanes, cfg.max_seq // block_size), i32(lanes),
+                     i32(lanes)])
+    compiled = jax.jit(dm.make_fed_step(cfg, kv, lanes), donate_argnums=(0,)
+                       ).lower(carry, params, *feeds).compile()
+
+    text = compiled.as_text()
+    assert _kernel_calls(text) == 1            # the one attention layer
+    pool_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in carry)
+    assert pool_bytes == 2 * 2048 * 16 * 512 * 2 + 2 * 33 * 4096 * 2
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= pool_bytes
+    assert memory.temp_size_in_bytes < 64 * 32 * 1536 * 4
+    # nothing of a window pool's shape is copied or rebuilt
+    big = re.compile(
+        r" = bf16\[33,4096\]\S* "
+        r"(copy|select|transpose|slice|dynamic-slice|gather|concatenate)\(")
+    found = [line.strip()[:160] for line in text.splitlines()
+             if big.search(line)]
+    assert not found, found

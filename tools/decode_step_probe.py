@@ -26,11 +26,15 @@ the scopes gain ``layerN/ssm/in_proj``, ``conv``, ``state_update`` and
 (``benchmark/ssm_cost.py``'s state traffic over the scope's device time);
 ``--ssm-update xla`` swaps the kernel for the gather, update and scatter the
 step makes of it off the TPU: the comparison the kernel was adopted by.
-For a routed-expert configuration the result also gives ``moe/experts``'
-achieved bytes/s (``benchmark/moe_cost.py`` over the scope's device time),
-and ``--experts ragged`` swaps the block's expert matmuls for this file's
-``experts_ragged`` (tokens sorted by expert, ``jax.lax.ragged_dot``): the
-comparison the block's choice was made by, not an option of the program.
+For ``--config lfm2-24b-a2b-serve --blocks 2048`` every lane holds a window
+slot and the scopes gain ``layerN/conv/in_proj``, ``window`` and
+``out_proj``.  For a routed-expert configuration the result also gives
+``moe/experts``' achieved bytes/s (``benchmark/moe_cost.py``, or
+``benchmark/lfm2_cost.py`` for a source with its keys, over the scope's
+device time), and ``--experts ragged`` swaps the block's expert matmuls for
+this file's ``experts_ragged`` (tokens sorted by expert,
+``jax.lax.ragged_dot``): the comparison the block's choice was made by, not
+an option of the program.
 
 ``--check`` leaves the model out and compares the step's attention alone,
 at the configuration's shapes, on one layer's random pools and the same
@@ -82,7 +86,8 @@ def scope_of(op_name):
     keep = [p for p in parts
             if p in ("layerN", "attn", "mlp", "moe", "router", "experts",
                      "lm_head", "kv_write", "kv_read", "kv_gather",
-                     "ssm", "in_proj", "conv", "state_update", "out_proj")]
+                     "ssm", "in_proj", "conv", "state_update", "out_proj",
+                     "window")]
     return "/".join(keep) or "other"
 
 
@@ -217,7 +222,7 @@ def main(argv=None):
     import jax
     import numpy as np
 
-    from benchmark import moe_cost, ssm_cost, trace_reduce
+    from benchmark import lfm2_cost, moe_cost, ssm_cost, trace_reduce
     from benchmark.run import load_module
     import paddle_tpu as fluid
     from paddle_tpu.core import telemetry
@@ -233,6 +238,8 @@ def main(argv=None):
     if args.layers:
         config["n_layer" if "n_layer" in config
                else "num_hidden_layers"] = args.layers
+        if "layer_types" in config:
+            config["layer_types"] = config["layer_types"][:args.layers]
     device = jax.devices()[0]
     model = load_module("models", config["model"])
     cfg = model.decoder_config(config)
@@ -289,7 +296,8 @@ def main(argv=None):
     fluid.set_flags({"FLAGS_telemetry": True})
     stepfn = CarriedStepFn(dm.make_paged_step(cfg, kv), donate_argnums=(0,),
                            name="probe")
-    slots = (np.arange(1, b + 1, dtype=np.int32),) if cfg.ssm_layers else ()
+    slots = (np.arange(1, b + 1, dtype=np.int32),) \
+        if cfg.recurrent_layers else ()
     feed = lambda n: (cache.carry(), params, tok, lens + n - 1, tables,
                       lens + n) + slots
     warm = stepfn.warmup(*feed(0))
@@ -361,10 +369,13 @@ def main(argv=None):
              "/".join(index.get(short(n), ("", "", ""))[::2])[:120]]
             for n, s in prof["device_ops"]]
         moe_ms = sum(v for k, v in scopes.items() if k.endswith("experts"))
-        if cfg.arch == "olmoe" and moe_ms:
-            # every expert of every layer, read once a step (32 lanes x 8
-            # over 64 experts leave none unread), over the scope's time
-            moved = moe_cost.expert_stream_bytes_per_step(
+        if cfg.routed_layers and moe_ms:
+            # every expert of every routed layer, read once a step (the
+            # block runs them all, hit or not), over the scope's time; the
+            # cost file is the one that reads this source's keys
+            moved = (lfm2_cost.routed_stream_floor_bytes_per_step
+                     if "moe_intermediate_size" in config
+                     else moe_cost.expert_stream_bytes_per_step)(
                 config, config["num_experts"])
             result["moe_experts_bytes_per_step"] = moved
             result["moe_experts_bytes_per_s"] = moved / (moe_ms / 1e3)
