@@ -49,12 +49,11 @@ products, the convolution and the residual additions are float32.  The
 convolution's window takes its store's dtype (bfloat16 as served); K and V
 leave here in float32 and are cast to the pool's dtype by the step's write.
 
-Routing that drops nothing, shape-static per lane bucket, is
-``olmoe._experts`` as it is: every expert runs over every lane and the
-unchosen are weighted zero.  At 32 lanes x 4 experts over 64, with a seeded
-selection bias, 24-26 experts a layer go unhit and their weights are
-streamed all the same (PERF.md section 6, PR 33, has what that costs on the
-chip).
+Routing that drops nothing, shape-static per lane bucket, is OLMoE's:
+``pallas_kernels/moe_experts.py`` ``routed_experts``.  At 32 lanes x 4
+experts over 64, with a seeded selection bias, 24-26 experts a layer go
+unhit, and on a TPU their weights are not read (PERF.md section 6, PR 34:
+5.8e9 B a step where the einsums streamed 9.66e9).
 
 Params (``init_params`` makes seeded ones): ``embed [V, H]``, ``lnf_g`` and
 per layer ``l<i>_`` + ``ln1_g``, ``ln2_g``; conv layers ``in_proj [H, 3
@@ -69,7 +68,8 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from .olmoe import NP_DTYPES, _experts, _mm, _rmsnorm, _rope
+from ..pallas_kernels import moe_experts as _moe
+from .olmoe import NP_DTYPES, _mm, _rmsnorm, _rope
 
 __all__ = ["token_logits", "param_shapes", "init_params"]
 
@@ -215,8 +215,9 @@ def token_logits(params, cfg, tok, pos, attend, live, recur):
                         routed.append(jnp.sum(chosen & live[:, None], axis=0,
                                               dtype=jnp.int32))
                     with jax.named_scope("experts"):
-                        x = x + _experts(h2, gates, p("wgate"), p("wup"),
-                                         p("wdown"))
+                        x = x + _moe.routed_experts(
+                            h2, gates, live, p("wgate"), p("wup"),
+                            p("wdown"))
     with jax.named_scope("lm_head"):
         hx = _rmsnorm(x, params["lnf_g"], eps).astype(embed.dtype)
         logits = jax.lax.dot_general(

@@ -28,12 +28,13 @@ served, float32 in the CPU parity tests) and accumulate in float32
 softmax, RoPE and the residual additions are float32.  K and V leave here
 in float32 and are cast to the pool's dtype by the step's one write.
 
-Routing that drops nothing, shape-static per lane bucket: every expert
-runs over every lane (``[E, B, F]``) and the unchosen are weighted zero.
-At a decode bucket of 32 lanes x 8 experts over 64, every expert is hit in
-nearly every layer of every step anyway, so the step streams all expert
-weights either way and the extra matmul rows ride under that stream
-(PERF.md section 6, PR 27, has the chip's numbers beside ``ragged_dot``).
+Routing that drops nothing, shape-static per lane bucket, is
+``pallas_kernels/moe_experts.py`` ``routed_experts``: on a TPU one kernel a
+layer reads the experts some live lane chose, steered by the list of them,
+and nothing of the others (PERF.md section 6, PR 34: it streams this model's
+63 of 64 hit experts at 752 GB/s where the einsums read all 64 at 706);
+elsewhere every expert runs over every lane (``[E, B, F]``) with the
+unchosen weighted zero, which is the same sum.
 
 Params (``init_params`` makes seeded ones): ``embed [V, H]``, ``head [H,
 V]``, ``lnf_g [H]`` and per layer ``l<i>_`` + ``ln1_g``, ``wq``, ``wk``,
@@ -44,6 +45,8 @@ V]``, ``lnf_g [H]`` and per layer ``l<i>_`` + ``ln1_g``, ``wq``, ``wk``,
 import jax
 import jax.numpy as jnp
 import numpy as np
+
+from ..pallas_kernels import moe_experts as _moe
 
 __all__ = ["token_logits", "param_shapes", "init_params", "NP_DTYPES"]
 
@@ -112,18 +115,6 @@ def _route(h2, router, k):
     return jnp.where(chosen, p, 0.0), chosen
 
 
-def _experts(h2, gates, wgate, wup, wdown):
-    """sum_e gates[b, e] * expert_e(h2[b]): all experts over all lanes,
-    the unchosen weighted zero."""
-    hx = h2.astype(wgate.dtype)
-    up = lambda w: jnp.einsum("bh,ehf->ebf", hx, w,
-                              preferred_element_type=jnp.float32)
-    act = jax.nn.silu(up(wgate)) * up(wup)
-    y = jnp.einsum("ebf,efh->ebh", act.astype(wdown.dtype), wdown,
-                   preferred_element_type=jnp.float32)
-    return jnp.sum(y * gates.T[:, :, None], axis=0)
-
-
 def token_logits(params, cfg, tok, pos, attend, live, recur=None):
     """-> (logits [B, vocab] float32, (routed,)) with ``routed``
     int32 [layers, experts]: the tokens of live lanes sent to each expert
@@ -156,8 +147,8 @@ def token_logits(params, cfg, tok, pos, attend, live, recur=None):
                     routed.append(jnp.sum(chosen & live[:, None], axis=0,
                                           dtype=jnp.int32))
                 with jax.named_scope("experts"):
-                    x = x + _experts(h2, gates, p("wgate"), p("wup"),
-                                     p("wdown"))
+                    x = x + _moe.routed_experts(
+                        h2, gates, live, p("wgate"), p("wup"), p("wdown"))
     with jax.named_scope("lm_head"):
         logits = _mm(_rmsnorm(x, params["lnf_g"], eps), params["head"])
     return logits, (jnp.stack(routed),)

@@ -8,7 +8,7 @@ whether it engages.  A family is chosen from what the lowering can observe
   check list; the first failing check becomes the fallback *reason*.  The
   checks live beside the kernel that owns them (``fused_ln_checks``,
   ``flash_attention_checks``, ``paged_attention_checks``,
-  ``ssm_update_checks``).
+  ``ssm_update_checks``, ``moe_experts_checks``).
 * **telemetry** — every decision increments
   ``pallas_kernel_used_total{kernel}`` or
   ``pallas_kernel_fallback_total{kernel,reason}`` in the telemetry
@@ -35,7 +35,8 @@ __all__ = ["decide", "active_kernels", "reset", "interpret_mode",
            "interpret", "auto_partitioned", "shape_inference", "KERNELS"]
 
 # the kernel families sharing this funnel
-KERNELS = ("fused_ln", "flash_attention", "paged_attention", "ssm_update")
+KERNELS = ("fused_ln", "flash_attention", "paged_attention", "ssm_update",
+           "moe_experts")
 
 _lock = threading.Lock()
 _active = set()          # kernels that engaged >= 1 time this process

@@ -17,7 +17,7 @@ shapes):
     canonical EP schedule.
 
 This is the trainer's routing.  Serving routes in ``models/olmoe.py``
-(``_route`` / ``_experts``) and differs on purpose: there every token is
+(``_route``, then ``pallas_kernels/moe_experts.py``) and differs on purpose: there every token is
 computed by exactly its chosen experts with the published softmax weights,
 so a served logit can equal a reference's; capacity (and the dropped
 tokens it implies), renormalised gates and the auxiliary loss are what a

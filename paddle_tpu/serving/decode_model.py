@@ -67,6 +67,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..pallas_kernels import moe_experts as _moe
 from ..pallas_kernels import paged_attention as _pa
 from ..pallas_kernels import ssm_update as _ssm
 from ..pallas_kernels.paged_attention import gather_blocks, \
@@ -75,7 +76,8 @@ from . import kv_cache as _kv
 
 __all__ = ["DecoderConfig", "init_decoder_params", "save_decoder",
            "load_decoder", "is_decoder_dir", "has_draft", "load_draft",
-           "truncate_decoder", "attention_path", "make_paged_step",
+           "truncate_decoder", "attention_path", "experts_path",
+           "make_paged_step",
            "make_fed_step", "make_paged_step_multi",
            "make_draft_rollout", "make_unpaged_step", "unpaged_generate",
            "cache_config"]
@@ -502,6 +504,17 @@ def attention_path(cfg, kv_config, lanes=1):
         (kv_config.num_blocks, kv_config.block_size,
          kv_config.heads * kv_config.head_dim),
         _kv._PAYLOAD[kv_config.dtype][0])
+
+
+def experts_path(cfg, params, lanes=1):
+    """``"pallas"`` where a routed layer's experts are the kernel that
+    reads the experts hit and no others, for this model's weights on this
+    backend at a bucket of ``lanes``; ``"einsum"`` where every expert is
+    streamed; None for a model with no routed layer."""
+    if not cfg.routed_layers:
+        return None
+    wgate = params["l%d_wgate" % cfg.routed_layers[0]]
+    return _moe.experts_path(lanes, wgate.shape, wgate.dtype)
 
 
 def _pool_index(cfg):

@@ -901,7 +901,8 @@ class _DecodeSeq:
 
 class _DecodeModel:
     __slots__ = ("name", "cfg", "params", "kv_config", "cache", "stepfn",
-                 "maxb", "attn_path", "blocks_read", "step_ms", "prefix",
+                 "maxb", "attn_path", "experts_path", "blocks_read",
+                 "step_ms", "prefix",
                  "declines", "slot_bytes", "state_name", "feed0",
                  "__weakref__",
                  # speculative decode (spec_k == 0 means off): the draft
@@ -923,6 +924,10 @@ class _DecodeModel:
         # (add_model sets both)
         self.attn_path = None
         self.blocks_read = None
+        # bucket -> how a routed layer's experts are read at that many
+        # lanes ("pallas": the experts hit alone | "einsum": all of them);
+        # empty for a model with no routed layer (add_model sets it)
+        self.experts_path = {}
         self.step_ms = 0.0          # EWMA of one decode step
         self.prefix = None          # PrefixCache (FLAGS_prefix_cache)
         # why this model declines what starts or moves a sequence at
@@ -1200,21 +1205,27 @@ class DecodeEngine:
         # where the recurrent layers' state at that position is nowhere
         jparams = {key: jnp.asarray(v) for key, v in params.items()}
         attn_path = _dm.attention_path(cfg, kv_config, max(self.buckets))
+        experts_path = {b: _dm.experts_path(cfg, jparams, b)
+                        for b in self.buckets if cfg.routed_layers}
+        # the paths the step's attention and (a routed model's) experts
+        # take: an executable compiled for one is never restored for the
+        # other
+        paths = {"attention": attn_path}
+        if experts_path:
+            paths["experts"] = sorted(experts_path.items())
         stepfn = CarriedStepFn(
             # make_paged_step's step with the token feed on the device:
             # still one executable an engine step
             _dm.make_fed_step(cfg, kv_config, max(self.buckets)),
             donate_argnums=(0,), name="decode_step",
-            key_parts={"kind": "decode_step", "model": name,
-                       "cfg": cfg.to_dict(),
-                       "kv": {"block_size": kv_config.block_size,
-                              "num_blocks": kv_config.num_blocks,
-                              "dtype": kv_config.dtype},
-                       # the path the step's attention takes: an executable
-                       # compiled for one is never restored for the other
-                       "attention": attn_path})
+            key_parts=dict(paths, kind="decode_step", model=name,
+                           cfg=cfg.to_dict(),
+                           kv={"block_size": kv_config.block_size,
+                               "num_blocks": kv_config.num_blocks,
+                               "dtype": kv_config.dtype}))
         entry = _DecodeModel(name, cfg, jparams, kv_config, cache, stepfn)
         entry.attn_path = attn_path
+        entry.experts_path = experts_path
         entry.blocks_read = functools.partial(
             _pa.blocks_read, block_size=kv_config.block_size,
             maxb=entry.maxb, path=attn_path)
@@ -1238,12 +1249,12 @@ class DecodeEngine:
             # reports the exact combined pool bytes afterwards
             draft_kv = _dm.cache_config(dcfg, kv_config.block_size, n,
                                         kv_config.dtype)
-            base_parts = {"model": name, "kv": {
+            base_parts = dict(paths, model=name, kv={
                 "block_size": kv_config.block_size, "num_blocks": n,
                 "dtype": kv_config.dtype},
-                "attention": [attn_path,
-                              _dm.attention_path(dcfg, draft_kv,
-                                                 max(self.buckets))]}
+                attention=[attn_path,
+                           _dm.attention_path(dcfg, draft_kv,
+                                              max(self.buckets))])
             entry.spec_k = k
             entry.draft_cfg = dcfg
             entry.draft_params = {key: jnp.asarray(v)
@@ -1309,12 +1320,15 @@ class DecodeEngine:
             # much of them (the KV pool) it updates in their own buffers
             _tm.inc("serving_prewarm_total", model=model,
                     source=got["source"])
+            m = self._models[model]
+            if m.experts_path:
+                extra["experts"] = m.experts_path[bucket]
             _tm.event("serving_prewarm", model=model, bucket=bucket,
                       source=got["source"], decode=True, fn=fn,
                       ms=round(got["compile_ms"], 3),
                       temp_bytes=got["temp_bytes"],
                       alias_bytes=got["alias_bytes"],
-                      attention=self._models[model].attn_path, **extra)
+                      attention=m.attn_path, **extra)
             for key in ("temp_bytes", "alias_bytes"):
                 if got[key] is not None:
                     _tm.set_gauge("serving_step_" + key, got[key],
@@ -2641,7 +2655,7 @@ class DecodeEngine:
         try:
             with _tr.phase("serving.fetch"):
                 nxt = np.asarray(flight.nxt)
-                moe = self._moe_attrs(m, flight.extras)
+                moe = self._moe_attrs(m, flight.bucket, flight.extras)
         except Exception as e:
             self._fail_lanes_locked(m, [s for _, s in flight.live()], str(e))
             return {"error": str(e)[:200]}
@@ -2696,20 +2710,24 @@ class DecodeEngine:
                     published=published)
 
     @staticmethod
-    def _moe_attrs(m, extras):
+    def _moe_attrs(m, bucket, extras):
         """A routed-expert step returns the tokens it sent to each expert
         in each layer that routes (int32 [routed layers, experts], live
         lanes only: a dense layer has no row, so the means are over the
         layers that route).  The
         caller hands them over only while the step span is being recorded,
         so an untraced window pays for no transfer; a step with no experts
-        has none."""
+        has none.  Where the step's experts are the kernel's, an expert
+        with no token was not read: counted."""
         if not extras:
             return {}
         routed = np.asarray(extras[0])
         hit = float((routed > 0).sum(axis=1).mean())
         _tm.inc("moe_tokens_routed_total", int(routed.sum()), model=m.name)
         _tm.set_gauge("moe_experts_hit", hit, model=m.name)
+        if m.experts_path.get(bucket) == "pallas":
+            _tm.inc("moe_expert_reads_skipped_total",
+                    int((routed == 0).sum()), model=m.name)
         # means over the routed layers: experts with a token, the fullest
         # expert's tokens, and the tokens routed (lanes x experts a token)
         return {"moe_experts_hit": round(hit, 3),

@@ -37,6 +37,7 @@ from paddle_tpu.pallas_kernels import adoption
 from paddle_tpu.pallas_kernels import fused_ln
 from paddle_tpu.pallas_kernels.flash_attention import flash_attention_checks
 from paddle_tpu.pallas_kernels import paged_attention as pa
+from paddle_tpu.pallas_kernels import moe_experts
 from paddle_tpu.pallas_kernels import ssm_update
 
 _FLAGS = ("FLAGS_deterministic_reduction", "FLAGS_telemetry")
@@ -87,6 +88,9 @@ _ELIGIBLE = {
     # granite-4.0-h-micro's state slots: 33 of [128, 64 heads x 64]
     "ssm_update": lambda: ssm_update.ssm_update_checks(
         (33, 128, 4096), "float32", 32),
+    # LFM2-24B-A2B's experts at 32 lanes: 64 of [2048, 1536] in bf16
+    "moe_experts": lambda: moe_experts.moe_experts_checks(
+        32, (64, 2048, 1536), "bfloat16"),
 }
 
 RULE = {
@@ -129,6 +133,18 @@ RULE = {
     "ssm_update-sublanes": (
         "ssm_update", lambda: ssm_update.ssm_update_checks(
             (33, 100, 4096), "float32", 32), True, "sublanes"),
+    "moe_experts-backend": (
+        "moe_experts", _ELIGIBLE["moe_experts"], False, "backend"),
+    "moe_experts-dtype": (
+        "moe_experts", lambda: moe_experts.moe_experts_checks(
+            32, (64, 2048, 1536), "int8"), True, "dtype"),
+    "moe_experts-lanes": (
+        "moe_experts", lambda: moe_experts.moe_experts_checks(
+            32, (64, 2048, 1536 + 64), "bfloat16"), True, "lanes"),
+    "moe_experts-vmem": (
+        # no 128-column chunk of a 32768-wide hidden fits twice
+        "moe_experts", lambda: moe_experts.moe_experts_checks(
+            32, (8, 32768, 1024), "bfloat16"), True, "vmem"),
 }
 for _family in adoption.KERNELS:
     for _kind in ("gspmd_mesh", "shape_inference"):

@@ -31,10 +31,21 @@ slot and the scopes gain ``layerN/conv/in_proj``, ``window`` and
 ``out_proj``.  For a routed-expert configuration the result also gives
 ``moe/experts``' achieved bytes/s (``benchmark/moe_cost.py``, or
 ``benchmark/lfm2_cost.py`` for a source with its keys, over the scope's
-device time), and ``--experts ragged`` swaps the block's expert matmuls for
-this file's ``experts_ragged`` (tokens sorted by expert,
-``jax.lax.ragged_dot``): the comparison the block's choice was made by, not
-an option of the program.
+device time) and ``--experts`` names the forms of the routed layer to run,
+one after another in this one process over the same weights, a result line
+each: ``block`` (what the program picks: ``moe_experts.routed_experts``),
+``dense`` (the einsums over all experts, the fallback), ``kernel`` (the
+Pallas kernel that reads the hit experts alone, whatever the shape rule
+says) and ``ragged`` (this file's ``experts_ragged``: tokens sorted by
+expert, ``jax.lax.ragged_dot``).  Each line has ``moe/experts``' ms a step,
+the expert bytes that form reads a step (``dense`` all of them, the others
+the experts this step's tokens hit, from the step's own routed counts) and
+bytes/s: the comparison the block's choice was made by, not an option of the
+program.  (XLA's expansion of ``ragged_dot`` loses the scope, so that form's
+expert time lands in ``other``: compare its ``busy_ms_per_step``.)
+
+    chiprun -- python tools/decode_step_probe.py --config \
+        lfm2-24b-a2b-serve --blocks 2048 --experts dense,ragged,kernel
 
 ``--check`` leaves the model out and compares the step's attention alone,
 at the configuration's shapes, on one layer's random pools and the same
@@ -61,6 +72,9 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 sys.path.insert(0, ROOT)
 
 WARM_STEPS = 5
+
+# --experts: what stands in for moe_experts.routed_experts
+EXPERT_FORMS = ("block", "dense", "kernel", "ragged")
 
 
 def hlo_index(text):
@@ -106,13 +120,14 @@ def pool_sized(index, pool_elems):
 
 
 def experts_ragged(k):
-    """``models/olmoe.py`` ``_experts`` by sorting: each lane's ``k``
+    """``moe_experts.routed_experts`` by sorting: each lane's ``k``
     chosen experts become ``B * k`` rows ordered by expert, and one
     ``ragged_dot`` per projection runs each expert over its own rows."""
     import jax
     import jax.numpy as jnp
 
-    def experts(h2, gates, wgate, wup, wdown):
+    def experts(h2, gates, live, wgate, wup, wdown):
+        del live                     # every lane of the probe holds a sequence
         weight, idx = jax.lax.top_k(gates, k)
         order = jnp.argsort(idx.reshape(-1))
         lane = order // k
@@ -193,13 +208,132 @@ def check_attention(cfg, kv, tables, lens, seed, repeat=24):
             "table_slots": int(tables.size)}
 
 
+def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
+    """Compile the step as ``moe_experts.routed_experts`` stands now, and on
+    a TPU run, time and profile it -> the result line of ``form``, which
+    reads every expert (``reads_all``) or the hit ones."""
+    import jax
+    import numpy as np
+
+    from benchmark import lfm2_cost, moe_cost, ssm_cost, trace_reduce
+    from paddle_tpu.core import telemetry
+    from paddle_tpu.core.executor import CarriedStepFn
+    from paddle_tpu.serving import decode_model as dm
+    from paddle_tpu.serving import kv_cache as kvc
+
+    b = args.bucket
+    stepfn = CarriedStepFn(dm.make_paged_step(cfg, kv), donate_argnums=(0,),
+                           name="probe")
+    warm = stepfn.warmup(*feed(0))
+    compiled = stepfn._compiled[stepfn._sig(feed(0))]
+    memory = compiled.memory_analysis()
+    text = compiled.as_text()
+    if args.hlo_out:
+        os.makedirs(os.path.dirname(args.hlo_out) or ".", exist_ok=True)
+        with open(args.hlo_out, "w") as fp:
+            fp.write(text)
+    index = hlo_index(text)
+    # one layer's K (or V) pool, or a recurrent layer's state slots,
+    # whichever is smaller: a copy of either is what the search is for
+    pool_elems = args.blocks * args.block_size * kv.heads * kv.head_dim
+    if cfg.ssm_layers:
+        pool_elems = min(pool_elems,
+                         kv.state_slots * cfg.ssm_state * cfg.ssm_inner)
+    result = {
+        "label": args.label, "config": config["name"],
+        "experts": form, "ssm_update": args.ssm_update,
+        "device": device.device_kind,
+        "platform": device.platform, "blocks": args.blocks,
+        "bucket": b, "dtype": args.dtype, "layers": cfg.layers,
+        "memory": {"temp_bytes": int(memory.temp_size_in_bytes),
+                   "alias_bytes": int(memory.alias_size_in_bytes),
+                   "pool_bytes": cache.kv_nbytes,
+                   "state_bytes": kvc.state_bytes(kv),
+                   "compile_ms": round(warm["compile_ms"], 1),
+                   "pool_sized_instructions": pool_sized(index, pool_elems)},
+        "attention": dm.attention_path(cfg, kv, b),
+        "pallas_kernel_counters": {
+            key: value for key, value in telemetry.snapshot()["counters"].items()
+            if key.startswith("pallas_kernel_")},
+    }
+    if args.compile_only:
+        return result
+    if device.platform != "tpu":
+        print("decode_step_probe: no TPU, so no time (use --compile-only)",
+              file=sys.stderr)
+        return result
+
+    routed = []
+
+    def run(n0, n):
+        for i in range(n0, n0 + n):
+            carry, nxt, _logits, *extras = stepfn(*feed(i))
+            cache.replace_carry(carry)
+            nxt.block_until_ready()
+            routed.extend(extras[:1])
+
+    run(0, WARM_STEPS)
+    t0 = time.perf_counter()
+    run(WARM_STEPS, args.steps)
+    result["step_ms"] = (time.perf_counter() - t0) * 1e3 / args.steps
+    trace_dir = tempfile.mkdtemp(prefix="decode_step_probe_")
+    options = jax.profiler.ProfileOptions()
+    options.python_tracer_level = 0
+    jax.profiler.start_trace(trace_dir, profiler_options=options)
+    with jax.profiler.TraceAnnotation(trace_reduce.WINDOW_ANNOTATION):
+        run(WARM_STEPS + args.steps, args.steps)
+    jax.profiler.stop_trace()
+    prof = trace_reduce.reduce_dir(trace_dir, top=12)
+    scopes = {}
+    short = lambda name: trace_reduce._short(name).lstrip("%_")
+    for name, secs in prof["op_seconds"].items():
+        key = scope_of(index.get(short(name), ("", "", ""))[2])
+        scopes[key] = scopes.get(key, 0.0) + secs * 1e3 / args.steps
+    result["busy_ms_per_step"] = prof["busy_s"] * 1e3 / args.steps
+    result["scope_ms_per_step"] = dict(
+        sorted(scopes.items(), key=lambda kv: -kv[1]))
+    result["top_ops"] = [
+        [n, round(s * 1e3 / args.steps, 4),
+         "/".join(index.get(short(n), ("", "", ""))[::2])[:120]]
+        for n, s in prof["device_ops"]]
+    moe_ms = sum(v for k, v in scopes.items() if k.endswith("experts"))
+    if cfg.routed_layers and moe_ms:
+        # the experts this form reads in a routed layer, once a step:
+        # every one for the einsums (hit or not), the profiled steps' hit
+        # ones for the others; over the scope's time.  The cost file is the
+        # one that reads this source's keys
+        hit = float(np.mean([(np.asarray(r) > 0).sum(axis=1).mean()
+                             for r in routed[-args.steps:]]))
+        moved = (lfm2_cost.routed_stream_floor_bytes_per_step
+                 if "moe_intermediate_size" in config
+                 else moe_cost.expert_stream_bytes_per_step)(
+            config, config["num_experts"] if reads_all else hit)
+        result["moe_experts_hit_per_layer"] = hit
+        result["moe_experts_ms_per_step"] = moe_ms
+        result["moe_experts_bytes_per_step"] = moved
+        result["moe_experts_bytes_per_s"] = moved / (moe_ms / 1e3)
+    ssm_ms = sum(v for k, v in scopes.items()
+                 if k.endswith("ssm/state_update"))
+    if cfg.ssm_layers and ssm_ms:
+        # every lane's state in every mamba layer, read and written
+        # once a step, over the scope's time
+        moved = ssm_cost.state_traffic_bytes_per_step(config, b)
+        result["ssm_state_bytes_per_step"] = moved
+        result["ssm_state_update_bytes_per_s"] = moved / (ssm_ms / 1e3)
+    stats = device.memory_stats() or {}
+    result["peak_bytes_in_use"] = stats.get("peak_bytes_in_use")
+    result["peak_bytes_reserved"] = stats.get("peak_bytes_reserved")
+    return result
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser()
     ap.add_argument("--config", default="gpt2-medium-serve",
                     help="a serving configuration of the benchmark: a name "
                     "under benchmark/configs/ or a path to such a file")
     ap.add_argument("--experts", default="block",
-                    choices=("block", "ragged"))
+                    help="forms of the routed layer to run, comma separated: "
+                    + ", ".join(EXPERT_FORMS))
     ap.add_argument("--ssm-update", default="step", choices=("step", "xla"),
                     help="xla: the state update as gather, update, scatter "
                     "(what the step does off the TPU) in place of the kernel")
@@ -222,11 +356,10 @@ def main(argv=None):
     import jax
     import numpy as np
 
-    from benchmark import lfm2_cost, moe_cost, ssm_cost, trace_reduce
     from benchmark.run import load_module
     import paddle_tpu as fluid
     from paddle_tpu.core import telemetry
-    from paddle_tpu.core.executor import CarriedStepFn
+    from paddle_tpu.pallas_kernels import moe_experts
     from paddle_tpu.serving import decode_model as dm
     from paddle_tpu.serving import kv_cache as kvc
 
@@ -243,10 +376,9 @@ def main(argv=None):
     device = jax.devices()[0]
     model = load_module("models", config["model"])
     cfg = model.decoder_config(config)
-    if args.experts == "ragged":
-        from paddle_tpu.models import olmoe
-
-        olmoe._experts = experts_ragged(cfg.experts_per_token)
+    forms = args.experts.split(",")
+    if set(forms) - set(EXPERT_FORMS):
+        ap.error("--experts takes " + ", ".join(EXPERT_FORMS))
     if args.ssm_update == "xla":
         from paddle_tpu.pallas_kernels import ssm_update
 
@@ -292,108 +424,33 @@ def main(argv=None):
         return 0
 
     params = model.make_params(config, args.seed, device)
-    # count which attention path each layer's lowering takes
+    # count which path each layer's lowering takes
     fluid.set_flags({"FLAGS_telemetry": True})
-    stepfn = CarriedStepFn(dm.make_paged_step(cfg, kv), donate_argnums=(0,),
-                           name="probe")
     slots = (np.arange(1, b + 1, dtype=np.int32),) \
         if cfg.recurrent_layers else ()
     feed = lambda n: (cache.carry(), params, tok, lens + n - 1, tables,
                       lens + n) + slots
-    warm = stepfn.warmup(*feed(0))
-    compiled = stepfn._compiled[stepfn._sig(feed(0))]
-    memory = compiled.memory_analysis()
-    text = compiled.as_text()
-    if args.hlo_out:
-        os.makedirs(os.path.dirname(args.hlo_out) or ".", exist_ok=True)
-        with open(args.hlo_out, "w") as fp:
-            fp.write(text)
-    index = hlo_index(text)
-    # one layer's K (or V) pool, or a recurrent layer's state slots,
-    # whichever is smaller: a copy of either is what the search is for
-    pool_elems = args.blocks * args.block_size * kv.heads * kv.head_dim
-    if cfg.ssm_layers:
-        pool_elems = min(pool_elems,
-                         kv.state_slots * cfg.ssm_state * cfg.ssm_inner)
-    result = {
-        "label": args.label, "config": config["name"],
-        "experts": args.experts, "ssm_update": args.ssm_update,
-        "device": device.device_kind,
-        "platform": device.platform, "blocks": args.blocks,
-        "bucket": b, "dtype": args.dtype, "layers": cfg.layers,
-        "memory": {"temp_bytes": int(memory.temp_size_in_bytes),
-                   "alias_bytes": int(memory.alias_size_in_bytes),
-                   "pool_bytes": cache.kv_nbytes,
-                   "state_bytes": kvc.state_bytes(kv),
-                   "compile_ms": round(warm["compile_ms"], 1),
-                   "pool_sized_instructions": pool_sized(index, pool_elems)},
-        "attention": dm.attention_path(cfg, kv, b),
-        "pallas_kernel_counters": {
-            key: value for key, value in telemetry.snapshot()["counters"].items()
-            if key.startswith("pallas_kernel_")},
-    }
-    if not args.compile_only:
-        if device.platform != "tpu":
-            print("decode_step_probe: no TPU, so no time "
-                  "(use --compile-only)", file=sys.stderr)
-            return 2
-
-        def run(n0, n):
-            for i in range(n0, n0 + n):
-                carry, nxt = stepfn(*feed(i))[:2]
-                cache.replace_carry(carry)
-                nxt.block_until_ready()
-
-        run(0, WARM_STEPS)
-        t0 = time.perf_counter()
-        run(WARM_STEPS, args.steps)
-        result["step_ms"] = (time.perf_counter() - t0) * 1e3 / args.steps
-        trace_dir = tempfile.mkdtemp(prefix="decode_step_probe_")
-        options = jax.profiler.ProfileOptions()
-        options.python_tracer_level = 0
-        jax.profiler.start_trace(trace_dir, profiler_options=options)
-        with jax.profiler.TraceAnnotation(trace_reduce.WINDOW_ANNOTATION):
-            run(WARM_STEPS + args.steps, args.steps)
-        jax.profiler.stop_trace()
-        prof = trace_reduce.reduce_dir(trace_dir, top=12)
-        scopes = {}
-        short = lambda name: trace_reduce._short(name).lstrip("%_")
-        for name, secs in prof["op_seconds"].items():
-            key = scope_of(index.get(short(name), ("", "", ""))[2])
-            scopes[key] = scopes.get(key, 0.0) + secs * 1e3 / args.steps
-        result["busy_ms_per_step"] = prof["busy_s"] * 1e3 / args.steps
-        result["scope_ms_per_step"] = dict(
-            sorted(scopes.items(), key=lambda kv: -kv[1]))
-        result["top_ops"] = [
-            [n, round(s * 1e3 / args.steps, 4),
-             "/".join(index.get(short(n), ("", "", ""))[::2])[:120]]
-            for n, s in prof["device_ops"]]
-        moe_ms = sum(v for k, v in scopes.items() if k.endswith("experts"))
-        if cfg.routed_layers and moe_ms:
-            # every expert of every routed layer, read once a step (the
-            # block runs them all, hit or not), over the scope's time; the
-            # cost file is the one that reads this source's keys
-            moved = (lfm2_cost.routed_stream_floor_bytes_per_step
-                     if "moe_intermediate_size" in config
-                     else moe_cost.expert_stream_bytes_per_step)(
-                config, config["num_experts"])
-            result["moe_experts_bytes_per_step"] = moved
-            result["moe_experts_bytes_per_s"] = moved / (moe_ms / 1e3)
-        ssm_ms = sum(v for k, v in scopes.items()
-                     if k.endswith("ssm/state_update"))
-        if cfg.ssm_layers and ssm_ms:
-            # every lane's state in every mamba layer, read and written
-            # once a step, over the scope's time
-            moved = ssm_cost.state_traffic_bytes_per_step(config, b)
-            result["ssm_state_bytes_per_step"] = moved
-            result["ssm_state_update_bytes_per_s"] = moved / (ssm_ms / 1e3)
-        stats = device.memory_stats() or {}
-        result["peak_bytes_in_use"] = stats.get("peak_bytes_in_use")
-        result["peak_bytes_reserved"] = stats.get("peak_bytes_reserved")
-    with open(os.path.join(out_dir, "decode_step_probe.jsonl"), "a") as fp:
-        fp.write(json.dumps(result) + "\n")
-    print(json.dumps(result))
-    return 0
+    block_experts = moe_experts.routed_experts
+    # form -> (what stands in for routed_experts, whether it reads every
+    # expert of a layer or the hit ones)
+    swapped = {
+        "block": (block_experts,
+                  dm.experts_path(cfg, params, b) != "pallas"),
+        "dense": (lambda h2, gates, live, *w: moe_experts.experts_reference(
+            h2, gates, *w), True),
+        "kernel": (moe_experts._experts_pallas, False),
+        "ragged": (experts_ragged(cfg.experts_per_token), False)}
+    for form in forms:
+        moe_experts.routed_experts, reads_all = swapped[form]
+        telemetry.reset()
+        result = probe_step(args, form, reads_all, config, cfg, kv, cache,
+                            device, feed)
+        with open(os.path.join(out_dir, "decode_step_probe.jsonl"),
+                  "a") as fp:
+            fp.write(json.dumps(result) + "\n")
+        print(json.dumps(result))
+    moe_experts.routed_experts = block_experts
+    return 0 if device.platform == "tpu" or args.compile_only else 2
 
 
 if __name__ == "__main__":
