@@ -128,14 +128,20 @@ class _CompiledPlan:
     wrapper when the eager path had to fall back.  ``jit_fn`` keeps the
     jit wrapper either way for tools that need ``.lower()`` (hbm audit)."""
 
-    __slots__ = ("plan", "jfn", "mesh", "data_axis", "jit_fn")
+    __slots__ = ("plan", "jfn", "mesh", "data_axis", "jit_fn",
+                 "param_shardings")
 
-    def __init__(self, plan, jfn, mesh=None, data_axis=None, jit_fn=None):
+    def __init__(self, plan, jfn, mesh=None, data_axis=None, jit_fn=None,
+                 param_shardings=None):
         self.plan = plan
         self.jfn = jfn
         self.mesh = mesh
         self.data_axis = data_axis
         self.jit_fn = jit_fn if jit_fn is not None else jfn
+        # {persistable name: NamedSharding} on the entry's mesh (None
+        # without one): where run() wants each ro/rw input, computed once
+        # in _build, so the hit path only compares
+        self.param_shardings = param_shardings
 
 
 class _BuildResult:
@@ -144,16 +150,17 @@ class _BuildResult:
     trace, without having traced anything yet."""
 
     __slots__ = ("plan", "fn", "donate", "mesh", "data_axis",
-                 "out_shardings")
+                 "out_shardings", "param_shardings")
 
     def __init__(self, plan, fn, donate, mesh=None, data_axis=None,
-                 out_shardings=None):
+                 out_shardings=None, param_shardings=None):
         self.plan = plan
         self.fn = fn
         self.donate = donate
         self.mesh = mesh
         self.data_axis = data_axis
         self.out_shardings = out_shardings
+        self.param_shardings = param_shardings
 
 
 # XLA:CPU only: an executable that jax's persistent cache (tier A) served
@@ -665,13 +672,16 @@ class Executor:
             rng = np.asarray([seed & 0xFFFFFFFF, counter & 0xFFFFFFFF],
                              dtype=np.uint32)
 
+        params_placed = params_passed = 0
         if mesh is not None:
             with _tracing.phase("executor.shard_feeds"):
                 feed_arrays = self._shard_feeds(feed_arrays, mesh,
                                                 data_axis)
             with _tracing.phase("executor.shard_params"):
-                params_ro = self._shard_params(params_ro, mesh, block)
-                params_rw = self._shard_params(params_rw, mesh, block)
+                params_ro, params_rw, params_placed, params_passed = \
+                    self._place_params(
+                        scope, (entry or build).param_shardings, params_ro,
+                        params_rw, place_all=entry is None)
 
         cstats = None
         if entry is None:
@@ -706,6 +716,9 @@ class Executor:
                 _telemetry.set_info("memory_audit", report)
 
         sspan.annotate(step=int(counter), cache_hit=cache_hit)
+        if mesh is not None:
+            sspan.annotate(params_placed=params_placed,
+                           params_passed=params_passed)
         t_step = time.perf_counter() if tel else 0.0
         try:
             with ctx, _tracing.phase("executor.dispatch"):
@@ -747,7 +760,9 @@ class Executor:
                     donated=0 if no_donate else
                     len(params_rw) + len(params_carry),
                     feed_bytes=feed_bytes, fetch_bytes=fetch_bytes,
-                    carry_hits=carry_hits, carry_converts=carry_converts)
+                    carry_hits=carry_hits, carry_converts=carry_converts,
+                    params_placed=params_placed,
+                    params_passed=params_passed)
                 cmeta = getattr(program, "_collective_meta", None)
                 if cmeta and cmeta.get("wire_bytes_per_step"):
                     # analytic bytes-on-ICI for the step's gradient
@@ -919,18 +934,23 @@ class Executor:
                 return fetches, updated, {}
 
             return _BuildResult(plan, _with_seed_counter(fn5), donate,
-                                mesh, "data")
+                                mesh, "data",
+                                param_shardings=self._param_shardings(
+                                    mesh, block, plan))
         fn = _with_seed_counter(build_block_fn(plan, mesh=mesh))
         if mesh is None:
             return _BuildResult(plan, fn, donate)
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         replicated = NamedSharding(mesh, P())
+        targets = self._param_shardings(mesh, block, plan)
+        # the step hands every written persistable back where it takes it:
+        # from the second step on an input already has its target
         out_shardings = ([replicated] * len(fetch_names),
-                         {n: self._param_sharding(mesh, block, n)
-                          for n in plan.persist_written},
+                         {n: targets[n] for n in plan.persist_written},
                          {})
-        return _BuildResult(plan, fn, donate, out_shardings=out_shardings)
+        return _BuildResult(plan, fn, donate, out_shardings=out_shardings,
+                            param_shardings=targets)
 
     def _disk_key(self, program, plan, feed_arrays, fetch_names, trace_flags,
                   mesh, devices):
@@ -975,7 +995,8 @@ class Executor:
                   "n_feeds": len(feeds)})
         entry = _CompiledPlan(
             build.plan, compiled if compiled is not None else jfn,
-            build.mesh, build.data_axis, jit_fn=jfn)
+            build.mesh, build.data_axis, jit_fn=jfn,
+            param_shardings=build.param_shardings)
         return entry, cstats
 
     def warmup(self, program=None, feed_specs=None, fetch_list=None,
@@ -1063,8 +1084,9 @@ class Executor:
             if mesh is not None:
                 feed_arrays = self._shard_feeds(feed_arrays, mesh,
                                                 data_axis)
-                params_ro = self._shard_params(params_ro, mesh, block)
-                params_rw = self._shard_params(params_rw, mesh, block)
+                params_ro, params_rw, _placed, _passed = \
+                    self._place_params(scope, build.param_shardings,
+                                       params_ro, params_rw, place_all=True)
             run_devices = self._devices(mesh)
             disk_key = self._disk_key(program, plan, feed_arrays,
                                       fetch_names, trace_flags, mesh,
@@ -1131,25 +1153,92 @@ class Executor:
             return NamedSharding(mesh, P(*spec))
         return NamedSharding(mesh, P())
 
-    def _shard_params(self, params, mesh, block):
+    def _param_shardings(self, mesh, block, plan):
+        """{name: NamedSharding} of every persistable the step reads or
+        writes.  A property of (program version, mesh), which is what the
+        executable cache is keyed by: computed once a build and kept with
+        the entry."""
+        # rw_names are among persist_written, ro_names are not
+        return {n: self._param_sharding(mesh, block, n)
+                for n in (*plan.ro_names, *plan.persist_written)}
+
+    def _place_params(self, scope, targets, params_ro, params_rw,
+                      place_all):
+        """One call's persistables, each on its target of ``targets`` (the
+        entry's table on a hit, the build's on the compile path, where
+        ``place_all``): (ro, rw, arrays placed, arrays passed through)."""
+        kept = scope.__dict__.setdefault("_mesh_placed_ro", {})
+        ro, placed_ro = self._shard_params(params_ro, targets, kept=kept,
+                                           place_all=place_all)
+        rw, placed_rw = self._shard_params(params_rw, targets,
+                                           place_all=place_all)
+        placed = placed_ro + placed_rw
+        return ro, rw, placed, len(ro) + len(rw) - placed
+
+    @staticmethod
+    def _lies_on(v, target, exact=False):
+        """Whether ``v`` is already a device array laid out as ``target``,
+        by what the array itself says: the same sharding object, an equal
+        one (a step's outputs under ``out_shardings``), or — unless
+        ``exact`` — an equivalent one (the transpiled ``shard_map`` route
+        declares no ``out_shardings``, the compiler names its outputs'
+        layout its own way).  An executable takes all three as they are."""
+        if not isinstance(v, jax.Array):
+            return False
+        sh = v.sharding
+        if sh is target or sh == target:
+            return True
+        return not exact and sh.is_equivalent_to(target, v.ndim)
+
+    def _shard_params(self, params, targets, kept=None, place_all=False):
+        """Place each persistable on the mesh once: an array that already
+        lies on its target (``_lies_on``) goes into the call untouched;
+        anything else — numpy from the startup program or a checkpoint
+        restore, a single-device array, an array on another mesh after an
+        elastic re-quorum — is placed.  Returns (arrays, number placed).
+
+        ``kept`` (read-only persistables: the step never writes them back,
+        so the scope keeps what the user put there) pairs each placed copy
+        with the scope object it was made from, by identity, the way
+        ``_gather_carry`` keeps its bf16 copies: an external ``scope.set``
+        breaks the pair and forces a fresh placement.
+
+        ``place_all`` is the compile path: every array goes through the
+        placement call, whose result fixes the executable's input
+        shardings (for an array already on its target that call hands back
+        its argument)."""
         multi = jax.process_count() > 1
         out = {}
+        placed = 0
         for n, v in params.items():
-            sh = self._param_sharding(mesh, block, n)
-            if multi:
-                if isinstance(v, jax.Array) and v.sharding.device_set == \
-                        sh.device_set:
-                    out[n] = jax.device_put(v, sh)
+            sh = targets[n]
+            if not place_all:
+                # multi-process: pass through only what equals the target
+                if self._lies_on(v, sh, exact=multi):
+                    out[n] = v
+                    if kept is not None:
+                        # a copy of what the scope held before is of no use
+                        kept.pop(n, None)
                     continue
+                ent = kept.get(n) if kept is not None else None
+                if ent is not None and ent[0] is v \
+                        and self._lies_on(ent[1], sh, exact=multi):
+                    out[n] = ent[1]
+                    continue
+            placed += 1
+            if not multi or (isinstance(v, jax.Array)
+                             and v.sharding.device_set == sh.device_set):
+                out[n] = jax.device_put(v, sh)
+            else:
                 # multi-process (nccl2-mode analog): every process holds
                 # the full (identically-seeded) value — locally-committed
                 # arrays (e.g. from a single-device startup run) included;
                 # assemble the global array from process-local data
                 out[n] = jax.make_array_from_process_local_data(
                     sh, np.asarray(v))
-            else:
-                out[n] = jax.device_put(v, sh)
-        return out
+            if kept is not None and out[n] is not v:
+                kept[n] = (v, out[n])
+        return out, placed
 
     def _shard_feeds(self, feed_arrays, mesh, data_axis):
         from jax.sharding import NamedSharding, PartitionSpec as P

@@ -405,7 +405,8 @@ def _event_fh(d):
 
 
 def record_step(wall_ms, cache_hit, compile_ms=None, donated=0,
-                feed_bytes=0, fetch_bytes=0, carry_hits=0, carry_converts=0):
+                feed_bytes=0, fetch_bytes=0, carry_hits=0, carry_converts=0,
+                params_placed=0, params_passed=0):
     """One executor step: bundle the counter/histogram updates plus the
     step event so the hot path pays a single enabled() check."""
     if not enabled():
@@ -433,6 +434,12 @@ def record_step(wall_ms, cache_hit, compile_ms=None, donated=0,
     if carry_converts:
         inc("executor_carry_convert_total", carry_converts)
         fields["carry_converts"] = carry_converts
+    if params_placed:
+        inc("executor_params_placed_total", params_placed)
+        fields["params_placed"] = params_placed
+    if params_passed:
+        inc("executor_params_passed_total", params_passed)
+        fields["params_passed"] = params_passed
     event("step", **fields)
 
 

@@ -319,6 +319,34 @@ def test_executor_step_spans_carry_phases_and_host_time(tmp_path):
     assert not [r for r in tr._recent if r["t"] == "inst"]
 
 
+def test_mesh_step_spans_carry_the_placement_counts_and_phase(tmp_path):
+    """A run on a mesh: the span says how many persistables were placed and
+    how many passed through, and still times ``executor.shard_params`` (the
+    benchmark's ``shard_ms_per_step.train`` reads that phase by name)."""
+    _tracing_on(tmp_path)
+    main, startup, loss = _tiny_program()
+    prog = fluid.CompiledProgram(main).with_data_parallel(
+        loss_name=loss.name, places=[fluid.TPUPlace(i) for i in range(4)])
+    exe = fluid.Executor(fluid.CPUPlace())
+    with fluid.scope_guard(fluid.Scope()):
+        exe.run(startup)
+        tr.reset()
+        for _ in range(3):
+            exe.run(prog, feed={"x": np.ones((8, 4), "f")},
+                    fetch_list=[loss])
+        exe.run(main, feed={"x": np.ones((8, 4), "f")}, fetch_list=[loss])
+    *mesh_steps, plain = tr.records("executor.step")
+    for s in mesh_steps:
+        a = s["attrs"]
+        assert EXECUTOR_PHASES | {"executor.shard_feeds",
+                                  "executor.shard_params"} <= set(a["phases"])
+        assert a["params_placed"] + a["params_passed"] == 2    # fc w and b
+    assert [s["attrs"]["params_placed"] for s in mesh_steps] == [2, 0, 0]
+    # no mesh, no placement: neither the counts nor the phase
+    assert "params_placed" not in plain["attrs"]
+    assert "executor.shard_params" not in plain["attrs"]["phases"]
+
+
 # -- the profiler's clock -----------------------------------------------------
 
 def test_profile_holds_the_phases_as_host_events_with_tracing_off(tmp_path):
