@@ -41,6 +41,24 @@ each operand is split into three bfloat16 pieces and the six products
 probabilities) stacked as rows so that each piece of K (and V) passes
 through the MXU once.  Only the order of the softmax's sums differs.
 
+A **window** layer (``window`` given) attends the last ``window`` positions
+only, and its table is a *ring*: ``R`` slots a lane, position ``p`` in slot
+``(p // block_size) % R`` (serving/kv_cache.py ``WindowRing``), so that a
+sequence holds ``R`` blocks there whatever its length.  Entry ``j`` of the
+ring's ``R * block_size`` rows then holds the newest position congruent to
+``j``, ``age = (context_len - 1 - j) mod (R * block_size)`` tokens back, and
+is attended iff ``age < min(window, context_len)`` (``ring_mask``): the
+kernel fetches the ring's ``R`` blocks as one chunk, in the table's order,
+and the gather reads the ring as it lies.  Nothing of the history before the
+window is read.
+
+Where ``H / KH`` query heads share a KV head and ``D`` is a multiple of the
+128 lanes, the query and the output cross the kernel's boundary compact
+(``[B, H, D]``): the kernel repeats a lane's query under every KV head's
+columns itself and folds the output's owned columns back, so VMEM holds
+``B * H * D`` values of each and not ``B * H * KH * D`` (64 heads over 8 of
+128: 1 MB for 32 lanes where the spread layout takes 8.4).
+
 ``masked_attention`` is also the core of the UNPAGED reference loop in
 decode_model.py: sharing it is what makes paged-vs-unpaged decode
 bitwise-comparable on the CPU tier.
@@ -59,7 +77,10 @@ from . import adoption
 
 __all__ = ["paged_attention", "paged_attention_reference",
            "paged_attention_checks", "attention_path", "blocks_read",
-           "masked_attention", "gather_blocks"]
+           "masked_attention", "gather_blocks", "ring_mask", "KERNEL_NAME"]
+
+# the name the kernel's executions carry in a device trace
+KERNEL_NAME = "paged_attention"
 
 _MASK = -1e30  # finite: a fully-masked lane softmaxes to uniform, not NaN
 
@@ -86,14 +107,31 @@ def _own(heads, kv_heads, dtype):
             == jnp.arange(kv_heads)[None, :]).astype(dtype)
 
 
-def masked_attention(q, k, v, context_lens, scale=None):
+def _in_window(ctx, entry, ring_len, window):
+    """Is ``entry`` of a ring of ``ring_len`` rows attended from a context
+    of ``ctx`` tokens?  It holds the newest position congruent to it,
+    ``age`` tokens before the last one, which is inside the window, and
+    written, iff ``age < min(window, ctx)``."""
+    return (ctx - 1 - entry) % ring_len < jnp.minimum(window, ctx)
+
+
+def ring_mask(context_lens, ring_len, window):
+    """[B, ring_len] bool: which entries of a window layer's ring each lane
+    attends (``_in_window``)."""
+    return _in_window(context_lens.astype(jnp.int32)[:, None],
+                      jnp.arange(ring_len, dtype=jnp.int32)[None, :],
+                      ring_len, window)
+
+
+def masked_attention(q, k, v, context_lens, scale=None, window=None):
     """Single-token attention over a contiguous history: q [B, H, D],
     k/v [B, S, KH, D] with ``H`` a multiple of ``KH`` (grouped queries:
     query head ``r`` reads KV head ``r // (H // KH)``), context_lens [B]
     -> [B, H, D].  Positions >= the context length are masked; scores are
-    multiplied by ``scale`` (None: ``1 / sqrt(D)``).  Shared by the paged
-    gather path AND the unpaged reference loop so the two stay
-    bitwise-comparable.
+    multiplied by ``scale`` (None: ``1 / sqrt(D)``).  With ``window`` the
+    ``S`` rows are a ring (``ring_mask``) and the last ``window`` positions
+    alone are attended.  Shared by the paged gather path AND the unpaged
+    reference loop so the two stay bitwise-comparable.
 
     Both contractions run over the folded minor dimension KH * D, against
     a block-diagonal query: row r of ``qx`` holds head r's query in the D
@@ -120,9 +158,12 @@ def masked_attention(q, k, v, context_lens, scale=None):
     own = _own(h, kh, q.dtype)[None, :, :, None]
     qx = (q[:, :, None, :] * own).reshape(b, h, kh * d).astype(k.dtype)
     sc = dot("bhc,bsc->bhs", qx, k.reshape(b, s, kh * d)) * scale
-    pos = jnp.arange(s, dtype=jnp.int32)[None, None, :]
-    sc = jnp.where(pos < context_lens[:, None, None].astype(jnp.int32),
-                   sc, _MASK)
+    if window is None:
+        pos = jnp.arange(s, dtype=jnp.int32)[None, :]
+        seen = pos < context_lens[:, None].astype(jnp.int32)
+    else:
+        seen = ring_mask(context_lens, s, window)
+    sc = jnp.where(seen[:, None, :], sc, _MASK)
     p = jax.nn.softmax(sc, axis=-1)
     out = dot("bhs,bsc->bhc", p.astype(v.dtype), v.reshape(b, s, kh * d))
     return (out.reshape(b, h, kh, d) * own).sum(axis=2)
@@ -141,16 +182,17 @@ def gather_blocks(cache, block_tables):
 
 
 def paged_attention_reference(q, k_cache, v_cache, block_tables,
-                              context_lens, scale=None):
+                              context_lens, scale=None, window=None):
     """The jnp path: gather the table's blocks into contiguous K/V, then
     masked_attention.  q [B, H, D]; k_cache/v_cache the serving pool's
     [num_blocks, block_size, KH * D] (or [num_blocks, block_size, KH, D]):
-    the heads are split after the gather, never on the pool."""
+    the heads are split after the gather, never on the pool.  With
+    ``window`` the table is a window layer's ring."""
     bb, _h, d = q.shape
     with jax.named_scope("kv_gather"):
         k, v = (gather_blocks(c, block_tables).reshape(bb, -1, _kv_heads(
             c.shape, d), d) for c in (k_cache, v_cache))
-    return masked_attention(q, k, v, context_lens, scale)
+    return masked_attention(q, k, v, context_lens, scale, window)
 
 
 def _kv_heads(kv_shape, head_dim):
@@ -161,10 +203,12 @@ def _kv_heads(kv_shape, head_dim):
 
 # -- the shape rule ----------------------------------------------------------
 
-def paged_attention_checks(q_shape, kv_shape, kv_dtype):
+def paged_attention_checks(q_shape, kv_shape, kv_dtype, ring=0):
     """Ordered (reason, ok) pairs for adoption.decide(): what the kernel
     needs of the query ``[B, H, D]`` and of a pool ``[num_blocks,
-    block_size, KH * D]`` in ``kv_dtype``, ``H`` a multiple of ``KH``."""
+    block_size, KH * D]`` in ``kv_dtype``, ``H`` a multiple of ``KH``.
+    ``ring`` is the slots of a window layer's ring table (its one chunk);
+    0 for a layer that attends its whole context."""
     dims = tuple(q_shape) + tuple(kv_shape)
     static = all(isinstance(x, int) and x >= 0 for x in dims)
     rank = len(q_shape) == 3 and len(kv_shape) == 3
@@ -185,11 +229,20 @@ def paged_attention_checks(q_shape, kv_shape, kv_dtype):
          and kv_shape[1] > 0 and kv_shape[1] % tile == 0),
         ("empty", static and all(x > 0 for x in dims)),
         ("vmem", grouped and tile is not None
-         and (4 * max(CHUNK_TOKENS, kv_shape[1]) * jnp.dtype(kv_dtype).itemsize
-              + 2 * q_shape[0] * 4 * _query_rows(
-                  q_shape[1], kv_shape[2] // q_shape[2]))
-         * kv_shape[2] <= _VMEM_BUDGET),
+         and vmem_bytes(q_shape, kv_shape, kv_dtype, ring) <= _VMEM_BUDGET),
     ]
+
+
+def vmem_bytes(q_shape, kv_shape, kv_dtype, ring=0):
+    """What the kernel holds in VMEM for these shapes: the four chunk
+    buffers (a window layer's chunk is its whole ring), and every lane's
+    query and output in float32."""
+    kv_heads = kv_shape[2] // q_shape[2]
+    span = ring * kv_shape[1] if ring else max(CHUNK_TOKENS, kv_shape[1])
+    width = q_shape[2] if _compact(q_shape[1], kv_heads, q_shape[2]) \
+        else kv_shape[2]
+    return 4 * span * jnp.dtype(kv_dtype).itemsize * kv_shape[2] \
+        + 2 * q_shape[0] * 4 * _query_rows(q_shape[1], kv_heads) * width
 
 
 def _query_rows(heads, kv_heads):
@@ -200,23 +253,35 @@ def _query_rows(heads, kv_heads):
     return 1 if heads == kv_heads else -(-heads // 16) * 16
 
 
-def attention_path(q_shape, kv_shape, kv_dtype):
+def _compact(heads, kv_heads, head_dim):
+    """Do the query and the output cross the kernel's boundary ``[rows,
+    D]`` a lane, the kernel spreading them over the pool's width itself?
+    Where query heads share a KV head and a head's ``D`` values fill whole
+    128-lane tiles (the pieces are then joined and cut on tile borders)."""
+    return heads != kv_heads and head_dim % 128 == 0
+
+
+def attention_path(q_shape, kv_shape, kv_dtype, ring=0):
     """``"pallas"`` where the kernel would serve these shapes on this
     backend, else ``"gather"``: the same rule as ``paged_attention``,
     counted nowhere.  The engine names the step's path by it, in the
     executable's cache key and on the ``serving_prewarm`` event."""
     ok = all(ok for _reason, ok in
-             paged_attention_checks(q_shape, kv_shape, kv_dtype))
+             paged_attention_checks(q_shape, kv_shape, kv_dtype, ring))
     return "pallas" if ok else "gather"
 
 
-def blocks_read(context_lens, block_size, maxb, path):
+def blocks_read(context_lens, block_size, maxb, path, ring=False):
     """Blocks one layer's attention fetches for these lanes: every slot of
     the table on the gather path; on the kernel's, each lane's live blocks
-    rounded up to the kernel's chunk (a host-side count for the step's
-    span: ``context_lens`` is the numpy feed)."""
+    rounded up to the kernel's chunk, or for a window layer (``ring``: the
+    table is its ring of ``maxb`` slots) a live lane's whole ring (a
+    host-side count for the step's span: ``context_lens`` is the numpy
+    feed)."""
     if path != "pallas":
         return len(context_lens) * maxb
+    if ring:
+        return int((context_lens > 0).sum()) * maxb
     per = _chunk_blocks(block_size, maxb)
     return int((-(-context_lens // (per * block_size))).sum()) * per
 
@@ -265,7 +330,12 @@ def _product(rows, x, dims):
 
 
 def _kernel(bt_ref, cl_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, *,
-            heads, kv_heads, head_dim, block_size, maxb, per, scale):
+            heads, kv_heads, head_dim, block_size, maxb, per, scale,
+            window=None):
+    """``window`` None: a lane's chunks cover positions ``[0,
+    context_len)``.  Given: the table is a ring of ``maxb == per`` slots, a
+    live lane's one chunk is the ring as it lies, and ``ring_mask``'s rule
+    says which of its rows are attended."""
     lanes = q_ref.shape[0]
     hd = kv_heads * head_dim             # the pool's width
     group = heads // kv_heads            # query heads a KV head
@@ -273,6 +343,8 @@ def _kernel(bt_ref, cl_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, *,
     span = per * block_size              # positions a chunk
 
     def chunks(b):
+        if window is not None:
+            return jnp.minimum(cl_ref[b], 1)
         return (cl_ref[b] + span - 1) // span
 
     def copies(slot, block_of):
@@ -320,8 +392,12 @@ def _kernel(bt_ref, cl_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, *,
             start(b, 0, g % 2)
 
         # one row broadcast over the heads, or (grouped) a row a head
-        # with its query already in every KV head's columns
-        qx = q_ref[b] * own                              # [rows, hd]
+        # with its query in every KV head's columns: as it came, or (the
+        # compact layout) repeated here
+        qb = q_ref[b]
+        if qb.shape[1] != hd:
+            qb = jnp.concatenate([qb] * kv_heads, axis=1)
+        qx = qb * own                                    # [rows, hd]
 
         def chunk(c, carry):
             m, l, acc = carry
@@ -338,7 +414,12 @@ def _kernel(bt_ref, cl_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, *,
             sc = _product(qx, kbuf[slot], _NT) * scale   # [rows, span]
             pos = c * span + jax.lax.broadcasted_iota(
                 jnp.int32, (1, span), 1)
-            sc = jnp.where(pos < ctx, sc, _MASK)
+            if window is None:
+                seen = pos < ctx
+            else:
+                # c is 0 and span the ring's length
+                seen = _in_window(ctx, pos, span, window)
+            sc = jnp.where(seen, sc, _MASK)
             m_new = jnp.maximum(m, jnp.max(sc, axis=1, keepdims=True))
             alpha = jnp.exp(m - m_new)
             p = jnp.exp(sc - m_new)
@@ -355,6 +436,11 @@ def _kernel(bt_ref, cl_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, *,
         if group == 1:
             # each column belongs to one row: fold the rows
             out = jnp.sum(out, axis=0, keepdims=True)
+        elif o_ref.shape[2] != hd:
+            # compact: a row's owned columns are its KV head's D; every
+            # other piece is zeros
+            out = sum(out[:, i * head_dim:(i + 1) * head_dim]
+                      for i in range(kv_heads))
         o_ref[b] = out.astype(o_ref.dtype)
         return g + n
 
@@ -362,65 +448,75 @@ def _kernel(bt_ref, cl_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, *,
 
 
 def _paged_pallas(q, k_cache, v_cache, block_tables, context_lens,
-                  scale=None, interpret=None):
+                  scale=None, interpret=None, window=None):
     """q [B, H, D] against folded pools [num_blocks, block_size, KH * D]
-    -> [B, H, D].  ``interpret`` None follows the backend."""
+    -> [B, H, D].  ``interpret`` None follows the backend; ``window``
+    given, the table is a window layer's ring."""
     bb, h, d = q.shape
     _nb, bs, hd = k_cache.shape
     kh = hd // d
     maxb = block_tables.shape[1]
-    per = _chunk_blocks(bs, maxb)
+    per = maxb if window is not None else _chunk_blocks(bs, maxb)
     if interpret is None:
         interpret = adoption.interpret()
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     qrows = _query_rows(h, kh)
     q = q.astype(jnp.float32)
+    compact = _compact(h, kh, d)
     if kh == h:
         qx = q.reshape(bb, 1, hd)
     else:
-        # a row a head, the head's query repeated under every KV head's
-        # columns: the kernel's mask keeps the one it owns
-        qx = jnp.pad(jnp.tile(q, (1, 1, kh)), ((0, 0), (0, qrows - h), (0, 0)))
+        # a row a head; spread, the head's query repeated under every KV
+        # head's columns (the kernel's mask keeps the one it owns); compact,
+        # as it is, and the kernel repeats it
+        qx = jnp.pad(q if compact else jnp.tile(q, (1, 1, kh)),
+                     ((0, 0), (0, qrows - h), (0, 0)))
+    width = qx.shape[2]
     whole = lambda i, bt, cl: (0, 0, 0)
     out = pl.pallas_call(
         functools.partial(_kernel, heads=h, kv_heads=kh, head_dim=d,
                           block_size=bs, maxb=maxb, per=per,
-                          scale=float(scale)),
+                          scale=float(scale), window=window),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(1,),
-            in_specs=[pl.BlockSpec((bb, qrows, hd), whole),
+            in_specs=[pl.BlockSpec((bb, qrows, width), whole),
                       pl.BlockSpec(memory_space=pl.ANY),
                       pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec((bb, qrows, hd), whole),
+            out_specs=pl.BlockSpec((bb, qrows, width), whole),
             scratch_shapes=[pltpu.VMEM((2, per * bs, hd), k_cache.dtype),
                             pltpu.VMEM((2, per * bs, hd), v_cache.dtype),
                             pltpu.SemaphoreType.DMA((2, 2))],
         ),
-        out_shape=jax.ShapeDtypeStruct((bb, qrows, hd), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((bb, qrows, width), jnp.float32),
+        name=KERNEL_NAME,
         interpret=interpret,
     )(block_tables.astype(jnp.int32).reshape(-1),
       context_lens.astype(jnp.int32), qx, k_cache, v_cache)
     if kh == h:
         return out.reshape(bb, h, d)
+    if compact:
+        return out[:, :h]
     # row r holds head r's output in its KV head's columns, zeros elsewhere
     return out[:, :h].reshape(bb, h, kh, d).sum(axis=2)
 
 
 def paged_attention(q, k_cache, v_cache, block_tables, context_lens,
-                    scale=None):
+                    scale=None, window=None):
     """The step's attention over a layer's pools: the kernel where the
     shape rule admits it (``adoption.decide`` counts the lowering under
     ``pallas_kernel_used_total`` / ``..._fallback_total{reason}``), the
     gather otherwise.  The pools' width says how many KV heads they store;
-    ``scale`` None is ``1 / sqrt(D)``."""
+    ``scale`` None is ``1 / sqrt(D)``.  ``window`` given, the layer attends
+    its last ``window`` positions and ``block_tables`` is its ring."""
+    ring = block_tables.shape[1] if window is not None else 0
     use, _reason = adoption.decide(
         "paged_attention",
-        paged_attention_checks(q.shape, k_cache.shape, k_cache.dtype))
+        paged_attention_checks(q.shape, k_cache.shape, k_cache.dtype, ring))
     if use:
         with jax.named_scope("kv_read"):
             return _paged_pallas(q, k_cache, v_cache, block_tables,
-                                 context_lens, scale)
+                                 context_lens, scale, window=window)
     return paged_attention_reference(q, k_cache, v_cache, block_tables,
-                                     context_lens, scale)
+                                     context_lens, scale, window)

@@ -15,18 +15,21 @@ that reads each lane's live KV blocks from the pool where they lie, and
 elsewhere (the CPU tier, the int8 residency) a gather of the padded table
 into ``masked_attention``, chosen by what the shapes and the backend are.
 
-The decoder is one of four blocks, picked by ``DecoderConfig.arch``: the
+The decoder is one of five blocks, picked by ``DecoderConfig.arch``: the
 ``gpt2`` block of this file, in float32; the routed-expert ``olmoe`` block
 of ``models/olmoe.py``, in bfloat16 with a bfloat16 cache; the
-``granite_hybrid`` block of ``models/granite_hybrid.py``; and the
-``lfm2_moe`` block of ``models/lfm2_moe.py``.  Layers are of three kinds
-(``LAYER_KINDS``): ``attention`` (multi-head, or grouped-query with fewer KV
-heads than query heads, so pools ``kv_heads * head_dim`` wide), which keeps
-K and V a token; ``mamba``, a Mamba-2 state-space mixer, which keeps a
+``granite_hybrid`` block of ``models/granite_hybrid.py``; the ``lfm2_moe``
+block of ``models/lfm2_moe.py``; and the ``exaone_moe`` block of
+``models/exaone_moe.py``.  Layers are of four kinds (``LAYER_KINDS``):
+``attention`` (multi-head, or grouped-query with fewer KV heads than query
+heads, so pools ``kv_heads * head_dim`` wide), which keeps K and V a token;
+``window``, attention over the last ``cfg.window`` positions only, which
+keeps K and V in a ring of blocks of its own pools and gives back what
+leaves the window; ``mamba``, a Mamba-2 state-space mixer, which keeps a
 convolution window and a recurrent state a sequence; and ``conv``, a gated
 short convolution, which keeps a window and no state.  The hybrid blocks
-mix attention with one recurrent kind (``ARCH_LAYER_KINDS``).  Every step
-builder below serves all four through one contract (``_block``), so there
+mix attention with one other kind (``ARCH_LAYER_KINDS``).  Every step
+builder below serves all five through one contract (``_block``), so there
 is one paged step, one multi-token step, one draft rollout and one unpaged
 reference, whatever the block.
 
@@ -87,16 +90,19 @@ __all__ = ["DecoderConfig", "init_decoder_params", "save_decoder",
 # gpt2's is ``models/<arch>.py``)
 ARCH_LAYER_KINDS = {"gpt2": ("attention",), "olmoe": ("attention",),
                     "granite_hybrid": ("attention", "mamba"),
-                    "lfm2_moe": ("attention", "conv")}
+                    "lfm2_moe": ("attention", "conv"),
+                    "exaone_moe": ("attention", "window")}
 ARCHS = tuple(ARCH_LAYER_KINDS)
-LAYER_KINDS = ("attention", "mamba", "conv")
+LAYER_KINDS = ("attention", "mamba", "conv", "window")
 # recurrent kind -> the name its slot goes by in spans, gauges and counters
 # (``ssm_state_lanes``, ``conv_state_bytes{model}``, ...)
 STATE_NAMES = {"mamba": "ssm_state", "conv": "conv_state"}
 # the blocks whose attention may have fewer KV heads than query heads, and
 # those whose feed-forward is routed experts
-_GROUPED_QUERY = ("granite_hybrid", "lfm2_moe")
-_ROUTED = ("olmoe", "lfm2_moe")
+_GROUPED_QUERY = ("granite_hybrid", "lfm2_moe", "exaone_moe")
+_ROUTED = ("olmoe", "lfm2_moe", "exaone_moe")
+# the blocks whose first ``dense_layers`` layers end in a gated MLP
+_DENSE_LEAD = ("lfm2_moe", "exaone_moe")
 
 
 class DecoderConfig:
@@ -121,7 +127,17 @@ class DecoderConfig:
     layers a gated MLP of width ``dense_ffn``, the rest ``experts`` experts
     of width ``ffn`` routed by sigmoid scores (a bias selects
     ``experts_per_token``, the gates are renormalised and scaled by
-    ``routed_scaling``), and a tied head.
+    ``routed_scaling``), and a tied head.  ``exaone_moe`` is the block of
+    ``models/exaone_moe.py``: ``layer_types`` of ``attention`` | ``window``
+    (the latter attends the last ``window`` positions and alone is rotated),
+    grouped-query attention with per-head Q/K norm, norms on each sublayer's
+    output, ``dense_layers`` leading gated MLPs and then experts routed as
+    ``lfm2_moe``'s with a shared expert of width ``shared_ffn`` beside them,
+    and an untied head.  It may hold a *share* of each routed layer: the
+    router scores all ``experts``, the weights are those of the
+    ``experts_held`` experts from ``expert_first`` on (0: all of them), and
+    what the absent ones would add is left out.  Its stream may be
+    narrower than its query heads together (``hidden_size``).
 
     ``kv_heads`` None means ``heads`` (multi-head); ``layer_types`` None
     means ``layers`` attention layers.  ``dtype`` is the weights' (``f32``
@@ -136,7 +152,8 @@ class DecoderConfig:
                  "ssm_state", "ssm_conv", "embedding_multiplier",
                  "residual_multiplier", "attention_multiplier",
                  "logits_scaling", "conv_taps", "dense_layers", "dense_ffn",
-                 "routed_scaling")
+                 "routed_scaling", "window", "experts_held", "expert_first",
+                 "shared_ffn", "hidden_size")
 
     def __init__(self, vocab, layers, heads, head_dim, ffn=None,
                  max_seq=64, arch="gpt2", dtype="f32", kv_dtype=None,
@@ -146,7 +163,8 @@ class DecoderConfig:
                  embedding_multiplier=1.0, residual_multiplier=1.0,
                  attention_multiplier=None, logits_scaling=1.0,
                  conv_taps=0, dense_layers=0, dense_ffn=0,
-                 routed_scaling=1.0):
+                 routed_scaling=1.0, window=0, experts_held=0,
+                 expert_first=0, shared_ffn=0, hidden_size=None):
         if arch not in ARCHS:
             raise ValueError("decoder arch must be %s: %r"
                              % ("|".join(ARCHS), arch))
@@ -190,6 +208,15 @@ class DecoderConfig:
         self.dense_layers = int(dense_layers)
         self.dense_ffn = int(dense_ffn)
         self.routed_scaling = float(routed_scaling)
+        self.window = int(window)
+        self.experts_held = int(experts_held) or self.experts
+        self.expert_first = int(expert_first)
+        self.shared_ffn = int(shared_ffn)
+        self.hidden_size = None if hidden_size is None else int(hidden_size)
+        if self.hidden_size not in (None, self.heads * self.head_dim) \
+                and arch != "exaone_moe":
+            raise ValueError("the %s block's stream is heads * head_dim "
+                             "wide" % arch)
         if len(self.layer_types) != self.layers or any(
                 k not in LAYER_KINDS for k in self.layer_types):
             raise ValueError("layer_types must name each of the %d layers "
@@ -199,7 +226,7 @@ class DecoderConfig:
             raise ValueError("heads %d must be a multiple of kv_heads %d"
                              % (self.heads, self.kv_heads))
         if any(k not in ARCH_LAYER_KINDS[arch] for k in self.layer_types):
-            raise ValueError("the %s block's layers are %s: %r"
+            raise ValueError("layer_types: the %s block's layers are %s: %r"
                              % (arch, "|".join(ARCH_LAYER_KINDS[arch]),
                                 layer_types))
         if arch not in _GROUPED_QUERY and self.kv_heads != self.heads:
@@ -212,16 +239,30 @@ class DecoderConfig:
         if self.conv_layers and self.conv_taps < 2:
             raise ValueError("conv layers want conv_taps >= 2")
         if not 0 <= self.dense_layers <= self.layers or (
-                self.dense_layers and (arch != "lfm2_moe"
+                self.dense_layers and (arch not in _DENSE_LEAD
                                        or self.dense_ffn < 1)):
             raise ValueError(
-                "dense_layers leads the lfm2_moe block's %d layers with a "
+                "dense_layers leads the %s blocks' %d layers with a "
                 "gated MLP of width dense_ffn >= 1: %r of width %r"
-                % (self.layers, dense_layers, dense_ffn))
+                % ("|".join(_DENSE_LEAD), self.layers, dense_layers,
+                   dense_ffn))
+        if self.window_layers and self.window < 1:
+            raise ValueError("window layers want window >= 1")
+        if not 0 <= self.expert_first \
+                <= self.experts - self.experts_held or (
+                    self.experts_held != self.experts
+                    and arch != "exaone_moe"):
+            raise ValueError(
+                "the exaone_moe block may hold experts [expert_first, "
+                "expert_first + experts_held) of %d: %r from %r"
+                % (self.experts, experts_held, expert_first))
 
     @property
     def hidden(self):
-        return self.heads * self.head_dim
+        """The residual stream's width: ``heads * head_dim`` unless the
+        model says otherwise (``hidden_size``: the exaone_moe block projects
+        a narrower stream up to its query heads)."""
+        return self.hidden_size or self.heads * self.head_dim
 
     def _of_kind(self, kind):
         return tuple(l for l, k in enumerate(self.layer_types) if k == kind)
@@ -230,6 +271,12 @@ class DecoderConfig:
     def attn_layers(self):
         """Indices of the layers that hold K and V, in order."""
         return self._of_kind("attention")
+
+    @property
+    def window_layers(self):
+        """Indices of the layers that hold K and V of the last ``window``
+        positions only, in order."""
+        return self._of_kind("window")
 
     @property
     def ssm_layers(self):
@@ -266,6 +313,12 @@ class DecoderConfig:
         return tuple(range(self.dense_layers, self.layers))
 
     @property
+    def held_experts(self):
+        """The columns of a routed layer's ``experts`` this model holds the
+        weights of (a slice; all of them for a model that holds no share)."""
+        return slice(self.expert_first, self.expert_first + self.experts_held)
+
+    @property
     def ssm_inner(self):
         return self.ssm_heads * self.ssm_head_dim
 
@@ -280,15 +333,21 @@ class DecoderConfig:
 
 def cache_config(cfg, block_size, num_blocks, dtype=None, state_slots=0):
     """The cache geometry a model's step is built over: K and V pools for
-    its attention layers (``kv_heads`` wide), and for its recurrent layers
-    what one sequence's slot holds (``_state_shapes``: a window and a state
-    for ``mamba`` layers, a window alone for ``conv`` layers), in
-    ``state_slots`` slots (slot 0 the idle lanes' scratch)."""
+    its attention layers (``kv_heads`` wide), for its recurrent layers what
+    one sequence's slot holds (``_state_shapes``: a window and a state for
+    ``mamba`` layers, a window alone for ``conv`` layers), in
+    ``state_slots`` slots (slot 0 the idle lanes' scratch), and for its
+    window layers as many rings (a sequence holds a ring as it holds a
+    slot: one a lane and the scratch), each ``cfg.window`` positions
+    long."""
     return _kv.KVCacheConfig(
         len(cfg.attn_layers), cfg.kv_heads, cfg.head_dim, block_size,
         num_blocks, dtype or cfg.kv_dtype or "f32",
         state_layers=len(cfg.recurrent_layers),
-        state_shapes=_state_shapes(cfg), state_slots=state_slots)
+        state_shapes=_state_shapes(cfg),
+        state_slots=state_slots,
+        window_layers=len(cfg.window_layers), window=cfg.window,
+        window_slots=state_slots if cfg.window_layers else 0)
 
 
 def _conv_window(cfg):
@@ -442,9 +501,9 @@ def _block(cfg):
     marks the lanes that hold a sequence; ``extras`` is a tuple of small
     arrays the step returns after its logits (a routed block's tokens sent
     to each expert, a row a layer of ``cfg.routed_layers``; nothing for the
-    others).  The four blocks: ``_token_logits`` here (gpt2), and
-    ``token_logits`` of ``models/olmoe.py``, ``models/granite_hybrid.py``
-    and ``models/lfm2_moe.py``."""
+    others).  The five blocks: ``_token_logits`` here (gpt2), and
+    ``token_logits`` of ``models/olmoe.py``, ``models/granite_hybrid.py``,
+    ``models/lfm2_moe.py`` and ``models/exaone_moe.py``."""
     return _token_logits if cfg.arch == "gpt2" else _model(cfg).token_logits
 
 
@@ -493,17 +552,20 @@ def _write_rows(pool, blk_ids, offs, rows):
         rows.reshape(rows.shape[0], -1).astype(pool.dtype))
 
 
-def attention_path(cfg, kv_config, lanes=1):
+def attention_path(cfg, kv_config, lanes=1, kind="attention"):
     """``"pallas"`` where ``make_paged_step``'s attention is the kernel
     that reads the live blocks in place, for this model and pool on this
     backend at a bucket of ``lanes`` (what holds for a bucket holds for
     every smaller one); ``"gather"`` where it gathers the padded table
-    (the int8 residency always does)."""
+    (the int8 residency always does).  ``kind`` ``"window"`` asks it of the
+    window layers, whose table is their ring."""
+    windowed = kind == "window"
     return _pa.attention_path(
         (lanes, cfg.heads, cfg.head_dim),
-        (kv_config.num_blocks, kv_config.block_size,
-         kv_config.heads * kv_config.head_dim),
-        _kv._PAYLOAD[kv_config.dtype][0])
+        (kv_config.window_blocks if windowed else kv_config.num_blocks,
+         kv_config.block_size, kv_config.heads * kv_config.head_dim),
+        _kv._PAYLOAD[kv_config.dtype][0],
+        ring=kv_config.window_ring if windowed else 0)
 
 
 def experts_path(cfg, params, lanes=1):
@@ -520,7 +582,8 @@ def experts_path(cfg, params, lanes=1):
 def _pool_index(cfg):
     """layer -> its place among the layers of its kind (the index of its
     pools, or of its state arrays, in the cache's groups)."""
-    return {l: i for kind in (cfg.attn_layers, cfg.recurrent_layers)
+    return {l: i for kind in (cfg.attn_layers, cfg.window_layers,
+                              cfg.recurrent_layers)
             for i, l in enumerate(kind)}
 
 
@@ -560,8 +623,8 @@ class _Recurrent:
 
 def make_paged_step(cfg, kv_config):
     """-> step(kv_carry, params, tok, pos, block_tables, context_lens[,
-    state_slots]) returning (new_kv_carry, next_tokens, logits) and then
-    the block's extras, if it has any (``_block``).
+    state_slots][, window_tables]) returning (new_kv_carry, next_tokens,
+    logits) and then the block's extras, if it has any (``_block``).
 
     ``kv_carry`` is ``PagedKVCache.carry()``: per-layer pools, K then V
     (then their scales for int8) for the attention layers and then the
@@ -580,6 +643,13 @@ def make_paged_step(cfg, kv_config):
     it, and a lane whose ``pos`` is 0 starts from zeros whatever the slot
     holds, so a slot needs no clearing between sequences.
 
+    A model with window layers takes, last, ``window_tables`` [B, R] int32:
+    each lane's ring in the window layers' pools (``kv_cache.WindowRing``;
+    idle lanes' rows are -1, which reads and writes the scratch block 0).
+    A window layer writes position ``pos`` into the block of ring slot
+    ``(pos // bs) % R`` and attends the ring's entries of the last
+    ``cfg.window`` positions; it never looks at ``block_tables``.
+
     Feed-planning contract (what prefix caching leans on): the step
     WRITES exactly one position — ``pos``, into block
     ``block_tables[b, pos // bs]`` — and only READS every earlier
@@ -593,50 +663,71 @@ def make_paged_step(cfg, kv_config):
     holds for K and V.  A recurrent state at ``pos`` exists only in the
     slot of the sequence that computed it, so a model with recurrent
     layers starts every sequence at position 0 (the engine declines prefix
-    reuse for it)."""
+    reuse for it).  So does one with window layers: K and V older than the
+    window are in no block."""
     bs = kv_config.block_size
     int8 = kv_config.dtype == "int8"
     block = _block(cfg)
     pool_of = _pool_index(cfg)
     taps = _conv_window(cfg)[0]
+    windowed = frozenset(cfg.window_layers)
+    ring = kv_config.window_ring
 
     def step(kv_carry, params, tok, pos, block_tables, context_lens,
-             state_slots=None):
+             *more):
+        # what a model's kinds of layer add, in this order
+        more = list(more)
+        state_slots = more.pop(0) if cfg.recurrent_layers else None
+        window_tables = more.pop(0).astype(jnp.int32) if windowed else None
         tok = tok.astype(jnp.int32)
         pos = pos.astype(jnp.int32)
         block_tables = block_tables.astype(jnp.int32)
         context_lens = context_lens.astype(jnp.int32)
-        blk_ids = jnp.take_along_axis(
-            jnp.maximum(block_tables, 0), (pos // bs)[:, None], axis=1)[:, 0]
         offs = pos % bs
         pools, state = kv_config.groups(kv_carry)
+        wpools = kv_config.window_groups(kv_carry)
 
-        def write(group, i, rows):
-            pools[group][i] = _write_rows(pools[group][i], blk_ids, offs,
-                                          rows)
+        def block_of(tables, slot):
+            return jnp.take_along_axis(
+                jnp.maximum(tables, 0), slot[:, None], axis=1)[:, 0]
+
+        # a layer's kind -> (its pools, the table that steers them, the
+        # block this step writes, its window or None)
+        kinds = {False: (pools, block_tables,
+                         block_of(block_tables, pos // bs), None)}
+        if windowed:
+            kinds[True] = (wpools, window_tables,
+                           block_of(window_tables, (pos // bs) % ring),
+                           cfg.window)
 
         def attend(l, q, k, v):
             i = pool_of[l]
+            mine, tables, blk_ids, window = kinds[l in windowed]
+
+            def write(group, rows):
+                mine[group][i] = _write_rows(mine[group][i], blk_ids, offs,
+                                             rows)
+
             if not int8:
                 with jax.named_scope("kv_write"):
-                    write(0, i, k)
-                    write(1, i, v)
-                return paged_attention(q, pools[0][i], pools[1][i],
-                                       block_tables, context_lens,
-                                       cfg.attention_multiplier)
+                    write(0, k)
+                    write(1, v)
+                return paged_attention(q, mine[0][i], mine[1][i], tables,
+                                       context_lens,
+                                       cfg.attention_multiplier, window)
             with jax.named_scope("kv_write"):
                 for group, x in ((0, k), (1, v)):
                     payload, scale = _kv.quantize_kv(x)
-                    write(group, i, payload)
-                    write(group + 2, i, scale)
+                    write(group, payload)
+                    write(group + 2, scale)
             with jax.named_scope("kv_gather"):
                 kk, vv = (_kv.dequantize_kv(
-                    gather_blocks(pools[g][i], block_tables).reshape(
+                    gather_blocks(mine[g][i], tables).reshape(
                         k.shape[0], -1, *k.shape[1:]),
-                    gather_blocks(pools[g + 2][i], block_tables))
+                    gather_blocks(mine[g + 2][i], tables))
                     for g in (0, 1))
             return masked_attention(q, kk, vv, context_lens,
-                                    cfg.attention_multiplier)
+                                    cfg.attention_multiplier, window)
 
         recur = None
         if state:
@@ -662,16 +753,17 @@ def make_paged_step(cfg, kv_config):
         logits, extras = block(params, cfg, tok, pos, attend,
                                context_lens > 0, recur)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return (tuple(a for group in pools + state for a in group), nxt,
-                logits) + tuple(extras)
+        return (tuple(a for group in pools + wpools + state for a in group),
+                nxt, logits) + tuple(extras)
 
     return step
 
 
 def make_fed_step(cfg, kv_config, feed_width):
     """-> step(kv_carry, params, tok, prev_next, src, pos, block_tables,
-    context_lens[, state_slots]): ``make_paged_step``'s step with each
-    lane's input token chosen on the device, in the same executable.
+    context_lens[, state_slots][, window_tables]): ``make_paged_step``'s
+    step with each lane's input token chosen on the device, in the same
+    executable.
 
     ``prev_next`` is the ``next_tokens`` of the step before, int32
     [feed_width], as that step returned them: a device array the host need
@@ -686,12 +778,12 @@ def make_fed_step(cfg, kv_config, feed_width):
     step = make_paged_step(cfg, kv_config)
 
     def fed(kv_carry, params, tok, prev_next, src, pos, block_tables,
-            context_lens, state_slots=None):
+            context_lens, *more):
         src = src.astype(jnp.int32)
         tok = jnp.where(src >= 0, prev_next[jnp.maximum(src, 0)],
                         tok.astype(jnp.int32))
         carry, nxt, *rest = step(kv_carry, params, tok, pos, block_tables,
-                                 context_lens, state_slots)
+                                 context_lens, *more)
         return (carry, jnp.pad(nxt, (0, feed_width - nxt.shape[0])), *rest)
 
     return fed
@@ -719,11 +811,15 @@ def make_paged_step_multi(cfg, kv_config, width):
     model with recurrent layers takes ``state_slots`` [B] after the lens,
     and every column of a live lane has to be a real token (a chunk of
     prefill; its state cannot be rolled back, which is why the engine
-    refuses it speculation)."""
+    refuses it speculation).  A ring holds one write beside its window, so
+    a model with window layers has no multi-token step."""
+    if cfg.window_layers:
+        raise ValueError("a multi-token step is not planned over window "
+                         "layers' rings")
     base = make_paged_step(cfg, kv_config)
 
     def step(kv_carry, params, tok, pos, block_tables, context_lens,
-             *state_slots):
+             *tail):
         tok = tok.astype(jnp.int32)
         pos = pos.astype(jnp.int32)
         context_lens = context_lens.astype(jnp.int32)
@@ -740,7 +836,7 @@ def make_paged_step_multi(cfg, kv_config, width):
                 tok_j = jnp.where(nxts[-1] < 0, nxts[-1], tok_j)
             kv_carry, nxt, lg, *more = base(
                 kv_carry, params, tok_j, pos[:, j], block_tables,
-                context_lens[:, j], *state_slots)
+                context_lens[:, j], *tail)
             nxts.append(nxt)
             logits.append(lg)
             extras = more if extras is None \
@@ -791,31 +887,40 @@ def make_draft_rollout(cfg, kv_config, k):
 
 # -- unpaged reference -------------------------------------------------------
 
-def make_unpaged_step(cfg, pad_len):
+def make_unpaged_step(cfg, pad_len, ring_len=None):
     """Reference step over contiguous per-lane K/V [L, B, pad_len, KH, D]
     (``L`` the attention layers).  Same ``masked_attention`` core at the
     same [B, pad_len, KH, D] shapes as the paged gather path — the bitwise
-    comparison target.  A model with recurrent layers carries their window
-    ``[Lr, B, (K - 1) * W]`` and state ``[Lr, B, N, I]`` after K and V, a
+    comparison target.  A model with window layers carries their K and V
+    next, ``[Lw, B, ring_len, KH, D]``, position ``p`` at row ``p %
+    ring_len`` (``ring_len`` None: ``cfg.window`` rows; the paged step's
+    gathered ring is ``window_ring * block_size`` long, and the bitwise
+    comparison wants that).  A model with recurrent layers carries their
+    window ``[Lr, B, (K - 1) * W]`` and state ``[Lr, B, N, I]`` last, a
     lane a row (the window alone where the layers keep no state), through
     the same ``_Recurrent`` as the paged step."""
     block = _block(cfg)
     pool_of = _pool_index(cfg)
+    windowed = frozenset(cfg.window_layers)
 
     def step(kv_carry, params, tok, pos, context_lens):
         tok = tok.astype(jnp.int32)
         pos = pos.astype(jnp.int32)
         context_lens = context_lens.astype(jnp.int32)
-        k_c, v_c, *state = kv_carry
-        lanes = jnp.arange(k_c.shape[1], dtype=jnp.int32)
+        # K and V of the global layers, then of the window layers
+        kv = list(kv_carry[:4 if windowed else 2])
+        state = list(kv_carry[len(kv):])
+        lanes = jnp.arange(kv[0].shape[1], dtype=jnp.int32)
 
         def attend(l, q, k, v):
-            nonlocal k_c, v_c
             i = pool_of[l]
-            k_c = k_c.at[i, lanes, pos].set(k.astype(k_c.dtype))
-            v_c = v_c.at[i, lanes, pos].set(v.astype(v_c.dtype))
-            return masked_attention(q, k_c[i], v_c[i], context_lens,
-                                    cfg.attention_multiplier)
+            at = 2 if l in windowed else 0
+            row = pos % kv[at].shape[2] if at else pos
+            for j, x in ((at, k), (at + 1, v)):
+                kv[j] = kv[j].at[i, lanes, row].set(x.astype(kv[j].dtype))
+            return masked_attention(q, kv[at][i], kv[at + 1][i],
+                                    context_lens, cfg.attention_multiplier,
+                                    cfg.window if at else None)
 
         def put(i, value):
             state[0] = state[0].at[i].set(value)
@@ -833,20 +938,26 @@ def make_unpaged_step(cfg, pad_len):
         logits, _extras = block(params, cfg, tok, pos, attend,
                                 context_lens > 0, recur)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return (k_c, v_c, *state), nxt, logits
+        return (*kv, *state), nxt, logits
 
     return step
 
 
-def _unpaged_carry(cfg, lanes, pad_len):
+def _unpaged_carry(cfg, lanes, pad_len, ring_len=None):
     """Zeros for ``make_unpaged_step``'s carry: K and V in the residency
     the paged pool would have (bf16 for a bf16 model; the f32 default
-    otherwise: int8 has no unpaged twin), and the recurrent layers' window
-    and state as the paged cache would hold them."""
+    otherwise: int8 has no unpaged twin), the window layers' rings, and the
+    recurrent layers' window and state as the paged cache would hold
+    them."""
     kv_dtype = jnp.bfloat16 if cfg.kv_dtype == "bf16" else jnp.float32
     carry = tuple(jnp.zeros((len(cfg.attn_layers), lanes, pad_len,
                              cfg.kv_heads, cfg.head_dim), kv_dtype)
                   for _ in range(2))
+    if cfg.window_layers:
+        carry += tuple(jnp.zeros((len(cfg.window_layers), lanes,
+                                  ring_len or cfg.window, cfg.kv_heads,
+                                  cfg.head_dim), kv_dtype)
+                       for _ in range(2))
     return carry + tuple(
         jnp.zeros((len(cfg.recurrent_layers), lanes) + shape,
                   _kv._PAYLOAD[dt][0])
@@ -854,16 +965,19 @@ def _unpaged_carry(cfg, lanes, pad_len):
 
 
 def unpaged_generate(cfg, params, prompt_ids, max_new, pad_len=None,
-                     eos_id=-1, return_logits=False):
+                     eos_id=-1, return_logits=False, ring_len=None):
     """Greedy single-sequence reference loop (no paging, no batching):
     feed the prompt one token per step, then decode ``max_new`` tokens.
     ``pad_len`` must match the paged path's gathered history length
-    (MAXB * block_size) for the bitwise comparison."""
+    (MAXB * block_size) for the bitwise comparison, and for a model with
+    window layers ``ring_len`` its gathered ring's (``window_ring *
+    block_size``)."""
     if pad_len is None:
         pad_len = cfg.max_seq
-    step = jax.jit(make_unpaged_step(cfg, pad_len), donate_argnums=(0,))
+    step = jax.jit(make_unpaged_step(cfg, pad_len, ring_len),
+                   donate_argnums=(0,))
     jparams = {k: jnp.asarray(v) for k, v in params.items()}
-    kv = _unpaged_carry(cfg, 1, pad_len)
+    kv = _unpaged_carry(cfg, 1, pad_len, ring_len)
     prompt_ids = [int(t) for t in prompt_ids]
     out, logits_hist = [], []
     tok = prompt_ids[0]

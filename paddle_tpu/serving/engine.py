@@ -783,7 +783,7 @@ class _DecodeSeq:
 
     __slots__ = ("pending", "prompt", "max_new", "eos_id", "on_token",
                  "blocks", "table", "draft_blocks", "draft_table",
-                 "state_slot", "n_fed", "n_disp", "out",
+                 "state_slot", "window_ring", "n_fed", "n_disp", "out",
                  "t_admit", "t_first", "token_times", "admit_seq",
                  "aborted", "hashes", "published", "cached_tokens",
                  "handoff", "prefill_upto",
@@ -803,6 +803,10 @@ class _DecodeSeq:
         # the slot holding the recurrent layers' state (a model that has
         # them): taken at admission, given back with the blocks
         self.state_slot = None
+        # the blocks held in the window layers' pools (a model that has
+        # them): a ring started at admission, moved on as the sequence is
+        # dispatched, given back with the blocks
+        self.window_ring = None
         self.n_fed = 0
         self.n_disp = 0
         self.out = []
@@ -881,8 +885,10 @@ class _DecodeSeq:
         history blocks, so the replay usually skips straight past the
         cached prefix again.  A model with recurrent layers has no
         index to match: its replay starts at position 0, in whatever
-        slot re-admission hands it.)"""
+        slot re-admission hands it; so does a model's with window layers,
+        in an empty ring.)"""
         self.state_slot = None
+        self.window_ring = None
         self.blocks = []
         self.table.fill(-1)
         self.draft_blocks = []
@@ -901,7 +907,8 @@ class _DecodeSeq:
 
 class _DecodeModel:
     __slots__ = ("name", "cfg", "params", "kv_config", "cache", "stepfn",
-                 "maxb", "attn_path", "experts_path", "blocks_read",
+                 "maxb", "attn_path", "window_path", "experts_path",
+                 "blocks_read", "window_read",
                  "step_ms", "prefix",
                  "declines", "slot_bytes", "state_name", "feed0",
                  "__weakref__",
@@ -924,6 +931,12 @@ class _DecodeModel:
         # (add_model sets both)
         self.attn_path = None
         self.blocks_read = None
+        # the same of the window layers' attention over their rings
+        # (``window_read``: lens -> the blocks one such layer fetches of a
+        # ring, and would fetch of the whole table), None for a model with
+        # no such layer
+        self.window_path = None
+        self.window_read = None
         # bucket -> how a routed layer's experts are read at that many
         # lanes ("pallas": the experts hit alone | "einsum": all of them);
         # empty for a model with no routed layer (add_model sets it)
@@ -932,7 +945,9 @@ class _DecodeModel:
         self.prefix = None          # PrefixCache (FLAGS_prefix_cache)
         # why this model declines what starts or moves a sequence at
         # pos > 0 over K/V blocks alone (prefix reuse, history
-        # publication, block adoption, session export), or None
+        # publication, block adoption, session export), or None:
+        # "recurrent_state" (a slot is nowhere but in its sequence),
+        # "window_layers" (K and V older than the window are in no block)
         self.declines = None
         # one sequence's recurrent state over all such layers, in bytes,
         # and what its spans, gauge and counter call it (add_model sets
@@ -1156,6 +1171,13 @@ class DecodeEngine:
         if draft is None:
             k = 0   # no draft bundle -> non-speculative regardless of k
         recurrent = bool(cfg.recurrent_layers)
+        windowed = bool(cfg.window_layers)
+        if windowed and k > 0:
+            # verify's junk-first columns and the draft's rollout write
+            # where no ring has a block yet
+            raise ValueError(
+                "model %r has window layers: speculative decoding is not "
+                "planned over their rings (speculative_k=%d)" % (name, k))
         if recurrent and k > 0:
             # verify rolls a rejected proposal back by trimming the block
             # table; a recurrent state that has consumed it cannot be
@@ -1182,16 +1204,18 @@ class DecodeEngine:
             # the model's own residency (a bf16 model keeps a bf16 cache),
             # else the deployment's flag
             cfg.kv_dtype or str(_flag("kv_cache_dtype")),
-            # a sequence holds a state slot exactly while it holds a lane:
-            # one a lane of the largest bucket, and the scratch
-            state_slots=max(self.buckets) + 1 if recurrent else 0)
+            # a sequence holds a state slot, and a ring of the window
+            # layers' pools, exactly while it holds a lane: one a lane of
+            # the largest bucket, and the scratch
+            state_slots=max(self.buckets) + 1 if recurrent or windowed
+            else 0)
         n, capped = _kvc.plan_num_blocks(
             kv_config, model_resident_bytes=resident + draft_resident,
             requested=kv_blocks)
         kv_config.num_blocks = n
         cache = _kvc.PagedKVCache(kv_config)
         prefix = None
-        if bool(_flag("prefix_cache")) and not recurrent:
+        if bool(_flag("prefix_cache")) and not (recurrent or windowed):
             # content-addressed prefix reuse over the SAME pool: sealed
             # full-prompt blocks park evictable at zero refs, the index
             # revives them on a hash-chain match at admission.  The draft
@@ -1202,7 +1226,8 @@ class DecodeEngine:
                                       kv_config.block_size, namespace=name)
         # a model with recurrent layers gets no index (entry.declines): a
         # hit would start a sequence at pos > 0 over shared K/V blocks,
-        # where the recurrent layers' state at that position is nowhere
+        # where the recurrent layers' state at that position is nowhere,
+        # and the window layers' K and V before it in no block
         jparams = {key: jnp.asarray(v) for key, v in params.items()}
         attn_path = _dm.attention_path(cfg, kv_config, max(self.buckets))
         experts_path = {b: _dm.experts_path(cfg, jparams, b)
@@ -1211,6 +1236,10 @@ class DecodeEngine:
         # take: an executable compiled for one is never restored for the
         # other
         paths = {"attention": attn_path}
+        window_path = _dm.attention_path(
+            cfg, kv_config, max(self.buckets), "window") if windowed else None
+        if windowed:
+            paths["window_attention"] = window_path
         if experts_path:
             paths["experts"] = sorted(experts_path.items())
         stepfn = CarriedStepFn(
@@ -1222,9 +1251,18 @@ class DecodeEngine:
                            cfg=cfg.to_dict(),
                            kv={"block_size": kv_config.block_size,
                                "num_blocks": kv_config.num_blocks,
+                               "window_blocks": kv_config.window_blocks,
                                "dtype": kv_config.dtype}))
         entry = _DecodeModel(name, cfg, jparams, kv_config, cache, stepfn)
         entry.attn_path = attn_path
+        entry.window_path = window_path
+        if windowed:
+            read = functools.partial(
+                _pa.blocks_read, block_size=kv_config.block_size,
+                path=window_path)
+            entry.window_read = lambda lens: (
+                read(lens, maxb=kv_config.window_ring, ring=True),
+                read(lens, maxb=entry.maxb))
         entry.experts_path = experts_path
         entry.blocks_read = functools.partial(
             _pa.blocks_read, block_size=kv_config.block_size,
@@ -1234,6 +1272,8 @@ class DecodeEngine:
             np.zeros(max(self.buckets), np.int32),
             min(next(iter(jparams.values())).sharding.device_set,
                 key=lambda d: d.id))
+        if windowed:
+            entry.declines = "window_layers"
         if recurrent:
             entry.declines = "recurrent_state"
             entry.slot_bytes = _kvc.slot_bytes(kv_config)
@@ -1283,6 +1323,7 @@ class DecodeEngine:
         _tm.event("decode_model_added", model=name, blocks=n,
                   budget_capped=capped, kv_bytes=cache.kv_nbytes,
                   state_bytes=_kvc.state_bytes(kv_config),
+                  window_bytes=_kvc.window_bytes(kv_config),
                   speculative_k=k, prefix_cache=prefix is not None,
                   draft_kv_bytes=entry.draft_cache.nbytes if k else 0)
         return self._models[name]
@@ -1323,6 +1364,8 @@ class DecodeEngine:
             m = self._models[model]
             if m.experts_path:
                 extra["experts"] = m.experts_path[bucket]
+            if m.window_path is not None:
+                extra["window_attention"] = m.window_path
             _tm.event("serving_prewarm", model=model, bucket=bucket,
                       source=got["source"], decode=True, fn=fn,
                       ms=round(got["compile_ms"], 3),
@@ -1375,13 +1418,15 @@ class DecodeEngine:
         return manifest
 
     def _step_args(self, m, bucket, tok, pos, tables, lens, slots=None,
-                   prev=None, src=None):
+                   prev=None, src=None, rings=None):
         """The step's arguments (``make_fed_step``).  ``prev`` is the step
         before's tokens on the device and ``src`` the lane of it each lane
         feeds from (None: nothing in flight, every lane feeds the host's
         ``tok``, as prewarm has them).  A model with recurrent layers is
         told the state slot of each lane's sequence too (None: every lane
-        idle, on the scratch slot)."""
+        idle, on the scratch slot), and one with window layers each lane's
+        ring in their pools (None: every lane idle, on the scratch
+        block)."""
         args = (m.cache.carry(), m.params, tok,
                 prev if prev is not None else m.feed0,
                 src if src is not None else np.full(bucket, -1, np.int32),
@@ -1389,6 +1434,9 @@ class DecodeEngine:
         if m.cache.slots is not None:
             args += (slots if slots is not None
                      else np.zeros(bucket, np.int32),)
+        if m.cache.window_allocator is not None:
+            args += (rings if rings is not None else np.full(
+                (bucket, m.kv_config.window_ring), -1, np.int32),)
         return args
 
     # -- admission -----------------------------------------------------------
@@ -1975,6 +2023,9 @@ class DecodeEngine:
             # first step starts from zeros
             m.cache.slots.give(seq.state_slot)
             seq.state_slot = None
+        if seq.window_ring is not None:
+            m.cache.release_ring(seq.window_ring)
+            seq.window_ring = None
 
     def _tokens_emitted(self):
         """Every ``on_token`` call of this iteration has been made: tell
@@ -2098,6 +2149,8 @@ class DecodeEngine:
             s.t_admit = now
             if m.cache.slots is not None:
                 s.state_slot = m.cache.slots.take()
+            if m.cache.window_allocator is not None:
+                s.window_ring = m.cache.new_ring()
             if m.declines is not None and bool(_flag("prefix_cache")):
                 # no index to match: the whole prompt (or replay) is fed
                 _tm.inc("prefix_cache_declined_total", model=m.name,
@@ -2590,6 +2643,18 @@ class DecodeEngine:
                 lens[i] = p + 1  # token valid AFTER this step's write
                 if slots is not None:
                     slots[i] = s.state_slot
+            windowed = m.cache.window_allocator is not None
+            rings, released = None, 0
+            if windowed:
+                # the windows move on: what left them goes back to the
+                # window layers' pools, the blocks this step writes come
+                # from them
+                rings = np.full((bucket, m.kv_config.window_ring), -1,
+                                np.int32)
+                for i, s in enumerate(lanes):
+                    released += m.cache.advance_ring(s.window_ring,
+                                                     s.n_disp + 1)
+                    rings[i] = s.window_ring.table
             # blocks a layer's attention fetches this step, of the slots
             # the table has: the live context's share where the kernel
             # reads in place, all of them where the table is gathered
@@ -2606,10 +2671,13 @@ class DecodeEngine:
                     # the recurrent state this step reads and writes
                     read[m.state_name + "_lanes"] = len(lanes)
                     read[m.state_name + "_bytes"] = len(lanes) * m.slot_bytes
+            if windowed and _tr.enabled():
+                read.update(self._window_attrs(m, lens, released))
             sspan = self._open_step_span(m, bucket, lanes, **read)
             args = self._step_args(
                 m, bucket, tok, pos, tables, lens, slots,
-                prev=prev.nxt if prev is not None else None, src=src)
+                prev=prev.nxt if prev is not None else None, src=src,
+                rings=rings)
         # is the device still at work on the step before?  Then it never
         # runs dry between the two, and the host's time since the last
         # fetch kept nothing waiting
@@ -2710,6 +2778,26 @@ class DecodeEngine:
                     published=published)
 
     @staticmethod
+    def _window_attrs(m, lens, released):
+        """What the window layers' attention fetches this step, over all
+        such layers, beside what it would fetch of the lanes' whole
+        contexts (were they global layers on the same path), and the blocks
+        their pools then hold; recorded only while the step span is.  The
+        counter and the gauges ride along."""
+        n = len(m.cfg.window_layers)
+        walloc, alloc = m.cache.window_allocator, m.cache.allocator
+        _tm.inc("kv_window_blocks_released_total", released, model=m.name)
+        _tm.set_gauge("kv_pool_blocks", walloc.in_use, model=m.name,
+                      kind="window")
+        _tm.set_gauge("kv_pool_blocks", alloc.in_use, model=m.name,
+                      kind="global")
+        read, full = m.window_read(lens)
+        return {"kv_window_blocks_read": n * read,
+                "kv_window_blocks_full": n * full,
+                "kv_window_blocks_held": walloc.in_use,
+                "kv_block_size": m.kv_config.block_size}
+
+    @staticmethod
     def _moe_attrs(m, bucket, extras):
         """A routed-expert step returns the tokens it sent to each expert
         in each layer that routes (int32 [routed layers, experts], live
@@ -2721,7 +2809,10 @@ class DecodeEngine:
         with no token was not read: counted."""
         if not extras:
             return {}
-        routed = np.asarray(extras[0])
+        everywhere = np.asarray(extras[0])
+        # the experts this model holds the weights of: all of them, or its
+        # share of a router that scores more
+        routed = everywhere[:, m.cfg.held_experts]
         hit = float((routed > 0).sum(axis=1).mean())
         _tm.inc("moe_tokens_routed_total", int(routed.sum()), model=m.name)
         _tm.set_gauge("moe_experts_hit", hit, model=m.name)
@@ -2730,10 +2821,19 @@ class DecodeEngine:
                     int((routed == 0).sum()), model=m.name)
         # means over the routed layers: experts with a token, the fullest
         # expert's tokens, and the tokens routed (lanes x experts a token)
-        return {"moe_experts_hit": round(hit, 3),
-                "moe_load_max": round(float(routed.max(axis=1).mean()), 3),
-                "moe_assignments": round(float(routed.sum(axis=1).mean()),
-                                         3)}
+        attrs = {"moe_experts_hit": round(hit, 3),
+                 "moe_load_max": round(float(routed.max(axis=1).mean()), 3),
+                 "moe_assignments": round(float(routed.sum(axis=1).mean()),
+                                          3)}
+        if routed.shape != everywhere.shape:
+            # a share: the assignments computed here, and those left to the
+            # experts it does not hold
+            absent = int(everywhere.sum() - routed.sum())
+            _tm.inc("moe_assignments_absent_total", absent, model=m.name)
+            attrs["moe_local_assignments"] = attrs["moe_assignments"]
+            attrs["moe_absent_assignments"] = round(
+                absent / float(len(routed)), 3)
+        return attrs
 
     def _spec_step_locked(self, m):
         """One speculative iteration (lock held): the draft decoder

@@ -50,6 +50,20 @@ shared, trimmed or framed: prefix reuse, speculative roll-back and block
 export stay with models whose every layer pages, and the engine declines
 them for the others under counters.
 
+Window layers (sliding-window attention) are a third kind.  They attend
+the last ``window`` positions only, so their K and V live in pools of their
+own, apart from the global layers' ``num_blocks``: ``window_slots`` *rings*
+of ``window_ring = ceil(window / block_size) + 1`` blocks, the largest lane
+bucket's lanes and a scratch, as the recurrent slots are sized.  A sequence
+keeps a ``WindowRing`` there: position ``p`` lies in the block of ring slot
+``(p // block_size) % window_ring``, taken from ``window_allocator`` when
+the sequence reaches it and given back as soon as every position of it has
+left the window, so a sequence of any length holds at most ``window_ring``
+window blocks and the step is handed the ring as a second, short table.
+What left the window is gone: a window layer's history cannot be matched,
+published, exported or resumed from, and the engine declines those for such
+a model as it does for recurrent state.
+
 ``PrefixCache`` is the content-addressed index over sealed blocks: a
 per-model hash chain ``h_i = sha(h_{i-1}, block_token_ids)`` over *full*
 prompt blocks keys each physical block, ``match`` revives the longest
@@ -82,12 +96,14 @@ from collections import OrderedDict
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from ..core import telemetry as _tm
 
 __all__ = ["KVCacheConfig", "BlockAllocator", "SlotAllocator",
-           "PagedKVCache", "PrefixCache",
+           "WindowRing", "PagedKVCache", "PrefixCache",
            "plan_num_blocks", "block_bytes", "slot_bytes", "state_bytes",
+           "window_bytes",
            "engine_owned_kv_bytes",
            "engine_owned_resident_bytes", "register_resident_bytes",
            "quantize_kv", "dequantize_kv"]
@@ -129,14 +145,20 @@ class KVCacheConfig:
     of each ``(shape, dtype)`` of ``state_shapes`` (dtype ``f32`` |
     ``bf16``), constant in the sequence's length, in one of
     ``state_slots`` slots (slot 0 is the idle lanes' scratch, as block 0
-    is).  A model of attention layers only has none."""
+    is).  A model of attention layers only has none.
+
+    Window layers: ``window_layers`` of them hold K and V of the last
+    ``window`` positions, same heads, block size and residency, in
+    ``window_slots`` rings of ``window_ring`` blocks (ring 0's first block
+    is the idle lanes' scratch)."""
 
     __slots__ = ("layers", "heads", "head_dim", "block_size", "num_blocks",
-                 "dtype", "state_layers", "state_shapes", "state_slots")
+                 "dtype", "state_layers", "state_shapes", "state_slots",
+                 "window_layers", "window", "window_slots")
 
     def __init__(self, layers, heads, head_dim, block_size, num_blocks,
                  dtype="f32", state_layers=0, state_shapes=(),
-                 state_slots=0):
+                 state_slots=0, window_layers=0, window=0, window_slots=0):
         if dtype not in _PAYLOAD:
             raise ValueError("kv_cache dtype must be f32|bf16|int8: %r"
                              % (dtype,))
@@ -153,6 +175,13 @@ class KVCacheConfig:
         self.state_shapes = tuple(
             (tuple(int(x) for x in shape), dt) for shape, dt in state_shapes)
         self.state_slots = int(state_slots)
+        self.window_layers = int(window_layers)
+        self.window = int(window)
+        self.window_slots = int(window_slots)
+        if self.window_layers and (self.window < 1 or self.window_slots <= 1):
+            raise ValueError("window layers need window >= 1 and "
+                             "window_slots > 1 (a ring a lane and the "
+                             "scratch)")
         if any(dt not in ("f32", "bf16") for _shape, dt in self.state_shapes):
             raise ValueError("a recurrent state is f32|bf16: %r"
                              % (state_shapes,))
@@ -168,31 +197,71 @@ class KVCacheConfig:
         residency their scales."""
         return 4 if self.dtype == "int8" else 2
 
+    @property
+    def window_ring(self):
+        """Blocks of a sequence's ring in a window layer's pool: the window
+        may straddle one block more than it fills."""
+        if not self.window_layers:
+            return 0
+        return -(-self.window // self.block_size) + 1
+
+    @property
+    def window_blocks(self):
+        """Blocks of a window layer's pool: a ring a slot."""
+        return self.window_slots * self.window_ring
+
+    def _cuts(self, carry):
+        carry = list(carry)
+        cut = self.kv_groups * self.layers
+        wcut = cut + self.kv_groups * self.window_layers
+        held = len(self.state_shapes) * self.state_layers
+        if len(carry) != wcut + held:
+            raise ValueError("a carry of %d arrays is not this cache's "
+                             "(%d KV + %d window + %d state)"
+                             % (len(carry), cut, wcut - cut, held))
+        return carry, cut, wcut
+
     def groups(self, carry):
         """A cache carry (or anything laid out like one) by what it holds
         -> ``(kv, state)``: ``kv`` the groups of ``layers`` per-layer
         pools (``[k, v]``, and ``[k, v, k_scales, v_scales]`` for int8
         residency), ``state`` one group of ``state_layers`` per-layer
-        arrays for each entry of ``state_shapes``."""
-        carry = list(carry)
-        cut = self.kv_groups * self.layers
-        held = len(self.state_shapes) * self.state_layers
-        if len(carry) != cut + held:
-            raise ValueError("a carry of %d arrays is not this cache's "
-                             "(%d KV + %d state)" % (len(carry), cut, held))
-        kv = [carry[i:i + self.layers] for i in range(0, cut, self.layers)]
+        arrays for each entry of ``state_shapes``.  The window layers'
+        pools lie between the two (``window_groups``)."""
+        carry, cut, wcut = self._cuts(carry)
+        kv = [carry[i:i + self.layers]
+              for i in range(0, cut, self.layers or 1)]
         state = [carry[i:i + self.state_layers]
-                 for i in range(cut, len(carry), self.state_layers or 1)]
+                 for i in range(wcut, len(carry), self.state_layers or 1)]
         return kv, state
 
+    def window_groups(self, carry):
+        """The window layers' pools of a carry, grouped as ``groups``'s
+        ``kv`` is: ``window_layers`` arrays a group."""
+        carry, cut, wcut = self._cuts(carry)
+        return [carry[i:i + self.window_layers]
+                for i in range(cut, wcut, self.window_layers or 1)]
 
-def block_bytes(config):
-    """HBM bytes ONE block costs across all attention layers (K + V, +
-    scales for int8)."""
+
+def _layer_block_bytes(config):
+    """HBM bytes ONE block costs in one layer (K + V, + scales for
+    int8)."""
     tok = config.heads * config.head_dim * _PAYLOAD[config.dtype][1]
     if config.dtype == "int8":
         tok += config.heads * 4                     # f32 scales
-    return 2 * config.layers * config.block_size * tok
+    return 2 * config.block_size * tok
+
+
+def block_bytes(config):
+    """HBM bytes ONE block costs across all (global) attention layers."""
+    return config.layers * _layer_block_bytes(config)
+
+
+def window_bytes(config):
+    """HBM bytes of the window layers' pools: every ring, the scratch one
+    included.  Fixed by the lane buckets, as the recurrent slots are."""
+    return config.window_layers * config.window_blocks \
+        * _layer_block_bytes(config)
 
 
 def slot_bytes(config):
@@ -232,10 +301,10 @@ def plan_num_blocks(config, model_resident_bytes=0, requested=None,
         budget = int(_flags.flag("hbm_budget_bytes") or 0)
     per = block_bytes(config)
     if budget > 0:
-        # the recurrent layers' slots are held whatever the blocks: they
-        # come off the budget first
+        # the recurrent layers' slots and the window layers' rings are
+        # held whatever the blocks: they come off the budget first
         model_resident_bytes = int(model_resident_bytes) \
-            + state_bytes(config)
+            + state_bytes(config) + window_bytes(config)
         fit = int((budget - int(model_resident_bytes)) // per)
         if fit < 2:
             raise ValueError(
@@ -248,6 +317,12 @@ def plan_num_blocks(config, model_resident_bytes=0, requested=None,
             return min(requested, fit), fit < requested
         return fit, False
     return (requested if requested > 0 else _DEFAULT_BLOCKS), False
+
+
+class _Quiet:
+    """Telemetry that records nothing (``BlockAllocator(gauges=False)``)."""
+
+    inc = set_gauge = staticmethod(lambda *args, **labels: None)
 
 
 class BlockAllocator:
@@ -269,12 +344,16 @@ class BlockAllocator:
     decisions must budget against: a warm cache never causes a spurious
     shed."""
 
-    def __init__(self, num_blocks, reserve=0):
+    def __init__(self, num_blocks, reserve=0, gauges=True):
         if num_blocks <= reserve:
             raise ValueError("num_blocks %d <= reserve %d"
                              % (num_blocks, reserve))
         self.num_blocks = int(num_blocks)
         self.reserve = int(reserve)
+        # the process-wide kv_blocks_* gauges and kv_block_* counters are
+        # the global pool's: a window layers' allocator keeps out of them
+        # (its owner reports kv_pool_blocks{kind="window"})
+        self._tm = _tm if gauges else _Quiet
         # LIFO: the most recently freed block is the next handed out, so a
         # churning batch keeps touching the same hot cache lines
         self._free = list(range(num_blocks - 1, reserve - 1, -1))
@@ -325,7 +404,7 @@ class BlockAllocator:
         evicted = []
         with self._lock:
             if n > len(self._free) + len(self._evictable):
-                _tm.inc("kv_block_oom_total")
+                self._tm.inc("kv_block_oom_total")
                 return None
             got = []
             while len(got) < n and self._free:
@@ -338,9 +417,9 @@ class BlockAllocator:
                 self._owned.add(b)
                 self._ref[b] = 1
             self._note_high_water_locked()
-            _tm.inc("kv_block_alloc_total", n)
-            _tm.set_gauge("kv_blocks_in_use", len(self._owned))
-            _tm.set_gauge("kv_blocks_evictable", len(self._evictable))
+            self._tm.inc("kv_block_alloc_total", n)
+            self._tm.set_gauge("kv_blocks_in_use", len(self._owned))
+            self._tm.set_gauge("kv_blocks_evictable", len(self._evictable))
             cb = self.on_evict
         # the index callback runs outside the allocator lock (it takes the
         # PrefixCache lock; the module-level LOCK_ORDER registry declares
@@ -367,7 +446,7 @@ class BlockAllocator:
             self._ref[block] = 1
             self._sealed[block] = tag        # stays sealed: re-parks at 0
             self._note_high_water_locked()
-            _tm.set_gauge("kv_blocks_in_use", len(self._owned))
+            self._tm.set_gauge("kv_blocks_in_use", len(self._owned))
             return True
 
     def seal(self, block, tag):
@@ -402,9 +481,9 @@ class BlockAllocator:
                     self._evictable[b] = tag     # newest = last (LRU front)
                 else:
                     self._free.append(b)
-            _tm.inc("kv_block_free_total", released)
-            _tm.set_gauge("kv_blocks_in_use", len(self._owned))
-            _tm.set_gauge("kv_blocks_evictable", len(self._evictable))
+            self._tm.inc("kv_block_free_total", released)
+            self._tm.set_gauge("kv_blocks_in_use", len(self._owned))
+            self._tm.set_gauge("kv_blocks_evictable", len(self._evictable))
 
     def discard_evictable(self, block):
         """Truly free a zero-ref evictable block (back to the free list,
@@ -420,8 +499,8 @@ class BlockAllocator:
                 return False
             del self._evictable[block]
             self._free.append(block)
-            _tm.inc("kv_block_discard_total")
-            _tm.set_gauge("kv_blocks_evictable", len(self._evictable))
+            self._tm.inc("kv_block_discard_total")
+            self._tm.set_gauge("kv_blocks_evictable", len(self._evictable))
             return True
 
     def _note_high_water_locked(self):
@@ -479,6 +558,25 @@ class SlotAllocator:
                                  % (slot,))
             self._held.discard(slot)
             self._free.append(slot)
+
+
+class WindowRing:
+    """One sequence's blocks in the window layers' pools: ``table`` the
+    ring handed to the step (physical block of each ring slot, -1 where the
+    sequence holds none) and ``[lo, hi)`` the logical blocks held, block
+    ``i`` (positions ``[i * block_size, (i + 1) * block_size)``) in slot ``i
+    % len(table)``.  ``PagedKVCache.advance_ring`` / ``release_ring`` move
+    it."""
+
+    __slots__ = ("table", "lo", "hi")
+
+    def __init__(self, slots):
+        self.table = np.full(int(slots), -1, np.int32)
+        self.lo = self.hi = 0
+
+    @property
+    def held(self):
+        return self.hi - self.lo
 
 
 class PrefixCache:
@@ -722,21 +820,34 @@ class PagedKVCache:
     ``allocator`` hands out blocks and ``slots`` (None without recurrent
     layers) state slots.  A slot is constant in the sequence's length and
     cannot be shared, trimmed or snapshotted: prefix reuse, speculative
-    roll-back and block export are for models whose every layer pages."""
+    roll-back and block export are for models whose every layer pages.
+
+    The window layers' pools follow the global K/V groups in the carry, in
+    the same groups, ``[window_blocks, block_size, heads * head_dim]``
+    each; ``window_allocator`` (None without window layers) hands out their
+    blocks, one id for every window layer alike, through a sequence's
+    ``WindowRing``."""
 
     def __init__(self, config):
         self.config = config
         self.allocator = BlockAllocator(config.num_blocks, reserve=1)
         self.slots = SlotAllocator(config.state_slots) \
             if config.state_layers else None
-        rows = (config.num_blocks, config.block_size)
-        payload = rows + (config.heads * config.head_dim,)
-        groups = [(payload, _PAYLOAD[config.dtype][0])] * 2
-        if config.dtype == "int8":
-            groups += [(rows + (config.heads,), jnp.float32)] * 2
-        self._carry = tuple(jnp.zeros(shape, dtype)
-                            for shape, dtype in groups
-                            for _ in range(config.layers))
+        self.window_allocator = BlockAllocator(
+            config.window_blocks, reserve=1, gauges=False) \
+            if config.window_layers else None
+
+        def pools(num_blocks, layers):
+            rows = (num_blocks, config.block_size)
+            payload = rows + (config.heads * config.head_dim,)
+            groups = [(payload, _PAYLOAD[config.dtype][0])] * 2
+            if config.dtype == "int8":
+                groups += [(rows + (config.heads,), jnp.float32)] * 2
+            return tuple(jnp.zeros(shape, dtype) for shape, dtype in groups
+                         for _ in range(layers))
+
+        self._carry = pools(config.num_blocks, config.layers) \
+            + pools(config.window_blocks, config.window_layers)
         self._carry += tuple(
             jnp.zeros((config.state_slots,) + shape, _PAYLOAD[dt][0])
             for shape, dt in config.state_shapes
@@ -751,9 +862,11 @@ class PagedKVCache:
 
     @property
     def nbytes(self):
-        """Everything this cache holds on the device: the K/V pools and
-        the recurrent layers' slots (``state_bytes``)."""
-        return self.kv_nbytes + state_bytes(self.config)
+        """Everything this cache holds on the device: the K/V pools, the
+        window layers' (``window_bytes``) and the recurrent layers' slots
+        (``state_bytes``)."""
+        return self.kv_nbytes + window_bytes(self.config) \
+            + state_bytes(self.config)
 
     def carry(self):
         """The current device arrays, in decode-step argument order."""
@@ -828,6 +941,52 @@ class PagedKVCache:
             kv_carry, block,
             [a[l].reshape(c.shape[1:]) for group, a in zip(groups, arrays)
              for l, c in enumerate(group)]) + self._carry[len(kv_carry):]
+
+    # -- the window layers' rings --------------------------------------------
+
+    def new_ring(self):
+        """An empty ring for a sequence about to start (or replay from)
+        position 0."""
+        return WindowRing(self.config.window_ring)
+
+    def advance_ring(self, ring, context_len):
+        """Move a sequence's ring on for a step that attends from a context
+        of ``context_len`` tokens (the position it writes included; a
+        sequence's steps come one position after another from 0): give back
+        every block whose positions have all left the window, take the
+        block the write reaches.  -> blocks given back.  The pools hold a
+        ring for every lane, so running out is a bug of the caller's (more
+        sequences than lanes) and raises."""
+        c = self.config
+        lo = max(context_len - c.window, 0) // c.block_size
+        hi = self.blocks_for_tokens(context_len)
+        released = self._drop(ring, range(ring.lo, lo))
+        ring.lo = max(ring.lo, lo)
+        if hi > ring.hi:
+            got = self.window_allocator.alloc(hi - ring.hi)
+            if got is None:
+                raise RuntimeError(
+                    "no window block free (%d held): more sequences hold "
+                    "rings than the largest bucket has lanes"
+                    % self.window_allocator.in_use)
+            for i, b in zip(range(ring.hi, hi), got):
+                ring.table[i % len(ring.table)] = b
+            ring.hi = hi
+        return released
+
+    def release_ring(self, ring):
+        """Give back everything a ring holds (the sequence ended, or was
+        preempted: its replay starts an empty ring at position 0)."""
+        freed = self._drop(ring, range(ring.lo, ring.hi))
+        ring.lo = ring.hi = 0
+        return freed
+
+    def _drop(self, ring, logical):
+        slots = [i % len(ring.table) for i in logical]
+        if slots:
+            self.window_allocator.free([int(ring.table[j]) for j in slots])
+            ring.table[slots] = -1
+        return len(slots)
 
     # -- multi-token growth / rollback (the speculative-decode contract) -----
 

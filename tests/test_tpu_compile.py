@@ -355,3 +355,82 @@ def test_lfm2_moe_step_updates_pools_and_windows_in_place(one_chip,
     found = [line.strip()[:160] for line in text.splitlines()
              if big.search(line)]
     assert not found, found
+
+
+def test_exaone_moe_step_compiles_both_attention_kinds_and_its_experts(
+        one_chip, as_on_tpu):
+    """K-EXAONE-236B-A23B's published widths (64 query heads over 8 KV
+    heads of 128 behind a stream of 6144, a window of 128, 16 held experts
+    of width 2048 under a router of 128, the dense MLP of 18432), published
+    layers 0 and 3 of the configuration's cut (sliding + dense, full +
+    sparse), bucket 32, the cell's pools (12,832 bf16 blocks 1024 wide for
+    the global layer, 33 rings of 9 blocks for the window layer): Mosaic
+    accepts the paged-attention kernel twice inside the whole step, once
+    over a ring as one chunk of 144 positions and once over the whole
+    context, the query compact in VMEM both times (2.1e6 B of queries and
+    outputs where the spread layout asked 16.8e6 and was refused), and the
+    routed-expert kernel over the 16 held experts in column chunks of 256
+    (22.2e6 B of VMEM, under its 40 MiB limit); both kinds of pool are
+    aliased whole."""
+    from benchmark.models import exaone_moe_decoder
+    from paddle_tpu.pallas_kernels import moe_experts as moe
+    from paddle_tpu.pallas_kernels import paged_attention as pa
+
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "k-exaone-236b-a23b-serve.json")) as fp:
+        config = json.load(fp)
+    keep = [0, 3]
+    config = dict(config, num_hidden_layers=2, **{
+        key: [config[key][l] for l in keep]
+        for key in ("layer_types", "mlp_layer_types", "sliding_windows")})
+    cfg = exaone_moe_decoder.decoder_config(config)
+    assert (cfg.hidden, cfg.heads, cfg.kv_heads, cfg.head_dim, cfg.window,
+            cfg.experts, cfg.experts_held, cfg.experts_per_token, cfg.ffn,
+            cfg.shared_ffn, cfg.dense_ffn, cfg.layer_types,
+            cfg.routed_layers, cfg.max_seq) == (
+        6144, 64, 8, 128, 128, 128, 16, 8, 2048, 2048, 18432,
+        ("window", "attention"), (1,), 8192)
+    lanes, block_size, blocks = 32, 16, 12832
+    kv = dm.cache_config(cfg, block_size, blocks, state_slots=lanes + 1)
+    assert (kv.window_ring, kv.window_blocks) == (9, 297)
+    assert dm.attention_path(cfg, kv, lanes) == "pallas"
+    assert dm.attention_path(cfg, kv, lanes, "window") == "pallas"
+    q = (lanes, 64, 128)
+    assert pa.vmem_bytes(q, (blocks, 16, 1024), jnp.bfloat16) \
+        == 4 * 128 * 1024 * 2 + 2 * lanes * 64 * 128 * 4 == 3145728
+    assert pa.vmem_bytes(q, (297, 16, 1024), jnp.bfloat16, 9) \
+        == 4 * 144 * 1024 * 2 + 2 * lanes * 64 * 128 * 4 == 3276800
+    assert moe.f_chunk(6144, 2048, 2) == 256
+    assert moe._vmem_bytes(32, 6144, 256, 2) == 22151168 < moe._VMEM_LIMIT
+    assert moe.experts_path(lanes, (16, 6144, 2048), jnp.bfloat16) == "pallas"
+
+    on_chip = functools.partial(_placed, one_chip)
+
+    carry = on_chip(jax.eval_shape(lambda: PagedKVCache(kv).carry()))
+    params = on_chip({
+        name: jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+        for name, (shape, _kind)
+        in exaone_moe_decoder.param_shapes(config).items()})
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    feeds = on_chip([i32(lanes), i32(lanes), i32(lanes), i32(lanes),
+                     i32(lanes, cfg.max_seq // block_size), i32(lanes),
+                     i32(lanes, kv.window_ring)])
+    compiled = jax.jit(dm.make_fed_step(cfg, kv, lanes), donate_argnums=(0,)
+                       ).lower(carry, params, *feeds).compile()
+
+    text = compiled.as_text()
+    assert _expert_kernels(text) == 1          # the one sparse layer
+    assert _kernel_calls(text) == 3            # and an attention a layer
+    assert len(re.findall(r"%paged_attention\S* = ", text)) == 2
+    assert not _expert_passes(text, 16, 6144, 2048)
+    pool_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in carry)
+    assert pool_bytes == 2 * (12832 + 297) * 16 * 1024 * 2
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= pool_bytes
+    # beside its arguments: the dense layer's [32, 18432] activations and
+    # the like, nothing of a pool's or of the spread query's size
+    assert memory.temp_size_in_bytes < 2 * lanes * 64 * 1024 * 4
+    spread = re.compile(r" = f32\[32,64,1024\]")
+    found = [line.strip()[:160] for line in text.splitlines()
+             if spread.search(line)]
+    assert not found, found

@@ -28,7 +28,11 @@ the scopes gain ``layerN/ssm/in_proj``, ``conv``, ``state_update`` and
 step makes of it off the TPU: the comparison the kernel was adopted by.
 For ``--config lfm2-24b-a2b-serve --blocks 2048`` every lane holds a window
 slot and the scopes gain ``layerN/conv/in_proj``, ``window`` and
-``out_proj``.  For a routed-expert configuration the result also gives
+``out_proj``.  For ``--config k-exaone-236b-a23b-serve --blocks 12832`` every
+lane holds a full ring in the window layers' pools beside its blocks of the
+global layer's, ``layerN/attn/kv_read`` is the kernel over a ring or over the
+whole context by the layer's kind, and the routed layers gain
+``layerN/moe/shared``.  For a routed-expert configuration the result also gives
 ``moe/experts``' achieved bytes/s (``benchmark/moe_cost.py``, or
 ``benchmark/lfm2_cost.py`` for a source with its keys, over the scope's
 device time) and ``--experts`` names the forms of the routed layer to run,
@@ -144,12 +148,23 @@ def experts_ragged(k):
     return experts
 
 
-def check_attention(cfg, kv, tables, lens, seed, repeat=24):
+def full_rings(kv, lanes):
+    """Window tables [lanes, ring] for lanes past their first window: a
+    full ring of its own blocks each (ring 0 holds the scratch block)."""
+    import numpy as np
+
+    ring = kv.window_ring
+    return (ring + np.arange(lanes * ring, dtype=np.int32)).reshape(lanes,
+                                                                    ring)
+
+
+def check_attention(cfg, kv, tables, lens, seed, repeat=24, window=None):
     """The kernel against the gather path on one layer's pools (see the
     module docstring).  ``scale`` is the rms of the exact output.  A path is
     timed as ``repeat`` calls chained inside one program, each call's query
     the last one's output: one call alone costs less than its dispatch from
-    the host (0.2 ms)."""
+    the host (0.2 ms).  ``window`` given, the pools are a window layer's and
+    ``tables`` the lanes' rings."""
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -158,7 +173,8 @@ def check_attention(cfg, kv, tables, lens, seed, repeat=24):
     from paddle_tpu.serving import kv_cache as kvc
 
     dtype = kvc._PAYLOAD[kv.dtype][0]
-    shape = (kv.num_blocks, kv.block_size, kv.heads * kv.head_dim)
+    shape = (kv.window_blocks if window else kv.num_blocks, kv.block_size,
+             kv.heads * kv.head_dim)
     kq, kk, kv_ = jax.random.split(jax.random.PRNGKey(seed % (2 ** 31)), 3)
     q = jax.random.normal(kq, (len(lens), cfg.heads, cfg.head_dim),
                           jnp.float32)
@@ -168,16 +184,18 @@ def check_attention(cfg, kv, tables, lens, seed, repeat=24):
 
     scale = cfg.attention_multiplier
     paths = {
-        "kernel": lambda q, k, v, t, n: pa._paged_pallas(q, k, v, t, n,
-                                                         scale),
+        "kernel": lambda q, k, v, t, n: pa._paged_pallas(
+            q, k, v, t, n, scale, window=window),
         "gather": lambda q, k, v, t, n: pa.paged_attention_reference(
-            q, k, v, t, n, scale),
+            q, k, v, t, n, scale, window),
         # float32 mathematics on the values as stored
         "exact": lambda q, k, v, t, n: pa.paged_attention_reference(
-            q, k.astype(jnp.float32), v.astype(jnp.float32), t, n, scale),
+            q, k.astype(jnp.float32), v.astype(jnp.float32), t, n, scale,
+            window),
         # every product of bfloat16 operands
         "bf16_products": lambda q, k, v, t, n: pa.paged_attention_reference(
-            q, k.astype(jnp.bfloat16), v.astype(jnp.bfloat16), t, n, scale),
+            q, k.astype(jnp.bfloat16), v.astype(jnp.bfloat16), t, n, scale,
+            window),
     }
     rest = (k_pool, v_pool, tables, lens)
     out, ms = {}, {}
@@ -204,7 +222,8 @@ def check_attention(cfg, kv, tables, lens, seed, repeat=24):
             "ms_per_call": ms,
             "live_blocks": int((-(-np.asarray(lens) // kv.block_size)).sum()),
             "blocks_read": pa.blocks_read(np.asarray(lens), kv.block_size,
-                                          tables.shape[1], "pallas"),
+                                          tables.shape[1], "pallas",
+                                          ring=bool(window)),
             "table_slots": int(tables.size)}
 
 
@@ -215,7 +234,8 @@ def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
     import jax
     import numpy as np
 
-    from benchmark import lfm2_cost, moe_cost, ssm_cost, trace_reduce
+    from benchmark import exaone_cost, lfm2_cost, moe_cost, ssm_cost, \
+        trace_reduce
     from paddle_tpu.core import telemetry
     from paddle_tpu.core.executor import CarriedStepFn
     from paddle_tpu.serving import decode_model as dm
@@ -252,6 +272,8 @@ def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
                    "compile_ms": round(warm["compile_ms"], 1),
                    "pool_sized_instructions": pool_sized(index, pool_elems)},
         "attention": dm.attention_path(cfg, kv, b),
+        "window_attention": dm.attention_path(cfg, kv, b, "window")
+        if cfg.window_layers else None,
         "pallas_kernel_counters": {
             key: value for key, value in telemetry.snapshot()["counters"].items()
             if key.startswith("pallas_kernel_")},
@@ -302,12 +324,18 @@ def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
         # every one for the einsums (hit or not), the profiled steps' hit
         # ones for the others; over the scope's time.  The cost file is the
         # one that reads this source's keys
-        hit = float(np.mean([(np.asarray(r) > 0).sum(axis=1).mean()
-                             for r in routed[-args.steps:]]))
-        moved = (lfm2_cost.routed_stream_floor_bytes_per_step
-                 if "moe_intermediate_size" in config
-                 else moe_cost.expert_stream_bytes_per_step)(
-            config, config["num_experts"] if reads_all else hit)
+        hit = float(np.mean([
+            (np.asarray(r)[:, cfg.held_experts] > 0).sum(axis=1).mean()
+            for r in routed[-args.steps:]]))
+        if "mlp_layer_types" in config:
+            # a share: the held experts of each sparse layer
+            bytes_of = lambda _c, n: exaone_cost.sparse_layers(config) * n \
+                * exaone_cost.expert_bytes(config)
+        elif "moe_intermediate_size" in config:
+            bytes_of = lfm2_cost.routed_stream_floor_bytes_per_step
+        else:
+            bytes_of = moe_cost.expert_stream_bytes_per_step
+        moved = bytes_of(config, config["num_experts"] if reads_all else hit)
         result["moe_experts_hit_per_layer"] = hit
         result["moe_experts_ms_per_step"] = moe_ms
         result["moe_experts_bytes_per_step"] = moved
@@ -371,8 +399,9 @@ def main(argv=None):
     if args.layers:
         config["n_layer" if "n_layer" in config
                else "num_hidden_layers"] = args.layers
-        if "layer_types" in config:
-            config["layer_types"] = config["layer_types"][:args.layers]
+        for key in ("layer_types", "mlp_layer_types", "sliding_windows"):
+            if key in config:
+                config[key] = config[key][:args.layers]
     device = jax.devices()[0]
     model = load_module("models", config["model"])
     cfg = model.decoder_config(config)
@@ -417,6 +446,11 @@ def main(argv=None):
             check_attention(cfg, kv, tables, lens, args.seed),
             label=args.label, config=config["name"], dtype=args.dtype,
             device=device.device_kind, blocks=args.blocks, bucket=b)
+        if cfg.window_layers:
+            # the same of a window layer over every lane's ring
+            result["window"] = check_attention(
+                cfg, kv, full_rings(kv, b), lens, args.seed,
+                window=cfg.window)
         with open(os.path.join(out_dir, "decode_step_check.jsonl"),
                   "a") as fp:
             fp.write(json.dumps(result) + "\n")
@@ -428,6 +462,8 @@ def main(argv=None):
     fluid.set_flags({"FLAGS_telemetry": True})
     slots = (np.arange(1, b + 1, dtype=np.int32),) \
         if cfg.recurrent_layers else ()
+    if cfg.window_layers:
+        slots += (full_rings(kv, b),)
     feed = lambda n: (cache.carry(), params, tok, lens + n - 1, tables,
                       lens + n) + slots
     block_experts = moe_experts.routed_experts
