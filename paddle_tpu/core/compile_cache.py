@@ -42,8 +42,12 @@ plus a ``_SUCCESS`` manifest written last, carrying a per-file crc32):
 Keys are CONTENT hashes — ``Program.to_dict()`` (so a re-built or
 re-transpiled program with identical IR hits, regardless of ``_uid``), the
 trace-affecting flag fingerprint, the ``_collective_meta`` world, feed
-shapes/dtypes, fetch names, mesh axes, and the jax version + backend
-platform (an upgraded jaxlib must never deserialize a stale executable).
+shapes/dtypes, fetch names, mesh axes, the jax version + backend
+platform (an upgraded jaxlib must never deserialize a stale executable),
+and ``code_fingerprint()``, the package's own sources: an executable is
+what the lowerings made of the Program, so another checkout's lowerings
+(PR 39: the parent's composed epilogue, restored by the change from a
+cache directory the two shared) must never be restored either.
 
 Invalidation is by construction: anything that changes the executable
 changes the key; anything that changes the serialization contract fails
@@ -68,7 +72,8 @@ from . import telemetry as _tm
 __all__ = [
     "enabled", "cache_dir", "aot_dir", "xla_dir", "enable_xla_cache",
     "place", "DEFAULT_DIR",
-    "program_fingerprint", "artifact_key", "raw_artifact_key", "load",
+    "program_fingerprint", "code_fingerprint", "artifact_key",
+    "raw_artifact_key", "load",
     "store", "invalidate",
     "entries", "stats", "clear", "evict_to_cap",
 ]
@@ -149,6 +154,29 @@ def _json_default(o):
     return str(o)
 
 
+_code_fp = []
+
+
+def code_fingerprint():
+    """sha256 over the package's ``*.py`` sources (relative path and
+    bytes, in sorted order), once a process: 215 files, under 10 ms.  The
+    lowerings are part of what an executable is made from, and tier A's
+    key (the HLO) already says so; this makes tier B's say it."""
+    if not _code_fp:
+        root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+        h = hashlib.sha256()
+        for d, subdirs, files in os.walk(root):
+            subdirs.sort()
+            for f in sorted(files):
+                if f.endswith(".py"):
+                    path = os.path.join(d, f)
+                    h.update(os.path.relpath(path, root).encode())
+                    with open(path, "rb") as fp:
+                        h.update(fp.read())
+        _code_fp.append(h.hexdigest())
+    return _code_fp[0]
+
+
 _fp_memo = {}
 
 
@@ -192,6 +220,7 @@ def artifact_key(program, feed_sig, fetch_names, trace_flags, mesh_sig=None,
         "world": world,
         "jax": jax.__version__,
         "backend": jax.default_backend(),
+        "code": code_fingerprint(),
         "extra": extra,
     }
     blob = json.dumps(payload, sort_keys=True, default=_json_default)
@@ -209,7 +238,8 @@ def raw_artifact_key(kind, payload):
 
     blob = json.dumps({"format": FORMAT, "kind": str(kind),
                        "payload": payload, "jax": jax.__version__,
-                       "backend": jax.default_backend()},
+                       "backend": jax.default_backend(),
+                       "code": code_fingerprint()},
                       sort_keys=True, default=_json_default)
     return hashlib.sha256(blob.encode()).hexdigest()
 

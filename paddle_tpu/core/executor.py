@@ -937,7 +937,8 @@ class Executor:
                                 mesh, "data",
                                 param_shardings=self._param_shardings(
                                     mesh, block, plan))
-        fn = _with_seed_counter(build_block_fn(plan, mesh=mesh))
+        fn = _with_seed_counter(build_block_fn(plan, mesh=mesh,
+                                               data_axis=data_axis))
         if mesh is None:
             return _BuildResult(plan, fn, donate)
         from jax.sharding import NamedSharding, PartitionSpec as P

@@ -56,6 +56,27 @@ class LowerCtx:
                 key = jax.random.fold_in(base_key, i)
             run_op(op, env, key, mesh=self.mesh, axis_names=self.axis_names)
 
+    def rows_axis(self, lead):
+        """The mesh axis a row-wise lowering may make manual (a shard_map
+        around its own kernel call) in a program XLA partitions
+        automatically, or None: the data axis, where it is the mesh's only
+        axis of size over 1 and divides the operand's leading dimension
+        ``lead``.  None off a mesh, inside a shard_map (``axis_names``: the
+        op runs per shard already), on a dp x tp mesh, for a ragged batch,
+        and under FLAGS_deterministic_reduction, which pins the operands
+        replicated for one summation order that a per-shard sum of the
+        small gradients cannot keep."""
+        mesh, axis = self.mesh, self.data_axis
+        if mesh is None or self.axis_names or axis is None:
+            return None
+        from .. import flags as _flags
+
+        n = mesh.shape[axis]
+        if (n <= 1 or mesh.size != n or not isinstance(lead, int)
+                or lead % n or _flags.flag("deterministic_reduction")):
+            return None
+        return axis
+
     def rng(self):
         if self._rng_key is None:
             if self.mode == "abstract":
@@ -362,8 +383,9 @@ def run_op(op, env, rng_key, mesh=None, axis_names=(), runner=None,
                    data_axis=data_axis)
     guard = contextlib.nullcontext()
     if mesh is not None and not axis_names and mesh.size > 1:
-        # a program XLA partitions automatically: no Pallas kernel may be
-        # chosen while its ops lower (pallas_kernels/adoption.py)
+        # a program XLA partitions automatically: a Pallas kernel is chosen
+        # only inside a shard_map its lowering wraps around the call
+        # (pallas_kernels/adoption.py, LowerCtx.rows_axis)
         from ..pallas_kernels import adoption
 
         guard = adoption.auto_partitioned()
@@ -494,9 +516,12 @@ def build_spmd_block_fn(plan, mesh, axis="data"):
     return fn
 
 
-def build_block_fn(plan, mesh=None, axis_names=()):
+def build_block_fn(plan, mesh=None, axis_names=(), data_axis=None):
     """Return fn(feeds, params_ro, params_rw, params_carry, rng) ->
     (fetches, updated_rw, updated_carry).
+
+    `data_axis` names the axis of `mesh` that the feeds' batch dimension is
+    split over (the GSPMD route; LowerCtx.rows_axis).
 
     feeds/params are dicts name->array. `rng` is a jax PRNG key; op i uses
     fold_in(rng, i) so randomness is deterministic per (seed, step, op).
@@ -525,7 +550,8 @@ def build_block_fn(plan, mesh=None, axis_names=()):
         env.update(feeds)
         for i, op in enumerate(_iter_runtime_ops(block)):
             key = jax.random.fold_in(rng, i) if rng is not None else None
-            run_op(op, env, key, mesh=mesh, axis_names=axis_names)
+            run_op(op, env, key, mesh=mesh, axis_names=axis_names,
+                   data_axis=data_axis)
         fetches = []
         for n in fetch_names:
             if n not in env:

@@ -883,6 +883,17 @@ def _fdaln_uses_dropout(attrs):
             and not attrs.get("is_test", False))
 
 
+def _fdaln_rows_over(ctx, x, begin_norm_axis):
+    """(mesh, axis) when the epilogue runs per shard of x's leading
+    dimension (LowerCtx.rows_axis: the GSPMD route on a data-only mesh that
+    divides it), else None: one rule for the op and its grad op, so both
+    split alike and the mask replays."""
+    if begin_norm_axis < 1 or x.ndim < 2:
+        return None
+    axis = ctx.rows_axis(x.shape[0])
+    return None if axis is None else (ctx.mesh, axis)
+
+
 def _fused_dropout_add_ln_grad_maker(op, no_grad_set):
     inputs = {
         "R": list(op.output("R")),
@@ -946,8 +957,9 @@ def fused_dropout_add_ln_op(ctx, x, y, scale, bias, dropout_prob=0.0,
         seed_arr = jax.random.bits(key, (2,), jnp.uint32)
     else:
         seed_arr = jnp.zeros((2,), jnp.uint32)
-    z, r, mean, var = _fln.fused_ln_fwd(x, y, scale, bias, p, seed_arr,
-                                        epsilon, begin_norm_axis)
+    z, r, mean, var = _fln.fused_ln_fwd(
+        x, y, scale, bias, p, seed_arr, epsilon, begin_norm_axis,
+        rows_over=_fdaln_rows_over(ctx, x, begin_norm_axis))
     return z, r, mean, var, seed_arr.astype(jnp.int32)
 
 
@@ -967,8 +979,9 @@ def fused_dropout_add_ln_grad_op(ctx, r, scale, seed_words, mean, var,
     from ..pallas_kernels import fused_ln as _fln
 
     p = 0.0 if is_test else float(dropout_prob)
-    return _fln.fused_ln_bwd(r, scale, seed_words, mean, var, dz, p,
-                             epsilon, begin_norm_axis)
+    return _fln.fused_ln_bwd(
+        r, scale, seed_words, mean, var, dz, p, epsilon, begin_norm_axis,
+        rows_over=_fdaln_rows_over(ctx, r, begin_norm_axis))
 
 
 fused_dropout_add_ln_op.opdef.rng_when = _fdaln_uses_dropout

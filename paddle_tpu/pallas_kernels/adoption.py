@@ -16,8 +16,14 @@ whether it engages.  A family is chosen from what the lowering can observe
   countable event and never silent.
 * **the trace kind** — build-time shape inference chooses nothing and
   counts nothing (``shape_inference``); in a program that XLA partitions
-  automatically over several devices no kernel engages
-  (``auto_partitioned``, counted under reason ``gspmd_mesh``).
+  automatically over several devices a bare kernel call cannot lower
+  (``auto_partitioned``, counted under reason ``gspmd_mesh``), so a
+  lowering that wants its kernel there wraps the call in a ``shard_map``
+  over the axis its rows are split on and enters ``per_shard`` inside
+  the body: there the family's own checks decide on the shard's shape,
+  as they do on one chip.  ``fused_ln`` does (ops/nn.py, on a mesh whose
+  only axis of size over 1 is the data axis); the other four families
+  and any ``fused_ln`` call outside such a wrap still decline.
 
 ``PADDLE_PALLAS_INTERPRET=1`` forces interpret-mode execution (kernels run
 through the Pallas interpreter on the CPU test tier).  On any backend but
@@ -32,7 +38,8 @@ import os
 import threading
 
 __all__ = ["decide", "active_kernels", "reset", "interpret_mode",
-           "interpret", "auto_partitioned", "shape_inference", "KERNELS"]
+           "interpret", "auto_partitioned", "per_shard", "shape_inference",
+           "KERNELS"]
 
 # the kernel families sharing this funnel
 KERNELS = ("fused_ln", "flash_attention", "paged_attention", "ssm_update",
@@ -95,11 +102,21 @@ def auto_partitioned():
     XLA partitions automatically over a mesh of several devices
     (CompiledProgram.with_data_parallel: jit + NamedSharding, no shard_map).
     Mosaic kernels cannot be partitioned automatically — JAX raises
-    "wrap the call in a shard_map" at lowering — so there every family falls
-    back, counted under reason ``gspmd_mesh``.  Inside a shard_map (the
-    transpiled collective route) kernels run per shard and this is not
-    entered."""
+    "wrap the call in a shard_map" at lowering — so a kernel call that is
+    not wrapped falls back there, counted under reason ``gspmd_mesh``.  A
+    lowering that has wrapped its call enters ``per_shard`` inside the
+    body.  Inside the transpiled collective route's shard_map kernels run
+    per shard and this is not entered."""
     return _tracing("gspmd_mesh")
+
+
+def per_shard():
+    """Entered by a lowering inside the body of a ``shard_map`` it put
+    around its own kernel call in an auto-partitioned program: the axis the
+    rows are split over is manual there, Mosaic sees one shard's operands,
+    and ``decide`` runs the family's checks on the shard's shape instead of
+    declining under ``gspmd_mesh``."""
+    return _tracing(None)
 
 
 def reset():

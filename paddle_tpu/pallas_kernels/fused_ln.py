@@ -29,6 +29,16 @@ Off TPU (CPU test mesh) or for un-tileable shapes, an identical-contract
 jnp fallback keyed on the same seed pair runs instead; forward and
 backward always agree on the mask because both derive it from the saved
 seeds with the same (static) path choice.
+
+On a mesh: Mosaic kernels cannot be partitioned automatically, so in a
+program XLA partitions by itself (with_data_parallel) the op-mode entry
+points take ``rows_over=(mesh, axis)`` and run their row-wise body inside
+a ``shard_map`` over that axis: each shard decides as one chip does on
+its own rows (adoption.per_shard), folds its index along the axis into
+the seed so the shards draw different masks (forward and backward alike;
+the saved seed stays the unfolded pair), and the small gradients are
+summed over the axis in the body.  Without ``rows_over`` a call traced in
+such a program keeps the jnp composition, counted under ``gspmd_mesh``.
 """
 
 import functools
@@ -320,16 +330,65 @@ def _fused_bwd(thr, eps, res, cts):
 _fused.defvjp(_fused_fwd, _fused_bwd)
 
 
+# odd, so rank -> rank * _RANK_MIX is one-to-one on uint32: with the block
+# index folded into word 0 (prng.seed_block_prng) no two (shard, block)
+# pairs share a seed
+_RANK_MIX = 0x9E3779B1
+
+
+def _per_shard(body, rows_over, split_in, split_out):
+    """`body(*args, axis=axis)` as a shard_map over `rows_over` = (mesh,
+    axis).  `split_in` / `split_out`: one flag an argument / a result,
+    split over the axis on its leading dimension (rows) or replicated.
+    Inside, the funnel decides on the shard's shape (adoption.per_shard)."""
+    from jax.sharding import PartitionSpec as P
+
+    from . import adoption
+
+    mesh, axis = rows_over
+
+    def local(*args):
+        with adoption.per_shard():
+            return body(*args, axis=axis)
+
+    return jax.shard_map(
+        local, mesh=mesh,
+        in_specs=tuple(P(axis) if rows else P() for rows in split_in),
+        out_specs=tuple(P(axis) if rows else P() for rows in split_out),
+        check_vma=False)
+
+
+def _shard_seed(seed, axis, thr):
+    """The shard's own seed words: its index along `axis` mixed into word
+    1.  Forward and backward both come through here, so the mask replays
+    per shard."""
+    if axis is None or thr is None:
+        return seed
+    rank = jax.lax.axis_index(axis).astype(jnp.uint32)
+    return seed.at[1].set(seed[1] ^ (rank * jnp.uint32(_RANK_MIX)))
+
+
 def fused_ln_fwd(x, y, gamma, beta, dropout_prob, seed, epsilon,
-                 begin_norm_axis):
+                 begin_norm_axis, rows_over=None):
     """Op-mode forward (explicit-grad-op integration, cf. the dropout op's
     Mask contract): returns (z, r, mean [N], variance [N]) with NO vjp
     tracking — the program-level grad op calls fused_ln_bwd with the saved
     r/seed/stats instead.  r is the post-dropout residual sum, the only
-    large backward residual."""
+    large backward residual.  ``rows_over=(mesh, axis)``: per shard of the
+    leading dimension (module docstring)."""
+    body = functools.partial(_fwd_rows, dropout_prob=dropout_prob,
+                             epsilon=epsilon,
+                             begin_norm_axis=begin_norm_axis)
+    if rows_over is not None:
+        body = _per_shard(body, rows_over, (1, 1, 0, 0, 0), (1, 1, 1, 1))
+    return body(x, y, gamma, beta, jnp.asarray(seed))
+
+
+def _fwd_rows(x, y, gamma, beta, seed, *, dropout_prob, epsilon,
+              begin_norm_axis, axis=None):
     n, h = ln_stat_shapes(x.shape, begin_norm_axis)
     thr = _keep_threshold(dropout_prob)
-    seed = jnp.asarray(seed).reshape(2).astype(jnp.uint32)
+    seed = _shard_seed(seed.reshape(2).astype(jnp.uint32), axis, thr)
     # the epilogue computes in x's carry dtype: casting y up front keeps
     # the fwd/bwd block choice a function of ONE dtype (mask replay)
     z, r, mean, var = _fwd_any(x.reshape(n, h),
@@ -341,18 +400,31 @@ def fused_ln_fwd(x, y, gamma, beta, dropout_prob, seed, epsilon,
 
 
 def fused_ln_bwd(r, gamma, seed, mean, var, dz, dropout_prob, epsilon,
-                 begin_norm_axis):
+                 begin_norm_axis, rows_over=None):
     """Op-mode backward: (dx, dy, dgamma, dbeta) from the saved residual
     sum r; the dropout mask for dy is re-drawn from the SAME seed and
-    grid blocking as the forward."""
+    grid blocking as the forward.  ``rows_over`` as the forward's: dgamma
+    and dbeta are then each shard's sums, added over the axis."""
+    body = functools.partial(_bwd_rows, dropout_prob=dropout_prob,
+                             epsilon=epsilon,
+                             begin_norm_axis=begin_norm_axis)
+    if rows_over is not None:
+        body = _per_shard(body, rows_over, (1, 1, 1, 1, 0, 0), (1, 1, 0, 0))
+    return body(r, dz, mean, var, gamma, jnp.asarray(seed))
+
+
+def _bwd_rows(r, dz, mean, var, gamma, seed, *, dropout_prob, epsilon,
+              begin_norm_axis, axis=None):
     n, h = ln_stat_shapes(r.shape, begin_norm_axis)
     thr = _keep_threshold(dropout_prob)
-    seed = jnp.asarray(seed).reshape(2).astype(jnp.uint32)
+    seed = _shard_seed(seed.reshape(2).astype(jnp.uint32), axis, thr)
     dx, dy, dg, db = _bwd_any(
         r.reshape(n, h), gamma.reshape(h), seed,
         mean.reshape(n, 1).astype(jnp.float32),
         var.reshape(n, 1).astype(jnp.float32), dz.reshape(n, h), thr,
         float(epsilon))
+    if axis is not None:
+        dg, db = jax.lax.psum((dg, db), axis)
     return (dx.reshape(r.shape), dy.reshape(r.shape),
             dg.astype(gamma.dtype), db.astype(gamma.dtype))
 

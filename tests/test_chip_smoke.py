@@ -172,14 +172,36 @@ def test_place_that_names_no_device_raises():
         fluid.Executor(fluid.TPUPlace(n)).run(fluid.Program())
 
 
-def test_no_pallas_kernel_is_chosen_in_an_auto_partitioned_program():
-    """On four real chips with_data_parallel died at lowering: "Mosaic
-    kernels cannot be automatically partitioned".  The funnel now falls
-    back there, by name, before any other check."""
-    from paddle_tpu.pallas_kernels import adoption
+# case -> (mesh shape over ("data", "model") or None for with_data_parallel's
+# own ("data",) mesh, devices, batch, flags, the reason fused_ln counts)
+WRAP_RULE = {
+    # the data axis is the mesh's only one and divides the batch: the op
+    # runs per shard, where the family's own checks decide (off the chip
+    # the first of them, ``backend``, declines)
+    "data_mesh": (None, 4, 8, {}, "backend"),
+    "data_mesh_of_8": (None, 8, 8, {}, "backend"),
+    # everything else keeps the composition XLA partitions, by name
+    "dp_x_tp_mesh": ((4, 2), 8, 8, {}, "gspmd_mesh"),
+    "ragged_batch": (None, 4, 6, {}, "gspmd_mesh"),
+    "deterministic_reduction": (
+        None, 4, 8, {"FLAGS_deterministic_reduction": True}, "gspmd_mesh"),
+}
 
-    old = fluid.get_flags(["FLAGS_telemetry"])
-    fluid.set_flags({"FLAGS_telemetry": True})
+
+@pytest.mark.parametrize("case", sorted(WRAP_RULE))
+def test_fused_ln_runs_per_shard_where_the_mesh_only_splits_the_batch(case):
+    """On four real chips with_data_parallel died at lowering: "Mosaic
+    kernels cannot be automatically partitioned ... wrap the call in a
+    shard_map".  PR 24 made the funnel decline there (``gspmd_mesh``);
+    since PR 39 the epilogue's two lowerings wrap their own call where the
+    lowering sees a data-only mesh that divides the batch, and inside the
+    wrap the funnel decides as on one chip."""
+    import jax
+    from jax.sharding import Mesh
+
+    shape, n, batch, flags, expected = WRAP_RULE[case]
+    old = fluid.get_flags(["FLAGS_telemetry", *flags])
+    fluid.set_flags({"FLAGS_telemetry": True, **flags})
     tm.reset()
     try:
         main, startup = fluid.Program(), fluid.Program()
@@ -187,23 +209,51 @@ def test_no_pallas_kernel_is_chosen_in_an_auto_partitioned_program():
             x = fluid.layers.data("x", shape=[4, 128])
             y = fluid.layers.fc(x, 128, num_flatten_dims=2)
             loss = fluid.layers.mean(
-                fluid.layers.fused_dropout_add_ln(x, y, dropout_prob=0.0))
+                fluid.layers.fused_dropout_add_ln(x, y, dropout_prob=0.1,
+                                                  begin_norm_axis=2))
             fluid.optimizer.SGD(0.1).minimize(loss)
+        compiled = fluid.CompiledProgram(main)
+        if shape is None:
+            compiled.with_data_parallel(
+                loss_name=loss.name,
+                places=[fluid.TPUPlace(i) for i in range(n)])
+        else:
+            compiled._with_mesh(
+                Mesh(np.array(jax.devices()[:n]).reshape(shape),
+                     ("data", "model")), data_axis="data")
         exe = fluid.Executor(fluid.TPUPlace(0))
         with fluid.scope_guard(fluid.Scope()):
             exe.run(startup)
-            exe.run(fluid.CompiledProgram(main).with_data_parallel(
-                loss_name=loss.name),
-                feed={"x": np.ones((8, 4, 128), "f")}, fetch_list=[loss])
-        reasons = {ls["reason"] for _flat, ls in
+            out, = exe.run(compiled, feed={"x": np.ones((batch, 4, 128), "f")},
+                           fetch_list=[loss])
+        assert np.isfinite(out).all()
+        reasons = [ls["reason"] for _flat, ls in
                    tm.label_sets("pallas_kernel_fallback_total")
-                   if ls["kernel"] == "fused_ln"}
-        assert reasons == {"gspmd_mesh"}, reasons
-        assert adoption.decide("fused_ln",
-                               [("backend", False)])[1] == "backend"
+                   if ls["kernel"] == "fused_ln"]
+        # the op and its grad op, one rule: both count the same reason
+        assert reasons == [expected], reasons
+        assert tm.counter_total("pallas_kernel_fallback_total") == 2
+        assert tm.counter_total("pallas_kernel_used_total") == 0
     finally:
         tm.reset()
         fluid.set_flags(old)
+
+
+def test_per_shard_lifts_the_guard_only_inside_the_wrap():
+    """Under the bare guard the funnel declines before it looks at a check;
+    inside ``per_shard`` the checks decide; leaving it restores the guard."""
+    from paddle_tpu.pallas_kernels import adoption
+
+    with adoption.auto_partitioned():
+        assert adoption.decide(
+            "fused_ln", [("backend", True)]) == (False, "gspmd_mesh")
+        with adoption.per_shard():
+            assert adoption.decide(
+                "fused_ln", [("backend", False)]) == (False, "backend")
+            assert adoption.decide("fused_ln", [("backend", True)])[0]
+        assert adoption.decide(
+            "fused_ln", [("backend", True)]) == (False, "gspmd_mesh")
+    adoption.reset()
 
 
 def test_client_beside_a_server_never_opens_a_jax_backend():

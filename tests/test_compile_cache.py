@@ -263,6 +263,29 @@ def test_artifact_key_stable_and_flag_sensitive(tmp_path):
     assert k4 != k1
 
 
+@pytest.mark.parametrize("key", ["artifact_key", "raw_artifact_key"])
+def test_another_checkouts_lowerings_never_share_a_key(monkeypatch, key):
+    """PR 39's chip runs: parent and change shared one cache directory and
+    one Program, and the change restored the parent's executable (the
+    composed epilogue, 436k tokens/s where its own compiled to 472k).  The
+    key now carries the package's sources: stable within a checkout,
+    different where any lowering differs."""
+    main, _s, loss = _build()
+
+    def make():
+        if key == "artifact_key":
+            return cc.artifact_key(main, (("x", (8, 4), "float32"),),
+                                   (loss.name,), ())
+        return cc.raw_artifact_key("decode_step", {"bucket": 32})
+
+    here = cc.code_fingerprint()
+    assert here == cc.code_fingerprint() and len(here) == 64
+    k1 = make()
+    assert k1 == make()
+    monkeypatch.setattr(cc, "_code_fp", ["another checkout's sources"])
+    assert make() != k1
+
+
 _FLIPPED = {"FLAGS_check_nan_inf": True, "FLAGS_bn_stat_subsample": 2,
             "FLAGS_layout_match_params": False,
             "FLAGS_deterministic_reduction": True}

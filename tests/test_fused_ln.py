@@ -161,6 +161,89 @@ def test_program_op_with_dropout_trains():
     np.testing.assert_array_equal(a, c)
 
 
+def _epilogue_with_grads(p, devices, x, y, w):
+    """One fused epilogue and its grad op through the Executor, on one
+    device or data-parallel over `devices` (with_data_parallel: the op and
+    its grad op then run per shard, pallas_kernels/fused_ln.py).  -> Out,
+    R, dX, dY, dScale, dBias."""
+    main, startup = fluid.Program(), fluid.Program()
+    main.random_seed = startup.random_seed = 5
+    with fluid.program_guard(main, startup):
+        xin = fluid.layers.data("x", shape=list(x.shape[1:]))
+        yin = fluid.layers.data("y", shape=list(x.shape[1:]))
+        win = fluid.layers.data("w", shape=list(x.shape[1:]))
+        xin.stop_gradient = yin.stop_gradient = False
+        z = fluid.layers.fused_dropout_add_ln(
+            xin, yin, dropout_prob=p, begin_norm_axis=2,
+            param_attr=fluid.ParamAttr(
+                name="ln_g",
+                initializer=fluid.initializer.Uniform(0.5, 1.5, seed=3)),
+            bias_attr=fluid.ParamAttr(
+                name="ln_b",
+                initializer=fluid.initializer.Uniform(-1.0, 1.0, seed=4)))
+        loss = fluid.layers.reduce_sum(z * win)
+        block = main.global_block()
+        grads = fluid.gradients(
+            [loss], [xin, yin, block.var("ln_g"), block.var("ln_b")])
+        op, = [o for o in block.ops if o.type == "fused_dropout_add_ln"]
+    program = main
+    if devices > 1:
+        program = fluid.CompiledProgram(main).with_data_parallel(
+            loss_name=loss.name,
+            places=[fluid.TPUPlace(i) for i in range(devices)])
+    exe = fluid.Executor(fluid.TPUPlace(0))
+    with fluid.scope_guard(fluid.Scope()):
+        exe.run(startup)
+        return exe.run(program, feed={"x": x, "y": y, "w": w},
+                       fetch_list=[z.name, op.output("R")[0]]
+                       + [g.name for g in grads])
+
+
+def test_per_shard_op_and_grad_equal_one_device_at_dropout_0():
+    """Over 4 devices the wrapped op and its grad op compute what one
+    device computes: rows are independent, and dScale / dBias are the
+    shards' sums added over the axis (f32 rounding: another order)."""
+    rng = np.random.RandomState(6)
+    x, y, w = (rng.randn(8, 4, 128).astype("float32") for _ in range(3))
+    one = _epilogue_with_grads(0.0, 1, x, y, w)
+    four = _epilogue_with_grads(0.0, 4, x, y, w)
+    for name, a, b in zip(("Out", "R", "dX", "dY", "dScale", "dBias"),
+                          one, four):
+        assert a.shape == b.shape, name
+        np.testing.assert_allclose(b, a, rtol=2e-5, atol=2e-5, err_msg=name)
+
+
+def test_per_shard_masks_differ_and_replay(monkeypatch):
+    """At dropout 0.1 over 4 devices: every shard is fed the same rows, so
+    R (= 0 + dropout(y)) shows each shard's mask; the shards draw
+    different masks (the rank is folded into the seed), each keeps 0.9 of
+    its elements within 3 sigma, and dY is zero exactly where the forward
+    dropped (the grad op folds the same rank into the same saved seed).
+    Control: with the fold taken out, the four shards draw one mask."""
+    rng = np.random.RandomState(7)
+    rows = rng.uniform(1.0, 2.0, (2, 16, 128)).astype("float32")
+    y = np.tile(rows, (4, 1, 1))                    # shard k: y[2k:2k+2]
+    x = np.zeros_like(y)
+    w = rng.uniform(1.0, 2.0, y.shape).astype("float32")
+    _z, r, _dx, dy, _dg, _db = _epilogue_with_grads(0.1, 4, x, y, w)
+    kept = (r != 0.0).reshape(4, -1)
+    for a in range(4):
+        for b in range(a + 1, 4):
+            assert (kept[a] != kept[b]).mean() > 0.1, (a, b)
+    n = kept.shape[1]
+    sigma = (0.9 * 0.1 / n) ** 0.5
+    for k in range(4):
+        assert abs(kept[k].mean() - 0.9) < 3 * sigma, (k, kept[k].mean())
+    np.testing.assert_array_equal((dy != 0.0).reshape(4, -1), kept)
+    # what was kept is upscaled by the realised keep probability
+    np.testing.assert_allclose(r[r != 0.0], (y / 0.9)[r != 0.0], rtol=1e-6)
+
+    monkeypatch.setattr(F, "_shard_seed", lambda seed, axis, thr: seed)
+    _z, r, _dx, dy, _dg, _db = _epilogue_with_grads(0.1, 4, x, y, w)
+    same = (r != 0.0).reshape(4, -1)
+    assert (same == same[0]).all() and not same.all()
+
+
 @pytest.mark.tpu
 def test_pallas_kernel_parity_tpu():
     """On-chip: the Pallas path vs the jnp fallback math at p=0, and

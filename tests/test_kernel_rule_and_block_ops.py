@@ -156,8 +156,9 @@ for _family in adoption.KERNELS:
 def test_kernel_rule_names_the_fallback(monkeypatch, case):
     """Each way a family can fall back is decided from what the lowering
     observes and counted under one reason: the family's own checks in their
-    order, ``gspmd_mesh`` in a program XLA partitions by itself (the dp4
-    cell), and nothing at all during build-time shape inference."""
+    order, ``gspmd_mesh`` for a bare call in a program XLA partitions by
+    itself (since PR 39 the dp4 cell's ``fused_ln`` wraps its call and is
+    not one), and nothing at all during build-time shape inference."""
     family, checks, on_chip, expected = RULE[case]
     fluid.set_flags({"FLAGS_telemetry": True})
     if on_chip:

@@ -27,13 +27,17 @@ ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     from jax.experimental import topologies
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        return topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:
         pytest.skip("no v5e:2x2 topology can be described here: %s" % e)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
     return SingleDeviceSharding(topo.devices[0])
 
 
@@ -434,3 +438,78 @@ def test_exaone_moe_step_compiles_both_attention_kinds_and_its_experts(
     found = [line.strip()[:160] for line in text.splitlines()
              if spread.search(line)]
     assert not found, found
+
+
+def test_data_parallel_bert_layer_runs_fused_ln_per_shard_on_v5e_2x2(
+        topo, as_on_tpu):
+    """BERT-base's widths (hidden 768, bf16 AMP, dropout 0.1), one layer,
+    batch 8 x seq 128 a chip, through ``with_data_parallel``'s route (jit +
+    NamedSharding over a ("data",) mesh of the described 2x2): Mosaic
+    accepts the fused epilogue inside the program XLA partitions (PR 24:
+    "Mosaic kernels cannot be automatically partitioned"; since PR 39 the
+    op and its grad op wrap their call in a shard_map), each shard's
+    ``[1024, 768]`` rows stay where they lie (nothing of that size is
+    gathered or permuted around the kernels), and the funnel counts the
+    kernel, not ``gspmd_mesh``."""
+    import bench
+    import paddle_tpu as fluid
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from paddle_tpu.core import telemetry
+    from paddle_tpu.framework import dtype_to_np
+    from paddle_tpu.models import bert
+
+    chips, per, seq = 4, 8, 128
+    mesh = Mesh(np.array(topo.devices), ("data",))
+    cfg = bert.BertConfig(layers=1, dropout=0.1)
+    main, _startup, loss = bench.build_bert_pretrain(cfg, seq, amp=True)
+    feed = bench._bert_feed(np.random.RandomState(0), cfg, chips * per, seq)
+    old = fluid.get_flags(["FLAGS_telemetry"])
+    fluid.set_flags({"FLAGS_telemetry": True})
+    telemetry.reset()
+    try:
+        build = fluid.Executor(fluid.CPUPlace())._build(
+            main, sorted(feed), [loss.name], mesh, "data")
+        block = main.global_block()
+
+        def persistable(name):
+            v = block._find_var_recursive(name)
+            return jax.ShapeDtypeStruct(
+                tuple(v.shape), dtype_to_np(v.dtype),
+                sharding=build.param_shardings[name])
+
+        def fed(a):      # Executor._shard_feeds: split what divides
+            spec = P("data") if a.shape[0] % chips == 0 else P()
+            return jax.ShapeDtypeStruct(a.shape, a.dtype,
+                                        sharding=NamedSharding(mesh, spec))
+
+        compiled = jax.jit(
+            build.fn, donate_argnums=build.donate,
+            out_shardings=build.out_shardings).lower(
+                {n: fed(a) for n, a in feed.items()},
+                {n: persistable(n) for n in build.plan.ro_names},
+                {n: persistable(n) for n in build.plan.rw_names}, {},
+                jax.ShapeDtypeStruct((2,), np.uint32,
+                                     sharding=NamedSharding(mesh, P()))
+            ).compile()
+        used = telemetry.counter_total("pallas_kernel_used_total")
+        fell = [ls for _flat, ls in
+                telemetry.label_sets("pallas_kernel_fallback_total")]
+    finally:
+        telemetry.reset()
+        fluid.set_flags(old)
+
+    text = compiled.as_text()
+    # two epilogues a layer, forward and backward
+    assert _kernel_calls(text) == 4
+    assert (used, fell) == (4, [])
+    rows = per * seq * 768
+    moved = re.compile(r" (all-gather|collective-permute|all-to-all)"
+                       r"(-start)?\(")
+    shape = re.compile(r"(?:bf16|f32)\[([0-9,]+)\]")
+    found = [line.strip()[:160] for line in text.splitlines()
+             if moved.search(line) and any(
+                 int(np.prod([int(d) for d in dims.split(",")])) >= rows
+                 for dims in shape.findall(line))]
+    assert not found, found
+    # the gradients still meet in all-reduces, the small ones among them
+    assert re.search(r" all-reduce(-start)?\(", text)
