@@ -36,7 +36,8 @@ The layer, for hidden ``x`` of one token (``rm`` the residual multiplier)::
 with ``x0 = embedding_multiplier * embed[tok]`` and ``logits = rmsnorm(x,
 lnf_g) @ embed^T / logits_scaling`` (a tied head).  ``I = ssm_heads *
 ssm_head_dim``, ``N = ssm_state``, ``SH = ssm_heads``, one group of B and C
-for all heads.
+for all heads (``mamba_mixer`` takes ``cfg.ssm_groups`` of them: the
+``nemotron_h`` block's mixer is this one with eight).
 
 Precision: matmul inputs are cast to the weights' dtype (bfloat16 as
 served, float32 in the CPU parity tests) and accumulate in float32; norms,
@@ -59,7 +60,8 @@ import numpy as np
 
 from .olmoe import NP_DTYPES, _mm, _rmsnorm
 
-__all__ = ["token_logits", "param_shapes", "init_params"]
+__all__ = ["token_logits", "param_shapes", "init_params", "mamba_mixer",
+           "mamba_param_shapes", "draw"]
 
 
 def param_shapes(cfg):
@@ -67,18 +69,11 @@ def param_shapes(cfg):
     dt_bias."""
     h, f, v = cfg.hidden, cfg.ffn, cfg.vocab
     kv = cfg.kv_heads * cfg.head_dim
-    inner, n, sh = cfg.ssm_inner, cfg.ssm_state, cfg.ssm_heads
-    conv_dim = inner + 2 * n
     shapes = {"embed": ((v, h), "normal"), "lnf_g": ((h,), "ones")}
     kinds = {
         "attention": (("wq", (h, h), "normal"), ("wk", (h, kv), "normal"),
                       ("wv", (h, kv), "normal"), ("wo", (h, h), "normal")),
-        "mamba": (("in_proj", (h, 2 * inner + 2 * n + sh), "normal"),
-                  ("conv_w", (cfg.ssm_conv, conv_dim), "conv"),
-                  ("conv_b", (conv_dim,), "conv"),
-                  ("dt_bias", (sh,), "dt_bias"), ("A_log", (sh,), "a_log"),
-                  ("D", (sh,), "ones"), ("ssm_norm", (inner,), "ones"),
-                  ("out_proj", (inner, h), "normal")),
+        "mamba": mamba_param_shapes(cfg),
     }
     for l, kind in enumerate(cfg.layer_types):
         for name, shape, init in (
@@ -100,40 +95,33 @@ def init_params(cfg, seed=0, std=0.02):
     0.1].  Host-side: tests and demo bundles."""
     r = np.random.RandomState(seed)
     dtype = NP_DTYPES[cfg.dtype]
-
-    def make(shape, kind):
-        if kind == "ones":
-            return np.ones(shape, np.float32)
-        if kind == "conv":
-            bound = cfg.ssm_conv ** -0.5
-            return r.uniform(-bound, bound, shape)
-        if kind == "a_log":
-            return np.log(r.uniform(1.0, 16.0, shape))
-        if kind == "dt_bias":
-            dt = np.exp(r.uniform(np.log(1e-3), np.log(1e-1), shape))
-            return dt + np.log(-np.expm1(-dt))
-        return r.standard_normal(shape) * std
-
-    return {name: make(shape, kind).astype(np.float32).astype(dtype)
+    return {name: draw(r, cfg, shape, kind, std).astype(np.float32)
+            .astype(dtype)
             for name, (shape, kind) in sorted(param_shapes(cfg).items())}
 
 
-def _mamba(cfg, p, l, h, recur):
-    """The state-space mixer of layer ``l`` over h [B, H] float32."""
+def mamba_mixer(cfg, p, l, h, recur):
+    """The Mamba-2 mixer of layer ``l`` over h [B, H] float32, B and C in
+    ``cfg.ssm_groups`` groups of heads (one here; ``models/nemotron_h.py``
+    calls it with eight): the convolution is ``I + 2 G N`` wide, head ``h``
+    reads group ``h // (heads / G)``, and the gated norm is over each
+    group's ``I / G`` values."""
     f32 = jnp.float32
-    inner, n, sh = cfg.ssm_inner, cfg.ssm_state, cfg.ssm_heads
+    inner, n, groups = cfg.ssm_inner, cfg.ssm_state, cfg.ssm_groups
+    bc = groups * n
     with jax.named_scope("in_proj"):
         zxbcdt = _mm(h, p("in_proj"))
         z = zxbcdt[:, :inner]
-        xbc = zxbcdt[:, inner:2 * inner + 2 * n]
-        dt = zxbcdt[:, 2 * inner + 2 * n:]
+        xbc = zxbcdt[:, inner:2 * inner + 2 * bc]
+        dt = zxbcdt[:, 2 * inner + 2 * bc:]
     with jax.named_scope("conv"):
-        window = recur.window(l, xbc)               # [B, K, I + 2 N]
+        window = recur.window(l, xbc)               # [B, K, I + 2 G N]
         xbc = jax.nn.silu(
             p("conv_b").astype(f32)
             + jnp.sum(p("conv_w").astype(f32)[None] * window, axis=1))
-        xs, b, c = xbc[:, :inner], xbc[:, inner:inner + n], \
-            xbc[:, inner + n:]
+        xs = xbc[:, :inner]
+        b, c = (xbc[:, at:at + bc].reshape(-1, groups, n)
+                for at in (inner, inner + bc))
     with jax.named_scope("state_update"):
         dt = jax.nn.softplus(dt + p("dt_bias").astype(f32))     # [B, SH]
         decay = jnp.exp(dt * -jnp.exp(p("A_log").astype(f32)))
@@ -141,8 +129,37 @@ def _mamba(cfg, p, l, h, recur):
         y = recur.advance(l, per_head(decay), per_head(dt) * xs, b, c)
         y = y + per_head(p("D").astype(f32)) * xs
     with jax.named_scope("out_proj"):
-        y = _rmsnorm(y * jax.nn.silu(z), p("ssm_norm"), cfg.norm_eps)
+        by_group = lambda a: a.reshape(a.shape[:-1] + (groups, -1))
+        y = _rmsnorm(by_group(y * jax.nn.silu(z)), by_group(p("ssm_norm")),
+                     cfg.norm_eps).reshape(y.shape)
         return _mm(y, p("out_proj"))
+
+
+def mamba_param_shapes(cfg):
+    """(name, shape, kind) of a mamba layer's mixer."""
+    h, inner, sh = cfg.hidden, cfg.ssm_inner, cfg.ssm_heads
+    conv_dim = inner + 2 * cfg.ssm_groups * cfg.ssm_state
+    return (("in_proj", (h, inner + conv_dim + sh), "normal"),
+            ("conv_w", (cfg.ssm_conv, conv_dim), "conv"),
+            ("conv_b", (conv_dim,), "conv"),
+            ("dt_bias", (sh,), "dt_bias"), ("A_log", (sh,), "a_log"),
+            ("D", (sh,), "ones"), ("ssm_norm", (inner,), "ones"),
+            ("out_proj", (inner, h), "normal"))
+
+
+def draw(r, cfg, shape, kind, std):
+    """One seeded array of ``init_params``, by its kind (float64)."""
+    if kind == "ones":
+        return np.ones(shape, np.float32)
+    if kind == "conv":
+        bound = cfg.ssm_conv ** -0.5
+        return r.uniform(-bound, bound, shape)
+    if kind == "a_log":
+        return np.log(r.uniform(1.0, 16.0, shape))
+    if kind == "dt_bias":
+        dt = np.exp(r.uniform(np.log(1e-3), np.log(1e-1), shape))
+        return dt + np.log(-np.expm1(-dt))
+    return r.standard_normal(shape) * std
 
 
 def token_logits(params, cfg, tok, pos, attend, live, recur):
@@ -172,7 +189,7 @@ def token_logits(params, cfg, tok, pos, attend, live, recur):
                     x = x + rm * _mm(a, p("wo"))
             else:
                 with jax.named_scope("ssm"):
-                    x = x + rm * _mamba(cfg, p, l, h, recur)
+                    x = x + rm * mamba_mixer(cfg, p, l, h, recur)
             with jax.named_scope("mlp"):
                 ab = _mm(_rmsnorm(x, p("ln2_g"), eps), p("w_in"))
                 x = x + rm * _mm(jax.nn.silu(ab[:, :cfg.ffn])

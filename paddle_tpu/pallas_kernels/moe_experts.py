@@ -33,6 +33,18 @@ chosen expert) pair is computed.
   over a mesh, shapes the kernel does not take): every expert over every
   lane, the unchosen weighted zero, which streams all ``E`` experts whatever
   was hit.  ``experts_reference`` is that path, unchanged.
+
+**The two-matrix form.**  An expert with no gate, ``relu(x @ up)^2 @ down``
+(Nemotron-H's), is held as two tensors of ONE shape, ``up`` and ``down``
+``[E, F, H]``: ``up`` in its ``nn.Linear`` orientation, so both have ``H``,
+a multiple of 128, as the minor dimension and ``F`` is cut on the
+second-minor one, in chunks of whole sublane tiles.  That admits widths no
+multiple of 128 (1856 = 4 x 464) without padding them: a padded ``F`` would
+stream bytes the model does not have.  ``relu2_experts`` is
+``routed_experts`` for it, with the same ``hit_order`` steering, the same
+rule (``adoption.decide("moe_experts", ...)``, counted under the same name)
+and the same fallback (``relu2_reference``); the square is taken per chunk,
+which is exact because a chunk holds whole columns of ``x @ up^T``.
 """
 
 import jax
@@ -44,10 +56,13 @@ from jax.experimental.pallas import tpu as pltpu
 from . import adoption
 
 __all__ = ["routed_experts", "experts_reference", "moe_experts_checks",
-           "experts_path", "hit_order", "f_chunk", "KERNEL_NAME"]
+           "experts_path", "hit_order", "f_chunk", "KERNEL_NAME",
+           "relu2_experts", "relu2_reference", "relu2_checks", "f_rows",
+           "RELU2_KERNEL_NAME"]
 
-# the name the kernel's executions carry in a device trace
+# the names the kernels' executions carry in a device trace
 KERNEL_NAME = "moe_routed_experts"
+RELU2_KERNEL_NAME = "moe_relu2_experts"
 
 _SUBLANES = {"float32": 8, "bfloat16": 16}   # rows of a dtype's memory tile
 
@@ -135,12 +150,14 @@ def moe_experts_checks(rows, w_shape, w_dtype):
     ]
 
 
-def experts_path(rows, w_shape, w_dtype):
+def experts_path(rows, w_shape, w_dtype, matrices=3):
     """``"pallas"`` where the kernel would serve these shapes on this
-    backend, else ``"einsum"``: the same rule as ``routed_experts``,
-    counted nowhere.  The engine names the step's path by it, in the
-    executable's cache key and on the ``serving_prewarm`` event."""
-    ok = all(ok for _reason, ok in moe_experts_checks(rows, w_shape, w_dtype))
+    backend, else ``"einsum"``: the same rule as ``routed_experts`` (or,
+    ``matrices`` 2, as ``relu2_experts`` for ``up`` ``[E, F, H]``), counted
+    nowhere.  The engine names the step's path by it, in the executable's
+    cache key and on the ``serving_prewarm`` event."""
+    checks = moe_experts_checks if matrices == 3 else relu2_checks
+    ok = all(ok for _reason, ok in checks(rows, w_shape, w_dtype))
     return "pallas" if ok else "einsum"
 
 
@@ -230,3 +247,139 @@ def routed_experts(h2, gates, live, wgate, wup, wdown):
     if use:
         return _experts_pallas(h2, gates, live, wgate, wup, wdown)
     return experts_reference(h2, gates, wgate, wup, wdown)
+
+
+# -- the two-matrix form: relu(x @ up^T)^2 @ down ----------------------------
+
+def relu2_reference(h2, gates, up, down):
+    """sum_e gates[b, e] * (relu(h2[b] @ up_e^T)^2 @ down_e), ``up`` and
+    ``down`` [E, F, H]: all experts over all lanes, the unchosen weighted
+    zero.  Rounded as the kernel rounds: inputs in the weights' dtype,
+    float32 sums, the squared activation in the weights' dtype."""
+    hx = h2.astype(up.dtype)
+    h = jnp.einsum("bh,efh->ebf", hx, up, preferred_element_type=jnp.float32)
+    # relu(h)^2 as h * relu(h): XLA:CPU (jaxlib 0.9.0) fuses a bare
+    # max(dot, 0) into the batched dot's epilogue and then has no bfloat16
+    # thunk for it ("Unsupported element type for DotThunk")
+    act = h * jax.nn.relu(h)
+    y = jnp.einsum("ebf,efh->ebh", act.astype(down.dtype), down,
+                   preferred_element_type=jnp.float32)
+    return jnp.sum(y * gates.T[:, :, None], axis=0)
+
+
+def f_rows(hidden, ffn, dtype):
+    """Rows of ``up`` and of ``down`` a grid step reads: the largest divisor
+    of ``ffn`` that is whole sublane tiles of ``dtype`` and whose two
+    blocks, double buffered, fit ``_BLOCK_BUDGET``; 0 where none does."""
+    tile = _SUBLANES[jnp.dtype(dtype).name]
+    itemsize = jnp.dtype(dtype).itemsize
+    for n in range(1, ffn // tile + 1):
+        fr = ffn // n
+        if ffn % n == 0 and fr % tile == 0 \
+                and 4 * hidden * fr * itemsize <= _BLOCK_BUDGET:
+            return fr
+    return 0
+
+
+def relu2_checks(rows, w_shape, w_dtype):
+    """Ordered (reason, ok) pairs for adoption.decide(): what the two-matrix
+    kernel needs of ``rows`` lanes and of ``up`` ``[E, F, H]`` in
+    ``w_dtype`` (``down`` has the same shape)."""
+    dims = tuple(w_shape) + (rows,)
+    static = all(isinstance(x, int) and x >= 0 for x in dims)
+    rank = len(w_shape) == 3
+    tile = _SUBLANES.get(jnp.dtype(w_dtype).name)
+    shaped = static and rank and tile is not None
+    fr = f_rows(w_shape[2], w_shape[1], w_dtype) \
+        if shaped and min(w_shape) > 0 else 0
+    return [
+        ("backend", adoption.interpret_mode()
+         or jax.default_backend() == "tpu"),
+        ("symbolic_shape", static),
+        ("rank", rank),
+        ("dtype", tile is not None),
+        # H fills whole lanes; F whole sublane tiles (its chunks then do)
+        ("lanes", shaped and w_shape[2] % 128 == 0
+         and w_shape[1] % tile == 0),
+        ("empty", static and all(x > 0 for x in dims)),
+        ("vmem", fr > 0 and _vmem_bytes(
+            -(-rows // tile) * tile, w_shape[2], fr,
+            jnp.dtype(w_dtype).itemsize) <= _VMEM_LIMIT),
+    ]
+
+
+def _relu2_kernel(order_ref, n_ref, x_ref, gates_ref, up_ref, down_ref,
+                  out_ref):
+    i, j = pl.program_id(0), pl.program_id(1)
+
+    @pl.when((i == 0) & (j == 0))
+    def _start():
+        out_ref[...] = jnp.zeros_like(out_ref)
+
+    @pl.when(i < n_ref[0])
+    def _expert():
+        # [rows, H] x [fr, H]^T: the contraction over H is whole within the
+        # chunk, so the activation is this chunk's columns of the expert's
+        h = jax.lax.dot_general(
+            x_ref[...], up_ref[...], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)              # [rows, fr]
+        act = jnp.square(jnp.maximum(h, 0.0))
+        y = jnp.dot(act.astype(down_ref.dtype), down_ref[...],
+                    preferred_element_type=jnp.float32)      # [rows, H]
+        col = jax.lax.broadcasted_iota(jnp.int32, gates_ref.shape, 1)
+        gate = jnp.sum(jnp.where(col == order_ref[i], gates_ref[...], 0.0),
+                       axis=1, keepdims=True)
+        out_ref[...] += gate * y
+
+
+def _relu2_pallas(h2, gates, live, up, down, fr=None, interpret=None):
+    """``relu2_experts`` on the kernel: an idle lane's gates count as
+    zeros.  ``fr`` None follows ``f_rows``; ``interpret`` None follows the
+    backend."""
+    b, hidden = h2.shape
+    e, ffn, _h = up.shape
+    if fr is None:
+        fr = f_rows(hidden, ffn, up.dtype)
+    if interpret is None:
+        interpret = adoption.interpret()
+    nj = ffn // fr
+    tile = _SUBLANES[up.dtype.name]
+    pad = (0, -b % tile), (0, 0)
+    x = jnp.pad(h2.astype(up.dtype), pad)
+    gates = jnp.pad(jnp.where(live[:, None], gates.astype(jnp.float32), 0.0),
+                    pad)
+    order, n_hit = hit_order(gates)
+    rows = x.shape[0]
+    whole = lambda i, j, order, n: (0, 0)
+    # past the hit experts every step names the last block fetched
+    chunk = pl.BlockSpec((None, fr, hidden), lambda i, j, order, n:
+                         (order[i], jnp.where(i < n[0], j, nj - 1), 0))
+    out = pl.pallas_call(
+        _relu2_kernel,
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(e, nj),
+            in_specs=[pl.BlockSpec((rows, hidden), whole),
+                      pl.BlockSpec((rows, e), whole), chunk, chunk],
+            out_specs=pl.BlockSpec((rows, hidden), whole),
+        ),
+        out_shape=jax.ShapeDtypeStruct((rows, hidden), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
+        name=RELU2_KERNEL_NAME,
+        interpret=interpret,
+    )(order, n_hit, x, gates, up, down)
+    return out[:b]
+
+
+def relu2_experts(h2, gates, live, up, down):
+    """``routed_experts`` for two-matrix experts, ``up`` and ``down`` [E, F,
+    H]: the kernel where ``relu2_checks`` admits the shapes (counted as
+    ``moe_experts``, like the three-matrix form), reading the experts some
+    live lane chose; ``relu2_reference`` otherwise."""
+    use, _reason = adoption.decide(
+        "moe_experts", relu2_checks(h2.shape[0], up.shape, up.dtype))
+    if use:
+        return _relu2_pallas(h2, gates, live, up, down)
+    return relu2_reference(h2, gates, up, down)

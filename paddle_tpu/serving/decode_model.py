@@ -15,21 +15,25 @@ that reads each lane's live KV blocks from the pool where they lie, and
 elsewhere (the CPU tier, the int8 residency) a gather of the padded table
 into ``masked_attention``, chosen by what the shapes and the backend are.
 
-The decoder is one of five blocks, picked by ``DecoderConfig.arch``: the
+The decoder is one of six blocks, picked by ``DecoderConfig.arch``: the
 ``gpt2`` block of this file, in float32; the routed-expert ``olmoe`` block
 of ``models/olmoe.py``, in bfloat16 with a bfloat16 cache; the
 ``granite_hybrid`` block of ``models/granite_hybrid.py``; the ``lfm2_moe``
-block of ``models/lfm2_moe.py``; and the ``exaone_moe`` block of
-``models/exaone_moe.py``.  Layers are of four kinds (``LAYER_KINDS``):
+block of ``models/lfm2_moe.py``; the ``exaone_moe`` block of
+``models/exaone_moe.py``; and the ``nemotron_h`` block of
+``models/nemotron_h.py``.  Layers are of five kinds (``LAYER_KINDS``):
 ``attention`` (multi-head, or grouped-query with fewer KV heads than query
 heads, so pools ``kv_heads * head_dim`` wide), which keeps K and V a token;
 ``window``, attention over the last ``cfg.window`` positions only, which
 keeps K and V in a ring of blocks of its own pools and gives back what
 leaves the window; ``mamba``, a Mamba-2 state-space mixer, which keeps a
-convolution window and a recurrent state a sequence; and ``conv``, a gated
-short convolution, which keeps a window and no state.  The hybrid blocks
-mix attention with one other kind (``ARCH_LAYER_KINDS``).  Every step
-builder below serves all five through one contract (``_block``), so there
+convolution window and a recurrent state a sequence; ``conv``, a gated
+short convolution, which keeps a window and no state; and ``experts``, a
+layer that is a feed-forward alone (routed experts beside a shared one),
+which keeps nothing: the cache manager gives it neither pool nor slot.  A
+hybrid block names its layers' kinds one by one, two or three of them in
+one model (``ARCH_LAYER_KINDS``).  Every step
+builder below serves all six through one contract (``_block``), so there
 is one paged step, one multi-token step, one draft rollout and one unpaged
 reference, whatever the block.
 
@@ -80,6 +84,7 @@ from . import kv_cache as _kv
 __all__ = ["DecoderConfig", "init_decoder_params", "save_decoder",
            "load_decoder", "is_decoder_dir", "has_draft", "load_draft",
            "truncate_decoder", "attention_path", "experts_path",
+           "state_update_path",
            "make_paged_step",
            "make_fed_step", "make_paged_step_multi",
            "make_draft_rollout", "make_unpaged_step", "unpaged_generate",
@@ -91,18 +96,24 @@ __all__ = ["DecoderConfig", "init_decoder_params", "save_decoder",
 ARCH_LAYER_KINDS = {"gpt2": ("attention",), "olmoe": ("attention",),
                     "granite_hybrid": ("attention", "mamba"),
                     "lfm2_moe": ("attention", "conv"),
-                    "exaone_moe": ("attention", "window")}
+                    "exaone_moe": ("attention", "window"),
+                    "nemotron_h": ("attention", "mamba", "experts")}
 ARCHS = tuple(ARCH_LAYER_KINDS)
-LAYER_KINDS = ("attention", "mamba", "conv", "window")
+LAYER_KINDS = ("attention", "mamba", "conv", "window", "experts")
 # recurrent kind -> the name its slot goes by in spans, gauges and counters
 # (``ssm_state_lanes``, ``conv_state_bytes{model}``, ...)
 STATE_NAMES = {"mamba": "ssm_state", "conv": "conv_state"}
 # the blocks whose attention may have fewer KV heads than query heads, and
 # those whose feed-forward is routed experts
-_GROUPED_QUERY = ("granite_hybrid", "lfm2_moe", "exaone_moe")
-_ROUTED = ("olmoe", "lfm2_moe", "exaone_moe")
+_GROUPED_QUERY = ("granite_hybrid", "lfm2_moe", "exaone_moe", "nemotron_h")
+_ROUTED = ("olmoe", "lfm2_moe", "exaone_moe", "nemotron_h")
 # the blocks whose first ``dense_layers`` layers end in a gated MLP
 _DENSE_LEAD = ("lfm2_moe", "exaone_moe")
+# the blocks that may hold a share of each routed layer's experts (their
+# router scores every expert, the weights are the held ones'), and those
+# whose stream may be narrower than their query heads together
+_HOLDS_SHARE = ("exaone_moe", "nemotron_h")
+_OWN_STREAM_WIDTH = ("exaone_moe", "nemotron_h")
 
 
 class DecoderConfig:
@@ -138,6 +149,15 @@ class DecoderConfig:
     ``experts_held`` experts from ``expert_first`` on (0: all of them), and
     what the absent ones would add is left out.  Its stream may be
     narrower than its query heads together (``hidden_size``).
+    ``nemotron_h`` is the block of ``models/nemotron_h.py``: every layer ONE
+    pre-norm sublayer, of a kind named in ``layer_types`` (``mamba`` |
+    ``attention`` | ``experts``): Mamba-2 mixers as Granite's with B and C
+    in ``ssm_groups`` groups of heads, grouped-query attention with no
+    position encoding, and experts of two matrices (``relu(x @ up)^2 @
+    down``, width ``ffn``) routed as ``exaone_moe``'s beside a shared one of
+    width ``shared_ffn``, with an untied head.  It too may hold a share and
+    have a stream of its own width; its routed layers are its ``experts``
+    layers, wherever they lie.
 
     ``kv_heads`` None means ``heads`` (multi-head); ``layer_types`` None
     means ``layers`` attention layers.  ``dtype`` is the weights' (``f32``
@@ -153,7 +173,7 @@ class DecoderConfig:
                  "residual_multiplier", "attention_multiplier",
                  "logits_scaling", "conv_taps", "dense_layers", "dense_ffn",
                  "routed_scaling", "window", "experts_held", "expert_first",
-                 "shared_ffn", "hidden_size")
+                 "shared_ffn", "hidden_size", "ssm_groups")
 
     def __init__(self, vocab, layers, heads, head_dim, ffn=None,
                  max_seq=64, arch="gpt2", dtype="f32", kv_dtype=None,
@@ -164,7 +184,8 @@ class DecoderConfig:
                  attention_multiplier=None, logits_scaling=1.0,
                  conv_taps=0, dense_layers=0, dense_ffn=0,
                  routed_scaling=1.0, window=0, experts_held=0,
-                 expert_first=0, shared_ffn=0, hidden_size=None):
+                 expert_first=0, shared_ffn=0, hidden_size=None,
+                 ssm_groups=1):
         if arch not in ARCHS:
             raise ValueError("decoder arch must be %s: %r"
                              % ("|".join(ARCHS), arch))
@@ -199,6 +220,7 @@ class DecoderConfig:
         self.ssm_head_dim = int(ssm_head_dim)
         self.ssm_state = int(ssm_state)
         self.ssm_conv = int(ssm_conv)
+        self.ssm_groups = int(ssm_groups)
         self.embedding_multiplier = float(embedding_multiplier)
         self.residual_multiplier = float(residual_multiplier)
         self.attention_multiplier = None if attention_multiplier is None \
@@ -214,9 +236,10 @@ class DecoderConfig:
         self.shared_ffn = int(shared_ffn)
         self.hidden_size = None if hidden_size is None else int(hidden_size)
         if self.hidden_size not in (None, self.heads * self.head_dim) \
-                and arch != "exaone_moe":
+                and arch not in _OWN_STREAM_WIDTH:
             raise ValueError("the %s block's stream is heads * head_dim "
-                             "wide" % arch)
+                             "wide (%s may say otherwise)"
+                             % (arch, "|".join(_OWN_STREAM_WIDTH)))
         if len(self.layer_types) != self.layers or any(
                 k not in LAYER_KINDS for k in self.layer_types):
             raise ValueError("layer_types must name each of the %d layers "
@@ -236,6 +259,10 @@ class DecoderConfig:
                                    self.ssm_state, self.ssm_conv - 1) < 1:
             raise ValueError("mamba layers want ssm_heads, ssm_head_dim, "
                              "ssm_state >= 1 and ssm_conv >= 2")
+        if self.ssm_groups < 1 or (self.ssm_layers
+                                   and self.ssm_heads % self.ssm_groups):
+            raise ValueError("ssm_groups %d must divide ssm_heads %d"
+                             % (self.ssm_groups, self.ssm_heads))
         if self.conv_layers and self.conv_taps < 2:
             raise ValueError("conv layers want conv_taps >= 2")
         if not 0 <= self.dense_layers <= self.layers or (
@@ -251,17 +278,18 @@ class DecoderConfig:
         if not 0 <= self.expert_first \
                 <= self.experts - self.experts_held or (
                     self.experts_held != self.experts
-                    and arch != "exaone_moe"):
+                    and arch not in _HOLDS_SHARE):
             raise ValueError(
-                "the exaone_moe block may hold experts [expert_first, "
+                "the %s blocks may hold experts [expert_first, "
                 "expert_first + experts_held) of %d: %r from %r"
-                % (self.experts, experts_held, expert_first))
+                % ("|".join(_HOLDS_SHARE), self.experts, experts_held,
+                   expert_first))
 
     @property
     def hidden(self):
         """The residual stream's width: ``heads * head_dim`` unless the
-        model says otherwise (``hidden_size``: the exaone_moe block projects
-        a narrower stream up to its query heads)."""
+        model says otherwise (``hidden_size``: the exaone_moe and nemotron_h
+        blocks project a narrower stream up to their query heads)."""
         return self.hidden_size or self.heads * self.head_dim
 
     def _of_kind(self, kind):
@@ -307,9 +335,13 @@ class DecoderConfig:
     @property
     def routed_layers(self):
         """Indices of the layers whose feed-forward is routed experts, in
-        order: the rows of the step's ``routed`` counts."""
+        order: the rows of the step's ``routed`` counts.  A block that names
+        ``experts`` layers routes in those; any other routed block in every
+        layer after its ``dense_layers``."""
         if self.arch not in _ROUTED:
             return ()
+        if "experts" in ARCH_LAYER_KINDS[self.arch]:
+            return self._of_kind("experts")
         return tuple(range(self.dense_layers, self.layers))
 
     @property
@@ -339,7 +371,8 @@ def cache_config(cfg, block_size, num_blocks, dtype=None, state_slots=0):
     ``state_slots`` slots (slot 0 the idle lanes' scratch), and for its
     window layers as many rings (a sequence holds a ring as it holds a
     slot: one a lane and the scratch), each ``cfg.window`` positions
-    long."""
+    long.  A layer of a kind that keeps nothing (``experts``) is counted
+    nowhere."""
     return _kv.KVCacheConfig(
         len(cfg.attn_layers), cfg.kv_heads, cfg.head_dim, block_size,
         num_blocks, dtype or cfg.kv_dtype or "f32",
@@ -354,7 +387,8 @@ def _conv_window(cfg):
     """``(taps, width)`` of the causal convolution of the model's recurrent
     layers: a slot keeps its ``taps - 1`` newest inputs."""
     if cfg.ssm_layers:
-        return cfg.ssm_conv, cfg.ssm_inner + 2 * cfg.ssm_state
+        return cfg.ssm_conv, \
+            cfg.ssm_inner + 2 * cfg.ssm_groups * cfg.ssm_state
     return cfg.conv_taps, cfg.hidden
 
 
@@ -501,9 +535,10 @@ def _block(cfg):
     marks the lanes that hold a sequence; ``extras`` is a tuple of small
     arrays the step returns after its logits (a routed block's tokens sent
     to each expert, a row a layer of ``cfg.routed_layers``; nothing for the
-    others).  The five blocks: ``_token_logits`` here (gpt2), and
+    others).  The six blocks: ``_token_logits`` here (gpt2), and
     ``token_logits`` of ``models/olmoe.py``, ``models/granite_hybrid.py``,
-    ``models/lfm2_moe.py`` and ``models/exaone_moe.py``."""
+    ``models/lfm2_moe.py``, ``models/exaone_moe.py`` and
+    ``models/nemotron_h.py``."""
     return _token_logits if cfg.arch == "gpt2" else _model(cfg).token_logits
 
 
@@ -575,8 +610,26 @@ def experts_path(cfg, params, lanes=1):
     streamed; None for a model with no routed layer."""
     if not cfg.routed_layers:
         return None
-    wgate = params["l%d_wgate" % cfg.routed_layers[0]]
-    return _moe.experts_path(lanes, wgate.shape, wgate.dtype)
+    first = "l%d_" % cfg.routed_layers[0]
+    if first + "wgate" in params:
+        wgate = params[first + "wgate"]
+        return _moe.experts_path(lanes, wgate.shape, wgate.dtype)
+    # the two-matrix form (``up`` and ``down`` both [E, F, H])
+    up = params[first + "experts_up"]
+    return _moe.experts_path(lanes, up.shape, up.dtype, matrices=2)
+
+
+def state_update_path(cfg, kv_config, lanes=1):
+    """``"pallas"`` where a state-space layer's state is moved by the kernel
+    that updates each lane's slot in place, for this model's pool on this
+    backend at a bucket of ``lanes``; ``"gather"`` where the slots are
+    gathered, moved and scattered back; None for a model with no such
+    layer."""
+    if not cfg.ssm_layers:
+        return None
+    shape, dtype = kv_config.state_shapes[1]
+    return _ssm.update_path((kv_config.state_slots,) + shape,
+                            _kv._PAYLOAD[dtype][0], lanes, cfg.ssm_groups)
 
 
 def _pool_index(cfg):
@@ -617,7 +670,8 @@ class _Recurrent:
     def advance(self, l, decay, dx, b, c):
         """``S = decay * S + outer(b, dx)`` -> ``c . S`` [B, I]: the state
         [N, I] of each lane one token on, ``decay`` and ``dx`` [B, I] (a
-        head's decay repeated over its values), ``b`` and ``c`` [B, N]."""
+        head's decay repeated over its values), ``b`` and ``c`` [B, G, N]
+        (a pair a group of heads)."""
         return self._advance(self._at[l], self._fresh, decay, dx, b, c)
 
 

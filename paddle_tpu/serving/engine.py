@@ -908,7 +908,7 @@ class _DecodeSeq:
 class _DecodeModel:
     __slots__ = ("name", "cfg", "params", "kv_config", "cache", "stepfn",
                  "maxb", "attn_path", "window_path", "experts_path",
-                 "blocks_read", "window_read",
+                 "state_path", "blocks_read", "window_read",
                  "step_ms", "prefix",
                  "declines", "slot_bytes", "state_name", "feed0",
                  "__weakref__",
@@ -941,6 +941,10 @@ class _DecodeModel:
         # lanes ("pallas": the experts hit alone | "einsum": all of them);
         # empty for a model with no routed layer (add_model sets it)
         self.experts_path = {}
+        # bucket -> how a state-space layer's state is moved at that many
+        # lanes ("pallas": each slot in place | "gather"); empty for a
+        # model with no such layer (add_model sets it)
+        self.state_path = {}
         self.step_ms = 0.0          # EWMA of one decode step
         self.prefix = None          # PrefixCache (FLAGS_prefix_cache)
         # why this model declines what starts or moves a sequence at
@@ -1232,6 +1236,8 @@ class DecodeEngine:
         attn_path = _dm.attention_path(cfg, kv_config, max(self.buckets))
         experts_path = {b: _dm.experts_path(cfg, jparams, b)
                         for b in self.buckets if cfg.routed_layers}
+        state_path = {b: _dm.state_update_path(cfg, kv_config, b)
+                      for b in self.buckets if cfg.ssm_layers}
         # the paths the step's attention and (a routed model's) experts
         # take: an executable compiled for one is never restored for the
         # other
@@ -1242,6 +1248,8 @@ class DecodeEngine:
             paths["window_attention"] = window_path
         if experts_path:
             paths["experts"] = sorted(experts_path.items())
+        if state_path:
+            paths["state_update"] = sorted(state_path.items())
         stepfn = CarriedStepFn(
             # make_paged_step's step with the token feed on the device:
             # still one executable an engine step
@@ -1264,6 +1272,7 @@ class DecodeEngine:
                 read(lens, maxb=kv_config.window_ring, ring=True),
                 read(lens, maxb=entry.maxb))
         entry.experts_path = experts_path
+        entry.state_path = state_path
         entry.blocks_read = functools.partial(
             _pa.blocks_read, block_size=kv_config.block_size,
             maxb=entry.maxb, path=attn_path)
@@ -1364,6 +1373,14 @@ class DecodeEngine:
             m = self._models[model]
             if m.experts_path:
                 extra["experts"] = m.experts_path[bucket]
+            if m.state_path:
+                extra["state_update"] = m.state_path[bucket]
+            if len(set(m.cfg.layer_types)) > 1:
+                # a hybrid's layers by kind, those that keep nothing in
+                # the cache among them
+                extra["layers"] = {
+                    kind: m.cfg.layer_types.count(kind)
+                    for kind in sorted(set(m.cfg.layer_types))}
             if m.window_path is not None:
                 extra["window_attention"] = m.window_path
             _tm.event("serving_prewarm", model=model, bucket=bucket,
@@ -2659,7 +2676,8 @@ class DecodeEngine:
             # the table has: the live context's share where the kernel
             # reads in place, all of them where the table is gathered
             read = {"kv_blocks_read": m.blocks_read(lens),
-                    "kv_table_slots": bucket * m.maxb} \
+                    "kv_table_slots": bucket * m.maxb,
+                    "kv_block_size": m.kv_config.block_size} \
                 if _tr.enabled() else {}
             if slots is not None:
                 # lanes at position 0 start their slot from zeros

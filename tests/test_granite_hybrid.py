@@ -714,7 +714,8 @@ def test_state_update_kernel_equals_the_gather(interpreted, monkeypatch,
     slots = jnp.asarray([3, 5, 1, 0], jnp.int32)
     fresh = jnp.asarray([False, True, False, True])
     decay = jnp.asarray(r.uniform(0.2, 1.0, (lanes, inner)), jnp.float32)
-    args = (slots, fresh, decay, f(lanes, inner), f(lanes, n), f(lanes, n))
+    args = (slots, fresh, decay, f(lanes, inner), f(lanes, 1, n),
+            f(lanes, 1, n))
     assert all(ok for _r, ok in su.ssm_update_checks(pool.shape, pool.dtype,
                                                      lanes))
     want_pool, want_y = jax.jit(su.state_update_reference)(pool, *args)

@@ -145,6 +145,29 @@ RULE = {
         # no 128-column chunk of a 32768-wide hidden fits twice
         "moe_experts", lambda: moe_experts.moe_experts_checks(
             32, (8, 32768, 1024), "bfloat16"), True, "vmem"),
+    # the same families' second forms (PR 41): B and C in groups, and
+    # two-matrix experts ``[E, F, H]`` (Nemotron-3-Nano's 16 of 1856 x 2688)
+    "ssm_update-groups": (
+        # 64 groups of 64 columns: no whole 128-column slice
+        "ssm_update", lambda: ssm_update.ssm_update_checks(
+            (33, 128, 4096), "float32", 32, 64), True, "groups"),
+    "ssm_update-groups_uneven": (
+        "ssm_update", lambda: ssm_update.ssm_update_checks(
+            (33, 128, 4096), "float32", 32, 3), True, "groups"),
+    "moe_experts-relu2_backend": (
+        "moe_experts", lambda: moe_experts.relu2_checks(
+            32, (16, 1856, 2688), "bfloat16"), False, "backend"),
+    "moe_experts-relu2_lanes": (
+        # H is the minor dimension of both tensors: whole lanes
+        "moe_experts", lambda: moe_experts.relu2_checks(
+            32, (16, 1856, 2688 + 64), "bfloat16"), True, "lanes"),
+    "moe_experts-relu2_sublanes": (
+        # F is cut in whole sublane tiles: 1850 is none
+        "moe_experts", lambda: moe_experts.relu2_checks(
+            32, (16, 1850, 2688), "bfloat16"), True, "lanes"),
+    "moe_experts-relu2_vmem": (
+        "moe_experts", lambda: moe_experts.relu2_checks(
+            32, (8, 1024, 1 << 20), "bfloat16"), True, "vmem"),
 }
 for _family in adoption.KERNELS:
     for _kind in ("gspmd_mesh", "shape_inference"):

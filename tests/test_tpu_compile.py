@@ -440,6 +440,87 @@ def test_exaone_moe_step_compiles_both_attention_kinds_and_its_experts(
     assert not found, found
 
 
+def test_nemotron_h_step_compiles_its_three_kernels_at_published_shapes(
+        one_chip, as_on_tpu):
+    """NVIDIA-Nemotron-3-Nano-30B-A3B's published widths, its first 6 blocks
+    (``M E M E M *``: every kind), bucket 32, the cell's pools (2048 bf16
+    blocks 256 wide for 2 KV heads of 128, 33 state slots of [128, 4096]):
+    Mosaic accepts, inside the whole fed step, the state-update kernel with
+    B and C in 8 groups (a 2048-column grid step spans 4), the two-matrix
+    expert kernel over 16 held experts ``[16, 1856, 2688]`` cut on the
+    second-minor axis (1856 is no multiple of 128), and the paged-attention
+    kernel for 32 query heads over 2 (groups of 16, compact); the experts
+    are read as they lie and every pool is aliased whole."""
+    from benchmark.models import nemotron_h_decoder
+    from paddle_tpu.pallas_kernels import moe_experts as moe
+    from paddle_tpu.pallas_kernels import paged_attention as pa
+    from paddle_tpu.pallas_kernels import ssm_update as ssm
+
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "nemotron-3-nano-30b-a3b-serve.json")) as fp:
+        config = json.load(fp)
+    config = dict(config, num_hidden_layers=6,
+                  hybrid_override_pattern=config[
+                      "hybrid_override_pattern"][:6])
+    cfg = nemotron_h_decoder.decoder_config(config)
+    assert (cfg.hidden, cfg.heads, cfg.kv_heads, cfg.head_dim, cfg.ssm_heads,
+            cfg.ssm_head_dim, cfg.ssm_state, cfg.ssm_groups, cfg.experts,
+            cfg.experts_held, cfg.experts_per_token, cfg.ffn, cfg.shared_ffn,
+            cfg.layer_types, cfg.routed_layers) == (
+        2688, 32, 2, 128, 64, 64, 128, 8, 128, 16, 6, 1856, 3712,
+        ("mamba", "experts", "mamba", "experts", "mamba", "attention"),
+        (1, 3))
+    lanes, block_size, blocks = 32, 16, 2048
+    kv = dm.cache_config(cfg, block_size, blocks, state_slots=lanes + 1)
+    assert pa._compact(32, 2, 128)
+    assert dm.attention_path(cfg, kv, lanes) == "pallas"
+    assert dm.state_update_path(cfg, kv, lanes) == "pallas"
+    assert ssm._groups_tile(4096, 8) and ssm.COLUMNS // (4096 // 8) == 4
+    assert moe.experts_path(lanes, (16, 1856, 2688), jnp.bfloat16,
+                            matrices=2) == "pallas"
+    assert moe.experts_path(lanes, (16, 2688, 1856), jnp.bfloat16) == "einsum"
+    fr = moe.f_rows(2688, 1856, jnp.bfloat16)
+    assert 1856 % fr == 0 and fr % 16 == 0
+    assert 4 * 2688 * fr * 2 <= moe._BLOCK_BUDGET
+
+    on_chip = functools.partial(_placed, one_chip)
+
+    carry = on_chip(jax.eval_shape(lambda: PagedKVCache(kv).carry()))
+    params = on_chip({
+        name: jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+        for name, (shape, _kind)
+        in nemotron_h_decoder.param_shapes(config).items()})
+    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
+    feeds = on_chip([i32(lanes), i32(lanes), i32(lanes), i32(lanes),
+                     i32(lanes, cfg.max_seq // block_size), i32(lanes),
+                     i32(lanes)])
+    compiled = jax.jit(dm.make_fed_step(cfg, kv, lanes), donate_argnums=(0,)
+                       ).lower(carry, params, *feeds).compile()
+
+    text = compiled.as_text()
+    assert _kernel_calls(text) == 6     # 3 state updates, 2 experts, 1 attn
+    assert len(re.findall(r"%ssm_state_update\S* = ", text)) == 3
+    assert len(re.findall(r"%moe_relu2_experts\S* = ", text)) == 2
+    assert len(re.findall(r"%paged_attention\S* = ", text)) == 1
+    assert _expert_kernels(text) == 0   # the three-matrix form is not here
+    assert not _expert_passes(text, 16, 2688, 1856)
+    pool_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in carry)
+    state_pool = (lanes + 1) * 128 * 4096 * 4
+    assert pool_bytes == 2 * 2048 * 16 * 256 * 2 + 3 * (
+        state_pool + 33 * 3 * 6144 * 2)
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= pool_bytes
+    # b and c cross into the kernel [32, 128, 128] float32 each (2.1e6 B),
+    # not spread over the groups' columns (16.8e6 B each)
+    assert memory.temp_size_in_bytes < state_pool / 4
+    big = re.compile(
+        r" = f32\[(33|32),128,(4096|2048|1024)\]\S* "
+        r"(copy|select|transpose|slice|dynamic-slice|gather|scatter|fusion)\(")
+    found = [line.strip()[:160] for line in text.splitlines()
+             if big.search(line)]
+    assert not found, found
+
+
 def test_data_parallel_bert_layer_runs_fused_ln_per_shard_on_v5e_2x2(
         topo, as_on_tpu):
     """BERT-base's widths (hidden 768, bf16 AMP, dropout 0.1), one layer,

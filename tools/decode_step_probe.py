@@ -46,7 +46,14 @@ the expert bytes that form reads a step (``dense`` all of them, the others
 the experts this step's tokens hit, from the step's own routed counts) and
 bytes/s: the comparison the block's choice was made by, not an option of the
 program.  (XLA's expansion of ``ragged_dot`` loses the scope, so that form's
-expert time lands in ``other``: compare its ``busy_ms_per_step``.)
+expert time lands in ``other``: compare its ``busy_ms_per_step``.)  For
+``--config nemotron-3-nano-30b-a3b-serve --blocks 2048`` the step is all 52
+blocks of one sublayer each (``--layers 6`` keeps ``M E M E M *``): the
+``ssm`` scopes with B and C in 8 groups, ``attn/kv_read`` for 32 query heads
+over 2, ``moe/router``, ``moe/experts`` and ``moe/shared`` on the layers that
+are experts alone; the bytes are ``benchmark/nemotron_cost.py``'s, and
+``--experts block,dense,kernel`` compares the two-matrix forms
+(``moe_experts.relu2_experts``; ``ragged`` is the three-matrix form's).
 
     chiprun -- python tools/decode_step_probe.py --config \
         lfm2-24b-a2b-serve --blocks 2048 --experts dense,ragged,kernel
@@ -234,8 +241,8 @@ def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
     import jax
     import numpy as np
 
-    from benchmark import exaone_cost, lfm2_cost, moe_cost, ssm_cost, \
-        trace_reduce
+    from benchmark import exaone_cost, lfm2_cost, moe_cost, nemotron_cost, \
+        ssm_cost, trace_reduce
     from paddle_tpu.core import telemetry
     from paddle_tpu.core.executor import CarriedStepFn
     from paddle_tpu.serving import decode_model as dm
@@ -327,7 +334,12 @@ def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
         hit = float(np.mean([
             (np.asarray(r)[:, cfg.held_experts] > 0).sum(axis=1).mean()
             for r in routed[-args.steps:]]))
-        if "mlp_layer_types" in config:
+        held = "num_experts"
+        if "hybrid_override_pattern" in config:
+            # two-matrix experts in the layers the pattern names, a share
+            bytes_of = nemotron_cost.experts_hit_bytes_per_step
+            held = "n_routed_experts"
+        elif "mlp_layer_types" in config:
             # a share: the held experts of each sparse layer
             bytes_of = lambda _c, n: exaone_cost.sparse_layers(config) * n \
                 * exaone_cost.expert_bytes(config)
@@ -335,7 +347,7 @@ def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
             bytes_of = lfm2_cost.routed_stream_floor_bytes_per_step
         else:
             bytes_of = moe_cost.expert_stream_bytes_per_step
-        moved = bytes_of(config, config["num_experts"] if reads_all else hit)
+        moved = bytes_of(config, config[held] if reads_all else hit)
         result["moe_experts_hit_per_layer"] = hit
         result["moe_experts_ms_per_step"] = moe_ms
         result["moe_experts_bytes_per_step"] = moved
@@ -345,7 +357,9 @@ def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
     if cfg.ssm_layers and ssm_ms:
         # every lane's state in every mamba layer, read and written
         # once a step, over the scope's time
-        moved = ssm_cost.state_traffic_bytes_per_step(config, b)
+        cost = nemotron_cost if "hybrid_override_pattern" in config \
+            else ssm_cost
+        moved = cost.state_traffic_bytes_per_step(config, b)
         result["ssm_state_bytes_per_step"] = moved
         result["ssm_state_update_bytes_per_s"] = moved / (ssm_ms / 1e3)
     stats = device.memory_stats() or {}
@@ -399,7 +413,8 @@ def main(argv=None):
     if args.layers:
         config["n_layer" if "n_layer" in config
                else "num_hidden_layers"] = args.layers
-        for key in ("layer_types", "mlp_layer_types", "sliding_windows"):
+        for key in ("layer_types", "mlp_layer_types", "sliding_windows",
+                    "hybrid_override_pattern"):
             if key in config:
                 config[key] = config[key][:args.layers]
     device = jax.devices()[0]
@@ -466,18 +481,28 @@ def main(argv=None):
         slots += (full_rings(kv, b),)
     feed = lambda n: (cache.carry(), params, tok, lens + n - 1, tables,
                       lens + n) + slots
-    block_experts = moe_experts.routed_experts
-    # form -> (what stands in for routed_experts, whether it reads every
-    # expert of a layer or the hit ones)
+    # the model's form of the routed layer: three matrices with a gate, or
+    # two (relu^2, ``up`` and ``down`` both [E, F, H])
+    two = cfg.routed_layers and "l%d_experts_up" % cfg.routed_layers[0] \
+        in params
+    entry = "relu2_experts" if two else "routed_experts"
+    block_experts = getattr(moe_experts, entry)
+    # form -> (what stands in for it, whether it reads every expert of a
+    # layer or the hit ones)
     swapped = {
         "block": (block_experts,
                   dm.experts_path(cfg, params, b) != "pallas"),
-        "dense": (lambda h2, gates, live, *w: moe_experts.experts_reference(
-            h2, gates, *w), True),
-        "kernel": (moe_experts._experts_pallas, False),
+        "dense": (lambda h2, gates, live, *w: (
+            moe_experts.relu2_reference if two
+            else moe_experts.experts_reference)(h2, gates, *w), True),
+        "kernel": (moe_experts._relu2_pallas if two
+                   else moe_experts._experts_pallas, False),
         "ragged": (experts_ragged(cfg.experts_per_token), False)}
+    if two and "ragged" in forms:
+        ap.error("--experts ragged is the three-matrix form's")
     for form in forms:
-        moe_experts.routed_experts, reads_all = swapped[form]
+        stand_in, reads_all = swapped[form]
+        setattr(moe_experts, entry, stand_in)
         telemetry.reset()
         result = probe_step(args, form, reads_all, config, cfg, kv, cache,
                             device, feed)
@@ -485,7 +510,7 @@ def main(argv=None):
                   "a") as fp:
             fp.write(json.dumps(result) + "\n")
         print(json.dumps(result))
-    moe_experts.routed_experts = block_experts
+    setattr(moe_experts, entry, block_experts)
     return 0 if device.platform == "tpu" or args.compile_only else 2
 
 
