@@ -321,21 +321,28 @@ class CarriedStepFn:
     """AOT-compiled step function with a persistent donated carry — the
     decode-serving analog of the Program path's bf16 param-carry: the
     carry (the paged KV cache) lives on device across steps, every call
-    donates it back in, and the compiled executable is keyed per argument
-    signature with tier-B disk persistence (``aot_compile_cached``).
+    donates it back in, and each compiled executable is filed under a
+    small hashable ``key`` its caller holds (the engine: the lane bucket;
+    the multi-token step kinds: ``(bucket, width)``), with tier-B disk
+    persistence (``aot_compile_cached``).
 
     ``key_parts`` is a JSON-able description of everything that affects
     the lowering besides the argument signature (model fingerprint, cache
     geometry, trace flags) — it feeds ``compile_cache.raw_artifact_key``.
-    ``warmup()`` compiles eagerly for one signature (the serving
-    prewarm) and says what the executable needs beside its arguments
-    (``temp_bytes``) and how much of them it updates in their own buffers
-    (``alias_bytes``: a carry written in place is aliased whole, and its
-    temporaries stay far under it); a ``__call__`` on a signature never
-    warmed compiles on the
-    spot and counts ``executor_cache_miss_total``, so "zero runtime
-    compiles under decode load" stays provable from the same counter the
-    Program path uses."""
+    ``warmup(key, *args)`` compiles eagerly for the arguments' signature
+    (the serving prewarm: the one place the argument tree is flattened and
+    described, for the disk key), files the executable under ``key`` and
+    says what it needs beside its arguments (``temp_bytes``) and how much
+    of them it updates in their own buffers (``alias_bytes``: a carry
+    written in place is aliased whole, and its temporaries stay far under
+    it).  ``__call__(key, *args)`` is a dict lookup and the executable's
+    own call: what it costs does not grow with the number of leaves in
+    the arguments.  A key never warmed compiles on the spot and counts
+    ``executor_cache_miss_total``, so "zero runtime compiles under decode
+    load" stays provable from the same counter the Program path uses.
+    Arguments that do not fit the executable filed under their key raise
+    (the executable checks its arguments' tree and avals itself) and
+    never run."""
 
     def __init__(self, fn, donate_argnums=(0,), key_parts=None, name=None):
         self._jfn = jax.jit(fn, donate_argnums=donate_argnums)
@@ -346,8 +353,9 @@ class CarriedStepFn:
         # counter_total() still sums across the labels, so the
         # zero-runtime-compile asserts stay one prefix sum
         self._name = name
+        # key -> (executable, the signature it was compiled for, its
+        # memory)
         self._compiled = {}
-        self._memory = {}
 
     @staticmethod
     def _sig(args):
@@ -378,40 +386,64 @@ class CarriedStepFn:
                              "tree": sig[0],
                              "devices": [d.id for d in devices]})
 
-    def warmup(self, *args):
-        """Eager-compile for this signature; {"source", "compile_ms",
-        "key", "temp_bytes", "alias_bytes"} (the last two from the
-        executable's ``memory_analysis()``, None where it has none).
-        Memory hits are free (idempotent prewarm)."""
+    def _lazy(self, sig):
+        """What stands in for an executable where the eager compile
+        failed: the lazy jit, held to the one signature as an executable
+        holds itself (a jit alone would trace another shape and run)."""
+        def call(*args):
+            if self._sig(args) != sig:
+                raise TypeError(
+                    "%s: arguments do not fit the signature this key was "
+                    "warmed with" % (self._name or "carried step"))
+            return self._jfn(*args)
+
+        return call
+
+    def warmup(self, key, *args):
+        """Eager-compile for these arguments and file the executable under
+        ``key``; {"source", "compile_ms", "key", "temp_bytes",
+        "alias_bytes"} (``key`` here is the disk key; the last two from
+        the executable's ``memory_analysis()``, None where it has none).
+        A key already warmed is free (idempotent prewarm), and refuses
+        arguments of another signature."""
         sig = self._sig(args)
-        if sig in self._compiled:
-            return dict(self._memory[sig], source="memory", compile_ms=0.0,
-                        key=None)
+        held = self._compiled.get(key)
+        if held is not None:
+            if held[1] != sig:
+                raise TypeError(
+                    "%s: key %r is warmed for another signature"
+                    % (self._name or "carried step", key))
+            return dict(held[2], source="memory", compile_ms=0.0, key=None)
         devices = self._devices(args)
         disk_key = self._disk_key(sig, devices)
         compiled, cstats = aot_compile_cached(
             self._jfn, args, disk_key, devices,
             meta={"kind": "carried_step"})
-        self._compiled[sig] = compiled if compiled is not None \
-            else self._jfn
-        self._memory[sig] = _step_memory(compiled)
+        memory = _step_memory(compiled)
+        self._compiled[key] = (
+            compiled if compiled is not None else self._lazy(sig),
+            sig, memory)
         if _telemetry.enabled():
             labels = {"fn": self._name} if self._name else {}
             _telemetry.inc("executor_cache_miss_total", **labels)
-        return dict(self._memory[sig], source=cstats["source"],
+        return dict(memory, source=cstats["source"],
                     compile_ms=cstats["compile_ms"], key=disk_key)
 
-    def __call__(self, *args):
-        sig = self._sig(args)
-        fn = self._compiled.get(sig)
-        if fn is None:
-            self.warmup(*args)
-            fn = self._compiled[sig]
+    def executable(self, key):
+        """What ``key`` was warmed to: the AOT executable (``cost_analysis``,
+        ``memory_analysis``, ``as_text``)."""
+        return self._compiled[key][0]
+
+    def __call__(self, key, *args):
+        held = self._compiled.get(key)
+        if held is None:
+            self.warmup(key, *args)
+            held = self._compiled[key]
         elif _telemetry.enabled():
             labels = {"fn": self._name} if self._name else {}
             _telemetry.inc("executor_cache_hit_total", **labels)
             _telemetry.inc("executor_steps_total")
-        return fn(*args)
+        return held[0](*args)
 
 
 def _cache_key(program, feed_arrays, fetch_names, mesh):

@@ -9,7 +9,9 @@ win, so the decode scenario carries its own minimal decoder (pre-LN
 transformer: embed + learned positions, per-layer MHA + GELU MLP, tied
 vocab head kept separate for clarity) and plugs into the SAME executor
 machinery the Program path uses: ``core.executor.CarriedStepFn`` AOT-
-compiles the step per lane bucket with tier-B disk persistence, and the
+compiles the step per lane bucket with tier-B disk persistence and finds
+it again by that bucket (the engine's form of the step,
+``make_packed_step``, takes the lanes' integers in one array), and the
 step's attention is ``pallas_kernels.paged_attention``: on a TPU a kernel
 that reads each lane's live KV blocks from the pool where they lie, and
 elsewhere (the CPU tier, the int8 residency) a gather of the padded table
@@ -841,6 +843,53 @@ def make_fed_step(cfg, kv_config, feed_width):
         return (carry, jnp.pad(nxt, (0, feed_width - nxt.shape[0])), *rest)
 
     return fed
+
+
+def lane_columns(kv_config, maxb):
+    """Where each of a step's per-lane integers lies in the one
+    ``int32[bucket, C]`` array the host sends up a step (``make_packed_step``
+    slices it, ``DecodeEngine._decode_step_locked`` fills it): ({name:
+    ``slice`` of columns}, ``C``), in the order ``tok | src | pos | lens |
+    [slot] | tables[maxb] | [ring[window_ring]]``.  The bracketed columns
+    exist where the cache has what they steer: state slots for recurrent
+    layers, rings for window layers."""
+    widths = [("tok", 1), ("src", 1), ("pos", 1), ("lens", 1)]
+    if kv_config.state_layers:
+        widths.append(("slot", 1))
+    widths.append(("tables", maxb))
+    if kv_config.window_layers:
+        widths.append(("ring", kv_config.window_ring))
+    columns, at = {}, 0
+    for name, width in widths:
+        columns[name] = slice(at, at + width)
+        at += width
+    return columns, at
+
+
+def make_packed_step(cfg, kv_config, feed_width):
+    """-> step(kv_carry, params, prev_next, lanes): ``make_fed_step``'s
+    step with every per-lane host array in one ``int32[bucket, C]`` array
+    (``lane_columns``), cut by static column offsets in the same
+    executable.  The host then uploads one array a step, whatever the
+    model's cache holds, and the columns carry the integers the separate
+    arrays did: the step's arithmetic is ``make_fed_step``'s."""
+    fed = make_fed_step(cfg, kv_config, feed_width)
+
+    def packed(kv_carry, params, prev_next, lanes):
+        # the block table is as wide as the other columns leave it
+        _, others = lane_columns(kv_config, 0)
+        at, _ = lane_columns(kv_config, lanes.shape[1] - others)
+        # the one-column quantities turned once, so that each is a row:
+        # cut as columns they cost the device a relayout copy apiece
+        head = lanes[:, :at["tables"].start].T
+        one = lambda name: head[at[name].start]
+        more = [one("slot")] if "slot" in at else []
+        if "ring" in at:
+            more.append(lanes[:, at["ring"]])
+        return fed(kv_carry, params, one("tok"), prev_next, one("src"),
+                   one("pos"), lanes[:, at["tables"]], one("lens"), *more)
+
+    return packed
 
 
 # -- multi-token paged step (speculative verify / prefill chunks) ------------

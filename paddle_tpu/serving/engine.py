@@ -911,6 +911,7 @@ class _DecodeModel:
                  "state_path", "blocks_read", "window_read",
                  "step_ms", "prefix",
                  "declines", "slot_bytes", "state_name", "feed0",
+                 "columns", "idle_lane", "upload",
                  "__weakref__",
                  # speculative decode (spec_k == 0 means off): the draft
                  # decoder runs k tokens ahead through its own paged pool,
@@ -924,7 +925,7 @@ class _DecodeModel:
         self.params = params        # jnp arrays (device-resident)
         self.kv_config = kv_config
         self.cache = cache
-        self.stepfn = stepfn        # CarriedStepFn over make_paged_step
+        self.stepfn = stepfn        # CarriedStepFn over make_packed_step
         self.maxb = -(-cfg.max_seq // kv_config.block_size)
         # how the step's attention reads the pool ("pallas" | "gather"),
         # and lens -> the blocks a layer's attention then fetches
@@ -961,6 +962,17 @@ class _DecodeModel:
         # what the first step after a pause takes as "the step before's
         # tokens": zeros on the device, never selected (every src is -1)
         self.feed0 = None
+        # where each per-lane integer lies in the one ``int32[bucket, C]``
+        # array a step sends up (``decode_model.lane_columns``); a row of
+        # it for an idle lane, ``int32[C]``: no token of the step before
+        # (``src`` -1), position and length 0, the scratch state slot 0,
+        # no block (-1, which reads and writes the scratch block) in the
+        # table or the ring; and what starts the upload:
+        # ``jax.device_put`` to the device the parameters are on
+        # (add_model sets the three)
+        self.columns = None
+        self.idle_lane = None
+        self.upload = None
         self.spec_k = 0
         self.draft_cfg = None
         self.draft_params = None
@@ -1012,11 +1024,16 @@ class DecodeEngine:
        ``request`` mode admission only happens when no lane is active —
        the comparison baseline for the token-level win);
     2. picks the smallest configured lane bucket >= the lanes it plans and
-       rebuilds tok/src/pos/block_tables/context_lens arrays for it — idle
-       lanes point at the reserved scratch block with context_len 0;
-    3. dispatches ONE AOT-compiled step (``CarriedStepFn``; the paged KV
-       carry is donated and swapped back into the cache at dispatch), so
-       mixed-length sequences never trigger a runtime compile;
+       fills ONE ``int32[bucket, C]`` host array for it, whose columns are
+       tok | src | pos | context_lens | [state slot] | block table |
+       [window ring] (``decode_model.lane_columns``) — idle lanes point at
+       the reserved scratch block with context_len 0 — and starts its
+       upload at once;
+    3. dispatches ONE AOT-compiled step (``CarriedStepFn``, found by the
+       bucket: a dict lookup and the executable's own call, whatever the
+       model's number of parameters; the paged KV carry is donated and
+       swapped back into the cache at dispatch), so mixed-length
+       sequences never trigger a runtime compile;
     4. *then* fetches the tokens of the step dispatched an iteration
        before, and appends each live lane's sampled token, finishing
        sequences at max_new/EOS and freeing their blocks in the SAME
@@ -1025,10 +1042,14 @@ class DecodeEngine:
     **One step ahead.**  Step n+1 is queued on the device before step n's
     tokens are read, so dispatch, emit, plan and admission run under the
     device's work and not beside it.  The token a lane feeds stays on the
-    device: the compiled step (``make_fed_step``) takes the step before's
+    device: the compiled step (``make_packed_step`` over
+    ``make_fed_step``) takes the step before's
     ``next_tokens`` and picks lane i's input from it where ``src[i] >= 0``,
     from the host's ``tok[i]`` where it is -1 (prompt and replayed tokens,
-    a lane's first step).  The host plans from ``n_disp`` (positions
+    a lane's first step).  What a step costs the host to start does not
+    grow with the model: the executable is filed under its bucket, and
+    what the host knows of the lanes goes up as one array (span attribute
+    ``uploads``, counter ``serving_step_uploads_total{model}``: 1 a step).  The host plans from ``n_disp`` (positions
     dispatched), which needs no token's value; ``n_fed``, ``out`` and what
     is published go by what has been read back.  Three rules keep that
     exact:
@@ -1251,9 +1272,10 @@ class DecodeEngine:
         if state_path:
             paths["state_update"] = sorted(state_path.items())
         stepfn = CarriedStepFn(
-            # make_paged_step's step with the token feed on the device:
-            # still one executable an engine step
-            _dm.make_fed_step(cfg, kv_config, max(self.buckets)),
+            # make_paged_step's step with the token feed on the device and
+            # the lanes' integers in one array: still one executable an
+            # engine step
+            _dm.make_packed_step(cfg, kv_config, max(self.buckets)),
             donate_argnums=(0,), name="decode_step",
             key_parts=dict(paths, kind="decode_step", model=name,
                            cfg=cfg.to_dict(),
@@ -1277,10 +1299,16 @@ class DecodeEngine:
             _pa.blocks_read, block_size=kv_config.block_size,
             maxb=entry.maxb, path=attn_path)
         entry.prefix = prefix
-        entry.feed0 = jax.device_put(
-            np.zeros(max(self.buckets), np.int32),
-            min(next(iter(jparams.values())).sharding.device_set,
-                key=lambda d: d.id))
+        entry.columns, width = _dm.lane_columns(kv_config, entry.maxb)
+        entry.idle_lane = np.zeros(width, np.int32)
+        for column in ("src", "tables", "ring"):
+            if column in entry.columns:
+                entry.idle_lane[entry.columns[column]] = -1
+        entry.upload = functools.partial(
+            jax.device_put,
+            device=min(next(iter(jparams.values())).sharding.device_set,
+                       key=lambda d: d.id))
+        entry.feed0 = entry.upload(np.zeros(max(self.buckets), np.int32))
         if windowed:
             entry.declines = "window_layers"
         if recurrent:
@@ -1406,18 +1434,19 @@ class DecodeEngine:
                     w = m.spec_k + 1
                     warms = {
                         "verify": m.verifyfn.warmup(
-                            m.cache.carry(), m.params,
+                            (b, w), m.cache.carry(), m.params,
                             np.zeros((b, w), np.int32),
                             np.zeros((b, w), np.int32),
                             np.full((b, m.maxb), -1, np.int32),
                             np.zeros((b, w), np.int32)),
                         "draft_rollout": m.rolloutfn.warmup(
-                            m.draft_cache.carry(), m.draft_params,
+                            (b, m.spec_k), m.draft_cache.carry(),
+                            m.draft_params,
                             np.zeros(b, np.int32), np.zeros(b, np.int32),
                             np.full((b, m.maxb), -1, np.int32),
                             np.zeros(b, np.int32), np.zeros(b, np.int32)),
                         "draft_ingest": m.ingestfn.warmup(
-                            m.draft_cache.carry(), m.draft_params,
+                            (b, w), m.draft_cache.carry(), m.draft_params,
                             np.zeros((b, w), np.int32),
                             np.zeros((b, w), np.int32),
                             np.full((b, m.maxb), -1, np.int32),
@@ -1427,34 +1456,18 @@ class DecodeEngine:
                               for kind, got in warms.items()}
                     continue
                 per[b] = note(name, b, "decode", m.stepfn.warmup(
-                    *self._step_args(
-                        m, b, np.zeros(b, np.int32), np.zeros(b, np.int32),
-                        np.full((b, m.maxb), -1, np.int32),
-                        np.zeros(b, np.int32))))
+                    b, *self._step_args(m, np.tile(m.idle_lane, (b, 1)))))
             manifest[name] = per
         return manifest
 
-    def _step_args(self, m, bucket, tok, pos, tables, lens, slots=None,
-                   prev=None, src=None, rings=None):
-        """The step's arguments (``make_fed_step``).  ``prev`` is the step
-        before's tokens on the device and ``src`` the lane of it each lane
-        feeds from (None: nothing in flight, every lane feeds the host's
-        ``tok``, as prewarm has them).  A model with recurrent layers is
-        told the state slot of each lane's sequence too (None: every lane
-        idle, on the scratch slot), and one with window layers each lane's
-        ring in their pools (None: every lane idle, on the scratch
-        block)."""
-        args = (m.cache.carry(), m.params, tok,
-                prev if prev is not None else m.feed0,
-                src if src is not None else np.full(bucket, -1, np.int32),
-                pos, tables, lens)
-        if m.cache.slots is not None:
-            args += (slots if slots is not None
-                     else np.zeros(bucket, np.int32),)
-        if m.cache.window_allocator is not None:
-            args += (rings if rings is not None else np.full(
-                (bucket, m.kv_config.window_ring), -1, np.int32),)
-        return args
+    @staticmethod
+    def _step_args(m, lanes, prev=None):
+        """The step's arguments (``make_packed_step``): the carry, the
+        parameters, the step before's tokens on the device (None: nothing
+        in flight, so no lane's ``src`` names one, as prewarm has it) and
+        the lanes' integers in their one array."""
+        return (m.cache.carry(), m.params,
+                prev if prev is not None else m.feed0, lanes)
 
     # -- admission -----------------------------------------------------------
 
@@ -2580,6 +2593,14 @@ class DecodeEngine:
         and apply the step dispatched an iteration ago.  At most one step
         is in flight beyond the one being read.
 
+        What goes up a step is one ``int32[bucket, C]`` array (idle
+        lanes' rows with the live lanes filled in; the plan's ``tok``,
+        ``src``, ``pos``, ``lens``, ``slots``, ``tables`` and ``rings`` are
+        views of its columns), uploaded as soon as it is filled so that it
+        travels while the span is opened; the step itself is found by the
+        bucket (``CarriedStepFn``), and is handed nothing that is still
+        on the host.
+
         NOTE: the dispatch and the apply run under the lock — sequences
         can only join/leave at iteration boundaries, which is exactly the
         continuous-batching contract.  submit()/abort() block for at
@@ -2640,13 +2661,14 @@ class DecodeEngine:
         prev = self._flight
         with _tr.phase("serving.plan"):
             bucket = self._bucket_for(len(lanes))
-            tok = np.zeros(bucket, np.int32)
-            src = np.full(bucket, -1, np.int32)
-            pos = np.zeros(bucket, np.int32)
-            tables = np.full((bucket, m.maxb), -1, np.int32)
-            lens = np.zeros(bucket, np.int32)
-            slots = np.zeros(bucket, np.int32) \
-                if m.cache.slots is not None else None
+            # every per-lane integer of the step in ONE host array, so one
+            # upload: tok, src, ... are its columns, written through
+            at = m.columns
+            packed = np.tile(m.idle_lane, (bucket, 1))
+            tok, src, pos, lens = (packed[:, at[name].start]
+                                   for name in ("tok", "src", "pos", "lens"))
+            tables = packed[:, at["tables"]]
+            slots = packed[:, at["slot"].start] if "slot" in at else None
             for i, s in enumerate(lanes):
                 p = s.n_disp
                 if p < s.known:
@@ -2660,18 +2682,24 @@ class DecodeEngine:
                 lens[i] = p + 1  # token valid AFTER this step's write
                 if slots is not None:
                     slots[i] = s.state_slot
-            windowed = m.cache.window_allocator is not None
-            rings, released = None, 0
+            windowed = "ring" in at
+            released = 0
             if windowed:
                 # the windows move on: what left them goes back to the
                 # window layers' pools, the blocks this step writes come
                 # from them
-                rings = np.full((bucket, m.kv_config.window_ring), -1,
-                                np.int32)
+                rings = packed[:, at["ring"]]
                 for i, s in enumerate(lanes):
                     released += m.cache.advance_ring(s.window_ring,
                                                      s.n_disp + 1)
                     rings[i] = s.window_ring.table
+            # filled: the upload starts now and travels while the host
+            # opens the span; what it counts is every host array this
+            # dispatch hands up, the executable's own call included
+            args = self._step_args(m, m.upload(packed),
+                                   prev.nxt if prev is not None else None)
+            uploads = 1 + sum(isinstance(a, np.ndarray) for a in args[2:])
+            _tm.inc("serving_step_uploads_total", uploads, model=m.name)
             # blocks a layer's attention fetches this step, of the slots
             # the table has: the live context's share where the kernel
             # reads in place, all of them where the table is gathered
@@ -2691,11 +2719,8 @@ class DecodeEngine:
                     read[m.state_name + "_bytes"] = len(lanes) * m.slot_bytes
             if windowed and _tr.enabled():
                 read.update(self._window_attrs(m, lens, released))
-            sspan = self._open_step_span(m, bucket, lanes, **read)
-            args = self._step_args(
-                m, bucket, tok, pos, tables, lens, slots,
-                prev=prev.nxt if prev is not None else None, src=src,
-                rings=rings)
+            sspan = self._open_step_span(m, bucket, lanes, uploads=uploads,
+                                         **read)
         # is the device still at work on the step before?  Then it never
         # runs dry between the two, and the host's time since the last
         # fetch kept nothing waiting
@@ -2705,7 +2730,7 @@ class DecodeEngine:
         try:
             with _tr.activate(sspan), _tr.phase("serving.dispatch"):
                 # threadlint: waive CC102 continuous-batching contract: the step is dispatched under _cond so lane state is frozen while it is planned and queued (see _decode_step_locked docstring); submitters park on the cond, never spin
-                carry, nxt, _logits, *extras = m.stepfn(*args)
+                carry, nxt, _logits, *extras = m.stepfn(bucket, *args)
                 # at dispatch: the next step, and whoever writes a block,
                 # queues behind outputs the device has yet to compute
                 m.cache.replace_carry(carry)
@@ -2953,7 +2978,8 @@ class DecodeEngine:
                             _tr.phase("serving.dispatch"):
                         # threadlint: waive CC102 draft rollout runs under _cond by the same frozen-lane contract as stepfn in _decode_step_locked
                         dcarry, props = m.rolloutfn(
-                            m.draft_cache.carry(), m.draft_params,
+                            (bucket, k), m.draft_cache.carry(),
+                            m.draft_params,
                             rtok, rpos, rtables, rlens, rmax)
                     with _tr.phase("serving.fetch"):
                         m.draft_cache.replace_carry(dcarry)
@@ -2969,7 +2995,8 @@ class DecodeEngine:
                               width=width), _tr.phase("serving.dispatch"):
                     # threadlint: waive CC102 target-model verify runs under _cond by the same frozen-lane contract as stepfn in _decode_step_locked
                     carry, nxt, _logits, *_extras = m.verifyfn(
-                        m.cache.carry(), m.params, tok, pos, tables, lens)
+                        (bucket, width), m.cache.carry(), m.params, tok,
+                        pos, tables, lens)
                 with _tr.phase("serving.fetch"):
                     m.cache.replace_carry(carry)
                     nxt = np.asarray(nxt)
@@ -3093,7 +3120,8 @@ class DecodeEngine:
                         _tr.phase("serving.dispatch"):
                     # threadlint: waive CC102 draft-cache ingest runs under _cond by the same frozen-lane contract as stepfn in _decode_step_locked
                     dcarry, _nx, _lg, *_extras = m.ingestfn(
-                        m.draft_cache.carry(), m.draft_params,
+                        (bucket, width), m.draft_cache.carry(),
+                        m.draft_params,
                         itok, ipos, itables, ilens)
                 m.draft_cache.replace_carry(dcarry)
             except Exception:

@@ -359,18 +359,19 @@ def test_stop_reads_the_step_in_flight_back(telemetry_on):
 
 def test_one_step_call_per_engine_step_and_no_compile(telemetry_on):
     """Lanes come and go over buckets 1, 2 and 4: every engine step is one
-    call of the one ``CarriedStepFn`` (the token feed is inside it), and
-    the step before's tokens, padded to the largest bucket, never make a
-    new signature."""
+    call of the one ``CarriedStepFn`` (the token feed is inside it) under
+    its bucket's key, with the lanes' integers in one array of the
+    bucket's one shape, and the step before's tokens, padded to the
+    largest bucket, never make a new signature."""
     e = _engine("gpt2", buckets="1,2,4")
     m = e._models["m"]
     calls = []
     inner = m.stepfn
 
     class Counting:
-        def __call__(self, *args):
-            calls.append(tuple(np.shape(a) for a in args[2:]))
-            return inner(*args)
+        def __call__(self, key, *args):
+            calls.append((key,) + tuple(np.shape(a) for a in args[2:]))
+            return inner(key, *args)
 
         def __getattr__(self, name):
             return getattr(inner, name)
@@ -391,9 +392,12 @@ def test_one_step_call_per_engine_step_and_no_compile(telemetry_on):
             assert list(s.reply.outputs["tokens"]) == _unpaged("gpt2", p, n)
         _drive(e, lambda: e._flight is None)
         assert len(calls) == _tm.counter_total("serving_decode_steps_total")
-        assert {shape[0][0] for shape in calls} == {1, 2, 4}
+        assert {key for key, _prev, _lanes in calls} == {1, 2, 4}
         # prev_next is always the largest bucket's width
-        assert {shape[1] for shape in calls} == {(4,)}
+        assert {prev for _key, prev, _lanes in calls} == {(4,)}
+        # tok | src | pos | lens | tables: bucket rows of 4 + MAXB columns
+        assert all(lanes == (key, 4 + GPT2.max_seq // BS)
+                   for key, _prev, lanes in calls)
         assert _tm.counter_total("executor_cache_miss_total") == miss0
         assert _tm.counter_total("executor_cache_hit_total") == len(calls)
     finally:

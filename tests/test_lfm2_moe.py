@@ -113,8 +113,9 @@ def run_paged(cfg, params, seqs, blocks=40, table_seed=5, dirty=None,
     paged step and real pools, a shuffled block table and shuffled window
     slots: the prompt one token a step, then the step's own argmax.
     ``dirty`` fills every slot before the first step; ``feed`` takes the
-    step with the token feed on the device (``make_fed_step``), each lane's
-    decoded token chosen there from the step before's.  -> per lane (tokens
+    step as the engine compiles it (``make_packed_step``: the token feed on
+    the device, the lanes' integers in one array), each lane's decoded
+    token chosen there from the step before's.  -> per lane (tokens
     fed, logits [n, vocab] of every position fed, routed counts [n, routed
     layers, experts])."""
     b = len(seqs)
@@ -133,8 +134,9 @@ def run_paged(cfg, params, seqs, blocks=40, table_seed=5, dirty=None,
     for i, t in enumerate(total):
         for j in range(-(-t // BS)):
             tables[i, j] = next(order)
-    make = dm.make_fed_step(cfg, kv, b) if feed \
+    make = dm.make_packed_step(cfg, kv, b) if feed \
         else dm.make_paged_step(cfg, kv)
+    columns, width = dm.lane_columns(kv, maxb)
     step = jax.jit(make, donate_argnums=(0,))
     jparams = _jnp(params)
     fed = [list(p) for p, _ in seqs]
@@ -156,9 +158,15 @@ def run_paged(cfg, params, seqs, blocks=40, table_seed=5, dirty=None,
             else:
                 tok[i] = fed[i][at]
         where = np.where(lens > 0, slots, 0).astype(np.int32)
-        args = (tok, prev, src) if feed else (tok,)
-        carry, nxt, lg, counts = step(cache.carry(), jparams, *args, pos,
-                                      tables, lens, where)
+        if feed:
+            lanes = np.zeros((b, width), np.int32)
+            for name, value in dict(tok=tok, src=src, pos=pos, lens=lens,
+                                    slot=where, tables=tables).items():
+                lanes[:, columns[name]] = value.reshape(b, -1)
+            args = (prev, lanes)
+        else:
+            args = (tok, pos, tables, lens, where)
+        carry, nxt, lg, counts = step(cache.carry(), jparams, *args)
         cache.replace_carry(carry)
         prev = nxt
         nxt, lg = np.asarray(nxt), np.asarray(lg)
@@ -438,9 +446,10 @@ def test_joining_and_leaving_lanes_equal_each_alone():
 
 
 def test_fed_step_feeds_the_step_before_on_the_device():
-    """``make_fed_step`` with this model: decoded tokens chosen on the
-    device from the step before's give the logits of the step the host
-    feeds."""
+    """``make_packed_step`` with this model (the step as the engine
+    compiles it): decoded tokens chosen on the device from the step
+    before's, every lane's integers the columns of one array, give the
+    logits of the step the host feeds."""
     seqs = _sequences(2, seed=7)
     host = run_paged(CFG, PARAMS, seqs)
     device = run_paged(CFG, PARAMS, seqs, feed=True)

@@ -69,6 +69,15 @@ def _expert_passes(text, experts, hidden, ffn):
             if whole.search(line)]
 
 
+def _packed_feeds(kv, lanes, maxb):
+    """What ``make_packed_step`` takes after the carry and the parameters:
+    the step before's tokens and the lanes' integers, ``int32[lanes, C]``
+    (``decode_model.lane_columns``)."""
+    return [jax.ShapeDtypeStruct((lanes,), jnp.int32),
+            jax.ShapeDtypeStruct(
+                (lanes, dm.lane_columns(kv, maxb)[1]), jnp.int32)]
+
+
 def _placed(sharding, tree):
     """The tree's shapes, placed on the described chip."""
     return jax.tree_util.tree_map(
@@ -171,7 +180,7 @@ def test_olmoe_step_streams_its_experts_and_keeps_the_pool_in_place(one_chip):
     assert not _expert_passes(compiled.as_text(), 64, 2048, 1024)
 
 
-@pytest.mark.parametrize("kind", ["step", "multi"])
+@pytest.mark.parametrize("kind", ["step", "multi", "packed"])
 @pytest.mark.parametrize("model", ["gpt2-medium-serve", "olmoe-1b-7b-serve"])
 def test_decode_step_reads_the_pool_through_the_kernel_on_v5e(
         one_chip, as_on_tpu, model, kind):
@@ -196,7 +205,7 @@ def test_decode_step_reads_the_pool_through_the_kernel_on_v5e(
                            model + ".json")) as fp:
         config = dict(json.load(fp), **{layers_key: 2})
     cfg = module.decoder_config(config)
-    lanes, block_size, width = 32, 16, 1 if kind == "step" else 2
+    lanes, block_size, width = 32, 16, 2 if kind == "multi" else 1
     kv = KVCacheConfig(cfg.layers, cfg.heads, cfg.head_dim, block_size,
                        blocks, cfg.kv_dtype or "f32")
     assert dm.attention_path(cfg, kv) == "pallas"
@@ -215,6 +224,11 @@ def test_decode_step_reads_the_pool_through_the_kernel_on_v5e(
         jax.ShapeDtypeStruct(per_lane, jnp.int32)])
     fn = dm.make_paged_step(cfg, kv) if kind == "step" \
         else dm.make_paged_step_multi(cfg, kv, width)
+    if kind == "packed":
+        # the step as the engine compiles it (PR 43): the lanes' integers
+        # in one array, cut apart in the same executable
+        feeds = on_chip(_packed_feeds(kv, lanes, cfg.max_seq // block_size))
+        fn = dm.make_packed_step(cfg, kv, lanes)
     compiled = jax.jit(fn, donate_argnums=(0,)).lower(
         carry, params, *feeds).compile()
 
@@ -336,11 +350,9 @@ def test_lfm2_moe_step_updates_pools_and_windows_in_place(one_chip,
         name: jax.ShapeDtypeStruct(shape, jnp.bfloat16)
         for name, (shape, _kind)
         in lfm2_moe_decoder.param_shapes(config).items()})
-    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
-    feeds = on_chip([i32(lanes), i32(lanes), i32(lanes), i32(lanes),
-                     i32(lanes, cfg.max_seq // block_size), i32(lanes),
-                     i32(lanes)])
-    compiled = jax.jit(dm.make_fed_step(cfg, kv, lanes), donate_argnums=(0,)
+    feeds = on_chip(_packed_feeds(kv, lanes, cfg.max_seq // block_size))
+    compiled = jax.jit(dm.make_packed_step(cfg, kv, lanes),
+                       donate_argnums=(0,)
                        ).lower(carry, params, *feeds).compile()
 
     text = compiled.as_text()
@@ -415,11 +427,9 @@ def test_exaone_moe_step_compiles_both_attention_kinds_and_its_experts(
         name: jax.ShapeDtypeStruct(shape, jnp.bfloat16)
         for name, (shape, _kind)
         in exaone_moe_decoder.param_shapes(config).items()})
-    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
-    feeds = on_chip([i32(lanes), i32(lanes), i32(lanes), i32(lanes),
-                     i32(lanes, cfg.max_seq // block_size), i32(lanes),
-                     i32(lanes, kv.window_ring)])
-    compiled = jax.jit(dm.make_fed_step(cfg, kv, lanes), donate_argnums=(0,)
+    feeds = on_chip(_packed_feeds(kv, lanes, cfg.max_seq // block_size))
+    compiled = jax.jit(dm.make_packed_step(cfg, kv, lanes),
+                       donate_argnums=(0,)
                        ).lower(carry, params, *feeds).compile()
 
     text = compiled.as_text()
@@ -445,12 +455,13 @@ def test_nemotron_h_step_compiles_its_three_kernels_at_published_shapes(
     """NVIDIA-Nemotron-3-Nano-30B-A3B's published widths, its first 6 blocks
     (``M E M E M *``: every kind), bucket 32, the cell's pools (2048 bf16
     blocks 256 wide for 2 KV heads of 128, 33 state slots of [128, 4096]):
-    Mosaic accepts, inside the whole fed step, the state-update kernel with
-    B and C in 8 groups (a 2048-column grid step spans 4), the two-matrix
-    expert kernel over 16 held experts ``[16, 1856, 2688]`` cut on the
-    second-minor axis (1856 is no multiple of 128), and the paged-attention
-    kernel for 32 query heads over 2 (groups of 16, compact); the experts
-    are read as they lie and every pool is aliased whole."""
+    Mosaic accepts, inside the whole step as the engine compiles it
+    (``make_packed_step``), the state-update kernel with B and C in 8
+    groups (a 2048-column grid step spans 4), the two-matrix expert kernel
+    over 16 held experts ``[16, 1856, 2688]`` cut on the second-minor axis
+    (1856 is no multiple of 128), and the paged-attention kernel for 32
+    query heads over 2 (groups of 16, compact); the experts are read as
+    they lie and every pool is aliased whole."""
     from benchmark.models import nemotron_h_decoder
     from paddle_tpu.pallas_kernels import moe_experts as moe
     from paddle_tpu.pallas_kernels import paged_attention as pa
@@ -490,11 +501,9 @@ def test_nemotron_h_step_compiles_its_three_kernels_at_published_shapes(
         name: jax.ShapeDtypeStruct(shape, jnp.bfloat16)
         for name, (shape, _kind)
         in nemotron_h_decoder.param_shapes(config).items()})
-    i32 = lambda *shape: jax.ShapeDtypeStruct(shape, jnp.int32)
-    feeds = on_chip([i32(lanes), i32(lanes), i32(lanes), i32(lanes),
-                     i32(lanes, cfg.max_seq // block_size), i32(lanes),
-                     i32(lanes)])
-    compiled = jax.jit(dm.make_fed_step(cfg, kv, lanes), donate_argnums=(0,)
+    feeds = on_chip(_packed_feeds(kv, lanes, cfg.max_seq // block_size))
+    compiled = jax.jit(dm.make_packed_step(cfg, kv, lanes),
+                       donate_argnums=(0,)
                        ).lower(carry, params, *feeds).compile()
 
     text = compiled.as_text()

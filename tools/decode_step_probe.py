@@ -251,8 +251,8 @@ def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
     b = args.bucket
     stepfn = CarriedStepFn(dm.make_paged_step(cfg, kv), donate_argnums=(0,),
                            name="probe")
-    warm = stepfn.warmup(*feed(0))
-    compiled = stepfn._compiled[stepfn._sig(feed(0))]
+    warm = stepfn.warmup(b, *feed(0))
+    compiled = stepfn.executable(b)
     memory = compiled.memory_analysis()
     text = compiled.as_text()
     if args.hlo_out:
@@ -296,7 +296,7 @@ def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
 
     def run(n0, n):
         for i in range(n0, n0 + n):
-            carry, nxt, _logits, *extras = stepfn(*feed(i))
+            carry, nxt, _logits, *extras = stepfn(b, *feed(i))
             cache.replace_carry(carry)
             nxt.block_until_ready()
             routed.extend(extras[:1])
