@@ -22,14 +22,22 @@ sequence, serving/kv_cache.py) and lane ``b`` of a step holds slot
 ``slots[b]``.  ``state_update`` picks the path from what it can see, with no
 flag, as ``paged_attention`` does:
 
-* **the kernel**, on a TPU backend, for a float32 pool whose ``I`` is a
-  multiple of ``COLUMNS``, whose ``N`` is a multiple of the 8 sublanes and
-  whose groups are whole 128-column slices that tile a grid step's columns
-  (or are tiled by them):
-  a grid over (lane, column chunk) whose blocks of the pool are steered by
-  the scalar-prefetched slots, each read into VMEM, updated and written back
-  to the same place (the pool is aliased to the output), the next block in
-  flight meanwhile.  A slot is read once and written once, and nothing of the
+* **the kernel**, on a TPU backend, for a float32 pool whose ``I`` is whole
+  128-column slices, whose ``N`` is a multiple of the 8 sublanes and whose
+  groups are whole slices too: a grid over (lane, column chunk) whose unit
+  is a lane's whole slot where VMEM allows (``transfer_columns``: ``N x I``
+  float32, 2 MiB contiguous at the published sizes) and the widest chunk of
+  it that fits otherwise.  The kernel moves the units itself, by async
+  copies steered by the scalar-prefetched slots, to and from the same place
+  (the pool is aliased to the output), **a batch of units at a time and
+  reads and writes in turn**: while a batch is updated in VMEM the next one
+  is read, and only when that has arrived is the batch written back, with
+  nothing else in flight.  The chip's HBM takes reads at 745 GB/s and
+  writes at 653; with both in flight everything moves at the writes' rate
+  (a layer's call 204.8 us whatever the block or the depth of the queue),
+  in turns each moves at its own (195.8 us: PERF.md section 6, PR 44).  A
+  slot is read once and written once, ``b`` and ``c`` come in as they are
+  (``[B, G, N]``, turned into columns in the kernel), and nothing of the
   pool's size is made: XLA's form of the same update splits the pool in
   halves (a whole-pool pass), gathers the lanes' slots, updates them and
   scatters them back, four times the traffic (PERF.md section 6, PR 31).
@@ -52,16 +60,26 @@ from jax.experimental.pallas import tpu as pltpu
 from . import adoption
 
 __all__ = ["advance", "state_update", "state_update_reference",
-           "ssm_update_checks", "update_path", "started", "KERNEL_NAME"]
+           "ssm_update_checks", "update_path", "transfer_columns", "started",
+           "KERNEL_NAME"]
 
 # the name the kernel's executions carry in a device trace
 KERNEL_NAME = "ssm_state_update"
 
-# columns of the state a grid step moves: a block of N x COLUMNS float32
-# (1 MB at N = 128), double buffered in and out
-COLUMNS = 2048
+# what the kernel asks of VMEM (Mosaic's default scoped limit is 16 MiB of
+# the chip's 128), and the part of it the units in flight may take: two
+# batches of them, one being updated or written while the other is read; the
+# rest is the lanes' rows, b and c and Mosaic's own.  XLA keeps the next
+# matmuls' weights in the same VMEM across the call: asking for more than
+# this costs the step more than longer turns win the kernel (PERF.md
+# section 6, PR 44: batches of 8 in 40 MiB are 1.2 us a call faster alone
+# and 0.06-0.57 ms a step slower inside Granite's and Nemotron-H's steps)
+_VMEM_LIMIT = 24 << 20
+_UNIT_BUDGET = 16 << 20
 
-_VMEM_BUDGET = 8 << 20
+# units read (or written) together at most: 4 whole slots of 2 MiB twice
+# fill the budget
+BATCH = 4
 
 
 def advance(state, decay, dx, b, c):
@@ -94,16 +112,31 @@ def state_update_reference(pool, slots, fresh, decay, dx, b, c):
     return pool.at[slots].set(state), y
 
 
-def _groups_tile(inner, groups):
-    """Are the groups' columns whole 128-column slices, and does a group
-    tile a grid step's ``min(COLUMNS, inner)`` columns or a step a group?
-    Then every slice has one group, known from the step."""
-    cols = min(COLUMNS, inner)
-    if groups < 1 or inner < 1 or inner % groups \
-            or (inner // groups) % 128:
-        return False
+def _whole_groups(inner, groups):
+    """Are the groups' columns whole 128-column slices?"""
+    return groups >= 1 and inner >= 1 and inner % groups == 0 \
+        and (inner // groups) % 128 == 0
+
+
+def transfer_columns(pool_shape, groups=1):
+    """The columns of a slot one transfer moves: ``I`` where two batches of
+    two whole slots fit ``_UNIT_BUDGET``, else the widest chunk that does,
+    divides ``I`` into whole 128-column slices and lies within one group or
+    spans whole groups (so every slice has one group, known from the chunk).
+    None where not even a 128-column chunk fits, or the groups are not whole
+    slices."""
+    _slots, n, inner = pool_shape
+    if n < 1 or not _whole_groups(inner, groups):
+        return None
     per = inner // groups
-    return per % cols == 0 or cols % per == 0
+    for pieces in range(1, inner // 128 + 1):
+        cols = inner // pieces
+        if inner % pieces or cols % 128 \
+                or (per % cols and cols % per):
+            continue
+        if 2 * 2 * 4 * n * cols <= _UNIT_BUDGET:
+            return cols
+    return None
 
 
 def ssm_update_checks(pool_shape, pool_dtype, lanes, groups=1):
@@ -113,24 +146,21 @@ def ssm_update_checks(pool_shape, pool_dtype, lanes, groups=1):
     dims = tuple(pool_shape) + (lanes, groups)
     static = all(isinstance(x, int) and x >= 0 for x in dims)
     rank = len(pool_shape) == 3
+    shaped = static and rank and all(x > 0 for x in dims)
     return [
         ("backend", adoption.interpret_mode()
          or jax.default_backend() == "tpu"),
         ("symbolic_shape", static),
         ("rank", rank),
         ("dtype", jnp.dtype(pool_dtype) == jnp.float32),
-        ("lanes", static and rank and pool_shape[2] % 128 == 0
-         and pool_shape[2] % min(COLUMNS, pool_shape[2]) == 0),
+        ("lanes", static and rank and pool_shape[2] % 128 == 0),
         ("sublanes", static and rank and pool_shape[1] % 8 == 0),
-        ("empty", static and all(x > 0 for x in dims)),
-        # a group is whole 128-column slices, and tiles a grid step's
-        # columns or is tiled by them
-        ("groups", static and rank and groups <= 128
-         and _groups_tile(pool_shape[2], groups)),
-        # the pool's block in and out, double buffered, and b and c spread
-        # over the lanes
-        ("vmem", static and rank and 4 * pool_shape[1] * (
-            4 * min(COLUMNS, pool_shape[2]) + 4 * 128) <= _VMEM_BUDGET),
+        ("empty", shaped),
+        # a group is whole 128-column slices
+        ("groups", shaped and _whole_groups(pool_shape[2], groups)),
+        # two batches of two units, be a unit only 128 columns wide, within
+        # what the kernel asks of VMEM
+        ("vmem", shaped and transfer_columns(pool_shape, groups) is not None),
     ]
 
 
@@ -144,74 +174,135 @@ def update_path(pool_shape, pool_dtype, lanes, groups=1):
     return "pallas" if ok else "gather"
 
 
-def _kernel(slots_ref, fresh_ref, pool_ref, decay_ref, dx_ref, b_ref, c_ref,
-            out_ref, y_ref, *, groups, per):
-    del slots_ref                        # steers the blocks, not the body
-    lane = pl.program_id(0)
+def _columns_of(ref, first):
+    """``ref`` [G, N] (``b`` or ``c`` of a lane, as they come) -> ``g`` ->
+    [N, 128] with row ``first + g``'s value for state row ``n`` in every
+    lane of sublane ``n``: the row picked by a masked sum over the sublanes,
+    laid along the diagonal of [N, N] and summed over the lanes (one term
+    and zeros: exact)."""
+    x = ref[...]
+    groups, n = x.shape
+    which = jax.lax.broadcasted_iota(jnp.int32, x.shape, 0)
+    diagonal = jax.lax.broadcasted_iota(jnp.int32, (n, n), 0) \
+        == jax.lax.broadcasted_iota(jnp.int32, (n, n), 1)
+    made = {}
+
+    def column(g):
+        if g not in made:
+            row = jnp.sum(jnp.where(which == first + g, x, 0.0), axis=0,
+                          keepdims=True)
+            made[g] = jnp.broadcast_to(
+                jnp.sum(jnp.where(diagonal, row, 0.0), axis=1,
+                        keepdims=True), (n, 128))
+        return made[g]
+    return column
+
+
+def _kernel(slots_ref, fresh_ref, pool_hbm, decay_ref, dx_ref, b_ref, c_ref,
+            out_hbm, y_ref, buf, rsem, wsem, *, lanes, chunks, per):
+    """Grid step (lane, chunk) updates unit ``lane * chunks + chunk`` where
+    it lies in ``buf`` [2, K, N, cols]: batch ``unit // K`` in half ``batch %
+    2``.  The first step reads batch 0; a batch's first step starts the next
+    batch's reads, its last waits for them, then writes the batch back and
+    waits for that; the last batch, with nothing left to read, writes each
+    unit as it is done."""
+    del pool_hbm                         # out_hbm is the same buffer
+    lane, chunk = pl.program_id(0), pl.program_id(1)
+    k_n, cols = buf.shape[1], buf.shape[3]
+    total = lanes * chunks
+    unit = lane * chunks + chunk
+    batch, at = unit // k_n, unit % k_n
+    last = batch == (total - 1) // k_n
+
+    def copy(kk, jj, write):
+        u = kk * k_n + jj
+        where = out_hbm.at[
+            slots_ref[u // chunks], :,
+            pl.ds(pl.multiple_of(u % chunks * cols, 128), cols)]
+        here = buf.at[kk % 2, jj]
+        if write:
+            return pltpu.make_async_copy(here, where, wsem.at[kk % 2, jj])
+        return pltpu.make_async_copy(where, here, rsem.at[kk % 2, jj])
+
+    def each(kk, write, act):
+        """``act`` on the copy of every unit batch ``kk`` has (the last
+        batch may be short)."""
+        for jj in range(k_n):
+            go = lambda jj=jj: act(copy(kk, jj, write))
+            if total % k_n:
+                pl.when(kk * k_n + jj < total)(go)
+            else:
+                go()
+
+    start, wait = (lambda dma: dma.start()), (lambda dma: dma.wait())
+
+    @pl.when(unit == 0)
+    def _first():
+        each(0, False, start)
+        each(0, False, wait)
+
+    @pl.when((at == 0) & jnp.logical_not(last))
+    def _ahead():
+        each(batch + 1, False, start)
+
+    half = batch % 2
     fresh = fresh_ref[lane] != 0
-    cols = pool_ref.shape[1]
-    if groups == 1:
-        # [N, 128], a value a row, the same for every column
-        first = 0
-        pairs = {0: (b_ref[...], c_ref[...])}
-    else:
-        # [N, 128] with group g's values in lane g: the first group this
-        # grid step's columns belong to, and a pair a group as it is met
-        first = pl.program_id(1) * cols // per
-        pairs = {}
-
-    def pair(g):
-        if g not in pairs:
-            at = jax.lax.broadcasted_iota(jnp.int32, b_ref.shape, 1)
-            pairs[g] = tuple(
-                jnp.broadcast_to(jnp.sum(
-                    jnp.where(at == first + g, ref[...], 0.0), axis=1,
-                    keepdims=True), ref.shape) for ref in (b_ref, c_ref))
-        return pairs[g]
-
+    # b and c of the groups this chunk's columns belong to, a pair a group
+    # as it is met
+    first = chunk * cols // per
+    b_of, c_of = _columns_of(b_ref, first), _columns_of(c_ref, first)
     # 128 columns at a time: every operand is whole (8, 128) tiles, b and c
     # rows broadcast over the lanes, decay and dx columns over the sublanes
     for k in range(cols // 128):
-        at = pl.ds(k * 128, 128)
-        b, c = pair(k * 128 // per)
-        state = jnp.where(fresh, 0.0, pool_ref[:, at])
-        state = decay_ref[:, at] * state + b * dx_ref[:, at]
-        out_ref[:, at] = state
-        y_ref[:, at] = jnp.sum(state * c, axis=0, keepdims=True)
+        sl = pl.ds(k * 128, 128)
+        g = k * 128 // per
+        state = jnp.where(fresh, 0.0, buf[half, at, :, sl])
+        state = decay_ref[:, sl] * state + b_of(g) * dx_ref[:, sl]
+        buf[half, at, :, sl] = state
+        y_ref[:, sl] = jnp.sum(state * c_of(g), axis=0, keepdims=True)
+
+    @pl.when(last)
+    def _write_now():
+        copy(batch, at, True).start()
+
+    @pl.when((at == k_n - 1) & jnp.logical_not(last))
+    def _turn():
+        each(batch + 1, False, wait)
+        each(batch, True, start)
+        each(batch, True, wait)
+
+    @pl.when(unit == total - 1)
+    def _drain():
+        each(batch, True, wait)
 
 
 def _state_update_pallas(pool, slots, fresh, decay, dx, b, c, interpret=None):
     """-> (pool updated in its own buffer, y [B, I])."""
     lanes, inner = decay.shape
     n, groups = pool.shape[1], b.shape[1]
-    cols = min(COLUMNS, inner)
+    cols = transfer_columns(pool.shape, groups)
+    chunks = inner // cols
+    k_n = min(BATCH, _UNIT_BUDGET // (2 * 4 * n * cols), lanes * chunks)
     if interpret is None:
         interpret = adoption.interpret()
     f32 = jnp.float32
     row = lambda x: x.astype(f32).reshape(lanes, 1, inner)
-
-    # b and c as columns, a value a sublane: one group's repeated over the
-    # 128 lanes; several groups' side by side, group g in lane g (the
-    # kernel spreads the one a slice of columns belongs to)
-    def col(x):
-        x = jnp.swapaxes(x.astype(f32), 1, 2)               # [B, N, G]
-        if groups == 1:
-            return jnp.broadcast_to(x, (lanes, n, 128))
-        return jnp.pad(x, ((0, 0), (0, 0), (0, 128 - groups)))
-
-    slot_block = pl.BlockSpec((None, n, cols),
-                              lambda i, j, slots, fresh: (slots[i], 0, j))
     lane_row = pl.BlockSpec((None, 1, cols),
                             lambda i, j, slots, fresh: (i, 0, j))
-    lane_col = pl.BlockSpec((None, n, 128),
-                            lambda i, j, slots, fresh: (i, 0, 0))
+    lane_pair = pl.BlockSpec((None, groups, n),
+                             lambda i, j, slots, fresh: (i, 0, 0))
+    in_place = pl.BlockSpec(memory_space=pl.ANY)
     pool, y = pl.pallas_call(
-        functools.partial(_kernel, groups=groups, per=inner // groups),
+        functools.partial(_kernel, lanes=lanes, chunks=chunks,
+                          per=inner // groups),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(lanes, inner // cols),
-            in_specs=[slot_block, lane_row, lane_row, lane_col, lane_col],
-            out_specs=[slot_block, lane_row],
+            grid=(lanes, chunks),
+            in_specs=[in_place, lane_row, lane_row, lane_pair, lane_pair],
+            out_specs=[in_place, lane_row],
+            scratch_shapes=[pltpu.VMEM((2, k_n, n, cols), f32),
+                            pltpu.SemaphoreType.DMA((2, k_n)),
+                            pltpu.SemaphoreType.DMA((2, k_n))],
         ),
         out_shape=[jax.ShapeDtypeStruct(pool.shape, pool.dtype),
                    jax.ShapeDtypeStruct((lanes, 1, inner), f32)],
@@ -219,8 +310,12 @@ def _state_update_pallas(pool, slots, fresh, decay, dx, b, c, interpret=None):
         input_output_aliases={2: 0},
         name=KERNEL_NAME,
         interpret=interpret,
+        # the batches carry over from one grid step to the next
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT),
     )(slots.astype(jnp.int32), fresh.astype(jnp.int32), pool, row(decay),
-      row(dx), col(b), col(c))
+      row(dx), b.astype(f32), c.astype(f32))
     return pool, y.reshape(lanes, inner)
 
 

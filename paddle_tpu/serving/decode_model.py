@@ -86,7 +86,7 @@ from . import kv_cache as _kv
 __all__ = ["DecoderConfig", "init_decoder_params", "save_decoder",
            "load_decoder", "is_decoder_dir", "has_draft", "load_draft",
            "truncate_decoder", "attention_path", "experts_path",
-           "state_update_path",
+           "state_update_path", "state_update_columns",
            "make_paged_step",
            "make_fed_step", "make_paged_step_multi",
            "make_draft_rollout", "make_unpaged_step", "unpaged_generate",
@@ -632,6 +632,19 @@ def state_update_path(cfg, kv_config, lanes=1):
     shape, dtype = kv_config.state_shapes[1]
     return _ssm.update_path((kv_config.state_slots,) + shape,
                             _kv._PAYLOAD[dtype][0], lanes, cfg.ssm_groups)
+
+
+def state_update_columns(cfg, kv_config):
+    """The columns of a slot one transfer of the state-update kernel moves
+    for this model's pool: the slot's whole width where the VMEM the kernel
+    asks for holds batches of whole slots, else the chunk it falls back to
+    (``ssm_update.transfer_columns``); None for a model with no such layer
+    or a pool no chunk of which fits."""
+    if not cfg.ssm_layers:
+        return None
+    shape, _dtype = kv_config.state_shapes[1]
+    return _ssm.transfer_columns((kv_config.state_slots,) + shape,
+                                 cfg.ssm_groups)
 
 
 def _pool_index(cfg):

@@ -1393,6 +1393,8 @@ class DecodeEngine:
         this, mixed-length continuous batching can only hit the
         in-memory executables: ``executor_cache_miss_total`` stays flat
         under load — the zero-runtime-compile proof."""
+        from . import decode_model as _dm
+
         def note(model, bucket, fn, got, **extra):
             # what the executable holds beside its arguments, and how
             # much of them (the KV pool) it updates in their own buffers
@@ -1403,6 +1405,11 @@ class DecodeEngine:
                 extra["experts"] = m.experts_path[bucket]
             if m.state_path:
                 extra["state_update"] = m.state_path[bucket]
+                if m.state_path[bucket] == "pallas":
+                    # what one transfer of the kernel moves: the slot's
+                    # whole width, or the chunk a larger slot falls back to
+                    extra["state_update_columns"] = \
+                        _dm.state_update_columns(m.cfg, m.kv_config)
             if len(set(m.cfg.layer_types)) > 1:
                 # a hybrid's layers by kind, those that keep nothing in
                 # the cache among them

@@ -505,6 +505,56 @@ def test_step_span_and_gauge_carry_the_state(cache_dir, telemetry_on,
     assert gauges["ssm_state_bytes{model=hy}"] == 3 * per_slot
 
 
+PREWARM = {
+    # case: (kernels interpreted?, ssm heads, chunk columns forced,
+    #        the event's state_update, its state_update_columns)
+    "gather": (False, 8, None, "gather", None),
+    "whole_slot": (True, 16, None, "pallas", 256),
+    "chunks": (True, 16, 128, "pallas", 128),
+}
+
+
+@pytest.mark.parametrize("case", sorted(PREWARM))
+def test_prewarm_event_says_what_a_transfer_moves(case, monkeypatch,
+                                                  cache_dir, telemetry_on,
+                                                  tmp_path):
+    """The ``serving_prewarm`` event of a model with state-space layers
+    names the state update's path and, where that is the kernel, the
+    columns of a slot one transfer moves: the whole slot, or the chunk a
+    slot too large for the VMEM asked falls back to; the served tokens are
+    those of the unpaged step either way."""
+    kernels, heads, columns, path, said = PREWARM[case]
+    cfg = CFG.replace(ssm_heads=heads)
+    params = gh.init_params(cfg, seed=3, std=0.3)
+    if kernels:
+        monkeypatch.setenv("PADDLE_PALLAS_INTERPRET", "1")
+    if columns:
+        _chunked(monkeypatch, cfg.ssm_state, columns)
+    adoption.reset()
+    try:
+        with _flags(telemetry_dir=str(tmp_path)):
+            e = _engine(cfg, params, 16, buckets="2")
+            try:
+                e.prewarm()
+                r = e.generate("hy", [5, 6, 7], max_new_tokens=5,
+                               deadline_ms=60000.0)
+            finally:
+                e.stop()
+            _tm.flush()
+    finally:
+        adoption.reset()
+    assert r.status == "ok"
+    assert np.array_equal(r.outputs["tokens"],
+                          _alone(cfg, params, [5, 6, 7], 5))
+    import json
+    with open(os.path.join(tmp_path, "steps.jsonl")) as fp:
+        warm = [ev for ev in map(json.loads, fp)
+                if ev["ev"] == "serving_prewarm"]
+    assert warm and all(ev["state_update"] == path
+                        and ev.get("state_update_columns") == said
+                        for ev in warm)
+
+
 # -- 4. the manager: layers by kind, bytes, budget -------------------------------
 
 def test_cache_describes_layers_by_kind():
@@ -700,17 +750,27 @@ def test_grouped_attention_is_attention_over_repeated_heads():
         np.asarray(pa.masked_attention(q, k, v, lens, 0.25)))
 
 
-@pytest.mark.parametrize("inner", [256, 128], ids=["two_chunks", "one"])
+def _chunked(monkeypatch, n, columns):
+    """Leave the kernel VMEM for four units of ``columns`` columns: a slot
+    wider than that moves in chunks."""
+    monkeypatch.setattr(su, "_UNIT_BUDGET", 4 * 4 * n * columns)
+
+
+@pytest.mark.parametrize("inner,columns", [(256, 128), (128, 128),
+                                           (256, None)],
+                         ids=["two_chunks", "one", "whole_slot"])
 def test_state_update_kernel_equals_the_gather(interpreted, monkeypatch,
-                                               inner):
+                                               inner, columns):
     """The state-update kernel under the interpreter against gather, update
     and scatter: the lanes' slots moved one token in place, the others
     untouched, a fresh lane started from zeros, bit for bit."""
-    monkeypatch.setattr(su, "COLUMNS", 128)
     r = np.random.default_rng(0)
     slots_n, n, lanes = 6, 16, 4
+    if columns:
+        _chunked(monkeypatch, n, columns)
     f = lambda *shape: jnp.asarray(r.standard_normal(shape), jnp.float32)
     pool = f(slots_n, n, inner)
+    assert su.transfer_columns(pool.shape) == (columns or inner)
     slots = jnp.asarray([3, 5, 1, 0], jnp.int32)
     fresh = jnp.asarray([False, True, False, True])
     decay = jnp.asarray(r.uniform(0.2, 1.0, (lanes, inner)), jnp.float32)
@@ -719,7 +779,7 @@ def test_state_update_kernel_equals_the_gather(interpreted, monkeypatch,
     assert all(ok for _r, ok in su.ssm_update_checks(pool.shape, pool.dtype,
                                                      lanes))
     want_pool, want_y = jax.jit(su.state_update_reference)(pool, *args)
-    got_pool, got_y = jax.jit(su.state_update)(pool, *args)
+    got_pool, got_y = jax.jit(lambda *a: su.state_update(*a))(pool, *args)
     assert adoption.active_kernels() == ["ssm_update"]
     assert np.array_equal(np.asarray(got_y), np.asarray(want_y))
     live = [1, 3, 5, 2, 4]                               # all but the scratch
@@ -727,6 +787,113 @@ def test_state_update_kernel_equals_the_gather(interpreted, monkeypatch,
                           np.asarray(want_pool)[live])
     assert np.array_equal(np.asarray(got_pool)[[2, 4]],
                           np.asarray(pool)[[2, 4]])
+
+
+# lanes of a step by the slots they name (0: an idle lane's scratch slot)
+# and whether they start; pool [slots, N, I]; the chunk to compare with
+WHOLE_SLOTS = {
+    # case: (slots_n, n, inner, groups, slots, fresh lanes, chunk columns)
+    "published_widths": (6, 128, 4096, 1, [3, 5, 1, 4], [1], 2048),
+    "small": (6, 16, 256, 1, [3, 5, 1, 4], [1], 128),
+    "slots_out_of_order": (10, 16, 256, 1, [9, 2, 7, 1, 8, 3, 6, 4], [],
+                           128),
+    "every_lane_fresh": (6, 16, 256, 1, [3, 5, 1, 4], [0, 1, 2, 3], 128),
+    "idle_lanes_name_slot_0": (8, 16, 256, 1, [0, 5, 0, 2, 0, 0, 7, 0],
+                               [3], 128),
+    "every_lane_idle": (4, 16, 128, 1, [0, 0, 0, 0], [], 128),
+    "one_lane": (3, 16, 256, 1, [2], [], 128),
+    # 10 units in batches of 4: the last batch is short
+    "short_last_batch": (14, 16, 128, 1,
+                         [13, 1, 12, 2, 11, 3, 10, 4, 9, 5], [2, 7],
+                         128),
+    # five batches: reads of the third go where the first was written from
+    "five_batches": (21, 8, 128, 1, list(range(20, 0, -1)), [5], 128),
+}
+
+
+@pytest.mark.parametrize("case", sorted(WHOLE_SLOTS))
+def test_state_update_kernel_moves_whole_slots(interpreted, monkeypatch,
+                                               case):
+    """A lane's slot as one transfer in and one out, batches of slots read
+    and written in turn: against gather, update and scatter on every slot a
+    live lane names, every other slot (but the scratch one) untouched, and
+    bit for bit the kernel that moves the same slots in column chunks."""
+    slots_n, n, inner, groups, slots, fresh, columns = WHOLE_SLOTS[case]
+    r = np.random.default_rng(len(case))
+    lanes = len(slots)
+    f = lambda *shape: jnp.asarray(r.standard_normal(shape), jnp.float32)
+    pool = f(slots_n, n, inner)
+    args = (jnp.asarray(slots, jnp.int32),
+            jnp.asarray([i in fresh for i in range(lanes)]),
+            jnp.asarray(r.uniform(0.2, 1.0, (lanes, inner)), jnp.float32),
+            f(lanes, inner), f(lanes, groups, n), f(lanes, groups, n))
+    assert su.transfer_columns(pool.shape, groups) == inner
+    assert su.update_path(pool.shape, pool.dtype, lanes, groups) == "pallas"
+    got_pool, got_y = jax.jit(lambda *a: su.state_update(*a))(pool, *args)
+    assert adoption.active_kernels() == ["ssm_update"]
+    want_pool, want_y = jax.jit(su.state_update_reference)(pool, *args)
+    live = [i for i, s in enumerate(slots) if s]
+    named = sorted(set(slots) - {0})
+    others = sorted(set(range(1, slots_n)) - set(named))
+    np.testing.assert_allclose(np.asarray(got_y)[live],
+                               np.asarray(want_y)[live], rtol=2e-6,
+                               atol=2e-6)
+    assert np.array_equal(np.asarray(got_pool)[named],
+                          np.asarray(want_pool)[named])
+    assert np.array_equal(np.asarray(got_pool)[others],
+                          np.asarray(pool)[others])
+    for i in fresh:
+        # started from zeros: the state is the token's own outer product
+        if slots[i]:
+            np.testing.assert_allclose(
+                np.asarray(got_pool)[slots[i]],
+                np.asarray(args[4])[i, 0][:, None]
+                * np.asarray(args[3])[i][None, :], rtol=1e-6, atol=1e-6)
+    # the same slots in chunks: the same arithmetic a column
+    _chunked(monkeypatch, n, columns)
+    assert su.transfer_columns(pool.shape, groups) == min(columns, inner)
+    chunk_pool, chunk_y = jax.jit(lambda *a: su.state_update(*a))(pool, *args)
+    assert np.array_equal(np.asarray(chunk_y)[live], np.asarray(got_y)[live])
+    assert np.array_equal(np.asarray(chunk_pool)[1:],
+                          np.asarray(got_pool)[1:])
+
+
+def test_a_slot_too_large_for_the_limit_moves_in_chunks(interpreted,
+                                                        monkeypatch,
+                                                        telemetry_on):
+    """Which form is taken follows from the slot's shape and the VMEM the
+    kernel asks for: ``transfer_columns`` says which, ``ssm_update_checks``
+    stays whole while some chunk fits and names ``vmem`` when none does
+    (the gather then, counted)."""
+    assert su._UNIT_BUDGET < su._VMEM_LIMIT
+    checks = lambda shape: dict(su.ssm_update_checks(shape, jnp.float32, 4))
+    # the published slot: whole, 4 of them a batch
+    assert su.transfer_columns((33, 128, 4096)) == 4096
+    assert 2 * su.BATCH * 4 * 128 * 4096 <= su._UNIT_BUDGET
+    # a slot of 16 MiB: quarters, chosen by nothing but the shape
+    assert su.transfer_columns((5, 1024, 4096)) == 1024
+    assert all(checks((5, 1024, 4096)).values())
+    # less VMEM: the same slot in narrower chunks, then not at all
+    _chunked(monkeypatch, 128, 512)
+    assert su.transfer_columns((33, 128, 4096)) == 512
+    assert all(checks((33, 128, 4096)).values())
+    monkeypatch.setattr(su, "_UNIT_BUDGET", 4 * 4 * 128 * 128 - 1)
+    assert su.transfer_columns((33, 128, 4096)) is None
+    assert not checks((33, 128, 4096))["vmem"]
+    assert su.update_path((33, 128, 4096), jnp.float32, 4) == "gather"
+    r = np.random.default_rng(1)
+    f = lambda *shape: jnp.asarray(r.standard_normal(shape), jnp.float32)
+    pool = f(4, 128, 256)
+    args = (jnp.asarray([1, 3], jnp.int32), jnp.asarray([False, True]),
+            jnp.abs(f(2, 256)), f(2, 256), f(2, 1, 128), f(2, 1, 128))
+    got = jax.jit(lambda *a: su.state_update(*a))(pool, *args)
+    want = jax.jit(su.state_update_reference)(pool, *args)
+    assert adoption.active_kernels() == []
+    assert [ls for _f, ls in _tm.label_sets(
+        "pallas_kernel_fallback_total")] == [
+            {"kernel": "ssm_update", "reason": "vmem"}]
+    for g, w in zip(got, want):
+        assert np.array_equal(np.asarray(g), np.asarray(w))
 
 
 def test_paged_step_with_both_kernels_equals_the_gather_step(interpreted):

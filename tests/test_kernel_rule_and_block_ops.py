@@ -154,6 +154,17 @@ RULE = {
     "ssm_update-groups_uneven": (
         "ssm_update", lambda: ssm_update.ssm_update_checks(
             (33, 128, 4096), "float32", 32, 3), True, "groups"),
+    # the state-update kernel's transfers (PR 44): a slot of which not even
+    # a 128-column chunk fits four times in what the kernel asks of VMEM
+    "ssm_update-vmem": (
+        "ssm_update", lambda: ssm_update.ssm_update_checks(
+            (3, 1 << 15, 4096), "float32", 2), True, "vmem"),
+    "ssm_update-vmem_groups": (
+        "ssm_update", lambda: ssm_update.ssm_update_checks(
+            (3, 1 << 15, 4096), "float32", 2, 8), True, "vmem"),
+    "ssm_update-empty": (
+        "ssm_update", lambda: ssm_update.ssm_update_checks(
+            (33, 128, 4096), "float32", 0), True, "empty"),
     "moe_experts-relu2_backend": (
         "moe_experts", lambda: moe_experts.relu2_checks(
             32, (16, 1856, 2688), "bfloat16"), False, "backend"),
@@ -205,6 +216,45 @@ def test_kernel_rule_names_the_fallback(monkeypatch, case):
     else:
         assert counted == [{"kernel": family, "reason": expected}]
         assert telemetry.counter_total("pallas_kernel_fallback_total") == 1
+
+
+TRANSFERS = {
+    # case: (pool [slots, N, I], groups, the columns one transfer moves)
+    "granite_whole_slot": ((33, 128, 4096), 1, 4096),
+    "nemotron_whole_slot_spans_8_groups": ((33, 128, 4096), 8, 4096),
+    "three_groups_of_1024": ((9, 128, 3072), 3, 3072),
+    # a slot of 4 MiB fits four times in 16 MiB, one of 8 MiB is halved
+    "slot_of_4_mib": ((5, 128, 8192), 1, 8192),
+    "slot_of_8_mib_in_halves": ((5, 256, 8192), 1, 4096),
+    "a_chunk_a_group": ((5, 256, 6144), 2, 3072),
+    # thirds (2048) would fit, but a chunk lies in one group or spans whole
+    # ones: quarters
+    "chunks_keep_to_groups": ((5, 512, 6144), 2, 1536),
+    "narrowest_chunk": ((3, 1 << 13, 256), 1, 128),
+    "nothing_fits": ((3, 1 << 15, 4096), 1, None),
+    "groups_of_64_columns": ((33, 128, 4096), 64, None),
+    "groups_uneven": ((33, 128, 4096), 3, None),
+}
+
+
+@pytest.mark.parametrize("case", sorted(TRANSFERS))
+def test_state_update_transfer_follows_the_slots_shape(monkeypatch, case):
+    """What one transfer of the state-update kernel moves is read from the
+    pool's shape and the VMEM the kernel asks for, by no flag: the whole
+    slot where two batches of two fit, else the widest chunk that keeps to
+    the groups; the family's ``vmem`` check is that some chunk exists."""
+    shape, groups, columns = TRANSFERS[case]
+    assert ssm_update.transfer_columns(shape, groups) == columns
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    checks = dict(ssm_update.ssm_update_checks(shape, "float32", 2, groups))
+    assert all(checks.values()) == (columns is not None)
+    assert ssm_update.update_path(shape, "float32", 2, groups) == (
+        "pallas" if columns else "gather")
+    if columns:
+        # at least two units a batch, two batches, inside the budget
+        unit = 4 * shape[1] * columns
+        assert 4 * unit <= ssm_update._UNIT_BUDGET \
+            < ssm_update._VMEM_LIMIT
 
 
 # names kept so that a Fluid script that sets them still runs; XLA/PJRT owns

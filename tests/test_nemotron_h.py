@@ -758,6 +758,7 @@ def test_step_span_counters_gauges_and_prewarm_event(cache_dir, telemetry_on,
     assert warm and all(
         ev["model"] == "nm" and ev["attention"] == "gather"
         and ev["experts"] == "einsum" and ev["state_update"] == "gather"
+        and "state_update_columns" not in ev
         and ev["layers"] == {"attention": 1, "experts": 2, "mamba": 3}
         for ev in warm)
 
@@ -820,18 +821,27 @@ def _state_args(rng, slots_n, n, inner, groups, lanes):
                   f(lanes, groups, n))
 
 
+def _chunked(monkeypatch, n, columns):
+    """Leave the kernel VMEM for four units of ``columns`` columns: a slot
+    wider than that moves in chunks."""
+    monkeypatch.setattr(su, "_UNIT_BUDGET", 4 * 4 * n * columns)
+
+
 @pytest.mark.parametrize("groups,columns", [(8, 512), (8, 128), (2, 512),
-                                            (4, 1024)])
+                                            (4, 1024), (8, None), (2, None),
+                                            (1, None), (8, 256)])
 def test_state_update_kernel_with_groups_equals_advance_by_group(
         interpreted, monkeypatch, groups, columns):
-    """The kernel with B and C by group (a grid step spanning four groups,
-    one, half of one) against ``advance`` applied a group at a time with
-    that group's pair alone, and against gather, update, scatter bit for
-    bit."""
-    monkeypatch.setattr(su, "COLUMNS", columns)
-    rng = np.random.default_rng(groups + columns)
+    """The kernel with B and C by group (a transfer spanning four groups,
+    one, half of one; the whole slot and every group, ``columns`` None; two
+    groups a chunk) against ``advance`` applied a group at a time with that
+    group's pair alone, and against gather, update, scatter bit for bit."""
+    if columns:
+        _chunked(monkeypatch, 16, columns)
+    rng = np.random.default_rng(groups + (columns or 0))
     pool, args = _state_args(rng, 6, 16, 1024, groups, 4)
     slots, fresh, decay, dx, b, c = args
+    assert su.transfer_columns(pool.shape, groups) == (columns or 1024)
     assert all(ok for _r, ok in su.ssm_update_checks(pool.shape, pool.dtype,
                                                      4, groups))
     # a jit of its own: a cached trace would decide nothing
@@ -854,12 +864,45 @@ def test_state_update_kernel_with_groups_equals_advance_by_group(
             np.asarray(state), rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("groups,n,inner,columns", [
+    (8, 128, 4096, 2048), (2, 128, 4096, 512), (8, 16, 1024, 128),
+    (4, 8, 512, 256)], ids=["nemotron_h", "two_groups", "small", "narrow"])
+def test_a_whole_slot_spans_every_group(interpreted, monkeypatch, groups, n,
+                                        inner, columns):
+    """One transfer holds all the groups' columns (Nemotron-H's eight of
+    512): against gather, update and scatter, and bit for bit the same
+    slots moved in chunks of ``columns`` (PR 41's grid step held 2048),
+    with lanes out of order, a fresh one and two idle ones on slot 0."""
+    rng = np.random.default_rng(groups + n)
+    pool, args = _state_args(rng, 8, n, inner, groups, 6)
+    slots = jnp.asarray([5, 0, 7, 2, 0, 3], jnp.int32)
+    args = (slots,) + args[1:]
+    assert su.transfer_columns(pool.shape, groups) == inner
+    got_pool, got_y = jax.jit(lambda *a: su.state_update(*a))(pool, *args)
+    assert adoption.active_kernels() == ["ssm_update"]
+    want_pool, want_y = jax.jit(su.state_update_reference)(pool, *args)
+    live, named = [0, 2, 3, 5], [2, 3, 5, 7]
+    np.testing.assert_allclose(np.asarray(got_y)[live],
+                               np.asarray(want_y)[live], rtol=2e-6,
+                               atol=2e-6)
+    assert np.array_equal(np.asarray(got_pool)[named],
+                          np.asarray(want_pool)[named])
+    assert np.array_equal(np.asarray(got_pool)[[1, 4, 6]],
+                          np.asarray(pool)[[1, 4, 6]])
+    _chunked(monkeypatch, n, columns)
+    assert su.transfer_columns(pool.shape, groups) == columns
+    chunk_pool, chunk_y = jax.jit(lambda *a: su.state_update(*a))(pool, *args)
+    assert np.array_equal(np.asarray(chunk_y)[live], np.asarray(got_y)[live])
+    assert np.array_equal(np.asarray(chunk_pool)[1:],
+                          np.asarray(got_pool)[1:])
+
+
 def test_one_group_is_bit_identical_to_the_update_as_it_was(interpreted,
                                                             monkeypatch):
     """Granite's G = 1 through the grouped ``advance`` and the kernel: the
     bits of the one-group expressions they replaced (``decay * S + outer(b,
     dx)``, ``c . S`` with ``b``, ``c`` [B, N])."""
-    monkeypatch.setattr(su, "COLUMNS", 128)
+    _chunked(monkeypatch, 16, 128)
     rng = np.random.default_rng(0)
     pool, args = _state_args(rng, 6, 16, 256, 1, 4)
     slots, fresh, decay, dx, b, c = args
@@ -886,7 +929,11 @@ def test_groups_the_kernel_cannot_tile_fall_back_counted(interpreted):
     assert all(checks(8).values()) and all(checks(1).values())
     assert not checks(3)["groups"]                 # 4096 / 3
     assert not checks(64)["groups"]                # 64 columns a group
-    assert not checks(8, 3072)["groups"]           # 384 against 2048
+    assert not checks(16, 3072)["groups"]          # 192 columns a group
+    # 384 columns a group tiled no 2048-column grid step (PR 41); a whole
+    # slot spans all eight
+    assert all(checks(8, 3072).values())
+    assert su.transfer_columns((33, 128, 3072), 8) == 3072
     assert su.update_path((33, 128, 4096), jnp.float32, 32, 8) == "pallas"
     assert su.update_path((33, 128, 4096), jnp.float32, 32, 64) == "gather"
 

@@ -263,6 +263,7 @@ def test_granite_hybrid_step_updates_both_pools_in_place(one_chip, as_on_tpu):
     form of the update made a whole-pool pass a layer and 0.15e9 bytes of
     temporaries)."""
     from benchmark.models import granite_hybrid_decoder
+    from paddle_tpu.pallas_kernels import ssm_update as ssm
 
     with open(os.path.join(ROOT, "benchmark", "configs",
                            "granite-4.0-h-micro-serve.json")) as fp:
@@ -297,6 +298,14 @@ def test_granite_hybrid_step_updates_both_pools_in_place(one_chip, as_on_tpu):
     assert _kernel_calls(text) == 6            # 1 attention, 5 state updates
     assert _expert_kernels(text) == 0          # no routed layer, no such call
     assert len(re.findall(r"%ssm_state_update\S* = ", text)) == 5
+    # a lane's slot [128, 4096] is one transfer, 4 of them a batch, two
+    # batches inside the VMEM the kernel asks for; b and c go in as they
+    # are, not a value a lane repeated 128 times
+    assert dm.state_update_path(cfg, kv, lanes) == "pallas"
+    assert ssm.transfer_columns((lanes + 1, 128, 4096)) == 4096
+    assert 2 * ssm.BATCH * 128 * 4096 * 4 <= ssm._UNIT_BUDGET \
+        < ssm._VMEM_LIMIT
+    assert not re.search(r"f32\[32,128,128\]", text)
     pool_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in carry)
     state_pool = (lanes + 1) * 128 * 4096 * 4
     assert pool_bytes == 2 * 2048 * 16 * 512 * 2 + 5 * (
@@ -457,7 +466,9 @@ def test_nemotron_h_step_compiles_its_three_kernels_at_published_shapes(
     blocks 256 wide for 2 KV heads of 128, 33 state slots of [128, 4096]):
     Mosaic accepts, inside the whole step as the engine compiles it
     (``make_packed_step``), the state-update kernel with B and C in 8
-    groups (a 2048-column grid step spans 4), the two-matrix expert kernel
+    groups (a lane's whole slot one transfer, spanning all 8, batches of 4
+    slots in the 24 MiB of VMEM the kernel asks for), the two-matrix expert
+    kernel
     over 16 held experts ``[16, 1856, 2688]`` cut on the second-minor axis
     (1856 is no multiple of 128), and the paged-attention kernel for 32
     query heads over 2 (groups of 16, compact); the experts are read as
@@ -486,7 +497,11 @@ def test_nemotron_h_step_compiles_its_three_kernels_at_published_shapes(
     assert pa._compact(32, 2, 128)
     assert dm.attention_path(cfg, kv, lanes) == "pallas"
     assert dm.state_update_path(cfg, kv, lanes) == "pallas"
-    assert ssm._groups_tile(4096, 8) and ssm.COLUMNS // (4096 // 8) == 4
+    # a lane's whole slot is one transfer: it spans all 8 groups of 512
+    assert ssm.transfer_columns(
+        (kv.state_slots,) + kv.state_shapes[1][0], 8) == 4096 == 8 * 512
+    assert 2 * ssm.BATCH * 128 * 4096 * 4 <= ssm._UNIT_BUDGET \
+        < ssm._VMEM_LIMIT
     assert moe.experts_path(lanes, (16, 1856, 2688), jnp.bfloat16,
                             matrices=2) == "pallas"
     assert moe.experts_path(lanes, (16, 2688, 1856), jnp.bfloat16) == "einsum"
@@ -519,9 +534,11 @@ def test_nemotron_h_step_compiles_its_three_kernels_at_published_shapes(
         state_pool + 33 * 3 * 6144 * 2)
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= pool_bytes
-    # b and c cross into the kernel [32, 128, 128] float32 each (2.1e6 B),
-    # not spread over the groups' columns (16.8e6 B each)
+    # b and c cross into the kernel as they are, [32, 8, 128] float32 each
+    # (131e3 B): not a value a lane repeated 128 times (2.1e6 B each, until
+    # PR 44), nor spread over the groups' columns (16.8e6 B each)
     assert memory.temp_size_in_bytes < state_pool / 4
+    assert not re.search(r"f32\[32,128,128\]", text)
     big = re.compile(
         r" = f32\[(33|32),128,(4096|2048|1024)\]\S* "
         r"(copy|select|transpose|slice|dynamic-slice|gather|scatter|fusion)\(")

@@ -23,7 +23,11 @@ For a configuration with state-space layers (``--config
 granite-4.0-h-micro-serve --blocks 2048``) every lane holds a state slot,
 the scopes gain ``layerN/ssm/in_proj``, ``conv``, ``state_update`` and
 ``out_proj``, and the result gives ``ssm/state_update``'s achieved bytes/s
-(``benchmark/ssm_cost.py``'s state traffic over the scope's device time);
+(``benchmark/ssm_cost.py``'s state traffic over the scope's device time),
+beside it the ``ssm_state_update`` kernels' own ms a step and bytes/s over
+the same bytes (what ``ssm_update_roofline_share.serve`` divides, so the two
+can be laid side by side) and ``state_update_columns``, the columns of a
+slot one transfer of the kernel moves;
 ``--ssm-update xla`` swaps the kernel for the gather, update and scatter the
 step makes of it off the TPU: the comparison the kernel was adopted by.
 For ``--config lfm2-24b-a2b-serve --blocks 2048`` every lane holds a window
@@ -245,6 +249,7 @@ def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
         ssm_cost, trace_reduce
     from paddle_tpu.core import telemetry
     from paddle_tpu.core.executor import CarriedStepFn
+    from paddle_tpu.pallas_kernels import ssm_update
     from paddle_tpu.serving import decode_model as dm
     from paddle_tpu.serving import kv_cache as kvc
 
@@ -279,6 +284,11 @@ def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
                    "compile_ms": round(warm["compile_ms"], 1),
                    "pool_sized_instructions": pool_sized(index, pool_elems)},
         "attention": dm.attention_path(cfg, kv, b),
+        # the columns of a slot one transfer of the state-update kernel
+        # moves (the engine's ``serving_prewarm`` says the same)
+        "state_update_columns": dm.state_update_columns(cfg, kv)
+        if args.ssm_update == "step"
+        and dm.state_update_path(cfg, kv, b) == "pallas" else None,
         "window_attention": dm.attention_path(cfg, kv, b, "window")
         if cfg.window_layers else None,
         "pallas_kernel_counters": {
@@ -362,6 +372,16 @@ def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
         moved = cost.state_traffic_bytes_per_step(config, b)
         result["ssm_state_bytes_per_step"] = moved
         result["ssm_state_update_bytes_per_s"] = moved / (ssm_ms / 1e3)
+        # the kernel's executions alone, by the name they carry in the
+        # trace: what ``ssm_update_roofline_share.serve`` divides (the
+        # scope above also holds the fusions that make its operands)
+        kernel_s = sum(s for name, s in prof["op_seconds"].items()
+                       if name.lstrip("%").startswith(ssm_update.KERNEL_NAME))
+        if kernel_s:
+            result["ssm_state_update_kernel_ms_per_step"] = \
+                kernel_s * 1e3 / args.steps
+            result["ssm_state_update_kernel_bytes_per_s"] = \
+                moved / (kernel_s / args.steps)
     stats = device.memory_stats() or {}
     result["peak_bytes_in_use"] = stats.get("peak_bytes_in_use")
     result["peak_bytes_reserved"] = stats.get("peak_bytes_reserved")
