@@ -1,0 +1,37 @@
+"""What a decoder family declares of itself.
+
+A family the decode steps serve is ONE module, ``models/<arch>.py``, with
+``token_logits`` (its block, under the contract ``serving/decode_model.py``
+``_block`` states), ``init_params(cfg, seed)`` and ``FAMILY``, a
+``DecoderFamily``: plain data, read by ``DecoderConfig`` to refuse what the
+block does not compute and by the step makers to find what it routes.  To
+add a family: write that module, name it in ``decode_model.ARCHS``, and give
+it a row in ``tests/decoder_families.py``; nothing else in ``serving/``
+knows a family by name.
+"""
+
+import collections
+
+__all__ = ["DecoderFamily"]
+
+
+class DecoderFamily(collections.namedtuple(
+        "DecoderFamily",
+        ("kinds", "dtypes", "grouped_query", "routes", "expert_matrices",
+         "dense_lead", "holds_share", "own_stream_width"),
+        defaults=(("f32", "bf16"), False, None, None, False, False, False))):
+    """``kinds``: the kinds of layer the block computes
+    (``decode_model.LAYER_KINDS``).  ``dtypes``: the weight dtypes it is
+    served in.  ``grouped_query``: its attention may have fewer KV heads than
+    query heads.  ``routes``: where its feed-forward is routed experts: None
+    (nowhere), ``"after_dense"`` (every layer after ``cfg.dense_layers``) or
+    ``"experts_layers"`` (the layers of kind ``experts``).
+    ``expert_matrices``: how many matrices an expert has, 3 (``wgate``,
+    ``wup``, ``wdown``), 2 (``experts_up``, ``experts_down``) or None.
+    ``dense_lead``: its first ``cfg.dense_layers`` layers may end in a gated
+    MLP.  ``holds_share``: it may hold a share of each routed layer's experts
+    (``cfg.experts_held`` from ``cfg.expert_first`` on).
+    ``own_stream_width``: its stream (``cfg.hidden_size``) may be narrower
+    than its query heads together."""
+
+    __slots__ = ()

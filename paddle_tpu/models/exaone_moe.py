@@ -69,10 +69,16 @@ import numpy as np
 
 from ..pallas_kernels import moe_experts as _moe
 from . import lfm2_moe as _lfm2
+from .decoder_family import DecoderFamily
 from .olmoe import NP_DTYPES, _mm, _rmsnorm, _rope
 
 __all__ = ["token_logits", "param_shapes", "init_params", "routed_part",
-           "shared_part"]
+           "shared_part", "FAMILY"]
+
+FAMILY = DecoderFamily(kinds=("attention", "window"), grouped_query=True,
+                       routes="after_dense", expert_matrices=3,
+                       dense_lead=True, holds_share=True,
+                       own_stream_width=True)
 
 # the least the renormalised gates' denominator can be (the DeepSeek-V3
 # router adds it to the sum of the chosen scores)

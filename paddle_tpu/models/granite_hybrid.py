@@ -58,10 +58,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from .decoder_family import DecoderFamily
 from .olmoe import NP_DTYPES, _mm, _rmsnorm
 
 __all__ = ["token_logits", "param_shapes", "init_params", "mamba_mixer",
-           "mamba_param_shapes", "draw"]
+           "mamba_param_shapes", "draw", "FAMILY"]
+
+FAMILY = DecoderFamily(kinds=("attention", "mamba"), grouped_query=True)
 
 
 def param_shapes(cfg):

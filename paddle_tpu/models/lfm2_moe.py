@@ -69,9 +69,14 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..pallas_kernels import moe_experts as _moe
+from .decoder_family import DecoderFamily
 from .olmoe import NP_DTYPES, _mm, _rmsnorm, _rope
 
-__all__ = ["token_logits", "param_shapes", "init_params"]
+__all__ = ["token_logits", "param_shapes", "init_params", "FAMILY"]
+
+FAMILY = DecoderFamily(kinds=("attention", "conv"), grouped_query=True,
+                       routes="after_dense", expert_matrices=3,
+                       dense_lead=True)
 
 # the least the renormalised gates' denominator can be (the family's
 # modelling code adds it to the sum of the chosen scores)

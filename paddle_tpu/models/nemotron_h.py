@@ -72,10 +72,16 @@ from ..pallas_kernels import moe_experts as _moe
 from . import granite_hybrid as _granite
 from . import lfm2_moe as _lfm2
 from .exaone_moe import GATE_EPS
+from .decoder_family import DecoderFamily
 from .olmoe import NP_DTYPES, _mm, _rmsnorm
 
 __all__ = ["token_logits", "param_shapes", "init_params", "routed_part",
-           "shared_part", "BIAS_STD"]
+           "shared_part", "BIAS_STD", "FAMILY"]
+
+FAMILY = DecoderFamily(kinds=("attention", "mamba", "experts"),
+                       grouped_query=True, routes="experts_layers",
+                       expert_matrices=2, holds_share=True,
+                       own_stream_width=True)
 
 # standard deviation of a seeded ``expert_bias``.  This stream is pre-norm:
 # the router sees rmsnorm(x), entries of root-mean-square 1, so its logits

@@ -47,8 +47,13 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..pallas_kernels import moe_experts as _moe
+from .decoder_family import DecoderFamily
 
-__all__ = ["token_logits", "param_shapes", "init_params", "NP_DTYPES"]
+__all__ = ["token_logits", "param_shapes", "init_params", "NP_DTYPES",
+           "FAMILY"]
+
+FAMILY = DecoderFamily(kinds=("attention",), routes="after_dense",
+                       expert_matrices=3)
 
 NP_DTYPES = {"f32": np.dtype(np.float32), "bf16": np.dtype(jnp.bfloat16)}
 

@@ -17,13 +17,15 @@ that reads each lane's live KV blocks from the pool where they lie, and
 elsewhere (the CPU tier, the int8 residency) a gather of the padded table
 into ``masked_attention``, chosen by what the shapes and the backend are.
 
-The decoder is one of six blocks, picked by ``DecoderConfig.arch``: the
-``gpt2`` block of this file, in float32; the routed-expert ``olmoe`` block
-of ``models/olmoe.py``, in bfloat16 with a bfloat16 cache; the
-``granite_hybrid`` block of ``models/granite_hybrid.py``; the ``lfm2_moe``
-block of ``models/lfm2_moe.py``; the ``exaone_moe`` block of
-``models/exaone_moe.py``; and the ``nemotron_h`` block of
-``models/nemotron_h.py``.  Layers are of five kinds (``LAYER_KINDS``):
+The decoder's block is its family's, picked by ``DecoderConfig.arch``: a
+family is one module, ``models/<arch>.py`` (``ARCHS`` lists them), which
+holds the block (``token_logits``), seeded weights (``init_params``) and a
+declaration of what the block computes (``FAMILY``, a
+``models/decoder_family.py`` ``DecoderFamily``: its kinds of layer, its
+weight dtypes, whether and where it routes, ...).  This file names no
+family beside that list: ``DecoderConfig`` refuses by the declaration, and
+adding a family is its module, its name in ``ARCHS`` and its row in
+``tests/decoder_families.py``.  Layers are of five kinds (``LAYER_KINDS``):
 ``attention`` (multi-head, or grouped-query with fewer KV heads than query
 heads, so pools ``kv_heads * head_dim`` wide), which keeps K and V a token;
 ``window``, attention over the last ``cfg.window`` positions only, which
@@ -34,10 +36,10 @@ short convolution, which keeps a window and no state; and ``experts``, a
 layer that is a feed-forward alone (routed experts beside a shared one),
 which keeps nothing: the cache manager gives it neither pool nor slot.  A
 hybrid block names its layers' kinds one by one, two or three of them in
-one model (``ARCH_LAYER_KINDS``).  Every step
-builder below serves all six through one contract (``_block``), so there
-is one paged step, one multi-token step, one draft rollout and one unpaged
-reference, whatever the block.
+one model (its ``FAMILY.kinds``).  Every step builder below serves every
+family through one contract (``_block``), so there is one paged step, one
+multi-token step, one draft rollout and one unpaged reference, whatever the
+block.
 
 Two step builders share every layer of math through two callbacks, which
 own what a layer keeps between tokens: ``attend`` the K and V of an
@@ -93,34 +95,30 @@ __all__ = ["DecoderConfig", "init_decoder_params", "save_decoder",
            "cache_config"]
 
 
-# architecture -> the kinds of layer its block computes (a block other than
-# gpt2's is ``models/<arch>.py``)
-ARCH_LAYER_KINDS = {"gpt2": ("attention",), "olmoe": ("attention",),
-                    "granite_hybrid": ("attention", "mamba"),
-                    "lfm2_moe": ("attention", "conv"),
-                    "exaone_moe": ("attention", "window"),
-                    "nemotron_h": ("attention", "mamba", "experts")}
-ARCHS = tuple(ARCH_LAYER_KINDS)
+# the families, a module each: ``models/<arch>.py``
+ARCHS = ("gpt2", "olmoe", "granite_hybrid", "lfm2_moe", "exaone_moe",
+         "nemotron_h")
 LAYER_KINDS = ("attention", "mamba", "conv", "window", "experts")
 # recurrent kind -> the name its slot goes by in spans, gauges and counters
 # (``ssm_state_lanes``, ``conv_state_bytes{model}``, ...)
 STATE_NAMES = {"mamba": "ssm_state", "conv": "conv_state"}
-# the blocks whose attention may have fewer KV heads than query heads, and
-# those whose feed-forward is routed experts
-_GROUPED_QUERY = ("granite_hybrid", "lfm2_moe", "exaone_moe", "nemotron_h")
-_ROUTED = ("olmoe", "lfm2_moe", "exaone_moe", "nemotron_h")
-# the blocks whose first ``dense_layers`` layers end in a gated MLP
-_DENSE_LEAD = ("lfm2_moe", "exaone_moe")
-# the blocks that may hold a share of each routed layer's experts (their
-# router scores every expert, the weights are the held ones'), and those
-# whose stream may be narrower than their query heads together
-_HOLDS_SHARE = ("exaone_moe", "nemotron_h")
-_OWN_STREAM_WIDTH = ("exaone_moe", "nemotron_h")
+
+
+def _model(arch):
+    """A family's module, ``models/<arch>.py``: its block
+    (``token_logits``), ``init_params`` and its declaration (``FAMILY``)."""
+    return importlib.import_module("..models." + arch, __package__)
+
+
+def _declaring(field):
+    """The families whose declaration says ``field``, as a refusal names
+    them."""
+    return "|".join(a for a in ARCHS if getattr(_model(a).FAMILY, field))
 
 
 class DecoderConfig:
     """What a decode step is built from.  ``arch`` picks the block:
-    ``gpt2`` is the pre-LN MHA + GELU block of this file (learned
+    ``gpt2`` is the pre-LN MHA + GELU block of ``models/gpt2.py`` (learned
     positions), ``olmoe`` the routed-expert block of ``models/olmoe.py``
     (RMSNorm, Q/K norm, RoPE, ``experts`` SiLU-gated experts of width
     ``ffn``, ``experts_per_token`` of them a token), ``granite_hybrid`` the
@@ -193,9 +191,11 @@ class DecoderConfig:
                              % ("|".join(ARCHS), arch))
         if dtype not in ("f32", "bf16"):
             raise ValueError("decoder dtype must be f32|bf16: %r" % (dtype,))
-        if arch == "gpt2" and dtype != "f32":
-            raise ValueError("the gpt2 block is served in f32")
-        if arch in _ROUTED \
+        family = _model(arch).FAMILY
+        if dtype not in family.dtypes:
+            raise ValueError("the %s block is served in %s"
+                             % (arch, "|".join(family.dtypes)))
+        if family.routes \
                 and not 0 < int(experts_per_token) <= int(experts):
             raise ValueError("%s wants 0 < experts_per_token <= experts, "
                              "got %r of %r"
@@ -238,10 +238,10 @@ class DecoderConfig:
         self.shared_ffn = int(shared_ffn)
         self.hidden_size = None if hidden_size is None else int(hidden_size)
         if self.hidden_size not in (None, self.heads * self.head_dim) \
-                and arch not in _OWN_STREAM_WIDTH:
+                and not family.own_stream_width:
             raise ValueError("the %s block's stream is heads * head_dim "
                              "wide (%s may say otherwise)"
-                             % (arch, "|".join(_OWN_STREAM_WIDTH)))
+                             % (arch, _declaring("own_stream_width")))
         if len(self.layer_types) != self.layers or any(
                 k not in LAYER_KINDS for k in self.layer_types):
             raise ValueError("layer_types must name each of the %d layers "
@@ -250,11 +250,10 @@ class DecoderConfig:
         if self.heads % self.kv_heads:
             raise ValueError("heads %d must be a multiple of kv_heads %d"
                              % (self.heads, self.kv_heads))
-        if any(k not in ARCH_LAYER_KINDS[arch] for k in self.layer_types):
+        if any(k not in family.kinds for k in self.layer_types):
             raise ValueError("layer_types: the %s block's layers are %s: %r"
-                             % (arch, "|".join(ARCH_LAYER_KINDS[arch]),
-                                layer_types))
-        if arch not in _GROUPED_QUERY and self.kv_heads != self.heads:
+                             % (arch, "|".join(family.kinds), layer_types))
+        if not family.grouped_query and self.kv_heads != self.heads:
             raise ValueError("the %s block is multi-head attention in every "
                              "layer" % arch)
         if self.ssm_layers and min(self.ssm_heads, self.ssm_head_dim,
@@ -268,30 +267,31 @@ class DecoderConfig:
         if self.conv_layers and self.conv_taps < 2:
             raise ValueError("conv layers want conv_taps >= 2")
         if not 0 <= self.dense_layers <= self.layers or (
-                self.dense_layers and (arch not in _DENSE_LEAD
+                self.dense_layers and (not family.dense_lead
                                        or self.dense_ffn < 1)):
             raise ValueError(
                 "dense_layers leads the %s blocks' %d layers with a "
                 "gated MLP of width dense_ffn >= 1: %r of width %r"
-                % ("|".join(_DENSE_LEAD), self.layers, dense_layers,
+                % (_declaring("dense_lead"), self.layers, dense_layers,
                    dense_ffn))
         if self.window_layers and self.window < 1:
             raise ValueError("window layers want window >= 1")
         if not 0 <= self.expert_first \
                 <= self.experts - self.experts_held or (
                     self.experts_held != self.experts
-                    and arch not in _HOLDS_SHARE):
+                    and not family.holds_share):
             raise ValueError(
                 "the %s blocks may hold experts [expert_first, "
                 "expert_first + experts_held) of %d: %r from %r"
-                % ("|".join(_HOLDS_SHARE), self.experts, experts_held,
+                % (_declaring("holds_share"), self.experts, experts_held,
                    expert_first))
 
     @property
     def hidden(self):
         """The residual stream's width: ``heads * head_dim`` unless the
-        model says otherwise (``hidden_size``: the exaone_moe and nemotron_h
-        blocks project a narrower stream up to their query heads)."""
+        model says otherwise (``hidden_size``: a family that declares
+        ``own_stream_width`` projects a narrower stream up to its query
+        heads)."""
         return self.hidden_size or self.heads * self.head_dim
 
     def _of_kind(self, kind):
@@ -340,11 +340,10 @@ class DecoderConfig:
         order: the rows of the step's ``routed`` counts.  A block that names
         ``experts`` layers routes in those; any other routed block in every
         layer after its ``dense_layers``."""
-        if self.arch not in _ROUTED:
-            return ()
-        if "experts" in ARCH_LAYER_KINDS[self.arch]:
+        routes = _model(self.arch).FAMILY.routes
+        if routes == "experts_layers":
             return self._of_kind("experts")
-        return tuple(range(self.dense_layers, self.layers))
+        return tuple(range(self.dense_layers, self.layers)) if routes else ()
 
     @property
     def held_experts(self):
@@ -413,30 +412,8 @@ def _state_shapes(cfg):
 
 def init_decoder_params(cfg, seed=0):
     """name -> np array in the config's weight dtype; 0.02-normal
-    weights, identity norms."""
-    if cfg.arch != "gpt2":
-        return _model(cfg).init_params(cfg, seed)
-    r = np.random.RandomState(seed)
-    h, f, v = cfg.hidden, cfg.ffn, cfg.vocab
-
-    def w(*shape):
-        return (r.standard_normal(shape) * 0.02).astype(np.float32)
-
-    p = {"embed": w(v, h), "pos_embed": w(cfg.max_seq, h),
-         "lnf_g": np.ones(h, np.float32), "lnf_b": np.zeros(h, np.float32),
-         "head": w(h, v)}
-    for l in range(cfg.layers):
-        p.update({
-            "l%d_ln1_g" % l: np.ones(h, np.float32),
-            "l%d_ln1_b" % l: np.zeros(h, np.float32),
-            "l%d_wq" % l: w(h, h), "l%d_wk" % l: w(h, h),
-            "l%d_wv" % l: w(h, h), "l%d_wo" % l: w(h, h),
-            "l%d_ln2_g" % l: np.ones(h, np.float32),
-            "l%d_ln2_b" % l: np.zeros(h, np.float32),
-            "l%d_w1" % l: w(h, f), "l%d_b1" % l: np.zeros(f, np.float32),
-            "l%d_w2" % l: w(f, h), "l%d_b2" % l: np.zeros(h, np.float32),
-        })
-    return p
+    weights, identity norms (the family's ``init_params``)."""
+    return _model(cfg.arch).init_params(cfg, seed)
 
 
 _BF16 = np.dtype(jnp.bfloat16)
@@ -512,18 +489,6 @@ def truncate_decoder(cfg, params, layers=1):
 
 # -- shared forward ----------------------------------------------------------
 
-def _ln(x, g, b):
-    m = jnp.mean(x, axis=-1, keepdims=True)
-    var = jnp.mean(jnp.square(x - m), axis=-1, keepdims=True)
-    return (x - m) * jax.lax.rsqrt(var + 1e-5) * g + b
-
-
-def _model(cfg):
-    """The module of an architecture that has one of its own:
-    ``models/<arch>.py``."""
-    return importlib.import_module("..models." + cfg.arch, __package__)
-
-
 def _block(cfg):
     """The architecture's block: ``block(params, cfg, tok, pos, attend,
     live, recur) -> (logits [B, vocab], extras)``.  Two callbacks own what
@@ -537,41 +502,8 @@ def _block(cfg):
     marks the lanes that hold a sequence; ``extras`` is a tuple of small
     arrays the step returns after its logits (a routed block's tokens sent
     to each expert, a row a layer of ``cfg.routed_layers``; nothing for the
-    others).  The six blocks: ``_token_logits`` here (gpt2), and
-    ``token_logits`` of ``models/olmoe.py``, ``models/granite_hybrid.py``,
-    ``models/lfm2_moe.py``, ``models/exaone_moe.py`` and
-    ``models/nemotron_h.py``."""
-    return _token_logits if cfg.arch == "gpt2" else _model(cfg).token_logits
-
-
-def _token_logits(params, cfg, tok, pos, attend, live=None, recur=None):
-    """The gpt2 block: one token per lane through every layer.  The
-    ``jax.named_scope`` names (``layer<i>/attn``, ``.../kv_write``,
-    ``.../kv_read`` or ``.../kv_gather``, ``layer<i>/mlp``, ``lm_head``)
-    are metadata: they reach each HLO instruction's ``op_name``, so a
-    device trace can be grouped by them, and change nothing computed."""
-    bb = tok.shape[0]
-    x = jnp.take(params["embed"], tok, axis=0) \
-        + jnp.take(params["pos_embed"], pos, axis=0)
-    for l in range(cfg.layers):
-        def p(n, _l=l):
-            return params["l%d_%s" % (_l, n)]
-
-        with jax.named_scope("layer%d" % l):
-            with jax.named_scope("attn"):
-                h = _ln(x, p("ln1_g"), p("ln1_b"))
-                q = (h @ p("wq")).reshape(bb, cfg.heads, cfg.head_dim)
-                k = (h @ p("wk")).reshape(bb, cfg.heads, cfg.head_dim)
-                v = (h @ p("wv")).reshape(bb, cfg.heads, cfg.head_dim)
-                a = attend(l, q, k, v).reshape(bb, cfg.hidden)
-                x = x + a @ p("wo")
-            with jax.named_scope("mlp"):
-                h2 = _ln(x, p("ln2_g"), p("ln2_b"))
-                x = x + jax.nn.gelu(h2 @ p("w1") + p("b1")) @ p("w2") \
-                    + p("b2")
-    with jax.named_scope("lm_head"):
-        x = _ln(x, params["lnf_g"], params["lnf_b"])
-        return x @ params["head"], ()
+    others).  Every family's block is ``token_logits`` of its module."""
+    return _model(cfg.arch).token_logits
 
 
 # -- paged step --------------------------------------------------------------
@@ -612,13 +544,11 @@ def experts_path(cfg, params, lanes=1):
     streamed; None for a model with no routed layer."""
     if not cfg.routed_layers:
         return None
-    first = "l%d_" % cfg.routed_layers[0]
-    if first + "wgate" in params:
-        wgate = params[first + "wgate"]
-        return _moe.experts_path(lanes, wgate.shape, wgate.dtype)
-    # the two-matrix form (``up`` and ``down`` both [E, F, H])
-    up = params[first + "experts_up"]
-    return _moe.experts_path(lanes, up.shape, up.dtype, matrices=2)
+    # three matrices with a gate, or two (``up`` and ``down`` both [E, F, H])
+    matrices = _model(cfg.arch).FAMILY.expert_matrices
+    w = params["l%d_%s" % (cfg.routed_layers[0],
+                           "wgate" if matrices == 3 else "experts_up")]
+    return _moe.experts_path(lanes, w.shape, w.dtype, matrices)
 
 
 def state_update_path(cfg, kv_config, lanes=1):

@@ -68,6 +68,44 @@ def _no_spans_left_for_the_next_test():
             tracing._unwritten[:] = []
 
 
+@pytest.fixture(scope="module")
+def cache_dir(tmp_path_factory):
+    """The executor's tier-B compile cache in a directory of this test
+    module's own."""
+    import paddle_tpu as fluid
+
+    d = str(tmp_path_factory.mktemp("cc"))
+    old = fluid.get_flags(["FLAGS_compile_cache_dir"])
+    fluid.set_flags({"FLAGS_compile_cache_dir": d})
+    yield d
+    fluid.set_flags(old)
+
+
+@pytest.fixture()
+def telemetry_on():
+    """Counters and gauges on, and empty, for one test."""
+    import paddle_tpu as fluid
+    from paddle_tpu.core import telemetry
+
+    fluid.set_flags({"FLAGS_telemetry": True})
+    telemetry.reset()
+    yield
+    telemetry.reset()
+    fluid.set_flags({"FLAGS_telemetry": False})
+
+
+@pytest.fixture()
+def interpreted(monkeypatch):
+    """Pallas kernels run on the CPU, under the interpreter, and what they
+    adopt is counted anew."""
+    from paddle_tpu.pallas_kernels import adoption
+
+    monkeypatch.setenv("PADDLE_PALLAS_INTERPRET", "1")
+    adoption.reset()
+    yield
+    adoption.reset()
+
+
 def pytest_collection_modifyitems(config, items):
     if TPU_TIER and _have_accelerator():
         # inverse guard: the default-tier tests need the 8-device CPU mesh

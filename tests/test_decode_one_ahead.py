@@ -10,7 +10,6 @@ the discard counter; the drain rule (``export_session`` mid-stream); one
 executable an engine step and no compile after prewarm; the synchronous path
 a speculating model keeps; the span's ``ahead`` and ``gap_us``."""
 
-import contextlib
 import glob
 import json
 import time
@@ -18,32 +17,22 @@ import time
 import numpy as np
 import pytest
 
+import decoder_families as fam
 import paddle_tpu as fluid
 from paddle_tpu.core import telemetry as _tm
 from paddle_tpu.core import tracing as _trc
-from paddle_tpu.models import granite_hybrid as gh
-from paddle_tpu.models import olmoe
 from paddle_tpu.serving import DecodeEngine
 from paddle_tpu.serving import decode_model as dm
 from paddle_tpu.serving import engine as engine_mod
 from paddle_tpu.utils import fault_injection
 
-BS = 4
-GPT2 = dm.DecoderConfig(vocab=31, layers=2, heads=2, head_dim=8, max_seq=48)
-OLMOE = dm.DecoderConfig(arch="olmoe", vocab=97, layers=2, heads=4,
-                         head_dim=16, ffn=32, max_seq=64, experts=8,
-                         experts_per_token=2)
-GRANITE = dm.DecoderConfig(
-    arch="granite_hybrid", vocab=97, layers=4, heads=4, kv_heads=2,
-    head_dim=16, ffn=48, max_seq=64,
-    layer_types=("mamba", "mamba", "attention", "mamba"), ssm_heads=8,
-    ssm_head_dim=16, ssm_state=32, ssm_conv=4, embedding_multiplier=2.0,
-    residual_multiplier=0.22, attention_multiplier=0.25, logits_scaling=8.0)
-MODELS = {
-    "gpt2": (GPT2, dm.init_decoder_params(GPT2, seed=7)),
-    "olmoe": (OLMOE, olmoe.init_params(OLMOE, seed=3, std=0.05)),
-    "granite_hybrid": (GRANITE, gh.init_params(GRANITE, seed=3, std=0.3)),
-}
+BS = fam.BS
+# three blocks: attention alone, routed experts, a recurrent state by slot
+MODELS = {arch: fam.ROWS[arch].f32
+          for arch in ("gpt2", "olmoe", "granite_hybrid")}
+GPT2 = MODELS["gpt2"][0]
+pytestmark = pytest.mark.usefixtures("cache_dir")
+_flags = fam.flags
 PA, PB, PC = [1, 2, 3, 4], [5, 6, 7, 8], [9, 10, 11]
 
 
@@ -51,35 +40,6 @@ def _unpaged(arch, prompt, max_new, eos_id=-1):
     cfg, params = MODELS[arch]
     return [int(t) for t in dm.unpaged_generate(
         cfg, params, prompt, max_new, pad_len=cfg.max_seq, eos_id=eos_id)]
-
-
-@contextlib.contextmanager
-def _flags(**kv):
-    kv = {"FLAGS_" + k: v for k, v in kv.items()}
-    old = fluid.get_flags(list(kv))
-    fluid.set_flags(kv)
-    try:
-        yield
-    finally:
-        fluid.set_flags(old)
-
-
-@pytest.fixture(scope="module", autouse=True)
-def cache_dir(tmp_path_factory):
-    d = str(tmp_path_factory.mktemp("cc"))
-    old = fluid.get_flags(["FLAGS_compile_cache_dir"])
-    fluid.set_flags({"FLAGS_compile_cache_dir": d})
-    yield d
-    fluid.set_flags(old)
-
-
-@pytest.fixture()
-def telemetry_on():
-    fluid.set_flags({"FLAGS_telemetry": True})
-    _tm.reset()
-    yield
-    _tm.reset()
-    fluid.set_flags({"FLAGS_telemetry": False})
 
 
 def _ctr(name, **labels):

@@ -1,0 +1,317 @@
+"""The contract every decoder family meets, written once and run over the
+rows of ``tests/decoder_families.py`` (the family's name is in each case's
+id): the paged step bitwise equal to the unpaged loop; the multi-token step
+equal to single steps, or refused over rings; the engine moving lanes up and
+reusing what a sequence held; preemption replaying into a fresh slot or an
+empty ring; what a family that keeps more than K and V declines (prefix
+reuse, speculation, export and adoption), each under its reason; the bundle
+round trip; and ``tools/serve.py``'s demo bundle served at the defaults.  A
+family's own file (``tests/test_<family>.py``) holds what is its alone."""
+
+import threading
+
+import numpy as np
+import pytest
+
+import decoder_families as fam
+import paddle_tpu as fluid
+from paddle_tpu.core import telemetry as _tm
+from paddle_tpu.serving import decode_model as dm
+from paddle_tpu.utils import fault_injection
+
+BS = fam.BS
+holding = fam.cases(lambda row: row.holds, ["f32"])
+
+
+@pytest.mark.parametrize("row,key", fam.cases())
+def test_paged_is_bitwise_equal_to_unpaged(row, key):
+    """A prompt, then 20 decoded tokens (40 over rings: five windows deep)
+    through the paged step and the cache manager against the unpaged loop:
+    the same tokens and, bit for bit, the same logits.  A ring never holds
+    more than ``ceil(window / block) + 1`` blocks, whatever the length: what
+    left the window went back."""
+    cfg, params = row.configs[key]
+    n = 40 if row.holds == "ring" else 20
+    log = []
+    ((fed, logits),), _routed = fam.run_paged(cfg, params, [(fam.PROMPT, n)],
+                                             ring_log=log)
+    want, want_logits = fam.generate(cfg, params, fam.PROMPT, n,
+                                     return_logits=True)
+    at = len(fam.PROMPT)
+    assert fed[at:] == want and len(set(want)) > 2
+    assert np.array_equal(logits[at - 1:at - 1 + n], np.stack(want_logits))
+    if row.holds == "ring":
+        ring = fam.ring_blocks(cfg)
+        assert at + n > 5 * cfg.window
+        assert max(hi - lo for _i, _p, lo, hi, _t, _u in log) == ring
+        assert all(used == hi - lo == (table >= 0).sum()
+                   for _i, _p, lo, hi, table, used in log)
+        _i, pos, lo, hi, _t, _u = log[-1]
+        assert (lo, hi) == ((pos + 1 - cfg.window) // BS, pos // BS + 1)
+
+
+@pytest.mark.parametrize("width", [2, 3])
+@pytest.mark.parametrize("row,key", fam.cases(dtypes=["f32"]))
+def test_multi_token_step_equals_single(row, key, width):
+    """``width`` single steps composed in one call give the single steps'
+    logits (bit for bit, or as near as the row says) and route as many
+    tokens: whole chunks of prefill for a model with recurrent layers, and
+    for the others decode too, a verify's junk columns frozen.  A ring holds
+    one write beside its window, so over rings the step is refused."""
+    cfg, params = row.configs[key]
+    if row.holds == "ring":
+        kv = dm.cache_config(cfg, BS, 40, state_slots=3)
+        with pytest.raises(ValueError, match="window layers' rings"):
+            dm.make_paged_step_multi(cfg, kv, width)
+        return
+    n = 0 if cfg.recurrent_layers else 4
+    seqs = [(list(range(3, 15)), n), (list(range(20, 26)), n)]
+    single, routed1 = fam.run_paged(cfg, params, seqs)
+    multi, routed = fam.run_paged(cfg, params, seqs, width=width)
+    for (t1, l1), (tw, lw) in zip(single, multi):
+        assert t1 == tw
+        if row.multi_atol is None:
+            assert np.array_equal(l1, lw)
+        else:
+            np.testing.assert_allclose(lw, l1, atol=row.multi_atol)
+    if cfg.routed_layers:
+        # a step's counts are summed over its columns
+        assert routed[0].shape == (len(cfg.routed_layers), cfg.experts)
+        if not n:
+            assert sum(int(r.sum()) for r in routed) \
+                == sum(int(r.sum()) for r in routed1) \
+                == 18 * len(cfg.routed_layers) * cfg.experts_per_token
+
+
+@pytest.mark.parametrize("row,key", fam.cases(lambda row: row.holds,
+                                              ["f32", "bf16"]))
+def test_lanes_move_up_and_what_a_sequence_held_is_reused(
+        row, key, cache_dir, telemetry_on):
+    """Six requests over four lanes, lengths all different and up to six
+    windows long, through add_model -> prewarm -> the engine's loop (a step
+    ahead of its tokens): sequences finish mid-batch, later lanes move up a
+    place, the waiting ones take the freed slots or the freed rings' blocks
+    (dirty: nothing clears them, a lane at position 0 starts from zeros),
+    and every request's tokens are those of the sequence alone."""
+    cfg, params = row.configs[key]
+    e = fam.engine(cfg, params, 80)
+    try:
+        manifest = e.prewarm()
+        assert manifest["m"][4]["source"] in ("compiled", "disk")
+        m = e._models["m"]
+        assert e.spec("m")["arch"] == row.arch
+        assert m.prefix is None and m.declines == row.declines
+        for name, want in row.entry.items():
+            assert getattr(m, name) == (want(cfg) if callable(want)
+                                        else want), name
+        if row.holds == "slot":
+            assert e.spec("m")["state_slots"] == 5
+        else:
+            assert m.kv_config.window_blocks == 5 * fam.ring_blocks(cfg)
+        miss0 = _tm.counter_total("executor_cache_miss_total")
+        prompts = [fam.PROMPT, [2, 7], [1, 8, 2, 8], [6],
+                   [9, 9, 8, 7, 6, 5], [4, 4]]
+        news = [37, 11, 50, 8, 27, 19] if row.holds == "ring" \
+            else [5, 11, 3, 8, 7, 6]
+        if key == "bf16" and row.batch_dependent_bf16:
+            # bfloat16 rounds what float32 sums in another order at another
+            # batch: alone, but a lane of the same four-lane step
+            want = [e.generate("m", p, max_new_tokens=n,
+                               deadline_ms=60000.0).outputs["tokens"]
+                    for p, n in zip(prompts, news)]
+            _tm.reset()
+            miss0 = 0
+        else:
+            want = [fam.alone(cfg, params, p, n)
+                    for p, n in zip(prompts, news)]
+        with e._cond:
+            waits = [e.submit("m", p, max_new_tokens=n, deadline_ms=60000.0)
+                     for p, n in zip(prompts, news)]
+        for p, tokens, w in zip(prompts, want, waits):
+            r = w.wait(timeout=120.0)
+            assert r is not None and r.status == "ok", r and r.error
+            assert np.array_equal(r.outputs["tokens"], tokens), p
+        assert m.cache.allocator.in_use == 0
+        if row.holds == "slot":
+            assert m.cache.slots.in_use == 0
+            # one reset a sequence: its first step starts the slot from
+            # zeros, under the name of the kind of state it holds
+            resets = {name: _tm.counter_total(name + "_resets_total")
+                      for name in dm.STATE_NAMES.values()}
+            assert resets.pop(cfg.state_name) == len(prompts)
+            assert not any(resets.values())
+        else:
+            # four lanes of a ring each at the most, while the global
+            # layer's blocks were held to the end: 13 for the longest
+            assert m.cache.allocator.high_water >= 13
+            assert m.cache.window_allocator.in_use == 0
+            assert m.cache.window_allocator.high_water \
+                <= 4 * fam.ring_blocks(cfg)
+        assert _tm.counter_total("executor_cache_miss_total") == miss0
+        assert _tm.counter_total("serving_steps_ahead_total") > 0
+    finally:
+        e.stop()
+
+
+@pytest.mark.parametrize("row,key", holding)
+def test_preemption_replays_into_a_fresh_slot_or_an_empty_ring(
+        row, key, cache_dir, telemetry_on):
+    """Capacity 7 blocks, A wants 6 and B 4, both several windows long: B is
+    preempted, gives its slot or its ring back with its blocks, and replays
+    from position 0 (its state reset); both finish with the tokens of the
+    sequence alone."""
+    cfg, params = row.configs[key]
+    e = fam.engine(cfg, params, 8, buckets="2")
+    try:
+        asks = ([1, 2, 3, 4], 20), ([5, 6, 7, 8], 12)
+        with e._cond:
+            waits = [e.submit("m", p, max_new_tokens=n, deadline_ms=60000.0)
+                     for p, n in asks]
+        for (p, n), w in zip(asks, waits):
+            r = w.wait(timeout=120.0)
+            assert r is not None and r.status == "ok", r and r.error
+            assert np.array_equal(r.outputs["tokens"],
+                                  fam.alone(cfg, params, p, n))
+        assert _tm.counter_total("kv_block_evictions_total") >= 1
+        cache = e._models["m"].cache
+        if row.holds == "slot":
+            assert _tm.counter_total(cfg.state_name + "_resets_total") >= 3
+            assert cache.slots.in_use == 0
+        else:
+            assert cache.window_allocator.in_use == 0
+            assert cache.window_allocator.high_water \
+                <= 2 * fam.ring_blocks(cfg)
+    finally:
+        e.stop()
+
+
+@pytest.mark.parametrize("row,key", holding)
+def test_prefix_cache_declines_and_counts(row, key, cache_dir, telemetry_on):
+    """FLAGS_prefix_cache is on by default: for a model that keeps more
+    than K and V there is no index, each admission is counted under the
+    family's reason, and two requests with one prompt give the tokens of
+    the prompt alone (a hit would have started the second at pos 12 with no
+    state, no window, an empty ring)."""
+    assert fluid.get_flags(["FLAGS_prefix_cache"])["FLAGS_prefix_cache"]
+    cfg, params = row.configs[key]
+    e = fam.engine(cfg, params, 40)
+    try:
+        prompt = fam.PROMPT + [8, 9, 7]
+        want = fam.alone(cfg, params, prompt, 9)
+        for _ in range(2):
+            r = e.generate("m", prompt, max_new_tokens=9,
+                           deadline_ms=60000.0)
+            assert r.status == "ok" and r.phases["cached_tokens"] == 0
+            assert np.array_equal(r.outputs["tokens"], want)
+        # the hand-off of a prefill replica has nothing to transfer
+        assert e.handoff_prefill_upto("m", len(prompt)) == 0
+        assert fam.counters("prefix_cache_declined_total") == {
+            "prefix_cache_declined_total{model=m,reason=%s}"
+            % row.declines: 2}
+        assert not fam.counters("prefix_cache_hit_tokens_total")
+    finally:
+        e.stop()
+
+
+@pytest.mark.parametrize("row,key", holding)
+def test_speculation_is_refused(row, key, cache_dir):
+    """A draft is a truncation that keeps the family's block; the engine
+    refuses to speculate with it for a state that cannot be rolled back or
+    a ring that holds one write, and without a draft ignores ``k``."""
+    cfg, params = row.configs[key]
+    dcfg, _dparams = draft = dm.truncate_decoder(cfg, params, layers=2)
+    assert dcfg.layer_types == cfg.layer_types[:2]
+    assert dcfg.dense_layers == min(cfg.dense_layers, 2)
+    assert dcfg.routed_layers == tuple(l for l in cfg.routed_layers if l < 2)
+    e = fam.engine(cfg, params, 16, buckets="2", start=False,
+                   speculative_k=2)
+    with fam.flags(kv_block_size=BS), pytest.raises(ValueError,
+                                                    match=row.refusal):
+        e.add_model("m2", (cfg, params), kv_blocks=16, draft=draft,
+                    speculative_k=2)
+    assert e.spec("m")["speculative_k"] == 0
+
+
+@pytest.mark.parametrize("row,key", holding)
+def test_export_adoption_and_history_are_refused_with_their_reason(
+        row, key, cache_dir, telemetry_on):
+    cfg, params = row.configs[key]
+    with fam.flags(session_migration=True):
+        e = fam.engine(cfg, params, 24, buckets="2")
+        try:
+            # 1 ms a step keeps the request alive while it is exported
+            fault_injection.arm("serving.decode_step:delay:1")
+            streamed = threading.Event()
+            done = e.submit("m", [1, 2, 3, 4, 5], max_new_tokens=40,
+                            deadline_ms=60000.0,
+                            on_token=lambda *a: streamed.set())
+            assert streamed.wait(60.0)
+            with pytest.raises(ValueError, match=row.declines):
+                e.export_session(done.req_id)
+            fault_injection.disarm()
+            with e._cond:        # between steps: the carry is donated
+                block = e._models["m"].cache.export_block(1)
+            assert e.adopt_kv_block("m", "00" * 32, block) \
+                == "rejected:" + row.declines
+            assert fam.counters("kv_migrate_refused_total") == {
+                "kv_migrate_refused_total{reason=%s}" % row.declines: 2}
+            r = done.wait(timeout=120.0)
+            assert r.status == "ok"
+            assert np.array_equal(
+                r.outputs["tokens"],
+                fam.alone(cfg, params, [1, 2, 3, 4, 5], 40))
+            # 45 positions, 11 full blocks: no history block was published
+            assert not fam.counters("kv_history_published_total")
+        finally:
+            fault_injection.disarm()
+            e.stop()
+
+
+@pytest.mark.parametrize("row,key", fam.cases())
+def test_bundle_roundtrip(row, key, tmp_path):
+    cfg, params = row.configs[key]
+    d = dm.save_decoder(str(tmp_path / "b"), cfg, params)
+    got_cfg, got = dm.load_decoder(d)
+    assert got_cfg.to_dict() == cfg.to_dict()
+    assert got_cfg.layer_types == cfg.layer_types
+    assert set(got) == set(params)
+    for k, v in params.items():
+        assert got[k].dtype == v.dtype and got[k].shape == v.shape
+        assert np.array_equal(got[k].view(np.uint8), v.view(np.uint8)), k
+
+
+@pytest.mark.parametrize("row", [
+    pytest.param(row, id=row.arch) for row in fam.ROWS.values() if row.serve])
+def test_serve_tool_writes_and_serves_a_demo_bundle(row, tmp_path,
+                                                    cache_dir):
+    """tools/serve.py builds a demo bundle from the benchmark's
+    configuration file (its tiny sizes; GPT-2's from the tool's own six
+    numbers), a one-layer draft beside it, and the engine serves that
+    directory at the defaults: no speculation, and the tokens of the
+    unpaged loop, several windows deep where there is a window."""
+    name, says = row.serve
+    d = fam.save_demo_decoder(str(tmp_path / "dec"),
+                              config=name and fam.config_file(name))
+    cfg, params = dm.load_decoder(d)
+    served = "bf16" if "bf16" in row.configs else "f32"
+    assert (cfg.arch, cfg.dtype, cfg.kv_dtype) \
+        == (row.arch, served, "bf16" if served == "bf16" else None)
+    for field, want in says.items():
+        assert getattr(cfg, field) == want, field
+    draft, _params = dm.load_draft(d)
+    assert (draft.arch, draft.layer_types) == (row.arch, cfg.layer_types[:1])
+    e = fam.engine(d, None, 24, buckets="2")
+    try:
+        assert e.spec("m")["arch"] == row.arch \
+            and e.spec("m")["kv_dtype"] == (cfg.kv_dtype or "f32") \
+            and e.spec("m")["speculative_k"] == 0
+        n = 30 if row.holds == "ring" else 20
+        r = e.generate("m", [5, 6, 7], max_new_tokens=n, deadline_ms=60000.0)
+        assert r.status == "ok", r.error
+        again = e.generate("m", [5, 6, 7], max_new_tokens=n,
+                           deadline_ms=60000.0)
+        assert np.array_equal(r.outputs["tokens"], again.outputs["tokens"])
+        assert np.array_equal(r.outputs["tokens"],
+                              fam.alone(cfg, params, [5, 6, 7], n))
+    finally:
+        e.stop()

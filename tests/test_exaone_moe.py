@@ -1,24 +1,23 @@
-"""The EXAONE-MoE decoder block (paddle_tpu/models/exaone_moe.py: window
-layers beside global ones, grouped-query attention with per-head Q/K norm,
-norms on the sublayers' outputs, a dense lead layer, a share of
-sigmoid-routed experts and a shared expert, an untied head) through the
-same step makers, cache manager and engine as the other blocks, against its
-plain reference (benchmark/reference/exaone_moe_ref.py, the file the
-benchmark uses): logits at every position; paged against unpaged decode,
-bitwise, five windows deep; the window layers' rings (never more than
-``ceil(window / block) + 1`` blocks a sequence, the rest given back);
-preemption, replay and a roll-back across the window's edge; what declines
-for a model with window layers, and under which counter; the attention
-kernel in interpret mode against the gather for 64 query heads over 8 KV
-heads, compact, with a ring; and the share: eight shares' routed parts and
-the shared expert once are the uncut layer, the sliced head's logits the
-whole head's rows.  Tiny sizes on the CPU: window 8, block 4, 5 layers
-``window, window, window, attention, window``, the first dense (width 48),
-four routed (16 experts of width 16, 4 a token, the router 16 wide), hidden
-48 under 8 query heads over 2 KV heads of 8, vocab 61."""
+"""What is the EXAONE-MoE decoder block's own (paddle_tpu/models/
+exaone_moe.py: window layers beside global ones, grouped-query attention
+with per-head Q/K norm, norms on the sublayers' outputs, a dense lead layer,
+a share of sigmoid-routed experts and a shared expert, an untied head):
+logits at every position against its plain reference
+(benchmark/reference/exaone_moe_ref.py, the file the benchmark uses) and the
+reference told otherwise; the window layers' rings (a ring gives back what
+left the window, an int8 pool rings too) and their bytes; the step's span
+and prewarm event; the attention kernel in interpret mode against the gather
+for 64 query heads over 8 KV heads, compact, with a ring; and the share:
+eight shares' routed parts and the shared expert once are the uncut layer,
+the sliced head's logits the whole head's rows.  The contract it shares with
+every family (paged against unpaged five windows deep, the multi-token step
+refused over rings, the engine's lanes, rings, preemption and refusals, the
+bundle) is tests/test_decoder_families.py's, over its row of
+tests/decoder_families.py, whose tiny sizes these are: window 8, block 4, 5
+layers ``window, window, window, attention, window``, the first dense (width
+48), four routed (16 experts of width 16, 4 a token, the router 16 wide),
+hidden 48 under 8 query heads over 2 KV heads of 8, vocab 61."""
 
-import contextlib
-import importlib.util
 import json
 import os
 
@@ -27,44 +26,31 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import paddle_tpu as fluid
+import decoder_families as fam
 from paddle_tpu.core import telemetry as _tm
 from paddle_tpu.core import tracing as _trc
 from paddle_tpu.models import exaone_moe as em
 from paddle_tpu.pallas_kernels import paged_attention as pa
-from paddle_tpu.serving import DecodeEngine
 from paddle_tpu.serving import decode_model as dm
 from paddle_tpu.serving import kv_cache as kvc
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
-
-
-def _load(*parts):
-    spec = importlib.util.spec_from_file_location(
-        parts[-1][:-3], os.path.join(ROOT, *parts))
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
-
-
-ref = _load("benchmark", "reference", "exaone_moe_ref.py")
-
-BS = 4
-WINDOW = 8
+ref = fam.load("benchmark", "reference", "exaone_moe_ref.py")
+BS = fam.BS
+(CFG, PARAMS), (CFG16, PARAMS16) = (
+    fam.ROWS["exaone_moe"].configs[k] for k in ("f32", "bf16"))
+KINDS, WINDOW = CFG.layer_types, CFG.window
 RING = 3                # ceil(8 / 4) + 1
-KINDS = ("window", "window", "window", "attention", "window")
-CFG = dm.DecoderConfig(
-    arch="exaone_moe", vocab=61, layers=5, heads=8, kv_heads=2, head_dim=8,
-    hidden_size=48, ffn=16, max_seq=96, layer_types=KINDS, window=WINDOW, dense_layers=1,
-    dense_ffn=48, experts=16, experts_per_token=4, shared_ffn=16,
-    routed_scaling=2.5, rope_theta=1e6)
-CFG16 = CFG.replace(dtype="bf16")
+MAXB = CFG.max_seq // BS
+_jnp = fam.as_jnp
+_generate = fam.generate
+_teacher_forced = fam.teacher_forced
+_engine = fam.engine
+_flags = fam.flags
+_counters = fam.counters
+
 # normal(0, 0.3) and a bias of 0.05: at this hidden size the family's 0.02
 # leaves the router's scores within hundredths of a half, where neither a
 # fault's mark nor the bias's would show
-PARAMS = em.init_params(CFG, seed=3, std=0.3, bias_std=0.05)
-PARAMS16 = em.init_params(CFG16, seed=3, std=0.3, bias_std=0.05)
-MAXB = CFG.max_seq // BS
 
 
 def ref_config(cfg, **changed):
@@ -96,10 +82,6 @@ def ref_config(cfg, **changed):
 TOL_F32 = 5e-4
 
 
-def _jnp(params):
-    return {k: jnp.asarray(v) for k, v in params.items()}
-
-
 def _ref(cfg, params, tokens, kept=False, **changed):
     with jax.default_matmul_precision("highest"):
         out = ref.forward(ref_config(cfg, **changed), _jnp(params),
@@ -107,14 +89,9 @@ def _ref(cfg, params, tokens, kept=False, **changed):
     return jax.tree_util.tree_map(np.asarray, out)
 
 
-def _generate(cfg, params, prompt, n, **kw):
-    return dm.unpaged_generate(cfg, params, prompt, n, pad_len=cfg.max_seq,
-                               ring_len=RING * BS, **kw)
-
-
 # -- 1. the block against the reference ------------------------------------------
 
-PROMPT = [3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5]
+PROMPT = fam.PROMPT
 
 
 def _block_logits(cfg, params, n=40):
@@ -211,8 +188,7 @@ def test_the_served_bias_moves_a_tenth_of_the_choices_and_no_experts_load():
     ignores it is seen), and the 16 held experts a 32-lane step hits stay
     within half an expert of an even router's 13.8-14.0 from seed to seed
     (so a run's time does not hang on its seed: PR 36's refusal)."""
-    with open(os.path.join(ROOT, "benchmark", "configs",
-                           "k-exaone-236b-a23b-serve.json")) as fp:
+    with open(fam.config_file("k-exaone-236b-a23b-serve.json")) as fp:
         config = json.load(fp)
     std = config["expert_bias_std"]
     assert std == em.BIAS_STD
@@ -235,17 +211,6 @@ def test_the_served_bias_moves_a_tenth_of_the_choices_and_no_experts_load():
     assert max(hits) - min(hits) < 0.5 and 13.3 < np.mean(hits) < 14.3, hits
 
 
-def _teacher_forced(cfg, params, fed):
-    step = jax.jit(dm.make_unpaged_step(cfg, cfg.max_seq, RING * BS))
-    kv = dm._unpaged_carry(cfg, 1, cfg.max_seq, RING * BS)
-    rows = []
-    for pos, tok in enumerate(fed):
-        kv, _nxt, lg = step(kv, _jnp(params), jnp.asarray([tok]),
-                            jnp.asarray([pos]), jnp.asarray([pos + 1]))
-        rows.append(np.asarray(lg[0]))
-    return np.stack(rows)
-
-
 def test_bf16_logits_within_tolerance_and_fp8_weights_outside():
     """bfloat16 as served against the float32 reference on the same
     weights, logits of standard deviation 2: root-mean-square error
@@ -256,68 +221,18 @@ def test_bf16_logits_within_tolerance_and_fp8_weights_outside():
     want = _ref(CFG16, PARAMS16, fed)[len(PROMPT) - 1:-1]
     rms = lambda x: float(np.sqrt(np.mean(np.square(x - want))))
     assert rms(got) < 0.2, rms(got)
-    fp8 = {k: np.asarray(jnp.asarray(v).astype(jnp.float8_e4m3fn)
-                         .astype(jnp.bfloat16)) for k, v in PARAMS16.items()}
+    fp8 = fam.fp8_rounded(PARAMS16)
     rounded = _teacher_forced(CFG16, fp8, fed[:-1])[len(PROMPT) - 1:]
     assert rms(rounded) > 0.3, rms(rounded)
 
 
-# -- 2. paged against unpaged, the rings -----------------------------------------
-
-def _paged_generate(cfg, params, prompt, n, ring_log=None, kv_dtype=None):
-    """The paged step a sequence at a time, its table and ring moved by the
-    cache manager as the engine moves them."""
-    kv = dm.cache_config(cfg, BS, 40, dtype=kv_dtype, state_slots=3)
-    cache = kvc.PagedKVCache(kv)
-    step = jax.jit(dm.make_paged_step(cfg, kv), donate_argnums=(0,))
-    jparams = _jnp(params)
-    table, blocks = np.full(MAXB, -1, np.int32), []
-    ring = cache.new_ring()
-    out, logits, tok = [], [], prompt[0]
-    for pos in range(len(prompt) + n - 1):
-        assert cache.ensure_table(table, blocks, pos + 1)
-        cache.advance_ring(ring, pos + 1)
-        if ring_log is not None:
-            ring_log.append((pos, ring.lo, ring.hi, ring.table.copy(),
-                             cache.window_allocator.in_use))
-        carry, nxt, lg, _routed = step(
-            cache.carry(), jparams, np.asarray([tok], np.int32),
-            np.asarray([pos], np.int32), table[None],
-            np.asarray([pos + 1], np.int32), ring.table[None])
-        cache.replace_carry(carry)
-        if pos + 1 < len(prompt):
-            tok = prompt[pos + 1]
-            continue
-        tok = int(nxt[0])
-        out.append(tok)
-        logits.append(np.asarray(lg[0]))
-    cache.release_ring(ring)
-    assert cache.window_allocator.in_use == 0
-    return out, np.stack(logits)
-
-
-@pytest.mark.parametrize("cfg,params", [(CFG, PARAMS), (CFG16, PARAMS16)],
-                         ids=["f32", "bf16"])
-def test_paged_is_bitwise_equal_to_unpaged_five_windows_deep(cfg, params):
-    log = []
-    out, logits = _paged_generate(cfg, params, PROMPT, 40, log)
-    want, want_logits = _generate(cfg, params, PROMPT, 40,
-                                  return_logits=True)
-    assert len(PROMPT) + 40 > 5 * WINDOW
-    assert out == want
-    assert np.array_equal(logits, np.stack(want_logits))
-    # a sequence never holds more than ceil(window / block) + 1 blocks of
-    # the window layers' pools, whatever its length: what left the window
-    # went back
-    assert max(hi - lo for _p, lo, hi, _t, _u in log) == RING
-    assert all(used == hi - lo == (table >= 0).sum()
-               for _p, lo, hi, table, used in log)
-    pos, lo, hi, _t, _u = log[-1]
-    assert (lo, hi) == ((pos + 1 - WINDOW) // BS, pos // BS + 1)
+# -- 2. the rings ----------------------------------------------------------------
 
 
 def test_an_int8_pool_rings_too():
-    out, _lg = _paged_generate(CFG, PARAMS, PROMPT, 30, kv_dtype="int8")
+    ((fed, _lg),), _routed = fam.run_paged(CFG.replace(kv_dtype="int8"),
+                                           PARAMS, [(PROMPT, 30)])
+    out = fed[len(PROMPT):]
     want = _generate(CFG, PARAMS, PROMPT, 30)
     # quantised K and V: the same tokens for a while, not for ever
     assert out[:4] == want[:4]
@@ -352,15 +267,6 @@ def test_a_ring_gives_back_what_left_the_window():
             cache.advance_ring(rings[2], p + 1)
 
 
-@pytest.mark.parametrize("width", [2, 3])
-def test_a_multi_token_step_over_rings_is_refused(width):
-    """A ring holds one write beside its window: a verify's or a chunk's
-    later columns would write where the sequence holds no block."""
-    kv = dm.cache_config(CFG, BS, 40, state_slots=3)
-    with pytest.raises(ValueError, match="window layers' rings"):
-        dm.make_paged_step_multi(CFG, kv, width)
-
-
 def test_cache_describes_layers_by_kind_and_counts_the_rings_bytes():
     kv = dm.cache_config(CFG16, BS, 16, state_slots=5)
     assert (kv.layers, kv.window_layers, kv.state_layers) == (1, 4, 0)
@@ -391,8 +297,7 @@ def test_cache_describes_layers_by_kind_and_counts_the_rings_bytes():
 def test_published_sizes_give_the_issues_bytes():
     from benchmark.models import exaone_moe_decoder as model
 
-    with open(os.path.join(ROOT, "benchmark", "configs",
-                           "k-exaone-236b-a23b-serve.json")) as fp:
+    with open(fam.config_file("k-exaone-236b-a23b-serve.json")) as fp:
         config = json.load(fp)
     config.pop("tiny")
     cfg = model.decoder_config(config)
@@ -415,11 +320,6 @@ def test_published_sizes_give_the_issues_bytes():
 
 # -- 3. the kernel ---------------------------------------------------------------
 
-@pytest.fixture()
-def interpret(monkeypatch):
-    monkeypatch.setenv("PADDLE_PALLAS_INTERPRET", "1")
-
-
 def _pools(rng, blocks, bs, width, dtype):
     return [jnp.asarray(rng.randn(blocks, bs, width), dtype)
             for _ in range(2)]
@@ -428,7 +328,7 @@ def _pools(rng, blocks, bs, width, dtype):
 @pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
                                        (jnp.bfloat16, 2e-2)],
                          ids=["f32", "bf16"])
-def test_kernel_64_heads_over_8_compact_equals_the_gather(interpret, dtype,
+def test_kernel_64_heads_over_8_compact_equals_the_gather(interpreted, dtype,
                                                           tol):
     """The published attention shape: 64 query heads over 8 KV heads of
     128.  Compact, the query crosses into the kernel ``[B, 64, 128]`` and
@@ -453,7 +353,7 @@ def test_kernel_64_heads_over_8_compact_equals_the_gather(interpret, dtype,
 @pytest.mark.parametrize("heads,kv_heads,dim", [(64, 8, 128), (8, 2, 64),
                                                 (4, 4, 32)],
                          ids=["compact", "spread", "multi_head"])
-def test_kernel_reads_a_ring_and_nothing_before_the_window(interpret, heads,
+def test_kernel_reads_a_ring_and_nothing_before_the_window(interpreted, heads,
                                                            kv_heads, dim):
     """A window layer's call: the table is the ring (9 slots of 16 for a
     window of 128), the kernel's one chunk is the ring as it lies, and a
@@ -511,7 +411,7 @@ def test_the_spread_layout_is_over_the_vmem_budget_and_compact_under_it():
         == 4 * 128 * 2 * 512 + 2 * 32 * 4 * 32 * 512
 
 
-def test_the_paged_step_on_the_kernel_gives_the_gathers_tokens(interpret):
+def test_the_paged_step_on_the_kernel_gives_the_gathers_tokens(interpreted):
     cfg = dm.DecoderConfig(
         arch="exaone_moe", vocab=61, layers=3, heads=4, kv_heads=2,
         head_dim=128, ffn=128, max_seq=64, layer_types=KINDS[2:],
@@ -526,31 +426,18 @@ def test_the_paged_step_on_the_kernel_gives_the_gathers_tokens(interpret):
     assert dm.experts_path(cfg, _jnp(params), 2) == "pallas"
 
     def run():
-        cache = kvc.PagedKVCache(kv)
-        step = jax.jit(dm.make_paged_step(cfg, kv), donate_argnums=(0,))
-        table, blocks, ring = np.full(8, -1, np.int32), [], cache.new_ring()
-        tok, out = 7, []
-        for pos in range(20):
-            cache.ensure_table(table, blocks, pos + 1)
-            cache.advance_ring(ring, pos + 1)
-            carry, nxt, lg, _routed = step(
-                cache.carry(), _jnp(params), np.asarray([tok, 0], np.int32),
-                np.asarray([pos, 0], np.int32),
-                np.stack([table, np.full(8, -1, np.int32)]),
-                np.asarray([pos + 1, 0], np.int32),
-                np.stack([ring.table, np.full(2, -1, np.int32)]))
-            cache.replace_carry(carry)
-            tok = int(nxt[0])
-            out.append((tok, np.asarray(lg[0])))
-        return out
+        # one lane of a two-lane step, 20 tokens by the step's own argmax
+        ((fed, logits), _idle), _routed = fam.run_paged(
+            cfg, params, [([7], 20), ([], 0)], blocks=24, block_size=8)
+        return fed, logits
 
     on_kernel = run()
     os.environ.pop("PADDLE_PALLAS_INTERPRET")
     assert dm.attention_path(cfg, kv, 2, "window") == "gather"
     gathered = run()
-    assert [t for t, _l in on_kernel] == [t for t, _l in gathered]
-    for (_t, a), (_u, b) in zip(on_kernel, gathered):
-        np.testing.assert_allclose(a, b, atol=1e-4, rtol=1e-4)
+    assert on_kernel[0] == gathered[0]
+    np.testing.assert_allclose(on_kernel[1], gathered[1], atol=1e-4,
+                               rtol=1e-4)
 
 
 # -- 4. the share ----------------------------------------------------------------
@@ -561,33 +448,9 @@ def test_eight_shares_and_the_shared_expert_once_are_the_uncut_layer():
     and the shared expert's output, counted once, equal the uncut
     reference's layer.  No share alone does."""
     cfg = CFG.replace(layers=1, layer_types=KINDS[:1], dense_layers=0)
-    params = em.init_params(cfg, seed=11, std=0.3, bias_std=0.05)
-    rng = np.random.RandomState(2)
-    x = jnp.asarray(rng.randn(12, cfg.hidden), jnp.float32)
-    live = jnp.ones(12, bool)
-    whole = {k[3:]: jnp.asarray(v) for k, v in params.items()
-             if k.startswith("l0_")}
-    with jax.default_matmul_precision("highest"):
-        gates, _margin = ref.gates_of(ref_config(cfg), whole, x)
-        want = ref.routed_sum(ref_config(cfg), whole, x, gates) \
-            + ref.shared_out(ref_config(cfg), whole, x)
-        parts = []
-        for share in range(8):
-            mine = cfg.replace(experts_held=2, expert_first=2 * share)
-            held = dict(whole, **{w: whole[w][mine.held_experts]
-                                  for w in ("wgate", "wup", "wdown")})
-            part, chosen = em.routed_part(mine, held.__getitem__, x, live)
-            assert chosen.shape == (12, 16) and (chosen.sum(axis=1) == 4).all()
-            # the reference given the same share computes the same part
-            np.testing.assert_allclose(
-                np.asarray(part), np.asarray(ref.routed_sum(
-                    ref_config(mine), held, x, gates)), atol=1e-5)
-            parts.append(np.asarray(part))
-        shared = np.asarray(em.shared_part(whole.__getitem__, x))
-    np.testing.assert_allclose(sum(parts) + shared, np.asarray(want),
-                               atol=2e-5)
-    assert np.abs(parts[0] + shared - np.asarray(want)).max() > 1e-2
-    assert np.abs(sum(parts) + 8 * shared - np.asarray(want)).max() > 1e-2
+    fam.check_shares_add_up(
+        cfg, em.init_params(cfg, seed=11, std=0.3, bias_std=0.05), em, ref,
+        ref_config, ("wgate", "wup", "wdown"), (1e-5, 2e-5))
 
 
 def test_a_share_through_the_block_equals_the_reference_given_the_share():
@@ -628,195 +491,6 @@ def test_the_sliced_heads_logits_are_the_whole_heads_rows():
 
 # -- 5. the engine ---------------------------------------------------------------
 
-@contextlib.contextmanager
-def _flags(**kv):
-    kv = {"FLAGS_" + k: v for k, v in kv.items()}
-    old = fluid.get_flags(list(kv))
-    fluid.set_flags(kv)
-    try:
-        yield
-    finally:
-        fluid.set_flags(old)
-
-
-@pytest.fixture(scope="module")
-def cache_dir(tmp_path_factory):
-    d = str(tmp_path_factory.mktemp("cc"))
-    old = fluid.get_flags(["FLAGS_compile_cache_dir"])
-    fluid.set_flags({"FLAGS_compile_cache_dir": d})
-    yield d
-    fluid.set_flags(old)
-
-
-@pytest.fixture()
-def telemetry_on():
-    fluid.set_flags({"FLAGS_telemetry": True})
-    _tm.reset()
-    yield
-    _tm.reset()
-    fluid.set_flags({"FLAGS_telemetry": False})
-
-
-def _engine(cfg, params, kv_blocks, buckets="4", start=True, **kw):
-    with _flags(kv_block_size=BS):
-        e = DecodeEngine(buckets=buckets, deadline_ms=60000.0)
-        e.add_model("ex", (cfg, params), kv_blocks=kv_blocks, **kw)
-    return e.start() if start else e
-
-
-def _alone(cfg, params, prompt, n):
-    return np.asarray(_generate(cfg, params, prompt, n), np.int32)
-
-
-def _counters(prefix):
-    return {k: v for k, v in _tm.snapshot()["counters"].items()
-            if k.startswith(prefix)}
-
-
-@pytest.mark.parametrize("cfg,params", [(CFG, PARAMS), (CFG16, PARAMS16)],
-                         ids=["f32", "bf16"])
-def test_lanes_move_up_and_rings_are_reused(cfg, params, cache_dir,
-                                            telemetry_on):
-    """Six requests over four lanes, lengths all different and up to six
-    windows long, through the engine's loop (a step ahead of its tokens):
-    sequences finish mid-batch, the waiting ones take the freed rings'
-    blocks (dirty: nothing clears them), no sequence ever holds more than
-    three window blocks, and every request's tokens are those of the
-    sequence alone."""
-    e = _engine(cfg, params, 80)
-    try:
-        manifest = e.prewarm()
-        assert manifest["ex"][4]["source"] in ("compiled", "disk")
-        m = e._models["ex"]
-        assert e.spec("ex")["arch"] == "exaone_moe"
-        assert m.prefix is None and m.declines == "window_layers"
-        assert (m.attn_path, m.window_path) == ("gather", "gather")
-        assert m.kv_config.window_blocks == 5 * RING
-        miss0 = _tm.counter_total("executor_cache_miss_total")
-        prompts = [[3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5], [2, 7], [1, 8, 2, 8],
-                   [6], [9, 9, 8, 7, 6, 5], [4, 4]]
-        news = [37, 11, 50, 8, 27, 19]
-        if cfg.dtype == "f32":
-            alone = [_alone(cfg, params, p, n) for p, n in zip(prompts, news)]
-        else:
-            # bfloat16 rounds what float32 sums in another order at another
-            # batch: alone, but a lane of the same four-lane step
-            alone = [e.generate("ex", p, max_new_tokens=n,
-                                deadline_ms=60000.0).outputs["tokens"]
-                     for p, n in zip(prompts, news)]
-        with e._cond:
-            waits = [e.submit("ex", p, max_new_tokens=n, deadline_ms=60000.0)
-                     for p, n in zip(prompts, news)]
-        for p, want, w in zip(prompts, alone, waits):
-            r = w.wait(timeout=120.0)
-            assert r is not None and r.status == "ok", r and r.error
-            assert np.array_equal(r.outputs["tokens"], want), p
-        assert m.cache.window_allocator.in_use == 0
-        assert m.cache.allocator.in_use == 0
-        # four lanes of three blocks each at the most, of 14 in circulation
-        assert m.cache.window_allocator.high_water <= 4 * RING
-        # the global layer's blocks were held to the end: 13 for the longest
-        assert m.cache.allocator.high_water >= 13
-        assert _tm.counter_total("executor_cache_miss_total") == miss0
-        assert _tm.counter_total("serving_steps_ahead_total") > 0
-    finally:
-        e.stop()
-
-
-def test_preemption_replays_into_an_empty_ring(cache_dir, telemetry_on):
-    """Capacity 7 global blocks, A wants 6 and B 4, both several windows
-    long: B is preempted past its first window, gives its ring back with
-    its blocks, and replays from position 0; both finish with the tokens of
-    the sequence alone."""
-    e = _engine(CFG, PARAMS, 8, buckets="2")
-    try:
-        with e._cond:
-            ra = e.submit("ex", [1, 2, 3, 4], max_new_tokens=20,
-                          deadline_ms=60000.0)
-            rb = e.submit("ex", [5, 6, 7, 8], max_new_tokens=12,
-                          deadline_ms=60000.0)
-        a, b = ra.wait(timeout=120.0), rb.wait(timeout=120.0)
-        assert a is not None and a.status == "ok", a and a.error
-        assert b is not None and b.status == "ok", b and b.error
-        assert np.array_equal(a.outputs["tokens"],
-                              _alone(CFG, PARAMS, [1, 2, 3, 4], 20))
-        assert np.array_equal(b.outputs["tokens"],
-                              _alone(CFG, PARAMS, [5, 6, 7, 8], 12))
-        assert _tm.counter_total("kv_block_evictions_total") >= 1
-        m = e._models["ex"]
-        assert m.cache.window_allocator.in_use == 0
-        assert m.cache.window_allocator.high_water <= 2 * RING
-    finally:
-        e.stop()
-
-
-def test_prefix_cache_declines_and_counts(cache_dir, telemetry_on):
-    """FLAGS_prefix_cache is on by default: for a model with window layers
-    there is no index, each admission is counted under its own reason, and
-    two requests with one prompt give the tokens of the prompt alone (a hit
-    would have started the second at pos 12 over rings that hold
-    nothing)."""
-    assert fluid.get_flags(["FLAGS_prefix_cache"])["FLAGS_prefix_cache"]
-    e = _engine(CFG, PARAMS, 40)
-    try:
-        prompt = [3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5, 8, 9, 7]
-        want = _alone(CFG, PARAMS, prompt, 9)
-        for _ in range(2):
-            r = e.generate("ex", prompt, max_new_tokens=9,
-                           deadline_ms=60000.0)
-            assert r.status == "ok" and r.phases["cached_tokens"] == 0
-            assert np.array_equal(r.outputs["tokens"], want)
-        assert e.handoff_prefill_upto("ex", len(prompt)) == 0
-        assert _counters("prefix_cache_declined_total") == {
-            "prefix_cache_declined_total{model=ex,reason=window_layers}": 2}
-        assert not _counters("prefix_cache_hit_tokens_total")
-    finally:
-        e.stop()
-
-
-def test_speculation_is_refused(cache_dir):
-    draft = dm.truncate_decoder(CFG, PARAMS, layers=2)
-    assert draft[0].layer_types == KINDS[:2] and draft[0].dense_layers == 1
-    e = _engine(CFG, PARAMS, 16, buckets="2", start=False)
-    with _flags(kv_block_size=BS), pytest.raises(ValueError,
-                                                 match="window layers"):
-        e.add_model("ex2", (CFG, PARAMS), kv_blocks=16, draft=draft,
-                    speculative_k=2)
-    assert e.spec("ex")["speculative_k"] == 0
-
-
-def test_export_adoption_and_history_are_refused_with_their_reason(
-        cache_dir, telemetry_on):
-    from paddle_tpu.utils import fault_injection
-    import threading
-
-    with _flags(session_migration=True):
-        e = _engine(CFG, PARAMS, 24, buckets="2")
-        try:
-            fault_injection.arm("serving.decode_step:delay:1")
-            streamed = threading.Event()
-            done = e.submit("ex", [1, 2, 3, 4, 5], max_new_tokens=40,
-                            deadline_ms=60000.0,
-                            on_token=lambda *a: streamed.set())
-            assert streamed.wait(60.0)
-            with pytest.raises(ValueError, match="window_layers"):
-                e.export_session(done.req_id)
-            fault_injection.disarm()
-            with e._cond:        # between steps: the carry is donated
-                block = e._models["ex"].cache.export_block(1)
-            assert e.adopt_kv_block("ex", "00" * 32, block) \
-                == "rejected:window_layers"
-            assert _counters("kv_migrate_refused_total") == {
-                "kv_migrate_refused_total{reason=window_layers}": 2}
-            r = done.wait(timeout=120.0)
-            assert r.status == "ok"
-            assert np.array_equal(r.outputs["tokens"],
-                                  _alone(CFG, PARAMS, [1, 2, 3, 4, 5], 40))
-            assert not _counters("kv_history_published_total")
-        finally:
-            fault_injection.disarm()
-            e.stop()
-
 
 def test_step_span_counters_gauges_and_prewarm_event(cache_dir, telemetry_on,
                                                      tmp_path):
@@ -828,7 +502,7 @@ def test_step_span_counters_gauges_and_prewarm_event(cache_dir, telemetry_on,
     cfg = CFG.replace(experts_held=4, expert_first=4)
     params = em.init_params(cfg, seed=3, std=0.3, bias_std=0.05)
     with _flags(tracing=True, telemetry_dir=str(tmp_path)):
-        e = _engine(cfg, params, 24, buckets="2")
+        e = _engine(cfg, params, 24, buckets="2", name="ex")
         try:
             e.prewarm()
             r = e.generate("ex", [1, 2, 3], max_new_tokens=30,
@@ -838,12 +512,7 @@ def test_step_span_counters_gauges_and_prewarm_event(cache_dir, telemetry_on,
             e.stop()
         _trc.flush()
         _tm.flush()
-    records = [json.loads(line) for fn in os.listdir(tmp_path)
-               if fn.startswith("trace-")
-               for line in open(os.path.join(tmp_path, fn))]
-    steps = [s["attrs"] for s in records
-             if s.get("name") == "serving.decode_step"
-             and s["attrs"].get("model") == "ex"]
+    steps = fam.step_spans(tmp_path, "ex")
     assert len(steps) >= 32
     # the gather reads every slot of the table it is given: the ring's 3 in
     # each of 4 window layers x 2 lanes, against the whole table's 24
@@ -868,9 +537,7 @@ def test_step_span_counters_gauges_and_prewarm_event(cache_dir, telemetry_on,
     gauges = _tm.snapshot()["gauges"]
     assert gauges["kv_pool_blocks{kind=window,model=ex}"] <= RING
     assert gauges["kv_pool_blocks{kind=global,model=ex}"] >= 8
-    with open(os.path.join(tmp_path, "steps.jsonl")) as fp:
-        warm = [ev for ev in map(json.loads, fp)
-                if ev["ev"] == "serving_prewarm"]
+    warm = fam.prewarm_events(tmp_path)
     assert warm and all(
         ev["model"] == "ex" and ev["attention"] == "gather"
         and ev["window_attention"] == "gather" and ev["experts"] == "einsum"
@@ -901,52 +568,3 @@ def test_config_refuses_what_no_block_computes():
         .held_experts == slice(0, 8)
 
 
-def test_bundle_roundtrip(tmp_path):
-    d = dm.save_decoder(str(tmp_path / "ex"), CFG16, PARAMS16)
-    cfg, params = dm.load_decoder(d)
-    assert cfg.to_dict() == CFG16.to_dict()
-    assert cfg.window == WINDOW and cfg.experts_held == 16
-    assert all(np.array_equal(params[k], PARAMS16[k]) for k in PARAMS16)
-
-
-def test_serve_tool_writes_and_serves_an_exaone_bundle(tmp_path, cache_dir):
-    """tools/serve.py builds a demo bundle from the benchmark's
-    configuration file (its tiny sizes: a share of 4 of 16 experts), and
-    the engine serves that directory at the defaults: tokens equal the
-    unpaged loop's, four windows deep."""
-    import sys
-
-    sys.path.insert(0, os.path.join(ROOT, "tools"))
-    try:
-        from serve import save_demo_decoder
-    finally:
-        sys.path.pop(0)
-    d = save_demo_decoder(
-        str(tmp_path / "dec"), config=os.path.join(
-            ROOT, "benchmark", "configs", "k-exaone-236b-a23b-serve.json"))
-    cfg, params = dm.load_decoder(d)
-    assert (cfg.arch, cfg.dtype, cfg.kv_dtype) == ("exaone_moe", "bf16",
-                                                   "bf16")
-    assert (cfg.layer_types, cfg.dense_layers, cfg.experts, cfg.experts_held,
-            cfg.experts_per_token, cfg.window, cfg.hidden, cfg.heads,
-            cfg.rope_theta) == (KINDS, 1, 16, 4, 4, 8, 48, 8, 1e6)
-    assert dm.load_draft(d)[0].layer_types == ("window",)
-    with _flags(kv_block_size=BS):
-        e = DecodeEngine(buckets="2", deadline_ms=60000.0)
-        e.add_model("ex", d, kv_blocks=24)
-    e.start()
-    try:
-        assert e.spec("ex")["arch"] == "exaone_moe" \
-            and e.spec("ex")["kv_dtype"] == "bf16" \
-            and e.spec("ex")["speculative_k"] == 0
-        r = e.generate("ex", [5, 6, 7], max_new_tokens=30,
-                       deadline_ms=60000.0)
-        assert r.status == "ok", r.error
-        # alone, but a lane of the same two-lane step (bfloat16: see above)
-        again = e.generate("ex", [5, 6, 7], max_new_tokens=30,
-                           deadline_ms=60000.0)
-        assert np.array_equal(r.outputs["tokens"], again.outputs["tokens"])
-        assert np.array_equal(r.outputs["tokens"],
-                              _alone(cfg, params, [5, 6, 7], 30))
-    finally:
-        e.stop()

@@ -1,63 +1,55 @@
-"""The LFM2-MoE decoder block (paddle_tpu/models/lfm2_moe.py: gated short
-convolutions beside grouped-query attention, a dense lead layer and
-sigmoid-routed experts) through the same step makers, cache manager and
-engine as the GPT-2, OLMoE and Granite blocks, against its plain reference
-(benchmark/reference/lfm2_moe_ref.py, the file the benchmark uses): logits
-at every position, prefill then decode through the paged cache and the
-window slots; each piece of the routing rule; the per-head Q/K norm; the
-window's start, reuse and replay; lanes that join and leave; the
-one-step-ahead loop; what declines for a model whose layers keep a window,
-and under which counter; the manager's bytes.  Tiny sizes on the CPU: 5
-layers ``conv, attention, conv, conv, attention``, the first dense (width
-48), four routed (8 experts of width 32, 2 a token), hidden 64, 4 query
-heads over 2 KV heads of 16, 3 taps, vocab 97."""
+"""What is the LFM2-MoE decoder block's own (paddle_tpu/models/lfm2_moe.py:
+gated short convolutions beside grouped-query attention, a dense lead layer
+and sigmoid-routed experts): logits at every position against its plain
+reference (benchmark/reference/lfm2_moe_ref.py, the file the benchmark
+uses), prefill then decode through the paged cache and the window slots;
+each piece of the routing rule; the per-head Q/K norm; the window's start;
+lanes that join and leave; the packed step's feed; the step's span and
+prewarm event; the manager's bytes.  The contract it shares with every
+family is tests/test_decoder_families.py's, over its row of
+tests/decoder_families.py, whose tiny sizes these are: 5 layers ``conv,
+attention, conv, conv, attention``, the first dense (width 48), four routed
+(8 experts of width 32, 2 a token), hidden 64, 4 query heads over 2 KV heads
+of 16, 3 taps, vocab 97."""
 
-import contextlib
-import importlib.util
+import functools
 import json
-import os
-import threading
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import paddle_tpu as fluid
+import decoder_families as fam
 from paddle_tpu.core import telemetry as _tm
 from paddle_tpu.core import tracing as _trc
 from paddle_tpu.models import lfm2_moe as lm
 from paddle_tpu.models import olmoe
-from paddle_tpu.serving import DecodeEngine
 from paddle_tpu.serving import decode_model as dm
 from paddle_tpu.serving import kv_cache as kvc
-from paddle_tpu.utils import fault_injection
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ref = fam.load("benchmark", "reference", "lfm2_moe_ref.py")
+BS = fam.BS
+(CFG, PARAMS), (CFG16, PARAMS16) = (
+    fam.ROWS["lfm2_moe"].configs[k] for k in ("f32", "bf16"))
+KINDS = CFG.layer_types
+_jnp = fam.as_jnp
+_sequences = fam.sequences
+_engine = fam.engine
+_flags = fam.flags
 
 
-def _load(*parts):
-    spec = importlib.util.spec_from_file_location(
-        parts[-1][:-3], os.path.join(ROOT, *parts))
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
+def run_paged(cfg, params, seqs, **kw):
+    """``fam.run_paged`` -> per lane (tokens fed, logits, and for a sequence
+    run alone its routed counts [n, routed layers, experts])."""
+    out, routed = fam.run_paged(cfg, params, seqs, **kw)
+    return [(f, lg, np.stack(routed) if len(seqs) == 1 else None)
+            for f, lg in out]
 
 
-ref = _load("benchmark", "reference", "lfm2_moe_ref.py")
-
-BS = 4
-KINDS = ("conv", "attention", "conv", "conv", "attention")
-CFG = dm.DecoderConfig(
-    arch="lfm2_moe", vocab=97, layers=5, heads=4, kv_heads=2, head_dim=16,
-    ffn=32, max_seq=64, layer_types=KINDS, conv_taps=3, dense_layers=1,
-    dense_ffn=48, experts=8, experts_per_token=2, rope_theta=1e6)
-CFG16 = CFG.replace(dtype="bf16")
 # normal(0, 0.3): at this hidden size the family's 0.02 leaves the layers'
 # share of the residual stream, and so a fault's mark on the logits, small,
 # and the tied head would make every token repeat its input
-PARAMS = lm.init_params(CFG, seed=3, std=0.3)
-PARAMS16 = lm.init_params(CFG16, seed=3, std=0.3)
 
 
 def ref_config(cfg):
@@ -90,94 +82,11 @@ TOL_F32 = 5e-4
 RMS_BF16 = 0.04
 
 
-def _jnp(params):
-    return {k: jnp.asarray(v) for k, v in params.items()}
-
-
 def _ref(cfg, params, tokens, kept=False, **changed):
     with jax.default_matmul_precision("highest"):
         out = ref.forward(dict(ref_config(cfg), **changed), _jnp(params),
                           jnp.asarray(tokens, jnp.int32), kept)
     return jax.tree_util.tree_map(np.asarray, out)
-
-
-def _sequences(n, seed=0, lo=5, hi=14, n_decode=8):
-    rng = np.random.RandomState(seed)
-    return [(list(rng.randint(0, CFG.vocab, rng.randint(lo, hi))), n_decode)
-            for _ in range(n)]
-
-
-def run_paged(cfg, params, seqs, blocks=40, table_seed=5, dirty=None,
-              feed=False):
-    """Every (prompt, n_decode) of ``seqs`` in its own lane through the
-    paged step and real pools, a shuffled block table and shuffled window
-    slots: the prompt one token a step, then the step's own argmax.
-    ``dirty`` fills every slot before the first step; ``feed`` takes the
-    step as the engine compiles it (``make_packed_step``: the token feed on
-    the device, the lanes' integers in one array), each lane's decoded
-    token chosen there from the step before's.  -> per lane (tokens
-    fed, logits [n, vocab] of every position fed, routed counts [n, routed
-    layers, experts])."""
-    b = len(seqs)
-    kv = dm.cache_config(cfg, BS, blocks, state_slots=b + 3)
-    cache = kvc.PagedKVCache(kv)
-    if dirty is not None:
-        groups, (windows,) = kv.groups(cache.carry())
-        cache.replace_carry(tuple(a for g in groups for a in g) + tuple(
-            jnp.full_like(w, dirty) for w in windows))
-    maxb = cfg.max_seq // BS
-    rs = np.random.RandomState(table_seed)
-    order = iter(rs.permutation(np.arange(1, blocks)))
-    slots = rs.permutation(np.arange(1, b + 3))[:b].astype(np.int32)
-    tables = np.full((b, maxb), -1, np.int32)
-    total = [len(p) + n for p, n in seqs]
-    for i, t in enumerate(total):
-        for j in range(-(-t // BS)):
-            tables[i, j] = next(order)
-    make = dm.make_packed_step(cfg, kv, b) if feed \
-        else dm.make_paged_step(cfg, kv)
-    columns, width = dm.lane_columns(kv, maxb)
-    step = jax.jit(make, donate_argnums=(0,))
-    jparams = _jnp(params)
-    fed = [list(p) for p, _ in seqs]
-    logits = [[] for _ in seqs]
-    routed = [[] for _ in seqs]
-    prev = jnp.zeros(b, jnp.int32)
-    while any(len(lg) < t for lg, t in zip(logits, total)):
-        tok, pos, lens = (np.zeros(b, np.int32) for _ in range(3))
-        src = np.full(b, -1, np.int32)
-        live = []
-        for i in range(b):
-            at = len(logits[i])
-            if at >= total[i]:
-                continue
-            live.append(i)
-            pos[i], lens[i] = at, at + 1
-            if feed and at >= len(seqs[i][0]):
-                src[i] = i              # the token lane i made a step ago
-            else:
-                tok[i] = fed[i][at]
-        where = np.where(lens > 0, slots, 0).astype(np.int32)
-        if feed:
-            lanes = np.zeros((b, width), np.int32)
-            for name, value in dict(tok=tok, src=src, pos=pos, lens=lens,
-                                    slot=where, tables=tables).items():
-                lanes[:, columns[name]] = value.reshape(b, -1)
-            args = (prev, lanes)
-        else:
-            args = (tok, pos, tables, lens, where)
-        carry, nxt, lg, counts = step(cache.carry(), jparams, *args)
-        cache.replace_carry(carry)
-        prev = nxt
-        nxt, lg = np.asarray(nxt), np.asarray(lg)
-        for i in live:
-            logits[i].append(lg[i])
-            if len(live) == 1:
-                routed[i].append(np.asarray(counts))
-            if len(logits[i]) == len(fed[i]) < total[i]:
-                fed[i].append(int(nxt[i]))
-    return [(f, np.stack(lg) if lg else None, np.stack(r) if r else None)
-            for f, lg, r in zip(fed, logits, routed)]
 
 
 def _worst(cfg, out, params, **changed):
@@ -187,13 +96,9 @@ def _worst(cfg, out, params, **changed):
 
 # -- 1. against the reference, and the reference broken ------------------------
 
-F32_OUT = {}
-
-
+@functools.lru_cache(None)
 def _f32_out():
-    if not F32_OUT:
-        F32_OUT["out"] = run_paged(CFG, PARAMS, _sequences(3))
-    return F32_OUT["out"]
+    return run_paged(CFG, PARAMS, _sequences(3))
 
 
 def test_f32_logits_equal_the_reference_at_every_position():
@@ -311,9 +216,7 @@ def test_bf16_logits_within_tolerance_and_fp8_weights_outside():
         return (sq / n) ** 0.5, agreed / positions
 
     served, share = rms(PARAMS16)
-    fp8 = {k: np.asarray(jnp.asarray(v).astype(jnp.float8_e4m3fn)
-                         .astype(jnp.bfloat16)) for k, v in PARAMS16.items()}
-    coarse, _share = rms(fp8)
+    coarse, _share = rms(fam.fp8_rounded(PARAMS16))
     assert share > 0.7
     assert served < RMS_BF16 < coarse
 
@@ -418,30 +321,17 @@ def test_a_lane_at_position_zero_starts_from_a_zero_window():
 
 # -- 3. paged against unpaged, the fed step -------------------------------------
 
-@pytest.mark.parametrize("cfg,params", [(CFG, PARAMS), (CFG16, PARAMS16)],
-                         ids=["f32", "bf16"])
-def test_paged_is_bitwise_equal_to_unpaged(cfg, params):
-    (prompt, n), = _sequences(1, seed=2)
-    (fed, lg, _r), = run_paged(cfg, params, [(prompt, n)])
-    toks, want = dm.unpaged_generate(cfg, params, prompt, n,
-                                     pad_len=cfg.max_seq,
-                                     return_logits=True)
-    assert fed[len(prompt):] == toks and len(set(toks)) > 2
-    assert np.array_equal(lg[len(prompt) - 1:len(prompt) - 1 + n],
-                          np.stack(want))
-
 
 def test_joining_and_leaving_lanes_equal_each_alone():
     """Three sequences of different lengths share a step: lanes fall idle
     one by one, and each sequence's logits are those it has with the other
-    lanes idle throughout, in other blocks and another slot, bit for bit
-    (the CPU tier's gather path)."""
+    lanes idle throughout, in other blocks and (but for the first) another
+    slot, bit for bit (the CPU tier's gather path)."""
     seqs = _sequences(3, seed=5)
     together = run_paged(CFG, PARAMS, seqs)
     for i, (fed, lg, _r) in enumerate(together):
         alone = run_paged(CFG, PARAMS, [seq if j == i else ([], 0)
-                                        for j, seq in enumerate(seqs)],
-                          table_seed=11)
+                                        for j, seq in enumerate(seqs)])
         assert fed == alone[i][0] and np.array_equal(lg, alone[i][1])
 
 
@@ -457,207 +347,7 @@ def test_fed_step_feeds_the_step_before_on_the_device():
         assert np.array_equal(a, b)
 
 
-def test_multi_token_step_equals_single():
-    """``width`` single steps composed in one call (a chunk of prefill):
-    the same logits, bit for bit."""
-    prompt = list(range(3, 12))
-    (_f, single, _r), = run_paged(CFG, PARAMS, [(prompt, 0)])
-    kv = dm.cache_config(CFG, BS, 16, state_slots=3)
-    cache = kvc.PagedKVCache(kv)
-    step = jax.jit(dm.make_paged_step_multi(CFG, kv, 3), donate_argnums=(0,))
-    table = np.full((1, CFG.max_seq // BS), -1, np.int32)
-    table[0, :3] = [4, 2, 9]
-    got = []
-    for at in range(0, 9, 3):
-        pos = np.arange(at, at + 3)[None]
-        carry, _nxt, lg, _c = step(
-            cache.carry(), _jnp(PARAMS), np.array([prompt[at:at + 3]]), pos,
-            table, pos + 1, np.array([1]))
-        cache.replace_carry(carry)
-        got.append(np.asarray(lg[0]))
-    assert np.array_equal(np.concatenate(got), single)
-
-
 # -- 4. the engine ---------------------------------------------------------------
-
-@contextlib.contextmanager
-def _flags(**kv):
-    kv = {"FLAGS_" + k: v for k, v in kv.items()}
-    old = fluid.get_flags(list(kv))
-    fluid.set_flags(kv)
-    try:
-        yield
-    finally:
-        fluid.set_flags(old)
-
-
-@pytest.fixture(scope="module")
-def cache_dir(tmp_path_factory):
-    d = str(tmp_path_factory.mktemp("cc"))
-    old = fluid.get_flags(["FLAGS_compile_cache_dir"])
-    fluid.set_flags({"FLAGS_compile_cache_dir": d})
-    yield d
-    fluid.set_flags(old)
-
-
-@pytest.fixture()
-def telemetry_on():
-    fluid.set_flags({"FLAGS_telemetry": True})
-    _tm.reset()
-    yield
-    _tm.reset()
-    fluid.set_flags({"FLAGS_telemetry": False})
-
-
-def _engine(cfg, params, kv_blocks, buckets="4", start=True, **kw):
-    with _flags(kv_block_size=BS):
-        e = DecodeEngine(buckets=buckets, deadline_ms=60000.0)
-        e.add_model("lf", (cfg, params), kv_blocks=kv_blocks, **kw)
-    return e.start() if start else e
-
-
-def _alone(cfg, params, prompt, n):
-    return np.asarray(dm.unpaged_generate(cfg, params, prompt, n,
-                                          pad_len=cfg.max_seq), np.int32)
-
-
-def _counters(prefix):
-    return {k: v for k, v in _tm.snapshot()["counters"].items()
-            if k.startswith(prefix)}
-
-
-@pytest.mark.parametrize("cfg,params", [(CFG, PARAMS), (CFG16, PARAMS16)],
-                         ids=["f32", "bf16"])
-def test_lanes_move_up_and_slots_are_reused(cfg, params, cache_dir,
-                                            telemetry_on):
-    """Six requests over four lanes, lengths all different, through the
-    engine's loop (a step ahead of its tokens): sequences finish mid-batch,
-    later lanes move up a place, the waiting ones take the freed slots
-    (dirty: nothing clears them), and every request's tokens are those of
-    the sequence alone."""
-    e = _engine(cfg, params, 60)
-    try:
-        manifest = e.prewarm()
-        assert manifest["lf"][4]["source"] in ("compiled", "disk")
-        m = e._models["lf"]
-        assert e.spec("lf")["arch"] == "lfm2_moe"
-        assert e.spec("lf")["state_slots"] == 5 and m.prefix is None
-        assert m.state_name == "conv_state" and m.slot_bytes \
-            == 3 * 2 * 64 * (4 if cfg.dtype == "f32" else 2)
-        miss0 = _tm.counter_total("executor_cache_miss_total")
-        prompts = [[3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5], [2, 7], [1, 8, 2, 8],
-                   [6], [9, 9, 8, 7, 6, 5], [4, 4]]
-        news = [5, 11, 3, 8, 7, 6]
-        with e._cond:
-            waits = [e.submit("lf", p, max_new_tokens=n, deadline_ms=60000.0)
-                     for p, n in zip(prompts, news)]
-        for p, n, w in zip(prompts, news, waits):
-            r = w.wait(timeout=120.0)
-            assert r is not None and r.status == "ok", r and r.error
-            assert np.array_equal(r.outputs["tokens"],
-                                  _alone(cfg, params, p, n)), p
-        assert m.cache.slots.in_use == 0
-        assert m.cache.allocator.in_use == 0
-        assert _tm.counter_total("executor_cache_miss_total") == miss0
-        # one reset a sequence: its first step starts the slot from zeros
-        assert _tm.counter_total("conv_state_resets_total") == len(prompts)
-        assert _tm.counter_total("ssm_state_resets_total") == 0
-        assert _tm.counter_total("serving_steps_ahead_total") > 0
-    finally:
-        e.stop()
-
-
-def test_preemption_replays_into_a_fresh_slot(cache_dir, telemetry_on):
-    """Capacity 3 blocks, A wants 3 and B 2: B is preempted, gives its
-    slot back with its blocks, and replays from position 0; both finish
-    with the tokens of the sequence alone."""
-    e = _engine(CFG, PARAMS, 4, buckets="2")
-    try:
-        with e._cond:
-            ra = e.submit("lf", [1, 2, 3, 4], max_new_tokens=8,
-                          deadline_ms=60000.0)
-            rb = e.submit("lf", [5, 6, 7, 8], max_new_tokens=4,
-                          deadline_ms=60000.0)
-        a, b = ra.wait(timeout=120.0), rb.wait(timeout=120.0)
-        assert a is not None and a.status == "ok", a and a.error
-        assert b is not None and b.status == "ok", b and b.error
-        assert np.array_equal(a.outputs["tokens"],
-                              _alone(CFG, PARAMS, [1, 2, 3, 4], 8))
-        assert np.array_equal(b.outputs["tokens"],
-                              _alone(CFG, PARAMS, [5, 6, 7, 8], 4))
-        assert _tm.counter_total("kv_block_evictions_total") >= 1
-        assert _tm.counter_total("conv_state_resets_total") >= 3
-        assert e._models["lf"].cache.slots.in_use == 0
-    finally:
-        e.stop()
-
-
-def test_prefix_cache_declines_and_counts(cache_dir, telemetry_on):
-    """FLAGS_prefix_cache is on by default: for a model whose layers keep a
-    window there is no index, each admission is counted under the reason
-    Granite's is, and two requests with one prompt give the tokens of the
-    prompt alone (a hit would have started the second at pos 12 with no
-    window)."""
-    assert fluid.get_flags(["FLAGS_prefix_cache"])["FLAGS_prefix_cache"]
-    e = _engine(CFG, PARAMS, 40)
-    try:
-        prompt = [3, 1, 4, 1, 5, 9, 2, 6, 5, 3, 5, 8, 9, 7]
-        want = _alone(CFG, PARAMS, prompt, 9)
-        for _ in range(2):
-            r = e.generate("lf", prompt, max_new_tokens=9,
-                           deadline_ms=60000.0)
-            assert r.status == "ok" and r.phases["cached_tokens"] == 0
-            assert np.array_equal(r.outputs["tokens"], want)
-        # the hand-off of a prefill replica has nothing to transfer
-        assert e.handoff_prefill_upto("lf", len(prompt)) == 0
-        assert _counters("prefix_cache_declined_total") == {
-            "prefix_cache_declined_total{model=lf,reason=recurrent_state}": 2}
-        assert not _counters("prefix_cache_hit_tokens_total")
-    finally:
-        e.stop()
-
-
-def test_speculation_is_refused(cache_dir):
-    draft = dm.truncate_decoder(CFG, PARAMS, layers=2)
-    assert draft[0].layer_types == KINDS[:2] and draft[0].dense_layers == 1
-    e = _engine(CFG, PARAMS, 16, buckets="2", start=False)
-    with _flags(kv_block_size=BS), pytest.raises(ValueError,
-                                                 match="recurrent"):
-        e.add_model("lf2", (CFG, PARAMS), kv_blocks=16, draft=draft,
-                    speculative_k=2)
-    # without a draft there is nothing to speculate with: k is ignored
-    assert e.spec("lf")["speculative_k"] == 0
-
-
-def test_export_adoption_and_history_are_refused_with_their_reason(
-        cache_dir, telemetry_on):
-    with _flags(session_migration=True):
-        e = _engine(CFG, PARAMS, 16, buckets="2")
-        try:
-            fault_injection.arm("serving.decode_step:delay:1")
-            streamed = threading.Event()
-            done = e.submit("lf", [1, 2, 3, 4, 5], max_new_tokens=40,
-                            deadline_ms=60000.0,
-                            on_token=lambda *a: streamed.set())
-            assert streamed.wait(60.0)
-            with pytest.raises(ValueError, match="recurrent_state"):
-                e.export_session(done.req_id)
-            fault_injection.disarm()
-            with e._cond:        # between steps: the carry is donated
-                block = e._models["lf"].cache.export_block(1)
-            assert e.adopt_kv_block("lf", "00" * 32, block) \
-                == "rejected:recurrent_state"
-            assert _counters("kv_migrate_refused_total") == {
-                "kv_migrate_refused_total{reason=recurrent_state}": 2}
-            r = done.wait(timeout=120.0)
-            assert r.status == "ok"
-            assert np.array_equal(r.outputs["tokens"],
-                                  _alone(CFG, PARAMS, [1, 2, 3, 4, 5], 40))
-            # 45 positions, 11 full blocks: no history block was published
-            assert not _counters("kv_history_published_total")
-        finally:
-            fault_injection.disarm()
-            e.stop()
 
 
 def test_step_span_gauge_and_prewarm_event(cache_dir, telemetry_on, tmp_path):
@@ -667,7 +357,7 @@ def test_step_span_gauge_and_prewarm_event(cache_dir, telemetry_on, tmp_path):
     slots' bytes; Granite's names are not used; the prewarm event names the
     attention path."""
     with _flags(tracing=True, telemetry_dir=str(tmp_path)):
-        e = _engine(CFG, PARAMS, 16, buckets="2")
+        e = _engine(CFG, PARAMS, 16, buckets="2", name="lf")
         try:
             e.prewarm()
             r = e.generate("lf", [1, 2, 3], max_new_tokens=4,
@@ -677,11 +367,7 @@ def test_step_span_gauge_and_prewarm_event(cache_dir, telemetry_on, tmp_path):
             e.stop()
         _trc.flush()
         _tm.flush()
-    records = [json.loads(line) for fn in os.listdir(tmp_path)
-               if fn.startswith("trace-")
-               for line in open(os.path.join(tmp_path, fn))]
-    steps = [s["attrs"] for s in records
-             if s.get("name") == "serving.decode_step"]
+    steps = fam.step_spans(tmp_path)
     per_slot = 3 * 2 * 64 * 4
     assert steps and all(s["conv_state_lanes"] == 1
                          and s["conv_state_bytes"] == per_slot
@@ -697,9 +383,7 @@ def test_step_span_gauge_and_prewarm_event(cache_dir, telemetry_on, tmp_path):
     assert "ssm_state_bytes{model=lf}" not in gauges
     assert gauges["moe_experts_hit{model=lf}"] == 2.0
     assert _tm.counter_total("moe_tokens_routed_total") > 0
-    with open(os.path.join(tmp_path, "steps.jsonl")) as fp:
-        warm = [ev for ev in map(json.loads, fp)
-                if ev["ev"] == "serving_prewarm"]
+    warm = fam.prewarm_events(tmp_path)
     assert warm and all(ev["model"] == "lf" and ev["attention"] == "gather"
                         for ev in warm)
 
@@ -729,9 +413,8 @@ def test_published_sizes_give_the_issues_bytes():
     """At the published widths and the configuration's cut: 4,096 B of K and
     V a token over 2 layers, 65,536 B a block, 57,344 B of windows a
     sequence, 5,177,950,976 parameters."""
-    model = _load("benchmark", "models", "lfm2_moe_decoder.py")
-    with open(os.path.join(ROOT, "benchmark", "configs",
-                           "lfm2-24b-a2b-serve.json")) as fp:
+    model = fam.load("benchmark", "models", "lfm2_moe_decoder.py")
+    with open(fam.config_file("lfm2-24b-a2b-serve.json")) as fp:
         config = json.load(fp)
     cfg = model.decoder_config(config)
     assert (cfg.layers, len(cfg.conv_layers), len(cfg.attn_layers),
@@ -771,48 +454,3 @@ def test_config_refuses_what_no_block_computes():
                          dense_layers=1, dense_ffn=8)
 
 
-def test_bundle_roundtrip(tmp_path):
-    d = dm.save_decoder(str(tmp_path / "lf"), CFG16, PARAMS16)
-    cfg, params = dm.load_decoder(d)
-    assert cfg.to_dict() == CFG16.to_dict() and cfg.arch == "lfm2_moe"
-    assert all(np.array_equal(np.asarray(params[k]).view(np.uint16),
-                              np.asarray(v).view(np.uint16))
-               for k, v in PARAMS16.items())
-
-
-def test_serve_tool_writes_and_serves_an_lfm2_bundle(tmp_path, cache_dir):
-    """tools/serve.py builds a demo bundle from the benchmark's
-    configuration file (its tiny sizes), and the engine serves that
-    directory at the defaults: tokens equal the unpaged loop's."""
-    import sys
-
-    sys.path.insert(0, os.path.join(ROOT, "tools"))
-    try:
-        from serve import save_demo_decoder
-    finally:
-        sys.path.pop(0)
-    d = save_demo_decoder(
-        str(tmp_path / "dec"), config=os.path.join(
-            ROOT, "benchmark", "configs", "lfm2-24b-a2b-serve.json"))
-    cfg, params = dm.load_decoder(d)
-    assert (cfg.arch, cfg.dtype, cfg.kv_dtype) == ("lfm2_moe", "bf16",
-                                                   "bf16")
-    assert (cfg.layer_types, cfg.dense_layers, cfg.experts,
-            cfg.experts_per_token, cfg.conv_taps, cfg.rope_theta) == (
-        ("conv", "attention", "conv", "conv"), 1, 8, 2, 3, 1e6)
-    assert dm.load_draft(d)[0].layer_types == ("conv",)
-    with _flags(kv_block_size=BS):
-        e = DecodeEngine(buckets="2", deadline_ms=60000.0)
-        e.add_model("lf", d, kv_blocks=16)
-    e.start()
-    try:
-        assert e.spec("lf")["arch"] == "lfm2_moe" \
-            and e.spec("lf")["kv_dtype"] == "bf16" \
-            and e.spec("lf")["speculative_k"] == 0
-        r = e.generate("lf", [5, 6, 7], max_new_tokens=6,
-                       deadline_ms=60000.0)
-        assert r.status == "ok", r.error
-        assert np.array_equal(r.outputs["tokens"],
-                              _alone(cfg, params, [5, 6, 7], 6))
-    finally:
-        e.stop()

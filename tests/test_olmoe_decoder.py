@@ -1,15 +1,16 @@
-"""The routed-expert decoder block (paddle_tpu/models/olmoe.py) through the
-same step makers, cache and engine as the GPT-2 block, against its plain
-reference (benchmark/reference/olmoe_ref.py, the file the benchmark uses):
-logits at every position in float32 and in bfloat16, the chosen experts,
-paged against unpaged, the multi-token step, the engine (mixed lanes,
-prefix-cache hit, preemption with recompute), the bf16 KV residency and the
-bundle round trip.  Tiny sizes on the CPU: 2 layers, hidden 64, 4 heads x
-16, 8 experts of width 32, top 2, vocab 97, 64 positions."""
+"""What is the routed-expert decoder block's own (paddle_tpu/models/
+olmoe.py): logits at every position in float32 and in bfloat16 against its
+plain reference (benchmark/reference/olmoe_ref.py, the file the benchmark
+uses) and the reference broken, the chosen experts, the engine with a
+prefix index (mixed lanes, prefix-cache hit, preemption with recompute), the
+step's span, the bf16 KV residency and the bundle a GPT-2 wrote before the
+config knew architectures.  The contract it shares with every family (paged
+against unpaged, the multi-token step, the bundle round trip, the serve
+tool) is tests/test_decoder_families.py's, over its row of
+tests/decoder_families.py, whose tiny sizes these are: 2 layers, hidden 64,
+4 heads x 16, 8 experts of width 32, top 2, vocab 97, 64 positions."""
 
-import contextlib
-import glob
-import importlib.util
+import functools
 import inspect
 import json
 import os
@@ -21,37 +22,30 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-import paddle_tpu as fluid
+import decoder_families as fam
 from paddle_tpu.core import telemetry as _tm
 from paddle_tpu.core import tracing as _trc
-from paddle_tpu.models import olmoe
-from paddle_tpu.serving import DecodeEngine
 from paddle_tpu.serving import decode_model as dm
 from paddle_tpu.serving import kv_cache as kvc
 from paddle_tpu.utils import fault_injection
 
-ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+ref = fam.load("benchmark", "reference", "olmoe_ref.py")
+BS = fam.BS
+(CFG, PARAMS), (CFG16, PARAMS16) = (
+    fam.ROWS["olmoe"].configs[k] for k in ("f32", "bf16"))
+run_paged = fam.run_paged
+_sequences = fam.sequences
+_flags = fam.flags
+_fp8_rounded = fam.fp8_rounded
+_unpaged = fam.alone
 
 
-def _load_ref():
-    spec = importlib.util.spec_from_file_location(
-        "olmoe_ref", os.path.join(ROOT, "benchmark", "reference",
-                                  "olmoe_ref.py"))
-    module = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(module)
-    return module
+def _engine(cfg, params, kv_blocks, buckets="4"):
+    return fam.engine(cfg, params, kv_blocks, buckets, name="moe")
 
 
-ref = _load_ref()
-
-BS = 4
-CFG = dm.DecoderConfig(arch="olmoe", vocab=97, layers=2, heads=4, head_dim=16,
-                       ffn=32, max_seq=64, experts=8, experts_per_token=2)
-CFG16 = CFG.replace(dtype="bf16")
 # normal(0, 0.05): at this hidden size the family's 0.02 leaves the layers'
 # share of the residual stream, and so a fault's mark on the logits, small
-PARAMS = olmoe.init_params(CFG, seed=3, std=0.05)
-PARAMS16 = olmoe.init_params(CFG16, seed=3, std=0.05)
 # the reference reads the source's keys
 REF_CONFIG = {"num_attention_heads": 4, "hidden_size": 64,
               "rms_norm_eps": 1e-5, "rope_theta": 10000.0, "num_experts": 8,
@@ -77,70 +71,6 @@ def _ref_logits(params, tokens, return_routing=False):
     return jax.tree_util.tree_map(np.asarray, out)
 
 
-def _sequences(n, seed=0, lo=5, hi=14, n_decode=8):
-    rng = np.random.RandomState(seed)
-    return [(list(rng.randint(0, CFG.vocab, rng.randint(lo, hi))), n_decode)
-            for _ in range(n)]
-
-
-def run_paged(cfg, params, seqs, width=1, blocks=40, table_seed=5):
-    """Every (prompt, n_decode) of ``seqs`` in its own lane through the
-    paged step and a real pool with a shuffled block table: the prompt one
-    token a step (``width`` a step for the multi-token step), then the
-    step's own argmax.  -> per lane (tokens fed, logits [n, vocab] of every
-    position fed), and per step the routed-token counts (None for width >
-    1 or a block without experts)."""
-    kv = kvc.KVCacheConfig(cfg.layers, cfg.heads, cfg.head_dim, BS, blocks,
-                           cfg.kv_dtype or "f32")
-    cache = kvc.PagedKVCache(kv)
-    maxb = cfg.max_seq // BS
-    b = len(seqs)
-    order = iter(np.random.RandomState(table_seed).permutation(
-        np.arange(1, blocks)))
-    tables = np.full((b, maxb), -1, np.int32)
-    total = [len(p) + n for p, n in seqs]
-    for i, t in enumerate(total):
-        for j in range(-(-t // BS)):
-            tables[i, j] = next(order)
-    make = dm.make_paged_step(cfg, kv) if width == 1 \
-        else dm.make_paged_step_multi(cfg, kv, width)
-    step = jax.jit(make, donate_argnums=(0,))
-    jparams = {k: jnp.asarray(v) for k, v in params.items()}
-    fed = [list(p) for p, _ in seqs]          # grows by the step's argmax
-    logits = [[] for _ in seqs]
-    routed = []
-    while any(len(lg) < t for lg, t in zip(logits, total)):
-        tok = np.zeros((b, width), np.int32)
-        pos = np.zeros((b, width), np.int32)
-        lens = np.zeros((b, width), np.int32)
-        cols = []
-        for i in range(b):
-            at = len(logits[i])
-            # a lane feeds what it already knows: prompt tokens, then the
-            # one token its last step chose
-            n = max(min(width, len(fed[i]) - at, total[i] - at), 0)
-            cols.append(n)
-            for j in range(width):
-                jj = min(j, n - 1)
-                if n:
-                    tok[i, j] = fed[i][at + jj]
-                    pos[i, j] = at + jj
-                    lens[i, j] = at + jj + 1
-        args = (tok, pos, tables, lens) if width > 1 \
-            else (tok[:, 0], pos[:, 0], tables, lens[:, 0])
-        carry, nxt, lg, *extras = step(cache.carry(), jparams, *args)
-        cache.replace_carry(carry)
-        routed.append(np.asarray(extras[0]) if extras else None)
-        nxt = np.asarray(nxt).reshape(b, width)
-        lg = np.asarray(lg).reshape(b, width, -1)
-        for i, n in enumerate(cols):
-            for j in range(n):
-                logits[i].append(lg[i, j])
-            if n and len(logits[i]) == len(fed[i]) < total[i]:
-                fed[i].append(int(nxt[i, n - 1]))
-    return [(f, np.stack(lg)) for f, lg in zip(fed, logits)], routed
-
-
 def _worst(out, params):
     return max(float(np.abs(lg - _ref_logits(params, toks)).max())
                for toks, lg in out)
@@ -149,14 +79,9 @@ def _worst(out, params):
 # -- 1. float32 against the reference, and the reference broken ----------------
 
 
-F32_OUT = {}
-
-
+@functools.lru_cache(None)
 def _f32_out():
-    if not F32_OUT:
-        F32_OUT["out"], F32_OUT["routed"] = run_paged(
-            CFG, PARAMS, _sequences(3))
-    return F32_OUT["out"]
+    return run_paged(CFG, PARAMS, _sequences(3))[0]
 
 
 def test_f32_logits_equal_the_reference_at_every_position():
@@ -203,11 +128,6 @@ def test_f32_tolerance_catches_a_broken_reference(how):
 
 
 # -- 2. bfloat16 as served, and what falls outside its tolerance ---------------
-
-
-def _fp8_rounded(params):
-    return {k: np.asarray(jnp.asarray(v).astype(jnp.float8_e4m3fn)
-                          .astype(jnp.bfloat16)) for k, v in params.items()}
 
 
 def test_bf16_logits_within_tolerance_and_lower_precision_outside():
@@ -266,77 +186,10 @@ def test_idle_lanes_route_nothing():
 # -- 4. paged against unpaged: bitwise -----------------------------------------
 
 
-@pytest.mark.parametrize("cfg,params", [(CFG, PARAMS), (CFG16, PARAMS16)],
-                         ids=["f32", "bf16"])
-def test_paged_is_bitwise_equal_to_unpaged(cfg, params):
-    prompt, n = [7, 3, 9, 1, 4, 4, 8], 10
-    toks, hist = dm.unpaged_generate(cfg, params, prompt, n,
-                                     pad_len=cfg.max_seq, return_logits=True)
-    out, _ = run_paged(cfg, params, [(prompt, n)])
-    fed, logits = out[0]
-    assert fed[len(prompt):] == toks
-    # the logits of the last prompt position and of every decoded one
-    assert np.array_equal(logits[len(prompt) - 1:len(prompt) - 1 + n],
-                          np.stack(hist))
-
-
 # -- 5. the multi-token step ---------------------------------------------------
 
 
-def test_multi_token_step_equals_single():
-    seqs = _sequences(3, seed=4)
-    single, _ = run_paged(CFG, PARAMS, seqs)
-    multi, routed = run_paged(CFG, PARAMS, seqs, width=4)
-    for (t1, l1), (t4, l4) in zip(single, multi):
-        assert t1 == t4
-        assert float(np.abs(l1 - l4).max()) < TOL_F32
-    assert _worst(multi, PARAMS) < TOL_F32
-    # its routed counts are summed over the columns
-    assert routed[0].shape == (CFG.layers, CFG.experts)
-
-
 # -- 6. through the engine ------------------------------------------------------
-
-
-@contextlib.contextmanager
-def _flags(**kv):
-    kv = {"FLAGS_" + k: v for k, v in kv.items()}
-    old = fluid.get_flags(list(kv))
-    fluid.set_flags(kv)
-    try:
-        yield
-    finally:
-        fluid.set_flags(old)
-
-
-@pytest.fixture(scope="module")
-def cache_dir(tmp_path_factory):
-    d = str(tmp_path_factory.mktemp("cc"))
-    old = fluid.get_flags(["FLAGS_compile_cache_dir"])
-    fluid.set_flags({"FLAGS_compile_cache_dir": d})
-    yield d
-    fluid.set_flags(old)
-
-
-@pytest.fixture()
-def telemetry_on():
-    fluid.set_flags({"FLAGS_telemetry": True})
-    _tm.reset()
-    yield
-    _tm.reset()
-    fluid.set_flags({"FLAGS_telemetry": False})
-
-
-def _engine(cfg, params, kv_blocks, buckets="4"):
-    with _flags(kv_block_size=BS):
-        e = DecodeEngine(buckets=buckets, deadline_ms=60000.0)
-        e.add_model("moe", (cfg, params), kv_blocks=kv_blocks)
-    return e.start()
-
-
-def _unpaged(cfg, params, prompt, n):
-    return np.asarray(dm.unpaged_generate(cfg, params, prompt, n,
-                                          pad_len=cfg.max_seq), np.int32)
 
 
 @pytest.mark.parametrize("cfg,params", [(CFG, PARAMS), (CFG16, PARAMS16)],
@@ -395,33 +248,19 @@ def test_step_span_carries_routing_only_for_a_routed_block(cache_dir,
     """Traced, the olmoe step's span says how many experts were hit, the
     fullest expert's load and the tokens routed; the counters move; a
     GPT-2 step's span has none of it."""
-    fluid.set_flags({"FLAGS_tracing": True,
-                     "FLAGS_telemetry_dir": str(tmp_path)})
-    gcfg = dm.DecoderConfig(vocab=31, layers=2, heads=2, head_dim=8,
-                            max_seq=48)
     try:
-        for name, cfg, params in (
-                ("moe", CFG, PARAMS),
-                ("toy", gcfg, dm.init_decoder_params(gcfg, seed=7))):
-            with _flags(kv_block_size=BS):
-                e = DecodeEngine(buckets="2", deadline_ms=60000.0)
-                e.add_model(name, (cfg, params), kv_blocks=16)
-            e.start()
-            try:
-                r = e.generate(name, [1, 2, 3], max_new_tokens=4,
-                               deadline_ms=60000.0)
-                assert r.status == "ok", r.error
-            finally:
-                e.stop()
-        _trc.flush()
-        recs = []
-        for path in glob.glob(str(tmp_path / "trace-*.jsonl")):
-            with open(path) as fp:
-                recs += [json.loads(line) for line in fp if line.strip()]
-        steps = [s for s in recs if s.get("t") == "span"
-                 and s.get("name") == "serving.decode_step"]
-        moe = [s["attrs"] for s in steps if s["attrs"]["model"] == "moe"]
-        toy = [s["attrs"] for s in steps if s["attrs"]["model"] == "toy"]
+        with _flags(tracing=True, telemetry_dir=str(tmp_path)):
+            for name, (cfg, params) in (("moe", (CFG, PARAMS)),
+                                        ("toy", fam.ROWS["gpt2"].f32)):
+                e = fam.engine(cfg, params, 16, buckets="2", name=name)
+                try:
+                    r = e.generate(name, [1, 2, 3], max_new_tokens=4,
+                                   deadline_ms=60000.0)
+                    assert r.status == "ok", r.error
+                finally:
+                    e.stop()
+            _trc.flush()
+        moe, toy = (fam.step_spans(tmp_path, name) for name in ("moe", "toy"))
         assert len(moe) == 6 and len(toy) == 6
         # a span is one iteration of the one-ahead loop: it dispatches a
         # step and reads the step before.  The first reads none, and the
@@ -438,7 +277,6 @@ def test_step_span_carries_routing_only_for_a_routed_block(cache_dir,
             == 6 * CFG.layers * CFG.experts_per_token
     finally:
         _trc.reset()
-        fluid.set_flags({"FLAGS_tracing": False, "FLAGS_telemetry_dir": ""})
 
 
 # -- 7. bf16 KV residency -------------------------------------------------------
@@ -482,10 +320,8 @@ def test_bf16_session_export_is_refused_loudly(cache_dir, telemetry_on):
     """codec.py frames arrays by numpy's dtype string, which bfloat16 does
     not have: the migration export refuses a bf16 pool, counted under
     reason="dtype", before anything is encoded."""
-    with _flags(session_migration=True, kv_block_size=BS):
-        e = DecodeEngine(buckets="2", deadline_ms=60000.0)
-        e.add_model("moe", (CFG16, PARAMS16), kv_blocks=16)
-        e.start()
+    with _flags(session_migration=True):
+        e = _engine(CFG16, PARAMS16, 16, buckets="2")
         try:
             # 1 ms a step keeps the request alive while it is exported
             fault_injection.arm("serving.decode_step:delay:1")
@@ -507,18 +343,6 @@ def test_bf16_session_export_is_refused_loudly(cache_dir, telemetry_on):
 
 
 # -- 8. the bundle --------------------------------------------------------------
-
-
-@pytest.mark.parametrize("cfg,params", [(CFG, PARAMS), (CFG16, PARAMS16)],
-                         ids=["f32", "bf16"])
-def test_bundle_roundtrip(cfg, params, tmp_path):
-    d = dm.save_decoder(str(tmp_path / "b"), cfg, params)
-    got_cfg, got = dm.load_decoder(d)
-    assert got_cfg.to_dict() == cfg.to_dict()
-    assert set(got) == set(params)
-    for k, v in params.items():
-        assert got[k].dtype == v.dtype and got[k].shape == v.shape
-        assert np.array_equal(got[k].view(np.uint8), v.view(np.uint8)), k
 
 
 def test_a_gpt2_bundle_written_before_this_block_still_loads(tmp_path):
@@ -556,33 +380,3 @@ def test_config_refuses_what_no_block_computes():
         dm.DecoderConfig(vocab=9, layers=1, heads=1, head_dim=8, dtype="bf16")
 
 
-def test_serve_tool_writes_and_serves_an_olmoe_bundle(tmp_path, cache_dir):
-    """tools/serve.py builds a demo bundle from a benchmark configuration
-    file (its tiny sizes), and the engine serves that directory: tokens
-    equal the unpaged loop's."""
-    import sys
-
-    sys.path.insert(0, os.path.join(ROOT, "tools"))
-    try:
-        from serve import save_demo_decoder
-    finally:
-        sys.path.pop(0)
-    d = save_demo_decoder(
-        str(tmp_path / "dec"), config=os.path.join(
-            ROOT, "benchmark", "configs", "olmoe-1b-7b-serve.json"))
-    cfg, params = dm.load_decoder(d)
-    assert (cfg.arch, cfg.dtype, cfg.kv_dtype) == ("olmoe", "bf16", "bf16")
-    assert (cfg.experts, cfg.experts_per_token, cfg.ffn) == (8, 2, 32)
-    assert dm.load_draft(d)[0].arch == "olmoe"
-    with _flags(kv_block_size=BS):
-        e = DecodeEngine(buckets="2", deadline_ms=60000.0)
-        e.add_model("moe", d, kv_blocks=16, speculative_k=0)
-    e.start()
-    try:
-        r = e.generate("moe", [5, 6, 7], max_new_tokens=6,
-                       deadline_ms=60000.0)
-        assert r.status == "ok", r.error
-        assert np.array_equal(r.outputs["tokens"],
-                              _unpaged(cfg, params, [5, 6, 7], 6))
-    finally:
-        e.stop()

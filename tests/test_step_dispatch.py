@@ -16,7 +16,6 @@ alone: a warmed key does not flatten its arguments, an unwarmed one compiles
 once and counts the miss, arguments of another shape under a warmed key
 raise and never run."""
 
-import contextlib
 import glob
 import json
 
@@ -25,43 +24,24 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
+import decoder_families as fam
 import paddle_tpu as fluid
 from paddle_tpu.core import executor as executor_mod
 from paddle_tpu.core import telemetry as _tm
 from paddle_tpu.core import tracing as _trc
 from paddle_tpu.core.executor import CarriedStepFn
-from paddle_tpu.models import exaone_moe as em
-from paddle_tpu.models import granite_hybrid as gh
-from paddle_tpu.models import olmoe
 from paddle_tpu.serving import DecodeEngine
 from paddle_tpu.serving import decode_model as dm
 from paddle_tpu.serving import kv_cache as kvc
 
-BS = 4
-GPT2 = dm.DecoderConfig(vocab=31, layers=2, heads=2, head_dim=8, max_seq=48)
-OLMOE = dm.DecoderConfig(arch="olmoe", vocab=97, layers=2, heads=4,
-                         head_dim=16, ffn=32, max_seq=64, experts=8,
-                         experts_per_token=2)
-GRANITE = dm.DecoderConfig(
-    arch="granite_hybrid", vocab=97, layers=4, heads=4, kv_heads=2,
-    head_dim=16, ffn=48, max_seq=64,
-    layer_types=("mamba", "mamba", "attention", "mamba"), ssm_heads=8,
-    ssm_head_dim=16, ssm_state=32, ssm_conv=4, embedding_multiplier=2.0,
-    residual_multiplier=0.22, attention_multiplier=0.25, logits_scaling=8.0)
-EXAONE = dm.DecoderConfig(
-    arch="exaone_moe", vocab=61, layers=3, heads=8, kv_heads=2, head_dim=8,
-    hidden_size=48, ffn=16, max_seq=64,
-    layer_types=("window", "attention", "window"), window=8, dense_layers=1,
-    dense_ffn=48, experts=16, experts_per_token=4, shared_ffn=16,
-    routed_scaling=2.5, rope_theta=1e6)
+BS = fam.BS
 # what the cache holds beside K/V pools decides the packed columns
-MODELS = {
-    "attention_only": (GPT2, dm.init_decoder_params(GPT2, seed=7)),
-    "routed": (OLMOE, olmoe.init_params(OLMOE, seed=3, std=0.05)),
-    "state_slots": (GRANITE, gh.init_params(GRANITE, seed=3, std=0.3)),
-    "window_rings": (EXAONE, em.init_params(EXAONE, seed=3, std=0.3,
-                                            bias_std=0.05)),
-}
+MODELS = {"attention_only": fam.ROWS["gpt2"].f32,
+          "routed": fam.ROWS["olmoe"].f32,
+          "state_slots": fam.ROWS["granite_hybrid"].f32,
+          "window_rings": fam.ROWS["exaone_moe"].f32}
+pytestmark = pytest.mark.usefixtures("cache_dir")
+_flags = fam.flags
 KINDS = sorted(MODELS)
 FIXED = {"attention_only": ["tok", "src", "pos", "lens", "tables"],
          "routed": ["tok", "src", "pos", "lens", "tables"],
@@ -76,35 +56,6 @@ def _unpaged(kind, prompt, max_new):
     return [int(t) for t in dm.unpaged_generate(
         cfg, params, prompt, max_new, pad_len=cfg.max_seq,
         ring_len=ring * BS if ring else None)]
-
-
-@contextlib.contextmanager
-def _flags(**kv):
-    kv = {"FLAGS_" + k: v for k, v in kv.items()}
-    old = fluid.get_flags(list(kv))
-    fluid.set_flags(kv)
-    try:
-        yield
-    finally:
-        fluid.set_flags(old)
-
-
-@pytest.fixture(scope="module", autouse=True)
-def cache_dir(tmp_path_factory):
-    d = str(tmp_path_factory.mktemp("cc"))
-    old = fluid.get_flags(["FLAGS_compile_cache_dir"])
-    fluid.set_flags({"FLAGS_compile_cache_dir": d})
-    yield d
-    fluid.set_flags(old)
-
-
-@pytest.fixture()
-def telemetry_on():
-    fluid.set_flags({"FLAGS_telemetry": True})
-    _tm.reset()
-    yield
-    _tm.reset()
-    fluid.set_flags({"FLAGS_telemetry": False})
 
 
 @pytest.fixture()

@@ -42,25 +42,22 @@ whole context by the layer's kind, and the routed layers gain
 device time) and ``--experts`` names the forms of the routed layer to run,
 one after another in this one process over the same weights, a result line
 each: ``block`` (what the program picks: ``moe_experts.routed_experts``),
-``dense`` (the einsums over all experts, the fallback), ``kernel`` (the
+``dense`` (the einsums over all experts, the fallback) and ``kernel`` (the
 Pallas kernel that reads the hit experts alone, whatever the shape rule
-says) and ``ragged`` (this file's ``experts_ragged``: tokens sorted by
-expert, ``jax.lax.ragged_dot``).  Each line has ``moe/experts``' ms a step,
-the expert bytes that form reads a step (``dense`` all of them, the others
-the experts this step's tokens hit, from the step's own routed counts) and
-bytes/s: the comparison the block's choice was made by, not an option of the
-program.  (XLA's expansion of ``ragged_dot`` loses the scope, so that form's
-expert time lands in ``other``: compare its ``busy_ms_per_step``.)  For
+says).  Each line has ``moe/experts``' ms a step, the expert bytes that form
+reads a step (``dense`` all of them, the others the experts this step's
+tokens hit, from the step's own routed counts) and bytes/s: the comparison
+the block's choice was made by, not an option of the program.  For
 ``--config nemotron-3-nano-30b-a3b-serve --blocks 2048`` the step is all 52
 blocks of one sublayer each (``--layers 6`` keeps ``M E M E M *``): the
 ``ssm`` scopes with B and C in 8 groups, ``attn/kv_read`` for 32 query heads
 over 2, ``moe/router``, ``moe/experts`` and ``moe/shared`` on the layers that
 are experts alone; the bytes are ``benchmark/nemotron_cost.py``'s, and
 ``--experts block,dense,kernel`` compares the two-matrix forms
-(``moe_experts.relu2_experts``; ``ragged`` is the three-matrix form's).
+(``moe_experts.relu2_experts``).
 
     chiprun -- python tools/decode_step_probe.py --config \
-        lfm2-24b-a2b-serve --blocks 2048 --experts dense,ragged,kernel
+        lfm2-24b-a2b-serve --blocks 2048 --experts dense,kernel
 
 ``--check`` leaves the model out and compares the step's attention alone,
 at the configuration's shapes, on one layer's random pools and the same
@@ -75,6 +72,7 @@ then says what the local backend's compiler made, which is not the chip's).
 """
 
 import argparse
+import importlib
 import json
 import math
 import os
@@ -89,7 +87,7 @@ sys.path.insert(0, ROOT)
 WARM_STEPS = 5
 
 # --experts: what stands in for moe_experts.routed_experts
-EXPERT_FORMS = ("block", "dense", "kernel", "ragged")
+EXPERT_FORMS = ("block", "dense", "kernel")
 
 
 def hlo_index(text):
@@ -132,31 +130,6 @@ def pool_sized(index, pool_elems):
         if elems >= pool_elems:
             found[op] = found.get(op, 0) + 1
     return found
-
-
-def experts_ragged(k):
-    """``moe_experts.routed_experts`` by sorting: each lane's ``k``
-    chosen experts become ``B * k`` rows ordered by expert, and one
-    ``ragged_dot`` per projection runs each expert over its own rows."""
-    import jax
-    import jax.numpy as jnp
-
-    def experts(h2, gates, live, wgate, wup, wdown):
-        del live                     # every lane of the probe holds a sequence
-        weight, idx = jax.lax.top_k(gates, k)
-        order = jnp.argsort(idx.reshape(-1))
-        lane = order // k
-        sizes = jnp.bincount(idx.reshape(-1), length=gates.shape[1]
-                             ).astype(jnp.int32)
-        dot = lambda x, w: jax.lax.ragged_dot(
-            x.astype(w.dtype), w, sizes,
-            preferred_element_type=jnp.float32)
-        x = h2[lane]
-        y = dot(jax.nn.silu(dot(x, wgate)) * dot(x, wup), wdown)
-        y = y * weight.reshape(-1)[order][:, None]
-        return jnp.zeros(h2.shape, jnp.float32).at[lane].add(y)
-
-    return experts
 
 
 def full_rings(kv, lanes):
@@ -501,10 +474,10 @@ def main(argv=None):
         slots += (full_rings(kv, b),)
     feed = lambda n: (cache.carry(), params, tok, lens + n - 1, tables,
                       lens + n) + slots
-    # the model's form of the routed layer: three matrices with a gate, or
-    # two (relu^2, ``up`` and ``down`` both [E, F, H])
-    two = cfg.routed_layers and "l%d_experts_up" % cfg.routed_layers[0] \
-        in params
+    # the model's form of the routed layer, as its family declares it: three
+    # matrices with a gate, or two (relu^2, ``up`` and ``down`` both [E, F, H])
+    two = importlib.import_module(
+        "paddle_tpu.models." + cfg.arch).FAMILY.expert_matrices == 2
     entry = "relu2_experts" if two else "routed_experts"
     block_experts = getattr(moe_experts, entry)
     # form -> (what stands in for it, whether it reads every expert of a
@@ -516,10 +489,7 @@ def main(argv=None):
             moe_experts.relu2_reference if two
             else moe_experts.experts_reference)(h2, gates, *w), True),
         "kernel": (moe_experts._relu2_pallas if two
-                   else moe_experts._experts_pallas, False),
-        "ragged": (experts_ragged(cfg.experts_per_token), False)}
-    if two and "ragged" in forms:
-        ap.error("--experts ragged is the three-matrix form's")
+                   else moe_experts._experts_pallas, False)}
     for form in forms:
         stand_in, reads_all = swapped[form]
         setattr(moe_experts, entry, stand_in)
