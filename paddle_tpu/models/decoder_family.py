@@ -21,7 +21,8 @@ class DecoderFamily(collections.namedtuple(
          "dense_lead", "holds_share", "own_stream_width"),
         defaults=(("f32", "bf16"), False, None, None, False, False, False))):
     """``kinds``: the kinds of layer the block computes
-    (``decode_model.LAYER_KINDS``).  ``dtypes``: the weight dtypes it is
+    (``decode_model.LAYER_KINDS``: seven of them, of which a family names
+    one to three).  ``dtypes``: the weight dtypes it is
     served in.  ``grouped_query``: its attention may have fewer KV heads than
     query heads.  ``routes``: where its feed-forward is routed experts: None
     (nowhere), ``"after_dense"`` (every layer after ``cfg.dense_layers``) or

@@ -8,7 +8,8 @@ whether it engages.  A family is chosen from what the lowering can observe
   check list; the first failing check becomes the fallback *reason*.  The
   checks live beside the kernel that owns them (``fused_ln_checks``,
   ``flash_attention_checks``, ``paged_attention_checks``,
-  ``ssm_update_checks``, ``moe_experts_checks``).
+  ``ssm_update_checks``, ``moe_experts_checks``,
+  ``latent_attention_checks``, ``kda_update_checks``).
 * **telemetry** — every decision increments
   ``pallas_kernel_used_total{kernel}`` or
   ``pallas_kernel_fallback_total{kernel,reason}`` in the telemetry
@@ -22,7 +23,7 @@ whether it engages.  A family is chosen from what the lowering can observe
   over the axis its rows are split on and enters ``per_shard`` inside
   the body: there the family's own checks decide on the shard's shape,
   as they do on one chip.  ``fused_ln`` does (ops/nn.py, on a mesh whose
-  only axis of size over 1 is the data axis); the other four families
+  only axis of size over 1 is the data axis); the other families
   and any ``fused_ln`` call outside such a wrap still decline.
 
 ``PADDLE_PALLAS_INTERPRET=1`` forces interpret-mode execution (kernels run
@@ -43,7 +44,7 @@ __all__ = ["decide", "active_kernels", "reset", "interpret_mode",
 
 # the kernel families sharing this funnel
 KERNELS = ("fused_ln", "flash_attention", "paged_attention", "ssm_update",
-           "moe_experts")
+           "moe_experts", "latent_attention", "kda_update")
 
 _lock = threading.Lock()
 _active = set()          # kernels that engaged >= 1 time this process

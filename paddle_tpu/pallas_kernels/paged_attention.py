@@ -59,6 +59,18 @@ columns itself and folds the output's owned columns back, so VMEM holds
 ``B * H * D`` values of each and not ``B * H * KH * D`` (64 heads over 8 of
 128: 1 MB for 32 lanes where the spread layout takes 8.4).
 
+The **latent** form (``latent_attention``) serves absorbed multi-head latent
+attention: a layer keeps ONE row a token, ``W`` wide (a compressed K/V of
+``rank`` values and the key's shared part), in one pool ``[num_blocks,
+block_size, W]``; every query head (``[B, H, W]``, the key's up-projection
+already folded into it) scores all ``W`` columns of that one cached head,
+and the value is the row's first ``rank`` columns, so a block is fetched
+once and serves as K and as V (-> ``[B, H, rank]``).  The same kernel with
+one pool and one chunk buffer, under its own name in a trace
+(``LATENT_KERNEL_NAME``).  ``W`` and ``rank`` are whole 128-lane tiles: the
+cache rounds a row up to that and keeps the rest zeros (576 values lie in
+rows of 640), since a kernel fetches whole tiles of the pool's layout.
+
 ``masked_attention`` is also the core of the UNPAGED reference loop in
 decode_model.py: sharing it is what makes paged-vs-unpaged decode
 bitwise-comparable on the CPU tier.
@@ -77,10 +89,16 @@ from . import adoption
 
 __all__ = ["paged_attention", "paged_attention_reference",
            "paged_attention_checks", "attention_path", "blocks_read",
-           "masked_attention", "gather_blocks", "ring_mask", "KERNEL_NAME"]
+           "masked_attention", "gather_blocks", "ring_mask", "KERNEL_NAME",
+           "latent_attention", "latent_attention_reference",
+           "latent_attention_checks", "latent_path", "masked_latent",
+           "LATENT_KERNEL_NAME"]
 
 # the name the kernel's executions carry in a device trace
 KERNEL_NAME = "paged_attention"
+# ... and those of its latent form (one pool, the value the key's first
+# columns): a name of its own, so that a reader of one never sums the other
+LATENT_KERNEL_NAME = "latent_attention"
 
 _MASK = -1e30  # finite: a fully-masked lane softmaxes to uniform, not NaN
 
@@ -127,7 +145,8 @@ def masked_attention(q, k, v, context_lens, scale=None, window=None):
     """Single-token attention over a contiguous history: q [B, H, D],
     k/v [B, S, KH, D] with ``H`` a multiple of ``KH`` (grouped queries:
     query head ``r`` reads KV head ``r // (H // KH)``), context_lens [B]
-    -> [B, H, D].  Positions >= the context length are masked; scores are
+    -> [B, H, D] (``v`` may be narrower than ``k``, ``[B, S, KH, Dv]``: the
+    output is then ``[B, H, Dv]``).  Positions >= the context length are masked; scores are
     multiplied by ``scale`` (None: ``1 / sqrt(D)``).  With ``window`` the
     ``S`` rows are a ring (``ring_mask``) and the last ``window`` positions
     alone are attended.  Shared by the paged gather path AND the unpaged
@@ -150,7 +169,7 @@ def masked_attention(q, k, v, context_lens, scale=None, window=None):
     is stored, and both contractions accumulate in float32.  Scores,
     mask and softmax are float32 either way."""
     b, h, d = q.shape
-    s, kh = k.shape[1], k.shape[2]
+    s, kh, dv = k.shape[1], k.shape[2], v.shape[3]
     if scale is None:
         scale = 1.0 / math.sqrt(d)
     dot = functools.partial(jnp.einsum, precision=jax.lax.Precision.HIGHEST,
@@ -165,8 +184,8 @@ def masked_attention(q, k, v, context_lens, scale=None, window=None):
         seen = ring_mask(context_lens, s, window)
     sc = jnp.where(seen[:, None, :], sc, _MASK)
     p = jax.nn.softmax(sc, axis=-1)
-    out = dot("bhs,bsc->bhc", p.astype(v.dtype), v.reshape(b, s, kh * d))
-    return (out.reshape(b, h, kh, d) * own).sum(axis=2)
+    out = dot("bhs,bsc->bhc", p.astype(v.dtype), v.reshape(b, s, kh * dv))
+    return (out.reshape(b, h, kh, dv) * own).sum(axis=2)
 
 
 def gather_blocks(cache, block_tables):
@@ -329,13 +348,20 @@ def _product(rows, x, dims):
             + (by_mid[:n] + by_hi[n:2 * n])) + by_hi[:n]
 
 
-def _kernel(bt_ref, cl_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, *,
-            heads, kv_heads, head_dim, block_size, maxb, per, scale,
-            window=None):
+def _kernel(bt_ref, cl_ref, q_ref, *refs, heads, kv_heads, head_dim,
+            block_size, maxb, per, scale, window=None, value_cols=None):
     """``window`` None: a lane's chunks cover positions ``[0,
     context_len)``.  Given: the table is a ring of ``maxb == per`` slots, a
     live lane's one chunk is the ring as it lies, and ``ring_mask``'s rule
-    says which of its rows are attended."""
+    says which of its rows are attended.  ``value_cols`` given (the latent
+    form): there is one pool and one chunk buffer, and a row's value is its
+    first ``value_cols`` columns."""
+    if value_cols is None:
+        k_hbm, v_hbm, o_ref, kbuf, vbuf, sem = refs
+        pools = ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1))
+    else:
+        k_hbm, o_ref, kbuf, sem = refs
+        pools = ((k_hbm, kbuf, 0),)
     lanes = q_ref.shape[0]
     hd = kv_heads * head_dim             # the pool's width
     group = heads // kv_heads            # query heads a KV head
@@ -348,10 +374,10 @@ def _kernel(bt_ref, cl_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, *,
         return (cl_ref[b] + span - 1) // span
 
     def copies(slot, block_of):
-        """A chunk's 2 x ``per`` block copies into buffer ``slot``."""
+        """A chunk's ``per`` block copies a pool into buffer ``slot``."""
         for i in range(per):
             at = pl.ds(i * block_size, block_size)
-            for pool, buf, which in ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1)):
+            for pool, buf, which in pools:
                 yield pltpu.make_async_copy(
                     pool.at[block_of(i)], buf.at[slot, at],
                     sem.at[which, slot])
@@ -397,7 +423,7 @@ def _kernel(bt_ref, cl_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, *,
         qb = q_ref[b]
         if qb.shape[1] != hd:
             qb = jnp.concatenate([qb] * kv_heads, axis=1)
-        qx = qb * own                                    # [rows, hd]
+        qx = qb if value_cols is not None else qb * own  # [rows, hd]
 
         def chunk(c, carry):
             m, l, acc = carry
@@ -424,23 +450,28 @@ def _kernel(bt_ref, cl_ref, q_ref, k_hbm, v_hbm, o_ref, kbuf, vbuf, sem, *,
             alpha = jnp.exp(m - m_new)
             p = jnp.exp(sc - m_new)
             l = alpha * l + jnp.sum(p, axis=1, keepdims=True)
-            acc = alpha * acc + _product(p, vbuf[slot], _NN)
+            value = vbuf[slot] if value_cols is None \
+                else kbuf[slot][:, :value_cols]
+            acc = alpha * acc + _product(p, value, _NN)
             return m_new, l, acc
 
         _m, l, acc = jax.lax.fori_loop(
             0, n, chunk, (jnp.full((rows, 1), _MASK, jnp.float32),
                           jnp.zeros((rows, 1), jnp.float32),
-                          jnp.zeros((rows, hd), jnp.float32)))
+                          jnp.zeros((rows, value_cols or hd), jnp.float32)))
         # an idle lane has l == 0 and acc == 0: zeros out, not 0 / 0
-        out = acc / jnp.where(l > 0, l, 1.0) * own
-        if group == 1:
-            # each column belongs to one row: fold the rows
-            out = jnp.sum(out, axis=0, keepdims=True)
-        elif o_ref.shape[2] != hd:
-            # compact: a row's owned columns are its KV head's D; every
-            # other piece is zeros
-            out = sum(out[:, i * head_dim:(i + 1) * head_dim]
-                      for i in range(kv_heads))
+        out = acc / jnp.where(l > 0, l, 1.0)
+        if value_cols is None:
+            # (the latent form's one cached head is every row's: no mask)
+            out = out * own
+            if group == 1:
+                # each column belongs to one row: fold the rows
+                out = jnp.sum(out, axis=0, keepdims=True)
+            elif o_ref.shape[2] != hd:
+                # compact: a row's owned columns are its KV head's D; every
+                # other piece is zeros
+                out = sum(out[:, i * head_dim:(i + 1) * head_dim]
+                          for i in range(kv_heads))
         o_ref[b] = out.astype(o_ref.dtype)
         return g + n
 
@@ -520,3 +551,123 @@ def paged_attention(q, k_cache, v_cache, block_tables, context_lens,
                                  context_lens, scale, window=window)
     return paged_attention_reference(q, k_cache, v_cache, block_tables,
                                      context_lens, scale, window)
+
+
+# -- the latent form ---------------------------------------------------------
+
+def masked_latent(q, rows, context_lens, scale, rank):
+    """Absorbed latent attention over a contiguous history: q [B, H, W]
+    against ``rows`` [B, S, W], one cached head whose value is its first
+    ``rank`` columns -> [B, H, rank].  ``masked_attention`` with that one
+    head, so the gather path and the unpaged loop stay bitwise-comparable."""
+    rows = rows[:, :, None, :]
+    return masked_attention(q, rows, rows[..., :rank], context_lens, scale)
+
+
+def latent_attention_reference(q, pool, block_tables, context_lens, scale,
+                               rank):
+    """The jnp path: gather the table's blocks into contiguous rows, then
+    ``masked_latent``."""
+    with jax.named_scope("kv_gather"):
+        rows = gather_blocks(pool, block_tables)
+    return masked_latent(q, rows, context_lens, scale, rank)
+
+
+def latent_vmem_bytes(q_shape, pool_shape, pool_dtype, rank):
+    """What the latent kernel holds in VMEM: two chunk buffers, every
+    lane's query and its output in float32."""
+    lanes, heads, padded = q_shape
+    rows = -(-heads // 16) * 16
+    span = max(CHUNK_TOKENS, pool_shape[1])
+    return 2 * span * jnp.dtype(pool_dtype).itemsize * padded \
+        + lanes * 4 * rows * (padded + rank)
+
+
+def latent_attention_checks(q_shape, pool_shape, pool_dtype, rank):
+    """Ordered (reason, ok) pairs for adoption.decide(): what the latent
+    kernel needs of the query ``[B, H, W]``, of the pool ``[num_blocks,
+    block_size, W]`` in ``pool_dtype`` and of ``rank``, the leading columns
+    of a row that are its value."""
+    dims = tuple(q_shape) + tuple(pool_shape) + (rank,)
+    static = all(isinstance(x, int) and x >= 0 for x in dims)
+    rank3 = len(q_shape) == 3 and len(pool_shape) == 3
+    tile = _SUBLANES.get(jnp.dtype(pool_dtype).name)
+    shaped = static and rank3 and all(x > 0 for x in dims)
+    return [
+        ("backend", adoption.interpret_mode()
+         or jax.default_backend() == "tpu"),
+        ("symbolic_shape", static),
+        ("rank", rank3),
+        ("dtype", tile is not None),
+        # the row, which the query's width is, and its value are whole
+        # 128-lane tiles
+        ("lanes", shaped and q_shape[2] == pool_shape[2]
+         and pool_shape[2] % 128 == 0 and rank % 128 == 0
+         and rank <= pool_shape[2]),
+        ("block_size", static and rank3 and tile is not None
+         and pool_shape[1] > 0 and pool_shape[1] % tile == 0),
+        ("empty", shaped),
+        ("vmem", shaped and tile is not None and latent_vmem_bytes(
+            q_shape, pool_shape, pool_dtype, rank) <= _VMEM_BUDGET),
+    ]
+
+
+def latent_path(q_shape, pool_shape, pool_dtype, rank):
+    """``"pallas"`` where the latent kernel would serve these shapes on this
+    backend, else ``"gather"``: ``latent_attention``'s rule, counted
+    nowhere."""
+    ok = all(ok for _reason, ok in
+             latent_attention_checks(q_shape, pool_shape, pool_dtype, rank))
+    return "pallas" if ok else "gather"
+
+
+def _latent_pallas(q, pool, block_tables, context_lens, scale, rank,
+                   interpret=None):
+    """q [B, H, W] against one pool [num_blocks, block_size, W] -> [B, H,
+    rank]."""
+    bb, h, width = q.shape
+    bs = pool.shape[1]
+    maxb = block_tables.shape[1]
+    per = _chunk_blocks(bs, maxb)
+    if interpret is None:
+        interpret = adoption.interpret()
+    rows = -(-h // 16) * 16
+    qx = jnp.pad(q.astype(jnp.float32), ((0, 0), (0, rows - h), (0, 0)))
+    whole = lambda i, bt, cl: (0, 0, 0)
+    out = pl.pallas_call(
+        functools.partial(_kernel, heads=h, kv_heads=1, head_dim=width,
+                          block_size=bs, maxb=maxb, per=per,
+                          scale=float(scale), value_cols=rank),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(1,),
+            in_specs=[pl.BlockSpec((bb, rows, width), whole),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((bb, rows, rank), whole),
+            scratch_shapes=[pltpu.VMEM((2, per * bs, width), pool.dtype),
+                            pltpu.SemaphoreType.DMA((1, 2))],
+        ),
+        out_shape=jax.ShapeDtypeStruct((bb, rows, rank), jnp.float32),
+        name=LATENT_KERNEL_NAME,
+        interpret=interpret,
+    )(block_tables.astype(jnp.int32).reshape(-1),
+      context_lens.astype(jnp.int32), qx, pool)
+    return out[:, :h]
+
+
+def latent_attention(q, pool, block_tables, context_lens, scale, rank):
+    """The step's attention over a latent layer's pool: q [B, H, W] (the
+    absorbed query: its latent part and its shared-key part), ``pool``
+    [num_blocks, block_size, W] -> [B, H, rank], the probabilities' sum of
+    the rows' first ``rank`` columns.  The kernel where the shape rule
+    admits it (``adoption.decide`` counts the lowering as
+    ``latent_attention``), the gather otherwise."""
+    use, _reason = adoption.decide(
+        "latent_attention",
+        latent_attention_checks(q.shape, pool.shape, pool.dtype, rank))
+    if use:
+        with jax.named_scope("kv_read"):
+            return _latent_pallas(q, pool, block_tables, context_lens, scale,
+                                  rank)
+    return latent_attention_reference(q, pool, block_tables, context_lens,
+                                      scale, rank)

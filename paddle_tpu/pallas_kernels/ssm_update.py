@@ -61,7 +61,7 @@ from . import adoption
 
 __all__ = ["advance", "state_update", "state_update_reference",
            "ssm_update_checks", "update_path", "transfer_columns", "started",
-           "KERNEL_NAME"]
+           "in_turns", "units_in_flight", "KERNEL_NAME"]
 
 # the name the kernel's executions carry in a device trace
 KERNEL_NAME = "ssm_state_update"
@@ -198,15 +198,18 @@ def _columns_of(ref, first):
     return column
 
 
-def _kernel(slots_ref, fresh_ref, pool_hbm, decay_ref, dx_ref, b_ref, c_ref,
-            out_hbm, y_ref, buf, rsem, wsem, *, lanes, chunks, per):
-    """Grid step (lane, chunk) updates unit ``lane * chunks + chunk`` where
-    it lies in ``buf`` [2, K, N, cols]: batch ``unit // K`` in half ``batch %
-    2``.  The first step reads batch 0; a batch's first step starts the next
-    batch's reads, its last waits for them, then writes the batch back and
-    waits for that; the last batch, with nothing left to read, writes each
-    unit as it is done."""
-    del pool_hbm                         # out_hbm is the same buffer
+def in_turns(slots_ref, out_hbm, buf, rsem, wsem, lanes, chunks, update):
+    """The transfers of a kernel whose grid is (lane, chunk) over the slots
+    of a pool ``out_hbm`` [slots, N, I] (aliased to its input), around
+    ``update(lane, chunk, half, at)``, which moves unit ``lane * chunks +
+    chunk`` (columns ``chunk * cols`` on of slot ``slots_ref[lane]``) where
+    it lies in ``buf`` [2, K, N, cols]: batch ``unit // K`` in half ``batch
+    % 2``, at ``buf[half, at]``.  The first step reads batch 0; a batch's
+    first step starts the next batch's reads, its last waits for them, then
+    writes the batch back and waits for that; the last batch, with nothing
+    left to read, writes each unit as it is done.  Shared by every kernel
+    that updates slots in place (``ssm_state_update``, ``kda_state_update``):
+    the grid's dimensions are ``arbitrary``, the batches carry over."""
     lane, chunk = pl.program_id(0), pl.program_id(1)
     k_n, cols = buf.shape[1], buf.shape[3]
     total = lanes * chunks
@@ -245,21 +248,7 @@ def _kernel(slots_ref, fresh_ref, pool_hbm, decay_ref, dx_ref, b_ref, c_ref,
     def _ahead():
         each(batch + 1, False, start)
 
-    half = batch % 2
-    fresh = fresh_ref[lane] != 0
-    # b and c of the groups this chunk's columns belong to, a pair a group
-    # as it is met
-    first = chunk * cols // per
-    b_of, c_of = _columns_of(b_ref, first), _columns_of(c_ref, first)
-    # 128 columns at a time: every operand is whole (8, 128) tiles, b and c
-    # rows broadcast over the lanes, decay and dx columns over the sublanes
-    for k in range(cols // 128):
-        sl = pl.ds(k * 128, 128)
-        g = k * 128 // per
-        state = jnp.where(fresh, 0.0, buf[half, at, :, sl])
-        state = decay_ref[:, sl] * state + b_of(g) * dx_ref[:, sl]
-        buf[half, at, :, sl] = state
-        y_ref[:, sl] = jnp.sum(state * c_of(g), axis=0, keepdims=True)
+    update(lane, chunk, batch % 2, at)
 
     @pl.when(last)
     def _write_now():
@@ -276,13 +265,47 @@ def _kernel(slots_ref, fresh_ref, pool_hbm, decay_ref, dx_ref, b_ref, c_ref,
         each(batch, True, wait)
 
 
+def units_in_flight(pool_shape, cols, units):
+    """Units read (or written) together, K of ``in_turns``'s ``buf`` [2, K,
+    N, cols]: ``BATCH``, fewer where two batches of that many would not fit
+    ``_UNIT_BUDGET`` or the call has fewer ``units``."""
+    return min(BATCH, _UNIT_BUDGET // (2 * 4 * pool_shape[1] * cols), units)
+
+
+def _kernel(slots_ref, fresh_ref, pool_hbm, decay_ref, dx_ref, b_ref, c_ref,
+            out_hbm, y_ref, buf, rsem, wsem, *, lanes, chunks, per):
+    """Grid step (lane, chunk) updates one unit where ``in_turns`` has put
+    it."""
+    del pool_hbm                         # out_hbm is the same buffer
+    cols = buf.shape[3]
+
+    def update(lane, chunk, half, at):
+        fresh = fresh_ref[lane] != 0
+        # b and c of the groups this chunk's columns belong to, a pair a
+        # group as it is met
+        first = chunk * cols // per
+        b_of, c_of = _columns_of(b_ref, first), _columns_of(c_ref, first)
+        # 128 columns at a time: every operand is whole (8, 128) tiles, b
+        # and c rows broadcast over the lanes, decay and dx columns over the
+        # sublanes
+        for k in range(cols // 128):
+            sl = pl.ds(k * 128, 128)
+            g = k * 128 // per
+            state = jnp.where(fresh, 0.0, buf[half, at, :, sl])
+            state = decay_ref[:, sl] * state + b_of(g) * dx_ref[:, sl]
+            buf[half, at, :, sl] = state
+            y_ref[:, sl] = jnp.sum(state * c_of(g), axis=0, keepdims=True)
+
+    in_turns(slots_ref, out_hbm, buf, rsem, wsem, lanes, chunks, update)
+
+
 def _state_update_pallas(pool, slots, fresh, decay, dx, b, c, interpret=None):
     """-> (pool updated in its own buffer, y [B, I])."""
     lanes, inner = decay.shape
     n, groups = pool.shape[1], b.shape[1]
     cols = transfer_columns(pool.shape, groups)
     chunks = inner // cols
-    k_n = min(BATCH, _UNIT_BUDGET // (2 * 4 * n * cols), lanes * chunks)
+    k_n = units_in_flight(pool.shape, cols, lanes * chunks)
     if interpret is None:
         interpret = adoption.interpret()
     f32 = jnp.float32

@@ -25,18 +25,25 @@ declaration of what the block computes (``FAMILY``, a
 weight dtypes, whether and where it routes, ...).  This file names no
 family beside that list: ``DecoderConfig`` refuses by the declaration, and
 adding a family is its module, its name in ``ARCHS`` and its row in
-``tests/decoder_families.py``.  Layers are of five kinds (``LAYER_KINDS``):
+``tests/decoder_families.py``.  Layers are of seven kinds (``LAYER_KINDS``):
 ``attention`` (multi-head, or grouped-query with fewer KV heads than query
 heads, so pools ``kv_heads * head_dim`` wide), which keeps K and V a token;
 ``window``, attention over the last ``cfg.window`` positions only, which
 keeps K and V in a ring of blocks of its own pools and gives back what
 leaves the window; ``mamba``, a Mamba-2 state-space mixer, which keeps a
 convolution window and a recurrent state a sequence; ``conv``, a gated
-short convolution, which keeps a window and no state; and ``experts``, a
+short convolution, which keeps a window and no state; ``experts``, a
 layer that is a feed-forward alone (routed experts beside a shared one),
-which keeps nothing: the cache manager gives it neither pool nor slot.  A
-hybrid block names its layers' kinds one by one, two or three of them in
-one model (its ``FAMILY.kinds``).  Every step builder below serves every
+which keeps nothing: the cache manager gives it neither pool nor slot;
+``kda``, a Kimi Delta Attention mixer, which keeps three convolution
+windows and a matrix state a head a sequence, moved by a gated delta rule
+(``pallas_kernels.kda_update``); and ``latent``, multi-head latent
+attention served absorbed, which keeps ONE row a token (a compressed K/V
+and the key's shared part, ``latent_rank + latent_rope`` values) in a pool
+of its own width on the global block tables, read as key and as value both
+(``paged_attention.latent_attention``).  A hybrid block names its layers'
+kinds one by one, two or three of them in one model (its
+``FAMILY.kinds``).  Every step builder below serves every
 family through one contract (``_block``), so there is one paged step, one
 multi-token step, one draft rollout and one unpaged reference, whatever the
 block.
@@ -54,12 +61,13 @@ what a kind keeps):
   serves, ``kv_gather`` where the table is gathered); a recurrent layer's
   window and state are read from and written to the slot the step is told
   for each lane (``state_slots``), the state through
-  ``pallas_kernels.ssm_update.state_update`` (on a TPU a kernel that moves
+  ``pallas_kernels.ssm_update.state_update`` or, for ``kda`` layers,
+  ``pallas_kernels.kda_update.state_update`` (on a TPU a kernel that moves
   each slot in place, elsewhere gather, update, scatter).
 * ``make_unpaged_step`` — the reference: contiguous per-lane K/V
   ``[L, B, S, KH, D]`` updated at ``pos`` and attended via the same
   ``masked_attention`` core, window and state a row a lane moved by the
-  same ``ssm_update.advance``.
+  same ``ssm_update.advance`` (``kda_update.advance``).
 
 Because the gather path and the unpaged loop feed bitwise-identical K/V
 values into the identical attention/MLP expressions at identical shapes,
@@ -78,11 +86,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..pallas_kernels import kda_update as _kda
 from ..pallas_kernels import moe_experts as _moe
 from ..pallas_kernels import paged_attention as _pa
 from ..pallas_kernels import ssm_update as _ssm
 from ..pallas_kernels.paged_attention import gather_blocks, \
-    masked_attention, paged_attention
+    latent_attention, masked_attention, masked_latent, paged_attention
 from . import kv_cache as _kv
 
 __all__ = ["DecoderConfig", "init_decoder_params", "save_decoder",
@@ -97,11 +106,13 @@ __all__ = ["DecoderConfig", "init_decoder_params", "save_decoder",
 
 # the families, a module each: ``models/<arch>.py``
 ARCHS = ("gpt2", "olmoe", "granite_hybrid", "lfm2_moe", "exaone_moe",
-         "nemotron_h")
-LAYER_KINDS = ("attention", "mamba", "conv", "window", "experts")
+         "nemotron_h", "kimi_linear")
+LAYER_KINDS = ("attention", "mamba", "conv", "window", "experts", "kda",
+               "latent")
 # recurrent kind -> the name its slot goes by in spans, gauges and counters
 # (``ssm_state_lanes``, ``conv_state_bytes{model}``, ...)
-STATE_NAMES = {"mamba": "ssm_state", "conv": "conv_state"}
+STATE_NAMES = {"mamba": "ssm_state", "conv": "conv_state",
+               "kda": "kda_state"}
 
 
 def _model(arch):
@@ -157,7 +168,16 @@ class DecoderConfig:
     down``, width ``ffn``) routed as ``exaone_moe``'s beside a shared one of
     width ``shared_ffn``, with an untied head.  It too may hold a share and
     have a stream of its own width; its routed layers are its ``experts``
-    layers, wherever they lie.
+    layers, wherever they lie.  ``kimi_linear`` is the block of
+    ``models/kimi_linear.py``: ``layer_types`` of ``kda`` | ``latent``: Kimi
+    Delta Attention mixers of ``kda_heads`` heads of ``kda_head_dim`` (keys
+    and values alike) behind depthwise convolutions of ``kda_conv`` taps,
+    beside latent attention of ``heads`` heads (``head_dim`` their own key
+    and value width, ``latent_rope`` key values shared by all heads,
+    ``latent_rank`` the compressed K/V's width) with no position encoding;
+    ``dense_layers`` leading gated MLPs and then ``exaone_moe``'s routed
+    layer (the share it may hold included) under pre-norms, an untied head
+    and a stream of its own width.
 
     ``kv_heads`` None means ``heads`` (multi-head); ``layer_types`` None
     means ``layers`` attention layers.  ``dtype`` is the weights' (``f32``
@@ -173,7 +193,8 @@ class DecoderConfig:
                  "residual_multiplier", "attention_multiplier",
                  "logits_scaling", "conv_taps", "dense_layers", "dense_ffn",
                  "routed_scaling", "window", "experts_held", "expert_first",
-                 "shared_ffn", "hidden_size", "ssm_groups")
+                 "shared_ffn", "hidden_size", "ssm_groups", "kda_heads",
+                 "kda_head_dim", "kda_conv", "latent_rank", "latent_rope")
 
     def __init__(self, vocab, layers, heads, head_dim, ffn=None,
                  max_seq=64, arch="gpt2", dtype="f32", kv_dtype=None,
@@ -185,7 +206,8 @@ class DecoderConfig:
                  conv_taps=0, dense_layers=0, dense_ffn=0,
                  routed_scaling=1.0, window=0, experts_held=0,
                  expert_first=0, shared_ffn=0, hidden_size=None,
-                 ssm_groups=1):
+                 ssm_groups=1, kda_heads=0, kda_head_dim=0, kda_conv=0,
+                 latent_rank=0, latent_rope=0):
         if arch not in ARCHS:
             raise ValueError("decoder arch must be %s: %r"
                              % ("|".join(ARCHS), arch))
@@ -237,6 +259,11 @@ class DecoderConfig:
         self.expert_first = int(expert_first)
         self.shared_ffn = int(shared_ffn)
         self.hidden_size = None if hidden_size is None else int(hidden_size)
+        self.kda_heads = int(kda_heads)
+        self.kda_head_dim = int(kda_head_dim)
+        self.kda_conv = int(kda_conv)
+        self.latent_rank = int(latent_rank)
+        self.latent_rope = int(latent_rope)
         if self.hidden_size not in (None, self.heads * self.head_dim) \
                 and not family.own_stream_width:
             raise ValueError("the %s block's stream is heads * head_dim "
@@ -266,6 +293,14 @@ class DecoderConfig:
                              % (self.ssm_groups, self.ssm_heads))
         if self.conv_layers and self.conv_taps < 2:
             raise ValueError("conv layers want conv_taps >= 2")
+        if self.kda_layers and min(self.kda_heads, self.kda_head_dim,
+                                   self.kda_conv - 1) < 1:
+            raise ValueError("kda layers want kda_heads, kda_head_dim >= 1 "
+                             "and kda_conv >= 2")
+        if self.latent_layers and min(self.latent_rank,
+                                      self.latent_rope) < 1:
+            raise ValueError("latent layers want latent_rank and "
+                             "latent_rope >= 1")
         if not 0 <= self.dense_layers <= self.layers or (
                 self.dense_layers and (not family.dense_lead
                                        or self.dense_ffn < 1)):
@@ -319,18 +354,37 @@ class DecoderConfig:
         return self._of_kind("conv")
 
     @property
+    def kda_layers(self):
+        """Indices of the Kimi Delta Attention layers (three convolution
+        windows and a matrix state), in order."""
+        return self._of_kind("kda")
+
+    @property
+    def latent_layers(self):
+        """Indices of the layers that hold one latent row a token, in
+        order."""
+        return self._of_kind("latent")
+
+    @property
+    def state_layers(self):
+        """Indices of the recurrent layers that keep a state beside their
+        window (``mamba`` or ``kda``: a model's are of one kind), in
+        order."""
+        return self.ssm_layers or self.kda_layers
+
+    @property
     def recurrent_layers(self):
         """Indices of the layers that keep, a sequence, something constant
-        in its length (a slot of the cache): the model's ``mamba`` or
-        ``conv`` layers, in order."""
+        in its length (a slot of the cache): the model's ``mamba``,
+        ``conv`` or ``kda`` layers, in order."""
         return tuple(l for l, k in enumerate(self.layer_types)
                      if k in STATE_NAMES)
 
     @property
     def state_name(self):
         """What the recurrent layers' slot goes by in telemetry
-        (``ssm_state`` | ``conv_state``); None for a model with no such
-        layer."""
+        (``ssm_state`` | ``conv_state`` | ``kda_state``); None for a model
+        with no such layer."""
         return next((STATE_NAMES[k] for k in self.layer_types
                      if k in STATE_NAMES), None)
 
@@ -355,6 +409,25 @@ class DecoderConfig:
     def ssm_inner(self):
         return self.ssm_heads * self.ssm_head_dim
 
+    @property
+    def kda_inner(self):
+        return self.kda_heads * self.kda_head_dim
+
+    @property
+    def latent_width(self):
+        """Values a latent layer keeps a token: the compressed K/V and the
+        key's shared part."""
+        return self.latent_rank + self.latent_rope
+
+    @property
+    def latent_scale(self):
+        """The scale of a latent layer's scores: ``attention_multiplier``,
+        or one over the root of a key's width (its own ``head_dim`` values
+        and the shared ``latent_rope``)."""
+        if self.attention_multiplier is not None:
+            return self.attention_multiplier
+        return float(self.head_dim + self.latent_rope) ** -0.5
+
     def to_dict(self):
         d = {s: getattr(self, s) for s in self.__slots__}
         d["layer_types"] = list(self.layer_types)
@@ -368,8 +441,9 @@ def cache_config(cfg, block_size, num_blocks, dtype=None, state_slots=0):
     """The cache geometry a model's step is built over: K and V pools for
     its attention layers (``kv_heads`` wide), for its recurrent layers what
     one sequence's slot holds (``_state_shapes``: a window and a state for
-    ``mamba`` layers, a window alone for ``conv`` layers), in
-    ``state_slots`` slots (slot 0 the idle lanes' scratch), and for its
+    ``mamba`` and ``kda`` layers, a window alone for ``conv`` layers), in
+    ``state_slots`` slots (slot 0 the idle lanes' scratch), for its latent
+    layers one pool each, ``latent_width`` values a token, and for its
     window layers as many rings (a sequence holds a ring as it holds a
     slot: one a lane and the scratch), each ``cfg.window`` positions
     long.  A layer of a kind that keeps nothing (``experts``) is counted
@@ -381,7 +455,9 @@ def cache_config(cfg, block_size, num_blocks, dtype=None, state_slots=0):
         state_shapes=_state_shapes(cfg),
         state_slots=state_slots,
         window_layers=len(cfg.window_layers), window=cfg.window,
-        window_slots=state_slots if cfg.window_layers else 0)
+        window_slots=state_slots if cfg.window_layers else 0,
+        latent_layers=len(cfg.latent_layers),
+        latent_width=cfg.latent_width if cfg.latent_layers else 0)
 
 
 def _conv_window(cfg):
@@ -390,6 +466,9 @@ def _conv_window(cfg):
     if cfg.ssm_layers:
         return cfg.ssm_conv, \
             cfg.ssm_inner + 2 * cfg.ssm_groups * cfg.ssm_state
+    if cfg.kda_layers:
+        # q, k and v each pass through a convolution of their own
+        return cfg.kda_conv, 3 * cfg.kda_inner
     return cfg.conv_taps, cfg.hidden
 
 
@@ -399,14 +478,18 @@ def _state_shapes(cfg):
     1`` inputs, flat, in the weights' dtype); a ``mamba`` layer then its
     state (``[ssm_state, ssm_inner]`` float32: the heads' ``[head_dim,
     ssm_state]`` matrices, transposed so the minor dimension is the
-    128-lane-dense one), a ``conv`` layer nothing more.  Nothing for a
-    model with no such layer."""
+    128-lane-dense one), a ``kda`` layer its state (``[kda_head_dim,
+    kda_inner]`` float32: the heads' ``[keys, values]`` matrices side by
+    side), a ``conv`` layer nothing more.  Nothing for a model with no such
+    layer."""
     if not cfg.recurrent_layers:
         return ()
     taps, width = _conv_window(cfg)
     window = (((taps - 1) * width,), cfg.dtype)
     if cfg.ssm_layers:
         return (window, ((cfg.ssm_state, cfg.ssm_inner), "f32"))
+    if cfg.kda_layers:
+        return (window, ((cfg.kda_head_dim, cfg.kda_inner), "f32"))
     return (window,)
 
 
@@ -494,11 +577,15 @@ def _block(cfg):
     live, recur) -> (logits [B, vocab], extras)``.  Two callbacks own what
     a layer keeps between tokens, the only paged/unpaged difference:
     ``attend(l, q, k, v)`` the KV write + history attention of attention
-    layer ``l``, and ``recur`` what the recurrent layers keep
+    layer ``l`` (of a ``latent`` layer: ``q`` [B, H, W] the absorbed query,
+    ``k`` [B, W] this token's row, ``v`` None -> the probabilities' sum of
+    the rows' first ``latent_rank`` columns, [B, H, latent_rank]), and
+    ``recur`` what the recurrent layers keep
     (``recur.window(l, x)`` pushes this token's convolution input and
     returns the newest ``taps``; for a kind with a state,
     ``recur.advance(l, decay, dx, b, c)`` moves it one token and returns
-    its read-out; None for a model with no such layer).  ``live`` [B] bool
+    its read-out, ``recur.delta(l, alpha, beta, k, v, q)`` a ``kda``
+    layer's; None for a model with no such layer).  ``live`` [B] bool
     marks the lanes that hold a sequence; ``extras`` is a tuple of small
     arrays the step returns after its logits (a routed block's tokens sent
     to each expert, a row a layer of ``cfg.routed_layers``; nothing for the
@@ -527,7 +614,14 @@ def attention_path(cfg, kv_config, lanes=1, kind="attention"):
     backend at a bucket of ``lanes`` (what holds for a bucket holds for
     every smaller one); ``"gather"`` where it gathers the padded table
     (the int8 residency always does).  ``kind`` ``"window"`` asks it of the
-    window layers, whose table is their ring."""
+    window layers, whose table is their ring, and ``"latent"`` of the latent
+    layers and their form of the kernel."""
+    if kind == "latent":
+        return _pa.latent_path(
+            (lanes, cfg.heads, kv_config.latent_row),
+            (kv_config.num_blocks, kv_config.block_size,
+             kv_config.latent_row),
+            _kv._PAYLOAD[kv_config.dtype][0], cfg.latent_rank)
     windowed = kind == "window"
     return _pa.attention_path(
         (lanes, cfg.heads, cfg.head_dim),
@@ -552,16 +646,20 @@ def experts_path(cfg, params, lanes=1):
 
 
 def state_update_path(cfg, kv_config, lanes=1):
-    """``"pallas"`` where a state-space layer's state is moved by the kernel
-    that updates each lane's slot in place, for this model's pool on this
-    backend at a bucket of ``lanes``; ``"gather"`` where the slots are
-    gathered, moved and scattered back; None for a model with no such
-    layer."""
-    if not cfg.ssm_layers:
+    """``"pallas"`` where a state-space or delta-rule layer's state is moved
+    by the kernel that updates each lane's slot in place, for this model's
+    pool on this backend at a bucket of ``lanes``; ``"gather"`` where the
+    slots are gathered, moved and scattered back; None for a model with no
+    such layer."""
+    if not cfg.state_layers:
         return None
     shape, dtype = kv_config.state_shapes[1]
-    return _ssm.update_path((kv_config.state_slots,) + shape,
-                            _kv._PAYLOAD[dtype][0], lanes, cfg.ssm_groups)
+    pool = (kv_config.state_slots,) + shape
+    if cfg.kda_layers:
+        return _kda.update_path(pool, _kv._PAYLOAD[dtype][0], lanes,
+                                cfg.kda_heads)
+    return _ssm.update_path(pool, _kv._PAYLOAD[dtype][0], lanes,
+                            cfg.ssm_groups)
 
 
 def state_update_columns(cfg, kv_config):
@@ -569,19 +667,26 @@ def state_update_columns(cfg, kv_config):
     for this model's pool: the slot's whole width where the VMEM the kernel
     asks for holds batches of whole slots, else the chunk it falls back to
     (``ssm_update.transfer_columns``); None for a model with no such layer
-    or a pool no chunk of which fits."""
-    if not cfg.ssm_layers:
+    or a pool no chunk of which fits.  A delta-rule layer's chunk is whole
+    heads, as a state-space layer's is whole groups."""
+    if not cfg.state_layers:
         return None
     shape, _dtype = kv_config.state_shapes[1]
     return _ssm.transfer_columns((kv_config.state_slots,) + shape,
-                                 cfg.ssm_groups)
+                                 cfg.kda_heads or cfg.ssm_groups)
+
+
+def _widened(x, row):
+    """``x`` [..., latent_width] as a latent pool's row holds it: ``row``
+    wide, the rest zeros (they add nothing to a score)."""
+    return jnp.pad(x, [(0, 0)] * (x.ndim - 1) + [(0, row - x.shape[-1])])
 
 
 def _pool_index(cfg):
     """layer -> its place among the layers of its kind (the index of its
     pools, or of its state arrays, in the cache's groups)."""
-    return {l: i for kind in (cfg.attn_layers, cfg.window_layers,
-                              cfg.recurrent_layers)
+    return {l: i for kind in (cfg.attn_layers, cfg.latent_layers,
+                              cfg.window_layers, cfg.recurrent_layers)
             for i, l in enumerate(kind)}
 
 
@@ -591,7 +696,8 @@ class _Recurrent:
     reach recurrent layer ``i``'s window ``[B, (K - 1) * W]`` for the step's
     lanes, and ``advance(i, fresh, decay, dx, b, c) -> y`` moves its state
     one token (``ssm_update.advance``'s mathematics, on values or on the
-    slots of a pool; None for a kind of layer that keeps a window and no
+    slots of a pool; for ``kda`` layers ``kda_update.advance``'s, reached
+    as ``delta``; None for a kind of layer that keeps a window and no
     state).  A lane at position 0 (``fresh``) starts from zeros whatever is
     stored."""
 
@@ -618,6 +724,13 @@ class _Recurrent:
         head's decay repeated over its values), ``b`` and ``c`` [B, G, N]
         (a pair a group of heads)."""
         return self._advance(self._at[l], self._fresh, decay, dx, b, c)
+
+    def delta(self, l, alpha, beta, k, v, q):
+        """A KDA layer's state one token on by the gated delta rule
+        (``kda_update.advance``: decay by ``alpha`` [B, H, D], a value a
+        key; write ``beta`` [B, H] times ``outer(k, v - S^T k)``) -> its
+        read-out ``S^T q`` [B, H, D]."""
+        return self._advance(self._at[l], self._fresh, alpha, beta, k, v, q)
 
 
 def make_paged_step(cfg, kv_config):
@@ -670,7 +783,10 @@ def make_paged_step(cfg, kv_config):
     pool_of = _pool_index(cfg)
     taps = _conv_window(cfg)[0]
     windowed = frozenset(cfg.window_layers)
+    latent = frozenset(cfg.latent_layers)
     ring = kv_config.window_ring
+    # the rule a recurrent layer's state moves by, on the slots of a pool
+    move = _kda.state_update if cfg.kda_layers else _ssm.state_update
 
     def step(kv_carry, params, tok, pos, block_tables, context_lens,
              *more):
@@ -684,6 +800,7 @@ def make_paged_step(cfg, kv_config):
         context_lens = context_lens.astype(jnp.int32)
         offs = pos % bs
         pools, state = kv_config.groups(kv_carry)
+        lpools = kv_config.latent_pools(kv_carry)
         wpools = kv_config.window_groups(kv_carry)
 
         def block_of(tables, slot):
@@ -692,8 +809,8 @@ def make_paged_step(cfg, kv_config):
 
         # a layer's kind -> (its pools, the table that steers them, the
         # block this step writes, its window or None)
-        kinds = {False: (pools, block_tables,
-                         block_of(block_tables, pos // bs), None)}
+        written = block_of(block_tables, pos // bs)
+        kinds = {False: (pools, block_tables, written, None)}
         if windowed:
             kinds[True] = (wpools, window_tables,
                            block_of(window_tables, (pos // bs) % ring),
@@ -701,6 +818,15 @@ def make_paged_step(cfg, kv_config):
 
         def attend(l, q, k, v):
             i = pool_of[l]
+            if l in latent:
+                # one row a token, which is key and value both
+                row = kv_config.latent_row
+                with jax.named_scope("kv_write"):
+                    lpools[i] = _write_rows(lpools[i], written, offs,
+                                            _widened(k, row))
+                return latent_attention(_widened(q, row), lpools[i],
+                                        block_tables, context_lens,
+                                        cfg.latent_scale, cfg.latent_rank)
             mine, tables, blk_ids, window = kinds[l in windowed]
 
             def write(group, rows):
@@ -740,8 +866,7 @@ def make_paged_step(cfg, kv_config):
                 windows[i] = windows[i].at[slots].set(value)
 
             def advance(i, fresh, *operands):
-                states[i], y = _ssm.state_update(states[i], slots, fresh,
-                                                 *operands)
+                states[i], y = move(states[i], slots, fresh, *operands)
                 return y
 
             recur = _Recurrent(
@@ -752,8 +877,8 @@ def make_paged_step(cfg, kv_config):
         logits, extras = block(params, cfg, tok, pos, attend,
                                context_lens > 0, recur)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return (tuple(a for group in pools + wpools + state for a in group),
-                nxt, logits) + tuple(extras)
+        return (tuple(a for group in pools + [lpools] + wpools + state
+                      for a in group), nxt, logits) + tuple(extras)
 
     return step
 
@@ -941,26 +1066,39 @@ def make_unpaged_step(cfg, pad_len, ring_len=None):
     next, ``[Lw, B, ring_len, KH, D]``, position ``p`` at row ``p %
     ring_len`` (``ring_len`` None: ``cfg.window`` rows; the paged step's
     gathered ring is ``window_ring * block_size`` long, and the bitwise
-    comparison wants that).  A model with recurrent layers carries their
+    comparison wants that).  A model with latent layers carries their rows
+    after the global K and V, ``[Ll, B, pad_len, latent_row]``, a row as the
+    paged pool holds it.  A model with recurrent layers carries their
     window ``[Lr, B, (K - 1) * W]`` and state ``[Lr, B, N, I]`` last, a
     lane a row (the window alone where the layers keep no state), through
     the same ``_Recurrent`` as the paged step."""
     block = _block(cfg)
     pool_of = _pool_index(cfg)
     windowed = frozenset(cfg.window_layers)
+    latent = frozenset(cfg.latent_layers)
+    advance_values = _kda.advance if cfg.kda_layers else _ssm.advance
 
     def step(kv_carry, params, tok, pos, context_lens):
         tok = tok.astype(jnp.int32)
         pos = pos.astype(jnp.int32)
         context_lens = context_lens.astype(jnp.int32)
-        # K and V of the global layers, then of the window layers
-        kv = list(kv_carry[:4 if windowed else 2])
+        # K and V of the global layers, then the latent layers' rows, then
+        # K and V of the window layers
+        first = 3 if latent else 2
+        kv = list(kv_carry[:first + (2 if windowed else 0)])
         state = list(kv_carry[len(kv):])
         lanes = jnp.arange(kv[0].shape[1], dtype=jnp.int32)
 
         def attend(l, q, k, v):
             i = pool_of[l]
-            at = 2 if l in windowed else 0
+            if l in latent:
+                row = kv[2].shape[-1]
+                kv[2] = kv[2].at[i, lanes, pos].set(
+                    _widened(k, row).astype(kv[2].dtype))
+                return masked_latent(_widened(q, row), kv[2][i],
+                                     context_lens, cfg.latent_scale,
+                                     cfg.latent_rank)
+            at = first if l in windowed else 0
             row = pos % kv[at].shape[2] if at else pos
             for j, x in ((at, k), (at + 1, v)):
                 kv[j] = kv[j].at[i, lanes, row].set(x.astype(kv[j].dtype))
@@ -972,8 +1110,8 @@ def make_unpaged_step(cfg, pad_len, ring_len=None):
             state[0] = state[0].at[i].set(value)
 
         def advance(i, fresh, *operands):
-            new, y = _ssm.advance(_ssm.started(fresh, state[1][i]),
-                                  *operands)
+            new, y = advance_values(_ssm.started(fresh, state[1][i]),
+                                    *operands)
             state[1] = state[1].at[i].set(new)
             return y
 
@@ -999,6 +1137,10 @@ def _unpaged_carry(cfg, lanes, pad_len, ring_len=None):
     carry = tuple(jnp.zeros((len(cfg.attn_layers), lanes, pad_len,
                              cfg.kv_heads, cfg.head_dim), kv_dtype)
                   for _ in range(2))
+    if cfg.latent_layers:
+        carry += (jnp.zeros((len(cfg.latent_layers), lanes, pad_len,
+                             _kv.latent_row_of(cfg.latent_width)),
+                            kv_dtype),)
     if cfg.window_layers:
         carry += tuple(jnp.zeros((len(cfg.window_layers), lanes,
                                   ring_len or cfg.window, cfg.kv_heads,

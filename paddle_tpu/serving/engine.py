@@ -1254,15 +1254,19 @@ class DecodeEngine:
         # where the recurrent layers' state at that position is nowhere,
         # and the window layers' K and V before it in no block
         jparams = {key: jnp.asarray(v) for key, v in params.items()}
-        attn_path = _dm.attention_path(cfg, kv_config, max(self.buckets))
+        # of the layers that page a history on the global tables: attention
+        # layers, or a latent model's latent layers
+        latent = bool(cfg.latent_layers)
+        attn_path = _dm.attention_path(cfg, kv_config, max(self.buckets),
+                                       "latent" if latent else "attention")
         experts_path = {b: _dm.experts_path(cfg, jparams, b)
                         for b in self.buckets if cfg.routed_layers}
         state_path = {b: _dm.state_update_path(cfg, kv_config, b)
-                      for b in self.buckets if cfg.ssm_layers}
+                      for b in self.buckets if cfg.state_layers}
         # the paths the step's attention and (a routed model's) experts
         # take: an executable compiled for one is never restored for the
         # other
-        paths = {"attention": attn_path}
+        paths = {"latent_attention" if latent else "attention": attn_path}
         window_path = _dm.attention_path(
             cfg, kv_config, max(self.buckets), "window") if windowed else None
         if windowed:
@@ -1314,10 +1318,15 @@ class DecodeEngine:
         if recurrent:
             entry.declines = "recurrent_state"
             entry.slot_bytes = _kvc.slot_bytes(kv_config)
-            # what the slots hold names them: ssm_state_*, conv_state_*
+            # what the slots hold names them: ssm_state_*, conv_state_*,
+            # kda_state_*
             entry.state_name = cfg.state_name
             _tm.set_gauge(entry.state_name + "_bytes",
                           _kvc.state_bytes(kv_config), model=name)
+        if latent:
+            _tm.set_gauge("latent_pool_bytes", kv_config.latent_layers
+                          * _kvc.latent_block_bytes(kv_config) * n,
+                          model=name)
         if k > 0:
             # draft pool mirrors the target's block COUNT (draft blocks
             # are strictly smaller at fewer layers), so any sequence the
@@ -1418,6 +1427,8 @@ class DecodeEngine:
                     for kind in sorted(set(m.cfg.layer_types))}
             if m.window_path is not None:
                 extra["window_attention"] = m.window_path
+            if m.cfg.latent_layers:
+                extra["latent_attention"] = m.attn_path
             _tm.event("serving_prewarm", model=model, bucket=bucket,
                       source=got["source"], decode=True, fn=fn,
                       ms=round(got["compile_ms"], 3),
@@ -2714,6 +2725,10 @@ class DecodeEngine:
                     "kv_table_slots": bucket * m.maxb,
                     "kv_block_size": m.kv_config.block_size} \
                 if _tr.enabled() else {}
+            if m.cfg.latent_layers and read:
+                # what a latent layer fetches (each of them the same): a
+                # block there is one row a token, not K and V
+                read["latent_blocks_read"] = read["kv_blocks_read"]
             if slots is not None:
                 # lanes at position 0 start their slot from zeros
                 resets = int((pos[:len(lanes)] == 0).sum())

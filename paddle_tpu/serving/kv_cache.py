@@ -102,7 +102,8 @@ from ..core import telemetry as _tm
 
 __all__ = ["KVCacheConfig", "BlockAllocator", "SlotAllocator",
            "WindowRing", "PagedKVCache", "PrefixCache",
-           "plan_num_blocks", "block_bytes", "slot_bytes", "state_bytes",
+           "plan_num_blocks", "block_bytes", "latent_block_bytes",
+           "slot_bytes", "state_bytes",
            "window_bytes",
            "engine_owned_kv_bytes",
            "engine_owned_resident_bytes", "register_resident_bytes",
@@ -150,18 +151,38 @@ class KVCacheConfig:
     Window layers: ``window_layers`` of them hold K and V of the last
     ``window`` positions, same heads, block size and residency, in
     ``window_slots`` rings of ``window_ring`` blocks (ring 0's first block
-    is the idle lanes' scratch)."""
+    is the idle lanes' scratch).
+
+    Latent layers: ``latent_layers`` of them hold ONE row a token,
+    ``latent_width`` values wide (a compressed K/V and the key's shared
+    part: what absorbed latent attention reads), in a pool each of the same
+    ``num_blocks`` blocks on the same tables and allocator: a block id names
+    a block in every layer's pool, whatever the layer's kind.  Their
+    residency is ``f32`` | ``bf16``: a latent row has no heads for int8's
+    scales to go by, and the kernel reads it as it lies.  A pool's rows are
+    ``latent_row`` wide, the width rounded up to whole 128-lane tiles and
+    the rest zeros: the only form in which a kernel can fetch a block from
+    the pool where it lies (Mosaic fetches whole tiles of the chip's tiled
+    HBM layout; asked for a 576-wide row XLA copies the whole pool into
+    that layout, 640 wide, around every call: PERF.md section 6, PR 46)."""
 
     __slots__ = ("layers", "heads", "head_dim", "block_size", "num_blocks",
                  "dtype", "state_layers", "state_shapes", "state_slots",
-                 "window_layers", "window", "window_slots")
+                 "window_layers", "window", "window_slots", "latent_layers",
+                 "latent_width")
 
     def __init__(self, layers, heads, head_dim, block_size, num_blocks,
                  dtype="f32", state_layers=0, state_shapes=(),
-                 state_slots=0, window_layers=0, window=0, window_slots=0):
+                 state_slots=0, window_layers=0, window=0, window_slots=0,
+                 latent_layers=0, latent_width=0):
         if dtype not in _PAYLOAD:
             raise ValueError("kv_cache dtype must be f32|bf16|int8: %r"
                              % (dtype,))
+        if latent_layers and (dtype == "int8" or latent_width < 1):
+            raise ValueError(
+                "a latent pool is f32|bf16 and latent_width >= 1 wide "
+                "(int8 residency scales a head's values, and a latent row "
+                "has no heads): %r, %r" % (dtype, latent_width))
         if block_size <= 0 or num_blocks <= 1:
             raise ValueError("need block_size > 0 and num_blocks > 1 "
                              "(block 0 is the idle-lane scratch)")
@@ -178,6 +199,8 @@ class KVCacheConfig:
         self.window_layers = int(window_layers)
         self.window = int(window)
         self.window_slots = int(window_slots)
+        self.latent_layers = int(latent_layers)
+        self.latent_width = int(latent_width)
         if self.window_layers and (self.window < 1 or self.window_slots <= 1):
             raise ValueError("window layers need window >= 1 and "
                              "window_slots > 1 (a ring a lane and the "
@@ -213,12 +236,14 @@ class KVCacheConfig:
     def _cuts(self, carry):
         carry = list(carry)
         cut = self.kv_groups * self.layers
-        wcut = cut + self.kv_groups * self.window_layers
+        first = cut + self.latent_layers
+        wcut = first + self.kv_groups * self.window_layers
         held = len(self.state_shapes) * self.state_layers
         if len(carry) != wcut + held:
             raise ValueError("a carry of %d arrays is not this cache's "
-                             "(%d KV + %d window + %d state)"
-                             % (len(carry), cut, wcut - cut, held))
+                             "(%d KV + %d latent + %d window + %d state)"
+                             % (len(carry), cut, self.latent_layers,
+                                wcut - first, held))
         return carry, cut, wcut
 
     def groups(self, carry):
@@ -226,8 +251,9 @@ class KVCacheConfig:
         -> ``(kv, state)``: ``kv`` the groups of ``layers`` per-layer
         pools (``[k, v]``, and ``[k, v, k_scales, v_scales]`` for int8
         residency), ``state`` one group of ``state_layers`` per-layer
-        arrays for each entry of ``state_shapes``.  The window layers'
-        pools lie between the two (``window_groups``)."""
+        arrays for each entry of ``state_shapes``.  The latent layers'
+        pools and then the window layers' lie between the two
+        (``latent_pools``, ``window_groups``)."""
         carry, cut, wcut = self._cuts(carry)
         kv = [carry[i:i + self.layers]
               for i in range(0, cut, self.layers or 1)]
@@ -240,7 +266,24 @@ class KVCacheConfig:
         ``kv`` is: ``window_layers`` arrays a group."""
         carry, cut, wcut = self._cuts(carry)
         return [carry[i:i + self.window_layers]
-                for i in range(cut, wcut, self.window_layers or 1)]
+                for i in range(cut + self.latent_layers, wcut,
+                               self.window_layers or 1)]
+
+    @property
+    def latent_row(self):
+        """Values a latent pool's row holds (``latent_row_of``)."""
+        return latent_row_of(self.latent_width)
+
+    def latent_pools(self, carry):
+        """The latent layers' pools of a carry, one a layer."""
+        carry, cut, _wcut = self._cuts(carry)
+        return carry[cut:cut + self.latent_layers]
+
+
+def latent_row_of(width):
+    """Values a latent pool's row holds for ``width`` values a token: the
+    width rounded up to whole 128-lane tiles."""
+    return -(-int(width) // 128) * 128
 
 
 def _layer_block_bytes(config):
@@ -252,9 +295,18 @@ def _layer_block_bytes(config):
     return 2 * config.block_size * tok
 
 
+def latent_block_bytes(config):
+    """HBM bytes ONE block costs in one latent layer: a row a token, as the
+    pool holds it (``latent_row`` wide)."""
+    return config.block_size * config.latent_row \
+        * _PAYLOAD[config.dtype][1]
+
+
 def block_bytes(config):
-    """HBM bytes ONE block costs across all (global) attention layers."""
-    return config.layers * _layer_block_bytes(config)
+    """HBM bytes ONE block costs across all (global) attention layers and
+    all latent layers."""
+    return config.layers * _layer_block_bytes(config) \
+        + config.latent_layers * latent_block_bytes(config)
 
 
 def window_bytes(config):
@@ -789,7 +841,7 @@ def _get_block(carry, block, layers):
     carry = list(carry)
     return [jnp.stack([jax.lax.dynamic_index_in_dim(c, block, 0, False)
                        for c in carry[i:i + layers]])
-            for i in range(0, len(carry), layers)]
+            for i in range(0, len(carry), layers or 1)]
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -822,8 +874,10 @@ class PagedKVCache:
     cannot be shared, trimmed or snapshotted: prefix reuse, speculative
     roll-back and block export are for models whose every layer pages.
 
-    The window layers' pools follow the global K/V groups in the carry, in
-    the same groups, ``[window_blocks, block_size, heads * head_dim]``
+    The latent layers' pools (``[num_blocks, block_size, latent_row]``,
+    one a layer, on ``allocator``'s blocks) follow the global K/V groups in
+    the carry, and the window layers' pools follow those, in the K/V
+    groups' order, ``[window_blocks, block_size, heads * head_dim]``
     each; ``window_allocator`` (None without window layers) hands out their
     blocks, one id for every window layer alike, through a sequence's
     ``WindowRing``."""
@@ -847,6 +901,10 @@ class PagedKVCache:
                          for _ in range(layers))
 
         self._carry = pools(config.num_blocks, config.layers) \
+            + tuple(jnp.zeros((config.num_blocks, config.block_size,
+                               config.latent_row),
+                              _PAYLOAD[config.dtype][0])
+                    for _ in range(config.latent_layers)) \
             + pools(config.window_blocks, config.window_layers)
         self._carry += tuple(
             jnp.zeros((config.state_slots,) + shape, _PAYLOAD[dt][0])

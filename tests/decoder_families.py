@@ -22,8 +22,8 @@ import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu.core import telemetry as _tm
-from paddle_tpu.models import exaone_moe, granite_hybrid, lfm2_moe, \
-    nemotron_h, olmoe
+from paddle_tpu.models import exaone_moe, granite_hybrid, kimi_linear, \
+    lfm2_moe, nemotron_h, olmoe
 from paddle_tpu.serving import DecodeEngine
 from paddle_tpu.serving import decode_model as dm
 from paddle_tpu.serving import kv_cache as kvc
@@ -135,6 +135,13 @@ _NEMOTRON = dm.DecoderConfig(
                  "experts"), ssm_heads=8, ssm_head_dim=8, ssm_state=16,
     ssm_conv=4, ssm_groups=2, ffn=24, shared_ffn=40, experts=16,
     experts_per_token=3, routed_scaling=2.5)
+_KIMI = dm.DecoderConfig(
+    arch="kimi_linear", vocab=97, layers=6, heads=4, head_dim=16,
+    hidden_size=48, max_seq=64,
+    layer_types=("kda", "kda", "kda", "latent", "kda", "latent"),
+    kda_heads=4, kda_head_dim=8, kda_conv=4, latent_rank=24, latent_rope=8,
+    dense_layers=1, dense_ffn=64, ffn=24, shared_ffn=24, experts=16,
+    experts_per_token=3, routed_scaling=2.446)
 _GRANITE_G4 = _GRANITE.replace(kv_heads=1)
 
 # Weights are normal(0, 0.3) (OLMoE's 0.05) and a router bias of 0.05: at
@@ -182,6 +189,17 @@ ROWS = {row.arch: row for row in (
                dict(layer_types=_NEMOTRON.layer_types, experts=16,
                     experts_held=4, expert_first=4, experts_per_token=3,
                     ssm_groups=2, hidden=48, ffn=24))),
+    Row("kimi_linear",
+        _both(_KIMI, kimi_linear.init_params, std=0.3, bias_std=0.05),
+        declines="recurrent_state", holds="slot", refusal="recurrent",
+        multi_atol=1e-5, batch_dependent_bf16=True,
+        entry=dict(state_name="kda_state", attn_path="gather",
+                   experts_path={4: "einsum"}, state_path={4: "gather"}),
+        serve=("kimi-linear-48b-a3b-serve.json",
+               dict(layer_types=_KIMI.layer_types, experts=16,
+                    experts_held=4, expert_first=4, experts_per_token=3,
+                    kda_heads=4, kda_head_dim=8, latent_rank=24,
+                    latent_rope=8, hidden=48, ffn=24))),
 )}
 assert tuple(ROWS) == dm.ARCHS
 
@@ -226,7 +244,8 @@ def run_paged(cfg, params, seqs, width=1, blocks=40, block_size=BS,
     if dirty is not None:
         pools, state = kv.groups(cache.carry())
         cache.replace_carry(tuple(
-            a for g in pools + kv.window_groups(cache.carry()) for a in g)
+            a for g in pools + [kv.latent_pools(cache.carry())]
+            + kv.window_groups(cache.carry()) for a in g)
             + tuple(jnp.full_like(a, dirty) for g in state for a in g))
     make = dm.make_packed_step(cfg, kv, b) if feed \
         else dm.make_paged_step(cfg, kv) if width == 1 \

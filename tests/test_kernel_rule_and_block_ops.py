@@ -35,6 +35,7 @@ from paddle_tpu.ops import manip as manip_ops
 from paddle_tpu.ops import nn as nn_ops
 from paddle_tpu.pallas_kernels import adoption
 from paddle_tpu.pallas_kernels import fused_ln
+from paddle_tpu.pallas_kernels import kda_update
 from paddle_tpu.pallas_kernels.flash_attention import flash_attention_checks
 from paddle_tpu.pallas_kernels import paged_attention as pa
 from paddle_tpu.pallas_kernels import moe_experts
@@ -91,6 +92,12 @@ _ELIGIBLE = {
     # LFM2-24B-A2B's experts at 32 lanes: 64 of [2048, 1536] in bf16
     "moe_experts": lambda: moe_experts.moe_experts_checks(
         32, (64, 2048, 1536), "bfloat16"),
+    # Kimi-Linear's latent pool (rows of 576 values held 640 wide, the value
+    # the first 512) and its KDA slots: 33 of [128, 32 heads x 128]
+    "latent_attention": lambda: pa.latent_attention_checks(
+        (32, 32, 640), (12832, 16, 640), "bfloat16", 512),
+    "kda_update": lambda: kda_update.kda_update_checks(
+        (33, 128, 4096), "float32", 32, 32),
 }
 
 RULE = {
@@ -135,6 +142,24 @@ RULE = {
             (33, 100, 4096), "float32", 32), True, "sublanes"),
     "moe_experts-backend": (
         "moe_experts", _ELIGIBLE["moe_experts"], False, "backend"),
+    "latent_attention-backend": (
+        "latent_attention", _ELIGIBLE["latent_attention"], False, "backend"),
+    "latent_attention-lanes": (
+        # a row of 576 is four and a half tiles: the cache holds it 640 wide
+        "latent_attention", lambda: pa.latent_attention_checks(
+            (32, 32, 576), (12832, 16, 576), "bfloat16", 512), True, "lanes"),
+    "latent_attention-dtype": (
+        "latent_attention", lambda: pa.latent_attention_checks(
+            (32, 32, 640), (12832, 16, 640), "int8", 512), True, "dtype"),
+    "kda_update-backend": (
+        "kda_update", _ELIGIBLE["kda_update"], False, "backend"),
+    "kda_update-dtype": (
+        "kda_update", lambda: kda_update.kda_update_checks(
+            (33, 128, 4096), "bfloat16", 32, 32), True, "dtype"),
+    "kda_update-heads": (
+        # heads of 64 values: no whole 128-column slice
+        "kda_update", lambda: kda_update.kda_update_checks(
+            (33, 64, 2048), "float32", 32, 32), True, "heads"),
     "moe_experts-dtype": (
         "moe_experts", lambda: moe_experts.moe_experts_checks(
             32, (64, 2048, 1536), "int8"), True, "dtype"),

@@ -547,6 +547,90 @@ def test_nemotron_h_step_compiles_its_three_kernels_at_published_shapes(
     assert not found, found
 
 
+def test_kimi_linear_step_compiles_its_three_kernels_at_published_shapes(
+        one_chip, as_on_tpu):
+    """Kimi-Linear-48B-A3B's published widths, its first 5 layers (``kda``
+    under the dense lead, ``kda``, ``kda``, ``latent``, ``kda``: every kind),
+    bucket 32, the cell's pools (12,832 bf16 latent blocks whose rows of 576
+    values lie 640 wide, 33 state slots of [128, 4096]): Mosaic accepts,
+    inside the whole step as the engine compiles it (``make_packed_step``),
+    the delta-rule state-update kernel (a lane's whole slot one transfer,
+    ``ssm_update``'s batches of 4), the latent form of the paged-attention
+    kernel (32 query rows of 640 over one cached head, the value its first
+    512 columns) and the routed-expert kernel over 16 held experts of width
+    1024; every pool is aliased whole and nothing of a pool's size is made
+    beside the arguments."""
+    from benchmark.models import kimi_linear_decoder
+    from paddle_tpu.pallas_kernels import moe_experts as moe
+    from paddle_tpu.pallas_kernels import ssm_update as ssm
+
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "kimi-linear-48b-a3b-serve.json")) as fp:
+        config = json.load(fp)
+    linear = dict(config["linear_attn_config"], kda_layers=[1, 2, 3, 5],
+                  full_attn_layers=[4])
+    config = dict(config, num_hidden_layers=5, linear_attn_config=linear)
+    cfg = kimi_linear_decoder.decoder_config(config)
+    assert (cfg.hidden, cfg.heads, cfg.head_dim, cfg.latent_rank,
+            cfg.latent_rope, cfg.kda_heads, cfg.kda_head_dim, cfg.kda_conv,
+            cfg.experts, cfg.experts_held, cfg.experts_per_token, cfg.ffn,
+            cfg.shared_ffn, cfg.dense_ffn, cfg.vocab, cfg.layer_types,
+            cfg.routed_layers) == (
+        2304, 32, 128, 512, 64, 32, 128, 4, 256, 16, 8, 1024, 1024, 9216,
+        163840, ("kda", "kda", "kda", "latent", "kda"), (1, 2, 3, 4))
+    lanes, block_size, blocks = 32, 16, 12832
+    kv = dm.cache_config(cfg, block_size, blocks, state_slots=lanes + 1)
+    assert (kv.layers, kv.latent_layers, kv.latent_width, kv.latent_row,
+            kv.state_layers) == (0, 1, 576, 640, 4)
+    assert kv.state_shapes == (((3 * 12288,), "bf16"),
+                               ((128, 4096), "f32"))
+    assert dm.attention_path(cfg, kv, lanes, "latent") == "pallas"
+    assert dm.state_update_path(cfg, kv, lanes) == "pallas"
+    assert dm.state_update_columns(cfg, kv) == 4096
+    assert moe.experts_path(lanes, (16, 2304, 1024), jnp.bfloat16) \
+        == "pallas"
+
+    on_chip = functools.partial(_placed, one_chip)
+
+    carry = on_chip(jax.eval_shape(lambda: PagedKVCache(kv).carry()))
+    params = on_chip({
+        name: jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+        for name, (shape, _kind)
+        in kimi_linear_decoder.param_shapes(config).items()})
+    feeds = on_chip(_packed_feeds(kv, lanes, cfg.max_seq // block_size))
+    compiled = jax.jit(dm.make_packed_step(cfg, kv, lanes),
+                       donate_argnums=(0,)
+                       ).lower(carry, params, *feeds).compile()
+
+    text = compiled.as_text()
+    assert _kernel_calls(text) == 9     # 4 state updates, 1 latent, 4 experts
+    assert len(re.findall(r"%kda_state_update\S* = ", text)) == 4
+    assert len(re.findall(r"%latent_attention\S* = ", text)) == 1
+    assert _expert_kernels(text) == 4
+    assert not re.findall(r"%(ssm_state_update|paged_attention)\S* = ", text)
+    assert not _expert_passes(text, 16, 2304, 1024)
+    pool_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in carry)
+    state_pool = (lanes + 1) * 128 * 4096 * 4
+    assert pool_bytes == 12832 * 16 * 640 * 2 + 4 * (
+        state_pool + 33 * 3 * 12288 * 2)
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= pool_bytes
+    # what varies down a head's keys crosses into the state kernel turned,
+    # one [32, 128, 128] float32 array a layer (2.1e6 B), and nothing else
+    # of a slot's size is made (20.7e6 B beside the arguments here, under
+    # a third of one state pool)
+    assert memory.temp_size_in_bytes < state_pool / 3
+    # the latent pool is written a row a lane where it lies (a scatter into
+    # the donated array) and never copied into another layout
+    big = re.compile(
+        r" = (f32\[(33|32),128,(4096|2048|1024)\]\S* (copy|select|transpose"
+        r"|slice|dynamic-slice|gather|scatter|fusion)|bf16\[12832,16,640\]"
+        r"\S* (copy|transpose|convert))\(")
+    found = [line.strip()[:160] for line in text.splitlines()
+             if big.search(line)]
+    assert not found, found
+
+
 def test_data_parallel_bert_layer_runs_fused_ln_per_shard_on_v5e_2x2(
         topo, as_on_tpu):
     """BERT-base's widths (hidden 768, bf16 AMP, dropout 0.1), one layer,

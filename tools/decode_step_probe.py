@@ -54,7 +54,15 @@ blocks of one sublayer each (``--layers 6`` keeps ``M E M E M *``): the
 over 2, ``moe/router``, ``moe/experts`` and ``moe/shared`` on the layers that
 are experts alone; the bytes are ``benchmark/nemotron_cost.py``'s, and
 ``--experts block,dense,kernel`` compares the two-matrix forms
-(``moe_experts.relu2_experts``).
+(``moe_experts.relu2_experts``).  For ``--config kimi-linear-48b-a3b-serve
+--blocks 12832`` the step is all 27 layers (``--layers 5`` keeps ``kda kda
+kda latent kda``): the scopes ``layerN/kda/conv``, ``kda/state`` and
+``kda/out``, ``layerN/latent/absorb``, ``latent/kv_write``,
+``latent/kv_read`` and ``latent/out``, ``mlp`` on the dense lead and the
+``moe`` scopes elsewhere; the bytes are ``benchmark/kimi_cost.py``'s, and the
+result gives the ``kda_state_update`` and ``latent_attention`` kernels' own
+ms a step and bytes/s (what ``kimi_kda_state_roofline_share.serve`` and
+``kimi_latent_attention_roofline_share.serve`` divide).
 
     chiprun -- python tools/decode_step_probe.py --config \
         lfm2-24b-a2b-serve --blocks 2048 --experts dense,kernel
@@ -114,7 +122,7 @@ def scope_of(op_name):
             if p in ("layerN", "attn", "mlp", "moe", "router", "experts",
                      "lm_head", "kv_write", "kv_read", "kv_gather",
                      "ssm", "in_proj", "conv", "state_update", "out_proj",
-                     "window")]
+                     "window", "kda", "state", "out", "latent", "absorb")]
     return "/".join(keep) or "other"
 
 
@@ -218,11 +226,12 @@ def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
     import jax
     import numpy as np
 
-    from benchmark import exaone_cost, lfm2_cost, moe_cost, nemotron_cost, \
-        ssm_cost, trace_reduce
+    from benchmark import exaone_cost, kimi_cost, lfm2_cost, moe_cost, \
+        nemotron_cost, ssm_cost, trace_reduce
     from paddle_tpu.core import telemetry
     from paddle_tpu.core.executor import CarriedStepFn
-    from paddle_tpu.pallas_kernels import ssm_update
+    from paddle_tpu.pallas_kernels import kda_update, paged_attention, \
+        ssm_update
     from paddle_tpu.serving import decode_model as dm
     from paddle_tpu.serving import kv_cache as kvc
 
@@ -241,9 +250,11 @@ def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
     # one layer's K (or V) pool, or a recurrent layer's state slots,
     # whichever is smaller: a copy of either is what the search is for
     pool_elems = args.blocks * args.block_size * kv.heads * kv.head_dim
-    if cfg.ssm_layers:
-        pool_elems = min(pool_elems,
-                         kv.state_slots * cfg.ssm_state * cfg.ssm_inner)
+    if cfg.latent_layers:
+        pool_elems = args.blocks * args.block_size * kv.latent_row
+    if cfg.ssm_layers or cfg.kda_layers:
+        pool_elems = min(pool_elems, kv.state_slots * int(np.prod(
+            kv.state_shapes[1][0])))
     result = {
         "label": args.label, "config": config["name"],
         "experts": form, "ssm_update": args.ssm_update,
@@ -256,7 +267,8 @@ def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
                    "state_bytes": kvc.state_bytes(kv),
                    "compile_ms": round(warm["compile_ms"], 1),
                    "pool_sized_instructions": pool_sized(index, pool_elems)},
-        "attention": dm.attention_path(cfg, kv, b),
+        "attention": dm.attention_path(
+            cfg, kv, b, "latent" if cfg.latent_layers else "attention"),
         # the columns of a slot one transfer of the state-update kernel
         # moves (the engine's ``serving_prewarm`` says the same)
         "state_update_columns": dm.state_update_columns(cfg, kv)
@@ -322,6 +334,9 @@ def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
             # two-matrix experts in the layers the pattern names, a share
             bytes_of = nemotron_cost.experts_hit_bytes_per_step
             held = "n_routed_experts"
+        elif "linear_attn_config" in config:
+            # three-matrix experts behind a dense lead, a share
+            bytes_of = kimi_cost.experts_hit_bytes_per_step
         elif "mlp_layer_types" in config:
             # a share: the held experts of each sparse layer
             bytes_of = lambda _c, n: exaone_cost.sparse_layers(config) * n \
@@ -355,6 +370,32 @@ def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
                 kernel_s * 1e3 / args.steps
             result["ssm_state_update_kernel_bytes_per_s"] = \
                 moved / (kernel_s / args.steps)
+    kernel_ms = lambda kernel: sum(
+        s for name, s in prof["op_seconds"].items()
+        if name.lstrip("%").startswith(kernel)) * 1e3 / args.steps
+    if cfg.kda_layers:
+        # every lane's state in every KDA layer, read and written once a
+        # step, over the kernel's own executions
+        moved = kimi_cost.state_traffic_bytes_per_step(config, b)
+        result["kda_state_bytes_per_step"] = moved
+        ms = kernel_ms(kda_update.KERNEL_NAME)
+        if ms:
+            result["kda_state_update_kernel_ms_per_step"] = ms
+            result["kda_state_update_kernel_bytes_per_s"] = moved / (ms / 1e3)
+    if cfg.latent_layers:
+        # the rows the latent layers fetched at the profiled steps' contexts
+        # (the values of a row, not the width its pool holds it in)
+        read = paged_attention.blocks_read(
+            np.asarray(feed(WARM_STEPS + args.steps)[5]), args.block_size,
+            cfg.max_seq // args.block_size, result["attention"])
+        moved = kimi_cost.latent_floor_bytes_per_step(config, read,
+                                                      args.block_size)
+        result["latent_blocks_read"] = read
+        result["latent_bytes_per_step"] = moved
+        ms = kernel_ms(paged_attention.LATENT_KERNEL_NAME)
+        if ms:
+            result["latent_attention_kernel_ms_per_step"] = ms
+            result["latent_attention_kernel_bytes_per_s"] = moved / (ms / 1e3)
     stats = device.memory_stats() or {}
     result["peak_bytes_in_use"] = stats.get("peak_bytes_in_use")
     result["peak_bytes_reserved"] = stats.get("peak_bytes_reserved")
@@ -410,6 +451,13 @@ def main(argv=None):
                     "hybrid_override_pattern"):
             if key in config:
                 config[key] = config[key][:args.layers]
+        if "linear_attn_config" in config:
+            # the source names its layers in two lists, from 1
+            config["linear_attn_config"] = dict(
+                config["linear_attn_config"], **{
+                    key: [l for l in config["linear_attn_config"][key]
+                          if l <= args.layers]
+                    for key in ("kda_layers", "full_attn_layers")})
     device = jax.devices()[0]
     model = load_module("models", config["model"])
     cfg = model.decoder_config(config)
