@@ -12,14 +12,23 @@ block table.
 * **the kernel** (scope ``kv_read``), on a TPU backend, for a rank-3 pool
   in float32 or bfloat16 whose ``KH * D`` is a multiple of the 128 lanes and
   whose ``block_size`` is a multiple of the dtype's sublane tile.  One
-  invocation walks the lanes; for each it loops over chunks of
-  ``CHUNK_TOKENS`` positions up to ``context_lens[b]``, fetches the chunk's
-  blocks from the pools in HBM by async copies steered by the
-  scalar-prefetched table (double buffered, the next chunk or the next
-  lane's first in flight while this one is reduced), and folds them into
-  an online softmax.  Work follows the live context: an idle lane
-  (``context_lens`` 0) fetches nothing and returns zeros; nothing of the
-  table's size is written.
+  invocation walks the lanes; for each it loops over chunks up to
+  ``context_lens[b]``, fetches the chunk's blocks from the pools in HBM by
+  async copies steered by the scalar-prefetched table (double buffered,
+  the next chunk or the next lane's first in flight while this one is
+  reduced), and folds them into an online softmax.  **A chunk is as many
+  of the lane's live blocks as are worth a wait**: its span is the fewest
+  whole steps of ``CHUNK_TOKENS`` (128) positions that hold ``_CHUNK_BYTES``
+  in the pools fetched (K and V rows of 1024 float32, or of 2048 or 1024
+  bfloat16: 128 positions; 8 KV heads of 64: 256; 2 of 128, or one latent
+  row of 640: 512, the most), less where the queries and outputs leave
+  the buffers no room (``_chunk_positions``: a function of the shapes, one
+  constant, no flag), and of a lane's last chunk only the blocks the lane
+  holds are copied and waited for; the rows of a buffer no copy wrote are
+  zeros or an earlier chunk's (finite: their probabilities are 0).  Work
+  follows the live context: an idle lane (``context_lens`` 0) fetches
+  nothing and returns zeros; nothing of the table's size is written and no
+  block the table does not name is read.
 * **the gather** (scope ``kv_gather``) everywhere else: on the CPU tier,
   for the int8 residency (gather, dequantize), in a program XLA partitions
   over a mesh.  ``gather_blocks`` copies every slot of the padded table
@@ -48,8 +57,9 @@ sequence holds ``R`` blocks there whatever its length.  Entry ``j`` of the
 ring's ``R * block_size`` rows then holds the newest position congruent to
 ``j``, ``age = (context_len - 1 - j) mod (R * block_size)`` tokens back, and
 is attended iff ``age < min(window, context_len)`` (``ring_mask``): the
-kernel fetches the ring's ``R`` blocks as one chunk, in the table's order,
-and the gather reads the ring as it lies.  Nothing of the history before the
+kernel fetches the ring's ``R`` blocks as one chunk, whole and in the
+table's order (a slot not held yet fetches block 0, masked), and the gather
+reads the ring as it lies.  Nothing of the history before the
 window is read.
 
 Where ``H / KH`` query heads share a KV head and ``D`` is a multiple of the
@@ -92,7 +102,8 @@ __all__ = ["paged_attention", "paged_attention_reference",
            "masked_attention", "gather_blocks", "ring_mask", "KERNEL_NAME",
            "latent_attention", "latent_attention_reference",
            "latent_attention_checks", "latent_path", "masked_latent",
-           "LATENT_KERNEL_NAME"]
+           "LATENT_KERNEL_NAME", "chunk_positions",
+           "latent_chunk_positions"]
 
 # the name the kernel's executions carry in a device trace
 KERNEL_NAME = "paged_attention"
@@ -102,10 +113,21 @@ LATENT_KERNEL_NAME = "latent_attention"
 
 _MASK = -1e30  # finite: a fully-masked lane softmaxes to uniform, not NaN
 
-# positions the kernel fetches and reduces at a time (whole blocks: 8 of 16
-# tokens).  A lane's last chunk is fetched whole, so the mean waste is half
-# a chunk a lane; smaller chunks leave the MXU's 128 columns part empty.
+# the step of a chunk's span, in positions (whole blocks: 8 of 16 tokens):
+# the MXU's 128 columns.  A chunk is a whole multiple of it, as many as make
+# the chunk's fetch worth its wait (``_chunk_positions``); of a lane's last
+# chunk only the blocks the lane holds are fetched.
 CHUNK_TOKENS = 128
+# bytes a chunk should hold before it is worth a wait.  What a chunk costs
+# beside its bytes (the softmax's max, exp and rescale chain, once a chunk,
+# and the issue of its copies) is paid once whatever the span, so rows of
+# 1,024-2,048 B a position run 26-38% faster by the position at 512
+# positions than at 128, while rows of 8,192 B (1 MiB in 128 positions)
+# lose 2-4% at 256 (PERF.md section 6, PR 47: the copies-only,
+# arithmetic-only and whole forms at spans of 128, 256 and 512 positions;
+# and why K-EXAONE's 4,096 B, 17% faster at 256, stay at 128 for now)
+_CHUNK_BYTES = 512 << 10
+_MAX_CHUNK_TOKENS = 512
 
 _SUBLANES = {"float32": 8, "bfloat16": 16}   # rows of a dtype's memory tile
 
@@ -254,14 +276,22 @@ def paged_attention_checks(q_shape, kv_shape, kv_dtype, ring=0):
 
 def vmem_bytes(q_shape, kv_shape, kv_dtype, ring=0):
     """What the kernel holds in VMEM for these shapes: the four chunk
-    buffers (a window layer's chunk is its whole ring), and every lane's
-    query and output in float32."""
+    buffers (a window layer's chunk is its whole ring; any other's the span
+    ``_chunk_positions`` gives these shapes, which the call uses too), and
+    every lane's query and output in float32."""
+    fetched = 2 * jnp.dtype(kv_dtype).itemsize * kv_shape[2]   # K and V
+    held = _held_bytes(q_shape, kv_shape)
+    span = ring * kv_shape[1] if ring \
+        else _chunk_positions(fetched, kv_shape[1], held)
+    return 2 * span * fetched + held
+
+
+def _held_bytes(q_shape, kv_shape):
+    """Every lane's query and output as the kernel holds them, float32."""
     kv_heads = kv_shape[2] // q_shape[2]
-    span = ring * kv_shape[1] if ring else max(CHUNK_TOKENS, kv_shape[1])
     width = q_shape[2] if _compact(q_shape[1], kv_heads, q_shape[2]) \
         else kv_shape[2]
-    return 4 * span * jnp.dtype(kv_dtype).itemsize * kv_shape[2] \
-        + 2 * q_shape[0] * 4 * _query_rows(q_shape[1], kv_heads) * width
+    return 2 * q_shape[0] * 4 * _query_rows(q_shape[1], kv_heads) * width
 
 
 def _query_rows(heads, kv_heads):
@@ -292,21 +322,47 @@ def attention_path(q_shape, kv_shape, kv_dtype, ring=0):
 
 def blocks_read(context_lens, block_size, maxb, path, ring=False):
     """Blocks one layer's attention fetches for these lanes: every slot of
-    the table on the gather path; on the kernel's, each lane's live blocks
-    rounded up to the kernel's chunk, or for a window layer (``ring``: the
-    table is its ring of ``maxb`` slots) a live lane's whole ring (a
-    host-side count for the step's span: ``context_lens`` is the numpy
-    feed)."""
+    the table on the gather path; on the kernel's, the blocks each lane
+    holds (``ceil(context_len / block_size)``: a chunk fetches its live
+    blocks and no others), or for a window layer (``ring``: the table is
+    its ring of ``maxb`` slots) a live lane's whole ring (a host-side count
+    for the step's span: ``context_lens`` is the numpy feed)."""
     if path != "pallas":
         return len(context_lens) * maxb
     if ring:
         return int((context_lens > 0).sum()) * maxb
-    per = _chunk_blocks(block_size, maxb)
-    return int((-(-context_lens // (per * block_size))).sum()) * per
+    return int((-(-context_lens // block_size)).clip(0, maxb).sum())
 
 
-def _chunk_blocks(block_size, maxb):
-    return max(1, min(CHUNK_TOKENS // block_size, maxb))
+def _chunk_positions(fetched, block_size, held):
+    """Positions a chunk spans where one position costs ``fetched`` bytes
+    in the pools the kernel reads (K and V rows, or the one latent row, in
+    the pool's dtype): the fewest whole steps of ``CHUNK_TOKENS`` that hold
+    ``_CHUNK_BYTES``, at most ``_MAX_CHUNK_TOKENS``, and no more than leave
+    each pool's two buffers room in ``_VMEM_BUDGET`` beside the ``held``
+    bytes of queries and outputs; one step, or one block, at the least."""
+    step = CHUNK_TOKENS * fetched
+    steps = min(-(-_CHUNK_BYTES // step), _MAX_CHUNK_TOKENS // CHUNK_TOKENS,
+                (_VMEM_BUDGET - held) // (2 * step))
+    return max(CHUNK_TOKENS * max(steps, 1), block_size)
+
+
+def _chunk_blocks(block_size, maxb, fetched, held=0):
+    """Blocks a chunk: ``_chunk_positions`` in whole blocks, capped by the
+    table."""
+    return max(1, min(_chunk_positions(fetched, block_size, held)
+                      // block_size, maxb))
+
+
+def chunk_positions(q_shape, kv_shape, kv_dtype, maxb, ring=0):
+    """Positions one chunk of the kernel spans for these shapes and a table
+    of ``maxb`` slots (a window layer's: its ring): what the engine puts on
+    the ``serving_prewarm`` event beside the path's name."""
+    if ring:
+        return ring * kv_shape[1]
+    fetched = 2 * jnp.dtype(kv_dtype).itemsize * kv_shape[2]
+    return kv_shape[1] * _chunk_blocks(kv_shape[1], maxb, fetched,
+                                       _held_bytes(q_shape, kv_shape))
 
 
 # -- the kernel --------------------------------------------------------------
@@ -368,34 +424,81 @@ def _kernel(bt_ref, cl_ref, q_ref, *refs, heads, kv_heads, head_dim,
     rows = -(-heads // 16) * 16          # whole sublane tiles in any dtype
     span = per * block_size              # positions a chunk
 
+    def blocks(b):
+        """Blocks lane ``b`` holds: what its chunks fetch."""
+        return jnp.minimum((cl_ref[b] + block_size - 1) // block_size, maxb)
+
     def chunks(b):
         if window is not None:
             return jnp.minimum(cl_ref[b], 1)
-        return (cl_ref[b] + span - 1) // span
+        return (blocks(b) + per - 1) // per
 
-    def copies(slot, block_of):
-        """A chunk's ``per`` block copies a pool into buffer ``slot``."""
-        for i in range(per):
-            at = pl.ds(i * block_size, block_size)
-            for pool, buf, which in pools:
-                yield pltpu.make_async_copy(
-                    pool.at[block_of(i)], buf.at[slot, at],
-                    sem.at[which, slot])
+    def copies(slot, i, block):
+        """Block ``block()`` of each pool into rows ``i`` of buffer
+        ``slot``, a copy a pool (``block`` is called a pool: the ring's
+        lowering reads its slot of the table once for K and once for V)."""
+        at = pl.ds(i * block_size, block_size) if isinstance(i, int) \
+            else pl.ds(pl.multiple_of(i * block_size, block_size), block_size)
+        for pool, buf, which in pools:
+            yield pltpu.make_async_copy(pool.at[block()], buf.at[slot, at],
+                                        sem.at[which, slot])
 
-    def start(b, c, slot):
-        # slots past the table's end or unused (-1) fetch block 0: their
-        # positions lie beyond the context and are masked
-        def block_of(i):
-            j = jnp.minimum(c * per + i, maxb - 1)
-            return jnp.maximum(bt_ref[b * maxb + j], 0)
+    if window is not None:
+        # the ring is one chunk, fetched whole in the table's order: a slot
+        # the lane does not hold yet (-1) fetches block 0, and ``ring_mask``
+        # leaves its positions out
+        def start(b, c, slot):
+            def slot_of(i):
+                j = jnp.minimum(c * per + i, maxb - 1)
+                return jnp.maximum(bt_ref[b * maxb + j], 0)
 
-        for dma in copies(slot, block_of):
-            dma.start()
+            for i in range(per):
+                for dma in copies(slot, i, functools.partial(slot_of, i)):
+                    dma.start()
 
-    def wait(slot):
-        # a wait needs the copy's shape and semaphore, not its source
-        for dma in copies(slot, lambda i: 0):
-            dma.wait()
+        def wait(b, c, slot):
+            # a wait needs the copy's shape and semaphore, not its source
+            for i in range(per):
+                for dma in copies(slot, i, lambda: 0):
+                    dma.wait()
+    else:
+        # a chunk is the lane's live blocks among its ``per``: the last
+        # chunk of a lane fetches the blocks the lane holds and no others,
+        # and as many copies are waited for as were started.  A copy issued
+        # from straight-line code costs a third of one issued from a loop
+        # (the scalar core runs the kernel's one instruction stream), so
+        # the blocks go by whole steps of ``CHUNK_TOKENS`` positions, each
+        # under one predicate, and only the last step's few in a loop
+        step = max(1, CHUNK_TOKENS // block_size)
+        steps = per // step
+
+        def transfer(act, b, c, slot):
+            n = jnp.minimum(blocks(b) - c * per, per)
+
+            def one(i, _=None):
+                # a wait needs the copy's shape and semaphore, not its source
+                block = jnp.maximum(bt_ref[b * maxb + c * per + i], 0) \
+                    if act == "start" else 0
+                for dma in copies(slot, i, lambda: block):
+                    getattr(dma, act)()
+
+            for k in range(steps):
+                @pl.when(n >= (k + 1) * step)
+                def _whole_step():
+                    for i in range(k * step, (k + 1) * step):
+                        one(i)
+
+            jax.lax.fori_loop(jnp.minimum(n // step, steps) * step, n, one,
+                              None)
+
+        start = functools.partial(transfer, "start")
+        wait = functools.partial(transfer, "wait")
+
+        # rows no copy writes are still multiplied (by probabilities that
+        # are 0): they have to be finite, so they start as zeros, and later
+        # hold an earlier chunk's rows
+        for _pool, buf, _which in pools:
+            buf[...] = jnp.zeros(buf.shape, buf.dtype)
 
     # row r of the mask covers the columns of query head r's KV head
     # (r // group; its own where group is 1) in the folded width
@@ -436,7 +539,7 @@ def _kernel(bt_ref, cl_ref, q_ref, *refs, heads, kv_heads, head_dim,
                 start(jnp.where(more, b, nxt), jnp.where(more, c + 1, 0),
                       1 - slot)
 
-            wait(slot)
+            wait(b, c, slot)
             sc = _product(qx, kbuf[slot], _NT) * scale   # [rows, span]
             pos = c * span + jax.lax.broadcasted_iota(
                 jnp.int32, (1, span), 1)
@@ -487,7 +590,8 @@ def _paged_pallas(q, k_cache, v_cache, block_tables, context_lens,
     _nb, bs, hd = k_cache.shape
     kh = hd // d
     maxb = block_tables.shape[1]
-    per = maxb if window is not None else _chunk_blocks(bs, maxb)
+    per = chunk_positions(q.shape, k_cache.shape, k_cache.dtype, maxb,
+                          ring=maxb if window is not None else 0) // bs
     if interpret is None:
         interpret = adoption.interpret()
     if scale is None:
@@ -574,13 +678,25 @@ def latent_attention_reference(q, pool, block_tables, context_lens, scale,
 
 
 def latent_vmem_bytes(q_shape, pool_shape, pool_dtype, rank):
-    """What the latent kernel holds in VMEM: two chunk buffers, every
-    lane's query and its output in float32."""
+    """What the latent kernel holds in VMEM: two chunk buffers of the span
+    ``_chunk_positions`` gives these shapes (the call's too), every lane's
+    query and its output in float32."""
+    fetched = jnp.dtype(pool_dtype).itemsize * q_shape[2]     # the one row
+    held = _latent_held_bytes(q_shape, rank)
+    return 2 * _chunk_positions(fetched, pool_shape[1], held) * fetched \
+        + held
+
+
+def _latent_held_bytes(q_shape, rank):
     lanes, heads, padded = q_shape
-    rows = -(-heads // 16) * 16
-    span = max(CHUNK_TOKENS, pool_shape[1])
-    return 2 * span * jnp.dtype(pool_dtype).itemsize * padded \
-        + lanes * 4 * rows * (padded + rank)
+    return lanes * 4 * (-(-heads // 16) * 16) * (padded + rank)
+
+
+def latent_chunk_positions(q_shape, pool_shape, pool_dtype, rank, maxb):
+    """``chunk_positions`` of the latent form."""
+    fetched = jnp.dtype(pool_dtype).itemsize * q_shape[2]
+    return pool_shape[1] * _chunk_blocks(
+        pool_shape[1], maxb, fetched, _latent_held_bytes(q_shape, rank))
 
 
 def latent_attention_checks(q_shape, pool_shape, pool_dtype, rank):
@@ -628,7 +744,8 @@ def _latent_pallas(q, pool, block_tables, context_lens, scale, rank,
     bb, h, width = q.shape
     bs = pool.shape[1]
     maxb = block_tables.shape[1]
-    per = _chunk_blocks(bs, maxb)
+    per = latent_chunk_positions(q.shape, pool.shape, pool.dtype, rank,
+                                 maxb) // bs
     if interpret is None:
         interpret = adoption.interpret()
     rows = -(-h // 16) * 16

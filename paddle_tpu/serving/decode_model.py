@@ -631,6 +631,37 @@ def attention_path(cfg, kv_config, lanes=1, kind="attention"):
         ring=kv_config.window_ring if windowed else 0)
 
 
+def chunk_positions(cfg, kv_config, lanes=1):
+    """Positions one chunk of the attention kernel spans, by kind of layer
+    that pages a history (``attention``, ``window``, ``latent``) and takes
+    the kernel at a bucket of ``lanes``: the kernel sizes a chunk by the
+    bytes a position costs in that kind's pools, a window layer's is its
+    ring (``paged_attention.chunk_positions``).  Kinds on the gather path
+    have no chunk and no entry."""
+    maxb = -(-cfg.max_seq // kv_config.block_size)
+    dtype = _kv._PAYLOAD[kv_config.dtype][0]
+    q = (lanes, cfg.heads, cfg.head_dim)
+    width = kv_config.heads * kv_config.head_dim
+    out = {}
+    for kind in ("attention", "window", "latent"):
+        if not cfg._of_kind(kind) \
+                or attention_path(cfg, kv_config, lanes, kind) != "pallas":
+            continue
+        if kind == "latent":
+            row = kv_config.latent_row
+            out[kind] = _pa.latent_chunk_positions(
+                (lanes, cfg.heads, row), (kv_config.num_blocks,
+                                          kv_config.block_size, row),
+                dtype, cfg.latent_rank, maxb)
+        else:
+            ring = kv_config.window_ring if kind == "window" else 0
+            out[kind] = _pa.chunk_positions(
+                q, (kv_config.window_blocks if ring
+                    else kv_config.num_blocks, kv_config.block_size, width),
+                dtype, maxb, ring)
+    return out
+
+
 def experts_path(cfg, params, lanes=1):
     """``"pallas"`` where a routed layer's experts are the kernel that
     reads the experts hit and no others, for this model's weights on this

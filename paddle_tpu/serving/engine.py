@@ -1429,6 +1429,10 @@ class DecodeEngine:
                 extra["window_attention"] = m.window_path
             if m.cfg.latent_layers:
                 extra["latent_attention"] = m.attn_path
+            # positions a chunk of the attention kernel spans, by kind of
+            # layer that takes it
+            extra["chunk_positions"] = _dm.chunk_positions(
+                m.cfg, m.kv_config, bucket)
             _tm.event("serving_prewarm", model=model, bucket=bucket,
                       source=got["source"], decode=True, fn=fn,
                       ms=round(got["compile_ms"], 3),

@@ -77,8 +77,8 @@ def chunked(monkeypatch, n, columns):
 class Row(collections.namedtuple(
         "Row",
         ("arch", "configs", "declines", "holds", "refusal", "multi_atol",
-         "batch_dependent_bf16", "entry", "serve"),
-        defaults=(None, None, None, None, False, {}, None))):
+         "batch_dependent_bf16", "entry", "serve", "chunk"),
+        defaults=(None, None, None, None, False, {}, None, None))):
     """``configs``: id -> (DecoderConfig, params), ``f32`` first and, where
     the family has one, ``bf16``.  ``declines``: why the engine gives the
     family no prefix reuse, export or adoption (None: it has them).
@@ -91,7 +91,9 @@ class Row(collections.namedtuple(
     loop.  ``entry``: attribute of the engine's entry -> what it has to be
     (a callable takes the configuration).  ``serve``: (the benchmark
     configuration ``tools/serve.py`` builds a demo bundle from, attribute of
-    the bundle's configuration -> its value), or None."""
+    the bundle's configuration -> its value), or None.  ``chunk``: (the
+    family's benchmark configuration, its cell's KV blocks, kind of layer ->
+    the positions a chunk of the attention kernel spans at those widths)."""
 
     @property
     def f32(self):
@@ -151,17 +153,22 @@ _GRANITE_G4 = _GRANITE.replace(kv_heads=1)
 # within hundredths of a half.
 ROWS = {row.arch: row for row in (
     Row("gpt2", {"f32": (_GPT2, dm.init_decoder_params(_GPT2, seed=7))},
-        serve=(None, dict(vocab=31, layers=2, max_seq=48))),
+        serve=(None, dict(vocab=31, layers=2, max_seq=48)),
+        # K and V rows of 1024 float32: 128 positions are 1 MiB
+        chunk=("gpt2-medium-serve.json", 1024, {"attention": 128})),
     Row("olmoe", _both(_OLMOE, olmoe.init_params, std=0.05),
         multi_atol=2e-4,
         serve=("olmoe-1b-7b-serve.json",
-               dict(experts=8, experts_per_token=2, ffn=32))),
+               dict(experts=8, experts_per_token=2, ffn=32)),
+        chunk=("olmoe-1b-7b-serve.json", 2048, {"attention": 128})),
     Row("granite_hybrid",
         dict(_both(_GRANITE, granite_hybrid.init_params, std=0.3),
              group4=(_GRANITE_G4, granite_hybrid.init_params(
                  _GRANITE_G4, seed=3, std=0.3))),
         declines="recurrent_state", holds="slot", refusal="recurrent",
-        entry=dict(state_name="ssm_state", state_path={4: "gather"})),
+        entry=dict(state_name="ssm_state", state_path={4: "gather"}),
+        # 8 KV heads of 64 in bfloat16: 2,048 B a position
+        chunk=("granite-4.0-h-micro-serve.json", 2048, {"attention": 256})),
     Row("lfm2_moe", _both(_LFM2, lfm2_moe.init_params, std=0.3),
         declines="recurrent_state", holds="slot", refusal="recurrent",
         entry=dict(state_name="conv_state", slot_bytes=lambda cfg:
@@ -169,7 +176,8 @@ ROWS = {row.arch: row for row in (
         serve=("lfm2-24b-a2b-serve.json",
                dict(layer_types=("conv", "attention", "conv", "conv"),
                     dense_layers=1, experts=8, experts_per_token=2,
-                    conv_taps=3, rope_theta=1e6))),
+                    conv_taps=3, rope_theta=1e6)),
+        chunk=("lfm2-24b-a2b-serve.json", 2048, {"attention": 256})),
     Row("exaone_moe",
         _both(_EXAONE, exaone_moe.init_params, std=0.3, bias_std=0.05),
         declines="window_layers", holds="ring", refusal="window layers",
@@ -178,7 +186,11 @@ ROWS = {row.arch: row for row in (
         serve=("k-exaone-236b-a23b-serve.json",
                dict(layer_types=_EXAONE.layer_types, dense_layers=1,
                     experts=16, experts_held=4, experts_per_token=4,
-                    window=8, hidden=48, heads=8, rope_theta=1e6))),
+                    window=8, hidden=48, heads=8, rope_theta=1e6)),
+        # 8 KV heads of 128: 524,288 B in 128 positions; a window layer's
+        # chunk is its ring of 9 blocks
+        chunk=("k-exaone-236b-a23b-serve.json", 12832,
+               {"attention": 128, "window": 144})),
     Row("nemotron_h",
         _both(_NEMOTRON, nemotron_h.init_params, std=0.3, bias_std=0.05),
         declines="recurrent_state", holds="slot", refusal="recurrent",
@@ -188,7 +200,10 @@ ROWS = {row.arch: row for row in (
         serve=("nemotron-3-nano-30b-a3b-serve.json",
                dict(layer_types=_NEMOTRON.layer_types, experts=16,
                     experts_held=4, expert_first=4, experts_per_token=3,
-                    ssm_groups=2, hidden=48, ffn=24))),
+                    ssm_groups=2, hidden=48, ffn=24)),
+        # 2 KV heads of 128: 1,024 B a position
+        chunk=("nemotron-3-nano-30b-a3b-serve.json", 2048,
+               {"attention": 512})),
     Row("kimi_linear",
         _both(_KIMI, kimi_linear.init_params, std=0.3, bias_std=0.05),
         declines="recurrent_state", holds="slot", refusal="recurrent",
@@ -199,7 +214,9 @@ ROWS = {row.arch: row for row in (
                dict(layer_types=_KIMI.layer_types, experts=16,
                     experts_held=4, expert_first=4, experts_per_token=3,
                     kda_heads=4, kda_head_dim=8, latent_rank=24,
-                    latent_rope=8, hidden=48, ffn=24))),
+                    latent_rope=8, hidden=48, ffn=24)),
+        # one row of 640 bfloat16 a position: 1,280 B
+        chunk=("kimi-linear-48b-a3b-serve.json", 12832, {"latent": 512})),
 )}
 assert tuple(ROWS) == dm.ARCHS
 

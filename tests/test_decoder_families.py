@@ -8,6 +8,7 @@ reuse, speculation, export and adoption), each under its reason; the bundle
 round trip; and ``tools/serve.py``'s demo bundle served at the defaults.  A
 family's own file (``tests/test_<family>.py``) holds what is its alone."""
 
+import json
 import threading
 
 import numpy as np
@@ -151,6 +152,38 @@ def test_lanes_move_up_and_what_a_sequence_held_is_reused(
         assert _tm.counter_total("serving_steps_ahead_total") > 0
     finally:
         e.stop()
+
+
+@pytest.mark.parametrize("row,key", fam.cases(dtypes=["f32"]))
+def test_prewarm_names_the_chunk_by_kind_of_layer(
+        row, key, cache_dir, telemetry_on, tmp_path, monkeypatch):
+    """The ``serving_prewarm`` event carries ``chunk_positions``: the
+    positions a chunk of the attention kernel spans, by kind of layer that
+    takes the kernel (the kernel sizes its chunk by the bytes a position
+    costs in that kind's pools).  At the family's published widths (its
+    benchmark configuration, 32 lanes, its cell's pool) that is the row's
+    ``chunk``; at the tests' widths, under the 128 lanes, every kind gathers
+    and the engine's event says so: no kind has a chunk."""
+    name, blocks, want = row.chunk
+    with open(fam.config_file(name)) as fp:
+        config = json.load(fp)
+    config.pop("tiny", None)
+    published = fam.load("benchmark", "models", config["model"] + ".py") \
+        .decoder_config(config)
+    kv = dm.cache_config(published, 16, blocks, published.kv_dtype or "f32",
+                         state_slots=33)
+    assert dm.chunk_positions(published, kv, 32) == {}      # the CPU gathers
+    with monkeypatch.context() as mp:
+        mp.setenv("PADDLE_PALLAS_INTERPRET", "1")           # as a TPU would
+        assert dm.chunk_positions(published, kv, 32) == want
+    cfg, params = row.configs[key]
+    with fam.flags(telemetry_dir=str(tmp_path)):
+        e = fam.engine(cfg, params, 40, start=False)
+        e.prewarm()
+        _tm.flush()
+    warm = fam.prewarm_events(tmp_path)
+    assert warm and all(ev["chunk_positions"] == {} == dm.chunk_positions(
+        cfg, e._models["m"].kv_config, ev["bucket"]) for ev in warm)
 
 
 @pytest.mark.parametrize("row,key", holding)

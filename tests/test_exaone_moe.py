@@ -393,7 +393,8 @@ def test_kernel_reads_a_ring_and_nothing_before_the_window(interpreted, heads,
                                    rtol=2e-5)
     # work follows the ring: 9 blocks a live lane, whatever the context
     assert pa.blocks_read(lens, bs, ring, "pallas", ring=True) == 5 * ring
-    assert pa.blocks_read(lens, bs, 64, "pallas") == 8 * (1 + 1 + 2 + 2 + 8)
+    # ... where a global layer's kernel fetches the blocks each lane holds
+    assert pa.blocks_read(lens, bs, 64, "pallas") == 1 + 8 + 9 + 10 + 63
 
 
 def test_the_spread_layout_is_over_the_vmem_budget_and_compact_under_it():
@@ -406,9 +407,10 @@ def test_the_spread_layout_is_over_the_vmem_budget_and_compact_under_it():
         == 4 * 128 * 2 * 1024 + 2 * 32 * 4 * 64 * 128 < pa._VMEM_BUDGET
     assert pa.vmem_bytes(q, (297, 16, 1024), jnp.bfloat16, 9) \
         == 4 * 144 * 2 * 1024 + 2 * 32 * 4 * 64 * 128
-    # LFM2's and Granite's shapes keep the spread layout (head_dim 64)
+    # LFM2's and Granite's shapes keep the spread layout (head_dim 64), and
+    # their narrower rows make a chunk 256 positions
     assert pa.vmem_bytes((32, 32, 64), (2048, 16, 512), jnp.bfloat16) \
-        == 4 * 128 * 2 * 512 + 2 * 32 * 4 * 32 * 512
+        == 4 * 256 * 2 * 512 + 2 * 32 * 4 * 32 * 512
 
 
 def test_the_paged_step_on_the_kernel_gives_the_gathers_tokens(interpreted):

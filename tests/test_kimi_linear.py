@@ -565,8 +565,8 @@ def test_latent_attention_kernel_equals_the_gather(interpreted, dtype, tol):
     np.testing.assert_allclose(np.asarray(got)[live], np.asarray(want)[live],
                                atol=tol, rtol=tol)
     assert not np.asarray(got)[2].any()
-    # what the kernel's span counts: live blocks rounded up to its chunk
-    assert pa.blocks_read(np.asarray(lens), bs, maxb, "pallas") == 8 + 16 + 8
+    # what the kernel's span counts: the blocks each lane holds
+    assert pa.blocks_read(np.asarray(lens), bs, maxb, "pallas") == 1 + 12 + 5
 
 
 def test_a_latent_pool_the_kernel_cannot_read_falls_back():

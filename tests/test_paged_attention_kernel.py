@@ -51,18 +51,18 @@ def _contexts(block_size):
             "full_table": [full, full - 1]}
 
 
-def _pools(kind, lens, seed=0):
+def _pools(kind, lens, seed=0, maxb=MAXB):
     """Random folded pools and a table: each lane's blocks its own, but the
     first block of lanes 0 and 1 shared (a prefix-cache hit) wherever both
     have one; every unused slot is -1."""
     dtype, head_dim, block_size, _tol = POOLS[kind]
     rng = np.random.RandomState(seed)
-    blocks = 1 + MAXB * len(lens)
+    blocks = 1 + maxb * len(lens)
     width = HEADS * head_dim
     q = rng.randn(len(lens), HEADS, head_dim).astype(np.float32)
     k, v = (jnp.asarray(rng.randn(blocks, block_size, width)
                         .astype(np.float32)).astype(dtype) for _ in "kv")
-    tables = np.full((len(lens), MAXB), -1, np.int32)
+    tables = np.full((len(lens), maxb), -1, np.int32)
     free = list(rng.permutation(np.arange(1, blocks)))
     for i, n in enumerate(lens):
         for j in range(-(-n // block_size)):
@@ -89,6 +89,82 @@ def test_kernel_matches_the_gather_path(interpreted, kind, context):
     assert adoption.active_kernels() == ["paged_attention"]
     assert _tm.counter_total("pallas_kernel_used_total") == 1
     assert _tm.counter_total("pallas_kernel_fallback_total") == 0
+
+
+def _borders(block_size, span):
+    """Contexts at a chunk's borders, and the table they need: nothing, one
+    position, a block, a chunk less one, a chunk, a chunk and one, two
+    chunks and a block and three, the table's full length."""
+    full = 2 * span + 4 * block_size
+    return [0, 1, block_size, span - 1, span, span + 1,
+            2 * span + block_size + 3, full], full // block_size
+
+
+def _unnamed_are_nan(pool, tables):
+    """``pool`` with NaN in every block no lane's table names (block 0 and
+    the free ones): a kernel that fetched one would return it."""
+    free = np.ones(pool.shape[0], bool)
+    free[np.unique(tables[tables >= 0])] = False
+    return jnp.where(jnp.asarray(free)[:, None, None], jnp.nan, pool)
+
+
+@pytest.mark.parametrize("span", [128, 256])
+@pytest.mark.parametrize("kind", sorted(POOLS))
+def test_a_chunk_fetches_the_blocks_a_lane_holds_and_no_others(
+        interpreted, monkeypatch, kind, span):
+    """A position costs 1,024 B in either pool pair here, so ``_CHUNK_BYTES``
+    of ``span`` KiB makes a chunk ``span`` positions.  Every block no table
+    names is NaN: the outputs are finite and the clean pools' gather, at
+    each border of a chunk; an idle lane returns zeros."""
+    monkeypatch.setattr(pa, "_CHUNK_BYTES", span * 1024)
+    dtype, head_dim, block_size, tol = POOLS[kind]
+    lens, maxb = _borders(block_size, span)
+    q, k, v, tables, lens = _pools(kind, lens, maxb=maxb)
+    assert pa.chunk_positions(q.shape, k.shape, dtype, maxb) == span
+    ref = np.asarray(pa.paged_attention_reference(q, k, v, tables, lens))
+    out = np.asarray(pa.paged_attention(
+        q, _unnamed_are_nan(k, tables), _unnamed_are_nan(v, tables), tables,
+        lens))
+    assert adoption.active_kernels() == ["paged_attention"]
+    assert np.isfinite(out).all()
+    assert np.abs(out[1:] - ref[1:]).max() <= tol
+    assert not out[0].any()
+
+
+@pytest.mark.parametrize("span", [128, 256])
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                       (jnp.bfloat16, 2e-2)],
+                         ids=["f32", "bf16"])
+def test_a_latent_chunk_fetches_the_blocks_a_lane_holds_and_no_others(
+        interpreted, monkeypatch, dtype, tol, span):
+    """The same of the latent form (12 query rows over one cached head 256
+    wide, the value its first 128 columns)."""
+    heads, width, rank, block_size = 12, 256, 128, 16
+    monkeypatch.setattr(pa, "_CHUNK_BYTES",
+                        span * width * jnp.dtype(dtype).itemsize)
+    lens, maxb = _borders(block_size, span)
+    rng = np.random.default_rng(5)
+    blocks = 1 + maxb * len(lens)
+    pool = jnp.asarray(rng.standard_normal((blocks, block_size, width)),
+                       dtype)
+    q = jnp.asarray(rng.standard_normal((len(lens), heads, width)),
+                    jnp.float32)
+    tables = np.full((len(lens), maxb), -1, np.int32)
+    free = iter(rng.permutation(np.arange(1, blocks)))
+    for b, n in enumerate(lens):
+        for j in range(-(-n // block_size)):
+            tables[b, j] = next(free)
+    lens = np.asarray(lens, np.int32)
+    assert pa.latent_chunk_positions(q.shape, pool.shape, dtype, rank,
+                                     maxb) == span
+    ref = np.asarray(pa.latent_attention_reference(q, pool, tables, lens,
+                                                   0.1, rank))
+    out = np.asarray(pa.latent_attention(
+        q, _unnamed_are_nan(pool, tables), tables, lens, 0.1, rank))
+    assert adoption.active_kernels() == ["latent_attention"]
+    assert np.isfinite(out).all()
+    np.testing.assert_allclose(out[1:], ref[1:], atol=tol, rtol=tol)
+    assert not out[0].any()
 
 
 def test_f32_products_are_f32(interpreted):
@@ -148,13 +224,87 @@ def test_shape_rule(interpreted, monkeypatch, case):
             {"kernel": "paged_attention", "reason": expected}]
 
 
-def test_blocks_read_counts_live_chunks():
+CELLS = {
+    # the seven serving cells' attention at 32 lanes and blocks of 16:
+    # (q shape, pool shape, dtype, table slots, ring or latent rank,
+    #  positions a chunk, bytes of VMEM)
+    "gpt2": ((32, 16, 64), (1024, 16, 1024), "float32", 64, 0,
+             128, 4 * 128 * 4 * 1024 + 2 * 32 * 4 * 1024),
+    "olmoe": ((32, 16, 128), (2048, 16, 2048), "bfloat16", 128, 0,
+              128, 4 * 128 * 2 * 2048 + 2 * 32 * 4 * 2048),
+    "k_exaone_global": ((32, 64, 128), (12832, 16, 1024), "bfloat16", 512, 0,
+                        128, 4 * 128 * 2 * 1024 + 2 * 32 * 4 * 64 * 128),
+    "k_exaone_ring": ((32, 64, 128), (297, 16, 1024), "bfloat16", 9, 9,
+                      144, 4 * 144 * 2 * 1024 + 2 * 32 * 4 * 64 * 128),
+    "granite_lfm2": ((32, 32, 64), (2048, 16, 512), "bfloat16", 128, 0,
+                     256, 4 * 256 * 2 * 512 + 2 * 32 * 4 * 32 * 512),
+    "nemotron_h": ((32, 32, 128), (2048, 16, 256), "bfloat16", 128, 0,
+                   512, 4 * 512 * 2 * 256 + 2 * 32 * 4 * 32 * 128),
+    "kimi_latent": ((32, 32, 640), (12832, 16, 640), "bfloat16", 512, 512,
+                    512, 2 * 512 * 2 * 640 + 32 * 4 * 32 * (640 + 512)),
+    # a table shorter than the rule's chunk is one chunk
+    "short_table": ((32, 32, 128), (2048, 16, 256), "bfloat16", 20, 0,
+                    320, 4 * 512 * 2 * 256 + 2 * 32 * 4 * 32 * 128),
+}
+
+
+@pytest.mark.parametrize("cell", sorted(CELLS))
+def test_a_chunk_spans_what_its_bytes_are_worth(cell):
+    """The span follows the bytes a position costs in the pools fetched: 128
+    positions where they hold ``_CHUNK_BYTES`` (512 KiB: K and V rows of
+    4,096 B and over), the fewest steps of 128 that do otherwise, 512 at the
+    most; a ring is its own chunk; and the ``vmem`` check counts the buffers
+    of the span the call uses."""
+    q, pool, dtype, maxb, extra, span, vmem = CELLS[cell]
+    assert pa._CHUNK_BYTES == 512 << 10
+    if cell == "kimi_latent":
+        fetched = jnp.dtype(dtype).itemsize * pool[2]
+        held = pa._latent_held_bytes(q, extra)
+        assert pa.latent_chunk_positions(q, pool, dtype, extra, maxb) == span
+        assert pa.latent_vmem_bytes(q, pool, dtype, extra) == vmem
+    else:
+        fetched = 2 * jnp.dtype(dtype).itemsize * pool[2]
+        held = pa._held_bytes(q, pool)
+        assert pa.chunk_positions(q, pool, dtype, maxb, ring=extra) == span
+        assert pa.vmem_bytes(q, pool, dtype, ring=extra) == vmem
+    if not extra or cell == "kimi_latent":
+        assert pa._chunk_blocks(16, maxb, fetched, held) == span // 16
+    assert vmem <= pa._VMEM_BUDGET
+
+
+def test_a_chunk_shrinks_to_the_vmem_left_and_no_further():
+    """Queries and outputs that leave the buffers less room make the chunk
+    shorter, by steps of 128 down to 128; under that the ``vmem`` check
+    declines, as it did when every chunk was 128 positions: multi-head
+    pools over 4096 wide in bfloat16, over 2048 in float32, at 32 lanes."""
+    fetched = 1280                                  # Kimi's latent row
+    room = lambda held: pa._chunk_positions(fetched, 16, held)
+    assert room(0) == 512
+    assert room(pa._VMEM_BUDGET - 2 * 512 * fetched) == 512
+    assert room(pa._VMEM_BUDGET - 2 * 512 * fetched + 1) == 384
+    assert room(pa._VMEM_BUDGET - 2 * 256 * fetched) == 256
+    assert room(pa._VMEM_BUDGET) == 128
+    for dtype, size, fits, not_ in (("bfloat16", 2, 4096, 8192),
+                                    ("float32", 4, 2048, 4096)):
+        for width in range(128, 8192 + 128, 128):
+            q, pool = (32, width // 64, 64), (64, 16, width)
+            ok = dict(pa.paged_attention_checks(q, pool, dtype))["vmem"]
+            # what the check said of chunks of 128 positions
+            assert ok == (4 * 128 * size * width + 2 * 32 * 4 * width
+                          <= pa._VMEM_BUDGET), (dtype, width)
+            assert ok or width > fits
+            assert not ok or width < not_
+
+
+def test_blocks_read_counts_live_blocks():
     lens = np.array([0, 1, 128, 129, 1000], np.int32)
     assert pa.blocks_read(lens, 16, 64, "gather") == 5 * 64
-    # chunks of 128 positions = 8 blocks: 0 + 1 + 1 + 2 + 8 chunks
-    assert pa.blocks_read(lens, 16, 64, "pallas") == 12 * 8
-    # a table shorter than a chunk is one chunk
-    assert pa.blocks_read(np.array([5, 0], np.int32), 4, 12, "pallas") == 12
+    # the blocks each lane holds, whatever the chunk: 0 + 1 + 8 + 9 + 63
+    assert pa.blocks_read(lens, 16, 64, "pallas") == 81
+    # a table shorter than a chunk: still the blocks held, two of four
+    assert pa.blocks_read(np.array([5, 0], np.int32), 4, 12, "pallas") == 2
+    # ... and never more than the table names
+    assert pa.blocks_read(np.array([100], np.int32), 4, 12, "pallas") == 12
 
 
 # -- the decode steps on the kernel ------------------------------------------
@@ -264,9 +414,12 @@ def test_engine_names_the_path_and_counts_the_blocks(interpreted, tmp_path):
         attrs = [s["attrs"] for s in tr.records("serving.decode_step")]
         maxb = CFG.max_seq // BS
         assert attrs and all(a["kv_table_slots"] == 2 * maxb for a in attrs)
-        # one lane of 1..12 positions and an idle one: a table of six blocks
-        # is one chunk, fetched whole
-        assert all(a["kv_blocks_read"] == maxb for a in attrs)
+        # one lane of 1..12 positions and an idle one: the one or two
+        # blocks the lane holds, of a table of six
+        assert {a["kv_blocks_read"] for a in attrs} == {1, 2}
+        # the chunk's span by kind of layer, beside the path's name
+        assert all(ev["chunk_positions"] == {"attention": CFG.max_seq}
+                   for ev in warm)
     finally:
         fluid.set_flags(old)
         tr.reset()

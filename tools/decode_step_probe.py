@@ -67,19 +67,30 @@ ms a step and bytes/s (what ``kimi_kda_state_roofline_share.serve`` and
     chiprun -- python tools/decode_step_probe.py --config \
         lfm2-24b-a2b-serve --blocks 2048 --experts dense,kernel
 
+Every result names ``attention_kernels``: by kind of layer that takes the
+attention kernel, the positions a chunk spans (the kernel sizes it by the
+bytes a position costs), the chunks a step walks at the profiled contexts,
+the kernels' own ms a step, microseconds a chunk, and bytes/s over the live
+blocks' bytes.  ``--contexts lo-hi`` draws the lanes' contexts from that
+range (a cell's window: ``1100-3100`` for Kimi-Linear's) where the default
+is a third to the whole of a lane's share of the pool.
+
 ``--check`` leaves the model out and compares the step's attention alone,
 at the configuration's shapes, on one layer's random pools and the same
-tables: the kernel (``pallas_kernels/paged_attention.py``) against the
+tables: the kernel (``pallas_kernels/paged_attention.py``; its latent form
+over one pool of rows for a model with latent layers) against the
 gather path, largest absolute and rms error, beside a control whose
 products are rounded to bfloat16 (an f32 pool's kernel has to sit orders
 below it) and, for a bf16 pool, beside the gather path's own distance from
-float32 mathematics on the stored values; and the time of each a call.
+float32 mathematics on the stored values; and the time of each a call, the
+kernel's also by the chunk.
 
 It needs the TPU for a time; ``--compile-only`` stops after ``memory`` (it
 then says what the local backend's compiler made, which is not the chip's).
 """
 
 import argparse
+import functools
 import importlib
 import json
 import math
@@ -165,35 +176,54 @@ def check_attention(cfg, kv, tables, lens, seed, repeat=24, window=None):
     from paddle_tpu.serving import kv_cache as kvc
 
     dtype = kvc._PAYLOAD[kv.dtype][0]
+    latent = bool(cfg.latent_layers)
+    width = kv.latent_row if latent else kv.heads * kv.head_dim
     shape = (kv.window_blocks if window else kv.num_blocks, kv.block_size,
-             kv.heads * kv.head_dim)
+             width)
     kq, kk, kv_ = jax.random.split(jax.random.PRNGKey(seed % (2 ** 31)), 3)
-    q = jax.random.normal(kq, (len(lens), cfg.heads, cfg.head_dim),
-                          jnp.float32)
+    q = jax.random.normal(
+        kq, (len(lens), cfg.heads, width if latent else cfg.head_dim),
+        jnp.float32)
     k_pool = jax.random.normal(kk, shape, jnp.float32).astype(dtype)
     v_pool = jax.random.normal(kv_, shape, jnp.float32).astype(dtype)
     tables, lens = jnp.asarray(tables), jnp.asarray(lens)
 
-    scale = cfg.attention_multiplier
+    scale = cfg.latent_scale if latent else cfg.attention_multiplier
+    if latent:
+        # one pool of rows, the value a row's first ``latent_rank`` columns
+        # (``v`` rides along unread)
+        rank = cfg.latent_rank
+        gather = lambda q, k, _v, t, n, to=None: \
+            pa.latent_attention_reference(
+                q, k.astype(to or k.dtype), t, n, scale, rank)
+        kernel = lambda q, k, _v, t, n: pa._latent_pallas(q, k, t, n, scale,
+                                                          rank)
+        span = pa.latent_chunk_positions(q.shape, shape, dtype, rank,
+                                         tables.shape[1])
+    else:
+        gather = lambda q, k, v, t, n, to=None: pa.paged_attention_reference(
+            q, k.astype(to or k.dtype), v.astype(to or v.dtype), t, n, scale,
+            window)
+        kernel = lambda q, k, v, t, n: pa._paged_pallas(
+            q, k, v, t, n, scale, window=window)
+        span = pa.chunk_positions(q.shape, shape, dtype, tables.shape[1],
+                                  ring=tables.shape[1] if window else 0)
     paths = {
-        "kernel": lambda q, k, v, t, n: pa._paged_pallas(
-            q, k, v, t, n, scale, window=window),
-        "gather": lambda q, k, v, t, n: pa.paged_attention_reference(
-            q, k, v, t, n, scale, window),
+        "kernel": kernel,
+        "gather": gather,
         # float32 mathematics on the values as stored
-        "exact": lambda q, k, v, t, n: pa.paged_attention_reference(
-            q, k.astype(jnp.float32), v.astype(jnp.float32), t, n, scale,
-            window),
+        "exact": functools.partial(gather, to=jnp.float32),
         # every product of bfloat16 operands
-        "bf16_products": lambda q, k, v, t, n: pa.paged_attention_reference(
-            q, k.astype(jnp.bfloat16), v.astype(jnp.bfloat16), t, n, scale,
-            window),
+        "bf16_products": functools.partial(gather, to=jnp.bfloat16),
     }
     rest = (k_pool, v_pool, tables, lens)
     out, ms = {}, {}
     for name, fn in paths.items():
         out[name] = np.asarray(jax.jit(fn)(q, *rest), np.float64)
-        chained = jax.jit(lambda q, *rest, _fn=fn: jax.lax.fori_loop(
+        # the latent form's output is narrower than its query
+        again = lambda q, *rest, _fn=fn: jnp.pad(_fn(q, *rest), (
+            (0, 0), (0, 0), (0, q.shape[2] - out[name].shape[2])))
+        chained = jax.jit(lambda q, *rest, _fn=again: jax.lax.fori_loop(
             0, repeat, lambda _i, q: _fn(q, *rest), q))
         chained(q, *rest).block_until_ready()
         t0 = time.perf_counter()
@@ -205,13 +235,30 @@ def check_attention(cfg, kv, tables, lens, seed, repeat=24, window=None):
         return {"max_abs": float(np.abs(d).max()),
                 "rms": float(np.sqrt((d ** 2).mean()))}
 
+    finite = bool(np.isfinite(out["kernel"]).all())
+    kernel, gathered = err("kernel", "exact"), err("gather", "exact")
+    coarse = err("bf16_products", "exact")
+    if dtype == jnp.float32:
+        # the gather is the exact path here: the kernel orders under a
+        # path whose products are bfloat16
+        passed = finite and kernel["max_abs"] <= 1e-2 * coarse["max_abs"]
+    else:
+        # both round the query and the probabilities to the pool's dtype
+        passed = finite and kernel["rms"] <= 1.5 * gathered["rms"] \
+            and kernel["max_abs"] <= 3 * gathered["max_abs"]
+    # the chunks a call walks (a live lane's ring is one)
+    chunks = int((np.asarray(lens) > 0).sum()) if window \
+        else int((-(-np.asarray(lens) // span)).sum())
     return {"scale": float(np.sqrt((out["exact"] ** 2).mean())),
-            "finite": bool(np.isfinite(out["kernel"]).all()),
+            "finite": finite, "passed": bool(passed),
             "kernel_vs_gather": err("kernel", "gather"),
-            "kernel_vs_exact": err("kernel", "exact"),
-            "gather_vs_exact": err("gather", "exact"),
-            "bf16_products_vs_exact": err("bf16_products", "exact"),
+            "kernel_vs_exact": kernel,
+            "gather_vs_exact": gathered,
+            "bf16_products_vs_exact": coarse,
             "ms_per_call": ms,
+            # the kernel's chunk, how many a call walks, and what one costs
+            "chunk_positions": span, "chunks": chunks,
+            "kernel_us_per_chunk": ms["kernel"] * 1e3 / chunks,
             "live_blocks": int((-(-np.asarray(lens) // kv.block_size)).sum()),
             "blocks_read": pa.blocks_read(np.asarray(lens), kv.block_size,
                                           tables.shape[1], "pallas",
@@ -382,11 +429,37 @@ def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
         if ms:
             result["kda_state_update_kernel_ms_per_step"] = ms
             result["kda_state_update_kernel_bytes_per_s"] = moved / (ms / 1e3)
+    # each kind of attention kernel at the profiled steps' contexts: its
+    # chunk, the chunks a step walks over the kind's layers, its own time by
+    # the step and by the chunk, and the rate over the live blocks' bytes
+    # (a window layer's kernels carry the global layers' name in a trace:
+    # ``attention`` holds both where a model has both)
+    now = np.asarray(feed(WARM_STEPS + args.steps)[5])
+    row = kvc._PAYLOAD[kv.dtype][0].dtype.itemsize * args.block_size
+    result["attention_kernels"] = {}
+    for kind, span in dm.chunk_positions(cfg, kv, b).items():
+        if kind == "window":
+            continue
+        layers = cfg.latent_layers if kind == "latent" else cfg.attn_layers
+        ms = kernel_ms(paged_attention.LATENT_KERNEL_NAME if kind == "latent"
+                       else paged_attention.KERNEL_NAME)
+        chunks = int((-(-now // span)).sum()) * len(layers)
+        moved = len(layers) * row * paged_attention.blocks_read(
+            now, args.block_size, cfg.max_seq // args.block_size, "pallas") \
+            * (kv.latent_row if kind == "latent"
+               else 2 * kv.heads * kv.head_dim)
+        result["attention_kernels"][kind] = {
+            "chunk_positions": span, "chunks_per_step": chunks,
+            "kernel_ms_per_step": ms,
+            "us_per_chunk": ms * 1e3 / chunks if chunks else None,
+            "bytes_per_step": moved,
+            "bytes_per_s": moved / (ms / 1e3) if ms else None,
+            "with_window_kernels": bool(cfg.window_layers)}
     if cfg.latent_layers:
         # the rows the latent layers fetched at the profiled steps' contexts
         # (the values of a row, not the width its pool holds it in)
         read = paged_attention.blocks_read(
-            np.asarray(feed(WARM_STEPS + args.steps)[5]), args.block_size,
+            now, args.block_size,
             cfg.max_seq // args.block_size, result["attention"])
         moved = kimi_cost.latent_floor_bytes_per_step(config, read,
                                                       args.block_size)
@@ -419,6 +492,10 @@ def main(argv=None):
     ap.add_argument("--dtype", default=None,
                     help="KV residency (default: the model's own, else f32)")
     ap.add_argument("--layers", type=int, default=None)
+    ap.add_argument("--contexts", default=None, metavar="LO-HI",
+                    help="draw the lanes' contexts from this range (a "
+                    "cell's window) in place of a third to the whole of a "
+                    "lane's share of the pool")
     ap.add_argument("--steps", type=int, default=20)
     ap.add_argument("--seed", type=int, default=2147483659)
     ap.add_argument("--compile-only", action="store_true")
@@ -483,7 +560,13 @@ def main(argv=None):
     grow = WARM_STEPS + 2 * args.steps
     longest = min(cfg.max_seq - grow,
                   (args.blocks - 1) // b * args.block_size - grow)
-    lens = rng.integers(longest // 3, longest, b).astype(np.int32)
+    lo, hi = longest // 3, longest
+    if args.contexts:
+        lo, hi = map(int, args.contexts.split("-"))
+        if not 0 < lo < hi <= longest:
+            ap.error("--contexts: 0 < lo < hi <= %d at this pool, bucket "
+                     "and model" % longest)
+    lens = rng.integers(lo, hi, b).astype(np.int32)
     tables = np.full((b, maxb), -1, np.int32)
     free = iter(rng.permutation(np.arange(1, args.blocks)))
     for i in range(b):
@@ -511,7 +594,8 @@ def main(argv=None):
                   "a") as fp:
             fp.write(json.dumps(result) + "\n")
         print(json.dumps(result))
-        return 0
+        return 0 if result["passed"] \
+            and result.get("window", result)["passed"] else 1
 
     params = model.make_params(config, args.seed, device)
     # count which path each layer's lowering takes
