@@ -29,11 +29,13 @@ from what it can see, with no flag:
   for a float32 pool whose heads are whole 128-column slices and whose keys
   are whole sublane tiles: ``ssm_update``'s grid over (lane, column chunk)
   and its transfers (``ssm_update.in_turns``: whole slots where VMEM
-  allows, a batch at a time, reads and writes in turn), with this rule in
-  VMEM, a slot read once and written once.  What varies down a head's keys
-  (``alpha``, ``k``, ``q`` and ``beta k``) comes in turned, keys down the
-  sublanes and a head a lane (``[B, D, 4 H]``, one array), so a head's
-  column is one lane spread over the 128; ``v`` and ``o`` cross as rows.
+  allows, a batch at a time, reads and writes in turn, a batch updated
+  beside the write before it and the read after it, which is what hides
+  this rule's long update), with this rule in VMEM, a slot read once and
+  written once.  What varies down a head's keys (``alpha``, ``k``, ``q``
+  and ``beta k``) comes in turned, keys down the sublanes and a head a
+  lane (``[B, D, 4 H]``, one array), so a head's column is one lane spread
+  over the 128; ``v`` and ``o`` cross as rows.
 * **the gather** everywhere else: the lanes' slots are gathered, ``advance``
   moves them, a scatter writes them back.
 

@@ -30,12 +30,14 @@ flag, as ``paged_attention`` does:
   it that fits otherwise.  The kernel moves the units itself, by async
   copies steered by the scalar-prefetched slots, to and from the same place
   (the pool is aliased to the output), **a batch of units at a time and
-  reads and writes in turn**: while a batch is updated in VMEM the next one
-  is read, and only when that has arrived is the batch written back, with
-  nothing else in flight.  The chip's HBM takes reads at 745 GB/s and
-  writes at 653; with both in flight everything moves at the writes' rate
-  (a layer's call 204.8 us whatever the block or the depth of the queue),
-  in turns each moves at its own (195.8 us: PERF.md section 6, PR 44).  A
+  reads and writes in turn** (``in_turns``): a batch is updated in VMEM
+  first beside the write of the batch before it, then beside the read of
+  the batch after it, so a batch costs ``max(write, first units) +
+  max(read, rest)`` and never a read and a write are in flight together.
+  The chip's HBM takes reads at 745 GB/s and writes at 653; with both in
+  flight everything moves at the writes' rate (a layer's call 204.8 us
+  whatever the block or the depth of the queue), in turns each moves at
+  its own (195.8 us: PERF.md section 6, PRs 44 and 48).  A
   slot is read once and written once, ``b`` and ``c`` come in as they are
   (``[B, G, N]``, turned into columns in the kernel), and nothing of the
   pool's size is made: XLA's form of the same update splits the pool in
@@ -204,12 +206,18 @@ def in_turns(slots_ref, out_hbm, buf, rsem, wsem, lanes, chunks, update):
     ``update(lane, chunk, half, at)``, which moves unit ``lane * chunks +
     chunk`` (columns ``chunk * cols`` on of slot ``slots_ref[lane]``) where
     it lies in ``buf`` [2, K, N, cols]: batch ``unit // K`` in half ``batch
-    % 2``, at ``buf[half, at]``.  The first step reads batch 0; a batch's
-    first step starts the next batch's reads, its last waits for them, then
-    writes the batch back and waits for that; the last batch, with nothing
-    left to read, writes each unit as it is done.  Shared by every kernel
-    that updates slots in place (``ssm_state_update``, ``kda_state_update``):
-    the grid's dimensions are ``arbitrary``, the batches carry over."""
+    % 2``, at ``buf[half, at]``.  Reads and writes take turns, R(0), R(1),
+    W(0), R(2), W(1), ..., and the core waits out neither: batch ``k``'s
+    last step waits for R(k + 1), starts W(k) and returns; batch ``k + 1``'s
+    first ``K // 2`` units are updated beside W(k); there the kernel waits
+    for W(k) and starts R(k + 2) into the half W(k) has freed; the rest are
+    updated beside R(k + 2).  A batch costs ``max(write, first units) +
+    max(read, rest)``.  The first step starts R(0) and R(1) at once and
+    batch 0's units are updated as each arrives; the last batch, with
+    nothing left to read, writes each unit as it is done and waits for
+    every write at the end.  Shared by every kernel that updates slots in
+    place (``ssm_state_update``, ``kda_state_update``): the grid's
+    dimensions are ``arbitrary``, the batches carry over."""
     lane, chunk = pl.program_id(0), pl.program_id(1)
     k_n, cols = buf.shape[1], buf.shape[3]
     total = lanes * chunks
@@ -239,14 +247,28 @@ def in_turns(slots_ref, out_hbm, buf, rsem, wsem, lanes, chunks, update):
 
     start, wait = (lambda dma: dma.start()), (lambda dma: dma.wait())
 
+    def written_before():
+        """Wait for the write of the batch before this one, if there is
+        one."""
+        pl.when(batch > 0)(lambda: each(batch - 1, True, wait))
+
     @pl.when(unit == 0)
     def _first():
         each(0, False, start)
-        each(0, False, wait)
 
-    @pl.when((at == 0) & jnp.logical_not(last))
+    # the turn from the write before to the read ahead: batch 0 has no
+    # write before it
+    @pl.when((at == jnp.where(batch == 0, 0, k_n // 2))
+             & jnp.logical_not(last))
     def _ahead():
+        written_before()
         each(batch + 1, False, start)
+
+    # batch 0 has no batch before it to be updated beside its read: each
+    # of its units is updated as it arrives
+    @pl.when(batch == 0)
+    def _arrived():
+        copy(0, at, False).wait()
 
     update(lane, chunk, batch % 2, at)
 
@@ -258,10 +280,10 @@ def in_turns(slots_ref, out_hbm, buf, rsem, wsem, lanes, chunks, update):
     def _turn():
         each(batch + 1, False, wait)
         each(batch, True, start)
-        each(batch, True, wait)
 
     @pl.when(unit == total - 1)
     def _drain():
+        written_before()
         each(batch, True, wait)
 
 

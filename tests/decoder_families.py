@@ -72,6 +72,96 @@ def chunked(monkeypatch, n, columns):
     monkeypatch.setattr(ssm_update, "_UNIT_BUDGET", 4 * 4 * n * columns)
 
 
+# the TPU interpreter's two models of an async copy: done as it is started,
+# or only when it is waited for (memory no copy has filled reads NaN).  A
+# kernel right under both waits for what it reads and overwrites nothing a
+# copy in flight still has to read
+COPY_ORDERS = ("eager", "on_wait")
+
+
+def in_turns_and_plainly(monkeypatch, module, order, pool, args, live):
+    """``module``'s state-update kernel (``ssm_update`` or ``kda_update``)
+    with its copies run by ``order``, as ``in_turns`` orders them and then
+    one unit at a time, which it has to equal bit for bit on every slot but
+    the scratch one and on the ``live`` lanes' read-outs -> (pool,
+    read-out)."""
+    from jax.experimental.pallas import tpu as pltpu
+    from paddle_tpu.pallas_kernels import ssm_update
+
+    kernel = lambda: jax.jit(lambda *a: module._state_update_pallas(
+        *a, interpret=pltpu.InterpretParams(dma_execution_mode=order)))(
+            pool, *args)
+    got = kernel()
+    monkeypatch.setattr(ssm_update, "in_turns", one_at_a_time)
+    plain = kernel()
+    assert np.array_equal(np.asarray(plain[1])[live],
+                          np.asarray(got[1])[live])
+    assert np.array_equal(np.asarray(plain[0])[1:], np.asarray(got[0])[1:])
+    return got
+
+
+def one_at_a_time(slots_ref, out_hbm, buf, rsem, wsem, lanes, chunks,
+                  update):
+    """``ssm_update.in_turns``' contract in the plainest order: a unit is
+    read, updated and written back before the next is touched.  What any
+    order of the transfers must equal bit for bit."""
+    from jax.experimental import pallas as pl
+    from jax.experimental.pallas import tpu as pltpu
+
+    lane, chunk = pl.program_id(0), pl.program_id(1)
+    k_n, cols = buf.shape[1], buf.shape[3]
+    unit = lane * chunks + chunk
+    half, at = unit // k_n % 2, unit % k_n
+    where = out_hbm.at[slots_ref[lane], :,
+                       pl.ds(pl.multiple_of(chunk * cols, 128), cols)]
+    read = pltpu.make_async_copy(where, buf.at[half, at], rsem.at[half, at])
+    read.start()
+    read.wait()
+    update(lane, chunk, half, at)
+    write = pltpu.make_async_copy(buf.at[half, at], where, wsem.at[half, at])
+    write.start()
+    write.wait()
+
+
+# what the order of the transfers makes delicate, for both kernels that share
+# it: case -> (lanes, ssm_update.BATCH, chunks a slot, every lane idle)
+TURNS = {
+    "one_lane": (1, 4, 1, False),
+    "one_batch": (4, 4, 1, False),
+    "a_last_batch_of_one": (5, 4, 1, False),
+    "two_whole_batches": (8, 4, 1, False),
+    "three_batches_the_last_short": (9, 4, 1, False),
+    "batches_of_one": (5, 1, 1, False),
+    "batches_of_three": (7, 3, 1, False),
+    "a_slot_in_two_chunks": (5, 4, 2, False),
+    "a_slot_in_three_chunks_across_batches": (3, 4, 3, False),
+    "a_slot_in_two_chunks_batches_of_one": (3, 1, 2, False),
+    "every_lane_on_the_scratch_slot": (6, 4, 1, True),
+}
+
+
+def turns_case(monkeypatch, case, n):
+    """Set ``ssm_update`` up for ``TURNS[case]`` on slots of ``n`` rows ->
+    (slots in the pool, the lanes' slots, a slot's columns, the columns a
+    transfer moves, the units a batch holds)."""
+    from paddle_tpu.pallas_kernels import ssm_update
+
+    lanes, batch, pieces, idle = TURNS[case]
+    # 256 columns whole or in halves (two a batch: a whole slot is four
+    # halves' worth), 384 in thirds (four a batch)
+    inner, room = {1: (256, batch), 2: (256, 2), 3: (384, 4)}[pieces]
+    cols = inner // pieces
+    monkeypatch.setattr(ssm_update, "BATCH", batch)
+    if pieces > 1:
+        monkeypatch.setattr(ssm_update, "_UNIT_BUDGET",
+                            2 * room * 4 * n * cols)
+    slots_n = lanes + 3
+    rng = np.random.default_rng(lanes + batch)
+    slots = [0] * lanes if idle \
+        else [int(s) for s in 1 + rng.permutation(slots_n - 1)[:lanes]]
+    return slots_n, slots, inner, cols, min(batch, room, lanes * pieces)
+
+
 # -- the rows ------------------------------------------------------------------
 
 class Row(collections.namedtuple(

@@ -551,6 +551,42 @@ def test_state_update_kernel_moves_whole_slots(interpreted, monkeypatch,
                           np.asarray(got_pool)[1:])
 
 
+@pytest.mark.parametrize("order", fam.COPY_ORDERS)
+@pytest.mark.parametrize("case", sorted(fam.TURNS))
+def test_state_update_kernel_keeps_its_turns(monkeypatch, case, order):
+    """The order of the transfers (a batch updated beside the write of the
+    batch before it, then beside the read of the batch after it) where it
+    is delicate: one batch, whole batches, a short last one, batches of one
+    and of three, a slot in chunks, every lane on the scratch slot; under
+    the interpreter that runs a copy as it is started and under the one
+    that runs it only when it is waited for.  Against gather, update and
+    scatter, and bit for bit the plainest order, one unit at a time."""
+    n = 16
+    slots_n, slots, inner, cols, k_n = fam.turns_case(monkeypatch, case, n)
+    r = np.random.default_rng(len(case))
+    lanes = len(slots)
+    f = lambda *shape: jnp.asarray(r.standard_normal(shape), jnp.float32)
+    pool = f(slots_n, n, inner)
+    args = (jnp.asarray(slots, jnp.int32),
+            jnp.asarray([i % 3 == 1 for i in range(lanes)]),
+            jnp.asarray(r.uniform(0.2, 1.0, (lanes, inner)), jnp.float32),
+            f(lanes, inner), f(lanes, 1, n), f(lanes, 1, n))
+    assert su.transfer_columns(pool.shape) == cols
+    assert su.units_in_flight(pool.shape, cols,
+                              lanes * (inner // cols)) == k_n
+    # every idle lane writes the scratch slot, and nothing reads it
+    live = [i for i, s in enumerate(slots) if s]
+    got_pool, got_y = fam.in_turns_and_plainly(monkeypatch, su, order, pool,
+                                               args, live)
+    want_pool, want_y = jax.jit(su.state_update_reference)(pool, *args)
+    np.testing.assert_allclose(np.asarray(got_y)[live],
+                               np.asarray(want_y)[live], rtol=2e-6,
+                               atol=2e-6)
+    assert np.array_equal(np.asarray(got_pool)[1:],
+                          np.asarray(want_pool)[1:])
+    assert np.isfinite(np.asarray(got_pool)).all()
+
+
 def test_a_slot_too_large_for_the_limit_moves_in_chunks(interpreted,
                                                         monkeypatch,
                                                         telemetry_on):

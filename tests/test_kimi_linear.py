@@ -520,6 +520,37 @@ def test_kda_state_update_kernel_equals_advance(interpreted, monkeypatch,
                           np.asarray(pool)[untouched])
 
 
+@pytest.mark.parametrize("order", fam.COPY_ORDERS)
+@pytest.mark.parametrize("case", sorted(fam.TURNS))
+def test_kda_state_update_kernel_keeps_its_turns(monkeypatch, case, order):
+    """``ssm_update.in_turns``' order under the delta rule, whose update is
+    the long one (it runs beside the write of the batch before and beside
+    the read of the batch after): ``tests/decoder_families.py``'s cases, a
+    head a chunk where a slot moves in several, under both of the
+    interpreter's models of a copy.  Against gather, ``advance``, scatter,
+    and bit for bit the plainest order, one unit at a time."""
+    slots_n, slots, inner, cols, k_n = fam.turns_case(monkeypatch, case, 128)
+    heads, lanes = inner // 128, len(slots)
+    rng = np.random.default_rng(len(case))
+    pool, args = _kda_args(rng, slots_n, 128, heads, lanes)
+    args = (jnp.asarray(slots, jnp.int32),) + args[1:]
+    assert su.transfer_columns(pool.shape, heads) == cols
+    assert su.units_in_flight(pool.shape, cols,
+                              lanes * (inner // cols)) == k_n
+    # every idle lane writes the scratch slot, and nothing reads it
+    live = [i for i, s in enumerate(slots) if s]
+    got_pool, got_o = fam.in_turns_and_plainly(monkeypatch, ku, order, pool,
+                                               args, live)
+    want_pool, want_o = jax.jit(ku.state_update_reference)(pool, *args)
+    np.testing.assert_allclose(np.asarray(got_o)[live],
+                               np.asarray(want_o)[live], rtol=2e-6,
+                               atol=2e-6)
+    np.testing.assert_allclose(np.asarray(got_pool)[1:],
+                               np.asarray(want_pool)[1:], rtol=2e-6,
+                               atol=2e-6)
+    assert np.isfinite(np.asarray(got_pool)).all()
+
+
 def test_shapes_the_kda_kernel_cannot_tile_fall_back_counted(interpreted):
     checks = lambda shape, heads: dict(ku.kda_update_checks(
         shape, jnp.float32, 32, heads))

@@ -480,6 +480,28 @@ def test_state_update_kernel_with_groups_equals_advance_by_group(
             np.asarray(state), rtol=1e-5, atol=1e-5)
 
 
+@pytest.mark.parametrize("order", fam.COPY_ORDERS)
+@pytest.mark.parametrize("lanes,columns", [(5, None), (9, None), (3, 256),
+                                           (5, 512)])
+def test_state_update_kernel_with_groups_keeps_its_turns(monkeypatch, lanes,
+                                                         columns, order):
+    """B and C in 8 groups under ``in_turns``' order: a last batch of one,
+    three batches, a slot in four chunks of two groups and in two of four,
+    under both of the interpreter's models of a copy, bit for bit gather,
+    update, scatter and the plainest order, one unit at a time."""
+    if columns:
+        _chunked(monkeypatch, 16, columns)
+    rng = np.random.default_rng(lanes + (columns or 0))
+    pool, args = _state_args(rng, 12, 16, 1024, 8, lanes)
+    assert su.transfer_columns(pool.shape, 8) == (columns or 1024)
+    got_pool, got_y = fam.in_turns_and_plainly(monkeypatch, su, order, pool,
+                                               args, list(range(lanes)))
+    want_pool, want_y = jax.jit(su.state_update_reference)(pool, *args)
+    np.testing.assert_allclose(np.asarray(got_y), np.asarray(want_y),
+                               rtol=2e-6, atol=2e-6)
+    assert np.array_equal(np.asarray(got_pool), np.asarray(want_pool))
+
+
 @pytest.mark.parametrize("groups,n,inner,columns", [
     (8, 128, 4096, 2048), (2, 128, 4096, 512), (8, 16, 1024, 128),
     (4, 8, 512, 256)], ids=["nemotron_h", "two_groups", "small", "narrow"])
