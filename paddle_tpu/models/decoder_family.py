@@ -18,8 +18,10 @@ __all__ = ["DecoderFamily"]
 class DecoderFamily(collections.namedtuple(
         "DecoderFamily",
         ("kinds", "dtypes", "grouped_query", "routes", "expert_matrices",
-         "dense_lead", "holds_share", "own_stream_width"),
-        defaults=(("f32", "bf16"), False, None, None, False, False, False))):
+         "dense_lead", "holds_share", "own_stream_width", "grouped_router",
+         "rotated_latent"),
+        defaults=(("f32", "bf16"), False, None, None, False, False, False,
+                  False, False))):
     """``kinds``: the kinds of layer the block computes
     (``decode_model.LAYER_KINDS``: seven of them, of which a family names
     one to three).  ``dtypes``: the weight dtypes it is
@@ -33,6 +35,11 @@ class DecoderFamily(collections.namedtuple(
     MLP.  ``holds_share``: it may hold a share of each routed layer's experts
     (``cfg.experts_held`` from ``cfg.expert_first`` on).
     ``own_stream_width``: its stream (``cfg.hidden_size``) may be narrower
-    than its query heads together."""
+    than its query heads together.  ``grouped_router``: its router may
+    choose ``cfg.topk_group`` of ``cfg.n_group`` groups of experts before it
+    chooses experts (``exaone_moe.routed_part``).  ``rotated_latent``: its
+    latent layers rotate the row's shared key and the query's last
+    ``cfg.latent_rope`` values by position (``cfg.rope_scaling``: YaRN) and
+    may compress the query (``cfg.q_rank``)."""
 
     __slots__ = ()

@@ -35,7 +35,11 @@ position ``t``::
 
 and ``logits = rmsnorm(x, lnf_g) @ head`` (an untied head), each ``E_e`` and
 ``shared`` a SiLU-gated MLP.  The bias chooses and never weighs (the
-DeepSeek-V3 router, ``n_group`` 1); no capacity.
+DeepSeek-V3 router); no capacity.  ``cfg.n_group`` over 1 (K-EXAONE's is 1;
+``dots_vlm`` shares ``routed_part``), the experts lie in that many groups of
+consecutive ones, a group scores its two largest ``s + expert_bias`` summed,
+and ``S`` is chosen among the ``cfg.topk_group`` best groups' experts (the
+others' selection scores count as 0).
 
 **The share.**  One chip of a deployment that divides each layer's experts
 over several holds ``cfg.experts_held`` of the ``cfg.experts``, from
@@ -78,7 +82,7 @@ __all__ = ["token_logits", "param_shapes", "init_params", "routed_part",
 FAMILY = DecoderFamily(kinds=("attention", "window"), grouped_query=True,
                        routes="after_dense", expert_matrices=3,
                        dense_lead=True, holds_share=True,
-                       own_stream_width=True)
+                       own_stream_width=True, grouped_router=True)
 
 # the least the renormalised gates' denominator can be (the DeepSeek-V3
 # router adds it to the sum of the chosen scores)
@@ -123,11 +127,12 @@ def param_shapes(cfg):
     return shapes
 
 
-def init_params(cfg, seed=0, std=0.02, bias_std=BIAS_STD):
+def init_params(cfg, seed=0, std=0.02, bias_std=BIAS_STD, shapes=None):
     """name -> np array in the config's weight dtype: ``std``-normal
     weights, norms at 1, ``expert_bias`` normal(0, ``bias_std``) (at zero a
     block that ignores it is indistinguishable).  Host-side: tests and demo
-    bundles."""
+    bundles.  ``shapes`` is another family's ``param_shapes`` of the same
+    three kinds (``dots_vlm``)."""
     r = np.random.RandomState(seed)
     dtype = NP_DTYPES[cfg.dtype]
 
@@ -138,14 +143,42 @@ def init_params(cfg, seed=0, std=0.02, bias_std=BIAS_STD):
                                            else std)
 
     return {name: make(shape, kind).astype(np.float32).astype(dtype)
-            for name, (shape, kind) in sorted(param_shapes(cfg).items())}
+            for name, (shape, kind) in sorted(
+                (shapes or param_shapes)(cfg).items())}
 
 
-def _route(x, router, bias, k, scaling):
+def _route(x, router, bias, k, scaling, n_group=1, topk_group=1, kept=None):
     """LFM2-MoE's router (sigmoid scores, a bias that chooses and never
     weighs, gates renormalised over the chosen) with this family's
-    denominator."""
-    return _lfm2._route(x, router, bias, k, scaling, GATE_EPS)
+    denominator.  ``n_group`` over 1, the ``k`` are chosen among the
+    ``topk_group`` best groups' experts (``_in_kept_groups``), and a list
+    given as ``kept`` receives the groups each token kept, [B, n_group]
+    bool; one group is the plain choice, the same operations as before
+    there were groups."""
+    if n_group == 1:
+        return _lfm2._route(x, router, bias, k, scaling, GATE_EPS)
+
+    def limit(select):
+        select, groups = _in_kept_groups(select, n_group, topk_group)
+        if kept is not None:
+            kept.append(groups)
+        return select
+
+    return _lfm2._route(x, router, bias, k, scaling, GATE_EPS, limit)
+
+
+def _in_kept_groups(select, n_group, topk_group):
+    """The DeepSeek-V3 router's choice of groups (``noaux_tc``): the
+    selection scores [B, E] in ``n_group`` groups of consecutive experts, a
+    group's score its two largest summed, the ``topk_group`` best groups
+    kept -> (the scores with 0 in place of every other group's, the groups
+    kept [B, n_group] bool)."""
+    bb, e = select.shape
+    best2, _idx = jax.lax.top_k(select.reshape(bb, n_group, e // n_group), 2)
+    _top, idx = jax.lax.top_k(jnp.sum(best2, axis=-1), topk_group)
+    groups = jnp.any(jax.nn.one_hot(idx, n_group, dtype=bool), axis=1)
+    return jnp.where(jnp.repeat(groups, e // n_group, axis=1), select,
+                     0.0), groups
 
 
 def _gated_mlp(x, w1, w3, w2):
@@ -168,13 +201,15 @@ def _head(x, params, eps):
     return _mm(_rmsnorm(x, params["lnf_g"], eps), params["head"])
 
 
-def routed_part(cfg, p, x, live):
+def routed_part(cfg, p, x, live, kept=None):
     """-> (this share's routed sum [B, H] float32: the held experts' part
     for the tokens routed to them; ``chosen`` [B, E] bool over the whole
-    router).  ``p(name)`` is the layer's parameter."""
+    router).  ``p(name)`` is the layer's parameter; ``kept`` as
+    ``_route``'s."""
     with jax.named_scope("router"):
         gates, chosen = _route(x, p("router"), p("expert_bias"),
-                               cfg.experts_per_token, cfg.routed_scaling)
+                               cfg.experts_per_token, cfg.routed_scaling,
+                               cfg.n_group, cfg.topk_group, kept)
     with jax.named_scope("experts"):
         y = _moe.routed_experts(x, gates[:, cfg.held_experts], live,
                                 p("wgate"), p("wup"), p("wdown"))

@@ -12,13 +12,16 @@ token per lane through every layer.  The mixers are of two kinds, named by
   ``recur.window(l, qkv)`` pushes this token's input and returns the
   ``kda_conv`` newest, ``recur.delta(l, alpha, beta, k, v, q)`` moves the
   state one token and returns its read-out.
-* ``latent``: multi-head latent attention (MLA) with **no** position
-  encoding (``mla_use_nope``: the KDA layers carry position), served
-  *absorbed*: the cache keeps one row a token, ``[c | k_pe]``
-  (``latent_rank + latent_rope`` values), and the key's and the value's
-  up-projections are folded into the query and the output.  ``attend(l, q,
-  row, None)`` owns the row's write and the history read and returns the
-  probabilities' sum of the rows' latent parts.
+* ``latent``: multi-head latent attention (MLA), served *absorbed*: the
+  cache keeps one row a token, ``[c | k_pe]`` (``latent_rank +
+  latent_rope`` values), and the key's and the value's up-projections are
+  folded into the query and the output.  ``attend(l, q, row, None)`` owns
+  the row's write and the history read and returns the probabilities' sum
+  of the rows' latent parts.  This family applies **no** position encoding
+  there (``mla_use_nope``: the KDA layers carry position) and projects the
+  query in one matrix; ``latent_mixer`` takes a rotation of the row's and
+  the query's ``latent_rope`` values and a compressed query as options, for
+  the families that share it (``dots_vlm``).
 * layer 0 (``cfg.dense_layers``) ends in a SiLU-gated MLP of width
   ``cfg.dense_ffn``; every later one in experts of width ``cfg.ffn`` routed
   over ``cfg.experts``, ``cfg.experts_per_token`` a token, beside one shared
@@ -81,7 +84,7 @@ __all__ = ["token_logits", "param_shapes", "init_params", "routed_part",
 
 FAMILY = DecoderFamily(kinds=("kda", "latent"), routes="after_dense",
                        expert_matrices=3, dense_lead=True, holds_share=True,
-                       own_stream_width=True)
+                       own_stream_width=True, grouped_router=True)
 
 # what the L2 norm of a KDA head's q and k adds under the root
 L2_EPS = 1e-6
@@ -169,10 +172,12 @@ def _l2(x):
 
 
 # by name, so that a check can serve the block with one of them taken out
-# (benchmark/tests/chip_check_kimi.py): the KDA output's gate, and the norm
-# of the latent row's compressed part
+# (benchmark/tests/chip_check_kimi.py, chip_check_dots.py): the KDA output's
+# gate, and the norms of the latent row's and of a compressed query's
+# compressed part
 _out_gate = jax.nn.sigmoid
 _kv_norm = _rmsnorm
+_q_norm = _rmsnorm
 
 
 def kda_mixer(cfg, p, l, h, recur):
@@ -202,23 +207,43 @@ def kda_mixer(cfg, p, l, h, recur):
         return _mm(y.reshape(bb, inner), p("wo"))
 
 
-def latent_mixer(cfg, p, l, h, attend):
-    """The absorbed MLA mixer of layer ``l`` over h [B, H] float32."""
+def latent_mixer(cfg, p, l, h, attend, rotate=None):
+    """The absorbed MLA mixer of layer ``l`` over h [B, H] float32.  Two
+    options, for the families that share it (``dots_vlm``): ``cfg.q_rank``
+    given, the query goes through a low-rank pair with a norm between
+    (``wq_a``, ``q_norm``, ``wq_b``) and not through ``wq``; ``rotate``
+    given, ``rotate(x [B, n, latent_rope])`` turns the row's shared key
+    and each head's query's last ``latent_rope`` values by the lanes'
+    positions, before ``attend`` writes the row and before the absorb."""
     bb = h.shape[0]
     heads, d, rank = cfg.heads, cfg.head_dim, cfg.latent_rank
     dot = lambda eq, a, b: jnp.einsum(
         eq, a.astype(b.dtype), b, preferred_element_type=jnp.float32)
     # a head's columns of wkvb: the key's up-projection, then the value's
     up = p("wkvb").reshape(rank, heads, 2 * d)
+    # (``absorb`` is opened again around each rotation so that ``rope`` is
+    # its sibling, and without one the operations come in the order they
+    # had before there were options: the lowered step is the same text)
+    with jax.named_scope("q_compress" if cfg.q_rank else "absorb"):
+        q = _mm(_q_norm(_mm(h, p("wq_a")), p("q_norm"), cfg.norm_eps),
+                p("wq_b")) if cfg.q_rank else _mm(h, p("wq"))
+        q = q.reshape(bb, heads, d + cfg.latent_rope)
     with jax.named_scope("absorb"):
-        q = _mm(h, p("wq")).reshape(bb, heads, d + cfg.latent_rope)
         row = _mm(h, p("wkva"))
-        row = jnp.concatenate(
-            [_kv_norm(row[:, :rank], p("kv_norm"), cfg.norm_eps),
-             row[:, rank:]], axis=1)                       # [c | k_pe]
-        q = jnp.concatenate(
-            [dot("bhd,rhd->bhr", q[..., :d], up[..., :d]), q[..., d:]],
-            axis=2)                                        # [q_lat | q_pe]
+        c = _kv_norm(row[:, :rank], p("kv_norm"), cfg.norm_eps)
+        k_pe = row[:, rank:]
+    if rotate is not None:
+        with jax.named_scope("rope"):
+            k_pe = rotate(k_pe[:, None])[:, 0]
+    with jax.named_scope("absorb"):
+        row = jnp.concatenate([c, k_pe], axis=1)           # [c | k_pe]
+        q_lat = dot("bhd,rhd->bhr", q[..., :d], up[..., :d])
+        q_pe = q[..., d:]
+    if rotate is not None:
+        with jax.named_scope("rope"):
+            q_pe = rotate(q_pe)
+    with jax.named_scope("absorb"):
+        q = jnp.concatenate([q_lat, q_pe], axis=2)         # [q_lat | q_pe]
     o_lat = attend(l, q, row, None)                        # [B, heads, rank]
     with jax.named_scope("out"):
         o = dot("bhr,rhd->bhd", o_lat, up[..., d:])

@@ -138,15 +138,19 @@ def init_params(cfg, seed=0, std=0.02):
             for name, (shape, kind) in sorted(param_shapes(cfg).items())}
 
 
-def _route(h2, router, bias, k, scaling, eps=GATE_EPS):
+def _route(h2, router, bias, k, scaling, eps=GATE_EPS, limit=None):
     """-> (gates [B, E] float32: each of the token's k chosen experts'
     sigmoid score over the chosen scores' sum (and ``eps``), times
     ``scaling``, 0 elsewhere; chosen [B, E] bool).  ``bias`` moves the
-    choice alone."""
+    choice alone.  ``limit`` given, the k are chosen among what it leaves
+    of the selection scores [B, E] (``exaone_moe``'s choice of groups)."""
     f32 = jnp.float32
     score = jax.nn.sigmoid(jnp.dot(h2, router.astype(f32),
                                    precision=jax.lax.Precision.HIGHEST))
-    _top, idx = jax.lax.top_k(score + bias.astype(f32), k)
+    select = score + bias.astype(f32)
+    if limit is not None:
+        select = limit(select)
+    _top, idx = jax.lax.top_k(select, k)
     chosen = jnp.any(jax.nn.one_hot(idx, score.shape[-1], dtype=bool), axis=1)
     gates = jnp.where(chosen, score, 0.0)
     return gates / (jnp.sum(gates, axis=-1, keepdims=True) + eps) \

@@ -405,20 +405,24 @@ def _product(rows, x, dims):
 
 
 def _kernel(bt_ref, cl_ref, q_ref, *refs, heads, kv_heads, head_dim,
-            block_size, maxb, per, scale, window=None, value_cols=None):
+            block_size, maxb, per, scale, window=None, value_cols=None,
+            lane_grid=False):
     """``window`` None: a lane's chunks cover positions ``[0,
     context_len)``.  Given: the table is a ring of ``maxb == per`` slots, a
     live lane's one chunk is the ring as it lies, and ``ring_mask``'s rule
     says which of its rows are attended.  ``value_cols`` given (the latent
     form): there is one pool and one chunk buffer, and a row's value is its
-    first ``value_cols`` columns."""
+    first ``value_cols`` columns.  ``lane_grid`` (of the latent form): the
+    grid walks the lanes, ``q_ref`` and ``o_ref`` are one lane's, and the
+    count of chunks fetched so far passes from a grid step to the next in
+    SMEM, as the chunk buffers and the copies in flight do in VMEM."""
     if value_cols is None:
         k_hbm, v_hbm, o_ref, kbuf, vbuf, sem = refs
         pools = ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1))
     else:
-        k_hbm, o_ref, kbuf, sem = refs
+        k_hbm, o_ref, kbuf, sem = refs[:4]
         pools = ((k_hbm, kbuf, 0),)
-    lanes = q_ref.shape[0]
+    lanes = cl_ref.shape[0]
     hd = kv_heads * head_dim             # the pool's width
     group = heads // kv_heads            # query heads a KV head
     rows = -(-heads // 16) * 16          # whole sublane tiles in any dtype
@@ -497,8 +501,14 @@ def _kernel(bt_ref, cl_ref, q_ref, *refs, heads, kv_heads, head_dim,
         # rows no copy writes are still multiplied (by probabilities that
         # are 0): they have to be finite, so they start as zeros, and later
         # hold an earlier chunk's rows
-        for _pool, buf, _which in pools:
-            buf[...] = jnp.zeros(buf.shape, buf.dtype)
+        def clear():
+            for _pool, buf, _which in pools:
+                buf[...] = jnp.zeros(buf.shape, buf.dtype)
+
+        if lane_grid:
+            pl.when(pl.program_id(0) == 0)(clear)
+        else:
+            clear()
 
     # row r of the mask covers the columns of query head r's KV head
     # (r // group; its own where group is 1) in the folded width
@@ -523,7 +533,8 @@ def _kernel(bt_ref, cl_ref, q_ref, *refs, heads, kv_heads, head_dim,
         # one row broadcast over the heads, or (grouped) a row a head
         # with its query in every KV head's columns: as it came, or (the
         # compact layout) repeated here
-        qb = q_ref[b]
+        mine = 0 if lane_grid else b    # this lane's place in q_ref, o_ref
+        qb = q_ref[mine]
         if qb.shape[1] != hd:
             qb = jnp.concatenate([qb] * kv_heads, axis=1)
         qx = qb if value_cols is not None else qb * own  # [rows, hd]
@@ -575,10 +586,20 @@ def _kernel(bt_ref, cl_ref, q_ref, *refs, heads, kv_heads, head_dim,
                 # other piece is zeros
                 out = sum(out[:, i * head_dim:(i + 1) * head_dim]
                           for i in range(kv_heads))
-        o_ref[b] = out.astype(o_ref.dtype)
+        o_ref[mine] = out.astype(o_ref.dtype)
         return g + n
 
-    jax.lax.fori_loop(0, lanes, lane, jnp.int32(0))
+    if lane_grid:
+        fetched = refs[4]
+        b = pl.program_id(0)
+
+        @pl.when(b == 0)
+        def _none_yet():
+            fetched[0] = 0
+
+        fetched[0] = lane(b, fetched[0])
+    else:
+        jax.lax.fori_loop(0, lanes, lane, jnp.int32(0))
 
 
 def _paged_pallas(q, k_cache, v_cache, block_tables, context_lens,
@@ -679,24 +700,48 @@ def latent_attention_reference(q, pool, block_tables, context_lens, scale,
 
 def latent_vmem_bytes(q_shape, pool_shape, pool_dtype, rank):
     """What the latent kernel holds in VMEM: two chunk buffers of the span
-    ``_chunk_positions`` gives these shapes (the call's too), every lane's
-    query and its output in float32."""
+    ``_chunk_positions`` gives these shapes (the call's too), and the
+    queries and outputs of the lanes it holds at once, float32."""
     fetched = jnp.dtype(pool_dtype).itemsize * q_shape[2]     # the one row
-    held = _latent_held_bytes(q_shape, rank)
+    held = _latent_held_bytes(q_shape, pool_shape, pool_dtype, rank)
     return 2 * _chunk_positions(fetched, pool_shape[1], held) * fetched \
         + held
 
 
-def _latent_held_bytes(q_shape, rank):
-    lanes, heads, padded = q_shape
-    return lanes * 4 * (-(-heads // 16) * 16) * (padded + rank)
+def _latent_lane_bytes(q_shape, rank):
+    """One lane's query and output as the latent kernel holds them,
+    float32."""
+    _lanes, heads, padded = q_shape
+    return 4 * (-(-heads // 16) * 16) * (padded + rank)
+
+
+def _latent_lane_grid(q_shape, pool_shape, pool_dtype, rank):
+    """Does the grid walk the lanes, a lane's query and output in VMEM at a
+    time (the pipeline fetches the next lane's beside them)?  Where every
+    lane's together would not leave the chunk buffers their full span in
+    ``_VMEM_BUDGET``: 128 query heads of 640 + 512 values are 589,824 B a
+    lane, 18.9e6 B at 32 lanes.  Where they do (32 heads: 4.7e6 B), one
+    grid step holds them all and the lanes are a loop inside it."""
+    fetched = jnp.dtype(pool_dtype).itemsize * q_shape[2]
+    return q_shape[0] * _latent_lane_bytes(q_shape, rank) \
+        + 2 * _chunk_positions(fetched, pool_shape[1], 0) * fetched \
+        > _VMEM_BUDGET
+
+
+def _latent_held_bytes(q_shape, pool_shape, pool_dtype, rank):
+    """Queries and outputs the latent kernel holds, float32: every lane's,
+    or (``_latent_lane_grid``) one lane's twice."""
+    lanes = 2 if _latent_lane_grid(q_shape, pool_shape, pool_dtype, rank) \
+        else q_shape[0]
+    return lanes * _latent_lane_bytes(q_shape, rank)
 
 
 def latent_chunk_positions(q_shape, pool_shape, pool_dtype, rank, maxb):
     """``chunk_positions`` of the latent form."""
     fetched = jnp.dtype(pool_dtype).itemsize * q_shape[2]
     return pool_shape[1] * _chunk_blocks(
-        pool_shape[1], maxb, fetched, _latent_held_bytes(q_shape, rank))
+        pool_shape[1], maxb, fetched,
+        _latent_held_bytes(q_shape, pool_shape, pool_dtype, rank))
 
 
 def latent_attention_checks(q_shape, pool_shape, pool_dtype, rank):
@@ -750,19 +795,25 @@ def _latent_pallas(q, pool, block_tables, context_lens, scale, rank,
         interpret = adoption.interpret()
     rows = -(-h // 16) * 16
     qx = jnp.pad(q.astype(jnp.float32), ((0, 0), (0, rows - h), (0, 0)))
-    whole = lambda i, bt, cl: (0, 0, 0)
+    # every lane's query and output in one grid step, or a lane a step
+    lane_grid = _latent_lane_grid(q.shape, pool.shape, pool.dtype, rank)
+    held = 1 if lane_grid else bb
+    mine = (lambda i, bt, cl: (i, 0, 0)) if lane_grid \
+        else (lambda i, bt, cl: (0, 0, 0))
     out = pl.pallas_call(
         functools.partial(_kernel, heads=h, kv_heads=1, head_dim=width,
                           block_size=bs, maxb=maxb, per=per,
-                          scale=float(scale), value_cols=rank),
+                          scale=float(scale), value_cols=rank,
+                          lane_grid=lane_grid),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
-            grid=(1,),
-            in_specs=[pl.BlockSpec((bb, rows, width), whole),
+            grid=(bb // held,),
+            in_specs=[pl.BlockSpec((held, rows, width), mine),
                       pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec((bb, rows, rank), whole),
+            out_specs=pl.BlockSpec((held, rows, rank), mine),
             scratch_shapes=[pltpu.VMEM((2, per * bs, width), pool.dtype),
-                            pltpu.SemaphoreType.DMA((1, 2))],
+                            pltpu.SemaphoreType.DMA((1, 2))]
+            + ([pltpu.SMEM((1,), jnp.int32)] if lane_grid else []),
         ),
         out_shape=jax.ShapeDtypeStruct((bb, rows, rank), jnp.float32),
         name=LATENT_KERNEL_NAME,

@@ -79,6 +79,7 @@ another order, so there the bar is the same tokens and logits to 1e-5.
 
 import importlib
 import json
+import math
 import os
 import re
 
@@ -97,7 +98,7 @@ from . import kv_cache as _kv
 __all__ = ["DecoderConfig", "init_decoder_params", "save_decoder",
            "load_decoder", "is_decoder_dir", "has_draft", "load_draft",
            "truncate_decoder", "attention_path", "experts_path",
-           "state_update_path", "state_update_columns",
+           "state_update_path", "state_update_columns", "experts_chunk",
            "make_paged_step",
            "make_fed_step", "make_paged_step_multi",
            "make_draft_rollout", "make_unpaged_step", "unpaged_generate",
@@ -106,13 +107,24 @@ __all__ = ["DecoderConfig", "init_decoder_params", "save_decoder",
 
 # the families, a module each: ``models/<arch>.py``
 ARCHS = ("gpt2", "olmoe", "granite_hybrid", "lfm2_moe", "exaone_moe",
-         "nemotron_h", "kimi_linear")
+         "nemotron_h", "kimi_linear", "dots_vlm")
 LAYER_KINDS = ("attention", "mamba", "conv", "window", "experts", "kda",
                "latent")
 # recurrent kind -> the name its slot goes by in spans, gauges and counters
 # (``ssm_state_lanes``, ``conv_state_bytes{model}``, ...)
 STATE_NAMES = {"mamba": "ssm_state", "conv": "conv_state",
                "kda": "kda_state"}
+
+
+# what ``DecoderConfig.rope_scaling`` holds: the source's YaRN group
+YARN_KEYS = ("factor", "original_max_position_embeddings", "beta_fast",
+             "beta_slow", "mscale", "mscale_all_dim")
+
+
+def yarn_mscale(factor, mscale):
+    """YaRN's attention factor ``0.1 * mscale * ln(factor) + 1`` (1 for a
+    context not stretched)."""
+    return 0.1 * mscale * math.log(factor) + 1.0 if factor > 1 else 1.0
 
 
 def _model(arch):
@@ -177,7 +189,14 @@ class DecoderConfig:
     ``latent_rank`` the compressed K/V's width) with no position encoding;
     ``dense_layers`` leading gated MLPs and then ``exaone_moe``'s routed
     layer (the share it may hold included) under pre-norms, an untied head
-    and a stream of its own width.
+    and a stream of its own width.  ``dots_vlm`` is the block of
+    ``models/dots_vlm.py``: every layer ``latent``, the query compressed to
+    ``q_rank`` values and normed before its up-projection, the row's shared
+    key and the query's last ``latent_rope`` values a head rotated by
+    position with YaRN's frequencies (``rope_theta``, ``rope_scaling``: the
+    source's group, whose ``mscale_all_dim`` also scales the scores), and
+    ``kimi_linear``'s feed-forwards with a router that keeps ``topk_group``
+    of ``n_group`` groups of experts before it chooses experts.
 
     ``kv_heads`` None means ``heads`` (multi-head); ``layer_types`` None
     means ``layers`` attention layers.  ``dtype`` is the weights' (``f32``
@@ -194,7 +213,8 @@ class DecoderConfig:
                  "logits_scaling", "conv_taps", "dense_layers", "dense_ffn",
                  "routed_scaling", "window", "experts_held", "expert_first",
                  "shared_ffn", "hidden_size", "ssm_groups", "kda_heads",
-                 "kda_head_dim", "kda_conv", "latent_rank", "latent_rope")
+                 "kda_head_dim", "kda_conv", "latent_rank", "latent_rope",
+                 "q_rank", "n_group", "topk_group", "rope_scaling")
 
     def __init__(self, vocab, layers, heads, head_dim, ffn=None,
                  max_seq=64, arch="gpt2", dtype="f32", kv_dtype=None,
@@ -207,7 +227,8 @@ class DecoderConfig:
                  routed_scaling=1.0, window=0, experts_held=0,
                  expert_first=0, shared_ffn=0, hidden_size=None,
                  ssm_groups=1, kda_heads=0, kda_head_dim=0, kda_conv=0,
-                 latent_rank=0, latent_rope=0):
+                 latent_rank=0, latent_rope=0, q_rank=0, n_group=1,
+                 topk_group=1, rope_scaling=None):
         if arch not in ARCHS:
             raise ValueError("decoder arch must be %s: %r"
                              % ("|".join(ARCHS), arch))
@@ -264,6 +285,11 @@ class DecoderConfig:
         self.kda_conv = int(kda_conv)
         self.latent_rank = int(latent_rank)
         self.latent_rope = int(latent_rope)
+        self.q_rank = int(q_rank)
+        self.n_group = int(n_group)
+        self.topk_group = int(topk_group)
+        self.rope_scaling = None if rope_scaling is None \
+            else {k: float(rope_scaling[k]) for k in YARN_KEYS}
         if self.hidden_size not in (None, self.heads * self.head_dim) \
                 and not family.own_stream_width:
             raise ValueError("the %s block's stream is heads * head_dim "
@@ -301,6 +327,22 @@ class DecoderConfig:
                                       self.latent_rope) < 1:
             raise ValueError("latent layers want latent_rank and "
                              "latent_rope >= 1")
+        if (self.q_rank or self.rope_scaling) and not (
+                family.rotated_latent and self.latent_rope % 2 == 0):
+            raise ValueError(
+                "q_rank and rope_scaling are for the %s blocks' latent "
+                "layers, whose latent_rope values turn in pairs: %r, %r"
+                % (_declaring("rotated_latent"), q_rank, rope_scaling))
+        if not 1 <= self.topk_group <= self.n_group or (
+                self.n_group > 1 and (
+                    not family.grouped_router
+                    or self.experts % self.n_group
+                    or self.experts // self.n_group < 2)):
+            raise ValueError(
+                "the %s blocks' routers keep topk_group of n_group groups "
+                "of two experts or more: %r of %r over %d experts"
+                % (_declaring("grouped_router"), topk_group, n_group,
+                   self.experts))
         if not 0 <= self.dense_layers <= self.layers or (
                 self.dense_layers and (not family.dense_lead
                                        or self.dense_ffn < 1)):
@@ -423,10 +465,24 @@ class DecoderConfig:
     def latent_scale(self):
         """The scale of a latent layer's scores: ``attention_multiplier``,
         or one over the root of a key's width (its own ``head_dim`` values
-        and the shared ``latent_rope``)."""
+        and the shared ``latent_rope``), times ``m^2`` under
+        ``rope_scaling`` (``m = yarn_mscale(factor, mscale_all_dim)``)."""
         if self.attention_multiplier is not None:
             return self.attention_multiplier
-        return float(self.head_dim + self.latent_rope) ** -0.5
+        scale = float(self.head_dim + self.latent_rope) ** -0.5
+        if self.rope_scaling:
+            # YaRN stretches the rotation and sharpens the softmax for it
+            scale *= yarn_mscale(self.rope_scaling["factor"],
+                                 self.rope_scaling["mscale_all_dim"]) ** 2
+        return scale
+
+    @property
+    def rope_mscale(self):
+        """What YaRN scales a rotation's cos and sin by (1 without
+        ``rope_scaling``, and wherever ``mscale`` is ``mscale_all_dim``)."""
+        y = self.rope_scaling
+        return yarn_mscale(y["factor"], y["mscale"]) \
+            / yarn_mscale(y["factor"], y["mscale_all_dim"]) if y else 1.0
 
     def to_dict(self):
         d = {s: getattr(self, s) for s in self.__slots__}
@@ -674,6 +730,20 @@ def experts_path(cfg, params, lanes=1):
     w = params["l%d_%s" % (cfg.routed_layers[0],
                            "wgate" if matrices == 3 else "experts_up")]
     return _moe.experts_path(lanes, w.shape, w.dtype, matrices)
+
+
+def experts_chunk(cfg):
+    """Columns of an expert's ``wgate`` / ``wup`` (rows of its ``wdown``;
+    of a two-matrix expert's ``up`` and ``down``) one grid step of the
+    expert kernel reads at this model's widths, by the VMEM its blocks may
+    take (``moe_experts.f_chunk``, ``f_rows``); None for a model with no
+    routed layer, 0 where no chunk fits."""
+    if not cfg.routed_layers:
+        return None
+    dtype = _kv._PAYLOAD[cfg.dtype][0]
+    if _model(cfg.arch).FAMILY.expert_matrices == 3:
+        return _moe.f_chunk(cfg.hidden, cfg.ffn, jnp.dtype(dtype).itemsize)
+    return _moe.f_rows(cfg.hidden, cfg.ffn, dtype)
 
 
 def state_update_path(cfg, kv_config, lanes=1):

@@ -1412,6 +1412,9 @@ class DecodeEngine:
             m = self._models[model]
             if m.experts_path:
                 extra["experts"] = m.experts_path[bucket]
+                if m.experts_path[bucket] == "pallas":
+                    # columns of an expert a grid step of the kernel reads
+                    extra["experts_f_chunk"] = _dm.experts_chunk(m.cfg)
             if m.state_path:
                 extra["state_update"] = m.state_path[bucket]
                 if m.state_path[bucket] == "pallas":
@@ -2763,7 +2766,8 @@ class DecodeEngine:
                 # a routed step's counts ride with the tokens, started
                 # now, and only while the span is recorded
                 if extras and _tr.enabled():
-                    extras[0].copy_to_host_async()
+                    for counts in extras:
+                        counts.copy_to_host_async()
                 else:
                     extras = None
         except Exception as e:
@@ -2871,7 +2875,8 @@ class DecodeEngine:
         """A routed-expert step returns the tokens it sent to each expert
         in each layer that routes (int32 [routed layers, experts], live
         lanes only: a dense layer has no row, so the means are over the
-        layers that route).  The
+        layers that route; a router with groups returns next the lanes that
+        kept each group, [routed layers, n_group]).  The
         caller hands them over only while the step span is being recorded,
         so an untraced window pays for no transfer; a step with no experts
         has none.  Where the step's experts are the kernel's, an expert
@@ -2902,6 +2907,19 @@ class DecodeEngine:
             attrs["moe_local_assignments"] = attrs["moe_assignments"]
             attrs["moe_absent_assignments"] = round(
                 absent / float(len(routed)), 3)
+        if m.cfg.n_group > 1:
+            # a router that keeps groups before it chooses experts counts,
+            # next, the live lanes that kept each group: of the groups that
+            # hold a held expert, how many a token kept (mean over layers)
+            size = m.cfg.experts // m.cfg.n_group
+            held = m.cfg.held_experts
+            mine = np.asarray(extras[1])[
+                :, held.start // size:(held.stop - 1) // size + 1]
+            lanes = everywhere.sum() / float(m.cfg.experts_per_token
+                                             * len(routed))
+            attrs["moe_groups_kept"] = round(
+                float(mine.sum()) / (len(routed) * lanes), 3) if lanes \
+                else 0.0
         return attrs
 
     def _spec_step_locked(self, m):

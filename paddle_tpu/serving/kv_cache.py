@@ -835,13 +835,12 @@ def dequantize_kv(q, scale):
     return q.astype(jnp.float32) * scale[..., None]
 
 
-@functools.partial(jax.jit, static_argnums=(2,))
-def _get_block(carry, block, layers):
-    """One block of every K/V pool, stacked over the layers per group."""
-    carry = list(carry)
+@jax.jit
+def _get_block(groups, block):
+    """One block of every pool of ``groups`` (tuples of per-layer pools),
+    stacked over the layers per group."""
     return [jnp.stack([jax.lax.dynamic_index_in_dim(c, block, 0, False)
-                       for c in carry[i:i + layers]])
-            for i in range(0, len(carry), layers or 1)]
+                       for c in group]) for group in groups]
 
 
 @functools.partial(jax.jit, donate_argnums=(0,))
@@ -872,7 +871,8 @@ class PagedKVCache:
     ``allocator`` hands out blocks and ``slots`` (None without recurrent
     layers) state slots.  A slot is constant in the sequence's length and
     cannot be shared, trimmed or snapshotted: prefix reuse, speculative
-    roll-back and block export are for models whose every layer pages.
+    roll-back and block export are for models whose every layer pages
+    (attention layers' K and V, latent layers' rows, or both).
 
     The latent layers' pools (``[num_blocks, block_size, latent_row]``,
     one a layer, on ``allocator``'s blocks) follow the global K/V groups in
@@ -943,32 +943,40 @@ class PagedKVCache:
 
     # -- sealed-block export/import (the disaggregated transfer unit) --------
 
-    def _kv_carry(self):
-        """The K/V pools of the carry (a block's export and import frame
-        these only: a model with recurrent state is refused both by the
-        engine, before it gets here)."""
-        return self._carry[:self.config.kv_groups * self.config.layers]
-
-    def _wire_shape(self, group):
-        """One block of one carry group on the wire: every layer's rows
-        with the heads split, ``[layers, block_size, heads, head_dim]``
-        (scales ``[layers, block_size, heads]``)."""
+    def _wire_groups(self):
+        """What a block's export and import frame, a group of per-layer
+        pools after another as they lie at the head of the carry: K then V
+        (then their scales for int8) of the attention layers, then the
+        latent layers' pools, a row a token (a model with recurrent state or
+        rings is refused both by the engine, before it gets here) ->
+        [(the group's pools, one block of it on the wire)]: every layer's
+        rows with the heads split, ``[layers, block_size, heads,
+        head_dim]`` (scales ``[layers, block_size, heads]``; latent rows
+        as the pool holds them, ``[latent_layers, block_size,
+        latent_row]``)."""
         c = self.config
-        tail = (c.heads,) if group >= 2 else (c.heads, c.head_dim)
-        return (c.layers, c.block_size) + tail
+        groups, _state = c.groups(self._carry)
+        out = [(tuple(g), (c.layers, c.block_size, c.heads)
+                + ((c.head_dim,) if i < 2 else ()))
+               for i, g in enumerate(groups)] if c.layers else []
+        if c.latent_layers:
+            out.append((tuple(c.latent_pools(self._carry)),
+                        (c.latent_layers, c.block_size, c.latent_row)))
+        return out
 
     def export_block(self, block):
         """Host copies of one physical block, one array per carry group:
         ``[k, v]`` for f32 and bf16 residency, ``[k, v, k_scales, v_scales]`` for
-        int8, each stacked over the layers in its wire shape.  The wire
+        int8, each stacked over the layers in its wire shape, and then the
+        latent layers' rows.  The wire
         payload IS the residency payload — prefill's compiled step is
         deterministic, so an adopted block is bitwise-identical to the
         one the decode replica would have computed itself."""
         import numpy as np
 
-        return [np.asarray(a).reshape(self._wire_shape(g))
-                for g, a in enumerate(_get_block(
-                    self._kv_carry(), block, self.config.layers))]
+        groups = self._wire_groups()
+        return [np.asarray(a).reshape(shape) for a, (_g, shape) in zip(
+            _get_block(tuple(g for g, _shape in groups), block), groups)]
 
     def import_block(self, block, arrays):
         """Install transferred payloads into physical ``block``: one
@@ -979,26 +987,26 @@ class PagedKVCache:
         would corrupt every sequence that later matches the digest."""
         import numpy as np
 
-        kv_carry = self._kv_carry()
-        groups, _state = self.config.groups(self._carry)
+        groups = self._wire_groups()
         if len(arrays) != len(groups):
             raise ValueError(
                 "kv import arity mismatch: %d arrays for a %s-dtype "
                 "carry of %d" % (len(arrays), self.config.dtype,
                                  len(groups)))
         arrays = [np.asarray(a) for a in arrays]
-        for g, (group, a) in enumerate(zip(groups, arrays)):
-            want_shape, want = self._wire_shape(g), group[0].dtype
+        for (group, want_shape), a in zip(groups, arrays):
+            want = group[0].dtype
             if tuple(a.shape) != want_shape or a.dtype != want:
                 raise ValueError(
                     "kv import geometry mismatch: got %s%s, carry wants "
                     "%s%s (block_size/heads/head_dim/dtype must agree "
                     "across the disaggregated pair)"
                     % (a.dtype, tuple(a.shape), want, want_shape))
+        framed = sum(len(group) for group, _shape in groups)
         self._carry = _set_block(
-            kv_carry, block,
-            [a[l].reshape(c.shape[1:]) for group, a in zip(groups, arrays)
-             for l, c in enumerate(group)]) + self._carry[len(kv_carry):]
+            self._carry[:framed], block,
+            [a[l].reshape(c.shape[1:]) for (group, _s), a in zip(groups, arrays)
+             for l, c in enumerate(group)]) + self._carry[framed:]
 
     # -- the window layers' rings --------------------------------------------
 

@@ -22,8 +22,8 @@ import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu.core import telemetry as _tm
-from paddle_tpu.models import exaone_moe, granite_hybrid, kimi_linear, \
-    lfm2_moe, nemotron_h, olmoe
+from paddle_tpu.models import dots_vlm, exaone_moe, granite_hybrid, \
+    kimi_linear, lfm2_moe, nemotron_h, olmoe
 from paddle_tpu.serving import DecodeEngine
 from paddle_tpu.serving import decode_model as dm
 from paddle_tpu.serving import kv_cache as kvc
@@ -234,6 +234,17 @@ _KIMI = dm.DecoderConfig(
     kda_heads=4, kda_head_dim=8, kda_conv=4, latent_rank=24, latent_rope=8,
     dense_layers=1, dense_ffn=64, ffn=24, shared_ffn=24, experts=16,
     experts_per_token=3, routed_scaling=2.446)
+# the published YaRN group: the two correction dims of 8 rotated values lie
+# at 1.3 and 2.8, so pairs 0 and 1 keep their frequency, pair 3 has it
+# divided by 40 and pair 2 is half of each
+YARN = dict(factor=40, original_max_position_embeddings=4096, beta_fast=32,
+            beta_slow=1, mscale=1, mscale_all_dim=1)
+_DOTS = dm.DecoderConfig(
+    arch="dots_vlm", vocab=97, layers=4, heads=4, head_dim=16,
+    hidden_size=48, max_seq=64, layer_types=("latent",) * 4, latent_rank=24,
+    latent_rope=8, q_rank=20, rope_scaling=YARN, norm_eps=1e-6,
+    dense_layers=1, dense_ffn=64, ffn=24, shared_ffn=24, experts=16,
+    experts_per_token=3, n_group=4, topk_group=2, routed_scaling=2.5)
 _GRANITE_G4 = _GRANITE.replace(kv_heads=1)
 
 # Weights are normal(0, 0.3) (OLMoE's 0.05) and a router bias of 0.05: at
@@ -307,6 +318,19 @@ ROWS = {row.arch: row for row in (
                     latent_rope=8, hidden=48, ffn=24)),
         # one row of 640 bfloat16 a position: 1,280 B
         chunk=("kimi-linear-48b-a3b-serve.json", 12832, {"latent": 512})),
+    # every layer pages (one latent row a token): nothing is declined
+    Row("dots_vlm",
+        _both(_DOTS, dots_vlm.init_params, std=0.3, bias_std=0.05),
+        multi_atol=1e-5, batch_dependent_bf16=True,
+        entry=dict(attn_path="gather", experts_path={4: "einsum"},
+                   state_path={}, declines=None),
+        serve=("dots-vlm1-inst-serve.json",
+               dict(layer_types=_DOTS.layer_types, experts=16,
+                    experts_held=4, expert_first=4, experts_per_token=3,
+                    n_group=4, topk_group=2, q_rank=20, latent_rank=24,
+                    latent_rope=8, hidden=48, ffn=24)),
+        # one row of 640 bfloat16 a position: 1,280 B
+        chunk=("dots-vlm1-inst-serve.json", 12832, {"latent": 512})),
 )}
 assert tuple(ROWS) == dm.ARCHS
 
@@ -460,13 +484,13 @@ def teacher_forced(cfg, params, fed):
 
 
 def check_shares_add_up(cfg, params, block, ref, ref_config, held_names,
-                        atol):
-    """One routed layer of ``cfg`` (16 experts) cut in eight shares of 2:
-    each routes over all 16 and computes its own experts' part
-    (``block.routed_part``, equal to the reference given the same share, to
-    ``atol[0]``); their sum and the shared expert's output counted once equal
-    the uncut reference's layer (to ``atol[1]``), and neither a share alone
-    nor the shared expert counted eight times does."""
+                        atol, shares=8):
+    """One routed layer of ``cfg`` (16 experts) cut in ``shares`` shares of
+    as many experts each: each routes over all 16 and computes its own
+    experts' part (``block.routed_part``, equal to the reference given the
+    same share, to ``atol[0]``); their sum and the shared expert's output
+    counted once equal the uncut reference's layer (to ``atol[1]``), and
+    neither a share alone nor the shared expert counted a share does."""
     rng = np.random.RandomState(2)
     x = jnp.asarray(rng.randn(12, cfg.hidden), jnp.float32)
     live = jnp.ones(12, bool)
@@ -477,8 +501,9 @@ def check_shares_add_up(cfg, params, block, ref, ref_config, held_names,
         want = np.asarray(ref.routed_sum(ref_config(cfg), whole, x, gates)
                           + ref.shared_out(ref_config(cfg), whole, x))
         parts = []
-        for share in range(8):
-            mine = cfg.replace(experts_held=2, expert_first=2 * share)
+        for share in range(shares):
+            mine = cfg.replace(experts_held=16 // shares,
+                               expert_first=16 // shares * share)
             held = dict(whole, **{w: whole[w][mine.held_experts]
                                   for w in held_names})
             part, chosen = block.routed_part(mine, held.__getitem__, x, live)
@@ -491,7 +516,7 @@ def check_shares_add_up(cfg, params, block, ref, ref_config, held_names,
         shared = np.asarray(block.shared_part(whole.__getitem__, x))
     np.testing.assert_allclose(sum(parts) + shared, want, atol=atol[1])
     assert np.abs(parts[0] + shared - want).max() > 1e-2
-    assert np.abs(sum(parts) + 8 * shared - want).max() > 1e-2
+    assert np.abs(sum(parts) + shares * shared - want).max() > 1e-2
 
 
 # -- the engine -----------------------------------------------------------------

@@ -4,7 +4,9 @@ id): the paged step bitwise equal to the unpaged loop; the multi-token step
 equal to single steps, or refused over rings; the engine moving lanes up and
 reusing what a sequence held; preemption replaying into a fresh slot or an
 empty ring; what a family that keeps more than K and V declines (prefix
-reuse, speculation, export and adoption), each under its reason; the bundle
+reuse, speculation, export and adoption), each under its reason, and what a
+family whose every layer pages (K and V, or a latent pool's rows) is given
+of them; the bundle
 round trip; and ``tools/serve.py``'s demo bundle served at the defaults.  A
 family's own file (``tests/test_<family>.py``) holds what is its alone."""
 
@@ -22,6 +24,9 @@ from paddle_tpu.utils import fault_injection
 
 BS = fam.BS
 holding = fam.cases(lambda row: row.holds, ["f32"])
+# the families whose every layer pages (K and V, or latent rows): nothing
+# that starts or moves a sequence at a position is declined for them
+paging = fam.cases(lambda row: not row.holds)
 
 
 @pytest.mark.parametrize("row,key", fam.cases())
@@ -298,6 +303,172 @@ def test_export_adoption_and_history_are_refused_with_their_reason(
         finally:
             fault_injection.disarm()
             e.stop()
+
+
+@pytest.mark.parametrize("row,key", paging)
+def test_a_prefix_hit_and_a_chunked_prompt_give_the_prompts_own_tokens(
+        row, key, cache_dir, telemetry_on):
+    """Every layer pages, so there is an index and nothing is declined: a
+    repeated prompt starts past its cached full blocks (rows a step before
+    wrote, rotated by the same positions, for a latent pool as for K and V),
+    a prompt that shares its first blocks too, and prompts fed two tokens an
+    iteration through the multi-token step (chunked ingest) come out as
+    alone."""
+    cfg, params = row.configs[key]
+    e = fam.engine(cfg, params, 64)
+    try:
+        m = e._models["m"]
+        assert m.declines is None and m.prefix is not None
+        assert e.handoff_prefill_upto("m", 14) == 12
+        prompt = fam.PROMPT + [8, 9, 7]
+        first = e.generate("m", prompt, max_new_tokens=9,
+                           deadline_ms=60000.0)
+        again = e.generate("m", prompt, max_new_tokens=9,
+                           deadline_ms=60000.0)
+        assert first.status == again.status == "ok", first.error
+        assert (first.phases["cached_tokens"],
+                again.phases["cached_tokens"]) == (0, 12)
+        assert np.array_equal(first.outputs["tokens"],
+                              again.outputs["tokens"])
+        if not (key == "bf16" and row.batch_dependent_bf16):
+            assert np.array_equal(first.outputs["tokens"],
+                                  fam.alone(cfg, params, prompt, 9))
+        other = prompt[:8] + [2, 2, 3]
+        cold = fam.engine(cfg, params, 64, name="cold")
+        try:
+            want = cold.generate("cold", other, max_new_tokens=9,
+                                 deadline_ms=60000.0)
+            with fam.flags(decode_prefill_token_budget=2):
+                with cold._cond:        # admitted the same iteration
+                    asks = [cold.submit("cold", [t] * 13, max_new_tokens=5,
+                                        deadline_ms=60000.0)
+                            for t in (1, 2, 3)]
+                chunked = [a.wait(timeout=120.0) for a in asks]
+            singly = [cold.generate("cold", [t] * 13, max_new_tokens=5,
+                                    deadline_ms=60000.0) for t in (1, 2, 3)]
+        finally:
+            cold.stop()
+        hit0 = _tm.counter_total("prefix_cache_hit_tokens_total")
+        shared = e.generate("m", other, max_new_tokens=9,
+                            deadline_ms=60000.0)
+        assert shared.status == "ok" and shared.phases["cached_tokens"] == 8
+        assert _tm.counter_total("prefix_cache_hit_tokens_total") == hit0 + 8
+        assert np.array_equal(shared.outputs["tokens"],
+                              want.outputs["tokens"])
+        for a, b in zip(chunked, singly):
+            assert a is not None and a.status == b.status == "ok"
+            if not (key == "bf16" and row.batch_dependent_bf16):
+                assert np.array_equal(a.outputs["tokens"],
+                                      b.outputs["tokens"])
+        assert not fam.counters("prefix_cache_declined_total")
+    finally:
+        e.stop()
+
+
+@pytest.mark.parametrize("row,key", fam.cases(lambda row: not row.holds,
+                                              ["f32"]))
+def test_speculation_verifies_over_what_the_layers_page(row, key, cache_dir,
+                                                        telemetry_on):
+    """A one-layer truncation drafts two tokens a step, the multi-token step
+    verifies three positions over the target's pools (a latent pool's rows
+    beyond the accepted context are rewritten before anything attends them,
+    as K and V are), rejected proposals give their blocks back, and the
+    tokens are the greedy ones."""
+    cfg, params = row.configs[key]
+    e = fam.engine(cfg, params, 64, buckets="2",
+                   draft=dm.truncate_decoder(cfg, params, layers=1),
+                   speculative_k=2)
+    try:
+        assert e.spec("m")["speculative_k"] == 2
+        asks = [(fam.PROMPT, 14), ([4, 4, 2], 9)]
+        with e._cond:
+            waits = [e.submit("m", p, max_new_tokens=n, deadline_ms=60000.0)
+                     for p, n in asks]
+        for (p, n), w in zip(asks, waits):
+            r = w.wait(timeout=120.0)
+            assert r is not None and r.status == "ok", r and r.error
+            assert np.array_equal(r.outputs["tokens"],
+                                  fam.alone(cfg, params, p, n))
+        assert _tm.counter_total("spec_tokens_proposed_total") > 0
+        m = e._models["m"]
+        assert m.cache.allocator.in_use == 0 \
+            and m.draft_cache.allocator.in_use == 0
+    finally:
+        e.stop()
+
+
+@pytest.mark.parametrize("row,key", paging)
+def test_a_block_is_exported_and_adopted_with_every_layers_rows(
+        row, key, cache_dir, telemetry_on):
+    """What a block's frame holds is every paging layer's rows of it: K and
+    V stacked over the attention layers, a latent pool's rows stacked over
+    the latent layers (never read as K and V).  A float32 session is
+    exported with its history blocks and an exported block adopted under a
+    digest is matched by the next prompt that hashes to it; bfloat16 has no
+    frame (the codec names dtypes as numpy does) and is refused as such."""
+    from paddle_tpu.serving import kv_cache as kvc
+
+    cfg, params = row.configs[key]
+    with fam.flags(session_migration=True):
+        e = fam.engine(cfg, params, 32, buckets="2")
+        try:
+            fault_injection.arm("serving.decode_step:delay:1")
+            streamed = threading.Event()
+            done = e.submit("m", [1, 2, 3, 4, 5], max_new_tokens=40,
+                            deadline_ms=60000.0,
+                            on_token=lambda *a: streamed.set())
+            assert streamed.wait(60.0)
+            m = e._models["m"]
+            kv = m.kv_config
+            with e._cond:        # between steps: the carry is donated
+                e._drain_locked()
+                block = m.cache.export_block(1)
+            shapes = [a.shape for a in block]
+            assert shapes == [(kv.layers, BS, kv.heads, kv.head_dim)] * (
+                2 if kv.layers else 0) + [
+                (kv.latent_layers, BS, kv.latent_row)] * bool(
+                    kv.latent_layers)
+            # the first block of the sequence: its first four rows
+            assert all(np.abs(a.astype(np.float32)).sum() > 0 for a in block)
+            if key == "bf16":
+                with pytest.raises(ValueError, match="dtype"):
+                    e.export_session(done.req_id)
+                assert fam.counters("kv_migrate_refused_total") == {
+                    "kv_migrate_refused_total{reason=dtype}": 1}
+            else:
+                manifest, payloads = e.export_session(done.req_id)
+                assert manifest["pos"] >= 5 and payloads
+                assert [a.shape for a in payloads[0][2]] == shapes
+                e.abort_migration(done.req_id)
+        finally:
+            fault_injection.disarm()
+            e.stop()
+        if key == "bf16":
+            return
+        # another engine adopts the block under the digest of its tokens
+        other = fam.engine(cfg, params, 32, buckets="2", name="o")
+        try:
+            mo = other._models["o"]
+            digest = mo.prefix.chain([1, 2, 3, 4])[0]
+            assert other.adopt_kv_block("o", digest, block) == "adopted"
+            assert other.adopt_kv_block("o", digest, block) == "cached"
+            with other._cond:
+                at = mo.prefix.lookup(digest)
+                got = mo.cache.export_block(at)
+            assert all(np.array_equal(a, b) for a, b in zip(got, block))
+            bad = [a[:, :2] for a in block]
+            assert other.adopt_kv_block("o", "11" * 32, bad).startswith(
+                "rejected:kv import geometry mismatch")
+            r = other.generate("o", [1, 2, 3, 4, 5], max_new_tokens=12,
+                               deadline_ms=60000.0)
+            assert r.status == "ok" and r.phases["cached_tokens"] == 4
+            assert np.array_equal(
+                r.outputs["tokens"],
+                fam.alone(cfg, params, [1, 2, 3, 4, 5], 12))
+            assert kvc.block_bytes(mo.kv_config) == sum(
+                a.nbytes for a in block)
+        finally:
+            other.stop()
 
 
 @pytest.mark.parametrize("row,key", fam.cases())

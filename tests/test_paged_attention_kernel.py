@@ -225,7 +225,7 @@ def test_shape_rule(interpreted, monkeypatch, case):
 
 
 CELLS = {
-    # the seven serving cells' attention at 32 lanes and blocks of 16:
+    # the eight serving cells' attention at 32 lanes and blocks of 16:
     # (q shape, pool shape, dtype, table slots, ring or latent rank,
     #  positions a chunk, bytes of VMEM)
     "gpt2": ((32, 16, 64), (1024, 16, 1024), "float32", 64, 0,
@@ -242,6 +242,10 @@ CELLS = {
                    512, 4 * 512 * 2 * 256 + 2 * 32 * 4 * 32 * 128),
     "kimi_latent": ((32, 32, 640), (12832, 16, 640), "bfloat16", 512, 512,
                     512, 2 * 512 * 2 * 640 + 32 * 4 * 32 * (640 + 512)),
+    # 128 heads: 18.9e6 B of queries and outputs at 32 lanes, so the grid
+    # walks the lanes and holds one lane's (and the next's) at a time
+    "dots_latent": ((32, 128, 640), (12832, 16, 640), "bfloat16", 512, 512,
+                    512, 2 * 512 * 2 * 640 + 2 * 4 * 128 * (640 + 512)),
     # a table shorter than the rule's chunk is one chunk
     "short_table": ((32, 32, 128), (2048, 16, 256), "bfloat16", 20, 0,
                     320, 4 * 512 * 2 * 256 + 2 * 32 * 4 * 32 * 128),
@@ -257,9 +261,11 @@ def test_a_chunk_spans_what_its_bytes_are_worth(cell):
     of the span the call uses."""
     q, pool, dtype, maxb, extra, span, vmem = CELLS[cell]
     assert pa._CHUNK_BYTES == 512 << 10
-    if cell == "kimi_latent":
+    if cell.endswith("_latent"):
         fetched = jnp.dtype(dtype).itemsize * pool[2]
-        held = pa._latent_held_bytes(q, extra)
+        held = pa._latent_held_bytes(q, pool, dtype, extra)
+        assert pa._latent_lane_grid(q, pool, dtype, extra) \
+            == (cell == "dots_latent")
         assert pa.latent_chunk_positions(q, pool, dtype, extra, maxb) == span
         assert pa.latent_vmem_bytes(q, pool, dtype, extra) == vmem
     else:
@@ -267,7 +273,7 @@ def test_a_chunk_spans_what_its_bytes_are_worth(cell):
         held = pa._held_bytes(q, pool)
         assert pa.chunk_positions(q, pool, dtype, maxb, ring=extra) == span
         assert pa.vmem_bytes(q, pool, dtype, ring=extra) == vmem
-    if not extra or cell == "kimi_latent":
+    if not extra or cell.endswith("_latent"):
         assert pa._chunk_blocks(16, maxb, fetched, held) == span // 16
     assert vmem <= pa._VMEM_BUDGET
 

@@ -631,6 +631,69 @@ def test_kimi_linear_step_compiles_its_three_kernels_at_published_shapes(
     assert not found, found
 
 
+def test_dots_vlm_step_compiles_its_two_kernels_at_published_shapes(
+        one_chip, as_on_tpu):
+    """dots.vlm1's published widths, its dense lead and two routed layers,
+    bucket 32, the cell's pools (12,832 bf16 latent blocks whose rows of 576
+    values lie 640 wide, nothing else): Mosaic accepts, inside the whole
+    step as the engine compiles it (``make_packed_step``), the latent form
+    of the paged-attention kernel at 128 query rows of 640 (the grid walking
+    the lanes, a lane's query and output in VMEM at a time) and the
+    routed-expert kernel over 16 held experts of 7168 x 2048 in chunks of
+    256 columns; every pool is aliased whole, no pool and no expert tensor
+    is copied, turned or converted."""
+    from benchmark.models import dots_vlm_decoder
+    from paddle_tpu.pallas_kernels import moe_experts as moe
+
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "dots-vlm1-inst-serve.json")) as fp:
+        config = dict(json.load(fp), num_hidden_layers=3)
+    cfg = dots_vlm_decoder.decoder_config(config)
+    assert (cfg.hidden, cfg.heads, cfg.head_dim, cfg.latent_rank,
+            cfg.latent_rope, cfg.q_rank, cfg.experts, cfg.experts_held,
+            cfg.n_group, cfg.topk_group, cfg.ffn, cfg.dense_ffn,
+            cfg.layer_types, cfg.routed_layers) == (
+        7168, 128, 128, 512, 64, 1536, 256, 16, 8, 4, 2048, 18432,
+        ("latent",) * 3, (1, 2))
+    lanes, block_size, blocks = 32, 16, 12832
+    kv = dm.cache_config(cfg, block_size, blocks)
+    assert (kv.layers, kv.latent_layers, kv.latent_row, kv.state_layers) \
+        == (0, 3, 640, 0)
+    assert dm.attention_path(cfg, kv, lanes, "latent") == "pallas"
+    assert dm.chunk_positions(cfg, kv, lanes) == {"latent": 512}
+    assert moe.experts_path(lanes, (16, 7168, 2048), jnp.bfloat16) \
+        == "pallas"
+
+    on_chip = functools.partial(_placed, one_chip)
+    carry = on_chip(jax.eval_shape(lambda: PagedKVCache(kv).carry()))
+    params = on_chip({
+        name: jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+        for name, (shape, _kind)
+        in dots_vlm_decoder.param_shapes(config).items()})
+    feeds = on_chip(_packed_feeds(kv, lanes, cfg.max_seq // block_size))
+    compiled = jax.jit(dm.make_packed_step(cfg, kv, lanes),
+                       donate_argnums=(0,)
+                       ).lower(carry, params, *feeds).compile()
+
+    text = compiled.as_text()
+    assert _kernel_calls(text) == 5             # 3 latent, 2 experts
+    assert len(re.findall(r"%latent_attention\S* = ", text)) == 3
+    assert _expert_kernels(text) == 2
+    assert not _expert_passes(text, 16, 7168, 2048)
+    pool_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in carry)
+    assert pool_bytes == 3 * 12832 * 16 * 640 * 2
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= pool_bytes
+    # beside the arguments: the lanes' queries and outputs of 128 heads
+    # (10.5e6 and 8.4e6 B a layer, float32) and activations; under a
+    # fifth of one pool
+    assert memory.temp_size_in_bytes < 12832 * 16 * 640 * 2 / 5
+    big = re.compile(r" = bf16\[12832,16,640\]\S* (copy|transpose|convert)\(")
+    found = [line.strip()[:160] for line in text.splitlines()
+             if big.search(line)]
+    assert not found, found
+
+
 def test_data_parallel_bert_layer_runs_fused_ln_per_shard_on_v5e_2x2(
         topo, as_on_tpu):
     """BERT-base's widths (hidden 768, bf16 AMP, dropout 0.1), one layer,

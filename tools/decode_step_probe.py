@@ -13,7 +13,8 @@ writes under ``chiprun_out/``, one JSON object:
 * ``scope_ms_per_step``: device time per step by ``jax.named_scope``
   (``layer<i>`` folded to ``layerN``), from a profile of ``--steps`` steps
   reduced by ``benchmark/trace_reduce.py`` and keyed through the HLO's
-  ``op_name`` metadata; ``top_ops`` names the dearest single instructions.
+  ``op_name`` metadata; ``top_ops`` names the dearest single instructions
+  and ``unscoped_ops`` the dearest of those under no scope (``other``).
 
     chiprun -- python tools/decode_step_probe.py --blocks 1024 --bucket 32
     chiprun -- python tools/decode_step_probe.py --config olmoe-1b-7b-serve \
@@ -62,7 +63,15 @@ kda latent kda``): the scopes ``layerN/kda/conv``, ``kda/state`` and
 ``moe`` scopes elsewhere; the bytes are ``benchmark/kimi_cost.py``'s, and the
 result gives the ``kda_state_update`` and ``latent_attention`` kernels' own
 ms a step and bytes/s (what ``kimi_kda_state_roofline_share.serve`` and
-``kimi_latent_attention_roofline_share.serve`` divide).
+``kimi_latent_attention_roofline_share.serve`` divide).  For ``--config
+dots-vlm1-inst-serve --blocks 12832`` the step is the dense lead and five
+routed layers, every one latent: the scopes ``layerN/latent/q_compress``,
+``latent/absorb``, ``latent/rope``, ``latent/kv_write``, ``latent/kv_read``
+and ``latent/out``, ``mlp``, ``moe/router``, ``moe/experts``,
+``moe/shared`` and ``lm_head``; the bytes and the attention's operations are
+``benchmark/dots_cost.py``'s.  ``layerN/staged`` (any configuration) is what
+XLA puts in itself around a layer's weights and names nothing: a weight
+relaid for the product that reads it, or fetched ahead of it.
 
     chiprun -- python tools/decode_step_probe.py --config \
         lfm2-24b-a2b-serve --blocks 2048 --experts dense,kernel
@@ -113,15 +122,33 @@ def hlo_index(text):
     """instruction name -> (opcode, shape, op_name scope) of a compiled
     module's entry computation and fusions."""
     out = {}
-    for line in text.splitlines():
+    lines = text.splitlines()
+    named = re.compile(r"\s*(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) "
+                       r"([\w\-]+)\(")
+    starts = {m.group(1): line for line in lines
+              for m in [named.match(line)]
+              if m and m.group(3) in ("copy-start", "slice-start")}
+    for line in lines:
         # a result is one shape, or (a kernel's) a tuple of them
-        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) "
-                     r"([\w\-]+)\(", line)
+        m = named.match(line)
         if not m:
             continue
         scope = re.search(r'op_name="([^"]*)"', line)
-        out[m.group(1)] = (m.group(3), m.group(2),
-                           scope.group(1) if scope else "")
+        scope = scope.group(1) if scope else ""
+        # what XLA puts in itself around a weight names no scope: a copy
+        # that relays it for the product that reads it carries the
+        # parameter's own name (``params['l3_wkvb']``), a fetch ahead of its
+        # product (copy-start or slice-start, and the -done that names it)
+        # carries none.  Both go to the weight's layer, as ``staged``
+        param = re.match(r"params\[\\?'(?:l(\d+)_)?", scope)
+        if not scope:
+            done = re.search(r"%((?:copy|slice)-start[\w.\-]*)\)", line)
+            param = re.search(r"%params__(?:l(\d+)_)?", starts.get(
+                done.group(1), "") if done else line)
+        if param:
+            scope = ("layer%s/" % param.group(1) if param.group(1)
+                     else "") + "staged"
+        out[m.group(1)] = (m.group(3), m.group(2), scope)
     return out
 
 
@@ -133,7 +160,8 @@ def scope_of(op_name):
             if p in ("layerN", "attn", "mlp", "moe", "router", "experts",
                      "lm_head", "kv_write", "kv_read", "kv_gather",
                      "ssm", "in_proj", "conv", "state_update", "out_proj",
-                     "window", "kda", "state", "out", "latent", "absorb")]
+                     "window", "kda", "state", "out", "latent", "absorb",
+                     "shared", "q_compress", "rope", "staged")]
     return "/".join(keep) or "other"
 
 
@@ -273,8 +301,8 @@ def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
     import jax
     import numpy as np
 
-    from benchmark import exaone_cost, kimi_cost, lfm2_cost, moe_cost, \
-        nemotron_cost, ssm_cost, trace_reduce
+    from benchmark import dots_cost, exaone_cost, kimi_cost, lfm2_cost, \
+        moe_cost, nemotron_cost, ssm_cost, trace_reduce
     from paddle_tpu.core import telemetry
     from paddle_tpu.core.executor import CarriedStepFn
     from paddle_tpu.pallas_kernels import kda_update, paged_attention, \
@@ -357,12 +385,20 @@ def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
     prof = trace_reduce.reduce_dir(trace_dir, top=12)
     scopes = {}
     short = lambda name: trace_reduce._short(name).lstrip("%_")
+    unscoped = {}
     for name, secs in prof["op_seconds"].items():
         key = scope_of(index.get(short(name), ("", "", ""))[2])
         scopes[key] = scopes.get(key, 0.0) + secs * 1e3 / args.steps
+        if key == "other":
+            unscoped[name] = secs * 1e3 / args.steps
     result["busy_ms_per_step"] = prof["busy_s"] * 1e3 / args.steps
     result["scope_ms_per_step"] = dict(
         sorted(scopes.items(), key=lambda kv: -kv[1]))
+    # what lies under no scope, dearest first: [name, ms a step, opcode and
+    # shape], so that a share of the step nobody named can be found
+    result["unscoped_ops"] = [
+        [n, round(ms, 4), " ".join(index.get(short(n), ("", "", ""))[:2])[:96]]
+        for n, ms in sorted(unscoped.items(), key=lambda kv: -kv[1])[:12]]
     result["top_ops"] = [
         [n, round(s * 1e3 / args.steps, 4),
          "/".join(index.get(short(n), ("", "", ""))[::2])[:120]]
@@ -384,6 +420,9 @@ def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
         elif "linear_attn_config" in config:
             # three-matrix experts behind a dense lead, a share
             bytes_of = kimi_cost.experts_hit_bytes_per_step
+        elif "n_group" in config and "kv_lora_rank" in config:
+            # the same behind latent attention in every layer, a share
+            bytes_of = dots_cost.experts_hit_bytes_per_step
         elif "mlp_layer_types" in config:
             # a share: the held experts of each sparse layer
             bytes_of = lambda _c, n: exaone_cost.sparse_layers(config) * n \
@@ -461,14 +500,21 @@ def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
         read = paged_attention.blocks_read(
             now, args.block_size,
             cfg.max_seq // args.block_size, result["attention"])
-        moved = kimi_cost.latent_floor_bytes_per_step(config, read,
-                                                      args.block_size)
+        cost = kimi_cost if "linear_attn_config" in config else dots_cost
+        moved = cost.latent_floor_bytes_per_step(config, read,
+                                                 args.block_size)
         result["latent_blocks_read"] = read
         result["latent_bytes_per_step"] = moved
         ms = kernel_ms(paged_attention.LATENT_KERNEL_NAME)
         if ms:
             result["latent_attention_kernel_ms_per_step"] = ms
             result["latent_attention_kernel_bytes_per_s"] = moved / (ms / 1e3)
+            if cost is dots_cost:
+                # 128 heads over a row put the kernel at the chip's ridge:
+                # its operations beside its bytes
+                result["latent_attention_kernel_flops_per_s"] = \
+                    dots_cost.latent_flops_per_step(
+                        config, read, args.block_size) / (ms / 1e3)
     stats = device.memory_stats() or {}
     result["peak_bytes_in_use"] = stats.get("peak_bytes_in_use")
     result["peak_bytes_reserved"] = stats.get("peak_bytes_reserved")
