@@ -75,11 +75,33 @@ attention: a layer keeps ONE row a token, ``W`` wide (a compressed K/V of
 block_size, W]``; every query head (``[B, H, W]``, the key's up-projection
 already folded into it) scores all ``W`` columns of that one cached head,
 and the value is the row's first ``rank`` columns, so a block is fetched
-once and serves as K and as V (-> ``[B, H, rank]``).  The same kernel with
-one pool and one chunk buffer, under its own name in a trace
-(``LATENT_KERNEL_NAME``).  ``W`` and ``rank`` are whole 128-lane tiles: the
-cache rounds a row up to that and keeps the rest zeros (576 values lie in
-rows of 640), since a kernel fetches whole tiles of the pool's layout.
+once and serves as K and as V (-> ``[B, H, rank]``).  ``W`` and ``rank`` are
+whole 128-lane tiles: the cache rounds a row up to that and keeps the rest
+zeros (576 values lie in rows of 640), since a kernel fetches whole tiles of
+the pool's layout.  The K/V kernel with one pool and one chunk buffer,
+under its own name in a trace (``LATENT_KERNEL_NAME``), and with one of two
+chunk **bodies**, chosen by the shapes (``_latent_straight_line``: the
+operations a byte of a chunk against one constant).  The K/V body guards
+each step of 8 copies by a predicate and loops over the last few, so that a
+lane's last chunk fetches its live blocks and no others: right where the
+transfers bind, as at 32 query heads (58 operations a byte: a chunk's
+copies take 0.9 us, its arithmetic 0.57, and in the step the kernel shares
+the HBM with the weights XLA fetches ahead beside it).  At 128 heads (230
+a byte) the arithmetic outlasts the transfers, 1.09 us to 0.87, and the
+copies' issue and waits, scalar work in the kernel's one instruction
+stream, stood beside both (1.75 us a chunk).  There (``_latent_kernel``)
+every chunk is ONE basic block that the scheduler fills with scalar, vector
+and matrix work side by side, which takes two things.  No predicate: every
+chunk issues all its copies, a slot past the lane's last block fetching
+that block again (masked positions; no block the table does not name is
+read, and of a lane's last chunk up to one chunk less a block is fetched
+twice), and a chunk that nothing follows is fetched again for nobody and
+waited out later.  And buffer indices that are compile-time constants: a
+lane walks its chunks in pairs, first buffer then second, in one of two
+copies of the walk chosen by the parity of the chunks fetched before it;
+with a traced index the compiler must assume that a copy into one buffer
+and a load from the other touch the same memory, and keeps them in program
+order (1.5 us a chunk; 1.27 with constants: PERF.md section 6, PR 50).
 
 ``masked_attention`` is also the core of the UNPAGED reference loop in
 decode_model.py: sharing it is what makes paged-vs-unpaged decode
@@ -91,6 +113,7 @@ import math
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
@@ -99,6 +122,7 @@ from . import adoption
 
 __all__ = ["paged_attention", "paged_attention_reference",
            "paged_attention_checks", "attention_path", "blocks_read",
+           "chunks_read",
            "masked_attention", "gather_blocks", "ring_mask", "KERNEL_NAME",
            "latent_attention", "latent_attention_reference",
            "latent_attention_checks", "latent_path", "masked_latent",
@@ -324,14 +348,33 @@ def blocks_read(context_lens, block_size, maxb, path, ring=False):
     """Blocks one layer's attention fetches for these lanes: every slot of
     the table on the gather path; on the kernel's, the blocks each lane
     holds (``ceil(context_len / block_size)``: a chunk fetches its live
-    blocks and no others), or for a window layer (``ring``: the table is
-    its ring of ``maxb`` slots) a live lane's whole ring (a host-side count
-    for the step's span: ``context_lens`` is the numpy feed)."""
+    blocks and no others; the latent form's straight-line body fetches a
+    lane's last block again for the rest of its last chunk, which this does
+    not count), or for a
+    window layer (``ring``: the table is its ring of ``maxb`` slots) a live
+    lane's whole ring (a host-side count for the step's span:
+    ``context_lens`` is the numpy feed)."""
     if path != "pallas":
         return len(context_lens) * maxb
     if ring:
         return int((context_lens > 0).sum()) * maxb
     return int((-(-context_lens // block_size)).clip(0, maxb).sum())
+
+
+def chunks_read(context_lens, block_size, maxb, span):
+    """``(chunks, full chunks)`` one layer's kernel walks for these lanes at
+    ``span`` positions a chunk: a lane's chunks cover the blocks it holds
+    (``blocks_read``), and a chunk is *full* when the lane sees every
+    position of it (by positions, not blocks: a lane at 1,020 holds all the
+    blocks of two chunks of 512 and sees 508 positions of the second).  All
+    of a full chunk's copies and arithmetic are of use; of a lane's last
+    chunk the arithmetic covers the whole span whatever part is seen, and
+    the latent form's straight-line body pays a whole chunk's copies too.
+    A host-side count for the step's span, as ``blocks_read``."""
+    per = span // block_size
+    chunks = -(-(-(-context_lens // block_size)).clip(0, maxb) // per)
+    return int(chunks.sum()), int(np.minimum(context_lens // span,
+                                             chunks).sum())
 
 
 def _chunk_positions(fetched, block_size, held):
@@ -602,6 +645,128 @@ def _kernel(bt_ref, cl_ref, q_ref, *refs, heads, kv_heads, head_dim,
         jax.lax.fori_loop(0, lanes, lane, jnp.int32(0))
 
 
+def _latent_kernel(bt_ref, cl_ref, q_ref, pool, o_ref, buf, sem, *fetched,
+                   block_size, maxb, per, scale, value_cols):
+    """The latent form: one pool, one pair of chunk buffers, a row's value
+    its first ``value_cols`` columns, ``q_ref`` rows the query heads over
+    the one cached head.  ``fetched`` given (one SMEM cell): the grid walks
+    the lanes, ``q_ref`` and ``o_ref`` are one lane's, and the count of
+    chunks fetched so far passes from a grid step to the next there, as the
+    chunk buffers and the copies in flight do in VMEM.
+
+    Every chunk runs ONE straight-line body in which the scheduler lays the
+    copies' issue, the matrix work and the softmax chain side by side.  Two
+    things make that possible.  A chunk's copies need no predicate: a slot
+    of the table past the lane's last block fetches that block again (rows
+    the mask leaves out), and where nothing follows a chunk it is fetched
+    again for nobody, waited out by the next lane that starts its own first
+    chunk or by the kernel's end.  And the buffers' indices are compile-time
+    constants: a lane walks its chunks in pairs, the first of a pair in one
+    buffer and the second in the other, in one of two copies of the walk by
+    the parity of the chunks fetched before it; with a traced index the
+    compiler must take a copy into one buffer and a load from the other for
+    the same memory, and keeps them in program order."""
+    lanes = cl_ref.shape[0]
+    rows = q_ref.shape[1]
+    span = per * block_size              # positions a chunk
+
+    def blocks(b):
+        """Blocks lane ``b`` holds."""
+        return jnp.minimum((cl_ref[b] + block_size - 1) // block_size, maxb)
+
+    def chunks(b):
+        return (blocks(b) + per - 1) // per
+
+    def copy(slot, i, block):
+        return pltpu.make_async_copy(
+            pool.at[block], buf.at[slot, pl.ds(i * block_size, block_size)],
+            sem.at[slot])
+
+    def start(b, c, slot):
+        """Chunk ``c`` of live lane ``b`` into buffer ``slot``."""
+        base, last = b * maxb, blocks(b) - 1
+        for i in range(per):
+            copy(slot, i,
+                 bt_ref[base + jnp.minimum(c * per + i, last)]).start()
+
+    def wait(slot):
+        # a wait needs the copy's shape and semaphore, not its source
+        for i in range(per):
+            copy(slot, i, 0).wait()
+
+    def lane(b, g):
+        """One lane; ``g`` counts the chunks fetched so far, so ``g % 2``
+        is the buffer this lane's first chunk lies (or will lie) in."""
+        n = chunks(b)
+        ctx = cl_ref[b]
+        nxt = jnp.minimum(b + 1, lanes - 1)
+        follows = (b + 1 < lanes) & (chunks(nxt) > 0)
+
+        # the lane before, when it had a chunk, fetched this one's first
+        @pl.when((n > 0) & ((b == 0) | (chunks(jnp.maximum(b - 1, 0)) == 0)))
+        def _first():
+            # the last live lane's last chunk fetched again for nobody
+            pl.when(g > 0)(lambda: wait(g % 2))
+            start(b, 0, g % 2)
+
+        mine = 0 if fetched else b      # this lane's place in q_ref, o_ref
+        qx = q_ref[mine]
+
+        def chunk(c, carry, slot):
+            m, l, acc = carry
+            # what follows: the lane's next chunk, the next lane's first,
+            # or (nothing) this one again
+            more = c + 1 < n
+            start(jnp.where(more | ~follows, b, nxt),
+                  jnp.where(more, c + 1, jnp.where(follows, 0, c)), 1 - slot)
+            wait(slot)
+            sc = _product(qx, buf[slot], _NT) * scale    # [rows, span]
+            pos = c * span + jax.lax.broadcasted_iota(
+                jnp.int32, (1, span), 1)
+            sc = jnp.where(pos < ctx, sc, _MASK)
+            m_new = jnp.maximum(m, jnp.max(sc, axis=1, keepdims=True))
+            alpha = jnp.exp(m - m_new)
+            p = jnp.exp(sc - m_new)
+            l = alpha * l + jnp.sum(p, axis=1, keepdims=True)
+            acc = alpha * acc + _product(p, buf[slot][:, :value_cols], _NN)
+            return m_new, l, acc
+
+        def walk(even):
+            """The lane's chunks, 0, 2, ... in buffer ``even``."""
+            def pair(k, carry):
+                return chunk(2 * k + 1, chunk(2 * k, carry, even), 1 - even)
+
+            carry = jax.lax.fori_loop(
+                0, n // 2, pair, (jnp.full((rows, 1), _MASK, jnp.float32),
+                                  jnp.zeros((rows, 1), jnp.float32),
+                                  jnp.zeros((rows, value_cols), jnp.float32)))
+            _m, l, acc = jax.lax.fori_loop(
+                n // 2 * 2, n, lambda c, carry: chunk(c, carry, even), carry)
+            # an idle lane has l == 0 and acc == 0: zeros out, not 0 / 0
+            o_ref[mine] = (acc / jnp.where(l > 0, l, 1.0)).astype(o_ref.dtype)
+
+        for even in (0, 1):
+            pl.when(g % 2 == even)(functools.partial(walk, even))
+        return g + n
+
+    def drain(g):
+        """Wait out what the last live lane's last chunk fetched again."""
+        pl.when(g > 0)(lambda: wait(g % 2))
+
+    if fetched:
+        count, = fetched
+        b = pl.program_id(0)
+
+        @pl.when(b == 0)
+        def _none_yet():
+            count[0] = 0
+
+        count[0] = lane(b, count[0])
+        pl.when(b == lanes - 1)(lambda: drain(count[0]))
+    else:
+        drain(jax.lax.fori_loop(0, lanes, lane, jnp.int32(0)))
+
+
 def _paged_pallas(q, k_cache, v_cache, block_tables, context_lens,
                   scale=None, interpret=None, window=None):
     """q [B, H, D] against folded pools [num_blocks, block_size, KH * D]
@@ -736,6 +901,28 @@ def _latent_held_bytes(q_shape, pool_shape, pool_dtype, rank):
     return lanes * _latent_lane_bytes(q_shape, rank)
 
 
+# Operations a byte of a chunk (both products over the bytes fetched) from
+# which the latent form takes its straight-line body.  Half the v5e's ridge
+# of 240: at 230 (128 query heads over rows of 640 bfloat16) a chunk's
+# arithmetic outlasts its transfer, 1.09 us to 0.87, and the body that lays
+# the copies' issue beside it gains a quarter of the call and of it all in
+# the step; at 58 (32 heads) the transfer outlasts the arithmetic, 0.9 to
+# 0.57, the kernel shares the HBM with the weights XLA fetches ahead beside
+# it, and what the body gains alone (a sixth) the step gives back to those
+# fetches and to the last block's repeats (PERF.md section 6, PR 50)
+_STRAIGHT_LINE_OPS_PER_BYTE = 120
+
+
+def _latent_straight_line(q_shape, pool_dtype, rank):
+    """Does the latent kernel run every chunk as one straight-line block
+    (``_latent_kernel``), or guard its copies as the K/V form does?  By the
+    operations a byte of a chunk: a function of the shapes, one constant."""
+    _lanes, heads, width = q_shape
+    rows = -(-heads // 16) * 16
+    return 2 * rows * (width + rank) \
+        >= _STRAIGHT_LINE_OPS_PER_BYTE * width * jnp.dtype(pool_dtype).itemsize
+
+
 def latent_chunk_positions(q_shape, pool_shape, pool_dtype, rank, maxb):
     """``chunk_positions`` of the latent form."""
     fetched = jnp.dtype(pool_dtype).itemsize * q_shape[2]
@@ -800,11 +987,18 @@ def _latent_pallas(q, pool, block_tables, context_lens, scale, rank,
     held = 1 if lane_grid else bb
     mine = (lambda i, bt, cl: (i, 0, 0)) if lane_grid \
         else (lambda i, bt, cl: (0, 0, 0))
+    # one straight-line block a chunk where its arithmetic outlasts its
+    # transfer, the K/V form's guarded copies (live blocks only) otherwise
+    straight = _latent_straight_line(q.shape, pool.dtype, rank)
+    kernel = functools.partial(
+        _latent_kernel, block_size=bs, maxb=maxb, per=per,
+        scale=float(scale), value_cols=rank) if straight \
+        else functools.partial(
+            _kernel, heads=h, kv_heads=1, head_dim=width, block_size=bs,
+            maxb=maxb, per=per, scale=float(scale), value_cols=rank,
+            lane_grid=lane_grid)
     out = pl.pallas_call(
-        functools.partial(_kernel, heads=h, kv_heads=1, head_dim=width,
-                          block_size=bs, maxb=maxb, per=per,
-                          scale=float(scale), value_cols=rank,
-                          lane_grid=lane_grid),
+        kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(bb // held,),
@@ -812,7 +1006,8 @@ def _latent_pallas(q, pool, block_tables, context_lens, scale, rank,
                       pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((held, rows, rank), mine),
             scratch_shapes=[pltpu.VMEM((2, per * bs, width), pool.dtype),
-                            pltpu.SemaphoreType.DMA((1, 2))]
+                            pltpu.SemaphoreType.DMA(
+                                (2,) if straight else (1, 2))]
             + ([pltpu.SMEM((1,), jnp.int32)] if lane_grid else []),
         ),
         out_shape=jax.ShapeDtypeStruct((bb, rows, rank), jnp.float32),

@@ -908,7 +908,7 @@ class _DecodeSeq:
 class _DecodeModel:
     __slots__ = ("name", "cfg", "params", "kv_config", "cache", "stepfn",
                  "maxb", "attn_path", "window_path", "experts_path",
-                 "state_path", "blocks_read", "window_read",
+                 "state_path", "blocks_read", "window_read", "chunks_read",
                  "step_ms", "prefix",
                  "declines", "slot_bytes", "state_name", "feed0",
                  "columns", "idle_lane", "upload",
@@ -932,6 +932,10 @@ class _DecodeModel:
         # (add_model sets both)
         self.attn_path = None
         self.blocks_read = None
+        # bucket -> (lens -> the chunks a latent layer's attention walks,
+        # and how many of them are full); empty for a model with no latent
+        # layer (add_model sets it)
+        self.chunks_read = {}
         # the same of the window layers' attention over their rings
         # (``window_read``: lens -> the blocks one such layer fetches of a
         # ring, and would fetch of the whole table), None for a model with
@@ -1302,6 +1306,14 @@ class DecodeEngine:
         entry.blocks_read = functools.partial(
             _pa.blocks_read, block_size=kv_config.block_size,
             maxb=entry.maxb, path=attn_path)
+        for b in self.buckets if latent else ():
+            # the kernel's chunk; on the gather path a lane's whole padded
+            # table is its one chunk
+            entry.chunks_read[b] = functools.partial(
+                _pa.chunks_read, block_size=kv_config.block_size,
+                maxb=entry.maxb,
+                span=_dm.chunk_positions(cfg, kv_config, b).get(
+                    "latent", entry.maxb * kv_config.block_size))
         entry.prefix = prefix
         entry.columns, width = _dm.lane_columns(kv_config, entry.maxb)
         entry.idle_lane = np.zeros(width, np.int32)
@@ -2736,6 +2748,10 @@ class DecodeEngine:
                 # what a latent layer fetches (each of them the same): a
                 # block there is one row a token, not K and V
                 read["latent_blocks_read"] = read["kv_blocks_read"]
+                # ... in so many chunks, each a whole chunk's arithmetic to
+                # the kernel; of the full ones all of it is of use
+                read["latent_chunks"], read["latent_full_chunks"] = \
+                    m.chunks_read[bucket](lens)
             if slots is not None:
                 # lanes at position 0 start their slot from zeros
                 resets = int((pos[:len(lanes)] == 0).sum())

@@ -151,6 +151,12 @@ RULE = {
     "latent_attention-dtype": (
         "latent_attention", lambda: pa.latent_attention_checks(
             (32, 32, 640), (12832, 16, 640), "int8", 512), True, "dtype"),
+    "latent_attention-vmem": (
+        # 1,024 query rows of 640 + 512 float32 are 4.7e6 B a lane: the
+        # grid walks the lanes, and two lanes' do not fit beside the buffers
+        "latent_attention", lambda: pa.latent_attention_checks(
+            (32, 1024, 640), (12832, 16, 640), "bfloat16", 512), True,
+        "vmem"),
     "kda_update-backend": (
         "kda_update", _ELIGIBLE["kda_update"], False, "backend"),
     "kda_update-dtype": (

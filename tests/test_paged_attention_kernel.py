@@ -131,19 +131,11 @@ def test_a_chunk_fetches_the_blocks_a_lane_holds_and_no_others(
     assert not out[0].any()
 
 
-@pytest.mark.parametrize("span", [128, 256])
-@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
-                                       (jnp.bfloat16, 2e-2)],
-                         ids=["f32", "bf16"])
-def test_a_latent_chunk_fetches_the_blocks_a_lane_holds_and_no_others(
-        interpreted, monkeypatch, dtype, tol, span):
-    """The same of the latent form (12 query rows over one cached head 256
-    wide, the value its first 128 columns)."""
-    heads, width, rank, block_size = 12, 256, 128, 16
-    monkeypatch.setattr(pa, "_CHUNK_BYTES",
-                        span * width * jnp.dtype(dtype).itemsize)
-    lens, maxb = _borders(block_size, span)
-    rng = np.random.default_rng(5)
+def _latent_case(lens, heads, width, block_size, maxb, dtype, seed):
+    """A random latent pool, absorbed queries and a table in which each
+    lane's blocks are its own and every unused slot is -1 -> (q, pool,
+    tables, lens)."""
+    rng = np.random.default_rng(seed)
     blocks = 1 + maxb * len(lens)
     pool = jnp.asarray(rng.standard_normal((blocks, block_size, width)),
                        dtype)
@@ -154,7 +146,30 @@ def test_a_latent_chunk_fetches_the_blocks_a_lane_holds_and_no_others(
     for b, n in enumerate(lens):
         for j in range(-(-n // block_size)):
             tables[b, j] = next(free)
-    lens = np.asarray(lens, np.int32)
+    return q, pool, tables, np.asarray(lens, np.int32)
+
+
+@pytest.mark.parametrize("span", [128, 256, 512])
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                       (jnp.bfloat16, 2e-2)],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("body", ["guarded", "straight"])
+def test_a_latent_chunk_fetches_the_blocks_a_lane_holds_and_no_others(
+        interpreted, monkeypatch, body, dtype, tol, span):
+    """The same of the latent form (12 query rows over one cached head 256
+    wide, the value its first 128 columns), with the guarded body its shapes
+    take by the rule and with the straight-line one, whose last chunk
+    fetches the lane's last block again and no block it does not hold."""
+    heads, width, rank, block_size = 12, 256, 128, 16
+    if body == "straight":
+        monkeypatch.setattr(pa, "_STRAIGHT_LINE_OPS_PER_BYTE", 0)
+    assert pa._latent_straight_line((8, heads, width), dtype, rank) \
+        == (body == "straight")
+    monkeypatch.setattr(pa, "_CHUNK_BYTES",
+                        span * width * jnp.dtype(dtype).itemsize)
+    lens, maxb = _borders(block_size, span)
+    q, pool, tables, lens = _latent_case(lens, heads, width, block_size,
+                                         maxb, dtype, seed=5)
     assert pa.latent_chunk_positions(q.shape, pool.shape, dtype, rank,
                                      maxb) == span
     ref = np.asarray(pa.latent_attention_reference(q, pool, tables, lens,
@@ -165,6 +180,94 @@ def test_a_latent_chunk_fetches_the_blocks_a_lane_holds_and_no_others(
     assert np.isfinite(out).all()
     np.testing.assert_allclose(out[1:], ref[1:], atol=tol, rtol=tol)
     assert not out[0].any()
+
+
+# lanes' contexts at the borders of the latent cells' chunk of 512 positions,
+# over a table of 32 blocks of 64 (8 copies a chunk, which the interpreter
+# compiles four times sooner than the cells' 32): a lane's last chunk fetches
+# its last block again for the slots past it, a lane walks its chunks in
+# pairs, and a lane's last chunk starts the next live lane's first
+LATENT_LANES = {
+    "0": [0, 0, 0], "1": [1, 1543, 1], "511": [511, 512, 511],
+    "512": [512, 1, 512], "513": [513, 513, 0], "1024": [1024, 1025, 1024],
+    "1025": [1025, 1024, 1], "1543": [1543, 511, 1543],
+    "whole_table": [2048, 2047, 2048],
+    "live_between_idle": [0, 1025, 0], "idle_between_live": [1024, 0, 513],
+}
+
+
+@pytest.mark.parametrize("lanes", sorted(LATENT_LANES))
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                       (jnp.bfloat16, 2e-2)],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("heads", [32, 128])
+def test_latent_kernel_matches_the_gather_path(interpreted, monkeypatch,
+                                               heads, dtype, tol, lanes):
+    """The latent kernel, with the body its shapes take by the rule (the
+    straight-line one at 128 heads over a bfloat16 pool, the guarded one
+    elsewhere), against ``latent_attention_reference`` where a
+    lane's chunks are all full, all but the last, or only a part of one; 32
+    query heads (every lane's query in one grid step) and 128 (a lane a grid
+    step: the budget is shrunk until three lanes' queries no longer fit
+    beside the buffers and two still do).  Every block no table names is
+    NaN; an idle lane returns zeros."""
+    width, rank, block_size, maxb = 256, 128, 64, 32
+    lens = LATENT_LANES[lanes]
+    grid = heads == 128
+    if grid:
+        lane = 4 * heads * (width + rank)
+        monkeypatch.setattr(
+            pa, "_VMEM_BUDGET",
+            2 * 512 * width * jnp.dtype(dtype).itemsize + 2 * lane + lane // 3)
+    q, pool, tables, lens = _latent_case(lens, heads, width, block_size,
+                                         maxb, dtype, seed=11)
+    assert pa._latent_lane_grid(q.shape, pool.shape, dtype, rank) == grid
+    # 128 heads over a bfloat16 row: 192 operations a byte, the
+    # straight-line body; the others keep the guarded one
+    assert pa._latent_straight_line(q.shape, dtype, rank) \
+        == (grid and dtype == jnp.bfloat16)
+    assert pa.latent_chunk_positions(q.shape, pool.shape, dtype, rank,
+                                     maxb) == 512
+    ref = np.asarray(pa.latent_attention_reference(q, pool, tables, lens,
+                                                   0.1, rank))
+    out = np.asarray(pa.latent_attention(
+        q, _unnamed_are_nan(pool, tables), tables, lens, 0.1, rank))
+    assert adoption.active_kernels() == ["latent_attention"]
+    assert _tm.counter_total("pallas_kernel_fallback_total") == 0
+    assert np.isfinite(out).all()
+    live = lens > 0
+    np.testing.assert_allclose(out[live], ref[live], atol=tol, rtol=tol)
+    assert not out[~live].any()
+
+
+@pytest.mark.parametrize("order", ["eager", "on_wait"])
+def test_the_latent_kernel_keeps_its_turns(interpreted, monkeypatch, order):
+    """The latent kernel's straight-line body (forced: 12 heads take the
+    guarded one) under the TPU interpreter's two models of an async
+    copy, done as it is started and only when it is waited for (memory no
+    copy has filled reads NaN there): it waits for what it reads, starts
+    nothing into a buffer whose copies are in flight, and what a chunk that
+    nothing follows fetches for nobody (before an idle lane, at the end) is
+    waited out.  Bit for bit the plain interpreter's output."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    heads, width, rank, block_size, maxb = 12, 256, 128, 16, 24
+    monkeypatch.setattr(pa, "_CHUNK_BYTES", 128 * width * 4)
+    monkeypatch.setattr(pa, "_STRAIGHT_LINE_OPS_PER_BYTE", 0)
+    lens = [0, 300, 0, 0, 128, 129, 1, 384, 0]
+    q, pool, tables, lens = _latent_case(lens, heads, width, block_size,
+                                         maxb, jnp.float32, seed=3)
+    ref = np.asarray(pa.latent_attention_reference(q, pool, tables, lens,
+                                                   0.1, rank))
+    pool = _unnamed_are_nan(pool, tables)
+    plain = np.asarray(pa._latent_pallas(q, pool, tables, lens, 0.1, rank))
+    got = np.asarray(pa._latent_pallas(
+        q, pool, tables, lens, 0.1, rank,
+        interpret=pltpu.InterpretParams(dma_execution_mode=order)))
+    assert np.isfinite(got).all()
+    assert np.array_equal(got, plain)
+    np.testing.assert_allclose(got[lens > 0], ref[lens > 0], atol=2e-5,
+                               rtol=2e-5)
 
 
 def test_f32_products_are_f32(interpreted):
@@ -265,6 +368,10 @@ def test_a_chunk_spans_what_its_bytes_are_worth(cell):
         fetched = jnp.dtype(dtype).itemsize * pool[2]
         held = pa._latent_held_bytes(q, pool, dtype, extra)
         assert pa._latent_lane_grid(q, pool, dtype, extra) \
+            == (cell == "dots_latent")
+        # 230 operations a byte at 128 heads, 58 at 32: one straight-line
+        # block a chunk there, guarded copies here
+        assert pa._latent_straight_line(q, dtype, extra) \
             == (cell == "dots_latent")
         assert pa.latent_chunk_positions(q, pool, dtype, extra, maxb) == span
         assert pa.latent_vmem_bytes(q, pool, dtype, extra) == vmem
@@ -423,9 +530,72 @@ def test_engine_names_the_path_and_counts_the_blocks(interpreted, tmp_path):
         # one lane of 1..12 positions and an idle one: the one or two
         # blocks the lane holds, of a table of six
         assert {a["kv_blocks_read"] for a in attrs} == {1, 2}
+        # a model with no latent layer counts no latent chunks
+        assert not any(key.startswith("latent_") for a in attrs for key in a)
         # the chunk's span by kind of layer, beside the path's name
         assert all(ev["chunk_positions"] == {"attention": CFG.max_seq}
                    for ev in warm)
+    finally:
+        fluid.set_flags(old)
+        tr.reset()
+
+
+def test_engine_counts_the_chunks_a_latent_kernel_walks(interpreted,
+                                                        monkeypatch, tmp_path):
+    """An engine whose one layer is latent, on the kernel (a row of 256
+    float32 and ``_CHUNK_BYTES`` of 128 rows make a chunk 128 positions):
+    each step's span carries the chunks the kernel walks and how many of
+    them are full beside the blocks read, as the lane's context passes one
+    chunk; the tokens are the jnp step's."""
+    from paddle_tpu.models import dots_vlm
+
+    monkeypatch.setattr(pa, "_CHUNK_BYTES", 128 * 256 * 4)
+    cfg = dm.DecoderConfig(
+        arch="dots_vlm", vocab=61, layers=1, heads=4, head_dim=128,
+        hidden_size=128, max_seq=320, layer_types=("latent",),
+        latent_rank=128, latent_rope=32, q_rank=64, dense_layers=1,
+        dense_ffn=64, norm_eps=1e-6,
+        # the family routes; its one layer here is the dense lead
+        ffn=128, shared_ffn=64, experts=16, experts_per_token=3, n_group=4,
+        topk_group=2, routed_scaling=2.5)
+    params = dots_vlm.init_params(cfg, seed=5, std=0.1)
+    d = str(tmp_path / "tel")
+    old = fluid.get_flags(["FLAGS_kv_block_size", "FLAGS_kv_cache_dtype",
+                           "FLAGS_compile_cache_dir", "FLAGS_tracing",
+                           "FLAGS_telemetry_dir"])
+    fluid.set_flags({"FLAGS_kv_block_size": 16, "FLAGS_kv_cache_dtype": "f32",
+                     "FLAGS_compile_cache_dir": str(tmp_path / "cc"),
+                     "FLAGS_tracing": True, "FLAGS_telemetry_dir": d})
+    tr.reset()
+    try:
+        e = DecodeEngine(buckets="2", deadline_ms=120000.0)
+        e.add_model("toy", (cfg, params), kv_blocks=24)
+        e.prewarm()
+        e.start()
+        try:
+            prompt = list(np.random.RandomState(2).randint(0, 61, 120))
+            r = e.generate("toy", prompt, max_new_tokens=12,
+                           deadline_ms=120000.0)
+        finally:
+            e.stop()
+        assert r.status == "ok", r.error
+        _tm.flush()
+        with open(os.path.join(d, "steps.jsonl")) as fp:
+            warm = [ev for ev in map(json.loads, fp)
+                    if ev["ev"] == "serving_prewarm"]
+        assert warm and all(ev["latent_attention"] == "pallas"
+                            and ev["chunk_positions"] == {"latent": 128}
+                            for ev in warm)
+        attrs = [s["attrs"] for s in tr.records("serving.decode_step")]
+        assert len(attrs) >= 131
+        # one lane at contexts of 1..131: blocks of 16, chunks of 128
+        for a in attrs:
+            ctx = -(-a["latent_blocks_read"] // 8) * 128  # its chunks' end
+            assert a["latent_blocks_read"] == a["kv_blocks_read"]
+            assert a["latent_chunks"] == ctx // 128
+            assert a["latent_chunks"] - a["latent_full_chunks"] in (0, 1)
+        assert {(a["latent_chunks"], a["latent_full_chunks"])
+                for a in attrs} == {(1, 0), (1, 1), (2, 1)}
     finally:
         fluid.set_flags(old)
         tr.reset()
@@ -446,3 +616,45 @@ def test_kv_blocks_read_share_reader():
                         "decode_spans": [{"attrs": {"lanes": 3}}]}) is None
     assert reader.read({"kind": "serve", "decode_spans": None}) is None
     assert reader.read({"kind": "train", "decode_spans": spans}) is None
+
+
+def test_latent_full_chunk_share_reader():
+    """The benchmark's reader of ``latent_chunks`` / ``latent_full_chunks``:
+    the median share over the steps that carry them; nothing from spans
+    without them (the parent, the gather path, a model with no latent
+    layer) and nothing from a training run."""
+    from benchmark.run import load_module
+
+    reader = load_module("layer_metrics", "latent_full_chunk_share.serve")
+    spans = [{"attrs": {"latent_chunks": 128, "latent_full_chunks": n}}
+             for n in (96, 100, 64)] + [{"attrs": {"lanes": 3}}]
+    assert reader.read({"kind": "serve", "decode_spans": spans}) \
+        == pytest.approx(75.0)
+    # a step of idle lanes walks no chunk, and says nothing
+    idle = [{"attrs": {"latent_chunks": 0, "latent_full_chunks": 0}}]
+    assert reader.read({"kind": "serve", "decode_spans": idle}) is None
+    assert reader.read({"kind": "serve", "decode_spans": [
+        {"attrs": {"kv_blocks_read": 5, "latent_blocks_read": 5}}]}) is None
+    assert reader.read({"kind": "serve", "decode_spans": None}) is None
+    assert reader.read({"kind": "train", "decode_spans": spans}) is None
+
+
+def test_chunks_read_counts_the_full_chunks():
+    """``chunks_read``: a lane's chunks cover the blocks it holds, and a
+    chunk is full by positions (a lane at 1,020 holds every block of two
+    chunks of 512 and sees 508 positions of the second), at the two latent
+    cells' table of 512 slots and chunk of 512 positions."""
+    count = lambda *lens: pa.chunks_read(np.array(lens, np.int32), 16, 512,
+                                         512)
+    assert count(0) == (0, 0)
+    assert count(1) == count(511) == (1, 0)
+    assert count(512) == (1, 1)
+    assert count(513) == (2, 1)
+    assert count(1020) == (2, 1)
+    assert count(1024) == (2, 2)
+    assert count(8192) == count(9000) == (16, 16)
+    # the cells' windows: contexts of 1,800-2,050 and of 1,100-2,300
+    assert count(1800, 2048, 2050) == (4 + 4 + 5, 3 + 4 + 4)
+    assert count(1100, 2300, 0) == (3 + 5, 2 + 4)
+    # a chunk of a whole short table
+    assert pa.chunks_read(np.array([40, 64], np.int32), 16, 4, 64) == (2, 1)
