@@ -134,6 +134,9 @@ def _declare(lib):
         c.POINTER(c.c_longlong), c.c_int, c.POINTER(c.c_char_p),
     ]
     lib.rpcs_wait_stats.argtypes = [c.c_void_p, c.POINTER(c.c_longlong)]
+    lib.rpcs_time_gets.argtypes = [c.c_void_p, c.c_char_p]
+    lib.rpcs_drain_gets.restype = c.c_longlong
+    lib.rpcs_drain_gets.argtypes = [c.c_void_p, c.c_void_p, c.c_longlong]
     lib.rpcs_serve.argtypes = [c.c_void_p, c.c_int]
     lib.rpcs_del_var.argtypes = [c.c_void_p, c.c_char_p]
     lib.rpcs_destroy.argtypes = [c.c_void_p]

@@ -22,6 +22,9 @@
 #include <sys/time.h>
 #include <unistd.h>
 
+#include <algorithm>
+#include <atomic>
+#include <chrono>
 #include <condition_variable>
 #include <cstdint>
 #include <cstring>
@@ -41,7 +44,24 @@ struct Tensor {
   uint8_t dtype = 0;  // opaque to the transport (numpy dtype enum on the py side)
   std::vector<int64_t> dims;
   std::string data;
+  int64_t stored_ns = 0;  // when its transaction entered the store
 };
+
+// CLOCK_MONOTONIC, the clock of Python's time.monotonic() on this host.
+int64_t now_ns() {
+  return std::chrono::duration_cast<std::chrono::nanoseconds>(
+             std::chrono::steady_clock::now().time_since_epoch())
+      .count();
+}
+
+// One timed GET reply (rpcs_time_gets): when the variable was stored, when
+// the request's frame was read, when the reply was written, and when the
+// reply before it on the same connection was written (0 if that was not a
+// timed one).
+struct GetRecord {
+  int64_t stored_ns, get_ns, sent_ns, prev_sent_ns;
+};
+constexpr size_t kGetRing = 4096;
 
 struct Event {  // delivered to the Python pserver loop
   uint8_t type;  // kSendVar | kBarrier | kComplete
@@ -138,6 +158,17 @@ struct Server {
   long long wakeups = 0;  // times a parked GET handler woke (tests read it)
   bool serving = false;  // GETs blocked until Python publishes + enables
   bool stop = false;
+  // Timed GETs (rpcs_time_gets).  ``timed_gen`` is odd while the switch is
+  // on and moves at every call of the switch, so a GET that read its frame
+  // under one setting leaves no record under another; a GET with the switch
+  // off pays this one load.  The rest is under ``timed_mu``, never ``mu``:
+  // the ring holds the newest kGetRing records, oldest at ``ring_head``.
+  std::atomic<uint32_t> timed_gen{0};
+  std::mutex timed_mu;
+  std::string timed_prefix;
+  std::vector<GetRecord> ring;
+  size_t ring_head = 0, ring_count = 0;
+  long long ring_dropped = 0;
 
   // Erase ``gone`` and store ``items`` as ONE transaction: a reader sees
   // all of it or none of it, and each waiter of a stored name is woken
@@ -147,8 +178,12 @@ struct Server {
   void publish(std::vector<std::pair<std::string, Tensor>>* items,
                const std::vector<std::string>& gone) {
     std::lock_guard<std::mutex> lk(mu);
+    const int64_t stored_ns = now_ns();
     for (const auto& name : gone) store.erase(name);
-    for (auto& kv : *items) store[kv.first] = std::move(kv.second);
+    for (auto& kv : *items) {
+      kv.second.stored_ns = stored_ns;
+      store[kv.first] = std::move(kv.second);
+    }
     if (!serving) return;  // parked GETs stay parked until rpcs_serve(1)
     for (const auto& kv : *items) {
       auto range = waiters.equal_range(kv.first);
@@ -173,12 +208,35 @@ struct Server {
     }
   }
 
+  // After a timed GET's reply is written: one record, unless the switch
+  // has moved since the frame was read or the name is not under the
+  // prefix.  -> whether it was recorded.
+  bool record_get(uint32_t gen, const std::string& name, const GetRecord& r) {
+    std::lock_guard<std::mutex> lk(timed_mu);
+    if (timed_gen.load(std::memory_order_relaxed) != gen ||
+        name.compare(0, timed_prefix.size(), timed_prefix) != 0)
+      return false;
+    if (ring_count == kGetRing) {  // full: the oldest falls out
+      ring[ring_head] = r;
+      ring_head = (ring_head + 1) % kGetRing;
+      ++ring_dropped;
+    } else {
+      ring[(ring_head + ring_count++) % kGetRing] = r;
+    }
+    return true;
+  }
+
   void handle_conn(int fd) {
     int one = 1;
     ::setsockopt(fd, IPPROTO_TCP, TCP_NODELAY, &one, sizeof(one));
     Frame f;
+    // this connection's last reply, if it was a timed one, and the setting
+    // of the switch it was timed under
+    int64_t prev_sent_ns = 0;
+    uint32_t prev_gen = 0;
     while (read_frame(fd, &f)) {
       if (f.type == kSendVar || f.type == kBarrier || f.type == kComplete) {
+        prev_sent_ns = 0;
         {
           std::lock_guard<std::mutex> lk(mu);
           events.push_back({f.type, f.name, std::move(f.tensor)});
@@ -186,6 +244,9 @@ struct Server {
         events_cv.notify_all();
         if (!write_frame(fd, kAck, "", nullptr)) break;
       } else if (f.type == kGetVar) {
+        const uint32_t gen = timed_gen.load(std::memory_order_relaxed);
+        const bool timed = gen & 1;
+        const int64_t get_ns = timed ? now_ns() : 0;
         Tensor t;
         {
           std::unique_lock<std::mutex> lk(mu);
@@ -205,6 +266,15 @@ struct Server {
           t = store[f.name];
         }
         if (!write_frame(fd, kReplyVar, f.name, &t)) break;
+        if (timed) {
+          const int64_t sent_ns = now_ns();
+          const bool kept = record_get(
+              gen, f.name,
+              {t.stored_ns, get_ns, sent_ns,
+               prev_gen == gen ? prev_sent_ns : 0});
+          prev_sent_ns = kept ? sent_ns : 0;
+          prev_gen = gen;
+        }
       }
     }
     // drop from conn_fds BEFORE closing: destroy() must never shutdown()
@@ -344,6 +414,58 @@ void rpcs_wait_stats(void* h, long long* out) {
   std::lock_guard<std::mutex> lk(s->mu);
   out[0] = static_cast<long long>(s->waiters.size());
   out[1] = s->wakeups;
+}
+
+// Time the replies to GETs of names that start with ``prefix``; a null
+// prefix turns the timing off (the default).  Either way the ring is
+// emptied, and a GET whose frame was read before the call leaves no record.
+void rpcs_time_gets(void* h, const char* prefix) {
+  auto* s = static_cast<Server*>(h);
+  std::lock_guard<std::mutex> lk(s->timed_mu);
+  // the next value of the wanted parity: odd is on
+  uint32_t gen = s->timed_gen.load(std::memory_order_relaxed) + 1;
+  if ((gen & 1) != (prefix != nullptr)) ++gen;
+  s->timed_gen.store(gen, std::memory_order_relaxed);
+  s->timed_prefix = prefix ? prefix : "";
+  if (prefix && s->ring.empty()) s->ring.resize(kGetRing);
+  s->ring_head = s->ring_count = 0;
+  s->ring_dropped = 0;
+}
+
+// What the oldest records (at most ``cap``) say, in microseconds, and take
+// them off the ring.  out[0]: records that fell out of the full ring since
+// the last drain; out[1], out[2]: how many ``late`` and ``turnaround``
+// values follow.  Then, ``n`` being the count returned: at out + 3 ``n``
+// times ``deliver`` (from chunk and request both there to the reply
+// written: sent - max(stored, get)); at out + 3 + n ``late`` (get - stored
+// where the request came after its chunk); at out + 3 + 2n ``turnaround``
+// (get - the connection's reply before, where that one was timed).  The
+// arithmetic is here because the caller is a decode loop that pays for every
+// call it makes.  ``out`` holds 3 + 3 * cap values.
+long long rpcs_drain_gets(void* h, long long* out, long long cap) {
+  auto* s = static_cast<Server*>(h);
+  std::lock_guard<std::mutex> lk(s->timed_mu);
+  out[0] = s->ring_dropped;
+  s->ring_dropped = 0;
+  const long long n =
+      std::min<long long>(cap, static_cast<long long>(s->ring_count));
+  long long* deliver = out + 3;
+  long long* late = deliver + n;
+  long long* turnaround = late + n;
+  long long n_late = 0, n_turn = 0;
+  for (long long i = 0; i < n; ++i) {
+    const GetRecord& r = s->ring[s->ring_head];
+    s->ring_head = (s->ring_head + 1) % kGetRing;
+    deliver[i] = (r.sent_ns - std::max(r.stored_ns, r.get_ns)) / 1000;
+    if (r.get_ns > r.stored_ns)
+      late[n_late++] = (r.get_ns - r.stored_ns) / 1000;
+    if (r.prev_sent_ns)
+      turnaround[n_turn++] = (r.get_ns - r.prev_sent_ns) / 1000;
+  }
+  s->ring_count -= static_cast<size_t>(n);
+  out[1] = n_late;
+  out[2] = n_turn;
+  return n;
 }
 
 void rpcs_destroy(void* h) {

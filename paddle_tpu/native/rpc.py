@@ -61,6 +61,9 @@ EV_SEND = 1
 EV_BARRIER = 3
 EV_COMPLETE = 4
 
+GET_RING = 4096     # timed GET replies the server keeps undrained (kGetRing)
+_DRAIN_AT_ONCE = 512    # of them a call of rpcs_drain_gets copies out
+
 
 class RpcServer:
     def __init__(self, port=0):
@@ -147,6 +150,44 @@ class RpcServer:
         out = (ctypes.c_longlong * 2)()
         self._lib.rpcs_wait_stats(self._h, out)
         return {"parked": out[0], "wakeups": out[1]}
+
+    def time_gets(self, prefix):
+        """Time every reply to a GET of a name that starts with ``prefix``
+        from now on (``drain_gets`` reads them); ``None`` turns it off, as
+        a new server is.  Either way what was waiting is thrown away."""
+        if self._h is None:
+            raise ConnectionError("rpc server already shut down")
+        self._lib.rpcs_time_gets(
+            self._h, None if prefix is None else prefix.encode())
+
+    def drain_gets(self):
+        """What the store timed of the replies written since the last
+        drain, oldest first, in microseconds: ``(deliver, late,
+        turnaround, dropped)``.  ``deliver`` has a value a reply: from
+        variable and request both there to the reply written; ``late`` one
+        for each request that came after its variable was stored: how long
+        after; ``turnaround`` one for each reply whose connection's reply
+        before was a timed one too: from that one written to this request
+        read.  ``dropped`` replies fell out of the ring of ``GET_RING``
+        undrained."""
+        if self._h is None:
+            raise ConnectionError("rpc server already shut down")
+        parts, dropped = [], 0
+        while True:
+            # 512 a call: a buffer the allocator hands out of its heap (the
+            # whole ring's would be mapped and unmapped at every call)
+            out = np.empty(3 + 3 * _DRAIN_AT_ONCE, np.int64)
+            n = self._lib.rpcs_drain_gets(self._h, out.ctypes.data,
+                                          _DRAIN_AT_ONCE)
+            fell, n_late, n_turn = out[:3].tolist()
+            dropped += fell
+            parts.append((out[3:3 + n], out[3 + n:3 + n + n_late],
+                          out[3 + 2 * n:3 + 2 * n + n_turn]))
+            if n < _DRAIN_AT_ONCE:
+                break
+        deliver, late, turnaround = parts[0] if len(parts) == 1 else (
+            np.concatenate(col) for col in zip(*parts))
+        return deliver, late, turnaround, dropped
 
     def serve(self, enable=True):
         if self._h is None:

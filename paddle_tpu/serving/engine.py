@@ -1129,21 +1129,25 @@ class DecodeEngine:
         self._rr_prefill = 0        # round-robin pointer (token budget)
         # what the next serving.decode_step span reports: (admit wait,
         # lock wait) in ms of each request admitted since the last one,
-        # when the device last handed a step's tokens back, and the lane
-        # set last written to the flight recorder
+        # when the device last handed a step's tokens back, when the span
+        # before it was opened, and the lane set last written to the
+        # flight recorder
         self._admit_waits = []
         self._t_fetched = None
+        self._t_step_opened = None
         self._noted_lanes = None
         # the step dispatched and not read back yet (one-ahead loop), and
         # "a step is in flight or being applied"
         self._flight = None
         self.in_batch = False
         self.on_batch_boundary = None
-        # ``on_tokens_emitted()`` fires once where an iteration has made
-        # its last ``on_token`` call (the end of a step's emit, under the
-        # step lock) and after a terminal chunk emitted outside a step:
-        # the server publishes the stream chunks it gathered meanwhile as
-        # one store transaction, and returns their number
+        # ``on_tokens_emitted(step=)`` fires once where an iteration has
+        # made its last ``on_token`` call (the end of a step's emit, under
+        # the step lock: ``step`` true) and after a terminal chunk emitted
+        # outside a step: the server publishes the stream chunks it
+        # gathered meanwhile as one store transaction, and returns their
+        # number, or from a step's call that number and a dict of
+        # attributes for the step's span
         self.on_tokens_emitted = None
         # disaggregated prefill role hooks (serving/disagg.py wires them):
         # on_block_sealed(m, seq, j, digest) fires under the step lock for
@@ -2094,16 +2098,20 @@ class DecodeEngine:
             m.cache.release_ring(seq.window_ring)
             seq.window_ring = None
 
-    def _tokens_emitted(self):
+    def _tokens_emitted(self, step=False):
         """Every ``on_token`` call of this iteration has been made: tell
-        whoever gathers them (``on_tokens_emitted``).  Returns how many
-        stream chunks that published, 0 with no hook set."""
-        if self.on_tokens_emitted is None:
-            return 0
-        try:
-            return int(self.on_tokens_emitted() or 0)
-        except Exception:
-            return 0
+        whoever gathers them (``on_tokens_emitted``), and whether a step's
+        emit ends here.  Returns the step span's attributes from it:
+        ``published``, how many stream chunks that stored (0 with no hook
+        set), and whatever else the hook hands a step, unread."""
+        got = 0
+        if self.on_tokens_emitted is not None:
+            try:
+                got = self.on_tokens_emitted(step=step) or 0
+            except Exception:
+                pass
+        published, attrs = got if isinstance(got, tuple) else (got, {})
+        return dict(attrs, published=int(published))
 
     def _finish(self, seq, reply):
         r = seq.pending
@@ -2534,8 +2542,10 @@ class DecodeEngine:
                             break
                         self._cond.wait(left)
                 # waiting for work is not the host keeping the
-                # device waiting: gap_us counts from here
+                # device waiting: gap_us counts from here, and the next
+                # step's span has no period
                 self._t_fetched = time.perf_counter()
+                self._t_step_opened = None
                 return True
             step_ok = self._decode_step_locked()
             preempted, self._preempted = self._preempted, []
@@ -2569,7 +2579,15 @@ class DecodeEngine:
         for s in lanes:
             sspan.link(s.pending.span.context
                        if s.pending.span is not None else None)
+        opened, self._t_step_opened = self._t_step_opened, None
         if _tr.enabled():
+            # the loop's whole period, lock, admission and plan with it:
+            # from the span before's open to this one's (not on the first
+            # span since the flag came on or the loop last idled)
+            self._t_step_opened = time.perf_counter()
+            if opened is not None:
+                sspan.annotate(period_us=int(
+                    (self._t_step_opened - opened) * 1e6))
             req_ids = [s.pending.req_id for s in lanes]
             if req_ids != self._noted_lanes:
                 self._noted_lanes = req_ids
@@ -2613,7 +2631,7 @@ class DecodeEngine:
         from outside the loop, calls this before it looks."""
         flight, self._flight = self._flight, None
         if flight is not None:
-            self._apply_flight_locked(flight)
+            self._apply_flight_locked(flight, spanned=False)
         self.in_batch = False
 
     def _fail_lanes_locked(self, m, lanes, error):
@@ -2803,11 +2821,11 @@ class DecodeEngine:
         self._close_step_span(sspan, gap_us=gap_us, ahead=ahead, **applied)
         return "error" not in applied
 
-    def _apply_flight_locked(self, flight):
+    def _apply_flight_locked(self, flight, spanned=True):
         """Fetch a dispatched step's tokens (the wait, if the device is
         still at it) and apply them: one token for every lane that is
         still what the step took it for.  -> the attributes its iteration's
-        span reports."""
+        span reports (``spanned``: there is one to report them)."""
         m = flight.m
         try:
             with _tr.phase("serving.fetch"):
@@ -2862,9 +2880,8 @@ class DecodeEngine:
             _tm.observe("decode_batch_occupancy",
                         len(flight.lanes) / float(flight.bucket),
                         model=m.name)
-            published = self._tokens_emitted()
-        return dict(moe, generated=n_generated, ms=round(ms, 3),
-                    published=published)
+            stream = self._tokens_emitted(step=spanned)
+        return dict(moe, generated=n_generated, ms=round(ms, 3), **stream)
 
     @staticmethod
     def _window_attrs(m, lens, released):
@@ -3157,7 +3174,7 @@ class DecodeEngine:
                 _tm.inc("spec_blocks_rolled_back_total", rolled,
                         model=m.name)
             # before the draft's catch-up dispatch: the tokens are final
-            published = self._tokens_emitted()
+            stream = self._tokens_emitted(step=True)
         ingest = [(s, q, t) for (s, q, t) in ingest if s in self._active]
         if ingest:
             with _tr.phase("serving.plan"):
@@ -3201,6 +3218,6 @@ class DecodeEngine:
             _tm.observe("decode_batch_occupancy",
                         len(lanes) / float(bucket), model=m.name)
         self._close_step_span(sspan, generated=n_generated, ms=round(ms, 3),
-                              gap_us=gap_us, published=published,
-                              k_proposed=k_proposed, k_accepted=k_accepted)
+                              gap_us=gap_us, k_proposed=k_proposed,
+                              k_accepted=k_accepted, **stream)
         return True

@@ -72,6 +72,9 @@ from . import codec
 __all__ = ["ServingServer"]
 
 _REPLY_RING = 1024
+# steps whose stream timings enter their histograms together: a call costs
+# the decode loop 10-20 us whatever it carries, a value 0.1 us
+_OBSERVE_EVERY = 16
 
 
 class ServingServer:
@@ -95,6 +98,12 @@ class ServingServer:
         self._reply_keys = collections.deque()
         self._chunks = []
         self._reply_lock = threading.Lock()
+        # whether the RPC store times its streamed replies: it follows
+        # FLAGS_tracing, looked at once a step by the decode loop
+        self._gets_timed = False
+        # histogram -> a step's values (us) not yet observed, and the steps
+        self._stream_seen = {}
+        self._stream_steps = 0
         self._thread = None
         self._pub_stop = None
         self._stopped = threading.Event()
@@ -163,18 +172,13 @@ class ServingServer:
 
     def _pre_publish(self):
         """Derived per-window gauges, recomputed on every 1s republish
-        (runs inside the publisher tick, after series_record): per-tier
-        windowed shed RATE from the tier-labeled counter's series
-        deltas, and per-namespace prefix hit rate from the
-        namespace-labeled token counters — the windowed signals the
-        autoscaler's tier policy and the prefix-aware router bias on."""
+        (runs inside the publisher tick, after series_record):
+        per-namespace prefix hit rate from the namespace-labeled token
+        counters — the windowed signal the prefix-aware router biases
+        on."""
         from .. import flags
 
         window = float(flags.flag("serving_rate_window"))
-        for flat, labels in _tm.label_sets("serving_tier_shed_total"):
-            _tm.set_gauge("serving_tier_shed_rate",
-                          _tm.series_rate(flat, window),
-                          tier=labels.get("tier", "default"))
         for flat, labels in _tm.label_sets(
                 "prefix_cache_ns_lookup_tokens_total"):
             ns = labels.get("namespace", "default")
@@ -767,7 +771,7 @@ class ServingServer:
                 self._chunks.append(chunk)
         return on_token
 
-    def _store(self, items=()):
+    def _store(self, items=(), step=False):
         """The one way a per-request key (stream chunk, reply, pair,
         resume ack) enters the RPC store: the waiting stream chunks, then
         ``items``, and the keys the GC ring retires for them, as one
@@ -775,21 +779,82 @@ class ServingServer:
         readers under one acquisition of the store's mutex, a request's
         last chunk is there no later than its reply, and the ring bounds
         the store at ``_REPLY_RING`` keys whoever crashed mid-stream.
-        Returns the stream chunks handed over."""
+        Returns the stream chunks handed over.
+
+        ``step`` is the decode loop ending a step's emit
+        (``DecodeEngine.on_tokens_emitted``).  While ``FLAGS_tracing`` is
+        on, that call also has the store time its streamed replies and
+        returns, beside the count, what became of the replies written
+        since the step before (``_stream_delivery``), for the step span.
+        They are read BEFORE this step's chunks go in: its 30 handler
+        threads wake on the transaction and take the cores this thread's
+        arithmetic then waits for (drained after it, the same work cost
+        ``serving.emit`` 0.19 ms a step on the chip, PERF.md, PR 51)."""
+        delivery = self._stream_delivery() \
+            if step and self._follow_tracing() else None
         with self._reply_lock:
             chunks, self._chunks = self._chunks, []
             batch = chunks + list(items)
-            if not batch:
-                return 0
-            ring = self._reply_keys
-            ring.extend(key for key, _ in batch)
-            gone = [ring.popleft()
-                    for _ in range(len(ring) - _REPLY_RING)]
-            self.rpc.set_vars(batch, delete=gone)
+            if batch:
+                ring = self._reply_keys
+                ring.extend(key for key, _ in batch)
+                gone = [ring.popleft()
+                        for _ in range(len(ring) - _REPLY_RING)]
+                self.rpc.set_vars(batch, delete=gone)
         if chunks:
             _tm.inc("serving_stream_publish_total")
             _tm.inc("serving_stream_chunks_total", len(chunks))
+        if delivery is not None:
+            return len(chunks), delivery
         return len(chunks)
+
+    def _follow_tracing(self):
+        """Turn the store's timing of ``__stream__`` GETs on or off where
+        ``FLAGS_tracing`` has changed since the step before (going off,
+        what the store had timed is thrown away).  -> the flag."""
+        on = _tr.enabled()
+        if on != self._gets_timed:
+            self.rpc.time_gets(codec.STREAM_KEY if on else None)
+            self._gets_timed = on
+            if not on:
+                self._observe_stream()
+        return on
+
+    def _stream_delivery(self):
+        """What the store timed of the stream replies written since the
+        last call, as step span attributes, microseconds a reply:
+        ``deliver_us`` from chunk and request both there to the reply
+        written (the server's own: wake, the store's mutex, copy, write);
+        ``late_us``, where the request came after its chunk, how long the
+        chunk lay waiting for its reader; ``turnaround_us`` from a
+        connection's reply written to its next request read (the reader's
+        own, with the loopback twice).  They are the replies to the chunks
+        of the step BEFORE this one (and to older ones, from readers that
+        fell behind): a one-step shift, as ``lanes`` and ``generated`` are a
+        step apart.  The same values go to the ``serving_stream_*_ms``
+        histograms, ``_OBSERVE_EVERY`` steps' at a time."""
+        deliver, late, turnaround, dropped = self.rpc.drain_gets()
+        for hist, us in (("serving_stream_deliver_ms", deliver),
+                         ("serving_stream_late_ms", late),
+                         ("serving_stream_turnaround_ms", turnaround)):
+            if us.size:
+                self._stream_seen.setdefault(hist, []).append(us)
+        out = {"deliver_us": deliver.tolist(), "late_us": late.tolist(),
+               "turnaround_us": turnaround.tolist(),
+               "stream_replies": deliver.size,
+               "stream_records_dropped": dropped}
+        self._stream_steps += 1
+        if self._stream_steps >= _OBSERVE_EVERY:
+            self._observe_stream()
+        return out
+
+    def _observe_stream(self):
+        """The stream timings gathered since the last call, into their
+        histograms (the decode loop's thread: the step that finds
+        ``_OBSERVE_EVERY`` of them waiting, or the flag gone off)."""
+        seen, self._stream_seen, self._stream_steps = self._stream_seen, {}, 0
+        for hist, steps in seen.items():
+            _tm.observe_many(hist, np.concatenate(steps) / 1e3)
 
     def _publish(self, req_id, reply, pending=None):
         from .engine import InferReply

@@ -350,3 +350,191 @@ def test_an_engine_without_a_server_has_no_hook_and_streams_as_before():
         assert p.wait(10.0).status == "error" and order == ["chunk", "reply"]
     finally:
         e.stop()
+
+
+# -- what became of a step's chunks on their way out (FLAGS_tracing) ---------
+
+def _spy_on_the_switch(w):
+    """Every ``RpcServer.time_gets`` call of ``w``'s server from now on."""
+    calls, inner = [], w.server.rpc.time_gets
+
+    def time_gets(prefix):
+        calls.append(prefix)
+        inner(prefix)
+
+    w.server.rpc.time_gets = time_gets
+    return calls
+
+
+def _read_all(w, rids):
+    """Each stream walked to its done chunk on a thread and a connection
+    of its own, as clients do: the threads, started."""
+    threads = [threading.Thread(target=w.chunks, args=(rid,))
+               for rid in rids]
+    for t in threads:
+        t.start()
+    return threads
+
+
+def _join_all(threads):
+    for t in threads:
+        t.join(60.0)
+    assert not any(t.is_alive() for t in threads), "a reader hung"
+
+
+def test_the_step_span_says_what_became_of_the_chunks_a_step_late(
+        fresh, tmp_path):
+    w, rids, n = fresh, _rids(), 8
+    # the flag is off: a step's call that finds the switch on (a traced
+    # test before this one) turns it off and throws away what was timed
+    assert w.server._store(step=True) == 0 and not w.server._gets_timed
+    with _flags(FLAGS_tracing=True, FLAGS_telemetry=True,
+                FLAGS_telemetry_dir=str(tmp_path)):
+        _trc.reset()
+        _tm.reset()
+        try:
+            # slow steps: every reader is parked on its next chunk long
+            # before the step that stores it
+            fault_injection.arm("serving.decode_step:delay:1")
+            with w.engine._cond:
+                for rid, prompt in zip(rids, PROMPTS):
+                    w.generate(rid, prompt, n)
+            _join_all(_read_all(w, rids))
+            fault_injection.disarm()
+            w.idle()
+            # no step follows the last one: its replies are still waiting
+            # (a reply reaches its reader before its record the ring)
+            time.sleep(0.05)
+            published, rest = w.server._store(step=True)
+            # the histograms take sixteen steps' values at a time
+            w.server._observe_stream()
+            steps = sorted((s["attrs"] for s in
+                            _trc.records("serving.decode_step")),
+                           key=lambda a: a["step"])
+            hists = _tm.snapshot()["histograms"]
+        finally:
+            fault_injection.disarm()
+            _trc.reset()
+            _tm.reset()
+    assert published == 0 and len(steps) >= n
+    assert "period_us" not in steps[0]
+    assert all(a["period_us"] > 0 for a in steps[1:])
+    # the first iteration dispatches and has no step before it to read
+    assert "stream_replies" not in steps[0] and steps[0]["published"] == 0
+    steps = steps[1:]
+    for a in steps + [rest]:
+        assert a["stream_records_dropped"] == 0
+        assert len(a["deliver_us"]) == a["stream_replies"]
+        assert len(a["late_us"]) <= a["stream_replies"]
+        assert len(a["turnaround_us"]) <= a["stream_replies"]
+        assert min(a["deliver_us"] + a["turnaround_us"] + [0]) >= 0
+    # every chunk was read once, and its reply is on the span of the step
+    # AFTER the one that stored it: a step's call reads what was timed
+    # before its own chunks go in.  But for the ends: the requests for each
+    # stream's first chunk were read before the first step's call turned
+    # the switch on, and leave no record; the last step is read with no
+    # step after it to dispatch, so no span reports its chunks and what was
+    # timed after it waits for the next
+    assert sum(a["published"] for a in steps) == LANES * (n - 1)
+    assert sum(a["stream_replies"] for a in steps + [rest]) \
+        == LANES * (n - 1)
+    first = next(i for i, a in enumerate(steps) if a["published"])
+    assert not any(a["stream_replies"] for a in steps[:first + 2])
+    for a, after in zip(steps[first + 1:], steps[first + 2:]):
+        assert after["stream_replies"] == a["published"]
+    assert rest["stream_replies"] == 2 * LANES
+    # parked readers: no chunk waited for its reader, and each reader's
+    # first timed reply has no timed reply before it to turn around from
+    turns = sum(len(a["turnaround_us"]) for a in steps + [rest])
+    assert turns == LANES * (n - 2)
+    assert not any(a["late_us"] for a in steps + [rest])
+    assert hists["serving_stream_deliver_ms"]["count"] == LANES * (n - 1)
+    assert hists["serving_stream_turnaround_ms"]["count"] == turns
+    assert "serving_stream_late_ms" not in hists
+
+
+def test_the_switch_follows_the_flag_on_off_and_on(fresh, tmp_path):
+    w, rid, n = fresh, uuid.uuid4().hex, 26
+
+    def at(tokens, do):
+        """``do()`` between two steps, once ``rid`` has that many tokens
+        (the loop holds the step lock from plan to publish); -> the last
+        step's number."""
+        while True:
+            with w.engine._cond:
+                if w.engine._active and \
+                        len(w.engine._active[0].out) >= tokens:
+                    do()
+                    return w.engine._step_no
+            time.sleep(0.005)
+
+    # tracing off: the hook's return is the count it was, and the switch
+    # goes off if a traced test before this one left it on
+    assert w.server._store(step=True) == 0 and not w.server._gets_timed
+    calls = _spy_on_the_switch(w)
+    off = {}
+
+    def look_while_off():
+        *timed, off["dropped"] = w.server.rpc.drain_gets()
+        off["recs"] = timed[0]
+        off["timed"] = w.server._gets_timed
+        fluid.set_flags({"FLAGS_tracing": True})
+
+    try:
+        with _flags(FLAGS_tracing=True, FLAGS_telemetry_dir=str(tmp_path)):
+            _trc.reset()
+            fault_injection.arm("serving.decode_step:delay:1")
+            w.generate(rid, [1, 2], n)
+            readers = _read_all(w, [rid])
+            t_off = time.perf_counter()
+            s_off = at(6, lambda: fluid.set_flags({"FLAGS_tracing": False}))
+            s_on = at(14, look_while_off)
+            off_us = (time.perf_counter() - t_off) * 1e6
+            _join_all(readers)
+            fault_injection.disarm()
+            w.idle()
+            steps = {s["attrs"]["step"]: s["attrs"]
+                     for s in _trc.records("serving.decode_step")}
+    finally:
+        fault_injection.disarm()
+        _trc.reset()
+    assert calls == [codec.STREAM_KEY, None, codec.STREAM_KEY]
+    # off: steps ran and chunks were read, nothing was timed or recorded
+    assert s_on - s_off >= 7 and not off["timed"]
+    assert (len(off["recs"]), off["dropped"]) == (0, 0)
+    assert not any(s_off < k <= s_on for k in steps)
+    # on again: the GET answered by the first step was read while the switch
+    # was off and leaves no record; the next reply has none to turn around
+    # from; and nothing after it reaches back over the stretch
+    assert "period_us" not in steps[s_on + 1]
+    assert steps[s_on + 1]["stream_replies"] == 0
+    before = [steps[k] for k in sorted(steps) if k <= s_off]
+    after = [steps[k] for k in sorted(steps) if k > s_on]
+    # (the request's first span dispatched with nothing to read before it)
+    assert sum(a.get("stream_replies", 0) for a in before) >= 3
+    assert sum(a["stream_replies"] for a in after) >= 8
+    assert sum(len(a["turnaround_us"]) for a in after) \
+        == sum(a["stream_replies"] for a in after) - 1
+    assert max(t for a in after for t in a["turnaround_us"] + a["late_us"]
+               + a["deliver_us"]) < off_us / 2
+
+
+def test_a_server_that_never_saw_tracing_on_never_asks_for_the_timing(
+        tmp_path_factory):
+    with _flags(FLAGS_compile_cache_dir=str(tmp_path_factory.mktemp("cc")),
+                FLAGS_kv_block_size=BS, FLAGS_kv_cache_dtype="f32"):
+        w = _Wire()
+    try:
+        calls, rid = _spy_on_the_switch(w), uuid.uuid4().hex
+        w.generate(rid, [1, 2], 6)
+        got = w.chunks(rid)
+        w.idle()
+        assert [c[1] for c in got] == _unpaged([1, 2], 6)
+        # the hook returns the count it always did
+        w.server._stream_publisher(rid)(rid, 6, 1, False, "ok")
+        assert w.server._store(step=True) == 1
+        assert calls == [] and not w.server._gets_timed
+        deliver, _late, _turnaround, dropped = w.server.rpc.drain_gets()
+        assert (len(deliver), dropped) == (0, 0)
+    finally:
+        w.server.shutdown()
