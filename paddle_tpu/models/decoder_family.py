@@ -4,7 +4,11 @@ A family the decode steps serve is ONE module, ``models/<arch>.py``, with
 ``token_logits`` (its block, under the contract ``serving/decode_model.py``
 ``_block`` states), ``init_params(cfg, seed)`` and ``FAMILY``, a
 ``DecoderFamily``: plain data, read by ``DecoderConfig`` to refuse what the
-block does not compute and by the step makers to find what it routes.  To
+block does not compute and by the step makers to find what it routes.  A
+module may also hold ``laid_out(cfg, params)``: the published parameters as
+a decode step should hold them, where its block multiplies a weight in
+another layout than the source publishes it in
+(``decode_model.laid_out`` asks; a module without one is held as it is).  To
 add a family: write that module, name it in ``decode_model.ARCHS``, and give
 it a row in ``tests/decoder_families.py``; nothing else in ``serving/``
 knows a family by name.

@@ -63,8 +63,9 @@ from . import kimi_linear as _kimi
 from .decoder_family import DecoderFamily
 from .olmoe import _rmsnorm
 
-__all__ = ["token_logits", "param_shapes", "init_params", "routed_part",
-           "shared_part", "yarn_inv_freq", "BIAS_STD", "FAMILY"]
+__all__ = ["token_logits", "param_shapes", "init_params", "laid_out",
+           "routed_part", "shared_part", "yarn_inv_freq", "BIAS_STD",
+           "FAMILY"]
 
 FAMILY = DecoderFamily(kinds=("latent",), routes="after_dense",
                        expert_matrices=3, dense_lead=True, holds_share=True,
@@ -89,6 +90,8 @@ BIAS_STD = 0.001
 
 routed_part = _exaone.routed_part
 shared_part = _exaone.shared_part
+# every layer's ``wkvb`` as ``latent_mixer`` multiplies it
+laid_out = _kimi.laid_out
 
 
 def param_shapes(cfg):

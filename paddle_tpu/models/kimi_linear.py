@@ -65,8 +65,9 @@ V]``, ``lnf_g`` and per layer ``l<i>_`` + ``ln1_g``, ``ln2_g``; kda layers
 (the decay's and the output gate's down-projections and ``b_proj``),
 ``f_b``, ``g_b [R, I]``, ``dt_bias [I]``, ``A_log [SH]``, ``o_norm [D]``,
 ``wo [I, H]``; latent layers ``wq [H, heads * (D + P)]``, ``wkva [H, rank +
-P]``, ``kv_norm [rank]``, ``wkvb [rank, heads * 2 D]``, ``wo [heads * D,
-H]``; the dense layer ``w1``, ``w3 [H, F]``, ``w2 [F, H]``; routed layers as
+P]``, ``kv_norm [rank]``, ``wkvb [rank, heads * 2 D]`` (as published; a
+decode step holds it as ``laid_out`` leaves it), ``wo [heads * D, H]``; the
+dense layer ``w1``, ``w3 [H, F]``, ``w2 [F, H]``; routed layers as
 ``exaone_moe``'s.
 """
 
@@ -79,8 +80,8 @@ from . import granite_hybrid as _granite
 from .decoder_family import DecoderFamily
 from .olmoe import NP_DTYPES, _mm, _rmsnorm
 
-__all__ = ["token_logits", "param_shapes", "init_params", "routed_part",
-           "shared_part", "BIAS_STD", "FAMILY"]
+__all__ = ["token_logits", "param_shapes", "init_params", "laid_out",
+           "routed_part", "shared_part", "BIAS_STD", "FAMILY"]
 
 FAMILY = DecoderFamily(kinds=("kda", "latent"), routes="after_dense",
                        expert_matrices=3, dense_lead=True, holds_share=True,
@@ -166,6 +167,25 @@ def init_params(cfg, seed=0, std=0.02, bias_std=BIAS_STD):
             for name, (shape, kind) in sorted(param_shapes(cfg).items())}
 
 
+def laid_out(cfg, params):
+    """``params`` as a decode step holds them (``decode_model.laid_out``):
+    each latent layer's ``wkvb [rank, heads * 2 D]`` gives way to the two
+    arrays ``latent_mixer`` multiplies, heads leading: ``wkvb_k [heads,
+    rank, D]``, the key's up-projection as ``bhd,hrd->bhr`` reads it, and
+    ``wkvb_v [heads, D, rank]``, the value's as ``bhr,hdr->bhd`` does.  The
+    same values in the same dtype, turned once on the device; published,
+    the head axis lies in the middle and XLA turns the weight in every
+    step (PERF.md section 6, PR 52).  A new dict: the caller's keeps the
+    published form, which bundles, references and checks read."""
+    rank, heads, d = cfg.latent_rank, cfg.heads, cfg.head_dim
+    out = dict(params)
+    for l in cfg.latent_layers:
+        up = jnp.asarray(out.pop("l%d_wkvb" % l)).reshape(rank, heads, 2, d)
+        out["l%d_wkvb_k" % l] = up[:, :, 0].transpose(1, 0, 2)
+        out["l%d_wkvb_v" % l] = up[:, :, 1].transpose(1, 2, 0)
+    return out
+
+
 def _l2(x):
     """x [B, SH, D] with each head's values at unit length."""
     return x * jax.lax.rsqrt(jnp.sum(x * x, axis=-1, keepdims=True) + L2_EPS)
@@ -207,6 +227,17 @@ def kda_mixer(cfg, p, l, h, recur):
         return _mm(y.reshape(bb, inner), p("wo"))
 
 
+def _held_laid_out(p):
+    """The key's and the value's up-projection as ``laid_out`` left them
+    (``[heads, rank, D]``, ``[heads, D, rank]``) where the layer's
+    parameters hold them so, else None: told apart by the keys the layer
+    has, not by a flag."""
+    try:
+        return p("wkvb_k"), p("wkvb_v")
+    except KeyError:
+        return None
+
+
 def latent_mixer(cfg, p, l, h, attend, rotate=None):
     """The absorbed MLA mixer of layer ``l`` over h [B, H] float32.  Two
     options, for the families that share it (``dots_vlm``): ``cfg.q_rank``
@@ -214,13 +245,17 @@ def latent_mixer(cfg, p, l, h, attend, rotate=None):
     (``wq_a``, ``q_norm``, ``wq_b``) and not through ``wq``; ``rotate``
     given, ``rotate(x [B, n, latent_rope])`` turns the row's shared key
     and each head's query's last ``latent_rope`` values by the lanes'
-    positions, before ``attend`` writes the row and before the absorb."""
+    positions, before ``attend`` writes the row and before the absorb.
+    ``wkvb`` is read as ``laid_out`` left it or, handed the published
+    array, cut from that: the same products of the same values."""
     bb = h.shape[0]
     heads, d, rank = cfg.heads, cfg.head_dim, cfg.latent_rank
     dot = lambda eq, a, b: jnp.einsum(
         eq, a.astype(b.dtype), b, preferred_element_type=jnp.float32)
-    # a head's columns of wkvb: the key's up-projection, then the value's
-    up = p("wkvb").reshape(rank, heads, 2 * d)
+    laid = _held_laid_out(p)
+    # published, a head's columns of wkvb: the key's up-projection, then
+    # the value's
+    up = None if laid else p("wkvb").reshape(rank, heads, 2 * d)
     # (``absorb`` is opened again around each rotation so that ``rope`` is
     # its sibling, and without one the operations come in the order they
     # had before there were options: the lowered step is the same text)
@@ -237,7 +272,8 @@ def latent_mixer(cfg, p, l, h, attend, rotate=None):
             k_pe = rotate(k_pe[:, None])[:, 0]
     with jax.named_scope("absorb"):
         row = jnp.concatenate([c, k_pe], axis=1)           # [c | k_pe]
-        q_lat = dot("bhd,rhd->bhr", q[..., :d], up[..., :d])
+        q_lat = dot("bhd,hrd->bhr", q[..., :d], laid[0]) if laid \
+            else dot("bhd,rhd->bhr", q[..., :d], up[..., :d])
         q_pe = q[..., d:]
     if rotate is not None:
         with jax.named_scope("rope"):
@@ -246,7 +282,8 @@ def latent_mixer(cfg, p, l, h, attend, rotate=None):
         q = jnp.concatenate([q_lat, q_pe], axis=2)         # [q_lat | q_pe]
     o_lat = attend(l, q, row, None)                        # [B, heads, rank]
     with jax.named_scope("out"):
-        o = dot("bhr,rhd->bhd", o_lat, up[..., d:])
+        o = dot("bhr,hdr->bhd", o_lat, laid[1]) if laid \
+            else dot("bhr,rhd->bhd", o_lat, up[..., d:])
         return _mm(o.reshape(bb, heads * d), p("wo"))
 
 

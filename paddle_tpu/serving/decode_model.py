@@ -97,7 +97,7 @@ from . import kv_cache as _kv
 
 __all__ = ["DecoderConfig", "init_decoder_params", "save_decoder",
            "load_decoder", "is_decoder_dir", "has_draft", "load_draft",
-           "truncate_decoder", "attention_path", "experts_path",
+           "truncate_decoder", "laid_out", "attention_path", "experts_path",
            "state_update_path", "state_update_columns", "experts_chunk",
            "make_paged_step",
            "make_fed_step", "make_paged_step_multi",
@@ -627,6 +627,22 @@ def truncate_decoder(cfg, params, layers=1):
 
 
 # -- shared forward ----------------------------------------------------------
+
+def laid_out(cfg, params):
+    """``params`` (published names and shapes: ``init_params``, a bundle,
+    a benchmark's ``make_params``) as a decode step holds them: every value
+    a device array, and where the family's module declares a layout
+    (``laid_out(cfg, params)``: ``kimi_linear``, ``dots_vlm``) the weights
+    it names in the form its block multiplies, laid out here once so that
+    no step lays them out again.  A new dict; a family that declares none
+    gets its own arrays back.  Whatever builds a step that is served or
+    timed hands it these (the engine for the model and a draft,
+    ``unpaged_generate``, ``tools/decode_step_probe.py``); a block handed
+    the published arrays computes the same."""
+    params = {key: jnp.asarray(v) for key, v in params.items()}
+    family = getattr(_model(cfg.arch), "laid_out", None)
+    return family(cfg, params) if family else params
+
 
 def _block(cfg):
     """The architecture's block: ``block(params, cfg, tok, pos, attend,
@@ -1265,7 +1281,7 @@ def unpaged_generate(cfg, params, prompt_ids, max_new, pad_len=None,
         pad_len = cfg.max_seq
     step = jax.jit(make_unpaged_step(cfg, pad_len, ring_len),
                    donate_argnums=(0,))
-    jparams = {k: jnp.asarray(v) for k, v in params.items()}
+    jparams = laid_out(cfg, params)
     kv = _unpaged_carry(cfg, 1, pad_len, ring_len)
     prompt_ids = [int(t) for t in prompt_ids]
     out, logits_hist = [], []

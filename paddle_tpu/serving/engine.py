@@ -1186,7 +1186,6 @@ class DecodeEngine:
         step fns replace the single-token one — verify ([B, k+1] target),
         rollout (k chained draft proposals), ingest (draft catch-up)."""
         import jax
-        import jax.numpy as jnp
 
         from . import decode_model as _dm
         from . import kv_cache as _kvc
@@ -1261,7 +1260,10 @@ class DecodeEngine:
         # hit would start a sequence at pos > 0 over shared K/V blocks,
         # where the recurrent layers' state at that position is nowhere,
         # and the window layers' K and V before it in no block
-        jparams = {key: jnp.asarray(v) for key, v in params.items()}
+        jparams = _dm.laid_out(cfg, params)
+        # the weights held in the family's own layout and not as published
+        # (the same bytes: ``resident`` stands)
+        laid = {key: v for key, v in jparams.items() if key not in params}
         # of the layers that page a history on the global tables: attention
         # layers, or a latent model's latent layers
         latent = bool(cfg.latent_layers)
@@ -1283,6 +1285,9 @@ class DecodeEngine:
             paths["experts"] = sorted(experts_path.items())
         if state_path:
             paths["state_update"] = sorted(state_path.items())
+        if laid:
+            # (the argument shapes tell the two forms of a weight apart too)
+            paths["weights_laid_out"] = sorted(laid)
         stepfn = CarriedStepFn(
             # make_paged_step's step with the token feed on the device and
             # the lanes' integers in one array: still one executable an
@@ -1343,6 +1348,8 @@ class DecodeEngine:
             _tm.set_gauge("latent_pool_bytes", kv_config.latent_layers
                           * _kvc.latent_block_bytes(kv_config) * n,
                           model=name)
+        _tm.set_gauge("decode_weights_laid_out_bytes",
+                      sum(int(v.nbytes) for v in laid.values()), model=name)
         if k > 0:
             # draft pool mirrors the target's block COUNT (draft blocks
             # are strictly smaller at fewer layers), so any sequence the
@@ -1359,8 +1366,7 @@ class DecodeEngine:
                                               max(self.buckets))])
             entry.spec_k = k
             entry.draft_cfg = dcfg
-            entry.draft_params = {key: jnp.asarray(v)
-                                  for key, v in dparams.items()}
+            entry.draft_params = _dm.laid_out(dcfg, dparams)
             entry.draft_kv_config = draft_kv
             entry.draft_cache = _kvc.PagedKVCache(draft_kv)
             entry.verifyfn = CarriedStepFn(

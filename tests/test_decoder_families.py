@@ -7,12 +7,17 @@ empty ring; what a family that keeps more than K and V declines (prefix
 reuse, speculation, export and adoption), each under its reason, and what a
 family whose every layer pages (K and V, or a latent pool's rows) is given
 of them; the bundle
-round trip; and ``tools/serve.py``'s demo bundle served at the defaults.  A
+round trip; ``tools/serve.py``'s demo bundle served at the defaults; and the
+weights as a step holds them (``decode_model.laid_out``): the published
+arrays' own logits, no relayout between a laid-out weight and its product,
+nothing moved for a family that declares no layout, and an engine that
+leaves its caller's arrays alone.  A
 family's own file (``tests/test_<family>.py``) holds what is its alone."""
 
 import json
 import threading
 
+import jax
 import numpy as np
 import pytest
 
@@ -469,6 +474,117 @@ def test_a_block_is_exported_and_adopted_with_every_layers_rows(
                 a.nbytes for a in block)
         finally:
             other.stop()
+
+
+# the families that declare a layout of their own for a step's weights
+laying = fam.cases(lambda row: hasattr(dm._model(row.arch), "laid_out"))
+
+
+@pytest.mark.parametrize("width", [1, 2])
+@pytest.mark.parametrize("row,key", laying)
+def test_laid_out_weights_give_the_published_weights_logits(row, key, width):
+    """The single-token and the multi-token step over ``laid_out(cfg,
+    params)`` against the same steps over the published ``params``: the
+    same tokens and, bit for bit, the same logits (on the CPU both forms of
+    each ``wkvb`` product contract a head's values in the same order)."""
+    cfg, params = row.configs[key]
+    held = dm.laid_out(cfg, params)
+    assert sorted(set(held) - set(params)) == sorted(
+        "l%d_wkvb_%s" % (l, half)
+        for l in cfg.latent_layers for half in "kv")
+    assert sorted(set(params) - set(held)) == [
+        "l%d_wkvb" % l for l in cfg.latent_layers]
+    n = 0 if cfg.recurrent_layers and width > 1 else 4
+    seqs = [(list(range(3, 15)), n), (list(range(20, 26)), n)]
+    published, _ = fam.run_paged(cfg, params, seqs, width=width)
+    laid, _ = fam.run_paged(cfg, held, seqs, width=width)
+    for (t0, l0), (t1, l1) in zip(published, laid):
+        assert t0 == t1 and len(set(t0)) > 2
+        assert np.array_equal(l0, l1)
+
+
+def _readers(jaxpr, var):
+    """The primitives that read ``var`` in ``jaxpr``, looked for inside the
+    calls it is handed to (``jnp.einsum`` is one)."""
+    found = []
+    for eqn in jaxpr.eqns:
+        for at, operand in enumerate(eqn.invars):
+            if operand is not var:
+                continue
+            inner = eqn.params.get("jaxpr")
+            if inner is None:
+                found.append(eqn.primitive.name)
+            else:
+                inner = getattr(inner, "jaxpr", inner)
+                found += _readers(inner, inner.invars[at])
+    return found
+
+
+@pytest.mark.parametrize("row,key", laying)
+def test_a_laid_out_weight_is_read_by_its_product_and_nothing_else(row, key):
+    """In the traced step (the jaxpr: no backend's choice) each half of a
+    laid-out ``wkvb`` is an operand of its ``dot_general`` and of nothing
+    before it: no transpose, no reshape and slice, no copy.  The published
+    array, by contrast, is reshaped first."""
+    cfg, params = row.configs[key]
+    kv = dm.cache_config(cfg, BS, 40, state_slots=6)
+    _columns, ncols = dm.lane_columns(kv, cfg.max_seq // BS)
+    shaped = lambda tree: jax.tree_util.tree_map(
+        lambda a: jax.ShapeDtypeStruct(a.shape, a.dtype), tree)
+    carry = shaped(fam.kvc.PagedKVCache(kv).carry())
+    feeds = (jax.ShapeDtypeStruct((4,), np.int32),
+             jax.ShapeDtypeStruct((4, ncols), np.int32))
+
+    def readers(weights):
+        weights = shaped(weights)
+        traced = jax.make_jaxpr(dm.make_packed_step(cfg, kv, 4))(
+            carry, weights, *feeds).jaxpr
+        names = sorted(weights)           # a dict flattens by its keys
+        at = len(jax.tree_util.tree_leaves(carry))
+        return {name: _readers(traced, traced.invars[at + i])
+                for i, name in enumerate(names) if "_wkvb" in name}
+
+    laid = readers(dm.laid_out(cfg, params))
+    assert len(laid) == 2 * len(cfg.latent_layers)
+    assert all(read == ["dot_general"] for read in laid.values()), laid
+    published = readers(fam.as_jnp(params))
+    assert len(published) == len(cfg.latent_layers)
+    assert all(read == ["reshape"] for read in published.values()), published
+
+
+@pytest.mark.parametrize("row,key", fam.cases())
+def test_the_engine_lays_out_a_copy_and_counts_it(row, key, cache_dir,
+                                                  telemetry_on):
+    """``laid_out`` hands a family that declares no layout its own arrays
+    back, every one; for one that does, the arrays it does not name.
+    ``add_model`` leaves the caller's dict and its arrays as they were, the
+    bytes it plans with are the published ones (the two forms weigh the
+    same), ``decode_weights_laid_out_bytes{model}`` says what the step
+    holds in the family's own layout (nothing, or every latent layer's
+    ``wkvb``) and the step's cache key names those weights."""
+    cfg, params = row.configs[key]
+    mine = fam.as_jnp(params)
+    held = dm.laid_out(cfg, mine)
+    assert all(held[name] is mine[name] for name in held if name in mine)
+    own = list(cfg.latent_layers)
+    if not hasattr(dm._model(row.arch), "laid_out"):
+        assert not own and sorted(held) == sorted(mine)
+    before = dict(mine)
+    e = fam.engine(cfg, mine, 40, start=False)
+    assert sorted(mine) == sorted(before) \
+        and all(mine[name] is before[name] for name in before)
+    entry = e._models["m"]
+    published = sum(int(v.nbytes) for v in mine.values())
+    assert sum(int(v.nbytes) for v in entry.params.values()) == published \
+        == fam.kvc._LIVE_RESIDENT[entry]
+    laid = sorted("l%d_wkvb_%s" % (l, half) for l in own for half in "kv")
+    assert sorted(set(entry.params) - set(mine)) == laid
+    # the step's cache key names them, and is as it was where there are none
+    assert entry.stepfn._key_parts.get("weights_laid_out", []) == laid \
+        and ("weights_laid_out" in entry.stepfn._key_parts) == bool(laid)
+    assert _tm.snapshot()["gauges"][
+        "decode_weights_laid_out_bytes{model=m}"] == sum(
+            int(mine["l%d_wkvb" % l].nbytes) for l in own)
 
 
 @pytest.mark.parametrize("row,key", fam.cases())

@@ -78,11 +78,104 @@ def _packed_feeds(kv, lanes, maxb):
                 (lanes, dm.lane_columns(kv, maxb)[1]), jnp.int32)]
 
 
+def _as_held(cfg, shapes):
+    """A bfloat16 model's published ``param_shapes`` as a step holds them
+    (``decode_model.laid_out``), shapes alone."""
+    return jax.eval_shape(lambda p: dm.laid_out(cfg, p), {
+        name: jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+        for name, (shape, _kind) in shapes.items()})
+
+
+def _weights_relaid(text):
+    """The step's own copies of its weights into another layout
+    (``tools/decode_step_probe.py`` ``parameter_fed_copies``)."""
+    import decoder_families as fam
+
+    return fam.load("tools", "decode_step_probe.py").parameter_fed_copies(
+        text)
+
+
 def _placed(sharding, tree):
     """The tree's shapes, placed on the described chip."""
     return jax.tree_util.tree_map(
         lambda x: jax.ShapeDtypeStruct(x.shape, x.dtype, sharding=sharding),
         tree)
+
+
+_MODULE = """HloModule m, is_scheduled=true
+
+%%fused_computation.1 (param_0: bf16[8,4]) -> bf16[4,8] {
+  %%param_0 = bf16[8,4]{1,0} parameter(0)
+  ROOT %%transpose.1 = bf16[4,8]{1,0} transpose(%%param_0), dimensions={1,0}
+}
+
+%%fused_computation.2 (param_0.1: bf16[8,4], param_1: f32[2,8]) -> f32[2,4] {
+  %%param_0.1 = bf16[8,4]{1,0} parameter(0)
+  %%param_1 = f32[2,8]{1,0} parameter(1)
+  ROOT %%convolution.1 = f32[2,4]{1,0} convolution(%%param_1, %%param_0.1), dim_labels=bf_io->bf
+}
+
+%%async_computation.1 (param_0.2: bf16[8,4]) -> bf16[8,4] {
+  %%param_0.2 = bf16[8,4]{1,0} parameter(0)
+  ROOT %%slice.1 = bf16[8,4]{1,0:S(1)} slice(%%param_0.2), slice={[0:8], [0:4]}
+}
+
+ENTRY %%main.1 (kv_carry_0_.1: bf16[8,4], params__l3_wkvb__.1: bf16[8,4], x.1: f32[2,8]) -> f32[2,4] {
+  %%kv_carry_0_.1 = bf16[8,4]{1,0} parameter(0)
+  %%params__l3_wkvb__.1 = bf16[8,4]{1,0} parameter(1), metadata={op_name="params[\\'l3_wkvb\\']"}
+  %%x.1 = f32[2,8]{1,0} parameter(2)
+  %%copy.9 = bf16[8,4]{0,1} copy(%%kv_carry_0_.1)
+%s
+}
+"""
+_READS = {
+    # the weight itself, copied into another layout
+    "a_copy": ('  %copy.1 = bf16[8,4]{0,1:T(8,128)(2,1)S(1)} copy('
+               '%params__l3_wkvb__.1), metadata={op_name="params"}',
+               [["copy.1", "l3_wkvb", 64]]),
+    # fetched ahead in two slices, put together, then turned (Kimi-Linear's)
+    "a_copy_of_what_was_fetched": (
+        "  %slice-start.1 = ((bf16[8,4]{1,0}), bf16[4,4]{1,0:S(1)}, s32[]) "
+        "slice-start(%params__l3_wkvb__.1), slice={[0:4], [0:4]}\n"
+        "  %slice-start.2 = ((bf16[8,4]{1,0}), bf16[4,4]{1,0:S(1)}, s32[]) "
+        "slice-start(%params__l3_wkvb__.1), slice={[4:8], [0:4]}\n"
+        "  %slice-done.1 = bf16[4,4]{1,0:S(1)} slice-done(%slice-start.1)\n"
+        "  %slice-done.2 = bf16[4,4]{1,0:S(1)} slice-done(%slice-start.2)\n"
+        "  %custom-call.1 = bf16[8,4]{1,0:S(1)} custom-call(%slice-done.1, "
+        '%slice-done.2), custom_call_target="ConcatBitcast"\n'
+        "  %copy.2 = bf16[8,4]{0,1:S(1)} copy(%custom-call.1)",
+        [["copy.2", "l3_wkvb", 64]]),
+    # the same as a module restored from the compile cache prints it
+    "a_copy_of_what_an_async_slice_fetched": (
+        "  %slice-start.3 = ((bf16[8,4]{1,0}), bf16[8,4]{1,0:S(1)}, s32[]) "
+        "async-start(%params__l3_wkvb__.1), calls=%async_computation.1\n"
+        "  %slice-done.3 = bf16[8,4]{1,0:S(1)} async-done(%slice-start.3)\n"
+        "  %copy.3 = bf16[8,4]{0,1:S(1)} copy(%slice-done.3)",
+        [["copy.3", "l3_wkvb", 64]]),
+    # a fusion that only turns it
+    "a_fusion_of_a_transpose": (
+        "  %fusion.1 = bf16[4,8]{1,0} fusion(%params__l3_wkvb__.1), "
+        "kind=kLoop, calls=%fused_computation.1",
+        [["fusion.1", "l3_wkvb", 64]]),
+    # fetched ahead and read by its product: no copy
+    "a_fetch_ahead_of_the_product": (
+        "  %copy-start.1 = (bf16[8,4]{1,0:S(1)}, bf16[8,4]{1,0}, u32[]) "
+        "copy-start(%params__l3_wkvb__.1)\n"
+        "  %copy-done.1 = bf16[8,4]{1,0:S(1)} copy-done(%copy-start.1)\n"
+        "  ROOT %fusion.2 = f32[2,4]{1,0} fusion(%copy-done.1, %x.1), "
+        "kind=kOutput, calls=%fused_computation.2", []),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_READS))
+def test_the_probe_lists_a_weight_the_step_copies_and_no_fetch(case):
+    """``parameter_fed_copies`` over a module's text: a weight the entry
+    computation copies, transposes or hands to a fusion of nothing else,
+    itself or through what only moved it, is listed with its bytes; a fetch
+    ahead of the product that reads it is not, nor a copy of the cache's
+    carry (no weight)."""
+    body, want = _READS[case]
+    assert _weights_relaid(_MODULE % body) == want
 
 
 @pytest.mark.parametrize("kind", ["step", "multi"])
@@ -593,10 +686,7 @@ def test_kimi_linear_step_compiles_its_three_kernels_at_published_shapes(
     on_chip = functools.partial(_placed, one_chip)
 
     carry = on_chip(jax.eval_shape(lambda: PagedKVCache(kv).carry()))
-    params = on_chip({
-        name: jax.ShapeDtypeStruct(shape, jnp.bfloat16)
-        for name, (shape, _kind)
-        in kimi_linear_decoder.param_shapes(config).items()})
+    params = on_chip(_as_held(cfg, kimi_linear_decoder.param_shapes(config)))
     feeds = on_chip(_packed_feeds(kv, lanes, cfg.max_seq // block_size))
     compiled = jax.jit(dm.make_packed_step(cfg, kv, lanes),
                        donate_argnums=(0,)
@@ -609,6 +699,12 @@ def test_kimi_linear_step_compiles_its_three_kernels_at_published_shapes(
     assert _expert_kernels(text) == 4
     assert not re.findall(r"%(ssm_state_update|paged_attention)\S* = ", text)
     assert not _expert_passes(text, 16, 2304, 1024)
+    # the latent layer's up-projection was laid out at load: no weight is
+    # copied into another layout by the step (the published ``wkvb`` was
+    # fetched into VMEM and turned there, a layer a step)
+    assert sorted(n for n in params if "wkvb" in n) == ["l3_wkvb_k",
+                                                        "l3_wkvb_v"]
+    assert _weights_relaid(text) == []
     pool_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in carry)
     state_pool = (lanes + 1) * 128 * 4096 * 4
     assert pool_bytes == 12832 * 16 * 640 * 2 + 4 * (
@@ -666,10 +762,7 @@ def test_dots_vlm_step_compiles_its_two_kernels_at_published_shapes(
 
     on_chip = functools.partial(_placed, one_chip)
     carry = on_chip(jax.eval_shape(lambda: PagedKVCache(kv).carry()))
-    params = on_chip({
-        name: jax.ShapeDtypeStruct(shape, jnp.bfloat16)
-        for name, (shape, _kind)
-        in dots_vlm_decoder.param_shapes(config).items()})
+    params = on_chip(_as_held(cfg, dots_vlm_decoder.param_shapes(config)))
     feeds = on_chip(_packed_feeds(kv, lanes, cfg.max_seq // block_size))
     compiled = jax.jit(dm.make_packed_step(cfg, kv, lanes),
                        donate_argnums=(0,)
@@ -680,6 +773,12 @@ def test_dots_vlm_step_compiles_its_two_kernels_at_published_shapes(
     assert len(re.findall(r"%latent_attention\S* = ", text)) == 3
     assert _expert_kernels(text) == 2
     assert not _expert_passes(text, 16, 7168, 2048)
+    # every layer's up-projection was laid out at load: no weight is copied
+    # into another layout by the step (the published ``wkvb`` was, 33.5e6 B
+    # a layer a step: PERF.md section 6, PR 52)
+    assert params["l0_wkvb_k"].shape == (128, 512, 128) \
+        and params["l0_wkvb_v"].shape == (128, 128, 512)
+    assert _weights_relaid(text) == []
     pool_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in carry)
     assert pool_bytes == 3 * 12832 * 16 * 640 * 2
     memory = compiled.memory_analysis()

@@ -9,6 +9,11 @@ writes under ``chiprun_out/``, one JSON object:
   pool's bytes, and the pool-sized instructions left in its HLO; ``attention``
   and ``pallas_kernel_counters``: the path the step's attention took, as the
   rule names it and as each layer's lowering counted it;
+* ``parameter_fed_copies``: ``[instruction, parameter, bytes]`` for every
+  weight the compiled step copies into another layout each time it runs.
+  The step is built from the weights as the engine holds them
+  (``decode_model.laid_out``), so a family that lays a weight out at load
+  shows none for it; a name here is a layout to choose at load;
 * ``step_ms``: host clock over steps that end in ``block_until_ready``;
 * ``scope_ms_per_step``: device time per step by ``jax.named_scope``
   (``layer<i>`` folded to ``layerN``), from a profile of ``--steps`` steps
@@ -118,19 +123,22 @@ WARM_STEPS = 5
 EXPERT_FORMS = ("block", "dense", "kernel")
 
 
+# an instruction of a compiled module's text: its name, its result (one shape,
+# or a kernel's or an async start's tuple of them), its opcode, and the rest
+_NAMED = re.compile(r"\s*(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) "
+                    r"([\w\-]+)\((.*)")
+
+
 def hlo_index(text):
     """instruction name -> (opcode, shape, op_name scope) of a compiled
     module's entry computation and fusions."""
     out = {}
     lines = text.splitlines()
-    named = re.compile(r"\s*(?:ROOT )?%?([\w.\-]+) = (\(.*?\)|\S+) "
-                       r"([\w\-]+)\(")
     starts = {m.group(1): line for line in lines
-              for m in [named.match(line)]
+              for m in [_NAMED.match(line)]
               if m and m.group(3) in ("copy-start", "slice-start")}
     for line in lines:
-        # a result is one shape, or (a kernel's) a tuple of them
-        m = named.match(line)
+        m = _NAMED.match(line)
         if not m:
             continue
         scope = re.search(r'op_name="([^"]*)"', line)
@@ -150,6 +158,74 @@ def hlo_index(text):
                      else "") + "staged"
         out[m.group(1)] = (m.group(3), m.group(2), scope)
     return out
+
+
+# opcodes that move a value and compute nothing: a fetch ahead of its use (the
+# -start and -done of a slice or a copy, which a module restored from the
+# compile cache prints as ``async-start`` / ``async-done`` of a computation
+# that holds the slice), a view, the pieces put together again
+_MOVES = ("slice-start", "slice-done", "copy-start", "copy-done",
+          "async-start", "async-done", "bitcast", "get-tuple-element",
+          "ConcatBitcast")
+# ... and those that lay it out anew
+_RELAYS = ("copy", "transpose")
+_ITEMSIZE = {"f32": 4, "s32": 4, "u32": 4, "bf16": 2, "f16": 2}
+
+
+def parameter_fed_copies(text):
+    """What the compiled step copies into another layout of its own
+    parameters (``params[...]``, the weights: constants, so each is work a
+    layout chosen at load would save) -> [[instruction, parameter, bytes of
+    the result], ...] over the entry computation: a ``copy`` or a
+    ``transpose``, or a fusion of nothing else, whose operand is the
+    parameter or what only moved it (``_MOVES``).  A fetch ahead of a
+    product is no copy in this sense: the product reads what it fetched."""
+    bodies, entry, inside = {}, {}, None
+    for line in text.splitlines():
+        head = re.match(r"(ENTRY )?%?([\w.\-]+) \(.*\{$", line)
+        if head:
+            inside = entry if head.group(1) else bodies.setdefault(
+                head.group(2), {})
+            continue
+        m = _NAMED.match(line)
+        if m and inside is not None:
+            name, shape, op, rest = m.groups()
+            target = re.search(r'custom_call_target="(\w+)"', rest)
+            calls = re.search(r"calls=%?([\w.\-]+)", rest)
+            inside[name] = (
+                target.group(1) if target else op, shape,
+                re.findall(r"%([\w.\-]+)", rest.split("), ")[0]),
+                calls.group(1) if calls else None)
+
+    def relays(name):
+        op, _shape, _operands, calls = entry[name]
+        if op == "fusion":
+            inner = {o for o, *_ in bodies.get(calls, {}).values()}
+            return bool(inner & set(_RELAYS)) and inner <= set(
+                _RELAYS + ("parameter", "bitcast"))
+        return op in _RELAYS
+
+    def parameter(name):
+        """The step parameter ``name`` is, or only moves."""
+        op, _shape, operands, calls = entry.get(name, ("", "", [], None))
+        if op == "parameter":
+            return name if name.startswith("params__") else None
+        inner = {o for o, *_ in bodies.get(calls, {}).values()}
+        if op in _MOVES and inner <= {"parameter", "slice", "copy"}:
+            return next(filter(None, map(parameter, operands)), None)
+        return None
+
+    found = []
+    for name, (_op, shape, operands, _calls) in entry.items():
+        source = relays(name) and next(
+            filter(None, map(parameter, operands)), None)
+        if source:
+            dtype, dims = re.match(r"\(?(\w+)\[([\d,]*)\]", shape).groups()
+            found.append([
+                name, re.sub(r"^params__|__(\.\d+)?$", "", source),
+                _ITEMSIZE.get(dtype, 1) * math.prod(
+                    map(int, filter(None, dims.split(","))))])
+    return found
 
 
 def scope_of(op_name):
@@ -342,6 +418,9 @@ def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
                    "state_bytes": kvc.state_bytes(kv),
                    "compile_ms": round(warm["compile_ms"], 1),
                    "pool_sized_instructions": pool_sized(index, pool_elems)},
+        # the weights the step lays out anew every time it runs (a family's
+        # ``laid_out`` is there to empty this list)
+        "parameter_fed_copies": parameter_fed_copies(text),
         "attention": dm.attention_path(
             cfg, kv, b, "latent" if cfg.latent_layers else "attention"),
         # the columns of a slot one transfer of the state-update kernel
@@ -643,7 +722,8 @@ def main(argv=None):
         return 0 if result["passed"] \
             and result.get("window", result)["passed"] else 1
 
-    params = model.make_params(config, args.seed, device)
+    # as the engine holds them: what is timed here is what a cell serves
+    params = dm.laid_out(cfg, model.make_params(config, args.seed, device))
     # count which path each layer's lowering takes
     fluid.set_flags({"FLAGS_telemetry": True})
     slots = (np.arange(1, b + 1, dtype=np.int32),) \
