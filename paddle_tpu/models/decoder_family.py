@@ -23,9 +23,9 @@ class DecoderFamily(collections.namedtuple(
         "DecoderFamily",
         ("kinds", "dtypes", "grouped_query", "routes", "expert_matrices",
          "dense_lead", "holds_share", "own_stream_width", "grouped_router",
-         "rotated_latent"),
+         "rotated_latent", "shared_expert", "expert_gate"),
         defaults=(("f32", "bf16"), False, None, None, False, False, False,
-                  False, False))):
+                  False, False, False, "silu"))):
     """``kinds``: the kinds of layer the block computes
     (``decode_model.LAYER_KINDS``: seven of them, of which a family names
     one to three).  ``dtypes``: the weight dtypes it is
@@ -44,6 +44,9 @@ class DecoderFamily(collections.namedtuple(
     chooses experts (``exaone_moe.routed_part``).  ``rotated_latent``: its
     latent layers rotate the row's shared key and the query's last
     ``cfg.latent_rope`` values by position (``cfg.rope_scaling``: YaRN) and
-    may compress the query (``cfg.q_rank``)."""
+    may compress the query (``cfg.q_rank``).  ``shared_expert``: beside its
+    routed experts every token passes through a shared one of width
+    ``cfg.shared_ffn``.  ``expert_gate``: the activation of a three-matrix
+    expert's gate, as ``moe_experts.GATES`` names it."""
 
     __slots__ = ()

@@ -70,7 +70,7 @@ __all__ = ["token_logits", "param_shapes", "init_params", "laid_out",
 FAMILY = DecoderFamily(kinds=("latent",), routes="after_dense",
                        expert_matrices=3, dense_lead=True, holds_share=True,
                        own_stream_width=True, grouped_router=True,
-                       rotated_latent=True)
+                       rotated_latent=True, shared_expert=True)
 
 # standard deviation of a seeded ``expert_bias``.  The stream is pre-norm:
 # the router sees rmsnorm(x), its logits (weights normal(0, 0.02) over

@@ -82,7 +82,8 @@ __all__ = ["token_logits", "param_shapes", "init_params", "routed_part",
 FAMILY = DecoderFamily(kinds=("attention", "window"), grouped_query=True,
                        routes="after_dense", expert_matrices=3,
                        dense_lead=True, holds_share=True,
-                       own_stream_width=True, grouped_router=True)
+                       own_stream_width=True, grouped_router=True,
+                       shared_expert=True)
 
 # the least the renormalised gates' denominator can be (the DeepSeek-V3
 # router adds it to the sum of the chosen scores)

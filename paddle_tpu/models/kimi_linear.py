@@ -85,7 +85,8 @@ __all__ = ["token_logits", "param_shapes", "init_params", "laid_out",
 
 FAMILY = DecoderFamily(kinds=("kda", "latent"), routes="after_dense",
                        expert_matrices=3, dense_lead=True, holds_share=True,
-                       own_stream_width=True, grouped_router=True)
+                       own_stream_width=True, grouped_router=True,
+                       shared_expert=True)
 
 # what the L2 norm of a KDA head's q and k adds under the root
 L2_EPS = 1e-6

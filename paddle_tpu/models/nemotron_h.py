@@ -81,7 +81,7 @@ __all__ = ["token_logits", "param_shapes", "init_params", "routed_part",
 FAMILY = DecoderFamily(kinds=("attention", "mamba", "experts"),
                        grouped_query=True, routes="experts_layers",
                        expert_matrices=2, holds_share=True,
-                       own_stream_width=True)
+                       own_stream_width=True, shared_expert=True)
 
 # standard deviation of a seeded ``expert_bias``.  This stream is pre-norm:
 # the router sees rmsnorm(x), entries of root-mean-square 1, so its logits

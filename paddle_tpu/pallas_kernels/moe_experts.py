@@ -7,8 +7,11 @@ A routed layer holds ``E`` experts as three tensors, ``wgate`` and ``wup``
 [B, E]`` float32: lane ``b``'s weight on each of its chosen experts, an exact
 zero on every other.  The layer's output is ::
 
-    y[b] = sum_e gates[b, e] * ((silu(x[b] @ wgate_e) * (x[b] @ wup_e)) @ wdown_e)
+    y[b] = sum_e gates[b, e] * ((act(x[b] @ wgate_e) * (x[b] @ wup_e)) @ wdown_e)
 
+``act`` the gate's activation, which the family declares (``GATES``:
+``"silu"`` unless its ``FAMILY.expert_gate`` says ``"relu"``; a static
+argument of the call and never a flag),
 with ``x`` rounded to the weights' dtype, every matmul accumulating in
 float32, the activation rounded to the weights' dtype before the down
 projection and the gates applied in float32.  No capacity: every (token,
@@ -47,6 +50,8 @@ and the same fallback (``relu2_reference``); the square is taken per chunk,
 which is exact because a chunk holds whole columns of ``x @ up^T``.
 """
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -58,13 +63,20 @@ from . import adoption
 __all__ = ["routed_experts", "experts_reference", "moe_experts_checks",
            "experts_path", "hit_order", "f_chunk", "KERNEL_NAME",
            "relu2_experts", "relu2_reference", "relu2_checks", "f_rows",
-           "RELU2_KERNEL_NAME"]
+           "RELU2_KERNEL_NAME", "GATES"]
 
 # the names the kernels' executions carry in a device trace
 KERNEL_NAME = "moe_routed_experts"
 RELU2_KERNEL_NAME = "moe_relu2_experts"
 
 _SUBLANES = {"float32": 8, "bfloat16": 16}   # rows of a dtype's memory tile
+
+# the activations a three-matrix expert's gate may have, by the name a family
+# states: the kernel, the einsums and the reference take the same one
+# (relu as a select on the sign: XLA:CPU (jaxlib 0.9.0) fuses a bare max(dot,
+# 0) into the batched dot's epilogue and then has no bfloat16 thunk for it,
+# "Unsupported element type for DotThunk"; the same values)
+GATES = {"silu": jax.nn.silu, "relu": lambda g: jnp.where(g > 0, g, 0.0)}
 
 # what the three weight blocks of a grid step may take in VMEM, the next
 # step's beside them (double buffered), and the limit the kernel asks
@@ -74,13 +86,14 @@ _BLOCK_BUDGET = 26 << 20
 _VMEM_LIMIT = 40 << 20
 
 
-def experts_reference(h2, gates, wgate, wup, wdown):
+def experts_reference(h2, gates, wgate, wup, wdown, gate="silu"):
     """sum_e gates[b, e] * expert_e(h2[b]): all experts over all lanes,
-    the unchosen weighted zero."""
+    the unchosen weighted zero.  ``gate`` names the gate's activation
+    (``GATES``)."""
     hx = h2.astype(wgate.dtype)
     up = lambda w: jnp.einsum("bh,ehf->ebf", hx, w,
                               preferred_element_type=jnp.float32)
-    act = jax.nn.silu(up(wgate)) * up(wup)
+    act = GATES[gate](up(wgate)) * up(wup)
     y = jnp.einsum("ebf,efh->ebh", act.astype(wdown.dtype), wdown,
                    preferred_element_type=jnp.float32)
     return jnp.sum(y * gates.T[:, :, None], axis=0)
@@ -162,7 +175,7 @@ def experts_path(rows, w_shape, w_dtype, matrices=3):
 
 
 def _kernel(order_ref, n_ref, x_ref, gates_ref, wgate_ref, wup_ref,
-            wdown_ref, out_ref):
+            wdown_ref, out_ref, gate="silu"):
     i, j = pl.program_id(0), pl.program_id(1)
 
     @pl.when((i == 0) & (j == 0))
@@ -174,21 +187,21 @@ def _kernel(order_ref, n_ref, x_ref, gates_ref, wgate_ref, wup_ref,
         x = x_ref[...]
         up = lambda w_ref: jnp.dot(x, w_ref[...],
                                    preferred_element_type=jnp.float32)
-        act = jax.nn.silu(up(wgate_ref)) * up(wup_ref)       # [rows, fc]
+        act = GATES[gate](up(wgate_ref)) * up(wup_ref)       # [rows, fc]
         y = jnp.dot(act.astype(wdown_ref.dtype), wdown_ref[...],
                     preferred_element_type=jnp.float32)      # [rows, H]
         # this expert's column of the gates, a value a row
         col = jax.lax.broadcasted_iota(jnp.int32, gates_ref.shape, 1)
-        gate = jnp.sum(jnp.where(col == order_ref[i], gates_ref[...], 0.0),
-                       axis=1, keepdims=True)
-        out_ref[...] += gate * y
+        weight = jnp.sum(jnp.where(col == order_ref[i], gates_ref[...], 0.0),
+                         axis=1, keepdims=True)
+        out_ref[...] += weight * y
 
 
 def _experts_pallas(h2, gates, live, wgate, wup, wdown, fc=None,
-                    interpret=None):
+                    interpret=None, gate="silu"):
     """``routed_experts`` on the kernel: an idle lane's gates count as
     zeros.  ``fc`` None follows ``f_chunk``; ``interpret`` None follows the
-    backend."""
+    backend; ``gate`` names the gate's activation (``GATES``)."""
     b, hidden = h2.shape
     e, _h, ffn = wgate.shape
     if fc is None:
@@ -212,7 +225,9 @@ def _experts_pallas(h2, gates, live, wgate, wup, wdown, fc=None,
     columns = pl.BlockSpec((None, hidden, fc), lambda i, j, order, n:
                            (order[i], 0, chunk(i, j, n)))
     out = pl.pallas_call(
-        _kernel,
+        # (the default gate as the bare function: the call every SiLU
+        # family lowered to before a gate could be named)
+        _kernel if gate == "silu" else functools.partial(_kernel, gate=gate),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(e, nj),
@@ -233,10 +248,11 @@ def _experts_pallas(h2, gates, live, wgate, wup, wdown, fc=None,
     return out[:b]
 
 
-def routed_experts(h2, gates, live, wgate, wup, wdown):
+def routed_experts(h2, gates, live, wgate, wup, wdown, gate="silu"):
     """A routed layer's experts for the lanes of a step: h2 [B, H] float32,
     gates [B, E] float32 (zero off a lane's chosen experts), live [B] bool
-    -> [B, H] float32.  The kernel where the shape rule admits it
+    -> [B, H] float32; ``gate`` (static) names the activation of an expert's
+    gate, ``"silu"`` | ``"relu"``.  The kernel where the shape rule admits it
     (``adoption.decide`` counts the lowering under
     ``pallas_kernel_used_total`` / ``..._fallback_total{reason}``): it reads
     the experts some live lane chose and returns zeros for an idle lane.
@@ -245,8 +261,8 @@ def routed_experts(h2, gates, live, wgate, wup, wdown):
         "moe_experts",
         moe_experts_checks(h2.shape[0], wgate.shape, wgate.dtype))
     if use:
-        return _experts_pallas(h2, gates, live, wgate, wup, wdown)
-    return experts_reference(h2, gates, wgate, wup, wdown)
+        return _experts_pallas(h2, gates, live, wgate, wup, wdown, gate=gate)
+    return experts_reference(h2, gates, wgate, wup, wdown, gate)
 
 
 # -- the two-matrix form: relu(x @ up^T)^2 @ down ----------------------------

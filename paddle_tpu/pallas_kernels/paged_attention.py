@@ -56,11 +56,22 @@ only, and its table is a *ring*: ``R`` slots a lane, position ``p`` in slot
 sequence holds ``R`` blocks there whatever its length.  Entry ``j`` of the
 ring's ``R * block_size`` rows then holds the newest position congruent to
 ``j``, ``age = (context_len - 1 - j) mod (R * block_size)`` tokens back, and
-is attended iff ``age < min(window, context_len)`` (``ring_mask``): the
-kernel fetches the ring's ``R`` blocks as one chunk, whole and in the
-table's order (a slot not held yet fetches block 0, masked), and the gather
-reads the ring as it lies.  Nothing of the history before the
-window is read.
+is attended iff ``age < min(window, context_len)`` (``ring_mask``); the
+gather reads the ring as it lies.  The kernel takes a ring one of two ways,
+by its length alone (``_ring_whole``: one rule for ``vmem_bytes``,
+``chunk_positions``, ``attention_path`` and ``blocks_read``).  A ring no
+longer than the longest chunk (``_MAX_CHUNK_TOKENS`` positions: a window of
+128 in 9 blocks of 16) is ONE chunk, fetched whole and in the table's order
+(a slot not held yet fetches block 0, masked).  A longer one (a window of
+4,096: 257 blocks) is *walked in chunks* of the span ``_chunk_positions``
+gives its rows, in the table's order, through the same two buffers and the
+same guarded copies as a global layer's context: chunk ``c`` holds entries
+``[c * span, (c + 1) * span)`` of the ring and is masked by their age, and a
+lane fetches the slots it holds and no others, ``min(ceil(context_len /
+block_size), R)`` leading ones (under ``R * block_size`` positions the ring
+has not wrapped and the slots past the context hold nothing; from there on
+every slot, the one whose block has just left the window as block 0,
+masked).  Nothing of the history before the window is read.
 
 Where ``H / KH`` query heads share a KV head and ``D`` is a multiple of the
 128 lanes, the query and the output cross the kernel's boundary compact
@@ -272,8 +283,9 @@ def paged_attention_checks(q_shape, kv_shape, kv_dtype, ring=0):
     """Ordered (reason, ok) pairs for adoption.decide(): what the kernel
     needs of the query ``[B, H, D]`` and of a pool ``[num_blocks,
     block_size, KH * D]`` in ``kv_dtype``, ``H`` a multiple of ``KH``.
-    ``ring`` is the slots of a window layer's ring table (its one chunk);
-    0 for a layer that attends its whole context."""
+    ``ring`` is the slots of a window layer's ring table (one chunk, or
+    walked in chunks: ``_ring_whole``); 0 for a layer that attends its whole
+    context."""
     dims = tuple(q_shape) + tuple(kv_shape)
     static = all(isinstance(x, int) and x >= 0 for x in dims)
     rank = len(q_shape) == 3 and len(kv_shape) == 3
@@ -300,14 +312,23 @@ def paged_attention_checks(q_shape, kv_shape, kv_dtype, ring=0):
 
 def vmem_bytes(q_shape, kv_shape, kv_dtype, ring=0):
     """What the kernel holds in VMEM for these shapes: the four chunk
-    buffers (a window layer's chunk is its whole ring; any other's the span
-    ``_chunk_positions`` gives these shapes, which the call uses too), and
-    every lane's query and output in float32."""
+    buffers (a window layer's chunk is its whole ring where ``_ring_whole``
+    says so; any other chunk the span ``_chunk_positions`` gives these
+    shapes, which the call uses too), and every lane's query and output in
+    float32."""
     fetched = 2 * jnp.dtype(kv_dtype).itemsize * kv_shape[2]   # K and V
     held = _held_bytes(q_shape, kv_shape)
-    span = ring * kv_shape[1] if ring \
+    span = ring * kv_shape[1] if _ring_whole(ring, kv_shape[1]) \
         else _chunk_positions(fetched, kv_shape[1], held)
     return 2 * span * fetched + held
+
+
+def _ring_whole(ring, block_size):
+    """Is a window layer's ring of ``ring`` slots ONE chunk of the kernel,
+    fetched whole?  Where it is no longer than the longest chunk; a longer
+    ring is walked in chunks, as a context is.  False for ``ring`` 0 (no
+    ring: a layer that attends its whole context)."""
+    return 0 < ring * block_size <= _MAX_CHUNK_TOKENS
 
 
 def _held_bytes(q_shape, kv_shape):
@@ -350,13 +371,15 @@ def blocks_read(context_lens, block_size, maxb, path, ring=False):
     holds (``ceil(context_len / block_size)``: a chunk fetches its live
     blocks and no others; the latent form's straight-line body fetches a
     lane's last block again for the rest of its last chunk, which this does
-    not count), or for a
-    window layer (``ring``: the table is its ring of ``maxb`` slots) a live
-    lane's whole ring (a host-side count for the step's span:
-    ``context_lens`` is the numpy feed)."""
+    not count).  For a window layer (``ring``: the table is its ring of
+    ``maxb`` slots) a live lane's whole ring where the ring is one chunk
+    (``_ring_whole``), and where it is walked in chunks the slots the lane
+    holds, ``min(ceil(context_len / block_size), maxb)``: the same count as
+    a context's (a host-side count for the step's span: ``context_lens`` is
+    the numpy feed)."""
     if path != "pallas":
         return len(context_lens) * maxb
-    if ring:
+    if ring and _ring_whole(maxb, block_size):
         return int((context_lens > 0).sum()) * maxb
     return int((-(-context_lens // block_size)).clip(0, maxb).sum())
 
@@ -399,10 +422,13 @@ def _chunk_blocks(block_size, maxb, fetched, held=0):
 
 def chunk_positions(q_shape, kv_shape, kv_dtype, maxb, ring=0):
     """Positions one chunk of the kernel spans for these shapes and a table
-    of ``maxb`` slots (a window layer's: its ring): what the engine puts on
-    the ``serving_prewarm`` event beside the path's name."""
-    if ring:
+    of ``maxb`` slots (a window layer's is its ring: the ring's length where
+    it is one chunk, ``_ring_whole``, else the span its rows are worth, as a
+    context's): what the engine puts on the ``serving_prewarm`` event beside
+    the path's name."""
+    if _ring_whole(ring, kv_shape[1]):
         return ring * kv_shape[1]
+    maxb = ring or maxb
     fetched = 2 * jnp.dtype(kv_dtype).itemsize * kv_shape[2]
     return kv_shape[1] * _chunk_blocks(kv_shape[1], maxb, fetched,
                                        _held_bytes(q_shape, kv_shape))
@@ -451,9 +477,11 @@ def _kernel(bt_ref, cl_ref, q_ref, *refs, heads, kv_heads, head_dim,
             block_size, maxb, per, scale, window=None, value_cols=None,
             lane_grid=False):
     """``window`` None: a lane's chunks cover positions ``[0,
-    context_len)``.  Given: the table is a ring of ``maxb == per`` slots, a
-    live lane's one chunk is the ring as it lies, and ``ring_mask``'s rule
-    says which of its rows are attended.  ``value_cols`` given (the latent
+    context_len)``.  Given: the table is a ring of ``maxb`` slots and
+    ``ring_mask``'s rule says which of its rows are attended; with ``per ==
+    maxb`` a live lane's one chunk is the ring as it lies, with ``per <
+    maxb`` the lane's chunks cover the slots it holds, as they cover a
+    context.  ``value_cols`` given (the latent
     form): there is one pool and one chunk buffer, and a row's value is its
     first ``value_cols`` columns.  ``lane_grid`` (of the latent form): the
     grid walks the lanes, ``q_ref`` and ``o_ref`` are one lane's, and the
@@ -470,13 +498,16 @@ def _kernel(bt_ref, cl_ref, q_ref, *refs, heads, kv_heads, head_dim,
     group = heads // kv_heads            # query heads a KV head
     rows = -(-heads // 16) * 16          # whole sublane tiles in any dtype
     span = per * block_size              # positions a chunk
+    # a window layer's ring in one chunk, fetched whole; a longer ring is
+    # walked as a context is
+    whole = window is not None and per == maxb
 
     def blocks(b):
         """Blocks lane ``b`` holds: what its chunks fetch."""
         return jnp.minimum((cl_ref[b] + block_size - 1) // block_size, maxb)
 
     def chunks(b):
-        if window is not None:
+        if whole:
             return jnp.minimum(cl_ref[b], 1)
         return (blocks(b) + per - 1) // per
 
@@ -490,7 +521,7 @@ def _kernel(bt_ref, cl_ref, q_ref, *refs, heads, kv_heads, head_dim,
             yield pltpu.make_async_copy(pool.at[block()], buf.at[slot, at],
                                         sem.at[which, slot])
 
-    if window is not None:
+    if whole:
         # the ring is one chunk, fetched whole in the table's order: a slot
         # the lane does not hold yet (-1) fetches block 0, and ``ring_mask``
         # leaves its positions out
@@ -509,7 +540,9 @@ def _kernel(bt_ref, cl_ref, q_ref, *refs, heads, kv_heads, head_dim,
                 for dma in copies(slot, i, lambda: 0):
                     dma.wait()
     else:
-        # a chunk is the lane's live blocks among its ``per``: the last
+        # a chunk is the lane's live blocks among its ``per`` (of a ring
+        # walked in chunks: the slots it holds; one whose block left the
+        # window names none and fetches block 0, masked): the last
         # chunk of a lane fetches the blocks the lane holds and no others,
         # and as many copies are waited for as were started.  A copy issued
         # from straight-line code costs a third of one issued from a loop
@@ -599,9 +632,15 @@ def _kernel(bt_ref, cl_ref, q_ref, *refs, heads, kv_heads, head_dim,
                 jnp.int32, (1, span), 1)
             if window is None:
                 seen = pos < ctx
-            else:
+            elif whole:
                 # c is 0 and span the ring's length
                 seen = _in_window(ctx, pos, span, window)
+            else:
+                # chunk c of the ring: its entries by age; the last chunk
+                # may reach past the ring's end
+                ring_len = maxb * block_size
+                seen = _in_window(ctx, pos, ring_len, window) \
+                    & (pos < ring_len)
             sc = jnp.where(seen, sc, _MASK)
             m_new = jnp.maximum(m, jnp.max(sc, axis=1, keepdims=True))
             alpha = jnp.exp(m - m_new)

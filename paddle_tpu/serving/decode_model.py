@@ -99,6 +99,7 @@ __all__ = ["DecoderConfig", "init_decoder_params", "save_decoder",
            "load_decoder", "is_decoder_dir", "has_draft", "load_draft",
            "truncate_decoder", "laid_out", "attention_path", "experts_path",
            "state_update_path", "state_update_columns", "experts_chunk",
+           "experts_gate",
            "make_paged_step",
            "make_fed_step", "make_paged_step_multi",
            "make_draft_rollout", "make_unpaged_step", "unpaged_generate",
@@ -107,7 +108,7 @@ __all__ = ["DecoderConfig", "init_decoder_params", "save_decoder",
 
 # the families, a module each: ``models/<arch>.py``
 ARCHS = ("gpt2", "olmoe", "granite_hybrid", "lfm2_moe", "exaone_moe",
-         "nemotron_h", "kimi_linear", "dots_vlm")
+         "nemotron_h", "kimi_linear", "dots_vlm", "smallthinker")
 LAYER_KINDS = ("attention", "mamba", "conv", "window", "experts", "kda",
                "latent")
 # recurrent kind -> the name its slot goes by in spans, gauges and counters
@@ -197,6 +198,12 @@ class DecoderConfig:
     source's group, whose ``mscale_all_dim`` also scales the scores), and
     ``kimi_linear``'s feed-forwards with a router that keeps ``topk_group``
     of ``n_group`` groups of experts before it chooses experts.
+    ``smallthinker`` is the block of ``models/smallthinker.py``:
+    ``exaone_moe``'s two kinds of attention (``window`` layers rotated,
+    ``attention`` layers not) with no Q/K norm under pre-norms, and in every
+    layer ``experts`` ReLU-gated experts of width ``ffn`` chosen by a softmax
+    router that reads the attention's input; no shared expert, no dense
+    lead, no share; an untied head and a stream of its own width.
 
     ``kv_heads`` None means ``heads`` (multi-head); ``layer_types`` None
     means ``layers`` attention layers.  ``dtype`` is the weights' (``f32``
@@ -362,6 +369,11 @@ class DecoderConfig:
                 "expert_first + experts_held) of %d: %r from %r"
                 % (_declaring("holds_share"), self.experts, experts_held,
                    expert_first))
+        if self.shared_ffn and not family.shared_expert:
+            raise ValueError(
+                "the %s blocks pass every token through a shared expert of "
+                "width shared_ffn: %r" % (_declaring("shared_expert"),
+                                          shared_ffn))
 
     @property
     def hidden(self):
@@ -707,9 +719,11 @@ def chunk_positions(cfg, kv_config, lanes=1):
     """Positions one chunk of the attention kernel spans, by kind of layer
     that pages a history (``attention``, ``window``, ``latent``) and takes
     the kernel at a bucket of ``lanes``: the kernel sizes a chunk by the
-    bytes a position costs in that kind's pools, a window layer's is its
-    ring (``paged_attention.chunk_positions``).  Kinds on the gather path
-    have no chunk and no entry."""
+    bytes a position costs in that kind's pools; a window layer's is its
+    whole ring where the ring is no longer than the longest chunk, and the
+    same span as a context's where the ring is walked in chunks
+    (``paged_attention.chunk_positions``).  Kinds on the gather path have
+    no chunk and no entry."""
     maxb = -(-cfg.max_seq // kv_config.block_size)
     dtype = _kv._PAYLOAD[kv_config.dtype][0]
     q = (lanes, cfg.heads, cfg.head_dim)
@@ -746,6 +760,12 @@ def experts_path(cfg, params, lanes=1):
     w = params["l%d_%s" % (cfg.routed_layers[0],
                            "wgate" if matrices == 3 else "experts_up")]
     return _moe.experts_path(lanes, w.shape, w.dtype, matrices)
+
+
+def experts_gate(cfg):
+    """The activation of an expert's gate in this model's routed layers, as
+    ``moe_experts.GATES`` names it (the family's declaration)."""
+    return _model(cfg.arch).FAMILY.expert_gate
 
 
 def experts_chunk(cfg):

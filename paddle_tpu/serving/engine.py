@@ -938,8 +938,8 @@ class _DecodeModel:
         self.chunks_read = {}
         # the same of the window layers' attention over their rings
         # (``window_read``: lens -> the blocks one such layer fetches of a
-        # ring, and would fetch of the whole table), None for a model with
-        # no such layer
+        # ring, would fetch of the whole table, and the chunks it walks),
+        # None for a model with no such layer
         self.window_path = None
         self.window_read = None
         # bucket -> how a routed layer's experts are read at that many
@@ -1307,9 +1307,17 @@ class DecodeEngine:
             read = functools.partial(
                 _pa.blocks_read, block_size=kv_config.block_size,
                 path=window_path)
+            # the kernel's chunk of a ring: the ring itself, or the span a
+            # longer ring is walked in; on the gather path a lane's ring is
+            # its one chunk
+            ring_len = kv_config.window_ring * kv_config.block_size
+            span = _dm.chunk_positions(cfg, kv_config, max(self.buckets)
+                                       ).get("window", ring_len)
             entry.window_read = lambda lens: (
                 read(lens, maxb=kv_config.window_ring, ring=True),
-                read(lens, maxb=entry.maxb))
+                read(lens, maxb=entry.maxb),
+                _pa.chunks_read(lens, kv_config.block_size,
+                                kv_config.window_ring, span)[0])
         entry.experts_path = experts_path
         entry.state_path = state_path
         entry.blocks_read = functools.partial(
@@ -1437,6 +1445,11 @@ class DecodeEngine:
                 if m.experts_path[bucket] == "pallas":
                     # columns of an expert a grid step of the kernel reads
                     extra["experts_f_chunk"] = _dm.experts_chunk(m.cfg)
+                gate = _dm.experts_gate(m.cfg)
+                if gate != "silu":
+                    # the activation of a three-matrix expert's gate, where
+                    # the family declares another than SiLU
+                    extra["experts_gate"] = gate
             if m.state_path:
                 extra["state_update"] = m.state_path[bucket]
                 if m.state_path[bucket] == "pallas":
@@ -1452,6 +1465,8 @@ class DecodeEngine:
                     for kind in sorted(set(m.cfg.layer_types))}
             if m.window_path is not None:
                 extra["window_attention"] = m.window_path
+                # the slots of a sequence's ring in a window layer's pool
+                extra["window_ring"] = m.kv_config.window_ring
             if m.cfg.latent_layers:
                 extra["latent_attention"] = m.attn_path
             # positions a chunk of the attention kernel spans, by kind of
@@ -2903,10 +2918,15 @@ class DecodeEngine:
                       kind="window")
         _tm.set_gauge("kv_pool_blocks", alloc.in_use, model=m.name,
                       kind="global")
-        read, full = m.window_read(lens)
+        read, full, chunks = m.window_read(lens)
         return {"kv_window_blocks_read": n * read,
                 "kv_window_blocks_full": n * full,
                 "kv_window_blocks_held": walloc.in_use,
+                # live lanes whose context is past the window (their rings
+                # have given blocks back), and the chunks ONE window layer's
+                # attention walked
+                "kv_window_lanes_wrapped": int((lens > m.cfg.window).sum()),
+                "kv_window_chunks": chunks,
                 "kv_block_size": m.kv_config.block_size}
 
     @staticmethod

@@ -23,7 +23,7 @@ import pytest
 import paddle_tpu as fluid
 from paddle_tpu.core import telemetry as _tm
 from paddle_tpu.models import dots_vlm, exaone_moe, granite_hybrid, \
-    kimi_linear, lfm2_moe, nemotron_h, olmoe
+    kimi_linear, lfm2_moe, nemotron_h, olmoe, smallthinker
 from paddle_tpu.serving import DecodeEngine
 from paddle_tpu.serving import decode_model as dm
 from paddle_tpu.serving import kv_cache as kvc
@@ -245,6 +245,12 @@ _DOTS = dm.DecoderConfig(
     latent_rope=8, q_rank=20, rope_scaling=YARN, norm_eps=1e-6,
     dense_layers=1, dense_ffn=64, ffn=24, shared_ffn=24, experts=16,
     experts_per_token=3, n_group=4, topk_group=2, routed_scaling=2.5)
+# 14 query heads over 2 KV heads: 7 a group, the published ratio (28 over 4)
+_SMALLTHINKER = dm.DecoderConfig(
+    arch="smallthinker", vocab=61, layers=8, heads=14, kv_heads=2, head_dim=8,
+    hidden_size=48, ffn=16, max_seq=96,
+    layer_types=("attention", "window", "window", "window") * 2, window=8,
+    experts=16, experts_per_token=3, rope_theta=1.5e6, norm_eps=1e-6)
 _GRANITE_G4 = _GRANITE.replace(kv_heads=1)
 
 # Weights are normal(0, 0.3) (OLMoE's 0.05) and a router bias of 0.05: at
@@ -331,6 +337,19 @@ ROWS = {row.arch: row for row in (
                     latent_rope=8, hidden=48, ffn=24)),
         # one row of 640 bfloat16 a position: 1,280 B
         chunk=("dots-vlm1-inst-serve.json", 12832, {"latent": 512})),
+    Row("smallthinker", _both(_SMALLTHINKER, smallthinker.init_params,
+                              std=0.3),
+        declines="window_layers", holds="ring", refusal="window layers",
+        entry=dict(attn_path="gather", window_path="gather",
+                   experts_path={4: "einsum"}),
+        serve=("smallthinker-21b-a3b-serve.json",
+               dict(layer_types=_SMALLTHINKER.layer_types, experts=16,
+                    experts_per_token=3, window=8, hidden=48, heads=14,
+                    kv_heads=2, rope_theta=1.5e6)),
+        # 4 KV heads of 128: 2,048 B a position, so 256 positions a chunk; a
+        # window layer's ring of 257 blocks is walked in such chunks
+        chunk=("smallthinker-21b-a3b-serve.json", 25120,
+               {"attention": 256, "window": 256})),
 )}
 assert tuple(ROWS) == dm.ARCHS
 

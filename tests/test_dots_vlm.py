@@ -678,7 +678,8 @@ def test_the_latent_and_expert_rules_at_the_published_shapes():
             ).decoder_config(config))
     assert chunks == {"olmoe": 1024, "granite_hybrid": None, "lfm2_moe": 768,
                       "exaone_moe": 256, "nemotron_h": 928,
-                      "kimi_linear": 512, "dots_vlm": 256}
+                      "kimi_linear": 512, "dots_vlm": 256,
+                      "smallthinker": 768}
 
 
 def test_routed_experts_kernel_in_chunks_of_an_eighth_of_the_width(

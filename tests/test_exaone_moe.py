@@ -563,10 +563,13 @@ def test_config_refuses_what_no_block_computes():
     with pytest.raises(ValueError, match="may hold experts"):
         dm.DecoderConfig(arch="olmoe", experts_held=4,
                          **dict(base, kv_heads=4))
+    with pytest.raises(ValueError, match="a shared expert of width"):
+        dm.DecoderConfig(arch="olmoe", **dict(base, kv_heads=4))
     cfg = dm.DecoderConfig(arch="exaone_moe", layer_types=["window"] * 2,
                            window=4, experts_held=4, expert_first=4, **base)
     assert cfg.held_experts == slice(4, 8) and cfg.routed_layers == (0, 1)
-    assert dm.DecoderConfig(**dict(base, arch="olmoe", kv_heads=4)) \
+    assert dm.DecoderConfig(**dict(base, arch="olmoe", kv_heads=4,
+                                   shared_ffn=0)) \
         .held_experts == slice(0, 8)
 
 

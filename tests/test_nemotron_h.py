@@ -342,7 +342,7 @@ def test_config_refuses_what_no_block_computes():
         dm.DecoderConfig(arch="lfm2_moe", layer_types=["attention"] * 2,
                          experts_held=4, **base)
     with pytest.raises(ValueError, match="exaone_moe\\|nemotron_h\\|"
-                       "kimi_linear\\|dots_vlm may say"):
+                       "kimi_linear\\|dots_vlm\\|smallthinker may say"):
         dm.DecoderConfig(arch="lfm2_moe", layer_types=["attention"] * 2,
                          hidden_size=24, **base)
     cfg = dm.DecoderConfig(

@@ -552,6 +552,76 @@ def test_exaone_moe_step_compiles_both_attention_kinds_and_its_experts(
     assert not found, found
 
 
+def test_smallthinker_step_walks_a_ring_of_257_blocks_in_chunks(
+        one_chip, as_on_tpu):
+    """SmallThinker-21BA3B's published widths (28 query heads over 4 KV heads
+    of 128 behind a stream of 2560, a window of 4,096, 64 ReLU-gated experts
+    of width 768), published layers 0 and 1 (global, window), bucket 32, the
+    cell's pools (25,120 bf16 blocks 512 wide for the global layer, 33 rings
+    of 257 blocks for the window layer) and tables (1,024 slots, the
+    published 16,384 positions): Mosaic accepts the paged-attention kernel
+    twice inside the whole step, once over the whole context and once over
+    a ring walked in chunks of 256 positions (2.1e6 B of VMEM where the ring
+    as one chunk asked 16.8e6 of buffers and was refused), 7 query heads a
+    KV head, compact, and the routed-expert kernel with its ReLU gate over
+    the 64 experts in one column chunk; both kinds of pool are aliased
+    whole."""
+    from benchmark.models import smallthinker_decoder
+    from paddle_tpu.pallas_kernels import moe_experts as moe
+
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "smallthinker-21b-a3b-serve.json")) as fp:
+        config = json.load(fp)
+    config = dict(config, num_hidden_layers=2, **{
+        key: config[key][:2]
+        for key in ("layer_types", "rope_layout", "sliding_window_layout")})
+    cfg = smallthinker_decoder.decoder_config(config)
+    assert (cfg.hidden, cfg.heads, cfg.kv_heads, cfg.head_dim, cfg.window,
+            cfg.experts, cfg.experts_per_token, cfg.ffn, cfg.layer_types,
+            cfg.routed_layers, cfg.max_seq) == (
+        2560, 28, 4, 128, 4096, 64, 6, 768, ("attention", "window"), (0, 1),
+        16384)
+    lanes, block_size, blocks = 32, 16, 25120
+    kv = dm.cache_config(cfg, block_size, blocks, state_slots=lanes + 1)
+    assert (kv.window_ring, kv.window_blocks) == (257, 8481)
+    assert dm.attention_path(cfg, kv, lanes) == "pallas"
+    assert dm.attention_path(cfg, kv, lanes, "window") == "pallas"
+    assert dm.chunk_positions(cfg, kv, lanes) == {"attention": 256,
+                                                  "window": 256}
+    assert moe.f_chunk(2560, 768, 2) == 768
+    assert moe.experts_path(lanes, (64, 2560, 768), jnp.bfloat16) == "pallas"
+
+    on_chip = functools.partial(_placed, one_chip)
+
+    carry = on_chip(jax.eval_shape(lambda: PagedKVCache(kv).carry()))
+    params = on_chip({
+        name: jax.ShapeDtypeStruct(shape, jnp.bfloat16)
+        for name, (shape, _kind)
+        in smallthinker_decoder.param_shapes(config).items()})
+    feeds = on_chip(_packed_feeds(kv, lanes, cfg.max_seq // block_size))
+    assert feeds[1].shape == (lanes, 4 + 1024 + 257)
+    compiled = jax.jit(dm.make_packed_step(cfg, kv, lanes),
+                       donate_argnums=(0,)
+                       ).lower(carry, params, *feeds).compile()
+
+    text = compiled.as_text()
+    assert _expert_kernels(text) == 2          # one a layer
+    assert _kernel_calls(text) == 4            # and an attention a layer
+    assert len(re.findall(r"%paged_attention\S* = ", text)) == 2
+    assert not _expert_passes(text, 64, 2560, 768)
+    pool_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in carry)
+    assert pool_bytes == 2 * (25120 + 8481) * 16 * 512 * 2
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= pool_bytes
+    # beside its arguments: the logits [32, 151936] in float32 and the like,
+    # nothing of a ring's size gathered ([32, 4112, 512] is 0.13e9 B)
+    assert memory.temp_size_in_bytes < 2 * lanes * 151936 * 4
+    gathered = re.compile(r" = (bf16|f32)\[32,4112,")
+    found = [line.strip()[:160] for line in text.splitlines()
+             if gathered.search(line)]
+    assert not found, found
+
+
 def test_nemotron_h_step_compiles_its_three_kernels_at_published_shapes(
         one_chip, as_on_tpu):
     """NVIDIA-Nemotron-3-Nano-30B-A3B's published widths, its first 6 blocks

@@ -350,8 +350,10 @@ def check_attention(cfg, kv, tables, lens, seed, repeat=24, window=None):
         # both round the query and the probabilities to the pool's dtype
         passed = finite and kernel["rms"] <= 1.5 * gathered["rms"] \
             and kernel["max_abs"] <= 3 * gathered["max_abs"]
-    # the chunks a call walks (a live lane's ring is one)
-    chunks = int((np.asarray(lens) > 0).sum()) if window \
+    # the chunks a call walks (a live lane's ring is one where the ring is
+    # a chunk; a longer ring's slots are walked as a context's blocks are)
+    chunks = pa.chunks_read(np.asarray(lens), kv.block_size,
+                            tables.shape[1], span)[0] if window \
         else int((-(-np.asarray(lens) // span)).sum())
     return {"scale": float(np.sqrt((out["exact"] ** 2).mean())),
             "finite": finite, "passed": bool(passed),
@@ -378,7 +380,7 @@ def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
     import numpy as np
 
     from benchmark import dots_cost, exaone_cost, kimi_cost, lfm2_cost, \
-        moe_cost, nemotron_cost, ssm_cost, trace_reduce
+        moe_cost, nemotron_cost, smallthinker_cost, ssm_cost, trace_reduce
     from paddle_tpu.core import telemetry
     from paddle_tpu.core.executor import CarriedStepFn
     from paddle_tpu.pallas_kernels import kda_update, paged_attention, \
@@ -502,6 +504,10 @@ def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
         elif "n_group" in config and "kv_lora_rank" in config:
             # the same behind latent attention in every layer, a share
             bytes_of = dots_cost.experts_hit_bytes_per_step
+        elif "moe_num_primary_experts" in config:
+            # ReLU-gated experts in every layer, all of them held
+            bytes_of = smallthinker_cost.experts_hit_bytes_per_step
+            held = "moe_num_primary_experts"
         elif "mlp_layer_types" in config:
             # a share: the held experts of each sparse layer
             bytes_of = lambda _c, n: exaone_cost.sparse_layers(config) * n \
@@ -650,7 +656,8 @@ def main(argv=None):
         config["n_layer" if "n_layer" in config
                else "num_hidden_layers"] = args.layers
         for key in ("layer_types", "mlp_layer_types", "sliding_windows",
-                    "hybrid_override_pattern"):
+                    "hybrid_override_pattern", "rope_layout",
+                    "sliding_window_layout"):
             if key in config:
                 config[key] = config[key][:args.layers]
         if "linear_attn_config" in config:
