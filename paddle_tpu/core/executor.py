@@ -1143,9 +1143,10 @@ class Executor:
         BuildStrategy fuse_all_optimizer_ops): hundreds of tiny
         per-parameter update fusions each pay a fixed launch cost — ~46 ms
         of a 211 ms ResNet-50 step in the round-3 profile.  Attempted once
-        per (program, version): with the rank-capped default most groups
-        stay unfused, so without memoization every step would pay a full
-        pass scan that is guaranteed to change nothing."""
+        per (program, version): only the vectors fuse (ir.py
+        MAX_FUSED_RANK), so many programs form no group, and without
+        memoization every step would pay a full pass scan that is
+        guaranteed to change nothing."""
         key = (program._uid, program.version)
         if key in self._fuse_attempted:
             return

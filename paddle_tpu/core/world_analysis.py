@@ -500,12 +500,12 @@ def _var_bytes(v, batch, mesh_axes, shape_override=None):
 
 # horizontal optimizer fusion (ir.py fuse_optimizer_ops_pass) lowers each
 # group through flat concatenated buffers: XLA materializes one
-# full-group-size temp per duplicable state slot (bert-tiny buffer
-# assignment: fused adam over all 2-D params shows 4 flat f32[total]
-# temps — param/grad/m1/m2 — dominating the temp slab).  Scalar
-# accumulators (beta pows) don't rate a slot.
+# full-group-size temp per state slot the lowering concatenates
+# (ops/optimizer_ops.py: fused_adam flattens Grad and both moments and
+# reads each Param where it lies; fused_momentum and fused_sgd flatten
+# the params too).  Scalar accumulators (beta pows) don't rate a slot.
 _FUSED_FLAT_SLOTS = {
-    "adam": ("Param", "Grad", "Moment1", "Moment2"),
+    "adam": ("Grad", "Moment1", "Moment2"),
     "momentum": ("Param", "Grad", "Velocity"),
     "sgd": ("Param", "Grad"),
 }
@@ -517,7 +517,7 @@ def _fused_optimizer_loads(program, block, nbytes):
     executor applies the pass in place before check_before_compile) and
     a pristine one — there the fusion the executor WILL apply is
     predicted with the pass's own grouping rules (per type+LR+dtype,
-    rank-capped, >= MIN_GROUP members)."""
+    rank <= MAX_FUSED_RANK, >= MIN_GROUP members)."""
     loads = []
     fused_seen = False
     for i, op in enumerate(block.ops):
@@ -530,10 +530,10 @@ def _fused_optimizer_loads(program, block, nbytes):
     if fused_seen:
         return loads
     from .. import flags
+    from ..ir import FuseOptimizerOpsPass
 
     if not flags.flag("fuse_optimizer_ops"):
         return loads
-    max_rank = int(flags.flag("fuse_optimizer_max_rank") or 0)
     groups = {}
     for i, op in enumerate(block.ops):
         if op.type not in _FUSED_FLAT_SLOTS:
@@ -542,7 +542,7 @@ def _fused_optimizer_loads(program, block, nbytes):
         pv = block._find_var_recursive(pname)
         if pv is None or pv.shape is None:
             continue
-        if max_rank and len(pv.shape) > max_rank:
+        if len(pv.shape) > FuseOptimizerOpsPass.MAX_FUSED_RANK:
             continue
         lr = (op.input("LearningRate") or [None])[0]
         last_idx, total, count = groups.get((op.type, lr, pv.dtype),
@@ -550,7 +550,7 @@ def _fused_optimizer_loads(program, block, nbytes):
         groups[(op.type, lr, pv.dtype)] = (i, total + nbytes(pname),
                                            count + 1)
     for (op_type, _lr, _dt), (last_idx, total, count) in groups.items():
-        if count >= 4:  # FuseOptimizerOpsPass.MIN_GROUP
+        if count >= FuseOptimizerOpsPass.MIN_GROUP:
             loads.append((last_idx,
                           total * len(_FUSED_FLAT_SLOTS[op_type])))
     return loads

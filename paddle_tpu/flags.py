@@ -24,8 +24,12 @@ _DEFAULTS = {
     "FLAGS_tensor_array_max_len": 256,
     # horizontal optimizer-update fusion (reference BuildStrategy
     # fuse_all_optimizer_ops / ir/fuse_optimizer_ops_pass.cc): coalesce
-    # per-parameter sgd/momentum/adam ops into one flat update — ~46 ms
-    # of a 211 ms ResNet-50 step was per-weight launch overhead
+    # the per-parameter sgd/momentum/adam ops of the vectors (biases,
+    # LayerNorm and BN scales) into one flat update — round 3: ~46 ms of a
+    # 211 ms ResNet-50 step was the launches of 315 tiny per-weight
+    # updates.  Matrices and conv kernels keep their plain ops: copying
+    # tiled arrays into a flat buffer cost BERT-base 22 ms of a 228 ms
+    # step on the chip (PR 54; ir.py FuseOptimizerOpsPass.MAX_FUSED_RANK)
     "FLAGS_fuse_optimizer_ops": True,
     # per-request PS RPC deadline in MILLISECONDS (reference units —
     # paddle/fluid/operators/distributed/ FLAGS_rpc_deadline, default
@@ -95,13 +99,6 @@ _DEFAULTS = {
     # raises) instead of an on-chip band-edge trip.  0 disables the gate;
     # MEM001 (the estimate itself) is always reported at info level.
     "FLAGS_hbm_budget_bytes": 0,
-    # max param rank eligible for horizontal optimizer fusion
-    # (ir.py FuseOptimizerOpsPass).  2 fuses BERT's [h,h]/[h,4h] encoder
-    # weights into one fused_adam group (the r5 wgrad/Adam residue) while
-    # keeping 4-D conv kernels unfused — flattening tiled TPU layouts
-    # costs relayout copies exceeding the launch savings (round-3:
-    # fuse-everything = 1786 img/s vs 2200 unfused).  0 = no restriction.
-    "FLAGS_fuse_optimizer_max_rank": 2,
     # deterministic collective reduction order (ops/collective.py
     # c_allreduce_sum): replace lax.psum with all_gather + a fixed-order
     # pairwise tree-reduce, so the cross-rank gradient sum reassociates

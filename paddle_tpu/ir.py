@@ -568,13 +568,19 @@ class FuseOptimizerOpsPass(Pass):
     bias correction."""
 
     MIN_GROUP = 4
-    # fuse only params of rank <= this (0 = no restriction).  None reads
-    # FLAGS_fuse_optimizer_max_rank at apply time (default 2: BERT's 2-D
-    # encoder weights + embeddings fuse into one adam group; 4-D conv
-    # kernels stay unfused — flattening tiled TPU layouts costs relayout
-    # copies that exceed the launch savings).  1-D params (BN gamma/beta,
-    # biases) are linear-layout so concat is copy-free at any setting.
-    max_param_rank = None
+    # Only params of rank <= this fuse: a vector (bias, LayerNorm or BN
+    # scale) is linear-layout, so the fused lowering's concat into one flat
+    # buffer is a plain copy of a few KB, and a launch of its own would
+    # cost more than its bytes (round 3: 315 tiny per-weight updates took
+    # ~46 ms of a 211 ms ResNet-50 step).  A matrix or a 4-D conv kernel
+    # lies in (8, 128) tiles: flattening it relays it as well as copying
+    # it, and its plain op is one elementwise fusion that XLA folds into
+    # the matmul producing its gradient.  Measured, not a setting: round 3,
+    # fuse-everything = 1786 img/s vs 2200 unfused on ResNet-50; PR 54,
+    # BERT-base's 77 matrices in the flat buffers cost 22 ms of a 228 ms
+    # step on the chip (PERF.md section 6).  core/world_analysis.py reads
+    # the same constant for its prediction of the flat temps.
+    MAX_FUSED_RANK = 1
     _STATE_SLOTS = {
         "sgd": ("Param", "Grad"),
         "momentum": ("Param", "Grad", "Velocity"),
@@ -603,31 +609,19 @@ class FuseOptimizerOpsPass(Pass):
                                       or op.input("Beta2Tensor")):
                 continue
             pv = block._find_var_recursive(op.input("Param")[0])
+            if (pv is None or pv.shape is None
+                    or len(pv.shape) > self.MAX_FUSED_RANK):
+                continue
             attrs_key = tuple(
                 (k, tuple(v) if isinstance(v, list) else v)
                 for k, v in sorted(op.attrs.items())
                 if k not in self._META_ATTRS)
-            key = (op.type, op.input("LearningRate")[0],
-                   None if pv is None else pv.dtype, attrs_key)
+            key = (op.type, op.input("LearningRate")[0], pv.dtype,
+                   attrs_key)
             groups.setdefault(key, []).append(op)
 
-        if self.max_param_rank is None:
-            from .flags import flag as _flag
-            max_rank = int(_flag("fuse_optimizer_max_rank") or 0)
-        else:
-            max_rank = int(self.max_param_rank)
         replaced = {}
         for (op_type, lr_name, _dt, _ak), ops in groups.items():
-            if max_rank:
-                # restrict fusion to low-rank params: flattening tiled
-                # TPU layouts (4-D conv kernels) costs relayout copies
-                # that exceed the launch savings (round-3 measurement:
-                # fuse-everything = 1786 img/s vs 2200 unfused)
-                ops = [o for o in ops
-                       if (lambda v: v is not None and v.shape is not None
-                           and len(v.shape) <= max_rank)(
-                               block._find_var_recursive(
-                                   o.input("Param")[0]))]
             if len(ops) < self.MIN_GROUP:
                 continue
             slots = self._STATE_SLOTS[op_type]

@@ -377,23 +377,25 @@ def test_mem003_budget_gate_via_flag():
 
 
 def test_mem_fused_optimizer_flat_buffers_counted():
-    """The fused-adam lowering materializes one full-group flat temp per
-    state slot; the estimator must predict that plateau on the pristine
-    program whenever FLAGS_fuse_optimizer_ops would fuse it."""
+    """The fused-adam lowering materializes one flat temp per state slot
+    it concatenates, over the group's vectors (ir.py MAX_FUSED_RANK: a
+    matrix keeps its plain op and rates none); the estimator must predict
+    that plateau on the pristine program whenever FLAGS_fuse_optimizer_ops
+    would fuse it."""
     main, startup = fluid.Program(), fluid.Program()
     with fluid.program_guard(main, startup):
-        x = fluid.data("x", [-1, 16])
+        x = fluid.data("x", [-1, 64])
         h = x
         for _ in range(4):
-            h = layers.fc(h, size=16, act="relu")
+            h = layers.relu(h + layers.create_parameter([64], "float32"))
         loss = layers.reduce_mean(h)
         optimizer.Adam(learning_rate=1e-3).minimize(loss)
     with _flags(fuse_optimizer_ops=True):
         fused = world_analysis.estimate_program_hbm(
-            main, feed_names=["x"], batch=4)
+            main, feed_names=["x"], batch=1)
     with _flags(fuse_optimizer_ops=False):
         plain = world_analysis.estimate_program_hbm(
-            main, feed_names=["x"], batch=4)
+            main, feed_names=["x"], batch=1)
     assert fused["transient_peak_bytes"] > plain["transient_peak_bytes"]
 
 
