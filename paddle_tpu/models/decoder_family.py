@@ -23,9 +23,9 @@ class DecoderFamily(collections.namedtuple(
         "DecoderFamily",
         ("kinds", "dtypes", "grouped_query", "routes", "expert_matrices",
          "dense_lead", "holds_share", "own_stream_width", "grouped_router",
-         "rotated_latent", "shared_expert", "expert_gate"),
+         "rotated_latent", "shared_expert", "expert_gate", "selects"),
         defaults=(("f32", "bf16"), False, None, None, False, False, False,
-                  False, False, False, "silu"))):
+                  False, False, False, "silu", False))):
     """``kinds``: the kinds of layer the block computes
     (``decode_model.LAYER_KINDS``: seven of them, of which a family names
     one to three).  ``dtypes``: the weight dtypes it is
@@ -47,6 +47,10 @@ class DecoderFamily(collections.namedtuple(
     may compress the query (``cfg.q_rank``).  ``shared_expert``: beside its
     routed experts every token passes through a shared one of width
     ``cfg.shared_ffn``.  ``expert_gate``: the activation of a three-matrix
-    expert's gate, as ``moe_experts.GATES`` names it."""
+    expert's gate, as ``moe_experts.GATES`` names it.  ``selects``: its
+    latent layers attend the ``cfg.index_topk`` positions a learned indexer
+    scores highest (``cfg.index_heads`` heads of ``cfg.index_head_dim``,
+    whose keys the cache holds in a pool beside each latent pool) and no
+    others."""
 
     __slots__ = ()

@@ -170,20 +170,22 @@ def init_params(cfg, seed=0, std=0.02, bias_std=BIAS_STD):
 
 def laid_out(cfg, params):
     """``params`` as a decode step holds them (``decode_model.laid_out``):
-    each latent layer's ``wkvb [rank, heads * 2 D]`` gives way to the two
-    arrays ``latent_mixer`` multiplies, heads leading: ``wkvb_k [heads,
+    each latent layer's ``wkvb [rank, heads * (D + Dv)]`` gives way to the
+    two arrays ``latent_mixer`` multiplies, heads leading: ``wkvb_k [heads,
     rank, D]``, the key's up-projection as ``bhd,hrd->bhr`` reads it, and
-    ``wkvb_v [heads, D, rank]``, the value's as ``bhr,hdr->bhd`` does.  The
-    same values in the same dtype, turned once on the device; published,
+    ``wkvb_v [heads, Dv, rank]``, the value's as ``bhr,hdr->bhd`` does
+    (``Dv`` is ``cfg.v_head_dim``: ``D`` unless the model says otherwise).
+    The same values in the same dtype, turned once on the device; published,
     the head axis lies in the middle and XLA turns the weight in every
     step (PERF.md section 6, PR 52).  A new dict: the caller's keeps the
     published form, which bundles, references and checks read."""
     rank, heads, d = cfg.latent_rank, cfg.heads, cfg.head_dim
     out = dict(params)
     for l in cfg.latent_layers:
-        up = jnp.asarray(out.pop("l%d_wkvb" % l)).reshape(rank, heads, 2, d)
-        out["l%d_wkvb_k" % l] = up[:, :, 0].transpose(1, 0, 2)
-        out["l%d_wkvb_v" % l] = up[:, :, 1].transpose(1, 2, 0)
+        up = jnp.asarray(out.pop("l%d_wkvb" % l)).reshape(
+            rank, heads, d + cfg.v_head_dim)
+        out["l%d_wkvb_k" % l] = up[:, :, :d].transpose(1, 0, 2)
+        out["l%d_wkvb_v" % l] = up[:, :, d:].transpose(1, 2, 0)
     return out
 
 
@@ -239,31 +241,39 @@ def _held_laid_out(p):
         return None
 
 
-def latent_mixer(cfg, p, l, h, attend, rotate=None):
-    """The absorbed MLA mixer of layer ``l`` over h [B, H] float32.  Two
-    options, for the families that share it (``dots_vlm``): ``cfg.q_rank``
-    given, the query goes through a low-rank pair with a norm between
-    (``wq_a``, ``q_norm``, ``wq_b``) and not through ``wq``; ``rotate``
-    given, ``rotate(x [B, n, latent_rope])`` turns the row's shared key
-    and each head's query's last ``latent_rope`` values by the lanes'
-    positions, before ``attend`` writes the row and before the absorb.
-    ``wkvb`` is read as ``laid_out`` left it or, handed the published
-    array, cut from that: the same products of the same values."""
+def latent_mixer(cfg, p, l, h, attend, rotate=None, index=None):
+    """The absorbed MLA mixer of layer ``l`` over h [B, H] float32.  Three
+    options, for the families that share it (``dots_vlm``, ``glm_dsa``):
+    ``cfg.q_rank`` given, the query goes through a low-rank pair with a
+    norm between (``wq_a``, ``q_norm``, ``wq_b``) and not through ``wq``;
+    ``rotate`` given, ``rotate(x [B, n, latent_rope])`` turns the row's
+    shared key and each head's query's last ``latent_rope`` values by the
+    lanes' positions, before ``attend`` writes the row and before the
+    absorb; ``index`` given, ``index(cq)`` makes of the normed compressed
+    query what ``attend`` chooses the attended positions by (an indexer's
+    queries, their heads' weights and this token's index key), handed to it
+    where a plain latent layer hands None.  A head's values are
+    ``cfg.v_head_dim`` wide, its own key part ``cfg.head_dim``.  ``wkvb`` is
+    read as ``laid_out`` left it or, handed the published array, cut from
+    that: the same products of the same values."""
     bb = h.shape[0]
     heads, d, rank = cfg.heads, cfg.head_dim, cfg.latent_rank
+    dv = cfg.v_head_dim
     dot = lambda eq, a, b: jnp.einsum(
         eq, a.astype(b.dtype), b, preferred_element_type=jnp.float32)
     laid = _held_laid_out(p)
     # published, a head's columns of wkvb: the key's up-projection, then
     # the value's
-    up = None if laid else p("wkvb").reshape(rank, heads, 2 * d)
+    up = None if laid else p("wkvb").reshape(rank, heads, d + dv)
     # (``absorb`` is opened again around each rotation so that ``rope`` is
     # its sibling, and without one the operations come in the order they
     # had before there were options: the lowered step is the same text)
     with jax.named_scope("q_compress" if cfg.q_rank else "absorb"):
-        q = _mm(_q_norm(_mm(h, p("wq_a")), p("q_norm"), cfg.norm_eps),
-                p("wq_b")) if cfg.q_rank else _mm(h, p("wq"))
+        cq = _q_norm(_mm(h, p("wq_a")), p("q_norm"), cfg.norm_eps) \
+            if cfg.q_rank else None
+        q = _mm(cq, p("wq_b")) if cfg.q_rank else _mm(h, p("wq"))
         q = q.reshape(bb, heads, d + cfg.latent_rope)
+    chosen_by = index(cq) if index is not None else None
     with jax.named_scope("absorb"):
         row = _mm(h, p("wkva"))
         c = _kv_norm(row[:, :rank], p("kv_norm"), cfg.norm_eps)
@@ -281,11 +291,11 @@ def latent_mixer(cfg, p, l, h, attend, rotate=None):
             q_pe = rotate(q_pe)
     with jax.named_scope("absorb"):
         q = jnp.concatenate([q_lat, q_pe], axis=2)         # [q_lat | q_pe]
-    o_lat = attend(l, q, row, None)                        # [B, heads, rank]
+    o_lat = attend(l, q, row, chosen_by)                   # [B, heads, rank]
     with jax.named_scope("out"):
         o = dot("bhr,hdr->bhd", o_lat, laid[1]) if laid \
             else dot("bhr,rhd->bhd", o_lat, up[..., d:])
-        return _mm(o.reshape(bb, heads * d), p("wo"))
+        return _mm(o.reshape(bb, heads * dv), p("wo"))
 
 
 def token_logits(params, cfg, tok, pos, attend, live, recur):

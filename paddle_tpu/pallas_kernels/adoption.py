@@ -44,7 +44,7 @@ __all__ = ["decide", "active_kernels", "reset", "interpret_mode",
 
 # the kernel families sharing this funnel
 KERNELS = ("fused_ln", "flash_attention", "paged_attention", "ssm_update",
-           "moe_experts", "latent_attention", "kda_update")
+           "moe_experts", "latent_attention", "kda_update", "index_scores")
 
 _lock = threading.Lock()
 _active = set()          # kernels that engaged >= 1 time this process

@@ -114,6 +114,28 @@ with a traced index the compiler must assume that a copy into one buffer
 and a load from the other touch the same memory, and keeps them in program
 order (1.5 us a chunk; 1.27 with constants: PERF.md section 6, PR 50).
 
+A latent layer that **selects** (a model whose ``index_topk`` is set)
+attends the positions a learned indexer scores highest and no others, in
+three calls.  ``index_scores`` scores every cached position of a lane: the
+indexer's queries ``[B, J, E]`` against the lane's index keys, which lie in
+a pool of their own beside the latent one (``[num_blocks, block_size, E]``,
+on the same tables), a ReLU a head and the heads' weighted sum -> ``[B,
+positions]`` float32, ``-inf`` past a lane's context.  A kernel
+(``INDEX_KERNEL_NAME``) whose grid walks the lanes and which walks a lane's
+live blocks in chunks of ``INDEX_CHUNK_TOKENS`` positions through two
+buffers, every chunk's copies issued straight-line and a slot past the
+lane's last block fetching that block again (masked), as the latent form's
+straight-line body does; the gather of the padded table and
+``dense_index_scores`` elsewhere.  ``choose`` takes the exact ``top_k`` of
+those scores (ties to the lower position; no approximation: an approximate
+set is another model); a lane at or under ``k`` positions chooses all it
+has.  ``selected_latent_attention`` then reads the chosen rows alone: where
+the latent kernel serves, the rows are gathered by (block, offset) into
+contiguous blocks (scope ``kv_gather``) and the kernel's body runs over
+them as over a lane's context (``kv_read``); elsewhere the whole table is
+gathered and what was not chosen is masked (``masked_latent``'s ``chosen``),
+which is the same mathematics and what the unpaged loop computes too.
+
 ``masked_attention`` is also the core of the UNPAGED reference loop in
 decode_model.py: sharing it is what makes paged-vs-unpaged decode
 bitwise-comparable on the CPU tier.
@@ -138,13 +160,18 @@ __all__ = ["paged_attention", "paged_attention_reference",
            "latent_attention", "latent_attention_reference",
            "latent_attention_checks", "latent_path", "masked_latent",
            "LATENT_KERNEL_NAME", "chunk_positions",
-           "latent_chunk_positions"]
+           "latent_chunk_positions", "index_scores", "dense_index_scores",
+           "index_scores_checks", "index_path", "choose", "chosen_mask",
+           "selected_latent_attention", "selected_latent_path",
+           "INDEX_KERNEL_NAME", "INDEX_CHUNK_TOKENS"]
 
 # the name the kernel's executions carry in a device trace
 KERNEL_NAME = "paged_attention"
 # ... and those of its latent form (one pool, the value the key's first
 # columns): a name of its own, so that a reader of one never sums the other
 LATENT_KERNEL_NAME = "latent_attention"
+# ... and those of the kernel that scores a lane's cached index keys
+INDEX_KERNEL_NAME = "index_scores"
 
 _MASK = -1e30  # finite: a fully-masked lane softmaxes to uniform, not NaN
 
@@ -198,7 +225,8 @@ def ring_mask(context_lens, ring_len, window):
                       ring_len, window)
 
 
-def masked_attention(q, k, v, context_lens, scale=None, window=None):
+def masked_attention(q, k, v, context_lens, scale=None, window=None,
+                     chosen=None):
     """Single-token attention over a contiguous history: q [B, H, D],
     k/v [B, S, KH, D] with ``H`` a multiple of ``KH`` (grouped queries:
     query head ``r`` reads KV head ``r // (H // KH)``), context_lens [B]
@@ -206,8 +234,9 @@ def masked_attention(q, k, v, context_lens, scale=None, window=None):
     output is then ``[B, H, Dv]``).  Positions >= the context length are masked; scores are
     multiplied by ``scale`` (None: ``1 / sqrt(D)``).  With ``window`` the
     ``S`` rows are a ring (``ring_mask``) and the last ``window`` positions
-    alone are attended.  Shared by the paged gather path AND the unpaged
-    reference loop so the two stay bitwise-comparable.
+    alone are attended; with ``chosen`` ([B, S] bool) only the positions it
+    marks, among those the context holds.  Shared by the paged gather path
+    AND the unpaged reference loop so the two stay bitwise-comparable.
 
     Both contractions run over the folded minor dimension KH * D, against
     a block-diagonal query: row r of ``qx`` holds head r's query in the D
@@ -239,6 +268,8 @@ def masked_attention(q, k, v, context_lens, scale=None, window=None):
         seen = pos < context_lens[:, None].astype(jnp.int32)
     else:
         seen = ring_mask(context_lens, s, window)
+    if chosen is not None:
+        seen = seen & chosen
     sc = jnp.where(seen[:, None, :], sc, _MASK)
     p = jax.nn.softmax(sc, axis=-1)
     out = dot("bhs,bsc->bhc", p.astype(v.dtype), v.reshape(b, s, kh * dv))
@@ -884,13 +915,15 @@ def paged_attention(q, k_cache, v_cache, block_tables, context_lens,
 
 # -- the latent form ---------------------------------------------------------
 
-def masked_latent(q, rows, context_lens, scale, rank):
+def masked_latent(q, rows, context_lens, scale, rank, chosen=None):
     """Absorbed latent attention over a contiguous history: q [B, H, W]
     against ``rows`` [B, S, W], one cached head whose value is its first
-    ``rank`` columns -> [B, H, rank].  ``masked_attention`` with that one
+    ``rank`` columns -> [B, H, rank]; ``chosen`` [B, S] bool given, over the
+    positions it marks alone.  ``masked_attention`` with that one
     head, so the gather path and the unpaged loop stay bitwise-comparable."""
     rows = rows[:, :, None, :]
-    return masked_attention(q, rows, rows[..., :rank], context_lens, scale)
+    return masked_attention(q, rows, rows[..., :rank], context_lens, scale,
+                            chosen=chosen)
 
 
 def latent_attention_reference(q, pool, block_tables, context_lens, scale,
@@ -1073,3 +1106,244 @@ def latent_attention(q, pool, block_tables, context_lens, scale, rank):
                                   rank)
     return latent_attention_reference(q, pool, block_tables, context_lens,
                                       scale, rank)
+
+
+# -- a latent layer that selects ---------------------------------------------
+
+# positions a chunk of the index kernel spans: 64 blocks of 16 keys, 262,144
+# B of 128 bfloat16 values a key in each of two buffers
+INDEX_CHUNK_TOKENS = 1024
+
+# by name, so that a check can score with it taken out
+# (benchmark/tests/chip_check_glm.py): a head's activation
+_index_act = jax.nn.relu
+
+
+def dense_index_scores(qi, w, keys, context_lens):
+    """The indexer's scores over a contiguous history: ``qi`` [B, J, E] its
+    queries and ``w`` [B, J] their heads' weights (float32), ``keys`` [B, S,
+    E] the cached index keys -> [B, S] float32, ``sum_j w_j relu(qi_j .
+    key(s))``, ``-inf`` at and past ``context_lens``.  The keys' dtype is the
+    products' input dtype (a bfloat16 pool rounds the queries once), the
+    accumulation, the ReLU and the heads' sum float32.  Shared by the gather
+    path and the unpaged loop."""
+    sc = jnp.einsum("bjd,bsd->bjs", qi.astype(keys.dtype), keys,
+                    precision=jax.lax.Precision.HIGHEST,
+                    preferred_element_type=jnp.float32)
+    out = jnp.sum(_index_act(sc) * w.astype(jnp.float32)[:, :, None], axis=1)
+    pos = jnp.arange(keys.shape[1], dtype=jnp.int32)[None, :]
+    return jnp.where(pos < context_lens.astype(jnp.int32)[:, None], out,
+                     -jnp.inf)
+
+
+def _index_chunk_blocks(block_size, maxb):
+    return max(1, min(INDEX_CHUNK_TOKENS // block_size, maxb))
+
+
+def index_scores_checks(q_shape, pool_shape, pool_dtype, maxb=1):
+    """Ordered (reason, ok) pairs for adoption.decide(): what the index
+    kernel needs of the queries ``[B, J, E]`` and of the pool ``[num_blocks,
+    block_size, E]`` in ``pool_dtype``, under a table of ``maxb`` slots."""
+    dims = tuple(q_shape) + tuple(pool_shape) + (maxb,)
+    static = all(isinstance(x, int) and x >= 0 for x in dims)
+    rank3 = len(q_shape) == 3 and len(pool_shape) == 3
+    tile = _SUBLANES.get(jnp.dtype(pool_dtype).name)
+    shaped = static and rank3 and all(x > 0 for x in dims)
+    return [
+        ("backend", adoption.interpret_mode()
+         or jax.default_backend() == "tpu"),
+        ("symbolic_shape", static),
+        ("rank", rank3),
+        ("dtype", tile is not None),
+        # a key is whole 128-lane tiles, the queries' heads whole sublanes
+        ("lanes", shaped and q_shape[2] == pool_shape[2]
+         and pool_shape[2] % 128 == 0 and q_shape[1] % 8 == 0),
+        ("block_size", static and rank3 and tile is not None
+         and pool_shape[1] > 0 and pool_shape[1] % tile == 0),
+        ("empty", shaped),
+        # two chunk buffers, a lane's scores twice, its queries
+        ("vmem", shaped and tile is not None
+         and 2 * _index_chunk_blocks(pool_shape[1], maxb) * pool_shape[1]
+         * pool_shape[2] * jnp.dtype(pool_dtype).itemsize
+         + 8 * (maxb * pool_shape[1] + INDEX_CHUNK_TOKENS)
+         + 8 * q_shape[1] * (q_shape[2] + 128) <= _VMEM_BUDGET),
+    ]
+
+
+def index_path(q_shape, pool_shape, pool_dtype, maxb=1):
+    """``"pallas"`` where the index kernel would serve these shapes on this
+    backend, else ``"gather"``: ``index_scores``'s rule, counted nowhere."""
+    ok = all(ok for _reason, ok in
+             index_scores_checks(q_shape, pool_shape, pool_dtype, maxb))
+    return "pallas" if ok else "gather"
+
+
+def _index_kernel(bt_ref, cl_ref, q_ref, w_ref, pool, o_ref, buf, sem, *,
+                  block_size, maxb, per):
+    """One lane a grid step: its queries ``q_ref`` [1, J, E] and weights
+    ``w_ref`` [1, J, 1] against its live blocks of ``pool``, ``per`` blocks
+    a chunk through the two buffers of ``buf``, the next chunk in flight
+    while this one is scored -> ``o_ref`` [1, 1, positions] (whole chunks
+    long).  Every chunk issues all its copies: a slot past the lane's last
+    block fetches that block again, and its positions are masked."""
+    b = pl.program_id(0)
+    span = per * block_size
+    ctx = cl_ref[b]
+    held = jnp.minimum((ctx + block_size - 1) // block_size, maxb)
+    n = (held + per - 1) // per
+
+    def copy(slot, i, block):
+        return pltpu.make_async_copy(
+            pool.at[block], buf.at[slot, pl.ds(i * block_size, block_size)],
+            sem.at[slot])
+
+    def start(c, slot):
+        base, last = b * maxb, held - 1
+        for i in range(per):
+            copy(slot, i,
+                 bt_ref[base + jnp.minimum(c * per + i, last)]).start()
+
+    def wait(slot):
+        # a wait needs the copy's shape and semaphore, not its source
+        for i in range(per):
+            copy(slot, i, 0).wait()
+
+    o_ref[...] = jnp.full(o_ref.shape, -jnp.inf, o_ref.dtype)
+    pl.when(n > 0)(lambda: start(0, 0))
+    qx = q_ref[0]                         # [J, E]
+    wx = w_ref[0]                         # [J, 1]
+
+    def chunk(c, carry):
+        slot = c % 2
+        pl.when(c + 1 < n)(lambda: start(c + 1, 1 - slot))
+        wait(slot)
+        sc = _product(qx, buf[slot], _NT)                 # [J, span]
+        out = jnp.sum(_index_act(sc) * wx, axis=0, keepdims=True)
+        pos = c * span + jax.lax.broadcasted_iota(jnp.int32, (1, span), 1)
+        o_ref[0, :, pl.ds(pl.multiple_of(c * span, span), span)] = \
+            jnp.where(pos < ctx, out, -jnp.inf)
+        return carry
+
+    jax.lax.fori_loop(0, n, chunk, None)
+
+
+def _index_pallas(qi, w, pool, block_tables, context_lens, interpret=None):
+    """qi [B, J, E], w [B, J] against one pool [num_blocks, block_size, E]
+    -> [B, maxb * block_size] float32."""
+    bb, heads, width = qi.shape
+    bs = pool.shape[1]
+    maxb = block_tables.shape[1]
+    per = _index_chunk_blocks(bs, maxb)
+    span = per * bs
+    padded = -(-maxb * bs // span) * span
+    if interpret is None:
+        interpret = adoption.interpret()
+    mine = lambda i, bt, cl: (i, 0, 0)
+    out = pl.pallas_call(
+        functools.partial(_index_kernel, block_size=bs, maxb=maxb, per=per),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(bb,),
+            in_specs=[pl.BlockSpec((1, heads, width), mine),
+                      pl.BlockSpec((1, heads, 1), mine),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((1, 1, padded), mine),
+            scratch_shapes=[pltpu.VMEM((2, span, width), pool.dtype),
+                            pltpu.SemaphoreType.DMA((2,))],
+        ),
+        out_shape=jax.ShapeDtypeStruct((bb, 1, padded), jnp.float32),
+        name=INDEX_KERNEL_NAME,
+        interpret=interpret,
+    )(block_tables.astype(jnp.int32).reshape(-1),
+      context_lens.astype(jnp.int32), qi.astype(jnp.float32),
+      w.astype(jnp.float32)[:, :, None], pool)
+    return out[:, 0, :maxb * bs]
+
+
+def index_scores(qi, w, pool, block_tables, context_lens):
+    """The indexer's scores of every position a lane's table names: ``qi``
+    [B, J, E], ``w`` [B, J], ``pool`` [num_blocks, block_size, E] -> [B,
+    maxb * block_size] float32, ``-inf`` at and past a lane's context
+    (``dense_index_scores``'s mathematics).  The kernel where the shape rule
+    admits it (``adoption.decide`` counts the lowering as
+    ``index_scores``), the gather of the padded table otherwise."""
+    use, _reason = adoption.decide(
+        "index_scores", index_scores_checks(
+            qi.shape, pool.shape, pool.dtype, block_tables.shape[1]))
+    if use:
+        return _index_pallas(qi, w, pool, block_tables, context_lens)
+    return dense_index_scores(qi, w, gather_blocks(pool, block_tables),
+                              context_lens)
+
+
+def choose(scores, context_lens, k):
+    """The ``k`` positions of largest score a lane (exact; of equal scores
+    the lower position first), ``scores`` [B, S] with ``-inf`` past the
+    context -> (``positions`` [B, min(k, S)] int32, ``count`` [B] int32:
+    the leading ``min(context_len, k)`` of a lane's row are chosen, what
+    follows names positions past its context).  A lane at or under ``k``
+    positions chooses all it has."""
+    k = min(int(k), scores.shape[1])
+    _best, positions = jax.lax.top_k(scores, k)
+    return positions.astype(jnp.int32), \
+        jnp.minimum(context_lens.astype(jnp.int32), k)
+
+
+def chosen_mask(positions, count, length):
+    """``choose``'s result as [B, length] bool: the positions chosen."""
+    lanes = jnp.arange(positions.shape[0], dtype=jnp.int32)[:, None]
+    valid = jnp.arange(positions.shape[1], dtype=jnp.int32)[None, :] \
+        < count[:, None]
+    return jnp.zeros((positions.shape[0], length), bool) \
+        .at[lanes, positions].set(valid)
+
+
+def _selected_checks(q_shape, pool_shape, pool_dtype, rank, k):
+    """``latent_attention_checks`` for the kernel over ``k`` gathered rows a
+    lane, which must be whole blocks."""
+    bs = pool_shape[1]
+    whole = isinstance(k, int) and bs > 0 and k % bs == 0 and k > 0
+    gathered = (q_shape[0] * max(k // max(bs, 1), 1), bs, pool_shape[2])
+    return latent_attention_checks(q_shape, gathered, pool_dtype, rank) \
+        + [("selection", whole)]
+
+
+def selected_latent_path(q_shape, pool_shape, pool_dtype, rank, k):
+    """``"pallas"`` where ``selected_latent_attention`` gathers the chosen
+    rows and runs the latent kernel over them, ``"gather"`` where it gathers
+    the whole table and masks."""
+    ok = all(ok for _reason, ok in
+             _selected_checks(q_shape, pool_shape, pool_dtype, rank, k))
+    return "pallas" if ok else "gather"
+
+
+def selected_latent_attention(q, pool, block_tables, context_lens,
+                              positions, count, scale, rank):
+    """``latent_attention`` over the chosen positions alone (``choose``'s
+    ``positions`` [B, k] and ``count`` [B]).  Where the latent kernel serves:
+    the chosen rows gathered by (block, offset) into ``k / block_size``
+    contiguous blocks a lane (scope ``kv_gather``) and the kernel's body run
+    over them in that order, a lane's ``count`` leading ones (``kv_read``).
+    Elsewhere: the whole table gathered and what was not chosen masked."""
+    bb, k = positions.shape
+    bs = pool.shape[1]
+    use, _reason = adoption.decide(
+        "latent_attention",
+        _selected_checks(q.shape, pool.shape, pool.dtype, rank, k))
+    if use:
+        with jax.named_scope("kv_gather"):
+            blocks = jnp.take_along_axis(jnp.maximum(block_tables, 0),
+                                         positions // bs, axis=1)
+            # rows of the pool read as [num_blocks * block_size, W] (the
+            # same bytes): one index a row gathers 6% faster than two
+            rows = jnp.take(pool.reshape(-1, pool.shape[2]),
+                            blocks * bs + positions % bs, axis=0,
+                            mode="clip")                   # [B, k, W]
+            rows = rows.reshape(bb * k // bs, bs, pool.shape[2])
+            tables = jnp.arange(bb * k // bs, dtype=jnp.int32).reshape(bb, -1)
+        with jax.named_scope("kv_read"):
+            return _latent_pallas(q, rows, tables, count, scale, rank)
+    with jax.named_scope("kv_gather"):
+        rows = gather_blocks(pool, block_tables)
+    return masked_latent(q, rows, context_lens, scale, rank,
+                         chosen_mask(positions, count, rows.shape[1]))

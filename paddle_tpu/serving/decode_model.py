@@ -41,7 +41,11 @@ windows and a matrix state a head a sequence, moved by a gated delta rule
 attention served absorbed, which keeps ONE row a token (a compressed K/V
 and the key's shared part, ``latent_rank + latent_rope`` values) in a pool
 of its own width on the global block tables, read as key and as value both
-(``paged_attention.latent_attention``).  A hybrid block names its layers'
+(``paged_attention.latent_attention``); a latent layer that *selects*
+(``cfg.index_topk``) keeps an indexer's key a token in a second pool beside
+it and attends the positions the indexer scores highest and no others
+(``paged_attention.index_scores``, ``choose``,
+``selected_latent_attention``).  A hybrid block names its layers'
 kinds one by one, two or three of them in one model (its
 ``FAMILY.kinds``).  Every step builder below serves every
 family through one contract (``_block``), so there is one paged step, one
@@ -91,8 +95,10 @@ from ..pallas_kernels import kda_update as _kda
 from ..pallas_kernels import moe_experts as _moe
 from ..pallas_kernels import paged_attention as _pa
 from ..pallas_kernels import ssm_update as _ssm
-from ..pallas_kernels.paged_attention import gather_blocks, \
-    latent_attention, masked_attention, masked_latent, paged_attention
+from ..pallas_kernels.paged_attention import choose, chosen_mask, \
+    dense_index_scores, gather_blocks, index_scores, latent_attention, \
+    masked_attention, masked_latent, paged_attention, \
+    selected_latent_attention
 from . import kv_cache as _kv
 
 __all__ = ["DecoderConfig", "init_decoder_params", "save_decoder",
@@ -108,7 +114,7 @@ __all__ = ["DecoderConfig", "init_decoder_params", "save_decoder",
 
 # the families, a module each: ``models/<arch>.py``
 ARCHS = ("gpt2", "olmoe", "granite_hybrid", "lfm2_moe", "exaone_moe",
-         "nemotron_h", "kimi_linear", "dots_vlm", "smallthinker")
+         "nemotron_h", "kimi_linear", "dots_vlm", "smallthinker", "glm_dsa")
 LAYER_KINDS = ("attention", "mamba", "conv", "window", "experts", "kda",
                "latent")
 # recurrent kind -> the name its slot goes by in spans, gauges and counters
@@ -204,6 +210,11 @@ class DecoderConfig:
     layer ``experts`` ReLU-gated experts of width ``ffn`` chosen by a softmax
     router that reads the attention's input; no shared expert, no dense
     lead, no share; an untied head and a stream of its own width.
+    ``glm_dsa`` is the block of ``models/glm_dsa.py``: ``dots_vlm``'s with
+    plain rotation, a head's values ``v_head_dim`` wide beside its
+    ``head_dim`` own key values, and latent layers that select: an indexer
+    of ``index_heads`` heads of ``index_head_dim`` scores every cached
+    position and the attention reads the ``index_topk`` best.
 
     ``kv_heads`` None means ``heads`` (multi-head); ``layer_types`` None
     means ``layers`` attention layers.  ``dtype`` is the weights' (``f32``
@@ -221,7 +232,9 @@ class DecoderConfig:
                  "routed_scaling", "window", "experts_held", "expert_first",
                  "shared_ffn", "hidden_size", "ssm_groups", "kda_heads",
                  "kda_head_dim", "kda_conv", "latent_rank", "latent_rope",
-                 "q_rank", "n_group", "topk_group", "rope_scaling")
+                 "q_rank", "n_group", "topk_group", "rope_scaling",
+                 "v_head_dim", "index_heads", "index_head_dim",
+                 "index_topk")
 
     def __init__(self, vocab, layers, heads, head_dim, ffn=None,
                  max_seq=64, arch="gpt2", dtype="f32", kv_dtype=None,
@@ -235,7 +248,8 @@ class DecoderConfig:
                  expert_first=0, shared_ffn=0, hidden_size=None,
                  ssm_groups=1, kda_heads=0, kda_head_dim=0, kda_conv=0,
                  latent_rank=0, latent_rope=0, q_rank=0, n_group=1,
-                 topk_group=1, rope_scaling=None):
+                 topk_group=1, rope_scaling=None, v_head_dim=None,
+                 index_heads=0, index_head_dim=0, index_topk=0):
         if arch not in ARCHS:
             raise ValueError("decoder arch must be %s: %r"
                              % ("|".join(ARCHS), arch))
@@ -297,6 +311,10 @@ class DecoderConfig:
         self.topk_group = int(topk_group)
         self.rope_scaling = None if rope_scaling is None \
             else {k: float(rope_scaling[k]) for k in YARN_KEYS}
+        self.v_head_dim = int(v_head_dim or head_dim)
+        self.index_heads = int(index_heads)
+        self.index_head_dim = int(index_head_dim)
+        self.index_topk = int(index_topk)
         if self.hidden_size not in (None, self.heads * self.head_dim) \
                 and not family.own_stream_width:
             raise ValueError("the %s block's stream is heads * head_dim "
@@ -340,6 +358,23 @@ class DecoderConfig:
                 "q_rank and rope_scaling are for the %s blocks' latent "
                 "layers, whose latent_rope values turn in pairs: %r, %r"
                 % (_declaring("rotated_latent"), q_rank, rope_scaling))
+        if self.v_head_dim != self.head_dim and set(self.layer_types) - {
+                "latent", "kda", "experts"}:
+            raise ValueError(
+                "v_head_dim is a latent layer's: a head of attention and "
+                "window layers has values as wide as its keys: %r for %r"
+                % (v_head_dim, head_dim))
+        indexer = (self.index_heads, self.index_head_dim, self.index_topk)
+        selects = all(x > 0 for x in indexer)
+        if bool(family.selects) != selects or any(indexer) and not (
+                selects and self.q_rank and self.latent_layers
+                and self.index_head_dim >= self.latent_rope):
+            raise ValueError(
+                "index_heads, index_head_dim and index_topk >= 1 are for "
+                "the %s blocks and for no other, whose latent layers select "
+                "by an indexer that reads the compressed query (q_rank) and "
+                "turns latent_rope of a head's values: %r"
+                % (_declaring("selects"), indexer))
         if not 1 <= self.topk_group <= self.n_group or (
                 self.n_group > 1 and (
                     not family.grouped_router
@@ -499,6 +534,9 @@ class DecoderConfig:
     def to_dict(self):
         d = {s: getattr(self, s) for s in self.__slots__}
         d["layer_types"] = list(self.layer_types)
+        if self.v_head_dim == self.head_dim:
+            # not a width of its own: it follows ``head_dim`` (``replace``)
+            d["v_head_dim"] = None
         return d
 
     def replace(self, **changes):
@@ -511,7 +549,9 @@ def cache_config(cfg, block_size, num_blocks, dtype=None, state_slots=0):
     one sequence's slot holds (``_state_shapes``: a window and a state for
     ``mamba`` and ``kda`` layers, a window alone for ``conv`` layers), in
     ``state_slots`` slots (slot 0 the idle lanes' scratch), for its latent
-    layers one pool each, ``latent_width`` values a token, and for its
+    layers one pool each, ``latent_width`` values a token (and, where they
+    select, an index pool each beside it, ``index_head_dim`` values a
+    token), and for its
     window layers as many rings (a sequence holds a ring as it holds a
     slot: one a lane and the scratch), each ``cfg.window`` positions
     long.  A layer of a kind that keeps nothing (``experts``) is counted
@@ -525,7 +565,9 @@ def cache_config(cfg, block_size, num_blocks, dtype=None, state_slots=0):
         window_layers=len(cfg.window_layers), window=cfg.window,
         window_slots=state_slots if cfg.window_layers else 0,
         latent_layers=len(cfg.latent_layers),
-        latent_width=cfg.latent_width if cfg.latent_layers else 0)
+        latent_width=cfg.latent_width if cfg.latent_layers else 0,
+        index_layers=len(cfg.latent_layers) if cfg.index_topk else 0,
+        index_width=cfg.index_head_dim if cfg.index_topk else 0)
 
 
 def _conv_window(cfg):
@@ -663,7 +705,11 @@ def _block(cfg):
     ``attend(l, q, k, v)`` the KV write + history attention of attention
     layer ``l`` (of a ``latent`` layer: ``q`` [B, H, W] the absorbed query,
     ``k`` [B, W] this token's row, ``v`` None -> the probabilities' sum of
-    the rows' first ``latent_rank`` columns, [B, H, latent_rank]), and
+    the rows' first ``latent_rank`` columns, [B, H, latent_rank]; of one that
+    selects, ``v`` is ``(qi [B, J, E], w [B, J], ki [B, E])``: the indexer's
+    queries, their heads' weights and this token's index key, which
+    ``attend`` writes beside the row before it scores the lane's keys,
+    chooses ``cfg.index_topk`` positions and attends those alone), and
     ``recur`` what the recurrent layers keep
     (``recur.window(l, x)`` pushes this token's convolution input and
     returns the newest ``taps``; for a kind with a state,
@@ -699,20 +745,31 @@ def attention_path(cfg, kv_config, lanes=1, kind="attention"):
     every smaller one); ``"gather"`` where it gathers the padded table
     (the int8 residency always does).  ``kind`` ``"window"`` asks it of the
     window layers, whose table is their ring, and ``"latent"`` of the latent
-    layers and their form of the kernel."""
-    if kind == "latent":
-        return _pa.latent_path(
-            (lanes, cfg.heads, kv_config.latent_row),
+    layers and their form of the kernel (of a model that selects: the kernel
+    over the chosen rows, gathered first); ``"index"`` of the kernel that
+    scores a selecting model's cached index keys."""
+    maxb = -(-cfg.max_seq // kv_config.block_size)
+    pool_dtype = _kv._PAYLOAD[kv_config.dtype][0]
+    if kind == "index":
+        return _pa.index_path(
+            (lanes, cfg.index_heads, cfg.index_head_dim),
             (kv_config.num_blocks, kv_config.block_size,
-             kv_config.latent_row),
-            _kv._PAYLOAD[kv_config.dtype][0], cfg.latent_rank)
+             kv_config.index_width), pool_dtype, maxb)
+    if kind == "latent":
+        q = (lanes, cfg.heads, kv_config.latent_row)
+        pool = (kv_config.num_blocks, kv_config.block_size,
+                kv_config.latent_row)
+        if cfg.index_topk:
+            return _pa.selected_latent_path(
+                q, pool, pool_dtype, cfg.latent_rank,
+                min(cfg.index_topk, maxb * kv_config.block_size))
+        return _pa.latent_path(q, pool, pool_dtype, cfg.latent_rank)
     windowed = kind == "window"
     return _pa.attention_path(
         (lanes, cfg.heads, cfg.head_dim),
         (kv_config.window_blocks if windowed else kv_config.num_blocks,
          kv_config.block_size, kv_config.heads * kv_config.head_dim),
-        _kv._PAYLOAD[kv_config.dtype][0],
-        ring=kv_config.window_ring if windowed else 0)
+        pool_dtype, ring=kv_config.window_ring if windowed else 0)
 
 
 def chunk_positions(cfg, kv_config, lanes=1):
@@ -735,10 +792,13 @@ def chunk_positions(cfg, kv_config, lanes=1):
             continue
         if kind == "latent":
             row = kv_config.latent_row
+            # a model that selects walks the chosen rows, gathered
+            held = min(cfg.index_topk // kv_config.block_size, maxb) \
+                if cfg.index_topk else maxb
             out[kind] = _pa.latent_chunk_positions(
                 (lanes, cfg.heads, row), (kv_config.num_blocks,
                                           kv_config.block_size, row),
-                dtype, cfg.latent_rank, maxb)
+                dtype, cfg.latent_rank, held)
         else:
             ring = kv_config.window_ring if kind == "window" else 0
             out[kind] = _pa.chunk_positions(
@@ -876,7 +936,8 @@ def make_paged_step(cfg, kv_config):
     logits) and then the block's extras, if it has any (``_block``).
 
     ``kv_carry`` is ``PagedKVCache.carry()``: per-layer pools, K then V
-    (then their scales for int8) for the attention layers and then the
+    (then their scales for int8) for the attention layers, the latent
+    layers' pools (and their index pools), the window layers' and then the
     recurrent layers' window and state slots, donated by ``CarriedStepFn``
     and written in place.  All shapes are static per lane bucket:
     tok/pos/context_lens [B], block_tables [B, MAXB].  ``context_lens[b]``
@@ -938,6 +999,7 @@ def make_paged_step(cfg, kv_config):
         offs = pos % bs
         pools, state = kv_config.groups(kv_carry)
         lpools = kv_config.latent_pools(kv_carry)
+        ipools = kv_config.index_pools(kv_carry)
         wpools = kv_config.window_groups(kv_carry)
 
         def block_of(tables, slot):
@@ -961,6 +1023,21 @@ def make_paged_step(cfg, kv_config):
                 with jax.named_scope("kv_write"):
                     lpools[i] = _write_rows(lpools[i], written, offs,
                                             _widened(k, row))
+                if v is not None:
+                    # the layer selects: score the lane's index keys (this
+                    # token's among them), choose, attend the chosen rows
+                    qi, w, ki = v
+                    with jax.named_scope("index"):
+                        ipools[i] = _write_rows(ipools[i], written, offs, ki)
+                        scores = index_scores(qi, w, ipools[i], block_tables,
+                                              context_lens)
+                    with jax.named_scope("select"):
+                        positions, count = choose(scores, context_lens,
+                                                  cfg.index_topk)
+                    return selected_latent_attention(
+                        _widened(q, row), lpools[i], block_tables,
+                        context_lens, positions, count, cfg.latent_scale,
+                        cfg.latent_rank)
                 return latent_attention(_widened(q, row), lpools[i],
                                         block_tables, context_lens,
                                         cfg.latent_scale, cfg.latent_rank)
@@ -1014,8 +1091,8 @@ def make_paged_step(cfg, kv_config):
         logits, extras = block(params, cfg, tok, pos, attend,
                                context_lens > 0, recur)
         nxt = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        return (tuple(a for group in pools + [lpools] + wpools + state
-                      for a in group), nxt, logits) + tuple(extras)
+        return (tuple(a for group in pools + [lpools, ipools] + wpools
+                      + state for a in group), nxt, logits) + tuple(extras)
 
     return step
 
@@ -1120,7 +1197,9 @@ def make_paged_step_multi(cfg, kv_config, width):
     and every column of a live lane has to be a real token (a chunk of
     prefill; its state cannot be rolled back, which is why the engine
     refuses it speculation).  A ring holds one write beside its window, so
-    a model with window layers has no multi-token step."""
+    a model with window layers has no multi-token step.  A latent layer
+    that selects does so a query: each column is the single step, which
+    scores, chooses and attends over its own prefix."""
     if cfg.window_layers:
         raise ValueError("a multi-token step is not planned over window "
                          "layers' rings")
@@ -1205,7 +1284,10 @@ def make_unpaged_step(cfg, pad_len, ring_len=None):
     gathered ring is ``window_ring * block_size`` long, and the bitwise
     comparison wants that).  A model with latent layers carries their rows
     after the global K and V, ``[Ll, B, pad_len, latent_row]``, a row as the
-    paged pool holds it.  A model with recurrent layers carries their
+    paged pool holds it, and where they select their index keys after those,
+    ``[Ll, B, pad_len, index_head_dim]`` (scored densely, the positions not
+    chosen masked: the paged gather path's mathematics).  A model with
+    recurrent layers carries their
     window ``[Lr, B, (K - 1) * W]`` and state ``[Lr, B, N, I]`` last, a
     lane a row (the window alone where the layers keep no state), through
     the same ``_Recurrent`` as the paged step."""
@@ -1221,7 +1303,7 @@ def make_unpaged_step(cfg, pad_len, ring_len=None):
         context_lens = context_lens.astype(jnp.int32)
         # K and V of the global layers, then the latent layers' rows, then
         # K and V of the window layers
-        first = 3 if latent else 2
+        first = 2 + bool(latent) + bool(latent and cfg.index_topk)
         kv = list(kv_carry[:first + (2 if windowed else 0)])
         state = list(kv_carry[len(kv):])
         lanes = jnp.arange(kv[0].shape[1], dtype=jnp.int32)
@@ -1232,6 +1314,17 @@ def make_unpaged_step(cfg, pad_len, ring_len=None):
                 row = kv[2].shape[-1]
                 kv[2] = kv[2].at[i, lanes, pos].set(
                     _widened(k, row).astype(kv[2].dtype))
+                if v is not None:
+                    qi, w, ki = v
+                    kv[3] = kv[3].at[i, lanes, pos].set(
+                        ki.astype(kv[3].dtype))
+                    positions, count = choose(
+                        dense_index_scores(qi, w, kv[3][i], context_lens),
+                        context_lens, cfg.index_topk)
+                    return masked_latent(
+                        _widened(q, row), kv[2][i], context_lens,
+                        cfg.latent_scale, cfg.latent_rank,
+                        chosen_mask(positions, count, kv[2].shape[2]))
                 return masked_latent(_widened(q, row), kv[2][i],
                                      context_lens, cfg.latent_scale,
                                      cfg.latent_rank)
@@ -1278,6 +1371,9 @@ def _unpaged_carry(cfg, lanes, pad_len, ring_len=None):
         carry += (jnp.zeros((len(cfg.latent_layers), lanes, pad_len,
                              _kv.latent_row_of(cfg.latent_width)),
                             kv_dtype),)
+        if cfg.index_topk:
+            carry += (jnp.zeros((len(cfg.latent_layers), lanes, pad_len,
+                                 cfg.index_head_dim), kv_dtype),)
     if cfg.window_layers:
         carry += tuple(jnp.zeros((len(cfg.window_layers), lanes,
                                   ring_len or cfg.window, cfg.kv_heads,

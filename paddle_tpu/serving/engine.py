@@ -909,7 +909,7 @@ class _DecodeModel:
     __slots__ = ("name", "cfg", "params", "kv_config", "cache", "stepfn",
                  "maxb", "attn_path", "window_path", "experts_path",
                  "state_path", "blocks_read", "window_read", "chunks_read",
-                 "step_ms", "prefix",
+                 "index_path", "index_read", "step_ms", "prefix",
                  "declines", "slot_bytes", "state_name", "feed0",
                  "columns", "idle_lane", "upload",
                  "__weakref__",
@@ -936,6 +936,12 @@ class _DecodeModel:
         # and how many of them are full); empty for a model with no latent
         # layer (add_model sets it)
         self.chunks_read = {}
+        # of a model whose latent layers select: how a layer's indexer
+        # scores the cached index keys ("pallas" | "gather"), and lens ->
+        # the blocks of its index pool that then walks; None otherwise
+        # (add_model sets both)
+        self.index_path = None
+        self.index_read = None
         # the same of the window layers' attention over their rings
         # (``window_read``: lens -> the blocks one such layer fetches of a
         # ring, would fetch of the whole table, and the chunks it walks),
@@ -1277,6 +1283,11 @@ class DecodeEngine:
         # take: an executable compiled for one is never restored for the
         # other
         paths = {"latent_attention" if latent else "attention": attn_path}
+        index_path = _dm.attention_path(
+            cfg, kv_config, max(self.buckets), "index") \
+            if cfg.index_topk else None
+        if index_path:
+            paths["index_scores"] = index_path
         window_path = _dm.attention_path(
             cfg, kv_config, max(self.buckets), "window") if windowed else None
         if windowed:
@@ -1320,6 +1331,11 @@ class DecodeEngine:
                                 kv_config.window_ring, span)[0])
         entry.experts_path = experts_path
         entry.state_path = state_path
+        if index_path:
+            entry.index_path = index_path
+            entry.index_read = functools.partial(
+                _pa.blocks_read, block_size=kv_config.block_size,
+                maxb=entry.maxb, path=index_path)
         entry.blocks_read = functools.partial(
             _pa.blocks_read, block_size=kv_config.block_size,
             maxb=entry.maxb, path=attn_path)
@@ -1355,6 +1371,10 @@ class DecodeEngine:
         if latent:
             _tm.set_gauge("latent_pool_bytes", kv_config.latent_layers
                           * _kvc.latent_block_bytes(kv_config) * n,
+                          model=name)
+        if index_path:
+            _tm.set_gauge("index_pool_bytes", kv_config.index_layers
+                          * _kvc.index_block_bytes(kv_config) * n,
                           model=name)
         _tm.set_gauge("decode_weights_laid_out_bytes",
                       sum(int(v.nbytes) for v in laid.values()), model=name)
@@ -1469,6 +1489,12 @@ class DecodeEngine:
                 extra["window_ring"] = m.kv_config.window_ring
             if m.cfg.latent_layers:
                 extra["latent_attention"] = m.attn_path
+            if m.index_path:
+                # latent layers that select: how the indexer's scores are
+                # read, and how many positions are chosen
+                # (``latent_attention`` above is the chosen rows' read)
+                extra["index_path"] = m.index_path
+                extra["index_topk"] = m.cfg.index_topk
             # positions a chunk of the attention kernel spans, by kind of
             # layer that takes it
             extra["chunk_positions"] = _dm.chunk_positions(
@@ -2779,7 +2805,11 @@ class DecodeEngine:
             # blocks a layer's attention fetches this step, of the slots
             # the table has: the live context's share where the kernel
             # reads in place, all of them where the table is gathered
-            read = {"kv_blocks_read": m.blocks_read(lens),
+            # (latent layers that select attend a lane's chosen rows, the
+            # ``index_topk`` best at most)
+            attended = np.minimum(lens, m.cfg.index_topk) if m.index_path \
+                else lens
+            read = {"kv_blocks_read": m.blocks_read(attended),
                     "kv_table_slots": bucket * m.maxb,
                     "kv_block_size": m.kv_config.block_size} \
                 if _tr.enabled() else {}
@@ -2790,7 +2820,17 @@ class DecodeEngine:
                 # ... in so many chunks, each a whole chunk's arithmetic to
                 # the kernel; of the full ones all of it is of use
                 read["latent_chunks"], read["latent_full_chunks"] = \
-                    m.chunks_read[bucket](lens)
+                    m.chunks_read[bucket](attended)
+            if m.index_path and read:
+                # what the selection did: the blocks of a layer's index
+                # pool the scores walked, the rows attention then read of
+                # those the contexts hold, and the lanes past ``index_topk``
+                # (on which alone the two differ)
+                read["index_blocks_read"] = m.index_read(lens)
+                read["latent_rows_selected"] = int(attended.sum())
+                read["latent_rows_in_context"] = int(lens.sum())
+                read["sparse_lanes"] = int(
+                    (lens > m.cfg.index_topk).sum())
             if slots is not None:
                 # lanes at position 0 start their slot from zeros
                 resets = int((pos[:len(lanes)] == 0).sum())

@@ -103,6 +103,7 @@ from ..core import telemetry as _tm
 __all__ = ["KVCacheConfig", "BlockAllocator", "SlotAllocator",
            "WindowRing", "PagedKVCache", "PrefixCache",
            "plan_num_blocks", "block_bytes", "latent_block_bytes",
+           "index_block_bytes",
            "slot_bytes", "state_bytes",
            "window_bytes",
            "engine_owned_kv_bytes",
@@ -164,17 +165,28 @@ class KVCacheConfig:
     the rest zeros: the only form in which a kernel can fetch a block from
     the pool where it lies (Mosaic fetches whole tiles of the chip's tiled
     HBM layout; asked for a 576-wide row XLA copies the whole pool into
-    that layout, 640 wide, around every call: PERF.md section 6, PR 46)."""
+    that layout, 640 wide, around every call: PERF.md section 6, PR 46).
+
+    Index pools: ``index_layers`` of the latent layers (none, or every one:
+    the layers of a model whose latent attention selects its positions)
+    hold beside their latent pool a second one, the indexer's key of each
+    token, ``index_width`` values held as they are (the published 128 are
+    a whole 128-lane tile; a narrower pool is gathered, as a narrower
+    latent row would be), ``[num_blocks, block_size, index_width]`` in the
+    same residency.
+    One block id names a block in both: whatever shares, frees, exports or
+    adopts a block carries both rows of it."""
 
     __slots__ = ("layers", "heads", "head_dim", "block_size", "num_blocks",
                  "dtype", "state_layers", "state_shapes", "state_slots",
                  "window_layers", "window", "window_slots", "latent_layers",
-                 "latent_width")
+                 "latent_width", "index_layers", "index_width")
 
     def __init__(self, layers, heads, head_dim, block_size, num_blocks,
                  dtype="f32", state_layers=0, state_shapes=(),
                  state_slots=0, window_layers=0, window=0, window_slots=0,
-                 latent_layers=0, latent_width=0):
+                 latent_layers=0, latent_width=0, index_layers=0,
+                 index_width=0):
         if dtype not in _PAYLOAD:
             raise ValueError("kv_cache dtype must be f32|bf16|int8: %r"
                              % (dtype,))
@@ -183,6 +195,12 @@ class KVCacheConfig:
                 "a latent pool is f32|bf16 and latent_width >= 1 wide "
                 "(int8 residency scales a head's values, and a latent row "
                 "has no heads): %r, %r" % (dtype, latent_width))
+        if index_layers and (index_layers != latent_layers
+                             or index_width < 1):
+            raise ValueError(
+                "index pools lie one beside every latent pool, "
+                "index_width >= 1 wide: %r of width %r beside %r latent "
+                "layers" % (index_layers, index_width, latent_layers))
         if block_size <= 0 or num_blocks <= 1:
             raise ValueError("need block_size > 0 and num_blocks > 1 "
                              "(block 0 is the idle-lane scratch)")
@@ -201,6 +219,8 @@ class KVCacheConfig:
         self.window_slots = int(window_slots)
         self.latent_layers = int(latent_layers)
         self.latent_width = int(latent_width)
+        self.index_layers = int(index_layers)
+        self.index_width = int(index_width)
         if self.window_layers and (self.window < 1 or self.window_slots <= 1):
             raise ValueError("window layers need window >= 1 and "
                              "window_slots > 1 (a ring a lane and the "
@@ -236,14 +256,15 @@ class KVCacheConfig:
     def _cuts(self, carry):
         carry = list(carry)
         cut = self.kv_groups * self.layers
-        first = cut + self.latent_layers
+        first = cut + self.latent_layers + self.index_layers
         wcut = first + self.kv_groups * self.window_layers
         held = len(self.state_shapes) * self.state_layers
         if len(carry) != wcut + held:
             raise ValueError("a carry of %d arrays is not this cache's "
-                             "(%d KV + %d latent + %d window + %d state)"
+                             "(%d KV + %d latent + %d index + %d window + "
+                             "%d state)"
                              % (len(carry), cut, self.latent_layers,
-                                wcut - first, held))
+                                self.index_layers, wcut - first, held))
         return carry, cut, wcut
 
     def groups(self, carry):
@@ -252,8 +273,8 @@ class KVCacheConfig:
         pools (``[k, v]``, and ``[k, v, k_scales, v_scales]`` for int8
         residency), ``state`` one group of ``state_layers`` per-layer
         arrays for each entry of ``state_shapes``.  The latent layers'
-        pools and then the window layers' lie between the two
-        (``latent_pools``, ``window_groups``)."""
+        pools, their index pools and then the window layers' lie between
+        the two (``latent_pools``, ``index_pools``, ``window_groups``)."""
         carry, cut, wcut = self._cuts(carry)
         kv = [carry[i:i + self.layers]
               for i in range(0, cut, self.layers or 1)]
@@ -266,8 +287,8 @@ class KVCacheConfig:
         ``kv`` is: ``window_layers`` arrays a group."""
         carry, cut, wcut = self._cuts(carry)
         return [carry[i:i + self.window_layers]
-                for i in range(cut + self.latent_layers, wcut,
-                               self.window_layers or 1)]
+                for i in range(cut + self.latent_layers + self.index_layers,
+                               wcut, self.window_layers or 1)]
 
     @property
     def latent_row(self):
@@ -278,6 +299,20 @@ class KVCacheConfig:
         """The latent layers' pools of a carry, one a layer."""
         carry, cut, _wcut = self._cuts(carry)
         return carry[cut:cut + self.latent_layers]
+
+    @property
+    def index_places(self):
+        """Where the index pools lie in a carry: a ``range`` of its
+        places (empty for a model whose latent attention does not
+        select)."""
+        at = self.kv_groups * self.layers + self.latent_layers
+        return range(at, at + self.index_layers)
+
+    def index_pools(self, carry):
+        """The latent layers' index pools of a carry, one a layer."""
+        carry, _cut, _wcut = self._cuts(carry)
+        places = self.index_places
+        return carry[places.start:places.stop]
 
 
 def latent_row_of(width):
@@ -302,11 +337,19 @@ def latent_block_bytes(config):
         * _PAYLOAD[config.dtype][1]
 
 
+def index_block_bytes(config):
+    """HBM bytes ONE block costs in one index pool: an index key a
+    token."""
+    return config.block_size * config.index_width \
+        * _PAYLOAD[config.dtype][1]
+
+
 def block_bytes(config):
-    """HBM bytes ONE block costs across all (global) attention layers and
-    all latent layers."""
+    """HBM bytes ONE block costs across all (global) attention layers, all
+    latent layers and their index pools."""
     return config.layers * _layer_block_bytes(config) \
-        + config.latent_layers * latent_block_bytes(config)
+        + config.latent_layers * latent_block_bytes(config) \
+        + config.index_layers * index_block_bytes(config)
 
 
 def window_bytes(config):
@@ -876,7 +919,9 @@ class PagedKVCache:
 
     The latent layers' pools (``[num_blocks, block_size, latent_row]``,
     one a layer, on ``allocator``'s blocks) follow the global K/V groups in
-    the carry, and the window layers' pools follow those, in the K/V
+    the carry, their index pools (``[num_blocks, block_size,
+    index_width]``, on the same blocks) follow them, and the window layers'
+    pools follow those, in the K/V
     groups' order, ``[window_blocks, block_size, heads * head_dim]``
     each; ``window_allocator`` (None without window layers) hands out their
     blocks, one id for every window layer alike, through a sequence's
@@ -905,6 +950,10 @@ class PagedKVCache:
                                config.latent_row),
                               _PAYLOAD[config.dtype][0])
                     for _ in range(config.latent_layers)) \
+            + tuple(jnp.zeros((config.num_blocks, config.block_size,
+                               config.index_width),
+                              _PAYLOAD[config.dtype][0])
+                    for _ in range(config.index_layers)) \
             + pools(config.window_blocks, config.window_layers)
         self._carry += tuple(
             jnp.zeros((config.state_slots,) + shape, _PAYLOAD[dt][0])
@@ -947,13 +996,15 @@ class PagedKVCache:
         """What a block's export and import frame, a group of per-layer
         pools after another as they lie at the head of the carry: K then V
         (then their scales for int8) of the attention layers, then the
-        latent layers' pools, a row a token (a model with recurrent state or
+        latent layers' pools, a row a token, then their index pools, a key
+        a token (a model with recurrent state or
         rings is refused both by the engine, before it gets here) ->
         [(the group's pools, one block of it on the wire)]: every layer's
         rows with the heads split, ``[layers, block_size, heads,
         head_dim]`` (scales ``[layers, block_size, heads]``; latent rows
         as the pool holds them, ``[latent_layers, block_size,
-        latent_row]``)."""
+        latent_row]``; index keys ``[index_layers, block_size,
+        index_width]``)."""
         c = self.config
         groups, _state = c.groups(self._carry)
         out = [(tuple(g), (c.layers, c.block_size, c.heads)
@@ -962,13 +1013,16 @@ class PagedKVCache:
         if c.latent_layers:
             out.append((tuple(c.latent_pools(self._carry)),
                         (c.latent_layers, c.block_size, c.latent_row)))
+        if c.index_layers:
+            out.append((tuple(c.index_pools(self._carry)),
+                        (c.index_layers, c.block_size, c.index_width)))
         return out
 
     def export_block(self, block):
         """Host copies of one physical block, one array per carry group:
         ``[k, v]`` for f32 and bf16 residency, ``[k, v, k_scales, v_scales]`` for
-        int8, each stacked over the layers in its wire shape, and then the
-        latent layers' rows.  The wire
+        int8, each stacked over the layers in its wire shape, then the
+        latent layers' rows and then their index keys.  The wire
         payload IS the residency payload — prefill's compiled step is
         deterministic, so an adopted block is bitwise-identical to the
         one the decode replica would have computed itself."""

@@ -22,8 +22,8 @@ import pytest
 
 import paddle_tpu as fluid
 from paddle_tpu.core import telemetry as _tm
-from paddle_tpu.models import dots_vlm, exaone_moe, granite_hybrid, \
-    kimi_linear, lfm2_moe, nemotron_h, olmoe, smallthinker
+from paddle_tpu.models import dots_vlm, exaone_moe, glm_dsa, \
+    granite_hybrid, kimi_linear, lfm2_moe, nemotron_h, olmoe, smallthinker
 from paddle_tpu.serving import DecodeEngine
 from paddle_tpu.serving import decode_model as dm
 from paddle_tpu.serving import kv_cache as kvc
@@ -251,6 +251,15 @@ _SMALLTHINKER = dm.DecoderConfig(
     hidden_size=48, ffn=16, max_seq=96,
     layer_types=("attention", "window", "window", "window") * 2, window=8,
     experts=16, experts_per_token=3, rope_theta=1.5e6, norm_eps=1e-6)
+# a head's own key part 12 wide and its value 16 (the published 192 and 256
+# in their ratio), an indexer of 4 heads of 16 that keeps 8 positions: the
+# suite's prompt of 11 tokens is past the selection from its ninth token on
+_GLM = dm.DecoderConfig(
+    arch="glm_dsa", vocab=97, layers=4, heads=4, head_dim=12, v_head_dim=16,
+    hidden_size=48, max_seq=64, layer_types=("latent",) * 4, latent_rank=24,
+    latent_rope=8, q_rank=20, index_heads=4, index_head_dim=16, index_topk=8,
+    dense_layers=1, dense_ffn=64, ffn=24, shared_ffn=24, experts=16,
+    experts_per_token=3, routed_scaling=2.5, rope_theta=1e6)
 _GRANITE_G4 = _GRANITE.replace(kv_heads=1)
 
 # Weights are normal(0, 0.3) (OLMoE's 0.05) and a router bias of 0.05: at
@@ -350,6 +359,22 @@ ROWS = {row.arch: row for row in (
         # window layer's ring of 257 blocks is walked in such chunks
         chunk=("smallthinker-21b-a3b-serve.json", 25120,
                {"attention": 256, "window": 256})),
+    # every layer pages (a latent row and an index key a token): nothing is
+    # declined
+    Row("glm_dsa",
+        _both(_GLM, glm_dsa.init_params, std=0.3, bias_std=0.05),
+        multi_atol=1e-5, batch_dependent_bf16=True,
+        entry=dict(attn_path="gather", index_path="gather",
+                   experts_path={4: "einsum"}, state_path={}, declines=None),
+        serve=("glm-5-serve.json",
+               dict(layer_types=_GLM.layer_types, experts=16,
+                    experts_held=4, expert_first=4, experts_per_token=3,
+                    q_rank=20, latent_rank=24, latent_rope=8, head_dim=12,
+                    v_head_dim=16, index_heads=4, index_head_dim=16,
+                    index_topk=8, hidden=48, ffn=24)),
+        # the kernel walks the 2,048 chosen rows, gathered: rows of 640
+        # bfloat16, 1,280 B a position
+        chunk=("glm-5-serve.json", 25120, {"latent": 512})),
 )}
 assert tuple(ROWS) == dm.ARCHS
 
@@ -394,7 +419,8 @@ def run_paged(cfg, params, seqs, width=1, blocks=40, block_size=BS,
     if dirty is not None:
         pools, state = kv.groups(cache.carry())
         cache.replace_carry(tuple(
-            a for g in pools + [kv.latent_pools(cache.carry())]
+            a for g in pools + [kv.latent_pools(cache.carry()),
+                             kv.index_pools(cache.carry())]
             + kv.window_groups(cache.carry()) for a in g)
             + tuple(jnp.full_like(a, dirty) for g in state for a in g))
     make = dm.make_packed_step(cfg, kv, b) if feed \

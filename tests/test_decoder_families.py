@@ -407,7 +407,8 @@ def test_a_block_is_exported_and_adopted_with_every_layers_rows(
         row, key, cache_dir, telemetry_on):
     """What a block's frame holds is every paging layer's rows of it: K and
     V stacked over the attention layers, a latent pool's rows stacked over
-    the latent layers (never read as K and V).  A float32 session is
+    the latent layers (never read as K and V), and where those select their
+    index keys after them.  A float32 session is
     exported with its history blocks and an exported block adopted under a
     digest is matched by the next prompt that hashes to it; bfloat16 has no
     frame (the codec names dtypes as numpy does) and is refused as such."""
@@ -432,7 +433,9 @@ def test_a_block_is_exported_and_adopted_with_every_layers_rows(
             assert shapes == [(kv.layers, BS, kv.heads, kv.head_dim)] * (
                 2 if kv.layers else 0) + [
                 (kv.latent_layers, BS, kv.latent_row)] * bool(
-                    kv.latent_layers)
+                    kv.latent_layers) + [
+                (kv.index_layers, BS, kv.index_width)] * bool(
+                    kv.index_layers)
             # the first block of the sequence: its first four rows
             assert all(np.abs(a.astype(np.float32)).sum() > 0 for a in block)
             if key == "bf16":

@@ -98,6 +98,10 @@ _ELIGIBLE = {
         (32, 32, 640), (12832, 16, 640), "bfloat16", 512),
     "kda_update": lambda: kda_update.kda_update_checks(
         (33, 128, 4096), "float32", 32, 32),
+    # GLM-5's index pool (keys of 128) under tables of 784 slots, 32 index
+    # heads a lane
+    "index_scores": lambda: pa.index_scores_checks(
+        (32, 32, 128), (25120, 16, 128), "bfloat16", 784),
 }
 
 RULE = {
@@ -144,6 +148,26 @@ RULE = {
         "moe_experts", _ELIGIBLE["moe_experts"], False, "backend"),
     "latent_attention-backend": (
         "latent_attention", _ELIGIBLE["latent_attention"], False, "backend"),
+    "latent_attention-selection": (
+        # 2,040 chosen rows a lane are no whole blocks of 16: the whole
+        # table is gathered and what was not chosen masked
+        "latent_attention", lambda: pa._selected_checks(
+            (32, 64, 640), (25120, 16, 640), "bfloat16", 512, 2040), True,
+        "selection"),
+    "index_scores-backend": (
+        "index_scores", _ELIGIBLE["index_scores"], False, "backend"),
+    "index_scores-lanes": (
+        # a key of 64 values is half a tile
+        "index_scores", lambda: pa.index_scores_checks(
+            (32, 32, 64), (25120, 16, 64), "bfloat16", 784), True, "lanes"),
+    "index_scores-dtype": (
+        "index_scores", lambda: pa.index_scores_checks(
+            (32, 32, 128), (25120, 16, 128), "int8", 784), True, "dtype"),
+    "index_scores-vmem": (
+        # a lane's scores twice over a table of a million positions
+        "index_scores", lambda: pa.index_scores_checks(
+            (32, 32, 128), (1 << 17, 16, 128), "bfloat16", 1 << 16), True,
+        "vmem"),
     "latent_attention-lanes": (
         # a row of 576 is four and a half tiles: the cache holds it 640 wide
         "latent_attention", lambda: pa.latent_attention_checks(

@@ -863,6 +863,76 @@ def test_dots_vlm_step_compiles_its_two_kernels_at_published_shapes(
     assert not found, found
 
 
+def test_glm_dsa_step_compiles_its_three_kernels_at_published_shapes(
+        one_chip, as_on_tpu):
+    """GLM-5's published widths, its dense lead and two routed layers, bucket
+    32, the cell's pools (25,120 bf16 blocks: latent rows of 576 values held
+    640 wide and index keys of 128 beside them): Mosaic accepts, inside the
+    whole step as the engine compiles it (``make_packed_step``), the kernel
+    that scores a lane's cached index keys (32 heads of 128 over chunks of
+    1,024 positions under tables of 784 slots), the latent form of the
+    paged-attention kernel at 64 query rows of 640 over the 2,048 chosen
+    rows a lane, gathered into 4,096 contiguous blocks, and the routed-expert
+    kernel over 16 held experts of 6144 x 2048; the choice is an exact
+    ``top_k`` (no approximation); every pool is aliased whole, and no pool
+    is copied, turned or converted."""
+    from benchmark.models import glm_dsa_decoder
+    from paddle_tpu.pallas_kernels import moe_experts as moe
+
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "glm-5-serve.json")) as fp:
+        config = dict(json.load(fp), num_hidden_layers=3)
+    cfg = glm_dsa_decoder.decoder_config(config)
+    assert (cfg.hidden, cfg.heads, cfg.head_dim, cfg.v_head_dim,
+            cfg.latent_rank, cfg.latent_rope, cfg.q_rank, cfg.index_heads,
+            cfg.index_head_dim, cfg.index_topk, cfg.experts,
+            cfg.experts_held, cfg.ffn, cfg.dense_ffn, cfg.layer_types,
+            cfg.routed_layers, cfg.max_seq) == (
+        6144, 64, 192, 256, 512, 64, 2048, 32, 128, 2048, 256, 16, 2048,
+        12288, ("latent",) * 3, (1, 2), 12544)
+    lanes, block_size, blocks = 32, 16, 25120
+    kv = dm.cache_config(cfg, block_size, blocks)
+    assert (kv.layers, kv.latent_layers, kv.latent_row, kv.index_layers,
+            kv.index_width, kv.state_layers) == (0, 3, 640, 3, 128, 0)
+    assert dm.attention_path(cfg, kv, lanes, "latent") == "pallas"
+    assert dm.attention_path(cfg, kv, lanes, "index") == "pallas"
+    assert dm.chunk_positions(cfg, kv, lanes) == {"latent": 512}
+    assert moe.experts_path(lanes, (16, 6144, 2048), jnp.bfloat16) \
+        == "pallas"
+
+    on_chip = functools.partial(_placed, one_chip)
+    carry = on_chip(jax.eval_shape(lambda: PagedKVCache(kv).carry()))
+    params = on_chip(_as_held(cfg, glm_dsa_decoder.param_shapes(config)))
+    feeds = on_chip(_packed_feeds(kv, lanes, cfg.max_seq // block_size))
+    compiled = jax.jit(dm.make_packed_step(cfg, kv, lanes),
+                       donate_argnums=(0,)
+                       ).lower(carry, params, *feeds).compile()
+
+    text = compiled.as_text()
+    assert len(re.findall(r"%index_scores\S* = ", text)) == 3
+    assert len(re.findall(r"%latent_attention\S* = ", text)) == 3
+    assert _expert_kernels(text) == 2
+    assert _kernel_calls(text) == 8
+    assert "ApproxTopK" not in text and "approx" not in text.lower()
+    assert not _expert_passes(text, 16, 6144, 2048)
+    assert params["l0_wkvb_k"].shape == (64, 512, 192) \
+        and params["l0_wkvb_v"].shape == (64, 256, 512)
+    assert _weights_relaid(text) == []
+    pool_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in carry)
+    assert pool_bytes == 3 * 25120 * 16 * (640 + 128) * 2
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= pool_bytes
+    # beside the arguments: a layer's gathered rows (32 x 2,048 x 640
+    # bfloat16, 84e6 B), its scores (32 x 12,544 float32) and the sort's
+    # operands; under one latent pool
+    assert memory.temp_size_in_bytes < 25120 * 16 * 640 * 2
+    big = re.compile(r" = bf16\[25120,16,(640|128)\]\S* "
+                     r"(copy|transpose|convert)\(")
+    found = [line.strip()[:160] for line in text.splitlines()
+             if big.search(line)]
+    assert not found, found
+
+
 def test_data_parallel_bert_layer_runs_fused_ln_per_shard_on_v5e_2x2(
         topo, as_on_tpu):
     """BERT-base's widths (hidden 768, bf16 AMP, dropout 0.1), one layer,

@@ -74,7 +74,20 @@ routed layers, every one latent: the scopes ``layerN/latent/q_compress``,
 ``latent/absorb``, ``latent/rope``, ``latent/kv_write``, ``latent/kv_read``
 and ``latent/out``, ``mlp``, ``moe/router``, ``moe/experts``,
 ``moe/shared`` and ``lm_head``; the bytes and the attention's operations are
-``benchmark/dots_cost.py``'s.  ``layerN/staged`` (any configuration) is what
+``benchmark/dots_cost.py``'s.  For ``--config glm-5-serve --blocks 25120
+--contexts lo-hi`` the latent layers select: the scopes gain
+``layerN/latent/index`` (the indexer's projections and rotation, the index
+key's write and the ``index_scores`` kernel), ``latent/select`` (the exact
+top-k) and ``latent/kv_gather`` (the chosen rows' gather) before
+``latent/kv_read`` (the latent kernel over them); the index pools are filled
+with seeded keys, so that the chosen rows lie scattered as a served
+sequence's do; the result gives ``selection``: the index kernel's own ms a
+step and bytes/s over the live blocks' keys, and the rows chosen of those in
+context (``benchmark/glm_cost.py``).  ``--latent-read dense`` is the control:
+the latent kernel walks every live block of a lane, as the masked form of
+the selected read would (which adds the index and the choice to it; here
+nothing reads them and the compiler drops both), not an option of the
+program.  ``layerN/staged`` (any configuration) is what
 XLA puts in itself around a layer's weights and names nothing: a weight
 relaid for the product that reads it, or fetched ahead of it.
 
@@ -237,7 +250,8 @@ def scope_of(op_name):
                      "lm_head", "kv_write", "kv_read", "kv_gather",
                      "ssm", "in_proj", "conv", "state_update", "out_proj",
                      "window", "kda", "state", "out", "latent", "absorb",
-                     "shared", "q_compress", "rope", "staged")]
+                     "shared", "q_compress", "rope", "staged", "index",
+                     "select")]
     return "/".join(keep) or "other"
 
 
@@ -379,8 +393,9 @@ def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
     import jax
     import numpy as np
 
-    from benchmark import dots_cost, exaone_cost, kimi_cost, lfm2_cost, \
-        moe_cost, nemotron_cost, smallthinker_cost, ssm_cost, trace_reduce
+    from benchmark import dots_cost, exaone_cost, glm_cost, kimi_cost, \
+        lfm2_cost, moe_cost, nemotron_cost, smallthinker_cost, ssm_cost, \
+        trace_reduce
     from paddle_tpu.core import telemetry
     from paddle_tpu.core.executor import CarriedStepFn
     from paddle_tpu.pallas_kernels import kda_update, paged_attention, \
@@ -582,8 +597,10 @@ def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
     if cfg.latent_layers:
         # the rows the latent layers fetched at the profiled steps' contexts
         # (the values of a row, not the width its pool holds it in)
+        selects = bool(cfg.index_topk) and args.latent_read == "served"
         read = paged_attention.blocks_read(
-            now, args.block_size,
+            np.minimum(now, cfg.index_topk) if selects else now,
+            args.block_size,
             cfg.max_seq // args.block_size, result["attention"])
         cost = kimi_cost if "linear_attn_config" in config else dots_cost
         moved = cost.latent_floor_bytes_per_step(config, read,
@@ -600,6 +617,22 @@ def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
                 result["latent_attention_kernel_flops_per_s"] = \
                     dots_cost.latent_flops_per_step(
                         config, read, args.block_size) / (ms / 1e3)
+    if cfg.index_topk:
+        walked = paged_attention.blocks_read(
+            now, args.block_size, cfg.max_seq // args.block_size,
+            dm.attention_path(cfg, kv, b, "index"))
+        moved = glm_cost.index_floor_bytes_per_step(config, walked,
+                                                    args.block_size)
+        ms = kernel_ms(paged_attention.INDEX_KERNEL_NAME)
+        result["selection"] = {
+            "latent_read": args.latent_read,
+            "index_path": dm.attention_path(cfg, kv, b, "index"),
+            "index_blocks_read": walked, "index_bytes_per_step": moved,
+            "index_kernel_ms_per_step": ms,
+            "index_kernel_bytes_per_s": moved / (ms / 1e3) if ms else None,
+            "rows_selected": int(np.minimum(now, cfg.index_topk).sum()),
+            "rows_in_context": int(now.sum()),
+            "sparse_lanes": int((now > cfg.index_topk).sum())}
     stats = device.memory_stats() or {}
     result["peak_bytes_in_use"] = stats.get("peak_bytes_in_use")
     result["peak_bytes_reserved"] = stats.get("peak_bytes_reserved")
@@ -617,6 +650,10 @@ def main(argv=None):
     ap.add_argument("--ssm-update", default="step", choices=("step", "xla"),
                     help="xla: the state update as gather, update, scatter "
                     "(what the step does off the TPU) in place of the kernel")
+    ap.add_argument("--latent-read", default="served",
+                    choices=("served", "dense"),
+                    help="dense: a selecting model's latent kernel walks "
+                    "every live block (the masked control's walk)")
     ap.add_argument("--blocks", type=int, default=1024)
     ap.add_argument("--bucket", type=int, default=32)
     ap.add_argument("--block-size", type=int, default=16)
@@ -683,6 +720,18 @@ def main(argv=None):
                          state_slots=args.bucket + 1)
     cache = kvc.PagedKVCache(kv)
     b, maxb = args.bucket, cfg.max_seq // args.block_size
+    if cfg.index_topk:
+        # seeded index keys, so that the chosen rows lie scattered
+        carry = list(cache.carry())
+        for i in kv.index_places:
+            carry[i] = jax.random.normal(
+                jax.random.PRNGKey(i), carry[i].shape, carry[i].dtype)
+        cache.replace_carry(carry)
+        if args.latent_read == "dense":
+            dm.selected_latent_attention = (
+                lambda q, pool, tables, lens, _positions, _count, scale,
+                rank: dm.latent_attention(q, pool, tables, lens, scale,
+                                          rank))
 
     # every lane mid-sequence, its blocks its own, as in the cell's window:
     # contexts from a third of what a lane's share of the pool holds up to
