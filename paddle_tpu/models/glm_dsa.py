@@ -189,9 +189,11 @@ def token_logits(params, cfg, tok, pos, attend, live, recur=None, seen=None):
     router's one group).  Scope names: ``layer<i>/latent/`` + ``q_compress``,
     ``index`` (the indexer's projections and rotation, the index row's write
     and the scores), ``absorb``, ``rope``, ``kv_write``, ``select`` (the
-    choice), ``kv_gather`` (the chosen rows' gather, or the whole table's
-    where no kernel serves) and ``kv_read`` (the kernel over the chosen
-    rows), ``out``; ``layer<i>/mlp`` on dense layers,
+    choice), ``mask`` (the chosen positions laid out for the kernel) and
+    ``kv_read`` (the kernel over a lane's live blocks under that mask), or,
+    under a table too wide for that walk, ``kv_gather`` (the chosen rows'
+    gather; the whole table's where no kernel serves) and ``kv_read`` (the
+    kernel over the gathered rows), ``out``; ``layer<i>/mlp`` on dense layers,
     ``layer<i>/moe/router``, ``.../moe/experts`` and ``.../moe/shared`` on
     routed ones; ``lm_head``.  ``seen`` as ``dots_vlm``'s."""
     eps = cfg.norm_eps

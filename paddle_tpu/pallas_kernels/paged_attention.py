@@ -129,12 +129,24 @@ straight-line body does; the gather of the padded table and
 ``dense_index_scores`` elsewhere.  ``choose`` takes the exact ``top_k`` of
 those scores (ties to the lower position; no approximation: an approximate
 set is another model); a lane at or under ``k`` positions chooses all it
-has.  ``selected_latent_attention`` then reads the chosen rows alone: where
-the latent kernel serves, the rows are gathered by (block, offset) into
-contiguous blocks (scope ``kv_gather``) and the kernel's body runs over
-them as over a lane's context (``kv_read``); elsewhere the whole table is
-gathered and what was not chosen is masked (``masked_latent``'s ``chosen``),
-which is the same mathematics and what the unpaged loop computes too.
+has.  ``selected_latent_attention`` then reads the chosen rows alone, one of
+three ways by the shapes (``selected_latent_path``; one constant,
+``_WALK_POSITIONS_PER_CHOSEN``).  Where the table holds few positions a
+chosen one (12,544 for 2,048), **the masked walk**: the chosen positions
+are laid out as a mask by chunk (``_chunk_mask``: two one-hots contracted
+on the MXU, no scatter; scope ``mask``) and the latent kernel walks the
+lane's own table and context with that one more operand, a lane's chunks of
+it in VMEM, so that a position counts where the context holds it AND it was
+chosen (``kv_read``): every live block is fetched, none twice, and no row
+is gathered.  It is the guarded body that takes the mask; a shape whose
+body is the straight-line one keeps the row form.  Under a wider table (the
+published 202,752 positions), **the row form**: the rows are gathered by
+(block, offset) into contiguous blocks (scope ``kv_gather``: XLA's gather
+costs by the count of its rows, 26 ns each) and the kernel's body runs over
+them as over a lane's context (``kv_read``).  Elsewhere the whole table is
+gathered and what was not chosen is masked (``masked_latent``'s
+``chosen``), which is the same mathematics and what the unpaged loop
+computes too.
 
 ``masked_attention`` is also the core of the UNPAGED reference loop in
 decode_model.py: sharing it is what makes paged-vs-unpaged decode
@@ -506,7 +518,7 @@ def _product(rows, x, dims):
 
 def _kernel(bt_ref, cl_ref, q_ref, *refs, heads, kv_heads, head_dim,
             block_size, maxb, per, scale, window=None, value_cols=None,
-            lane_grid=False):
+            lane_grid=False, masked=False):
     """``window`` None: a lane's chunks cover positions ``[0,
     context_len)``.  Given: the table is a ring of ``maxb`` slots and
     ``ring_mask``'s rule says which of its rows are attended; with ``per ==
@@ -517,7 +529,13 @@ def _kernel(bt_ref, cl_ref, q_ref, *refs, heads, kv_heads, head_dim,
     first ``value_cols`` columns.  ``lane_grid`` (of the latent form): the
     grid walks the lanes, ``q_ref`` and ``o_ref`` are one lane's, and the
     count of chunks fetched so far passes from a grid step to the next in
-    SMEM, as the chunk buffers and the copies in flight do in VMEM."""
+    SMEM, as the chunk buffers and the copies in flight do in VMEM.
+    ``masked`` (of the latent form, a layer that selects): one more operand
+    follows ``q_ref``, ``[lanes, chunks, span]`` int32 laid out by chunk (a
+    lane's under ``lane_grid``), and a position counts where the context
+    holds it AND its entry there is not 0."""
+    if masked:
+        mask_ref, *refs = refs
     if value_cols is None:
         k_hbm, v_hbm, o_ref, kbuf, vbuf, sem = refs
         pools = ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1))
@@ -663,6 +681,8 @@ def _kernel(bt_ref, cl_ref, q_ref, *refs, heads, kv_heads, head_dim,
                 jnp.int32, (1, span), 1)
             if window is None:
                 seen = pos < ctx
+                if masked:
+                    seen = seen & (mask_ref[mine, pl.ds(c, 1), :] != 0)
             elif whole:
                 # c is 0 and span the ring's length
                 seen = _in_window(ctx, pos, span, window)
@@ -1042,9 +1062,11 @@ def latent_path(q_shape, pool_shape, pool_dtype, rank):
 
 
 def _latent_pallas(q, pool, block_tables, context_lens, scale, rank,
-                   interpret=None):
+                   interpret=None, chosen=None):
     """q [B, H, W] against one pool [num_blocks, block_size, W] -> [B, H,
-    rank]."""
+    rank].  ``chosen`` given (``_chunk_mask``'s [B, chunks, span] int32, of a
+    layer that selects): over the positions it marks alone, of those a
+    lane's context holds; the guarded body takes it (``_walk_checks``)."""
     bb, h, width = q.shape
     bs = pool.shape[1]
     maxb = block_tables.shape[1]
@@ -1068,14 +1090,18 @@ def _latent_pallas(q, pool, block_tables, context_lens, scale, rank,
         else functools.partial(
             _kernel, heads=h, kv_heads=1, head_dim=width, block_size=bs,
             maxb=maxb, per=per, scale=float(scale), value_cols=rank,
-            lane_grid=lane_grid)
+            lane_grid=lane_grid, masked=chosen is not None)
+    # a layer that selects hands its mask in after the query: a lane's
+    # chunks of it, or every lane's, as the query's
+    masks = () if chosen is None else (chosen,)
     out = pl.pallas_call(
         kernel,
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(bb // held,),
-            in_specs=[pl.BlockSpec((held, rows, width), mine),
-                      pl.BlockSpec(memory_space=pl.ANY)],
+            in_specs=[pl.BlockSpec((held, rows, width), mine)]
+            + [pl.BlockSpec((held,) + m.shape[1:], mine) for m in masks]
+            + [pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((held, rows, rank), mine),
             scratch_shapes=[pltpu.VMEM((2, per * bs, width), pool.dtype),
                             pltpu.SemaphoreType.DMA(
@@ -1086,7 +1112,7 @@ def _latent_pallas(q, pool, block_tables, context_lens, scale, rank,
         name=LATENT_KERNEL_NAME,
         interpret=interpret,
     )(block_tables.astype(jnp.int32).reshape(-1),
-      context_lens.astype(jnp.int32), qx, pool)
+      context_lens.astype(jnp.int32), qx, *masks, pool)
     return out[:, :h]
 
 
@@ -1298,9 +1324,44 @@ def chosen_mask(positions, count, length):
         .at[lanes, positions].set(valid)
 
 
+# Positions of a lane's table a chosen position, up to which the selected
+# read walks the table under a mask and past which it gathers the chosen
+# rows.  The row form costs by the rows chosen whatever the lanes hold: XLA's
+# gather and the kernel over the gathered rows, 25.6 ns a chosen row (10.08 ms
+# over 6 layers of 65,536).  The walk costs by the positions the lanes hold,
+# the whole table's at the worst: 2.81 ns a position with every lane at the
+# table's end (the kernel 6.50 ms and the mask 0.26 over 6 layers of 32 x
+# 12,544).  25.6 / 2.81 = 9.1: up to 9 the walk is never the worse form,
+# whatever the lanes hold (PERF.md section 6, PR 57: the probe at GLM-5's
+# shapes, served and ``--latent-read gathered``, contexts 12,300-12,499)
+_WALK_POSITIONS_PER_CHOSEN = 9
+
+
+def _chunk_mask(positions, count, chunks, span):
+    """``choose``'s result as the latent kernel reads it: [B, chunks, span]
+    int32, 1 at position ``c * span + j`` where a lane chose it (``chosen_mask``'s
+    set, exactly).  No scatter: a position is a (row, column) of ``lo``
+    columns, and the rows' one-hot is contracted against the columns' over
+    the ``k`` choices on the MXU, the choices past ``count`` zeroed; a lane's
+    valid choices are distinct, so an entry is 0 or 1 and bfloat16 holds it
+    exactly."""
+    lanes, k = positions.shape
+    lo = CHUNK_TOKENS if span % CHUNK_TOKENS == 0 else span
+    valid = jnp.arange(k, dtype=jnp.int32)[None, :] < count[:, None]
+    row = (positions // lo)[:, :, None] == jnp.arange(
+        chunks * span // lo, dtype=jnp.int32)[None, None, :]
+    col = (positions % lo)[:, :, None] == jnp.arange(
+        lo, dtype=jnp.int32)[None, None, :]
+    hits = jnp.einsum("bkr,bkc->brc",
+                      (row & valid[:, :, None]).astype(jnp.bfloat16),
+                      col.astype(jnp.bfloat16),
+                      preferred_element_type=jnp.float32)
+    return (hits > 0).astype(jnp.int32).reshape(lanes, chunks, span)
+
+
 def _selected_checks(q_shape, pool_shape, pool_dtype, rank, k):
     """``latent_attention_checks`` for the kernel over ``k`` gathered rows a
-    lane, which must be whole blocks."""
+    lane, which must be whole blocks (the row form)."""
     bs = pool_shape[1]
     whole = isinstance(k, int) and bs > 0 and k % bs == 0 and k > 0
     gathered = (q_shape[0] * max(k // max(bs, 1), 1), bs, pool_shape[2])
@@ -1308,28 +1369,81 @@ def _selected_checks(q_shape, pool_shape, pool_dtype, rank, k):
         + [("selection", whole)]
 
 
-def selected_latent_path(q_shape, pool_shape, pool_dtype, rank, k):
-    """``"pallas"`` where ``selected_latent_attention`` gathers the chosen
-    rows and runs the latent kernel over them, ``"gather"`` where it gathers
-    the whole table and masks."""
-    ok = all(ok for _reason, ok in
-             _selected_checks(q_shape, pool_shape, pool_dtype, rank, k))
-    return "pallas" if ok else "gather"
+def _walk_checks(q_shape, pool_shape, pool_dtype, rank, k, maxb):
+    """``latent_attention_checks`` for the kernel over a lane's own table of
+    ``maxb`` slots under a mask of its ``k`` chosen positions (the masked
+    walk): the body that takes a mask, a table no longer than
+    ``_WALK_POSITIONS_PER_CHOSEN`` positions a chosen one, and room for the
+    mask beside what the kernel holds."""
+    base = latent_attention_checks(q_shape, pool_shape, pool_dtype, rank)
+    shaped = all(ok for reason, ok in base if reason != "backend") \
+        and isinstance(k, int) and isinstance(maxb, int) \
+        and k > 0 and maxb > 0
+    if not shaped:
+        return base + [("selection", False)]
+    bs = pool_shape[1]
+    span = latent_chunk_positions(q_shape, pool_shape, pool_dtype, rank, maxb)
+    # a lane's chunks of the mask, 32-bit in whole sublane tiles: every
+    # lane's, or (the grid walks the lanes) one lane's twice
+    held = 2 if _latent_lane_grid(q_shape, pool_shape, pool_dtype, rank) \
+        else q_shape[0]
+    chunks = -(-maxb * bs // span)
+    mask = held * 4 * span * (-(-chunks // 8) * 8)
+    return base + [
+        ("mask_body", not _latent_straight_line(q_shape, pool_dtype, rank)),
+        ("selection", maxb * bs <= _WALK_POSITIONS_PER_CHOSEN * k),
+        ("mask_vmem", latent_vmem_bytes(q_shape, pool_shape, pool_dtype,
+                                        rank) + mask <= _VMEM_BUDGET)]
+
+
+def _selected_form(q_shape, pool_shape, pool_dtype, rank, k, maxb):
+    """-> (``selected_latent_path``'s name, the checks ``adoption.decide``
+    is given): the masked walk where its checks pass, else the row form's."""
+    walk = _walk_checks(q_shape, pool_shape, pool_dtype, rank, k, maxb)
+    if all(ok for _reason, ok in walk):
+        return "pallas_masked", walk
+    rows = _selected_checks(q_shape, pool_shape, pool_dtype, rank, k)
+    return "pallas" if all(ok for _reason, ok in rows) else "gather", rows
+
+
+def selected_latent_path(q_shape, pool_shape, pool_dtype, rank, k, maxb):
+    """Which form ``selected_latent_attention`` takes for ``k`` chosen
+    positions under a table of ``maxb`` slots: ``"pallas_masked"`` where the
+    latent kernel walks a lane's live blocks under a mask of the chosen
+    positions, ``"pallas"`` where it gathers the chosen rows and runs the
+    kernel over them, ``"gather"`` where it gathers the whole table and
+    masks.  From the shapes alone."""
+    return _selected_form(q_shape, pool_shape, pool_dtype, rank, k, maxb)[0]
 
 
 def selected_latent_attention(q, pool, block_tables, context_lens,
                               positions, count, scale, rank):
     """``latent_attention`` over the chosen positions alone (``choose``'s
-    ``positions`` [B, k] and ``count`` [B]).  Where the latent kernel serves:
-    the chosen rows gathered by (block, offset) into ``k / block_size``
-    contiguous blocks a lane (scope ``kv_gather``) and the kernel's body run
-    over them in that order, a lane's ``count`` leading ones (``kv_read``).
-    Elsewhere: the whole table gathered and what was not chosen masked."""
+    ``positions`` [B, k] and ``count`` [B]), one of three ways by the shapes
+    (``selected_latent_path``).  The masked walk, where the table is short
+    beside ``k``: the chosen positions laid out as a mask by chunk (scope
+    ``mask``) and the kernel's body run over the lane's own table and
+    context, a position counted where the context holds it and it was chosen
+    (``kv_read``).  The row form, for a wider table: the chosen rows
+    gathered by (block, offset) into ``k / block_size`` contiguous blocks a
+    lane (scope ``kv_gather``) and the kernel's body run over them in that
+    order, a lane's ``count`` leading ones (``kv_read``).  Elsewhere: the
+    whole table gathered and what was not chosen masked."""
     bb, k = positions.shape
     bs = pool.shape[1]
-    use, _reason = adoption.decide(
-        "latent_attention",
-        _selected_checks(q.shape, pool.shape, pool.dtype, rank, k))
+    maxb = block_tables.shape[1]
+    form, checks = _selected_form(q.shape, pool.shape, pool.dtype, rank, k,
+                                  maxb)
+    use, _reason = adoption.decide("latent_attention", checks)
+    if use and form == "pallas_masked":
+        span = latent_chunk_positions(q.shape, pool.shape, pool.dtype, rank,
+                                      maxb)
+        with jax.named_scope("mask"):
+            chosen = _chunk_mask(positions, count, -(-maxb * bs // span),
+                                 span)
+        with jax.named_scope("kv_read"):
+            return _latent_pallas(q, pool, block_tables, context_lens, scale,
+                                  rank, chosen=chosen)
     if use:
         with jax.named_scope("kv_gather"):
             blocks = jnp.take_along_axis(jnp.maximum(block_tables, 0),

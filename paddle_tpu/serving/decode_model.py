@@ -746,7 +746,11 @@ def attention_path(cfg, kv_config, lanes=1, kind="attention"):
     (the int8 residency always does).  ``kind`` ``"window"`` asks it of the
     window layers, whose table is their ring, and ``"latent"`` of the latent
     layers and their form of the kernel (of a model that selects: the kernel
-    over the chosen rows, gathered first); ``"index"`` of the kernel that
+    under either of its forms of the selected read); ``"selected"`` names
+    that form (``paged_attention.selected_latent_path``: ``"pallas_masked"``
+    where the kernel walks a lane's live blocks under a mask of the chosen
+    positions, ``"pallas"`` where it reads the chosen rows, gathered first;
+    None for a model that does not select); ``"index"`` of the kernel that
     scores a selecting model's cached index keys."""
     maxb = -(-cfg.max_seq // kv_config.block_size)
     pool_dtype = _kv._PAYLOAD[kv_config.dtype][0]
@@ -755,15 +759,18 @@ def attention_path(cfg, kv_config, lanes=1, kind="attention"):
             (lanes, cfg.index_heads, cfg.index_head_dim),
             (kv_config.num_blocks, kv_config.block_size,
              kv_config.index_width), pool_dtype, maxb)
-    if kind == "latent":
+    if kind in ("latent", "selected"):
         q = (lanes, cfg.heads, kv_config.latent_row)
         pool = (kv_config.num_blocks, kv_config.block_size,
                 kv_config.latent_row)
         if cfg.index_topk:
-            return _pa.selected_latent_path(
+            form = _pa.selected_latent_path(
                 q, pool, pool_dtype, cfg.latent_rank,
-                min(cfg.index_topk, maxb * kv_config.block_size))
-        return _pa.latent_path(q, pool, pool_dtype, cfg.latent_rank)
+                min(cfg.index_topk, maxb * kv_config.block_size), maxb)
+            return form if kind == "selected" or form == "gather" \
+                else "pallas"
+        return None if kind == "selected" \
+            else _pa.latent_path(q, pool, pool_dtype, cfg.latent_rank)
     windowed = kind == "window"
     return _pa.attention_path(
         (lanes, cfg.heads, cfg.head_dim),
@@ -779,8 +786,11 @@ def chunk_positions(cfg, kv_config, lanes=1):
     bytes a position costs in that kind's pools; a window layer's is its
     whole ring where the ring is no longer than the longest chunk, and the
     same span as a context's where the ring is walked in chunks
-    (``paged_attention.chunk_positions``).  Kinds on the gather path have
-    no chunk and no entry."""
+    (``paged_attention.chunk_positions``).  A latent layer that selects
+    walks its own table in such chunks under the mask of its choice, or (a
+    table too wide for that: the row form) the ``index_topk`` rows it
+    gathered, which may cap the span.  Kinds on the gather path have no
+    chunk and no entry."""
     maxb = -(-cfg.max_seq // kv_config.block_size)
     dtype = _kv._PAYLOAD[kv_config.dtype][0]
     q = (lanes, cfg.heads, cfg.head_dim)
@@ -792,9 +802,11 @@ def chunk_positions(cfg, kv_config, lanes=1):
             continue
         if kind == "latent":
             row = kv_config.latent_row
-            # a model that selects walks the chosen rows, gathered
+            # a model that selects walks its table under a mask, or (the
+            # row form) the chosen rows, gathered
             held = min(cfg.index_topk // kv_config.block_size, maxb) \
-                if cfg.index_topk else maxb
+                if attention_path(cfg, kv_config, lanes, "selected") \
+                == "pallas" else maxb
             out[kind] = _pa.latent_chunk_positions(
                 (lanes, cfg.heads, row), (kv_config.num_blocks,
                                           kv_config.block_size, row),

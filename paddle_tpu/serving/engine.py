@@ -909,7 +909,8 @@ class _DecodeModel:
     __slots__ = ("name", "cfg", "params", "kv_config", "cache", "stepfn",
                  "maxb", "attn_path", "window_path", "experts_path",
                  "state_path", "blocks_read", "window_read", "chunks_read",
-                 "index_path", "index_read", "step_ms", "prefix",
+                 "index_path", "index_read", "selected_read", "step_ms",
+                 "prefix",
                  "declines", "slot_bytes", "state_name", "feed0",
                  "columns", "idle_lane", "upload",
                  "__weakref__",
@@ -942,6 +943,11 @@ class _DecodeModel:
         # (add_model sets both)
         self.index_path = None
         self.index_read = None
+        # ... and bucket -> the form the chosen rows' read takes at that
+        # many lanes ("pallas_masked": the kernel walks a lane's live
+        # blocks under a mask | "pallas": it reads the chosen rows,
+        # gathered | "gather"); empty otherwise (add_model sets it)
+        self.selected_read = {}
         # the same of the window layers' attention over their rings
         # (``window_read``: lens -> the blocks one such layer fetches of a
         # ring, would fetch of the whole table, and the chunks it walks),
@@ -1336,6 +1342,9 @@ class DecodeEngine:
             entry.index_read = functools.partial(
                 _pa.blocks_read, block_size=kv_config.block_size,
                 maxb=entry.maxb, path=index_path)
+            entry.selected_read = {
+                b: _dm.attention_path(cfg, kv_config, b, "selected")
+                for b in self.buckets}
         entry.blocks_read = functools.partial(
             _pa.blocks_read, block_size=kv_config.block_size,
             maxb=entry.maxb, path=attn_path)
@@ -1491,9 +1500,10 @@ class DecodeEngine:
                 extra["latent_attention"] = m.attn_path
             if m.index_path:
                 # latent layers that select: how the indexer's scores are
-                # read, and how many positions are chosen
-                # (``latent_attention`` above is the chosen rows' read)
+                # read, how many positions are chosen, and the form the
+                # chosen rows' read takes at this bucket
                 extra["index_path"] = m.index_path
+                extra["latent_attention"] = m.selected_read[bucket]
                 extra["index_topk"] = m.cfg.index_topk
             # positions a chunk of the attention kernel spans, by kind of
             # layer that takes it
@@ -2806,9 +2816,10 @@ class DecodeEngine:
             # the table has: the live context's share where the kernel
             # reads in place, all of them where the table is gathered
             # (latent layers that select attend a lane's chosen rows, the
-            # ``index_topk`` best at most)
-            attended = np.minimum(lens, m.cfg.index_topk) if m.index_path \
-                else lens
+            # ``index_topk`` best at most: the row form fetches those,
+            # gathered; the masked walk every live block, as the gather)
+            walks = m.selected_read.get(bucket) != "pallas"
+            attended = lens if walks else np.minimum(lens, m.cfg.index_topk)
             read = {"kv_blocks_read": m.blocks_read(attended),
                     "kv_table_slots": bucket * m.maxb,
                     "kv_block_size": m.kv_config.block_size} \
@@ -2827,7 +2838,11 @@ class DecodeEngine:
                 # those the contexts hold, and the lanes past ``index_topk``
                 # (on which alone the two differ)
                 read["index_blocks_read"] = m.index_read(lens)
-                read["latent_rows_selected"] = int(attended.sum())
+                read["latent_rows_selected"] = int(
+                    np.minimum(lens, m.cfg.index_topk).sum())
+                if m.selected_read[bucket] == "pallas_masked":
+                    # the blocks a layer's kernel fetched to read them
+                    read["latent_blocks_walked"] = read["latent_blocks_read"]
                 read["latent_rows_in_context"] = int(lens.sum())
                 read["sparse_lanes"] = int(
                     (lens > m.cfg.index_topk).sum())

@@ -492,7 +492,8 @@ def test_step_span_counters_gauges_and_prewarm_event(cache_dir, telemetry_on,
     # one live lane of two; the CPU gathers the whole table
     assert all(s["index_blocks_read"] == s["kv_blocks_read"] == 2 * MAXB
                and s["latent_rows_in_context"] >= s["latent_rows_selected"]
-               and s["sparse_lanes"] in (0, 1) for s in steps)
+               and s["sparse_lanes"] in (0, 1)
+               and "latent_blocks_walked" not in s for s in steps)
     by_context = {s["latent_rows_in_context"]: s for s in steps}
     assert set(by_context) >= set(range(1, 22))
     for n, s in by_context.items():
@@ -578,11 +579,12 @@ def test_index_scores_kernel_walks_a_lanes_live_blocks(interpreted, dtype,
 def test_selected_rows_are_gathered_and_the_latent_kernel_walks_them(
         interpreted, monkeypatch, dtype, tol):
     """64 rows of chosen positions a lane, gathered by (block, offset) into
-    contiguous blocks, under the latent kernel: the masked form's numbers
+    contiguous blocks, under the latent kernel (the row form, which a table
+    of 768 positions, 12 a chosen one, takes): the masked form's numbers
     (the whole table gathered, what was not chosen left out), for lanes
     under the 64, past them, and idle."""
     rng = np.random.default_rng(6)
-    heads, width, rank, bs, maxb, k = 16, 256, 128, 16, 24, 64
+    heads, width, rank, bs, maxb, k = 16, 256, 128, 16, 48, 64
     lens = [40, 300, 0, 64, 383]
     pool = jnp.asarray(rng.standard_normal((80, bs, width)), dtype)
     q = jnp.asarray(rng.standard_normal((5, heads, width)), jnp.float32)
@@ -592,16 +594,16 @@ def test_selected_rows_are_gathered_and_the_latent_kernel_walks_them(
                                    jnp.float32), -jnp.inf)
     positions, count = pa.choose(scores, ctx, k)
     assert np.array_equal(np.asarray(count), [40, 64, 0, 64, 64])
-    assert pa.selected_latent_path(q.shape, pool.shape, dtype, rank, k) \
-        == "pallas"
+    assert pa.selected_latent_path(q.shape, pool.shape, dtype, rank, k,
+                                   maxb) == "pallas"
     attend = lambda: jax.jit(
         lambda *a: pa.selected_latent_attention(*a, 0.1, rank))(
         q, pool, tables, ctx, positions, count)
     got = np.asarray(attend())
     assert adoption.active_kernels() == ["latent_attention"]
     monkeypatch.delenv("PADDLE_PALLAS_INTERPRET")
-    assert pa.selected_latent_path(q.shape, pool.shape, dtype, rank, k) \
-        == "gather"
+    assert pa.selected_latent_path(q.shape, pool.shape, dtype, rank, k,
+                                   maxb) == "gather"
     want = np.asarray(attend())
     live = [0, 1, 3, 4]
     np.testing.assert_allclose(got[live], want[live], atol=tol, rtol=tol)
@@ -612,8 +614,146 @@ def test_selected_rows_are_gathered_and_the_latent_kernel_walks_them(
     assert np.abs(dense[1] - want[1]).max() > 0.05
     np.testing.assert_allclose(dense[0], want[0], atol=tol, rtol=tol)
     # a selection that is no whole blocks is gathered whole and masked
-    assert pa.selected_latent_path(q.shape, pool.shape, dtype, rank, 60) \
-        == "gather"
+    assert pa.selected_latent_path(q.shape, pool.shape, dtype, rank, 60,
+                                   maxb) == "gather"
+
+
+# lens of the lanes, slots of the table: 16 heads of 256 over rows in blocks
+# of 16, 64 positions chosen; a chunk is 512 positions, 32 blocks
+WALKS = {
+    "under_k": ([40, 7], 36),
+    "at_k": ([64, 63], 36),
+    "past_k": ([300, 65, 576], 36),
+    "an_idle_lane": ([0, 530, 0], 36),
+    "a_last_chunk_of_one_block": ([513, 528], 36),
+    "a_table_of_one_chunk": ([383, 90], 24),
+    "the_grid_walks_the_lanes": ([570, 0, 64, 513], 36),
+}
+
+
+@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
+                                       (jnp.bfloat16, 2e-2)],
+                         ids=["f32", "bf16"])
+@pytest.mark.parametrize("case", sorted(WALKS))
+def test_the_masked_walk_reads_the_chosen_positions_of_a_lanes_live_blocks(
+        interpreted, monkeypatch, case, dtype, tol):
+    """The latent kernel over a lane's own table under a mask of the chosen
+    positions (the form a table of at most 9 positions a chosen one takes):
+    ``masked_latent``'s numbers over ``chosen_mask``'s set, for lanes under
+    ``k``, at it and past it, an idle lane (zeros), a last chunk that holds
+    one block, a table of one chunk, and with the grid walking the lanes (a
+    lane's chunks of the mask in VMEM at a time)."""
+    lens, maxb = WALKS[case]
+    rng = np.random.default_rng(sorted(WALKS).index(case))
+    heads, width, rank, bs, k = 16, 256, 128, 16, 64
+    pool = jnp.asarray(rng.standard_normal((120, bs, width)), dtype)
+    q = jnp.asarray(rng.standard_normal((len(lens), heads, width)),
+                    jnp.float32)
+    tables, ctx = _lanes(rng, lens, bs, maxb, 120)
+    # scores with ties, so that the choice's order is no position's
+    scores = jnp.where(jnp.arange(maxb * bs)[None] < ctx[:, None],
+                       jnp.asarray(rng.integers(0, 9, (len(lens), maxb * bs)),
+                                   jnp.float32), -jnp.inf)
+    positions, count = pa.choose(scores, ctx, k)
+    if case == "the_grid_walks_the_lanes":
+        # two chunk buffers and these lanes' queries and outputs no longer
+        # fit together: a lane a grid step, as at the published shapes
+        monkeypatch.setattr(pa, "_VMEM_BUDGET", 90000 + 2 * 512 * width
+                            * jnp.dtype(dtype).itemsize)
+    assert pa._latent_lane_grid(q.shape, pool.shape, dtype, rank) \
+        == (case == "the_grid_walks_the_lanes")
+    assert pa.selected_latent_path(q.shape, pool.shape, dtype, rank, k,
+                                   maxb) == "pallas_masked"
+    assert pa.latent_chunk_positions(q.shape, pool.shape, dtype, rank,
+                                     maxb) == min(512, maxb * bs)
+    got = np.asarray(jax.jit(
+        lambda *a: pa.selected_latent_attention(*a, 0.1, rank))(
+        q, pool, tables, ctx, positions, count))
+    assert adoption.active_kernels() == ["latent_attention"]
+    want = np.asarray(pa.masked_latent(
+        q, pa.gather_blocks(pool, tables), ctx, 0.1, rank,
+        pa.chosen_mask(positions, count, maxb * bs)))
+    for b, n in enumerate(lens):
+        if n:
+            np.testing.assert_allclose(got[b], want[b], atol=tol, rtol=tol)
+        else:
+            assert not got[b].any()
+    if max(lens) > k:
+        # ... and that is no dense attention where a lane chose
+        dense = np.asarray(pa.latent_attention_reference(
+            q, pool, tables, ctx, 0.1, rank))
+        b = int(np.argmax(lens))
+        assert np.abs(dense[b] - want[b]).max() > 0.02
+
+
+@pytest.mark.parametrize("case,chunks,span", [
+    ("ties", 3, 128), ("past_count", 2, 256), ("a_span_of_no_128", 4, 24),
+    ("a_choice_named_twice_past_count", 2, 128)])
+def test_the_mask_laid_out_by_chunk_is_chosen_masks_set(case, chunks, span):
+    """``_chunk_mask`` (the one-hot of a position's row against that of its
+    column, no scatter) marks ``chosen_mask``'s set exactly, position ``c *
+    span + j`` at ``[c, j]``: on scores with ties, on lanes whose trailing
+    choices lie past ``count`` (a lane under ``k`` positions), where a chunk
+    is no multiple of 128 positions, and where a stand-in for ``choose``
+    names a chosen position again past ``count`` (chip_check_glm's
+    ``most_recent``), which the scatter leaves to chance."""
+    rng = np.random.default_rng(len(case))
+    length, k = chunks * span - 3, 20
+    lens = jnp.asarray([0, 1, 5, 19, 20, 21, length], jnp.int32)
+    scores = rng.integers(0, 3, (7, length)).astype(np.float32) \
+        if case == "ties" else rng.standard_normal((7, length))
+    scores = jnp.where(jnp.arange(length)[None] < lens[:, None],
+                       jnp.asarray(scores, jnp.float32), -jnp.inf)
+    positions, count = pa.choose(scores, lens, k)
+    want = np.asarray(pa.chosen_mask(positions, count, length))
+    if case == "a_choice_named_twice_past_count":
+        positions = jnp.where(jnp.arange(k)[None] < count[:, None],
+                              positions, positions[:, :1])
+    got = np.asarray(pa._chunk_mask(positions, count, chunks, span))
+    assert got.shape == (7, chunks, span) and got.dtype == np.int32
+    assert set(np.unique(got)) <= {0, 1}
+    assert np.array_equal(got.reshape(7, -1)[:, :length] != 0, want)
+    assert not got.reshape(7, -1)[:, length:].any()
+    assert np.array_equal(want.sum(axis=1), np.minimum(lens, k))
+
+
+# (heads, row, rank, block, k, table slots) -> the form, under the interpreter
+FORMS = {
+    "a_table_of_9_positions_a_chosen_one_walks":
+        ((4, 16, 256, 128, 16, 64, 36), "pallas_masked"),
+    "a_table_of_9_and_a_quarter_gathers_the_rows":
+        ((4, 16, 256, 128, 16, 64, 37), "pallas"),
+    "the_cells_table_walks": ((32, 64, 640, 512, 16, 2048, 784),
+                              "pallas_masked"),
+    "the_published_table_gathers_the_rows":
+        ((32, 64, 640, 512, 16, 2048, 12672), "pallas"),
+    "128_heads_have_no_masked_body": ((32, 128, 640, 512, 16, 2048, 784),
+                                      "pallas"),
+    "a_selection_of_no_whole_blocks_walks_a_short_table":
+        ((4, 16, 256, 128, 16, 60, 24), "pallas_masked"),
+    "a_selection_of_no_whole_blocks_gathers_a_wide_table_whole":
+        ((4, 16, 256, 128, 16, 60, 48), "gather"),
+    "a_row_of_no_whole_tiles_gathers_whole":
+        ((4, 16, 160, 128, 16, 64, 24), "gather"),
+}
+
+
+@pytest.mark.parametrize("case", sorted(FORMS))
+def test_the_selected_reads_form_follows_from_the_shapes(interpreted,
+                                                         monkeypatch, case):
+    """``selected_latent_path`` names one of three forms from the shapes
+    alone: the masked walk up to ``_WALK_POSITIONS_PER_CHOSEN`` positions of
+    the table a chosen one, where the kernel's guarded body serves (the
+    straight-line body of 128 heads takes no mask); the row form past that;
+    the whole table gathered where no kernel serves, and everywhere off the
+    TPU."""
+    (lanes, heads, row, rank, bs, k, maxb), form = FORMS[case]
+    shapes = ((lanes, heads, row), (4 * maxb, bs, row), jnp.bfloat16, rank,
+              k, maxb)
+    assert pa._WALK_POSITIONS_PER_CHOSEN == 9
+    assert pa.selected_latent_path(*shapes) == form
+    monkeypatch.delenv("PADDLE_PALLAS_INTERPRET")
+    assert pa.selected_latent_path(*shapes) == "gather"
 
 
 def test_the_index_and_selected_rules_at_the_published_shapes():
@@ -627,22 +767,29 @@ def test_the_index_and_selected_rules_at_the_published_shapes():
     q, pool = (32, 64, 640), (25120, 16, 640)
     checks = dict(pa._selected_checks(q, pool, jnp.bfloat16, 512, 2048))
     assert [k for k, ok in checks.items() if not ok] == ["backend"]
+    # ... and the masked walk under the cell's tables of 784 slots (12,544
+    # positions for 2,048 chosen), which a table of the published 202,752
+    # positions is too wide for
+    checks = dict(pa._walk_checks(q, pool, jnp.bfloat16, 512, 2048, 784))
+    assert [k for k, ok in checks.items() if not ok] == ["backend"]
+    checks = dict(pa._walk_checks(q, pool, jnp.bfloat16, 512, 2048, 12672))
+    assert [k for k, ok in checks.items() if not ok] == ["backend",
+                                                         "selection"]
     assert pa.latent_chunk_positions(q, (4096, 16, 640), jnp.bfloat16, 512,
                                      128) == 512
     _config, cfg = _published()
     kv = dm.cache_config(cfg, 16, 25120)
     assert dm.attention_path(cfg, kv, 32, "index") \
-        == dm.attention_path(cfg, kv, 32, "latent") == "gather"
+        == dm.attention_path(cfg, kv, 32, "latent") \
+        == dm.attention_path(cfg, kv, 32, "selected") == "gather"
     assert dm.experts_chunk(cfg) == 256
 
 
-def test_the_paged_step_on_three_kernels_gives_the_jnp_steps_tokens(
-        interpreted, monkeypatch):
-    """The whole step with the index, latent-attention and expert kernels
-    interpreted (4 query heads of 96 + 32 rotated and values of 128 over 128
-    latent values, rows of 160 held 256 wide; 8 index heads of 128 that keep
-    16 positions, a block of them): the tokens of the jnp step, 40 positions
-    deep."""
+def _at_kernel_widths():
+    """A block the three kernels take under the interpreter: 4 query heads
+    of 96 + 32 rotated and values of 128 over 128 latent values, rows of 160
+    held 256 wide; 8 index heads of 128 that keep 16 positions, a block of
+    them, of the 64 a table names."""
     cfg = dm.DecoderConfig(
         arch="glm_dsa", vocab=61, layers=3, heads=4, head_dim=96,
         v_head_dim=128, hidden_size=128, max_seq=64,
@@ -651,12 +798,22 @@ def test_the_paged_step_on_three_kernels_gives_the_jnp_steps_tokens(
         dense_layers=1, dense_ffn=64, ffn=128, shared_ffn=64, experts=16,
         experts_held=8, experts_per_token=3, routed_scaling=2.5,
         rope_theta=1e6)
-    params = gd.init_params(cfg, seed=5, std=0.1, bias_std=0.05)
+    return cfg, gd.init_params(cfg, seed=5, std=0.1, bias_std=0.05)
+
+
+def test_the_paged_step_on_three_kernels_gives_the_jnp_steps_tokens(
+        interpreted, monkeypatch):
+    """The whole step with the index, latent-attention and expert kernels
+    interpreted (``_at_kernel_widths``): the tokens of the jnp step, 40
+    positions deep."""
+    cfg, params = _at_kernel_widths()
     kv = dm.cache_config(cfg, 16, 12)
     assert (kv.latent_width, kv.latent_row, kv.index_width) == (160, 256, 128)
     assert dm.attention_path(cfg, kv, 2, "latent") == "pallas"
+    # a table of 64 positions for 16 chosen: the kernel walks it, masked
+    assert dm.attention_path(cfg, kv, 2, "selected") == "pallas_masked"
     assert dm.attention_path(cfg, kv, 2, "index") == "pallas"
-    assert dm.chunk_positions(cfg, kv, 2) == {"latent": 16}
+    assert dm.chunk_positions(cfg, kv, 2) == {"latent": 64}
 
     def run():
         ((fed, logits), _idle), _routed = fam.run_paged(
@@ -671,3 +828,45 @@ def test_the_paged_step_on_three_kernels_gives_the_jnp_steps_tokens(
     plain = run()
     assert on_kernels[0] == plain[0]
     np.testing.assert_allclose(on_kernels[1], plain[1], atol=2e-4)
+
+
+def test_the_step_span_counts_the_blocks_a_masked_walk_fetched(
+        interpreted, cache_dir, telemetry_on, tmp_path):
+    """Through the engine with the kernels interpreted: the prewarm event
+    names the form the selected read took at the bucket
+    (``"pallas_masked"``) and the chunk it walks, and a step's span gains
+    ``latent_blocks_walked``, the live blocks of the lanes' contexts (what
+    ``latent_blocks_read`` then says too), beside ``latent_rows_selected``,
+    which stays the rows chosen."""
+    from paddle_tpu.serving.engine import DecodeEngine
+
+    cfg, params = _at_kernel_widths()
+    with fam.flags(tracing=True, telemetry_dir=str(tmp_path),
+                   kv_block_size=16):
+        e = DecodeEngine(buckets="2", deadline_ms=60000.0)
+        e.add_model("glm", (cfg, params), kv_blocks=12)
+        e.start()
+        try:
+            e.prewarm()
+            r = e.generate("glm", [1, 2, 3], max_new_tokens=30,
+                           deadline_ms=60000.0)
+            assert r.status == "ok"
+        finally:
+            e.stop()
+        _trc.flush()
+        _tm.flush()
+    warm = fam.prewarm_events(tmp_path)
+    assert warm and all(
+        ev["latent_attention"] == "pallas_masked"
+        and ev["attention"] == ev["index_path"] == "pallas"
+        and ev["chunk_positions"] == {"latent": 64} for ev in warm)
+    by_context = {s["latent_rows_in_context"]: s
+                  for s in fam.step_spans(tmp_path, "glm")}
+    assert set(by_context) >= set(range(1, 33))
+    for n, s in by_context.items():
+        assert s["latent_blocks_walked"] == s["latent_blocks_read"] \
+            == s["index_blocks_read"] == -(-n // 16)
+        assert s["latent_rows_selected"] == min(n, 16)
+        assert s["latent_chunks"] == 1
+    assert set(adoption.active_kernels()) == {
+        "index_scores", "latent_attention", "moe_experts"}

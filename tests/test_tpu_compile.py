@@ -871,11 +871,11 @@ def test_glm_dsa_step_compiles_its_three_kernels_at_published_shapes(
     whole step as the engine compiles it (``make_packed_step``), the kernel
     that scores a lane's cached index keys (32 heads of 128 over chunks of
     1,024 positions under tables of 784 slots), the latent form of the
-    paged-attention kernel at 64 query rows of 640 over the 2,048 chosen
-    rows a lane, gathered into 4,096 contiguous blocks, and the routed-expert
-    kernel over 16 held experts of 6144 x 2048; the choice is an exact
-    ``top_k`` (no approximation); every pool is aliased whole, and no pool
-    is copied, turned or converted."""
+    paged-attention kernel at 64 query rows of 640 over a lane's own table
+    under a mask of its 2,048 chosen positions (25 chunks of 512 a lane; no
+    row is gathered), and the routed-expert kernel over 16 held experts of
+    6144 x 2048; the choice is an exact ``top_k`` (no approximation); every
+    pool is aliased whole, and no pool is copied, turned or converted."""
     from benchmark.models import glm_dsa_decoder
     from paddle_tpu.pallas_kernels import moe_experts as moe
 
@@ -895,6 +895,7 @@ def test_glm_dsa_step_compiles_its_three_kernels_at_published_shapes(
     assert (kv.layers, kv.latent_layers, kv.latent_row, kv.index_layers,
             kv.index_width, kv.state_layers) == (0, 3, 640, 3, 128, 0)
     assert dm.attention_path(cfg, kv, lanes, "latent") == "pallas"
+    assert dm.attention_path(cfg, kv, lanes, "selected") == "pallas_masked"
     assert dm.attention_path(cfg, kv, lanes, "index") == "pallas"
     assert dm.chunk_positions(cfg, kv, lanes) == {"latent": 512}
     assert moe.experts_path(lanes, (16, 6144, 2048), jnp.bfloat16) \
@@ -922,15 +923,44 @@ def test_glm_dsa_step_compiles_its_three_kernels_at_published_shapes(
     assert pool_bytes == 3 * 25120 * 16 * (640 + 128) * 2
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= pool_bytes
-    # beside the arguments: a layer's gathered rows (32 x 2,048 x 640
-    # bfloat16, 84e6 B), its scores (32 x 12,544 float32) and the sort's
-    # operands; under one latent pool
-    assert memory.temp_size_in_bytes < 25120 * 16 * 640 * 2
+    # beside the arguments: a layer's scores (32 x 12,544 float32), the
+    # sort's operands and the mask (32 x 25 x 512 int32); no layer's chosen
+    # rows (32 x 2,048 x 640 bfloat16, 84e6 B) are gathered
+    assert memory.temp_size_in_bytes < 32 * 2048 * 640 * 2
+    assert "bf16[32,2048,640]" not in text and "bf16[65536,640]" not in text
     big = re.compile(r" = bf16\[25120,16,(640|128)\]\S* "
                      r"(copy|transpose|convert)\(")
     found = [line.strip()[:160] for line in text.splitlines()
              if big.search(line)]
     assert not found, found
+
+
+def test_glm_dsa_selected_read_gathers_the_rows_under_the_published_table(
+        one_chip, as_on_tpu):
+    """The other side of the rule: under a table of the published 202,752
+    positions (12,672 slots, 99 positions a chosen one) the selected read
+    keeps the row form, and Mosaic accepts the latent kernel over the 2,048
+    gathered rows a lane (4,096 contiguous blocks of them) at the cell's
+    other shapes."""
+    from paddle_tpu.pallas_kernels import paged_attention as pa
+
+    lanes, maxb, k = 32, 12672, 2048
+    q, pool = (lanes, 64, 640), (25120, 16, 640)
+    assert pa.selected_latent_path(q, pool, jnp.bfloat16, 512, k, maxb) \
+        == "pallas"
+    assert pa.selected_latent_path(q, pool, jnp.bfloat16, 512, k, 784) \
+        == "pallas_masked"
+    shape = lambda dims, dtype: jax.ShapeDtypeStruct(dims, dtype,
+                                                     sharding=one_chip)
+    compiled = jax.jit(lambda *a: pa.selected_latent_attention(
+        *a, 0.0625, 512)).lower(
+        shape(q, jnp.float32), shape(pool, jnp.bfloat16),
+        shape((lanes, maxb), jnp.int32), shape((lanes,), jnp.int32),
+        shape((lanes, k), jnp.int32), shape((lanes,), jnp.int32)).compile()
+    text = compiled.as_text()
+    assert len(re.findall(r"%latent_attention\S* = ", text)) == 1
+    assert _kernel_calls(text) == 1
+    assert "bf16[65536,640]" in text or "bf16[32,2048,640]" in text
 
 
 def test_data_parallel_bert_layer_runs_fused_ln_per_shard_on_v5e_2x2(

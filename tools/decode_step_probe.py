@@ -78,16 +78,20 @@ and ``latent/out``, ``mlp``, ``moe/router``, ``moe/experts``,
 --contexts lo-hi`` the latent layers select: the scopes gain
 ``layerN/latent/index`` (the indexer's projections and rotation, the index
 key's write and the ``index_scores`` kernel), ``latent/select`` (the exact
-top-k) and ``latent/kv_gather`` (the chosen rows' gather) before
-``latent/kv_read`` (the latent kernel over them); the index pools are filled
-with seeded keys, so that the chosen rows lie scattered as a served
-sequence's do; the result gives ``selection``: the index kernel's own ms a
-step and bytes/s over the live blocks' keys, and the rows chosen of those in
-context (``benchmark/glm_cost.py``).  ``--latent-read dense`` is the control:
-the latent kernel walks every live block of a lane, as the masked form of
-the selected read would (which adds the index and the choice to it; here
-nothing reads them and the compiler drops both), not an option of the
-program.  ``layerN/staged`` (any configuration) is what
+top-k) and ``latent/mask`` (the chosen positions laid out as the kernel's
+mask) before ``latent/kv_read`` (the latent kernel over a lane's live blocks
+under that mask); the index pools are filled with seeded keys, so that the
+chosen rows lie scattered as a served sequence's do; the result gives
+``selection``: the index kernel's own ms a step and bytes/s over the live
+blocks' keys, the rows chosen of those in context
+(``benchmark/glm_cost.py``), the form the selected read took
+(``selected_read``), the blocks a layer's masked walk fetched and the mask's
+own ms a step.  ``--latent-read`` names a control, not an option of the
+program: ``gathered`` stands the row form in, which a table wider than the
+rule's serves (``latent/kv_gather``, the chosen rows' gather, then the
+kernel over them), and ``dense`` reads every live block under no mask
+(nothing reads the index and the choice there, and the compiler drops
+both).  ``layerN/staged`` (any configuration) is what
 XLA puts in itself around a layer's weights and names nothing: a weight
 relaid for the product that reads it, or fetched ahead of it.
 
@@ -251,7 +255,7 @@ def scope_of(op_name):
                      "ssm", "in_proj", "conv", "state_update", "out_proj",
                      "window", "kda", "state", "out", "latent", "absorb",
                      "shared", "q_compress", "rope", "staged", "index",
-                     "select")]
+                     "select", "mask")]
     return "/".join(keep) or "other"
 
 
@@ -594,12 +598,16 @@ def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
             "bytes_per_step": moved,
             "bytes_per_s": moved / (ms / 1e3) if ms else None,
             "with_window_kernels": bool(cfg.window_layers)}
+    # the form a selecting model's read took (None: no selection, or the
+    # dense control's none)
+    form = dm.attention_path(cfg, kv, b, "selected") \
+        if args.latent_read != "dense" else None
     if cfg.latent_layers:
         # the rows the latent layers fetched at the profiled steps' contexts
-        # (the values of a row, not the width its pool holds it in)
-        selects = bool(cfg.index_topk) and args.latent_read == "served"
+        # (the values of a row, not the width its pool holds it in; the row
+        # form of a selected read fetches the chosen rows alone)
         read = paged_attention.blocks_read(
-            np.minimum(now, cfg.index_topk) if selects else now,
+            np.minimum(now, cfg.index_topk) if form == "pallas" else now,
             args.block_size,
             cfg.max_seq // args.block_size, result["attention"])
         cost = kimi_cost if "linear_attn_config" in config else dots_cost
@@ -625,7 +633,13 @@ def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
                                                     args.block_size)
         ms = kernel_ms(paged_attention.INDEX_KERNEL_NAME)
         result["selection"] = {
-            "latent_read": args.latent_read,
+            "latent_read": args.latent_read, "selected_read": form,
+            # what a layer's masked walk fetched, and what laying the
+            # chosen positions out as its mask cost
+            "latent_blocks_walked": result["latent_blocks_read"]
+            if form == "pallas_masked" else None,
+            "mask_ms_per_step": sum(v for k, v in scopes.items()
+                                    if k.endswith("latent/mask")),
             "index_path": dm.attention_path(cfg, kv, b, "index"),
             "index_blocks_read": walked, "index_bytes_per_step": moved,
             "index_kernel_ms_per_step": ms,
@@ -651,9 +665,11 @@ def main(argv=None):
                     help="xla: the state update as gather, update, scatter "
                     "(what the step does off the TPU) in place of the kernel")
     ap.add_argument("--latent-read", default="served",
-                    choices=("served", "dense"),
-                    help="dense: a selecting model's latent kernel walks "
-                    "every live block (the masked control's walk)")
+                    choices=("served", "gathered", "dense"),
+                    help="a control of a selecting model's read: gathered, "
+                    "the chosen rows gathered and the latent kernel over "
+                    "them (the row form); dense, every live block under no "
+                    "mask")
     ap.add_argument("--blocks", type=int, default=1024)
     ap.add_argument("--bucket", type=int, default=32)
     ap.add_argument("--block-size", type=int, default=16)
@@ -680,7 +696,7 @@ def main(argv=None):
     from benchmark.run import load_module
     import paddle_tpu as fluid
     from paddle_tpu.core import telemetry
-    from paddle_tpu.pallas_kernels import moe_experts
+    from paddle_tpu.pallas_kernels import moe_experts, paged_attention
     from paddle_tpu.serving import decode_model as dm
     from paddle_tpu.serving import kv_cache as kvc
 
@@ -727,6 +743,9 @@ def main(argv=None):
             carry[i] = jax.random.normal(
                 jax.random.PRNGKey(i), carry[i].shape, carry[i].dtype)
         cache.replace_carry(carry)
+        if args.latent_read == "gathered":
+            # no table is short enough for the walk: the rule's other side
+            paged_attention._WALK_POSITIONS_PER_CHOSEN = 0
         if args.latent_read == "dense":
             dm.selected_latent_attention = (
                 lambda q, pool, tables, lens, _positions, _count, scale,
