@@ -91,6 +91,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from ..core import telemetry as _tm
 from ..pallas_kernels import kda_update as _kda
 from ..pallas_kernels import moe_experts as _moe
 from ..pallas_kernels import paged_attention as _pa
@@ -105,7 +106,7 @@ __all__ = ["DecoderConfig", "init_decoder_params", "save_decoder",
            "load_decoder", "is_decoder_dir", "has_draft", "load_draft",
            "truncate_decoder", "laid_out", "attention_path", "experts_path",
            "state_update_path", "state_update_columns", "experts_chunk",
-           "experts_gate",
+           "experts_gate", "StepAccount",
            "make_paged_step",
            "make_fed_step", "make_paged_step_multi",
            "make_draft_rollout", "make_unpaged_step", "unpaged_generate",
@@ -883,6 +884,251 @@ def state_update_columns(cfg, kv_config):
     shape, _dtype = kv_config.state_shapes[1]
     return _ssm.transfer_columns((kv_config.state_slots,) + shape,
                                  cfg.kda_heads or cfg.ssm_groups)
+
+
+class StepAccount:
+    """What one model's decode step takes and reads, by kind of layer: the
+    one place outside a family, its kernels and its cache group that knows
+    the kinds (the engine keeps lanes, blocks, rings and slots, and asks
+    here).  Built once a model from its configuration, its cache's, its
+    parameters as the step holds them (``laid_out``, whose own layouts
+    ``laid`` names) and the lane buckets; ``model`` labels the counters and
+    gauges that ride with the attributes.  Each kind the model has is
+    entered once, by its method (``_paged`` ... ``_recurrent``): its path,
+    or its form a bucket (None, or empty, for a kind the model has not), and
+    what it adds to ``key_parts`` (every ``CarriedStepFn`` of the model is
+    keyed by them: an executable compiled for one path is never restored for
+    another), to a bucket's ``serving_prewarm`` event and to a step's span.
+    A new kind is one more such method."""
+
+    def __init__(self, cfg, kv_config, params, buckets, model=None, laid=()):
+        self.cfg, self.kv_config, self.model = cfg, kv_config, model
+        self.buckets = tuple(sorted(buckets))
+        self.maxb = -(-cfg.max_seq // kv_config.block_size)
+        self.attn_path = self.window_path = self.index_path = None
+        self.experts_path, self.state_path, self.selected_read = {}, {}, {}
+        self.key_parts = {}
+        # bucket -> the positions a chunk of the attention kernel spans, by
+        # kind of layer that takes it
+        self._chunks = {b: chunk_positions(cfg, kv_config, b)
+                        for b in self.buckets}
+        # what the prewarm event says at every bucket, and at each
+        self._said, self._said_at = {}, {b: {} for b in self.buckets}
+        # what fills a step's span: each a ``(bucket, lens, attrs)``
+        self._reads = []
+        self._paged()
+        if cfg.window_layers:
+            self._window()
+        if cfg.routed_layers:
+            self._routed(params)
+        if cfg.recurrent_layers:
+            self._recurrent()
+        if laid:
+            # (the argument shapes tell the two forms of a weight apart too)
+            self.key_parts["weights_laid_out"] = sorted(laid)
+        kinds = sorted(set(cfg.layer_types))
+        if len(kinds) > 1:
+            # a hybrid's layers, those that keep nothing in the cache too
+            self._said["layers"] = {kind: cfg.layer_types.count(kind)
+                                    for kind in kinds}
+
+    def _paged(self):
+        """The layers that page a history on the global tables: attention
+        layers, or a latent model's latent layers (a block there is one row
+        a token, not K and V), which may select: the ``index_topk`` rows
+        an indexer scores best are read, in the form ``selected_read`` names
+        a bucket (``attention_path``'s ``"index"`` and ``"selected"``)."""
+        cfg, kv, top = self.cfg, self.kv_config, self.buckets[-1]
+        bs, maxb, topk = kv.block_size, self.maxb, cfg.index_topk
+        kind = "latent" if cfg.latent_layers else "attention"
+        self.attn_path = path = attention_path(cfg, kv, top, kind)
+        latent = {"latent_attention": path} if kind == "latent" else {}
+        self.key_parts.update(latent or {"attention": path})
+        self._said.update(latent, attention=path)
+        if topk:
+            self.index_path = attention_path(cfg, kv, top, "index")
+            self.key_parts["index_scores"] = self.index_path
+            self._said.update(index_path=self.index_path, index_topk=topk)
+            for b in self.buckets:
+                self.selected_read[b] = self._said_at[b]["latent_attention"] \
+                    = attention_path(cfg, kv, b, "selected")
+
+        def read(bucket, lens, attrs):
+            # the row form of a selected read fetches a lane's chosen rows,
+            # gathered; the masked walk every live block, as the gather
+            form = self.selected_read.get(bucket)
+            attended = np.minimum(lens, topk) if form == "pallas" else lens
+            attrs.update(
+                kv_blocks_read=_pa.blocks_read(attended, bs, maxb, path),
+                kv_table_slots=bucket * maxb, kv_block_size=bs)
+            if latent:
+                # each latent layer fetches the same, in so many chunks (on
+                # the gather path a lane's padded table is its one chunk)
+                attrs["latent_blocks_read"] = attrs["kv_blocks_read"]
+                attrs["latent_chunks"], attrs["latent_full_chunks"] = \
+                    _pa.chunks_read(attended, bs, maxb, self._chunks[
+                        bucket].get("latent", maxb * bs))
+            if topk:
+                # what the selection did: the rows read, of those the
+                # contexts hold, differ on the lanes past ``index_topk`` alone
+                attrs.update(
+                    index_blocks_read=_pa.blocks_read(lens, bs, maxb,
+                                                      self.index_path),
+                    latent_rows_selected=int(np.minimum(lens, topk).sum()),
+                    latent_rows_in_context=int(lens.sum()),
+                    sparse_lanes=int((lens > topk).sum()))
+                if form == "pallas_masked":
+                    # the blocks a layer's kernel fetched to read them
+                    attrs["latent_blocks_walked"] = \
+                        attrs["latent_blocks_read"]
+        self._reads.append(read)
+
+    def _window(self):
+        """The window layers' attention over their rings."""
+        self.window_path = path = attention_path(
+            self.cfg, self.kv_config, self.buckets[-1], "window")
+        self.key_parts["window_attention"] = path
+        self._said.update(window_attention=path,
+                          window_ring=self.kv_config.window_ring)
+
+    def window_attrs(self, lens, released, window_in_use, global_in_use):
+        """What the window layers' attention fetches this step, over all
+        such layers, beside what it would fetch of the lanes' whole contexts
+        (were they global layers on the same path), and the blocks their
+        pools hold (``released``: what the rings gave back at this dispatch);
+        asked only while the step span is recorded.  The counter and the
+        gauges ride along."""
+        cfg, kv = self.cfg, self.kv_config
+        n, bs, ring = len(cfg.window_layers), kv.block_size, kv.window_ring
+        _tm.inc("kv_window_blocks_released_total", released, model=self.model)
+        for kind, held in (("window", window_in_use),
+                           ("global", global_in_use)):
+            _tm.set_gauge("kv_pool_blocks", held, model=self.model, kind=kind)
+        read = lambda maxb, **kw: _pa.blocks_read(
+            lens, bs, maxb, self.window_path, **kw)
+        return {"kv_window_blocks_read": n * read(ring, ring=True),
+                "kv_window_blocks_full": n * read(self.maxb),
+                "kv_window_blocks_held": window_in_use,
+                # live lanes whose context is past the window (their rings
+                # have given blocks back), and the chunks ONE window layer's
+                # attention walked
+                "kv_window_lanes_wrapped": int((lens > cfg.window).sum()),
+                "kv_window_chunks": _pa.chunks_read(
+                    lens, bs, ring, self._chunks[self.buckets[-1]].get(
+                        "window", ring * bs))[0],
+                "kv_block_size": bs}
+
+    def _routed(self, params):
+        """The routed layers' experts: how they are read a bucket
+        (``"pallas"``: the experts hit alone | ``"einsum"``: all)."""
+        cfg = self.cfg
+        self.experts_path = {b: experts_path(cfg, params, b)
+                             for b in self.buckets}
+        self.key_parts["experts"] = sorted(self.experts_path.items())
+        for b, path in self.experts_path.items():
+            self._said_at[b]["experts"] = path
+            if path == "pallas":
+                self._said_at[b]["experts_f_chunk"] = experts_chunk(cfg)
+        if experts_gate(cfg) != "silu":
+            # said only where the family declares another than SiLU
+            self._said["experts_gate"] = experts_gate(cfg)
+
+    def moe_attrs(self, bucket, extras):
+        """A routed step's ``extras`` (``_block``: the tokens it sent to
+        each expert in each layer that routes, live lanes only, and from a
+        router with groups the lanes that kept each group) as its span's
+        attributes, means over the layers that route.  The caller hands them
+        over only while the span is recorded, so an untraced window pays for
+        no transfer; a step with no experts has none.  Where the experts are
+        the kernel's, an expert with no token was not read: counted."""
+        if not extras:
+            return {}
+        cfg = self.cfg
+        everywhere = np.asarray(extras[0])
+        routed = everywhere[:, cfg.held_experts]
+        hit = float((routed > 0).sum(axis=1).mean())
+        _tm.inc("moe_tokens_routed_total", int(routed.sum()), model=self.model)
+        _tm.set_gauge("moe_experts_hit", hit, model=self.model)
+        if self.experts_path.get(bucket) == "pallas":
+            _tm.inc("moe_expert_reads_skipped_total",
+                    int((routed == 0).sum()), model=self.model)
+        # means over the routed layers: experts with a token, the fullest
+        # expert's tokens, and the tokens routed (lanes x experts a token)
+        attrs = {"moe_experts_hit": round(hit, 3),
+                 "moe_load_max": round(float(routed.max(axis=1).mean()), 3),
+                 "moe_assignments": round(float(routed.sum(axis=1).mean()),
+                                          3)}
+        if routed.shape != everywhere.shape:
+            # a share: the assignments computed here, and those left to the
+            # experts it does not hold
+            absent = int(everywhere.sum() - routed.sum())
+            _tm.inc("moe_assignments_absent_total", absent, model=self.model)
+            attrs["moe_local_assignments"] = attrs["moe_assignments"]
+            attrs["moe_absent_assignments"] = round(
+                absent / float(len(routed)), 3)
+        if cfg.n_group > 1:
+            # of the groups that hold a held expert, how many a token kept
+            # (mean over layers)
+            size = cfg.experts // cfg.n_group
+            held = cfg.held_experts
+            mine = np.asarray(extras[1])[
+                :, held.start // size:(held.stop - 1) // size + 1]
+            lanes = everywhere.sum() / float(cfg.experts_per_token
+                                             * len(routed))
+            attrs["moe_groups_kept"] = round(
+                float(mine.sum()) / (len(routed) * lanes), 3) if lanes \
+                else 0.0
+        return attrs
+
+    def _recurrent(self):
+        """The recurrent layers' slots, named by what they hold
+        (``STATE_NAMES``): the state a step reads and writes, a slot a live
+        lane; and, of the kinds that keep a state beside their window, how
+        it is moved a bucket (``"pallas"``: each slot in place |
+        ``"gather"``) and what one transfer of the kernel then moves."""
+        cfg, kv = self.cfg, self.kv_config
+        name, slot = cfg.state_name, _kv.slot_bytes(kv)
+        for b in self.buckets if cfg.state_layers else ():
+            self.state_path[b] = path = state_update_path(cfg, kv, b)
+            self._said_at[b]["state_update"] = path
+            if path == "pallas":
+                self._said_at[b]["state_update_columns"] = \
+                    state_update_columns(cfg, kv)
+        if self.state_path:
+            self.key_parts["state_update"] = sorted(self.state_path.items())
+
+        def read(bucket, lens, attrs):
+            # an idle lane's context is 0, a live one's at least 1
+            live = int(np.count_nonzero(lens))
+            attrs.update({name + "_lanes": live,
+                          name + "_bytes": live * slot})
+        self._reads.append(read)
+
+    def prewarm_attrs(self, bucket):
+        """What the ``serving_prewarm`` event of ``bucket``'s executable
+        says of the step's kinds: each one's path, form and chunk."""
+        return dict(self._said, **self._said_at[bucket],
+                    chunk_positions=self._chunks[bucket])
+
+    def step_attrs(self, bucket, lens):
+        """A step's read counts for its span; ``lens`` its context lengths
+        (the numpy feed, ``int32[bucket]``, an idle lane's 0).  A traced
+        step pays this under the engine's lock: sums over ``lens``."""
+        attrs = {}
+        for read in self._reads:
+            read(bucket, lens, attrs)
+        return attrs
+
+    def pool_bytes(self):
+        """gauge -> the bytes of the pools beside K and V that the model's
+        kinds hold, by the cache's description (no kind, no gauge)."""
+        kv = self.kv_config
+        pools = {"latent_pool_bytes": kv.latent_layers
+                 * _kv.latent_block_bytes(kv) * kv.num_blocks,
+                 "index_pool_bytes": kv.index_layers
+                 * _kv.index_block_bytes(kv) * kv.num_blocks,
+                 "%s_bytes" % self.cfg.state_name: _kv.state_bytes(kv)}
+        return {gauge: n for gauge, n in pools.items() if n}
 
 
 def _widened(x, row):

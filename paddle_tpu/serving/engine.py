@@ -907,10 +907,7 @@ class _DecodeSeq:
 
 class _DecodeModel:
     __slots__ = ("name", "cfg", "params", "kv_config", "cache", "stepfn",
-                 "maxb", "attn_path", "window_path", "experts_path",
-                 "state_path", "blocks_read", "window_read", "chunks_read",
-                 "index_path", "index_read", "selected_read", "step_ms",
-                 "prefix",
+                 "maxb", "account", "step_ms", "prefix",
                  "declines", "slot_bytes", "state_name", "feed0",
                  "columns", "idle_lane", "upload",
                  "__weakref__",
@@ -920,7 +917,7 @@ class _DecodeModel:
                  "spec_k", "draft_cfg", "draft_params", "draft_kv_config",
                  "draft_cache", "rolloutfn", "ingestfn", "verifyfn")
 
-    def __init__(self, name, cfg, params, kv_config, cache, stepfn):
+    def __init__(self, name, cfg, params, kv_config, cache, stepfn, account):
         self.name = name
         self.cfg = cfg
         self.params = params        # jnp arrays (device-resident)
@@ -928,40 +925,9 @@ class _DecodeModel:
         self.cache = cache
         self.stepfn = stepfn        # CarriedStepFn over make_packed_step
         self.maxb = -(-cfg.max_seq // kv_config.block_size)
-        # how the step's attention reads the pool ("pallas" | "gather"),
-        # and lens -> the blocks a layer's attention then fetches
-        # (add_model sets both)
-        self.attn_path = None
-        self.blocks_read = None
-        # bucket -> (lens -> the chunks a latent layer's attention walks,
-        # and how many of them are full); empty for a model with no latent
-        # layer (add_model sets it)
-        self.chunks_read = {}
-        # of a model whose latent layers select: how a layer's indexer
-        # scores the cached index keys ("pallas" | "gather"), and lens ->
-        # the blocks of its index pool that then walks; None otherwise
-        # (add_model sets both)
-        self.index_path = None
-        self.index_read = None
-        # ... and bucket -> the form the chosen rows' read takes at that
-        # many lanes ("pallas_masked": the kernel walks a lane's live
-        # blocks under a mask | "pallas": it reads the chosen rows,
-        # gathered | "gather"); empty otherwise (add_model sets it)
-        self.selected_read = {}
-        # the same of the window layers' attention over their rings
-        # (``window_read``: lens -> the blocks one such layer fetches of a
-        # ring, would fetch of the whole table, and the chunks it walks),
-        # None for a model with no such layer
-        self.window_path = None
-        self.window_read = None
-        # bucket -> how a routed layer's experts are read at that many
-        # lanes ("pallas": the experts hit alone | "einsum": all of them);
-        # empty for a model with no routed layer (add_model sets it)
-        self.experts_path = {}
-        # bucket -> how a state-space layer's state is moved at that many
-        # lanes ("pallas": each slot in place | "gather"); empty for a
-        # model with no such layer (add_model sets it)
-        self.state_path = {}
+        # what the step takes and reads by kind of layer: its paths, its
+        # prewarm event's and its span's attributes
+        self.account = account      # decode_model.StepAccount
         self.step_ms = 0.0          # EWMA of one decode step
         self.prefix = None          # PrefixCache (FLAGS_prefix_cache)
         # why this model declines what starts or moves a sequence at
@@ -997,6 +963,14 @@ class _DecodeModel:
         self.rolloutfn = None       # draft: k chained proposals per lane
         self.ingestfn = None        # draft: multi-token catch-up writes
         self.verifyfn = None        # target: [B, k+1] multi-token step
+
+    # read-only views of the account for benchmark/tests/chip_check_{dots,
+    # exaone,nemotron}.py and tests/test_moe_experts_kernel.py, which read
+    # them off the entry: they go when those have a public read (Design 7)
+    attn_path = property(lambda self: self.account.attn_path)
+    window_path = property(lambda self: self.account.window_path)
+    experts_path = property(lambda self: self.account.experts_path)
+    state_path = property(lambda self: self.account.state_path)
 
 
 class _Flight:
@@ -1202,7 +1176,6 @@ class DecodeEngine:
         from . import decode_model as _dm
         from . import kv_cache as _kvc
         from ..core.executor import CarriedStepFn
-        from ..pallas_kernels import paged_attention as _pa
 
         if isinstance(source, str):
             cfg, params = _dm.load_decoder(source)
@@ -1276,86 +1249,24 @@ class DecodeEngine:
         # the weights held in the family's own layout and not as published
         # (the same bytes: ``resident`` stands)
         laid = {key: v for key, v in jparams.items() if key not in params}
-        # of the layers that page a history on the global tables: attention
-        # layers, or a latent model's latent layers
-        latent = bool(cfg.latent_layers)
-        attn_path = _dm.attention_path(cfg, kv_config, max(self.buckets),
-                                       "latent" if latent else "attention")
-        experts_path = {b: _dm.experts_path(cfg, jparams, b)
-                        for b in self.buckets if cfg.routed_layers}
-        state_path = {b: _dm.state_update_path(cfg, kv_config, b)
-                      for b in self.buckets if cfg.state_layers}
-        # the paths the step's attention and (a routed model's) experts
-        # take: an executable compiled for one is never restored for the
-        # other
-        paths = {"latent_attention" if latent else "attention": attn_path}
-        index_path = _dm.attention_path(
-            cfg, kv_config, max(self.buckets), "index") \
-            if cfg.index_topk else None
-        if index_path:
-            paths["index_scores"] = index_path
-        window_path = _dm.attention_path(
-            cfg, kv_config, max(self.buckets), "window") if windowed else None
-        if windowed:
-            paths["window_attention"] = window_path
-        if experts_path:
-            paths["experts"] = sorted(experts_path.items())
-        if state_path:
-            paths["state_update"] = sorted(state_path.items())
-        if laid:
-            # (the argument shapes tell the two forms of a weight apart too)
-            paths["weights_laid_out"] = sorted(laid)
+        # the paths the step's kinds of layer take go into the key: an
+        # executable compiled for one is never restored for another
+        account = _dm.StepAccount(cfg, kv_config, jparams, self.buckets,
+                                  model=name, laid=laid)
         stepfn = CarriedStepFn(
             # make_paged_step's step with the token feed on the device and
             # the lanes' integers in one array: still one executable an
             # engine step
             _dm.make_packed_step(cfg, kv_config, max(self.buckets)),
             donate_argnums=(0,), name="decode_step",
-            key_parts=dict(paths, kind="decode_step", model=name,
+            key_parts=dict(account.key_parts, kind="decode_step", model=name,
                            cfg=cfg.to_dict(),
                            kv={"block_size": kv_config.block_size,
                                "num_blocks": kv_config.num_blocks,
                                "window_blocks": kv_config.window_blocks,
                                "dtype": kv_config.dtype}))
-        entry = _DecodeModel(name, cfg, jparams, kv_config, cache, stepfn)
-        entry.attn_path = attn_path
-        entry.window_path = window_path
-        if windowed:
-            read = functools.partial(
-                _pa.blocks_read, block_size=kv_config.block_size,
-                path=window_path)
-            # the kernel's chunk of a ring: the ring itself, or the span a
-            # longer ring is walked in; on the gather path a lane's ring is
-            # its one chunk
-            ring_len = kv_config.window_ring * kv_config.block_size
-            span = _dm.chunk_positions(cfg, kv_config, max(self.buckets)
-                                       ).get("window", ring_len)
-            entry.window_read = lambda lens: (
-                read(lens, maxb=kv_config.window_ring, ring=True),
-                read(lens, maxb=entry.maxb),
-                _pa.chunks_read(lens, kv_config.block_size,
-                                kv_config.window_ring, span)[0])
-        entry.experts_path = experts_path
-        entry.state_path = state_path
-        if index_path:
-            entry.index_path = index_path
-            entry.index_read = functools.partial(
-                _pa.blocks_read, block_size=kv_config.block_size,
-                maxb=entry.maxb, path=index_path)
-            entry.selected_read = {
-                b: _dm.attention_path(cfg, kv_config, b, "selected")
-                for b in self.buckets}
-        entry.blocks_read = functools.partial(
-            _pa.blocks_read, block_size=kv_config.block_size,
-            maxb=entry.maxb, path=attn_path)
-        for b in self.buckets if latent else ():
-            # the kernel's chunk; on the gather path a lane's whole padded
-            # table is its one chunk
-            entry.chunks_read[b] = functools.partial(
-                _pa.chunks_read, block_size=kv_config.block_size,
-                maxb=entry.maxb,
-                span=_dm.chunk_positions(cfg, kv_config, b).get(
-                    "latent", entry.maxb * kv_config.block_size))
+        entry = _DecodeModel(name, cfg, jparams, kv_config, cache, stepfn,
+                             account)
         entry.prefix = prefix
         entry.columns, width = _dm.lane_columns(kv_config, entry.maxb)
         entry.idle_lane = np.zeros(width, np.int32)
@@ -1372,19 +1283,9 @@ class DecodeEngine:
         if recurrent:
             entry.declines = "recurrent_state"
             entry.slot_bytes = _kvc.slot_bytes(kv_config)
-            # what the slots hold names them: ssm_state_*, conv_state_*,
-            # kda_state_*
             entry.state_name = cfg.state_name
-            _tm.set_gauge(entry.state_name + "_bytes",
-                          _kvc.state_bytes(kv_config), model=name)
-        if latent:
-            _tm.set_gauge("latent_pool_bytes", kv_config.latent_layers
-                          * _kvc.latent_block_bytes(kv_config) * n,
-                          model=name)
-        if index_path:
-            _tm.set_gauge("index_pool_bytes", kv_config.index_layers
-                          * _kvc.index_block_bytes(kv_config) * n,
-                          model=name)
+        for gauge, nbytes in account.pool_bytes().items():
+            _tm.set_gauge(gauge, nbytes, model=name)
         _tm.set_gauge("decode_weights_laid_out_bytes",
                       sum(int(v.nbytes) for v in laid.values()), model=name)
         if k > 0:
@@ -1395,15 +1296,15 @@ class DecodeEngine:
             # reports the exact combined pool bytes afterwards
             draft_kv = _dm.cache_config(dcfg, kv_config.block_size, n,
                                         kv_config.dtype)
-            base_parts = dict(paths, model=name, kv={
-                "block_size": kv_config.block_size, "num_blocks": n,
-                "dtype": kv_config.dtype},
-                attention=[attn_path,
-                           _dm.attention_path(dcfg, draft_kv,
-                                              max(self.buckets))])
             entry.spec_k = k
             entry.draft_cfg = dcfg
             entry.draft_params = _dm.laid_out(dcfg, dparams)
+            base_parts = dict(account.key_parts, model=name, kv={
+                "block_size": kv_config.block_size, "num_blocks": n,
+                "dtype": kv_config.dtype},
+                attention=[account.attn_path, _dm.StepAccount(
+                    dcfg, draft_kv, entry.draft_params,
+                    self.buckets).attn_path])
             entry.draft_kv_config = draft_kv
             entry.draft_cache = _kvc.PagedKVCache(draft_kv)
             entry.verifyfn = CarriedStepFn(
@@ -1461,60 +1362,18 @@ class DecodeEngine:
         this, mixed-length continuous batching can only hit the
         in-memory executables: ``executor_cache_miss_total`` stays flat
         under load — the zero-runtime-compile proof."""
-        from . import decode_model as _dm
 
         def note(model, bucket, fn, got, **extra):
             # what the executable holds beside its arguments, and how
             # much of them (the KV pool) it updates in their own buffers
             _tm.inc("serving_prewarm_total", model=model,
                     source=got["source"])
-            m = self._models[model]
-            if m.experts_path:
-                extra["experts"] = m.experts_path[bucket]
-                if m.experts_path[bucket] == "pallas":
-                    # columns of an expert a grid step of the kernel reads
-                    extra["experts_f_chunk"] = _dm.experts_chunk(m.cfg)
-                gate = _dm.experts_gate(m.cfg)
-                if gate != "silu":
-                    # the activation of a three-matrix expert's gate, where
-                    # the family declares another than SiLU
-                    extra["experts_gate"] = gate
-            if m.state_path:
-                extra["state_update"] = m.state_path[bucket]
-                if m.state_path[bucket] == "pallas":
-                    # what one transfer of the kernel moves: the slot's
-                    # whole width, or the chunk a larger slot falls back to
-                    extra["state_update_columns"] = \
-                        _dm.state_update_columns(m.cfg, m.kv_config)
-            if len(set(m.cfg.layer_types)) > 1:
-                # a hybrid's layers by kind, those that keep nothing in
-                # the cache among them
-                extra["layers"] = {
-                    kind: m.cfg.layer_types.count(kind)
-                    for kind in sorted(set(m.cfg.layer_types))}
-            if m.window_path is not None:
-                extra["window_attention"] = m.window_path
-                # the slots of a sequence's ring in a window layer's pool
-                extra["window_ring"] = m.kv_config.window_ring
-            if m.cfg.latent_layers:
-                extra["latent_attention"] = m.attn_path
-            if m.index_path:
-                # latent layers that select: how the indexer's scores are
-                # read, how many positions are chosen, and the form the
-                # chosen rows' read takes at this bucket
-                extra["index_path"] = m.index_path
-                extra["latent_attention"] = m.selected_read[bucket]
-                extra["index_topk"] = m.cfg.index_topk
-            # positions a chunk of the attention kernel spans, by kind of
-            # layer that takes it
-            extra["chunk_positions"] = _dm.chunk_positions(
-                m.cfg, m.kv_config, bucket)
             _tm.event("serving_prewarm", model=model, bucket=bucket,
                       source=got["source"], decode=True, fn=fn,
                       ms=round(got["compile_ms"], 3),
                       temp_bytes=got["temp_bytes"],
-                      alias_bytes=got["alias_bytes"],
-                      attention=m.attn_path, **extra)
+                      alias_bytes=got["alias_bytes"], **extra,
+                      **self._models[model].account.prewarm_attrs(bucket))
             for key in ("temp_bytes", "alias_bytes"):
                 if got[key] is not None:
                     _tm.set_gauge("serving_step_" + key, got[key],
@@ -2812,52 +2671,21 @@ class DecodeEngine:
                                    prev.nxt if prev is not None else None)
             uploads = 1 + sum(isinstance(a, np.ndarray) for a in args[2:])
             _tm.inc("serving_step_uploads_total", uploads, model=m.name)
-            # blocks a layer's attention fetches this step, of the slots
-            # the table has: the live context's share where the kernel
-            # reads in place, all of them where the table is gathered
-            # (latent layers that select attend a lane's chosen rows, the
-            # ``index_topk`` best at most: the row form fetches those,
-            # gathered; the masked walk every live block, as the gather)
-            walks = m.selected_read.get(bucket) != "pallas"
-            attended = lens if walks else np.minimum(lens, m.cfg.index_topk)
-            read = {"kv_blocks_read": m.blocks_read(attended),
-                    "kv_table_slots": bucket * m.maxb,
-                    "kv_block_size": m.kv_config.block_size} \
-                if _tr.enabled() else {}
-            if m.cfg.latent_layers and read:
-                # what a latent layer fetches (each of them the same): a
-                # block there is one row a token, not K and V
-                read["latent_blocks_read"] = read["kv_blocks_read"]
-                # ... in so many chunks, each a whole chunk's arithmetic to
-                # the kernel; of the full ones all of it is of use
-                read["latent_chunks"], read["latent_full_chunks"] = \
-                    m.chunks_read[bucket](attended)
-            if m.index_path and read:
-                # what the selection did: the blocks of a layer's index
-                # pool the scores walked, the rows attention then read of
-                # those the contexts hold, and the lanes past ``index_topk``
-                # (on which alone the two differ)
-                read["index_blocks_read"] = m.index_read(lens)
-                read["latent_rows_selected"] = int(
-                    np.minimum(lens, m.cfg.index_topk).sum())
-                if m.selected_read[bucket] == "pallas_masked":
-                    # the blocks a layer's kernel fetched to read them
-                    read["latent_blocks_walked"] = read["latent_blocks_read"]
-                read["latent_rows_in_context"] = int(lens.sum())
-                read["sparse_lanes"] = int(
-                    (lens > m.cfg.index_topk).sum())
+            # what the step's kinds of layer read, and the rings' blocks:
+            # counted only while the span is recorded
+            read = {}
+            if _tr.enabled():
+                read = m.account.step_attrs(bucket, lens)
+                if windowed:
+                    read.update(m.account.window_attrs(
+                        lens, released, m.cache.window_allocator.in_use,
+                        m.cache.allocator.in_use))
             if slots is not None:
                 # lanes at position 0 start their slot from zeros
                 resets = int((pos[:len(lanes)] == 0).sum())
                 if resets:
                     _tm.inc(m.state_name + "_resets_total", resets,
                             model=m.name)
-                if _tr.enabled():
-                    # the recurrent state this step reads and writes
-                    read[m.state_name + "_lanes"] = len(lanes)
-                    read[m.state_name + "_bytes"] = len(lanes) * m.slot_bytes
-            if windowed and _tr.enabled():
-                read.update(self._window_attrs(m, lens, released))
             sspan = self._open_step_span(m, bucket, lanes, uploads=uploads,
                                          **read)
         # is the device still at work on the step before?  Then it never
@@ -2906,7 +2734,7 @@ class DecodeEngine:
         try:
             with _tr.phase("serving.fetch"):
                 nxt = np.asarray(flight.nxt)
-                moe = self._moe_attrs(m, flight.bucket, flight.extras)
+                moe = m.account.moe_attrs(flight.bucket, flight.extras)
         except Exception as e:
             self._fail_lanes_locked(m, [s for _, s in flight.live()], str(e))
             return {"error": str(e)[:200]}
@@ -2958,83 +2786,6 @@ class DecodeEngine:
                         model=m.name)
             stream = self._tokens_emitted(step=spanned)
         return dict(moe, generated=n_generated, ms=round(ms, 3), **stream)
-
-    @staticmethod
-    def _window_attrs(m, lens, released):
-        """What the window layers' attention fetches this step, over all
-        such layers, beside what it would fetch of the lanes' whole
-        contexts (were they global layers on the same path), and the blocks
-        their pools then hold; recorded only while the step span is.  The
-        counter and the gauges ride along."""
-        n = len(m.cfg.window_layers)
-        walloc, alloc = m.cache.window_allocator, m.cache.allocator
-        _tm.inc("kv_window_blocks_released_total", released, model=m.name)
-        _tm.set_gauge("kv_pool_blocks", walloc.in_use, model=m.name,
-                      kind="window")
-        _tm.set_gauge("kv_pool_blocks", alloc.in_use, model=m.name,
-                      kind="global")
-        read, full, chunks = m.window_read(lens)
-        return {"kv_window_blocks_read": n * read,
-                "kv_window_blocks_full": n * full,
-                "kv_window_blocks_held": walloc.in_use,
-                # live lanes whose context is past the window (their rings
-                # have given blocks back), and the chunks ONE window layer's
-                # attention walked
-                "kv_window_lanes_wrapped": int((lens > m.cfg.window).sum()),
-                "kv_window_chunks": chunks,
-                "kv_block_size": m.kv_config.block_size}
-
-    @staticmethod
-    def _moe_attrs(m, bucket, extras):
-        """A routed-expert step returns the tokens it sent to each expert
-        in each layer that routes (int32 [routed layers, experts], live
-        lanes only: a dense layer has no row, so the means are over the
-        layers that route; a router with groups returns next the lanes that
-        kept each group, [routed layers, n_group]).  The
-        caller hands them over only while the step span is being recorded,
-        so an untraced window pays for no transfer; a step with no experts
-        has none.  Where the step's experts are the kernel's, an expert
-        with no token was not read: counted."""
-        if not extras:
-            return {}
-        everywhere = np.asarray(extras[0])
-        # the experts this model holds the weights of: all of them, or its
-        # share of a router that scores more
-        routed = everywhere[:, m.cfg.held_experts]
-        hit = float((routed > 0).sum(axis=1).mean())
-        _tm.inc("moe_tokens_routed_total", int(routed.sum()), model=m.name)
-        _tm.set_gauge("moe_experts_hit", hit, model=m.name)
-        if m.experts_path.get(bucket) == "pallas":
-            _tm.inc("moe_expert_reads_skipped_total",
-                    int((routed == 0).sum()), model=m.name)
-        # means over the routed layers: experts with a token, the fullest
-        # expert's tokens, and the tokens routed (lanes x experts a token)
-        attrs = {"moe_experts_hit": round(hit, 3),
-                 "moe_load_max": round(float(routed.max(axis=1).mean()), 3),
-                 "moe_assignments": round(float(routed.sum(axis=1).mean()),
-                                          3)}
-        if routed.shape != everywhere.shape:
-            # a share: the assignments computed here, and those left to the
-            # experts it does not hold
-            absent = int(everywhere.sum() - routed.sum())
-            _tm.inc("moe_assignments_absent_total", absent, model=m.name)
-            attrs["moe_local_assignments"] = attrs["moe_assignments"]
-            attrs["moe_absent_assignments"] = round(
-                absent / float(len(routed)), 3)
-        if m.cfg.n_group > 1:
-            # a router that keeps groups before it chooses experts counts,
-            # next, the live lanes that kept each group: of the groups that
-            # hold a held expert, how many a token kept (mean over layers)
-            size = m.cfg.experts // m.cfg.n_group
-            held = m.cfg.held_experts
-            mine = np.asarray(extras[1])[
-                :, held.start // size:(held.stop - 1) // size + 1]
-            lanes = everywhere.sum() / float(m.cfg.experts_per_token
-                                             * len(routed))
-            attrs["moe_groups_kept"] = round(
-                float(mine.sum()) / (len(routed) * lanes), 3) if lanes \
-                else 0.0
-        return attrs
 
     def _spec_step_locked(self, m):
         """One speculative iteration (lock held): the draft decoder

@@ -364,8 +364,8 @@ ROWS = {row.arch: row for row in (
     Row("glm_dsa",
         _both(_GLM, glm_dsa.init_params, std=0.3, bias_std=0.05),
         multi_atol=1e-5, batch_dependent_bf16=True,
-        entry=dict(attn_path="gather", index_path="gather",
-                   experts_path={4: "einsum"}, state_path={}, declines=None),
+        entry=dict(attn_path="gather", experts_path={4: "einsum"},
+                   state_path={}, declines=None),
         serve=("glm-5-serve.json",
                dict(layer_types=_GLM.layer_types, experts=16,
                     experts_held=4, expert_first=4, experts_per_token=3,

@@ -419,6 +419,7 @@ def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
         with open(args.hlo_out, "w") as fp:
             fp.write(text)
     index = hlo_index(text)
+    said = dm.StepAccount(cfg, kv, feed(0)[1], (b,)).prewarm_attrs(b)
     # one layer's K (or V) pool, or a recurrent layer's state slots,
     # whichever is smaller: a copy of either is what the search is for
     pool_elems = args.blocks * args.block_size * kv.heads * kv.head_dim
@@ -442,15 +443,13 @@ def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
         # the weights the step lays out anew every time it runs (a family's
         # ``laid_out`` is there to empty this list)
         "parameter_fed_copies": parameter_fed_copies(text),
-        "attention": dm.attention_path(
-            cfg, kv, b, "latent" if cfg.latent_layers else "attention"),
-        # the columns of a slot one transfer of the state-update kernel
-        # moves (the engine's ``serving_prewarm`` says the same)
-        "state_update_columns": dm.state_update_columns(cfg, kv)
-        if args.ssm_update == "step"
-        and dm.state_update_path(cfg, kv, b) == "pallas" else None,
-        "window_attention": dm.attention_path(cfg, kv, b, "window")
-        if cfg.window_layers else None,
+        # the paths as the engine's ``serving_prewarm`` names them, the
+        # columns of a slot one transfer of the state-update kernel moves
+        # among them
+        "attention": said["attention"],
+        "state_update_columns": said.get("state_update_columns")
+        if args.ssm_update == "step" else None,
+        "window_attention": said.get("window_attention"),
         "pallas_kernel_counters": {
             key: value for key, value in telemetry.snapshot()["counters"].items()
             if key.startswith("pallas_kernel_")},
