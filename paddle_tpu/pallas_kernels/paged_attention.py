@@ -132,13 +132,20 @@ set is another model); a lane at or under ``k`` positions chooses all it
 has.  ``selected_latent_attention`` then reads the chosen rows alone, one of
 three ways by the shapes (``selected_latent_path``; one constant,
 ``_WALK_POSITIONS_PER_CHOSEN``).  Where the table holds few positions a
-chosen one (12,544 for 2,048), **the masked walk**: the chosen positions
-are laid out as a mask by chunk (``_chunk_mask``: two one-hots contracted
-on the MXU, no scatter; scope ``mask``) and the latent kernel walks the
-lane's own table and context with that one more operand, a lane's chunks of
-it in VMEM, so that a position counts where the context holds it AND it was
-chosen (``kv_read``): every live block is fetched, none twice, and no row
-is gathered.  It is the guarded body that takes the mask; a shape whose
+chosen one (12,544 for 2,048), **the masked walk**: the latent kernel walks
+the lane's own table and context under a mask of the chosen positions by
+chunk, one more operand, a lane's chunks of it in VMEM, so that a position
+counts where the context holds it AND it was chosen (``kv_read``): every
+live block is fetched, none twice, and no row is gathered.  The walk wants
+the chosen *set* and no list, so ``choose``'s result (a ``Choice``) gives it
+the set ``by_chunk``: made from the scores by an exact threshold
+(``chosen_by_chunk``: 46 counting passes, each a fusion over scores the
+compiler keeps in VMEM), handed to ``selected_latent_attention`` in the
+list's place (``chosen_for_read``; rank 3 where the list is rank 2), and the
+list, which nothing then reads, is dropped by the compiler with its sort.
+A stand-in for ``choose`` that returns a plain pair has its list laid out
+instead (``_chunk_mask``: two one-hots contracted on the MXU, no scatter;
+scope ``mask``).  It is the guarded body that takes the mask; a shape whose
 body is the straight-line one keeps the row form.  Under a wider table (the
 published 202,752 positions), **the row form**: the rows are gathered by
 (block, offset) into contiguous blocks (scope ``kv_gather``: XLA's gather
@@ -173,7 +180,8 @@ __all__ = ["paged_attention", "paged_attention_reference",
            "latent_attention_checks", "latent_path", "masked_latent",
            "LATENT_KERNEL_NAME", "chunk_positions",
            "latent_chunk_positions", "index_scores", "dense_index_scores",
-           "index_scores_checks", "index_path", "choose", "chosen_mask",
+           "index_scores_checks", "index_path", "choose", "Choice",
+           "chosen_mask", "chosen_by_chunk", "chosen_for_read",
            "selected_latent_attention", "selected_latent_path",
            "INDEX_KERNEL_NAME", "INDEX_CHUNK_TOKENS"]
 
@@ -1302,21 +1310,99 @@ def index_scores(qi, w, pool, block_tables, context_lens):
                               context_lens)
 
 
+def _order_key(scores):
+    """float32 -> the int32 that orders as it does in the plain total order
+    (``-inf < ... < -0.0 < +0.0 < ... < +inf``, nothing canonicalised, which
+    is how ``lax.top_k`` orders them on the CPU and on the TPU): the bits,
+    the magnitude flipped where the sign is set."""
+    bits = jax.lax.bitcast_convert_type(scores.astype(jnp.float32), jnp.int32)
+    return bits ^ ((bits >> 31) & jnp.int32(0x7FFFFFFF))
+
+
+def chosen_by_chunk(scores, context_lens, k, chunks, span):
+    """The ``k`` positions of largest score a lane as the masked walk reads
+    them: ``scores`` [B, S] (``-inf`` at and past a lane's context) -> [B,
+    chunks, span] int32, 1 at ``[c, j]`` where the lane chose position ``c *
+    span + j``: ``chosen_mask``'s set of ``choose``'s result exactly, "of
+    equal scores the lower position first", by a threshold and no sort.
+
+    A lane's threshold ``t`` is its ``k``-th largest key (``_order_key``):
+    the largest ``t`` with ``count(key >= t) >= k``, found bit by bit from
+    the sign down, 32 counts.  Every position with ``key > t`` is chosen and,
+    of those with ``key == t``, the ``need = k - count(key > t)`` lowest: the
+    position of the ``need``-th is found bit by bit too (``count(key == t and
+    position < p) < need``).  AND ``position < context_len``, so a lane at or
+    under ``k`` positions chooses all it has.  Each count is one pass over
+    the scores: a compare, a sum a lane.  The passes are written out, 46 of
+    them for a table of 12,544 positions, so that each is a fusion of its own
+    over operands the compiler keeps in VMEM (32 x 12,544 keys are 1.6e6 B):
+    on the chip they come to 37 us a layer where the sort took 335 and a
+    Pallas kernel holding 8 lanes a grid step 38-42 (PERF.md section 6, PR
+    60), and a loop the compiler does not unroll was not tried."""
+    lanes, length = scores.shape
+    k = min(int(k), length)
+    key = _order_key(scores)
+    count = lambda hit: jnp.sum(hit.astype(jnp.int32), axis=1, keepdims=True)
+    t = jnp.where(count(key >= 0) >= k, jnp.int32(0), jnp.int32(-2 ** 31))
+    for bit in range(30, -1, -1):
+        higher = t | jnp.int32(1 << bit)
+        t = jnp.where(count(key >= higher) >= k, higher, t)
+    tied = key == t
+    need = k - count(key > t)
+    pos = jax.lax.broadcasted_iota(jnp.int32, (lanes, length), 1)
+    # ``p`` ends as the position of the need-th of the tied
+    p = jnp.zeros((lanes, 1), jnp.int32)
+    for bit in range((length - 1).bit_length() - 1, -1, -1):
+        further = p | jnp.int32(1 << bit)
+        p = jnp.where(count(tied & (pos < further)) < need, further, p)
+    chosen = ((key > t) | (tied & (pos <= p))) \
+        & (pos < context_lens.astype(jnp.int32)[:, None])
+    return jnp.pad(chosen.astype(jnp.int32),
+                   ((0, 0), (0, chunks * span - length))) \
+        .reshape(lanes, chunks, span)
+
+
+class Choice(tuple):
+    """``choose``'s pair ``(positions, count)``, the list, which also
+    remembers what it was chosen from: a reader that wants the chosen *set*
+    and no list (the masked walk) asks for it ``by_chunk``, and the list it
+    then never reads is dead code that the compiler drops with its sort.  A
+    stand-in's plain pair cannot, and keeps the list."""
+
+    def __new__(cls, positions, count, scores, context_lens, k):
+        self = super().__new__(cls, (positions, count))
+        self._chosen_from = (scores, context_lens, k)
+        return self
+
+    def by_chunk(self, chunks, span):
+        """The set as ``_chunk_mask`` lays it out ([B, chunks, span] int32),
+        from the scores by a threshold and no sort (``chosen_by_chunk``)."""
+        return chosen_by_chunk(*self._chosen_from, chunks, span)
+
+
 def choose(scores, context_lens, k):
     """The ``k`` positions of largest score a lane (exact; of equal scores
     the lower position first), ``scores`` [B, S] with ``-inf`` past the
     context -> (``positions`` [B, min(k, S)] int32, ``count`` [B] int32:
     the leading ``min(context_len, k)`` of a lane's row are chosen, what
-    follows names positions past its context).  A lane at or under ``k``
-    positions chooses all it has."""
+    follows names positions past its context), a ``Choice``.  A lane at or
+    under ``k`` positions chooses all it has."""
     k = min(int(k), scores.shape[1])
     _best, positions = jax.lax.top_k(scores, k)
-    return positions.astype(jnp.int32), \
-        jnp.minimum(context_lens.astype(jnp.int32), k)
+    return Choice(positions.astype(jnp.int32),
+                  jnp.minimum(context_lens.astype(jnp.int32), k),
+                  scores, context_lens, k)
 
 
 def chosen_mask(positions, count, length):
-    """``choose``'s result as [B, length] bool: the positions chosen."""
+    """``choose``'s result as [B, length] bool: the positions chosen.  Of
+    either form: the list (``positions`` [B, k], its leading ``count``), or
+    the set by chunk that the masked walk is handed in the list's place
+    (``positions`` [B, chunks, span], position ``c * span + j`` at ``[c,
+    j]``)."""
+    if positions.ndim == 3:
+        flat = positions.reshape(positions.shape[0], -1)[:, :length] != 0
+        return jnp.pad(flat, ((0, 0), (0, length - flat.shape[1])))
     lanes = jnp.arange(positions.shape[0], dtype=jnp.int32)[:, None]
     valid = jnp.arange(positions.shape[1], dtype=jnp.int32)[None, :] \
         < count[:, None]
@@ -1373,12 +1459,13 @@ def _walk_checks(q_shape, pool_shape, pool_dtype, rank, k, maxb):
     """``latent_attention_checks`` for the kernel over a lane's own table of
     ``maxb`` slots under a mask of its ``k`` chosen positions (the masked
     walk): the body that takes a mask, a table no longer than
-    ``_WALK_POSITIONS_PER_CHOSEN`` positions a chosen one, and room for the
-    mask beside what the kernel holds."""
+    ``_WALK_POSITIONS_PER_CHOSEN`` positions a chosen one (``k`` None: the
+    caller holds the set as a mask already, whatever its count), and room
+    for the mask beside what the kernel holds."""
     base = latent_attention_checks(q_shape, pool_shape, pool_dtype, rank)
     shaped = all(ok for reason, ok in base if reason != "backend") \
-        and isinstance(k, int) and isinstance(maxb, int) \
-        and k > 0 and maxb > 0
+        and isinstance(maxb, int) and maxb > 0 \
+        and (k is None or (isinstance(k, int) and k > 0))
     if not shaped:
         return base + [("selection", False)]
     bs = pool_shape[1]
@@ -1391,7 +1478,8 @@ def _walk_checks(q_shape, pool_shape, pool_dtype, rank, k, maxb):
     mask = held * 4 * span * (-(-chunks // 8) * 8)
     return base + [
         ("mask_body", not _latent_straight_line(q_shape, pool_dtype, rank)),
-        ("selection", maxb * bs <= _WALK_POSITIONS_PER_CHOSEN * k),
+        ("selection", k is None
+         or maxb * bs <= _WALK_POSITIONS_PER_CHOSEN * k),
         ("mask_vmem", latent_vmem_bytes(q_shape, pool_shape, pool_dtype,
                                         rank) + mask <= _VMEM_BUDGET)]
 
@@ -1416,6 +1504,29 @@ def selected_latent_path(q_shape, pool_shape, pool_dtype, rank, k, maxb):
     return _selected_form(q_shape, pool_shape, pool_dtype, rank, k, maxb)[0]
 
 
+def _walk_layout(q_shape, pool_shape, pool_dtype, rank, maxb):
+    """-> (chunks, span): how the masked walk's mask lies, ``span`` positions
+    a chunk of the latent kernel over a table of ``maxb`` slots."""
+    span = latent_chunk_positions(q_shape, pool_shape, pool_dtype, rank, maxb)
+    return -(-maxb * pool_shape[1] // span), span
+
+
+def chosen_for_read(choice, q_shape, pool_shape, pool_dtype, rank, maxb):
+    """What ``selected_latent_attention`` is handed of ``choose``'s result
+    for a read of these shapes -> (``positions``, ``count``).  Where the read
+    is the masked walk and the choice can give its set by chunk (a
+    ``Choice``; a stand-in's plain pair cannot), the mask ``[B, chunks,
+    span]`` goes in the list's place, made from the scores with no sort;
+    elsewhere the list as it is."""
+    positions, count = choice
+    if isinstance(choice, Choice) and selected_latent_path(
+            q_shape, pool_shape, pool_dtype, rank, positions.shape[1],
+            maxb) == "pallas_masked":
+        return choice.by_chunk(*_walk_layout(q_shape, pool_shape, pool_dtype,
+                                             rank, maxb)), count
+    return positions, count
+
+
 def selected_latent_attention(q, pool, block_tables, context_lens,
                               positions, count, scale, rank):
     """``latent_attention`` over the chosen positions alone (``choose``'s
@@ -1424,23 +1535,28 @@ def selected_latent_attention(q, pool, block_tables, context_lens,
     beside ``k``: the chosen positions laid out as a mask by chunk (scope
     ``mask``) and the kernel's body run over the lane's own table and
     context, a position counted where the context holds it and it was chosen
-    (``kv_read``).  The row form, for a wider table: the chosen rows
-    gathered by (block, offset) into ``k / block_size`` contiguous blocks a
-    lane (scope ``kv_gather``) and the kernel's body run over them in that
-    order, a lane's ``count`` leading ones (``kv_read``).  Elsewhere: the
-    whole table gathered and what was not chosen masked."""
-    bb, k = positions.shape
+    (``kv_read``); ``positions`` of rank 3 are that mask already
+    (``chosen_for_read``: [B, chunks, span], the walk's form by what it is
+    handed) and nothing is laid out.  The row form, for a wider table: the
+    chosen rows gathered by (block, offset) into ``k / block_size``
+    contiguous blocks a lane (scope ``kv_gather``) and the kernel's body run
+    over them in that order, a lane's ``count`` leading ones (``kv_read``).
+    Elsewhere: the whole table gathered and what was not chosen masked."""
+    bb = positions.shape[0]
     bs = pool.shape[1]
     maxb = block_tables.shape[1]
-    form, checks = _selected_form(q.shape, pool.shape, pool.dtype, rank, k,
-                                  maxb)
+    laid_out = positions.ndim == 3
+    k = None if laid_out else positions.shape[1]
+    form, checks = ("pallas_masked", _walk_checks(
+        q.shape, pool.shape, pool.dtype, rank, k, maxb)) if laid_out \
+        else _selected_form(q.shape, pool.shape, pool.dtype, rank, k, maxb)
     use, _reason = adoption.decide("latent_attention", checks)
     if use and form == "pallas_masked":
-        span = latent_chunk_positions(q.shape, pool.shape, pool.dtype, rank,
-                                      maxb)
-        with jax.named_scope("mask"):
-            chosen = _chunk_mask(positions, count, -(-maxb * bs // span),
-                                 span)
+        chosen = positions
+        if not laid_out:
+            with jax.named_scope("mask"):
+                chosen = _chunk_mask(positions, count, *_walk_layout(
+                    q.shape, pool.shape, pool.dtype, rank, maxb))
         with jax.named_scope("kv_read"):
             return _latent_pallas(q, pool, block_tables, context_lens, scale,
                                   rank, chosen=chosen)

@@ -1290,8 +1290,12 @@ def make_paged_step(cfg, kv_config):
                         scores = index_scores(qi, w, ipools[i], block_tables,
                                               context_lens)
                     with jax.named_scope("select"):
-                        positions, count = choose(scores, context_lens,
-                                                  cfg.index_topk)
+                        # the list, or (the masked walk) the set as a mask
+                        positions, count = _pa.chosen_for_read(
+                            choose(scores, context_lens, cfg.index_topk),
+                            q.shape[:2] + (row,), lpools[i].shape,
+                            lpools[i].dtype, cfg.latent_rank,
+                            block_tables.shape[1])
                     return selected_latent_attention(
                         _widened(q, row), lpools[i], block_tables,
                         context_lens, positions, count, cfg.latent_scale,

@@ -874,8 +874,11 @@ def test_glm_dsa_step_compiles_its_three_kernels_at_published_shapes(
     paged-attention kernel at 64 query rows of 640 over a lane's own table
     under a mask of its 2,048 chosen positions (25 chunks of 512 a lane; no
     row is gathered), and the routed-expert kernel over 16 held experts of
-    6144 x 2048; the choice is an exact ``top_k`` (no approximation); every
-    pool is aliased whole, and no pool is copied, turned or converted."""
+    6144 x 2048; the mask is made from a layer's 32 x 12,544 scores by an
+    exact threshold (``chosen_by_chunk``: the list's ``top_k`` is dead code
+    in the served step, so no sort of the scores and no one-hot contraction
+    is compiled, and nothing approximates); every pool is aliased whole, and
+    no pool is copied, turned or converted."""
     from benchmark.models import glm_dsa_decoder
     from paddle_tpu.pallas_kernels import moe_experts as moe
 
@@ -915,6 +918,12 @@ def test_glm_dsa_step_compiles_its_three_kernels_at_published_shapes(
     assert _expert_kernels(text) == 2
     assert _kernel_calls(text) == 8
     assert "ApproxTopK" not in text and "approx" not in text.lower()
+    # the only sorts left are the routers' (8 of 256 experts): nothing of a
+    # lane's 12,544 scores is sorted, and no list is laid out as a mask
+    sorts = [line for line in text.splitlines() if " sort(" in line]
+    assert len(sorts) == 2 and all("moe/router" in line for line in sorts)
+    assert "latent/select/top_k" not in text and "latent/mask" not in text
+    assert "s32[32,2048]" not in text
     assert not _expert_passes(text, 16, 6144, 2048)
     assert params["l0_wkvb_k"].shape == (64, 512, 192) \
         and params["l0_wkvb_v"].shape == (64, 256, 512)
@@ -923,8 +932,8 @@ def test_glm_dsa_step_compiles_its_three_kernels_at_published_shapes(
     assert pool_bytes == 3 * 25120 * 16 * (640 + 128) * 2
     memory = compiled.memory_analysis()
     assert memory.alias_size_in_bytes >= pool_bytes
-    # beside the arguments: a layer's scores (32 x 12,544 float32), the
-    # sort's operands and the mask (32 x 25 x 512 int32); no layer's chosen
+    # beside the arguments: a layer's scores (32 x 12,544 float32) and the
+    # mask, as made and as read (32 x 25 x 512 int32); no layer's chosen
     # rows (32 x 2,048 x 640 bfloat16, 84e6 B) are gathered
     assert memory.temp_size_in_bytes < 32 * 2048 * 640 * 2
     assert "bf16[32,2048,640]" not in text and "bf16[65536,640]" not in text

@@ -77,21 +77,29 @@ and ``latent/out``, ``mlp``, ``moe/router``, ``moe/experts``,
 ``benchmark/dots_cost.py``'s.  For ``--config glm-5-serve --blocks 25120
 --contexts lo-hi`` the latent layers select: the scopes gain
 ``layerN/latent/index`` (the indexer's projections and rotation, the index
-key's write and the ``index_scores`` kernel), ``latent/select`` (the exact
-top-k) and ``latent/mask`` (the chosen positions laid out as the kernel's
-mask) before ``latent/kv_read`` (the latent kernel over a lane's live blocks
-under that mask); the index pools are filled with seeded keys, so that the
-chosen rows lie scattered as a served sequence's do; the result gives
+key's write and the ``index_scores`` kernel) and ``latent/select`` (the
+choice: the chosen set made from the scores as the walk's mask by an exact
+threshold, 46 counting passes and no sort:
+``paged_attention.chosen_by_chunk``) before ``latent/kv_read`` (the latent
+kernel over a lane's live blocks under that mask); the index pools are
+filled with seeded keys, so that the chosen rows lie scattered as a served
+sequence's do; the result gives
 ``selection``: the index kernel's own ms a step and bytes/s over the live
 blocks' keys, the rows chosen of those in context
 (``benchmark/glm_cost.py``), the form the selected read took
-(``selected_read``), the blocks a layer's masked walk fetched and the mask's
-own ms a step.  ``--latent-read`` names a control, not an option of the
-program: ``gathered`` stands the row form in, which a table wider than the
-rule's serves (``latent/kv_gather``, the chosen rows' gather, then the
-kernel over them), and ``dense`` reads every live block under no mask
-(nothing reads the index and the choice there, and the compiler drops
-both).  ``layerN/staged`` (any configuration) is what
+(``selected_read``), how the choice was made (``select_path``:
+``threshold``, the set as a mask; ``listed``, ``top_k``'s list), the
+choice's own ms a step, the blocks a layer's masked walk fetched and what
+laying a list out as the mask cost (``mask_ms_per_step``, the ``latent/mask``
+scope: nothing where the set comes as a mask).  ``--latent-read`` and
+``--select`` name controls, not options of the program: ``--latent-read
+gathered`` stands the row form in, which a table wider than the rule's
+serves (``latent/kv_gather``, the chosen rows' gather, then the kernel over
+them), ``dense`` reads every live block under no mask (nothing reads the
+index and the choice there, and the compiler drops both), and ``--select
+listed`` serves the walk as it was before PR 60: a choice that can give no
+mask, so the exact ``top_k`` (a sort) and the one-hot contraction that turns
+its list back into the mask.  ``layerN/staged`` (any configuration) is what
 XLA puts in itself around a layer's weights and names nothing: a weight
 relaid for the product that reads it, or fetched ahead of it.
 
@@ -633,6 +641,13 @@ def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
         ms = kernel_ms(paged_attention.INDEX_KERNEL_NAME)
         result["selection"] = {
             "latent_read": args.latent_read, "selected_read": form,
+            # how the choice was made (the walk alone reads a mask), and
+            # its scope's ms
+            "select": args.select,
+            "select_path": "threshold" if args.select == "served"
+            and form == "pallas_masked" else "listed",
+            "select_ms_per_step": sum(v for k, v in scopes.items()
+                                      if k.endswith("latent/select")),
             # what a layer's masked walk fetched, and what laying the
             # chosen positions out as its mask cost
             "latent_blocks_walked": result["latent_blocks_read"]
@@ -669,6 +684,12 @@ def main(argv=None):
                     "the chosen rows gathered and the latent kernel over "
                     "them (the row form); dense, every live block under no "
                     "mask")
+    ap.add_argument("--select", default="served",
+                    choices=("served", "listed"),
+                    help="a control of a selecting model's choice: listed, "
+                    "top_k's list and (where the read walks under a mask) "
+                    "the one-hot contraction that lays it out, in place of "
+                    "the threshold's mask")
     ap.add_argument("--blocks", type=int, default=1024)
     ap.add_argument("--bucket", type=int, default=32)
     ap.add_argument("--block-size", type=int, default=16)
@@ -745,6 +766,9 @@ def main(argv=None):
         if args.latent_read == "gathered":
             # no table is short enough for the walk: the rule's other side
             paged_attention._WALK_POSITIONS_PER_CHOSEN = 0
+        if args.select == "listed":
+            # a plain pair can give no mask: the list, laid out where walked
+            dm.choose = lambda *a: tuple(paged_attention.choose(*a))
         if args.latent_read == "dense":
             dm.selected_latent_attention = (
                 lambda q, pool, tables, lens, _positions, _count, scale,
