@@ -23,16 +23,25 @@ class DecoderFamily(collections.namedtuple(
         "DecoderFamily",
         ("kinds", "dtypes", "grouped_query", "routes", "expert_matrices",
          "dense_lead", "holds_share", "own_stream_width", "grouped_router",
-         "rotated_latent", "shared_expert", "expert_gate", "selects"),
+         "rotated_latent", "shared_expert", "expert_gate", "selects",
+         "zero_experts", "scaled_latent"),
         defaults=(("f32", "bf16"), False, None, None, False, False, False,
-                  False, False, False, "silu", False))):
+                  False, False, False, "silu", False, False, False))):
     """``kinds``: the kinds of layer the block computes
     (``decode_model.LAYER_KINDS``: seven of them, of which a family names
     one to three).  ``dtypes``: the weight dtypes it is
     served in.  ``grouped_query``: its attention may have fewer KV heads than
     query heads.  ``routes``: where its feed-forward is routed experts: None
-    (nowhere), ``"after_dense"`` (every layer after ``cfg.dense_layers``) or
-    ``"experts_layers"`` (the layers of kind ``experts``).
+    (nowhere), ``"after_dense"`` (every layer after ``cfg.dense_layers``),
+    ``"experts_layers"`` (the layers of kind ``experts``) or ``"pairs"``: the
+    block is a *pair* of sublayers, each a mixer and a dense MLP, round ONE
+    routed part that reads the stream behind the first sublayer's mixer and
+    is added behind the second sublayer's MLP.  ``cfg.layers`` and
+    ``cfg.layer_types`` then count sublayers (two a pair: an even number),
+    sublayer ``2 i`` and ``2 i + 1`` are pair ``i``'s, each its own ``l`` to
+    ``attend`` and its own pool in the cache, and ``cfg.routed_layers`` names
+    the first sublayer of every pair, which holds the pair's router and
+    experts.
     ``expert_matrices``: how many matrices an expert has, 3 (``wgate``,
     ``wup``, ``wdown``), 2 (``experts_up``, ``experts_down``) or None.
     ``dense_lead``: its first ``cfg.dense_layers`` layers may end in a gated
@@ -51,6 +60,11 @@ class DecoderFamily(collections.namedtuple(
     latent layers attend the ``cfg.index_topk`` positions a learned indexer
     scores highest (``cfg.index_heads`` heads of ``cfg.index_head_dim``,
     whose keys the cache holds in a pool beside each latent pool) and no
-    others."""
+    others.  ``zero_experts``: its router is wider than its experts by
+    ``cfg.zero_experts`` identity experts, which return their input: a token
+    that chooses one computes nothing for it, and its routed compute is the
+    router's to decide.  ``scaled_latent``: its latent layers scale the
+    projected query by ``cfg.latent_q_scale`` and the normed compressed K/V
+    by ``cfg.latent_kv_scale``."""
 
     __slots__ = ()

@@ -20,8 +20,9 @@ token per lane through every layer.  The mixers are of two kinds, named by
   of the rows' latent parts.  This family applies **no** position encoding
   there (``mla_use_nope``: the KDA layers carry position) and projects the
   query in one matrix; ``latent_mixer`` takes a rotation of the row's and
-  the query's ``latent_rope`` values and a compressed query as options, for
-  the families that share it (``dots_vlm``).
+  the query's ``latent_rope`` values, a compressed query, a selection and
+  two scales as options, for the families that share it (``dots_vlm``,
+  ``glm_dsa``, ``longcat_flash``).
 * layer 0 (``cfg.dense_layers``) ends in a SiLU-gated MLP of width
   ``cfg.dense_ffn``; every later one in experts of width ``cfg.ffn`` routed
   over ``cfg.experts``, ``cfg.experts_per_token`` a token, beside one shared
@@ -242,8 +243,9 @@ def _held_laid_out(p):
 
 
 def latent_mixer(cfg, p, l, h, attend, rotate=None, index=None):
-    """The absorbed MLA mixer of layer ``l`` over h [B, H] float32.  Three
-    options, for the families that share it (``dots_vlm``, ``glm_dsa``):
+    """The absorbed MLA mixer of layer ``l`` over h [B, H] float32.  Five
+    options, for the families that share it (``dots_vlm``, ``glm_dsa``,
+    ``longcat_flash``):
     ``cfg.q_rank`` given, the query goes through a low-rank pair with a
     norm between (``wq_a``, ``q_norm``, ``wq_b``) and not through ``wq``;
     ``rotate`` given, ``rotate(x [B, n, latent_rope])`` turns the row's
@@ -252,7 +254,13 @@ def latent_mixer(cfg, p, l, h, attend, rotate=None, index=None):
     absorb; ``index`` given, ``index(cq)`` makes of the normed compressed
     query what ``attend`` chooses the attended positions by (an indexer's
     queries, their heads' weights and this token's index key), handed to it
-    where a plain latent layer hands None.  A head's values are
+    where a plain latent layer hands None; ``cfg.latent_q_scale`` other than
+    1, the projected query (both its parts) is multiplied by it, and
+    ``cfg.latent_kv_scale`` other than 1, the normed compressed K/V, before
+    the row is written: the cache then holds ``[a_kv c | k_pe]``, from which
+    ``wkvb`` makes the scaled keys and values the source makes, so a score
+    and an output are the published ones with nothing folded into a weight.
+    A head's values are
     ``cfg.v_head_dim`` wide, its own key part ``cfg.head_dim``.  ``wkvb`` is
     read as ``laid_out`` left it or, handed the published array, cut from
     that: the same products of the same values."""
@@ -273,10 +281,14 @@ def latent_mixer(cfg, p, l, h, attend, rotate=None, index=None):
             if cfg.q_rank else None
         q = _mm(cq, p("wq_b")) if cfg.q_rank else _mm(h, p("wq"))
         q = q.reshape(bb, heads, d + cfg.latent_rope)
+        if cfg.latent_q_scale != 1.0:
+            q = q * cfg.latent_q_scale
     chosen_by = index(cq) if index is not None else None
     with jax.named_scope("absorb"):
         row = _mm(h, p("wkva"))
         c = _kv_norm(row[:, :rank], p("kv_norm"), cfg.norm_eps)
+        if cfg.latent_kv_scale != 1.0:
+            c = c * cfg.latent_kv_scale
         k_pe = row[:, rank:]
     if rotate is not None:
         with jax.named_scope("rope"):

@@ -115,7 +115,8 @@ __all__ = ["DecoderConfig", "init_decoder_params", "save_decoder",
 
 # the families, a module each: ``models/<arch>.py``
 ARCHS = ("gpt2", "olmoe", "granite_hybrid", "lfm2_moe", "exaone_moe",
-         "nemotron_h", "kimi_linear", "dots_vlm", "smallthinker", "glm_dsa")
+         "nemotron_h", "kimi_linear", "dots_vlm", "smallthinker", "glm_dsa",
+         "longcat_flash")
 LAYER_KINDS = ("attention", "mamba", "conv", "window", "experts", "kda",
                "latent")
 # recurrent kind -> the name its slot goes by in spans, gauges and counters
@@ -216,6 +217,18 @@ class DecoderConfig:
     ``head_dim`` own key values, and latent layers that select: an indexer
     of ``index_heads`` heads of ``index_head_dim`` scores every cached
     position and the attention reads the ``index_topk`` best.
+    ``longcat_flash`` is the block of ``models/longcat_flash.py``: a *pair*
+    of sublayers a block (``layers`` and ``layer_types`` count sublayers,
+    every one ``latent``), each ``dots_vlm``'s mixer with plain rotation,
+    the projected query times ``latent_q_scale`` and the normed compressed
+    K/V times ``latent_kv_scale``, and a gated MLP of width ``dense_ffn``;
+    one routed part a pair, read behind the first sublayer's mixer and added
+    behind the second's MLP, whose softmax router scores ``experts +
+    zero_experts`` outputs, chooses ``experts_per_token`` of them by a bias
+    that never weighs, and weighs the chosen by ``routed_scaling`` times
+    their probability, not renormalised: a chosen identity expert (one of
+    the last ``zero_experts``) adds the token's own input times its gate.
+    No shared expert, no dense lead; it may hold a share.
 
     ``kv_heads`` None means ``heads`` (multi-head); ``layer_types`` None
     means ``layers`` attention layers.  ``dtype`` is the weights' (``f32``
@@ -235,7 +248,8 @@ class DecoderConfig:
                  "kda_head_dim", "kda_conv", "latent_rank", "latent_rope",
                  "q_rank", "n_group", "topk_group", "rope_scaling",
                  "v_head_dim", "index_heads", "index_head_dim",
-                 "index_topk")
+                 "index_topk", "zero_experts", "latent_q_scale",
+                 "latent_kv_scale")
 
     def __init__(self, vocab, layers, heads, head_dim, ffn=None,
                  max_seq=64, arch="gpt2", dtype="f32", kv_dtype=None,
@@ -250,7 +264,8 @@ class DecoderConfig:
                  ssm_groups=1, kda_heads=0, kda_head_dim=0, kda_conv=0,
                  latent_rank=0, latent_rope=0, q_rank=0, n_group=1,
                  topk_group=1, rope_scaling=None, v_head_dim=None,
-                 index_heads=0, index_head_dim=0, index_topk=0):
+                 index_heads=0, index_head_dim=0, index_topk=0,
+                 zero_experts=0, latent_q_scale=1.0, latent_kv_scale=1.0):
         if arch not in ARCHS:
             raise ValueError("decoder arch must be %s: %r"
                              % ("|".join(ARCHS), arch))
@@ -316,6 +331,9 @@ class DecoderConfig:
         self.index_heads = int(index_heads)
         self.index_head_dim = int(index_head_dim)
         self.index_topk = int(index_topk)
+        self.zero_experts = int(zero_experts)
+        self.latent_q_scale = float(latent_q_scale)
+        self.latent_kv_scale = float(latent_kv_scale)
         if self.hidden_size not in (None, self.heads * self.head_dim) \
                 and not family.own_stream_width:
             raise ValueError("the %s block's stream is heads * head_dim "
@@ -405,6 +423,22 @@ class DecoderConfig:
                 "expert_first + experts_held) of %d: %r from %r"
                 % (_declaring("holds_share"), self.experts, experts_held,
                    expert_first))
+        if self.layers % self.layers_a_block:
+            raise ValueError(
+                "the %s block is a pair of sublayers: layers counts "
+                "sublayers, two a pair, got %d" % (arch, self.layers))
+        if self.zero_experts < 0 or (self.zero_experts
+                                     and not family.zero_experts):
+            raise ValueError(
+                "the %s blocks' routers score zero_experts identity experts "
+                "beside their experts: %r"
+                % (_declaring("zero_experts"), zero_experts))
+        if (self.latent_q_scale, self.latent_kv_scale) != (1.0, 1.0) \
+                and not (family.scaled_latent and self.latent_layers):
+            raise ValueError(
+                "latent_q_scale and latent_kv_scale are for the %s blocks' "
+                "latent layers: %r, %r" % (_declaring("scaled_latent"),
+                                           latent_q_scale, latent_kv_scale))
         if self.shared_ffn and not family.shared_expert:
             raise ValueError(
                 "the %s blocks pass every token through a shared expert of "
@@ -482,12 +516,29 @@ class DecoderConfig:
     def routed_layers(self):
         """Indices of the layers whose feed-forward is routed experts, in
         order: the rows of the step's ``routed`` counts.  A block that names
-        ``experts`` layers routes in those; any other routed block in every
+        ``experts`` layers routes in those; a block of pairs once a pair, in
+        its first sublayer (where the routed part reads the stream and where
+        its router and experts are held); any other routed block in every
         layer after its ``dense_layers``."""
         routes = _model(self.arch).FAMILY.routes
         if routes == "experts_layers":
             return self._of_kind("experts")
+        if routes == "pairs":
+            return tuple(range(0, self.layers, self.layers_a_block))
         return tuple(range(self.dense_layers, self.layers)) if routes else ()
+
+    @property
+    def layers_a_block(self):
+        """``layers`` and ``layer_types`` entries one block of the family
+        takes: 2 where the block is a pair of sublayers round one routed
+        part, else 1."""
+        return 2 if _model(self.arch).FAMILY.routes == "pairs" else 1
+
+    @property
+    def router_width(self):
+        """The outputs a routed layer's router scores: its experts and then
+        its identity experts."""
+        return self.experts + self.zero_experts
 
     @property
     def held_experts(self):
@@ -670,7 +721,9 @@ def truncate_decoder(cfg, params, layers=1):
     residual stream dominated by the embedding, the truncated argmax
     tracks the full model's closely — a distillation-free draft for
     demos and smokes (real deployments train one)."""
-    layers = min(int(layers), cfg.layers)
+    # whole blocks: of a family of pairs, whole pairs
+    period = cfg.layers_a_block
+    layers = min(-(-int(layers) // period) * period, cfg.layers)
     dcfg = cfg.replace(layers=layers, layer_types=cfg.layer_types[:layers],
                        dense_layers=min(cfg.dense_layers, layers))
     dparams = {}
@@ -720,7 +773,16 @@ def _block(cfg):
     marks the lanes that hold a sequence; ``extras`` is a tuple of small
     arrays the step returns after its logits (a routed block's tokens sent
     to each expert, a row a layer of ``cfg.routed_layers``; nothing for the
-    others).  Every family's block is ``token_logits`` of its module."""
+    others).  A block of *pairs* (``FAMILY.routes`` ``"pairs"``) runs two
+    sublayers and one routed part a pair: ``cfg.layers`` counts sublayers,
+    sublayer ``l`` is pair ``l // 2``'s, ``attend`` gets the sublayer's own
+    ``l`` (so every sublayer has its pool, as every layer of another block
+    has), and a row of the counts is a pair's, named in
+    ``cfg.routed_layers`` by its first sublayer; where the router scores
+    identity experts too the counts are ``cfg.router_width`` wide, the
+    identity experts' columns last, and a second extra counts the live lanes
+    by how many real experts they chose, ``[pairs, experts_per_token + 1]``.
+    Every family's block is ``token_logits`` of its module."""
     return _model(cfg.arch).token_logits
 
 
@@ -1032,6 +1094,11 @@ class StepAccount:
         if experts_gate(cfg) != "silu":
             # said only where the family declares another than SiLU
             self._said["experts_gate"] = experts_gate(cfg)
+        if cfg.zero_experts:
+            # said only where the routed part is not a layer's own
+            # feed-forward over experts that all compute
+            self._said.update(routes=_model(cfg.arch).FAMILY.routes,
+                              zero_experts=cfg.zero_experts)
 
     def moe_attrs(self, bucket, extras):
         """A routed step's ``extras`` (``_block``: the tokens it sent to
@@ -1040,11 +1107,18 @@ class StepAccount:
         attributes, means over the layers that route.  The caller hands them
         over only while the span is recorded, so an untraced window pays for
         no transfer; a step with no experts has none.  Where the experts are
-        the kernel's, an expert with no token was not read: counted."""
+        the kernel's, an expert with no token was not read: counted.  An
+        assignment is of up to three kinds: to an expert held here, to one
+        held elsewhere (a share) or to an identity expert (a router wider
+        than its experts), and the three add up to the live lanes times
+        ``experts_per_token``; ``moe_assignments`` is the first kind, as it
+        has been since shares."""
         if not extras:
             return {}
         cfg = self.cfg
-        everywhere = np.asarray(extras[0])
+        # the identity experts' columns lie behind the experts'
+        scored = np.asarray(extras[0])
+        everywhere = scored[:, :cfg.experts]
         routed = everywhere[:, cfg.held_experts]
         hit = float((routed > 0).sum(axis=1).mean())
         _tm.inc("moe_tokens_routed_total", int(routed.sum()), model=self.model)
@@ -1066,6 +1140,18 @@ class StepAccount:
             attrs["moe_local_assignments"] = attrs["moe_assignments"]
             attrs["moe_absent_assignments"] = round(
                 absent / float(len(routed)), 3)
+        if cfg.zero_experts:
+            # what the router left uncomputed, and the most and the fewest
+            # real experts one live lane chose in any routed layer
+            zero = int(scored.sum() - everywhere.sum())
+            _tm.inc("moe_assignments_zero_total", zero, model=self.model)
+            attrs["moe_zero_assignments"] = round(
+                zero / float(len(routed)), 3)
+            counts = np.flatnonzero(np.asarray(extras[1]).sum(axis=0))
+            most, fewest = (int(counts[-1]), int(counts[0])) \
+                if len(counts) else (0, 0)
+            attrs.update(moe_real_per_token_max=most,
+                         moe_real_per_token_min=fewest)
         if cfg.n_group > 1:
             # of the groups that hold a held expert, how many a token kept
             # (mean over layers)

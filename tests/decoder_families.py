@@ -23,7 +23,8 @@ import pytest
 import paddle_tpu as fluid
 from paddle_tpu.core import telemetry as _tm
 from paddle_tpu.models import dots_vlm, exaone_moe, glm_dsa, \
-    granite_hybrid, kimi_linear, lfm2_moe, nemotron_h, olmoe, smallthinker
+    granite_hybrid, kimi_linear, lfm2_moe, longcat_flash, nemotron_h, olmoe, \
+    smallthinker
 from paddle_tpu.serving import DecodeEngine
 from paddle_tpu.serving import decode_model as dm
 from paddle_tpu.serving import kv_cache as kvc
@@ -260,6 +261,15 @@ _GLM = dm.DecoderConfig(
     latent_rope=8, q_rank=20, index_heads=4, index_head_dim=16, index_topk=8,
     dense_layers=1, dense_ffn=64, ffn=24, shared_ffn=24, experts=16,
     experts_per_token=3, routed_scaling=2.5, rope_theta=1e6)
+# two pairs: four sublayers, every one a latent mixer and a dense MLP, a
+# router of 16 experts and 8 identity experts in sublayers 0 and 2; the two
+# scales as published, the roots of hidden over the two ranks
+_LONGCAT = dm.DecoderConfig(
+    arch="longcat_flash", vocab=97, layers=4, heads=4, head_dim=16,
+    hidden_size=48, max_seq=64, layer_types=("latent",) * 4, latent_rank=24,
+    latent_rope=8, q_rank=20, latent_q_scale=(48 / 20) ** 0.5,
+    latent_kv_scale=(48 / 24) ** 0.5, dense_ffn=64, ffn=24, experts=16,
+    zero_experts=8, experts_per_token=3, routed_scaling=6.0, rope_theta=1e7)
 _GRANITE_G4 = _GRANITE.replace(kv_heads=1)
 
 # Weights are normal(0, 0.3) (OLMoE's 0.05) and a router bias of 0.05: at
@@ -375,6 +385,21 @@ ROWS = {row.arch: row for row in (
         # the kernel walks the 2,048 chosen rows, gathered: rows of 640
         # bfloat16, 1,280 B a position
         chunk=("glm-5-serve.json", 25120, {"latent": 512})),
+    # every sublayer pages (one latent row a token): nothing is declined
+    Row("longcat_flash",
+        _both(_LONGCAT, longcat_flash.init_params, std=0.3, bias_std=0.05),
+        multi_atol=1e-5, batch_dependent_bf16=True,
+        entry=dict(attn_path="gather", experts_path={4: "einsum"},
+                   state_path={}, declines=None),
+        serve=("longcat-flash-chat-serve.json",
+               dict(layer_types=_LONGCAT.layer_types, experts=16,
+                    experts_held=4, expert_first=4, zero_experts=8,
+                    experts_per_token=3, q_rank=20, latent_rank=24,
+                    latent_rope=8, hidden=48, ffn=24, dense_ffn=64,
+                    latent_q_scale=(48 / 20) ** 0.5,
+                    latent_kv_scale=(48 / 24) ** 0.5)),
+        # one row of 640 bfloat16 a position: 1,280 B
+        chunk=("longcat-flash-chat-serve.json", 17472, {"latent": 512})),
 )}
 assert tuple(ROWS) == dm.ARCHS
 

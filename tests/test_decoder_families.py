@@ -87,7 +87,7 @@ def test_multi_token_step_equals_single(row, key, width):
             np.testing.assert_allclose(lw, l1, atol=row.multi_atol)
     if cfg.routed_layers:
         # a step's counts are summed over its columns
-        assert routed[0].shape == (len(cfg.routed_layers), cfg.experts)
+        assert routed[0].shape == (len(cfg.routed_layers), cfg.router_width)
         if not n:
             assert sum(int(r.sum()) for r in routed) \
                 == sum(int(r.sum()) for r in routed1) \
@@ -622,7 +622,9 @@ def test_serve_tool_writes_and_serves_a_demo_bundle(row, tmp_path,
     for field, want in says.items():
         assert getattr(cfg, field) == want, field
     draft, _params = dm.load_draft(d)
-    assert (draft.arch, draft.layer_types) == (row.arch, cfg.layer_types[:1])
+    # a layer's truncation; of a block of pairs, a pair's
+    assert (draft.arch, draft.layer_types) == (
+        row.arch, cfg.layer_types[:cfg.layers_a_block])
     e = fam.engine(d, None, 24, buckets="2")
     try:
         assert e.spec("m")["arch"] == row.arch \

@@ -75,14 +75,22 @@ def _lens(cfg, lanes):
 
 
 def _extras(cfg):
-    """A routed step's counts as it returns them: tokens an expert a layer,
-    every third expert without one, and the lanes that kept each group."""
+    """A routed step's counts as it returns them: tokens an output of the
+    router a layer (an expert, or behind the experts an identity expert),
+    every third without one, and the lanes that kept each group or, of a
+    router with identity experts, the lanes that chose so many real
+    experts."""
     rng = np.random.RandomState(5)
-    counts = rng.randint(0, 4, (len(cfg.routed_layers), cfg.experts))
+    counts = rng.randint(0, 4, (len(cfg.routed_layers), cfg.router_width))
     counts[:, ::3] = 0
-    groups = [rng.randint(0, 5, (len(cfg.routed_layers), cfg.n_group))
-              .astype(np.int32)] if cfg.n_group > 1 else []
-    return [counts.astype(np.int32)] + groups
+    more = [rng.randint(0, 5, (len(cfg.routed_layers), cfg.n_group))
+            .astype(np.int32)] if cfg.n_group > 1 else []
+    if cfg.zero_experts:
+        real = rng.randint(0, 3, (len(cfg.routed_layers),
+                                  cfg.experts_per_token + 1))
+        real[:, 0] = 0
+        more.append(real.astype(np.int32))
+    return [counts.astype(np.int32)] + more
 
 
 def _telemetry(prefixes):

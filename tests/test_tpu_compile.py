@@ -944,6 +944,72 @@ def test_glm_dsa_step_compiles_its_three_kernels_at_published_shapes(
     assert not found, found
 
 
+def test_longcat_flash_step_compiles_at_published_widths_and_64_lanes(
+        one_chip, as_on_tpu):
+    """LongCat-Flash's published widths, one layer of the source (a pair:
+    two latent sublayers, two dense MLPs of 12288, a router of 768 outputs
+    over 16 held experts of 6144 x 2048), the cell's bucket of 64 lanes and
+    its pools (17,472 bf16 latent blocks whose rows of 576 values lie 640
+    wide, one a sublayer): Mosaic accepts, inside the whole step as the
+    engine compiles it (``make_packed_step``), the latent form of the
+    paged-attention kernel at 64 heads x 64 lanes of 640 and the
+    routed-expert kernel ONCE a pair; every pool is aliased whole, no pool
+    and no expert tensor is copied, turned or converted, and no weight is
+    laid out again."""
+    from benchmark.models import longcat_flash_decoder
+    from paddle_tpu.pallas_kernels import moe_experts as moe
+
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "longcat-flash-chat-serve.json")) as fp:
+        config = dict(json.load(fp), num_layers=1)
+    cfg = longcat_flash_decoder.decoder_config(config)
+    assert (cfg.hidden, cfg.heads, cfg.head_dim, cfg.latent_rank,
+            cfg.latent_rope, cfg.q_rank, cfg.experts, cfg.experts_held,
+            cfg.zero_experts, cfg.router_width, cfg.experts_per_token,
+            cfg.ffn, cfg.dense_ffn, cfg.layer_types, cfg.routed_layers,
+            cfg.max_seq) == (
+        6144, 64, 128, 512, 64, 1536, 512, 16, 256, 768, 12, 2048, 12288,
+        ("latent",) * 2, (0,), 4352)
+    assert (cfg.latent_q_scale, round(cfg.latent_kv_scale, 4)) == (2.0, 3.4641)
+    lanes, block_size, blocks = 64, 16, 17472
+    kv = dm.cache_config(cfg, block_size, blocks)
+    assert (kv.layers, kv.latent_layers, kv.latent_row, kv.state_layers) \
+        == (0, 2, 640, 0)
+    assert dm.attention_path(cfg, kv, lanes, "latent") == "pallas"
+    assert dm.chunk_positions(cfg, kv, lanes) == {"latent": 512}
+    assert moe.experts_path(lanes, (16, 6144, 2048), jnp.bfloat16) \
+        == "pallas"
+
+    on_chip = functools.partial(_placed, one_chip)
+    carry = on_chip(jax.eval_shape(lambda: PagedKVCache(kv).carry()))
+    params = on_chip(_as_held(
+        cfg, longcat_flash_decoder.param_shapes(config)))
+    feeds = on_chip(_packed_feeds(kv, lanes, cfg.max_seq // block_size))
+    compiled = jax.jit(dm.make_packed_step(cfg, kv, lanes),
+                       donate_argnums=(0,)
+                       ).lower(carry, params, *feeds).compile()
+
+    text = compiled.as_text()
+    assert _kernel_calls(text) == 3             # 2 latent, 1 experts
+    assert len(re.findall(r"%latent_attention\S* = ", text)) == 2
+    assert _expert_kernels(text) == 1
+    assert not _expert_passes(text, 16, 6144, 2048)
+    assert params["l0_wkvb_k"].shape == (64, 512, 128) \
+        and params["l1_wkvb_v"].shape == (64, 128, 512)
+    assert _weights_relaid(text) == []
+    pool_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in carry)
+    assert pool_bytes == 2 * 17472 * 16 * 640 * 2
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= pool_bytes
+    # beside the arguments: 64 lanes' queries and outputs of 64 heads and
+    # the dense MLPs' activations; under a fifth of one pool
+    assert memory.temp_size_in_bytes < 17472 * 16 * 640 * 2 / 5
+    big = re.compile(r" = bf16\[17472,16,640\]\S* (copy|transpose|convert)\(")
+    found = [line.strip()[:160] for line in text.splitlines()
+             if big.search(line)]
+    assert not found, found
+
+
 def test_glm_dsa_selected_read_gathers_the_rows_under_the_published_table(
         one_chip, as_on_tpu):
     """The other side of the rule: under a table of the published 202,752

@@ -263,7 +263,7 @@ def scope_of(op_name):
                      "ssm", "in_proj", "conv", "state_update", "out_proj",
                      "window", "kda", "state", "out", "latent", "absorb",
                      "shared", "q_compress", "rope", "staged", "index",
-                     "select", "mask")]
+                     "select", "mask", "zero")]
     return "/".join(keep) or "other"
 
 
@@ -406,8 +406,8 @@ def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
     import numpy as np
 
     from benchmark import dots_cost, exaone_cost, glm_cost, kimi_cost, \
-        lfm2_cost, moe_cost, nemotron_cost, smallthinker_cost, ssm_cost, \
-        trace_reduce
+        lfm2_cost, longcat_cost, moe_cost, nemotron_cost, smallthinker_cost, \
+        ssm_cost, trace_reduce
     from paddle_tpu.core import telemetry
     from paddle_tpu.core.executor import CarriedStepFn
     from paddle_tpu.pallas_kernels import kda_update, paged_attention, \
@@ -527,6 +527,10 @@ def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
         elif "linear_attn_config" in config:
             # three-matrix experts behind a dense lead, a share
             bytes_of = kimi_cost.experts_hit_bytes_per_step
+        elif "zero_expert_num" in config:
+            # one routed part a pair of sublayers, a share; an identity
+            # expert has no bytes
+            bytes_of = longcat_cost.experts_hit_bytes_per_step
         elif "n_group" in config and "kv_lora_rank" in config:
             # the same behind latent attention in every layer, a share
             bytes_of = dots_cost.experts_hit_bytes_per_step
@@ -617,7 +621,8 @@ def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
             np.minimum(now, cfg.index_topk) if form == "pallas" else now,
             args.block_size,
             cfg.max_seq // args.block_size, result["attention"])
-        cost = kimi_cost if "linear_attn_config" in config else dots_cost
+        cost = kimi_cost if "linear_attn_config" in config \
+            else longcat_cost if "zero_expert_num" in config else dots_cost
         moved = cost.latent_floor_bytes_per_step(config, read,
                                                  args.block_size)
         result["latent_blocks_read"] = read
@@ -626,11 +631,11 @@ def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
         if ms:
             result["latent_attention_kernel_ms_per_step"] = ms
             result["latent_attention_kernel_bytes_per_s"] = moved / (ms / 1e3)
-            if cost is dots_cost:
-                # 128 heads over a row put the kernel at the chip's ridge:
-                # its operations beside its bytes
+            if cost is not kimi_cost:
+                # 128 heads over a row put the kernel at the chip's ridge
+                # (64, at half of it): its operations beside its bytes
                 result["latent_attention_kernel_flops_per_s"] = \
-                    dots_cost.latent_flops_per_step(
+                    cost.latent_flops_per_step(
                         config, read, args.block_size) / (ms / 1e3)
     if cfg.index_topk:
         walked = paged_attention.blocks_read(
@@ -726,7 +731,9 @@ def main(argv=None):
         config = json.load(fp)
     config.pop("tiny", None)
     if args.layers:
-        config["n_layer" if "n_layer" in config
+        # (``num_layers``: a source whose layers are pairs of sublayers)
+        config["n_layer" if "n_layer" in config else "num_layers"
+               if "num_layers" in config
                else "num_hidden_layers"] = args.layers
         for key in ("layer_types", "mlp_layer_types", "sliding_windows",
                     "hybrid_override_pattern", "rope_layout",
