@@ -89,20 +89,18 @@ and the value is the row's first ``rank`` columns, so a block is fetched
 once and serves as K and as V (-> ``[B, H, rank]``).  ``W`` and ``rank`` are
 whole 128-lane tiles: the cache rounds a row up to that and keeps the rest
 zeros (576 values lie in rows of 640), since a kernel fetches whole tiles of
-the pool's layout.  The K/V kernel with one pool and one chunk buffer,
-under its own name in a trace (``LATENT_KERNEL_NAME``), and with one of two
-chunk **bodies**, chosen by the shapes (``_latent_straight_line``: the
-operations a byte of a chunk against one constant).  The K/V body guards
-each step of 8 copies by a predicate and loops over the last few, so that a
-lane's last chunk fetches its live blocks and no others: right where the
-transfers bind, as at 32 query heads (58 operations a byte: a chunk's
-copies take 0.9 us, its arithmetic 0.57, and in the step the kernel shares
-the HBM with the weights XLA fetches ahead beside it).  At 128 heads (230
-a byte) the arithmetic outlasts the transfers, 1.09 us to 0.87, and the
-copies' issue and waits, scalar work in the kernel's one instruction
-stream, stood beside both (1.75 us a chunk).  There (``_latent_kernel``)
-every chunk is ONE basic block that the scheduler fills with scalar, vector
-and matrix work side by side, which takes two things.  No predicate: every
+the pool's layout.  A kernel of its own (``_latent_kernel``,
+``LATENT_KERNEL_NAME`` in a trace): one pool, one pair of chunk buffers, and
+a chunk body other than the K/V form's.  The K/V body guards each step of 8
+copies by a predicate and loops over the last few, so that a lane's last
+chunk fetches its live blocks and no others; in a latent chunk the copies'
+issue and waits, scalar work in the kernel's one instruction stream, then
+stood beside the transfers and the arithmetic, overlapping neither (us a
+chunk of 512 positions over bfloat16 rows of 640, copies alone | arithmetic
+alone | the guarded whole: 0.93 | 0.57 | 1.31 at 32 query heads, 0.95 | 0.73
+| 1.38 at 64, 0.87 | 1.09 | 1.75 at 128).  Here every chunk is ONE basic
+block that the scheduler fills with scalar, vector and matrix work side by
+side (1.11, 1.17 and 1.27 us), which takes two things.  No predicate: every
 chunk issues all its copies, a slot past the lane's last block fetching
 that block again (masked positions; no block the table does not name is
 read, and of a lane's last chunk up to one chunk less a block is fetched
@@ -113,6 +111,17 @@ copies of the walk chosen by the parity of the chunks fetched before it;
 with a traced index the compiler must assume that a copy into one buffer
 and a load from the other touch the same memory, and keeps them in program
 order (1.5 us a chunk; 1.27 with constants: PERF.md section 6, PR 50).
+From 64 heads on the body's gain shows in the step (dots.vlm1's cell at PR
+50, LongCat-Flash's and GLM-5's at PR 62: a kernel call at LongCat's
+contexts 480 -> 408 us); at Kimi-Linear's 32 the transfers bind, XLA's
+fetches beside the kernel take back what it gains alone and the step reads
+the same (its cell a tie, PRs 50 and 62), so the guarded latent body that
+cell had kept went: one body, and no rule between two (PR 62).  A last chunk
+of a shorter span of its own (whole steps of ``CHUNK_TOKENS``, in a copy of
+the walk's end a span: 18 chunk bodies for 6) was built and measured at PR
+62: 4% of the kernel alone, nothing LongCat-Flash's cell could show in three
+pairs, and taken out again (PERF.md section 6, PR 62).  The same body takes
+a selection's mask (below).
 
 A latent layer that **selects** (a model whose ``index_topk`` is set)
 attends the positions a learned indexer scores highest and no others, in
@@ -145,8 +154,7 @@ list's place (``chosen_for_read``; rank 3 where the list is rank 2), and the
 list, which nothing then reads, is dropped by the compiler with its sort.
 A stand-in for ``choose`` that returns a plain pair has its list laid out
 instead (``_chunk_mask``: two one-hots contracted on the MXU, no scatter;
-scope ``mask``).  It is the guarded body that takes the mask; a shape whose
-body is the straight-line one keeps the row form.  Under a wider table (the
+scope ``mask``).  Under a wider table (the
 published 202,752 positions), **the row form**: the rows are gathered by
 (block, offset) into contiguous blocks (scope ``kv_gather``: XLA's gather
 costs by the count of its rows, 26 ns each) and the kernel's body runs over
@@ -174,7 +182,7 @@ from . import adoption
 
 __all__ = ["paged_attention", "paged_attention_reference",
            "paged_attention_checks", "attention_path", "blocks_read",
-           "chunks_read",
+           "chunks_read", "blocks_refetched",
            "masked_attention", "gather_blocks", "ring_mask", "KERNEL_NAME",
            "latent_attention", "latent_attention_reference",
            "latent_attention_checks", "latent_path", "masked_latent",
@@ -420,9 +428,9 @@ def blocks_read(context_lens, block_size, maxb, path, ring=False):
     """Blocks one layer's attention fetches for these lanes: every slot of
     the table on the gather path; on the kernel's, the blocks each lane
     holds (``ceil(context_len / block_size)``: a chunk fetches its live
-    blocks and no others; the latent form's straight-line body fetches a
-    lane's last block again for the rest of its last chunk, which this does
-    not count).  For a window layer (``ring``: the table is its ring of
+    blocks and no others; what the latent form fetches twice, or for nobody,
+    is ``blocks_refetched``'s to count, not this).
+    For a window layer (``ring``: the table is its ring of
     ``maxb`` slots) a live lane's whole ring where the ring is one chunk
     (``_ring_whole``), and where it is walked in chunks the slots the lane
     holds, ``min(ceil(context_len / block_size), maxb)``: the same count as
@@ -443,12 +451,26 @@ def chunks_read(context_lens, block_size, maxb, span):
     blocks of two chunks of 512 and sees 508 positions of the second).  All
     of a full chunk's copies and arithmetic are of use; of a lane's last
     chunk the arithmetic covers the whole span whatever part is seen, and
-    the latent form's straight-line body pays a whole chunk's copies too.
+    the latent form pays a whole chunk's copies too.
     A host-side count for the step's span, as ``blocks_read``."""
     per = span // block_size
     chunks = -(-(-(-context_lens // block_size)).clip(0, maxb) // per)
     return int(chunks.sum()), int(np.minimum(context_lens // span,
                                              chunks).sum())
+
+
+def blocks_refetched(context_lens, block_size, maxb, span):
+    """Blocks one layer's latent kernel fetches beyond those the lanes hold
+    (``blocks_read``), at ``span`` positions a chunk.  Its body issues
+    every copy of a chunk, a slot past the lane's last block fetching that
+    block again (the rest of a lane's last chunk), and where nothing follows
+    a live lane its last chunk is fetched once more, whole, for nobody.  A
+    host-side count for the step's span, as ``blocks_read``."""
+    per = span // block_size
+    held = (-(-context_lens // block_size)).clip(0, maxb)
+    live = held > 0
+    ends = live & ~np.append(live[1:], False)     # nothing follows these
+    return int((-held % per).sum()) + per * int(ends.sum())
 
 
 def _chunk_positions(fetched, block_size, held):
@@ -525,31 +547,15 @@ def _product(rows, x, dims):
 
 
 def _kernel(bt_ref, cl_ref, q_ref, *refs, heads, kv_heads, head_dim,
-            block_size, maxb, per, scale, window=None, value_cols=None,
-            lane_grid=False, masked=False):
+            block_size, maxb, per, scale, window=None):
     """``window`` None: a lane's chunks cover positions ``[0,
     context_len)``.  Given: the table is a ring of ``maxb`` slots and
     ``ring_mask``'s rule says which of its rows are attended; with ``per ==
     maxb`` a live lane's one chunk is the ring as it lies, with ``per <
     maxb`` the lane's chunks cover the slots it holds, as they cover a
-    context.  ``value_cols`` given (the latent
-    form): there is one pool and one chunk buffer, and a row's value is its
-    first ``value_cols`` columns.  ``lane_grid`` (of the latent form): the
-    grid walks the lanes, ``q_ref`` and ``o_ref`` are one lane's, and the
-    count of chunks fetched so far passes from a grid step to the next in
-    SMEM, as the chunk buffers and the copies in flight do in VMEM.
-    ``masked`` (of the latent form, a layer that selects): one more operand
-    follows ``q_ref``, ``[lanes, chunks, span]`` int32 laid out by chunk (a
-    lane's under ``lane_grid``), and a position counts where the context
-    holds it AND its entry there is not 0."""
-    if masked:
-        mask_ref, *refs = refs
-    if value_cols is None:
-        k_hbm, v_hbm, o_ref, kbuf, vbuf, sem = refs
-        pools = ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1))
-    else:
-        k_hbm, o_ref, kbuf, sem = refs[:4]
-        pools = ((k_hbm, kbuf, 0),)
+    context."""
+    k_hbm, v_hbm, o_ref, kbuf, vbuf, sem = refs
+    pools = ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1))
     lanes = cl_ref.shape[0]
     hd = kv_heads * head_dim             # the pool's width
     group = heads // kv_heads            # query heads a KV head
@@ -638,10 +644,7 @@ def _kernel(bt_ref, cl_ref, q_ref, *refs, heads, kv_heads, head_dim,
             for _pool, buf, _which in pools:
                 buf[...] = jnp.zeros(buf.shape, buf.dtype)
 
-        if lane_grid:
-            pl.when(pl.program_id(0) == 0)(clear)
-        else:
-            clear()
+        clear()
 
     # row r of the mask covers the columns of query head r's KV head
     # (r // group; its own where group is 1) in the folded width
@@ -666,11 +669,10 @@ def _kernel(bt_ref, cl_ref, q_ref, *refs, heads, kv_heads, head_dim,
         # one row broadcast over the heads, or (grouped) a row a head
         # with its query in every KV head's columns: as it came, or (the
         # compact layout) repeated here
-        mine = 0 if lane_grid else b    # this lane's place in q_ref, o_ref
-        qb = q_ref[mine]
+        qb = q_ref[b]
         if qb.shape[1] != hd:
             qb = jnp.concatenate([qb] * kv_heads, axis=1)
-        qx = qb if value_cols is not None else qb * own  # [rows, hd]
+        qx = qb * own                                    # [rows, hd]
 
         def chunk(c, carry):
             m, l, acc = carry
@@ -689,8 +691,6 @@ def _kernel(bt_ref, cl_ref, q_ref, *refs, heads, kv_heads, head_dim,
                 jnp.int32, (1, span), 1)
             if window is None:
                 seen = pos < ctx
-                if masked:
-                    seen = seen & (mask_ref[mine, pl.ds(c, 1), :] != 0)
             elif whole:
                 # c is 0 and span the ring's length
                 seen = _in_window(ctx, pos, span, window)
@@ -705,50 +705,39 @@ def _kernel(bt_ref, cl_ref, q_ref, *refs, heads, kv_heads, head_dim,
             alpha = jnp.exp(m - m_new)
             p = jnp.exp(sc - m_new)
             l = alpha * l + jnp.sum(p, axis=1, keepdims=True)
-            value = vbuf[slot] if value_cols is None \
-                else kbuf[slot][:, :value_cols]
+            value = vbuf[slot]
             acc = alpha * acc + _product(p, value, _NN)
             return m_new, l, acc
 
         _m, l, acc = jax.lax.fori_loop(
             0, n, chunk, (jnp.full((rows, 1), _MASK, jnp.float32),
                           jnp.zeros((rows, 1), jnp.float32),
-                          jnp.zeros((rows, value_cols or hd), jnp.float32)))
+                          jnp.zeros((rows, hd), jnp.float32)))
         # an idle lane has l == 0 and acc == 0: zeros out, not 0 / 0
-        out = acc / jnp.where(l > 0, l, 1.0)
-        if value_cols is None:
-            # (the latent form's one cached head is every row's: no mask)
-            out = out * own
-            if group == 1:
-                # each column belongs to one row: fold the rows
-                out = jnp.sum(out, axis=0, keepdims=True)
-            elif o_ref.shape[2] != hd:
-                # compact: a row's owned columns are its KV head's D; every
-                # other piece is zeros
-                out = sum(out[:, i * head_dim:(i + 1) * head_dim]
-                          for i in range(kv_heads))
-        o_ref[mine] = out.astype(o_ref.dtype)
+        out = acc / jnp.where(l > 0, l, 1.0) * own
+        if group == 1:
+            # each column belongs to one row: fold the rows
+            out = jnp.sum(out, axis=0, keepdims=True)
+        elif o_ref.shape[2] != hd:
+            # compact: a row's owned columns are its KV head's D; every
+            # other piece is zeros
+            out = sum(out[:, i * head_dim:(i + 1) * head_dim]
+                      for i in range(kv_heads))
+        o_ref[b] = out.astype(o_ref.dtype)
         return g + n
 
-    if lane_grid:
-        fetched = refs[4]
-        b = pl.program_id(0)
-
-        @pl.when(b == 0)
-        def _none_yet():
-            fetched[0] = 0
-
-        fetched[0] = lane(b, fetched[0])
-    else:
-        jax.lax.fori_loop(0, lanes, lane, jnp.int32(0))
+    jax.lax.fori_loop(0, lanes, lane, jnp.int32(0))
 
 
-def _latent_kernel(bt_ref, cl_ref, q_ref, pool, o_ref, buf, sem, *fetched,
-                   block_size, maxb, per, scale, value_cols):
+def _latent_kernel(bt_ref, cl_ref, q_ref, *refs, block_size, maxb, per,
+                   scale, value_cols, masked=False):
     """The latent form: one pool, one pair of chunk buffers, a row's value
     its first ``value_cols`` columns, ``q_ref`` rows the query heads over
-    the one cached head.  ``fetched`` given (one SMEM cell): the grid walks
-    the lanes, ``q_ref`` and ``o_ref`` are one lane's, and the count of
+    the one cached head.  ``masked`` (a layer that selects): one more
+    operand follows ``q_ref``, ``[lanes, chunks, span]`` int32 laid out by
+    chunk, and a position counts where the context holds it AND its entry
+    there is not 0.  A last SMEM cell given: the grid walks the lanes,
+    ``q_ref``, ``o_ref`` and the mask are one lane's, and the count of
     chunks fetched so far passes from a grid step to the next there, as the
     chunk buffers and the copies in flight do in VMEM.
 
@@ -764,6 +753,9 @@ def _latent_kernel(bt_ref, cl_ref, q_ref, pool, o_ref, buf, sem, *fetched,
     the parity of the chunks fetched before it; with a traced index the
     compiler must take a copy into one buffer and a load from the other for
     the same memory, and keeps them in program order."""
+    if masked:
+        mask_ref, *refs = refs
+    pool, o_ref, buf, sem, *fetched = refs
     lanes = cl_ref.shape[0]
     rows = q_ref.shape[1]
     span = per * block_size              # positions a chunk
@@ -821,7 +813,10 @@ def _latent_kernel(bt_ref, cl_ref, q_ref, pool, o_ref, buf, sem, *fetched,
             sc = _product(qx, buf[slot], _NT) * scale    # [rows, span]
             pos = c * span + jax.lax.broadcasted_iota(
                 jnp.int32, (1, span), 1)
-            sc = jnp.where(pos < ctx, sc, _MASK)
+            seen = pos < ctx
+            if masked:
+                seen = seen & (mask_ref[mine, pl.ds(c, 1), :] != 0)
+            sc = jnp.where(seen, sc, _MASK)
             m_new = jnp.maximum(m, jnp.max(sc, axis=1, keepdims=True))
             alpha = jnp.exp(m - m_new)
             p = jnp.exp(sc - m_new)
@@ -985,7 +980,8 @@ def _latent_lane_grid(q_shape, pool_shape, pool_dtype, rank):
     time (the pipeline fetches the next lane's beside them)?  Where every
     lane's together would not leave the chunk buffers their full span in
     ``_VMEM_BUDGET``: 128 query heads of 640 + 512 values are 589,824 B a
-    lane, 18.9e6 B at 32 lanes.  Where they do (32 heads: 4.7e6 B), one
+    lane, 18.9e6 B at 32 lanes, and 64 heads half that a lane, 9.4e6 B at
+    32 lanes and 18.9e6 at 64.  Where they do (32 heads: 4.7e6 B), one
     grid step holds them all and the lanes are a loop inside it."""
     fetched = jnp.dtype(pool_dtype).itemsize * q_shape[2]
     return q_shape[0] * _latent_lane_bytes(q_shape, rank) \
@@ -999,28 +995,6 @@ def _latent_held_bytes(q_shape, pool_shape, pool_dtype, rank):
     lanes = 2 if _latent_lane_grid(q_shape, pool_shape, pool_dtype, rank) \
         else q_shape[0]
     return lanes * _latent_lane_bytes(q_shape, rank)
-
-
-# Operations a byte of a chunk (both products over the bytes fetched) from
-# which the latent form takes its straight-line body.  Half the v5e's ridge
-# of 240: at 230 (128 query heads over rows of 640 bfloat16) a chunk's
-# arithmetic outlasts its transfer, 1.09 us to 0.87, and the body that lays
-# the copies' issue beside it gains a quarter of the call and of it all in
-# the step; at 58 (32 heads) the transfer outlasts the arithmetic, 0.9 to
-# 0.57, the kernel shares the HBM with the weights XLA fetches ahead beside
-# it, and what the body gains alone (a sixth) the step gives back to those
-# fetches and to the last block's repeats (PERF.md section 6, PR 50)
-_STRAIGHT_LINE_OPS_PER_BYTE = 120
-
-
-def _latent_straight_line(q_shape, pool_dtype, rank):
-    """Does the latent kernel run every chunk as one straight-line block
-    (``_latent_kernel``), or guard its copies as the K/V form does?  By the
-    operations a byte of a chunk: a function of the shapes, one constant."""
-    _lanes, heads, width = q_shape
-    rows = -(-heads // 16) * 16
-    return 2 * rows * (width + rank) \
-        >= _STRAIGHT_LINE_OPS_PER_BYTE * width * jnp.dtype(pool_dtype).itemsize
 
 
 def latent_chunk_positions(q_shape, pool_shape, pool_dtype, rank, maxb):
@@ -1074,7 +1048,7 @@ def _latent_pallas(q, pool, block_tables, context_lens, scale, rank,
     """q [B, H, W] against one pool [num_blocks, block_size, W] -> [B, H,
     rank].  ``chosen`` given (``_chunk_mask``'s [B, chunks, span] int32, of a
     layer that selects): over the positions it marks alone, of those a
-    lane's context holds; the guarded body takes it (``_walk_checks``)."""
+    lane's context holds."""
     bb, h, width = q.shape
     bs = pool.shape[1]
     maxb = block_tables.shape[1]
@@ -1089,21 +1063,13 @@ def _latent_pallas(q, pool, block_tables, context_lens, scale, rank,
     held = 1 if lane_grid else bb
     mine = (lambda i, bt, cl: (i, 0, 0)) if lane_grid \
         else (lambda i, bt, cl: (0, 0, 0))
-    # one straight-line block a chunk where its arithmetic outlasts its
-    # transfer, the K/V form's guarded copies (live blocks only) otherwise
-    straight = _latent_straight_line(q.shape, pool.dtype, rank)
-    kernel = functools.partial(
-        _latent_kernel, block_size=bs, maxb=maxb, per=per,
-        scale=float(scale), value_cols=rank) if straight \
-        else functools.partial(
-            _kernel, heads=h, kv_heads=1, head_dim=width, block_size=bs,
-            maxb=maxb, per=per, scale=float(scale), value_cols=rank,
-            lane_grid=lane_grid, masked=chosen is not None)
     # a layer that selects hands its mask in after the query: a lane's
     # chunks of it, or every lane's, as the query's
     masks = () if chosen is None else (chosen,)
     out = pl.pallas_call(
-        kernel,
+        functools.partial(_latent_kernel, block_size=bs, maxb=maxb, per=per,
+                          scale=float(scale), value_cols=rank,
+                          masked=chosen is not None),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(bb // held,),
@@ -1112,8 +1078,7 @@ def _latent_pallas(q, pool, block_tables, context_lens, scale, rank,
             + [pl.BlockSpec(memory_space=pl.ANY)],
             out_specs=pl.BlockSpec((held, rows, rank), mine),
             scratch_shapes=[pltpu.VMEM((2, per * bs, width), pool.dtype),
-                            pltpu.SemaphoreType.DMA(
-                                (2,) if straight else (1, 2))]
+                            pltpu.SemaphoreType.DMA((2,))]
             + ([pltpu.SMEM((1,), jnp.int32)] if lane_grid else []),
         ),
         out_shape=jax.ShapeDtypeStruct((bb, rows, rank), jnp.float32),
@@ -1458,7 +1423,7 @@ def _selected_checks(q_shape, pool_shape, pool_dtype, rank, k):
 def _walk_checks(q_shape, pool_shape, pool_dtype, rank, k, maxb):
     """``latent_attention_checks`` for the kernel over a lane's own table of
     ``maxb`` slots under a mask of its ``k`` chosen positions (the masked
-    walk): the body that takes a mask, a table no longer than
+    walk): a table no longer than
     ``_WALK_POSITIONS_PER_CHOSEN`` positions a chosen one (``k`` None: the
     caller holds the set as a mask already, whatever its count), and room
     for the mask beside what the kernel holds."""
@@ -1477,7 +1442,6 @@ def _walk_checks(q_shape, pool_shape, pool_dtype, rank, k, maxb):
     chunks = -(-maxb * bs // span)
     mask = held * 4 * span * (-(-chunks // 8) * 8)
     return base + [
-        ("mask_body", not _latent_straight_line(q_shape, pool_dtype, rank)),
         ("selection", k is None
          or maxb * bs <= _WALK_POSITIONS_PER_CHOSEN * k),
         ("mask_vmem", latent_vmem_bytes(q_shape, pool_shape, pool_dtype,

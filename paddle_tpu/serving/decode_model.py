@@ -1027,9 +1027,12 @@ class StepAccount:
                 # each latent layer fetches the same, in so many chunks (on
                 # the gather path a lane's padded table is its one chunk)
                 attrs["latent_blocks_read"] = attrs["kv_blocks_read"]
+                span = self._chunks[bucket].get("latent", maxb * bs)
                 attrs["latent_chunks"], attrs["latent_full_chunks"] = \
-                    _pa.chunks_read(attended, bs, maxb, self._chunks[
-                        bucket].get("latent", maxb * bs))
+                    _pa.chunks_read(attended, bs, maxb, span)
+                # ... and what the kernel fetched twice, or for nobody
+                attrs["latent_blocks_refetched"] = _pa.blocks_refetched(
+                    attended, bs, maxb, span) if path == "pallas" else 0
             if topk:
                 # what the selection did: the rows read, of those the
                 # contexts hold, differ on the lanes past ``index_topk`` alone

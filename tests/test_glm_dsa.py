@@ -628,6 +628,10 @@ WALKS = {
     "a_last_chunk_of_one_block": ([513, 528], 36),
     "a_table_of_one_chunk": ([383, 90], 24),
     "the_grid_walks_the_lanes": ([570, 0, 64, 513], 36),
+    # lanes of one chunk of 1, 2, 3 and 4 steps of 128 positions, and a last
+    # chunk of one step behind a whole one
+    # (tests/test_latent_attention_kernel.py walks longer tables masked)
+    "a_lane_ends_on_a_short_chunk": ([127, 129, 300, 0, 530, 385], 36),
 }
 
 
@@ -641,8 +645,9 @@ def test_the_masked_walk_reads_the_chosen_positions_of_a_lanes_live_blocks(
     positions (the form a table of at most 9 positions a chosen one takes):
     ``masked_latent``'s numbers over ``chosen_mask``'s set, for lanes under
     ``k``, at it and past it, an idle lane (zeros), a last chunk that holds
-    one block, a table of one chunk, and with the grid walking the lanes (a
-    lane's chunks of the mask in VMEM at a time)."""
+    one block, a table of one chunk, lanes that end on a short chunk, and
+    with the grid walking the lanes (a lane's chunks of the mask in VMEM at
+    a time)."""
     lens, maxb = WALKS[case]
     rng = np.random.default_rng(sorted(WALKS).index(case))
     heads, width, rank, bs, k = 16, 256, 128, 16, 64
@@ -727,8 +732,8 @@ FORMS = {
                               "pallas_masked"),
     "the_published_table_gathers_the_rows":
         ((32, 64, 640, 512, 16, 2048, 12672), "pallas"),
-    "128_heads_have_no_masked_body": ((32, 128, 640, 512, 16, 2048, 784),
-                                      "pallas"),
+    "128_heads_walk_under_the_mask_too": ((32, 128, 640, 512, 16, 2048, 784),
+                                          "pallas_masked"),
     "a_selection_of_no_whole_blocks_walks_a_short_table":
         ((4, 16, 256, 128, 16, 60, 24), "pallas_masked"),
     "a_selection_of_no_whole_blocks_gathers_a_wide_table_whole":
@@ -743,8 +748,8 @@ def test_the_selected_reads_form_follows_from_the_shapes(interpreted,
                                                          monkeypatch, case):
     """``selected_latent_path`` names one of three forms from the shapes
     alone: the masked walk up to ``_WALK_POSITIONS_PER_CHOSEN`` positions of
-    the table a chosen one, where the kernel's guarded body serves (the
-    straight-line body of 128 heads takes no mask); the row form past that;
+    the table a chosen one (the cell's 64 heads, and 128 too: the latent
+    kernel takes the mask at any head count); the row form past that;
     the whole table gathered where no kernel serves, and everywhere off the
     TPU."""
     (lanes, heads, row, rank, bs, k, maxb), form = FORMS[case]

@@ -781,16 +781,19 @@ def test_the_paged_step_on_two_kernels_gives_the_jnp_steps_tokens(
 
 def test_the_latent_and_expert_rules_at_the_published_shapes(interpreted):
     """What decides the cell's paths, by shape alone: 64 lanes of 64 heads
-    over rows of 640 take the latent kernel in chunks of 512 positions with
-    every lane's query and output in VMEM at once (no lane grid: that is for
-    128 heads), the guarded chunk body (121 operations a byte is under the
-    straight-line rule's), and 16 held experts of 6144 x 2048 the expert
-    kernel in chunks of 256 columns."""
+    over rows of 640 take the latent kernel in chunks of 512 positions, a
+    lane a grid step (64 x 294,912 B of queries and outputs do not fit the
+    8 MiB beside the buffers; nor do GLM-5's 32 lanes of them), and 16 held experts of 6144 x 2048 the expert kernel in
+    chunks of 256 columns."""
     from paddle_tpu.pallas_kernels import moe_experts as moe
     from paddle_tpu.pallas_kernels import paged_attention as pa
 
     q, pool = (64, 64, 640), (17472, 16, 640)
     assert pa.latent_path(q, pool, jnp.bfloat16, 512) == "pallas"
     assert pa.latent_chunk_positions(q, pool, jnp.bfloat16, 512, 272) == 512
+    assert pa._latent_lane_bytes(q, 512) == 294912
+    assert pa._latent_lane_grid(q, pool, jnp.bfloat16, 512)
+    assert pa._latent_lane_grid((32, 64, 640), (25120, 16, 640),
+                                jnp.bfloat16, 512)
     assert moe.experts_path(64, (16, 6144, 2048), jnp.bfloat16) == "pallas"
     assert moe.f_chunk(6144, 2048, 2) == 256

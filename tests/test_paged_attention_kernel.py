@@ -131,145 +131,6 @@ def test_a_chunk_fetches_the_blocks_a_lane_holds_and_no_others(
     assert not out[0].any()
 
 
-def _latent_case(lens, heads, width, block_size, maxb, dtype, seed):
-    """A random latent pool, absorbed queries and a table in which each
-    lane's blocks are its own and every unused slot is -1 -> (q, pool,
-    tables, lens)."""
-    rng = np.random.default_rng(seed)
-    blocks = 1 + maxb * len(lens)
-    pool = jnp.asarray(rng.standard_normal((blocks, block_size, width)),
-                       dtype)
-    q = jnp.asarray(rng.standard_normal((len(lens), heads, width)),
-                    jnp.float32)
-    tables = np.full((len(lens), maxb), -1, np.int32)
-    free = iter(rng.permutation(np.arange(1, blocks)))
-    for b, n in enumerate(lens):
-        for j in range(-(-n // block_size)):
-            tables[b, j] = next(free)
-    return q, pool, tables, np.asarray(lens, np.int32)
-
-
-@pytest.mark.parametrize("span", [128, 256, 512])
-@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
-                                       (jnp.bfloat16, 2e-2)],
-                         ids=["f32", "bf16"])
-@pytest.mark.parametrize("body", ["guarded", "straight"])
-def test_a_latent_chunk_fetches_the_blocks_a_lane_holds_and_no_others(
-        interpreted, monkeypatch, body, dtype, tol, span):
-    """The same of the latent form (12 query rows over one cached head 256
-    wide, the value its first 128 columns), with the guarded body its shapes
-    take by the rule and with the straight-line one, whose last chunk
-    fetches the lane's last block again and no block it does not hold."""
-    heads, width, rank, block_size = 12, 256, 128, 16
-    if body == "straight":
-        monkeypatch.setattr(pa, "_STRAIGHT_LINE_OPS_PER_BYTE", 0)
-    assert pa._latent_straight_line((8, heads, width), dtype, rank) \
-        == (body == "straight")
-    monkeypatch.setattr(pa, "_CHUNK_BYTES",
-                        span * width * jnp.dtype(dtype).itemsize)
-    lens, maxb = _borders(block_size, span)
-    q, pool, tables, lens = _latent_case(lens, heads, width, block_size,
-                                         maxb, dtype, seed=5)
-    assert pa.latent_chunk_positions(q.shape, pool.shape, dtype, rank,
-                                     maxb) == span
-    ref = np.asarray(pa.latent_attention_reference(q, pool, tables, lens,
-                                                   0.1, rank))
-    out = np.asarray(pa.latent_attention(
-        q, _unnamed_are_nan(pool, tables), tables, lens, 0.1, rank))
-    assert adoption.active_kernels() == ["latent_attention"]
-    assert np.isfinite(out).all()
-    np.testing.assert_allclose(out[1:], ref[1:], atol=tol, rtol=tol)
-    assert not out[0].any()
-
-
-# lanes' contexts at the borders of the latent cells' chunk of 512 positions,
-# over a table of 32 blocks of 64 (8 copies a chunk, which the interpreter
-# compiles four times sooner than the cells' 32): a lane's last chunk fetches
-# its last block again for the slots past it, a lane walks its chunks in
-# pairs, and a lane's last chunk starts the next live lane's first
-LATENT_LANES = {
-    "0": [0, 0, 0], "1": [1, 1543, 1], "511": [511, 512, 511],
-    "512": [512, 1, 512], "513": [513, 513, 0], "1024": [1024, 1025, 1024],
-    "1025": [1025, 1024, 1], "1543": [1543, 511, 1543],
-    "whole_table": [2048, 2047, 2048],
-    "live_between_idle": [0, 1025, 0], "idle_between_live": [1024, 0, 513],
-}
-
-
-@pytest.mark.parametrize("lanes", sorted(LATENT_LANES))
-@pytest.mark.parametrize("dtype,tol", [(jnp.float32, 2e-5),
-                                       (jnp.bfloat16, 2e-2)],
-                         ids=["f32", "bf16"])
-@pytest.mark.parametrize("heads", [32, 128])
-def test_latent_kernel_matches_the_gather_path(interpreted, monkeypatch,
-                                               heads, dtype, tol, lanes):
-    """The latent kernel, with the body its shapes take by the rule (the
-    straight-line one at 128 heads over a bfloat16 pool, the guarded one
-    elsewhere), against ``latent_attention_reference`` where a
-    lane's chunks are all full, all but the last, or only a part of one; 32
-    query heads (every lane's query in one grid step) and 128 (a lane a grid
-    step: the budget is shrunk until three lanes' queries no longer fit
-    beside the buffers and two still do).  Every block no table names is
-    NaN; an idle lane returns zeros."""
-    width, rank, block_size, maxb = 256, 128, 64, 32
-    lens = LATENT_LANES[lanes]
-    grid = heads == 128
-    if grid:
-        lane = 4 * heads * (width + rank)
-        monkeypatch.setattr(
-            pa, "_VMEM_BUDGET",
-            2 * 512 * width * jnp.dtype(dtype).itemsize + 2 * lane + lane // 3)
-    q, pool, tables, lens = _latent_case(lens, heads, width, block_size,
-                                         maxb, dtype, seed=11)
-    assert pa._latent_lane_grid(q.shape, pool.shape, dtype, rank) == grid
-    # 128 heads over a bfloat16 row: 192 operations a byte, the
-    # straight-line body; the others keep the guarded one
-    assert pa._latent_straight_line(q.shape, dtype, rank) \
-        == (grid and dtype == jnp.bfloat16)
-    assert pa.latent_chunk_positions(q.shape, pool.shape, dtype, rank,
-                                     maxb) == 512
-    ref = np.asarray(pa.latent_attention_reference(q, pool, tables, lens,
-                                                   0.1, rank))
-    out = np.asarray(pa.latent_attention(
-        q, _unnamed_are_nan(pool, tables), tables, lens, 0.1, rank))
-    assert adoption.active_kernels() == ["latent_attention"]
-    assert _tm.counter_total("pallas_kernel_fallback_total") == 0
-    assert np.isfinite(out).all()
-    live = lens > 0
-    np.testing.assert_allclose(out[live], ref[live], atol=tol, rtol=tol)
-    assert not out[~live].any()
-
-
-@pytest.mark.parametrize("order", ["eager", "on_wait"])
-def test_the_latent_kernel_keeps_its_turns(interpreted, monkeypatch, order):
-    """The latent kernel's straight-line body (forced: 12 heads take the
-    guarded one) under the TPU interpreter's two models of an async
-    copy, done as it is started and only when it is waited for (memory no
-    copy has filled reads NaN there): it waits for what it reads, starts
-    nothing into a buffer whose copies are in flight, and what a chunk that
-    nothing follows fetches for nobody (before an idle lane, at the end) is
-    waited out.  Bit for bit the plain interpreter's output."""
-    from jax.experimental.pallas import tpu as pltpu
-
-    heads, width, rank, block_size, maxb = 12, 256, 128, 16, 24
-    monkeypatch.setattr(pa, "_CHUNK_BYTES", 128 * width * 4)
-    monkeypatch.setattr(pa, "_STRAIGHT_LINE_OPS_PER_BYTE", 0)
-    lens = [0, 300, 0, 0, 128, 129, 1, 384, 0]
-    q, pool, tables, lens = _latent_case(lens, heads, width, block_size,
-                                         maxb, jnp.float32, seed=3)
-    ref = np.asarray(pa.latent_attention_reference(q, pool, tables, lens,
-                                                   0.1, rank))
-    pool = _unnamed_are_nan(pool, tables)
-    plain = np.asarray(pa._latent_pallas(q, pool, tables, lens, 0.1, rank))
-    got = np.asarray(pa._latent_pallas(
-        q, pool, tables, lens, 0.1, rank,
-        interpret=pltpu.InterpretParams(dma_execution_mode=order)))
-    assert np.isfinite(got).all()
-    assert np.array_equal(got, plain)
-    np.testing.assert_allclose(got[lens > 0], ref[lens > 0], atol=2e-5,
-                               rtol=2e-5)
-
-
 def test_f32_products_are_f32(interpreted):
     """Against an f32 pool the kernel sits orders below a path whose
     products are rounded to bfloat16: the three-piece split keeps what
@@ -349,6 +210,12 @@ CELLS = {
     # walks the lanes and holds one lane's (and the next's) at a time
     "dots_latent": ((32, 128, 640), (12832, 16, 640), "bfloat16", 512, 512,
                     512, 2 * 512 * 2 * 640 + 2 * 4 * 128 * (640 + 512)),
+    # 64 heads at LongCat-Flash's 64 lanes and at GLM-5's 32: the grid walks
+    # the lanes there too (18.9e6 and 9.4e6 B of queries and outputs)
+    "longcat_latent": ((64, 64, 640), (17472, 16, 640), "bfloat16", 272, 512,
+                       512, 2 * 512 * 2 * 640 + 2 * 4 * 64 * (640 + 512)),
+    "glm_latent": ((32, 64, 640), (25120, 16, 640), "bfloat16", 784, 512,
+                   512, 2 * 512 * 2 * 640 + 2 * 4 * 64 * (640 + 512)),
     # a table shorter than the rule's chunk is one chunk
     "short_table": ((32, 32, 128), (2048, 16, 256), "bfloat16", 20, 0,
                     320, 4 * 512 * 2 * 256 + 2 * 32 * 4 * 32 * 128),
@@ -368,11 +235,7 @@ def test_a_chunk_spans_what_its_bytes_are_worth(cell):
         fetched = jnp.dtype(dtype).itemsize * pool[2]
         held = pa._latent_held_bytes(q, pool, dtype, extra)
         assert pa._latent_lane_grid(q, pool, dtype, extra) \
-            == (cell == "dots_latent")
-        # 230 operations a byte at 128 heads, 58 at 32: one straight-line
-        # block a chunk there, guarded copies here
-        assert pa._latent_straight_line(q, dtype, extra) \
-            == (cell == "dots_latent")
+            == (cell != "kimi_latent")
         assert pa.latent_chunk_positions(q, pool, dtype, extra, maxb) == span
         assert pa.latent_vmem_bytes(q, pool, dtype, extra) == vmem
     else:
@@ -407,6 +270,20 @@ def test_a_chunk_shrinks_to_the_vmem_left_and_no_further():
                           <= pa._VMEM_BUDGET), (dtype, width)
             assert ok or width > fits
             assert not ok or width < not_
+
+
+def test_blocks_refetched_counts_what_the_latent_kernel_fetches_twice():
+    """``blocks_refetched`` at the cells' chunk of 32 blocks of 16: every
+    lane's last chunk is fetched whole, its last block again for the slots
+    past it, and after every run of live lanes a whole chunk is fetched for
+    nobody."""
+    lens = np.array([0, 1, 512, 513, 1000, 0, 1024 + 130], np.int32)
+    # (32 - 1) + 0 + (64 - 33) + (64 - 63) + (96 - 73), and two runs' ends
+    assert pa.blocks_refetched(lens, 16, 80, 512) \
+        == 31 + 0 + 31 + 1 + 23 + 2 * 32
+    assert pa.blocks_refetched(np.zeros(3, np.int32), 16, 80, 512) == 0
+    # ... and what it is beside: the blocks held are counted as before
+    assert pa.blocks_read(lens, 16, 80, "pallas") == 1 + 32 + 33 + 63 + 73
 
 
 def test_blocks_read_counts_live_blocks():
@@ -541,12 +418,15 @@ def test_engine_names_the_path_and_counts_the_blocks(interpreted, tmp_path):
 
 
 def test_engine_counts_the_chunks_a_latent_kernel_walks(interpreted,
-                                                        monkeypatch, tmp_path):
+                                                        monkeypatch,
+                                                        tmp_path):
     """An engine whose one layer is latent, on the kernel (a row of 256
     float32 and ``_CHUNK_BYTES`` of 128 rows make a chunk 128 positions):
     each step's span carries the chunks the kernel walks and how many of
     them are full beside the blocks read, as the lane's context passes one
-    chunk; the tokens are the jnp step's."""
+    chunk, and what the kernel fetched beyond the blocks held (the rest of
+    the lane's last chunk and a chunk for nobody); the tokens are the jnp
+    step's."""
     from paddle_tpu.models import dots_vlm
 
     monkeypatch.setattr(pa, "_CHUNK_BYTES", 128 * 256 * 4)
@@ -594,6 +474,10 @@ def test_engine_counts_the_chunks_a_latent_kernel_walks(interpreted,
             assert a["latent_blocks_read"] == a["kv_blocks_read"]
             assert a["latent_chunks"] == ctx // 128
             assert a["latent_chunks"] - a["latent_full_chunks"] in (0, 1)
+            # the last chunk's slots past the lane's blocks, and a chunk of
+            # 8 blocks once more for nobody
+            assert a["latent_blocks_refetched"] \
+                == 8 * a["latent_chunks"] - a["latent_blocks_read"] + 8
         assert {(a["latent_chunks"], a["latent_full_chunks"])
                 for a in attrs} == {(1, 0), (1, 1), (2, 1)}
     finally:
