@@ -27,8 +27,30 @@ block table.
   holds are copied and waited for; the rows of a buffer no copy wrote are
   zeros or an earlier chunk's (finite: their probabilities are 0).  Work
   follows the live context: an idle lane (``context_lens`` 0) fetches
-  nothing and returns zeros; nothing of the table's size is written and no
-  block the table does not name is read.
+  nothing and returns zeros; nothing of the table's size is written, no
+  block the table does not name is read and none is read twice.  **The
+  walk has two bodies, and which one a chunk runs follows from the lane's
+  count of chunks alone** (``_straight``, the one statement of the rule:
+  ``straight_chunks_read`` counts by it for the step's span).  A chunk
+  that is not a lane's last holds all its blocks, so all but a lane's last
+  two chunks run *straight-line* (since PR 63): the next chunk's copies
+  issued with no predicate and no loop, this chunk's waited for, then the
+  scores, the softmax chain and the value product, one basic block in
+  which the scheduler lays scalar issue, waits, matrix and vector work
+  side by side; and the buffers' indices there are compile-time constants
+  (a lane walks these chunks in pairs, first buffer then second, in one of
+  two copies of the walk by the parity of the chunks fetched before it),
+  since with a traced index the compiler must take a copy into one buffer
+  and a load from the other for the same memory and keeps them in program
+  order.  The lane's last two bodies (the one that fetches the last chunk,
+  and the last chunk, which fetches the next lane's first) are *guarded*:
+  each step of 8 copies under a predicate and the last few in a loop, a
+  traced buffer index, so that the last chunk fetches its live blocks and
+  no others (the latent form below fetches a lane's last block again
+  there, which for K/V rows is bytes: 13-18% of GPT-2's fetch).  A ring of
+  one chunk keeps the body it had.  What is left to take (ROADMAP Speed
+  1(c)): a last chunk of one block still pays a whole chunk's arithmetic,
+  and a fetch two chunks ahead is untried.
 * **the gather** (scope ``kv_gather``) everywhere else: on the CPU tier,
   for the int8 residency (gather, dequantize), in a program XLA partitions
   over a mesh.  ``gather_blocks`` copies every slot of the padded table
@@ -65,7 +87,7 @@ longer than the longest chunk (``_MAX_CHUNK_TOKENS`` positions: a window of
 (a slot not held yet fetches block 0, masked).  A longer one (a window of
 4,096: 257 blocks) is *walked in chunks* of the span ``_chunk_positions``
 gives its rows, in the table's order, through the same two buffers and the
-same guarded copies as a global layer's context: chunk ``c`` holds entries
+same two bodies as a global layer's context: chunk ``c`` holds entries
 ``[c * span, (c + 1) * span)`` of the ring and is masked by their age, and a
 lane fetches the slots it holds and no others, ``min(ceil(context_len /
 block_size), R)`` leading ones (under ``R * block_size`` positions the ring
@@ -91,7 +113,8 @@ whole 128-lane tiles: the cache rounds a row up to that and keeps the rest
 zeros (576 values lie in rows of 640), since a kernel fetches whole tiles of
 the pool's layout.  A kernel of its own (``_latent_kernel``,
 ``LATENT_KERNEL_NAME`` in a trace): one pool, one pair of chunk buffers, and
-a chunk body other than the K/V form's.  The K/V body guards each step of 8
+a chunk body other than the K/V form's.  The K/V form's guarded body (every
+chunk's before PR 63, a lane's last two since) guards each step of 8
 copies by a predicate and loops over the last few, so that a lane's last
 chunk fetches its live blocks and no others; in a latent chunk the copies'
 issue and waits, scalar work in the kernel's one instruction stream, then
@@ -182,7 +205,7 @@ from . import adoption
 
 __all__ = ["paged_attention", "paged_attention_reference",
            "paged_attention_checks", "attention_path", "blocks_read",
-           "chunks_read", "blocks_refetched",
+           "chunks_read", "straight_chunks_read", "blocks_refetched",
            "masked_attention", "gather_blocks", "ring_mask", "KERNEL_NAME",
            "latent_attention", "latent_attention_reference",
            "latent_attention_checks", "latent_path", "masked_latent",
@@ -443,6 +466,12 @@ def blocks_read(context_lens, block_size, maxb, path, ring=False):
     return int((-(-context_lens // block_size)).clip(0, maxb).sum())
 
 
+def _lane_chunks(context_lens, block_size, maxb, span):
+    """Chunks of ``span`` positions that cover the blocks each lane holds."""
+    held = (-(-context_lens // block_size)).clip(0, maxb)
+    return -(-held // (span // block_size))
+
+
 def chunks_read(context_lens, block_size, maxb, span):
     """``(chunks, full chunks)`` one layer's kernel walks for these lanes at
     ``span`` positions a chunk: a lane's chunks cover the blocks it holds
@@ -453,10 +482,31 @@ def chunks_read(context_lens, block_size, maxb, span):
     chunk the arithmetic covers the whole span whatever part is seen, and
     the latent form pays a whole chunk's copies too.
     A host-side count for the step's span, as ``blocks_read``."""
-    per = span // block_size
-    chunks = -(-(-(-context_lens // block_size)).clip(0, maxb) // per)
+    chunks = _lane_chunks(context_lens, block_size, maxb, span)
     return int(chunks.sum()), int(np.minimum(context_lens // span,
                                              chunks).sum())
+
+
+def _straight(chunks):
+    """Of a lane's ``chunks``, those the K/V kernel runs as its
+    straight-line body: all but the last two (``max(chunks - 2, 0)``).  A
+    chunk that is not the lane's last holds all its blocks, so its copies
+    and waits need no guard; the body also fetches the chunk after it, so
+    that one must hold all its own too.  The lane's last two bodies (the
+    one that fetches the last chunk, and the last chunk, which fetches the
+    next lane's first) keep the guarded copies.  The one statement of the
+    rule: the kernel's walk (a traced count) and the step span's count (the
+    numpy feed's) both read it here."""
+    return (chunks - 2).clip(0)
+
+
+def straight_chunks_read(context_lens, block_size, maxb, span):
+    """Of the chunks one layer's K/V kernel walks for these lanes
+    (``chunks_read``), those that run its straight-line body
+    (``_straight``).  A host-side count for the step's span, as
+    ``blocks_read``."""
+    return int(_straight(
+        _lane_chunks(context_lens, block_size, maxb, span)).sum())
 
 
 def blocks_refetched(context_lens, block_size, maxb, span):
@@ -547,13 +597,21 @@ def _product(rows, x, dims):
 
 
 def _kernel(bt_ref, cl_ref, q_ref, *refs, heads, kv_heads, head_dim,
-            block_size, maxb, per, scale, window=None):
+            block_size, maxb, per, scale, window, step, in_window, straights):
     """``window`` None: a lane's chunks cover positions ``[0,
     context_len)``.  Given: the table is a ring of ``maxb`` slots and
     ``ring_mask``'s rule says which of its rows are attended; with ``per ==
     maxb`` a live lane's one chunk is the ring as it lies, with ``per <
     maxb`` the lane's chunks cover the slots it holds, as they cover a
-    context."""
+    context.
+
+    A lane's chunks run one of two bodies, by their count alone
+    (``_straight``): all but the last two ``straight``, one basic block with
+    no predicate and constant buffer indices; the last two ``guarded``, whose
+    copies are the blocks the lane holds and no others.  ``step`` is the
+    blocks of ``CHUNK_TOKENS`` positions, ``in_window`` and ``straights``
+    the module's ``_in_window`` and ``_straight`` as the call found them
+    (``_kernel_call`` keys a traced kernel by all three)."""
     k_hbm, v_hbm, o_ref, kbuf, vbuf, sem = refs
     pools = ((k_hbm, kbuf, 0), (v_hbm, vbuf, 1))
     lanes = cl_ref.shape[0]
@@ -584,6 +642,13 @@ def _kernel(bt_ref, cl_ref, q_ref, *refs, heads, kv_heads, head_dim,
             yield pltpu.make_async_copy(pool.at[block()], buf.at[slot, at],
                                         sem.at[which, slot])
 
+    def wait_whole(slot):
+        """For every copy of a chunk that holds all its blocks."""
+        # a wait needs the copy's shape and semaphore, not its source
+        for i in range(per):
+            for dma in copies(slot, i, lambda: 0):
+                dma.wait()
+
     if whole:
         # the ring is one chunk, fetched whole in the table's order: a slot
         # the lane does not hold yet (-1) fetches block 0, and ``ring_mask``
@@ -598,12 +663,10 @@ def _kernel(bt_ref, cl_ref, q_ref, *refs, heads, kv_heads, head_dim,
                     dma.start()
 
         def wait(b, c, slot):
-            # a wait needs the copy's shape and semaphore, not its source
-            for i in range(per):
-                for dma in copies(slot, i, lambda: 0):
-                    dma.wait()
+            wait_whole(slot)
     else:
-        # a chunk is the lane's live blocks among its ``per`` (of a ring
+        # the guarded copies, for a chunk that may be a lane's last: the
+        # lane's live blocks among its ``per`` (of a ring
         # walked in chunks: the slots it holds; one whose block left the
         # window names none and fetches block 0, masked): the last
         # chunk of a lane fetches the blocks the lane holds and no others,
@@ -612,16 +675,18 @@ def _kernel(bt_ref, cl_ref, q_ref, *refs, heads, kv_heads, head_dim,
         # (the scalar core runs the kernel's one instruction stream), so
         # the blocks go by whole steps of ``CHUNK_TOKENS`` positions, each
         # under one predicate, and only the last step's few in a loop
-        step = max(1, CHUNK_TOKENS // block_size)
         steps = per // step
+
+        def named(b, c, i):
+            """The block slot ``i`` of lane ``b``'s chunk ``c`` names."""
+            return jnp.maximum(bt_ref[b * maxb + c * per + i], 0)
 
         def transfer(act, b, c, slot):
             n = jnp.minimum(blocks(b) - c * per, per)
 
             def one(i, _=None):
                 # a wait needs the copy's shape and semaphore, not its source
-                block = jnp.maximum(bt_ref[b * maxb + c * per + i], 0) \
-                    if act == "start" else 0
+                block = named(b, c, i) if act == "start" else 0
                 for dma in copies(slot, i, lambda: block):
                     getattr(dma, act)()
 
@@ -674,18 +739,9 @@ def _kernel(bt_ref, cl_ref, q_ref, *refs, heads, kv_heads, head_dim,
             qb = jnp.concatenate([qb] * kv_heads, axis=1)
         qx = qb * own                                    # [rows, hd]
 
-        def chunk(c, carry):
+        def fold(c, carry, slot):
+            """Chunk ``c``, as it lies in buffer ``slot``, into the sums."""
             m, l, acc = carry
-            slot = (g + c) % 2
-            more = c + 1 < n
-            nxt = jnp.minimum(b + 1, lanes - 1)
-
-            @pl.when(more | ((b + 1 < lanes) & (chunks(nxt) > 0)))
-            def _prefetch():
-                start(jnp.where(more, b, nxt), jnp.where(more, c + 1, 0),
-                      1 - slot)
-
-            wait(b, c, slot)
             sc = _product(qx, kbuf[slot], _NT) * scale   # [rows, span]
             pos = c * span + jax.lax.broadcasted_iota(
                 jnp.int32, (1, span), 1)
@@ -693,12 +749,12 @@ def _kernel(bt_ref, cl_ref, q_ref, *refs, heads, kv_heads, head_dim,
                 seen = pos < ctx
             elif whole:
                 # c is 0 and span the ring's length
-                seen = _in_window(ctx, pos, span, window)
+                seen = in_window(ctx, pos, span, window)
             else:
                 # chunk c of the ring: its entries by age; the last chunk
                 # may reach past the ring's end
                 ring_len = maxb * block_size
-                seen = _in_window(ctx, pos, ring_len, window) \
+                seen = in_window(ctx, pos, ring_len, window) \
                     & (pos < ring_len)
             sc = jnp.where(seen, sc, _MASK)
             m_new = jnp.maximum(m, jnp.max(sc, axis=1, keepdims=True))
@@ -709,10 +765,58 @@ def _kernel(bt_ref, cl_ref, q_ref, *refs, heads, kv_heads, head_dim,
             acc = alpha * acc + _product(p, value, _NN)
             return m_new, l, acc
 
-        _m, l, acc = jax.lax.fori_loop(
-            0, n, chunk, (jnp.full((rows, 1), _MASK, jnp.float32),
-                          jnp.zeros((rows, 1), jnp.float32),
-                          jnp.zeros((rows, hd), jnp.float32)))
+        def guarded(c, carry):
+            """A chunk that may be the lane's last, or fetch it: the copies
+            it starts and those it waits for are the blocks the lane holds,
+            and what it fetches may be the next lane's first chunk."""
+            slot = (g + c) % 2
+            more = c + 1 < n
+            nxt = jnp.minimum(b + 1, lanes - 1)
+
+            @pl.when(more | ((b + 1 < lanes) & (chunks(nxt) > 0)))
+            def _prefetch():
+                start(jnp.where(more, b, nxt), jnp.where(more, c + 1, 0),
+                      1 - slot)
+
+            wait(b, c, slot)
+            return fold(c, carry, slot)
+
+        def straight(c, carry, slot):
+            """A chunk that holds all its blocks and whose successor, in the
+            same lane, holds all its own (``_straight``): no predicate, no
+            loop and a buffer that is a constant, so one basic block in
+            which the scheduler lays the next chunk's copies, this one's
+            waits and the arithmetic side by side."""
+            for i in range(per):
+                block = named(b, c + 1, i)
+                for dma in copies(1 - slot, i, lambda: block):
+                    dma.start()
+            wait_whole(slot)
+            return fold(c, carry, slot)
+
+        def walk(even, carry):
+            """The lane's straight chunks, 0, 2, ... in buffer ``even``."""
+            def pair(k, carry):
+                return straight(2 * k + 1, straight(2 * k, carry, even),
+                                1 - even)
+
+            return jax.lax.fori_loop(
+                first // 2 * 2, first,
+                lambda c, carry: straight(c, carry, even),
+                jax.lax.fori_loop(0, first // 2, pair, carry))
+
+        carry = (jnp.full((rows, 1), _MASK, jnp.float32),
+                 jnp.zeros((rows, 1), jnp.float32),
+                 jnp.zeros((rows, hd), jnp.float32))
+        first = 0                  # the first guarded chunk of the lane
+        if maxb > 2 * per:
+            # a lane may hold more than two chunks: all but its last two go
+            # straight-line, in one of two copies of the walk by the buffer
+            # its first chunk lies in
+            first = straights(n)
+            carry = jax.lax.cond(g % 2 == 0, functools.partial(walk, 0),
+                                 functools.partial(walk, 1), carry)
+        _m, l, acc = jax.lax.fori_loop(first, n, guarded, carry)
         # an idle lane has l == 0 and acc == 0: zeros out, not 0 / 0
         out = acc / jnp.where(l > 0, l, 1.0) * own
         if group == 1:
@@ -860,6 +964,42 @@ def _latent_kernel(bt_ref, cl_ref, q_ref, *refs, block_size, maxb, per,
         drain(jax.lax.fori_loop(0, lanes, lane, jnp.int32(0)))
 
 
+@functools.lru_cache(maxsize=None)
+def _kernel_call(q_shape, pool_shape, k_dtype, v_dtype, maxb, heads, head_dim,
+                 per, scale, window, interpret, step, in_window, straights):
+    """The kernel's call for one set of shapes, traced once: a model's
+    layers (and a multi-token step's positions) call one ``jit`` whose
+    jaxpr is inlined where it is called, so a step of 24 layers traces and
+    lowers the kernel's seven chunk bodies once, not 24 times (0.7 s a
+    call site on the chip's host with nothing cached).  Everything the
+    kernel reads beside its operands is in the key, what a test or a chip
+    check's control swaps in the module (``CHUNK_TOKENS`` as ``step``,
+    ``_in_window``, ``_straight``) too: a swapped rule is another kernel."""
+    bb, qrows, width = q_shape
+    _nb, bs, hd = pool_shape
+    whole = lambda i, bt, cl: (0, 0, 0)
+    return jax.jit(pl.pallas_call(
+        functools.partial(_kernel, heads=heads, kv_heads=hd // head_dim,
+                          head_dim=head_dim, block_size=bs, maxb=maxb,
+                          per=per, scale=scale, window=window, step=step,
+                          in_window=in_window, straights=straights),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(1,),
+            in_specs=[pl.BlockSpec((bb, qrows, width), whole),
+                      pl.BlockSpec(memory_space=pl.ANY),
+                      pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((bb, qrows, width), whole),
+            scratch_shapes=[pltpu.VMEM((2, per * bs, hd), k_dtype),
+                            pltpu.VMEM((2, per * bs, hd), v_dtype),
+                            pltpu.SemaphoreType.DMA((2, 2))],
+        ),
+        out_shape=jax.ShapeDtypeStruct((bb, qrows, width), jnp.float32),
+        name=KERNEL_NAME,
+        interpret=interpret,
+    ), inline=True)
+
+
 def _paged_pallas(q, k_cache, v_cache, block_tables, context_lens,
                   scale=None, interpret=None, window=None):
     """q [B, H, D] against folded pools [num_blocks, block_size, KH * D]
@@ -886,28 +1026,12 @@ def _paged_pallas(q, k_cache, v_cache, block_tables, context_lens,
         # as it is, and the kernel repeats it
         qx = jnp.pad(q if compact else jnp.tile(q, (1, 1, kh)),
                      ((0, 0), (0, qrows - h), (0, 0)))
-    width = qx.shape[2]
-    whole = lambda i, bt, cl: (0, 0, 0)
-    out = pl.pallas_call(
-        functools.partial(_kernel, heads=h, kv_heads=kh, head_dim=d,
-                          block_size=bs, maxb=maxb, per=per,
-                          scale=float(scale), window=window),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(1,),
-            in_specs=[pl.BlockSpec((bb, qrows, width), whole),
-                      pl.BlockSpec(memory_space=pl.ANY),
-                      pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec((bb, qrows, width), whole),
-            scratch_shapes=[pltpu.VMEM((2, per * bs, hd), k_cache.dtype),
-                            pltpu.VMEM((2, per * bs, hd), v_cache.dtype),
-                            pltpu.SemaphoreType.DMA((2, 2))],
-        ),
-        out_shape=jax.ShapeDtypeStruct((bb, qrows, width), jnp.float32),
-        name=KERNEL_NAME,
-        interpret=interpret,
-    )(block_tables.astype(jnp.int32).reshape(-1),
-      context_lens.astype(jnp.int32), qx, k_cache, v_cache)
+    out = _kernel_call(
+        qx.shape, k_cache.shape, k_cache.dtype, v_cache.dtype, maxb, h, d,
+        per, float(scale), window, interpret, max(1, CHUNK_TOKENS // bs),
+        _in_window, _straight)(
+            block_tables.astype(jnp.int32).reshape(-1),
+            context_lens.astype(jnp.int32), qx, k_cache, v_cache)
     if kh == h:
         return out.reshape(bb, h, d)
     if compact:

@@ -1033,6 +1033,15 @@ class StepAccount:
                 # ... and what the kernel fetched twice, or for nobody
                 attrs["latent_blocks_refetched"] = _pa.blocks_refetched(
                     attended, bs, maxb, span) if path == "pallas" else 0
+            else:
+                # the chunks one layer's kernel walked, and those of them
+                # that ran its straight-line body (on the gather path a
+                # lane's padded table is its one chunk)
+                span = self._chunks[bucket].get("attention", maxb * bs)
+                attrs.update(
+                    kv_chunks=_pa.chunks_read(lens, bs, maxb, span)[0],
+                    kv_straight_chunks=_pa.straight_chunks_read(
+                        lens, bs, maxb, span))
             if topk:
                 # what the selection did: the rows read, of those the
                 # contexts hold, differ on the lanes past ``index_topk`` alone
@@ -1071,16 +1080,17 @@ class StepAccount:
             _tm.set_gauge("kv_pool_blocks", held, model=self.model, kind=kind)
         read = lambda maxb, **kw: _pa.blocks_read(
             lens, bs, maxb, self.window_path, **kw)
+        span = self._chunks[self.buckets[-1]].get("window", ring * bs)
         return {"kv_window_blocks_read": n * read(ring, ring=True),
                 "kv_window_blocks_full": n * read(self.maxb),
                 "kv_window_blocks_held": window_in_use,
                 # live lanes whose context is past the window (their rings
-                # have given blocks back), and the chunks ONE window layer's
-                # attention walked
+                # have given blocks back), the chunks ONE window layer's
+                # attention walked and those that ran the straight-line body
                 "kv_window_lanes_wrapped": int((lens > cfg.window).sum()),
-                "kv_window_chunks": _pa.chunks_read(
-                    lens, bs, ring, self._chunks[self.buckets[-1]].get(
-                        "window", ring * bs))[0],
+                "kv_window_chunks": _pa.chunks_read(lens, bs, ring, span)[0],
+                "kv_window_straight_chunks": _pa.straight_chunks_read(
+                    lens, bs, ring, span),
                 "kv_block_size": bs}
 
     def _routed(self, params):

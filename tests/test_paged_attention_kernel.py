@@ -297,6 +297,177 @@ def test_blocks_read_counts_live_blocks():
     assert pa.blocks_read(np.array([100], np.int32), 4, 12, "pallas") == 12
 
 
+# -- the walk's two bodies ---------------------------------------------------
+
+# queries over KV heads: a head its own K and V; 14 heads over 2 KV heads of
+# 128 (compact: the kernel repeats a lane's query itself) and of 64 (spread)
+LAYOUTS = {"group1": (2, 2, 64), "compact": (14, 2, 128),
+           "spread": (14, 2, 64)}
+DTYPES = {"f32": (jnp.float32, 8, 2e-5), "bf16": (jnp.bfloat16, 16, 2e-2)}
+# chunks a lane, in two orders: between them a lane of 1, 2, 3, 4 and 5
+# chunks starts after an even and after an odd count of chunks, behind an
+# idle lane, behind a lane of one chunk and behind a longer one
+ORDERS = {"even_first": [0, 1, 5, 0, 2, 4, 1, 3, 0, 3, 1, 1, 4, 2, 5],
+          "odd_first": [1, 0, 2, 3, 5, 1, 4, 4, 0, 1, 1, 3, 2, 0, 5, 1]}
+
+
+def _starts(chunks):
+    """(chunks of a lane, parity of the chunks fetched before it)."""
+    before = np.concatenate([[0], np.cumsum(chunks)[:-1]])
+    return {(int(n), int(g) % 2) for n, g in zip(chunks, before)}
+
+
+def _walk_case(layout, dtype, lens, maxb, seed=0):
+    """Random pools, a query of ``layout`` and each lane's own blocks in the
+    leading slots of a table of ``maxb`` (a ring names all its slots from
+    the first wrap on)."""
+    heads, kv_heads, dim = LAYOUTS[layout]
+    dt, block_size, _tol = DTYPES[dtype]
+    rng = np.random.RandomState(seed)
+    blocks = 1 + maxb * len(lens)
+    q = jnp.asarray(rng.randn(len(lens), heads, dim), jnp.float32)
+    k, v = (jnp.asarray(rng.randn(blocks, block_size, kv_heads * dim)
+                        .astype(np.float32)).astype(dt) for _ in "kv")
+    tables = np.full((len(lens), maxb), -1, np.int32)
+    free = list(rng.permutation(np.arange(1, blocks)))
+    for i, n in enumerate(lens):
+        for j in range(min(-(-n // block_size), maxb)):
+            tables[i, j] = free.pop()
+    lens = np.asarray(lens, np.int32)
+    return q, k, v, tables, lens
+
+
+def _small_chunks(monkeypatch):
+    """Chunks of 32 positions: 4 blocks of 8 (two steps of 2) or 2 of 16."""
+    monkeypatch.setattr(pa, "CHUNK_TOKENS", 16)
+    monkeypatch.setattr(pa, "_MAX_CHUNK_TOKENS", 32)
+    return 32
+
+
+def test_the_orders_cross_every_start():
+    """What the two orders are for: every length in either buffer, and a
+    lane with a straight chunk behind an idle lane, a lane of one chunk and
+    a longer one."""
+    assert _starts(ORDERS["even_first"]) | _starts(ORDERS["odd_first"]) \
+        >= {(n, parity) for n in range(1, 6) for parity in (0, 1)}
+    before = {min(a, 2) for order in ORDERS.values()
+              for a, n in zip(order[:-1], order[1:]) if n >= 3}
+    assert before == {0, 1, 2}
+
+
+@pytest.mark.parametrize("order", sorted(ORDERS))
+@pytest.mark.parametrize("dtype", sorted(DTYPES))
+@pytest.mark.parametrize("layout", sorted(LAYOUTS))
+def test_the_straight_body_meets_the_guarded_one_at_every_seam(
+        interpreted, monkeypatch, layout, dtype, order):
+    """Lanes of 1 to 5 chunks (all but a lane's last two run the
+    straight-line body: 0, 0, 1, 2 and 3 of them), each started in either
+    buffer, behind an idle lane, a lane of one chunk and a longer one; a
+    lane's last chunk one position, a block less one, a block and one, or
+    full.  Every block no table names is NaN: the outputs are finite and
+    the clean pools' gather, and the count is the rule's."""
+    span = _small_chunks(monkeypatch)
+    dt, block_size, tol = DTYPES[dtype]
+    ends = [1, block_size - 1, block_size + 1, span]
+    lens = [(n - 1) * span + ends[i % 4] if n else 0
+            for i, n in enumerate(ORDERS[order])]
+    maxb = 5 * span // block_size
+    q, k, v, tables, lens = _walk_case(layout, dtype, lens, maxb)
+    assert pa.chunk_positions(q.shape, k.shape, dt, maxb) == span
+    ref = np.asarray(pa.paged_attention_reference(q, k, v, tables, lens))
+    out = np.asarray(pa.paged_attention(
+        q, _unnamed_are_nan(k, tables), _unnamed_are_nan(v, tables), tables,
+        lens))
+    assert adoption.active_kernels() == ["paged_attention"]
+    live = lens > 0
+    assert np.isfinite(out).all()
+    assert np.abs(out[live] - ref[live]).max() <= tol
+    assert not out[~live].any()
+    chunks = np.asarray(ORDERS[order])
+    assert pa.chunks_read(lens, block_size, maxb, span)[0] == chunks.sum()
+    assert pa.straight_chunks_read(lens, block_size, maxb, span) \
+        == np.maximum(chunks - 2, 0).sum()
+
+
+@pytest.mark.parametrize("fill", ["part", "full", "wrapped"])
+@pytest.mark.parametrize("dtype", sorted(DTYPES))
+@pytest.mark.parametrize("layout", ["compact", "group1"])
+def test_a_ring_walked_in_chunks_goes_through_both_bodies(
+        interpreted, monkeypatch, layout, dtype, fill):
+    """A ring of 16 whole chunks and a block (SmallThinker's 257 blocks, in
+    small: 33 slots of 16 positions or 65 of 8 against chunks of 32), part
+    filled (7 chunks, the last a block), exactly full and wrapped (17
+    chunks, 15 of them straight), in either buffer: behind an idle lane and
+    behind a lane of one chunk.  Against the gather under the same mask."""
+    span = _small_chunks(monkeypatch)
+    dt, block_size, tol = DTYPES[dtype]
+    ring = 16 * span // block_size + 1
+    ring_len, window = ring * block_size, 16 * span
+    ctx = {"part": 6 * span + 3, "full": ring_len,
+           "wrapped": 2 * ring_len + span + 5}[fill]
+    lens = [0, ctx, 5, ctx + 1 if fill != "full" else ctx]
+    q, k, v, tables, lens = _walk_case(layout, dtype, lens, ring)
+    assert not pa._ring_whole(ring, block_size)
+    assert pa.chunk_positions(q.shape, k.shape, dt, 99, ring) == span
+    ref = np.asarray(pa.paged_attention_reference(q, k, v, tables, lens,
+                                                  window=window))
+    out = np.asarray(pa.paged_attention(
+        q, _unnamed_are_nan(k, tables), _unnamed_are_nan(v, tables), tables,
+        lens, window=window))
+    assert adoption.active_kernels() == ["paged_attention"]
+    assert np.isfinite(out).all() and not out[0].any()
+    assert np.abs(out[1:] - ref[1:]).max() <= tol
+    walked = 7 if fill == "part" else 17
+    assert pa.chunks_read(lens, block_size, ring, span)[0] == 2 * walked + 1
+    assert pa.straight_chunks_read(lens, block_size, ring, span) \
+        == 2 * (walked - 2)
+
+
+@pytest.mark.parametrize("order", ["eager", "on_wait"])
+def test_the_walk_keeps_its_turns(interpreted, monkeypatch, order):
+    """Both bodies under the TPU interpreter's two models of an async copy,
+    done as it is started and only when it is waited for: a chunk waits for
+    what it reads and starts nothing into a buffer it has yet to read.  Bit
+    for bit the walk with every chunk guarded (the rule told that no chunk
+    is straight), so the bodies differ in the order of issue alone."""
+    from jax.experimental.pallas import tpu as pltpu
+
+    span = _small_chunks(monkeypatch)
+    lens = [(n - 1) * span + 9 if n else 0 for n in ORDERS["odd_first"]]
+    q, k, v, tables, lens = _walk_case("compact", "f32", lens, 20)
+    k, v = _unnamed_are_nan(k, tables), _unnamed_are_nan(v, tables)
+    walk = lambda **kw: np.asarray(pa._paged_pallas(
+        q, k, v, jnp.asarray(tables), jnp.asarray(lens), **kw))
+    got = walk(interpret=pltpu.InterpretParams(dma_execution_mode=order))
+    assert np.isfinite(got).all()
+    assert np.array_equal(got, walk())
+    monkeypatch.setattr(pa, "_straight", lambda chunks: chunks * 0)
+    assert np.array_equal(got, walk())
+
+
+def test_straight_chunks_read_counts_all_but_a_lanes_last_two():
+    """``straight_chunks_read`` against a hand count, at the shapes the
+    cells hold: SmallThinker's ring of 257 blocks in chunks of 256 positions
+    (a wrapped lane walks 17 chunks, 15 of them straight), its global table
+    of 1,024 slots, GPT-2's lanes of 3-6 chunks of 128, and K-EXAONE's ring
+    of one chunk."""
+    count = lambda lens, maxb, span: pa.straight_chunks_read(
+        np.asarray(lens, np.int32), 16, maxb, span)
+    # chunks 0, 1, 2 (19 blocks), 16 (256 blocks), 17, 17, 17
+    ring = [0, 16, 300, 4096, 4112, 4113, 12544]
+    assert pa.chunks_read(np.asarray(ring, np.int32), 16, 257, 256)[0] \
+        == 1 + 2 + 16 + 3 * 17
+    assert count(ring, 257, 256) == 0 + 0 + 0 + 14 + 3 * 15
+    # chunks 4, 36, 3, 2, 1: a lane of one or two chunks has none
+    assert count([1000, 9000, 513, 512, 1], 1024, 256) == 2 + 34 + 1
+    # GPT-2: chunks 3, 4, 5, 6, and a lane the table cuts at 8
+    assert count([300, 512, 513, 760, 5000], 64, 128) == 1 + 2 + 3 + 4 + 6
+    # a ring that is one chunk, and the gather's padded table
+    assert count([50, 400, 0], 9, 144) == 0
+    assert count([50, 400, 9000], 1024, 1024 * 16) == 0
+    assert count([], 64, 128) == 0
+
+
 # -- the decode steps on the kernel ------------------------------------------
 
 CFG = dm.DecoderConfig(vocab=37, layers=2, heads=2, head_dim=64, max_seq=48)
@@ -407,6 +578,9 @@ def test_engine_names_the_path_and_counts_the_blocks(interpreted, tmp_path):
         # one lane of 1..12 positions and an idle one: the one or two
         # blocks the lane holds, of a table of six
         assert {a["kv_blocks_read"] for a in attrs} == {1, 2}
+        # ... in the one chunk the table is, which is no straight one
+        assert {(a["kv_chunks"], a["kv_straight_chunks"])
+                for a in attrs} == {(1, 0)}
         # a model with no latent layer counts no latent chunks
         assert not any(key.startswith("latent_") for a in attrs for key in a)
         # the chunk's span by kind of layer, beside the path's name

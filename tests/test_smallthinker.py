@@ -281,9 +281,10 @@ def _text(fn, *shapes):
 def test_a_ring_of_one_chunk_and_the_silu_experts_lower_to_the_parents_text(
         interpreted):
     """What this family added to the two kernels moves no other family's
-    step: K-EXAONE's ring (9 slots of 16, one chunk), a global layer's walk
-    and the SiLU-gated experts lower to the text they had before a ring
-    could be walked in chunks or a gate named (PR 52's tree, same jax)."""
+    step: K-EXAONE's ring (9 slots of 16, one chunk) and the SiLU-gated
+    experts lower to the text they had before a ring could be walked in
+    chunks or a gate named (PR 52's tree, same jax); a global layer's walk
+    to the text of its two bodies (PR 63's tree)."""
     shape = jax.ShapeDtypeStruct
     q, pool = shape((4, 64, 128), jnp.float32), \
         shape((40, 16, 1024), jnp.bfloat16)
@@ -293,7 +294,7 @@ def test_a_ring_of_one_chunk_and_the_silu_experts_lower_to_the_parents_text(
         lens) == "a8be3890b9dfa8ac"
     assert _text(lambda q, k, v, t, l: pa.paged_attention(q, k, v, t, l),
                  q, pool, pool, shape((4, 64), jnp.int32), lens) \
-        == "22fe9e55079855df"
+        == "2f3f331fcea4b85e"
     w, wd = shape((8, 256, 128), jnp.bfloat16), \
         shape((8, 128, 256), jnp.bfloat16)
     feeds = (shape((4, 256), jnp.float32), shape((4, 8), jnp.float32),
@@ -405,6 +406,7 @@ def test_step_span_counters_and_prewarm_event(cache_dir, telemetry_on,
     assert all(s["kv_window_blocks_read"] == 6 * 2 * RING
                and s["kv_window_blocks_full"] == 6 * 2 * maxb
                and s["kv_window_chunks"] == 1
+               and s["kv_window_straight_chunks"] == 0
                and 1 <= s["kv_window_blocks_held"] <= RING for s in steps)
     wrapped = [s["kv_window_lanes_wrapped"] for s in steps]
     assert wrapped[:WINDOW] == [0] * WINDOW and set(wrapped[WINDOW:]) == {1}

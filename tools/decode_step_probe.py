@@ -110,9 +110,12 @@ Every result names ``attention_kernels``: by kind of layer that takes the
 attention kernel, the positions a chunk spans (the kernel sizes it by the
 bytes a position costs), the chunks a step walks at the profiled contexts,
 the kernels' own ms a step, microseconds a chunk, and bytes/s over the live
-blocks' bytes.  ``--contexts lo-hi`` draws the lanes' contexts from that
-range (a cell's window: ``1100-3100`` for Kimi-Linear's) where the default
-is a third to the whole of a lane's share of the pool.
+blocks' bytes (``attention`` with the window layers' rings counted in, whose
+kernels carry the same name), and for the K/V kernel ``straight_chunk_share``:
+of its chunks, those that ran the straight-line body, by the function the
+step's span counts them with.  ``--contexts lo-hi`` draws the lanes'
+contexts from that range (a cell's window: ``1100-3100`` for Kimi-Linear's)
+where the default is a third to the whole of a lane's share of the pool.
 
 ``--check`` leaves the model out and compares the step's attention alone,
 at the configuration's shapes, on one layer's random pools and the same
@@ -587,19 +590,30 @@ def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
     # chunk, the chunks a step walks over the kind's layers, its own time by
     # the step and by the chunk, and the rate over the live blocks' bytes
     # (a window layer's kernels carry the global layers' name in a trace:
-    # ``attention`` holds both where a model has both)
+    # ``attention`` counts the rings' chunks and bytes beside the tables'
+    # where a model has both), and of the K/V kernel's chunks the share that
+    # ran its straight-line body (``paged_attention.straight_chunks_read``,
+    # what the step's span counts too)
     now = np.asarray(feed(WARM_STEPS + args.steps)[5])
     row = kvc._PAYLOAD[kv.dtype][0].dtype.itemsize * args.block_size
+    spans = dm.chunk_positions(cfg, kv, b)
     result["attention_kernels"] = {}
-    for kind, span in dm.chunk_positions(cfg, kv, b).items():
+    for kind, span in spans.items():
         if kind == "window":
             continue
         layers = cfg.latent_layers if kind == "latent" else cfg.attn_layers
         ms = kernel_ms(paged_attention.LATENT_KERNEL_NAME if kind == "latent"
                        else paged_attention.KERNEL_NAME)
-        chunks = int((-(-now // span)).sum()) * len(layers)
-        moved = len(layers) * row * paged_attention.blocks_read(
-            now, args.block_size, cfg.max_seq // args.block_size, "pallas") \
+        # (layers, table slots, positions a chunk, a ring?)
+        walks = [(len(layers), cfg.max_seq // args.block_size, span, False)]
+        if kind == "attention" and "window" in spans:
+            walks.append((len(cfg.window_layers), kv.window_ring,
+                          spans["window"], True))
+        chunks = sum(n * paged_attention.chunks_read(
+            now, args.block_size, slots, at)[0] for n, slots, at, _ in walks)
+        moved = row * sum(n * paged_attention.blocks_read(
+            now, args.block_size, slots, "pallas", ring=ring)
+            for n, slots, _at, ring in walks) \
             * (kv.latent_row if kind == "latent"
                else 2 * kv.heads * kv.head_dim)
         result["attention_kernels"][kind] = {
@@ -608,7 +622,12 @@ def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
             "us_per_chunk": ms * 1e3 / chunks if chunks else None,
             "bytes_per_step": moved,
             "bytes_per_s": moved / (ms / 1e3) if ms else None,
-            "with_window_kernels": bool(cfg.window_layers)}
+            "with_window_kernels": len(walks) > 1}
+        if kind == "attention" and chunks:
+            result["attention_kernels"][kind]["straight_chunk_share"] = \
+                100.0 * sum(n * paged_attention.straight_chunks_read(
+                    now, args.block_size, slots, at)
+                    for n, slots, at, _ in walks) / chunks
     # the form a selecting model's read took (None: no selection, or the
     # dense control's none)
     form = dm.attention_path(cfg, kv, b, "selected") \
