@@ -24,12 +24,14 @@ class DecoderFamily(collections.namedtuple(
         ("kinds", "dtypes", "grouped_query", "routes", "expert_matrices",
          "dense_lead", "holds_share", "own_stream_width", "grouped_router",
          "rotated_latent", "shared_expert", "expert_gate", "selects",
-         "zero_experts", "scaled_latent"),
+         "zero_experts", "scaled_latent", "neg_eigval"),
         defaults=(("f32", "bf16"), False, None, None, False, False, False,
-                  False, False, False, "silu", False, False, False))):
+                  False, False, False, "silu", False, False, False,
+                  False))):
     """``kinds``: the kinds of layer the block computes
     (``decode_model.LAYER_KINDS``: seven of them, of which a family names
-    one to three).  ``dtypes``: the weight dtypes it is
+    one to three, in any pairing the cache holds: a ``kda`` slot beside
+    latent pools, or beside K/V pools).  ``dtypes``: the weight dtypes it is
     served in.  ``grouped_query``: its attention may have fewer KV heads than
     query heads.  ``routes``: where its feed-forward is routed experts: None
     (nowhere), ``"after_dense"`` (every layer after ``cfg.dense_layers``),
@@ -65,6 +67,9 @@ class DecoderFamily(collections.namedtuple(
     that chooses one computes nothing for it, and its routed compute is the
     router's to decide.  ``scaled_latent``: its latent layers scale the
     projected query by ``cfg.latent_q_scale`` and the normed compressed K/V
-    by ``cfg.latent_kv_scale``."""
+    by ``cfg.latent_kv_scale``.  ``neg_eigval``: its ``kda`` layers' delta
+    rule may allow negative eigenvalues (``cfg.kda_neg_eigval``: ``beta`` is
+    twice the sigmoid, in (0, 2), so a write may turn about what the state
+    holds along its key and not only shrink it)."""
 
     __slots__ = ()

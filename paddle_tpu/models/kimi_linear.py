@@ -81,7 +81,8 @@ from . import granite_hybrid as _granite
 from .decoder_family import DecoderFamily
 from .olmoe import NP_DTYPES, _mm, _rmsnorm
 
-__all__ = ["token_logits", "param_shapes", "init_params", "laid_out",
+__all__ = ["token_logits", "param_shapes", "kda_param_shapes",
+           "init_params", "laid_out",
            "routed_part", "shared_part", "BIAS_STD", "FAMILY"]
 
 FAMILY = DecoderFamily(kinds=("kda", "latent"), routes="after_dense",
@@ -107,22 +108,28 @@ routed_part = _exaone.routed_part
 shared_part = _exaone.shared_part
 
 
+def kda_param_shapes(cfg):
+    """(name, shape, kind) of a kda layer's mixer."""
+    h, inner, sh, r = cfg.hidden, cfg.kda_inner, cfg.kda_heads, \
+        cfg.kda_head_dim
+    return (("wqkv", (h, 3 * inner), "normal"),
+            ("conv_w", (cfg.kda_conv, 3 * inner), "conv"),
+            ("low_a", (h, 2 * r + sh), "normal"),
+            ("f_b", (r, inner), "normal"), ("g_b", (r, inner), "normal"),
+            ("dt_bias", (inner,), "dt_bias"), ("A_log", (sh,), "a_log"),
+            ("o_norm", (r,), "ones"), ("wo", (inner, h), "normal"))
+
+
 def param_shapes(cfg):
     """name -> (shape, kind) with kind in normal | ones | bias | conv |
     a_log | dt_bias."""
     h, v, d = cfg.hidden, cfg.vocab, cfg.head_dim
-    inner, sh, r = cfg.kda_inner, cfg.kda_heads, cfg.kda_head_dim
     e, held, fe, fd, fs = cfg.experts, cfg.experts_held, cfg.ffn, \
         cfg.dense_ffn, cfg.shared_ffn
     shapes = {"embed": ((v, h), "normal"), "lnf_g": ((h,), "ones"),
               "head": ((h, v), "normal")}
     mixers = {
-        "kda": (("wqkv", (h, 3 * inner), "normal"),
-                ("conv_w", (cfg.kda_conv, 3 * inner), "conv"),
-                ("low_a", (h, 2 * r + sh), "normal"),
-                ("f_b", (r, inner), "normal"), ("g_b", (r, inner), "normal"),
-                ("dt_bias", (inner,), "dt_bias"), ("A_log", (sh,), "a_log"),
-                ("o_norm", (r,), "ones"), ("wo", (inner, h), "normal")),
+        "kda": kda_param_shapes(cfg),
         "latent": (("wq", (h, cfg.heads * (d + cfg.latent_rope)), "normal"),
                    ("wkva", (h, cfg.latent_width), "normal"),
                    ("kv_norm", (cfg.latent_rank,), "ones"),
@@ -146,14 +153,16 @@ def param_shapes(cfg):
     return shapes
 
 
-def init_params(cfg, seed=0, std=0.02, bias_std=BIAS_STD):
+def init_params(cfg, seed=0, std=0.02, bias_std=BIAS_STD, shapes=None):
     """name -> np array in the config's weight dtype: ``std``-normal
     weights, norms at 1, ``expert_bias`` normal(0, ``bias_std``), and for
     what sets how much the state holds and how long it remembers the start
     Granite's block gives Mamba-2's (``granite_hybrid.init_params`` says
     why): the depthwise convolutions uniform in +-1/sqrt(taps), ``A_log =
     log(u)``, u uniform in [1, 16], and ``dt_bias = softplus^-1(dt)``, dt
-    log-uniform in [0.001, 0.1].  Host-side: tests and demo bundles."""
+    log-uniform in [0.001, 0.1].  Host-side: tests and demo bundles.
+    ``shapes`` is another family's ``param_shapes`` of the same kinds
+    (``solar_open2``)."""
     r = np.random.RandomState(seed)
     dtype = NP_DTYPES[cfg.dtype]
 
@@ -166,7 +175,8 @@ def init_params(cfg, seed=0, std=0.02, bias_std=BIAS_STD):
         return _granite.draw(r, cfg, shape, kind, std)
 
     return {name: make(shape, kind).astype(np.float32).astype(dtype)
-            for name, (shape, kind) in sorted(param_shapes(cfg).items())}
+            for name, (shape, kind) in sorted(
+                (shapes or param_shapes)(cfg).items())}
 
 
 def laid_out(cfg, params):
@@ -205,7 +215,9 @@ _q_norm = _rmsnorm
 
 
 def kda_mixer(cfg, p, l, h, recur):
-    """The KDA mixer of layer ``l`` over h [B, H] float32."""
+    """The KDA mixer of layer ``l`` over h [B, H] float32: this family's,
+    and ``solar_open2``'s, which reads one more thing of the configuration
+    (``cfg.kda_neg_eigval``: ``beta`` twice the sigmoid)."""
     f32 = jnp.float32
     bb = h.shape[0]
     inner, sh, d = cfg.kda_inner, cfg.kda_heads, cfg.kda_head_dim
@@ -224,6 +236,10 @@ def kda_mixer(cfg, p, l, h, recur):
         alpha = jnp.exp(-jnp.exp(p("A_log").astype(f32))[None, :, None]
                         * jax.nn.softplus(decay))
         beta = jax.nn.sigmoid(low[:, 2 * d:])
+        if cfg.kda_neg_eigval:
+            # in (0, 2): I - beta k k^T has the eigenvalue 1 - beta in
+            # (-1, 1) along a unit k
+            beta = 2.0 * beta
         o = recur.delta(l, alpha, beta, k, v, q)           # [B, SH, D]
     with jax.named_scope("out"):
         gate = _out_gate(by_head(_mm(low[:, d:2 * d], p("g_b"))))

@@ -26,18 +26,31 @@ In the paged step the states live in a pool ``[slots, D, H * D]`` and lane
 from what it can see, with no flag:
 
 * **the kernel** (executions named ``kda_state_update``), on a TPU backend,
-  for a float32 pool whose heads are whole 128-column slices and whose keys
-  are whole sublane tiles: ``ssm_update``'s grid over (lane, column chunk)
-  and its transfers (``ssm_update.in_turns``: whole slots where VMEM
-  allows, a batch at a time, reads and writes in turn, a batch updated
-  beside the write before it and the read after it, which is what hides
-  this rule's long update), with this rule in VMEM, a slot read once and
-  written once.  What varies down a head's keys (``alpha``, ``k``, ``q``
-  and ``beta k``) comes in turned, keys down the sublanes and a head a
-  lane (``[B, D, 4 H]``, one array), so a head's column is one lane spread
-  over the 128; ``v`` and ``o`` cross as rows.
-* **the gather** everywhere else: the lanes' slots are gathered, ``advance``
-  moves them, a scatter writes them back.
+  for a float32 pool whose heads are whole 128-column slices, as many keys
+  as values, and whose keys are whole sublane tiles: ``ssm_update``'s grid
+  over (lane, column chunk) and its transfers (``ssm_update.in_turns``:
+  whole slots where VMEM allows, a batch at a time, reads and writes in
+  turn, a batch updated beside the write before it and the read after it,
+  which is what hides this rule's long update), with this rule in VMEM, a
+  slot read once and written once.  What varies down a head's keys
+  (``alpha``, ``k``, ``q`` and ``beta k``) comes in turned, keys down the
+  sublanes and a head a lane, **a tile of its own for each transfer a slot
+  moves in** (``[B, D, transfers x tile]``, one array: the four columns of
+  the transfer's own heads side by side, padded to whole 128-lane tiles),
+  so a head's column is one lane spread over the 128; ``v`` and ``o`` cross
+  as rows.  The rule puts no bound of its own on the heads: the transfers
+  do (``ssm_update.transfer_columns``: two batches of two units within the
+  VMEM the kernel asks for), and a transfer's columns are a thirty-second
+  of its bytes.  Kimi-Linear's 32 heads are one transfer and one tile
+  (``[33, 128, 4096]``: the kernel's text is what it was before a transfer
+  had a tile of its own); Solar-Open2's 64 are one transfer of 4 MiB and
+  two tiles (``[65, 128, 8192]``, exactly the budget, batches of two: 0.847
+  ms a call of 64 lanes on the chip, 634 GB/s, where the same slot in two
+  transfers of 32 heads reads 0.863 and the gather 9.06: PERF.md section 6,
+  PR 64); 65 heads would move in five transfers of 13.
+* **the gather** everywhere else (a head of 64 values, keys unlike values,
+  a bfloat16 pool, the CPU tier): the lanes' slots are gathered,
+  ``advance`` moves them, a scatter writes them back.
 
 Both start a lane whose ``fresh`` flag is set from zeros, whatever its slot
 holds.
@@ -109,9 +122,12 @@ def kda_update_checks(pool_shape, pool_dtype, lanes, heads):
         ("sublanes", static and rank and pool_shape[1] % 8 == 0),
         ("empty", shaped),
         # a head's values are whole 128-column slices, its keys as many as
-        # its values, and the four columns a head fill at most one tile
+        # its values.  How many heads is the transfers' to bound: the four
+        # columns a head of ONE transfer's heads lie in as many 128-lane
+        # tiles as they fill (32 heads one, 64 heads two), a thirty-second
+        # of the transfer's own bytes
         ("heads", shaped and pool_shape[2] == heads * pool_shape[1]
-         and pool_shape[1] % 128 == 0 and 4 * heads <= 128),
+         and pool_shape[1] % 128 == 0),
         ("vmem", shaped and _ssm.transfer_columns(pool_shape, heads)
          is not None),
     ]
@@ -125,33 +141,30 @@ def update_path(pool_shape, pool_dtype, lanes, heads):
     return "pallas" if ok else "gather"
 
 
+def _tile(heads):
+    """Lanes the four columns a head of ``heads`` heads take, in whole
+    128-lane tiles."""
+    return -(-4 * heads // 128) * 128
+
+
 def _kernel(slots_ref, fresh_ref, pool_hbm, cols_ref, v_ref, out_hbm, o_ref,
-            buf, rsem, wsem, *, lanes, chunks, heads, dim):
+            buf, rsem, wsem, *, lanes, chunks, dim):
     """Grid step (lane, chunk) updates one unit where ``ssm_update.in_turns``
-    has put it.  ``cols_ref`` [D, 128]: lane ``j * heads + i`` holds head
-    ``i``'s column ``j`` of (alpha, k, q, beta k) down the keys."""
+    has put it.  ``cols_ref`` [D, tile] is the unit's own: lane ``j * heads
+    + i`` holds column ``j`` of (alpha, k, q, beta k) of the ``i``-th of the
+    unit's ``heads`` heads down the keys."""
     del pool_hbm                         # out_hbm is the same buffer
-    width = buf.shape[3]
+    heads = buf.shape[3] // dim
 
     def update(lane, chunk, half, at):
+        del chunk                        # the unit's columns came with it
         fresh = fresh_ref[lane] != 0
-        for n in range(width // dim):
-            # a head's column, spread over the lanes.  The chunk is known
-            # only at run time where a slot is moved in several: every lane
-            # offset is then a select over the chunks' (a slot is one chunk
-            # at the published sizes)
-            def column(j, _n=n):
-                picked = None
-                for c in range(chunks):
-                    i = c * (width // dim) + _n
-                    got = jnp.broadcast_to(
-                        cols_ref[:, j * heads + i:j * heads + i + 1],
-                        (dim, 128))
-                    picked = got if picked is None \
-                        else jnp.where(chunk == c, got, picked)
-                return picked
-
-            a_col, k_col, q_col, bk_col = (column(j) for j in range(4))
+        for n in range(heads):
+            # a head's column, spread over the lanes
+            a_col, k_col, q_col, bk_col = (
+                jnp.broadcast_to(
+                    cols_ref[:, j * heads + n:j * heads + n + 1], (dim, 128))
+                for j in range(4))
             # 128 values at a time: whole (8, 128) tiles, v and o rows
             # broadcast over the sublanes
             for piece in range(dim // 128):
@@ -167,6 +180,22 @@ def _kernel(slots_ref, fresh_ref, pool_hbm, cols_ref, v_ref, out_hbm, o_ref,
     _ssm.in_turns(slots_ref, out_hbm, buf, rsem, wsem, lanes, chunks, update)
 
 
+def _turned(columns, chunks):
+    """``columns``, four arrays [B, H, D] over a head's keys -> [B, D,
+    chunks * tile]: keys down the sublanes and, for each of the ``chunks``
+    transfers a slot moves in, its own heads' four columns side by side
+    (alpha | k | q | beta k, a head a lane), padded to whole tiles."""
+    lanes, heads, dim = columns[0].shape
+    per = heads // chunks
+    turned = [jnp.swapaxes(x, 1, 2) for x in columns]
+    if chunks > 1:
+        turned = [x.reshape(lanes, dim, chunks, per) for x in turned]
+    turned = jnp.concatenate(turned, axis=-1)
+    turned = jnp.pad(turned, ((0, 0),) * (turned.ndim - 1)
+                     + ((0, _tile(per) - 4 * per),))
+    return turned.reshape(lanes, dim, -1) if chunks > 1 else turned
+
+
 def _state_update_pallas(pool, slots, fresh, alpha, beta, k, v, q,
                          interpret=None):
     """-> (pool updated in its own buffer, o [B, H, D])."""
@@ -178,19 +207,19 @@ def _state_update_pallas(pool, slots, fresh, alpha, beta, k, v, q,
     if interpret is None:
         interpret = adoption.interpret()
     f32 = jnp.float32
-    # keys down the sublanes, a head a lane: alpha | k | q | beta k
-    turned = jnp.concatenate(
-        [jnp.swapaxes(x.astype(f32), 1, 2)
-         for x in (alpha, k, q, beta.astype(f32)[..., None] * k)], axis=2)
-    turned = jnp.pad(turned, ((0, 0), (0, 0), (0, 128 - 4 * heads)))
+    turned = _turned([x.astype(f32) for x in (
+        alpha, k, q, beta.astype(f32)[..., None] * k)], chunks)
     lane_row = pl.BlockSpec((None, 1, cols),
                             lambda i, j, slots, fresh: (i, 0, j))
-    lane_cols = pl.BlockSpec((None, dim, 128),
-                             lambda i, j, slots, fresh: (i, 0, 0))
+    # (a slot moved whole has one tile, named as it was before a transfer
+    # had its own: the kernel's text at 32 heads is what it was)
+    lane_cols = pl.BlockSpec(
+        (None, dim, _tile(heads // chunks)),
+        (lambda i, j, slots, fresh: (i, 0, j)) if chunks > 1
+        else (lambda i, j, slots, fresh: (i, 0, 0)))
     in_place = pl.BlockSpec(memory_space=pl.ANY)
     pool, o = pl.pallas_call(
-        functools.partial(_kernel, lanes=lanes, chunks=chunks, heads=heads,
-                          dim=dim),
+        functools.partial(_kernel, lanes=lanes, chunks=chunks, dim=dim),
         grid_spec=pltpu.PrefetchScalarGridSpec(
             num_scalar_prefetch=2,
             grid=(lanes, chunks),

@@ -126,7 +126,10 @@ def transfer_columns(pool_shape, groups=1):
     divides ``I`` into whole 128-column slices and lies within one group or
     spans whole groups (so every slice has one group, known from the chunk).
     None where not even a 128-column chunk fits, or the groups are not whole
-    slices."""
+    slices.  A delta-rule pool's groups are its heads (``kda_update``): a
+    slot of 32 heads of 128 (2 MiB) and one of 64 (4 MiB: with ``n`` 128 and
+    ``I`` 8192 exactly the budget, in batches of two,
+    ``units_in_flight``) both move whole, and past that in whole heads."""
     _slots, n, inner = pool_shape
     if n < 1 or not _whole_groups(inner, groups):
         return None

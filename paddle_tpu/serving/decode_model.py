@@ -47,7 +47,10 @@ it and attends the positions the indexer scores highest and no others
 (``paged_attention.index_scores``, ``choose``,
 ``selected_latent_attention``).  A hybrid block names its layers'
 kinds one by one, two or three of them in one model (its
-``FAMILY.kinds``).  Every step builder below serves every
+``FAMILY.kinds``), in any pairing: a ``kda`` slot lies beside latent pools
+in one model's cache (``kimi_linear``) and beside K/V pools in another's
+(``solar_open2``, whose ``kda_neg_eigval`` also widens the delta rule's
+``beta`` to (0, 2)), as a ``mamba`` slot lies beside K/V pools.  Every step builder below serves every
 family through one contract (``_block``), so there is one paged step, one
 multi-token step, one draft rollout and one unpaged reference, whatever the
 block.
@@ -116,7 +119,7 @@ __all__ = ["DecoderConfig", "init_decoder_params", "save_decoder",
 # the families, a module each: ``models/<arch>.py``
 ARCHS = ("gpt2", "olmoe", "granite_hybrid", "lfm2_moe", "exaone_moe",
          "nemotron_h", "kimi_linear", "dots_vlm", "smallthinker", "glm_dsa",
-         "longcat_flash")
+         "longcat_flash", "solar_open2")
 LAYER_KINDS = ("attention", "mamba", "conv", "window", "experts", "kda",
                "latent")
 # recurrent kind -> the name its slot goes by in spans, gauges and counters
@@ -229,6 +232,15 @@ class DecoderConfig:
     their probability, not renormalised: a chosen identity expert (one of
     the last ``zero_experts``) adds the token's own input times its gate.
     No shared expert, no dense lead; it may hold a share.
+    ``solar_open2`` is the block of ``models/solar_open2.py``:
+    ``layer_types`` of ``kda`` | ``attention``: ``kimi_linear``'s KDA mixer
+    whose delta rule allows negative eigenvalues (``kda_neg_eigval``:
+    ``beta`` twice the sigmoid, in (0, 2); refused for a family that does
+    not declare it) beside grouped-query attention over K/V pools with no
+    position encoding, no Q/K norm and a sigmoid gate, a value a head
+    channel, on the heads' output; every layer routed as ``exaone_moe``'s
+    beside a shared expert, no dense lead; an untied head, a stream of its
+    own width, and the share it may hold.
 
     ``kv_heads`` None means ``heads`` (multi-head); ``layer_types`` None
     means ``layers`` attention layers.  ``dtype`` is the weights' (``f32``
@@ -249,7 +261,7 @@ class DecoderConfig:
                  "q_rank", "n_group", "topk_group", "rope_scaling",
                  "v_head_dim", "index_heads", "index_head_dim",
                  "index_topk", "zero_experts", "latent_q_scale",
-                 "latent_kv_scale")
+                 "latent_kv_scale", "kda_neg_eigval")
 
     def __init__(self, vocab, layers, heads, head_dim, ffn=None,
                  max_seq=64, arch="gpt2", dtype="f32", kv_dtype=None,
@@ -265,7 +277,8 @@ class DecoderConfig:
                  latent_rank=0, latent_rope=0, q_rank=0, n_group=1,
                  topk_group=1, rope_scaling=None, v_head_dim=None,
                  index_heads=0, index_head_dim=0, index_topk=0,
-                 zero_experts=0, latent_q_scale=1.0, latent_kv_scale=1.0):
+                 zero_experts=0, latent_q_scale=1.0, latent_kv_scale=1.0,
+                 kda_neg_eigval=False):
         if arch not in ARCHS:
             raise ValueError("decoder arch must be %s: %r"
                              % ("|".join(ARCHS), arch))
@@ -334,6 +347,7 @@ class DecoderConfig:
         self.zero_experts = int(zero_experts)
         self.latent_q_scale = float(latent_q_scale)
         self.latent_kv_scale = float(latent_kv_scale)
+        self.kda_neg_eigval = bool(kda_neg_eigval)
         if self.hidden_size not in (None, self.heads * self.head_dim) \
                 and not family.own_stream_width:
             raise ValueError("the %s block's stream is heads * head_dim "
@@ -439,6 +453,10 @@ class DecoderConfig:
                 "latent_q_scale and latent_kv_scale are for the %s blocks' "
                 "latent layers: %r, %r" % (_declaring("scaled_latent"),
                                            latent_q_scale, latent_kv_scale))
+        if self.kda_neg_eigval and not family.neg_eigval:
+            raise ValueError(
+                "kda_neg_eigval (beta in (0, 2)) is for the %s blocks' kda "
+                "layers: %r" % (_declaring("neg_eigval"), kda_neg_eigval))
         if self.shared_ffn and not family.shared_expert:
             raise ValueError(
                 "the %s blocks pass every token through a shared expert of "
@@ -1184,15 +1202,24 @@ class StepAccount:
         (``STATE_NAMES``): the state a step reads and writes, a slot a live
         lane; and, of the kinds that keep a state beside their window, how
         it is moved a bucket (``"pallas"``: each slot in place |
-        ``"gather"``) and what one transfer of the kernel then moves."""
+        ``"gather"``), what one transfer of the kernel then moves and in
+        how many a slot goes; of ``kda`` layers the heads a slot holds."""
         cfg, kv = self.cfg, self.kv_config
         name, slot = cfg.state_name, _kv.slot_bytes(kv)
+        if cfg.kda_layers:
+            # what the delta-rule kernel's shape rule turns on
+            self._said["kda_heads"] = cfg.kda_heads
         for b in self.buckets if cfg.state_layers else ():
             self.state_path[b] = path = state_update_path(cfg, kv, b)
             self._said_at[b]["state_update"] = path
             if path == "pallas":
-                self._said_at[b]["state_update_columns"] = \
-                    state_update_columns(cfg, kv)
+                # the columns one transfer moves, and the transfers a slot
+                # is moved in
+                columns = state_update_columns(cfg, kv)
+                self._said_at[b].update(
+                    state_update_columns=columns,
+                    state_update_transfers=kv.state_shapes[1][0][1]
+                    // columns)
         if self.state_path:
             self.key_parts["state_update"] = sorted(self.state_path.items())
 

@@ -24,7 +24,7 @@ import paddle_tpu as fluid
 from paddle_tpu.core import telemetry as _tm
 from paddle_tpu.models import dots_vlm, exaone_moe, glm_dsa, \
     granite_hybrid, kimi_linear, lfm2_moe, longcat_flash, nemotron_h, olmoe, \
-    smallthinker
+    smallthinker, solar_open2
 from paddle_tpu.serving import DecodeEngine
 from paddle_tpu.serving import decode_model as dm
 from paddle_tpu.serving import kv_cache as kvc
@@ -270,6 +270,15 @@ _LONGCAT = dm.DecoderConfig(
     latent_rope=8, q_rank=20, latent_q_scale=(48 / 20) ** 0.5,
     latent_kv_scale=(48 / 24) ** 0.5, dense_ffn=64, ffn=24, experts=16,
     zero_experts=8, experts_per_token=3, routed_scaling=6.0, rope_theta=1e7)
+# two periods [attention, kda, kda, kda], every layer routed: 8 query heads
+# over 2 KV heads (4 a group; the published 64 over 8 are 8), 4 KDA heads of
+# 8 whose beta lies in (0, 2), a router of 16 of which a share holds 4
+_SOLAR = dm.DecoderConfig(
+    arch="solar_open2", vocab=97, layers=8, heads=8, kv_heads=2, head_dim=8,
+    hidden_size=48, max_seq=64,
+    layer_types=("attention", "kda", "kda", "kda") * 2, kda_heads=4,
+    kda_head_dim=8, kda_conv=4, kda_neg_eigval=True, ffn=24, shared_ffn=24,
+    experts=16, experts_per_token=3)
 _GRANITE_G4 = _GRANITE.replace(kv_heads=1)
 
 # Weights are normal(0, 0.3) (OLMoE's 0.05) and a router bias of 0.05: at
@@ -400,6 +409,20 @@ ROWS = {row.arch: row for row in (
                     latent_kv_scale=(48 / 24) ** 0.5)),
         # one row of 640 bfloat16 a position: 1,280 B
         chunk=("longcat-flash-chat-serve.json", 17472, {"latent": 512})),
+    # a kda slot beside K/V pools
+    Row("solar_open2",
+        _both(_SOLAR, solar_open2.init_params, std=0.3, bias_std=0.05),
+        declines="recurrent_state", holds="slot", refusal="recurrent",
+        multi_atol=1e-5, batch_dependent_bf16=True,
+        entry=dict(state_name="kda_state", attn_path="gather",
+                   experts_path={4: "einsum"}, state_path={4: "gather"}),
+        serve=("solar-open2-250b-serve.json",
+               dict(layer_types=_SOLAR.layer_types, experts=16,
+                    experts_held=4, expert_first=4, experts_per_token=3,
+                    kda_heads=4, kda_head_dim=8, kda_neg_eigval=True,
+                    heads=8, kv_heads=2, hidden=48, ffn=24)),
+        # 8 KV heads of 128 in bfloat16: 4,096 B a position
+        chunk=("solar-open2-250b-serve.json", 25664, {"attention": 128})),
 )}
 assert tuple(ROWS) == dm.ARCHS
 

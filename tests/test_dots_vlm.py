@@ -682,7 +682,7 @@ def test_the_latent_and_expert_rules_at_the_published_shapes():
                       "exaone_moe": 256, "nemotron_h": 928,
                       "kimi_linear": 512, "dots_vlm": 256,
                       "smallthinker": 768, "glm_dsa": 256,
-                      "longcat_flash": 256}
+                      "longcat_flash": 256, "solar_open2": 256}
 
 
 def test_routed_experts_kernel_in_chunks_of_an_eighth_of_the_width(

@@ -797,6 +797,83 @@ def test_kimi_linear_step_compiles_its_three_kernels_at_published_shapes(
     assert not found, found
 
 
+def test_solar_open2_step_compiles_its_three_kernels_at_64_lanes(
+        one_chip, as_on_tpu):
+    """Solar-Open2-250B's published widths, one period of its layers
+    (``attention``, ``kda``, ``kda``, ``kda``: both kinds), bucket 64, the
+    cell's pools (25,664 bf16 K/V blocks of 8 heads of 128, 65 state slots
+    of [128, 8192]): Mosaic accepts, inside the whole step as the engine
+    compiles it (``make_packed_step``), the delta-rule state-update kernel
+    at 64 heads (a lane's whole slot of 4 MiB one transfer, batches of 2:
+    exactly ``ssm_update._UNIT_BUDGET``; the four columns a head in two
+    128-lane tiles), the K/V walk at 64 lanes of 64 query heads over 8 KV
+    heads, and the routed-expert kernel over 20 held experts of width 1280
+    under a router of 320; every pool is aliased whole and nothing of a
+    pool's size is made beside the arguments."""
+    from benchmark.models import solar_open2_decoder
+    from paddle_tpu.pallas_kernels import moe_experts as moe
+    from paddle_tpu.pallas_kernels import ssm_update as ssm
+
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "solar-open2-250b-serve.json")) as fp:
+        config = dict(json.load(fp), num_hidden_layers=4)
+    cfg = solar_open2_decoder.decoder_config(config)
+    assert (cfg.hidden, cfg.heads, cfg.kv_heads, cfg.head_dim, cfg.kda_heads,
+            cfg.kda_head_dim, cfg.kda_conv, cfg.kda_neg_eigval, cfg.experts,
+            cfg.experts_held, cfg.experts_per_token, cfg.ffn, cfg.shared_ffn,
+            cfg.vocab, cfg.layer_types, cfg.routed_layers) == (
+        4096, 64, 8, 128, 64, 128, 4, True, 320, 20, 8, 1280, 1280, 24576,
+        ("attention", "kda", "kda", "kda"), (0, 1, 2, 3))
+    lanes, block_size, blocks = 64, 16, 25664
+    kv = dm.cache_config(cfg, block_size, blocks, state_slots=lanes + 1)
+    assert (kv.layers, kv.heads, kv.head_dim, kv.latent_layers,
+            kv.state_layers) == (1, 8, 128, 0, 3)
+    assert kv.state_shapes == (((3 * 24576,), "bf16"),
+                               ((128, 8192), "f32"))
+    assert dm.attention_path(cfg, kv, lanes) == "pallas"
+    assert dm.state_update_path(cfg, kv, lanes) == "pallas"
+    assert dm.state_update_columns(cfg, kv) == 8192
+    assert ssm.units_in_flight((65, 128, 8192), 8192, lanes) == 2
+    assert moe.experts_path(lanes, (20, 4096, 1280), jnp.bfloat16) \
+        == "pallas"
+
+    on_chip = functools.partial(_placed, one_chip)
+
+    carry = on_chip(jax.eval_shape(lambda: PagedKVCache(kv).carry()))
+    params = on_chip(_as_held(cfg, solar_open2_decoder.param_shapes(config)))
+    feeds = on_chip(_packed_feeds(kv, lanes, cfg.max_seq // block_size))
+    compiled = jax.jit(dm.make_packed_step(cfg, kv, lanes),
+                       donate_argnums=(0,)
+                       ).lower(carry, params, *feeds).compile()
+
+    text = compiled.as_text()
+    assert _kernel_calls(text) == 8     # 3 state updates, 1 walk, 4 experts
+    assert len(re.findall(r"%kda_state_update\S* = ", text)) == 3
+    assert len(re.findall(r"%paged_attention\S* = ", text)) == 1
+    assert _expert_kernels(text) == 4
+    assert not re.findall(r"%(ssm_state_update|latent_attention)\S* = ",
+                          text)
+    assert not _expert_passes(text, 20, 4096, 1280)
+    assert _weights_relaid(text) == []
+    pool_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in carry)
+    state_pool = (lanes + 1) * 128 * 8192 * 4
+    assert pool_bytes == 2 * 25664 * 16 * 1024 * 2 + 3 * (
+        state_pool + 65 * 3 * 24576 * 2)
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= pool_bytes
+    # what varies down a head's keys crosses into the state kernel turned,
+    # one [64, 128, 256] float32 array a layer (8.4e6 B), and nothing else
+    # of a slot's size is made
+    assert memory.temp_size_in_bytes < state_pool / 3
+    big = re.compile(
+        r" = (f32\[(65|64),128,(8192|4096|2048)\]\S* (copy|select|transpose"
+        r"|slice|dynamic-slice|gather|scatter|fusion)|bf16\[25664,16,1024\]"
+        r"\S* (copy|transpose|convert))\(")
+    found = [line.strip()[:160] for line in text.splitlines()
+             if big.search(line)]
+    assert not found, found
+
+
 def test_dots_vlm_step_compiles_its_two_kernels_at_published_shapes(
         one_chip, as_on_tpu):
     """dots.vlm1's published widths, its dense lead and two routed layers,

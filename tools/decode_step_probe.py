@@ -257,7 +257,9 @@ def parameter_fed_copies(text):
 
 
 def scope_of(op_name):
-    """``jit(step)/layer3/attn/kv_write/scatter`` -> ``layerN/attn/kv_write``."""
+    """``jit(step)/layer3/attn/kv_write/scatter`` -> ``layerN/attn/kv_write``
+    (a gated softmax mixer's ``layerN/attention/`` + ``qkv``, ``kv_write``,
+    ``kv_read``, ``gate``, ``out`` among them)."""
     parts = [p for p in op_name.split("/") if not p.startswith("jit(")]
     parts = [re.sub(r"^layer\d+$", "layerN", p) for p in parts]
     keep = [p for p in parts
@@ -266,7 +268,7 @@ def scope_of(op_name):
                      "ssm", "in_proj", "conv", "state_update", "out_proj",
                      "window", "kda", "state", "out", "latent", "absorb",
                      "shared", "q_compress", "rope", "staged", "index",
-                     "select", "mask", "zero")]
+                     "select", "mask", "zero", "attention", "qkv", "gate")]
     return "/".join(keep) or "other"
 
 
@@ -410,7 +412,7 @@ def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
 
     from benchmark import dots_cost, exaone_cost, glm_cost, kimi_cost, \
         lfm2_cost, longcat_cost, moe_cost, nemotron_cost, smallthinker_cost, \
-        ssm_cost, trace_reduce
+        solar_cost, ssm_cost, trace_reduce
     from paddle_tpu.core import telemetry
     from paddle_tpu.core.executor import CarriedStepFn
     from paddle_tpu.pallas_kernels import kda_update, paged_attention, \
@@ -527,6 +529,10 @@ def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
             # two-matrix experts in the layers the pattern names, a share
             bytes_of = nemotron_cost.experts_hit_bytes_per_step
             held = "n_routed_experts"
+        elif "gqa_layers" in config:
+            # three-matrix experts in every layer, a share
+            bytes_of = solar_cost.experts_hit_bytes_per_step
+            held = "n_routed_experts"
         elif "linear_attn_config" in config:
             # three-matrix experts behind a dense lead, a share
             bytes_of = kimi_cost.experts_hit_bytes_per_step
@@ -580,7 +586,8 @@ def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
     if cfg.kda_layers:
         # every lane's state in every KDA layer, read and written once a
         # step, over the kernel's own executions
-        moved = kimi_cost.state_traffic_bytes_per_step(config, b)
+        moved = (solar_cost if "gqa_layers" in config else kimi_cost) \
+            .state_traffic_bytes_per_step(config, b)
         result["kda_state_bytes_per_step"] = moved
         ms = kernel_ms(kda_update.KERNEL_NAME)
         if ms:
@@ -765,7 +772,8 @@ def main(argv=None):
                 config["linear_attn_config"], **{
                     key: [l for l in config["linear_attn_config"][key]
                           if l <= args.layers]
-                    for key in ("kda_layers", "full_attn_layers")})
+                    for key in ("kda_layers", "full_attn_layers")
+                    if key in config["linear_attn_config"]})
     device = jax.devices()[0]
     model = load_module("models", config["model"])
     cfg = model.decoder_config(config)
@@ -773,9 +781,10 @@ def main(argv=None):
     if set(forms) - set(EXPERT_FORMS):
         ap.error("--experts takes " + ", ".join(EXPERT_FORMS))
     if args.ssm_update == "xla":
-        from paddle_tpu.pallas_kernels import ssm_update
+        from paddle_tpu.pallas_kernels import kda_update, ssm_update
 
         ssm_update.state_update = ssm_update.state_update_reference
+        kda_update.state_update = kda_update.state_update_reference
     args.dtype = args.dtype or cfg.kv_dtype or "f32"
     # a state slot a lane and the scratch, for a model with recurrent layers
     kv = dm.cache_config(cfg, args.block_size, args.blocks, args.dtype,
