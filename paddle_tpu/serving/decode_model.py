@@ -67,7 +67,8 @@ what a kind keeps):
   ``paged_attention`` over them (scope ``kv_read`` where the kernel
   serves, ``kv_gather`` where the table is gathered); a recurrent layer's
   window and state are read from and written to the slot the step is told
-  for each lane (``state_slots``), the state through
+  for each lane (``state_slots``), the window through ``push_windows`` (a
+  slot whole tiles, XLA's gather and scatter), the state through
   ``pallas_kernels.ssm_update.state_update`` or, for ``kda`` layers,
   ``pallas_kernels.kda_update.state_update`` (on a TPU a kernel that moves
   each slot in place, elsewhere gather, update, scatter).
@@ -109,7 +110,7 @@ __all__ = ["DecoderConfig", "init_decoder_params", "save_decoder",
            "load_decoder", "is_decoder_dir", "has_draft", "load_draft",
            "truncate_decoder", "laid_out", "attention_path", "experts_path",
            "state_update_path", "state_update_columns", "experts_chunk",
-           "experts_gate", "StepAccount",
+           "experts_gate", "StepAccount", "push_windows",
            "make_paged_step",
            "make_fed_step", "make_paged_step_multi",
            "make_draft_rollout", "make_unpaged_step", "unpaged_generate",
@@ -655,7 +656,9 @@ def _conv_window(cfg):
 def _state_shapes(cfg):
     """``(shape, dtype)`` of each array a recurrent layer keeps a sequence,
     by the kind of layer: always the convolution's window first (``taps -
-    1`` inputs, flat, in the weights' dtype); a ``mamba`` layer then its
+    1`` inputs, flat, in the weights' dtype; the cache holds a slot of it as
+    whole rows of 128, ``kv_cache.slot_layout``, and ``push_windows`` moves
+    it so); a ``mamba`` layer then its
     state (``[ssm_state, ssm_inner]`` float32: the heads' ``[head_dim,
     ssm_state]`` matrices, transposed so the minor dimension is the
     128-lane-dense one), a ``kda`` layer its state (``[kda_head_dim,
@@ -817,6 +820,43 @@ def _write_rows(pool, blk_ids, offs, rows):
     here (a bf16 pool rounds the block's float32 K and V once)."""
     return pool.at[blk_ids, offs].set(
         rows.reshape(rows.shape[0], -1).astype(pool.dtype))
+
+
+# the form ``push_windows`` moves a window in, as the engine names it (the
+# executables' keys, the ``serving_prewarm`` event): whole tiles of a pool
+# ``[slots, rows, 128]``
+WINDOW_UPDATE = "tiles"
+
+
+def pushed(old, xbc, taps):
+    """One token into convolution windows held as values: ``old`` [B, (K -
+    1) * W] the lanes' ``taps - 1`` newest inputs, oldest first, ``xbc`` [B,
+    W] this token's -> (the windows one token on, in ``old``'s dtype; the
+    ``taps`` newest inputs [B, K, W] float32, oldest first, this one, as
+    stored, last)."""
+    width = xbc.shape[1]
+    new = jnp.concatenate([old[:, width:], xbc.astype(old.dtype)], axis=1)
+    return new, jnp.concatenate([old[:, :width], new], axis=1).reshape(
+        xbc.shape[0], taps, width).astype(jnp.float32)
+
+
+def push_windows(pool, slots, fresh, xbc, taps):
+    """``pushed`` on the slots of a window pool where they lie: lane ``b``'s
+    window is slot ``slots[b]`` of ``pool`` [slots, rows, 128]
+    (``kv_cache.slot_layout``: the ``(taps - 1) * W`` values flat in whole
+    rows of 128, the last row's rest never read), started from zeros where
+    ``fresh[b]`` whatever the slot holds.  -> (pool, [B, K, W] float32).
+    A slot is one run of whole tiles, so XLA's own gather and scatter move
+    it: B slots out, B slots into the whole donated array, no slot other
+    than the lanes' changed.  Idle lanes all name slot 0 and all write it;
+    nothing reads it."""
+    lanes, held = xbc.shape[0], (taps - 1) * xbc.shape[1]
+    rows, row = pool.shape[1:]
+    old = jnp.take(pool, slots, axis=0, mode="clip").reshape(
+        lanes, rows * row)[:, :held]
+    new, window = pushed(_ssm.started(fresh, old), xbc, taps)
+    new = jnp.pad(new, ((0, 0), (0, rows * row - held)))
+    return pool.at[slots].set(new.reshape(lanes, rows, row)), window
 
 
 def attention_path(cfg, kv_config, lanes=1, kind="attention"):
@@ -1200,12 +1240,17 @@ class StepAccount:
     def _recurrent(self):
         """The recurrent layers' slots, named by what they hold
         (``STATE_NAMES``): the state a step reads and writes, a slot a live
-        lane; and, of the kinds that keep a state beside their window, how
+        lane; the form their windows move in (``window_update``); and, of
+        the kinds that keep a state beside their window, how
         it is moved a bucket (``"pallas"``: each slot in place |
         ``"gather"``), what one transfer of the kernel then moves and in
         how many a slot goes; of ``kda`` layers the heads a slot holds."""
         cfg, kv = self.cfg, self.kv_config
         name, slot = cfg.state_name, _kv.slot_bytes(kv)
+        # how a lane's convolution window moves between its slot and the
+        # step, whatever the bucket
+        self._said["window_update"] = self.key_parts["window_update"] \
+            = WINDOW_UPDATE
         if cfg.kda_layers:
             # what the delta-rule kernel's shape rule turns on
             self._said["kda_heads"] = cfg.kda_heads
@@ -1273,31 +1318,29 @@ def _pool_index(cfg):
 
 class _Recurrent:
     """The recurrent layers' callback of a step (``_block``), built by the
-    step maker from where it keeps things: ``read(i)`` / ``write(i, value)``
-    reach recurrent layer ``i``'s window ``[B, (K - 1) * W]`` for the step's
-    lanes, and ``advance(i, fresh, decay, dx, b, c) -> y`` moves its state
+    step maker from where it keeps things: ``push(i, fresh, xbc) -> [B, K,
+    W]`` moves recurrent layer ``i``'s windows one token on for the step's
+    lanes (``pushed``'s rule, on values or on the slots of a pool), and
+    ``advance(i, fresh, decay, dx, b, c) -> y`` moves its state
     one token (``ssm_update.advance``'s mathematics, on values or on the
     slots of a pool; for ``kda`` layers ``kda_update.advance``'s, reached
     as ``delta``; None for a kind of layer that keeps a window and no
     state).  A lane at position 0 (``fresh``) starts from zeros whatever is
     stored."""
 
-    def __init__(self, pool_of, taps, pos, read, write, advance):
-        self._at, self._taps = pool_of, taps
+    def __init__(self, pool_of, pos, push, advance):
+        self._at = pool_of
         self._fresh = pos == 0
-        self._read, self._write, self._advance = read, write, advance
+        self._push, self._advance = push, advance
 
     def window(self, l, xbc):
         """Push this token's convolution input ``xbc`` [B, W] -> the
         ``taps`` newest inputs [B, K, W] float32, oldest first, this one
-        (as stored) last."""
-        i = self._at[l]
-        old = _ssm.started(self._fresh, self._read(i))
-        new = jnp.concatenate(
-            [old[:, xbc.shape[1]:], xbc.astype(old.dtype)], axis=1)
-        self._write(i, new)
-        return jnp.concatenate([old[:, :xbc.shape[1]], new], axis=1).reshape(
-            xbc.shape[0], self._taps, -1).astype(jnp.float32)
+        (as stored) last.  The move between the slot and the step has a
+        scope of its own inside the mixer's (``kda/conv/window``,
+        ``ssm/conv/window``)."""
+        with jax.named_scope("window"):
+            return self._push(self._at[l], self._fresh, xbc)
 
     def advance(self, l, decay, dx, b, c):
         """``S = decay * S + outer(b, dx)`` -> ``c . S`` [B, I]: the state
@@ -1334,8 +1377,9 @@ def make_paged_step(cfg, kv_config):
     A model with recurrent layers takes ``state_slots`` [B] int32 too: the
     slot each lane's sequence holds (idle lanes name the scratch slot 0).
     A lane's window and state are read from its slot and written back to
-    it, and a lane whose ``pos`` is 0 starts from zeros whatever the slot
-    holds, so a slot needs no clearing between sequences.
+    it (``push_windows``; ``state_update``), and a lane whose ``pos`` is 0
+    starts from zeros whatever the slot holds, so a slot needs no clearing
+    between sequences.
 
     A model with window layers takes, last, ``window_tables`` [B, R] int32:
     each lane's ring in the window layers' pools (``kv_cache.WindowRing``;
@@ -1462,19 +1506,17 @@ def make_paged_step(cfg, kv_config):
             # the window, then (for a kind that has one) the state
             windows, states = state if len(state) == 2 else (state[0], None)
 
-            def put(i, value):
-                # a scatter of B slots into a whole donated array, as
-                # _write_rows is of B rows; idle lanes all name slot 0
-                windows[i] = windows[i].at[slots].set(value)
+            def push(i, fresh, xbc):
+                windows[i], window = push_windows(windows[i], slots, fresh,
+                                                  xbc, taps)
+                return window
 
             def advance(i, fresh, *operands):
                 states[i], y = move(states[i], slots, fresh, *operands)
                 return y
 
-            recur = _Recurrent(
-                pool_of, taps, pos,
-                lambda i: jnp.take(windows[i], slots, axis=0, mode="clip"),
-                put, advance if states is not None else None)
+            recur = _Recurrent(pool_of, pos, push,
+                               advance if states is not None else None)
 
         logits, extras = block(params, cfg, tok, pos, attend,
                                context_lens > 0, recur)
@@ -1684,6 +1726,7 @@ def make_unpaged_step(cfg, pad_len, ring_len=None):
     windowed = frozenset(cfg.window_layers)
     latent = frozenset(cfg.latent_layers)
     advance_values = _kda.advance if cfg.kda_layers else _ssm.advance
+    taps = _conv_window(cfg)[0]
 
     def step(kv_carry, params, tok, pos, context_lens):
         tok = tok.astype(jnp.int32)
@@ -1724,8 +1767,10 @@ def make_unpaged_step(cfg, pad_len, ring_len=None):
                                     context_lens, cfg.attention_multiplier,
                                     cfg.window if at else None)
 
-        def put(i, value):
-            state[0] = state[0].at[i].set(value)
+        def push(i, fresh, xbc):
+            new, window = pushed(_ssm.started(fresh, state[0][i]), xbc, taps)
+            state[0] = state[0].at[i].set(new)
+            return window
 
         def advance(i, fresh, *operands):
             new, y = advance_values(_ssm.started(fresh, state[1][i]),
@@ -1733,8 +1778,7 @@ def make_unpaged_step(cfg, pad_len, ring_len=None):
             state[1] = state[1].at[i].set(new)
             return y
 
-        recur = _Recurrent(pool_of, _conv_window(cfg)[0], pos,
-                           lambda i: state[0][i], put,
+        recur = _Recurrent(pool_of, pos, push,
                            advance if len(state) > 1 else None) \
             if state else None
         logits, _extras = block(params, cfg, tok, pos, attend,

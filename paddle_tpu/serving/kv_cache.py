@@ -40,7 +40,9 @@ page K and V as above, ``heads`` being the KV heads the pool stores (fewer
 than the query heads under grouped-query attention).  Recurrent layers
 (state-space mixers) keep, a sequence, a state that is constant in its
 length: the cache holds it in ``state_slots`` *slots* (one array per layer
-and per ``(shape, dtype)`` the config lists, ``[state_slots, *shape]``),
+and per ``(shape, dtype)`` the config lists, ``[state_slots,
+*slot_layout(shape)]``: a slot is whole tiles of the chip's tiled HBM, so
+a step moves it as one run of bytes),
 ``SlotAllocator`` hands a sequence one slot at admission for as long as it
 holds blocks, and the step is told each lane's slot beside its block table
 (slot 0, like block 0, is the idle lanes' scratch).  The slots ride in the
@@ -104,7 +106,7 @@ __all__ = ["KVCacheConfig", "BlockAllocator", "SlotAllocator",
            "WindowRing", "PagedKVCache", "PrefixCache",
            "plan_num_blocks", "block_bytes", "latent_block_bytes",
            "index_block_bytes",
-           "slot_bytes", "state_bytes",
+           "slot_bytes", "state_bytes", "slot_layout",
            "window_bytes",
            "engine_owned_kv_bytes",
            "engine_owned_resident_bytes", "register_resident_bytes",
@@ -359,9 +361,26 @@ def window_bytes(config):
         * _layer_block_bytes(config)
 
 
+def slot_layout(shape):
+    """The shape one slot of ``shape`` values takes in its pool.  A matrix
+    (a recurrent state, ``[N, I]``) lies as it is: its two minor dimensions
+    are whole tiles of the chip's tiled HBM.  A flat array (a convolution's
+    window, ``[(K - 1) * W]``) lies as rows of 128, ``[rows, 128]``, the
+    last row's rest zeros nothing reads: held flat, ``[slots, values]``,
+    the chip tiles the SLOTS with the values (16 bfloat16 rows a tile), a
+    slot is one sublane of every tile and a write of it as many masked
+    stores, 21 GB/s where a slot of whole tiles moves at the HBM's rate
+    (PERF.md section 6, PR 65).  ``slot_bytes`` counts the values held,
+    not the rest of the last row."""
+    if len(shape) != 1:
+        return tuple(shape)
+    return (-(-shape[0] // 128), 128)
+
+
 def slot_bytes(config):
     """HBM bytes ONE sequence's recurrent state costs across all recurrent
-    layers."""
+    layers: the values held (``slot_layout`` may round a flat array's last
+    row up)."""
     per = 0
     for shape, dt in config.state_shapes:
         n = _PAYLOAD[dt][1]
@@ -907,9 +926,9 @@ class PagedKVCache:
     layers`` arrays of ``[num_blocks, block_size, heads * head_dim]``),
     for int8 residency the K then V scales after them (``4 * layers`` in
     all; a scale array is ``[num_blocks, block_size, heads]``), and then
-    one group of ``state_layers`` arrays ``[state_slots, *shape]`` for
-    each entry of the config's ``state_shapes``.  ``config.groups`` splits
-    a carry back by that description.
+    one group of ``state_layers`` arrays ``[state_slots,
+    *slot_layout(shape)]`` for each entry of the config's ``state_shapes``.
+    ``config.groups`` splits a carry back by that description.
 
     ``allocator`` hands out blocks and ``slots`` (None without recurrent
     layers) state slots.  A slot is constant in the sequence's length and
@@ -956,7 +975,8 @@ class PagedKVCache:
                     for _ in range(config.index_layers)) \
             + pools(config.window_blocks, config.window_layers)
         self._carry += tuple(
-            jnp.zeros((config.state_slots,) + shape, _PAYLOAD[dt][0])
+            jnp.zeros((config.state_slots,) + slot_layout(shape),
+                      _PAYLOAD[dt][0])
             for shape, dt in config.state_shapes
             for _ in range(config.state_layers))
         _LIVE.add(self)

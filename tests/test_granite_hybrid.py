@@ -285,7 +285,8 @@ def test_cache_describes_layers_by_kind():
     assert [len(g) for g in groups] == [2, 2]
     assert all(a.shape == (16, BS, 32) for g in groups for a in g)
     assert len(windows) == len(states) == 6
-    assert all(w.shape == (5, 576) for w in windows)
+    # a slot of a window pool is whole rows of 128: 576 values in five
+    assert all(w.shape == (5, 5, 128) for w in windows)
     assert all(s.shape == (5, 32, 128) and s.dtype == jnp.float32
                for s in states)
     assert cache.kv_nbytes == 2 * 2 * 16 * BS * 32 * 4

@@ -322,7 +322,7 @@ def test_the_27_layer_pattern_gets_latent_pools_and_slots():
         and latent[0].dtype == jnp.bfloat16
     assert len(windows) == len(states) == 20
     assert states[0].shape == (33, 128, 4096) \
-        and windows[0].shape == (33, 3 * 12288)
+        and windows[0].shape == (33, 3 * 12288 // 128, 128)
     pool_of = dm._pool_index(cfg)
     assert [pool_of[l] for l in cfg.latent_layers] == list(range(7))
     assert [pool_of[l] for l in cfg.kda_layers] == list(range(20))

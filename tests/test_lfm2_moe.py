@@ -400,7 +400,8 @@ def test_cache_describes_layers_by_kind():
     carry = cache.carry()
     groups, state = kv.groups(carry)
     assert [len(g) for g in groups] == [2, 2] and len(state) == 1
-    assert all(w.shape == (5, 128) for w in state[0]) and len(state[0]) == 3
+    assert all(w.shape == (5, 1, 128) for w in state[0]) \
+        and len(state[0]) == 3
     assert kvc.slot_bytes(kv) == 3 * 128 * 4
     assert cache.nbytes == cache.kv_nbytes + 5 * 3 * 128 * 4
     assert [dt for _s, dt in dm.cache_config(

@@ -358,7 +358,7 @@ def test_the_published_pattern_gets_kv_pools_and_slots():
         and pools[0][0].dtype == jnp.bfloat16
     assert len(windows) == len(states) == 6
     assert states[0].shape == (65, 128, 8192) \
-        and windows[0].shape == (65, 3 * 24576)
+        and windows[0].shape == (65, 3 * 24576 // 128, 128)
     pool_of = dm._pool_index(cfg)
     assert [pool_of[l] for l in cfg.attn_layers] == [0, 1]
     assert [pool_of[l] for l in cfg.kda_layers] == list(range(6))

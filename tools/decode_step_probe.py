@@ -259,7 +259,10 @@ def parameter_fed_copies(text):
 def scope_of(op_name):
     """``jit(step)/layer3/attn/kv_write/scatter`` -> ``layerN/attn/kv_write``
     (a gated softmax mixer's ``layerN/attention/`` + ``qkv``, ``kv_write``,
-    ``kv_read``, ``gate``, ``out`` among them)."""
+    ``kv_read``, ``gate``, ``out`` among them).  A recurrent layer's window
+    moves under ``window`` inside its mixer's scope, ``kda/conv/window`` and
+    ``ssm/conv/window``; a ``conv`` layer's mixer names its own scope so,
+    and the two are one: ``conv/window``."""
     parts = [p for p in op_name.split("/") if not p.startswith("jit(")]
     parts = [re.sub(r"^layer\d+$", "layerN", p) for p in parts]
     keep = [p for p in parts
@@ -269,6 +272,7 @@ def scope_of(op_name):
                      "window", "kda", "state", "out", "latent", "absorb",
                      "shared", "q_compress", "rope", "staged", "index",
                      "select", "mask", "zero", "attention", "qkv", "gate")]
+    keep = [p for i, p in enumerate(keep) if not i or p != keep[i - 1]]
     return "/".join(keep) or "other"
 
 
