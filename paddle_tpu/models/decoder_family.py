@@ -24,10 +24,11 @@ class DecoderFamily(collections.namedtuple(
         ("kinds", "dtypes", "grouped_query", "routes", "expert_matrices",
          "dense_lead", "holds_share", "own_stream_width", "grouped_router",
          "rotated_latent", "shared_expert", "expert_gate", "selects",
-         "zero_experts", "scaled_latent", "neg_eigval"),
+         "zero_experts", "scaled_latent", "neg_eigval",
+         "residual_streams"),
         defaults=(("f32", "bf16"), False, None, None, False, False, False,
                   False, False, False, "silu", False, False, False,
-                  False))):
+                  False, False))):
     """``kinds``: the kinds of layer the block computes
     (``decode_model.LAYER_KINDS``: seven of them, of which a family names
     one to three, in any pairing the cache holds: a ``kda`` slot beside
@@ -70,6 +71,10 @@ class DecoderFamily(collections.namedtuple(
     by ``cfg.latent_kv_scale``.  ``neg_eigval``: its ``kda`` layers' delta
     rule may allow negative eigenvalues (``cfg.kda_neg_eigval``: ``beta`` is
     twice the sigmoid, in (0, 2), so a write may turn about what the state
-    holds along its key and not only shrink it)."""
+    holds along its key and not only shrink it).  ``residual_streams``: a
+    token's stream is ``cfg.hc_mult`` vectors and not one, mixed round every
+    sublayer by manifold-constrained hyper-connections
+    (``models/hyper_connections.py``; ``cfg.hc_sinkhorn_iters``,
+    ``cfg.hc_eps``, ``cfg.hc_clamp``)."""
 
     __slots__ = ()

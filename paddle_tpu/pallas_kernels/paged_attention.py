@@ -1167,13 +1167,54 @@ def latent_path(q_shape, pool_shape, pool_dtype, rank):
     return "pallas" if ok else "gather"
 
 
+@functools.lru_cache(maxsize=None)
+def _latent_call(q_shape, mask_shape, pool_shape, pool_dtype, maxb, per,
+                 scale, rank, lane_grid, interpret, kernel, product):
+    """The latent kernel's call for one set of shapes, traced once, as
+    ``_kernel_call`` is for the K/V form: a model's latent layers call one
+    ``jit`` whose jaxpr is inlined where it is called, so a step of 40 such
+    layers traces and lowers the kernel's straight-line body once, not 40
+    times (a second a call site with nothing cached: 45 s of lowering
+    Xing4.0's step here, 6 with this).  ``q_shape`` is the query as the
+    kernel holds it (its rows filled up to 16s), ``mask_shape`` a selecting
+    layer's mask or None.  Everything the kernel reads beside its operands
+    is in the key, the kernel and its product too (``kernel``, ``product``:
+    a test or a check that swaps either gets another call)."""
+    bb, rows, width = q_shape
+    bs = pool_shape[1]
+    held = 1 if lane_grid else bb
+    mine = (lambda i, bt, cl: (i, 0, 0)) if lane_grid \
+        else (lambda i, bt, cl: (0, 0, 0))
+    masks = () if mask_shape is None else (mask_shape,)
+    del product                         # read by ``kernel`` from the module
+    return jax.jit(pl.pallas_call(
+        functools.partial(kernel, block_size=bs, maxb=maxb, per=per,
+                          scale=scale, value_cols=rank,
+                          masked=mask_shape is not None),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=2,
+            grid=(bb // held,),
+            in_specs=[pl.BlockSpec((held, rows, width), mine)]
+            + [pl.BlockSpec((held,) + m[1:], mine) for m in masks]
+            + [pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((held, rows, rank), mine),
+            scratch_shapes=[pltpu.VMEM((2, per * bs, width), pool_dtype),
+                            pltpu.SemaphoreType.DMA((2,))]
+            + ([pltpu.SMEM((1,), jnp.int32)] if lane_grid else []),
+        ),
+        out_shape=jax.ShapeDtypeStruct((bb, rows, rank), jnp.float32),
+        name=LATENT_KERNEL_NAME,
+        interpret=interpret,
+    ), inline=True)
+
+
 def _latent_pallas(q, pool, block_tables, context_lens, scale, rank,
                    interpret=None, chosen=None):
     """q [B, H, W] against one pool [num_blocks, block_size, W] -> [B, H,
     rank].  ``chosen`` given (``_chunk_mask``'s [B, chunks, span] int32, of a
     layer that selects): over the positions it marks alone, of those a
     lane's context holds."""
-    bb, h, width = q.shape
+    h = q.shape[1]
     bs = pool.shape[1]
     maxb = block_tables.shape[1]
     per = latent_chunk_positions(q.shape, pool.shape, pool.dtype, rank,
@@ -1184,32 +1225,15 @@ def _latent_pallas(q, pool, block_tables, context_lens, scale, rank,
     qx = jnp.pad(q.astype(jnp.float32), ((0, 0), (0, rows - h), (0, 0)))
     # every lane's query and output in one grid step, or a lane a step
     lane_grid = _latent_lane_grid(q.shape, pool.shape, pool.dtype, rank)
-    held = 1 if lane_grid else bb
-    mine = (lambda i, bt, cl: (i, 0, 0)) if lane_grid \
-        else (lambda i, bt, cl: (0, 0, 0))
     # a layer that selects hands its mask in after the query: a lane's
     # chunks of it, or every lane's, as the query's
     masks = () if chosen is None else (chosen,)
-    out = pl.pallas_call(
-        functools.partial(_latent_kernel, block_size=bs, maxb=maxb, per=per,
-                          scale=float(scale), value_cols=rank,
-                          masked=chosen is not None),
-        grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=(bb // held,),
-            in_specs=[pl.BlockSpec((held, rows, width), mine)]
-            + [pl.BlockSpec((held,) + m.shape[1:], mine) for m in masks]
-            + [pl.BlockSpec(memory_space=pl.ANY)],
-            out_specs=pl.BlockSpec((held, rows, rank), mine),
-            scratch_shapes=[pltpu.VMEM((2, per * bs, width), pool.dtype),
-                            pltpu.SemaphoreType.DMA((2,))]
-            + ([pltpu.SMEM((1,), jnp.int32)] if lane_grid else []),
-        ),
-        out_shape=jax.ShapeDtypeStruct((bb, rows, rank), jnp.float32),
-        name=LATENT_KERNEL_NAME,
-        interpret=interpret,
-    )(block_tables.astype(jnp.int32).reshape(-1),
-      context_lens.astype(jnp.int32), qx, *masks, pool)
+    out = _latent_call(
+        qx.shape, None if chosen is None else tuple(chosen.shape),
+        tuple(pool.shape), pool.dtype, maxb, per, float(scale), rank,
+        lane_grid, interpret, _latent_kernel, _product)(
+            block_tables.astype(jnp.int32).reshape(-1),
+            context_lens.astype(jnp.int32), qx, *masks, pool)
     return out[:, :h]
 
 

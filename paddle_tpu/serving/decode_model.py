@@ -96,6 +96,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core import telemetry as _tm
+from ..models import hyper_connections as _hc
 from ..pallas_kernels import kda_update as _kda
 from ..pallas_kernels import moe_experts as _moe
 from ..pallas_kernels import paged_attention as _pa
@@ -120,7 +121,7 @@ __all__ = ["DecoderConfig", "init_decoder_params", "save_decoder",
 # the families, a module each: ``models/<arch>.py``
 ARCHS = ("gpt2", "olmoe", "granite_hybrid", "lfm2_moe", "exaone_moe",
          "nemotron_h", "kimi_linear", "dots_vlm", "smallthinker", "glm_dsa",
-         "longcat_flash", "solar_open2")
+         "longcat_flash", "solar_open2", "xing4")
 LAYER_KINDS = ("attention", "mamba", "conv", "window", "experts", "kda",
                "latent")
 # recurrent kind -> the name its slot goes by in spans, gauges and counters
@@ -241,7 +242,14 @@ class DecoderConfig:
     position encoding, no Q/K norm and a sigmoid gate, a value a head
     channel, on the heads' output; every layer routed as ``exaone_moe``'s
     beside a shared expert, no dense lead; an untied head, a stream of its
-    own width, and the share it may hold.
+    own width, and the share it may hold.  ``xing4`` is the block of
+    ``models/xing4.py``: ``dots_vlm``'s mixer and feed-forwards round a
+    residual path of ``hc_mult`` streams a token, mixed round every sublayer
+    by manifold-constrained hyper-connections: three maps made from the
+    streams themselves, the residual one normalised ``hc_sinkhorn_iters``
+    times by rows and columns (``hc_eps`` in each sum) from entries clipped
+    to ``hc_clamp`` before the exponential (``models/hyper_connections.py``;
+    refused for a family that does not declare ``residual_streams``).
 
     ``kv_heads`` None means ``heads`` (multi-head); ``layer_types`` None
     means ``layers`` attention layers.  ``dtype`` is the weights' (``f32``
@@ -262,7 +270,8 @@ class DecoderConfig:
                  "q_rank", "n_group", "topk_group", "rope_scaling",
                  "v_head_dim", "index_heads", "index_head_dim",
                  "index_topk", "zero_experts", "latent_q_scale",
-                 "latent_kv_scale", "kda_neg_eigval")
+                 "latent_kv_scale", "kda_neg_eigval", "hc_mult",
+                 "hc_sinkhorn_iters", "hc_eps", "hc_clamp")
 
     def __init__(self, vocab, layers, heads, head_dim, ffn=None,
                  max_seq=64, arch="gpt2", dtype="f32", kv_dtype=None,
@@ -279,7 +288,8 @@ class DecoderConfig:
                  topk_group=1, rope_scaling=None, v_head_dim=None,
                  index_heads=0, index_head_dim=0, index_topk=0,
                  zero_experts=0, latent_q_scale=1.0, latent_kv_scale=1.0,
-                 kda_neg_eigval=False):
+                 kda_neg_eigval=False, hc_mult=0, hc_sinkhorn_iters=0,
+                 hc_eps=0.0, hc_clamp=None):
         if arch not in ARCHS:
             raise ValueError("decoder arch must be %s: %r"
                              % ("|".join(ARCHS), arch))
@@ -349,6 +359,11 @@ class DecoderConfig:
         self.latent_q_scale = float(latent_q_scale)
         self.latent_kv_scale = float(latent_kv_scale)
         self.kda_neg_eigval = bool(kda_neg_eigval)
+        self.hc_mult = int(hc_mult)
+        self.hc_sinkhorn_iters = int(hc_sinkhorn_iters)
+        self.hc_eps = float(hc_eps)
+        self.hc_clamp = None if hc_clamp is None \
+            else tuple(float(x) for x in hc_clamp)
         if self.hidden_size not in (None, self.heads * self.head_dim) \
                 and not family.own_stream_width:
             raise ValueError("the %s block's stream is heads * head_dim "
@@ -458,6 +473,19 @@ class DecoderConfig:
             raise ValueError(
                 "kda_neg_eigval (beta in (0, 2)) is for the %s blocks' kda "
                 "layers: %r" % (_declaring("neg_eigval"), kda_neg_eigval))
+        streams = (self.hc_mult, self.hc_sinkhorn_iters, self.hc_eps,
+                   self.hc_clamp)
+        if any(streams) != bool(family.residual_streams) or any(streams) \
+                and not (self.hc_mult >= 2 and self.hc_sinkhorn_iters >= 1
+                         and self.hc_eps > 0 and self.hc_clamp is not None
+                         and len(self.hc_clamp) == 2
+                         and self.hc_clamp[0] < self.hc_clamp[1]):
+            raise ValueError(
+                "hc_mult >= 2 residual streams, mixed by maps normalised "
+                "hc_sinkhorn_iters >= 1 times with hc_eps > 0 from entries "
+                "clipped to hc_clamp (min < max), are for the %s blocks and "
+                "for no other: %r" % (_declaring("residual_streams"),
+                                      streams))
         if self.shared_ffn and not family.shared_expert:
             raise ValueError(
                 "the %s blocks pass every token through a shared expert of "
@@ -554,6 +582,13 @@ class DecoderConfig:
         return 2 if _model(self.arch).FAMILY.routes == "pairs" else 1
 
     @property
+    def mixings(self):
+        """The sublayers whose residual path is a mixing of ``hc_mult``
+        streams: two a layer (the mixer's and the feed-forward's) of a
+        family that declares ``residual_streams``, else none."""
+        return 2 * self.layers if self.hc_mult else 0
+
+    @property
     def router_width(self):
         """The outputs a routed layer's router scores: its experts and then
         its identity experts."""
@@ -605,6 +640,8 @@ class DecoderConfig:
     def to_dict(self):
         d = {s: getattr(self, s) for s in self.__slots__}
         d["layer_types"] = list(self.layer_types)
+        if self.hc_clamp is not None:
+            d["hc_clamp"] = list(self.hc_clamp)
         if self.v_head_dim == self.head_dim:
             # not a width of its own: it follows ``head_dim`` (``replace``)
             d["v_head_dim"] = None
@@ -1043,6 +1080,8 @@ class StepAccount:
             self._routed(params)
         if cfg.recurrent_layers:
             self._recurrent()
+        if cfg.mixings:
+            self._streams()
         if laid:
             # (the argument shapes tell the two forms of a weight apart too)
             self.key_parts["weights_laid_out"] = sorted(laid)
@@ -1275,6 +1314,26 @@ class StepAccount:
                           name + "_bytes": live * slot})
         self._reads.append(read)
 
+    def _streams(self):
+        """The residual path of a block that carries ``hc_mult`` streams a
+        token: how many, how often they are normalised, the sublayers that
+        mix them and what those mixings move of the live lanes' streams
+        (``hyper_connections.stream_bytes``, which the benchmark's cost file
+        is held to)."""
+        cfg = self.cfg
+        said = {"residual_streams": cfg.hc_mult,
+                "hc_sinkhorn_iters": cfg.hc_sinkhorn_iters}
+        self._said.update(said)
+        self.key_parts.update(said)
+
+        def read(bucket, lens, attrs):
+            attrs.update(
+                hc_streams=cfg.hc_mult, hc_mixings=cfg.mixings,
+                hc_stream_bytes=_hc.stream_bytes(
+                    cfg.hc_mult, cfg.hidden, cfg.mixings,
+                    int(np.count_nonzero(lens))))
+        self._reads.append(read)
+
     def prewarm_attrs(self, bucket):
         """What the ``serving_prewarm`` event of ``bucket``'s executable
         says of the step's kinds: each one's path, form and chunk."""
@@ -1292,13 +1351,17 @@ class StepAccount:
 
     def pool_bytes(self):
         """gauge -> the bytes of the pools beside K and V that the model's
-        kinds hold, by the cache's description (no kind, no gauge)."""
+        kinds hold, by the cache's description, and of what its residual
+        streams are mixed by (no kind, no gauge)."""
         kv = self.kv_config
         pools = {"latent_pool_bytes": kv.latent_layers
                  * _kv.latent_block_bytes(kv) * kv.num_blocks,
                  "index_pool_bytes": kv.index_layers
                  * _kv.index_block_bytes(kv) * kv.num_blocks,
-                 "%s_bytes" % self.cfg.state_name: _kv.state_bytes(kv)}
+                 "%s_bytes" % self.cfg.state_name: _kv.state_bytes(kv),
+                 # the mixings' parameters of a model of several streams
+                 "hc_param_bytes": _hc.param_bytes(self.cfg,
+                                                   self.cfg.mixings)}
         return {gauge: n for gauge, n in pools.items() if n}
 
 

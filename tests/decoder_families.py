@@ -24,7 +24,7 @@ import paddle_tpu as fluid
 from paddle_tpu.core import telemetry as _tm
 from paddle_tpu.models import dots_vlm, exaone_moe, glm_dsa, \
     granite_hybrid, kimi_linear, lfm2_moe, longcat_flash, nemotron_h, olmoe, \
-    smallthinker, solar_open2
+    smallthinker, solar_open2, xing4
 from paddle_tpu.serving import DecodeEngine
 from paddle_tpu.serving import decode_model as dm
 from paddle_tpu.serving import kv_cache as kvc
@@ -279,6 +279,15 @@ _SOLAR = dm.DecoderConfig(
     layer_types=("attention", "kda", "kda", "kda") * 2, kda_heads=4,
     kda_head_dim=8, kda_conv=4, kda_neg_eigval=True, ffn=24, shared_ffn=24,
     experts=16, experts_per_token=3)
+# dots.vlm1's mixer and feed-forwards (one group of experts, as published)
+# round four residual streams: 192 values a token, mixed eight times
+_XING = dm.DecoderConfig(
+    arch="xing4", vocab=97, layers=4, heads=4, head_dim=16, hidden_size=48,
+    max_seq=64, layer_types=("latent",) * 4, latent_rank=24, latent_rope=8,
+    q_rank=20, rope_scaling=dict(YARN, factor=64), norm_eps=1e-6,
+    dense_layers=1, dense_ffn=64, ffn=24, shared_ffn=24, experts=16,
+    experts_per_token=3, routed_scaling=2.0, hc_mult=4, hc_sinkhorn_iters=20,
+    hc_eps=1e-6, hc_clamp=(-30.0, 30.0))
 _GRANITE_G4 = _GRANITE.replace(kv_heads=1)
 
 # Weights are normal(0, 0.3) (OLMoE's 0.05) and a router bias of 0.05: at
@@ -423,6 +432,21 @@ ROWS = {row.arch: row for row in (
                     heads=8, kv_heads=2, hidden=48, ffn=24)),
         # 8 KV heads of 128 in bfloat16: 4,096 B a position
         chunk=("solar-open2-250b-serve.json", 25664, {"attention": 128})),
+    # every layer pages (one latent row a token): nothing is declined.
+    # ``phi`` normal(0, 0.17): over 192 values the maps' inputs then have
+    # the deviation the published 0.02 gives over 14,336 (2.4)
+    Row("xing4",
+        _both(_XING, xing4.init_params, std=0.3, bias_std=0.05, hc_std=0.17),
+        multi_atol=1e-5, batch_dependent_bf16=True,
+        entry=dict(attn_path="gather", experts_path={4: "einsum"},
+                   state_path={}, declines=None),
+        serve=("xing4.0-29b-a4b-serve.json",
+               dict(layer_types=_XING.layer_types, experts=16,
+                    experts_held=4, expert_first=4, experts_per_token=3,
+                    q_rank=20, latent_rank=24, latent_rope=8, hidden=48,
+                    ffn=24, hc_mult=4, hc_sinkhorn_iters=20)),
+        # one row of 640 bfloat16 a position: 1,280 B
+        chunk=("xing4.0-29b-a4b-serve.json", 3616, {"latent": 512})),
 )}
 assert tuple(ROWS) == dm.ARCHS
 

@@ -504,7 +504,7 @@ def test_config_refuses_what_no_block_computes():
     kimi = fam.ROWS["kimi_linear"].f32[0].to_dict()
     for changes in (dict(q_rank=8), dict(rope_scaling=fam.YARN)):
         with pytest.raises(ValueError,
-                           match=r"dots_vlm\|glm_dsa\|longcat_flash "
+                           match=r"dots_vlm\|glm_dsa\|longcat_flash\|xing4 "
                            r"blocks' latent"):
             dm.DecoderConfig(**dict(kimi, **changes))
     with pytest.raises(ValueError, match="exaone_moe|kimi_linear|dots_vlm"):
@@ -682,7 +682,8 @@ def test_the_latent_and_expert_rules_at_the_published_shapes():
                       "exaone_moe": 256, "nemotron_h": 928,
                       "kimi_linear": 512, "dots_vlm": 256,
                       "smallthinker": 768, "glm_dsa": 256,
-                      "longcat_flash": 256, "solar_open2": 256}
+                      "longcat_flash": 256, "solar_open2": 256,
+                      "xing4": 512}
 
 
 def test_routed_experts_kernel_in_chunks_of_an_eighth_of_the_width(

@@ -8,6 +8,7 @@ tests/test_paged_attention_kernel.py (one file a worker: the interpreter
 takes 5-25 s a case here); the selected read's forms in
 tests/test_glm_dsa.py."""
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -244,3 +245,46 @@ def test_the_latent_kernel_keeps_its_turns(interpreted, monkeypatch, order,
     assert np.array_equal(got, plain)
     np.testing.assert_allclose(got[lens > 0], ref[lens > 0], atol=2e-5,
                                rtol=2e-5)
+
+
+def test_a_models_latent_layers_share_one_traced_call(interpreted,
+                                                      monkeypatch):
+    """``_latent_call`` traces the kernel once a set of shapes: three layers
+    of a model (three pools of one shape) inside one jit call one cached
+    call; another scale, a mask, another chunk span (a test's shrunk
+    ``_CHUNK_BYTES``) or a swapped kernel is another key; and the cached
+    call's output is the gather's."""
+    heads, width, rank, block_size, maxb = 12, 256, 128, 16, 12
+    q, pool, tables, lens = _latent_case([40, 0, 97, 160], heads, width,
+                                         block_size, maxb, jnp.float32, 5)
+    pa._latent_call.cache_clear()
+    pools = [pool, pool * 0.5, pool * 2.0]
+
+    @jax.jit
+    def three(q, pools, tables, lens):
+        return [pa._latent_pallas(q, p, tables, lens, 0.1, rank)
+                for p in pools]
+
+    got = three(q, pools, tables, lens)
+    info = pa._latent_call.cache_info()
+    assert (info.misses, info.hits) == (1, 2)
+    for out, p in zip(got, pools):
+        ref = pa.latent_attention_reference(q, p, tables, lens, 0.1, rank)
+        np.testing.assert_allclose(np.asarray(out)[lens > 0],
+                                   np.asarray(ref)[lens > 0], atol=2e-5,
+                                   rtol=2e-5)
+    pa._latent_pallas(q, pool, tables, lens, 0.2, rank)
+    assert pa._latent_call.cache_info().misses == 2
+    monkeypatch.setattr(pa, "_CHUNK_BYTES", 64 * width * 4)
+    pa._latent_pallas(q, pool, tables, lens, 0.2, rank)
+    assert pa._latent_call.cache_info().misses == 3
+    kernel = pa._latent_kernel
+    monkeypatch.setattr(pa, "_latent_kernel",
+                        lambda *a, **kw: kernel(*a, **kw))
+    again = pa._latent_pallas(q, pool, tables, lens, 0.2, rank)
+    assert pa._latent_call.cache_info().misses == 4
+    np.testing.assert_allclose(
+        np.asarray(again)[lens > 0], np.asarray(pa.latent_attention_reference(
+            q, pool, tables, lens, 0.2, rank))[lens > 0], atol=2e-5,
+        rtol=2e-5)
+    pa._latent_call.cache_clear()

@@ -339,12 +339,12 @@ def test_config_refuses_what_no_block_computes():
                                 head_dim=8))
     with pytest.raises(ValueError, match="exaone_moe\\|nemotron_h\\|"
                        "kimi_linear\\|dots_vlm\\|glm_dsa\\|longcat_flash\\|"
-                       "solar_open2 blocks may hold experts"):
+                       "solar_open2\\|xing4 blocks may hold experts"):
         dm.DecoderConfig(arch="lfm2_moe", layer_types=["attention"] * 2,
                          experts_held=4, **base)
     with pytest.raises(ValueError, match="exaone_moe\\|nemotron_h\\|"
                        "kimi_linear\\|dots_vlm\\|smallthinker\\|glm_dsa\\|"
-                       "longcat_flash\\|solar_open2 may say"):
+                       "longcat_flash\\|solar_open2\\|xing4 may say"):
         dm.DecoderConfig(arch="lfm2_moe", layer_types=["attention"] * 2,
                          hidden_size=24, **base)
     cfg = dm.DecoderConfig(

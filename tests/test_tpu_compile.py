@@ -948,6 +948,78 @@ def test_dots_vlm_step_compiles_its_two_kernels_at_published_shapes(
     assert not found, found
 
 
+def test_xing4_step_compiles_its_mixings_round_two_kernels_at_the_cells_shapes(
+        one_chip, as_on_tpu):
+    """Xing4.0-29B-A4B's published widths, its dense lead and two routed
+    layers (four of its 40 layers: eight mixings), bucket 32, the cell's
+    pool (3,616 bf16 latent blocks whose rows of 576 values lie 640 wide):
+    Mosaic accepts, inside the whole step as the engine compiles it
+    (``make_packed_step``), the latent kernel at 32 heads x 32 lanes (every
+    lane's query and output in VMEM at once: no lane grid) and the
+    routed-expert kernel over 8 held experts of 3584 x 1024 in chunks of 512
+    columns; the mixings are XLA's (no kernel of their own, no loop: the
+    Sinkhorn iterations unrolled), their parameters float32; every pool is
+    aliased whole and no pool or expert tensor is copied, turned or
+    converted."""
+    from benchmark.models import xing4_decoder
+    from paddle_tpu.pallas_kernels import moe_experts as moe
+    from paddle_tpu.pallas_kernels import paged_attention as pa
+
+    with open(os.path.join(ROOT, "benchmark", "configs",
+                           "xing4.0-29b-a4b-serve.json")) as fp:
+        config = dict(json.load(fp), num_hidden_layers=4)
+    cfg = xing4_decoder.decoder_config(config)
+    assert (cfg.hidden, cfg.heads, cfg.head_dim, cfg.latent_rank,
+            cfg.latent_rope, cfg.q_rank, cfg.experts, cfg.experts_held,
+            cfg.experts_per_token, cfg.n_group, cfg.ffn, cfg.dense_ffn,
+            cfg.hc_mult, cfg.hc_sinkhorn_iters, cfg.hc_eps, cfg.hc_clamp,
+            cfg.mixings, cfg.layer_types, cfg.routed_layers) == (
+        3584, 32, 128, 512, 64, 768, 64, 8, 4, 1, 1024, 9216, 4, 20, 1e-6,
+        (-30.0, 30.0), 8, ("latent",) * 4, (2, 3))
+    lanes, block_size, blocks = 32, 16, 3616
+    kv = dm.cache_config(cfg, block_size, blocks)
+    assert (kv.layers, kv.latent_layers, kv.latent_row, kv.state_layers) \
+        == (0, 4, 640, 0)
+    assert dm.attention_path(cfg, kv, lanes, "latent") == "pallas"
+    assert dm.chunk_positions(cfg, kv, lanes) == {"latent": 512}
+    assert not pa._latent_lane_grid((32, 32, 640), (3616, 16, 640),
+                                    jnp.bfloat16, 512)
+    assert moe.experts_path(lanes, (8, 3584, 1024), jnp.bfloat16) == "pallas"
+    assert dm.experts_chunk(cfg) == 512
+
+    on_chip = functools.partial(_placed, one_chip)
+    carry = on_chip(jax.eval_shape(lambda: PagedKVCache(kv).carry()))
+    # the mixings' parameters are float32 whatever the weights' dtype
+    shapes = xing4_decoder.param_shapes(config)
+    params = on_chip(jax.eval_shape(lambda p: dm.laid_out(cfg, p), {
+        name: jax.ShapeDtypeStruct(
+            shape, jnp.float32 if kind.startswith("hc_") else jnp.bfloat16)
+        for name, (shape, kind) in shapes.items()}))
+    assert params["l3_hc_mlp_phi"].shape == (4 * 3584, 24) \
+        and params["l3_hc_mlp_phi"].dtype == jnp.float32
+    feeds = on_chip(_packed_feeds(kv, lanes, cfg.max_seq // block_size))
+    compiled = jax.jit(dm.make_packed_step(cfg, kv, lanes),
+                       donate_argnums=(0,)
+                       ).lower(carry, params, *feeds).compile()
+
+    text = compiled.as_text()
+    assert _kernel_calls(text) == 6             # 4 latent, 2 experts
+    assert len(re.findall(r"%latent_attention\S* = ", text)) == 4
+    assert _expert_kernels(text) == 2
+    assert not re.findall(r" while\(", text)
+    assert not _expert_passes(text, 8, 3584, 1024)
+    assert _weights_relaid(text) == []
+    pool_bytes = sum(int(np.prod(a.shape)) * a.dtype.itemsize for a in carry)
+    assert pool_bytes == 4 * 3616 * 16 * 640 * 2
+    memory = compiled.memory_analysis()
+    assert memory.alias_size_in_bytes >= pool_bytes
+    assert memory.temp_size_in_bytes < 3616 * 16 * 640 * 2 / 2
+    big = re.compile(r" = bf16\[3616,16,640\]\S* (copy|transpose|convert)\(")
+    found = [line.strip()[:160] for line in text.splitlines()
+             if big.search(line)]
+    assert not found, found
+
+
 def test_glm_dsa_step_compiles_its_three_kernels_at_published_shapes(
         one_chip, as_on_tpu):
     """GLM-5's published widths, its dense lead and two routed layers, bucket

@@ -25,6 +25,14 @@ writes under ``chiprun_out/``, one JSON object:
     chiprun -- python tools/decode_step_probe.py --config olmoe-1b-7b-serve \
         --blocks 2048
 
+For a model of several residual streams (``--config xing4.0-29b-a4b-serve
+--blocks 3616 --contexts 200-1700``) the scopes gain ``layerN/hc/attn_maps``,
+``attn_read``, ``attn_merge`` and ``mlp_*`` alike round ``latent/*``, ``mlp``
+and ``moe/*``, and the result gives ``residual_streams``: the mixings' ms a
+step by scope beside the kernels', their share of the busy time, the bytes
+they must move (``benchmark/xing_cost.py``) and bytes/s, and how many
+fusions the compiled step runs under them.
+
 For a configuration with state-space layers (``--config
 granite-4.0-h-micro-serve --blocks 2048``) every lane holds a state slot,
 the scopes gain ``layerN/ssm/in_proj``, ``conv``, ``state_update`` and
@@ -262,7 +270,10 @@ def scope_of(op_name):
     ``kv_read``, ``gate``, ``out`` among them).  A recurrent layer's window
     moves under ``window`` inside its mixer's scope, ``kda/conv/window`` and
     ``ssm/conv/window``; a ``conv`` layer's mixer names its own scope so,
-    and the two are one: ``conv/window``."""
+    and the two are one: ``conv/window``.  A model of several residual
+    streams mixes them under ``layerN/hc/`` + ``attn_maps``, ``attn_read``,
+    ``attn_merge`` and ``mlp_*`` alike (``hc``: ``hc/start`` and
+    ``hc/sum``)."""
     parts = [p for p in op_name.split("/") if not p.startswith("jit(")]
     parts = [re.sub(r"^layer\d+$", "layerN", p) for p in parts]
     keep = [p for p in parts
@@ -271,7 +282,11 @@ def scope_of(op_name):
                      "ssm", "in_proj", "conv", "state_update", "out_proj",
                      "window", "kda", "state", "out", "latent", "absorb",
                      "shared", "q_compress", "rope", "staged", "index",
-                     "select", "mask", "zero", "attention", "qkv", "gate")]
+                     "select", "mask", "zero", "attention", "qkv", "gate",
+                     # a model of several residual streams: a sublayer's
+                     # mixing (``hc`` alone: the two ends of the streams)
+                     "hc", "attn_maps", "attn_read", "attn_merge",
+                     "mlp_maps", "mlp_read", "mlp_merge")]
     keep = [p for i, p in enumerate(keep) if not i or p != keep[i - 1]]
     return "/".join(keep) or "other"
 
@@ -519,6 +534,26 @@ def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
         [n, round(s * 1e3 / args.steps, 4),
          "/".join(index.get(short(n), ("", "", ""))[::2])[:120]]
         for n, s in prof["device_ops"]]
+    hc_ms = {k: v for k, v in scopes.items() if "hc" in k.split("/")}
+    if cfg.mixings and hc_ms:
+        # the residual streams' mixings beside the kernels': their time by
+        # scope, what they must move (``benchmark/xing_cost.py``: every
+        # mixing's parameters and the lanes' streams three times a mixing)
+        # and how near its floor that runs
+        from benchmark import xing_cost
+
+        moved = xing_cost.hc_floor_bytes_per_step(config, b)
+        total = sum(hc_ms.values())
+        result["residual_streams"] = {
+            "streams": cfg.hc_mult, "mixings": cfg.mixings,
+            "sinkhorn_iters": cfg.hc_sinkhorn_iters,
+            "hc_ms_per_step": total, "hc_ms_by_scope": hc_ms,
+            "hc_share_of_busy": total / result["busy_ms_per_step"],
+            "hc_bytes_per_step": moved,
+            "hc_bytes_per_s": moved / (total / 1e3),
+            # the fusions the compiled step runs under the scopes
+            "hc_fusions": sum(op == "fusion" and "/hc/" in scope
+                              for op, _shape, scope in index.values())}
     moe_ms = sum(v for k, v in scopes.items() if k.endswith("experts"))
     if cfg.routed_layers and moe_ms:
         # the experts this form reads in a routed layer, once a step:
