@@ -31,6 +31,20 @@ exponentiated) and the two mixings as sums of products on the vector unit.
 The Sinkhorn normalisation is unrolled: ``cfg.hc_sinkhorn_iters`` is a
 constant of the model and a step has no loop.
 
+``maps`` picks its path from what it can see, with no flag, as
+``ssm_update.state_update`` does: **the kernel**
+(``pallas_kernels/hc_maps.py``: a sublayer's maps one device operation) on a
+TPU backend where its shape rule admits the step (``maps_path``), and
+``maps_reference``, the lines above in jnp, everywhere else.  XLA makes of
+the jnp form a reduce, a product and, because it fuses none of the Sinkhorn's
+normalisations with the next, two small fusions an iteration and direction:
+81 operations a mixing at the published 20 iterations.  The kernel reads
+``phi`` as ``[2 n + n^2, n C]``, and is handed the published array turned:
+the chip holds ``f32[n C, 2 n + n^2]`` with its long axis minor
+(``{0,1:T(8,128)}``: 24 columns would fill a fifth of a 128-lane tile), which
+is ``[2 n + n^2, n C]`` row by row, so the turn is a bitcast in the compiled
+step and no layout at load is needed (tests/test_tpu_compile.py holds it).
+
 ``width``, ``param_shapes``, ``param_bytes`` and ``stream_bytes`` are what
 the block's ``param_shapes``, ``decode_model.StepAccount`` and the
 benchmark's cost file count by.
@@ -39,8 +53,12 @@ benchmark's cost file count by.
 import jax
 import jax.numpy as jnp
 
+from ..pallas_kernels import adoption
+from ..pallas_kernels import hc_maps as _kernel
+
 __all__ = ["width", "param_shapes", "param_bytes", "stream_bytes", "maps",
-           "read", "merge", "start", "total", "sinkhorn"]
+           "maps_reference", "maps_path", "read", "merge", "start", "total",
+           "sinkhorn"]
 
 F32_BYTES = 4
 
@@ -105,9 +123,21 @@ def _flat_norm(X, eps):
         jnp.mean(jnp.square(flat), axis=-1, keepdims=True) + eps)
 
 
-def maps(cfg, phi, b, a, X):
-    """X [B, n, C] float32 -> (H_pre [B, n], H_post [B, n], H_res [B, n,
-    n]) by one sublayer's ``phi``, ``b`` and ``a`` (float32)."""
+def maps_path(cfg, lanes):
+    """``"pallas"`` where the kernel makes the maps of a step of ``lanes``
+    lanes of this model on this backend, else ``"xla"`` (the jnp form);
+    None for a model of one stream.  The engine names the step's path by
+    it: in the executable's cache key, on the ``serving_prewarm`` event and
+    on the step's span."""
+    if not cfg.hc_mult:
+        return None
+    return _kernel.maps_path(cfg.hc_mult, cfg.hidden, lanes)
+
+
+def maps_reference(cfg, phi, b, a, X):
+    """``maps`` in jnp, whatever the backend: the flattened norm, the
+    projection at the highest matmul precision and the unrolled
+    normalisation."""
     n = cfg.hc_mult
     lo, hi = cfg.hc_clamp
     f32 = jnp.float32
@@ -120,6 +150,20 @@ def maps(cfg, phi, b, a, X):
     res = jnp.exp(jnp.clip(a[2] * proj[:, 2 * n:] + b[2 * n:], lo, hi))
     return pre, post, sinkhorn(res.reshape(-1, n, n), cfg.hc_sinkhorn_iters,
                                cfg.hc_eps)
+
+
+def maps(cfg, phi, b, a, X):
+    """X [B, n, C] float32 -> (H_pre [B, n], H_post [B, n], H_res [B, n,
+    n]) by one sublayer's ``phi``, ``b`` and ``a`` (float32).  The kernel
+    where the shape rule admits it (``adoption.decide`` counts the lowering
+    under ``pallas_kernel_used_total`` / ``..._fallback_total{reason}``),
+    ``maps_reference`` otherwise."""
+    lanes, n, hidden = X.shape
+    use, _reason = adoption.decide(
+        "hc_maps", _kernel.hc_maps_checks(n, hidden, lanes, X.dtype))
+    if not use:
+        return maps_reference(cfg, phi, b, a, X)
+    return _kernel.maps(cfg, phi.T, b, a, X)
 
 
 def read(pre, X):

@@ -78,7 +78,8 @@ B_RES_DIAGONAL = 1.0
 
 routed_part = _exaone.routed_part
 shared_part = _exaone.shared_part
-# every layer's ``wkvb`` as ``latent_mixer`` multiplies it
+# every layer's ``wkvb`` as ``latent_mixer`` multiplies it (a mixing's
+# ``phi`` needs no layout of its own: ``hyper_connections`` says why)
 laid_out = _kimi.laid_out
 
 

@@ -9,7 +9,7 @@ whether it engages.  A family is chosen from what the lowering can observe
   checks live beside the kernel that owns them (``fused_ln_checks``,
   ``flash_attention_checks``, ``paged_attention_checks``,
   ``ssm_update_checks``, ``moe_experts_checks``,
-  ``latent_attention_checks``, ``kda_update_checks``).
+  ``latent_attention_checks``, ``kda_update_checks``, ``hc_maps_checks``).
 * **telemetry** — every decision increments
   ``pallas_kernel_used_total{kernel}`` or
   ``pallas_kernel_fallback_total{kernel,reason}`` in the telemetry
@@ -44,7 +44,8 @@ __all__ = ["decide", "active_kernels", "reset", "interpret_mode",
 
 # the kernel families sharing this funnel
 KERNELS = ("fused_ln", "flash_attention", "paged_attention", "ssm_update",
-           "moe_experts", "latent_attention", "kda_update", "index_scores")
+           "moe_experts", "latent_attention", "kda_update", "index_scores",
+           "hc_maps")
 
 _lock = threading.Lock()
 _active = set()          # kernels that engaged >= 1 time this process

@@ -1064,6 +1064,7 @@ class StepAccount:
         self.maxb = -(-cfg.max_seq // kv_config.block_size)
         self.attn_path = self.window_path = self.index_path = None
         self.experts_path, self.state_path, self.selected_read = {}, {}, {}
+        self.hc_maps_path = {}
         self.key_parts = {}
         # bucket -> the positions a chunk of the attention kernel spans, by
         # kind of layer that takes it
@@ -1316,18 +1317,24 @@ class StepAccount:
 
     def _streams(self):
         """The residual path of a block that carries ``hc_mult`` streams a
-        token: how many, how often they are normalised, the sublayers that
-        mix them and what those mixings move of the live lanes' streams
-        (``hyper_connections.stream_bytes``, which the benchmark's cost file
-        is held to)."""
+        token: how many, how often they are normalised, how a sublayer's
+        maps are made a bucket (``"pallas"``: one kernel a mixing | ``"xla"``:
+        the jnp form), the sublayers that mix them and what those mixings
+        move of the live lanes' streams (``hyper_connections.stream_bytes``,
+        which the benchmark's cost file is held to)."""
         cfg = self.cfg
         said = {"residual_streams": cfg.hc_mult,
                 "hc_sinkhorn_iters": cfg.hc_sinkhorn_iters}
         self._said.update(said)
         self.key_parts.update(said)
+        for b in self.buckets:
+            self.hc_maps_path[b] = self._said_at[b]["hc_maps_path"] \
+                = _hc.maps_path(cfg, b)
+        self.key_parts["hc_maps"] = sorted(self.hc_maps_path.items())
 
         def read(bucket, lens, attrs):
             attrs.update(
+                hc_maps_path=self.hc_maps_path[bucket],
                 hc_streams=cfg.hc_mult, hc_mixings=cfg.mixings,
                 hc_stream_bytes=_hc.stream_bytes(
                     cfg.hc_mult, cfg.hidden, cfg.mixings,

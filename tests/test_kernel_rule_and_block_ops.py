@@ -35,6 +35,7 @@ from paddle_tpu.ops import manip as manip_ops
 from paddle_tpu.ops import nn as nn_ops
 from paddle_tpu.pallas_kernels import adoption
 from paddle_tpu.pallas_kernels import fused_ln
+from paddle_tpu.pallas_kernels import hc_maps
 from paddle_tpu.pallas_kernels import kda_update
 from paddle_tpu.pallas_kernels.flash_attention import flash_attention_checks
 from paddle_tpu.pallas_kernels import paged_attention as pa
@@ -102,6 +103,8 @@ _ELIGIBLE = {
     # heads a lane
     "index_scores": lambda: pa.index_scores_checks(
         (32, 32, 128), (25120, 16, 128), "bfloat16", 784),
+    # Xing4.0's mixing: 4 streams of 3,584 at the cell's 32 lanes
+    "hc_maps": lambda: hc_maps.hc_maps_checks(4, 3584, 32),
 }
 
 RULE = {
@@ -220,6 +223,30 @@ RULE = {
     "ssm_update-empty": (
         "ssm_update", lambda: ssm_update.ssm_update_checks(
             (33, 128, 4096), "float32", 0), True, "empty"),
+    "hc_maps-backend": (
+        "hc_maps", _ELIGIBLE["hc_maps"], False, "backend"),
+    "hc_maps-symbolic_shape": (
+        "hc_maps", lambda: hc_maps.hc_maps_checks(4, 3584, None), True,
+        "symbolic_shape"),
+    "hc_maps-dtype": (
+        # the streams are float32 in the configuration: a bfloat16 one is
+        # another model, and the jnp form's
+        "hc_maps", lambda: hc_maps.hc_maps_checks(4, 3584, 32, "bfloat16"),
+        True, "dtype"),
+    "hc_maps-empty": (
+        "hc_maps", lambda: hc_maps.hc_maps_checks(4, 3584, 0), True,
+        "empty"),
+    "hc_maps-lanes": (
+        # a stream's columns of phi are whole 128-lane tiles: 48 is none
+        "hc_maps", lambda: hc_maps.hc_maps_checks(4, 48, 32), True, "lanes"),
+    "hc_maps-sublanes": (
+        # 3 streams make 15 maps' rows: no whole sublane tile
+        "hc_maps", lambda: hc_maps.hc_maps_checks(3, 3584, 32), True,
+        "sublanes"),
+    "hc_maps-vmem": (
+        # 1,024 lanes' streams are 59e6 B beside a phi of 1.4e6
+        "hc_maps", lambda: hc_maps.hc_maps_checks(4, 3584, 1024), True,
+        "vmem"),
     "moe_experts-relu2_backend": (
         "moe_experts", lambda: moe_experts.relu2_checks(
             32, (16, 1856, 2688), "bfloat16"), False, "backend"),

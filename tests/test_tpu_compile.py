@@ -957,11 +957,16 @@ def test_xing4_step_compiles_its_mixings_round_two_kernels_at_the_cells_shapes(
     (``make_packed_step``), the latent kernel at 32 heads x 32 lanes (every
     lane's query and output in VMEM at once: no lane grid) and the
     routed-expert kernel over 8 held experts of 3584 x 1024 in chunks of 512
-    columns; the mixings are XLA's (no kernel of their own, no loop: the
-    Sinkhorn iterations unrolled), their parameters float32; every pool is
-    aliased whole and no pool or expert tensor is copied, turned or
-    converted."""
+    columns; since PR 68 a mixing's maps are one kernel too (``hc_maps``,
+    eight here: the streams ``[4, 32, 3584]`` and a ``phi`` read as ``[24,
+    14336]`` whole in VMEM, the product at the highest precision, the
+    Sinkhorn iterations unrolled in it), under the scope the benchmark finds
+    its time by, and XLA makes no more than ten operations of the rest of a
+    mixing's maps (81 before); the read and the merge are XLA's, no loop,
+    the parameters float32; every pool is aliased whole and no pool, expert
+    tensor or ``phi`` is copied, turned or converted."""
     from benchmark.models import xing4_decoder
+    from paddle_tpu.models import hyper_connections as hc
     from paddle_tpu.pallas_kernels import moe_experts as moe
     from paddle_tpu.pallas_kernels import paged_attention as pa
 
@@ -997,15 +1002,39 @@ def test_xing4_step_compiles_its_mixings_round_two_kernels_at_the_cells_shapes(
         for name, (shape, kind) in shapes.items()}))
     assert params["l3_hc_mlp_phi"].shape == (4 * 3584, 24) \
         and params["l3_hc_mlp_phi"].dtype == jnp.float32
+    assert hc.maps_path(cfg, lanes) == hc.maps_path(cfg, 64) == "pallas"
     feeds = on_chip(_packed_feeds(kv, lanes, cfg.max_seq // block_size))
     compiled = jax.jit(dm.make_packed_step(cfg, kv, lanes),
                        donate_argnums=(0,)
                        ).lower(carry, params, *feeds).compile()
 
     text = compiled.as_text()
-    assert _kernel_calls(text) == 6             # 4 latent, 2 experts
+    assert _kernel_calls(text) == 14    # 4 latent, 2 experts, 8 mixings
     assert len(re.findall(r"%latent_attention\S* = ", text)) == 4
     assert _expert_kernels(text) == 2
+    mixed = re.findall(r"%hc_maps\S* = .*?op_name=\"([^\"]*)\"", text)
+    assert sorted(re.sub(r".*/(layer\d/hc/\w+_maps)/.*", r"\1", scope)
+                  for scope in mixed) == sorted(
+        "layer%d/hc/%s_maps" % (l, sub) for l in range(4)
+        for sub in ("attn", "mlp"))
+    # what XLA still runs under a mixing's maps beside the kernel
+    entry = text[text.index("\nENTRY "):]
+    under = [line for line in entry.splitlines()
+             if re.search(r" (fusion|copy|slice|reduce|transpose)\(", line)
+             and re.search(r"layer2/hc/attn_maps/", line)]
+    assert 0 < len(under) < 10, under
+    # the kernel reads ``phi`` as [24, 14336], and the published [14336, 24]
+    # needs no layout at load for it: the chip holds that array with its
+    # long axis minor, which is [24, 14336] row by row, so the turn is a
+    # bitcast of what XLA fetched ahead of the call
+    assert re.findall(r"%params__l2_hc_attn_phi\S* = f32\[14336,24\]"
+                      r"\{0,1:T\(8,128\)\} parameter", text)
+    call = re.search(r"%hc_maps\S* = f32\[24,32\]\S* custom-call\("
+                     r"%[\w.\-]+, %(bitcast[\w.\-]*), ", text)
+    assert call, "the kernel's phi is no bitcast"
+    turned = re.search(r"%%%s = f32\[24,14336\]\{1,0:T\(8,128\)S\(1\)\} "
+                       r"bitcast\(" % re.escape(call.group(1)), text)
+    assert turned, "phi is not handed over in VMEM as fetched"
     assert not re.findall(r" while\(", text)
     assert not _expert_passes(text, 8, 3584, 1024)
     assert _weights_relaid(text) == []

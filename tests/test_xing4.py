@@ -610,8 +610,10 @@ def test_the_paged_step_on_two_kernels_gives_the_jnp_steps_tokens(
         return fed, logits
 
     on_kernels = run()
+    # (since PR 68 a mixing's maps are a kernel too: its own cases, and
+    # this step's twin on that kernel alone, are tests/test_hc_maps.py's)
     assert set(adoption.active_kernels()) == {"latent_attention",
-                                              "moe_experts"}
+                                              "moe_experts", "hc_maps"}
     os.environ.pop("PADDLE_PALLAS_INTERPRET")
     assert dm.attention_path(cfg, kv, 2, "latent") == "gather"
     plain = run()

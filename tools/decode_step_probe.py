@@ -18,7 +18,8 @@ writes under ``chiprun_out/``, one JSON object:
 * ``scope_ms_per_step``: device time per step by ``jax.named_scope``
   (``layer<i>`` folded to ``layerN``), from a profile of ``--steps`` steps
   reduced by ``benchmark/trace_reduce.py`` and keyed through the HLO's
-  ``op_name`` metadata; ``top_ops`` names the dearest single instructions
+  ``op_name`` metadata (``device_ops_per_step``: how many instructions the
+  profile saw run a step); ``top_ops`` names the dearest single instructions
   and ``unscoped_ops`` the dearest of those under no scope (``other``).
 
     chiprun -- python tools/decode_step_probe.py --blocks 1024 --bucket 32
@@ -30,8 +31,12 @@ For a model of several residual streams (``--config xing4.0-29b-a4b-serve
 ``attn_read``, ``attn_merge`` and ``mlp_*`` alike round ``latent/*``, ``mlp``
 and ``moe/*``, and the result gives ``residual_streams``: the mixings' ms a
 step by scope beside the kernels', their share of the busy time, the bytes
-they must move (``benchmark/xing_cost.py``) and bytes/s, and how many
-fusions the compiled step runs under them.
+they must move (``benchmark/xing_cost.py``) and bytes/s, how many
+fusions the compiled step runs under them and one middle layer's mixings an
+operation at a time (``hc_ops``); ``hc_maps_path`` says how a mixing's maps
+were made, and ``--hc-maps xla`` swaps the kernel (one operation a mixing)
+for the jnp form the step takes off the TPU: the comparison the kernel was
+adopted by.
 
 For a configuration with state-space layers (``--config
 granite-4.0-h-micro-serve --blocks 2048``) every lane holds a state slot,
@@ -291,6 +296,23 @@ def scope_of(op_name):
     return "/".join(keep) or "other"
 
 
+def hc_ops(op_seconds, index, short, layer, steps, top=24):
+    """The operations of layer ``layer``'s two mixings by the profile:
+    [[scope under the layer, us an execution, opcode and shape], ...] the
+    dearest ``top`` of them, then how many the others are and their sum."""
+    under = "layer%d/hc/" % layer
+    found = []
+    for name, secs in op_seconds.items():
+        op, shape, scope = index.get(short(name), ("", "", ""))
+        if under in scope:
+            found.append([scope.split(under)[1], round(secs * 1e6 / steps, 3),
+                          (op + " " + shape)[:72]])
+    found.sort(key=lambda row: -row[1])
+    rest = found[top:]
+    return found[:top] + [["others: %d" % len(rest),
+                           round(sum(row[1] for row in rest), 3), ""]]
+
+
 def pool_sized(index, pool_elems):
     """Instructions whose result is at least one layer pool, by opcode."""
     found = {}
@@ -482,6 +504,9 @@ def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
         "state_update_columns": said.get("state_update_columns")
         if args.ssm_update == "step" else None,
         "window_attention": said.get("window_attention"),
+        # how a mixing's maps are made: the rule's word, the control's
+        "hc_maps_path": said.get("hc_maps_path")
+        if args.hc_maps == "step" else args.hc_maps,
         "pallas_kernel_counters": {
             key: value for key, value in telemetry.snapshot()["counters"].items()
             if key.startswith("pallas_kernel_")},
@@ -523,6 +548,8 @@ def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
         if key == "other":
             unscoped[name] = secs * 1e3 / args.steps
     result["busy_ms_per_step"] = prof["busy_s"] * 1e3 / args.steps
+    # the operations the device runs a step: each instruction runs once
+    result["device_ops_per_step"] = len(prof["op_seconds"])
     result["scope_ms_per_step"] = dict(
         sorted(scopes.items(), key=lambda kv: -kv[1]))
     # what lies under no scope, dearest first: [name, ms a step, opcode and
@@ -544,6 +571,8 @@ def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
 
         moved = xing_cost.hc_floor_bytes_per_step(config, b)
         total = sum(hc_ms.values())
+        fusions = sum(op == "fusion" and "/hc/" in scope
+                      for op, _shape, scope in index.values())
         result["residual_streams"] = {
             "streams": cfg.hc_mult, "mixings": cfg.mixings,
             "sinkhorn_iters": cfg.hc_sinkhorn_iters,
@@ -551,9 +580,14 @@ def probe_step(args, form, reads_all, config, cfg, kv, cache, device, feed):
             "hc_share_of_busy": total / result["busy_ms_per_step"],
             "hc_bytes_per_step": moved,
             "hc_bytes_per_s": moved / (total / 1e3),
-            # the fusions the compiled step runs under the scopes
-            "hc_fusions": sum(op == "fusion" and "/hc/" in scope
-                              for op, _shape, scope in index.values())}
+            # the fusions the compiled step runs under the scopes (maps,
+            # read and merge), and a mixing
+            "hc_fusions": fusions,
+            "hc_fusions_a_mixing": fusions / cfg.mixings,
+            # one middle layer's mixings an operation at a time, dearest
+            # first: [scope, us an execution, opcode and shape]
+            "hc_ops": hc_ops(prof["op_seconds"], index, short,
+                             cfg.layers // 2, args.steps)}
     moe_ms = sum(v for k, v in scopes.items() if k.endswith("experts"))
     if cfg.routed_layers and moe_ms:
         # the experts this form reads in a routed layer, once a step:
@@ -748,6 +782,10 @@ def main(argv=None):
     ap.add_argument("--ssm-update", default="step", choices=("step", "xla"),
                     help="xla: the state update as gather, update, scatter "
                     "(what the step does off the TPU) in place of the kernel")
+    ap.add_argument("--hc-maps", default="step", choices=("step", "xla"),
+                    help="xla: a mixing's maps in jnp (what the step does off "
+                    "the TPU: 81 fusions a mixing at 20 Sinkhorn iterations) "
+                    "in place of the kernel")
     ap.add_argument("--latent-read", default="served",
                     choices=("served", "gathered", "dense"),
                     help="a control of a selecting model's read: gathered, "
@@ -824,6 +862,10 @@ def main(argv=None):
 
         ssm_update.state_update = ssm_update.state_update_reference
         kda_update.state_update = kda_update.state_update_reference
+    if args.hc_maps == "xla":
+        from paddle_tpu.models import hyper_connections
+
+        hyper_connections.maps = hyper_connections.maps_reference
     args.dtype = args.dtype or cfg.kv_dtype or "f32"
     # a state slot a lane and the scratch, for a model with recurrent layers
     kv = dm.cache_config(cfg, args.block_size, args.blocks, args.dtype,

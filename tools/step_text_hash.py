@@ -95,7 +95,8 @@ LATENT_CELLS = {"kimi_latent": 32, "dots_latent": 128}
 
 
 def tpu_kernels():
-    """The attention kernels lowered FOR THE TPU: the head of Mosaic's
+    """The attention kernels, and the kernel of a mixing's maps, lowered FOR
+    THE TPU: the head of Mosaic's
     module in each custom call, printed without source locations (the call
     holds it as bytecode with the files' paths and lines, which differ
     between two trees)."""
@@ -144,6 +145,18 @@ def tpu_kernels():
                 lambda q, p, t, l: pa.latent_attention(q, p, t, l, 0.1, 512),
                 spec((32, h, 640), f32), spec((12832, 16, 640), jnp.bfloat16),
                 spec((32, 802), i32), spec((32,), i32))
+        # a mixing's maps at Xing4.0's cell: 4 streams of 3,584, 32 lanes
+        import types
+
+        from paddle_tpu.models import hyper_connections as hc
+
+        mixing = types.SimpleNamespace(
+            hc_mult=4, hidden=3584, hc_sinkhorn_iters=20, hc_eps=1e-6,
+            norm_eps=1e-6, hc_clamp=(-30.0, 30.0))
+        out["xing_hc_maps"] = kernel_head(
+            lambda phi, b, a, x: hc.maps(mixing, phi, b, a, x),
+            spec((4 * 3584, 24), f32), spec((24,), f32), spec((3,), f32),
+            spec((32, 4, 3584), f32))
     finally:
         jax.default_backend = backend
         reg.mosaic.lower_module_to_custom_call = lower
