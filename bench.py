@@ -128,9 +128,17 @@ def _telemetry_stats():
                              if _TIMED_RECOMPILES is not None
                              else telemetry.counter_total(
                                  "executor_cache_miss_total"))}
-    cold = sum(hists.get(k, {}).get("sum", 0.0)
-               for k in ("executor_trace_lower_ms", "executor_xla_compile_ms"))
-    warm = hists.get("compile_cache_load_ms", {}).get("sum", 0.0)
+    # the durations live on the set-up spans (main() arms the recorder
+    # beside the registry): trace + lower + XLA on ``executor.compile``,
+    # the read and ``deserialize_and_load`` on ``executor.cache_restore``
+    from paddle_tpu.core import tracing
+
+    cold = sum(s["attrs"].get("lower_ms", 0.0)
+               + s["attrs"].get("backend_ms", 0.0)
+               for s in tracing.records("executor.compile"))
+    warm = sum(s["dur"] / 1e3
+               for s in tracing.records("executor.cache_restore")
+               if s["attrs"].get("hit"))
     out["compile_ms_cold"] = round(cold, 1)
     out["compile_ms_warm"] = round(warm, 1)
     comp = hists.get("executor_compile_ms")
@@ -588,6 +596,9 @@ def main():
     # read FLAGS_* env at import time).  BENCH_TELEMETRY=0 opts out.
     if os.environ.get("BENCH_TELEMETRY", "1") == "1":
         os.environ.setdefault("FLAGS_telemetry", "1")
+        # the compile story's durations are span attributes; with no
+        # FLAGS_telemetry_dir the records stay in memory
+        os.environ.setdefault("FLAGS_tracing", "1")
     # persistent two-tier compilation cache, placed by the one resolver:
     # $JAX_COMPILATION_CACHE_DIR, else FLAGS_compile_cache_dir, else the
     # fixed in-checkout path — so a repeat of the same config pays

@@ -5,6 +5,12 @@ Usage mirrors the reference (``import paddle.fluid as fluid`` becomes
 Executor on CPUPlace/TPUPlace.  Execution lowers whole blocks to XLA via JAX.
 """
 
+import time as _time
+
+# `import paddle_tpu`, first line to last: the ``setup.import`` span
+# (core/tracing.py ``imported``), which no flag can cover
+_import_started = (_time.time(), _time.perf_counter())
+
 import jax as _jax
 
 # Make every in-trace random draw a pure function of (key, global element
@@ -163,3 +169,7 @@ class DataFeedDesc:
                       "  }"]
         lines.append("}")
         return "\n".join(lines)
+
+
+tracing.imported(_import_started[0],
+                 (_time.perf_counter() - _import_started[1]) * 1e3)

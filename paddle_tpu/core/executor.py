@@ -36,6 +36,7 @@ import contextlib
 import threading
 
 _RNG_COUNTER_LOCK = threading.Lock()
+_STEADY = contextlib.nullcontext()   # what a cache-hit step runs under
 
 # trace-affecting flags must key the cache: a cached executable baked the
 # flag value it was traced under, and flipping the flag without a cache miss
@@ -233,19 +234,20 @@ def aot_compile_cached(jfn, args, disk_key, devices, meta=None):
         rspan = _tracing.start_span("executor.cache_restore",
                                     key=disk_key[:12])
         got = _cc.load(disk_key)
+        t_read = time.perf_counter()
+        rspan.annotate(read_ms=round((t_read - t0) * 1e3, 3))
         if got is not None:
+            rspan.annotate(payload_bytes=len(got["payload"]))
             try:
                 from jax.experimental import serialize_executable as _se
 
-                with mkctx():
+                with mkctx(), _tracing.phase("executor.cache_load"):
                     compiled = _se.deserialize_and_load(
                         got["payload"], got["in_tree"], got["out_tree"],
                         execution_devices=devices)
                 cstats["source"] = "disk"
-                if tel:
-                    _telemetry.observe(
-                        "compile_cache_load_ms",
-                        (time.perf_counter() - t0) * 1e3)
+                rspan.annotate(
+                    load_ms=round((time.perf_counter() - t_read) * 1e3, 3))
             except Exception as e:
                 compiled = None
                 logging.warning(
@@ -266,14 +268,12 @@ def aot_compile_cached(jfn, args, disk_key, devices, meta=None):
                 lowered = jfn.lower(*args)
                 t_lo = time.perf_counter()
                 compiled = lowered.compile()
+            t_be = time.perf_counter()
             cstats["source"] = "compiled"
+            cspan.annotate(lower_ms=round((t_lo - t_tr) * 1e3, 3),
+                           backend_ms=round((t_be - t_lo) * 1e3, 3))
             if tel:
                 _telemetry.inc("executor_xla_compile_total")
-                _telemetry.observe("executor_trace_lower_ms",
-                                   (t_lo - t_tr) * 1e3)
-                _telemetry.observe(
-                    "executor_xla_compile_ms",
-                    (time.perf_counter() - t_lo) * 1e3)
             if disk_key is not None:
                 to_store = compiled
                 if (devices[0].platform == "cpu"
@@ -292,6 +292,10 @@ def aot_compile_cached(jfn, args, disk_key, devices, meta=None):
                         "compile_cache: serialize failed: %s", e)
                     _telemetry.inc("compile_cache_errors_total",
                                    kind="serialize")
+                # serialising, the write and, on the CPU, a compile
+                # past tier A
+                cspan.annotate(
+                    store_ms=round((time.perf_counter() - t_be) * 1e3, 3))
         except Exception as e:
             # the lazy path compiles inside the first call — identical
             # semantics, just conflated timing.  Counted: the chip smoke
@@ -415,10 +419,16 @@ class CarriedStepFn:
                     % (self._name or "carried step", key))
             return dict(held[2], source="memory", compile_ms=0.0, key=None)
         devices = self._devices(args)
-        disk_key = self._disk_key(sig, devices)
-        compiled, cstats = aot_compile_cached(
-            self._jfn, args, disk_key, devices,
-            meta={"kind": "carried_step"})
+        # one span a key: the disk key's, the restore's and the compile's
+        # spans are its children, as they are Executor.warmup's
+        with _tracing.span("executor.warmup", fn=self._name,
+                           key=str(key)) as wspan:
+            with _tracing.span("executor.disk_key"):
+                disk_key = self._disk_key(sig, devices)
+            compiled, cstats = aot_compile_cached(
+                self._jfn, args, disk_key, devices,
+                meta={"kind": "carried_step"})
+            wspan.annotate(source=cstats["source"]).device_memory()
         memory = _step_memory(compiled)
         self._compiled[key] = (
             compiled if compiled is not None else self._lazy(sig),
@@ -570,6 +580,8 @@ class Executor:
             finally:
                 phases = sspan.take_phases("executor.")
                 if phases:
+                    if not sspan.attrs.get("cache_hit", True):
+                        sspan.device_memory()
                     # host time of the call: all of it but the two
                     # stretches that hand work to the device and wait for
                     # it
@@ -658,15 +670,16 @@ class Executor:
                 from . import compile_cache as _cc
 
                 _cc.enable_xla_cache()
-                check_before_compile(program, list(feed_arrays), fetch_names,
-                                     scope=scope,
-                                     feed_shapes={n: tuple(a.shape)
-                                                  for n, a in
-                                                  feed_arrays.items()})
-                t_build = time.perf_counter()
-                build = self._build(program, list(feed_arrays), fetch_names,
-                                    mesh, data_axis)
-                build_s = time.perf_counter() - t_build
+                with _tracing.span("executor.build"):
+                    check_before_compile(program, list(feed_arrays),
+                                         fetch_names, scope=scope,
+                                         feed_shapes={n: tuple(a.shape)
+                                                      for n, a in
+                                                      feed_arrays.items()})
+                    t_build = time.perf_counter()
+                    build = self._build(program, list(feed_arrays),
+                                        fetch_names, mesh, data_axis)
+                    build_s = time.perf_counter() - t_build
                 plan = build.plan
                 if build.mesh is not None and mesh is None:
                     mesh = build.mesh
@@ -722,9 +735,10 @@ class Executor:
             # what every subsequent call passes, and compile_ms stops being
             # conflated with the first step's wall time
             devices = self._devices(mesh)
-            disk_key = self._disk_key(program, plan, feed_arrays,
-                                      fetch_names, trace_flags, mesh,
-                                      devices)
+            with _tracing.span("executor.disk_key"):
+                disk_key = self._disk_key(program, plan, feed_arrays,
+                                          fetch_names, trace_flags, mesh,
+                                          devices)
             entry, cstats = self._finalize_compile(
                 build, feed_arrays, params_ro, params_rw, params_carry,
                 rng, disk_key, devices)
@@ -751,116 +765,121 @@ class Executor:
         if mesh is not None:
             sspan.annotate(params_placed=params_placed,
                            params_passed=params_passed)
-        t_step = time.perf_counter() if tel else 0.0
-        try:
-            with ctx, _tracing.phase("executor.dispatch"):
-                fetches, updated, updated_carry = entry.jfn(
-                    feed_arrays, params_ro, params_rw, params_carry, rng)
-        except Exception:
-            if params_carry:
-                # the carry inputs were donated: a failed call may have
-                # consumed them, so drop the cache (next run reconverts
-                # from the still-live f32 masters)
-                cache = scope.__dict__.get("_layout_carry_cache") or {}
-                for n in params_carry:
-                    cache.pop(n, None)
-            if tel:
-                _telemetry.inc("executor_step_errors_total")
-                _telemetry.event("step_error", step=int(counter))
-            raise
+        # the executable's first call, to its fetched result, is part of
+        # set-up (on the chip it loads the program)
+        with (_STEADY if cache_hit
+              else _tracing.device_span("executor.first_run")):
+            t_step = time.perf_counter() if tel else 0.0
+            try:
+                with ctx, _tracing.phase("executor.dispatch"):
+                    fetches, updated, updated_carry = entry.jfn(
+                        feed_arrays, params_ro, params_rw, params_carry, rng)
+            except Exception:
+                if params_carry:
+                    # the carry inputs were donated: a failed call may have
+                    # consumed them, so drop the cache (next run reconverts
+                    # from the still-live f32 masters)
+                    cache = scope.__dict__.get("_layout_carry_cache") or {}
+                    for n in params_carry:
+                        cache.pop(n, None)
+                if tel:
+                    _telemetry.inc("executor_step_errors_total")
+                    _telemetry.event("step_error", step=int(counter))
+                raise
 
-        with _tracing.phase("executor.writeback"):
-            if tel:
-                step_ms = (time.perf_counter() - t_step) * 1e3
-                fetch_bytes = sum(int(getattr(f, "nbytes", 0))
-                                  for f in fetches)
-                no_donate = getattr(program, "_no_donate", False)
-                if cache_hit:
-                    compile_ms = None
-                elif cstats is not None and cstats["source"] != "fallback":
-                    # eager AOT path: plan build + trace/lower + XLA
-                    # compile (or tier-B deserialize) — measured apart
-                    # from the step
-                    compile_ms = build_s * 1e3 + cstats["compile_ms"]
-                else:
-                    # lazy fallback: jit compiles inside the first call,
-                    # so the pre-PR conflation is the honest number
-                    compile_ms = build_s * 1e3 + step_ms
-                _telemetry.record_step(
-                    step_ms, cache_hit,
-                    compile_ms=compile_ms,
-                    donated=0 if no_donate else
-                    len(params_rw) + len(params_carry),
-                    feed_bytes=feed_bytes, fetch_bytes=fetch_bytes,
-                    carry_hits=carry_hits, carry_converts=carry_converts,
-                    params_placed=params_placed,
-                    params_passed=params_passed)
-                cmeta = getattr(program, "_collective_meta", None)
-                if cmeta and cmeta.get("wire_bytes_per_step"):
-                    # analytic bytes-on-ICI for the step's gradient
-                    # exchange (stamped by the collective transpiler; see
-                    # transpiler/collective.py _wire_bytes)
-                    wire = float(cmeta["wire_bytes_per_step"])
-                    _telemetry.inc("collective_wire_bytes_total", wire)
-                    _telemetry.set_gauge("collective_wire_bytes_per_step",
-                                         wire)
-
-            for n, val in updated.items():
-                scope.var(n).set(val)
-            if updated_carry:
-                # refresh the carry cache: pair each bf16 copy with the
-                # scope object it mirrors so staleness is caught by
-                # identity (an external scope.set — checkpoint restore —
-                # forces reconvert)
-                cache = scope.__dict__.setdefault("_layout_carry_cache", {})
-                for n, bf in updated_carry.items():
-                    if n in updated:
-                        cache[n] = (scope.var(n).get_tensor().get(), bf)
-                    elif n in cache:
-                        cache[n] = (cache[n][0], bf)
+            with _tracing.phase("executor.writeback"):
+                if tel:
+                    step_ms = (time.perf_counter() - t_step) * 1e3
+                    fetch_bytes = sum(int(getattr(f, "nbytes", 0))
+                                      for f in fetches)
+                    no_donate = getattr(program, "_no_donate", False)
+                    if cache_hit:
+                        compile_ms = None
+                    elif cstats is not None and cstats["source"] != "fallback":
+                        # eager AOT path: plan build + trace/lower + XLA
+                        # compile (or tier-B deserialize) — measured apart
+                        # from the step
+                        compile_ms = build_s * 1e3 + cstats["compile_ms"]
                     else:
-                        cache[n] = (None, bf)
-            # the step consumed (donated) these inputs and the scope now
-            # holds their successors: drop the last references here, while
-            # the device works, not at the function's return, after the
-            # wait for it
-            del params_rw, params_carry, feed_arrays
+                        # lazy fallback: jit compiles inside the first call,
+                        # so the pre-PR conflation is the honest number
+                        compile_ms = build_s * 1e3 + step_ms
+                    _telemetry.record_step(
+                        step_ms, cache_hit,
+                        compile_ms=compile_ms,
+                        donated=0 if no_donate else
+                        len(params_rw) + len(params_carry),
+                        feed_bytes=feed_bytes, fetch_bytes=fetch_bytes,
+                        carry_hits=carry_hits, carry_converts=carry_converts,
+                        params_placed=params_placed,
+                        params_passed=params_passed)
+                    cmeta = getattr(program, "_collective_meta", None)
+                    if cmeta and cmeta.get("wire_bytes_per_step"):
+                        # analytic bytes-on-ICI for the step's gradient
+                        # exchange (stamped by the collective transpiler; see
+                        # transpiler/collective.py _wire_bytes)
+                        wire = float(cmeta["wire_bytes_per_step"])
+                        _telemetry.inc("collective_wire_bytes_total", wire)
+                        _telemetry.set_gauge("collective_wire_bytes_per_step",
+                                             wire)
 
-        # everything below reads device values on the host: the wait for
-        # the device is here
-        with _tracing.phase("executor.fetch"):
-            if _flag("check_nan_inf"):
-                # reference FLAGS_check_nan_inf (operator.cc:947): scan
-                # outputs; block compilation means we check fetches +
-                # updated state vars
-                for name, val in list(zip(fetch_names, fetches)) + list(
-                        updated.items()):
-                    arr = np.asarray(val)
-                    if np.issubdtype(arr.dtype, np.floating) \
-                            and not np.isfinite(arr).all():
-                        raise RuntimeError(
-                            "Operator output contains NaN/Inf: variable %r "
-                            "(FLAGS_check_nan_inf)" % name)
+                for n, val in updated.items():
+                    scope.var(n).set(val)
+                if updated_carry:
+                    # refresh the carry cache: pair each bf16 copy with the
+                    # scope object it mirrors so staleness is caught by
+                    # identity (an external scope.set — checkpoint
+                    # restore — forces reconvert)
+                    cache = scope.__dict__.setdefault(
+                        "_layout_carry_cache", {})
+                    for n, bf in updated_carry.items():
+                        if n in updated:
+                            cache[n] = (scope.var(n).get_tensor().get(), bf)
+                        elif n in cache:
+                            cache[n] = (cache[n][0], bf)
+                        else:
+                            cache[n] = (None, bf)
+                # the step consumed (donated) these inputs and the scope now
+                # holds their successors: drop the last references here, while
+                # the device works, not at the function's return, after the
+                # wait for it
+                del params_rw, params_carry, feed_arrays
 
-            if ps_meta is not None:
-                # send grads -> barrier -> pull params (the transpiler-
-                # rewritten send/recv op sequence, executed by the runtime
-                # so the compiled step stays pure).  Taken from the FULL
-                # fetch list: a grad the user fetches themselves is still
-                # a grad.
-                all_grads = set(ps_meta["param_grad"].values())
-                grad_vals = {
-                    name: np.asarray(v)
-                    for name, v in zip(fetch_names, fetches)
-                    if name in all_grads
-                }
-                scope._ps_comm.step(scope, grad_vals)
-                n_user = len(fetches) - len(ps_grad_names)
-                fetches = fetches[:n_user]
+            # everything below reads device values on the host: the wait for
+            # the device is here
+            with _tracing.phase("executor.fetch"):
+                if _flag("check_nan_inf"):
+                    # reference FLAGS_check_nan_inf (operator.cc:947): scan
+                    # outputs; block compilation means we check fetches +
+                    # updated state vars
+                    for name, val in list(zip(fetch_names, fetches)) + list(
+                            updated.items()):
+                        arr = np.asarray(val)
+                        if np.issubdtype(arr.dtype, np.floating) \
+                                and not np.isfinite(arr).all():
+                            raise RuntimeError(
+                                "Operator output contains NaN/Inf: variable "
+                                "%r (FLAGS_check_nan_inf)" % name)
 
-            if return_numpy:
-                return [as_numpy(f) for f in fetches]
-            return list(fetches)
+                if ps_meta is not None:
+                    # send grads -> barrier -> pull params (the transpiler-
+                    # rewritten send/recv op sequence, executed by the runtime
+                    # so the compiled step stays pure).  Taken from the FULL
+                    # fetch list: a grad the user fetches themselves is still
+                    # a grad.
+                    all_grads = set(ps_meta["param_grad"].values())
+                    grad_vals = {
+                        name: np.asarray(v)
+                        for name, v in zip(fetch_names, fetches)
+                        if name in all_grads
+                    }
+                    scope._ps_comm.step(scope, grad_vals)
+                    n_user = len(fetches) - len(ps_grad_names)
+                    fetches = fetches[:n_user]
+
+                if return_numpy:
+                    return [as_numpy(f) for f in fetches]
+                return list(fetches)
 
     # -- internals -----------------------------------------------------------
     def _devices(self, mesh):
@@ -1082,26 +1101,29 @@ class Executor:
                     arr = np.asarray(arr, dtype=dtype_to_np(v.dtype))
                 feed_arrays[name] = arr
 
-        self._maybe_fuse_optimizers(program, block, list(feed_arrays),
-                                    fetch_names)
-        key, trace_flags = _cache_key(program, feed_arrays, fetch_names,
-                                      mesh)
-        if devices is None and key in self._cache:
-            return {"source": "memory", "compile_ms": 0.0, "key": None}
         from .analysis import check_before_compile
         from . import compile_cache as _cc
 
-        _cc.enable_xla_cache()
-        check_before_compile(program, list(feed_arrays), fetch_names,
-                             scope=scope,
-                             feed_shapes={n: tuple(a.shape)
-                                          for n, a in feed_arrays.items()})
-        t0 = time.perf_counter()
         # the warmup span stacks over the whole build+compile so the
-        # cache_restore/compile child spans nest under it
+        # build/disk_key/cache_restore/compile child spans nest under it
         with _tracing.span("executor.warmup") as wspan:
-            build = self._build(program, list(feed_arrays), fetch_names,
-                                mesh, data_axis, devices=devices)
+            self._maybe_fuse_optimizers(program, block, list(feed_arrays),
+                                        fetch_names)
+            key, trace_flags = _cache_key(program, feed_arrays, fetch_names,
+                                          mesh)
+            if devices is None and key in self._cache:
+                wspan.annotate(source="memory")
+                return {"source": "memory", "compile_ms": 0.0, "key": None}
+            _cc.enable_xla_cache()
+            with _tracing.span("executor.build"):
+                check_before_compile(program, list(feed_arrays), fetch_names,
+                                     scope=scope,
+                                     feed_shapes={n: tuple(a.shape)
+                                                  for n, a in
+                                                  feed_arrays.items()})
+                t0 = time.perf_counter()
+                build = self._build(program, list(feed_arrays), fetch_names,
+                                    mesh, data_axis, devices=devices)
             plan = build.plan
             if build.mesh is not None and mesh is None:
                 mesh = build.mesh
@@ -1121,19 +1143,18 @@ class Executor:
                     self._place_params(scope, build.param_shardings,
                                        params_ro, params_rw, place_all=True)
             run_devices = self._devices(mesh)
-            disk_key = self._disk_key(program, plan, feed_arrays,
-                                      fetch_names, trace_flags, mesh,
-                                      run_devices)
+            with _tracing.span("executor.disk_key"):
+                disk_key = self._disk_key(program, plan, feed_arrays,
+                                          fetch_names, trace_flags, mesh,
+                                          run_devices)
             entry, cstats = self._finalize_compile(
                 build, feed_arrays, params_ro, params_rw, params_carry,
                 rng, disk_key, run_devices)
-            wspan.annotate(source=cstats["source"])
+            wspan.annotate(source=cstats["source"]).device_memory()
         if devices is None:
             self._cache[key] = entry
         ms = (time.perf_counter() - t0) * 1e3
         _telemetry.inc("executor_warmup_total")
-        _telemetry.event("warmup", source=cstats["source"],
-                         compile_ms=round(ms, 3))
         return {"source": cstats["source"], "compile_ms": ms,
                 "key": disk_key}
 
@@ -1169,8 +1190,11 @@ class Executor:
             return
         from .. import ir as _ir
 
-        _ir.apply_pass("fuse_optimizer_ops_pass", program, None,
-                       protected=set(feed_names) | set(fetch_names))
+        # an IR pass is part of the build: the first attempt at a version
+        # is always under a cache-miss step or a warmup
+        with _tracing.span("executor.build", stage="fuse_optimizer_ops"):
+            _ir.apply_pass("fuse_optimizer_ops_pass", program, None,
+                           protected=set(feed_names) | set(fetch_names))
         # the pass bumps the version when it fuses; mark the new version
         # attempted too so the next run doesn't rescan
         self._fuse_attempted.add((program._uid, program.version))

@@ -58,13 +58,15 @@ import sys
 import threading
 import time
 
+import jax
 from jax.profiler import TraceAnnotation
 
 from . import telemetry as _tm
 
 __all__ = [
     "enabled", "Span", "start_span", "span", "activate", "remote_parent",
-    "record_span", "instant", "phase", "current_span", "current_context",
+    "record_span", "instant", "phase", "device_span", "imported",
+    "current_span", "current_context",
     "traceparent", "parse_traceparent", "set_process_name", "note",
     "flight_dump", "flush", "records", "reset",
 ]
@@ -85,6 +87,8 @@ _flight_json = []          # their serialised forms (None until dumped)
 _recent = collections.deque(maxlen=_RECENT_CAP)
 _unwritten = []            # recorded, not yet in the sink
 _handlers_installed = [False]
+_import = []               # (wall start s, dur ms) of `import paddle_tpu`,
+                           # until the first record takes it with it
 _ids = random.Random(os.urandom(16))   # trace/span ids
 
 
@@ -216,6 +220,21 @@ class Span:
         self.attrs["phases"] = taken
         return taken
 
+    def device_memory(self):
+        """``hbm_in_use_bytes`` and ``hbm_peak_bytes`` of the fullest local
+        device as the runtime's allocator counts them now (set-up spans,
+        at their end); nothing where the backend keeps no such count (the
+        CPU's)."""
+        stats = [s for s in (d.memory_stats() for d in jax.local_devices())
+                 if s]
+        if stats:
+            self.attrs.update(
+                hbm_in_use_bytes=max(int(s.get("bytes_in_use", 0))
+                                     for s in stats),
+                hbm_peak_bytes=max(int(s.get("peak_bytes_in_use", 0))
+                                   for s in stats))
+        return self
+
     def end(self):
         if self._ended:
             return self
@@ -251,6 +270,9 @@ class _NullSpan:
         return self
 
     def link(self, other):
+        return self
+
+    def device_memory(self):
         return self
 
     def take_phases(self, prefix=""):
@@ -329,6 +351,35 @@ def span(name, parent=None, **attrs):
     """``with tracing.span("serving.execute", bucket=4) as s: ...`` —
     opens, stacks, and ends a span around the block."""
     return _SpanCtx(start_span(name, parent=parent, **attrs))
+
+
+class _DeviceSpanCtx(_SpanCtx):
+    """``_SpanCtx`` inside a ``TraceAnnotation`` of the span's name."""
+
+    __slots__ = ("_annotation",)
+
+    def __init__(self, s, name):
+        self.span = s
+        self._annotation = TraceAnnotation(name)
+
+    def __enter__(self):
+        self._annotation.__enter__()
+        return _SpanCtx.__enter__(self)
+
+    def __exit__(self, exc_type, exc, tb):
+        _SpanCtx.__exit__(self, exc_type, exc, tb)
+        self._annotation.__exit__(exc_type, exc, tb)
+        return False
+
+
+def device_span(name, parent=None, **attrs):
+    """``span()`` over a stretch of set-up that waits for the device (an
+    executable's first run, the pools' allocation): as a ``phase`` it is
+    always a ``jax.profiler.TraceAnnotation`` too, so a profile taken over
+    a start names the stretch on the profiler's clock; its duration goes
+    into its own record and not into the thread's phase tally, so a step
+    span's ``phases`` still sum to no more than the step."""
+    return _DeviceSpanCtx(start_span(name, parent=parent, **attrs), name)
 
 
 class _ActivateCtx:
@@ -411,6 +462,21 @@ def record_span(name, wall_start_s, dur_ms, parent=None, trace_id=None,
     s._ended = True
     _emit(s._record())
     return s
+
+
+def imported(wall_start_s, dur_ms):
+    """``import paddle_tpu`` took ``dur_ms`` from ``wall_start_s``: the
+    package's last line says so, before any caller can have set the flag.
+    The pair waits here and becomes the ``setup.import`` span just ahead
+    of the first record this process makes, whenever the flag comes on."""
+    _import[:] = [(float(wall_start_s), float(dur_ms))]
+
+
+def _record_import():
+    with _lock:
+        pair = _import.pop() if _import else None
+    if pair is not None:
+        record_span("setup.import", *pair)
 
 
 def instant(name, **attrs):
@@ -517,6 +583,8 @@ def _emit(rec):
     writing are ``flush()``'s."""
     if not _handlers_installed[0]:
         _install_handlers()
+    if _import:
+        _record_import()
     with _lock:
         _flight.append(rec)
         _flight_json.append(None)

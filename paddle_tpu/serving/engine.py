@@ -150,6 +150,17 @@ def _route_hash(req_id):
     return (zlib.crc32(req_id.encode("utf-8")) & 0xFFFFFFFF) / 2.0 ** 32
 
 
+def _prewarm_attrs(span, manifest):
+    """What one ``prewarm()`` did, on its ``serving.prewarm`` span:
+    (model, bucket) pairs warmed, and their executables by where they came
+    from (a speculative bucket holds three)."""
+    pairs = [got for per in manifest.values() for got in per.values()]
+    sources = [g["source"] for got in pairs
+               for g in ([got] if "source" in got else got.values())]
+    span.annotate(buckets=len(pairs), compiled=sources.count("compiled"),
+                  restored=sources.count("disk")).device_memory()
+
+
 class InferReply:
     """Terminal state of one request: status ok|shed|timeout|error."""
 
@@ -279,12 +290,14 @@ class ServingEngine:
         save_inference_model dir to load one from."""
         from ..inference import AnalysisConfig, AnalysisPredictor
 
-        if isinstance(predictor_or_dir, str):
-            # AnalysisConfig's default place is TPUPlace(0): a server on a
-            # chip machine serves from the chip
-            predictor_or_dir = AnalysisPredictor(
-                AnalysisConfig(predictor_or_dir))
-        self._models[name] = _ModelEntry(name, predictor_or_dir)
+        with _tr.span("serving.add_model", model=name) as span:
+            if isinstance(predictor_or_dir, str):
+                # AnalysisConfig's default place is TPUPlace(0): a server on
+                # a chip machine serves from the chip
+                predictor_or_dir = AnalysisPredictor(
+                    AnalysisConfig(predictor_or_dir))
+            self._models[name] = _ModelEntry(name, predictor_or_dir)
+            span.device_memory()
         return self._models[name].predictor
 
     def models(self):
@@ -371,23 +384,25 @@ class ServingEngine:
         FLAGS_compile_cache_dir set, compiled buckets land in the tier-B
         store and later replicas restore from disk."""
         manifest = {}
-        for name, e in self._models.items():
-            pred = e.predictor
-            per = {}
-            for b in self.buckets:
-                specs = {n: ((b,) + tuple(shape), None)
-                         for n, (shape, _dt) in e.feed_specs.items()}
-                got = pred._exe.warmup(
-                    pred.program(), feed_specs=specs,
-                    fetch_list=pred._fetch_vars, scope=pred._scope)
-                per[b] = {"source": got["source"],
-                          "compile_ms": round(got["compile_ms"], 3)}
-                _tm.inc("serving_prewarm_total", model=name,
-                        source=got["source"])
-                _tm.event("serving_prewarm", model=name, bucket=b,
-                          source=got["source"],
-                          ms=round(got["compile_ms"], 3))
-            manifest[name] = per
+        with _tr.span("serving.prewarm") as span:
+            for name, e in self._models.items():
+                pred = e.predictor
+                per = {}
+                for b in self.buckets:
+                    specs = {n: ((b,) + tuple(shape), None)
+                             for n, (shape, _dt) in e.feed_specs.items()}
+                    got = pred._exe.warmup(
+                        pred.program(), feed_specs=specs,
+                        fetch_list=pred._fetch_vars, scope=pred._scope)
+                    per[b] = {"source": got["source"],
+                              "compile_ms": round(got["compile_ms"], 3)}
+                    _tm.inc("serving_prewarm_total", model=name,
+                            source=got["source"])
+                    _tm.event("serving_prewarm", model=name, bucket=b,
+                              source=got["source"],
+                              ms=round(got["compile_ms"], 3))
+                manifest[name] = per
+            _prewarm_attrs(span, manifest)
         return manifest
 
     # -- admission -----------------------------------------------------------
@@ -1171,6 +1186,17 @@ class DecodeEngine:
         capacity keeps the two allocators in lockstep), and three AOT
         step fns replace the single-token one — verify ([B, k+1] target),
         rollout (k chained draft proposals), ingest (draft catch-up)."""
+        with _tr.span("serving.add_model", model=name) as span:
+            entry = self._add_model(span, name, source, kv_blocks, draft,
+                                    speculative_k)
+            span.device_memory()
+        return entry
+
+    def _add_model(self, span, name, source, kv_blocks, draft,
+                   speculative_k):
+        """``add_model`` below its span: ``serving.lay_out`` (the weights as
+        a step holds them) and ``serving.cache_alloc`` (the pools) are its
+        children."""
         import jax
 
         from . import decode_model as _dm
@@ -1230,7 +1256,10 @@ class DecodeEngine:
             kv_config, model_resident_bytes=resident + draft_resident,
             requested=kv_blocks)
         kv_config.num_blocks = n
-        cache = _kvc.PagedKVCache(kv_config)
+        span.annotate(blocks=n, budget_capped=capped)
+        with _tr.device_span("serving.cache_alloc") as aspan:
+            cache = _kvc.PagedKVCache(kv_config)
+            aspan.annotate(bytes=cache.nbytes)
         prefix = None
         if bool(_flag("prefix_cache")) and not (recurrent or windowed):
             # content-addressed prefix reuse over the SAME pool: sealed
@@ -1245,7 +1274,8 @@ class DecodeEngine:
         # hit would start a sequence at pos > 0 over shared K/V blocks,
         # where the recurrent layers' state at that position is nowhere,
         # and the window layers' K and V before it in no block
-        jparams = _dm.laid_out(cfg, params)
+        with _tr.span("serving.lay_out"):
+            jparams = _dm.laid_out(cfg, params)
         # the weights held in the family's own layout and not as published
         # (the same bytes: ``resident`` stands)
         laid = {key: v for key, v in jparams.items() if key not in params}
@@ -1298,7 +1328,8 @@ class DecodeEngine:
                                         kv_config.dtype)
             entry.spec_k = k
             entry.draft_cfg = dcfg
-            entry.draft_params = _dm.laid_out(dcfg, dparams)
+            with _tr.span("serving.lay_out", draft=True):
+                entry.draft_params = _dm.laid_out(dcfg, dparams)
             base_parts = dict(account.key_parts, model=name, kv={
                 "block_size": kv_config.block_size, "num_blocks": n,
                 "dtype": kv_config.dtype},
@@ -1306,7 +1337,10 @@ class DecodeEngine:
                     dcfg, draft_kv, entry.draft_params,
                     self.buckets).attn_path])
             entry.draft_kv_config = draft_kv
-            entry.draft_cache = _kvc.PagedKVCache(draft_kv)
+            with _tr.device_span("serving.cache_alloc",
+                                 draft=True) as aspan:
+                entry.draft_cache = _kvc.PagedKVCache(draft_kv)
+                aspan.annotate(bytes=entry.draft_cache.nbytes)
             entry.verifyfn = CarriedStepFn(
                 _dm.make_paged_step_multi(cfg, kv_config, k + 1),
                 donate_argnums=(0,), name="decode_verify",
@@ -1326,13 +1360,7 @@ class DecodeEngine:
         # MEM001 static peak beside the KV pool bytes
         _kvc.register_resident_bytes(entry, resident + draft_resident)
         self._models[name] = entry
-        _tm.event("decode_model_added", model=name, blocks=n,
-                  budget_capped=capped, kv_bytes=cache.kv_nbytes,
-                  state_bytes=_kvc.state_bytes(kv_config),
-                  window_bytes=_kvc.window_bytes(kv_config),
-                  speculative_k=k, prefix_cache=prefix is not None,
-                  draft_kv_bytes=entry.draft_cache.nbytes if k else 0)
-        return self._models[name]
+        return entry
 
     def models(self):
         return list(self._models)
@@ -1382,39 +1410,41 @@ class DecodeEngine:
                     "compile_ms": round(got["compile_ms"], 3)}
 
         manifest = {}
-        for name, m in self._models.items():
-            per = {}
-            for b in self.buckets:
-                if m.spec_k > 0:
-                    # speculation replaces the single-token step with
-                    # three fns; warm each per (model, bucket, k)
-                    w = m.spec_k + 1
-                    warms = {
-                        "verify": m.verifyfn.warmup(
-                            (b, w), m.cache.carry(), m.params,
-                            np.zeros((b, w), np.int32),
-                            np.zeros((b, w), np.int32),
-                            np.full((b, m.maxb), -1, np.int32),
-                            np.zeros((b, w), np.int32)),
-                        "draft_rollout": m.rolloutfn.warmup(
-                            (b, m.spec_k), m.draft_cache.carry(),
-                            m.draft_params,
-                            np.zeros(b, np.int32), np.zeros(b, np.int32),
-                            np.full((b, m.maxb), -1, np.int32),
-                            np.zeros(b, np.int32), np.zeros(b, np.int32)),
-                        "draft_ingest": m.ingestfn.warmup(
-                            (b, w), m.draft_cache.carry(), m.draft_params,
-                            np.zeros((b, w), np.int32),
-                            np.zeros((b, w), np.int32),
-                            np.full((b, m.maxb), -1, np.int32),
-                            np.zeros((b, w), np.int32)),
-                    }
-                    per[b] = {kind: note(name, b, kind, got, k=m.spec_k)
-                              for kind, got in warms.items()}
-                    continue
-                per[b] = note(name, b, "decode", m.stepfn.warmup(
-                    b, *self._step_args(m, np.tile(m.idle_lane, (b, 1)))))
-            manifest[name] = per
+        with _tr.span("serving.prewarm") as span:
+            for name, m in self._models.items():
+                per = {}
+                for b in self.buckets:
+                    if m.spec_k > 0:
+                        # speculation replaces the single-token step with
+                        # three fns; warm each per (model, bucket, k)
+                        w = m.spec_k + 1
+                        warms = {
+                            "verify": m.verifyfn.warmup(
+                                (b, w), m.cache.carry(), m.params,
+                                np.zeros((b, w), np.int32),
+                                np.zeros((b, w), np.int32),
+                                np.full((b, m.maxb), -1, np.int32),
+                                np.zeros((b, w), np.int32)),
+                            "draft_rollout": m.rolloutfn.warmup(
+                                (b, m.spec_k), m.draft_cache.carry(),
+                                m.draft_params,
+                                np.zeros(b, np.int32), np.zeros(b, np.int32),
+                                np.full((b, m.maxb), -1, np.int32),
+                                np.zeros(b, np.int32), np.zeros(b, np.int32)),
+                            "draft_ingest": m.ingestfn.warmup(
+                                (b, w), m.draft_cache.carry(), m.draft_params,
+                                np.zeros((b, w), np.int32),
+                                np.zeros((b, w), np.int32),
+                                np.full((b, m.maxb), -1, np.int32),
+                                np.zeros((b, w), np.int32)),
+                        }
+                        per[b] = {kind: note(name, b, kind, got, k=m.spec_k)
+                                  for kind, got in warms.items()}
+                        continue
+                    per[b] = note(name, b, "decode", m.stepfn.warmup(
+                        b, *self._step_args(m, np.tile(m.idle_lane, (b, 1)))))
+                manifest[name] = per
+            _prewarm_attrs(span, manifest)
         return manifest
 
     @staticmethod

@@ -60,7 +60,13 @@ def _no_spans_left_for_the_next_test():
     """Spans wait in the process's buffer for a flush.  A traced test that
     never flushes (or resets) would hand its spans to whichever traced test
     its xdist worker runs next, in another file as well, and that test
-    would count them: drop what is left unwritten when a test ends."""
+    would count them: drop what is left unwritten when a test ends.  The
+    package's own ``setup.import`` span waits for a process's first record
+    in the same way (``tracing.imported``), and the test that happened to
+    make that record would count it: a test that wants it arms it itself."""
+    tracing = sys.modules.get("paddle_tpu.core.tracing")
+    if tracing is not None:
+        del tracing._import[:]
     yield
     tracing = sys.modules.get("paddle_tpu.core.tracing")
     if tracing is not None and tracing._unwritten:

@@ -141,8 +141,9 @@ def main(argv=None):
     ap.add_argument("--compile", action="store_true", dest="compile_only",
                     help="show only compilation metrics: the two-tier "
                     "cache (compile_cache_* hit/miss/store/eviction/error "
-                    "counters, load/store latency) and the executor's "
-                    "trace/lower/XLA-compile breakdown")
+                    "counters) and the executor's compile, miss, warmup "
+                    "and fallback counts (the durations are the set-up "
+                    "spans': tools/trace_view.py --setup)")
     ap.add_argument("--kernels", action="store_true", dest="kernels_only",
                     help="show only Pallas kernel-adoption metrics: the "
                     "pallas_kernel_used_total{kernel} / "
@@ -222,9 +223,8 @@ def main(argv=None):
         snap = _filter_snap(snap, ("collective_", "zero1_"))
     if args.compile_only:
         snap = _filter_snap(snap, ("compile_cache_", "executor_compile",
-                                   "executor_xla_", "executor_trace_",
-                                   "executor_cache_", "executor_aot_",
-                                   "executor_warmup"))
+                                   "executor_xla_", "executor_cache_",
+                                   "executor_aot_", "executor_warmup"))
     if args.kernels_only:
         snap = _filter_snap(snap, "pallas_kernel_")
     if args.serving_only:

@@ -25,10 +25,21 @@ document:
 Usage:
     python tools/trace_view.py --telemetry_dir /tmp/tel --out trace.json
     python tools/trace_view.py --telemetry_dir ... --out ... --require-flow
+    python tools/trace_view.py --telemetry_dir /tmp/tel --setup
 
 ``--require-flow`` exits non-zero unless at least one cross-process flow
 was emitted (the --trace-smoke CI gate).  Open the output in
 https://ui.perfetto.dev or chrome://tracing.
+
+``--setup`` writes no file: it prints each process's set-up tree, the
+view to read after a slow start.  The roots are ``setup.import``, the
+``executor.step``s that missed the cache, ``executor.warmup``,
+``serving.add_model`` and ``serving.prewarm``; under each its children
+by parent id (``executor.build``, ``.disk_key``, ``.cache_restore``,
+``.compile``, ``.first_run``, ``serving.lay_out``, ``.cache_alloc``), a
+line a span: when it began after the process's first span, its duration,
+its self time (the duration less what its children cover) and its
+attributes.
 """
 
 import argparse
@@ -198,12 +209,67 @@ def merge(procs):
             "displayTimeUnit": "ms"}, flows, local_flows
 
 
+_SETUP_ROOTS = ("setup.import", "executor.warmup", "serving.add_model",
+                "serving.prewarm")
+
+
+def _starts_set_up(r):
+    return r["name"] in _SETUP_ROOTS or (
+        r["name"] == "executor.step"
+        and r.get("attrs", {}).get("cache_hit") is False)
+
+
+def setup_roots(records):
+    """The spans a set-up tree starts from, oldest first: the named roots
+    and every ``executor.step`` that missed the cache, where none of them
+    lies above (a bucket's ``executor.warmup`` is its prewarm's child)."""
+    spans = {r["sid"]: r for r in records if r.get("t") == "span"}
+
+    def nested(r):
+        above = spans.get(r.get("parent"))
+        return above is not None and (_starts_set_up(above) or nested(above))
+
+    return sorted((r for r in spans.values()
+                   if _starts_set_up(r) and not nested(r)),
+                  key=lambda r: r["ts"])
+
+
+def setup_tree(records):
+    """-> lines: each root of ``setup_roots`` and what lies under it."""
+    spans = [r for r in records if r.get("t") == "span"]
+    children = {}
+    for r in spans:
+        children.setdefault(r.get("parent"), []).append(r)
+    t0 = min((r["ts"] for r in spans), default=0)
+    lines = []
+
+    def walk(r, depth):
+        below = sorted(children.get(r["sid"], ()), key=lambda c: c["ts"])
+        covered = sum(c["dur"] for c in below)
+        # a step's phase tally is left out: the tree says it
+        attrs = {k: v for k, v in r.get("attrs", {}).items()
+                 if k != "phases"}
+        lines.append("%9.3f s  %-34s %10.3f s  self %10.3f s  %s"
+                     % ((r["ts"] - t0) / 1e6, "  " * depth + r["name"],
+                        r["dur"] / 1e6, max(r["dur"] - covered, 0) / 1e6,
+                        json.dumps(attrs, sort_keys=True) if attrs else ""))
+        for c in below:
+            walk(c, depth + 1)
+
+    for root in setup_roots(records):
+        walk(root, 0)
+    return lines
+
+
 def main(argv=None):
     ap = argparse.ArgumentParser(
         description="merge per-process trace-*.jsonl into trace.json")
     ap.add_argument("--telemetry_dir", required=True,
                     help="FLAGS_telemetry_dir of the traced run")
-    ap.add_argument("--out", required=True, help="output trace.json path")
+    ap.add_argument("--out", help="output trace.json path")
+    ap.add_argument("--setup", action="store_true",
+                    help="print each process's set-up tree (durations and "
+                    "self times) instead of writing trace.json")
     ap.add_argument("--require-flow", action="store_true",
                     help="exit 1 unless >=1 cross-process flow merged")
     args = ap.parse_args(argv)
@@ -212,6 +278,13 @@ def main(argv=None):
         print("no trace-*.jsonl under %s" % args.telemetry_dir,
               file=sys.stderr)
         return 1
+    if args.setup:
+        for pid, name, records in procs:
+            print("%s (pid %d)" % (name, pid))
+            print("\n".join(setup_tree(records)))
+        return 0
+    if not args.out:
+        ap.error("--out is required unless --setup is given")
     trace, flows, local_flows = merge(procs)
     with open(args.out, "w") as f:
         json.dump(trace, f)
